@@ -202,6 +202,12 @@ def run_trial_child(spec: Dict[str, Any],
     costs one trial, not the tuner. Only specs the trial module can
     reconstruct from JSON are supported (the built-in demo model zoo —
     see `trial.py`); in-process measurement has no such limit."""
+    # `--isolation process` from a session that already measured in-process
+    # (and so owns the chip) cannot work: every trial child would die at
+    # backend start-up
+    from deepspeed_tpu.platform.device import \
+        refuse_spawn_if_holding_accelerator
+    refuse_spawn_if_holding_accelerator("dstpu_tune --isolation process")
     rec, proc = run_json_child(
         [sys.executable, "-m", "deepspeed_tpu.autotuning.trial"],
         {TRIAL_ENV: json.dumps(spec, sort_keys=True)},

@@ -23,7 +23,7 @@ from enum import Enum
 import jax
 import jax.numpy as jnp
 import numpy as np
-from deepspeed_tpu.utils.jax_compat import shard_map
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from deepspeed_tpu.comm import collectives

@@ -90,8 +90,9 @@ class MeshSpec:
             assert n % fixed == 0, f"{n} devices not divisible by fixed axes product {fixed}"
             sizes[unknown[0]] = n // fixed
         spec = cls(**sizes)
-        # A spec smaller than the device count is allowed (uses the first
-        # world_size devices) — useful for tests and partial-slice runs.
+        # A spec smaller than the device count is allowed — tests and
+        # partial-slice runs use it — but never quietly: init_mesh names
+        # the devices it leaves idle.
         assert spec.world_size <= n, (
             f"mesh {spec} needs {spec.world_size} devices but only {n} are present")
         return spec
@@ -156,10 +157,16 @@ def init_mesh(mesh_config=None, devices=None, n_devices=None) -> Mesh:
     from deepspeed_tpu.config.core import MeshConfig
     mesh_config = mesh_config or MeshConfig()
     spec = MeshSpec.resolve(mesh_config, n_devices=n_devices or (len(devices) if devices else None))
-    devices = list(devices if devices is not None else jax.devices())[:spec.world_size]
+    present = list(devices if devices is not None else jax.devices())
+    devices, unused = present[:spec.world_size], present[spec.world_size:]
     mesh = build_mesh(spec, devices)
     set_mesh(mesh, spec)
     logger.info(f"mesh initialized: {spec} over {spec.world_size} devices")
+    if unused:
+        logger.warning(
+            f"mesh {spec} uses {len(devices)} of {len(present)} devices in "
+            f"jax.devices() list order; {len(unused)} left idle: "
+            f"{[str(d) for d in unused]}")
     return mesh
 
 

@@ -289,7 +289,9 @@ class TelemetryConfig(ConfigModel):
                                      # scalars so TB/WandB/CSV keep working
     chrome_trace: bool = False       # host-side span timeline (Perfetto)
     peak_tflops: float = 0.0         # per-chip peak override for MFU (TFLOPs);
-                                     # 0 = auto-detect from the device kind
+                                     # 0 = the live device_kind's published
+                                     # peak (platform/device.py); an unknown
+                                     # device then publishes no MFU gauge
     measure_program_flops: bool = True  # MFU numerator: cost-analyze the
                                      # compiled step once at first step (XLA's
                                      # exact program flops — an extra one-time

@@ -398,6 +398,8 @@ class InferenceEngine:
 def init_inference(model=None, config=None, **kwargs):
     """Reference signature (`deepspeed/__init__.py:269`): accepts config dict/path +
     kwargs overrides."""
+    from deepspeed_tpu.platform.device import ensure_compile_cache
+    ensure_compile_cache()
     if config is None:
         config = {}
     if isinstance(config, str):

@@ -49,7 +49,7 @@ from deepspeed_tpu.comm import mesh as mesh_mod
 from deepspeed_tpu.comm.mesh import SEQ_AXIS
 from deepspeed_tpu.inference.kv_cache import (BlockAllocator, blocks_needed,
                                               gather_block_kv)
-from deepspeed_tpu.utils.jax_compat import shard_map
+from jax import shard_map
 
 SPAN_TRASH = 0   # LOCAL physical block 0 of every shard: that shard's trash
 

@@ -36,7 +36,7 @@ def run_collective(op, size_bytes, trials, warmup, dtype_name="bfloat16"):
 
     x = jax.device_put(jnp.ones((elems,), dtype), NamedSharding(mesh, P(axes)))
 
-    from deepspeed_tpu.utils.jax_compat import shard_map
+    from jax import shard_map
 
     if op == "all_reduce":
         def body(v):

@@ -136,6 +136,18 @@ def main(args=None):
         cmd_head = [sys.executable, "-u"]
     cmd = cmd_head + [args.training_script] + args.training_script_args
 
+    if args.procs_per_node > 1:
+        # no per-process chip assignment exists here: on TPU hardware every
+        # rank would initialise the same chips and all but the first die
+        # at start-up ("The TPU is already in use"). One process drives all
+        # of a host's chips; >1 is the multi-controller CPU harness
+        logger.warning(
+            f"launch: --procs_per_node={args.procs_per_node} starts that "
+            f"many processes with the SAME device visibility; on a TPU "
+            f"host only one process can own the chips")
+    from deepspeed_tpu.platform.device import \
+        refuse_spawn_if_holding_accelerator
+    refuse_spawn_if_holding_accelerator("launcher/launch.py")
     procs = []
     for local_rank in range(args.procs_per_node):
         env = build_rank_env(world_info, node_rank, local_rank,

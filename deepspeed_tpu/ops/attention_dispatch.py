@@ -252,9 +252,49 @@ def _run_ring_ulysses(q, k, v, *, causal=True, sm_scale=None):
     return ring_ulysses_attention(q, k, v, causal=causal, sm_scale=sm_scale)
 
 
+def _per_shard(kernel, q):
+    """Run a zoo-layout ([B, T, H, hd]) attention kernel on each device's
+    own batch/head shard.
+
+    A compiled `pallas_call` is an opaque custom call: GSPMD cannot
+    partition it, so inside the engine's partitioned `jit` it all-gathers
+    q/k/v over `data` and runs the WHOLE batch on every chip (the CPU
+    interpreter lowers the kernel to ordinary HLO, which GSPMD does
+    partition — so virtual-device runs never showed this). `shard_map`
+    over the batch axes and the head axis hands the kernel the per-shard
+    operands instead. The sequence dim stays whole: this kernel attends
+    over all of T (context parallelism is the ring programs' job).
+
+    Left bare when there is nothing to split: no mesh, batch and tensor
+    axes of size 1, a trace already inside a shard_map body
+    (`constraints_disabled`), or a batch/head count the axes do not divide
+    (XLA replicates such an array anyway)."""
+    import jax
+    from jax.sharding import PartitionSpec as P
+
+    from deepspeed_tpu.comm import mesh as mesh_mod
+    if not mesh_mod.has_mesh() or mesh_mod._CONSTRAINTS_DISABLED:
+        return kernel
+    B, _, H, _ = q.shape
+    n_batch = mesh_mod.axis_size(mesh_mod.BATCH_AXES)
+    n_heads = mesh_mod.axis_size(mesh_mod.TENSOR_AXIS)
+    batch = mesh_mod.BATCH_AXES if n_batch > 1 and B % n_batch == 0 else None
+    heads = mesh_mod.TENSOR_AXIS if n_heads > 1 and H % n_heads == 0 else None
+    if batch is None and heads is None:
+        return kernel
+    spec = P(batch, None, heads, None)
+    return jax.shard_map(kernel, mesh=mesh_mod.get_mesh(),
+                         in_specs=(spec, spec, spec), out_specs=spec,
+                         check_vma=False)
+
+
 def _run_flash(q, k, v, *, causal=True, sm_scale=None):
+    import functools
+
     from deepspeed_tpu.ops.pallas.flash_attention import flash_attention
-    return flash_attention(q, k, v, causal=causal, sm_scale=sm_scale)
+    kernel = functools.partial(flash_attention, causal=causal,
+                               sm_scale=sm_scale)
+    return _per_shard(kernel, q)(q, k, v)
 
 
 def _run_chunked(q, k, v, *, causal=True, sm_scale=None):

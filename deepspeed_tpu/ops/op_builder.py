@@ -7,8 +7,10 @@ is Pallas/XLA; native code here is host-side (AIO swap, CPU optimizers).
 """
 
 import ctypes
+import hashlib
 import os
 import pathlib
+import platform
 import subprocess
 import threading
 
@@ -19,6 +21,25 @@ _CSRC = _REPO_ROOT / "csrc"
 _BUILD_DIR = pathlib.Path(__file__).resolve().parent / "_native"
 _LOCK = threading.Lock()
 _LOADED = {}
+
+
+def _host_cpu():
+    """Identity of the CPU `-march=native` compiles for: architecture, model
+    name and feature flags (Linux /proc/cpuinfo; elsewhere what `platform`
+    knows)."""
+    parts = [platform.machine(), platform.processor()]
+    try:
+        with open("/proc/cpuinfo") as f:
+            seen = set()
+            for line in f:
+                field = line.split(":", 1)[0].strip()
+                if field in ("model name", "flags", "Features") \
+                        and field not in seen:
+                    seen.add(field)
+                    parts.append(line.strip())
+    except OSError:
+        pass
+    return "|".join(parts)
 
 
 class OpBuilder:
@@ -36,17 +57,35 @@ class OpBuilder:
     def sources(self):
         return [str(_CSRC / s) for s in self.SOURCES]
 
+    FLAGS = ("-O3", "-march=native", "-fopenmp", "-fPIC", "-std=c++17")
+
+    def build_key(self):
+        """What a built library is a function of: the sources' bytes, the
+        compiler flags, and — because of `-march=native` — the CPU that
+        compiled it. A library found in the tree is reused only when its
+        recorded key matches: the `.so` files are git-ignored but travel
+        with a copied working tree, and one built for another host's CPU
+        must be rebuilt, not loaded (mtime said "fresh" for those)."""
+        h = hashlib.sha256(" ".join(self.FLAGS).encode())
+        for src in self.sources():
+            h.update(pathlib.Path(src).read_bytes())
+        h.update(_host_cpu().encode())
+        return h.hexdigest()
+
     def build(self, verbose=False):
         out = self.lib_path()
-        srcs = self.sources()
-        if out.exists() and all(out.stat().st_mtime >= pathlib.Path(s).stat().st_mtime
-                                for s in srcs):
+        stamp = out.with_suffix(".so.key")
+        key = self.build_key()
+        if out.exists() and stamp.exists() and stamp.read_text() == key:
             return out
         _BUILD_DIR.mkdir(parents=True, exist_ok=True)
-        cmd = ["g++", "-O3", "-march=native", "-fopenmp", "-fPIC", "-std=c++17",
-               *srcs, "-shared", "-lpthread", "-o", str(out)]
+        tmp = out.with_suffix(f".so.tmp{os.getpid()}")
+        cmd = ["g++", *self.FLAGS, *self.sources(), "-shared", "-lpthread",
+               "-o", str(tmp)]
         logger.info(f"building native op {self.NAME}: {' '.join(cmd)}")
         subprocess.run(cmd, check=True, capture_output=not verbose)
+        os.replace(tmp, out)            # atomic: concurrent builders race safely
+        stamp.write_text(key)
         return out
 
     def load(self, verbose=False):

@@ -22,7 +22,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from deepspeed_tpu.ops.pallas.flash_attention import _use_interpret
+from deepspeed_tpu.platform.device import pallas_interpret
 
 NEG_INF = -1e30
 DEFAULT_BLOCK_Q = 128
@@ -226,14 +226,12 @@ def evoformer_attention(q, k, v, biases=(), sm_scale=None,
     vi = jnp.moveaxis(v, 3, 2)
 
     if interpret is None:
-        interpret = _use_interpret()
+        interpret = pallas_interpret()
     bq, bk = min(block_q, S), min(block_k, S)
+    # ONE shape rule on every platform: a block-tileable S takes the kernel,
+    # which on a TPU compiles through Mosaic or raises the compiler's error
+    # — never a second, quieter path that only hardware runs take
     use_pallas = S % bq == 0 and S % bk == 0 and S >= 8
-    if use_pallas and not interpret:
-        # On real hardware require tile-aligned shapes (8-sublane blocks,
-        # 128-lane head dim) — same conservatism as flash_attention; anything
-        # else falls back to the XLA path until hardware-verified.
-        use_pallas = bq % 8 == 0 and bk % 8 == 0 and D % 128 == 0
     mode = (bq, bk, interpret) if use_pallas else None
     if mode is None:
         out = _evo_core(qi, ki, vi, mask, pair, float(sm_scale), 0, 0, "jnp")

@@ -42,7 +42,7 @@ from jax.sharding import PartitionSpec as P
 from deepspeed_tpu.comm import collectives as coll
 from deepspeed_tpu.comm.mesh import (BATCH_AXES, EXPERT_AXIS, SEQ_AXIS,
                                      TENSOR_AXIS, shard_constraint)
-from deepspeed_tpu.utils.jax_compat import shard_map
+from jax import shard_map
 
 
 def _capacity(num_tokens, num_experts, capacity_factor, min_capacity):

@@ -30,7 +30,7 @@ from typing import Any, Callable, List, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from deepspeed_tpu.utils.jax_compat import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from deepspeed_tpu.comm import collectives as coll

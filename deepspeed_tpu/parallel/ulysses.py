@@ -16,7 +16,7 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
-from deepspeed_tpu.utils.jax_compat import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from deepspeed_tpu.comm import mesh as mesh_mod

@@ -242,13 +242,8 @@ class Engine:
             or (config.optimizer and
                 config.optimizer.type.lower().startswith("deepspeedcpu")))
         if cpu_off and not force_host_step:
-            try:
-                from deepspeed_tpu.platform import get_accelerator
-                hbm = get_accelerator().total_memory()
-            except Exception:
-                hbm = 0
-            if not hbm:  # stats unavailable (e.g. tunneled runtimes): assume v5e
-                hbm = 16 * 2**30
+            from deepspeed_tpu.platform.device import device_memory_bytes
+            hbm = device_memory_bytes()
             # params bf16 + fp32 master + adam m/v transit HBM in the update —
             # PER DEVICE: ZeRO partitions the state over the data domain
             shards = max(mesh_mod.axis_size(mesh_mod.ZERO_AXES), 1)
@@ -648,13 +643,7 @@ class Engine:
 
     def _to_host(self, tree):
         """Move a pytree to pinned host memory (ZeRO-Offload optimizer states)."""
-        try:
-            return jax.device_put(tree, self._host_opt_shardings())
-        except Exception as e:  # CPU backend has no pinned_host memory space
-            logger.warning(f"optimizer-state host offload unavailable on this platform ({e}); "
-                           "keeping states in device memory")
-            self.offload_optimizer_states = False
-            return tree
+        return jax.device_put(tree, self._host_opt_shardings())
 
     def _stream_opt_to_device(self, state):
         """Eager half of the multi-device offload tier: states → HBM."""
@@ -833,7 +822,7 @@ class Engine:
         Supported on pure data-parallel meshes (tensor/sequence/pipe/expert = 1),
         matching the reference's DP-only scope for these features.
         """
-        from deepspeed_tpu.utils.jax_compat import shard_map
+        from jax import shard_map
         from deepspeed_tpu.runtime import quantized_collectives as qc
 
         zcfg = self.config.zero_optimization
@@ -911,7 +900,7 @@ class Engine:
         per-micro quantized path; like it, this needs a data-domain-only
         mesh.
         """
-        from deepspeed_tpu.utils.jax_compat import shard_map
+        from jax import shard_map
         from deepspeed_tpu.comm import collectives as coll
         from deepspeed_tpu.runtime import quantized_collectives as qc
 
@@ -1311,6 +1300,19 @@ class Engine:
     # public API (reference parity)
     # ------------------------------------------------------------------
 
+    def lower_train_step(self, batch):
+        """The fused train step, traced and lowered for `batch` but not run
+        (`jax.stages.Lowered`): what it compiles to (`.compile().as_text()`),
+        its cost and memory analysis, and the comm facade's trace-time byte
+        accounting all read from here."""
+        if self._train_step is None:
+            raise ValueError("the host-offload optimizer step has no fused "
+                             "train-step program to lower")
+        args = (self.state, self._maybe_split_gas(batch))
+        if self._comm_err is not None:
+            args += (self._comm_err,)
+        return self._train_step.lower(*args)
+
     def train_batch(self, batch=None, data_iter=None):
         """One full optimizer step: GAS micro-batches fused into one XLA program.
 
@@ -1596,8 +1598,8 @@ class Engine:
         """Per-step observability: step-time histogram, tokens/s gauge, and
         achieved MFU = program flops / (step wall time x per-chip peak).
         Program flops are measured ONCE (see _measure_program_flops); the
-        peak comes from the device-generation table with a
-        `telemetry.peak_tflops` override knob."""
+        peak is the live device_kind's published one or the
+        `telemetry.peak_tflops` override — with neither, no MFU gauge."""
         reg = self.telemetry.registry
         reg.histogram("train/step_time_ms").observe(step_seconds * 1e3)
         tokens = None
@@ -1612,7 +1614,9 @@ class Engine:
         if self._program_flops > 0:
             achieved = self._program_flops / step_seconds   # per-chip FLOPs/s
             reg.gauge("train/tflops_per_chip").set(achieved / 1e12)
-            reg.gauge("train/mfu").set(achieved / self.telemetry.peak_flops())
+            peak = self.telemetry.peak_flops()
+            if peak:
+                reg.gauge("train/mfu").set(achieved / peak)
         # device-memory watermarks (best-effort: the CPU harness and some
         # runtimes expose no allocator stats)
         try:
@@ -1932,6 +1936,8 @@ def initialize(args=None,
     `args.deepspeed_config`).
     """
     assert model is not None, "deepspeed_tpu.initialize: model is required"
+    from deepspeed_tpu.platform.device import ensure_compile_cache
+    ensure_compile_cache()
     if config is None and config_params is not None:
         config = config_params
     if config is None and args is not None:

@@ -241,6 +241,14 @@ class ReplicaProcess:
         tree = os.path.dirname(os.path.dirname(os.path.abspath(_pkg.__file__)))
         env["PYTHONPATH"] = tree + os.pathsep + env.get("PYTHONPATH", "")
         env.update(self._env_overrides)
+        # every replica child initialises its own backend: on a one-chip
+        # host the first to start owns the chip (later siblings exit at
+        # start-up and wait_ready reports it); a parent that already holds
+        # it can start none
+        from deepspeed_tpu.platform.device import \
+            refuse_spawn_if_holding_accelerator
+        refuse_spawn_if_holding_accelerator(
+            f"ReplicaProcess({self.replica_id})", env)
         cmd = [sys.executable, "-m", "deepspeed_tpu.serving.replica_server",
                "--factory", self.factory,
                "--kwargs", json.dumps(self.factory_kwargs),

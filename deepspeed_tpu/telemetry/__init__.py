@@ -168,13 +168,17 @@ class Telemetry:
             e.export(self.registry, step=step, snapshot=snap)
 
     def peak_flops(self):
-        """Per-chip peak FLOPs/s: the config override (TFLOPs) when set,
-        else the generation table in `profiling/flops_profiler.py`."""
+        """Per-chip peak FLOPs/s: the `peak_tflops` override when set, else
+        the published peak of the live `device_kind`
+        (`platform/device.py::DEVICE_PEAKS`), else None — a device nobody
+        measured on (the CPU harness included) gets NO `train/mfu` gauge
+        rather than a utilization against another chip's peak."""
         override = float(getattr(self.config, "peak_tflops", 0.0) or 0.0)
         if override > 0:
             return override * 1e12
-        from deepspeed_tpu.profiling.flops_profiler import _peak_flops
-        return _peak_flops()
+        from deepspeed_tpu.platform.device import DEVICE_PEAKS, device_kind
+        peaks = DEVICE_PEAKS.get(device_kind())
+        return None if peaks is None else peaks.bf16_tflops * 1e12
 
     def close(self):
         if self._closed:

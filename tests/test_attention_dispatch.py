@@ -318,3 +318,61 @@ class TestCompileStability:
         assert len(done) == 4
         assert serving.compile_stats() == {"decode_step": 1,
                                            "prefill_step": 1}
+
+
+class TestFlashRunsPerShard:
+    """A compiled pallas_call is opaque to GSPMD: bare inside the engine's
+    partitioned jit, its q/k/v are all-gathered over `data` and the whole
+    batch runs on every chip (seen in the four-chip HLO, PR 21 — and in the
+    CPU interpreter's HLO too). The flash runner hands the kernel one
+    device's batch/head shard through shard_map instead."""
+
+    def _qkv(self, B=4, T=128, H=4, hd=32):
+        rng = np.random.default_rng(0)
+        return tuple(jnp.asarray(rng.normal(0, 1, (B, T, H, hd)), jnp.float32)
+                     for _ in range(3))
+
+    @staticmethod
+    def _jaxpr(q, k, v):
+        # a fresh callable per trace: the runner reads the installed mesh at
+        # trace time, and jax caches traces by function identity
+        runner = ad.get_program("flash").runner
+        return str(jax.make_jaxpr(lambda q, k, v: runner(q, k, v))(q, k, v))
+
+    def test_shard_map_over_batch_and_heads(self, devices8):
+        from deepspeed_tpu.comm import mesh as mesh_mod
+        from deepspeed_tpu.config.core import MeshConfig
+        q, k, v = self._qkv()
+        runner = ad.get_program("flash").runner
+        bare = runner(q, k, v)                         # no mesh: bare kernel
+        assert "shard_map" not in self._jaxpr(q, k, v)
+
+        mesh_mod.init_mesh(MeshConfig(data=4, tensor=2))
+        jaxpr = self._jaxpr(q, k, v)
+        assert "shard_map" in jaxpr and "pallas_call" in jaxpr
+        # the kernel inside sees ONE device's share: B/4 x H/2 -> BH = 2
+        assert "f32[2,128,32]" in jaxpr and "f32[16,128,32]" not in jaxpr
+        sharded = jax.jit(lambda q, k, v: runner(q, k, v))
+        np.testing.assert_allclose(np.asarray(sharded(q, k, v)),
+                                   np.asarray(bare), rtol=1e-5, atol=1e-5)
+
+        # compiled: no activation-shaped all-gather feeds the kernel
+        spec = jax.sharding.NamedSharding(
+            mesh_mod.get_mesh(),
+            jax.sharding.PartitionSpec(mesh_mod.BATCH_AXES, None,
+                                       mesh_mod.TENSOR_AXIS, None))
+        placed = jax.device_put((q, k, v), spec)
+        hlo = sharded.lower(*placed).compile().as_text()
+        assert " all-gather(" not in hlo and " all-gather-start(" not in hlo
+
+    def test_left_bare_when_nothing_divides_or_inside_a_shard_map(
+            self, devices8):
+        from deepspeed_tpu.comm import mesh as mesh_mod
+        from deepspeed_tpu.config.core import MeshConfig
+        mesh_mod.init_mesh(MeshConfig(data=8))
+        q, k, v = self._qkv(B=4)                       # 4 rows, 8-way data
+        assert "shard_map" not in self._jaxpr(q, k, v)
+        q, k, v = self._qkv(B=8)
+        assert "shard_map" in self._jaxpr(q, k, v)
+        with mesh_mod.constraints_disabled():          # a shard_map body
+            assert "shard_map" not in self._jaxpr(q, k, v)

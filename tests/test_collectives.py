@@ -18,7 +18,7 @@ from deepspeed_tpu.comm import collectives as coll
 from deepspeed_tpu.comm import comm
 from deepspeed_tpu.comm import mesh as mesh_mod
 from deepspeed_tpu.config.core import MeshConfig
-from deepspeed_tpu.utils.jax_compat import shard_map
+from jax import shard_map
 
 
 def _mk_mesh(**axes):

@@ -82,8 +82,7 @@ def _compile_step(config, cfg=CFG, attn_fn=None, seq=33):
         "steps_per_print": 10**9, **config})
     batch = {"tokens": np.random.default_rng(0).integers(
         0, cfg.vocab_size, (e.train_batch_size(), seq)).astype(np.int32)}
-    placed = e._maybe_split_gas(batch)
-    txt = e._train_step.lower(e.state, placed).compile().as_text()
+    txt = e.lower_train_step(batch).compile().as_text()
     n_params = sum(x.size for x in jax.tree_util.tree_leaves(e.state.params))
     return e, n_params, collective_profile(txt)
 
@@ -276,9 +275,8 @@ def test_int8_grad_reduce_wire_bytes_from_facade_stats():
             "mesh": {"data": 8},
             "steps_per_print": 10**9})
         batch = {"x": np.ones((8, 256), np.float32)}
-        placed = e._maybe_split_gas(batch)
         coll.stats.reset()
-        e._train_step.lower(e.state, placed)   # trace → stats record
+        e.lower_train_step(batch)              # trace → stats record
         return coll.stats.snapshot()
 
     fp = build({})

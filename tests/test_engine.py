@@ -201,7 +201,7 @@ class TestCommParitySurface:
     def test_p2p_shift_in_shard_map(self):
         import deepspeed_tpu.comm as comm
         from jax.sharding import PartitionSpec as P
-        from deepspeed_tpu.utils.jax_compat import shard_map
+        from jax import shard_map
         mesh = self._mesh(data=8)
         x = jnp.arange(8, dtype=jnp.float32)
 

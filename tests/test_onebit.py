@@ -167,15 +167,20 @@ class TestEngineIntegration:
 class TestFacadeWireParity:
     def test_sign_compress_is_the_facade_onebit_wire(self):
         """_sign_compress now runs onebit_encode/decode (comm facade) — on
-        nonzero inputs it must be bit-identical to the inline sign*mean|x|
-        formula it replaced (the old 1-bit Adam compression rule)."""
+        nonzero inputs it must be the inline sign*mean|x| formula it
+        replaced (the old 1-bit Adam compression rule). Same formula, not
+        same ulp: the facade's mean reduces in another order, and under
+        jaxlib 0.9.0 the two differ by 3e-7."""
         from deepspeed_tpu.runtime.compressed_grads import _sign_compress
         for seed, shape in ((0, (257,)), (1, (33, 7)), (2, (128,))):
             x = jax.random.normal(jax.random.PRNGKey(seed), shape, jnp.float32)
             old = jnp.sign(x) * jnp.mean(jnp.abs(x))
             new = _sign_compress(x)
             assert new.shape == x.shape and new.dtype == jnp.float32
-            np.testing.assert_array_equal(np.asarray(new), np.asarray(old))
+            np.testing.assert_array_equal(np.sign(np.asarray(new)),
+                                          np.sign(np.asarray(old)))
+            np.testing.assert_allclose(np.asarray(new), np.asarray(old),
+                                       rtol=1e-6)
 
     def test_sign_compress_zero_maps_to_plus_scale(self):
         """The wire packs sign(0) as +1 (one bit per value); the EF residual
