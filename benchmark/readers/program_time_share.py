@@ -1,0 +1,10 @@
+"""Device time under the jitted programs whose name matches, over the
+device's busy time in the traced window."""
+import xplane
+
+
+def read(obs, trace, args):
+    if trace is None or not trace["busy_s"]:
+        return None
+    return 100.0 * xplane.matching(trace["modules"], args["match"]) \
+        / trace["busy_s"]
