@@ -275,7 +275,9 @@ class FlopsProfilerConfig(ConfigModel):
 class TelemetryConfig(ConfigModel):
     """Unified telemetry (`deepspeed_tpu/telemetry/`): metrics registry +
     exporters + spans. Opt-in: when disabled (default) the instrumented
-    subsystems record nothing and NO files are written. Shared by the train
+    subsystems record nothing in the registry and NO files are written (the
+    in-memory step timeline, `telemetry/steptrace.py`, is the one always-on
+    part and has no setting). Shared by the train
     config and `TpuInferenceConfig` — the serving scheduler reads the same
     block."""
     enabled: bool = False

@@ -117,7 +117,8 @@ class _Slot:
     __slots__ = ("idx", "state", "uid", "prompt", "prompt_len", "padded_len",
                  "max_new", "eos", "blocks", "cursor", "pos", "emitted",
                  "hashes", "reg", "cached", "prefill_only", "deadline",
-                 "t_arrive", "t_admit", "t_first", "t_prev", "trace")
+                 "t_arrive", "t_admit", "t_first", "t_prev", "trace",
+                 "step_first", "req")
 
     def __init__(self, idx):
         self.idx = idx
@@ -137,9 +138,11 @@ class _Slot:
         self.prefill_only = False  # disaggregated serving: park in _HANDOFF
                                 # after the last chunk instead of decoding
         self.deadline = None    # absolute hard deadline (engine clock)
-        self.t_arrive = self.t_admit = self.t_first = None  # telemetry stamps
+        self.t_arrive = self.t_admit = self.t_first = None  # lifecycle stamps
         self.t_prev = None      # last emission sync (TPOT interpolation anchor)
         self.trace = None       # TraceContext (None unless tracing is on)
+        self.step_first = 0     # step-timeline index of the first token's step
+        self.req = None         # open steptrace.RequestRecord
 
 
 class ServingEngine:
@@ -246,8 +249,11 @@ class ServingEngine:
         self.kv_quant = kvd == "int8"
         self.kv_group_size = int(qcfg.kv_group_size or 0)
         # injectable clock (tests pin TTFT/TPOT interpolation with it; the
-        # router injects its own for TTL — this one stamps request timing)
-        self._clock = clock if clock is not None else time.monotonic
+        # router injects its own for TTL — this one stamps request timing
+        # and the step timeline). perf_counter is the clock the chrome sink
+        # and the benchmark stamp with; on Linux it and time.monotonic both
+        # read CLOCK_MONOTONIC
+        self._clock = clock if clock is not None else time.perf_counter
 
         bs = int(getattr(engine.config, "kv_block_size", 0) or 0)
         if bs <= 0:
@@ -296,6 +302,11 @@ class ServingEngine:
         # attribute check and NOTHING is written anywhere.
         self.telemetry = Telemetry(getattr(engine.config, "telemetry", None),
                                    subsystem="serving")
+        # the step timeline (telemetry/steptrace.py) is the exception: ON by
+        # default, telemetry block or not — one in-memory record a step and
+        # per request, read through `serving.steptrace` (or
+        # `steptrace.latest("serving")`)
+        self.steptrace = self.telemetry.new_steptrace(self._clock)
         if self.telemetry.enabled and self.spec_on:
             # acceptance rates live in [0, 1] — the default log-scale ms
             # buckets would smear them into one decade; pin linear bounds
@@ -747,7 +758,13 @@ class ServingEngine:
         read ONE time source, so a chaos test drives the whole pool's time
         deterministically. Absolute `deadline_at` values stay comparable
         across replicas because every engine shares the router's clock."""
-        self._clock = clock
+        self._clock = self.steptrace.clock = clock
+
+    def _phase(self, name):
+        """One phase of the step timeline (`telemetry/steptrace.py::Phase`):
+        trace annotation, chrome event when that sink is on, the step ring,
+        and the `t0`/`t1` stamps the request tracer reuses."""
+        return self.steptrace.phase(name, tid=self.trace_tid)
 
     def submit(self, request: Request, prefill_only: bool = False,
                hashes: Optional[List[bytes]] = None, trace=None,
@@ -813,7 +830,10 @@ class ServingEngine:
         return eos
 
     def _admit(self, finished: List[CompletedRequest]):
+        """FIFO admission. Returns (requests admitted, why the head of a
+        still non-empty queue was not: "pool", "slots" or "")."""
         free = [s for s in self.slots if s.state == _FREE]
+        admitted, blocked_on = 0, ""
         while self.queue and free:
             (req, prompt, prompt_len, padded, need, hashes,
              t_arrive, prefill_only, trace, deadline_at) = self.queue[0]
@@ -861,6 +881,7 @@ class ServingEngine:
                 # before it strands a whole chain
                 if hit:
                     self.allocator.free(hit[::-1])
+                blocked_on = "pool"
                 break
             blocks = hit + blocks
             self.queue.popleft()
@@ -886,16 +907,18 @@ class ServingEngine:
             slot.prefill_only = prefill_only
             slot.deadline = deadline_at
             slot.t_arrive = t_arrive
+            slot.t_admit = t_adm = self._clock()
+            admitted += 1
+            slot.req = self.steptrace.open_request(
+                req.uid, t_arrive, t_adm, prompt_len,
+                len(hit) * self.block_size)
             if self.telemetry.enabled:
-                slot.t_admit = self._clock()
                 self.telemetry.observe("serving/queue_wait_ms",
-                                       (slot.t_admit - t_arrive) * 1e3)
+                                       (t_adm - t_arrive) * 1e3)
             slot.trace = trace
             if self.tracer.enabled and trace is not None:
                 # the queue-wait span + an admit mark; flow_end lands the
                 # router's dispatch arrow on THIS replica's Perfetto track
-                t_adm = slot.t_admit if slot.t_admit is not None \
-                    else self._clock()
                 self.tracer.flow_end(trace, t_adm, tid=self.trace_tid)
                 self.tracer.record(trace, "queued", t_arrive,
                                    max(0.0, t_adm - t_arrive),
@@ -920,6 +943,9 @@ class ServingEngine:
                 self.prefix_hit_blocks += len(hit)
                 self.prefix_hit_tokens += len(hit) * self.block_size
                 self.prefill_chunks_skipped += slot.cursor // self.chunk
+        if self.queue and not blocked_on:
+            blocked_on = "slots"        # the loop ran out of free slots
+        return admitted, blocked_on
 
     def _retire(self, slot: _Slot, reason: str) -> CompletedRequest:
         # blocks return to the pool the step the sequence finishes — a
@@ -935,8 +961,11 @@ class ServingEngine:
         if self.drafter is not None:
             self.drafter.retire(slot)       # stateful drafters drop slot state
         timing = None
+        t_finish = self._clock()
+        self.steptrace.close_request(
+            slot.req, slot.t_first, slot.step_first, t_finish,
+            len(slot.emitted), reason)
         if self.telemetry.enabled and slot.t_admit is not None:
-            t_finish = self._clock()
             self.telemetry.observe("serving/e2e_ms",
                                    (t_finish - slot.t_arrive) * 1e3)
             # TPOT (serving/tpot_ms) is recorded per emission burst in
@@ -946,14 +975,13 @@ class ServingEngine:
             timing = {"arrival": slot.t_arrive, "admit": slot.t_admit,
                       "first_token": slot.t_first, "finish": t_finish}
         if self.tracer.enabled and slot.trace is not None:
-            t_end = self._clock()
-            self.tracer.event(slot.trace, "retire", t_end,
+            self.tracer.event(slot.trace, "retire", t_finish,
                               tid=self.trace_tid,
                               attrs={"reason": reason,
                                      "tokens": len(slot.emitted)})
             if slot.trace.owner == "engine":
                 # no router above: this engine closes the root (e2e) span
-                self.tracer.finish(slot.trace, t_end, tid=self.trace_tid,
+                self.tracer.finish(slot.trace, t_finish, tid=self.trace_tid,
                                    attrs={"reason": reason})
         if self.flightrec.enabled:
             self.flightrec.record("retire", uid=slot.uid, reason=reason,
@@ -971,20 +999,22 @@ class ServingEngine:
     def _emit(self, slot: _Slot, tok: int, finished: List[CompletedRequest]):
         slot.emitted.append(int(tok))
         self.tokens_generated += 1
-        if self.telemetry.enabled and len(slot.emitted) == 1 \
-                and slot.t_arrive is not None:
+        if len(slot.emitted) == 1 and slot.t_arrive is not None:
             slot.t_first = slot.t_prev = self._clock()
-            self.telemetry.observe("serving/ttft_ms",
-                                   (slot.t_first - slot.t_arrive) * 1e3)
+            slot.step_first = self.steptrace.step
+            if self.telemetry.enabled:
+                self.telemetry.observe("serving/ttft_ms",
+                                       (slot.t_first - slot.t_arrive) * 1e3)
         if slot.eos is not None and int(tok) == slot.eos:
             finished.append(self._retire(slot, "eos"))
         elif len(slot.emitted) >= slot.max_new:
             finished.append(self._retire(slot, "length"))
 
-    def _observe_tpot(self, slot, anchor, j):
+    def _observe_tpot(self, slot, anchor, j, t_now):
         """Per-token TPOT with intra-burst interpolation: a decode sync
-        that emits `j` tokens for a slot since `anchor` (the previous
-        emission sync) interpolates the j timestamps evenly across the
+        (read back at `t_now`, the end of its step phase) that emits `j`
+        tokens for a slot since `anchor` (the previous emission sync)
+        interpolates the j timestamps evenly across the
         interval — j samples of dt/j each — so `serving/tpot_ms` stays
         honest whether a step emits exactly one token, a K-token decode
         window, or 1..k+1 tokens from a verify step's accepted draft. (A
@@ -992,7 +1022,6 @@ class ServingEngine:
         wall time by steps instead of tokens would overstate it.)"""
         if not self.telemetry.enabled or anchor is None or j <= 0:
             return
-        t_now = self._clock()
         per_tok = (t_now - anchor) / j * 1e3
         for _ in range(j):
             self.telemetry.observe("serving/tpot_ms", per_tok)
@@ -1308,6 +1337,10 @@ class ServingEngine:
         slot.t_first = state.get("t_first")
         slot.t_prev = slot.t_first         # TPOT interpolation re-anchors here
         slot.trace = state.get("trace")    # decode spans continue the trace
+        # the request's record here opens at adoption, under its uid
+        slot.req = self.steptrace.open_request(
+            slot.uid, slot.t_arrive, self._clock(), slot.prompt_len,
+            slot.cached * self.block_size, t_first_token=slot.t_first)
         self.tables[slot.idx, :] = TRASH_BLOCK
         self.tables[slot.idx, :len(blocks)] = blocks
         self.handoffs_in += 1
@@ -1322,6 +1355,9 @@ class ServingEngine:
         self.tables[slot.idx, :] = TRASH_BLOCK
         if self.drafter is not None:
             self.drafter.retire(slot)
+        self.steptrace.close_request(
+            slot.req, slot.t_first, slot.step_first, self._clock(),
+            len(slot.emitted), "handoff")
         slot.reset()
         self.handoffs_out += 1
 
@@ -1346,8 +1382,8 @@ class ServingEngine:
         k/v sits beyond it (overwritten by the next verify's writes, never
         attended — the causal mask stops at the cursor), and the slot's
         blocks and table rows do not move."""
-        tr_on = self.tracer.enabled
-        with self.telemetry.span("serving/draft", tid=self.trace_tid):
+        st = self.steptrace
+        with self._phase("serving/draft"):
             drafts, dlens = self.drafter.propose(dec, tok, pos, tables)
         if self.pressure is not None and self.pressure.draft_cap is not None:
             # ladder rung 1: cap the ACCEPTED draft length only — the
@@ -1355,59 +1391,61 @@ class ServingEngine:
             # the cap score as padding and land past the cursor (dead)
             dlens = np.minimum(dlens, self.pressure.draft_cap)
         toks = np.concatenate([tok[:, None], drafts], axis=1)
-        t0 = self._clock() if tr_on else 0.0
-        with self.telemetry.span("serving/verify", tid=self.trace_tid):
+        with self._phase("serving/verify") as ph:
+            st.dispatched()
             tgt, self.pool = self._verify_step(self.engine.params, toks,
                                                pos, self.pool, tables,
                                                self._next_rng())
             # dstpu: ignore[DT001]: THE one host roundtrip per verify step — acceptance runs host-side, amortized over k+1 tokens x all slots
             tgt = np.asarray(jax.device_get(tgt))       # [S, draft_k+1]
-        t1 = self._clock() if tr_on else 0.0
+            st.ready()
+        tr_on = self.tracer.enabled
         self.verify_calls += 1
         self.decode_steps += 1
-        for s in dec:
-            dlen = int(dlens[s.idx])
-            ctx, uid = s.trace, s.uid         # _retire resets the slot
-            n, emitted = accept_greedy(drafts[s.idx], tgt[s.idx], dlen)
-            # O(1) rollback/advance: the cursor moves past the accepted
-            # prefix + bonus only; everything else written this step is
-            # dead weight the next verify overwrites
-            s.pos += n + 1
-            self.verify_slot_steps += 1
-            self.drafted_tokens += dlen
-            self.accepted_tokens += n
+        with self._phase("serving/emit"):
+            for s in dec:
+                dlen = int(dlens[s.idx])
+                ctx, uid = s.trace, s.uid         # _retire resets the slot
+                n, emitted = accept_greedy(drafts[s.idx], tgt[s.idx], dlen)
+                # O(1) rollback/advance: the cursor moves past the accepted
+                # prefix + bonus only; everything else written this step is
+                # dead weight the next verify overwrites
+                s.pos += n + 1
+                self.verify_slot_steps += 1
+                self.drafted_tokens += dlen
+                self.accepted_tokens += n
+                if self.telemetry.enabled:
+                    if dlen:
+                        self.telemetry.observe("serving/spec_accept_rate",
+                                               n / dlen)
+                    self.telemetry.inc("serving/spec_accepted_tokens", n)
+                    self.telemetry.inc("serving/spec_drafted_tokens", dlen)
+                anchor, j = s.t_prev, 0
+                for t in emitted:
+                    # EOS inside an accepted draft retires the slot right here,
+                    # at the EOS position — the accepted tail past it (and the
+                    # bonus) is discarded exactly like a window tail
+                    self._emit(s, t, finished)
+                    j += 1
+                    if s.state == _FREE:
+                        break
+                # j, not len(emitted): an EOS or max_new retirement mid-burst
+                # truncates the accepted tail — only tokens that actually
+                # reached the output count toward the tokens/step multiple
+                self.spec_emitted_tokens += j
+                self._observe_tpot(s, anchor, j, ph.t1)
+                if tr_on and ctx is not None:
+                    self.tracer.record(ctx, "verify", ph.t0, ph.t1 - ph.t0,
+                                       tid=self.trace_tid,
+                                       attrs={"drafted": dlen, "accepted": n,
+                                              "emitted": j})
+                if self.flightrec.enabled and n < dlen:
+                    # spec-decode rollback: the cursor rewound past dlen-n
+                    # rejected draft tokens — O(1), but worth the black box
+                    self.flightrec.record("rollback", uid=uid,
+                                          rejected=dlen - n, accepted=n)
             if self.telemetry.enabled:
-                if dlen:
-                    self.telemetry.observe("serving/spec_accept_rate",
-                                           n / dlen)
-                self.telemetry.inc("serving/spec_accepted_tokens", n)
-                self.telemetry.inc("serving/spec_drafted_tokens", dlen)
-            anchor, j = s.t_prev, 0
-            for t in emitted:
-                # EOS inside an accepted draft retires the slot right here,
-                # at the EOS position — the accepted tail past it (and the
-                # bonus) is discarded exactly like a window tail
-                self._emit(s, t, finished)
-                j += 1
-                if s.state == _FREE:
-                    break
-            # j, not len(emitted): an EOS or max_new retirement mid-burst
-            # truncates the accepted tail — only tokens that actually
-            # reached the output count toward the tokens/step multiple
-            self.spec_emitted_tokens += j
-            self._observe_tpot(s, anchor, j)
-            if tr_on and ctx is not None:
-                self.tracer.record(ctx, "verify", t0, t1 - t0,
-                                   tid=self.trace_tid,
-                                   attrs={"drafted": dlen, "accepted": n,
-                                          "emitted": j})
-            if self.flightrec.enabled and n < dlen:
-                # spec-decode rollback: the cursor rewound past dlen-n
-                # rejected draft tokens — O(1), but worth the black box
-                self.flightrec.record("rollback", uid=uid,
-                                      rejected=dlen - n, accepted=n)
-        if self.telemetry.enabled:
-            self.telemetry.inc("serving/spec_verify_steps")
+                self.telemetry.inc("serving/spec_verify_steps")
 
     # ------------------------------------------------------------------
     # the engine step: admit -> prefill chunk(s) -> decode all slots
@@ -1431,9 +1469,16 @@ class ServingEngine:
         finished: List[CompletedRequest] = []
         self.steps += 1
         params = self.engine.params
+        # the step timeline: phases tile the step (admit, each prefill
+        # chunk, decode_build, decode_window or draft + verify, emit,
+        # housekeeping); dispatched()/ready() bracket the device calls
+        st = self.steptrace
+        st.begin_step()
+        compiled0 = self._compiled_programs()
+        chunks0, tokens0 = self.prefill_chunks, self.tokens_generated
 
-        with self.telemetry.span("serving/admit", tid=self.trace_tid):
-            self._admit(finished)
+        with self._phase("serving/admit"):
+            admitted, blocked_on = self._admit(finished)
 
         # chunked prefill, bounded per step so arriving prompts cannot stall
         # the running batch for more than prefill_budget chunk-times
@@ -1442,58 +1487,8 @@ class ServingEngine:
             if budget <= 0:
                 break
             while slot.state == _PREFILL and budget > 0:
-                start = slot.cursor
-                chunk = np.zeros((1, self.chunk), np.int32)
-                seg = slot.prompt[start:start + self.chunk]
-                chunk[0, :len(seg)] = seg
-                final = start + self.chunk >= slot.padded_len
-                last = (slot.prompt_len - 1 - start) if final else self.chunk - 1
-                tr_on = self.tracer.enabled and slot.trace is not None
-                t0 = self._clock() if tr_on else 0.0
-                with self.telemetry.span("serving/prefill_chunk",
-                                         tid=self.trace_tid):
-                    tok, self.pool = self._prefill_step(
-                        params, chunk, np.asarray([start], np.int32),
-                        np.asarray([last], np.int32), self.pool,
-                        self.tables[slot.idx][None], self._next_rng())
-                if tr_on:
-                    t1 = self._clock()
-                    self.tracer.record(slot.trace, "prefill_chunk", t0,
-                                       t1 - t0, tid=self.trace_tid,
-                                       attrs={"start": start,
-                                              "chunk": self.chunk})
-                if self.drafter is not None:
-                    # a stateful drafter (the draft model) shadows the chunk
-                    # into its own pool through the same table — the draft
-                    # cache is warm the moment this slot starts verifying
-                    self.drafter.prefill_chunk(
-                        slot, chunk, np.asarray([start], np.int32),
-                        np.asarray([last], np.int32),
-                        self.tables[slot.idx][None])
-                slot.cursor = start + self.chunk
+                self._prefill_chunk(slot, params, finished)
                 budget -= 1
-                self.prefill_chunks += 1
-                if self.prefix_cache is not None and slot.hashes:
-                    # register blocks the cursor just finished writing —
-                    # full blocks strictly below prompt_len only (the
-                    # padded tail and decode-written blocks stay private,
-                    # so shared blocks are immutable by construction). A
-                    # block becomes matchable only here, AFTER its content
-                    # exists in the pool: registering at admission would
-                    # let a same-step sibling map garbage.
-                    hi = min(slot.cursor, slot.prompt_len) // self.block_size
-                    for i in range(slot.reg, hi):
-                        self.prefix_cache.register(slot.hashes[i],
-                                                   slot.blocks[i])
-                    slot.reg = max(slot.reg, hi)
-                if final:
-                    # a prefill-only slot parks for handoff instead of
-                    # decoding; _emit may still retire it right here when
-                    # the first sampled token is EOS or max_new == 1 — the
-                    # router then sees a normal completion from this engine
-                    slot.state = _HANDOFF if slot.prefill_only else _DECODE
-                    # dstpu: ignore[DT001]: first-token readback at prefill completion — one scalar per prompt, the TTFT emission point
-                    self._emit(slot, int(np.asarray(tok)[0]), finished)
 
         # decode: ONE fixed-shape call for every slot; non-decoding slots
         # ride along against the trash block. With window > 1 the call
@@ -1503,78 +1498,167 @@ class ServingEngine:
         # the verify step replaces this call entirely.
         dec = [s for s in self.slots if s.state == _DECODE]
         if dec:
-            self.peak_active = max(self.peak_active, len(dec))
-            tok = np.zeros((self.max_slots,), np.int32)
-            pos = np.zeros((self.max_slots,), np.int32)
-            tables = np.full_like(self.tables, TRASH_BLOCK)
-            for s in dec:
-                tok[s.idx] = s.emitted[-1]
-                pos[s.idx] = s.pos
-                tables[s.idx] = self.tables[s.idx]
+            with self._phase("serving/decode_build"):
+                self.peak_active = max(self.peak_active, len(dec))
+                tok = np.zeros((self.max_slots,), np.int32)
+                pos = np.zeros((self.max_slots,), np.int32)
+                tables = np.full_like(self.tables, TRASH_BLOCK)
+                for s in dec:
+                    tok[s.idx] = s.emitted[-1]
+                    pos[s.idx] = s.pos
+                    tables[s.idx] = self.tables[s.idx]
             spec_active = self.spec_on and not (
                 self.pressure is not None and self.pressure.spec_disabled)
             if spec_active:
                 self._verify_decode(dec, tok, pos, tables, finished)
             else:
-                # the degraded paths run the 1-STEP decode program: with
-                # spec decode pressure-disabled the blocks were sized for
-                # the k-draft overhang (no window-rounding tail, so a K-step
-                # window could write past them), and the ladder's window-
-                # shrink rung trades dispatch amortization for K-times finer
-                # retirement/admission granularity under pool pressure
-                use_w1 = self.spec_on or (
-                    self.pressure is not None
-                    and self.pressure.force_window_1)
-                step_fn = self._degraded_decode_step() if use_w1 \
-                    else self._decode_step
-                win = 1 if use_w1 else self.window
-                tr_on = self.tracer.enabled
-                t0 = self._clock() if tr_on else 0.0
-                with self.telemetry.span("serving/decode_window",
-                                         tid=self.trace_tid):
-                    nxt, self.pool = step_fn(params, tok, pos,
-                                             self.pool, tables,
-                                             self._next_rng())
-                    # dstpu: ignore[DT001]: THE one host roundtrip per decode window — EOS/retirement decisions are host-side, amortized over `win` tokens
-                    nxt = np.asarray(jax.device_get(nxt))   # [S, win]
-                t1 = self._clock() if tr_on else 0.0
-                self.decode_steps += 1
-                for s in dec:
-                    s.pos += win
-                    ctx = s.trace             # _retire resets the slot
-                    anchor, j = s.t_prev, 0
-                    for t in nxt[s.idx]:
-                        self._emit(s, int(t), finished)
-                        j += 1
-                        if s.state == _FREE:            # retired mid-window
-                            break
-                    self._observe_tpot(s, anchor, j)
-                    if tr_on and ctx is not None:
-                        self.tracer.record(ctx, "decode_window", t0, t1 - t0,
-                                           tid=self.trace_tid,
-                                           attrs={"emitted": j})
+                self._decode_window(dec, params, tok, pos, tables, finished)
 
         # sync-point housekeeping: hard deadlines, the pressure ladder, and
         # the scheduled pool audit all run here — between compiled calls,
         # on host state only
-        self._sweep_deadlines(finished)
-        if self.pressure is not None:
-            self.pressure.update(finished)
-        if self.audit_interval and self.steps % self.audit_interval == 0:
-            self._scheduled_audit()
+        with self._phase("serving/housekeeping"):
+            self._sweep_deadlines(finished)
+            if self.pressure is not None:
+                self.pressure.update(finished)
+            if self.audit_interval and self.steps % self.audit_interval == 0:
+                self._scheduled_audit()
 
-        if self.telemetry.enabled:
-            self.telemetry.set_gauge("serving/queue_depth", len(self.queue))
-            self.telemetry.set_gauge("serving/active_slots", self.num_active)
-            self.telemetry.set_gauge("serving/free_blocks",
-                                     self.allocator.available)
-            if self.memscope is not None:
-                # mem/* ledger gauges; the first publish also runs the lazy
-                # per-program memory_analysis pass (AOT — no jit-cache hit)
-                self.memscope.publish()
-            self.telemetry.maybe_export(self.steps)
+            if self.telemetry.enabled:
+                self.telemetry.set_gauge("serving/queue_depth",
+                                         len(self.queue))
+                self.telemetry.set_gauge("serving/active_slots",
+                                         self.num_active)
+                self.telemetry.set_gauge("serving/free_blocks",
+                                         self.allocator.available)
+                if self.memscope is not None:
+                    # mem/* ledger gauges; the first publish also runs the
+                    # lazy per-program memory_analysis pass (AOT — no
+                    # jit-cache hit)
+                    self.memscope.publish()
+                self.telemetry.maybe_export(self.steps)
 
+        st.end_step(admitted=admitted,
+                    prefill_chunks=self.prefill_chunks - chunks0,
+                    decoding=len(dec),
+                    emitted=self.tokens_generated - tokens0,
+                    queued=len(self.queue),
+                    free_blocks=self.allocator.available,
+                    blocked_on=blocked_on,
+                    compiles=self._compiled_programs() - compiled0)
         return finished
+
+    def _prefill_chunk(self, slot, params, finished):
+        """One prefill chunk of `slot`: input build, dispatch, the cache
+        registrations it completes and, after the final chunk, the
+        first-token read-back."""
+        st = self.steptrace
+        ctx = slot.trace                      # _emit may retire the slot
+        with self._phase("serving/prefill_chunk") as ph:
+            start = slot.cursor
+            chunk = np.zeros((1, self.chunk), np.int32)
+            seg = slot.prompt[start:start + self.chunk]
+            chunk[0, :len(seg)] = seg
+            final = start + self.chunk >= slot.padded_len
+            last = (slot.prompt_len - 1 - start) if final else self.chunk - 1
+            st.dispatched()
+            tok, self.pool = self._prefill_step(
+                params, chunk, np.asarray([start], np.int32),
+                np.asarray([last], np.int32), self.pool,
+                self.tables[slot.idx][None], self._next_rng())
+            if self.drafter is not None:
+                # a stateful drafter (the draft model) shadows the chunk
+                # into its own pool through the same table — the draft
+                # cache is warm the moment this slot starts verifying
+                self.drafter.prefill_chunk(
+                    slot, chunk, np.asarray([start], np.int32),
+                    np.asarray([last], np.int32),
+                    self.tables[slot.idx][None])
+            slot.cursor = start + self.chunk
+            self.prefill_chunks += 1
+            if self.prefix_cache is not None and slot.hashes:
+                # register blocks the cursor just finished writing —
+                # full blocks strictly below prompt_len only (the
+                # padded tail and decode-written blocks stay private,
+                # so shared blocks are immutable by construction). A
+                # block becomes matchable only here, AFTER its content
+                # exists in the pool: registering at admission would
+                # let a same-step sibling map garbage.
+                hi = min(slot.cursor, slot.prompt_len) // self.block_size
+                for i in range(slot.reg, hi):
+                    self.prefix_cache.register(slot.hashes[i],
+                                               slot.blocks[i])
+                slot.reg = max(slot.reg, hi)
+            if final:
+                # a prefill-only slot parks for handoff instead of
+                # decoding; _emit may still retire it right here when
+                # the first sampled token is EOS or max_new == 1 — the
+                # router then sees a normal completion from this engine
+                slot.state = _HANDOFF if slot.prefill_only else _DECODE
+                # dstpu: ignore[DT001]: first-token readback at prefill completion — one scalar per prompt, the TTFT emission point
+                first = int(np.asarray(tok)[0])
+                st.ready()
+                self._emit(slot, first, finished)
+        if self.tracer.enabled and ctx is not None:
+            self.tracer.record(ctx, "prefill_chunk", ph.t0, ph.t1 - ph.t0,
+                               tid=self.trace_tid,
+                               attrs={"start": start, "chunk": self.chunk})
+
+    def _decode_window(self, dec, params, tok, pos, tables, finished):
+        """The decode call for every slot in `dec`, its read-back, and the
+        emission of what it sampled."""
+        # the degraded paths run the 1-STEP decode program: with
+        # spec decode pressure-disabled the blocks were sized for
+        # the k-draft overhang (no window-rounding tail, so a K-step
+        # window could write past them), and the ladder's window-
+        # shrink rung trades dispatch amortization for K-times finer
+        # retirement/admission granularity under pool pressure
+        use_w1 = self.spec_on or (
+            self.pressure is not None
+            and self.pressure.force_window_1)
+        step_fn = self._degraded_decode_step() if use_w1 \
+            else self._decode_step
+        win = 1 if use_w1 else self.window
+        st = self.steptrace
+        with self._phase("serving/decode_window") as ph:
+            st.dispatched()
+            nxt, self.pool = step_fn(params, tok, pos,
+                                     self.pool, tables,
+                                     self._next_rng())
+            # dstpu: ignore[DT001]: THE one host roundtrip per decode window — EOS/retirement decisions are host-side, amortized over `win` tokens
+            nxt = np.asarray(jax.device_get(nxt))   # [S, win]
+            st.ready()
+        self.decode_steps += 1
+        tr_on = self.tracer.enabled
+        with self._phase("serving/emit"):
+            for s in dec:
+                s.pos += win
+                ctx = s.trace             # _retire resets the slot
+                anchor, j = s.t_prev, 0
+                for t in nxt[s.idx]:
+                    self._emit(s, int(t), finished)
+                    j += 1
+                    if s.state == _FREE:            # retired mid-window
+                        break
+                self._observe_tpot(s, anchor, j, ph.t1)
+                if tr_on and ctx is not None:
+                    self.tracer.record(ctx, "decode_window", ph.t0,
+                                       ph.t1 - ph.t0, tid=self.trace_tid,
+                                       attrs={"emitted": j})
+
+    def _compiled_programs(self) -> int:
+        """Compiled-program count over the persistent step functions: its
+        growth during a step says that step recompiled."""
+        fns = (self._embed_prefill, self._layer_prefill, self._head_prefill,
+               self._embed_decode, self._layer_decode, self._head_decode) \
+            if self.streamed else (self._decode_step, self._prefill_step,
+                                   self._verify_step, self._decode_step_w1)
+        # a program not built (None) or replaced by a plain function (fault
+        # injection) has no cache and counts int() = 0
+        total = sum(getattr(fn, "_cache_size", int)() for fn in fns)
+        if self.drafter is not None:
+            total += sum(self.drafter.compile_stats().values())
+        return total
 
     # ------------------------------------------------------------------
     # batch front-end + introspection

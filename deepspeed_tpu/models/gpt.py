@@ -1211,23 +1211,26 @@ def _paged_attn_half(x, p, pool_l, positions, block_tables,
     # physical block = table[row, logical], offset = pos % bs. Rows of
     # inactive slots (all-trash tables, pos 0) collide in the trash block —
     # duplicate-index scatter order is unspecified there and irrelevant.
-    blk = jnp.take_along_axis(block_tables, positions // bs, axis=1)  # [B, C]
-    off = positions % bs
-    pool_l = dict(pool_l)
-    if quantized:
-        from deepspeed_tpu.inference.quantization import quantize_kv
-        g = cfg.head_dim // pool_l["k_scale"].shape[-1]
-        qk, sk = quantize_kv(k, g)
-        qv, sv = quantize_kv(v, g)
-        pool_l["k"] = pool_l["k"].at[blk, :, off, :].set(qk)
-        pool_l["v"] = pool_l["v"].at[blk, :, off, :].set(qv)
-        pool_l["k_scale"] = pool_l["k_scale"].at[blk, :, off, :].set(sk)
-        pool_l["v_scale"] = pool_l["v_scale"].at[blk, :, off, :].set(sv)
-    else:
-        pool_l["k"] = pool_l["k"].at[blk, :, off, :].set(
-            k.astype(pool_l["k"].dtype))
-        pool_l["v"] = pool_l["v"].at[blk, :, off, :].set(
-            v.astype(pool_l["v"].dtype))
+    # (`jax.named_scope`s below cost nothing: they name these regions in the
+    # operations' `op_name`, which xprof shows)
+    with jax.named_scope("kv_pool_write"):
+        blk = jnp.take_along_axis(block_tables, positions // bs, axis=1)  # [B, C]
+        off = positions % bs
+        pool_l = dict(pool_l)
+        if quantized:
+            from deepspeed_tpu.inference.quantization import quantize_kv
+            g = cfg.head_dim // pool_l["k_scale"].shape[-1]
+            qk, sk = quantize_kv(k, g)
+            qv, sv = quantize_kv(v, g)
+            pool_l["k"] = pool_l["k"].at[blk, :, off, :].set(qk)
+            pool_l["v"] = pool_l["v"].at[blk, :, off, :].set(qv)
+            pool_l["k_scale"] = pool_l["k_scale"].at[blk, :, off, :].set(sk)
+            pool_l["v_scale"] = pool_l["v_scale"].at[blk, :, off, :].set(sv)
+        else:
+            pool_l["k"] = pool_l["k"].at[blk, :, off, :].set(
+                k.astype(pool_l["k"].dtype))
+            pool_l["v"] = pool_l["v"].at[blk, :, off, :].set(
+                v.astype(pool_l["v"].dtype))
 
     # single-token steps ride the paged Pallas kernel when it is worth it:
     # same engage rule as the contiguous decode path (forced, or auto at
@@ -1246,26 +1249,30 @@ def _paged_attn_half(x, p, pool_l, positions, block_tables,
     if program == "paged_kernel_quant":
         from deepspeed_tpu.ops.pallas.decode_attention import \
             paged_decode_attention_quant
-        attn = paged_decode_attention_quant(
-            q[:, 0], pool_l["k"], pool_l["v"], pool_l["k_scale"],
-            pool_l["v_scale"], block_tables, positions[:, 0],
-            sm_scale=None if cfg.scale_attn else 1.0).reshape(B, 1, D)
+        with jax.named_scope("attn"):
+            attn = paged_decode_attention_quant(
+                q[:, 0], pool_l["k"], pool_l["v"], pool_l["k_scale"],
+                pool_l["v_scale"], block_tables, positions[:, 0],
+                sm_scale=None if cfg.scale_attn else 1.0).reshape(B, 1, D)
     elif program == "paged_kernel":
         from deepspeed_tpu.ops.pallas.decode_attention import \
             paged_decode_attention
-        attn = paged_decode_attention(
-            q[:, 0], pool_l["k"], pool_l["v"], block_tables,
-            positions[:, 0],
-            sm_scale=None if cfg.scale_attn else 1.0).reshape(B, 1, D)
+        with jax.named_scope("attn"):
+            attn = paged_decode_attention(
+                q[:, 0], pool_l["k"], pool_l["v"], block_tables,
+                positions[:, 0],
+                sm_scale=None if cfg.scale_attn else 1.0).reshape(B, 1, D)
     elif program in ("paged_gather_quant", "paged_gather"):
-        if program == "paged_gather_quant":
-            k_ctx, v_ctx = gather_block_kv_dequant(pool_l, block_tables,
-                                                   x.dtype)
-        else:
-            k_ctx, v_ctx = gather_block_kv(pool_l["k"], pool_l["v"],
-                                           block_tables)
-        attn = _paged_attend(q, k_ctx, v_ctx, positions, cfg,
-                             local_flag=local_flag)
+        with jax.named_scope("kv_pool_read"):
+            if program == "paged_gather_quant":
+                k_ctx, v_ctx = gather_block_kv_dequant(pool_l, block_tables,
+                                                       x.dtype)
+            else:
+                k_ctx, v_ctx = gather_block_kv(pool_l["k"], pool_l["v"],
+                                               block_tables)
+        with jax.named_scope("attn"):
+            attn = _paged_attend(q, k_ctx, v_ctx, positions, cfg,
+                                 local_flag=local_flag)
     else:
         # see the contiguous decode site: by-name dispatch, loud failure
         # for programs without a handler (an unknown name silently taking
@@ -1286,7 +1293,8 @@ def _block_paged(x, p, pool_l, positions, block_tables,
     attn_out, pool_l = _paged_attn_half(
         x, p, pool_l, positions, block_tables, cfg, local_flag=local_flag,
         phase=phase)
-    x = _residual_mlp(x, attn_out, p, cfg, constrain=False)
+    with jax.named_scope("mlp"):
+        x = _residual_mlp(x, attn_out, p, cfg, constrain=False)
     return x, pool_l
 
 

@@ -251,6 +251,7 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, pos, sm_scale=None,
         ),
         out_shape=jax.ShapeDtypeStruct((B, Hkv, G, hd), q.dtype),
         interpret=interpret,
+        name="dstpu_paged_decode",
     )(pos, block_tables, qg, k_pool, v_pool)
     return out.reshape(B, H, hd)
 

@@ -174,6 +174,7 @@ def _flash_fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret):
             pltpu.VMEM((block_q, _LANES), jnp.float32),
         ],
         interpret=interpret,
+        name="dstpu_flash_fwd",
     )(q2, k2, v2)
     return out.reshape(B, H, T, D), lse
 
@@ -321,6 +322,7 @@ def _flash_bwd(res, g, sm_scale, causal, block_q, block_k, interpret,
         out_shape=jax.ShapeDtypeStruct((BH, T, D), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
         interpret=interpret,
+        name="dstpu_flash_dq",
     )(q2, k2, v2, do2, lse2, delta2)
 
     if causal:
@@ -356,6 +358,7 @@ def _flash_bwd(res, g, sm_scale, causal, block_q, block_k, interpret,
             pltpu.VMEM((block_k, D), jnp.float32),
         ],
         interpret=interpret,
+        name="dstpu_flash_dkv",
     )(q2, k2, v2, do2, lse2, delta2)
 
     return (dq.reshape(B, H, T, D), dk.reshape(B, H, T, D), dv.reshape(B, H, T, D))

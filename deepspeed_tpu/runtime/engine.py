@@ -17,6 +17,7 @@ API parity with the reference engine: `train_batch`, `forward`, `backward`, `ste
 `cur_scale` (loss scale), `set_dataloader` etc.
 """
 
+import contextlib
 import dataclasses
 import inspect
 import time
@@ -89,6 +90,13 @@ class TrainState(NamedTuple):
     scaler: LossScaleState
     step: jnp.ndarray            # i32 global step counter
     rng: jnp.ndarray             # PRNG key
+
+
+def _is_ready(x):
+    """True when `x` is nothing to wait for: no array at all, a host value,
+    or a device array whose computation has finished."""
+    is_ready = getattr(x, "is_ready", None)
+    return True if is_ready is None else bool(is_ready())
 
 
 def _gather_site(spec, axes):
@@ -356,6 +364,10 @@ class Engine:
         # one attribute check per step and writes nothing.
         self.telemetry = Telemetry(config.telemetry, subsystem="train",
                                    monitor=self.monitor)
+        # the step timeline (telemetry/steptrace.py) is on with or without
+        # the block: one in-memory record a `train_batch`, with the phases
+        # train/place, train/dispatch, train/fence, train/after_step
+        self.steptrace = self.telemetry.new_steptrace(time.perf_counter)
         self._program_flops = None   # per-train_batch flops, measured once
         # comm facade stats mirror into this registry: comm/<op>_bytes,
         # comm/<op>_calls, comm/<op>_ms rows (see comm/collectives.py)
@@ -1319,6 +1331,81 @@ class Engine:
         Analog of `PipelineEngine.train_batch` / the forward-backward-step loop of
         the reference engine. `batch` leading dim must be gas × micro × dp_data.
         """
+        st = self.steptrace
+        # nothing is carried into this step when the last one's loss is
+        # ready: the device has run dry (the caller fetched the loss)
+        st.begin_step(device_idle=_is_ready(self._last_metrics.get("loss")))
+        compiled0 = self._compiled_train_programs()
+        placed = None
+        with st.phase("train/place"):
+            batch = self._next_batch(batch, data_iter)
+            self.tput_timer.start()
+            self.timers(TRAIN_BATCH_TIMER).start()
+            t_step0 = time.perf_counter()   # timer.start() already fenced the device
+            if self.host_optimizer is None:
+                with self._oom_forensics():
+                    placed = self._maybe_split_gas(batch)
+        with st.phase("train/dispatch"), self._oom_forensics():
+            st.dispatched()
+            if placed is None:
+                metrics = self._host_train_batch(batch)
+            elif self._comm_err is not None:
+                self.state, metrics, self._comm_err = \
+                    self._run_stateful_step(self._train_step, placed,
+                                            self._comm_err)
+            else:
+                self.state, metrics = self._run_stateful_step(
+                    self._train_step, placed)
+        with st.phase("train/fence"):
+            self.timers(TRAIN_BATCH_TIMER).stop()
+            step_seconds = time.perf_counter() - t_step0   # incl. stop()'s fence
+            self.tput_timer.stop(global_step=True)
+            if _is_ready(metrics["loss"]):
+                st.ready()
+        with st.phase("train/after_step"):
+            # auto-profile at profile_step (reference engine.forward:1782 /
+            # step:2162 flops_profiler_profile_step hook); outside the timer
+            # window — cost analysis recompiles the step from scratch
+            fp_cfg = self.config.flops_profiler
+            if fp_cfg.enabled and self._flops_profiler is None \
+                    and self.global_steps + 1 >= fp_cfg.profile_step:
+                if placed is not None:
+                    self._run_flops_profile(placed)
+                else:
+                    logger.warning("flops_profiler: not supported with the "
+                                   "host (CPU-offload) optimizer step; "
+                                   "skipping")
+                    from deepspeed_tpu.profiling.flops_profiler import \
+                        FlopsProfiler
+                    self._flops_profiler = FlopsProfiler(ds_engine=self)
+            self._after_step(metrics, count_micro=True)
+            if self.telemetry.enabled:
+                self._record_step_telemetry(batch, placed, step_seconds)
+            self._maybe_step_moq(batch)
+            self._maybe_step_compression()
+        st.end_step(compiles=self._compiled_train_programs() - compiled0)
+        return metrics["loss"]
+
+    @contextlib.contextmanager
+    def _oom_forensics(self):
+        """OOM-forensics dispatch boundary: RESOURCE_EXHAUSTED from placing
+        the batch or running the step dumps the memory ledger + planner
+        delta + flight ring, then re-raises."""
+        try:
+            yield
+        except Exception as e:
+            if self.memscope is not None:
+                self.memscope.on_step_error(e)
+            raise
+
+    def _compiled_train_programs(self) -> int:
+        """Compile-cache size of the fused train step (0 on the paths that
+        have none): its growth during a step says that step recompiled."""
+        return getattr(self._train_step, "_cache_size", int)()
+
+    def _next_batch(self, batch, data_iter):
+        """The step's batch: the caller's or the data iterator's next, with
+        the curriculum mask and the routing directives applied."""
         if batch is None:
             it = data_iter
             if it is None and self.training_dataloader is not None:
@@ -1341,49 +1428,7 @@ class Engine:
         if (self.progressive_layer_drop is not None
                 or self.random_ltd_scheduler is not None) and isinstance(batch, dict):
             batch = self._inject_routing_directives(batch)
-        self.tput_timer.start()
-        self.timers(TRAIN_BATCH_TIMER).start()
-        t_step0 = time.perf_counter()   # timer.start() already fenced the device
-        placed = None
-        try:
-            if self.host_optimizer is not None:
-                metrics = self._host_train_batch(batch)
-            elif self._comm_err is not None:
-                placed = self._maybe_split_gas(batch)
-                self.state, metrics, self._comm_err = self._run_stateful_step(
-                    self._train_step, placed, self._comm_err)
-            else:
-                placed = self._maybe_split_gas(batch)
-                self.state, metrics = self._run_stateful_step(
-                    self._train_step, placed)
-        except Exception as e:
-            # OOM-forensics dispatch boundary: RESOURCE_EXHAUSTED dumps the
-            # memory ledger + planner delta + flight ring, then re-raises
-            if self.memscope is not None:
-                self.memscope.on_step_error(e)
-            raise
-        self.timers(TRAIN_BATCH_TIMER).stop()
-        step_seconds = time.perf_counter() - t_step0   # incl. stop()'s fence
-        self.tput_timer.stop(global_step=True)
-        # auto-profile at profile_step (reference engine.forward:1782 /
-        # step:2162 flops_profiler_profile_step hook); outside the timer
-        # window — cost analysis recompiles the step from scratch
-        fp_cfg = self.config.flops_profiler
-        if fp_cfg.enabled and self._flops_profiler is None \
-                and self.global_steps + 1 >= fp_cfg.profile_step:
-            if placed is not None:
-                self._run_flops_profile(placed)
-            else:
-                logger.warning("flops_profiler: not supported with the host "
-                               "(CPU-offload) optimizer step; skipping")
-                from deepspeed_tpu.profiling.flops_profiler import FlopsProfiler
-                self._flops_profiler = FlopsProfiler(ds_engine=self)
-        self._after_step(metrics, count_micro=True)
-        if self.telemetry.enabled:
-            self._record_step_telemetry(batch, placed, step_seconds)
-        self._maybe_step_moq(batch)
-        self._maybe_step_compression()
-        return metrics["loss"]
+        return batch
 
     def _maybe_step_compression(self):
         """Advance stateful compression (snip_momentum masks, activation-

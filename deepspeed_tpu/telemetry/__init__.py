@@ -8,6 +8,10 @@ optional chrome-trace span sink (`spans.py`). Construction from a
 complete no-op: no directory is created, no file is written, `span()`
 returns a shared null context and every record method returns immediately,
 so the serving scheduler and the train loop can instrument unconditionally.
+The one thing that is on with or without the block is the STEP TIMELINE
+(`steptrace.py`): `new_steptrace()` gives each engine a bounded in-memory
+ring of per-step and per-request records, fed by its `phase()` spans; it
+writes nothing anywhere.
 
 Wiring (all opt-in via the `telemetry` config block):
 
@@ -44,6 +48,7 @@ from deepspeed_tpu.telemetry.exporters import (JsonlExporter, MonitorBridge,
                                                prometheus_text)
 from deepspeed_tpu.telemetry import spans
 from deepspeed_tpu.telemetry.spans import ChromeTraceSink, Span
+from deepspeed_tpu.telemetry.steptrace import StepTrace
 from deepspeed_tpu.telemetry.tracing import (NULL_TRACER, TraceContext,
                                              Tracer)
 from deepspeed_tpu.telemetry.flight_recorder import (NULL_RECORDER,
@@ -59,7 +64,7 @@ from deepspeed_tpu.telemetry.memscope import (MemoryPlan, PredictedOOMError,
 __all__ = ["Telemetry", "MetricsRegistry", "Counter", "Gauge", "Histogram",
            "merge_snapshots",
            "PrometheusFileExporter", "JsonlExporter", "MonitorBridge",
-           "prometheus_text", "ChromeTraceSink", "Span", "Tracer",
+           "prometheus_text", "ChromeTraceSink", "Span", "StepTrace", "Tracer",
            "TraceContext", "FlightRecorder", "CompileWatchdog",
            "MemoryPlan", "PredictedOOMError", "ServingMemScope",
            "TrainMemScope", "plan_training", "plan_serving",
@@ -149,6 +154,13 @@ class Telemetry:
         if not self.enabled:
             return _NULL_SPAN
         return spans.span(name, sink=self._trace, tid=tid)
+
+    # ---- step timeline (on by default; telemetry/steptrace.py) ---------
+
+    def new_steptrace(self, clock):
+        """This subsystem's step recorder on `clock`; its phases reach the
+        chrome sink too when that is on."""
+        return StepTrace(self.subsystem, clock=clock, sink=self._trace)
 
     # ---- export ------------------------------------------------------
 

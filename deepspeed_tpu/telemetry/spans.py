@@ -83,17 +83,17 @@ class ChromeTraceSink:
 
 
 class Span:
-    """Context manager: nvtx annotation + optional chrome-trace event +
-    optional histogram observation (duration in ms). `tid` selects the
-    chrome-trace track (default 0 — single-engine timelines; the serving
-    stack passes its replica tid so pool timelines stay separated)."""
+    """Context manager: nvtx annotation + optional chrome-trace event. `tid`
+    selects the chrome-trace track (default 0 — single-engine timelines; the
+    serving stack passes its replica tid so pool timelines stay separated).
+    The clock is read only for a sink; `telemetry/steptrace.py::Phase` adds
+    the step ring as a third destination."""
 
-    __slots__ = ("name", "sink", "histogram", "tid", "_t0", "_nvtx")
+    __slots__ = ("name", "sink", "tid", "_t0", "_nvtx")
 
-    def __init__(self, name, sink=None, histogram=None, tid=0):
+    def __init__(self, name, sink=None, tid=0):
         self.name = name
         self.sink = sink
-        self.histogram = histogram
         self.tid = tid
         self._t0 = 0.0
         self._nvtx = None
@@ -101,20 +101,19 @@ class Span:
     def __enter__(self):
         self._nvtx = nvtx.annotate(self.name)
         self._nvtx.__enter__()
-        self._t0 = time.perf_counter()
+        if self.sink is not None:
+            self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, exc_type, exc, tb):
-        dur = time.perf_counter() - self._t0
         self._nvtx.__exit__(exc_type, exc, tb)
         self._nvtx = None
         if self.sink is not None:
-            self.sink.add(self.name, self._t0, dur, tid=self.tid)
-        if self.histogram is not None:
-            self.histogram.observe(dur * 1e3)
+            self.sink.add(self.name, self._t0,
+                          time.perf_counter() - self._t0, tid=self.tid)
         return False
 
 
-def span(name, sink=None, histogram=None, tid=0):
+def span(name, sink=None, tid=0):
     """Open a named span (see `Span`); usable as `with span("admit"): ...`."""
-    return Span(name, sink=sink, histogram=histogram, tid=tid)
+    return Span(name, sink=sink, tid=tid)

@@ -1,0 +1,213 @@
+"""Step timeline: what each scheduler or train step did, and when.
+
+One recorder a subsystem, fed by the `Span` the repo already has
+(`telemetry/spans.py`). A `Phase` is a `Span` — it enters the same
+`jax.profiler.TraceAnnotation` (free while no profiler session runs, on the
+device trace's clock while one does) and writes the same chrome-trace event
+when `telemetry.chrome_trace` is on — that ALSO stamps the engine's clock and
+adds its seconds to the open step record: one span, three sinks.
+
+A step is `begin_step()`, phases that tile it with no gap (each starts where
+the one before ended, so one clock read a phase), `dispatched()` / `ready()`
+marks around device work, and `end_step(**counts)`. Its record holds the
+seconds per phase and the EXPOSED HOST SECONDS: the step's wall time less the
+time a device call was in flight (from a `dispatched()` to the next blocking
+read-back's `ready()`, carried across steps while no read-back came) — the
+time the chip provably had nothing of this step to run. Requests get a
+record when they are admitted and a completed one, under the same `uid`, when
+they retire.
+
+Records are plain tuples of numbers and strings in bounded rings, selected by
+stamp (`records(since, until)`, `requests(since, until)`), so any window of a
+run can be read after the fact. A recorder holds the rings and never the
+engine: `latest("serving")` hands the rings of an engine that is gone to
+whoever asks in the same process (the benchmark's readers do).
+"""
+
+import collections
+import time
+
+from deepspeed_tpu.telemetry.spans import Span
+
+__all__ = ["StepTrace", "Phase", "StepRecord", "RequestRecord", "latest",
+           "DEFAULT_CAPACITY"]
+
+DEFAULT_CAPACITY = 8192     # steps kept: about ten minutes of 76 ms steps
+
+StepRecord = collections.namedtuple("StepRecord", [
+    "step",             # 1-based index of the step in this recorder
+    "t_start", "t_end",  # on the engine's clock (time.perf_counter by default)
+    "phases",           # ((name, seconds), ...) in order of first entry;
+                        # the seconds sum to t_end - t_start
+    "exposed_s",        # wall time less the in-flight intervals
+    "admitted",         # requests given a slot this step
+    "prefill_chunks",   # prefill chunks dispatched this step
+    "decoding",         # slots in the decode call (0 = no decode call)
+    "emitted",          # tokens emitted this step
+    "queued",           # requests waiting as the step ended
+    "free_blocks",      # pool blocks free or reclaimable as the step ended
+    "blocked_on",       # "" | "pool" | "slots": why the head of a non-empty
+                        # queue was not admitted
+    "compiles",         # growth of the step programs' compile caches
+], defaults=(0, 0, 0, 0, 0, 0, "", 0))
+
+RequestRecord = collections.namedtuple("RequestRecord", [
+    "uid", "t_submit", "t_admit",
+    "t_first_token", "t_finish",        # None until they happen
+    "prompt_len", "emitted", "cached_prefix_tokens",
+    "finish_reason",                    # "" while the request runs
+    "step_admit", "step_first_token", "step_finish",   # StepRecord.step of
+                                        # the step that caused each; 0 = not yet
+])
+
+_LATEST = {}        # subsystem -> the most recently created recorder
+
+
+def latest(subsystem):
+    """The most recently created recorder of `subsystem` ("serving",
+    "train") in this process, or None. With several engines in one process
+    (a router's in-process replicas) this is the NEWEST one only; hold
+    `engine.steptrace` for a particular engine."""
+    return _LATEST.get(subsystem)
+
+
+class Phase(Span):
+    """One phase of a step: `Span`'s trace annotation and chrome event, plus
+    stamps on the engine's clock (`t0`, `t1`, kept for the request tracer)
+    that go into `trace`'s open step record. Inside a step a phase starts
+    where the one before it ended; outside one it only stamps."""
+
+    __slots__ = ("trace", "t0", "t1")
+
+    def __init__(self, name, trace, tid=0):
+        Span.__init__(self, name, sink=trace.sink, tid=tid)
+        self.trace = trace
+        self.t0 = self.t1 = 0.0
+
+    def __enter__(self):
+        Span.__enter__(self)
+        mark = self.trace._mark
+        self.t0 = self.trace.clock() if mark is None else mark
+        return self
+
+    def __exit__(self, exc_type, exc, tb):
+        trace = self.trace
+        self.t1 = t1 = trace.clock()
+        if trace._mark is not None:
+            phases = trace._phases
+            phases[self.name] = phases.get(self.name, 0.0) + (t1 - self.t0)
+            trace._mark = t1
+        return Span.__exit__(self, exc_type, exc, tb)
+
+
+class StepTrace:
+    """Ring of step records and ring of request records for one engine."""
+
+    def __init__(self, subsystem, capacity=DEFAULT_CAPACITY,
+                 clock=time.perf_counter, sink=None):
+        if capacity <= 0:
+            raise ValueError("a recorder needs a capacity > 0")
+        self.subsystem = subsystem
+        self.capacity = int(capacity)
+        self.clock = clock
+        self.sink = sink            # ChromeTraceSink or None
+        self.step = 0
+        self._steps = collections.deque(maxlen=self.capacity)
+        self._requests = collections.deque(maxlen=self.capacity)
+        self._mark = None           # end of the last phase; None = no open step
+        self._t_start = 0.0
+        self._phases = {}
+        self._busy = 0.0            # in-flight seconds inside the open step
+        self._inflight_since = None
+        _LATEST[subsystem] = self
+
+    # ---- one step ------------------------------------------------------
+
+    def begin_step(self, device_idle=False):
+        """Open a step. `device_idle=True` says the caller knows the device
+        finished what earlier steps left in flight (training: the last
+        step's loss is ready), so nothing is carried into this one."""
+        self.step += 1
+        self._t_start = self._mark = self.clock()
+        self._phases = {}
+        self._busy = 0.0
+        if device_idle:
+            self._inflight_since = None
+
+    def phase(self, name, tid=0):
+        return Phase(name, self, tid=tid)
+
+    def dispatched(self):
+        """A device call is about to be enqueued."""
+        if self._inflight_since is None:
+            self._inflight_since = self.clock()
+
+    def ready(self):
+        """A blocking read-back returned: nothing is in flight."""
+        if self._inflight_since is not None:
+            self._busy += self.clock() - max(self._inflight_since,
+                                             self._t_start)
+            self._inflight_since = None
+
+    def end_step(self, **counts):
+        """Close the step at the end of its last phase and keep its record
+        (`counts`: the `StepRecord` fields after `exposed_s`)."""
+        t_end = self._mark
+        if t_end is None:
+            return None
+        if not self._phases:
+            t_end = self.clock()
+        busy = self._busy
+        if self._inflight_since is not None:    # still running: carried over
+            busy += t_end - max(self._inflight_since, self._t_start)
+        rec = StepRecord(self.step, self._t_start, t_end,
+                         tuple(self._phases.items()),
+                         max(0.0, (t_end - self._t_start) - busy), **counts)
+        self._steps.append(rec)
+        self._mark = None
+        return rec
+
+    # ---- requests ------------------------------------------------------
+
+    def open_request(self, uid, t_submit, t_admit, prompt_len,
+                     cached_prefix_tokens=0, t_first_token=None,
+                     step_first_token=0):
+        """Record an admission (or the adoption of a handed-off request,
+        which brings its first-token stamp with it); returns the record,
+        which `close_request` takes back."""
+        rec = RequestRecord(uid, t_submit, t_admit, t_first_token, None,
+                            prompt_len, 0, cached_prefix_tokens, "",
+                            self.step, step_first_token, 0)
+        self._requests.append(rec)
+        return rec
+
+    def close_request(self, opened, t_first_token, step_first_token,
+                      t_finish, emitted, finish_reason):
+        rec = opened._replace(
+            t_first_token=t_first_token, step_first_token=step_first_token,
+            t_finish=t_finish, emitted=emitted, finish_reason=finish_reason,
+            step_finish=self.step)
+        self._requests.append(rec)
+        return rec
+
+    # ---- reading -------------------------------------------------------
+
+    def records(self, since=None, until=None):
+        """Step records with `since < t_end <= until` (None = unbounded)."""
+        return [r for r in self._steps
+                if (since is None or r.t_end > since)
+                and (until is None or r.t_end <= until)]
+
+    def requests(self, since=None, until=None, stamp="t_admit"):
+        """One record a request, its newest (completed if it has retired),
+        for the requests whose `stamp` lies in (`since`, `until`]."""
+        newest = {}
+        for r in self._requests:
+            newest[(r.uid, r.step_admit)] = r
+        out = []
+        for r in newest.values():
+            t = getattr(r, stamp)
+            if t is not None and (since is None or t > since) \
+                    and (until is None or t <= until):
+                out.append(r)
+        return out

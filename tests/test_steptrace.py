@@ -1,0 +1,526 @@
+"""The step timeline (deepspeed_tpu/telemetry/steptrace.py): phase spans that
+tile a scheduler or train step, exposed host seconds from the
+dispatched()/ready() marks, the request-lifecycle ring, and the stable kernel
+names in the compiled programs.
+
+Rides the `telemetry` marker (tier-1; `pytest -m telemetry`).
+"""
+
+import functools
+import gc
+import json
+import time
+import weakref
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import deepspeed_tpu
+from deepspeed_tpu.comm import mesh as mesh_mod
+from deepspeed_tpu.config.core import MeshConfig, TelemetryConfig
+from deepspeed_tpu.inference.engine import init_inference
+from deepspeed_tpu.inference.scheduler import Request
+from deepspeed_tpu.models.gpt import (GPTConfig, make_gpt_decode_model,
+                                      make_gpt_model)
+from deepspeed_tpu.telemetry import Telemetry, steptrace
+from deepspeed_tpu.telemetry.steptrace import StepTrace
+
+pytestmark = pytest.mark.telemetry
+
+TINY = GPTConfig(n_layer=2, n_head=4, d_model=64, max_seq_len=256,
+                 vocab_size=256, dtype=jnp.float32, remat=False)
+SERVING_PHASES = {"serving/admit", "serving/prefill_chunk",
+                  "serving/decode_build", "serving/decode_window",
+                  "serving/emit", "serving/housekeeping"}
+
+
+def _mk_mesh():
+    mesh_mod._CURRENT_MESH = None
+    mesh_mod._CURRENT_SPEC = None
+    return mesh_mod.init_mesh(MeshConfig(data=1, tensor=1, sequence=1,
+                                         expert=1, pipe=1))
+
+
+def _engine(**telemetry):
+    _mk_mesh()
+    cfg = {"dtype": "float32", "kv_cache_dtype": "float32", "greedy": True,
+           "kv_block_size": 16, "max_out_tokens": 64}
+    if telemetry:
+        cfg["telemetry"] = telemetry
+    return init_inference(model=make_gpt_decode_model(cfg=TINY, name="tiny"),
+                          config=cfg)
+
+
+def _requests(n, prompt_len=9, max_new=3, seed=0):
+    rng = np.random.default_rng(seed)
+    return [Request(uid=i, max_new_tokens=max_new, stop_on_eos=False,
+                    tokens=rng.integers(0, 256, (prompt_len,)).astype(np.int32))
+            for i in range(n)]
+
+
+class Ticker:
+    """A clock that advances one second a reading: every stamp is distinct,
+    so phases tile a step only if they share their boundaries."""
+
+    def __init__(self):
+        self.now = 0.0
+
+    def __call__(self):
+        self.now += 1.0
+        return self.now
+
+
+# ----------------------------------------------------------------------
+# the recorder alone
+# ----------------------------------------------------------------------
+
+
+def test_phases_tile_the_step_and_exposed_follows_the_marks():
+    t = {"now": 10.0}
+    st = StepTrace("unit", 8, clock=lambda: t["now"])
+    st.begin_step()
+    with st.phase("serving/admit"):
+        t["now"] += 1.0
+    with st.phase("serving/decode_build"):
+        t["now"] += 2.0
+    with st.phase("serving/decode_window"):
+        st.dispatched()                     # in flight from 13.0
+        t["now"] += 5.0
+        st.ready()                          # to 18.0
+    t["now"] += 0.5                         # between two phases: the next one's
+    with st.phase("serving/emit"):
+        t["now"] += 1.0
+    rec = st.end_step(admitted=1, decoding=2, emitted=2, queued=3,
+                      free_blocks=4, blocked_on="slots", compiles=0)
+    assert (rec.step, rec.t_start, rec.t_end) == (1, 10.0, 19.5)
+    assert dict(rec.phases) == {"serving/admit": 1.0, "serving/decode_build": 2.0,
+                                "serving/decode_window": 5.0,
+                                "serving/emit": 1.5}
+    assert sum(s for _, s in rec.phases) == rec.t_end - rec.t_start
+    assert rec.exposed_s == pytest.approx(9.5 - 5.0)
+    assert (rec.admitted, rec.decoding, rec.queued, rec.blocked_on) == \
+        (1, 2, 3, "slots")
+    assert st.records() == [rec]
+    # a phase met twice in a step adds up; records are flat tuples
+    st.begin_step()
+    for _ in range(2):
+        with st.phase("serving/prefill_chunk"):
+            t["now"] += 1.0
+    rec = st.end_step()
+    assert dict(rec.phases) == {"serving/prefill_chunk": 2.0}
+    assert all(isinstance(v, (int, float, str, tuple)) for v in rec)
+
+
+def test_in_flight_work_is_carried_across_steps_until_a_read_back():
+    t = {"now": 0.0}
+    st = StepTrace("unit", 8, clock=lambda: t["now"])
+    st.begin_step()
+    with st.phase("serving/prefill_chunk"):
+        t["now"] += 1.0
+        st.dispatched()                     # no read-back in this step
+        t["now"] += 1.0
+    first = st.end_step()
+    assert first.exposed_s == pytest.approx(1.0)
+    t["now"] += 3.0                         # between the steps: nobody's
+    st.begin_step()
+    with st.phase("serving/decode_window"):
+        t["now"] += 2.0
+        st.ready()                          # the carried call ends here
+        t["now"] += 1.0
+    second = st.end_step()
+    assert second.exposed_s == pytest.approx(1.0)
+    # training: the caller says the device ran dry before this step
+    st.begin_step()
+    with st.phase("train/dispatch"):
+        st.dispatched()
+        t["now"] += 1.0
+    st.end_step()
+    st.begin_step(device_idle=True)
+    with st.phase("train/place"):
+        t["now"] += 2.0
+    assert st.end_step().exposed_s == pytest.approx(2.0)
+
+
+def test_the_rings_are_bounded_and_every_telemetry_builds_one():
+    st = StepTrace("unit", 4)
+    for i in range(10):
+        st.begin_step()
+        st.end_step()
+        st.close_request(st.open_request(i, 0.0, 0.1, 5), 0.2, st.step,
+                         0.3, 2, "length")
+    assert [r.step for r in st.records()] == [7, 8, 9, 10]
+    assert len(st._requests) == 4
+    with pytest.raises(ValueError):
+        StepTrace("unit", 0)
+    # no setting turns it off: with no telemetry block, or a disabled one,
+    # the subsystem's recorder is there at the one capacity
+    for n, config in enumerate((None, TelemetryConfig(enabled=False))):
+        on = Telemetry(config, subsystem=f"unit{n}").new_steptrace(
+            time.perf_counter)
+        assert on.capacity == steptrace.DEFAULT_CAPACITY
+        assert on.sink is None
+        assert steptrace.latest(f"unit{n}") is on
+    assert not hasattr(TelemetryConfig(), "steptrace_capacity")
+
+
+def test_records_and_requests_are_selected_by_stamp():
+    t = {"now": 0.0}
+    st = StepTrace("unit", 32, clock=lambda: t["now"])
+    opened = []
+    for i in range(4):
+        st.begin_step()
+        with st.phase("serving/admit"):
+            t["now"] += 1.0                 # steps end at 1, 2, 3, 4
+            opened.append(st.open_request(f"r{i}", t["now"] - 0.5, t["now"],
+                                          8, cached_prefix_tokens=16 * i))
+        st.end_step()
+    assert [r.step for r in st.records(1.0, 3.0)] == [2, 3]
+    assert [r.step for r in st.records(since=3.0)] == [4]
+    done = st.close_request(opened[0], 2.0, 2, 4.0, 3, "length")
+    # one record a request, the newest; the still-running ones are there too
+    inside = st.requests(0.0, 2.0)
+    assert sorted(r.uid for r in inside) == ["r0", "r1"]
+    assert [r for r in inside if r.uid == "r0"] == [done]
+    assert done.step_admit == 1 and done.step_finish == 4
+    assert done.finish_reason == "length" and done.emitted == 3
+    running = [r for r in inside if r.uid == "r1"][0]
+    assert running.t_finish is None and running.finish_reason == ""
+    assert running.cached_prefix_tokens == 16
+    assert [r.uid for r in st.requests(3.0, 4.0, stamp="t_finish")] == ["r0"]
+
+
+def test_one_step_of_six_phases_costs_under_50_microseconds():
+    st = StepTrace("unit", 64)
+    names = sorted(SERVING_PHASES)
+    n = 2000
+
+    def mean_of_n():
+        t0 = time.perf_counter()
+        for _ in range(n):
+            st.begin_step()
+            for name in names:
+                with st.phase(name):
+                    pass
+            st.end_step()
+        return (time.perf_counter() - t0) / n
+
+    # the quietest of five rounds: the other test workers share these cores
+    per_step = min(mean_of_n() for _ in range(5))
+    assert per_step < 50e-6, f"{per_step * 1e6:.1f} us a step"
+
+
+# ----------------------------------------------------------------------
+# the serving scheduler
+# ----------------------------------------------------------------------
+
+
+def test_serving_phases_tile_every_step_under_an_injected_clock():
+    clock = Ticker()
+    serving = _engine().serving(max_slots=2, max_context=128, clock=clock)
+    assert serving.steptrace.clock is clock
+    serving.run(_requests(3, prompt_len=20))
+    recs = serving.steptrace.records()
+    assert len(recs) == serving.steps and recs[-1].step == serving.steps
+    seen = set()
+    for rec in recs:
+        assert sum(s for _, s in rec.phases) == rec.t_end - rec.t_start
+        assert 0.0 <= rec.exposed_s <= rec.t_end - rec.t_start
+        seen.update(name for name, _ in rec.phases)
+    assert seen == SERVING_PHASES
+    assert sum(r.admitted for r in recs) == 3
+    assert sum(r.prefill_chunks for r in recs) == serving.prefill_chunks
+    assert sum(r.emitted for r in recs) == serving.tokens_generated == 9
+    assert sum(1 for r in recs if r.decoding) == serving.decode_steps
+    # both step programs compiled once, in the steps that first ran them
+    assert sum(r.compiles for r in recs) == 2
+    assert recs[-1].queued == 0 and recs[-1].free_blocks == \
+        serving.allocator.available
+    # set_clock moves the recorder with the engine
+    other = Ticker()
+    serving.set_clock(other)
+    assert serving.steptrace.clock is other
+
+
+def test_request_records_with_telemetry_off_point_at_their_steps(
+        tmp_path, monkeypatch):
+    monkeypatch.chdir(tmp_path)
+    serving = _engine().serving(max_slots=1, max_context=128)
+    assert not serving.telemetry.enabled
+    for req in _requests(2, prompt_len=20, max_new=3):
+        serving.submit(req)
+    finished_in = {}
+    while serving.queue or serving.num_active:
+        for done in serving.step():
+            assert done.timing is None          # the disabled contract holds
+            finished_in[done.uid] = serving.steps
+    st = serving.steptrace
+    by_step = {r.step: r for r in st.records()}
+    reqs = {r.uid: r for r in st.requests()}
+    assert sorted(reqs) == [0, 1]
+    for uid, r in reqs.items():
+        assert r.t_submit <= r.t_admit <= r.t_first_token <= r.t_finish
+        assert (r.prompt_len, r.emitted, r.finish_reason) == (20, 3, "length")
+        assert by_step[r.step_admit].admitted == 1
+        assert by_step[r.step_first_token].emitted >= 1
+        assert by_step[r.step_first_token].prefill_chunks >= 1
+        assert r.step_finish == finished_in[uid]
+        assert r.step_admit <= r.step_first_token < r.step_finish
+    # one slot: the second request queued until the first retired
+    assert reqs[1].step_admit > reqs[0].step_finish - 1
+    assert reqs[1].t_admit - reqs[1].t_submit > \
+        reqs[0].t_admit - reqs[0].t_submit
+    assert "latency" not in serving.stats()
+    assert list(tmp_path.iterdir()) == []       # and nothing was written
+
+
+def test_blocked_on_names_the_pool_or_the_slots():
+    engine = _engine()
+    need = engine.serving(max_slots=2, max_context=128).check_admissible(20, 3)
+    # the pool holds one request and is one block short of the second
+    # (one block of the pool is the trash block)
+    serving = engine.serving(max_slots=2, max_context=128,
+                             num_kv_blocks=2 * need)
+    assert serving.allocator.capacity == 2 * need - 1
+    serving.run(_requests(2, prompt_len=20))
+    waits = [r.blocked_on for r in serving.steptrace.records() if r.queued]
+    assert waits and set(waits) == {"pool"}
+    assert serving.steptrace.records()[-1].blocked_on == ""
+    # every slot busy, blocks to spare
+    serving = engine.serving(max_slots=1, max_context=128)
+    serving.run(_requests(2, prompt_len=20))
+    waits = [r.blocked_on for r in serving.steptrace.records() if r.queued]
+    assert waits and set(waits) == {"slots"}
+
+
+def test_compiles_names_the_step_that_compiled():
+    serving = _engine().serving(max_slots=2, max_context=128)
+    serving.run(_requests(2))
+    recs = serving.steptrace.records()
+    # the first step runs a chunk and a decode call, so it compiles both
+    # programs; nothing after it compiles
+    assert [r.compiles for r in recs] == [2] + [0] * (len(recs) - 1)
+    assert sum(serving.compile_stats().values()) == 2
+    # a program replaced by a plain function (fault injection) counts 0
+    jitted = serving._decode_step
+    serving._decode_step = lambda *a: jitted(*a)
+    assert serving._compiled_programs() == 1
+    serving.run(_requests(1, seed=1))
+    assert serving.steptrace.records()[-1].compiles == 0
+
+
+def test_latest_holds_the_rings_and_never_the_engine():
+    serving = _engine().serving(max_slots=2, max_context=128)
+    serving.run(_requests(1))
+    steps = serving.steps
+    assert steptrace.latest("serving") is serving.steptrace
+    gone = weakref.ref(serving)
+    del serving
+    gc.collect()
+    assert gone() is None
+    assert len(steptrace.latest("serving").records()) == steps
+
+
+def test_a_profiler_session_holds_the_phases_on_the_host_plane(tmp_path):
+    serving = _engine().serving(max_slots=2, max_context=128)
+    serving.run(_requests(1))                   # compile outside the session
+    for req in _requests(2, prompt_len=20, max_new=4, seed=1):
+        serving.submit(req)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        for _ in range(3):
+            serving.step()
+    finally:
+        jax.profiler.stop_trace()
+    files = list(tmp_path.rglob("*.xplane.pb"))
+    assert len(files) == 1
+    data = jax.profiler.ProfileData.from_file(str(files[0]))
+    names = set()
+    for plane in data.planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                names.update(ev.name for ev in line.events)
+    assert {"serving/admit", "serving/decode_window", "serving/emit"} <= names
+
+
+def test_chrome_trace_still_holds_the_phases(tmp_path):
+    serving = _engine(enabled=True, output_path=str(tmp_path),
+                      chrome_trace=True, prometheus=False,
+                      jsonl=False).serving(max_slots=2, max_context=128)
+    done = serving.run(_requests(2, prompt_len=20))
+    assert all(d.timing is not None for d in done.values())
+    serving.close()
+    text = (tmp_path / "serving.trace.json").read_text().rstrip().rstrip(",")
+    names = {ev["name"] for ev in json.loads(text + "]")}
+    assert SERVING_PHASES <= names
+    # the same spans fed the ring: one span, three sinks
+    assert len(serving.steptrace.records()) == serving.steps
+
+
+def test_spec_decode_steps_carry_draft_and_verify_phases():
+    serving = _engine().serving(
+        max_slots=2, max_context=128,
+        spec_decode={"drafter": "ngram", "draft_k": 2})
+    serving.run(_requests(2, prompt_len=20, max_new=6))
+    seen = set()
+    for rec in serving.steptrace.records():
+        assert sum(s for _, s in rec.phases) == \
+            pytest.approx(rec.t_end - rec.t_start)
+        seen.update(name for name, _ in rec.phases)
+    assert {"serving/draft", "serving/verify", "serving/emit"} <= seen
+    assert "serving/decode_window" not in seen
+
+
+def test_a_handed_off_request_has_a_record_on_both_engines():
+    engine = _engine()
+    prefill = engine.serving(max_slots=2, max_context=128)
+    decode = engine.serving(max_slots=2, max_context=128)
+    prefill.submit(_requests(1, prompt_len=20, max_new=4)[0],
+                   prefill_only=True)
+    while not prefill.handoff_ready():
+        prefill.step()
+    assert decode.adopt_handoff(prefill.export_handoff(0), prefill.pool)
+    prefill.release_handoff(0)
+    done = {}
+    while decode.num_active:
+        done.update({d.uid: d for d in decode.step()})
+    source, = prefill.steptrace.requests()
+    target, = decode.steptrace.requests()
+    assert source.uid == target.uid == 0
+    assert source.finish_reason == "handoff" and source.emitted == 1
+    assert target.finish_reason == "length" and target.emitted == 4
+    # the first-token stamp travels with the request
+    assert target.t_first_token == source.t_first_token
+    assert target.t_submit == source.t_submit
+
+
+# ----------------------------------------------------------------------
+# the train engine
+# ----------------------------------------------------------------------
+
+
+def test_train_batch_leaves_one_record_a_step_with_its_four_phases():
+    # one device: once the loss is fetched, every shard of it is ready (on
+    # several devices the fetch waits for one shard, and a step that finds
+    # another still running rightly carries it as in flight)
+    _mk_mesh()
+    engine, _, _, _ = deepspeed_tpu.initialize(
+        model=make_gpt_model(cfg=TINY, name="tiny"), config={
+            "train_micro_batch_size_per_gpu": 1,
+            "optimizer": {"type": "AdamW", "params": {"lr": 1e-3}},
+            "zero_optimization": {"stage": 0}, "steps_per_print": 10**9})
+    assert not engine.telemetry.enabled
+    assert steptrace.latest("train") is engine.steptrace
+    toks = np.random.default_rng(0).integers(
+        0, 256, (engine.train_batch_size(), 33)).astype(np.int32)
+    batch = {"tokens": toks[:, :-1], "labels": toks[:, 1:]}
+    for _ in range(3):
+        float(engine.train_batch(batch))        # the fetch is the fence
+    recs = engine.steptrace.records()
+    assert [r.step for r in recs] == [1, 2, 3]
+    for rec in recs:
+        assert [name for name, _ in rec.phases] == [
+            "train/place", "train/dispatch", "train/fence",
+            "train/after_step"]
+        assert sum(s for _, s in rec.phases) == \
+            pytest.approx(rec.t_end - rec.t_start)
+        assert 0.0 < rec.exposed_s <= rec.t_end - rec.t_start
+    assert [r.compiles for r in recs] == [1, 0, 0]
+    # the loss was fetched before each next step, so nothing was carried:
+    # what is exposed is what ran before the dispatch
+    place = dict(recs[2].phases)["train/place"]
+    assert recs[2].exposed_s == pytest.approx(place, rel=0.5)
+
+
+def test_a_data_error_is_not_an_oom_forensics_event():
+    # the batch comes from the caller's iterator inside `train/place` but
+    # outside the OOM-forensics boundary; placing it and the step are inside
+    _mk_mesh()
+    engine, _, _, _ = deepspeed_tpu.initialize(
+        model=make_gpt_model(cfg=TINY, name="tiny"), config={
+            "train_micro_batch_size_per_gpu": 1,
+            "optimizer": {"type": "AdamW", "params": {"lr": 1e-3}},
+            "zero_optimization": {"stage": 0}, "steps_per_print": 10**9})
+    seen = []
+
+    class Scope:
+        def on_step_error(self, e):
+            seen.append(e)
+
+    engine.memscope = Scope()
+    with pytest.raises(StopIteration):
+        engine.train_batch(data_iter=iter(()))
+    assert seen == []
+    with pytest.raises(Exception):
+        engine.train_batch({"tokens": np.zeros((3, 5, 7), np.int32)})
+    assert len(seen) == 1
+    # the step that failed is dropped; the next one opens clean
+    toks = np.zeros((engine.train_batch_size(), 9), np.int32)
+    float(engine.train_batch({"tokens": toks[:, :-1], "labels": toks[:, 1:]}))
+    rec = engine.steptrace.records()[-1]
+    assert sum(s for _, s in rec.phases) == \
+        pytest.approx(rec.t_end - rec.t_start)
+
+
+# ----------------------------------------------------------------------
+# stable kernel names, compiled for a described TPU (no chip needed)
+# ----------------------------------------------------------------------
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _kernel_instructions(fn, *args):
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    return [line.split("=")[0].strip().lstrip("%")
+            for line in text.splitlines()
+            if "custom-call(" in line and "tpu_custom_call" in line]
+
+
+def test_kernels_keep_their_names_in_the_compiled_program(one_chip):
+    """The HLO instruction of each Pallas kernel on the benchmarked path is
+    named for the kernel, also under scan + checkpoint, where it used to take
+    the enclosing computation's name (`closed_call.13`, `checkpoint.22`):
+    the benchmark's `paged_decode_kernel_time_share.*` match on it."""
+    from deepspeed_tpu.ops.pallas.decode_attention import \
+        paged_decode_attention
+    from deepspeed_tpu.ops.pallas.flash_attention import flash_attention
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    decode = functools.partial(paged_decode_attention, interpret=False)
+
+    def scanned_decode(q, k, v, tables, pos):
+        def body(c, _):
+            return c + decode(c, k, v, tables, pos), None
+        return jax.lax.scan(jax.checkpoint(body), q, None, length=2)[0]
+
+    names = _kernel_instructions(
+        scanned_decode, sds((8, 32, 128), jnp.bfloat16),
+        sds((16, 8, 512, 128), jnp.bfloat16),
+        sds((16, 8, 512, 128), jnp.bfloat16), sds((8, 4), jnp.int32),
+        sds((8,), jnp.int32))
+    assert names and all(n.startswith("dstpu_paged_decode") for n in names)
+
+    flash = functools.partial(flash_attention, interpret=False)
+
+    def flash_grad(q, k, v):
+        loss = jax.checkpoint(lambda q, k, v: flash(q, k, v).sum()
+                              .astype(jnp.float32))
+        return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+    qkv = sds((2, 1024, 4, 128), jnp.bfloat16)
+    names = _kernel_instructions(flash_grad, qkv, qkv, qkv)
+    kinds = {n.rsplit(".", 1)[0] for n in names}
+    assert kinds == {"dstpu_flash_fwd", "dstpu_flash_dq", "dstpu_flash_dkv"}
