@@ -16,7 +16,7 @@ the adapters in inference/adapters.py build them from HF checkpoints (the
 """
 
 import dataclasses
-from typing import Any, Callable, Optional
+from typing import Any, Callable, Dict, Optional
 
 import jax
 import jax.numpy as jnp
@@ -116,6 +116,11 @@ class DecodeModelSpec:
     decode_paged_fn: Optional[Callable] = None
     verify_paged_fn: Optional[Callable] = None
     init_paged_pool: Optional[Callable] = None
+    # dispatch phase ("paged_decode" | "prefill_chunk" | "verify") -> the
+    # writer that paged program was TRACED with (`attention_dispatch.
+    # kv_pool_writer`'s names), filled in by the model as each program is
+    # traced. None: the model writes with the XLA scatter throughout.
+    kv_pool_writers: Optional[Dict[str, str]] = None
     # cache-identity fingerprint for the prefix cache's hash chain
     # (inference/prefix_cache.py): every arch field that changes the KV
     # VALUES written for a given token stream must be folded in, so two

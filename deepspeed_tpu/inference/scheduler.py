@@ -1715,6 +1715,19 @@ class ServingEngine:
             out["decode_step_w1"] = int(self._decode_step_w1._cache_size())
         return out
 
+    def kv_pool_writers(self) -> Dict[str, str]:
+        """Step program -> how it writes its new K/V rows into the pool:
+        `dstpu_kv_pool_write` (the in-place kernel on a carried pool) or
+        `xla_scatter` (`ops/attention_dispatch.py::kv_pool_writer` decides
+        from the pool's dtype and shape and the platform; there is nothing
+        to set). A program appears once it has been traced. Empty for a
+        model that keeps no record: the streamed layers and the MoE stack
+        have the scatter form only."""
+        traced = getattr(self.engine.model_spec, "kv_pool_writers", None) or {}
+        return {program: traced[phase] for program, phase in (
+            ("decode_step", "paged_decode"), ("prefill_step", "prefill_chunk"),
+            ("verify_step", "verify")) if phase in traced}
+
     def stats(self) -> Dict[str, Any]:
         out = {"steps": self.steps, "decode_steps": self.decode_steps,
                "prefill_chunks": self.prefill_chunks,
@@ -1728,7 +1741,8 @@ class ServingEngine:
                "free_blocks": self.allocator.num_free,
                "reclaimable_blocks": self.allocator.num_reclaimable,
                "available_blocks": self.allocator.available,
-               "compiles": self.compile_stats()}
+               "compiles": self.compile_stats(),
+               "kv_pool_writer": self.kv_pool_writers()}
         if self.spec_on:
             out["spec_decode"] = {
                 "drafter": self.drafter.name,

@@ -1038,27 +1038,55 @@ def make_gpt_decode_model(cfg: GPTConfig = None, name="gpt2-125m", params=None, 
     # stacked blocks with the pool's layer axis as scan data, exactly like
     # the contiguous cache path, so layer count stays out of compile time
 
-    def _scan_paged(params, x, pool, block_tables, positions, phase=None):
-        # the pool rides the scan as a PYTREE of [L, ...] leaves (k/v, plus
-        # the int8 pool's k_scale/v_scale), so the quantized and fp layouts
-        # share one scan body — the per-layer slice arrives as a dict.
-        # `phase` labels the dispatch site ("verify" for the spec-decode
-        # chunk; None = derive decode/prefill from the chunk width)
-        flags = _layer_local_flags(cfg)
+    # which writer each paged program was traced with, by dispatch phase
+    # (`ServingEngine.stats()["kv_pool_writer"]` reads it)
+    pool_writers = {}
 
-        def body(x, inputs, flag=None):
-            p, pool_l = inputs
+    def _scan_paged(params, x, pool, block_tables, positions, phase=None):
+        # the pool is a PYTREE of [L, N, ...] leaves (k/v, plus the int8
+        # pool's k_scale/v_scale), so the quantized and fp layouts share one
+        # scan body — a layer's pool arrives as a dict. `phase` labels the
+        # dispatch site ("verify" for the spec-decode chunk; None = derive
+        # decode/prefill from the chunk width).
+        #
+        # Two forms of one loop, chosen by `attn_dispatch.kv_pool_writer`
+        # (the rule and its invariant live there). In place: the leaves are
+        # flattened to [L*N, ...] (a bitcast: leading dimensions merge),
+        # CARRIED, and layer l addresses its blocks as `table + l*N`; every
+        # write is the aliased Mosaic call, so the compiled program holds
+        # nothing of the pool's size but those calls. Otherwise the pool
+        # rides the scan as xs and comes back as ys (which never alias: the
+        # program copies the pool once, donated or not) and each layer's
+        # slice takes an XLA scatter.
+        flags = _layer_local_flags(cfg)
+        writer = attn_dispatch.kv_pool_writer(pool)
+        pool_writers[phase or ("paged_decode" if x.shape[1] == 1
+                               else "prefill_chunk")] = writer
+        if writer == attn_dispatch.KV_POOL_WRITE_KERNEL:
+            L, N = pool["k"].shape[:2]
+
+            def body(carry, inputs):
+                x, flat = carry
+                p, layer, flag = inputs
+                x, flat = _block_paged(x, p, flat, positions, block_tables,
+                                       cfg, local_flag=flag, phase=phase,
+                                       block_base=layer * N)
+                return (x, flat), None
+
+            flat = {k: v.reshape((L * N,) + v.shape[2:])
+                    for k, v in pool.items()}
+            (x, flat), _ = jax.lax.scan(
+                body, (x, flat),
+                (params["blocks"], jnp.arange(L, dtype=jnp.int32), flags))
+            return x, {k: v.reshape(pool[k].shape) for k, v in flat.items()}
+
+        def body(x, inputs):
+            p, pool_l, flag = inputs
             x, pool_l = _block_paged(x, p, pool_l, positions, block_tables,
                                      cfg, local_flag=flag, phase=phase)
             return x, pool_l
 
-        layers = (params["blocks"], pool)
-        if flags is None:
-            x, pool = jax.lax.scan(body, x, layers)
-        else:
-            x, pool = jax.lax.scan(
-                lambda c, inp: body(c, inp[0], flag=inp[1]), x, (layers, flags))
-        return x, pool
+        return jax.lax.scan(body, x, (params["blocks"], pool, flags))
 
     def prefill_paged_fn(params, tokens, start_pos, last_idx, pool,
                          block_tables):
@@ -1102,6 +1130,7 @@ def make_gpt_decode_model(cfg: GPTConfig = None, name="gpt2-125m", params=None, 
                            decode_paged_fn=decode_paged_fn,
                            verify_paged_fn=verify_paged_fn,
                            init_paged_pool=init_paged_pool,
+                           kv_pool_writers=pool_writers,
                            cache_fingerprint=gpt_cache_identity(cfg, name))
 
 
@@ -1177,7 +1206,8 @@ def _paged_attend(q, k_ctx, v_ctx, q_pos, cfg: GPTConfig, local_flag=None):
 
 
 def _paged_attn_half(x, p, pool_l, positions, block_tables,
-                     cfg: GPTConfig, local_flag=None, phase=None):
+                     cfg: GPTConfig, local_flag=None, phase=None,
+                     block_base=None):
     """Attention half-block against one layer's paged pool.
 
     x: [B, C, D]; pool_l: one layer's pool slice — ``k``/``v``
@@ -1186,6 +1216,16 @@ def _paged_attn_half(x, p, pool_l, positions, block_tables,
     absolute; block_tables: [B, nb]. Writes the C new tokens' k/v into each
     row's blocks (logical position -> table -> physical block scatter), then
     attends over the row's whole table. Returns (attn_out, pool_l).
+
+    In-place form (`block_base` given; `_scan_paged` decides): `pool_l` is
+    the WHOLE stack flattened to [L*N, Hkv, block, hd], this layer's blocks
+    start at `block_base` (= layer * N, traced), the rows are written by the
+    aliased `dstpu_kv_pool_write` call and read through
+    `block_tables + block_base` by `dstpu_paged_decode` or
+    `dstpu_kv_pool_gather` — Mosaic calls only, the invariant of
+    `attn_dispatch.kv_pool_writer`. It takes each row's positions to be
+    consecutive (`positions[b, c] == positions[b, 0] + c`), as every paged
+    program builds them.
 
     Quantized pool: K/V are quantized AT CACHE-WRITE TIME (symmetric
     per-group int8 + f32 scales, `quantization.quantize_kv` — the same
@@ -1214,23 +1254,28 @@ def _paged_attn_half(x, p, pool_l, positions, block_tables,
     # (`jax.named_scope`s below cost nothing: they name these regions in the
     # operations' `op_name`, which xprof shows)
     with jax.named_scope("kv_pool_write"):
-        blk = jnp.take_along_axis(block_tables, positions // bs, axis=1)  # [B, C]
-        off = positions % bs
         pool_l = dict(pool_l)
-        if quantized:
-            from deepspeed_tpu.inference.quantization import quantize_kv
-            g = cfg.head_dim // pool_l["k_scale"].shape[-1]
-            qk, sk = quantize_kv(k, g)
-            qv, sv = quantize_kv(v, g)
-            pool_l["k"] = pool_l["k"].at[blk, :, off, :].set(qk)
-            pool_l["v"] = pool_l["v"].at[blk, :, off, :].set(qv)
-            pool_l["k_scale"] = pool_l["k_scale"].at[blk, :, off, :].set(sk)
-            pool_l["v_scale"] = pool_l["v_scale"].at[blk, :, off, :].set(sv)
+        if block_base is not None:
+            from deepspeed_tpu.ops.pallas.kv_pool import kv_pool_write
+            block_tables = block_tables + block_base
+            for leaf, rows in (("k", k), ("v", v)):
+                pool_l[leaf] = kv_pool_write(pool_l[leaf], rows,
+                                             positions[:, 0], block_tables)
         else:
-            pool_l["k"] = pool_l["k"].at[blk, :, off, :].set(
-                k.astype(pool_l["k"].dtype))
-            pool_l["v"] = pool_l["v"].at[blk, :, off, :].set(
-                v.astype(pool_l["v"].dtype))
+            blk = jnp.take_along_axis(block_tables, positions // bs,
+                                      axis=1)                       # [B, C]
+            off = positions % bs
+            if quantized:
+                from deepspeed_tpu.inference.quantization import quantize_kv
+                g = cfg.head_dim // pool_l["k_scale"].shape[-1]
+                qk, sk = quantize_kv(k, g)
+                qv, sv = quantize_kv(v, g)
+                new_rows = {"k": qk, "v": qv, "k_scale": sk, "v_scale": sv}
+            else:
+                new_rows = {"k": k, "v": v}
+            for leaf, rows in new_rows.items():
+                pool_l[leaf] = pool_l[leaf].at[blk, :, off, :].set(
+                    rows.astype(pool_l[leaf].dtype))
 
     # single-token steps ride the paged Pallas kernel when it is worth it:
     # same engage rule as the contiguous decode path (forced, or auto at
@@ -1267,6 +1312,12 @@ def _paged_attn_half(x, p, pool_l, positions, block_tables,
             if program == "paged_gather_quant":
                 k_ctx, v_ctx = gather_block_kv_dequant(pool_l, block_tables,
                                                        x.dtype)
+            elif block_base is not None:
+                # an XLA gather on the carried pool slices the WHOLE pool
+                # (see ops/pallas/kv_pool.py): reads are Mosaic calls too
+                from deepspeed_tpu.ops.pallas.kv_pool import kv_pool_gather
+                k_ctx = kv_pool_gather(pool_l["k"], block_tables)
+                v_ctx = kv_pool_gather(pool_l["v"], block_tables)
             else:
                 k_ctx, v_ctx = gather_block_kv(pool_l["k"], pool_l["v"],
                                                block_tables)
@@ -1286,13 +1337,14 @@ def _paged_attn_half(x, p, pool_l, positions, block_tables,
 
 
 def _block_paged(x, p, pool_l, positions, block_tables,
-                 cfg: GPTConfig, local_flag=None, phase=None):
+                 cfg: GPTConfig, local_flag=None, phase=None,
+                 block_base=None):
     """One transformer block against the paged pool (decode, prefill
     chunk, or the spec-decode verify chunk — `phase` labels the dispatch
-    site)."""
+    site; `block_base` selects `_paged_attn_half`'s in-place form)."""
     attn_out, pool_l = _paged_attn_half(
         x, p, pool_l, positions, block_tables, cfg, local_flag=local_flag,
-        phase=phase)
+        phase=phase, block_base=block_base)
     with jax.named_scope("mlp"):
         x = _residual_mlp(x, attn_out, p, cfg, constrain=False)
     return x, pool_l

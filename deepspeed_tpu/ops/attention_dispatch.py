@@ -372,6 +372,45 @@ def _paged_kernel_ok(site):
             and decode_kernel_wanted(site.force_flash, site.kv_len))
 
 
+# How a step program writes its new K/V rows into the pool. THE rule, and
+# the one place it lives: `models/gpt.py::_scan_paged` asks it once per
+# program and builds the whole layer loop in the form it names;
+# `ServingEngine.stats()["kv_pool_writer"]` reports what was built.
+#
+# The invariant it protects: NEVER AN XLA SCATTER, UPDATE-SLICE OR GATHER ON
+# A CARRIED POOL THAT A MOSAIC CALL ALSO TOUCHES. The scatter wants the pool
+# with the heads next to `hd`, the Mosaic calls are pinned to the default
+# layout, and XLA reconciles the two with a copy of the WHOLE pool inside
+# the layer loop (compiled for a v5e, PR 25: 16 whole-pool copies a step);
+# a gather of a few blocks is rewritten into slices of the whole pool
+# (measured on the chip, PR 25: slower than the form it replaced). So the
+# two forms are: pool carried through the scan + every write and read a
+# Mosaic call (`ops/pallas/kv_pool.py`, `dstpu_paged_decode`), or pool
+# sliced by the scan as xs/ys + XLA scatter and gather on the slice (a copy
+# of the pool per program call, but each layer's slice is re-laid-out
+# alone). Nothing in between.
+KV_POOL_WRITE_KERNEL = "dstpu_kv_pool_write"
+KV_POOL_WRITE_SCATTER = "xla_scatter"
+
+
+def kv_pool_writer(pool) -> str:
+    """Name the writer for `pool` (the `[L, N, Hkv, block, hd]` pytree of
+    `init_paged_kv_pool`) from what can be seen at trace time: the in-place
+    kernel for a float pool made of whole native tiles, on a TPU, in a
+    single-device program (a bare Mosaic call cannot be partitioned — the
+    same limit as `dstpu_paged_decode`); the scatter everywhere else: the
+    CPU, the int8 pool with its narrow scale leaves, head widths under a
+    lane tile, a multi-device mesh."""
+    from deepspeed_tpu.ops.pallas.kv_pool import pool_in_place_supported
+    from deepspeed_tpu.platform.device import pallas_interpret
+    k = pool["k"]
+    if (set(pool) == {"k", "v"} and not pallas_interpret()
+            and not active_mesh_axes()
+            and pool_in_place_supported(k.dtype, k.shape[-2], k.shape[-1])):
+        return KV_POOL_WRITE_KERNEL
+    return KV_POOL_WRITE_SCATTER
+
+
 register_program(AttentionProgram(
     name="paged_kernel_quant", phases=("paged_decode",), priority=60,
     matches=lambda s: _paged_kernel_ok(s) and s.kv_dtype == "int8",

@@ -9,6 +9,7 @@ Rides the `telemetry` marker (tier-1; `pytest -m telemetry`).
 import functools
 import gc
 import json
+import re
 import time
 import weakref
 
@@ -524,3 +525,104 @@ def test_kernels_keep_their_names_in_the_compiled_program(one_chip):
     names = _kernel_instructions(flash_grad, qkv, qkv, qkv)
     kinds = {n.rsplit(".", 1)[0] for n in names}
     assert kinds == {"dstpu_flash_fwd", "dstpu_flash_dq", "dstpu_flash_dkv"}
+
+
+# ----------------------------------------------------------------------
+# the paged programs hold nothing of the pool's size but the in-place
+# writes (compiled for a described TPU, no chip needed)
+# ----------------------------------------------------------------------
+
+_HLO_LINE = re.compile(
+    r"^\s*(?:ROOT )?%?([\w.\-]+) = (.+?) ([a-z][a-z0-9\-]*)\(")
+_HLO_ARRAY = re.compile(r"\b[a-z]+(\d+)\[([\d,]*)\]")      # f32[8,128]
+# instructions that only name, pass on or alias a buffer
+_NO_NEW_BUFFER = {"parameter", "tuple", "get-tuple-element", "bitcast",
+                  "while", "custom-call"}
+
+
+def _large_instructions(text, at_least):
+    """(name, opcode) of every instruction of the compiled program whose
+    result holds an array of `at_least` bytes or more, fused ones included."""
+    large = []
+    for line in text.splitlines():
+        found = _HLO_LINE.match(line)
+        if not found:
+            continue
+        name, result, opcode = found.groups()
+        for bits, dims in _HLO_ARRAY.findall(result):
+            elements = np.prod([int(d) for d in dims.split(",") if d] or [1])
+            if int(elements) * int(bits) // 8 >= at_least:
+                large.append((name, opcode))
+                break
+    return large
+
+
+def _compile_paged_programs(one_chip, pool_dtype):
+    """The paged decode and prefill programs of a 2-layer model at the
+    served tile widths (Hkv 8, block 512, hd 128), pool donated."""
+    from deepspeed_tpu.models.gpt import gpt_init_fn
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    cfg = GPTConfig(vocab_size=512, n_layer=2, n_head=8, n_kv_head=8,
+                    d_model=1024, d_ff=1024, max_seq_len=8192,
+                    use_rotary=True, use_rmsnorm=True, dtype=jnp.bfloat16,
+                    remat=False)
+    shapes = jax.eval_shape(gpt_init_fn(cfg, dtype=jnp.bfloat16),
+                            jax.random.PRNGKey(0))
+    spec = make_gpt_decode_model(cfg, name="guard", params=shapes)
+    params = jax.tree_util.tree_map(lambda a: sds(a.shape, a.dtype), shapes)
+    pool = jax.tree_util.tree_map(
+        lambda a: sds(a.shape, a.dtype),
+        jax.eval_shape(lambda: spec.init_paged_pool(64, 512, pool_dtype)))
+    layer_leaf = pool["k"].size // cfg.n_layer * pool["k"].dtype.itemsize
+    i32 = jnp.int32
+    # 16-wide tables: an 8192-token context, where the decode kernel engages
+    decode = jax.jit(spec.decode_paged_fn, donate_argnums=(3,)).lower(
+        params, sds((8,), i32), sds((8,), i32), pool,
+        sds((8, 16), i32)).compile()
+    prefill = jax.jit(spec.prefill_paged_fn, donate_argnums=(4,)).lower(
+        params, sds((1, 64), i32), sds((1,), i32), sds((1,), i32), pool,
+        sds((1, 8), i32)).compile()
+    return {"decode": decode, "prefill": prefill}, layer_leaf, \
+        dict(spec.kv_pool_writers)
+
+
+def test_paged_programs_hold_nothing_of_the_pools_size(one_chip, monkeypatch):
+    """The trap this guards (PERF.md §6, PR 25): an XLA scatter or
+    update-slice on a pool that is carried through the layer scan and read
+    by a Mosaic call makes XLA copy the WHOLE pool inside the loop. In the
+    in-place form the only instructions as large as ONE layer's pool leaf
+    are the aliased `dstpu_kv_pool_write` calls, and the program's
+    temporaries stay under that size too."""
+    from deepspeed_tpu.ops import attention_dispatch
+    from deepspeed_tpu.platform import device
+    mesh_mod.clear_mesh()
+    monkeypatch.setattr(device, "on_tpu", lambda: True)   # what the chip sees
+
+    programs, layer_leaf, writers = _compile_paged_programs(one_chip,
+                                                            jnp.bfloat16)
+    assert writers == {"paged_decode": attention_dispatch.KV_POOL_WRITE_KERNEL,
+                       "prefill_chunk": attention_dispatch.KV_POOL_WRITE_KERNEL}
+    for name, program in programs.items():
+        text = program.as_text()
+        large = _large_instructions(text, layer_leaf)
+        assert [x for x in large if x[1] not in _NO_NEW_BUFFER] == [], name
+        calls = [n for n, opcode in large if opcode == "custom-call"]
+        assert len(calls) == 2 and all(
+            n.startswith("dstpu_kv_pool_write") for n in calls), (name, calls)
+        assert program.memory_analysis().temp_size_in_bytes < layer_leaf, name
+    # and what reads the carried pool is a Mosaic call as well
+    assert "dstpu_paged_decode" in programs["decode"].as_text()
+    assert "dstpu_kv_pool_gather" in programs["prefill"].as_text()
+
+    # the int8 pool: the rule declines, and the program is today's — the pool
+    # sliced and restacked by the scan, an XLA scatter on each slice
+    programs, layer_leaf, writers = _compile_paged_programs(one_chip, jnp.int8)
+    assert set(writers.values()) == {attention_dispatch.KV_POOL_WRITE_SCATTER}
+    for name, program in programs.items():
+        text = program.as_text()
+        assert "dstpu_kv_pool_write" not in text, name
+        assert [x for x in _large_instructions(text, layer_leaf)
+                if x[1] not in _NO_NEW_BUFFER], name

@@ -325,6 +325,105 @@ def test_paged_decode_attention_quant_compiled(group):
                                atol=3e-2, rtol=3e-2)
 
 
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+def test_kv_pool_write_and_gather_compiled(dtype):
+    """The in-place pool kernels at the served tile widths, bit for bit
+    against the XLA scatter and gather they replace: a 32-slot decode step
+    with inactive slots in the trash block, a 512-token chunk that starts
+    off a tile boundary and crosses a block, a verify chunk."""
+    from deepspeed_tpu.inference.kv_cache import gather_block_kv
+    from deepspeed_tpu.ops.pallas.kv_pool import (kv_pool_gather,
+                                                  kv_pool_write,
+                                                  kv_pool_write_reference)
+    rng = np.random.default_rng(25)
+    N, Hkv, block, hd, nb = 104, 8, 512, 128, 4
+    pool = jnp.asarray(rng.standard_normal((N, Hkv, block, hd)), dtype)
+    write = jax.jit(lambda *a: kv_pool_write(*a, interpret=False))
+    for B, C, live in ((32, 1, 24), (1, 512, 1), (8, 5, 6)):
+        tables = np.zeros((B, nb), np.int32)
+        tables[:live] = 1 + rng.permutation(N - 1)[:live * nb].reshape(
+            live, nb)
+        start = np.zeros((B,), np.int32)
+        start[:live] = rng.integers(0, nb * block - C, live)
+        if C == 512:
+            start[0] = 293                   # off a tile, across block 0 -> 1
+        rows = jnp.asarray(rng.standard_normal((B, C, Hkv, hd)), dtype)
+        want = np.asarray(kv_pool_write_reference(
+            pool, rows, jnp.asarray(start), jnp.asarray(tables)), np.float32)
+        got = np.array(write(pool, rows, jnp.asarray(start),
+                             jnp.asarray(tables)), np.float32)
+        # inactive slots collide at block 0, positions 0..C-1: any one wins
+        np.testing.assert_array_equal(got[1:], want[1:])
+        np.testing.assert_array_equal(got[0, :, C:], want[0, :, C:])
+    tables = jnp.asarray(rng.integers(0, N, (3, nb)), jnp.int32)
+    want, _ = gather_block_kv(pool, pool, tables)
+    got = jax.jit(lambda *a: kv_pool_gather(*a, interpret=False))(pool, tables)
+    np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                  np.asarray(want, np.float32))
+
+
+def test_in_place_paged_programs_match_the_scatter_programs_on_chip(
+        monkeypatch):
+    """On the chip the rule admits a bfloat16 pool of 512x128 tiles: the
+    programs built in place (pool carried, Mosaic writes and reads) leave
+    the same pool and the same logits as the xs/ys + scatter form, which
+    declining the rule builds."""
+    from deepspeed_tpu.models.gpt import GPTConfig, make_gpt_decode_model
+    from deepspeed_tpu.ops import attention_dispatch
+
+    def run(in_place):
+        if not in_place:
+            monkeypatch.setattr(
+                attention_dispatch, "kv_pool_writer",
+                lambda pool: attention_dispatch.KV_POOL_WRITE_SCATTER)
+        cfg = GPTConfig(vocab_size=512, n_layer=3, n_head=8, n_kv_head=4,
+                        d_model=1024, d_ff=1024, max_seq_len=8192,
+                        use_rotary=True, use_rmsnorm=True, dtype=jnp.bfloat16,
+                        remat=False)
+        spec = make_gpt_decode_model(cfg, name="chip", seed=0)
+        params = jax.tree_util.tree_map(lambda a: a.astype(jnp.bfloat16),
+                                        spec.params)
+        pool = spec.init_paged_pool(40, 512, jnp.bfloat16)
+        tables = np.zeros((4, 16), np.int32)
+        tables[:3] = 1 + np.arange(3 * 13).reshape(3, 13)[:, :1] + \
+            np.arange(16)[None] % 13                      # 3 live slots
+        tables = jnp.asarray(tables)
+        toks = jnp.asarray(np.random.default_rng(0).integers(0, 512, (1, 512)),
+                           jnp.int32)
+        logits, pool = jax.jit(spec.prefill_paged_fn, donate_argnums=(4,))(
+            params, toks, jnp.asarray([293], jnp.int32),
+            jnp.asarray([511], jnp.int32), pool, tables[:1])
+        outs = [logits]
+        tok = jnp.asarray([3, 5, 7, 0], jnp.int32)
+        pos = jnp.asarray([805, 17, 4000, 0], jnp.int32)
+        decode = jax.jit(spec.decode_paged_fn, donate_argnums=(3,))
+        for _ in range(3):
+            logits, pool = decode(params, tok, pos, pool, tables)
+            outs.append(logits[:3])
+            tok = (tok * 7 + 1) % 512          # not the argmax: a last-bit
+            pos = pos + jnp.asarray([1, 1, 1, 0], jnp.int32)  # tie would fork
+        return outs, pool, dict(spec.kv_pool_writers)
+
+    got, got_pool, writers = run(True)
+    assert set(writers.values()) == {attention_dispatch.KV_POOL_WRITE_KERNEL}
+    want, want_pool, writers = run(False)
+    assert set(writers.values()) == {attention_dispatch.KV_POOL_WRITE_SCATTER}
+    # two different XLA programs around the same kernels: a row in the wrong
+    # place is a difference of order 1, a fusion's other rounding point is
+    # one bfloat16 ulp (the kernels alone are held bit for bit above)
+    def close(a, b):
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        assert np.isfinite(a).all()
+        np.testing.assert_allclose(a, b, rtol=2e-2,
+                                   atol=2e-2 * float(np.abs(b).max()))
+
+    for leaf in ("k", "v"):
+        close(got_pool[leaf][:, 1:], want_pool[leaf][:, 1:])
+        assert float(jnp.abs(got_pool[leaf][:, 1:]).max()) > 0
+    for a, b in zip(got, want):
+        close(a, b)
+
+
 @pytest.mark.parametrize("n,experts", [(1024, 4), (4096, 64)])
 def test_token_sort_compiled(n, experts):
     """All-int32 counting sort: bit-equal to the jnp oracle, compiled."""
