@@ -121,6 +121,12 @@ class DecodeModelSpec:
     # kv_pool_writer`'s names), filled in by the model as each program is
     # traced. None: the model writes with the XLA scatter throughout.
     kv_pool_writers: Optional[Dict[str, str]] = None
+    # names of the int32 counters the paged programs return as a THIRD
+    # result, `(logits, pool, counts[len(step_counters)])`, summed over the
+    # model's layers (the routed experts': `parallel.moe.ROUTED_COUNTERS`).
+    # The scheduler sums them over a decode window, reads them back with the
+    # tokens and keeps them on its step ring. None: two results, no counters.
+    step_counters: Optional[tuple] = None
     # cache-identity fingerprint for the prefix cache's hash chain
     # (inference/prefix_cache.py): every arch field that changes the KV
     # VALUES written for a given token stream must be folded in, so two

@@ -401,6 +401,17 @@ class ServingEngine:
             # in-flight bytes) land in THIS engine's serving registry
             engine.streamer.telemetry = self.telemetry
             engine.store.telemetry = self.telemetry
+        # a model's own per-call counters (`DecodeModelSpec.step_counters`,
+        # e.g. the routed experts'): computed on the device by each step
+        # program, read back WITH the tokens (a program whose tokens are
+        # not read — a prompt's earlier chunks — parks its counts until the
+        # next read-back), summed here and put on the step ring
+        self.step_counter_names = tuple(
+            getattr(engine.model_spec, "step_counters", None) or ())
+        self.step_counter_totals = np.zeros(len(self.step_counter_names),
+                                            np.int64)
+        self._step_counts = np.zeros_like(self.step_counter_totals)
+        self._parked_counts = []
         self._build_step_fns()
 
         # drafter AFTER pool/allocator: the draft-model drafter mirrors the
@@ -498,6 +509,10 @@ class ServingEngine:
         cfg = self.engine.config
         decode_paged = self.engine._fn_transform(spec.decode_paged_fn)
         prefill_paged = self.engine._fn_transform(spec.prefill_paged_fn)
+        # a counted model's paged programs return (logits, pool, counts);
+        # its step programs then return (tokens, counts) where the others
+        # return tokens — the uncounted programs are unchanged
+        counted = bool(self.step_counter_names)
 
         def sample(logits, rng):
             return sample_logits(logits, rng, greedy=cfg.greedy,
@@ -517,21 +532,27 @@ class ServingEngine:
 
             def decode_step(params, tok, pos, pool, tables, rng):
                 if window == 1:  # no scan wrapper: keep the 1-step hot path
-                    logits, pool = decode_paged(params, tok, pos, pool,
-                                                tables)
-                    return sample(logits, rng)[:, None], pool
+                    logits, pool, *counts = decode_paged(params, tok, pos,
+                                                         pool, tables)
+                    toks = sample(logits, rng)[:, None]
+                    return ((toks, counts[0]) if counted else toks), pool
 
                 def body(carry, _):
-                    tok, pos, pool, rng = carry
+                    tok, pos, pool, rng, acc = carry
                     rng, sub = jax.random.split(rng)
-                    logits, pool = decode_paged(params, tok, pos, pool,
-                                                tables)
+                    logits, pool, *counts = decode_paged(params, tok, pos,
+                                                         pool, tables)
                     nxt = sample(logits, sub)
-                    return (nxt, pos + 1, pool, rng), nxt
+                    if counted:
+                        acc = acc + counts[0]
+                    return (nxt, pos + 1, pool, rng, acc), nxt
 
-                (_, _, pool, _), toks = jax.lax.scan(
-                    body, (tok, pos, pool, rng), None, length=window)
-                return jnp.moveaxis(toks, 0, 1), pool
+                acc = jnp.zeros((len(self.step_counter_names),), jnp.int32) \
+                    if counted else None
+                (_, _, pool, _, acc), toks = jax.lax.scan(
+                    body, (tok, pos, pool, rng, acc), None, length=window)
+                toks = jnp.moveaxis(toks, 0, 1)
+                return ((toks, acc) if counted else toks), pool
 
             return decode_step
 
@@ -539,9 +560,10 @@ class ServingEngine:
         decode_step = make_decode_step(self.window)
 
         def prefill_step(params, toks, start, last_idx, pool, table, rng):
-            logits, pool = prefill_paged(params, toks, start, last_idx, pool,
-                                         table)
-            return sample(logits, rng), pool
+            logits, pool, *counts = prefill_paged(params, toks, start,
+                                                  last_idx, pool, table)
+            tok = sample(logits, rng)
+            return ((tok, counts[0]) if counted else tok), pool
 
         # the pool is donated: the update is in-place in HBM, the old buffer
         # is dead the moment the step returns the new one. The compile
@@ -573,11 +595,12 @@ class ServingEngine:
                 conservative sample-and-match scheme (output distribution
                 preserved; the true rejection-sampling upgrade would
                 return per-position probabilities here instead)."""
-                logits, pool = verify_paged(params, toks, pos, pool, tables)
+                logits, pool, *counts = verify_paged(params, toks, pos, pool,
+                                                     tables)
                 S, V = logits.shape[0], logits.shape[-1]
                 tgt = sample(logits.reshape(S * K1, V),
                              rng).reshape(S, K1)
-                return tgt, pool
+                return ((tgt, counts[0]) if counted else tgt), pool
 
             self._verify_step = wd.wrap(
                 "verify_step", jax.jit(verify_step, donate_argnums=(3,)))
@@ -1396,8 +1419,8 @@ class ServingEngine:
             tgt, self.pool = self._verify_step(self.engine.params, toks,
                                                pos, self.pool, tables,
                                                self._next_rng())
-            # dstpu: ignore[DT001]: THE one host roundtrip per verify step — acceptance runs host-side, amortized over k+1 tokens x all slots
-            tgt = np.asarray(jax.device_get(tgt))       # [S, draft_k+1]
+            # THE one host roundtrip per verify step — acceptance runs host-side, amortized over k+1 tokens x all slots
+            tgt = np.asarray(self._read_back(tgt))      # [S, draft_k+1]
             st.ready()
         tr_on = self.tracer.enabled
         self.verify_calls += 1
@@ -1538,7 +1561,12 @@ class ServingEngine:
                     self.memscope.publish()
                 self.telemetry.maybe_export(self.steps)
 
-        st.end_step(admitted=admitted,
+        counters = ()
+        if self.step_counter_names:
+            counters = tuple(int(v) for v in self._step_counts)
+            self.step_counter_totals += self._step_counts
+            self._step_counts[:] = 0
+        st.end_step(counters=counters, admitted=admitted,
                     prefill_chunks=self.prefill_chunks - chunks0,
                     decoding=len(dec),
                     emitted=self.tokens_generated - tokens0,
@@ -1589,14 +1617,17 @@ class ServingEngine:
                     self.prefix_cache.register(slot.hashes[i],
                                                slot.blocks[i])
                 slot.reg = max(slot.reg, hi)
+            if self.step_counter_names and not final:
+                tok, counts = tok
+                self._parked_counts.append(counts)
             if final:
                 # a prefill-only slot parks for handoff instead of
                 # decoding; _emit may still retire it right here when
                 # the first sampled token is EOS or max_new == 1 — the
                 # router then sees a normal completion from this engine
                 slot.state = _HANDOFF if slot.prefill_only else _DECODE
-                # dstpu: ignore[DT001]: first-token readback at prefill completion — one scalar per prompt, the TTFT emission point
-                first = int(np.asarray(tok)[0])
+                # first-token readback at prefill completion — one scalar per prompt, the TTFT emission point
+                first = int(np.asarray(self._read_back(tok))[0])
                 st.ready()
                 self._emit(slot, first, finished)
         if self.tracer.enabled and ctx is not None:
@@ -1625,8 +1656,8 @@ class ServingEngine:
             nxt, self.pool = step_fn(params, tok, pos,
                                      self.pool, tables,
                                      self._next_rng())
-            # dstpu: ignore[DT001]: THE one host roundtrip per decode window — EOS/retirement decisions are host-side, amortized over `win` tokens
-            nxt = np.asarray(jax.device_get(nxt))   # [S, win]
+            # THE one host roundtrip per decode window — EOS/retirement decisions are host-side, amortized over `win` tokens
+            nxt = np.asarray(self._read_back(nxt))  # [S, win]
             st.ready()
         self.decode_steps += 1
         tr_on = self.tracer.enabled
@@ -1645,6 +1676,21 @@ class ServingEngine:
                     self.tracer.record(ctx, "decode_window", ph.t0,
                                        ph.t1 - ph.t0, tid=self.trace_tid,
                                        attrs={"emitted": j})
+
+    def _read_back(self, out):
+        """THE blocking read of a step program's tokens. A counted model's
+        program hands (tokens, counts): the counts, and those parked by
+        programs whose tokens nobody read, come back in the same
+        `device_get` and are added to this step's sums."""
+        if not self.step_counter_names:
+            # dstpu: ignore[DT001]: the scheduler's one host roundtrip per device call (decode window, verify step, a prompt's first token) — retirement and acceptance are host-side
+            return jax.device_get(out)
+        toks, counts = out
+        # dstpu: ignore[DT001]: the same roundtrip for a counted model — tokens and counters in ONE device_get, no second sync
+        toks, *counts = jax.device_get([toks, counts] + self._parked_counts)
+        self._parked_counts = []
+        self._step_counts += np.sum(counts, axis=0, dtype=np.int64)
+        return toks
 
     def _compiled_programs(self) -> int:
         """Compiled-program count over the persistent step functions: its
@@ -1721,8 +1767,8 @@ class ServingEngine:
         `xla_scatter` (`ops/attention_dispatch.py::kv_pool_writer` decides
         from the pool's dtype and shape and the platform; there is nothing
         to set). A program appears once it has been traced. Empty for a
-        model that keeps no record: the streamed layers and the MoE stack
-        have the scatter form only."""
+        model that keeps no record: the streamed layers and the per-layer
+        (`moe_freq` >= 2) MoE stack have the scatter form only."""
         traced = getattr(self.engine.model_spec, "kv_pool_writers", None) or {}
         return {program: traced[phase] for program, phase in (
             ("decode_step", "paged_decode"), ("prefill_step", "prefill_chunk"),
@@ -1743,6 +1789,13 @@ class ServingEngine:
                "available_blocks": self.allocator.available,
                "compiles": self.compile_stats(),
                "kv_pool_writer": self.kv_pool_writers()}
+        if self.step_counter_names:
+            # the model's own counters (routed experts: calls, assignments,
+            # active experts, the largest expert's load), summed over layers
+            # and steps; `StepRecord.counters` has them a step
+            out["step_counters"] = dict(zip(
+                self.step_counter_names,
+                (int(v) for v in self.step_counter_totals)))
         if self.spec_on:
             out["spec_decode"] = {
                 "drafter": self.drafter.name,

@@ -163,7 +163,8 @@ def build_draft_program(decode_paged_fn, draft_k: int):
     def draft_steps(params, tok, pos, pool, tables):
         def body(carry, _):
             tok, pos, pool = carry
-            logits, pool = decode_paged_fn(params, tok, pos, pool, tables)
+            # [:2]: a counted model (`step_counters`) returns its counts too
+            logits, pool = decode_paged_fn(params, tok, pos, pool, tables)[:2]
             nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
             return (nxt, pos + 1, pool), nxt
 
@@ -255,9 +256,8 @@ class DraftModelDrafter(Drafter):
                                                 self.k)
 
         def prefill(params, toks, start, last_idx, pool, table):
-            _, pool = draft_spec.prefill_paged_fn(params, toks, start,
-                                                  last_idx, pool, table)
-            return pool
+            return draft_spec.prefill_paged_fn(params, toks, start, last_idx,
+                                               pool, table)[1]
 
         self._prefill = jax.jit(prefill, donate_argnums=(4,))
 

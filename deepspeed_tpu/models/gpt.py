@@ -59,6 +59,10 @@ class GPTConfig:
                                      # "local", ...): "local" layers apply the
                                      # sliding_window mask, "global" full causal
     scale_attn: bool = True          # GPT-Neo scores are NOT scaled by 1/sqrt(hd)
+    qk_norm: bool = False            # OLMoE/OLMo-2: RMSNorm over the WHOLE
+                                     # projected query and key (all heads'
+                                     # columns together) before the heads
+                                     # are split and rotated
     tie_embeddings: bool = True
     remat: bool = True               # jax.checkpoint each block
     remat_policy: str = "nothing_saveable"  # jax.checkpoint_policies name, or
@@ -213,6 +217,9 @@ def init_gpt_params(cfg: GPTConfig, seed: int = 0, dtype=jnp.float32):
     if not cfg.use_rmsnorm:
         block["ln1_bias"] = zeros(L, D)
         block["ln2_bias"] = zeros(L, D)
+    if cfg.qk_norm:
+        block["q_norm_scale"] = ones(L, H * cfg.head_dim)
+        block["k_norm_scale"] = ones(L, cfg.n_kv_head * cfg.head_dim)
     if cfg.use_swiglu:
         block["mlp_gate_w"] = norm(L, D, F)
         block["mlp_up_w"] = norm(L, D, F)
@@ -268,6 +275,9 @@ def gpt_init_fn(cfg: GPTConfig, dtype=jnp.float32):
         if not cfg.use_rmsnorm:
             block["ln1_bias"] = zeros(L, D)
             block["ln2_bias"] = zeros(L, D)
+        if cfg.qk_norm:
+            block["q_norm_scale"] = ones(L, cfg.n_head * cfg.head_dim)
+            block["k_norm_scale"] = ones(L, cfg.n_kv_head * cfg.head_dim)
         if cfg.use_swiglu:
             block["mlp_gate_w"] = norm(L, D, F)
             block["mlp_up_w"] = norm(L, D, F)
@@ -312,6 +322,10 @@ def gpt_param_specs(cfg: GPTConfig):
     if not cfg.use_rmsnorm:
         block["ln1_bias"] = P(None, None)
         block["ln2_bias"] = P(None, None)
+    if cfg.qk_norm:
+        # the norm reduces over all heads' columns: replicated, like ln1
+        block["q_norm_scale"] = P(None, None)
+        block["k_norm_scale"] = P(None, None)
     if cfg.use_swiglu:
         block["mlp_gate_w"] = P(None, None, t)
         block["mlp_up_w"] = P(None, None, t)
@@ -530,6 +544,16 @@ def _mlp(h, p, cfg, constrain=True):
     return _ckpt_name(up @ p["mlp_down_w"] + p["mlp_out_b"], "mlp_down")
 
 
+def _qk_norm(q, k, p, cfg: GPTConfig):
+    """`cfg.qk_norm`: RMSNorm over the whole projected query [.., H*hd] and
+    key [.., Hkv*hd], before the heads are split (one definition for the
+    training, prefill and paged halves)."""
+    if not cfg.qk_norm:
+        return q, k
+    return (_norm(q, p["q_norm_scale"], None, True, cfg.norm_eps),
+            _norm(k, p["k_norm_scale"], None, True, cfg.norm_eps))
+
+
 def _layer_local_flags(cfg: GPTConfig):
     """attn_layer_types → bool[L] scan data (None when uniform attention)."""
     if cfg.attn_layer_types is None:
@@ -553,6 +577,7 @@ def _attn_half(x, p, cfg: GPTConfig, positions, attn_fn=None, constrain=True,
     h = _act_quant(h, cfg)
     qkv = _ckpt_name(h @ p["attn_qkv_w"] + p["attn_qkv_b"], "qkv_proj")
     q, k, v = jnp.split(qkv, [H * hd, (H + Hkv) * hd], axis=-1)
+    q, k = _qk_norm(q, k, p, cfg)
     q = q.reshape(B, T, H, hd)
     k = k.reshape(B, T, Hkv, hd)
     v = v.reshape(B, T, Hkv, hd)
@@ -851,6 +876,7 @@ def _decode_qkv(x, p, positions, cfg: GPTConfig):
               cfg.norm_eps)
     qkv = h @ p["attn_qkv_w"] + p["attn_qkv_b"]
     q, k, v = jnp.split(qkv, [H * hd, (H + Hkv) * hd], axis=-1)
+    q, k = _qk_norm(q, k, p, cfg)
     q = q.reshape(B, C, H, hd)
     k = k.reshape(B, C, Hkv, hd)
     v = v.reshape(B, C, Hkv, hd)
@@ -974,6 +1000,8 @@ def gpt_cache_identity(cfg: GPTConfig, name: str = "") -> str:
               cfg.attn_layer_types, cfg.scale_attn, cfg.parallel_residual,
               cfg.use_swiglu, cfg.activation, jnp.dtype(cfg.dtype).name,
               jnp.dtype(cfg.softmax_dtype).name)
+    if cfg.qk_norm:     # appended only when set: older fingerprints keep
+        fields += ("qk_norm",)
     return "gpt:" + "|".join(map(str, fields))
 
 
@@ -1043,50 +1071,8 @@ def make_gpt_decode_model(cfg: GPTConfig = None, name="gpt2-125m", params=None, 
     pool_writers = {}
 
     def _scan_paged(params, x, pool, block_tables, positions, phase=None):
-        # the pool is a PYTREE of [L, N, ...] leaves (k/v, plus the int8
-        # pool's k_scale/v_scale), so the quantized and fp layouts share one
-        # scan body — a layer's pool arrives as a dict. `phase` labels the
-        # dispatch site ("verify" for the spec-decode chunk; None = derive
-        # decode/prefill from the chunk width).
-        #
-        # Two forms of one loop, chosen by `attn_dispatch.kv_pool_writer`
-        # (the rule and its invariant live there). In place: the leaves are
-        # flattened to [L*N, ...] (a bitcast: leading dimensions merge),
-        # CARRIED, and layer l addresses its blocks as `table + l*N`; every
-        # write is the aliased Mosaic call, so the compiled program holds
-        # nothing of the pool's size but those calls. Otherwise the pool
-        # rides the scan as xs and comes back as ys (which never alias: the
-        # program copies the pool once, donated or not) and each layer's
-        # slice takes an XLA scatter.
-        flags = _layer_local_flags(cfg)
-        writer = attn_dispatch.kv_pool_writer(pool)
-        pool_writers[phase or ("paged_decode" if x.shape[1] == 1
-                               else "prefill_chunk")] = writer
-        if writer == attn_dispatch.KV_POOL_WRITE_KERNEL:
-            L, N = pool["k"].shape[:2]
-
-            def body(carry, inputs):
-                x, flat = carry
-                p, layer, flag = inputs
-                x, flat = _block_paged(x, p, flat, positions, block_tables,
-                                       cfg, local_flag=flag, phase=phase,
-                                       block_base=layer * N)
-                return (x, flat), None
-
-            flat = {k: v.reshape((L * N,) + v.shape[2:])
-                    for k, v in pool.items()}
-            (x, flat), _ = jax.lax.scan(
-                body, (x, flat),
-                (params["blocks"], jnp.arange(L, dtype=jnp.int32), flags))
-            return x, {k: v.reshape(pool[k].shape) for k, v in flat.items()}
-
-        def body(x, inputs):
-            p, pool_l, flag = inputs
-            x, pool_l = _block_paged(x, p, pool_l, positions, block_tables,
-                                     cfg, local_flag=flag, phase=phase)
-            return x, pool_l
-
-        return jax.lax.scan(body, x, (params["blocks"], pool, flags))
+        return scan_paged(cfg, params["blocks"], x, pool, block_tables,
+                          positions, phase=phase, pool_writers=pool_writers)
 
     def prefill_paged_fn(params, tokens, start_pos, last_idx, pool,
                          block_tables):
@@ -1171,6 +1157,82 @@ def init_paged_kv_pool(cfg: GPTConfig, num_blocks, block_size,
         pool["k_scale"] = jnp.zeros(sshape, jnp.float32)
         pool["v_scale"] = jnp.zeros(sshape, jnp.float32)
     return pool
+
+
+def scan_paged(cfg: GPTConfig, blocks, x, pool, block_tables, positions,
+               phase=None, pool_writers=None, block_fn=None, aux=None):
+    """The layer loop of every paged program: x through the stacked `blocks`
+    against the paged pool. Returns (x, pool), or (x, pool, aux) when `aux`
+    is given.
+
+    The pool is a PYTREE of [L, N, ...] leaves (k/v, plus the int8 pool's
+    k_scale/v_scale), so the quantized and fp layouts share one scan body —
+    a layer's pool arrives as a dict. `phase` labels the dispatch site
+    ("verify" for the spec-decode chunk; None = derive decode/prefill from
+    the chunk width); `pool_writers[phase]` records the writer chosen.
+
+    `block_fn` (default `_block_paged`) is one layer: `(x, p, pool_l,
+    positions, block_tables, cfg, local_flag=, phase=, block_base=) ->
+    (x, pool_l)`, with `layer=` the traced layer index (what a layer needs
+    beside its slice `p` it addresses in a whole stack it closes over, as
+    the pool is). With `aux` (an initial value), it returns
+    a third result that is summed over the layers into `aux` — the routed
+    experts' counters (`models/moe_gpt.py`); the dense family passes
+    neither.
+
+    Two forms of one loop, chosen by `attn_dispatch.kv_pool_writer` (the
+    rule and its invariant live there). In place: the leaves are flattened
+    to [L*N, ...] (a bitcast: leading dimensions merge), CARRIED, and layer
+    l addresses its blocks as `table + l*N`; every write is the aliased
+    Mosaic call, so the compiled program holds nothing of the pool's size
+    but those calls. Otherwise the pool rides the scan as xs and comes back
+    as ys (which never alias: the program copies the pool once, donated or
+    not) and each layer's slice takes an XLA scatter."""
+    block_fn = block_fn or _block_paged
+    counted = aux is not None
+    L = pool["k"].shape[0]
+    layer_ids = jnp.arange(L, dtype=jnp.int32)
+    flags = _layer_local_flags(cfg)
+    writer = attn_dispatch.kv_pool_writer(pool)
+    if pool_writers is not None:
+        pool_writers[phase or ("paged_decode" if x.shape[1] == 1
+                               else "prefill_chunk")] = writer
+
+    def layer(x, p, pool_l, flag, acc, layer_id, block_base=None):
+        x, pool_l, *counts = block_fn(
+            x, p, pool_l, positions, block_tables, cfg, local_flag=flag,
+            phase=phase, block_base=block_base, layer=layer_id)
+        return x, pool_l, (acc + counts[0] if counted else acc)
+
+    def result(x, pool, acc):
+        return (x, pool, acc) if counted else (x, pool)
+
+    if writer == attn_dispatch.KV_POOL_WRITE_KERNEL:
+        N = pool["k"].shape[1]
+
+        def body(carry, inputs):
+            x, flat, acc = carry
+            p, layer_id, flag = inputs
+            return layer(x, p, flat, flag, acc, layer_id,
+                         block_base=layer_id * N), None
+
+        flat = {k: v.reshape((L * N,) + v.shape[2:])
+                for k, v in pool.items()}
+        (x, flat, aux), _ = jax.lax.scan(
+            body, (x, flat, aux),
+            (blocks, layer_ids, flags))
+        return result(x, {k: v.reshape(pool[k].shape)
+                          for k, v in flat.items()}, aux)
+
+    def body(carry, inputs):
+        x, acc = carry
+        p, pool_l, flag, layer_id = inputs
+        x, pool_l, acc = layer(x, p, pool_l, flag, acc, layer_id)
+        return (x, acc), pool_l
+
+    (x, aux), pool = jax.lax.scan(body, (x, aux),
+                                  (blocks, pool, flags, layer_ids))
+    return result(x, pool, aux)
 
 
 def _paged_attend(q, k_ctx, v_ctx, q_pos, cfg: GPTConfig, local_flag=None):
@@ -1338,15 +1400,18 @@ def _paged_attn_half(x, p, pool_l, positions, block_tables,
 
 def _block_paged(x, p, pool_l, positions, block_tables,
                  cfg: GPTConfig, local_flag=None, phase=None,
-                 block_base=None):
+                 block_base=None, layer=None, mlp_fn=None):
     """One transformer block against the paged pool (decode, prefill
     chunk, or the spec-decode verify chunk — `phase` labels the dispatch
-    site; `block_base` selects `_paged_attn_half`'s in-place form)."""
+    site; `block_base` selects `_paged_attn_half`'s in-place form;
+    `mlp_fn` swaps the dense MLP, as in `_residual_mlp`; `layer`,
+    `scan_paged`'s layer index, is for blocks that need it)."""
+    del layer
     attn_out, pool_l = _paged_attn_half(
         x, p, pool_l, positions, block_tables, cfg, local_flag=local_flag,
         phase=phase, block_base=block_base)
     with jax.named_scope("mlp"):
-        x = _residual_mlp(x, attn_out, p, cfg, constrain=False)
+        x = _residual_mlp(x, attn_out, p, cfg, constrain=False, mlp_fn=mlp_fn)
     return x, pool_l
 
 

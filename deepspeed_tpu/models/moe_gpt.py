@@ -14,16 +14,32 @@ through the dispatch-einsum + sharding-constraint fallback otherwise (XLA
 emits the all-to-all pair). Capacity overflow masks tokens (no dynamic
 shapes); drop/overflow counts surface as `moe/*` telemetry via the loss aux.
 
-Inference routes **capacity-free**: every token goes to its argmax expert
-with a one-hot combine (`_moe_mlp_nodrop`). That choice is deliberate — the
-routing decision depends only on the token itself, never on batch
-composition or chunk boundaries, which is exactly the invariance the paged
-serving path needs for token-identical continuous batching (a prompt chunked
-3 ways routes identically to the same prompt in one pass). Capacity is a
-training-throughput construct; at serving granularity it only creates drops.
+Inference routes **capacity-free**, top-k: every token goes to its `top_k`
+most probable experts (`_routed_mlp`: `parallel/moe.py::topk_routing` and
+`routed_experts`, a stable sort of the N*k assignments by expert and one
+grouped matmul a projection, `ops/pallas/moe_gmm.py`). That choice is
+deliberate — the routing decision and each token's result depend only on the
+token itself, never on batch composition or chunk boundaries, which is
+exactly the invariance the paged serving path needs for token-identical
+continuous batching (a prompt chunked 3 ways routes identically to the same
+prompt in one pass). Capacity is a training-throughput construct; at serving
+granularity it only creates drops. The top-1 presets are `top_k` 1 on the
+same path.
+
+Two layouts of the expert weights, told apart by what the tree holds:
+
+  * `moe_freq` >= 2 (MoE-GPT, the HF adapters): `params["moe"][str(layer)]`
+    = `{gate_w, w_up, b_up, w_down, b_down}` beside a dense skeleton; the
+    layers differ, so the serving programs loop over them in Python.
+  * `moe_freq` 1 (every block routed: OLMoE): the experts are STACKED in
+    `params["blocks"]` like every other block leaf — `moe_gate_w [L, D, E]`,
+    `moe_w_gate_up [L, E, D, 2F]` (SwiGLU, gate and up side by side),
+    `moe_w_down [L, E, F, D]` — there is no dense MLP, and the layer runs
+    inside `gpt.py::scan_paged`, on the carried pool in its in-place form.
 """
 
 import dataclasses
+import math
 from functools import partial
 from typing import Optional
 
@@ -35,22 +51,31 @@ from jax.sharding import PartitionSpec as P
 from deepspeed_tpu.comm.mesh import (BATCH_AXES, EXPERT_AXIS, SEQ_AXIS,
                                      TENSOR_AXIS, get_mesh, has_mesh,
                                      shard_constraint)
-from deepspeed_tpu.models.gpt import (GPTConfig, _attn_half, _block,
-                                      _block_decode, _decode_attn_half, _embed,
-                                      _lm_head, _norm, _paged_attn_half,
-                                      _residual_mlp, gpt_cache_identity,
+from deepspeed_tpu.models.gpt import (GPTConfig, _act, _attn_half, _block,
+                                      _block_decode, _block_paged,
+                                      _decode_attn_half, _embed, _lm_head,
+                                      _norm, _residual_mlp,
+                                      gpt_cache_identity, gpt_init_fn,
                                       init_gpt_params, init_kv_cache,
-                                      init_paged_kv_pool, gpt_param_specs)
-from deepspeed_tpu.parallel.moe import (can_use_expert_shard_map,
+                                      init_paged_kv_pool, gpt_param_specs,
+                                      scan_paged)
+from deepspeed_tpu.parallel.moe import (ROUTED_COUNTERS,
+                                        can_use_expert_shard_map,
                                         expert_parallel_moe,
-                                        gating_drop_stats, top1_gating)
+                                        gating_drop_stats, routed_experts,
+                                        top1_gating, topk_routing)
 from deepspeed_tpu.runtime.engine import ModelSpec
 
 
 @dataclasses.dataclass
 class MoEGPTConfig(GPTConfig):
     num_experts: int = 8
-    moe_freq: int = 2                 # every moe_freq-th block is MoE (from block 1)
+    moe_freq: int = 2                 # every moe_freq-th block is MoE (from
+                                      # block 1); 1 = EVERY block, experts
+                                      # stacked in params["blocks"], no dense MLP
+    top_k: int = 1                    # experts a token at inference (no
+                                      # capacity); training gates top-1
+    norm_topk_prob: bool = False      # rescale the k probabilities to sum to 1
     capacity_factor: float = 1.25
     eval_capacity_factor: float = 2.0
     min_capacity: int = 4
@@ -58,6 +83,8 @@ class MoEGPTConfig(GPTConfig):
     moe_dispatch_wire: str = "none"   # WireTransform on the facade a2a pair
 
     def moe_layer_ids(self):
+        if self.moe_freq == 1:
+            return list(range(self.n_layer))
         return [i for i in range(self.n_layer) if i % self.moe_freq == 1]
 
 
@@ -65,6 +92,8 @@ def init_moe_gpt_params(cfg: MoEGPTConfig, seed: int = 0, dtype=jnp.float32):
     """Dense skeleton (stacked blocks, gpt.py layout) + per-MoE-layer expert
     weights {layer_id: {gate_w, w_up [E,D,F], w_down [E,F,D]}}."""
     params = init_gpt_params(cfg, seed=seed, dtype=dtype)
+    if cfg.moe_freq == 1:
+        return _stack_experts(params, cfg, jax.random.PRNGKey(seed + 7), dtype)
     rng = np.random.default_rng(seed + 7)
     D, F, E = cfg.d_model, cfg.d_ff, cfg.num_experts
     moe = {}
@@ -81,9 +110,84 @@ def init_moe_gpt_params(cfg: MoEGPTConfig, seed: int = 0, dtype=jnp.float32):
     return params
 
 
+_DENSE_MLP_LEAVES = ("mlp_gate_w", "mlp_up_w", "mlp_up_b", "mlp_down_w",
+                     "mlp_out_b")
+
+
+def _stack_experts(params, cfg: MoEGPTConfig, rng, dtype):
+    """The `moe_freq` 1 layout: the dense MLP leaves leave `blocks`, the
+    stacked experts join it (module docstring). jax-traceable."""
+    D, F, E, L = cfg.d_model, cfg.d_ff, cfg.num_experts, cfg.n_layer
+    k_gate, k_up, k_down = jax.random.split(rng, 3)
+    normal = lambda key, shape, scale: (
+        jax.random.normal(key, shape, dtype) * scale)
+    blocks = {k: v for k, v in params["blocks"].items()
+              if k not in _DENSE_MLP_LEAVES}
+    blocks["moe_gate_w"] = normal(k_gate, (L, D, E), 0.02)
+    if not cfg.use_swiglu:
+        raise NotImplementedError(
+            "the stacked (moe_freq=1) layout holds gated SwiGLU experts; "
+            "plain experts with biases live in per-layer trees (moe_freq>=2)")
+    down_scale = 0.02 / math.sqrt(2 * L)     # a Python float: stays weak-typed
+    blocks["moe_w_gate_up"] = normal(k_up, (L, E, D, 2 * F), 0.02)
+    blocks["moe_w_down"] = normal(k_down, (L, E, F, D), down_scale)
+    return {**params, "blocks": blocks}
+
+
+def moe_gpt_init_fn(cfg: MoEGPTConfig, dtype=jnp.float32):
+    """jax-traceable initializer (rng -> params) for the `moe_freq` 1 layout,
+    the twin of `gpt.py::gpt_init_fn`: under one `jit` the whole tree is
+    made on the device in the type it is served in (the dense MLP leaves it
+    drops are never materialised)."""
+    if cfg.moe_freq != 1:
+        raise NotImplementedError(
+            "moe_gpt_init_fn builds the stacked (moe_freq=1) layout only; "
+            "use init_moe_gpt_params for per-layer expert trees")
+    dense = gpt_init_fn(cfg, dtype=dtype)
+
+    def init(rng):
+        rng, sub = jax.random.split(rng)
+        return _stack_experts(dense(rng), cfg, sub, dtype)
+
+    return init
+
+
+_EXPERT_STACKS = ("moe_w_gate_up", "moe_w_down")
+
+
+def _flat_expert_stacks(blocks):
+    """The stacked experts `[L, E, ...]` as `[L * E, ...]` (a bitcast), in
+    `routed_experts`' names: layer l's experts begin at `l * E`. The WHOLE
+    stack goes to the grouped matmul; slicing a layer out of it in front of
+    a custom call would copy that layer's experts every step."""
+    return {k[len("moe_"):]: v.reshape((-1,) + v.shape[2:])
+            for k, v in blocks.items() if k in _EXPERT_STACKS}
+
+
+def _layer_experts(params, p, lid):
+    """Layer `lid`'s experts as `_routed_mlp` takes them — the tree `{gate_w,
+    ...}` in `routed_experts`' names and where the layer's experts begin in
+    it — from whichever layout `params` is in; None for a dense layer. `p`
+    is the layer's slice of `params["blocks"]`; `lid` may be traced for the
+    stacked layout."""
+    if "moe_gate_w" in p:
+        return ({"gate_w": p["moe_gate_w"],
+                 **_flat_expert_stacks(params["blocks"])},
+                lid * params["blocks"]["moe_gate_w"].shape[-1])
+    mp = params.get("moe", {}).get(str(lid))
+    return mp and (mp, 0)
+
+
 def moe_gpt_param_specs(cfg: MoEGPTConfig):
     specs = gpt_param_specs(cfg)
     e, t = EXPERT_AXIS, TENSOR_AXIS
+    if cfg.moe_freq == 1:
+        blocks = {k: v for k, v in specs["blocks"].items()
+                  if k not in _DENSE_MLP_LEAVES}
+        blocks["moe_gate_w"] = P(None, None, None)
+        blocks["moe_w_gate_up"] = P(None, e, None, t)
+        blocks["moe_w_down"] = P(None, e, t, None)
+        return {**specs, "blocks": blocks}
     moe_spec = {
         "gate_w": P(None, None),
         "w_up": P(e, None, t),
@@ -146,30 +250,57 @@ def _moe_mlp(x, mp, cfg: MoEGPTConfig, training=True, mesh=None):
     return out.reshape(B, T, D), l_aux, stats
 
 
-def _moe_mlp_nodrop(x, mp, cfg: MoEGPTConfig):
-    """Capacity-free inference routing: x [B, T, D] → (out, l_aux).
+def _routed_mlp(x, experts, cfg: MoEGPTConfig):
+    """Capacity-free inference routing: x [B, T, D] -> (out, counters
+    int32[4] in `parallel.moe.ROUTED_COUNTERS` order, the chosen experts
+    [B*T, top_k]).
 
-    Every token goes to its argmax expert, weighted by the gate probability —
-    routing depends only on the token, so any batching/chunking of the same
-    tokens produces identical outputs (the paged-serving parity invariant).
-    Dispatches every token to all experts' rows and masks (E× FFN flops for
-    static shapes; decode is bandwidth-bound, prefill chunks are short).
-    The me·ce aux loss is still reported (eval-time routing balance).
-    """
+    Every token goes to its `cfg.top_k` most probable experts, weighted by
+    the router's probabilities (rescaled under `norm_topk_prob`) — routing
+    and result depend only on the token, so any batching/chunking of the
+    same tokens produces identical outputs (the paged-serving parity
+    invariant). `experts`: `_layer_experts`' (tree, base)."""
+    mp, base = experts
     B, T, D = x.shape
-    E = cfg.num_experts
     xf = x.reshape(B * T, D)
-    logits = (xf @ mp["gate_w"]).astype(jnp.float32)
-    probs = jax.nn.softmax(logits, axis=-1)
-    top = jnp.argmax(probs, axis=-1)                       # [N]
-    gate = jnp.max(probs, axis=-1).astype(x.dtype)         # [N]
-    onehot = jax.nn.one_hot(top, E, dtype=x.dtype)         # [N, E]
-    l_aux = jnp.sum(jnp.mean(probs, axis=0)
-                    * jnp.mean(onehot.astype(jnp.float32), axis=0)) * E
-    xe = jnp.einsum("ne,nd->end", onehot, xf)              # [E, N, D]
-    ye = _expert_ffn(xe, mp, cfg)                          # [E, N, D]
-    out = jnp.einsum("ne,end->nd", onehot, ye) * gate[:, None]
-    return out.reshape(B, T, D), l_aux
+    top_p, top_e = topk_routing(xf, mp["gate_w"], cfg.top_k,
+                                cfg.norm_topk_prob)
+    out, counters = routed_experts(
+        xf, top_p, top_e, {k: v for k, v in mp.items() if k != "gate_w"},
+        activation=lambda h: _act(h, cfg), num_experts=cfg.num_experts,
+        expert_base=base)
+    return out.reshape(B, T, D), counters, top_e
+
+
+def _router_balance(x, gate_w, cfg: MoEGPTConfig):
+    """The me.ce load-balance statistic (1 = even) of the inference router
+    on x [B, T, D]: reported by the evaluation forward, never trained on."""
+    E = cfg.num_experts
+    xf = x.reshape(-1, x.shape[-1])
+    probs = jax.nn.softmax(jnp.dot(xf, gate_w.astype(xf.dtype),
+                                   preferred_element_type=jnp.float32), -1)
+    _, top_e = jax.lax.top_k(probs, cfg.top_k)
+    chosen = jnp.sum(jax.nn.one_hot(top_e, E, dtype=jnp.float32), axis=1)
+    return jnp.sum(jnp.mean(probs, axis=0)
+                   * jnp.mean(chosen, axis=0) / cfg.top_k) * E
+
+
+def _routed_mlp_fn(experts, cfg, counters=None, routing=None):
+    """`_residual_mlp`'s `mlp_fn` for a routed layer (`experts`:
+    `_layer_experts`' pair, None for a dense layer -> None); each call's
+    counters and chosen experts are appended to the lists `counters` and
+    `routing` where given."""
+    if experts is None:
+        return None
+
+    def mlp_fn(h):
+        out, counted, top_e = _routed_mlp(h, experts, cfg)
+        if counters is not None:
+            counters.append(counted)
+        if routing is not None:
+            routing.append(top_e)
+        return out
+    return mlp_fn
 
 
 def _zero_drop_stats():
@@ -184,7 +315,7 @@ def _sum_drop_stats(acc, s):
 
 
 def moe_gpt_forward(params, tokens, cfg: MoEGPTConfig, training=True, rng=None,
-                    mesh=None, return_stats=False):
+                    mesh=None, return_stats=False, routing=None):
     """[B, T] → (logits, total_l_aux[, drop_stats]). Python loop over layers
     (MoE layers break the homogeneous scan; L is moderate for MoE models)."""
     B, T = tokens.shape
@@ -192,15 +323,20 @@ def moe_gpt_forward(params, tokens, cfg: MoEGPTConfig, training=True, rng=None,
     x = _embed(params, tokens, positions, cfg)
     x = shard_constraint(x, BATCH_AXES, SEQ_AXIS, None)
 
+    if training and cfg.moe_freq == 1:
+        raise NotImplementedError(
+            "training a moe_freq=1 (stacked, top-k routed) MoE is not built "
+            "yet: the capacity gating below is top-1 over per-layer trees "
+            "(ROADMAP R6, training half)")
     l_aux_total = jnp.asarray(0.0, jnp.float32)
     stats_total = _zero_drop_stats()
-    moe_ids = set(cfg.moe_layer_ids())
     for lid in range(cfg.n_layer):
         p = jax.tree_util.tree_map(lambda a: a[lid], params["blocks"])
-        if lid in moe_ids:
+        mp = _layer_experts(params, p, lid)
+        if mp is not None:
             # attention half from the dense block, MLP half replaced by MoE
-            x, l_aux, stats = _moe_block(x, p, params["moe"][str(lid)], cfg,
-                                         positions, training, mesh)
+            x, l_aux, stats = _moe_block(x, p, mp, cfg, positions, training,
+                                         mesh, routing)
             l_aux_total = l_aux_total + l_aux
             stats_total = _sum_drop_stats(stats_total, stats)
         else:
@@ -215,17 +351,31 @@ def moe_gpt_forward(params, tokens, cfg: MoEGPTConfig, training=True, rng=None,
     return logits, l_aux_total
 
 
-def _moe_block(x, p, mp, cfg, positions, training, mesh=None):
+def moe_gpt_routing(params, tokens, cfg: MoEGPTConfig):
+    """The experts the inference forward (`moe_gpt_forward(training=False)`,
+    in the model's own dtype) routes every token to: tokens [B, T] -> int32
+    [routed layers, B, T, top_k], each token's experts in ascending order.
+    The reference check compares these SETS with the float32 reference's
+    (near-ties can swap)."""
+    chosen = []
+    moe_gpt_forward(params, tokens, cfg, training=False, routing=chosen)
+    return jnp.stack([jnp.sort(top_e, axis=-1).reshape(tokens.shape + (-1,))
+                      for top_e in chosen])
+
+
+def _moe_block(x, p, mp, cfg, positions, training, mesh=None, routing=None):
     """Transformer block with MoE MLP (attention half shared with gpt._block,
     so alibi/sliding-window/parallel-residual behave identically)."""
     aux = []
 
     def moe_fn(h):
         if training:
-            out, l_aux, stats = _moe_mlp(h, mp, cfg, training=True, mesh=mesh)
+            out, l_aux, stats = _moe_mlp(h, mp[0], cfg, training=True,
+                                         mesh=mesh)
         else:
-            out, l_aux = _moe_mlp_nodrop(h, mp, cfg)
-            stats = _zero_drop_stats()
+            out = _routed_mlp_fn(mp, cfg, routing=routing)(h)
+            l_aux, stats = _router_balance(h, mp[0]["gate_w"], cfg), \
+                _zero_drop_stats()
         aux.append((l_aux, stats))
         return out
 
@@ -277,27 +427,19 @@ def make_moe_gpt_model(cfg: MoEGPTConfig, name="moe-gpt", seed=0,
 # ----------------------------------------------------------------------
 
 
-def _moe_mlp_decode(x, mp, cfg):
-    """Single-token routing (kept for the contiguous decode path): the
-    [B, 1, D] special case of `_moe_mlp_nodrop`."""
-    out, _ = _moe_mlp_nodrop(x, mp, cfg)
-    return out
-
-
 def moe_cache_identity(cfg: MoEGPTConfig, name: str = "") -> str:
     """`gpt_cache_identity` plus the MoE fields that change KV VALUES: expert
     count and placement change every MoE layer's output, hence every later
     layer's K/V. Capacity knobs are absent on purpose — inference routing is
     capacity-free, so they cannot change cached bytes."""
-    return (f"moe:{cfg.num_experts}|{cfg.moe_freq}|"
-            + gpt_cache_identity(cfg, name))
+    return (f"moe:{cfg.num_experts}|{cfg.moe_freq}|{cfg.top_k}|"
+            f"{int(cfg.norm_topk_prob)}|" + gpt_cache_identity(cfg, name))
 
 
 def make_moe_gpt_decode_model(cfg: MoEGPTConfig, params=None, name="moe-gpt", seed=0):
     from deepspeed_tpu.inference.engine import DecodeModelSpec
     if params is None:
         params = init_moe_gpt_params(cfg, seed=seed)
-    moe_ids = set(cfg.moe_layer_ids())
 
     def prefill_fn(params, tokens, cache, pad_mask):
         B, T = tokens.shape
@@ -309,12 +451,9 @@ def make_moe_gpt_decode_model(cfg: MoEGPTConfig, params=None, name="moe-gpt", se
             attn_out, k, v = _attn_half(x, p, cfg, positions)
             ks.append(jnp.moveaxis(k, 1, 2))
             vs.append(jnp.moveaxis(v, 1, 2))
-            if lid in moe_ids:
-                mp = params["moe"][str(lid)]
-                moe_fn = lambda h, mp=mp: _moe_mlp_nodrop(h, mp, cfg)[0]
-                x = _residual_mlp(x, attn_out, p, cfg, mlp_fn=moe_fn)
-            else:
-                x = _residual_mlp(x, attn_out, p, cfg)
+            mp = _layer_experts(params, p, lid)
+            x = _residual_mlp(x, attn_out, p, cfg,
+                              mlp_fn=_routed_mlp_fn(mp, cfg))
         x = _norm(x, params["lnf_scale"], params.get("lnf_bias"), cfg.use_rmsnorm,
                   cfg.norm_eps)
         head = params["lm_head"] if not cfg.tie_embeddings else params["wte"]
@@ -332,8 +471,9 @@ def make_moe_gpt_decode_model(cfg: MoEGPTConfig, params=None, name="moe-gpt", se
         new_k, new_v = [], []
         for lid in range(cfg.n_layer):
             p = jax.tree_util.tree_map(lambda a: a[lid], params["blocks"])
-            if lid in moe_ids:
-                x, ck, cv = _moe_block_decode(x, p, params["moe"][str(lid)],
+            mp = _layer_experts(params, p, lid)
+            if mp is not None:
+                x, ck, cv = _moe_block_decode(x, p, mp,
                                               cache["k"][lid], cache["v"][lid],
                                               pos, cfg)
             else:
@@ -353,53 +493,86 @@ def make_moe_gpt_decode_model(cfg: MoEGPTConfig, params=None, name="moe-gpt", se
         return init_kv_cache(cfg, batch_size, max_len, dtype)
 
     # paged-pool serving contract (see DecodeModelSpec): same pool layout and
-    # attention machinery as gpt.py's paged path, but the stacked-layer scan
-    # becomes a Python loop — MoE layers are heterogeneous (per-layer expert
-    # trees), and the capacity-free routing keeps every chunking of a prompt
-    # token-identical, which is what continuous batching relies on.
+    # attention machinery as gpt.py's paged path, and the routed experts keep
+    # every chunking of a prompt token-identical, which is what continuous
+    # batching relies on. Each program also returns the routed layers'
+    # counters, summed over the layers (`step_counters` below).
+    #
+    # Experts stacked in `blocks` (moe_freq 1): every block is alike, so the
+    # layer — attention half and routed experts — runs inside
+    # `gpt.py::scan_paged`, on the carried pool in its in-place form where
+    # `kv_pool_writer` allows it. Per-layer expert trees (`params["moe"]`):
+    # the layers differ, so the loop is Python's and the pool is sliced and
+    # re-stacked a layer (`_loop_paged`, the form every MoE model had
+    # before; no benchmark cell runs it).
+
+    pool_writers = {}
+    no_counts = jnp.zeros((len(ROUTED_COUNTERS),), jnp.int32)
+
 
     def _loop_paged(params, x, pool, block_tables, positions, phase=None):
-        slices = []
+        slices, counts = [], [no_counts]
         for lid in range(cfg.n_layer):
             p = jax.tree_util.tree_map(lambda a: a[lid], params["blocks"])
             pool_l = {k: v[lid] for k, v in pool.items()}
-            attn_out, pool_l = _paged_attn_half(x, p, pool_l, positions,
-                                                block_tables, cfg, phase=phase)
-            if lid in moe_ids:
-                mp = params["moe"][str(lid)]
-                moe_fn = lambda h, mp=mp: _moe_mlp_nodrop(h, mp, cfg)[0]
-                x = _residual_mlp(x, attn_out, p, cfg, constrain=False,
-                                  mlp_fn=moe_fn)
-            else:
-                x = _residual_mlp(x, attn_out, p, cfg, constrain=False)
+            mp = _layer_experts(params, p, lid)
+            x, pool_l = _block_paged(
+                x, p, pool_l, positions, block_tables, cfg, phase=phase,
+                mlp_fn=_routed_mlp_fn(mp, cfg, counts))
             slices.append(pool_l)
         pool = {k: jnp.stack([s[k] for s in slices], 0) for k in pool}
-        return x, pool
+        return x, pool, sum(counts)
+
+    def _layers_paged(params, x, pool, block_tables, positions, phase=None):
+        if "moe_gate_w" not in params["blocks"]:     # per-layer trees
+            return _loop_paged(params, x, pool, block_tables, positions,
+                               phase)
+        # the scan slices the small leaves a layer; the expert stacks stay
+        # whole (closed over, like the carried pool) and the layer finds
+        # its experts by index
+        blocks = params["blocks"]
+        scanned = {k: v for k, v in blocks.items()
+                   if k not in _EXPERT_STACKS}
+
+        def routed_block(x, p, pool_l, positions, block_tables, cfg, layer,
+                         **kwargs):
+            counted = []
+            x, pool_l = _block_paged(
+                x, p, pool_l, positions, block_tables, cfg,
+                mlp_fn=_routed_mlp_fn(_layer_experts(params, p, layer), cfg,
+                                      counted), **kwargs)
+            return x, pool_l, counted[0]
+
+        return scan_paged(cfg, scanned, x, pool, block_tables, positions,
+                          phase=phase, pool_writers=pool_writers,
+                          block_fn=routed_block, aux=no_counts)
 
     def prefill_paged_fn(params, tokens, start_pos, last_idx, pool,
                          block_tables):
         B, C = tokens.shape
         positions = start_pos[:, None] + jnp.arange(C, dtype=jnp.int32)[None]
         x = _embed(params, tokens, positions, cfg)
-        x, pool = _loop_paged(params, x, pool, block_tables, positions)
+        x, pool, counts = _layers_paged(params, x, pool, block_tables,
+                                        positions)
         last = jnp.take_along_axis(x, last_idx[:, None, None], axis=1)
         logits = _lm_head(params, last, cfg)[:, 0]
-        return logits, pool
+        return logits, pool, counts
 
     def decode_paged_fn(params, token, pos, pool, block_tables):
         x = _embed(params, token[:, None], pos[:, None], cfg)
-        x, pool = _loop_paged(params, x, pool, block_tables, pos[:, None])
+        x, pool, counts = _layers_paged(params, x, pool, block_tables,
+                                        pos[:, None])
         logits = _lm_head(params, x, cfg)[:, 0]
-        return logits, pool
+        return logits, pool, counts
 
     def verify_paged_fn(params, tokens, pos, pool, block_tables):
         B, C = tokens.shape
         positions = pos[:, None] + jnp.arange(C, dtype=jnp.int32)[None]
         x = _embed(params, tokens, positions, cfg)
-        x, pool = _loop_paged(params, x, pool, block_tables, positions,
-                              phase="verify")
+        x, pool, counts = _layers_paged(params, x, pool, block_tables,
+                                        positions, phase="verify")
         logits = _lm_head(params, x, cfg)
-        return logits, pool
+        return logits, pool, counts
 
     def init_paged_pool(num_blocks, block_size, dtype=jnp.bfloat16,
                         kv_group_size=0):
@@ -413,6 +586,8 @@ def make_moe_gpt_decode_model(cfg: MoEGPTConfig, params=None, name="moe-gpt", se
                            decode_paged_fn=decode_paged_fn,
                            verify_paged_fn=verify_paged_fn,
                            init_paged_pool=init_paged_pool,
+                           kv_pool_writers=pool_writers,
+                           step_counters=ROUTED_COUNTERS,
                            cache_fingerprint=moe_cache_identity(cfg, name))
 
 
@@ -420,7 +595,7 @@ def _moe_block_decode(x, p, mp, cache_k, cache_v, pos, cfg):
     """_block_decode with the MLP replaced by single-token MoE routing."""
     attn_out, cache_k, cache_v = _decode_attn_half(x, p, cache_k, cache_v, pos, cfg)
     x = _residual_mlp(x, attn_out, p, cfg, constrain=False,
-                      mlp_fn=lambda h: _moe_mlp_decode(h, mp, cfg))
+                      mlp_fn=_routed_mlp_fn(mp, cfg))
     return x, cache_k, cache_v
 
 

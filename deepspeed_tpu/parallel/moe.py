@@ -28,6 +28,10 @@ routing runs one of three ways:
     sort kernel (`ops/pallas/token_sort.py`) ranks each token within its
     expert's queue and tokens scatter into an [E, N] buffer (capacity = N is
     the only static dropless bound; memory E·N·D — for moderate N).
+
+Serving routes a fourth way, `topk_routing` + `routed_experts`: top-k without
+capacity on a sorted, grouped dispatch (`ops/pallas/moe_gmm.py`), whose work
+grows with the N·k assignments and not with E·C.
 """
 
 import dataclasses
@@ -325,6 +329,91 @@ def dropless_moe(flat, gate_w, ffn_fn, num_experts, *, interpret=None):
     ye = ffn_fn(xe)
     out = ye[expert_idx, pos] * gate_val[:, None]
     return out, l_aux, exp_counts
+
+
+# ----------------------------------------------------------------------
+# routed top-k experts on a sorted, grouped dispatch (serving)
+# ----------------------------------------------------------------------
+
+# what `routed_experts` counts a call, in this order (int32[4]); the serving
+# scheduler sums them over layers and steps (`ServingEngine.stats()["moe"]`)
+ROUTED_COUNTERS = ("moe_router_calls", "moe_assignments",
+                   "moe_active_experts", "moe_max_expert_load")
+
+
+def topk_routing(x, gate_w, top_k, normalize=False):
+    """Softmax router without capacity: x [N, D] -> (probabilities [N, k]
+    float32, experts [N, k] int32), the k largest by `lax.top_k`.
+
+    Logits (float32 accumulation) and softmax are float32 whatever `x` is.
+    `normalize=False` (OLMoE's `norm_topk_prob: false`) uses the k
+    probabilities as they are; True rescales them to sum to one. A token's
+    routing depends on that token alone: any batching or chunking of the
+    same tokens routes them the same way."""
+    with jax.named_scope("moe/router"):
+        logits = jnp.dot(x, gate_w.astype(x.dtype),
+                         preferred_element_type=jnp.float32)
+        probs = jax.nn.softmax(logits, axis=-1)
+        top_p, top_e = jax.lax.top_k(probs, top_k)
+        if normalize:
+            top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+    return top_p, top_e.astype(jnp.int32)
+
+
+def routed_experts(x, top_p, top_e, experts, activation=None,
+                   num_experts=None, expert_base=0):
+    """The expert MLPs of N tokens on their k chosen experts each, with no
+    capacity and no dropped token: x [N, D] -> (out [N, D] in `x.dtype`,
+    counters int32[4] in `ROUTED_COUNTERS` order).
+
+    The N*k assignments are sorted by expert (stable), the token rows are
+    gathered in that order, each projection is ONE grouped matmul over the
+    sorted rows (`ops/pallas/moe_gmm.py`: an expert with no rows is never
+    read), and each token's k results are weighted and summed in float32 in
+    the token's own top-k order, so the sum does not depend on what else is
+    in the batch. The same path for a prefill chunk, a decode row and a
+    verify chunk.
+
+    `experts`: gated (SwiGLU) `{"w_gate_up": [E, D, 2F], "w_down":
+    [E, F, D]}`, or plain `{"w_up": [E, D, F], "b_up": [E, F], "w_down":
+    [E, F, D], "b_down": [E, D]}` with `activation` between. The leading
+    dimension may be a longer stack (every layer's experts, `[L * E, ...]`):
+    then `num_experts` is E and `expert_base` (traced: `layer * E`) is where
+    this layer's begin — the whole stack goes to the kernel, nothing is
+    sliced out of it."""
+    from deepspeed_tpu.ops.pallas.moe_gmm import moe_gmm
+
+    N, D = x.shape
+    k = top_e.shape[1]
+    M = N * k
+    gated = "w_gate_up" in experts
+    E = num_experts or experts["w_down"].shape[0]
+    gmm = lambda rows, w: moe_gmm(rows, w, sizes, expert_base)
+    with jax.named_scope("moe/dispatch"):
+        flat_e = top_e.reshape(M)
+        order = jnp.argsort(flat_e, stable=True)      # sorted row -> assignment
+        sizes = jnp.sum(flat_e[:, None] == jnp.arange(E, dtype=jnp.int32),
+                        axis=0, dtype=jnp.int32)
+        rows = jnp.take(x, order // k, axis=0)        # [M, D]
+    with jax.named_scope("moe/experts"):
+        if gated:
+            gate, up = jnp.split(gmm(rows, experts["w_gate_up"]), 2, axis=-1)
+            y = gmm(jax.nn.silu(gate) * up, experts["w_down"])
+        else:
+            sorted_e = jnp.take(flat_e, order)      # per-layer trees: base 0
+            h = gmm(rows, experts["w_up"]) \
+                + jnp.take(experts["b_up"], sorted_e, axis=0)
+            y = gmm(activation(h), experts["w_down"]) \
+                + jnp.take(experts["b_down"], sorted_e, axis=0)
+    with jax.named_scope("moe/combine"):
+        back = jnp.zeros((M,), jnp.int32).at[order].set(
+            jnp.arange(M, dtype=jnp.int32))           # assignment -> sorted row
+        y = jnp.take(y, back, axis=0).reshape(N, k, D).astype(jnp.float32)
+        out = jnp.sum(y * top_p[:, :, None], axis=1).astype(x.dtype)
+    counters = jnp.stack([jnp.int32(1), jnp.int32(M),
+                          jnp.sum(sizes > 0, dtype=jnp.int32),
+                          jnp.max(sizes)])
+    return out, counters
 
 
 @dataclasses.dataclass

@@ -49,7 +49,11 @@ StepRecord = collections.namedtuple("StepRecord", [
     "blocked_on",       # "" | "pool" | "slots": why the head of a non-empty
                         # queue was not admitted
     "compiles",         # growth of the step programs' compile caches
-], defaults=(0, 0, 0, 0, 0, 0, "", 0))
+    "counters",         # the model's own counters of this step's device
+                        # calls, in `ServingEngine.step_counter_names` order
+                        # (routed experts: `parallel.moe.ROUTED_COUNTERS`,
+                        # summed over layers); () for a model with none
+], defaults=(0, 0, 0, 0, 0, 0, "", 0, ()))
 
 RequestRecord = collections.namedtuple("RequestRecord", [
     "uid", "t_submit", "t_admit",

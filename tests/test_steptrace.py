@@ -626,3 +626,85 @@ def test_paged_programs_hold_nothing_of_the_pools_size(one_chip, monkeypatch):
         assert "dstpu_kv_pool_write" not in text, name
         assert [x for x in _large_instructions(text, layer_leaf)
                 if x[1] not in _NO_NEW_BUFFER], name
+
+
+def _compile_routed_paged_programs(one_chip, window):
+    """The paged prefill program and the decode WINDOW program (the
+    scheduler's scan over `window` steps) of a 2-layer routed-expert model
+    at the served tile widths, pool donated."""
+    from deepspeed_tpu.models.moe_gpt import (MoEGPTConfig,
+                                              make_moe_gpt_decode_model,
+                                              moe_gpt_init_fn)
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    cfg = MoEGPTConfig(vocab_size=512, n_layer=2, n_head=8, n_kv_head=8,
+                       d_model=1024, d_ff=1024, max_seq_len=1536,
+                       use_rotary=True, use_rmsnorm=True, use_swiglu=True,
+                       qk_norm=True, tie_embeddings=False, num_experts=8,
+                       top_k=4, moe_freq=1, use_flash_attention=True,
+                       dtype=jnp.bfloat16, remat=False)
+    shapes = jax.eval_shape(moe_gpt_init_fn(cfg, dtype=jnp.bfloat16),
+                            jax.random.PRNGKey(0))
+    spec = make_moe_gpt_decode_model(cfg, name="guard", params=shapes)
+    params = jax.tree_util.tree_map(lambda a: sds(a.shape, a.dtype), shapes)
+    pool = jax.tree_util.tree_map(
+        lambda a: sds(a.shape, a.dtype),
+        jax.eval_shape(lambda: spec.init_paged_pool(64, 512, jnp.bfloat16)))
+    layer_leaf = pool["k"].size // cfg.n_layer * pool["k"].dtype.itemsize
+    experts = shapes["blocks"]["moe_w_gate_up"]
+    layer_experts = experts.size // cfg.n_layer * experts.dtype.itemsize
+    i32 = jnp.int32
+
+    def decode_window(params, tok, pos, pool, tables):
+        def body(carry, _):
+            tok, pos, pool, acc = carry
+            logits, pool, counts = spec.decode_paged_fn(params, tok, pos,
+                                                        pool, tables)
+            nxt = jnp.argmax(logits, -1).astype(i32)
+            return (nxt, pos + 1, pool, acc + counts), nxt
+        (_, _, pool, acc), toks = jax.lax.scan(
+            body, (tok, pos, pool, jnp.zeros((4,), i32)), None, length=window)
+        return (toks, acc), pool
+
+    decode = jax.jit(decode_window, donate_argnums=(3,)).lower(
+        params, sds((64,), i32), sds((64,), i32), pool,
+        sds((64, 3), i32)).compile()
+    prefill = jax.jit(spec.prefill_paged_fn, donate_argnums=(4,)).lower(
+        params, sds((1, 64), i32), sds((1,), i32), sds((1,), i32), pool,
+        sds((1, 3), i32)).compile()
+    return {"decode": decode, "prefill": prefill}, layer_leaf, \
+        layer_experts, dict(spec.kv_pool_writers)
+
+
+def test_routed_paged_programs_hold_nothing_of_the_pools_or_experts_size(
+        one_chip, monkeypatch):
+    """The same guard for the routed-expert model (`models/moe_gpt.py`, the
+    experts stacked in `blocks`), on the programs the scheduler runs: the
+    prefill chunk and the decode window of 4 (a scan of the layer scan).
+    The pool stays in place through both loops, and nothing copies a layer
+    of experts either: the grouped matmul takes the whole stack and an
+    offset (a slice of the stack in front of the Mosaic call would be a
+    copy of ~0.8 GB a layer a step at OLMoE's widths)."""
+    from deepspeed_tpu.ops import attention_dispatch
+    from deepspeed_tpu.platform import device
+    mesh_mod.clear_mesh()
+    monkeypatch.setattr(device, "on_tpu", lambda: True)
+
+    programs, layer_leaf, layer_experts, writers = \
+        _compile_routed_paged_programs(one_chip, window=4)
+    assert writers == {"paged_decode": attention_dispatch.KV_POOL_WRITE_KERNEL,
+                       "prefill_chunk": attention_dispatch.KV_POOL_WRITE_KERNEL}
+    for name, program in programs.items():
+        text = program.as_text()
+        large = _large_instructions(text, min(layer_leaf, layer_experts))
+        assert [x for x in large if x[1] not in _NO_NEW_BUFFER] == [], name
+        calls = {n.rsplit(".", 1)[0] for n, opcode in large
+                 if opcode == "custom-call"}
+        assert calls <= {"dstpu_kv_pool_write", "dstpu_moe_gmm"}, (name, calls)
+        assert program.memory_analysis().temp_size_in_bytes \
+            < min(layer_leaf, layer_experts), name
+        assert "dstpu_moe_gmm" in text, name
+    assert "dstpu_paged_decode" in programs["decode"].as_text()
+    assert "dstpu_kv_pool_gather" in programs["prefill"].as_text()

@@ -438,6 +438,108 @@ def test_token_sort_compiled(n, experts):
     np.testing.assert_array_equal(np.asarray(counts), np.asarray(counts_ref))
 
 
+def _group_sizes(kind, M, E, rng):
+    if kind == "even":
+        return np.full(E, M // E)
+    if kind == "one":                       # one group holds every row
+        sizes = np.zeros(E, np.int64)
+        sizes[E // 3] = M
+        return sizes
+    if kind == "sparse":                    # most experts idle, uneven runs
+        sizes = np.zeros(E, np.int64)
+        live = rng.choice(E, max(2, E // 8), replace=False)
+        sizes[live] = rng.multinomial(M, np.ones(len(live)) / len(live))
+        return sizes
+    return rng.multinomial(M, np.ones(E) / E)        # "random"
+
+
+@pytest.mark.parametrize("kind", ["even", "one", "sparse", "random"])
+@pytest.mark.parametrize("M,K,N", [(512, 2048, 2048), (512, 1024, 2048),
+                                   (4096, 2048, 2048), (64, 2048, 2048)])
+def test_moe_gmm_compiled_matches_the_segment_loop(M, K, N, kind):
+    """`dstpu_moe_gmm` at OLMoE's widths (64 experts; a 64-slot decode step,
+    a 512-token chunk, an 8-row step) against the plain loop over groups.
+    bfloat16 in, float32 accumulation over the whole K in one dot on both
+    sides: the results are equal to the bit, straddled tiles, idle experts
+    and the one-group case included."""
+    from deepspeed_tpu.ops.pallas.moe_gmm import moe_gmm, moe_gmm_reference
+    E = 64
+    rng = np.random.default_rng([M, K, len(kind)])
+    lhs = jnp.asarray(rng.normal(0, 1, (M, K)), jnp.bfloat16)
+    rhs = jnp.asarray(rng.normal(0, 0.05, (E, K, N)), jnp.bfloat16)
+    sizes = jnp.asarray(_group_sizes(kind, M, E, rng), jnp.int32)
+    assert int(sizes.sum()) == M
+    got = jax.jit(lambda a, b, s: moe_gmm(a, b, s, interpret=False))(
+        lhs, rhs, sizes)
+    want = jax.jit(moe_gmm_reference)(lhs, rhs, sizes)
+    np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                  np.asarray(want, np.float32))
+    # the second half of the groups as a layer of its own in the stack
+    half = sizes.at[:E // 2].set(0)
+    half = half.at[E // 2].add(M - half.sum())
+    got = jax.jit(lambda a, b, s, o: moe_gmm(a, b, s[E // 2:], o,
+                                             interpret=False))(
+        lhs, rhs, half, jnp.int32(E // 2))
+    want = jax.jit(moe_gmm_reference)(lhs, rhs, half)
+    np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                  np.asarray(want, np.float32))
+
+
+def test_routed_moe_paged_programs_on_chip():
+    """The routed top-k layer inside `scan_paged` on the chip: the paged
+    programs take the in-place pool form and the Mosaic grouped matmul, and
+    serving through the scheduler (window 1 and 4) emits the tokens of the
+    greedy full forward, teacher-free, at a size whose logits have wide
+    margins (float32)."""
+    import deepspeed_tpu
+    from deepspeed_tpu.comm import mesh as mesh_mod
+    from deepspeed_tpu.config.core import MeshConfig
+    from deepspeed_tpu.inference.scheduler import Request
+    from deepspeed_tpu.models.moe_gpt import (MoEGPTConfig,
+                                              init_moe_gpt_params,
+                                              make_moe_gpt_decode_model,
+                                              moe_gpt_forward)
+    cfg = MoEGPTConfig(n_layer=2, n_head=2, d_model=256, d_ff=128,
+                       vocab_size=512, max_seq_len=512, num_experts=8,
+                       top_k=4, moe_freq=1, use_rotary=True, use_swiglu=True,
+                       use_rmsnorm=True, qk_norm=True, tie_embeddings=False,
+                       use_flash_attention=True, dtype=jnp.float32)
+    params = init_moe_gpt_params(cfg, seed=5)
+    mesh_mod.clear_mesh()
+    mesh_mod.init_mesh(MeshConfig(data=1), devices=jax.devices()[:1])
+    engine = deepspeed_tpu.init_inference(
+        make_moe_gpt_decode_model(cfg, params=params, name="routed"),
+        config={"dtype": "float32", "kv_cache_dtype": "float32",
+                "greedy": True, "kv_block_size": 128, "max_out_tokens": 512})
+    rng = np.random.default_rng(11)
+    prompts = [rng.integers(0, 512, (n,), np.int32) for n in (200, 37)]
+    want = []
+    forward = jax.jit(lambda p, t: moe_gpt_forward(p, t, cfg,
+                                                   training=False)[0])
+    with jax.default_matmul_precision("highest"):
+        for prompt in prompts:
+            seq = list(prompt)
+            for _ in range(8):
+                padded = np.zeros((1, 256), np.int32)
+                padded[0, :len(seq)] = seq
+                seq.append(int(forward(params, padded)[0, len(seq) - 1]
+                               .argmax()))
+            want.append(seq[len(prompt):])
+        for window in (1, 4):
+            serving = engine.serving(max_slots=8, max_context=512,
+                                     prefill_chunk=128, num_kv_blocks=24,
+                                     decode_steps_per_sync=window)
+            done = serving.run([Request(uid=i, tokens=p, max_new_tokens=8,
+                                        stop_on_eos=False)
+                                for i, p in enumerate(prompts)])
+            stats = serving.stats()
+            assert set(stats["kv_pool_writer"].values()) == {
+                "dstpu_kv_pool_write"}
+            assert stats["step_counters"]["moe_assignments"] > 0
+            for i, tokens in enumerate(want):
+                assert list(done[i].tokens) == tokens, (window, i)
+
+
 def test_quant_int4_kernels_refuse_on_tpu():
     """Recorded state, not a TODO: the packed-nibble kernels need stride-2
     lane indexing, which the Pallas TPU lowering refuses; the wrappers say
