@@ -1499,6 +1499,7 @@ class ServingEngine:
         st.begin_step()
         compiled0 = self._compiled_programs()
         chunks0, tokens0 = self.prefill_chunks, self.tokens_generated
+        walk = (0, 0)       # the decode kernel's (live blocks, grid steps)
 
         with self._phase("serving/admit"):
             admitted, blocked_on = self._admit(finished)
@@ -1535,7 +1536,8 @@ class ServingEngine:
             if spec_active:
                 self._verify_decode(dec, tok, pos, tables, finished)
             else:
-                self._decode_window(dec, params, tok, pos, tables, finished)
+                walk = self._decode_window(dec, params, tok, pos, tables,
+                                           finished)
 
         # sync-point housekeeping: hard deadlines, the pressure ladder, and
         # the scheduled pool audit all run here — between compiled calls,
@@ -1566,7 +1568,9 @@ class ServingEngine:
             counters = tuple(int(v) for v in self._step_counts)
             self.step_counter_totals += self._step_counts
             self._step_counts[:] = 0
-        st.end_step(counters=counters, admitted=admitted,
+        st.end_step(counters=counters,
+                    decode_live_blocks=walk[0], decode_grid_steps=walk[1],
+                    admitted=admitted,
                     prefill_chunks=self.prefill_chunks - chunks0,
                     decoding=len(dec),
                     emitted=self.tokens_generated - tokens0,
@@ -1637,7 +1641,9 @@ class ServingEngine:
 
     def _decode_window(self, dec, params, tok, pos, tables, finished):
         """The decode call for every slot in `dec`, its read-back, and the
-        emission of what it sampled."""
+        emission of what it sampled. Returns what the paged decode kernel's
+        walk had to do and what it was launched with, a layer: (live (slot,
+        block) pairs, grid steps), summed over the call's tokens."""
         # the degraded paths run the 1-STEP decode program: with
         # spec decode pressure-disabled the blocks were sized for
         # the k-draft overhang (no window-rounding tail, so a K-step
@@ -1651,11 +1657,18 @@ class ServingEngine:
             else self._decode_step
         win = 1 if use_w1 else self.window
         st = self.steptrace
+        from deepspeed_tpu.ops.pallas.decode_attention import \
+            paged_decode_walk_steps
         with self._phase("serving/decode_window") as ph:
             st.dispatched()
             nxt, self.pool = step_fn(params, tok, pos,
                                      self.pool, tables,
                                      self._next_rng())
+            # counted here, while the device runs
+            live = (pos[[s.idx for s in dec]] + np.arange(win)[:, None]) \
+                // self.block_size + 1                     # [win, slots]
+            walk = (int(live.sum()),
+                    sum(paged_decode_walk_steps(n) for n in live.sum(axis=1)))
             # THE one host roundtrip per decode window — EOS/retirement decisions are host-side, amortized over `win` tokens
             nxt = np.asarray(self._read_back(nxt))  # [S, win]
             st.ready()
@@ -1676,6 +1689,7 @@ class ServingEngine:
                     self.tracer.record(ctx, "decode_window", ph.t0,
                                        ph.t1 - ph.t0, tid=self.trace_tid,
                                        attrs={"emitted": j})
+        return walk
 
     def _read_back(self, out):
         """THE blocking read of a step program's tokens. A counted model's

@@ -1172,13 +1172,14 @@ def scan_paged(cfg: GPTConfig, blocks, x, pool, block_tables, positions,
     the chunk width); `pool_writers[phase]` records the writer chosen.
 
     `block_fn` (default `_block_paged`) is one layer: `(x, p, pool_l,
-    positions, block_tables, cfg, local_flag=, phase=, block_base=) ->
-    (x, pool_l)`, with `layer=` the traced layer index (what a layer needs
-    beside its slice `p` it addresses in a whole stack it closes over, as
-    the pool is). With `aux` (an initial value), it returns
-    a third result that is summed over the layers into `aux` — the routed
-    experts' counters (`models/moe_gpt.py`); the dense family passes
-    neither.
+    positions, block_tables, cfg, local_flag=, phase=, block_base=,
+    decode_work=) -> (x, pool_l)`, with `layer=` the traced layer index
+    (what a layer needs beside its slice `p` it addresses in a whole stack
+    it closes over, as the pool is) and `decode_work=` the decode kernels'
+    work list of this token (None in a chunk). With `aux` (an initial
+    value), it returns a third result that is summed over the layers into
+    `aux` — the routed experts' counters (`models/moe_gpt.py`); the dense
+    family passes neither.
 
     Two forms of one loop, chosen by `attn_dispatch.kv_pool_writer` (the
     rule and its invariant live there). In place: the leaves are flattened
@@ -1194,14 +1195,23 @@ def scan_paged(cfg: GPTConfig, blocks, x, pool, block_tables, positions,
     layer_ids = jnp.arange(L, dtype=jnp.int32)
     flags = _layer_local_flags(cfg)
     writer = attn_dispatch.kv_pool_writer(pool)
+    site = phase or ("paged_decode" if x.shape[1] == 1 else "prefill_chunk")
     if pool_writers is not None:
-        pool_writers[phase or ("paged_decode" if x.shape[1] == 1
-                               else "prefill_chunk")] = writer
+        pool_writers[site] = writer
+    # the decode kernel's work list is the same for every layer (a layer only
+    # offsets the physical blocks): built HERE, once a token, not in the loop
+    decode_work = None
+    if site == "paged_decode":
+        from deepspeed_tpu.ops.pallas.decode_attention import \
+            paged_decode_work
+        decode_work = paged_decode_work(block_tables, positions[:, 0],
+                                        pool["k"].shape[3])
 
     def layer(x, p, pool_l, flag, acc, layer_id, block_base=None):
         x, pool_l, *counts = block_fn(
             x, p, pool_l, positions, block_tables, cfg, local_flag=flag,
-            phase=phase, block_base=block_base, layer=layer_id)
+            phase=phase, block_base=block_base, layer=layer_id,
+            decode_work=decode_work)
         return x, pool_l, (acc + counts[0] if counted else acc)
 
     def result(x, pool, acc):
@@ -1269,7 +1279,7 @@ def _paged_attend(q, k_ctx, v_ctx, q_pos, cfg: GPTConfig, local_flag=None):
 
 def _paged_attn_half(x, p, pool_l, positions, block_tables,
                      cfg: GPTConfig, local_flag=None, phase=None,
-                     block_base=None):
+                     block_base=None, decode_work=None):
     """Attention half-block against one layer's paged pool.
 
     x: [B, C, D]; pool_l: one layer's pool slice — ``k``/``v``
@@ -1288,6 +1298,10 @@ def _paged_attn_half(x, p, pool_l, positions, block_tables,
     `attn_dispatch.kv_pool_writer`. It takes each row's positions to be
     consecutive (`positions[b, c] == positions[b, 0] + c`), as every paged
     program builds them.
+
+    `decode_work`: the decode kernels' work list of these tables
+    (`paged_decode_work`), where the caller built it outside its layer loop;
+    None leaves it to the kernel's wrapper.
 
     Quantized pool: K/V are quantized AT CACHE-WRITE TIME (symmetric
     per-group int8 + f32 scales, `quantization.quantize_kv` — the same
@@ -1360,15 +1374,16 @@ def _paged_attn_half(x, p, pool_l, positions, block_tables,
             attn = paged_decode_attention_quant(
                 q[:, 0], pool_l["k"], pool_l["v"], pool_l["k_scale"],
                 pool_l["v_scale"], block_tables, positions[:, 0],
-                sm_scale=None if cfg.scale_attn else 1.0).reshape(B, 1, D)
+                sm_scale=None if cfg.scale_attn else 1.0,
+                work=decode_work).reshape(B, 1, D)
     elif program == "paged_kernel":
         from deepspeed_tpu.ops.pallas.decode_attention import \
             paged_decode_attention
         with jax.named_scope("attn"):
             attn = paged_decode_attention(
                 q[:, 0], pool_l["k"], pool_l["v"], block_tables,
-                positions[:, 0],
-                sm_scale=None if cfg.scale_attn else 1.0).reshape(B, 1, D)
+                positions[:, 0], sm_scale=None if cfg.scale_attn else 1.0,
+                work=decode_work).reshape(B, 1, D)
     elif program in ("paged_gather_quant", "paged_gather"):
         with jax.named_scope("kv_pool_read"):
             if program == "paged_gather_quant":
@@ -1400,16 +1415,17 @@ def _paged_attn_half(x, p, pool_l, positions, block_tables,
 
 def _block_paged(x, p, pool_l, positions, block_tables,
                  cfg: GPTConfig, local_flag=None, phase=None,
-                 block_base=None, layer=None, mlp_fn=None):
+                 block_base=None, layer=None, mlp_fn=None, decode_work=None):
     """One transformer block against the paged pool (decode, prefill
     chunk, or the spec-decode verify chunk — `phase` labels the dispatch
-    site; `block_base` selects `_paged_attn_half`'s in-place form;
-    `mlp_fn` swaps the dense MLP, as in `_residual_mlp`; `layer`,
-    `scan_paged`'s layer index, is for blocks that need it)."""
+    site; `block_base` selects `_paged_attn_half`'s in-place form and
+    `decode_work` is its decode kernels' work list; `mlp_fn` swaps the dense
+    MLP, as in `_residual_mlp`; `layer`, `scan_paged`'s layer index, is for
+    blocks that need it)."""
     del layer
     attn_out, pool_l = _paged_attn_half(
         x, p, pool_l, positions, block_tables, cfg, local_flag=local_flag,
-        phase=phase, block_base=block_base)
+        phase=phase, block_base=block_base, decode_work=decode_work)
     with jax.named_scope("mlp"):
         x = _residual_mlp(x, attn_out, p, cfg, constrain=False, mlp_fn=mlp_fn)
     return x, pool_l

@@ -21,8 +21,17 @@ supported by attending one kv head's group of query heads per grid cell.
 
 Layout: q [B, H, hd]; k/v cache [B, Hkv, M, hd]; pos [B] (current position,
 inclusive — the new token's k/v must already be scattered at pos).
+
+The PAGED kernels (a pool of physical blocks and a table a row) go further:
+their grid is a list of the live (row, block) pairs and nothing else, every
+KV head of a block in one step (`_paged_walk`). Measured alone on a v5e (PR
+28; 16 calls in one program, 512 x 128 bfloat16 blocks): 0.05 ms a call for
+10 live rows of 32 (14 blocks of 8 heads, 29 MB), where the grid (B, Hkv, nb)
+of one head a step took 1.52 ms whatever was live — 0.18 us a dead step; 2.1
+ms for 746 blocks (1.56 GB: 91% of 819 GB/s).
 """
 
+import collections
 import functools
 import math
 
@@ -175,22 +184,177 @@ def decode_attention(q, k, v, pos, sm_scale=None, block_m=None, interpret=None):
     return out.reshape(B, H, hd)
 
 
-def _paged_decode_kernel(pos_ref, bt_ref, q_ref, k_ref, v_ref, o_ref, acc_ref,
-                         m_ref, l_ref, *, sm_scale, block_m):
-    # Same math as _decode_kernel — only the ADDRESSING differs: the grid's
-    # block axis walks LOGICAL blocks 0..nb-1 of each row, and the index map
-    # (not this body) resolves each one to a physical pool block through the
-    # scalar-prefetched block table. bt_ref is therefore unused here; the
-    # online-softmax state, the live-prefix predicate (j*block_m <= pos) and
-    # the in-block position mask are identical because logical positions are
-    # what `pos` counts.
+# ----------------------------------------------------------------------
+# the paged walk: one grid step per LIVE (slot, logical block) pair
+# ----------------------------------------------------------------------
+
+# What a step's K and V tiles (the int8 pool's scale tiles with them), double-
+# buffered by the pipeline, may take of a kernel's scoped VMEM (16 MiB unless
+# a kernel asks for more): half, which leaves the other half to q, the output,
+# the softmax state and the dots' temporaries.
+_WALK_TILE_BYTES = 8 * 2**20
+
+PagedDecodeWork = collections.namedtuple("PagedDecodeWork", [
+    "count",    # [1] int32: live (slot, logical block) pairs
+    "slot",     # [B * nb] int32: the pairs' slots, slot-major; past `count`
+    "block",    # [B * nb] int32: ... and logical blocks, ascending in a slot
+                # (past `count` both are in range and mean nothing)
+    "live",     # [B] bool: slots with any block that is not the trash block
+])
+
+
+def paged_decode_work(block_tables, pos, block_m):
+    """The walk's work list, from the tables as the scheduler builds them
+    (NOT offset to a layer's blocks: the list is the same for every layer,
+    so `models/gpt.py::scan_paged` builds it once a token, outside the layer
+    loop). A slot is dead when its whole table row is the trash block; a
+    live slot holds blocks 0 .. pos // block_m. Everything here has the
+    tables' size, nothing the pool's."""
+    from deepspeed_tpu.inference.kv_cache import TRASH_BLOCK
+    B, nb = block_tables.shape
+    # the scope names these operations in a compiled program: the guard of
+    # tests/test_steptrace.py finds them by it, outside the layer loop
+    with jax.named_scope("paged_decode_work"):
+        live = jnp.any(block_tables != TRASH_BLOCK, axis=1)
+        blocks = jnp.where(
+            live, jnp.minimum(pos.astype(jnp.int32) // block_m + 1, nb), 0)
+        ends = jnp.cumsum(blocks)
+        i = jnp.arange(B * nb, dtype=jnp.int32)
+        slot = jnp.minimum(
+            jnp.searchsorted(ends, i, side="right", method="compare_all"),
+            B - 1).astype(jnp.int32)
+        block = jnp.clip(i - (ends - blocks)[slot], 0,
+                         nb - 1).astype(jnp.int32)
+        return PagedDecodeWork(ends[-1:].astype(jnp.int32), slot, block, live)
+
+
+def paged_decode_walk_steps(live_blocks):
+    """Host twin of the walk's grid bound: the block-axis steps a call with
+    `live_blocks` live (slot, logical block) pairs is launched with (the
+    scheduler's `StepRecord.decode_grid_steps`). A call with nothing live
+    still takes one step, which computes nothing."""
+    return max(int(live_blocks), 1)
+
+
+def _heads_per_step(Hkv, head_tile_bytes):
+    """KV heads a grid step carries: the most (a divisor of Hkv) whose K and
+    V tiles, double-buffered, fit `_WALK_TILE_BYTES`. All of them at the
+    served widths (8 x 512 x 128 bfloat16: 4 MiB; 16 heads: 8 MiB), so a
+    step moves ONE contiguous `[Hkv, block, hd]` run of the pool per leaf."""
+    heads = Hkv
+    while heads > 1 and (Hkv % heads
+                         or 2 * heads * head_tile_bytes > _WALK_TILE_BYTES):
+        heads -= 1
+    return heads
+
+
+def _vmem_tile_bytes(rows, cols, dtype):
+    """Bytes of a [rows, cols] tile in VMEM: the lane dimension pads to 128
+    (a 1-wide scale column costs what a 128-wide one does)."""
+    return rows * -(-cols // _LANES) * _LANES * jnp.dtype(dtype).itemsize
+
+
+def _paged_walk_kernel(cnt_ref, slot_ref, blk_ref, pos_ref, bt_ref, q_ref,
+                       *refs, load_head, sm_scale, block_m, last_block):
+    # grid (head groups, work items); a step holds every pool leaf's
+    # [1, heads, block_m, ...] tile of ONE live (slot, logical block) pair,
+    # resolved to its physical block by the index map (so bt_ref is unused
+    # here). q_ref / o_ref: [1, heads, G, hd] of the pair's slot; scratch acc
+    # [heads, G, hd] fp32, m/l [heads, G, _LANES] fp32 carry the online
+    # softmax over a slot's pairs, which are consecutive and ascending.
     del bt_ref
-    _decode_kernel(pos_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref,
-                   sm_scale=sm_scale, block_m=block_m)
+    *pool_refs, o_ref, acc_ref, m_ref, l_ref = refs
+    i = pl.program_id(1)
+    b = slot_ref[i]
+    j = blk_ref[i]
+    pos = pos_ref[b]
+
+    @pl.when(j == 0)
+    def _init():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+
+    # false only in the one step of a call with nothing live
+    @pl.when(i < cnt_ref[0])
+    def _step():
+        for h in range(q_ref.shape[1]):
+            k, v = load_head(pool_refs, h, q_ref.dtype)
+            _online_softmax_tile(q_ref[0, h], k, v, pos, j, acc_ref.at[h],
+                                 m_ref.at[h], l_ref.at[h],
+                                 sm_scale=sm_scale, block_m=block_m)
+
+    @pl.when(j == jnp.minimum(pos // block_m, last_block))
+    def _finish():
+        l_safe = jnp.maximum(l_ref[:, :, 0:1], 1e-30)
+        o_ref[0] = (acc_ref[...] / l_safe).astype(o_ref.dtype)
+
+
+def _paged_walk(load_head, q, leaves, block_tables, pos, work, sm_scale,
+                interpret):
+    """THE walk over a paged pool, shared by the float and the int8 kernel:
+    a 1-D list of the live (slot, logical block) pairs (`paged_decode_work`),
+    its length the grid's DYNAMIC bound, so a dead slot and a block past a
+    row's frontier make no step at all; a step carries `_heads_per_step` KV
+    heads of its block — at the served widths all of them, one contiguous
+    run of each pool leaf. `leaves`: the pool's arrays [N, Hkv, block, x],
+    whole; `load_head(pool_refs, h, dtype)` hands head h's K and V tiles
+    [block, hd] in the compute dtype to `_online_softmax_tile`. Rows of dead
+    slots come back ZERO (they ride on through the MLP, and through a routed
+    model's router and its counters)."""
+    if interpret is None:
+        interpret = pallas_interpret()
+    B, H, hd = q.shape
+    _, Hkv, block_m, _ = leaves[0].shape
+    nb = block_tables.shape[1]
+    assert H % Hkv == 0
+    G = H // Hkv
+    if sm_scale is None:
+        sm_scale = 1.0 / math.sqrt(hd)
+    if work is None:
+        work = paged_decode_work(block_tables, pos, block_m)
+    heads = _heads_per_step(Hkv, sum(
+        _vmem_tile_bytes(block_m, x.shape[-1], x.dtype) for x in leaves))
+
+    def pair_index(g, i, cnt_ref, slot_ref, blk_ref, pos_ref, bt_ref):
+        return (bt_ref[slot_ref[i], blk_ref[i]], g, 0, 0)
+
+    def slot_index(g, i, cnt_ref, slot_ref, blk_ref, pos_ref, bt_ref):
+        return (slot_ref[i], g, 0, 0)
+
+    out = pl.pallas_call(
+        functools.partial(_paged_walk_kernel, load_head=load_head,
+                          sm_scale=sm_scale, block_m=block_m,
+                          last_block=nb - 1),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=5,
+            grid=(Hkv // heads, jnp.maximum(work.count[0], 1)),
+            in_specs=[pl.BlockSpec((1, heads, G, hd), slot_index)] + [
+                pl.BlockSpec((1, heads, block_m, x.shape[-1]), pair_index)
+                for x in leaves],
+            out_specs=pl.BlockSpec((1, heads, G, hd), slot_index),
+            scratch_shapes=[
+                pltpu.VMEM((heads, G, hd), jnp.float32),
+                pltpu.VMEM((heads, G, _LANES), jnp.float32),
+                pltpu.VMEM((heads, G, _LANES), jnp.float32),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((B, Hkv, G, hd), q.dtype),
+        interpret=interpret,
+        name="dstpu_paged_decode",
+    )(work.count, work.slot, work.block, pos.astype(jnp.int32),
+      block_tables.astype(jnp.int32), q.reshape(B, Hkv, G, hd), *leaves)
+    # a slot the walk never visits is memory nobody wrote
+    return jnp.where(work.live[:, None, None], out.reshape(B, H, hd), 0)
+
+
+def _load_float_head(pool_refs, h, dtype):
+    k_ref, v_ref = pool_refs
+    return k_ref[0, h], v_ref[0, h]
 
 
 def paged_decode_attention(q, k_pool, v_pool, block_tables, pos, sm_scale=None,
-                           interpret=None):
+                           interpret=None, work=None):
     """Decode attention over a PAGED KV pool (vLLM's PagedAttention layout).
 
     q: [B, H, hd]; k_pool/v_pool: [N, Hkv, block, hd] physical blocks shared
@@ -199,61 +363,20 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, pos, sm_scale=None,
     inclusive — the new token's k/v must already be scattered at pos).
     Returns [B, H, hd].
 
-    The grid walks each row's logical blocks; the kv index map resolves
-    logical → physical through the scalar-prefetched table, so the kernel
-    DMAs exactly the pool tiles covering the live prefix — no [B, M] gather
-    is ever materialized in HBM (the XLA fallback path pays that gather
-    every step). Past-prefix steps clamp to the frontier logical block:
-    consecutive equal physical indices elide the DMA, same trick as the
-    contiguous kernel. Rows whose table entries all point at the reserved
-    trash block (inactive slots) produce garbage output that callers ignore.
-    """
-    if interpret is None:
-        interpret = pallas_interpret()
-    B, H, hd = q.shape
-    N, Hkv, block_m, _ = k_pool.shape
-    nb = block_tables.shape[1]
-    assert H % Hkv == 0
-    G = H // Hkv
-    if sm_scale is None:
-        sm_scale = 1.0 / math.sqrt(hd)
+    The grid walks the live (slot, logical block) pairs and nothing else
+    (`_paged_walk`); the index map resolves logical → physical through the
+    scalar-prefetched table, so the kernel DMAs exactly the pool blocks
+    covering the live prefixes, every KV head of a block in one tile — no
+    [B, M] gather is ever materialized in HBM (the XLA fallback path pays
+    that gather every step). Rows whose table entries all point at the
+    reserved trash block (inactive slots) cost no step and come back zero.
 
-    pos = pos.astype(jnp.int32)
-    block_tables = block_tables.astype(jnp.int32)
-    qg = q.reshape(B, Hkv, G, hd)
-
-    def kv_index(b, h, j, pos_ref, bt_ref):
-        # clamp to the frontier LOGICAL block, then translate to physical:
-        # dead logical blocks re-serve the frontier's physical tile and the
-        # repeated index elides the DMA
-        jj = jnp.minimum(j, pos_ref[b] // block_m)
-        return (bt_ref[b, jj], h, 0, 0)
-
-    out = pl.pallas_call(
-        functools.partial(_paged_decode_kernel, sm_scale=sm_scale,
-                          block_m=block_m),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(B, Hkv, nb),
-            in_specs=[
-                pl.BlockSpec((1, 1, G, hd),
-                             lambda b, h, j, pos_ref, bt_ref: (b, h, 0, 0)),
-                pl.BlockSpec((1, 1, block_m, hd), kv_index),
-                pl.BlockSpec((1, 1, block_m, hd), kv_index),
-            ],
-            out_specs=pl.BlockSpec(
-                (1, 1, G, hd), lambda b, h, j, pos_ref, bt_ref: (b, h, 0, 0)),
-            scratch_shapes=[
-                pltpu.VMEM((G, hd), jnp.float32),
-                pltpu.VMEM((G, _LANES), jnp.float32),
-                pltpu.VMEM((G, _LANES), jnp.float32),
-            ],
-        ),
-        out_shape=jax.ShapeDtypeStruct((B, Hkv, G, hd), q.dtype),
-        interpret=interpret,
-        name="dstpu_paged_decode",
-    )(pos, block_tables, qg, k_pool, v_pool)
-    return out.reshape(B, H, hd)
+    `work`: the `paged_decode_work` of the UN-offset tables, where the
+    caller has it already (the layer scan builds it once a token; here
+    `block_tables` may then be offset to one layer's blocks of a whole
+    stack); None builds it from `block_tables`."""
+    return _paged_walk(_load_float_head, q, (k_pool, v_pool), block_tables,
+                       pos, work, sm_scale, interpret)
 
 
 def _dequant_tile(q, scale, dtype):
@@ -277,49 +400,22 @@ def _dequant_tile(q, scale, dtype):
     return (q.astype(jnp.float32) * s_full).astype(dtype)
 
 
-def _paged_decode_quant_kernel(pos_ref, bt_ref, q_ref, k_ref, v_ref, ks_ref,
-                               vs_ref, o_ref, acc_ref, m_ref, l_ref, *,
-                               sm_scale, block_m):
+def _load_quant_head(pool_refs, h, dtype):
     # The int8-pool variant: k/v tiles arrive QUANTIZED (int8 payload +
-    # [block_m, g] f32 group scales, both resolved through the same
-    # logical->physical index map), are dequantized here in VMEM — fp K/V
-    # never exists in HBM — and then run the shared online-softmax tile
-    # update. Dequant ordering (int8 -> f32 x scale -> narrow to the
-    # compute dtype) is `quantization.dequantize_kv`'s (see _dequant_tile),
-    # so this kernel and the dequantizing gather oracle see bit-identical
-    # tiles.
-    del bt_ref
-    b = pl.program_id(0)
-    j = pl.program_id(2)
-    nm = pl.num_programs(2)
-    pos = pos_ref[b]
-    in_dtype = q_ref.dtype
-
-    @pl.when(j == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-
-    @pl.when(j * block_m <= pos)
-    def _step():
-        _online_softmax_tile(q_ref[0, 0],
-                             _dequant_tile(k_ref[0, 0], ks_ref[0, 0],
-                                           in_dtype),
-                             _dequant_tile(v_ref[0, 0], vs_ref[0, 0],
-                                           in_dtype), pos, j,
-                             acc_ref, m_ref, l_ref,
-                             sm_scale=sm_scale, block_m=block_m)
-
-    @pl.when(j == nm - 1)
-    def _finish():
-        l_safe = jnp.maximum(l_ref[:, 0], 1e-30)
-        o_ref[0, 0] = (acc_ref[...] / l_safe[:, None]).astype(o_ref.dtype)
+    # [block_m, g] f32 group scales, all four resolved through the same
+    # index map), are dequantized here in VMEM — fp K/V never exists in
+    # HBM — and then run the shared online-softmax tile update. Dequant
+    # ordering (int8 -> f32 x scale -> narrow to the compute dtype) is
+    # `quantization.dequantize_kv`'s (see _dequant_tile), so this kernel and
+    # the dequantizing gather oracle see bit-identical tiles.
+    k_ref, v_ref, ks_ref, vs_ref = pool_refs
+    return (_dequant_tile(k_ref[0, h], ks_ref[0, h], dtype),
+            _dequant_tile(v_ref[0, h], vs_ref[0, h], dtype))
 
 
 def paged_decode_attention_quant(q, k_pool, v_pool, k_scale, v_scale,
                                  block_tables, pos, sm_scale=None,
-                                 interpret=None):
+                                 interpret=None, work=None):
     """Decode attention over the INT8 paged pool: dequantize-inside-the-
     kernel PagedAttention.
 
@@ -327,57 +423,15 @@ def paged_decode_attention_quant(q, k_pool, v_pool, k_scale, v_scale,
     [N, Hkv, block, hd//g] f32 (the `init_paged_kv_pool` quantized layout);
     block_tables: [B, nb]; pos: [B]. Returns [B, H, hd] in q's dtype.
 
-    Identical grid walk to `paged_decode_attention` — the scale tiles ride
-    the SAME scalar-prefetched logical->physical index map as the payload,
-    so a step's HBM traffic is the live prefix's int8 bytes plus its scales
+    `paged_decode_attention`'s walk (`_paged_walk`) — the scale tiles ride
+    the SAME index map as the payload and count in the step's VMEM budget,
+    so a step's HBM traffic is its block's int8 bytes plus its scales
     (~half the bf16 pool's traffic at group >= 8): decode is HBM-bandwidth-
     bound, so the quantized pool buys tokens/s, not just capacity. fp K/V
     exists only tile-by-tile in VMEM."""
-    if interpret is None:
-        interpret = pallas_interpret()
-    B, H, hd = q.shape
-    N, Hkv, block_m, _ = k_pool.shape
-    g = k_scale.shape[-1]
-    nb = block_tables.shape[1]
-    assert H % Hkv == 0
-    G = H // Hkv
-    if sm_scale is None:
-        sm_scale = 1.0 / math.sqrt(hd)
-
-    pos = pos.astype(jnp.int32)
-    block_tables = block_tables.astype(jnp.int32)
-    qg = q.reshape(B, Hkv, G, hd)
-
-    def kv_index(b, h, j, pos_ref, bt_ref):
-        jj = jnp.minimum(j, pos_ref[b] // block_m)
-        return (bt_ref[b, jj], h, 0, 0)
-
-    out = pl.pallas_call(
-        functools.partial(_paged_decode_quant_kernel, sm_scale=sm_scale,
-                          block_m=block_m),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(B, Hkv, nb),
-            in_specs=[
-                pl.BlockSpec((1, 1, G, hd),
-                             lambda b, h, j, pos_ref, bt_ref: (b, h, 0, 0)),
-                pl.BlockSpec((1, 1, block_m, hd), kv_index),
-                pl.BlockSpec((1, 1, block_m, hd), kv_index),
-                pl.BlockSpec((1, 1, block_m, g), kv_index),
-                pl.BlockSpec((1, 1, block_m, g), kv_index),
-            ],
-            out_specs=pl.BlockSpec(
-                (1, 1, G, hd), lambda b, h, j, pos_ref, bt_ref: (b, h, 0, 0)),
-            scratch_shapes=[
-                pltpu.VMEM((G, hd), jnp.float32),
-                pltpu.VMEM((G, _LANES), jnp.float32),
-                pltpu.VMEM((G, _LANES), jnp.float32),
-            ],
-        ),
-        out_shape=jax.ShapeDtypeStruct((B, Hkv, G, hd), q.dtype),
-        interpret=interpret,
-    )(pos, block_tables, qg, k_pool, v_pool, k_scale, v_scale)
-    return out.reshape(B, H, hd)
+    return _paged_walk(_load_quant_head, q,
+                       (k_pool, v_pool, k_scale, v_scale), block_tables, pos,
+                       work, sm_scale, interpret)
 
 
 def paged_decode_attention_quant_reference(q, pool_l, block_tables, pos,

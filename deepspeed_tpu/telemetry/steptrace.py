@@ -53,7 +53,13 @@ StepRecord = collections.namedtuple("StepRecord", [
                         # calls, in `ServingEngine.step_counter_names` order
                         # (routed experts: `parallel.moe.ROUTED_COUNTERS`,
                         # summed over layers); () for a model with none
-], defaults=(0, 0, 0, 0, 0, 0, "", 0, ()))
+    "decode_live_blocks",   # live (slot, logical block) pairs of the decode
+                        # call: pos // block + 1 summed over its decoding
+                        # slots and its tokens
+    "decode_grid_steps",    # block-axis steps `dstpu_paged_decode`'s walk
+                        # takes for those positions, a layer, summed over
+                        # the call's tokens (KV heads folded out)
+], defaults=(0, 0, 0, 0, 0, 0, "", 0, (), 0, 0))
 
 RequestRecord = collections.namedtuple("RequestRecord", [
     "uid", "t_submit", "t_admit",

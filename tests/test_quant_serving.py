@@ -22,6 +22,8 @@ from deepspeed_tpu.inference.quantization import (dequantize_kv,
 from deepspeed_tpu.inference.scheduler import Request
 from deepspeed_tpu.models.gpt import (GPTConfig, init_paged_kv_pool,
                                       make_gpt_decode_model)
+from tests.paged_cases import (PAGED_KERNEL_HEADS, PAGED_KERNEL_ROWS,
+                               paged_kernel_case)
 
 pytestmark = pytest.mark.quant
 
@@ -127,25 +129,22 @@ def test_pallas_int4_packed_parity_with_jnp_scheme():
 # ----------------------------------------------------------------------
 
 
-def test_quant_paged_kernel_matches_dequant_gather_oracle():
+@pytest.mark.parametrize("rows", PAGED_KERNEL_ROWS)
+@pytest.mark.parametrize("heads", PAGED_KERNEL_HEADS, ids=str)
+def test_quant_paged_kernel_matches_dequant_gather_oracle(heads, rows):
+    """The float kernel's cases (tests/test_serving.py) on the int8 pool, at
+    4 scale groups a vector: live rows match the dequantizing gather oracle,
+    dead rows are exactly zero."""
     from deepspeed_tpu.ops.pallas.decode_attention import (
         paged_decode_attention_quant, paged_decode_attention_quant_reference)
-    rng = np.random.default_rng(11)
-    B, H, Hkv, hd, bm, N, nb = 4, 8, 4, 64, 128, 12, 3
-    q = jnp.asarray(rng.normal(size=(B, H, hd)), jnp.float32)
-    kq, ks = quantize_kv(jnp.asarray(rng.normal(size=(N, Hkv, bm, hd)),
-                                     jnp.float32), 32)
-    vq, vs = quantize_kv(jnp.asarray(rng.normal(size=(N, Hkv, bm, hd)),
-                                     jnp.float32), 32)
-    # shuffled physical mapping incl. a row parked on the trash block only
-    bt = jnp.asarray([[7, 2, 10], [1, 9, 4], [3, 5, 8], [0, 0, 0]],
-                     jnp.int32)
-    pos = jnp.asarray([5, 200, 383, 0], jnp.int32)
-    out = paged_decode_attention_quant(q, kq, vq, ks, vs, bt, pos)
-    ref = paged_decode_attention_quant_reference(
-        q, {"k": kq, "v": vq, "k_scale": ks, "v_scale": vs}, bt, pos)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                               rtol=2e-5, atol=2e-5)
+    q, k, v, bt, pos, live = paged_kernel_case(heads, rows)
+    kq, ks = quantize_kv(k, 8)
+    vq, vs = quantize_kv(v, 8)
+    out = np.asarray(paged_decode_attention_quant(q, kq, vq, ks, vs, bt, pos))
+    ref = np.asarray(paged_decode_attention_quant_reference(
+        q, {"k": kq, "v": vq, "k_scale": ks, "v_scale": vs}, bt, pos))
+    np.testing.assert_allclose(out[live], ref[live], rtol=2e-5, atol=2e-5)
+    assert not out[~live].any()
 
 
 def test_int8_pool_layout_and_zero_init():

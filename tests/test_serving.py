@@ -19,6 +19,8 @@ from deepspeed_tpu.inference.kv_cache import (BlockAllocator, TRASH_BLOCK,
                                               blocks_needed)
 from deepspeed_tpu.inference.scheduler import Request
 from deepspeed_tpu.models.gpt import GPTConfig, make_gpt_decode_model
+from tests.paged_cases import (PAGED_KERNEL_HEADS, PAGED_KERNEL_ROWS,
+                               paged_kernel_case)
 
 pytestmark = pytest.mark.serving
 
@@ -87,21 +89,48 @@ def test_blocks_needed_math():
 # ----------------------------------------------------------------------
 
 
-def test_paged_decode_kernel_matches_gather_oracle():
+@pytest.mark.parametrize("rows", PAGED_KERNEL_ROWS)
+@pytest.mark.parametrize("heads", PAGED_KERNEL_HEADS, ids=str)
+def test_paged_decode_kernel_matches_gather_oracle(heads, rows):
+    """Live rows match the gather oracle; a dead row (the oracle attends
+    the trash block there) comes back exactly zero."""
     from deepspeed_tpu.ops.pallas.decode_attention import (
         paged_decode_attention, paged_decode_attention_reference)
-    rng = np.random.default_rng(11)
-    B, H, Hkv, hd, bm, N, nb = 4, 8, 4, 64, 128, 12, 3
-    q = jnp.asarray(rng.normal(size=(B, H, hd)), jnp.float32)
-    kp = jnp.asarray(rng.normal(size=(N, Hkv, bm, hd)), jnp.float32)
-    vp = jnp.asarray(rng.normal(size=(N, Hkv, bm, hd)), jnp.float32)
-    # shuffled physical mapping incl. a row parked on the trash block only
-    bt = jnp.asarray([[7, 2, 10], [1, 9, 4], [3, 5, 8], [0, 0, 0]], jnp.int32)
-    pos = jnp.asarray([5, 200, 383, 0], jnp.int32)
-    out = paged_decode_attention(q, kp, vp, bt, pos)
-    ref = paged_decode_attention_reference(q, kp, vp, bt, pos)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                               rtol=2e-5, atol=2e-5)
+    q, kp, vp, bt, pos, live = paged_kernel_case(heads, rows)
+    out = np.asarray(paged_decode_attention(q, kp, vp, bt, pos))
+    ref = np.asarray(paged_decode_attention_reference(q, kp, vp, bt, pos))
+    np.testing.assert_allclose(out[live], ref[live], rtol=2e-5, atol=2e-5)
+    assert not out[~live].any()
+
+
+def test_paged_decode_work_lists_the_live_pairs_in_order():
+    """The walk's work list: one entry a live (slot, logical block) pair,
+    slot-major and ascending, `count` of them; the entries past `count` stay
+    in range. An offset table (a layer's blocks of a flat stack) reads as
+    the same list when the caller hands the list of the plain one."""
+    from deepspeed_tpu.ops.pallas.decode_attention import (
+        paged_decode_attention, paged_decode_walk_steps, paged_decode_work)
+    q, kp, vp, bt, pos, live = paged_kernel_case((2, 2), "mixed")
+    work = paged_decode_work(bt, pos, 512)
+    want = [(b, j) for b in range(len(live)) if live[b]
+            for j in range(int(pos[b]) // 512 + 1)]
+    n = int(work.count[0])
+    assert n == len(want) == 1 + 1 + 2 + 2 + 3
+    assert list(zip(np.asarray(work.slot)[:n].tolist(),
+                    np.asarray(work.block)[:n].tolist())) == want
+    assert np.asarray(work.live).tolist() == live.tolist()
+    assert work.slot.shape == work.block.shape == (bt.size,)
+    assert 0 <= int(work.slot.min()) and int(work.slot.max()) < bt.shape[0]
+    assert 0 <= int(work.block.min()) and int(work.block.max()) < bt.shape[1]
+    assert paged_decode_walk_steps(n) == n and paged_decode_walk_steps(0) == 1
+    # layer 1 of a two-layer flat pool: tables offset by N, the list handed in
+    N = kp.shape[0]
+    flat_k = jnp.concatenate([jnp.zeros_like(kp), kp])
+    flat_v = jnp.concatenate([jnp.zeros_like(vp), vp])
+    np.testing.assert_array_equal(
+        np.asarray(paged_decode_attention(q, flat_k, flat_v, bt + N, pos,
+                                          work=work)),
+        np.asarray(paged_decode_attention(q, kp, vp, bt, pos)))
 
 
 # ----------------------------------------------------------------------

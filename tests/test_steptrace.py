@@ -295,6 +295,45 @@ def test_blocked_on_names_the_pool_or_the_slots():
     assert waits and set(waits) == {"slots"}
 
 
+@pytest.mark.parametrize("window", [1, 4])
+def test_decode_walk_counts_equal_a_hand_count_from_the_lengths(window):
+    """`decode_live_blocks`: pos // block + 1 over a decode call's slots and
+    tokens; `decode_grid_steps`: what the paged decode kernel's walk takes
+    for them (one step a live pair, a token with nothing live one). A
+    request of prompt n and m new tokens is fed at positions n .. n + m - 2,
+    rounded up to whole windows (a slot rides its last window out)."""
+    block, lengths = 16, [(14, 6), (31, 3), (40, 9)]
+    rng = np.random.default_rng(5)
+    reqs = [Request(uid=i, max_new_tokens=m, stop_on_eos=False,
+                    tokens=rng.integers(0, 256, (n,)).astype(np.int32))
+            for i, (n, m) in enumerate(lengths)]
+
+    def fed(n, m):
+        return [n + t for t in range(-(-(m - 1) // window) * window)]
+
+    # one request alone: every decode call's record, position by position
+    serving = _engine().serving(max_slots=1, max_context=128,
+                                decode_steps_per_sync=window)
+    serving.run(reqs[:1])
+    calls = [r for r in serving.steptrace.records() if r.decoding]
+    positions = fed(*lengths[0])
+    assert [r.decode_live_blocks for r in calls] == [
+        sum(p // block + 1 for p in positions[i:i + window])
+        for i in range(0, len(positions), window)]
+    assert [r.decode_grid_steps for r in calls] == \
+        [r.decode_live_blocks for r in calls]
+    assert all(r.decode_live_blocks == r.decode_grid_steps == 0
+               for r in serving.steptrace.records() if not r.decoding)
+    # three at once, however the scheduler interleaves them: the sums
+    serving = _engine().serving(max_slots=2, max_context=128,
+                                decode_steps_per_sync=window)
+    serving.run(reqs)
+    recs = serving.steptrace.records()
+    want = sum(p // block + 1 for n, m in lengths for p in fed(n, m))
+    assert sum(r.decode_live_blocks for r in recs) == want
+    assert sum(r.decode_grid_steps for r in recs) == want
+
+
 def test_compiles_names_the_step_that_compiled():
     serving = _engine().serving(max_slots=2, max_context=128)
     serving.run(_requests(2))
@@ -557,6 +596,49 @@ def _large_instructions(text, at_least):
     return large
 
 
+def _computations(text):
+    """{name: its lines} for every computation of a compiled program."""
+    found, lines = {}, None
+    for line in text.splitlines():
+        head = re.match(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\{$", line)
+        if head:
+            lines = found[head.group(1)] = []
+        elif lines is not None:
+            lines.append(line)
+    return found
+
+
+def _assert_decode_walk_is_built_once_a_token(text):
+    """The layer loop's body holds ONE call named `dstpu_paged_decode*`, the
+    program no other, and nothing of the walk's work list (the operations
+    under the `paged_decode_work` scope) is computed inside that body or in
+    a computation it calls: the list is built once a token, outside."""
+    comps = _computations(text)
+    calls = {name: [l.split("=")[0].strip().lstrip("%") for l in lines
+                    if "custom-call(" in l and "tpu_custom_call" in l]
+             for name, lines in comps.items()}
+    holders = [name for name, kernels in calls.items()
+               if any(k.startswith("dstpu_paged_decode") for k in kernels)]
+    assert len(holders) == 1, holders
+    body = holders[0]
+    assert len([k for k in calls[body]
+                if k.startswith("dstpu_paged_decode")]) == 1, calls[body]
+    assert any(re.search(r"\bwhile\(.*body=%?" + re.escape(body) + r"\b", l)
+               for lines in comps.values() for l in lines), body
+    reached, todo = set(), [body]
+    while todo:
+        name = todo.pop()
+        if name in reached or name not in comps:
+            continue
+        reached.add(name)
+        for l in comps[name]:
+            todo += re.findall(r"(?:calls|to_apply|body|condition)=%?"
+                               r"([\w.\-]+)", l)
+    work = [name for name, lines in comps.items()
+            if any("paged_decode_work" in l for l in lines)]
+    assert work and not set(work) & reached, (work, sorted(reached))
+
+
 def _compile_paged_programs(one_chip, pool_dtype):
     """The paged decode and prefill programs of a 2-layer model at the
     served tile widths (Hkv 8, block 512, hd 128), pool donated."""
@@ -614,7 +696,7 @@ def test_paged_programs_hold_nothing_of_the_pools_size(one_chip, monkeypatch):
             n.startswith("dstpu_kv_pool_write") for n in calls), (name, calls)
         assert program.memory_analysis().temp_size_in_bytes < layer_leaf, name
     # and what reads the carried pool is a Mosaic call as well
-    assert "dstpu_paged_decode" in programs["decode"].as_text()
+    _assert_decode_walk_is_built_once_a_token(programs["decode"].as_text())
     assert "dstpu_kv_pool_gather" in programs["prefill"].as_text()
 
     # the int8 pool: the rule declines, and the program is today's — the pool
@@ -706,5 +788,7 @@ def test_routed_paged_programs_hold_nothing_of_the_pools_or_experts_size(
         assert program.memory_analysis().temp_size_in_bytes \
             < min(layer_leaf, layer_experts), name
         assert "dstpu_moe_gmm" in text, name
-    assert "dstpu_paged_decode" in programs["decode"].as_text()
+    # in the window program the list is built in the token loop's body, once
+    # a token, and not in the layer loop's inside it
+    _assert_decode_walk_is_built_once_a_token(programs["decode"].as_text())
     assert "dstpu_kv_pool_gather" in programs["prefill"].as_text()

@@ -304,6 +304,45 @@ def test_paged_decode_attention_compiled(Hkv):
                                atol=3e-2, rtol=3e-2)
 
 
+@pytest.mark.parametrize("cell", ["mistral", "olmoe"])
+def test_paged_decode_attention_compiled_at_the_served_shapes(cell):
+    """The benchmark's two serving shapes — 32 slots x 32 query heads over 8
+    KV heads and a 32-block table; 64 slots x 16 heads (MHA) over a 3-block
+    table; 512-token blocks of 128 — with live rows of ragged lengths among
+    dead ones: the walk's every-KV-head tile at both widths, the dynamic
+    grid bound, zeros for the dead rows."""
+    from deepspeed_tpu.ops.pallas.decode_attention import (
+        paged_decode_attention, paged_decode_attention_reference)
+    B, H, Hkv, nb, N, contexts = {
+        "mistral": (32, 32, 8, 32, 200,
+                    [1, 512, 513, 700, 2048, 5000, 9000, 16384, 12345, 33]),
+        "olmoe": (64, 16, 16, 3, 130,
+                  [1, 511, 512, 513, 1024, 1025, 1536] * 8),
+    }[cell]
+    rng = np.random.default_rng(28)
+    kq, kk, kv = jax.random.split(jax.random.PRNGKey(28), 3)
+    q = jax.random.normal(kq, (B, H, 128), jnp.bfloat16)
+    k = jax.random.normal(kk, (N, Hkv, 512, 128), jnp.bfloat16)
+    v = jax.random.normal(kv, (N, Hkv, 512, 128), jnp.bfloat16)
+    tables = np.zeros((B, nb), np.int32)
+    pos = np.zeros((B,), np.int32)
+    physical = iter(1 + rng.permutation(N - 1))
+    rows = rng.permutation(B)[:len(contexts)]
+    for b, context in zip(rows, contexts):
+        pos[b] = context - 1
+        for j in range(pos[b] // 512 + 1):
+            tables[b, j] = next(physical)
+    live = np.zeros((B,), bool)
+    live[rows] = True
+    out = np.asarray(jax.jit(lambda *a: paged_decode_attention(
+        *a, interpret=False))(q, k, v, jnp.asarray(tables),
+                              jnp.asarray(pos)), np.float32)
+    ref = np.asarray(paged_decode_attention_reference(
+        q, k, v, jnp.asarray(tables), jnp.asarray(pos)), np.float32)
+    np.testing.assert_allclose(out[live], ref[live], atol=3e-2, rtol=3e-2)
+    assert not out[~live].any()
+
+
 @pytest.mark.parametrize("group", [128, 32])
 def test_paged_decode_attention_quant_compiled(group):
     """int8 pool, dequantized in-kernel: one scale per K/V vector (the
