@@ -111,8 +111,8 @@ def main(argv=None) -> int:
                          "time on hardware")
     ap.add_argument("--isolation", default="inprocess",
                     choices=("inprocess", "process"),
-                    help="process = each trial in a child (the bench-lane "
-                         "recipe; a trial crash costs one trial)")
+                    help="process = each trial in a child process (a "
+                         "trial crash costs one trial)")
     ap.add_argument("--trial-timeout", type=float, default=None)
     ap.add_argument("--dry-run", action="store_true",
                     help="stop after the planner stage: artifact holds "

@@ -14,8 +14,8 @@ swaps in `time.monotonic` for real-hardware tuning, same code path.
 
 Trials can run in-process (the CPU-harness default: one engine at a time,
 torn down between trials) or in a child process via `run_trial_child` —
-the bench-lane `BENCH_*_CHILD` recipe (`utils/subproc.py`), which a crash
-or real OOM cannot take the tuner down with.
+the env-in, JSON-line-out recipe of `utils/subproc.py`, which a crash or
+real OOM cannot take the tuner down with.
 """
 
 import copy
@@ -197,9 +197,8 @@ def measure_training(model_factory, batch_factory,
 def run_trial_child(spec: Dict[str, Any],
                     timeout: Optional[float] = None) -> Dict[str, Any]:
     """Run one trial in a child process (`python -m
-    deepspeed_tpu.autotuning.trial` reading `DSTPU_TUNE_TRIAL`): the
-    bench-lane subprocess recipe, so a segfault or a real device OOM
-    costs one trial, not the tuner. Only specs the trial module can
+    deepspeed_tpu.autotuning.trial` reading `DSTPU_TUNE_TRIAL`), so a
+    segfault or a real device OOM costs one trial, not the tuner. Only specs the trial module can
     reconstruct from JSON are supported (the built-in demo model zoo —
     see `trial.py`); in-process measurement has no such limit."""
     # `--isolation process` from a session that already measured in-process
@@ -211,7 +210,7 @@ def run_trial_child(spec: Dict[str, Any],
     rec, proc = run_json_child(
         [sys.executable, "-m", "deepspeed_tpu.autotuning.trial"],
         {TRIAL_ENV: json.dumps(spec, sort_keys=True)},
-        clear_prefixes=("BENCH_", "DSTPU_TUNE_"), key="ok",
+        clear_prefixes=("DSTPU_TUNE_",), key="ok",
         timeout=timeout)
     if rec is None:
         return {"ok": False, "kind": spec.get("kind", "?"),

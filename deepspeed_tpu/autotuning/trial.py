@@ -1,6 +1,6 @@
 """Trial child entry — `python -m deepspeed_tpu.autotuning.trial`.
 
-The measured stage's subprocess half of the bench-lane recipe
+The measured stage's child half of the subprocess recipe
 (`utils/subproc.py`): the parent (`measure.run_trial_child`) puts a JSON
 trial spec in `DSTPU_TUNE_TRIAL`, this module reconstructs the model,
 runs ONE measurement, and prints the result record as the last stdout

@@ -12,7 +12,7 @@ reduce_scatter / all_to_all / ppermute), each usable two ways:
     bytes*: the bytes one participant hands to the wire per execution of the
     traced program at that call site. Re-running an already-compiled program
     records nothing new — `stats.reset()` then retrace (``jit(...).lower``)
-    to re-measure, which is exactly what bench.py's scaling lane and
+    to re-measure, which is what `Engine.lower_train_step` and
     tests/test_comm_volume.py do. Collectives inside `lax.scan` bodies trace
     once but execute every iteration; pass ``repeats=n_iters`` so the
     accounting matches (parallel/pipeline.py does this for its per-tick

@@ -40,9 +40,6 @@ from deepspeed_tpu.runtime.sentinel import BadStateError, BadStateSentinel
 from deepspeed_tpu.runtime.zero import ZeroShardingPolicy
 from deepspeed_tpu.telemetry import Telemetry
 from deepspeed_tpu.utils.logging import logger, log_dist
-from deepspeed_tpu.utils.timer import (SynchronizedWallClockTimer, ThroughputTimer,
-                                       FORWARD_GLOBAL_TIMER, BACKWARD_GLOBAL_TIMER,
-                                       STEP_GLOBAL_TIMER, TRAIN_BATCH_TIMER)
 from deepspeed_tpu.utils.tree import tree_cast, tree_global_norm, tree_num_params
 
 
@@ -351,9 +348,6 @@ class Engine:
         self.global_steps = 0
         self.micro_steps = 0
         self.skipped_steps = 0
-        self.timers = SynchronizedWallClockTimer()
-        self.tput_timer = ThroughputTimer(batch_size=self.train_batch_size_value,
-                                          steps_per_output=config.steps_per_print)
         self.monitor = self._build_monitor()
         self.losses = None
         self._last_metrics = {}
@@ -995,7 +989,7 @@ class Engine:
             else:
                 # same 2-hop reduce-scatter + all-gather structure for the
                 # fp32 and int8 wires — the facade byte stats then compare
-                # the ENCODING alone (the bench lane's wire-ratio claim)
+                # the ENCODING alone (tests/test_comm_volume.py's wire ratio)
                 grads = jax.tree_util.tree_map(
                     lambda g: coll.compressed_all_reduce(
                         g, slow, wire, group_size=group_size), grads)
@@ -1339,9 +1333,6 @@ class Engine:
         placed = None
         with st.phase("train/place"):
             batch = self._next_batch(batch, data_iter)
-            self.tput_timer.start()
-            self.timers(TRAIN_BATCH_TIMER).start()
-            t_step0 = time.perf_counter()   # timer.start() already fenced the device
             if self.host_optimizer is None:
                 with self._oom_forensics():
                     placed = self._maybe_split_gas(batch)
@@ -1357,15 +1348,12 @@ class Engine:
                 self.state, metrics = self._run_stateful_step(
                     self._train_step, placed)
         with st.phase("train/fence"):
-            self.timers(TRAIN_BATCH_TIMER).stop()
-            step_seconds = time.perf_counter() - t_step0   # incl. stop()'s fence
-            self.tput_timer.stop(global_step=True)
             if _is_ready(metrics["loss"]):
                 st.ready()
         with st.phase("train/after_step"):
             # auto-profile at profile_step (reference engine.forward:1782 /
-            # step:2162 flops_profiler_profile_step hook); outside the timer
-            # window — cost analysis recompiles the step from scratch
+            # step:2162 flops_profiler_profile_step hook); cost analysis
+            # recompiles the step from scratch
             fp_cfg = self.config.flops_profiler
             if fp_cfg.enabled and self._flops_profiler is None \
                     and self.global_steps + 1 >= fp_cfg.profile_step:
@@ -1379,11 +1367,10 @@ class Engine:
                         FlopsProfiler
                     self._flops_profiler = FlopsProfiler(ds_engine=self)
             self._after_step(metrics, count_micro=True)
-            if self.telemetry.enabled:
-                self._record_step_telemetry(batch, placed, step_seconds)
             self._maybe_step_moq(batch)
             self._maybe_step_compression()
         st.end_step(compiles=self._compiled_train_programs() - compiled0)
+        self._report_steps(batch, placed)
         return metrics["loss"]
 
     @contextlib.contextmanager
@@ -1618,9 +1605,6 @@ class Engine:
                                 float(v), self.global_steps)
                                for i, v in enumerate(self.block_eigenvalue)]
                 self.monitor.write_events(events)
-        if self.config.wall_clock_breakdown and \
-                self.global_steps % self.config.steps_per_print == 0:
-            self.timers.log([TRAIN_BATCH_TIMER])
         if self.config.memory_breakdown and \
                 self.global_steps % self.config.steps_per_print == 0:
             # the reference's memory_breakdown knob: periodic
@@ -1639,14 +1623,46 @@ class Engine:
     # telemetry (deepspeed_tpu/telemetry/; opt-in `telemetry` config block)
     # ------------------------------------------------------------------
 
-    def _record_step_telemetry(self, batch, placed, step_seconds):
-        """Per-step observability: step-time histogram, tokens/s gauge, and
-        achieved MFU = program flops / (step wall time x per-chip peak).
+    def _report_steps(self, batch, placed):
+        """What the step ring says of the newest step records, at most
+        `steps_per_print` of them; every time here is a difference of the
+        recorder's stamps. Seconds a step = the span from the first record's
+        start to the last one's end over their count, which holds whole
+        steps whether or not the caller fetched each loss. Every
+        `steps_per_print` steps: one log line with samples/s and, under
+        `wall_clock_breakdown`, the mean milliseconds a step of each phase."""
+        every = self.config.steps_per_print
+        printing = self.global_steps % every == 0
+        if not (printing or self.telemetry.enabled):
+            return
+        recs = self.steptrace.tail(every)
+        step_seconds = (recs[-1].t_end - recs[0].t_start) / len(recs)
+        if printing:
+            line = (f"step={self.global_steps}, samples/s="
+                    f"{self.train_batch_size_value / step_seconds:.6g}")
+            if self.config.wall_clock_breakdown:
+                phases = {}
+                for rec in recs:
+                    for name, seconds in rec.phases:
+                        phases[name] = phases.get(name, 0.0) + seconds
+                line += " | time (ms) a step" + "".join(
+                    f" | {name}: {seconds * 1e3 / len(recs):.2f}"
+                    for name, seconds in phases.items())
+            log_dist(line, ranks=[0])
+        if self.telemetry.enabled:
+            self._record_step_telemetry(batch, placed, step_seconds,
+                                        recs[-1].t_end - recs[-1].t_start)
+
+    def _record_step_telemetry(self, batch, placed, step_seconds,
+                               last_step_seconds):
+        """Per-step observability: step-time histogram (this step's record),
+        tokens/s gauge, and achieved MFU = program flops / (seconds a step x
+        per-chip peak), both over `_report_steps`' window of records.
         Program flops are measured ONCE (see _measure_program_flops); the
         peak is the live device_kind's published one or the
         `telemetry.peak_tflops` override — with neither, no MFU gauge."""
         reg = self.telemetry.registry
-        reg.histogram("train/step_time_ms").observe(step_seconds * 1e3)
+        reg.histogram("train/step_time_ms").observe(last_step_seconds * 1e3)
         tokens = None
         if isinstance(batch, dict):
             t = batch.get("tokens", batch.get("input_ids"))

@@ -14,6 +14,7 @@ the live params, with the reference's `generate()` surface.
 """
 
 import dataclasses
+import time
 from typing import Optional
 
 import jax
@@ -26,7 +27,6 @@ import numpy as np
 from deepspeed_tpu.inference.engine import sample_logits
 from deepspeed_tpu.runtime.engine import Engine, ModelSpec
 from deepspeed_tpu.utils.logging import logger, log_dist
-from deepspeed_tpu.utils.timer import SynchronizedWallClockTimer
 
 
 class HybridEngine(Engine):
@@ -37,7 +37,6 @@ class HybridEngine(Engine):
         super().__init__(model, config, **kw)
         self._decode_spec = decode_spec
         self._generate_fn = None
-        self._gen_timer = SynchronizedWallClockTimer()
         self.latency = 0.0
         self.generate_count = 0
 
@@ -109,13 +108,12 @@ class HybridEngine(Engine):
             rng = jax.random.fold_in(
                 jax.random.fold_in(self.state.rng, int(self.state.step)),
                 self.generate_count)
-        self._gen_timer("generate").start()
+        t0 = time.perf_counter()
         out = self._generate_fn(self.state.params, tokens, cache, prompt_len, rng)
         # dstpu: ignore[DT001]: rollout API boundary — RLHF consumers take host tokens, one transfer per generate()
         out = np.asarray(jax.device_get(out))
-        self._gen_timer("generate").stop()
+        self.latency = time.perf_counter() - t0     # the fetch was the fence
         self.generate_count += 1
-        self.latency = self._gen_timer("generate").elapsed(reset=True)
         return out
 
 

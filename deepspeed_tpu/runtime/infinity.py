@@ -97,7 +97,7 @@ class InfinityEngine:
                                         if landing_depth is not None
                                         else max(1, lookahead)))
         # hostward (grad-landing) stall accounting across the per-pass
-        # pipes — the bench lane's stall fraction includes BOTH directions
+        # pipes, so a stall share can count BOTH directions
         self.hostward_wait_ms_total = 0.0
         self.hostward_bytes_total = 0
         self.L = self.store.num_layers
@@ -433,7 +433,7 @@ class InfinityEngine:
         return self.streamer.peak_live_layers * self.store.layer_bytes
 
     def offload_stats(self):
-        """Host-side overlap counters for the bench offload lane,
+        """Host-side overlap counters,
         available with telemetry off. The two directions are reported
         SEPARATELY on purpose: `staging.stall_ms_total` (device-ward) is
         a pure transfer-lateness signal — acquiring a layer never waits

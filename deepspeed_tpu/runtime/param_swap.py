@@ -26,8 +26,8 @@ The streamer measures the overlap instead of asserting it: every layer
 acquisition that finds its buffer already staged records a ~0
 `offload/stage_wait_ms`; a genuinely late buffer records the real host
 stall. `offload/staging_occupancy` / `offload/inflight_bytes` gauges and
-the `stats()` counters (hits, stall_ms_total) feed the bench offload
-lane's stall-fraction column (docs/offload.md).
+the `stats()` counters (hits, stall_ms_total) give the stall fraction of
+docs/offload.md.
 
 The reference needs ~1.8k LoC of swap machinery because every torch param
 object must be rewired in place; here a layer's weights are just pytree
@@ -274,8 +274,7 @@ class LayerStreamer:
     one step deeper — layer i computes while layer i+1's `jax.device_put`
     and layer i+2's disk read are in flight, so the step never blocks
     except on a genuinely late buffer. `lookahead=0` is the blocking
-    baseline (every acquisition is a miss) — the bench offload lane's
-    comparison arm.
+    baseline (every acquisition is a miss), the arm to compare against.
 
     `cyclic=True` pins the look-ahead to the scan order of a repeating
     layer walk (decode: L-1 wraps to 0), so the first layer of the next
@@ -364,7 +363,7 @@ class LayerStreamer:
         return jax.tree_util.tree_unflatten(self.store.treedef, self._live[i])
 
     def stats(self):
-        """Host-side overlap counters for the bench offload lane (available
+        """Host-side overlap counters (available
         with telemetry off): acquisitions, staged hits, and the total host
         stall — stall_ms_total / step wall time is the stall fraction."""
         return {"acquires": self.acquires, "hits": self.hits,

@@ -97,8 +97,8 @@ class Telemetry:
         flight = bool(getattr(config, "flight_recorder", False))
         if config.prometheus or config.jsonl or config.chrome_trace \
                 or tracing or flight:
-            # registry-only configurations (all file sinks off — the bench
-            # lanes) must not litter an empty directory
+            # registry-only configurations (all file sinks off) must not
+            # litter an empty directory
             out.mkdir(parents=True, exist_ok=True)
         if config.prometheus:
             self._exporters.append(

@@ -6,8 +6,7 @@ Three sinks for one registry, each serving a different consumer:
     (tmp + rename), so a node-exporter-style textfile collector or a sidecar
     `cat` can scrape mid-write without tearing;
   * `JsonlExporter` — one JSON object per export (step, wall time, full
-    snapshot), append-only; `bin/dstpu_metrics` tails this file and the
-    bench records its latest snapshot into BENCH_*.json;
+    snapshot), append-only; `bin/dstpu_metrics` tails this file;
   * `MonitorBridge` — flattens snapshots into `(tag, value, step)` scalars
     through `monitor.write_events_safe`, so existing TB/WandB/CSV dashboards
     keep working: a histogram fans out to `<name>/p50|p90|p99|mean|count`.

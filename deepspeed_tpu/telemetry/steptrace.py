@@ -25,6 +25,7 @@ whoever asks in the same process (the benchmark's readers do).
 """
 
 import collections
+import itertools
 import time
 
 from deepspeed_tpu.telemetry.spans import Span
@@ -207,6 +208,11 @@ class StepTrace:
         return [r for r in self._steps
                 if (since is None or r.t_end > since)
                 and (until is None or r.t_end <= until)]
+
+    def tail(self, n):
+        """The newest `n` step records (all of them while the ring holds
+        fewer), oldest first."""
+        return list(itertools.islice(reversed(self._steps), n))[::-1]
 
     def requests(self, since=None, until=None, stamp="t_admit"):
         """One record a request, its newest (completed if it has retired),

@@ -1,6 +1,6 @@
 """Shared tiny serving-engine factory for the multi-process fabric.
 
-The kill -9 soak, the fabric bench lane, `bin/dstpu_pool`'s demo config and
+The kill -9 soak, `bin/dstpu_pool`'s demo config and
 the in-thread transport tests all need the SAME engine on both sides of a
 process boundary: parameters are seeded (`seed`), so a replica subprocess
 built from this factory is bit-identical to the parent's oracle engine —

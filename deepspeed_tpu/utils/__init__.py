@@ -1,5 +1,4 @@
 from deepspeed_tpu.utils.logging import logger, log_dist
-from deepspeed_tpu.utils.timer import SynchronizedWallClockTimer, ThroughputTimer
 from deepspeed_tpu.utils.memory import see_memory_usage
 from deepspeed_tpu.utils.tree import (
     tree_size_bytes,

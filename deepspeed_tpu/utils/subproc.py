@@ -1,19 +1,16 @@
 """One subprocess recipe: env knobs in, JSON result out.
 
-Every child-process harness in the repo speaks the same protocol — the
-parent sets environment knobs, the child runs one lane/trial and prints
-its result as a JSON object on the LAST line of stdout (progress chatter
-above it is fine). `bench.py`'s dozen `BENCH_*_CHILD` sub-lanes, the
-weak-scaling arms, and the autotuner's measured-trial runner
-(`autotuning/measure.py`) all route through this module so the recipe —
-env filtering, spawn, last-JSON-line parse, stderr salvage — exists
-exactly once.
+The parent sets environment knobs, the child runs one trial and prints its
+result as a JSON object on the LAST line of stdout (progress chatter above
+it is fine). The autotuner's measured-trial runner (`autotuning/measure.py`)
+is the caller: in a child, a crash, a device OOM or a hang costs one
+record and not the parent. The recipe — env filtering, spawn,
+last-JSON-line parse, stderr salvage — exists here once.
 """
 
 import json
 import os
 import subprocess
-import sys
 from typing import Dict, Optional, Sequence, Tuple
 
 
@@ -57,14 +54,3 @@ def run_json_child(argv: Sequence[str], overrides: Dict[str, str],
     proc = subprocess.run(list(argv), env=child_env(overrides, clear_prefixes),
                           capture_output=True, text=True, timeout=timeout)
     return last_json_line(proc.stdout, key=key), proc
-
-
-def run_self_child(overrides: Dict[str, str], script: Optional[str] = None,
-                   clear_prefixes: Sequence[str] = ("BENCH_",),
-                   key: Optional[str] = None, timeout: Optional[float] = None):
-    """The bench-lane flavor: re-run `script` (default: the calling
-    process's entry script, `sys.argv[0]`) under the filtered env."""
-    target = os.path.abspath(script if script is not None else sys.argv[0])
-    return run_json_child([sys.executable, target], overrides,
-                          clear_prefixes=clear_prefixes, key=key,
-                          timeout=timeout)

@@ -546,7 +546,7 @@ def test_disabled_telemetry_is_total_noop(tmp_path, monkeypatch):
 
 
 def test_registry_only_config_writes_no_dir(tmp_path):
-    # the bench lanes' configuration: enabled, every file sink off — the
+    # enabled with every file sink off: the
     # registry records but no output directory may appear
     out = tmp_path / "tel"
     t = Telemetry(TelemetryConfig(enabled=True, output_path=str(out),
@@ -699,6 +699,109 @@ def test_train_step_telemetry_mfu(tmp_path):
     assert engine._program_flops is not None and engine._program_flops > 0
     rec = load_latest(tmp_path / "train.jsonl")
     assert rec is not None and "train/mfu" in rec["metrics"]
+
+
+def _train_engine(**config):
+    _mk_mesh(data=1)
+    engine, _, _, _ = deepspeed_tpu.initialize(
+        model=make_gpt_model(cfg=TINY, name="tiny"), config={
+            "train_micro_batch_size_per_gpu": 1,
+            "optimizer": {"type": "AdamW", "params": {"lr": 1e-3}},
+            "zero_optimization": {"stage": 0}, **config})
+    toks = np.random.default_rng(0).integers(
+        0, 256, (engine.train_batch_size(), 33)).astype(np.int32)
+    return engine, {"tokens": toks[:, :-1], "labels": toks[:, 1:]}
+
+
+def test_train_rates_and_step_times_read_the_step_ring_only(tmp_path):
+    # a scripted clock on the recorder, 100 ms a reading, while a real
+    # `train_batch` of this model takes about a millisecond: whatever the
+    # gauges and the histogram hold came from the records' stamps, and from
+    # no clock of the engine's own
+    engine, batch = _train_engine(
+        steps_per_print=10**9,
+        telemetry={"enabled": True, "output_path": str(tmp_path),
+                   "prometheus": False, "jsonl": False, "peak_tflops": 1.0})
+    now = [0.0]
+
+    def clock():
+        now[0] += 0.1
+        return now[0]
+
+    engine.steptrace.clock = clock
+    steps = 3
+    for _ in range(steps):
+        float(engine.train_batch(batch))
+    recs = engine.steptrace.records()
+    assert len(recs) == steps
+    span = recs[-1].t_end - recs[0].t_start
+    assert span > steps * 0.5                   # >= 6 readings a step
+    reg = engine.telemetry.registry
+    tokens = batch["tokens"].size
+    assert reg.gauge("train/tokens_per_sec").value == \
+        pytest.approx(steps * tokens / span)
+    assert reg.gauge("train/tflops_per_chip").value == \
+        pytest.approx(engine._program_flops * steps / span / 1e12)
+    assert reg.gauge("train/mfu").value == \
+        pytest.approx(engine._program_flops * steps / span / 1e12)
+    hist = reg.histogram("train/step_time_ms")
+    windows = [(r.t_end - r.t_start) * 1e3 for r in recs]
+    assert hist.count == steps
+    assert hist.sum == pytest.approx(sum(windows))
+    assert (hist.min, hist.max) == pytest.approx((min(windows), max(windows)))
+
+
+def test_wall_clock_breakdown_logs_the_ring_phases_and_no_barrier(monkeypatch):
+    import logging
+
+    import jax
+
+    from deepspeed_tpu.utils.logging import logger as ds_logger
+
+    def no_barrier():
+        raise AssertionError("train_batch fences with effects_barrier, "
+                             "which waits for no ordinary computation")
+
+    monkeypatch.setattr(jax, "effects_barrier", no_barrier)
+    engine, batch = _train_engine(steps_per_print=2, wall_clock_breakdown=True)
+    messages = []
+    handler = logging.Handler()
+    handler.emit = lambda r: messages.append(r.getMessage())
+    ds_logger.addHandler(handler)       # propagate=False: hook it
+    try:
+        for _ in range(3):
+            engine.train_batch(batch)   # the loss is not fetched: no fence
+    finally:
+        ds_logger.removeHandler(handler)
+    lines = [m for m in messages if "samples/s=" in m]
+    assert len(lines) == 1 and "step=2," in lines[0], messages
+    for phase in ("train/place", "train/dispatch", "train/fence",
+                  "train/after_step"):
+        assert f"| {phase}: " in lines[0]
+    rate = float(lines[0].split("samples/s=")[1].split()[0])
+    first, second = engine.steptrace.records()[:2]
+    assert rate == pytest.approx(
+        2 * engine.train_batch_size() / (second.t_end - first.t_start),
+        rel=1e-4)
+
+
+def test_documents_and_package_name_no_retired_benchmark():
+    """`benchmark/run.py` is the one instrument for speed; the lane runner
+    it replaced and its environment knobs are gone, and no sentence of the
+    README, `docs/` or the package may send a reader to them."""
+    import pathlib
+    import re
+
+    root = pathlib.Path(deepspeed_tpu.__file__).parent.parent
+    files = [root / "README.md", *sorted((root / "docs").glob("*.md")),
+             *sorted((root / "deepspeed_tpu").rglob("*.py"))]
+    assert len(files) > 100
+    retired = re.compile(r"(?<![A-Za-z0-9_])bench\.py|BENCH_")
+    found = [f"{f.relative_to(root)}:{n}: {line.strip()}"
+             for f in files
+             for n, line in enumerate(f.read_text().splitlines(), 1)
+             if retired.search(line)]
+    assert not found, "\n".join(found)
 
 
 def test_train_peak_flops_override(tmp_path):
