@@ -121,6 +121,10 @@ class DecodeModelSpec:
     # kv_pool_writer`'s names), filled in by the model as each program is
     # traced. None: the model writes with the XLA scatter throughout.
     kv_pool_writers: Optional[Dict[str, str]] = None
+    # the same phases -> the attention program each was traced with
+    # (`attention_dispatch`'s registry names: "paged_prefill_kernel",
+    # "paged_kernel", "paged_gather", ...). None: the model keeps no record.
+    paged_attn_programs: Optional[Dict[str, str]] = None
     # names of the int32 counters the paged programs return as a THIRD
     # result, `(logits, pool, counts[len(step_counters)])`, summed over the
     # model's layers (the routed experts': `parallel.moe.ROUTED_COUNTERS`).

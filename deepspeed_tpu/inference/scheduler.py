@@ -1500,6 +1500,7 @@ class ServingEngine:
         compiled0 = self._compiled_programs()
         chunks0, tokens0 = self.prefill_chunks, self.tokens_generated
         walk = (0, 0)       # the decode kernel's (live blocks, grid steps)
+        reach = [0, 0]      # the prefill kernel's (live, table) blocks
 
         with self._phase("serving/admit"):
             admitted, blocked_on = self._admit(finished)
@@ -1511,7 +1512,9 @@ class ServingEngine:
             if budget <= 0:
                 break
             while slot.state == _PREFILL and budget > 0:
-                self._prefill_chunk(slot, params, finished)
+                live, table = self._prefill_chunk(slot, params, finished)
+                reach[0] += live
+                reach[1] += table
                 budget -= 1
 
         # decode: ONE fixed-shape call for every slot; non-decoding slots
@@ -1570,6 +1573,8 @@ class ServingEngine:
             self._step_counts[:] = 0
         st.end_step(counters=counters,
                     decode_live_blocks=walk[0], decode_grid_steps=walk[1],
+                    prefill_live_blocks=reach[0],
+                    prefill_table_blocks=reach[1],
                     admitted=admitted,
                     prefill_chunks=self.prefill_chunks - chunks0,
                     decoding=len(dec),
@@ -1583,7 +1588,9 @@ class ServingEngine:
     def _prefill_chunk(self, slot, params, finished):
         """One prefill chunk of `slot`: input build, dispatch, the cache
         registrations it completes and, after the final chunk, the
-        first-token read-back."""
+        first-token read-back. Returns what the prefill kernel's walk
+        attends, a layer: (logical blocks under the chunk's frontier, blocks
+        in its table) — (0, 0) where the program built is not that kernel."""
         st = self.steptrace
         ctx = slot.trace                      # _emit may retire the slot
         with self._phase("serving/prefill_chunk") as ph:
@@ -1598,6 +1605,15 @@ class ServingEngine:
                 params, chunk, np.asarray([start], np.int32),
                 np.asarray([last], np.int32), self.pool,
                 self.tables[slot.idx][None], self._next_rng())
+            # counted here, while the device runs
+            reach = (0, 0)
+            if self.attention_programs().get("prefill_step") \
+                    == "paged_prefill_kernel":
+                from deepspeed_tpu.ops.pallas.prefill_attention import \
+                    paged_prefill_live_blocks
+                table = self.tables.shape[1]
+                reach = (paged_prefill_live_blocks(
+                    start, self.chunk, self.block_size, table), table)
             if self.drafter is not None:
                 # a stateful drafter (the draft model) shadows the chunk
                 # into its own pool through the same table — the draft
@@ -1638,6 +1654,7 @@ class ServingEngine:
             self.tracer.record(ctx, "prefill_chunk", ph.t0, ph.t1 - ph.t0,
                                tid=self.trace_tid,
                                attrs={"start": start, "chunk": self.chunk})
+        return reach
 
     def _decode_window(self, dec, params, tok, pos, tables, finished):
         """The decode call for every slot in `dec`, its read-back, and the
@@ -1783,7 +1800,18 @@ class ServingEngine:
         to set). A program appears once it has been traced. Empty for a
         model that keeps no record: the streamed layers and the per-layer
         (`moe_freq` >= 2) MoE stack have the scatter form only."""
-        traced = getattr(self.engine.model_spec, "kv_pool_writers", None) or {}
+        return self._by_step_program("kv_pool_writers")
+
+    def attention_programs(self) -> Dict[str, str]:
+        """Step program -> the attention program its layers were traced with
+        (`ops/attention_dispatch.py`'s registry names: the prefill step's is
+        `paged_prefill_kernel` where the chunk walks the blocks under its
+        frontier, `paged_gather` where it attends its whole table). As
+        `kv_pool_writers`: nothing to set, a program appears once traced."""
+        return self._by_step_program("paged_attn_programs")
+
+    def _by_step_program(self, record) -> Dict[str, str]:
+        traced = getattr(self.engine.model_spec, record, None) or {}
         return {program: traced[phase] for program, phase in (
             ("decode_step", "paged_decode"), ("prefill_step", "prefill_chunk"),
             ("verify_step", "verify")) if phase in traced}
@@ -1802,7 +1830,8 @@ class ServingEngine:
                "reclaimable_blocks": self.allocator.num_reclaimable,
                "available_blocks": self.allocator.available,
                "compiles": self.compile_stats(),
-               "kv_pool_writer": self.kv_pool_writers()}
+               "kv_pool_writer": self.kv_pool_writers(),
+               "attention_program": self.attention_programs()}
         if self.step_counter_names:
             # the model's own counters (routed experts: calls, assignments,
             # active experts, the largest expert's load), summed over layers

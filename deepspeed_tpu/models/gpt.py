@@ -888,7 +888,7 @@ def _decode_qkv(x, p, positions, cfg: GPTConfig):
 
 
 def _decode_attn_site(cfg: GPTConfig, phase, C, M, kv_dtype="bfloat16",
-                      block_size=0):
+                      block_size=0, pool_in_place=False):
     """Dispatch key for the decode/paged call sites. The engage rule itself
     (`attn_dispatch.decode_kernel_wanted`) has ONE definition shared by the
     contiguous path (M = allocated cache length) and the paged path
@@ -897,6 +897,7 @@ def _decode_attn_site(cfg: GPTConfig, phase, C, M, kv_dtype="bfloat16",
         phase=phase, q_len=C, kv_len=M, causal=True,
         has_bias=cfg.use_alibi, has_window=bool(cfg.sliding_window),
         scale_attn=cfg.scale_attn, kv_dtype=kv_dtype, block_size=block_size,
+        pool_in_place=pool_in_place,
         mesh_axes=attn_dispatch.active_mesh_axes(),
         force_flash=cfg.use_flash_attention)
 
@@ -1069,10 +1070,14 @@ def make_gpt_decode_model(cfg: GPTConfig = None, name="gpt2-125m", params=None, 
     # which writer each paged program was traced with, by dispatch phase
     # (`ServingEngine.stats()["kv_pool_writer"]` reads it)
     pool_writers = {}
+    # ... and which attention program (`attention_dispatch`'s registry name;
+    # `stats()["attention_program"]`)
+    attn_programs = {}
 
     def _scan_paged(params, x, pool, block_tables, positions, phase=None):
         return scan_paged(cfg, params["blocks"], x, pool, block_tables,
-                          positions, phase=phase, pool_writers=pool_writers)
+                          positions, phase=phase, pool_writers=pool_writers,
+                          attn_programs=attn_programs)
 
     def prefill_paged_fn(params, tokens, start_pos, last_idx, pool,
                          block_tables):
@@ -1117,6 +1122,7 @@ def make_gpt_decode_model(cfg: GPTConfig = None, name="gpt2-125m", params=None, 
                            verify_paged_fn=verify_paged_fn,
                            init_paged_pool=init_paged_pool,
                            kv_pool_writers=pool_writers,
+                           paged_attn_programs=attn_programs,
                            cache_fingerprint=gpt_cache_identity(cfg, name))
 
 
@@ -1160,7 +1166,8 @@ def init_paged_kv_pool(cfg: GPTConfig, num_blocks, block_size,
 
 
 def scan_paged(cfg: GPTConfig, blocks, x, pool, block_tables, positions,
-               phase=None, pool_writers=None, block_fn=None, aux=None):
+               phase=None, pool_writers=None, block_fn=None, aux=None,
+               attn_programs=None):
     """The layer loop of every paged program: x through the stacked `blocks`
     against the paged pool. Returns (x, pool), or (x, pool, aux) when `aux`
     is given.
@@ -1169,11 +1176,13 @@ def scan_paged(cfg: GPTConfig, blocks, x, pool, block_tables, positions,
     k_scale/v_scale), so the quantized and fp layouts share one scan body —
     a layer's pool arrives as a dict. `phase` labels the dispatch site
     ("verify" for the spec-decode chunk; None = derive decode/prefill from
-    the chunk width); `pool_writers[phase]` records the writer chosen.
+    the chunk width); `pool_writers[phase]` records the writer chosen and
+    `attn_programs[phase]` the attention program the layers select.
 
     `block_fn` (default `_block_paged`) is one layer: `(x, p, pool_l,
     positions, block_tables, cfg, local_flag=, phase=, block_base=,
-    decode_work=) -> (x, pool_l)`, with `layer=` the traced layer index
+    decode_work=, attn_programs=) -> (x, pool_l)`, with `layer=` the traced
+    layer index
     (what a layer needs beside its slice `p` it addresses in a whole stack
     it closes over, as the pool is) and `decode_work=` the decode kernels'
     work list of this token (None in a chunk). With `aux` (an initial
@@ -1211,7 +1220,7 @@ def scan_paged(cfg: GPTConfig, blocks, x, pool, block_tables, positions,
         x, pool_l, *counts = block_fn(
             x, p, pool_l, positions, block_tables, cfg, local_flag=flag,
             phase=phase, block_base=block_base, layer=layer_id,
-            decode_work=decode_work)
+            decode_work=decode_work, attn_programs=attn_programs)
         return x, pool_l, (acc + counts[0] if counted else acc)
 
     def result(x, pool, acc):
@@ -1279,7 +1288,7 @@ def _paged_attend(q, k_ctx, v_ctx, q_pos, cfg: GPTConfig, local_flag=None):
 
 def _paged_attn_half(x, p, pool_l, positions, block_tables,
                      cfg: GPTConfig, local_flag=None, phase=None,
-                     block_base=None, decode_work=None):
+                     block_base=None, decode_work=None, attn_programs=None):
     """Attention half-block against one layer's paged pool.
 
     x: [B, C, D]; pool_l: one layer's pool slice — ``k``/``v``
@@ -1293,15 +1302,17 @@ def _paged_attn_half(x, p, pool_l, positions, block_tables,
     the WHOLE stack flattened to [L*N, Hkv, block, hd], this layer's blocks
     start at `block_base` (= layer * N, traced), the rows are written by the
     aliased `dstpu_kv_pool_write` call and read through
-    `block_tables + block_base` by `dstpu_paged_decode` or
-    `dstpu_kv_pool_gather` — Mosaic calls only, the invariant of
+    `block_tables + block_base` by `dstpu_paged_decode`,
+    `dstpu_paged_prefill` or `dstpu_kv_pool_gather` — Mosaic calls only,
+    the invariant of
     `attn_dispatch.kv_pool_writer`. It takes each row's positions to be
     consecutive (`positions[b, c] == positions[b, 0] + c`), as every paged
     program builds them.
 
     `decode_work`: the decode kernels' work list of these tables
     (`paged_decode_work`), where the caller built it outside its layer loop;
-    None leaves it to the kernel's wrapper.
+    None leaves it to the kernel's wrapper. `attn_programs`: a dict that
+    takes the name of the attention program selected, by dispatch phase.
 
     Quantized pool: K/V are quantized AT CACHE-WRITE TIME (symmetric
     per-group int8 + f32 scales, `quantization.quantize_kv` — the same
@@ -1358,16 +1369,26 @@ def _paged_attn_half(x, p, pool_l, positions, block_tables,
     # serving-scale effective context nb*bs), PLUS the paged-only
     # constraints: the kernel's no-bias/no-window contract, a lane-aligned
     # pool block (it cannot pad physical blocks the way the contiguous
-    # kernel pads a whole cache), and C == 1 — chunked prefill and the
-    # spec-decode verify chunk always take the gather path (matmul-bound,
-    # not gather-bound). The int8-pool kernel is an ordinary REGISTERED
-    # program keyed on kv_dtype, not a special case here.
-    program = attn_dispatch.select(_decode_attn_site(
+    # kernel pads a whole cache), and C == 1. The int8-pool kernel is an
+    # ordinary REGISTERED program keyed on kv_dtype, not a special case
+    # here. A prefill chunk on the in-place pool walks the blocks under its
+    # frontier (`paged_prefill_kernel`, a program with a runner: no branch
+    # of its own below); the spec-decode verify chunk, and a prefill chunk
+    # anywhere else, gather the row's whole table and attend it densely.
+    site = _decode_attn_site(
         cfg,
         phase or ("paged_decode" if C == 1 else "prefill_chunk"), C, nb * bs,
         kv_dtype="int8" if quantized else str(jnp.dtype(pool_l["k"].dtype)),
-        block_size=bs))
-    if program == "paged_kernel_quant":
+        block_size=bs, pool_in_place=block_base is not None)
+    program = attn_dispatch.select(site)
+    if attn_programs is not None:
+        attn_programs[site.phase] = program
+    runner = attn_dispatch.get_program(program).runner
+    if runner is not None:
+        with jax.named_scope("attn"):
+            attn = runner(q, pool_l, block_tables, positions[:, 0],
+                          sm_scale=None if cfg.scale_attn else 1.0)
+    elif program == "paged_kernel_quant":
         from deepspeed_tpu.ops.pallas.decode_attention import \
             paged_decode_attention_quant
         with jax.named_scope("attn"):
@@ -1415,17 +1436,20 @@ def _paged_attn_half(x, p, pool_l, positions, block_tables,
 
 def _block_paged(x, p, pool_l, positions, block_tables,
                  cfg: GPTConfig, local_flag=None, phase=None,
-                 block_base=None, layer=None, mlp_fn=None, decode_work=None):
+                 block_base=None, layer=None, mlp_fn=None, decode_work=None,
+                 attn_programs=None):
     """One transformer block against the paged pool (decode, prefill
     chunk, or the spec-decode verify chunk — `phase` labels the dispatch
     site; `block_base` selects `_paged_attn_half`'s in-place form and
-    `decode_work` is its decode kernels' work list; `mlp_fn` swaps the dense
-    MLP, as in `_residual_mlp`; `layer`, `scan_paged`'s layer index, is for
-    blocks that need it)."""
+    `decode_work` is its decode kernels' work list and `attn_programs` its
+    record of the program selected; `mlp_fn` swaps the dense MLP, as in
+    `_residual_mlp`; `layer`, `scan_paged`'s layer index, is for blocks that
+    need it)."""
     del layer
     attn_out, pool_l = _paged_attn_half(
         x, p, pool_l, positions, block_tables, cfg, local_flag=local_flag,
-        phase=phase, block_base=block_base, decode_work=decode_work)
+        phase=phase, block_base=block_base, decode_work=decode_work,
+        attn_programs=attn_programs)
     with jax.named_scope("mlp"):
         x = _residual_mlp(x, attn_out, p, cfg, constrain=False, mlp_fn=mlp_fn)
     return x, pool_l

@@ -507,6 +507,7 @@ def make_moe_gpt_decode_model(cfg: MoEGPTConfig, params=None, name="moe-gpt", se
     # before; no benchmark cell runs it).
 
     pool_writers = {}
+    attn_programs = {}
     no_counts = jnp.zeros((len(ROUTED_COUNTERS),), jnp.int32)
 
 
@@ -518,7 +519,8 @@ def make_moe_gpt_decode_model(cfg: MoEGPTConfig, params=None, name="moe-gpt", se
             mp = _layer_experts(params, p, lid)
             x, pool_l = _block_paged(
                 x, p, pool_l, positions, block_tables, cfg, phase=phase,
-                mlp_fn=_routed_mlp_fn(mp, cfg, counts))
+                mlp_fn=_routed_mlp_fn(mp, cfg, counts),
+                attn_programs=attn_programs)
             slices.append(pool_l)
         pool = {k: jnp.stack([s[k] for s in slices], 0) for k in pool}
         return x, pool, sum(counts)
@@ -545,7 +547,8 @@ def make_moe_gpt_decode_model(cfg: MoEGPTConfig, params=None, name="moe-gpt", se
 
         return scan_paged(cfg, scanned, x, pool, block_tables, positions,
                           phase=phase, pool_writers=pool_writers,
-                          block_fn=routed_block, aux=no_counts)
+                          block_fn=routed_block, aux=no_counts,
+                          attn_programs=attn_programs)
 
     def prefill_paged_fn(params, tokens, start_pos, last_idx, pool,
                          block_tables):
@@ -587,6 +590,7 @@ def make_moe_gpt_decode_model(cfg: MoEGPTConfig, params=None, name="moe-gpt", se
                            verify_paged_fn=verify_paged_fn,
                            init_paged_pool=init_paged_pool,
                            kv_pool_writers=pool_writers,
+                           paged_attn_programs=attn_programs,
                            step_counters=ROUTED_COUNTERS,
                            cache_fingerprint=moe_cache_identity(cfg, name))
 

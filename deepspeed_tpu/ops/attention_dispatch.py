@@ -18,7 +18,10 @@ Variants register once here instead of branching at five call sites:
     register with a `runner` — the training forward invokes them through
     the registry without knowing their internals;
   * the PR 12 quantized paged kernels register as ordinary programs keyed
-    on `kv_dtype`, not as an if/else inside the paged attention half.
+    on `kv_dtype`, not as an if/else inside the paged attention half;
+  * the chunked-prefill kernel (`paged_prefill_kernel`) registers with a
+    `runner` too, keyed on the pool's form and the chunk's shape: the paged
+    attention half invokes it without a branch of its own.
 
 Every predicate reads only TRACE-TIME-STATIC inputs (shapes, config
 fields, the installed mesh spec), so dispatch can never cause a recompile:
@@ -97,6 +100,9 @@ class AttnSite:
     scale_attn: bool = True       # False = unscaled scores (GPT-Neo)
     kv_dtype: str = "bfloat16"    # KV storage dtype ("int8" = quantized pool)
     block_size: int = 0           # paged pool physical block (paged phases)
+    pool_in_place: bool = False   # paged phases: the pool is the carried,
+                                  # flat, Mosaic-only form (`kv_pool_writer`
+                                  # named KV_POOL_WRITE_KERNEL for it)
     mesh_axes: Tuple[str, ...] = ()  # active (size>1) mesh axes
     force_flash: Optional[bool] = None  # GPTConfig.use_flash_attention
     chunk_min: Optional[int] = None     # GPTConfig.chunked_attn_min_seq
@@ -119,9 +125,13 @@ class AttentionProgram:
     """One registered attention implementation.
 
     `matches` decides eligibility from the AttnSite alone; `runner`, when
-    set, is the zoo-layout callable ([B, T, H, hd] q/k/v, matched heads)
-    the training forward invokes — phases whose call signatures carry pool
-    state (decode/paged) dispatch by NAME and invoke at the call site.
+    set, is the callable the site invokes without knowing the program: for
+    the train phase the zoo-layout `(q, k, v, *, causal, sm_scale)` with
+    [B, T, H, hd] operands and matched heads; for a paged phase
+    `(q, pool_l, block_tables, start, *, sm_scale)` with q [B, C, H, hd],
+    the float pool's leaves WHOLE, the tables in the pool's numbering and
+    each row's first position, returning [B, C, H*hd]. The other paged and
+    decode programs still dispatch by NAME and are invoked at the call site.
     `when` is the human-readable engage condition for `dispatch_table()`
     and docs/kernels.md."""
     name: str
@@ -385,7 +395,8 @@ def _paged_kernel_ok(site):
 # a gather of a few blocks is rewritten into slices of the whole pool
 # (measured on the chip, PR 25: slower than the form it replaced). So the
 # two forms are: pool carried through the scan + every write and read a
-# Mosaic call (`ops/pallas/kv_pool.py`, `dstpu_paged_decode`), or pool
+# Mosaic call (`ops/pallas/kv_pool.py`, `dstpu_paged_decode`,
+# `dstpu_paged_prefill`), or pool
 # sliced by the scan as xs/ys + XLA scatter and gather on the slice (a copy
 # of the pool per program call, but each layer's slice is re-laid-out
 # alone). Nothing in between.
@@ -423,6 +434,34 @@ register_program(AttentionProgram(
     when="C == 1, block % 128 == 0, effective context nb*block past the "
          "decode crossover; no alibi/window"))
 
+
+
+def _paged_prefill_ok(site):
+    # every disqualifier is a shape, a dtype or a mesh, all in the key: the
+    # in-place pool form (a float pool of whole tiles, on a TPU, in a
+    # single-device program: head widths of whole lane tiles with it) and
+    # lane-tile multiples for the chunk and the block. No crossover: the
+    # walk costs what the blocks under the frontier cost.
+    return (site.pool_in_place and not site.has_bias and not site.has_window
+            and site.block_size % 128 == 0 and site.q_len % 128 == 0)
+
+
+def _run_paged_prefill(q, pool_l, block_tables, start, *, sm_scale=None):
+    from deepspeed_tpu.ops.pallas.prefill_attention import \
+        paged_prefill_attention
+    return paged_prefill_attention(q, pool_l["k"], pool_l["v"], block_tables,
+                                   start, sm_scale=sm_scale)
+
+
+register_program(AttentionProgram(
+    name="paged_prefill_kernel", phases=("prefill_chunk",), priority=50,
+    matches=_paged_prefill_ok,
+    when="in-place pool form (float pool of whole tiles, TPU, single "
+         "device), C % 128 == 0, block % 128 == 0, no alibi/window: flash "
+         "walk over the blocks under the chunk's frontier "
+         "(dstpu_paged_prefill)",
+    runner=_run_paged_prefill))
+
 register_program(AttentionProgram(
     name="paged_gather_quant",
     phases=("paged_decode", "prefill_chunk", "verify"), priority=10,
@@ -434,5 +473,7 @@ register_program(AttentionProgram(
     name="paged_gather",
     phases=("paged_decode", "prefill_chunk", "verify"), priority=0,
     matches=lambda s: True,
-    when="fallback: table gather + dense attend (matmul-bound chunked "
-         "prefill and spec-decode verify always take this)"))
+    when="fallback: table gather + dense attend over the whole table "
+         "(the oracle; spec-decode verify, and chunked prefill wherever "
+         "paged_prefill_kernel does not apply: the CPU, a mesh, hd 64, "
+         "alibi/window, a chunk or block off the lane tile)"))

@@ -46,27 +46,17 @@ NEG_INF = -1e30
 _LANES = 128
 
 
-def _online_softmax_tile(q, k, v, pos, j, acc_ref, m_ref, l_ref, *,
-                         sm_scale, block_m):
-    """One streamed KV tile's online-softmax update — the SINGLE definition
-    of the decode-attention math, shared by the contiguous, paged, and
-    quantized-paged kernels (the dequantizing kernel hands in already-
-    dequantized tiles; everything after the load is identical, so the
-    variants cannot drift numerically).
+def _online_softmax_update(s, v, in_dtype, acc_ref, m_ref, l_ref):
+    """Fold one tile of MASKED float32 scores into the online softmax — the
+    single definition of that arithmetic for every streaming attention
+    kernel over a KV cache (the decode kernels below through
+    `_online_softmax_tile`, `prefill_attention.py`'s chunk kernel directly).
 
-    q: [G, hd]; k/v: [block_m, hd] in the compute dtype; scratch acc
-    [G, hd] fp32, m/l [G, _LANES] fp32 carried across the (sequential,
-    innermost) block axis.
-
-    native-dtype dots (fp32 accumulate via preferred_element_type):
-    pre-casting K/V blocks to fp32 doubles the VMEM working set and VPU
-    traffic (same fix as flash_attention.py)."""
-    G = q.shape[0]
-    in_dtype = q.dtype
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * sm_scale
-    k_pos = j * block_m + jax.lax.broadcasted_iota(jnp.int32, (G, block_m), 1)
-    s = jnp.where(k_pos <= pos, s, NEG_INF)
+    s: [R, n] float32, masked entries at NEG_INF; v: [n, hd] in the compute
+    dtype; scratch acc [R, hd] fp32, m/l [R, _LANES] fp32 (row statistics
+    replicated across one lane tile) carried across the tiles of a row's
+    walk. The probabilities narrow to `in_dtype` (the queries') for the p @ v
+    dot, which accumulates in float32."""
     m_prev = m_ref[:, 0:1]
     l_prev = l_ref[:, 0:1]
     m_cur = jnp.max(s, axis=-1, keepdims=True)
@@ -79,6 +69,29 @@ def _online_softmax_tile(q, k, v, pos, j, acc_ref, m_ref, l_ref, *,
         preferred_element_type=jnp.float32)
     m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
     l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
+
+
+def _online_softmax_tile(q, k, v, pos, j, acc_ref, m_ref, l_ref, *,
+                         sm_scale, block_m):
+    """One streamed KV tile's online-softmax update — the SINGLE definition
+    of the decode-attention math, shared by the contiguous, paged, and
+    quantized-paged kernels (the dequantizing kernel hands in already-
+    dequantized tiles; everything after the load is identical, so the
+    variants cannot drift numerically).
+
+    q: [G, hd]; k/v: [block_m, hd] in the compute dtype; scratch as in
+    `_online_softmax_update`, carried across the (sequential, innermost)
+    block axis.
+
+    native-dtype dots (fp32 accumulate via preferred_element_type):
+    pre-casting K/V blocks to fp32 doubles the VMEM working set and VPU
+    traffic (same fix as flash_attention.py)."""
+    G = q.shape[0]
+    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32) * sm_scale
+    k_pos = j * block_m + jax.lax.broadcasted_iota(jnp.int32, (G, block_m), 1)
+    s = jnp.where(k_pos <= pos, s, NEG_INF)
+    _online_softmax_update(s, v, q.dtype, acc_ref, m_ref, l_ref)
 
 
 def _decode_kernel(pos_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref,

@@ -2,7 +2,8 @@
 `decode_attention.py`: that kernel reads the pool through the block table
 for one token; `kv_pool_write` WRITES a step's new K/V rows into the pool
 where it lies, and `kv_pool_gather` copies a row's blocks out for the
-programs that attend a whole chunk (chunked prefill, spec-decode verify).
+programs that attend a whole table densely (spec-decode verify, and a prefill
+chunk where `prefill_attention.py`'s walk does not apply).
 
 Why kernels for a 32-row write and a block copy: what XLA makes of the same
 operations on a pool that is carried through the layer scan (PERF.md §6,

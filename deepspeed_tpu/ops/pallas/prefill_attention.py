@@ -1,0 +1,235 @@
+"""Chunked-prefill attention over the paged KV pool (Pallas) — the chunk's
+twin of `decode_attention.py`'s paged walk.
+
+A prefill chunk of C rows starting at position `start` can see positions
+0 .. start + C - 1 and nothing else. The gather path copies the row's WHOLE
+table out of the pool (`dstpu_kv_pool_gather`) and builds float32 scores over
+all `nb * block` table positions before the causal mask throws most of them
+away; at the served shapes (PERF.md §6, PR 30: a 512-row chunk, a table of 32
+blocks of 512) that was half of the busy time of the long-prompt cell for
+prompts that reach a quarter of the table.
+
+This kernel is a forward-only flash attention whose KV axis is a walk over
+the row's LIVE logical blocks, read where they lie:
+
+- the pool leaves come in WHOLE (`[M, Hkv, block, hd]`, one layer's or the
+  flat `[L*N, ...]` stack with the table already offset): a slice of the
+  pool in front of a Mosaic call is a copy;
+- the table and `start` are scalar-prefetched and the index map resolves
+  logical -> physical, so the blocks under the frontier are the only part of
+  the pool that is touched;
+- the KV grid axis is bounded DYNAMICALLY by the furthest frontier of the
+  call, `(max(start) + C - 1) // block + 1`, as the decode walk's is; a row
+  (or a query tile) whose own frontier is nearer re-serves its frontier
+  block, which fetches nothing, and computes nothing there;
+- the G query heads of a KV head share its K/V tile, the softmax statistics
+  and the accumulator are float32 scratch (`_online_softmax_update`, the
+  decode kernels' arithmetic), and the absolute-position causal mask is
+  applied only in the tiles that overlap the chunk's own positions: a tile
+  wholly below `start` needs none;
+- q and the result keep the model's `[B, C, H*hd]` layout (a step's q tile
+  is the lane-aligned `[tq, heads*G*hd]` slab of its KV heads), so nothing
+  is transposed on the way in or out.
+
+Rows of a final chunk past the prompt's end attend whatever their positions
+hold, as on the gather path; nobody reads them.
+"""
+
+import functools
+import math
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from deepspeed_tpu.ops.pallas.decode_attention import (NEG_INF, _LANES,
+                                                       _online_softmax_update)
+from deepspeed_tpu.platform.device import pallas_interpret
+
+# What a grid step may hold in VMEM (tiles double-buffered by the pipeline,
+# the float32 scratch, the score tiles' temporaries), and what the call asks
+# of the compiler for it (a v5e core has 128 MiB; the default scope is 16).
+_STEP_VMEM_BYTES = 24 * 2**20
+_VMEM_LIMIT_BYTES = 48 * 2**20
+# Query rows a step carries for each of its heads and keys a softmax update
+# takes (the largest of each that divides the chunk and the block), and the
+# query heads a step unrolls. Measured alone on a v5e at Mistral's chunk
+# (PERF.md §6, PR 30; ms a call at 8 live blocks): 512 rows x 256 keys x 8
+# heads 0.56; 256 rows 0.65, 128 keys 1.16, 512 keys 0.56, 4 heads 0.73, 16
+# heads 0.81.
+_Q_TILES = (512, 256, 128)
+_KV_TILES = (256, 128)
+_MAX_Q_HEADS = 8
+
+
+def _step_vmem_bytes(tq, tk, heads, G, block, hd, itemsize):
+    rows = heads * G * tq
+    tiles = 2 * (2 * rows * hd + 2 * heads * block * hd) * itemsize
+    scratch = rows * (hd + 2 * _LANES) * 4
+    scores = 4 * tq * tk * 4
+    return tiles + scratch + scores
+
+
+def _tiles(C, block, Hkv, G, hd, itemsize):
+    """(query rows, keys, KV heads) a grid step carries, from the shapes: the
+    largest 128-multiple tiles that divide the chunk and the block, then as
+    many KV heads (a divisor of Hkv) as keep a step's query heads under
+    `_MAX_Q_HEADS` and its VMEM under `_STEP_VMEM_BYTES`, halving the query
+    tile when one head alone is over (G of 16 and more)."""
+    tq = next((t for t in _Q_TILES if C % t == 0), C)
+    tk = next((t for t in _KV_TILES if block % t == 0), block)
+
+    def fits(tq, heads):
+        return _step_vmem_bytes(tq, tk, heads, G, block, hd,
+                                itemsize) <= _STEP_VMEM_BYTES
+
+    while tq % 256 == 0 and not fits(tq, 1):
+        tq //= 2
+    heads = max([h for h in range(1, Hkv + 1)
+                 if Hkv % h == 0 and h * G <= _MAX_Q_HEADS and fits(tq, h)],
+                default=1)
+    return tq, tk, heads
+
+
+def _prefill_kernel(start_ref, bt_ref, q_ref, k_ref, v_ref, o_ref, acc_ref,
+                    m_ref, l_ref, *, sm_scale, G, block, tk, last_block):
+    # grid (B, Hkv // heads, C // tq, live blocks); q_ref / o_ref:
+    # [1, tq, heads*G*hd], the step's query heads side by side in the lanes;
+    # k_ref / v_ref: [1, heads, block, hd], ONE logical block of the row,
+    # resolved to its physical block by the index map (so bt_ref is unused
+    # here); scratch acc [heads*G, tq, hd] fp32, m/l [heads*G, tq, _LANES]
+    # fp32 carry the online softmax over the row's blocks, innermost and
+    # ascending.
+    del bt_ref
+    b = pl.program_id(0)
+    qi = pl.program_id(2)
+    j = pl.program_id(3)
+    tq = q_ref.shape[1]
+    heads, hd = k_ref.shape[1], k_ref.shape[3]
+    q_lo = start_ref[b] + qi * tq           # this tile's first position
+    q_hi = q_lo + tq - 1
+    frontier = jnp.minimum(q_hi // block, last_block)
+
+    @pl.when(j == 0)
+    def _init():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+
+    def update(t, masked):
+        keys = slice(t * tk, (t + 1) * tk)
+        if masked:
+            # key position - query position, the same for every head
+            ahead = (j * block + t * tk - q_lo) \
+                + jax.lax.broadcasted_iota(jnp.int32, (tq, tk), 1) \
+                - jax.lax.broadcasted_iota(jnp.int32, (tq, tk), 0)
+
+        # unrolled: the heads' updates are independent, and the compiler
+        # overlaps one's matmuls with another's softmax (a `fori_loop` over
+        # them halves the kernel's speed; measured, PERF.md §6, PR 30)
+        for i in range(heads * G):
+            q = q_ref[0, :, i * hd:(i + 1) * hd]
+            k = k_ref[0, i // G, keys, :]
+            v = v_ref[0, i // G, keys, :]
+            s = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * sm_scale
+            if masked:
+                s = jnp.where(ahead <= 0, s, NEG_INF)
+            _online_softmax_update(s, v, q.dtype, acc_ref.at[i],
+                                   m_ref.at[i], l_ref.at[i])
+
+    # a tile of keys is live while any of this query tile's rows can see it
+    # (past the frontier the index map re-serves the frontier block), and
+    # needs the mask only where it reaches past the tile's FIRST row
+    for t in range(block // tk):
+        k_lo = j * block + t * tk
+        live = jnp.logical_and(j <= frontier, k_lo <= q_hi)
+        diagonal = k_lo + tk - 1 > q_lo
+        pl.when(jnp.logical_and(live, diagonal))(
+            functools.partial(update, t, True))
+        pl.when(jnp.logical_and(live, jnp.logical_not(diagonal)))(
+            functools.partial(update, t, False))
+
+    @pl.when(j == frontier)
+    def _finish():
+        for i in range(heads * G):
+            l_safe = jnp.maximum(l_ref[i][:, 0:1], 1e-30)
+            o_ref[0, :, i * hd:(i + 1) * hd] = \
+                (acc_ref[i] / l_safe).astype(o_ref.dtype)
+
+
+def paged_prefill_live_blocks(start, chunk, block, table_blocks):
+    """Host twin of the walk's grid bound for ONE row: the logical blocks a
+    chunk of `chunk` rows starting at `start` attends (the scheduler's
+    `StepRecord.prefill_live_blocks`), of `table_blocks` in its table."""
+    return min((int(start) + int(chunk) - 1) // int(block) + 1,
+               int(table_blocks))
+
+
+def paged_prefill_attention(q, k_pool, v_pool, block_tables, start,
+                            sm_scale=None, interpret=None):
+    """Causal attention of a prefill chunk over a PAGED KV pool, the live
+    blocks only.
+
+    q: [B, C, H, hd], row (b, c) being position `start[b] + c`, whose K/V
+    (and every earlier position's) must already be in the pool; k_pool /
+    v_pool: [M, Hkv, block, hd] physical blocks, WHOLE (one layer's, or the
+    flat `[L*N, ...]` stack); block_tables: [B, nb] int32 physical ids in
+    the pool's numbering (already offset to the layer's blocks of a flat
+    stack); start: [B] int32. Returns [B, C, H*hd] in q's dtype — what
+    `models/gpt.py::_paged_attend` gives over the gathered table with
+    neither alibi nor a window.
+
+    Row b reads logical blocks 0 .. (start[b] + C - 1) // block of its table
+    and no other: entries past them may hold anything (the trash block, a
+    stale id)."""
+    if interpret is None:
+        interpret = pallas_interpret()
+    B, C, H, hd = q.shape
+    _, Hkv, block, _ = k_pool.shape
+    nb = block_tables.shape[1]
+    assert H % Hkv == 0
+    G = H // Hkv
+    if sm_scale is None:
+        sm_scale = 1.0 / math.sqrt(hd)
+    tq, tk, heads = _tiles(C, block, Hkv, G, hd, q.dtype.itemsize)
+    width = heads * G * hd
+
+    start = start.astype(jnp.int32)
+    # the furthest frontier of the call: the KV axis ends there
+    live_blocks = jnp.minimum((jnp.max(start) + C - 1) // block + 1, nb)
+
+    def q_index(b, g, qi, j, start_ref, bt_ref):
+        return (b, qi, g)
+
+    def kv_index(b, g, qi, j, start_ref, bt_ref):
+        # the table is read in SMEM, where nothing checks the index
+        frontier = jnp.minimum((start_ref[b] + (qi + 1) * tq - 1) // block,
+                               nb - 1)
+        return (bt_ref[b, jnp.minimum(j, frontier)], g, 0, 0)
+
+    return pl.pallas_call(
+        functools.partial(_prefill_kernel, sm_scale=sm_scale, G=G,
+                          block=block, tk=tk, last_block=nb - 1),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(B, Hkv // heads, C // tq, live_blocks),
+            in_specs=[pl.BlockSpec((1, tq, width), q_index),
+                      pl.BlockSpec((1, heads, block, hd), kv_index),
+                      pl.BlockSpec((1, heads, block, hd), kv_index)],
+            out_specs=pl.BlockSpec((1, tq, width), q_index),
+            scratch_shapes=[
+                pltpu.VMEM((heads * G, tq, hd), jnp.float32),
+                pltpu.VMEM((heads * G, tq, _LANES), jnp.float32),
+                pltpu.VMEM((heads * G, tq, _LANES), jnp.float32),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((B, C, H * hd), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_VMEM_LIMIT_BYTES),
+        interpret=interpret,
+        name="dstpu_paged_prefill",
+    )(start, block_tables.astype(jnp.int32), q.reshape(B, C, H * hd),
+      k_pool, v_pool)

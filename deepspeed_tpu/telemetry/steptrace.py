@@ -60,7 +60,13 @@ StepRecord = collections.namedtuple("StepRecord", [
     "decode_grid_steps",    # block-axis steps `dstpu_paged_decode`'s walk
                         # takes for those positions, a layer, summed over
                         # the call's tokens (KV heads folded out)
-], defaults=(0, 0, 0, 0, 0, 0, "", 0, (), 0, 0))
+    "prefill_live_blocks",  # logical blocks `dstpu_paged_prefill` walks for
+                        # this step's chunks, a layer:
+                        # (start + chunk - 1) // block + 1 a chunk ...
+    "prefill_table_blocks",  # ... of the blocks in those chunks' tables
+                        # (what the gather path attends); both 0 where the
+                        # prefill program built is not that kernel
+], defaults=(0, 0, 0, 0, 0, 0, "", 0, (), 0, 0, 0, 0))
 
 RequestRecord = collections.namedtuple("RequestRecord", [
     "uid", "t_submit", "t_admit",

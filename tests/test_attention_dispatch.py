@@ -116,6 +116,50 @@ class TestSelectionTable:
                               block_size=128,
                               kv_dtype="int8")) == "paged_gather_quant"
 
+    # (phase, pool form, mask flags, shapes) -> program: the chunk kernel
+    # engages on the in-place pool for plain causal chunks of whole lane
+    # tiles, whatever `use_flash_attention` and the context say; verify, the
+    # int8 pool and everything the shapes disqualify stay on the gathers
+    PREFILL_SITES = {
+        "mistral_chunk": (dict(), "paged_prefill_kernel"),
+        "olmoe_chunk": (dict(q_len=256, kv_len=1536), "paged_prefill_kernel"),
+        "forced_off_is_not_consulted": (dict(force_flash=False),
+                                        "paged_prefill_kernel"),
+        "short_table": (dict(kv_len=512), "paged_prefill_kernel"),
+        "scatter_form": (dict(pool_in_place=False), "paged_gather"),
+        "alibi": (dict(has_bias=True), "paged_gather"),
+        "window": (dict(has_window=True), "paged_gather"),
+        "chunk_off_the_lane_tile": (dict(q_len=64), "paged_gather"),
+        "block_off_the_lane_tile": (dict(block_size=64, kv_len=2048),
+                                    "paged_gather"),
+        "int8_pool": (dict(pool_in_place=False, kv_dtype="int8"),
+                      "paged_gather_quant"),
+        "verify": (dict(phase="verify", q_len=5), "paged_gather"),
+        "verify_of_a_whole_tile": (dict(phase="verify", q_len=128),
+                                   "paged_gather"),
+        "verify_int8": (dict(phase="verify", q_len=5, pool_in_place=False,
+                             kv_dtype="int8"), "paged_gather_quant"),
+        "decode_keeps_its_kernel": (dict(phase="paged_decode", q_len=1),
+                                    "paged_kernel"),
+    }
+
+    @pytest.mark.parametrize("case", sorted(PREFILL_SITES))
+    def test_prefill_chunk_sites(self, case):
+        changes, program = self.PREFILL_SITES[case]
+        base = dict(phase="prefill_chunk", q_len=512, kv_len=16384,
+                    block_size=512, pool_in_place=True)
+        assert ad.select(site(**{**base, **changes})) == program
+
+    def test_the_prefill_kernel_is_the_paged_program_with_a_runner(self):
+        paged = [p for p in ad.registered_programs()
+                 if set(p.phases) & {"prefill_chunk", "paged_decode",
+                                     "verify"}]
+        assert [p.name for p in paged if p.runner is not None] \
+            == ["paged_prefill_kernel"]
+        names = [n for n, _ in ad.dispatch_table()["prefill_chunk"]]
+        assert names.index("paged_prefill_kernel") \
+            < names.index("paged_gather")
+
     def test_dispatch_table_is_total_and_ordered(self):
         table = ad.dispatch_table()
         for phase, rows in table.items():

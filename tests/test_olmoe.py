@@ -369,7 +369,8 @@ def test_benchmark_holds_the_cells_files():
     assert sorted(reported) == ["serve_tokens_per_s", "setup_s"]
     layered = [m for m in bench["per_layer"]
                if m.get("workloads") == [cell["name"]]]
-    assert len(layered) == 16          # PR 27's 15 + the live-step share
+    # PR 27's 15, PR 28's live-step share, PR 30's two of the prefill kernel
+    assert len(layered) == 18
     for metric in layered:
         with open(os.path.join(BENCH, "layer_metrics",
                                metric["name"] + ".json")) as f:

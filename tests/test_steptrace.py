@@ -639,9 +639,10 @@ def _assert_decode_walk_is_built_once_a_token(text):
     assert work and not set(work) & reached, (work, sorted(reached))
 
 
-def _compile_paged_programs(one_chip, pool_dtype):
+def _compile_paged_programs(one_chip, pool_dtype, chunk=64):
     """The paged decode and prefill programs of a 2-layer model at the
-    served tile widths (Hkv 8, block 512, hd 128), pool donated."""
+    served tile widths (Hkv 8, block 512, hd 128), pool donated; the prefill
+    chunk `chunk` rows over a table of 8 blocks."""
     from deepspeed_tpu.models.gpt import gpt_init_fn
 
     def sds(shape, dtype):
@@ -665,7 +666,7 @@ def _compile_paged_programs(one_chip, pool_dtype):
         params, sds((8,), i32), sds((8,), i32), pool,
         sds((8, 16), i32)).compile()
     prefill = jax.jit(spec.prefill_paged_fn, donate_argnums=(4,)).lower(
-        params, sds((1, 64), i32), sds((1,), i32), sds((1,), i32), pool,
+        params, sds((1, chunk), i32), sds((1,), i32), sds((1,), i32), pool,
         sds((1, 8), i32)).compile()
     return {"decode": decode, "prefill": prefill}, layer_leaf, \
         dict(spec.kv_pool_writers)
@@ -695,9 +696,11 @@ def test_paged_programs_hold_nothing_of_the_pools_size(one_chip, monkeypatch):
         assert len(calls) == 2 and all(
             n.startswith("dstpu_kv_pool_write") for n in calls), (name, calls)
         assert program.memory_analysis().temp_size_in_bytes < layer_leaf, name
-    # and what reads the carried pool is a Mosaic call as well
+    # and what reads the carried pool is a Mosaic call as well (a chunk of
+    # 64 rows is off the lane tile: the gather and the dense attend)
     _assert_decode_walk_is_built_once_a_token(programs["decode"].as_text())
     assert "dstpu_kv_pool_gather" in programs["prefill"].as_text()
+    assert "dstpu_paged_prefill" not in programs["prefill"].as_text()
 
     # the int8 pool: the rule declines, and the program is today's — the pool
     # sliced and restacked by the scan, an XLA scatter on each slice
@@ -708,6 +711,56 @@ def test_paged_programs_hold_nothing_of_the_pools_size(one_chip, monkeypatch):
         assert "dstpu_kv_pool_write" not in text, name
         assert [x for x in _large_instructions(text, layer_leaf)
                 if x[1] not in _NO_NEW_BUFFER], name
+
+
+def _instructions_spanning(text, width):
+    """Names of the instructions, fused ones included, whose result has a
+    dimension of `width`."""
+    found = []
+    for line in text.splitlines():
+        match = _HLO_LINE.match(line)
+        if match and any(str(width) in dims.split(",")
+                         for _, dims in _HLO_ARRAY.findall(match.group(2))):
+            found.append(match.group(1))
+    return found
+
+
+def test_prefill_chunk_program_holds_nothing_as_wide_as_the_table(
+        one_chip, monkeypatch):
+    """A 512-row chunk on the in-place pool (PERF.md §6, PR 30): attention is
+    the `dstpu_paged_prefill` walk, so the program holds no gather of the
+    row's table, no operation whose result spans the table's `nb * block`
+    positions (the gathered K/V, the float32 scores and probabilities of the
+    dense attend), and — as before — nothing of a pool layer's size but the
+    aliased writes. The same chunk on the scatter form is the control: the
+    width IS found there."""
+    from deepspeed_tpu.ops import attention_dispatch
+    from deepspeed_tpu.platform import device
+    mesh_mod.clear_mesh()
+    table_positions = 8 * 512
+
+    programs, layer_leaf, writers = _compile_paged_programs(
+        one_chip, jnp.int8, chunk=512)
+    assert writers["prefill_chunk"] == attention_dispatch.KV_POOL_WRITE_SCATTER
+    assert _instructions_spanning(programs["prefill"].as_text(),
+                                  table_positions)
+
+    monkeypatch.setattr(device, "on_tpu", lambda: True)   # what the chip sees
+    programs, layer_leaf, writers = _compile_paged_programs(
+        one_chip, jnp.bfloat16, chunk=512)
+    assert writers["prefill_chunk"] == attention_dispatch.KV_POOL_WRITE_KERNEL
+    text = programs["prefill"].as_text()
+    kernels = {line.split("=")[0].strip().lstrip("%").rsplit(".", 1)[0]
+               for line in text.splitlines()
+               if "custom-call(" in line and "tpu_custom_call" in line}
+    assert kernels == {"dstpu_kv_pool_write", "dstpu_paged_prefill"}
+    assert _instructions_spanning(text, table_positions) == []
+    large = _large_instructions(text, layer_leaf)
+    assert [x for x in large if x[1] not in _NO_NEW_BUFFER] == []
+    assert all(n.startswith("dstpu_kv_pool_write")
+               for n, opcode in large if opcode == "custom-call")
+    assert programs["prefill"].memory_analysis().temp_size_in_bytes \
+        < layer_leaf
 
 
 def _compile_routed_paged_programs(one_chip, window):

@@ -343,6 +343,43 @@ def test_paged_decode_attention_compiled_at_the_served_shapes(cell):
     assert not out[~live].any()
 
 
+@pytest.mark.parametrize("cell,start", [
+    ("mistral", 0), ("mistral", 3584), ("mistral", 15872),
+    ("olmoe", 0), ("olmoe", 256), ("olmoe", 1280)])
+def test_paged_prefill_attention_compiled_at_the_served_shapes(cell, start):
+    """`dstpu_paged_prefill` at the benchmark's two chunk shapes — 512 rows
+    of 32 query heads over 8 KV heads, a 32-block table; 256 rows of 16
+    heads (MHA), a 3-block table, so a chunk can start inside a block — at
+    the first chunk, a middle one and the table's last, against the gather
+    and the dense attend it replaces; the table past the frontier holds the
+    trash block."""
+    from deepspeed_tpu.models.gpt import GPTConfig, _paged_attend
+    from deepspeed_tpu.ops.pallas.kv_pool import kv_pool_gather
+    from deepspeed_tpu.ops.pallas.prefill_attention import (
+        paged_prefill_attention, paged_prefill_live_blocks)
+    C, H, Hkv, nb, N = {"mistral": (512, 32, 8, 32, 200),
+                        "olmoe": (256, 16, 16, 3, 130)}[cell]
+    rng = np.random.default_rng(30)
+    kq, kk, kv = jax.random.split(jax.random.PRNGKey(30), 3)
+    q = jax.random.normal(kq, (1, C, H, 128), jnp.bfloat16)
+    k = jax.random.normal(kk, (N, Hkv, 512, 128), jnp.bfloat16)
+    v = jax.random.normal(kv, (N, Hkv, 512, 128), jnp.bfloat16)
+    tables = np.zeros((1, nb), np.int32)
+    live = paged_prefill_live_blocks(start, C, 512, nb)
+    tables[0, :live] = 1 + rng.permutation(N - 1)[:live]
+    tables, starts = jnp.asarray(tables), jnp.asarray([start], jnp.int32)
+    out = jax.jit(lambda *a: paged_prefill_attention(
+        *a, interpret=False))(q, k, v, tables, starts)
+    cfg = GPTConfig(vocab_size=64, n_layer=1, n_head=H, n_kv_head=Hkv,
+                    d_model=H * 128, d_ff=64, max_seq_len=nb * 512)
+    ref = _paged_attend(q, kv_pool_gather(k, tables, interpret=False),
+                        kv_pool_gather(v, tables, interpret=False),
+                        starts[:, None] + jnp.arange(C)[None], cfg)
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(ref, np.float32),
+                               atol=3e-2, rtol=3e-2)
+
+
 @pytest.mark.parametrize("group", [128, 32])
 def test_paged_decode_attention_quant_compiled(group):
     """int8 pool, dequantized in-kernel: one scale per K/V vector (the
