@@ -131,6 +131,13 @@ class DecodeModelSpec:
     # The scheduler sums them over a decode window, reads them back with the
     # tokens and keeps them on its step ring. None: two results, no counters.
     step_counters: Optional[tuple] = None
+    # a model whose layers keep TWO kinds of cache (full attention beside
+    # sliding-window attention): `block_size -> (CacheKind full, CacheKind
+    # window)` (`inference/kv_cache.py`). The scheduler then builds the
+    # window kind's ring tables, hands `init_paged_pool` the keyword
+    # `window_blocks`, and passes every paged program its tables as the PAIR
+    # (full tables [B, nb], ring tables [B, nbw]). None: one kind, one table.
+    paged_cache_kinds: Optional[Callable] = None
     # cache-identity fingerprint for the prefix cache's hash chain
     # (inference/prefix_cache.py): every arch field that changes the KV
     # VALUES written for a given token stream must be folded in, so two

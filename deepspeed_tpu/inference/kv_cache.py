@@ -32,15 +32,56 @@ whose content is still registered in the prefix cache parks on a
 for future hits, but `alloc()` treats it as available and evicts it (via the
 `on_evict` hook, which unregisters the hash) the moment a fresh allocation
 would otherwise fail. Caching therefore never reduces usable capacity.
+
+A model whose layers are of two KINDS (`CacheKind`: full attention beside
+sliding-window attention) gets a pool of two kinds. The full kind is the pool
+above, its blocks the allocator's. The window kind never needs more of a
+sequence than its window and the chunk being written, so each slot owns a
+fixed RING of `ring_blocks(...)` physical blocks a window layer, addressed
+`logical block mod ring` (`ring_tables`: the same `[slots, table]` form the
+kernels already take, so a walk needs a lower bound and nothing else), never
+allocated and never freed; its block 0 is a trash block too.
 """
 
 import collections
+import dataclasses
 from typing import List, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 TRASH_BLOCK = 0  # physical block 0: write sink for inactive slots
+
+
+@dataclasses.dataclass(frozen=True)
+class CacheKind:
+    """What one kind of layer keeps of a sequence: `layers` layers, each
+    holding either the whole context in allocator blocks (`window` 0; `block`
+    is then the engine's `kv_block_size`) or the last `window` positions in
+    a per-slot ring of `block`-token blocks; `leaves` names its K and V
+    leaves in the pool pytree."""
+    name: str
+    layers: int
+    block: int
+    window: int = 0
+    leaves: tuple = ("k", "v")
+
+
+def ring_blocks(window: int, block: int, chunk: int, decode_steps: int) -> int:
+    """Blocks in a slot's ring of one window layer: enough that the positions
+    a call WRITES (a prefill chunk, or a decode window's steps) never alias a
+    position one of its queries can still see — window + the longest write,
+    starting anywhere in a block."""
+    return -(-(window + max(chunk, decode_steps)) // block) + 1
+
+
+def ring_tables(slots: int, table_blocks: int, ring: int) -> np.ndarray:
+    """The window kind's block tables, fixed for an engine's lifetime: slot
+    s's logical block j lives at physical `1 + s * ring + j % ring` (0 is
+    the kind's trash block). `[slots, table_blocks]` int32."""
+    j = np.arange(table_blocks, dtype=np.int32) % ring
+    return 1 + np.arange(slots, dtype=np.int32)[:, None] * ring + j[None]
 
 
 class BlockAllocator:

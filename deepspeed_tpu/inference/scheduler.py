@@ -58,6 +58,7 @@ from deepspeed_tpu.inference.audit import PoolAuditor, PoolCorruptionError
 from deepspeed_tpu.inference.engine import sample_logits
 from deepspeed_tpu.inference.kv_cache import (BlockAllocator, TRASH_BLOCK,
                                               blocks_needed, max_written_pos,
+                                              ring_blocks, ring_tables,
                                               transplant_blocks)
 from deepspeed_tpu.inference.spec_decode import accept_greedy, make_drafter
 from deepspeed_tpu.telemetry import Telemetry
@@ -294,6 +295,43 @@ class ServingEngine:
                 f"contract (make_gpt_decode_model provides it)")
         num_blocks = int(scfg.num_kv_blocks or
                          (self.max_slots * self.nb + 1))
+        # a pool of two kinds (`DecodeModelSpec.paged_cache_kinds`): the
+        # window kind's per-slot rings and their tables, fixed for this
+        # engine's lifetime. What is not built on such a pool is refused
+        # HERE, with the reason, rather than run wrong.
+        self.cache_kinds = None
+        self.ring = 0
+        self.ring_tables = None
+        kinds_of = getattr(spec, "paged_cache_kinds", None)
+        if kinds_of is not None and not self.streamed:
+            self.cache_kinds = kinds_of(bs)
+            _full, wkind = self.cache_kinds
+            unbuilt = {
+                "enable_prefix_caching": (
+                    scfg.enable_prefix_caching,
+                    "a registered block names a full layer's blocks only; "
+                    "the window layers' rings of the matching prefix are "
+                    "gone with the slot that wrote them"),
+                "kv_cache_dtype int8": (
+                    self.kv_quant,
+                    "the window kind's rings have no scale leaves and the "
+                    "windowed walks no dequantizing twin"),
+                "spec_decode": (
+                    self.spec_on,
+                    "a verify chunk writes k drafts ahead into a ring whose "
+                    "size counts prefill chunks and decode windows only"),
+            }
+            for what, (asked, why) in unbuilt.items():
+                if asked:
+                    raise ValueError(
+                        f"model spec '{spec.name}' keeps a KV pool of two "
+                        f"kinds (window rings beside full-context blocks): "
+                        f"{what} is not built for it — {why}")
+            self.ring = ring_blocks(wkind.window, wkind.block, self.chunk,
+                                    self.window)
+            self.ring_tables = ring_tables(
+                self.max_slots, -(-self.max_context // wkind.block),
+                self.ring)
 
         # telemetry (deepspeed_tpu/telemetry/): TTFT/TPOT/queue-wait/e2e
         # histograms + queue/slot/pool gauges + per-phase spans — built
@@ -379,6 +417,10 @@ class ServingEngine:
                     f"k_scale/v_scale leaves for dtype int8 — it does not "
                     f"implement the quantized-pool contract "
                     f"(init_paged_kv_pool in models/gpt.py is the reference)")
+        elif self.cache_kinds is not None:
+            pool = spec.init_paged_pool(
+                num_blocks, bs, jnp.dtype(kvd),
+                window_blocks=1 + self.max_slots * self.ring)
         else:
             pool = spec.init_paged_pool(num_blocks, bs, jnp.dtype(kvd))
         self.pool = jax.device_put(
@@ -814,6 +856,8 @@ class ServingEngine:
         the original submit-time deadline through every re-dispatch so a
         failover rerun or a hedged duplicate never extends the budget;
         without it, `request.deadline_ms` anchors at arrival here."""
+        if prefill_only:
+            self._refuse_transplant()
         prompt = np.asarray(request.tokens, np.int32).reshape(-1)
         prompt_len = int(prompt.shape[0])
         padded = -(-prompt_len // self.chunk) * self.chunk
@@ -841,6 +885,27 @@ class ServingEngine:
                                      "max_new": int(request.max_new_tokens)})
         self.queue.append((request, prompt, prompt_len, padded, need, hashes,
                            t_arrive, prefill_only, trace, deadline_at))
+
+    def _refuse_transplant(self):
+        if self.cache_kinds is not None:
+            raise ValueError(
+                f"model spec '{self.engine.model_spec.name}' keeps a KV pool "
+                f"of two kinds: block transplant (prefill-only slots, "
+                f"handoff) is not built for it — `transplant_blocks` copies "
+                f"allocator blocks, and a window layer's ring belongs to "
+                f"the slot, not to the request")
+
+    def _tables_arg(self, tables, rows=None):
+        """What a paged program takes as its tables: the block tables, or
+        for a pool of two kinds the pair (full tables, ring tables) — the
+        ring rows of `rows` (slot indices; None = every slot) with those of
+        slots whose full table is all trash (not in this call) at the ring
+        kind's trash block."""
+        if self.cache_kinds is None:
+            return tables
+        ring = self.ring_tables if rows is None else self.ring_tables[rows]
+        live = (tables != TRASH_BLOCK).any(axis=1, keepdims=True)
+        return tables, np.where(live, ring, TRASH_BLOCK).astype(np.int32)
 
     def _resolve_eos(self, req: Request):
         if not req.stop_on_eos:
@@ -1307,8 +1372,10 @@ class ServingEngine:
         axis 1 of the pool layout), and seed a _DECODE slot that continues
         from the first sampled token. Returns False when this engine has no
         free slot or blocks RIGHT NOW (the router retries later — source
-        blocks are still held); raises `InadmissibleRequestError` when the
+        blocks are still held; a pool of two kinds refuses, see
+        `_refuse_transplant`); raises `InadmissibleRequestError` when the
         request can never fit here."""
+        self._refuse_transplant()
         need = blocks_needed(state["prompt_len"], state["padded_len"],
                              state["max_new"], self.block_size,
                              window=self.window, spec_k=self.draft_k)
@@ -1499,8 +1566,10 @@ class ServingEngine:
         st.begin_step()
         compiled0 = self._compiled_programs()
         chunks0, tokens0 = self.prefill_chunks, self.tokens_generated
-        walk = (0, 0)       # the decode kernel's (live blocks, grid steps)
-        reach = [0, 0]      # the prefill kernel's (live, table) blocks
+        walk = (0, 0, 0, 0)  # the decode kernel's (live blocks, grid steps,
+                             # window layers' live blocks, ... unwindowed)
+        reach = [0, 0, 0, 0]  # the prefill kernel's (live, table) blocks
+                              # and the window layers' (live, unwindowed)
 
         with self._phase("serving/admit"):
             admitted, blocked_on = self._admit(finished)
@@ -1512,9 +1581,9 @@ class ServingEngine:
             if budget <= 0:
                 break
             while slot.state == _PREFILL and budget > 0:
-                live, table = self._prefill_chunk(slot, params, finished)
-                reach[0] += live
-                reach[1] += table
+                for i, n in enumerate(self._prefill_chunk(slot, params,
+                                                           finished)):
+                    reach[i] += n
                 budget -= 1
 
         # decode: ONE fixed-shape call for every slot; non-decoding slots
@@ -1575,6 +1644,10 @@ class ServingEngine:
                     decode_live_blocks=walk[0], decode_grid_steps=walk[1],
                     prefill_live_blocks=reach[0],
                     prefill_table_blocks=reach[1],
+                    decode_window_live_blocks=walk[2],
+                    decode_window_table_blocks=walk[3],
+                    prefill_window_live_blocks=reach[2],
+                    prefill_window_table_blocks=reach[3],
                     admitted=admitted,
                     prefill_chunks=self.prefill_chunks - chunks0,
                     decoding=len(dec),
@@ -1590,7 +1663,10 @@ class ServingEngine:
         registrations it completes and, after the final chunk, the
         first-token read-back. Returns what the prefill kernel's walk
         attends, a layer: (logical blocks under the chunk's frontier, blocks
-        in its table) — (0, 0) where the program built is not that kernel."""
+        in its table, and for a pool of two kinds the blocks a WINDOW
+        layer's walk visits and the blocks the same walk would visit with no
+        window, both in the window kind's blocks) — zeros where the program
+        built is not that kernel."""
         st = self.steptrace
         ctx = slot.trace                      # _emit may retire the slot
         with self._phase("serving/prefill_chunk") as ph:
@@ -1604,16 +1680,27 @@ class ServingEngine:
             tok, self.pool = self._prefill_step(
                 params, chunk, np.asarray([start], np.int32),
                 np.asarray([last], np.int32), self.pool,
-                self.tables[slot.idx][None], self._next_rng())
+                self._tables_arg(self.tables[slot.idx][None], [slot.idx]),
+                self._next_rng())
             # counted here, while the device runs
-            reach = (0, 0)
+            reach = (0, 0, 0, 0)
             if self.attention_programs().get("prefill_step") \
                     == "paged_prefill_kernel":
                 from deepspeed_tpu.ops.pallas.prefill_attention import \
                     paged_prefill_live_blocks
                 table = self.tables.shape[1]
-                reach = (paged_prefill_live_blocks(
-                    start, self.chunk, self.block_size, table), table)
+                full = paged_prefill_live_blocks(
+                    start, self.chunk, self.block_size, table)
+                reach = (full, table, 0, 0)
+                if self.cache_kinds is not None:
+                    # what a window layer's walk visits, in ITS blocks, of
+                    # what the same chunk's walk would visit with no window
+                    wkind = self.cache_kinds[1]
+                    width = self.ring_tables.shape[1]
+                    reach = (full, table, paged_prefill_live_blocks(
+                        start, self.chunk, wkind.block, width, wkind.window),
+                        paged_prefill_live_blocks(
+                            start, self.chunk, wkind.block, width))
             if self.drafter is not None:
                 # a stateful drafter (the draft model) shadows the chunk
                 # into its own pool through the same table — the draft
@@ -1660,7 +1747,10 @@ class ServingEngine:
         """The decode call for every slot in `dec`, its read-back, and the
         emission of what it sampled. Returns what the paged decode kernel's
         walk had to do and what it was launched with, a layer: (live (slot,
-        block) pairs, grid steps), summed over the call's tokens."""
+        block) pairs, grid steps, and for a pool of two kinds the pairs a
+        WINDOW layer's walk visits and the pairs it would visit with no
+        window, both in the window kind's blocks), summed over the call's
+        tokens."""
         # the degraded paths run the 1-STEP decode program: with
         # spec decode pressure-disabled the blocks were sized for
         # the k-draft overhang (no window-rounding tail, so a K-step
@@ -1674,18 +1764,26 @@ class ServingEngine:
             else self._decode_step
         win = 1 if use_w1 else self.window
         st = self.steptrace
-        from deepspeed_tpu.ops.pallas.decode_attention import \
-            paged_decode_walk_steps
+        from deepspeed_tpu.ops.pallas.decode_attention import (
+            paged_decode_walk_steps, window_first_block)
         with self._phase("serving/decode_window") as ph:
             st.dispatched()
             nxt, self.pool = step_fn(params, tok, pos,
-                                     self.pool, tables,
+                                     self.pool, self._tables_arg(tables),
                                      self._next_rng())
             # counted here, while the device runs
-            live = (pos[[s.idx for s in dec]] + np.arange(win)[:, None]) \
-                // self.block_size + 1                     # [win, slots]
+            at = pos[[s.idx for s in dec]] + np.arange(win)[:, None]
+            live = at // self.block_size + 1               # [win, slots]
             walk = (int(live.sum()),
-                    sum(paged_decode_walk_steps(n) for n in live.sum(axis=1)))
+                    sum(paged_decode_walk_steps(n) for n in live.sum(axis=1)),
+                    0, 0)
+            if self.cache_kinds is not None:
+                wkind = self.cache_kinds[1]
+                whole = at // wkind.block + 1
+                walk = walk[:2] + (
+                    int((whole - window_first_block(
+                        at, wkind.block, wkind.window)).sum()),
+                    int(whole.sum()))
             # THE one host roundtrip per decode window — EOS/retirement decisions are host-side, amortized over `win` tokens
             nxt = np.asarray(self._read_back(nxt))  # [S, win]
             st.ready()
@@ -1832,6 +1930,18 @@ class ServingEngine:
                "compiles": self.compile_stats(),
                "kv_pool_writer": self.kv_pool_writers(),
                "attention_program": self.attention_programs()}
+        if self.cache_kinds is not None:
+            # a kind of layer: its layers, its blocks (a window layer's are
+            # the slots' rings and one trash block) and what they hold
+            out["kv_pool_kinds"] = {
+                kind.name: {
+                    "layers": kind.layers, "block": kind.block,
+                    "window": kind.window,
+                    "blocks": int(self.pool[kind.leaves[0]].shape[1]),
+                    "bytes": int(sum(self.pool[leaf].nbytes
+                                     for leaf in kind.leaves))}
+                for kind in self.cache_kinds}
+            out["kv_pool_kinds"]["window"]["ring_blocks_per_slot"] = self.ring
         if self.step_counter_names:
             # the model's own counters (routed experts: calls, assignments,
             # active experts, the largest expert's load), summed over layers

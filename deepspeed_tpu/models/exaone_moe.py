@@ -1,0 +1,546 @@
+"""EXAONE-MoE — the K-EXAONE family (`model_type: exaone_moe`) on the paged
+serving path: window and full attention layers mixed, a leading dense layer,
+then routed layers with a sigmoid router and a shared expert, served as ONE
+CHIP'S SHARE of an expert-parallel deployment.
+
+The layer, as `benchmark/references/exaone_moe.py` computes it in float32:
+
+    q, k <- RMSNorm over each head's columns (one scale vector of head_dim)
+    window layer: rotary at the absolute position, keys `i - j < window`
+    full layer:   no rotary, every earlier key
+    h = x + RMSNorm(attn(x) Wo)            (post-norm: nothing in front)
+    y = h + RMSNorm(MLP(h))
+    dense MLP:  SwiGLU of width `d_ff_dense`
+    sparse MLP: sigmoid scores, the `top_k` largest `score + bias`, weights
+                renormalised over the chosen and scaled; the routed experts'
+                weighted sum + one shared SwiGLU expert
+
+What is new here beside `models/moe_gpt.py`, and where each piece lives:
+
+- THE LAYER PATTERN IS DATA (`layer_plan`): a prologue of listed layers (each
+  with a parameter tree of its own) and then whole PERIODS of the pattern,
+  scanned a period at a time with every position of the period traced for
+  its own kind — no `lax.cond` over kinds inside the loop, so a carried pool
+  is still touched by Mosaic calls only (`attention_dispatch.kv_pool_writer`'s
+  rule). Kinds are compile-time: a window layer runs on a config whose
+  `sliding_window` is set and `use_rotary` on, a full layer on one with
+  neither, through the SAME `gpt.py::_paged_attn_half`.
+- A POOL OF TWO KINDS (`inference/kv_cache.py::CacheKind`): full layers keep
+  a sequence's whole context in allocator blocks (`k`/`v`
+  `[Lf, N, Hkv, block, hd]`); window layers keep a per-slot ring
+  (`wk`/`wv` `[Lw, 1 + slots*ring, Hkv, window_block, hd]`) that nobody
+  allocates or frees. The paged programs take the tables as a PAIR
+  `(full tables, ring tables)`.
+- THE EXPERT SHARE: the router routes over all `num_experts`, this chip holds
+  `experts_held = (first, count)` of them (`parallel/moe.py::routed_experts(
+  held=)`), and what the others would add is left out, here and in the
+  reference alike.
+
+Not here: training, the contiguous-cache `generate()` path, the multi-token-
+prediction module (it proposes tokens; the main model's logits do not depend
+on it), the int8 pool, prefix caching and block transplant on a two-kind pool
+(`ServingEngine` refuses them with the reason).
+"""
+
+import copy
+import dataclasses
+import math
+from typing import Optional, Tuple
+
+import jax
+import jax.numpy as jnp
+
+from deepspeed_tpu.inference.kv_cache import CacheKind
+from deepspeed_tpu.models.gpt import (_attn_half, _embed, _lm_head,
+                                      _paged_attn_half, _residual_mlp)
+from deepspeed_tpu.models.moe_gpt import MoEGPTConfig
+from deepspeed_tpu.ops import attention_dispatch as attn_dispatch
+from deepspeed_tpu.parallel.moe import (HELD_ROUTED_COUNTERS, routed_experts,
+                                        topk_routing)
+
+WINDOW, FULL = "sliding_attention", "full_attention"
+DENSE, SPARSE = "dense", "sparse"
+
+
+@dataclasses.dataclass
+class ExaoneMoEConfig(MoEGPTConfig):
+    layer_types: Tuple[str, ...] = ()       # per layer: WINDOW | FULL
+    mlp_layer_types: Tuple[str, ...] = ()   # per layer: DENSE | SPARSE
+    d_ff_dense: int = 0                     # the dense layers' SwiGLU width
+                                            # (`d_ff` is ONE expert's, and
+                                            # the shared expert's a unit)
+    num_shared_experts: int = 1
+    experts_held: Optional[Tuple[int, int]] = None   # (first, count) of the
+                                            # `num_experts` the router
+                                            # chooses among; None = all
+    router_scoring: str = "sigmoid"
+    routed_scaling_factor: float = 1.0
+    window_block: int = 128                 # the window kind's ring block
+    pattern_period: int = 0                 # layers in a period of the
+                                            # pattern (`layer_plan`); 0 =
+                                            # found from the lists
+
+    def __post_init__(self):
+        # what the family fixes (the published config has no key for them)
+        self.use_rotary = self.use_rmsnorm = self.use_swiglu = True
+        self.post_norm = self.qk_norm_per_head = True
+        self.qk_norm = self.use_alibi = self.parallel_residual = False
+        self.moe_freq = 1
+        super().__post_init__()
+        if len(self.layer_types) != self.n_layer \
+                or len(self.mlp_layer_types) != self.n_layer:
+            raise ValueError(
+                f"layer_types and mlp_layer_types list {self.n_layer} "
+                f"layers each (got {len(self.layer_types)} and "
+                f"{len(self.mlp_layer_types)})")
+        if WINDOW in self.layer_types and not self.sliding_window:
+            raise ValueError("window layers need `sliding_window`")
+        if self.experts_held is None:
+            self.experts_held = (0, self.num_experts)
+        first, count = self.experts_held
+        if first < 0 or count < 1 or first + count > self.num_experts:
+            raise ValueError(f"experts_held {self.experts_held} is not a "
+                             f"range of the {self.num_experts} experts")
+
+
+# the pool's K and V leaves, a kind of attention layer
+_POOL_LEAVES = {FULL: ("k", "v"), WINDOW: ("wk", "wv")}
+
+
+def layer_plan(cfg: ExaoneMoEConfig):
+    """The layer pattern as data: (prologue, period, periods). `prologue`
+    and `period` list `(attention kind, MLP kind)` pairs; the model is the
+    prologue's layers, then `periods` repetitions of the period.
+
+    The period's length is `cfg.pattern_period` (the published
+    `sliding_window_pattern`, "LLLG": 4) and the prologue the shortest head
+    that leaves whole periods (K-EXAONE as published: the dense layer and
+    the three that complete its group of four, then eleven periods of
+    window, window, window, full; the one-chip cut: the dense layer, then
+    one). With no period given: the split that needs the fewest distinct
+    layer bodies, the longer prologue among equals."""
+    kinds = list(zip(cfg.layer_types, cfg.mlp_layer_types))
+    n = len(kinds)
+
+    def periodic(head, length):
+        rest = kinds[head:]
+        return len(rest) % length == 0 \
+            and rest == rest[:length] * (len(rest) // length)
+
+    lengths = [cfg.pattern_period] if cfg.pattern_period \
+        else range(1, n + 1)
+    plans = [(head + length, -head, head, length)
+             for length in lengths for head in range(n - length + 1)
+             if periodic(head, length)]
+    if not plans:
+        raise ValueError(
+            f"layer_types/mlp_layer_types do not end in whole periods of "
+            f"{cfg.pattern_period} layers")
+    _, _, head, length = min(plans)
+    return kinds[:head], kinds[head:head + length], (n - head) // length
+
+
+def cache_kinds(cfg: ExaoneMoEConfig, block_size: int):
+    """`CacheKind` a kind of attention layer, full first."""
+    return (CacheKind("full", cfg.layer_types.count(FULL), block_size,
+                      leaves=_POOL_LEAVES[FULL]),
+            CacheKind("window", cfg.layer_types.count(WINDOW),
+                      cfg.window_block, int(cfg.sliding_window or 0),
+                      leaves=_POOL_LEAVES[WINDOW]))
+
+
+def _kind_cfgs(cfg: ExaoneMoEConfig):
+    """The two configurations the attention halves are traced with: rotary
+    and the window belong to the window layers, a full layer has neither."""
+    window, full = copy.copy(cfg), copy.copy(cfg)   # no `__post_init__`
+    window.attn_layer_types = full.attn_layer_types = None
+    full.use_rotary, full.sliding_window = False, None
+    return {WINDOW: window, FULL: full}
+
+
+# ----------------------------------------------------------------------
+# parameters
+# ----------------------------------------------------------------------
+
+
+def _layer_shapes(cfg: ExaoneMoEConfig, mlp_kind, router_std=0.02):
+    """One layer's leaves -> (shape, init scale; 1.0 = ones, 0.0 = zeros)."""
+    D, hd = cfg.d_model, cfg.head_dim
+    attn = cfg.n_head * hd
+    down = 0.02 / math.sqrt(2 * cfg.n_layer)
+    shapes = {
+        "attn_qkv_w": ((D, cfg.qkv_dim), 0.02),
+        "attn_qkv_b": ((cfg.qkv_dim,), 0.0),
+        "attn_out_w": ((attn, D), down),
+        "attn_out_b": ((D,), 0.0),
+        "q_norm_scale": ((hd,), 1.0), "k_norm_scale": ((hd,), 1.0),
+        "ln1_scale": ((D,), 1.0), "ln2_scale": ((D,), 1.0),
+    }
+    if mlp_kind == DENSE:
+        F = cfg.d_ff_dense
+        shapes.update({"mlp_gate_w": ((D, F), 0.02), "mlp_up_w": ((D, F), 0.02),
+                       "mlp_down_w": ((F, D), down),
+                       "mlp_out_b": ((D,), 0.0)})
+    else:
+        F, Fs = cfg.d_ff, cfg.d_ff * cfg.num_shared_experts
+        held = cfg.experts_held[1]
+        shapes.update({
+            "moe_gate_w": ((D, cfg.num_experts), router_std),
+            "moe_gate_bias": ((cfg.num_experts,), 0.0),
+            "moe_w_gate_up": ((held, D, 2 * F), 0.02),
+            "moe_w_down": ((held, F, D), down),
+            "shared_gate_w": ((D, Fs), 0.02), "shared_up_w": ((D, Fs), 0.02),
+            "shared_down_w": ((Fs, D), down)})
+    return shapes
+
+
+def _make_layer(rng, cfg, mlp_kind, dtype, lead=(), router_std=0.02):
+    tree = {}
+    shapes = _layer_shapes(cfg, mlp_kind, float(router_std))
+    for name, (shape, scale) in sorted(shapes.items()):
+        rng, sub = jax.random.split(rng)
+        shape = tuple(lead) + shape
+        if name == "moe_gate_bias":
+            tree[name] = jnp.zeros(shape, jnp.float32)
+        elif scale in (0.0, 1.0):
+            tree[name] = jnp.full(shape, scale, dtype)
+        else:           # a Python float: the product stays in `dtype`
+            tree[name] = jax.random.normal(sub, shape, dtype) * scale
+    return tree
+
+
+def exaone_moe_init_fn(cfg: ExaoneMoEConfig, dtype=jnp.float32,
+                       embedding_std=0.02, router_std=0.02):
+    """jax-traceable initializer (rng -> params): under one `jit` the whole
+    tree is made on the device in the type it is served in. Layout:
+    `prologue`: a list of layer trees; `period`: one tree a position of the
+    period, every leaf with a leading `[periods]` axis; `wte`, `lm_head`,
+    `lnf_scale`.
+
+    `embedding_std`: every post-normed half adds a unit-RMS vector to the
+    stream, so with the matrices' 0.02 a token's own embedding is a fiftieth
+    of what its FIRST half-layer adds, the stream collapses onto what a
+    sequence's tokens have in common, and a random router sends a whole
+    sequence to the same few experts (at hidden 512: 46 of 128 experts idle
+    over a 512-token chunk at 0.02, none at 2-4). A benchmark that wants the
+    experts' loads of a trained model gives the embedding a few times the
+    RMS of what the layers add — and the router's matrix `router_std` that
+    much smaller, or its sigmoid saturates: at 0.02 under an embedding of 8 a
+    tenth of the 128 scores round to exactly 1.0 in float32, `top_k` breaks
+    the ties by index, and the low experts get twice their share."""
+    prologue, period, periods = layer_plan(cfg)
+
+    def init(rng):
+        keys = jax.random.split(rng, 2 + len(prologue) + len(period))
+        V, D = cfg.vocab_size, cfg.d_model
+        params = {
+            "wte": jax.random.normal(keys[0], (V, D), dtype)
+            * float(embedding_std),
+            "lm_head": jax.random.normal(keys[1], (V, D), dtype) * 0.02,
+            "lnf_scale": jnp.ones((D,), dtype),
+            "prologue": [_make_layer(k, cfg, mlp, dtype,
+                                     router_std=router_std)
+                         for k, (_, mlp) in zip(keys[2:], prologue)],
+            "period": [_make_layer(k, cfg, mlp, dtype, lead=(periods,),
+                                   router_std=router_std)
+                       for k, (_, mlp)
+                       in zip(keys[2 + len(prologue):], period)],
+        }
+        return params
+
+    return init
+
+
+_EXPERT_STACKS = ("moe_w_gate_up", "moe_w_down")
+
+
+def _period_stacks(params):
+    """(the scanned layers' small leaves, their expert stacks), a position
+    of the period each: the small leaves keep their leading `[periods]` axis
+    (a scan slices them a period), the experts are flat `[periods * held,
+    ...]` in `routed_experts`' names and stay WHOLE — a layer finds its
+    experts by index (`expert_base = period * held`), because a slice of a
+    stack in front of the grouped matmul is a copy of a layer's experts."""
+    small = [{k: v for k, v in tree.items() if k not in _EXPERT_STACKS}
+             for tree in params["period"]]
+    stacks = [{k[len("moe_"):]: v.reshape((-1,) + v.shape[2:])
+               for k, v in tree.items() if k in _EXPERT_STACKS}
+              for tree in params["period"]]
+    return small, stacks
+
+
+def _layers(params, cfg):
+    """Every layer in model order as (its small leaves, `_mlp_fn`'s expert
+    keywords): the unscanned forward's loop (the paged programs scan the
+    periods instead)."""
+    _, period, periods = layer_plan(cfg)
+    held = cfg.experts_held[1]
+    for tree in params["prologue"]:
+        yield tree, {}
+    small, stacks = _period_stacks(params)
+    for n in range(periods):
+        for i, (_, mlp_kind) in enumerate(period):
+            yield (jax.tree_util.tree_map(lambda a: a[n], small[i]),
+                   dict(stacks=stacks[i], expert_base=n * held)
+                   if mlp_kind == SPARSE else {})
+
+
+# ----------------------------------------------------------------------
+# the MLP halves
+# ----------------------------------------------------------------------
+
+
+def _swiglu(h, gate_w, up_w, down_w):
+    return (jax.nn.silu(h @ gate_w) * (h @ up_w)) @ down_w
+
+
+def _sparse_mlp(h, p, cfg: ExaoneMoEConfig, stacks=None, expert_base=0):
+    """The routed half of a sparse layer on h [B, T, D] -> (out, counters
+    int32[5] in `HELD_ROUTED_COUNTERS` order, chosen experts [B*T, top_k]).
+    `stacks`: the experts' weights where they are not `p`'s own leaves —
+    `{"w_gate_up": [n * held, D, 2F], "w_down": ...}`, a whole stack of the
+    scanned layers' experts, with `expert_base` where this layer's begin."""
+    B, T, D = h.shape
+    xf = h.reshape(B * T, D)
+    top_p, top_e = topk_routing(
+        xf, p["moe_gate_w"], cfg.top_k, cfg.norm_topk_prob,
+        scoring=cfg.router_scoring, bias=p["moe_gate_bias"],
+        scale=cfg.routed_scaling_factor)
+    if stacks is None:
+        stacks = {"w_gate_up": p["moe_w_gate_up"], "w_down": p["moe_w_down"]}
+    out, counters = routed_experts(xf, top_p, top_e, stacks,
+                                   expert_base=expert_base,
+                                   held=cfg.experts_held)
+    with jax.named_scope("moe/shared_expert"):
+        out = out + _swiglu(xf, p["shared_gate_w"], p["shared_up_w"],
+                            p["shared_down_w"])
+    return out.reshape(B, T, D), counters, top_e
+
+
+def _mlp_fn(p, cfg, mlp_kind, counts=None, routing=None, stacks=None,
+            expert_base=0):
+    """`_residual_mlp`'s `mlp_fn` for one layer; a sparse layer's counters
+    and chosen experts are appended to `counts` / `routing` where given."""
+    if mlp_kind == DENSE:
+        return lambda h: _swiglu(h, p["mlp_gate_w"], p["mlp_up_w"],
+                                 p["mlp_down_w"])
+
+    def mlp_fn(h):
+        out, counted, top_e = _sparse_mlp(h, p, cfg, stacks, expert_base)
+        if counts is not None:
+            counts.append(counted)
+        if routing is not None:
+            routing.append(top_e)
+        return out
+    return mlp_fn
+
+
+# ----------------------------------------------------------------------
+# the whole-sequence forward (no cache): what the tests and the reference
+# check read the program's own routing from
+# ----------------------------------------------------------------------
+
+
+def exaone_moe_forward(params, tokens, cfg: ExaoneMoEConfig, routing=None):
+    """tokens [B, T] -> logits [B, T, V]: dense masked attention, a Python
+    loop over the layers. `routing`: a list that takes each sparse layer's
+    chosen experts [B*T, top_k]."""
+    B, T = tokens.shape
+    positions = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32)[None], (B, T))
+    kcfg = _kind_cfgs(cfg)
+    x = _embed(params, tokens, positions, cfg)
+    kinds = zip(cfg.layer_types, cfg.mlp_layer_types)
+    for (p, experts), (attn_kind, mlp_kind) in zip(_layers(params, cfg),
+                                                   kinds):
+        attn_out, _, _ = _attn_half(x, p, kcfg[attn_kind], positions,
+                                    constrain=False)
+        x = _residual_mlp(x, attn_out, p, cfg, constrain=False,
+                          mlp_fn=_mlp_fn(p, cfg, mlp_kind, routing=routing,
+                                         **experts))
+    return _lm_head(params, x, cfg)
+
+
+def exaone_moe_cache_identity(cfg: ExaoneMoEConfig, name: str = "") -> str:
+    return (f"exaone_moe:{name}|{cfg.n_layer}|{cfg.d_model}|{cfg.n_head}|"
+            f"{cfg.n_kv_head}|{cfg.head_dim}|{cfg.sliding_window}|"
+            f"{','.join(t[0] for t in cfg.layer_types)}|"
+            f"{','.join(t[0] for t in cfg.mlp_layer_types)}|"
+            f"{cfg.num_experts}|{cfg.experts_held}|{cfg.top_k}|"
+            f"{cfg.router_scoring}|{cfg.routed_scaling_factor}|"
+            f"{cfg.rope_theta}|{cfg.norm_eps}")
+
+
+# ----------------------------------------------------------------------
+# the paged programs
+# ----------------------------------------------------------------------
+
+def make_exaone_moe_decode_model(cfg: ExaoneMoEConfig, params=None,
+                                 name="exaone-moe", seed=0):
+    """The paged serving contract (`DecodeModelSpec`) of the family. The
+    paged programs take `block_tables` as the PAIR `(full tables [B, nb],
+    ring tables [B, nbw])` and a pool of two kinds (module docstring).
+
+    `prefill_paged_fn` and `decode_paged_fn` take one keyword beside the
+    contract's arguments: `routing=True` adds a FOURTH result, the experts
+    the call routed every row to — int32 `[sparse layers, B, C, top_k]`
+    (`C` 1 for decode), ascending in a token. The scheduler never passes it
+    (it takes three results); a check that holds a reference to the served
+    programs' own choices calls the served spec's functions with it."""
+    from deepspeed_tpu.inference.engine import DecodeModelSpec
+    if params is None:
+        params = exaone_moe_init_fn(cfg)(jax.random.PRNGKey(seed))
+    prologue, period, periods = layer_plan(cfg)
+    kcfg = _kind_cfgs(cfg)
+    held = cfg.experts_held[1]
+    no_counts = jnp.zeros((len(HELD_ROUTED_COUNTERS),), jnp.int32)
+    pool_writers, attn_programs = {}, {}
+
+    def per_period(kind):
+        return sum(1 for attn, _ in period if attn == kind)
+
+    def _layers_paged(params, x, pool, block_tables, positions, routing):
+        tables = dict(zip((FULL, WINDOW), block_tables))
+        site = "paged_decode" if x.shape[1] == 1 else "prefill_chunk"
+        in_place = all(
+            attn_dispatch.kv_pool_writer({"k": pool[a], "v": pool[b]})
+            == attn_dispatch.KV_POOL_WRITE_KERNEL
+            for a, b in _POOL_LEAVES.values())
+        pool_writers[site] = attn_dispatch.KV_POOL_WRITE_KERNEL if in_place \
+            else attn_dispatch.KV_POOL_WRITE_SCATTER
+        blocks_of = {kind: pool[a].shape[1]
+                     for kind, (a, _) in _POOL_LEAVES.items()}
+        # one work list a KIND, built once a token, outside the layer loop
+        work = {FULL: None, WINDOW: None}
+        if site == "paged_decode":
+            from deepspeed_tpu.ops.pallas.decode_attention import \
+                paged_decode_work
+            work = {kind: paged_decode_work(
+                tables[kind], positions[:, 0], pool[a].shape[3],
+                window=kcfg[kind].sliding_window)
+                for kind, (a, _) in _POOL_LEAVES.items()}
+        # both kinds' leaves flat and CARRIED: layer i of a kind addresses
+        # its blocks as `table + i * N`
+        flat = {k: v.reshape((-1,) + v.shape[2:]) for k, v in pool.items()}
+
+        def layer(x, flat, p, kinds, kind_index, counts, **experts):
+            # `experts`: `_mlp_fn`'s keywords (routing=, stacks=, expert_base=)
+            attn_kind, mlp_kind = kinds
+            a, b = _POOL_LEAVES[attn_kind]
+            base = kind_index * blocks_of[attn_kind]
+            # the kernels take the layer's offset; the scatter and the
+            # gather of the other form take tables already offset
+            where = dict(block_base=base) if in_place else {}
+            table = tables[attn_kind] if in_place \
+                else tables[attn_kind] + base
+            with jax.named_scope("attn_window" if attn_kind == WINDOW
+                                 else "attn_full"):
+                attn_out, pool_l = _paged_attn_half(
+                    x, p, {"k": flat[a], "v": flat[b]}, positions, table,
+                    kcfg[attn_kind], decode_work=work[attn_kind],
+                    attn_programs=attn_programs, **where)
+            flat = {**flat, a: pool_l["k"], b: pool_l["v"]}
+            with jax.named_scope("mlp"):
+                x = _residual_mlp(x, attn_out, p, cfg, constrain=False,
+                                  mlp_fn=_mlp_fn(p, cfg, mlp_kind, counts,
+                                                 **experts))
+            return x, flat
+
+        counts = []
+        chosen = [] if routing else None     # a layer's [B*C, top_k]
+        seen = {FULL: 0, WINDOW: 0}
+        for p, kinds in zip(params["prologue"], prologue):
+            x, flat = layer(x, flat, p, kinds, seen[kinds[0]], counts,
+                            routing=chosen)
+            seen[kinds[0]] += 1
+        acc = sum(counts, no_counts)
+
+        if periods:
+            # the scan slices the small leaves a period; the expert stacks
+            # stay whole (closed over, like the carried pool) and a layer
+            # finds its experts by index
+            scanned, stacks = _period_stacks(params)
+
+            def body(carry, inputs):
+                x, flat, acc = carry
+                trees, n = inputs
+                counts = []
+                routed = [] if routing else None
+                rank = {FULL: 0, WINDOW: 0}
+                for i, kinds in enumerate(period):
+                    kind = kinds[0]
+                    index = seen[kind] + n * per_period(kind) + rank[kind]
+                    rank[kind] += 1
+                    experts = dict(stacks=stacks[i], expert_base=n * held) \
+                        if kinds[1] == SPARSE else {}
+                    x, flat = layer(x, flat, trees[i], kinds, index, counts,
+                                    routing=routed, **experts)
+                return (x, flat, acc + sum(counts, no_counts)), routed
+
+            (x, flat, acc), routed = jax.lax.scan(
+                body, (x, flat, acc),
+                (scanned, jnp.arange(periods, dtype=jnp.int32)))
+            if routing and routed:
+                # [periods, B*C, k] a sparse position -> model order
+                chosen += [r[n] for n in range(periods) for r in routed]
+        pool = {k: v.reshape(pool[k].shape) for k, v in flat.items()}
+        if routing:
+            B, C = positions.shape
+            return x, pool, acc, jnp.stack(
+                [jnp.sort(e, axis=-1).reshape(B, C, -1) for e in chosen])
+        return x, pool, acc
+
+    def prefill_paged_fn(params, tokens, start_pos, last_idx, pool,
+                         block_tables, routing=False):
+        B, C = tokens.shape
+        positions = start_pos[:, None] + jnp.arange(C, dtype=jnp.int32)[None]
+        x = _embed(params, tokens, positions, cfg)
+        x, pool, *counted = _layers_paged(params, x, pool, block_tables,
+                                          positions, routing)
+        last = jnp.take_along_axis(x, last_idx[:, None, None], axis=1)
+        return (_lm_head(params, last, cfg)[:, 0], pool, *counted)
+
+    def decode_paged_fn(params, token, pos, pool, block_tables,
+                        routing=False):
+        x = _embed(params, token[:, None], pos[:, None], cfg)
+        x, pool, *counted = _layers_paged(params, x, pool, block_tables,
+                                          pos[:, None], routing)
+        return (_lm_head(params, x, cfg)[:, 0], pool, *counted)
+
+    def init_paged_pool(num_blocks, block_size, dtype=jnp.bfloat16,
+                        kv_group_size=0, window_blocks=None):
+        if jnp.dtype(dtype) == jnp.int8:
+            raise ValueError(
+                f"model spec '{name}': the int8 pool is not built for a pool "
+                f"of two kinds (the window kind's rings have no scale leaves "
+                f"and the windowed walks no dequantizing twin)")
+        if window_blocks is None:
+            raise ValueError(
+                f"model spec '{name}' keeps a pool of two kinds: "
+                f"init_paged_pool needs `window_blocks` (1 + slots * "
+                f"kv_cache.ring_blocks(...)), as ServingEngine passes it")
+        pool = {}
+        for kind, n in zip(cache_kinds(cfg, block_size),
+                           (num_blocks, window_blocks)):
+            shape = (kind.layers, n, cfg.n_kv_head, kind.block, cfg.head_dim)
+            pool.update({leaf: jnp.zeros(shape, dtype)
+                         for leaf in kind.leaves})
+        return pool
+
+    def unserved(*_args, **_kwargs):
+        raise NotImplementedError(
+            f"model spec '{name}' (exaone_moe) is served through the paged "
+            f"scheduler only (`engine.serving(...)`): the contiguous-cache "
+            f"generate() path is not built for mixed window and full layers")
+
+    return DecodeModelSpec(prefill_fn=unserved, decode_fn=unserved,
+                           init_cache=unserved, params=params, name=name,
+                           prefill_paged_fn=prefill_paged_fn,
+                           decode_paged_fn=decode_paged_fn,
+                           init_paged_pool=init_paged_pool,
+                           paged_cache_kinds=lambda block_size: cache_kinds(
+                               cfg, block_size),
+                           kv_pool_writers=pool_writers,
+                           paged_attn_programs=attn_programs,
+                           step_counters=HELD_ROUTED_COUNTERS,
+                           cache_fingerprint=exaone_moe_cache_identity(
+                               cfg, name))

@@ -41,6 +41,10 @@ class GPTConfig:
     n_head: int = 12
     n_kv_head: Optional[int] = None  # grouped-query attention; None = n_head (MHA)
     d_model: int = 768
+    attn_head_dim: Optional[int] = None  # a head's width where it is not
+                                     # d_model // n_head (K-EXAONE: 64 heads
+                                     # of 128 on a 6144 stream); the
+                                     # projections are then [D, H*hd] / [H*hd, D]
     d_ff: Optional[int] = None       # default 4*d_model (or 8/3 for swiglu)
     max_seq_len: int = 1024
     dropout: float = 0.0
@@ -63,6 +67,14 @@ class GPTConfig:
                                      # projected query and key (all heads'
                                      # columns together) before the heads
                                      # are split and rotated
+    qk_norm_per_head: bool = False   # EXAONE-4 family: RMSNorm over EACH
+                                     # head's columns of q and k (one scale
+                                     # vector of head_dim shared by the
+                                     # heads), after the split, before rotary
+    post_norm: bool = False          # EXAONE-4 family: no norm in front of a
+                                     # half; `x + norm(attn(x))`, then
+                                     # `h + norm(mlp(h))` (ln1/ln2 scale the
+                                     # halves' OUTPUTS)
     tie_embeddings: bool = True
     remat: bool = True               # jax.checkpoint each block
     remat_policy: str = "nothing_saveable"  # jax.checkpoint_policies name, or
@@ -149,7 +161,7 @@ class GPTConfig:
 
     @property
     def head_dim(self):
-        return self.d_model // self.n_head
+        return self.attn_head_dim or self.d_model // self.n_head
 
     @property
     def qkv_dim(self):
@@ -544,14 +556,26 @@ def _mlp(h, p, cfg, constrain=True):
     return _ckpt_name(up @ p["mlp_down_w"] + p["mlp_out_b"], "mlp_down")
 
 
-def _qk_norm(q, k, p, cfg: GPTConfig):
-    """`cfg.qk_norm`: RMSNorm over the whole projected query [.., H*hd] and
-    key [.., Hkv*hd], before the heads are split (one definition for the
-    training, prefill and paged halves)."""
-    if not cfg.qk_norm:
+def _qk_norm(q, k, p, cfg: GPTConfig, heads_split=False):
+    """RMSNorm of the query and the key over their last axis, at one of two
+    places (one definition for the training, prefill and paged halves):
+    `cfg.qk_norm` BEFORE the heads are split, over the whole projected query
+    [.., H*hd] and key [.., Hkv*hd]; `cfg.qk_norm_per_head` AFTER
+    (`heads_split`), over each head's columns of [.., H, hd] / [.., Hkv, hd]
+    with one scale vector [hd] for all heads."""
+    if not (cfg.qk_norm_per_head if heads_split else cfg.qk_norm):
         return q, k
     return (_norm(q, p["q_norm_scale"], None, True, cfg.norm_eps),
             _norm(k, p["k_norm_scale"], None, True, cfg.norm_eps))
+
+
+def _half_input(x, p, cfg: GPTConfig):
+    """What the attention half reads: ln1(x), or x itself under
+    `cfg.post_norm` (the norm then follows the half, `_residual_mlp`)."""
+    if cfg.post_norm:
+        return x
+    return _norm(x, p["ln1_scale"], p.get("ln1_bias"), cfg.use_rmsnorm,
+                 cfg.norm_eps)
 
 
 def _layer_local_flags(cfg: GPTConfig):
@@ -573,7 +597,7 @@ def _attn_half(x, p, cfg: GPTConfig, positions, attn_fn=None, constrain=True,
     B, T, D = x.shape
     H, Hkv, hd = cfg.n_head, cfg.n_kv_head, cfg.head_dim
 
-    h = _norm(x, p["ln1_scale"], p.get("ln1_bias"), cfg.use_rmsnorm, cfg.norm_eps)
+    h = _half_input(x, p, cfg)
     h = _act_quant(h, cfg)
     qkv = _ckpt_name(h @ p["attn_qkv_w"] + p["attn_qkv_b"], "qkv_proj")
     q, k, v = jnp.split(qkv, [H * hd, (H + Hkv) * hd], axis=-1)
@@ -581,6 +605,7 @@ def _attn_half(x, p, cfg: GPTConfig, positions, attn_fn=None, constrain=True,
     q = q.reshape(B, T, H, hd)
     k = k.reshape(B, T, Hkv, hd)
     v = v.reshape(B, T, Hkv, hd)
+    q, k = _qk_norm(q, k, p, cfg, heads_split=True)
     if constrain:
         # activations: heads on tensor axis (Megatron), seq on sequence axis
         q = shard_constraint(q, BATCH_AXES, SEQ_AXIS, TENSOR_AXIS, None)
@@ -602,7 +627,7 @@ def _attn_half(x, p, cfg: GPTConfig, positions, attn_fn=None, constrain=True,
     # alibi uses in-sequence distances (standard unpadded formulation)
     bias = _alibi_bias(cfg, t_pos, t_pos) if cfg.use_alibi else None
     attn = _attention(q, k, v, causal, cfg, attn_fn=attn_fn, bias=bias)
-    attn_flat = _act_quant(attn.reshape(B, T, D), cfg)
+    attn_flat = _act_quant(attn.reshape(B, T, H * hd), cfg)
     attn_out = _ckpt_name(
         attn_flat @ p["attn_out_w"] + p["attn_out_b"], "attn_out")
     return attn_out, k, v
@@ -613,6 +638,11 @@ def _residual_mlp(x, attn_out, p, cfg: GPTConfig, constrain=True, mlp_fn=None):
     if mlp_fn is None:
         mlp_fn = lambda h: _mlp(h, p, cfg, constrain)
     use_rms = cfg.use_rmsnorm
+    if cfg.post_norm:
+        x = x + _norm(attn_out, p["ln1_scale"], p.get("ln1_bias"), use_rms,
+                      cfg.norm_eps)
+        return x + _norm(mlp_fn(x), p["ln2_scale"], p.get("ln2_bias"),
+                         use_rms, cfg.norm_eps)
     if cfg.parallel_residual:
         # NeoX/GPT-J: both halves read the block INPUT (GPT-J ties ln2 == ln1)
         h2 = _norm(x, p["ln2_scale"], p.get("ln2_bias"), use_rms, cfg.norm_eps)
@@ -872,19 +902,28 @@ def _decode_qkv(x, p, positions, cfg: GPTConfig):
     act-quant gates, remat checkpoint names, and shard constraints.)"""
     B, C, _ = x.shape
     H, Hkv, hd = cfg.n_head, cfg.n_kv_head, cfg.head_dim
-    h = _norm(x, p["ln1_scale"], p.get("ln1_bias"), cfg.use_rmsnorm,
-              cfg.norm_eps)
+    h = _half_input(x, p, cfg)
     qkv = h @ p["attn_qkv_w"] + p["attn_qkv_b"]
     q, k, v = jnp.split(qkv, [H * hd, (H + Hkv) * hd], axis=-1)
     q, k = _qk_norm(q, k, p, cfg)
     q = q.reshape(B, C, H, hd)
     k = k.reshape(B, C, Hkv, hd)
     v = v.reshape(B, C, Hkv, hd)
+    q, k = _qk_norm(q, k, p, cfg, heads_split=True)
     if cfg.use_rotary:
         rd = int(cfg.rotary_pct * hd) // 2 * 2
         q = _rope(q, positions, rd, cfg.rope_theta)
         k = _rope(k, positions, rd, cfg.rope_theta)
     return q, k, v
+
+
+def _static_window(cfg: GPTConfig):
+    """The sliding window EVERY layer of `cfg` has, which the paged walks
+    take as a lower bound and a mask (None: no window, or a per-layer local
+    flag, which is traced and stays with the gather path)."""
+    if cfg.sliding_window and cfg.attn_layer_types is None:
+        return int(cfg.sliding_window)
+    return None
 
 
 def _decode_attn_site(cfg: GPTConfig, phase, C, M, kv_dtype="bfloat16",
@@ -893,9 +932,14 @@ def _decode_attn_site(cfg: GPTConfig, phase, C, M, kv_dtype="bfloat16",
     (`attn_dispatch.decode_kernel_wanted`) has ONE definition shared by the
     contiguous path (M = allocated cache length) and the paged path
     (M = table_width * block = the effective context)."""
+    # a paged walk takes a window that every call of the site has; the
+    # contiguous decode kernel takes none
+    window = _static_window(cfg) if phase != "decode" else None
     return attn_dispatch.AttnSite(
         phase=phase, q_len=C, kv_len=M, causal=True,
-        has_bias=cfg.use_alibi, has_window=bool(cfg.sliding_window),
+        has_bias=cfg.use_alibi,
+        has_window=bool(cfg.sliding_window) and window is None,
+        window=window or 0,
         scale_attn=cfg.scale_attn, kv_dtype=kv_dtype, block_size=block_size,
         pool_in_place=pool_in_place,
         mesh_axes=attn_dispatch.active_mesh_axes(),
@@ -1214,7 +1258,8 @@ def scan_paged(cfg: GPTConfig, blocks, x, pool, block_tables, positions,
         from deepspeed_tpu.ops.pallas.decode_attention import \
             paged_decode_work
         decode_work = paged_decode_work(block_tables, positions[:, 0],
-                                        pool["k"].shape[3])
+                                        pool["k"].shape[3],
+                                        window=_static_window(cfg))
 
     def layer(x, p, pool_l, flag, acc, layer_id, block_base=None):
         x, pool_l, *counts = block_fn(
@@ -1387,7 +1432,8 @@ def _paged_attn_half(x, p, pool_l, positions, block_tables,
     if runner is not None:
         with jax.named_scope("attn"):
             attn = runner(q, pool_l, block_tables, positions[:, 0],
-                          sm_scale=None if cfg.scale_attn else 1.0)
+                          sm_scale=None if cfg.scale_attn else 1.0,
+                          window=site.window or None)
     elif program == "paged_kernel_quant":
         from deepspeed_tpu.ops.pallas.decode_attention import \
             paged_decode_attention_quant
@@ -1396,7 +1442,8 @@ def _paged_attn_half(x, p, pool_l, positions, block_tables,
                 q[:, 0], pool_l["k"], pool_l["v"], pool_l["k_scale"],
                 pool_l["v_scale"], block_tables, positions[:, 0],
                 sm_scale=None if cfg.scale_attn else 1.0,
-                work=decode_work).reshape(B, 1, D)
+                work=decode_work,
+                window=site.window or None).reshape(B, 1, -1)
     elif program == "paged_kernel":
         from deepspeed_tpu.ops.pallas.decode_attention import \
             paged_decode_attention
@@ -1404,7 +1451,8 @@ def _paged_attn_half(x, p, pool_l, positions, block_tables,
             attn = paged_decode_attention(
                 q[:, 0], pool_l["k"], pool_l["v"], block_tables,
                 positions[:, 0], sm_scale=None if cfg.scale_attn else 1.0,
-                work=decode_work).reshape(B, 1, D)
+                work=decode_work,
+                window=site.window or None).reshape(B, 1, -1)
     elif program in ("paged_gather_quant", "paged_gather"):
         with jax.named_scope("kv_pool_read"):
             if program == "paged_gather_quant":

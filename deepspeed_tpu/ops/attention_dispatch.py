@@ -96,7 +96,13 @@ class AttnSite:
     kv_len: int                   # key/context length (T, M, or nb*block)
     causal: bool = True
     has_bias: bool = False        # additive bias (alibi) present
-    has_window: bool = False      # sliding-window / per-layer local mask
+    has_window: bool = False      # a window mask no kernel applies: any
+                                  # window outside the paged phases, and the
+                                  # per-layer local flag (traced) in them
+    window: int = 0               # paged phases: the site's STATIC sliding
+                                  # window (every call of it windowed), which
+                                  # the paged walks take as a lower bound and
+                                  # a mask; 0 = none
     scale_attn: bool = True       # False = unscaled scores (GPT-Neo)
     kv_dtype: str = "bfloat16"    # KV storage dtype ("int8" = quantized pool)
     block_size: int = 0           # paged pool physical block (paged phases)
@@ -128,9 +134,10 @@ class AttentionProgram:
     set, is the callable the site invokes without knowing the program: for
     the train phase the zoo-layout `(q, k, v, *, causal, sm_scale)` with
     [B, T, H, hd] operands and matched heads; for a paged phase
-    `(q, pool_l, block_tables, start, *, sm_scale)` with q [B, C, H, hd],
-    the float pool's leaves WHOLE, the tables in the pool's numbering and
-    each row's first position, returning [B, C, H*hd]. The other paged and
+    `(q, pool_l, block_tables, start, *, sm_scale, window)` with q
+    [B, C, H, hd], the float pool's leaves WHOLE, the tables in the pool's
+    numbering, each row's first position and the site's static window (None
+    = none), returning [B, C, H*hd]. The other paged and
     decode programs still dispatch by NAME and are invoked at the call site.
     `when` is the human-readable engage condition for `dispatch_table()`
     and docs/kernels.md."""
@@ -432,7 +439,8 @@ register_program(AttentionProgram(
     name="paged_kernel", phases=("paged_decode",), priority=50,
     matches=_paged_kernel_ok,
     when="C == 1, block % 128 == 0, effective context nb*block past the "
-         "decode crossover; no alibi/window"))
+         "decode crossover; no alibi, no per-layer local flag (a static "
+         "window is the walk's lower bound and a mask)"))
 
 
 
@@ -446,19 +454,21 @@ def _paged_prefill_ok(site):
             and site.block_size % 128 == 0 and site.q_len % 128 == 0)
 
 
-def _run_paged_prefill(q, pool_l, block_tables, start, *, sm_scale=None):
+def _run_paged_prefill(q, pool_l, block_tables, start, *, sm_scale=None,
+                       window=None):
     from deepspeed_tpu.ops.pallas.prefill_attention import \
         paged_prefill_attention
     return paged_prefill_attention(q, pool_l["k"], pool_l["v"], block_tables,
-                                   start, sm_scale=sm_scale)
+                                   start, sm_scale=sm_scale, window=window)
 
 
 register_program(AttentionProgram(
     name="paged_prefill_kernel", phases=("prefill_chunk",), priority=50,
     matches=_paged_prefill_ok,
     when="in-place pool form (float pool of whole tiles, TPU, single "
-         "device), C % 128 == 0, block % 128 == 0, no alibi/window: flash "
-         "walk over the blocks under the chunk's frontier "
+         "device), C % 128 == 0, block % 128 == 0, no alibi, no per-layer "
+         "local flag: flash walk over the blocks under the chunk's frontier "
+         "and, with a static window, from the block the window begins in "
          "(dstpu_paged_prefill)",
     runner=_run_paged_prefill))
 
@@ -476,4 +486,5 @@ register_program(AttentionProgram(
     when="fallback: table gather + dense attend over the whole table "
          "(the oracle; spec-decode verify, and chunked prefill wherever "
          "paged_prefill_kernel does not apply: the CPU, a mesh, hd 64, "
-         "alibi/window, a chunk or block off the lane tile)"))
+         "alibi, a per-layer local flag, a chunk or block off the lane "
+         "tile)"))

@@ -37,6 +37,7 @@ import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -72,7 +73,7 @@ def _online_softmax_update(s, v, in_dtype, acc_ref, m_ref, l_ref):
 
 
 def _online_softmax_tile(q, k, v, pos, j, acc_ref, m_ref, l_ref, *,
-                         sm_scale, block_m):
+                         sm_scale, block_m, window=None):
     """One streamed KV tile's online-softmax update — the SINGLE definition
     of the decode-attention math, shared by the contiguous, paged, and
     quantized-paged kernels (the dequantizing kernel hands in already-
@@ -81,7 +82,9 @@ def _online_softmax_tile(q, k, v, pos, j, acc_ref, m_ref, l_ref, *,
 
     q: [G, hd]; k/v: [block_m, hd] in the compute dtype; scratch as in
     `_online_softmax_update`, carried across the (sequential, innermost)
-    block axis.
+    block axis. `window` (static; None = none): keys more than `window - 1`
+    positions behind `pos` are masked too — the mask inside the first live
+    block of a windowed walk.
 
     native-dtype dots (fp32 accumulate via preferred_element_type):
     pre-casting K/V blocks to fp32 doubles the VMEM working set and VPU
@@ -90,7 +93,10 @@ def _online_softmax_tile(q, k, v, pos, j, acc_ref, m_ref, l_ref, *,
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * sm_scale
     k_pos = j * block_m + jax.lax.broadcasted_iota(jnp.int32, (G, block_m), 1)
-    s = jnp.where(k_pos <= pos, s, NEG_INF)
+    seen = k_pos <= pos
+    if window is not None:
+        seen = jnp.logical_and(seen, k_pos > pos - window)
+    s = jnp.where(seen, s, NEG_INF)
     _online_softmax_update(s, v, q.dtype, acc_ref, m_ref, l_ref)
 
 
@@ -216,28 +222,50 @@ PagedDecodeWork = collections.namedtuple("PagedDecodeWork", [
 ])
 
 
-def paged_decode_work(block_tables, pos, block_m):
+def window_first_block(pos, block_m, window):
+    """The first logical block a query at `pos` can see: 0 with no window,
+    else the block of position `pos - window + 1` (the LOWER bound of a
+    windowed walk; the decode kernel, the work list and the scheduler's
+    host twin share this one definition). Works on Python ints, numpy and
+    traced values alike."""
+    if not window:
+        return pos * 0
+    first = pos - (window - 1)
+    on_host = isinstance(first, (int, np.integer, np.ndarray))
+    return (np.maximum(first, 0) if on_host
+            else jnp.maximum(first, 0)) // block_m
+
+
+def paged_decode_work(block_tables, pos, block_m, window=None):
     """The walk's work list, from the tables as the scheduler builds them
-    (NOT offset to a layer's blocks: the list is the same for every layer,
-    so `models/gpt.py::scan_paged` builds it once a token, outside the layer
-    loop). A slot is dead when its whole table row is the trash block; a
-    live slot holds blocks 0 .. pos // block_m. Everything here has the
-    tables' size, nothing the pool's."""
+    (NOT offset to a layer's blocks: the list is the same for every layer of
+    one KIND, so `models/gpt.py::scan_paged` builds it once a token, outside
+    the layer loop). A slot is dead when its whole table row is the trash
+    block; a live slot holds blocks 0 .. pos // block_m, of which a layer
+    with a `window` visits those from `window_first_block` on. Everything
+    here has the tables' size, nothing the pool's."""
     from deepspeed_tpu.inference.kv_cache import TRASH_BLOCK
     B, nb = block_tables.shape
     # the scope names these operations in a compiled program: the guard of
     # tests/test_steptrace.py finds them by it, outside the layer loop
     with jax.named_scope("paged_decode_work"):
         live = jnp.any(block_tables != TRASH_BLOCK, axis=1)
-        blocks = jnp.where(
-            live, jnp.minimum(pos.astype(jnp.int32) // block_m + 1, nb), 0)
+        pos = pos.astype(jnp.int32)
+        if window is None:
+            blocks = jnp.where(live, jnp.minimum(pos // block_m + 1, nb), 0)
+        else:
+            first = window_first_block(pos, block_m, window)
+            blocks = jnp.where(live, jnp.maximum(
+                jnp.minimum(pos // block_m + 1, nb) - first, 0), 0)
         ends = jnp.cumsum(blocks)
         i = jnp.arange(B * nb, dtype=jnp.int32)
         slot = jnp.minimum(
             jnp.searchsorted(ends, i, side="right", method="compare_all"),
             B - 1).astype(jnp.int32)
-        block = jnp.clip(i - (ends - blocks)[slot], 0,
-                         nb - 1).astype(jnp.int32)
+        block = i - (ends - blocks)[slot]
+        if window is not None:
+            block = block + first[slot]
+        block = jnp.clip(block, 0, nb - 1).astype(jnp.int32)
         return PagedDecodeWork(ends[-1:].astype(jnp.int32), slot, block, live)
 
 
@@ -268,7 +296,8 @@ def _vmem_tile_bytes(rows, cols, dtype):
 
 
 def _paged_walk_kernel(cnt_ref, slot_ref, blk_ref, pos_ref, bt_ref, q_ref,
-                       *refs, load_head, sm_scale, block_m, last_block):
+                       *refs, load_head, sm_scale, block_m, last_block,
+                       window=None):
     # grid (head groups, work items); a step holds every pool leaf's
     # [1, heads, block_m, ...] tile of ONE live (slot, logical block) pair,
     # resolved to its physical block by the index map (so bt_ref is unused
@@ -282,7 +311,10 @@ def _paged_walk_kernel(cnt_ref, slot_ref, blk_ref, pos_ref, bt_ref, q_ref,
     j = blk_ref[i]
     pos = pos_ref[b]
 
-    @pl.when(j == 0)
+    # a slot's first pair: block 0, or the block its window begins in
+    @pl.when(j == (0 if window is None
+                   else jnp.minimum(window_first_block(pos, block_m, window),
+                                    last_block)))
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
@@ -295,7 +327,8 @@ def _paged_walk_kernel(cnt_ref, slot_ref, blk_ref, pos_ref, bt_ref, q_ref,
             k, v = load_head(pool_refs, h, q_ref.dtype)
             _online_softmax_tile(q_ref[0, h], k, v, pos, j, acc_ref.at[h],
                                  m_ref.at[h], l_ref.at[h],
-                                 sm_scale=sm_scale, block_m=block_m)
+                                 sm_scale=sm_scale, block_m=block_m,
+                                 window=window)
 
     @pl.when(j == jnp.minimum(pos // block_m, last_block))
     def _finish():
@@ -304,7 +337,7 @@ def _paged_walk_kernel(cnt_ref, slot_ref, blk_ref, pos_ref, bt_ref, q_ref,
 
 
 def _paged_walk(load_head, q, leaves, block_tables, pos, work, sm_scale,
-                interpret):
+                interpret, window=None):
     """THE walk over a paged pool, shared by the float and the int8 kernel:
     a 1-D list of the live (slot, logical block) pairs (`paged_decode_work`),
     its length the grid's DYNAMIC bound, so a dead slot and a block past a
@@ -325,7 +358,7 @@ def _paged_walk(load_head, q, leaves, block_tables, pos, work, sm_scale,
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(hd)
     if work is None:
-        work = paged_decode_work(block_tables, pos, block_m)
+        work = paged_decode_work(block_tables, pos, block_m, window)
     heads = _heads_per_step(Hkv, sum(
         _vmem_tile_bytes(block_m, x.shape[-1], x.dtype) for x in leaves))
 
@@ -338,7 +371,7 @@ def _paged_walk(load_head, q, leaves, block_tables, pos, work, sm_scale,
     out = pl.pallas_call(
         functools.partial(_paged_walk_kernel, load_head=load_head,
                           sm_scale=sm_scale, block_m=block_m,
-                          last_block=nb - 1),
+                          last_block=nb - 1, window=window),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=5,
             grid=(Hkv // heads, jnp.maximum(work.count[0], 1)),
@@ -367,7 +400,7 @@ def _load_float_head(pool_refs, h, dtype):
 
 
 def paged_decode_attention(q, k_pool, v_pool, block_tables, pos, sm_scale=None,
-                           interpret=None, work=None):
+                           interpret=None, work=None, window=None):
     """Decode attention over a PAGED KV pool (vLLM's PagedAttention layout).
 
     q: [B, H, hd]; k_pool/v_pool: [N, Hkv, block, hd] physical blocks shared
@@ -387,9 +420,16 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, pos, sm_scale=None,
     `work`: the `paged_decode_work` of the UN-offset tables, where the
     caller has it already (the layer scan builds it once a token; here
     `block_tables` may then be offset to one layer's blocks of a whole
-    stack); None builds it from `block_tables`."""
+    stack); None builds it from `block_tables`.
+
+    `window` (static int, None = none): sliding-window attention — the walk
+    starts at the block of position `pos - window + 1` instead of block 0
+    and masks what lies before that position inside it. The table may then
+    be a RING (`inference/kv_cache.py::ring_tables`: logical block j at
+    physical `j mod R` of the slot's ring): the blocks the walk visits are
+    distinct physical blocks as long as the ring covers the window."""
     return _paged_walk(_load_float_head, q, (k_pool, v_pool), block_tables,
-                       pos, work, sm_scale, interpret)
+                       pos, work, sm_scale, interpret, window or None)
 
 
 def _dequant_tile(q, scale, dtype):
@@ -428,7 +468,7 @@ def _load_quant_head(pool_refs, h, dtype):
 
 def paged_decode_attention_quant(q, k_pool, v_pool, k_scale, v_scale,
                                  block_tables, pos, sm_scale=None,
-                                 interpret=None, work=None):
+                                 interpret=None, work=None, window=None):
     """Decode attention over the INT8 paged pool: dequantize-inside-the-
     kernel PagedAttention.
 
@@ -444,7 +484,7 @@ def paged_decode_attention_quant(q, k_pool, v_pool, k_scale, v_scale,
     exists only tile-by-tile in VMEM."""
     return _paged_walk(_load_quant_head, q,
                        (k_pool, v_pool, k_scale, v_scale), block_tables, pos,
-                       work, sm_scale, interpret)
+                       work, sm_scale, interpret, window or None)
 
 
 def paged_decode_attention_quant_reference(q, pool_l, block_tables, pos,
@@ -460,17 +500,18 @@ def paged_decode_attention_quant_reference(q, pool_l, block_tables, pos,
 
 
 def paged_decode_attention_reference(q, k_pool, v_pool, block_tables, pos,
-                                     sm_scale=None):
+                                     sm_scale=None, window=None):
     """jnp oracle: gather each row's blocks into a contiguous cache (the
     SAME gather the XLA fallback path uses — one definition, so the oracle
     cannot silently diverge from production), then run the contiguous
     reference."""
     from deepspeed_tpu.inference.kv_cache import gather_block_kv
     k, v = gather_block_kv(k_pool, v_pool, block_tables)
-    return decode_attention_reference(q, k, v, pos, sm_scale=sm_scale)
+    return decode_attention_reference(q, k, v, pos, sm_scale=sm_scale,
+                                      window=window)
 
 
-def decode_attention_reference(q, k, v, pos, sm_scale=None):
+def decode_attention_reference(q, k, v, pos, sm_scale=None, window=None):
     """jnp reference (numerics oracle for tests)."""
     B, H, hd = q.shape
     _, Hkv, M, _ = k.shape
@@ -480,8 +521,11 @@ def decode_attention_reference(q, k, v, pos, sm_scale=None):
     qg = q.reshape(B, Hkv, G, hd)
     s = jnp.einsum("bkgd,bkmd->bkgm", qg.astype(jnp.float32),
                    k.astype(jnp.float32)) * sm_scale
-    valid = (jnp.arange(M)[None, :] <= pos[:, None])[:, None, None, :]
-    s = jnp.where(valid, s, NEG_INF)
+    k_pos = jnp.arange(M)[None, :]
+    valid = k_pos <= pos[:, None]
+    if window:
+        valid = valid & (k_pos > pos[:, None] - window)
+    s = jnp.where(valid[:, None, None, :], s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     out = jnp.einsum("bkgm,bkmd->bkgd", p, v.astype(jnp.float32))
     return out.reshape(B, H, hd).astype(q.dtype)

@@ -44,7 +44,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from deepspeed_tpu.ops.pallas.decode_attention import (NEG_INF, _LANES,
-                                                       _online_softmax_update)
+                                                       _online_softmax_update,
+                                                       window_first_block)
 from deepspeed_tpu.platform.device import pallas_interpret
 
 # What a grid step may hold in VMEM (tiles double-buffered by the pipeline,
@@ -93,25 +94,30 @@ def _tiles(C, block, Hkv, G, hd, itemsize):
 
 
 def _prefill_kernel(start_ref, bt_ref, q_ref, k_ref, v_ref, o_ref, acc_ref,
-                    m_ref, l_ref, *, sm_scale, G, block, tk, last_block):
+                    m_ref, l_ref, *, sm_scale, G, block, tk, last_block,
+                    window=None):
     # grid (B, Hkv // heads, C // tq, live blocks); q_ref / o_ref:
     # [1, tq, heads*G*hd], the step's query heads side by side in the lanes;
     # k_ref / v_ref: [1, heads, block, hd], ONE logical block of the row,
     # resolved to its physical block by the index map (so bt_ref is unused
     # here); scratch acc [heads*G, tq, hd] fp32, m/l [heads*G, tq, _LANES]
     # fp32 carry the online softmax over the row's blocks, innermost and
-    # ascending.
+    # ascending. With a `window` the KV axis counts from the block the
+    # tile's first row's window begins in (`window_first_block`), not from 0.
     del bt_ref
     b = pl.program_id(0)
     qi = pl.program_id(2)
-    j = pl.program_id(3)
     tq = q_ref.shape[1]
     heads, hd = k_ref.shape[1], k_ref.shape[3]
     q_lo = start_ref[b] + qi * tq           # this tile's first position
     q_hi = q_lo + tq - 1
     frontier = jnp.minimum(q_hi // block, last_block)
+    j = pl.program_id(3)                    # the logical block of this step
+    first_step = j == 0
+    if window is not None:
+        j = j + window_first_block(q_lo, block, window)
 
-    @pl.when(j == 0)
+    @pl.when(first_step)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
@@ -136,7 +142,10 @@ def _prefill_kernel(start_ref, bt_ref, q_ref, k_ref, v_ref, o_ref, acc_ref,
                 q, k, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32) * sm_scale
             if masked:
-                s = jnp.where(ahead <= 0, s, NEG_INF)
+                seen = ahead <= 0
+                if window is not None:
+                    seen = jnp.logical_and(seen, ahead > -window)
+                s = jnp.where(seen, s, NEG_INF)
             _online_softmax_update(s, v, q.dtype, acc_ref.at[i],
                                    m_ref.at[i], l_ref.at[i])
 
@@ -147,6 +156,12 @@ def _prefill_kernel(start_ref, bt_ref, q_ref, k_ref, v_ref, o_ref, acc_ref,
         k_lo = j * block + t * tk
         live = jnp.logical_and(j <= frontier, k_lo <= q_hi)
         diagonal = k_lo + tk - 1 > q_lo
+        if window is not None:
+            # ... and while the tile's first row can still see its last key;
+            # the mask is needed too where the tile's LAST row cannot see
+            # its first key
+            live = jnp.logical_and(live, k_lo + tk - 1 > q_lo - window)
+            diagonal = jnp.logical_or(diagonal, k_lo <= q_hi - window)
         pl.when(jnp.logical_and(live, diagonal))(
             functools.partial(update, t, True))
         pl.when(jnp.logical_and(live, jnp.logical_not(diagonal)))(
@@ -160,16 +175,18 @@ def _prefill_kernel(start_ref, bt_ref, q_ref, k_ref, v_ref, o_ref, acc_ref,
                 (acc_ref[i] / l_safe).astype(o_ref.dtype)
 
 
-def paged_prefill_live_blocks(start, chunk, block, table_blocks):
+def paged_prefill_live_blocks(start, chunk, block, table_blocks, window=None):
     """Host twin of the walk's grid bound for ONE row: the logical blocks a
     chunk of `chunk` rows starting at `start` attends (the scheduler's
-    `StepRecord.prefill_live_blocks`), of `table_blocks` in its table."""
+    `StepRecord.prefill_live_blocks`), of `table_blocks` in its table; with
+    a `window`, those from the block the first row's window begins in."""
     return min((int(start) + int(chunk) - 1) // int(block) + 1,
-               int(table_blocks))
+               int(table_blocks)) \
+        - int(window_first_block(int(start), int(block), window))
 
 
 def paged_prefill_attention(q, k_pool, v_pool, block_tables, start,
-                            sm_scale=None, interpret=None):
+                            sm_scale=None, interpret=None, window=None):
     """Causal attention of a prefill chunk over a PAGED KV pool, the live
     blocks only.
 
@@ -184,7 +201,13 @@ def paged_prefill_attention(q, k_pool, v_pool, block_tables, start,
 
     Row b reads logical blocks 0 .. (start[b] + C - 1) // block of its table
     and no other: entries past them may hold anything (the trash block, a
-    stale id)."""
+    stale id).
+
+    `window` (static int, None = none): sliding-window attention, `i - j <
+    window` — what `_paged_attend` gives with `cfg.sliding_window`. A query
+    tile's walk then starts at the block its first row's window begins in
+    (the KV axis is a STATIC few blocks long), and the table may be a ring
+    (`inference/kv_cache.py::ring_tables`) that covers window + chunk."""
     if interpret is None:
         interpret = pallas_interpret()
     B, C, H, hd = q.shape
@@ -197,9 +220,15 @@ def paged_prefill_attention(q, k_pool, v_pool, block_tables, start,
     tq, tk, heads = _tiles(C, block, Hkv, G, hd, q.dtype.itemsize)
     width = heads * G * hd
 
+    window = window or None
     start = start.astype(jnp.int32)
-    # the furthest frontier of the call: the KV axis ends there
-    live_blocks = jnp.minimum((jnp.max(start) + C - 1) // block + 1, nb)
+    if window is None:
+        # the furthest frontier of the call: the KV axis ends there
+        live_blocks = jnp.minimum((jnp.max(start) + C - 1) // block + 1, nb)
+    else:
+        # the blocks a tile's rows can see between them: window + tile
+        # positions, starting anywhere in a block
+        live_blocks = min((window + tq - 2) // block + 2, nb)
 
     def q_index(b, g, qi, j, start_ref, bt_ref):
         return (b, qi, g)
@@ -208,11 +237,14 @@ def paged_prefill_attention(q, k_pool, v_pool, block_tables, start,
         # the table is read in SMEM, where nothing checks the index
         frontier = jnp.minimum((start_ref[b] + (qi + 1) * tq - 1) // block,
                                nb - 1)
+        if window is not None:
+            j = j + window_first_block(start_ref[b] + qi * tq, block, window)
         return (bt_ref[b, jnp.minimum(j, frontier)], g, 0, 0)
 
     return pl.pallas_call(
         functools.partial(_prefill_kernel, sm_scale=sm_scale, G=G,
-                          block=block, tk=tk, last_block=nb - 1),
+                          block=block, tk=tk, last_block=nb - 1,
+                          window=window),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(B, Hkv // heads, C // tq, live_blocks),

@@ -339,29 +339,55 @@ def dropless_moe(flat, gate_w, ffn_fn, num_experts, *, interpret=None):
 # scheduler sums them over layers and steps (`ServingEngine.stats()["moe"]`)
 ROUTED_COUNTERS = ("moe_router_calls", "moe_assignments",
                    "moe_active_experts", "moe_max_expert_load")
+# ... of an expert layer that holds a share of its experts (`held=`): the
+# three after the first count the HELD experts' rows, what `dstpu_moe_gmm`
+# multiplies; the fifth the assignments whose expert lives on another chip
+HELD_ROUTED_COUNTERS = ROUTED_COUNTERS + ("moe_routed_elsewhere",)
 
 
-def topk_routing(x, gate_w, top_k, normalize=False):
-    """Softmax router without capacity: x [N, D] -> (probabilities [N, k]
-    float32, experts [N, k] int32), the k largest by `lax.top_k`.
+def topk_routing(x, gate_w, top_k, normalize=False, scoring="softmax",
+                 bias=None, scale=None):
+    """The router without capacity: x [N, D] -> (weights [N, k] float32,
+    experts [N, k] int32).
 
-    Logits (float32 accumulation) and softmax are float32 whatever `x` is.
-    `normalize=False` (OLMoE's `norm_topk_prob: false`) uses the k
-    probabilities as they are; True rescales them to sum to one. A token's
-    routing depends on that token alone: any batching or chunking of the
-    same tokens routes them the same way."""
+    Logits (float32 accumulation) and scores are float32 whatever `x` is.
+    `scoring` "softmax" (OLMoE): the k largest probabilities by `lax.top_k`;
+    `normalize=False` (`norm_topk_prob: false`) uses them as they are, True
+    rescales them to sum to one. `scoring` "sigmoid" (the DeepSeek-V3
+    router, K-EXAONE's): scores are `sigmoid(logits)`, the k experts are
+    those with the largest `score + bias` (`e_score_correction_bias`, [E]
+    float32; it moves the CHOICE and never the weight), the weights are the
+    chosen experts' scores, under `normalize` divided by their sum + 1e-20,
+    then times `scale` (`routed_scaling_factor`). A token's routing depends
+    on that token alone: any batching or chunking of the same tokens routes
+    them the same way."""
     with jax.named_scope("moe/router"):
         logits = jnp.dot(x, gate_w.astype(x.dtype),
                          preferred_element_type=jnp.float32)
-        probs = jax.nn.softmax(logits, axis=-1)
-        top_p, top_e = jax.lax.top_k(probs, top_k)
-        if normalize:
-            top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+        if scoring == "softmax":
+            probs = jax.nn.softmax(logits, axis=-1)
+            top_p, top_e = jax.lax.top_k(probs, top_k)
+            if normalize:
+                top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+        elif scoring == "sigmoid":
+            scores = jax.nn.sigmoid(logits)
+            choice = scores if bias is None else \
+                scores + bias.astype(jnp.float32)
+            _, top_e = jax.lax.top_k(choice, top_k)
+            top_p = jnp.take_along_axis(scores, top_e, axis=-1)
+            if normalize:
+                top_p = top_p / (jnp.sum(top_p, axis=-1, keepdims=True)
+                                 + 1e-20)
+        else:
+            raise ValueError(f"unknown router scoring {scoring!r} "
+                             f"(expected 'softmax' or 'sigmoid')")
+        if scale is not None:
+            top_p = top_p * scale
     return top_p, top_e.astype(jnp.int32)
 
 
 def routed_experts(x, top_p, top_e, experts, activation=None,
-                   num_experts=None, expert_base=0):
+                   num_experts=None, expert_base=0, held=None):
     """The expert MLPs of N tokens on their k chosen experts each, with no
     capacity and no dropped token: x [N, D] -> (out [N, D] in `x.dtype`,
     counters int32[4] in `ROUTED_COUNTERS` order).
@@ -380,17 +406,34 @@ def routed_experts(x, top_p, top_e, experts, activation=None,
     dimension may be a longer stack (every layer's experts, `[L * E, ...]`):
     then `num_experts` is E and `expert_base` (traced: `layer * E`) is where
     this layer's begin — the whole stack goes to the kernel, nothing is
-    sliced out of it."""
+    sliced out of it.
+
+    `held=(first, count)`: this chip's share of an expert-parallel layer.
+    The router chose among ALL its experts; the weights here are those of
+    experts `first .. first + count - 1` (`count` a layer in a stack). The
+    held experts' rows sort to the front, in expert order, and are the only
+    rows multiplied (`moe_gmm` leaves rows past `sum(group_sizes)` alone);
+    an assignment to an expert that lives elsewhere contributes ZERO — what
+    that expert would add is the other chip's part of the sum — and is
+    counted (`HELD_ROUTED_COUNTERS`, five counters). No exchange, and
+    nothing that stands in for one."""
     from deepspeed_tpu.ops.pallas.moe_gmm import moe_gmm
 
     N, D = x.shape
     k = top_e.shape[1]
     M = N * k
     gated = "w_gate_up" in experts
-    E = num_experts or experts["w_down"].shape[0]
     gmm = lambda rows, w: moe_gmm(rows, w, sizes, expert_base)
     with jax.named_scope("moe/dispatch"):
         flat_e = top_e.reshape(M)
+        if held is None:
+            E = num_experts or experts["w_down"].shape[0]
+        else:
+            first, E = held
+            local = flat_e - first
+            # the sort key: the held experts by their place here, every
+            # other assignment after them all
+            flat_e = jnp.where((local >= 0) & (local < E), local, E)
         order = jnp.argsort(flat_e, stable=True)      # sorted row -> assignment
         sizes = jnp.sum(flat_e[:, None] == jnp.arange(E, dtype=jnp.int32),
                         axis=0, dtype=jnp.int32)
@@ -409,10 +452,19 @@ def routed_experts(x, top_p, top_e, experts, activation=None,
         back = jnp.zeros((M,), jnp.int32).at[order].set(
             jnp.arange(M, dtype=jnp.int32))           # assignment -> sorted row
         y = jnp.take(y, back, axis=0).reshape(N, k, D).astype(jnp.float32)
+        if held is not None:
+            # rows past the held ones are memory nobody wrote
+            y = jnp.where((flat_e < E).reshape(N, k, 1), y, 0.0)
         out = jnp.sum(y * top_p[:, :, None], axis=1).astype(x.dtype)
-    counters = jnp.stack([jnp.int32(1), jnp.int32(M),
-                          jnp.sum(sizes > 0, dtype=jnp.int32),
-                          jnp.max(sizes)])
+    if held is None:
+        counters = jnp.stack([jnp.int32(1), jnp.int32(M),
+                              jnp.sum(sizes > 0, dtype=jnp.int32),
+                              jnp.max(sizes)])
+    else:
+        here = jnp.sum(sizes)
+        counters = jnp.stack([jnp.int32(1), here,
+                              jnp.sum(sizes > 0, dtype=jnp.int32),
+                              jnp.max(sizes), jnp.int32(M) - here])
     return out, counters
 
 

@@ -66,7 +66,16 @@ StepRecord = collections.namedtuple("StepRecord", [
     "prefill_table_blocks",  # ... of the blocks in those chunks' tables
                         # (what the gather path attends); both 0 where the
                         # prefill program built is not that kernel
-], defaults=(0, 0, 0, 0, 0, 0, "", 0, (), 0, 0, 0, 0))
+    "decode_window_live_blocks",   # a pool of two kinds (window layers
+                        # beside full ones): the (slot, block) pairs a
+                        # WINDOW layer's decode walk visits, in the window
+                        # kind's blocks — from the block the window begins
+                        # in to the one `pos` is in ...
+    "decode_window_table_blocks",  # ... of the pairs the same slots' walk
+                        # would visit with no window (pos // block + 1)
+    "prefill_window_live_blocks",  # the same two for this step's prefill
+    "prefill_window_table_blocks",  # chunks; all four 0 for a one-kind pool
+], defaults=(0, 0, 0, 0, 0, 0, "", 0, (), 0, 0, 0, 0, 0, 0, 0, 0))
 
 RequestRecord = collections.namedtuple("RequestRecord", [
     "uid", "t_submit", "t_admit",

@@ -845,3 +845,134 @@ def test_routed_paged_programs_hold_nothing_of_the_pools_or_experts_size(
     # a token, and not in the layer loop's inside it
     _assert_decode_walk_is_built_once_a_token(programs["decode"].as_text())
     assert "dstpu_kv_pool_gather" in programs["prefill"].as_text()
+
+
+# ----------------------------------------------------------------------
+# a pool of TWO KINDS (models/exaone_moe.py): the same guard, both kinds
+# ----------------------------------------------------------------------
+
+
+def _compile_two_kind_paged_programs(one_chip, window):
+    """The paged prefill program and the decode WINDOW program of a
+    K-EXAONE-shaped model (a dense window layer, then two periods of window,
+    window, window, full with 8 of 32 experts held) at the served tile
+    widths: full-kind blocks of 512, window-kind rings of 128-token blocks."""
+    from deepspeed_tpu.inference.kv_cache import ring_blocks
+    from deepspeed_tpu.models import exaone_moe as em
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    layers = (em.WINDOW,) + (em.WINDOW, em.WINDOW, em.WINDOW, em.FULL) * 2
+    cfg = em.ExaoneMoEConfig(
+        vocab_size=512, n_layer=len(layers), n_head=16, n_kv_head=8,
+        d_model=1024, attn_head_dim=128, d_ff=1024, d_ff_dense=2048,
+        max_seq_len=8192, sliding_window=128, tie_embeddings=False,
+        num_experts=32, experts_held=(8, 8), top_k=4, norm_topk_prob=True,
+        routed_scaling_factor=2.5, layer_types=layers,
+        mlp_layer_types=(em.DENSE,) + (em.SPARSE,) * 8, pattern_period=4,
+        window_block=128, use_flash_attention=True, dtype=jnp.bfloat16,
+        remat=False)
+    shapes = jax.eval_shape(em.exaone_moe_init_fn(cfg, dtype=jnp.bfloat16),
+                            jax.random.PRNGKey(0))
+    spec = em.make_exaone_moe_decode_model(cfg, name="guard", params=shapes)
+    params = jax.tree_util.tree_map(lambda a: sds(a.shape, a.dtype), shapes)
+    # pools large beside the weights: at this width XLA prefetches whole
+    # weight leaves (16 MiB) into fast memory, which is not what is guarded
+    slots, chunk = 64, 512
+    ring = ring_blocks(128, 128, chunk, window)
+    pool = jax.tree_util.tree_map(
+        lambda a: sds(a.shape, a.dtype),
+        jax.eval_shape(lambda: spec.init_paged_pool(
+            128, 512, jnp.bfloat16, window_blocks=1 + slots * ring)))
+    # one LAYER's leaf of each kind, in bytes
+    leaves = {k: v.size // v.shape[0] * v.dtype.itemsize
+              for k, v in pool.items()}
+    i32 = jnp.int32
+    n_counters = len(spec.step_counters)
+
+    def decode_window(params, tok, pos, pool, tables):
+        def body(carry, _):
+            tok, pos, pool, acc = carry
+            logits, pool, counts = spec.decode_paged_fn(params, tok, pos,
+                                                        pool, tables)
+            nxt = jnp.argmax(logits, -1).astype(i32)
+            return (nxt, pos + 1, pool, acc + counts), nxt
+        (_, _, pool, acc), toks = jax.lax.scan(
+            body, (tok, pos, pool, jnp.zeros((n_counters,), i32)), None,
+            length=window)
+        return (toks, acc), pool
+
+    decode = jax.jit(decode_window, donate_argnums=(3,)).lower(
+        params, sds((slots,), i32), sds((slots,), i32), pool,
+        (sds((slots, 16), i32), sds((slots, 64), i32))).compile()
+    prefill = jax.jit(spec.prefill_paged_fn, donate_argnums=(4,)).lower(
+        params, sds((1, chunk), i32), sds((1,), i32), sds((1,), i32), pool,
+        (sds((1, 16), i32), sds((1, 64), i32))).compile()
+    return {"decode": decode, "prefill": prefill}, leaves, \
+        dict(spec.kv_pool_writers), dict(spec.paged_attn_programs)
+
+
+def test_two_kind_paged_programs_hold_nothing_of_either_pools_size(
+        one_chip, monkeypatch):
+    """PR 25's guard for a pool of two kinds, by BYTES and by halves: in the
+    prologue and inside the period scan alike, nothing HALF as large as one
+    layer's leaf of the smaller kind is made by an operation that is not an
+    aliased Mosaic call (`dstpu_kv_pool_write`, on either kind), and the program's
+    temporaries stay under that size too; the decode walks' two work lists
+    (one a kind) are built once a token, outside the period scan."""
+    from deepspeed_tpu.ops import attention_dispatch
+    from deepspeed_tpu.platform import device
+    mesh_mod.clear_mesh()
+    monkeypatch.setattr(device, "on_tpu", lambda: True)
+
+    programs, leaves, writers, attention = \
+        _compile_two_kind_paged_programs(one_chip, window=4)
+    assert writers == {"paged_decode": attention_dispatch.KV_POOL_WRITE_KERNEL,
+                       "prefill_chunk": attention_dispatch.KV_POOL_WRITE_KERNEL}
+    assert attention == {"paged_decode": "paged_kernel",
+                         "prefill_chunk": "paged_prefill_kernel"}
+    half = min(leaves.values()) // 2
+    for name, program in programs.items():
+        text = program.as_text()
+        large = _large_instructions(text, half)
+        assert [x for x in large if x[1] not in _NO_NEW_BUFFER] == [], name
+        calls = {n.rsplit(".", 1)[0] for n, opcode in large
+                 if opcode == "custom-call"}
+        assert calls == {"dstpu_kv_pool_write"}, (name, calls)
+        assert program.memory_analysis().temp_size_in_bytes < half, name
+        assert "dstpu_kv_pool_gather" not in text, name
+        assert "dstpu_moe_gmm" in text, name
+    assert "dstpu_paged_prefill" in programs["prefill"].as_text()
+
+    # the decode walks: in the token loop's body (the prologue's layer) and
+    # in the period scan's body inside it; the work lists in neither scan
+    # body's reach but the token loop's own
+    comps = _computations(programs["decode"].as_text())
+    holders = [name for name, lines in comps.items()
+               if any("dstpu_paged_decode" in l and "custom-call(" in l
+                      for l in lines)]
+    assert len(holders) == 2, holders
+
+    def reach(body):
+        reached, todo = set(), [body]
+        while todo:
+            name = todo.pop()
+            if name in reached or name not in comps:
+                continue
+            reached.add(name)
+            for l in comps[name]:
+                todo += re.findall(r"(?:calls|to_apply|body|condition)=%?"
+                                   r"([\w.\-]+)", l)
+        return reached
+
+    inner = [h for h in holders
+             if any(h in reach(o) for o in holders if o != h)]
+    assert len(inner) == 1, (holders, inner)
+    work = [name for name, lines in comps.items()
+            if any("paged_decode_work" in l for l in lines)]
+    assert work and not set(work) & reach(inner[0]), work
+    # four walks a period in the scan's body, one in the prologue
+    walks = {h: sum("dstpu_paged_decode" in l and "custom-call(" in l
+                    for l in comps[h]) for h in holders}
+    assert sorted(walks.values()) == [1, 4], walks
