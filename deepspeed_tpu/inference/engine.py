@@ -97,6 +97,15 @@ class DecodeModelSpec:
     #     final prompt token on the last chunk; ignored on earlier chunks)
     #   decode_paged_fn(params, token[B], pos[B], pool, block_tables[B,nb])
     #       -> (logits[B,V], pool)
+    #   mixed_paged_fn(params, chunk_tokens[1,C], start_pos[1], last_idx[1],
+    #                  chunk_table[1,nb], token[S], pos[S], pool,
+    #                  block_tables[S,nb]) -> (logits[1+S,V], pool)
+    #     a prefill chunk AND a decode token of every slot in one call, all
+    #     their rows through every weight as ONE tensor (the scheduler's
+    #     `mixed_step`: a step's chunk rides its decode call, and the weights
+    #     are read once where the two programs read them twice); logits of
+    #     the chunk's last_idx row, then the slots'. The chunk's slot is not
+    #     among the decoding ones. None: chunks and decode stay two calls.
     #   init_paged_pool(num_blocks, block_size, dtype[, kv_group_size])
     #       -> pool pytree. dtype int8 selects the QUANTIZED pool: the
     #     k/v payload leaves stay [L, N, Hkv, block, hd] but int8, and the
@@ -114,16 +123,19 @@ class DecodeModelSpec:
     #     prefill machinery as prefill_paged_fn, at an arbitrary cursor.
     prefill_paged_fn: Optional[Callable] = None
     decode_paged_fn: Optional[Callable] = None
+    mixed_paged_fn: Optional[Callable] = None
     verify_paged_fn: Optional[Callable] = None
     init_paged_pool: Optional[Callable] = None
-    # dispatch phase ("paged_decode" | "prefill_chunk" | "verify") -> the
-    # writer that paged program was TRACED with (`attention_dispatch.
+    # dispatch phase ("paged_decode" | "prefill_chunk" | "verify" | "mixed")
+    # -> the writer that paged program was TRACED with (`attention_dispatch.
     # kv_pool_writer`'s names), filled in by the model as each program is
     # traced. None: the model writes with the XLA scatter throughout.
     kv_pool_writers: Optional[Dict[str, str]] = None
     # the same phases -> the attention program each was traced with
     # (`attention_dispatch`'s registry names: "paged_prefill_kernel",
-    # "paged_kernel", "paged_gather", ...). None: the model keeps no record.
+    # "paged_kernel", "paged_gather", ...; the mixed program's two groups
+    # under "mixed/prefill_chunk" and "mixed/paged_decode"). None: the model
+    # keeps no record.
     paged_attn_programs: Optional[Dict[str, str]] = None
     # names of the int32 counters the paged programs return as a THIRD
     # result, `(logits, pool, counts[len(step_counters)])`, summed over the

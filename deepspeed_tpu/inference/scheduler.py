@@ -4,7 +4,7 @@ The Orca insight, TPU-style: a static-batch `generate()` call stalls its
 whole batch on the slowest sequence and pays one XLA compile per request
 shape. This scheduler instead owns `max_slots` fixed sequence slots and ONE
 paged KV pool (`inference/kv_cache.py`), and drives every request through
-two persistent jitted programs whose shapes never change:
+persistent jitted programs whose shapes never change:
 
   * `prefill_step` — [1, chunk] slice of a prompt: chunked prefill writes
     the chunk's K/V through the slot's block table and interleaves with
@@ -12,9 +12,14 @@ two persistent jitted programs whose shapes never change:
     arriving prompt can impose on the running batch);
   * `decode_step` — one token for ALL slots at once: inactive slots ride
     along pointed at the trash block, so slot liveness never changes the
-    program shape.
+    program shape;
+  * `mixed_step` — the two as ONE call: a step that has a chunk due and
+    slots already decoding sends the chunk's rows and the slots' rows
+    through the model as one tensor, so every weight is read once where the
+    two calls read it twice (`_chunks_riding` is the whole rule; steps that
+    hold one kind of work run the program of that kind).
 
-Iteration-level scheduling happens between the two calls, on the host, in
+Iteration-level scheduling happens between the calls, on the host, in
 plain Python: admit queued requests into freed slots (admission is a
 free-list pop — all-or-nothing, so a too-big request waits instead of
 half-occupying the pool), retire sequences the step they emit EOS, free
@@ -32,7 +37,7 @@ prompt's hash chain is matched against previously written full blocks, hit
 blocks are mapped into the new slot's table with a refcount bump, and the
 chunked-prefill cursor starts at the cached boundary — a shared system
 prompt prefills once per engine, not once per request. Only host-side state
-changes; the two compiled programs and their shapes are untouched.
+changes; the compiled programs and their shapes are untouched.
 
 Speculative decoding (`serving.spec_decode`, `inference/spec_decode.py`)
 swaps the decode step for a draft+verify loop: a drafter (model-free n-gram
@@ -511,6 +516,8 @@ class ServingEngine:
         self.steps = 0
         self.decode_steps = 0
         self.prefill_chunks = 0
+        self.fused_chunks = 0               # of them, chunks that rode a decode
+                                            # call (`mixed_step`)
         self.prefill_chunks_skipped = 0     # chunks the prefix cache elided
         self.prefix_hit_blocks = 0
         self.prefix_hit_tokens = 0
@@ -607,6 +614,69 @@ class ServingEngine:
             tok = sample(logits, rng)
             return ((tok, counts[0]) if counted else tok), pool
 
+        mixed_paged = getattr(spec, "mixed_paged_fn", None)
+        if mixed_paged is not None:
+            mixed_paged = self.engine._fn_transform(mixed_paged)
+        window = self.window
+
+        def mixed_step(params, chunks, starts, lasts, chunk_tables, n, tok,
+                       pos, pool, tables, rng):
+            """The MIXED program: a decode window whose first `n` tokens each
+            carry a prefill chunk through the model with them
+            (`DecodeModelSpec.mixed_paged_fn`: the chunk's rows and the
+            slots' rows as one tensor, every weight read once), and whose
+            other tokens are plain decode tokens. `chunks` [W, 1, C],
+            `starts` / `lasts` [W, 1] and `chunk_tables` [W, 1, nb] hold a
+            chunk a window position, of which the first `n` (traced, 1..W)
+            are real: two loops with dynamic bounds over one carried pool,
+            so ONE compile serves every count. Returns ((first tokens [W]:
+            what each chunk's last row sampled, window tokens [S, W]) and
+            a counted model's counters), pool."""
+
+            def ride(i, tok, pos, pool, rng):
+                def at(a):
+                    return jax.lax.dynamic_index_in_dim(a, i, 0,
+                                                        keepdims=False)
+                logits, pool, *counts = mixed_paged(
+                    params, at(chunks), at(starts), at(lasts),
+                    jax.tree_util.tree_map(at, chunk_tables), tok, pos, pool,
+                    tables)
+                sampled = sample(logits, rng)
+                return sampled[0], sampled[1:], pool, counts
+
+            if window == 1:     # as `decode_step`: no loop around one token
+                first, nxt, pool, counts = ride(0, tok, pos, pool, rng)
+                toks = (first[None], nxt[:, None])
+                return ((toks, counts[0]) if counted else toks), pool
+
+            def body(i, carry, riding):
+                tok, pos, pool, rng, acc, first, toks = carry
+                rng, sub = jax.random.split(rng)
+                if riding:
+                    head, nxt, pool, counts = ride(i, tok, pos, pool, sub)
+                    first = first.at[i].set(head)
+                else:
+                    logits, pool, *counts = decode_paged(params, tok, pos,
+                                                         pool, tables)
+                    nxt = sample(logits, sub)
+                if counted:
+                    acc = acc + counts[0]
+                return (nxt, pos + 1, pool, rng, acc, first,
+                        toks.at[:, i].set(nxt))
+
+            acc = jnp.zeros((len(self.step_counter_names),), jnp.int32) \
+                if counted else None
+            carry = (tok, pos, pool, rng, acc,
+                     jnp.zeros((window,), jnp.int32),
+                     jnp.zeros((tok.shape[0], window), jnp.int32))
+            carry = jax.lax.fori_loop(
+                0, n, lambda i, c: body(i, c, True), carry)
+            carry = jax.lax.fori_loop(
+                n, window, lambda i, c: body(i, c, False), carry)
+            _, _, pool, _, acc, first, toks = carry
+            toks = (first, toks)
+            return ((toks, acc) if counted else toks), pool
+
         # the pool is donated: the update is in-place in HBM, the old buffer
         # is dead the moment the step returns the new one. The compile
         # watchdog (telemetry/flight_recorder.py) wraps each program when
@@ -619,6 +689,13 @@ class ServingEngine:
             "decode_step", jax.jit(decode_step, donate_argnums=(3,)))
         self._prefill_step = wd.wrap(
             "prefill_step", jax.jit(prefill_step, donate_argnums=(4,)))
+        # a step's chunks ride its decode call where the model can run the
+        # two as one (`_chunks_riding` says when); spec decode has no decode
+        # call to ride
+        self._mixed_step = None
+        if mixed_paged is not None and not self.spec_on:
+            self._mixed_step = wd.wrap(
+                "mixed_step", jax.jit(mixed_step, donate_argnums=(8,)))
 
         self._verify_step = None
         if self.spec_on:
@@ -733,7 +810,7 @@ class ServingEngine:
 
         self._prefill_step = prefill_step
         self._decode_step = decode_step
-        self._verify_step = None
+        self._verify_step = self._mixed_step = None
 
     def _degraded_decode_step(self):
         """The 1-step decode program, built lazily the first time a
@@ -1538,7 +1615,8 @@ class ServingEngine:
                 self.telemetry.inc("serving/spec_verify_steps")
 
     # ------------------------------------------------------------------
-    # the engine step: admit -> prefill chunk(s) -> decode all slots
+    # the engine step: admit -> prefill chunk(s) -> decode all slots (the
+    # step's last chunks riding the decode call where slots decode already)
     # ------------------------------------------------------------------
 
     def step(self) -> List[CompletedRequest]:
@@ -1556,12 +1634,21 @@ class ServingEngine:
             raise
 
     def _step_impl(self) -> List[CompletedRequest]:
+        """Admit, prefill up to `prefill_budget` chunks, decode every slot.
+        The device calls of a step: each chunk a `prefill_step` call and then
+        one `decode_step` call — or, where slots were decoding as the step
+        began, the last min(chunks due, window) chunks and the decode window
+        as ONE `mixed_step` call (`_chunks_riding`, `_mixed_window`), the
+        chunks before them as their own calls first. One blocking read-back
+        a decode or mixed call, one for a prompt's last chunk where that was
+        a call of its own."""
         finished: List[CompletedRequest] = []
         self.steps += 1
         params = self.engine.params
         # the step timeline: phases tile the step (admit, each prefill
-        # chunk, decode_build, decode_window or draft + verify, emit,
-        # housekeeping); dispatched()/ready() bracket the device calls
+        # chunk that is a call of its own, decode_build, decode_window — the
+        # decode call, with or without chunks riding it — or draft + verify,
+        # emit, housekeeping); dispatched()/ready() bracket the device calls
         st = self.steptrace
         st.begin_step()
         compiled0 = self._compiled_programs()
@@ -1575,23 +1662,29 @@ class ServingEngine:
             admitted, blocked_on = self._admit(finished)
 
         # chunked prefill, bounded per step so arriving prompts cannot stall
-        # the running batch for more than prefill_budget chunk-times
-        budget = self.prefill_budget
-        for slot in self.slots:
-            if budget <= 0:
-                break
-            while slot.state == _PREFILL and budget > 0:
-                for i, n in enumerate(self._prefill_chunk(slot, params,
-                                                           finished)):
-                    reach[i] += n
-                budget -= 1
+        # the running batch for more than prefill_budget chunk-times. Where
+        # slots are decoding already, the step's last chunks (one a token of
+        # the decode window) RIDE the decode call below as one mixed
+        # program; the others, and every chunk of a step that fuses
+        # nothing, are a `prefill_step` call each
+        due = self._chunks_due()
+        ride = self._chunks_riding(len(due), bool(due) and sum(
+            s.state == _DECODE for s in self.slots))
+        riding = due[len(due) - ride:]
+        for slot, start in due[:len(due) - ride]:
+            for i, n in enumerate(self._prefill_chunk(slot, start, params,
+                                                       finished)):
+                reach[i] += n
 
         # decode: ONE fixed-shape call for every slot; non-decoding slots
         # ride along against the trash block. With window > 1 the call
         # emits a whole window per slot; a slot finishing mid-window
         # discards the tail (already written to its own blocks — the
         # blocks_needed window padding covers it). With spec decode on,
-        # the verify step replaces this call entirely.
+        # the verify step replaces this call entirely. A slot whose final
+        # chunk ran above decodes in this call; one whose final chunk RIDES
+        # this call takes its first token from the call's read-back and
+        # decodes from the next.
         dec = [s for s in self.slots if s.state == _DECODE]
         if dec:
             with self._phase("serving/decode_build"):
@@ -1607,6 +1700,10 @@ class ServingEngine:
                 self.pressure is not None and self.pressure.spec_disabled)
             if spec_active:
                 self._verify_decode(dec, tok, pos, tables, finished)
+            elif riding:
+                walk, rode = self._mixed_window(dec, riding, params, tok,
+                                                pos, tables, finished)
+                reach = [a + b for a, b in zip(reach, rode)]
             else:
                 walk = self._decode_window(dec, params, tok, pos, tables,
                                            finished)
@@ -1650,6 +1747,7 @@ class ServingEngine:
                     prefill_window_table_blocks=reach[3],
                     admitted=admitted,
                     prefill_chunks=self.prefill_chunks - chunks0,
+                    fused_chunks=len(riding),
                     decoding=len(dec),
                     emitted=self.tokens_generated - tokens0,
                     queued=len(self.queue),
@@ -1658,49 +1756,114 @@ class ServingEngine:
                     compiles=self._compiled_programs() - compiled0)
         return finished
 
-    def _prefill_chunk(self, slot, params, finished):
-        """One prefill chunk of `slot`: input build, dispatch, the cache
-        registrations it completes and, after the final chunk, the
-        first-token read-back. Returns what the prefill kernel's walk
-        attends, a layer: (logical blocks under the chunk's frontier, blocks
-        in its table, and for a pool of two kinds the blocks a WINDOW
+    def _chunks_due(self):
+        """This step's prefill chunks in dispatch order, (slot, start) each:
+        the slots in order, a slot's chunks back to back, `prefill_budget`
+        of them in all."""
+        due = []
+        for slot in self.slots:
+            if slot.state != _PREFILL:
+                continue
+            for start in range(slot.cursor, slot.padded_len, self.chunk):
+                if len(due) == self.prefill_budget:
+                    return due
+                due.append((slot, start))
+        return due
+
+    def _chunks_riding(self, due, decoding):
+        """How many of a step's `due` chunks (its last ones) ride its decode
+        call as one mixed program, given `decoding` slots in the decode
+        state as the step begins. The whole rule: there is a chunk and a
+        slot that decodes, the model can run the two as one
+        (`mixed_paged_fn`; built for resident engines without spec decode),
+        the program is one device's, and the pressure ladder is at rest (its
+        rungs reshape the decode call). One chunk a token of the window —
+        what a step holds decides, nothing is set."""
+        if not (due and decoding) or self._mixed_step is None \
+                or self.engine.mesh.size != 1 \
+                or (self.pressure is not None and self.pressure.level):
+            return 0
+        return min(due, self.window)
+
+    def _chunk_input(self, slot, start):
+        """The chunk of `slot`'s prompt at `start`: (tokens [1, chunk], index
+        of the row whose logits count, whether it is the prompt's last)."""
+        chunk = np.zeros((1, self.chunk), np.int32)
+        seg = slot.prompt[start:start + self.chunk]
+        chunk[0, :len(seg)] = seg
+        final = start + self.chunk >= slot.padded_len
+        last = (slot.prompt_len - 1 - start) if final else self.chunk - 1
+        return chunk, last, final
+
+    def _chunk_written(self, slot, start, program):
+        """Book a dispatched chunk of `slot`: cursor, counter, the prefix
+        cache registrations it completes. Returns what the prefill kernel's
+        walk attends, a layer: (logical blocks under the chunk's frontier,
+        blocks in its table, and for a pool of two kinds the blocks a WINDOW
         layer's walk visits and the blocks the same walk would visit with no
-        window, both in the window kind's blocks) — zeros where the program
-        built is not that kernel."""
+        window, both in the window kind's blocks) — zeros where `program`,
+        the attention program the chunk was traced with, is not that
+        kernel."""
+        reach = (0, 0, 0, 0)
+        if program == "paged_prefill_kernel":
+            from deepspeed_tpu.ops.pallas.prefill_attention import \
+                paged_prefill_live_blocks
+            table = self.tables.shape[1]
+            full = paged_prefill_live_blocks(
+                start, self.chunk, self.block_size, table)
+            reach = (full, table, 0, 0)
+            if self.cache_kinds is not None:
+                # what a window layer's walk visits, in ITS blocks, of
+                # what the same chunk's walk would visit with no window
+                wkind = self.cache_kinds[1]
+                width = self.ring_tables.shape[1]
+                reach = (full, table, paged_prefill_live_blocks(
+                    start, self.chunk, wkind.block, width, wkind.window),
+                    paged_prefill_live_blocks(
+                        start, self.chunk, wkind.block, width))
+        slot.cursor = start + self.chunk
+        self.prefill_chunks += 1
+        if self.prefix_cache is not None and slot.hashes:
+            # register blocks the cursor just finished writing —
+            # full blocks strictly below prompt_len only (the
+            # padded tail and decode-written blocks stay private,
+            # so shared blocks are immutable by construction). A
+            # block becomes matchable only here, AFTER the call that
+            # writes its content is dispatched: registering at admission
+            # would let a same-step sibling map garbage.
+            hi = min(slot.cursor, slot.prompt_len) // self.block_size
+            for i in range(slot.reg, hi):
+                self.prefix_cache.register(slot.hashes[i],
+                                           slot.blocks[i])
+            slot.reg = max(slot.reg, hi)
+        return reach
+
+    def _first_token(self, slot, tok, finished):
+        """A prompt's last chunk is in: the slot decodes from here (or parks
+        for handoff) and emits the token that chunk's last row sampled."""
+        # a prefill-only slot parks for handoff instead of
+        # decoding; _emit may still retire it right here when
+        # the first sampled token is EOS or max_new == 1 — the
+        # router then sees a normal completion from this engine
+        slot.state = _HANDOFF if slot.prefill_only else _DECODE
+        self._emit(slot, tok, finished)
+
+    def _prefill_chunk(self, slot, start, params, finished):
+        """One prefill chunk of `slot` (its prompt from `start`) as a call of
+        its own — a chunk that does not ride the decode call
+        (`_mixed_window`): input build, dispatch, the cache registrations
+        it completes and, after the final chunk, the first-token read-back.
+        Returns `_chunk_written`'s walk counts."""
         st = self.steptrace
         ctx = slot.trace                      # _emit may retire the slot
         with self._phase("serving/prefill_chunk") as ph:
-            start = slot.cursor
-            chunk = np.zeros((1, self.chunk), np.int32)
-            seg = slot.prompt[start:start + self.chunk]
-            chunk[0, :len(seg)] = seg
-            final = start + self.chunk >= slot.padded_len
-            last = (slot.prompt_len - 1 - start) if final else self.chunk - 1
+            chunk, last, final = self._chunk_input(slot, start)
             st.dispatched()
             tok, self.pool = self._prefill_step(
                 params, chunk, np.asarray([start], np.int32),
                 np.asarray([last], np.int32), self.pool,
                 self._tables_arg(self.tables[slot.idx][None], [slot.idx]),
                 self._next_rng())
-            # counted here, while the device runs
-            reach = (0, 0, 0, 0)
-            if self.attention_programs().get("prefill_step") \
-                    == "paged_prefill_kernel":
-                from deepspeed_tpu.ops.pallas.prefill_attention import \
-                    paged_prefill_live_blocks
-                table = self.tables.shape[1]
-                full = paged_prefill_live_blocks(
-                    start, self.chunk, self.block_size, table)
-                reach = (full, table, 0, 0)
-                if self.cache_kinds is not None:
-                    # what a window layer's walk visits, in ITS blocks, of
-                    # what the same chunk's walk would visit with no window
-                    wkind = self.cache_kinds[1]
-                    width = self.ring_tables.shape[1]
-                    reach = (full, table, paged_prefill_live_blocks(
-                        start, self.chunk, wkind.block, width, wkind.window),
-                        paged_prefill_live_blocks(
-                            start, self.chunk, wkind.block, width))
             if self.drafter is not None:
                 # a stateful drafter (the draft model) shadows the chunk
                 # into its own pool through the same table — the draft
@@ -1709,34 +1872,17 @@ class ServingEngine:
                     slot, chunk, np.asarray([start], np.int32),
                     np.asarray([last], np.int32),
                     self.tables[slot.idx][None])
-            slot.cursor = start + self.chunk
-            self.prefill_chunks += 1
-            if self.prefix_cache is not None and slot.hashes:
-                # register blocks the cursor just finished writing —
-                # full blocks strictly below prompt_len only (the
-                # padded tail and decode-written blocks stay private,
-                # so shared blocks are immutable by construction). A
-                # block becomes matchable only here, AFTER its content
-                # exists in the pool: registering at admission would
-                # let a same-step sibling map garbage.
-                hi = min(slot.cursor, slot.prompt_len) // self.block_size
-                for i in range(slot.reg, hi):
-                    self.prefix_cache.register(slot.hashes[i],
-                                               slot.blocks[i])
-                slot.reg = max(slot.reg, hi)
+            # counted here, while the device runs
+            reach = self._chunk_written(
+                slot, start, self.attention_programs().get("prefill_step"))
             if self.step_counter_names and not final:
                 tok, counts = tok
                 self._parked_counts.append(counts)
             if final:
-                # a prefill-only slot parks for handoff instead of
-                # decoding; _emit may still retire it right here when
-                # the first sampled token is EOS or max_new == 1 — the
-                # router then sees a normal completion from this engine
-                slot.state = _HANDOFF if slot.prefill_only else _DECODE
                 # first-token readback at prefill completion — one scalar per prompt, the TTFT emission point
                 first = int(np.asarray(self._read_back(tok))[0])
                 st.ready()
-                self._emit(slot, first, finished)
+                self._first_token(slot, first, finished)
         if self.tracer.enabled and ctx is not None:
             self.tracer.record(ctx, "prefill_chunk", ph.t0, ph.t1 - ph.t0,
                                tid=self.trace_tid,
@@ -1744,13 +1890,9 @@ class ServingEngine:
         return reach
 
     def _decode_window(self, dec, params, tok, pos, tables, finished):
-        """The decode call for every slot in `dec`, its read-back, and the
-        emission of what it sampled. Returns what the paged decode kernel's
-        walk had to do and what it was launched with, a layer: (live (slot,
-        block) pairs, grid steps, and for a pool of two kinds the pairs a
-        WINDOW layer's walk visits and the pairs it would visit with no
-        window, both in the window kind's blocks), summed over the call's
-        tokens."""
+        """The decode call for every slot in `dec` (no chunk riding it), its
+        read-back, and the emission of what it sampled. Returns
+        `_decode_walk`'s counts."""
         # the degraded paths run the 1-STEP decode program: with
         # spec decode pressure-disabled the blocks were sized for
         # the k-draft overhang (no window-rounding tail, so a K-step
@@ -1764,29 +1906,101 @@ class ServingEngine:
             else self._decode_step
         win = 1 if use_w1 else self.window
         st = self.steptrace
-        from deepspeed_tpu.ops.pallas.decode_attention import (
-            paged_decode_walk_steps, window_first_block)
         with self._phase("serving/decode_window") as ph:
             st.dispatched()
             nxt, self.pool = step_fn(params, tok, pos,
                                      self.pool, self._tables_arg(tables),
                                      self._next_rng())
             # counted here, while the device runs
-            at = pos[[s.idx for s in dec]] + np.arange(win)[:, None]
-            live = at // self.block_size + 1               # [win, slots]
-            walk = (int(live.sum()),
-                    sum(paged_decode_walk_steps(n) for n in live.sum(axis=1)),
-                    0, 0)
-            if self.cache_kinds is not None:
-                wkind = self.cache_kinds[1]
-                whole = at // wkind.block + 1
-                walk = walk[:2] + (
-                    int((whole - window_first_block(
-                        at, wkind.block, wkind.window)).sum()),
-                    int(whole.sum()))
+            walk = self._decode_walk(dec, pos, win)
             # THE one host roundtrip per decode window — EOS/retirement decisions are host-side, amortized over `win` tokens
             nxt = np.asarray(self._read_back(nxt))  # [S, win]
             st.ready()
+        self._emit_window(dec, nxt, win, ph, finished)
+        return walk
+
+    def _mixed_window(self, dec, riding, params, tok, pos, tables, finished):
+        """The decode call for every slot in `dec` WITH the chunks `riding`
+        it ((slot, start) each, one a token of the window from the first):
+        ONE device call (`mixed_step`) and one read-back, which brings the
+        window's tokens, the first token of every prompt whose last chunk
+        rode, and a counted model's counters. Each chunk is booked as
+        `_prefill_chunk` books its own. Returns (`_decode_walk`'s counts,
+        the chunks' `_chunk_written` counts summed)."""
+        st = self.steptrace
+        win, n = self.window, len(riding)
+        with self._phase("serving/decode_build"):
+            chunks = np.zeros((win, 1, self.chunk), np.int32)
+            starts = np.zeros((win, 1), np.int32)
+            lasts = np.zeros((win, 1), np.int32)
+            finals = []
+            for i, (slot, start) in enumerate(riding):
+                chunks[i], lasts[i, 0], final = self._chunk_input(slot, start)
+                starts[i, 0] = start
+                finals.append(final)
+            # positions of the window past `n` are never read: any slot's
+            # row fills them
+            idx = [slot.idx for slot, _ in riding]
+            idx += idx[-1:] * (win - n)
+            chunk_tables = jax.tree_util.tree_map(
+                lambda t: t[:, None], self._tables_arg(self.tables[idx], idx))
+        with self._phase("serving/decode_window") as ph:
+            st.dispatched()
+            out, self.pool = self._mixed_step(
+                params, chunks, starts, lasts, chunk_tables, np.int32(n),
+                tok, pos, self.pool, self._tables_arg(tables),
+                self._next_rng())
+            # counted here, while the device runs
+            walk = self._decode_walk(dec, pos, win)
+            program = (self.engine.model_spec.paged_attn_programs
+                       or {}).get("mixed/prefill_chunk")
+            rode = [sum(counts) for counts in zip(*(
+                self._chunk_written(slot, start, program)
+                for slot, start in riding))]
+            # THE one host roundtrip of the call: window tokens and first tokens together
+            first, nxt = self._read_back(out)       # [win], [S, win]
+            st.ready()
+        self.fused_chunks += n
+        if self.tracer.enabled:
+            for slot, start in riding:
+                if slot.trace is not None:
+                    self.tracer.record(
+                        slot.trace, "prefill_chunk", ph.t0, ph.t1 - ph.t0,
+                        tid=self.trace_tid,
+                        attrs={"start": start, "chunk": self.chunk,
+                               "fused": True})
+        for (slot, _), final, tok1 in zip(riding, finals, first):
+            if final:
+                self._first_token(slot, int(tok1), finished)
+        self._emit_window(dec, np.asarray(nxt), win, ph, finished)
+        return walk, rode
+
+    def _decode_walk(self, dec, pos, win):
+        """What the paged decode kernel's walk has to do in a call of `win`
+        tokens for the slots `dec`, and what it is launched with, a layer:
+        (live (slot, block) pairs, grid steps, and for a pool of two kinds
+        the pairs a WINDOW layer's walk visits and the pairs it would visit
+        with no window, both in the window kind's blocks), summed over the
+        call's tokens."""
+        from deepspeed_tpu.ops.pallas.decode_attention import (
+            paged_decode_walk_steps, window_first_block)
+        at = pos[[s.idx for s in dec]] + np.arange(win)[:, None]
+        live = at // self.block_size + 1               # [win, slots]
+        walk = (int(live.sum()),
+                sum(paged_decode_walk_steps(n) for n in live.sum(axis=1)),
+                0, 0)
+        if self.cache_kinds is not None:
+            wkind = self.cache_kinds[1]
+            whole = at // wkind.block + 1
+            walk = walk[:2] + (
+                int((whole - window_first_block(
+                    at, wkind.block, wkind.window)).sum()),
+                int(whole.sum()))
+        return walk
+
+    def _emit_window(self, dec, nxt, win, ph, finished):
+        """Hand the tokens `nxt` [S, win] of the decode call timed by `ph`
+        to the slots `dec` that were in it."""
         self.decode_steps += 1
         tr_on = self.tracer.enabled
         with self._phase("serving/emit"):
@@ -1804,13 +2018,13 @@ class ServingEngine:
                     self.tracer.record(ctx, "decode_window", ph.t0,
                                        ph.t1 - ph.t0, tid=self.trace_tid,
                                        attrs={"emitted": j})
-        return walk
 
     def _read_back(self, out):
-        """THE blocking read of a step program's tokens. A counted model's
-        program hands (tokens, counts): the counts, and those parked by
-        programs whose tokens nobody read, come back in the same
-        `device_get` and are added to this step's sums."""
+        """THE blocking read of a step program's tokens (the mixed step's: a
+        pair, first tokens and window tokens). A counted model's program
+        hands (tokens, counts): the counts, and those parked by programs
+        whose tokens nobody read, come back in the same `device_get` and are
+        added to this step's sums."""
         if not self.step_counter_names:
             # dstpu: ignore[DT001]: the scheduler's one host roundtrip per device call (decode window, verify step, a prompt's first token) — retirement and acceptance are host-side
             return jax.device_get(out)
@@ -1827,7 +2041,8 @@ class ServingEngine:
         fns = (self._embed_prefill, self._layer_prefill, self._head_prefill,
                self._embed_decode, self._layer_decode, self._head_decode) \
             if self.streamed else (self._decode_step, self._prefill_step,
-                                   self._verify_step, self._decode_step_w1)
+                                   self._mixed_step, self._verify_step,
+                                   self._decode_step_w1)
         # a program not built (None) or replaced by a plain function (fault
         # injection) has no cache and counts int() = 0
         total = sum(getattr(fn, "_cache_size", int)() for fn in fns)
@@ -1881,6 +2096,10 @@ class ServingEngine:
                 ("head_decode", self._head_decode))}
         out = {"decode_step": int(self._decode_step._cache_size()),
                "prefill_step": int(self._prefill_step._cache_size())}
+        if self._mixed_step is not None and self._mixed_step._cache_size():
+            # appears once a chunk has ridden a decode call; until then the
+            # program is a jit wrapper nothing has traced
+            out["mixed_step"] = int(self._mixed_step._cache_size())
         if self.spec_on:
             out["verify_step"] = int(self._verify_step._cache_size())
             out.update(self.drafter.compile_stats())
@@ -1910,13 +2129,20 @@ class ServingEngine:
 
     def _by_step_program(self, record) -> Dict[str, str]:
         traced = getattr(self.engine.model_spec, record, None) or {}
-        return {program: traced[phase] for program, phase in (
+        out = {program: traced[phase] for program, phase in (
             ("decode_step", "paged_decode"), ("prefill_step", "prefill_chunk"),
-            ("verify_step", "verify")) if phase in traced}
+            ("verify_step", "verify"), ("mixed_step", "mixed"))
+            if phase in traced}
+        if "mixed/prefill_chunk" in traced:     # its two groups' programs
+            out["mixed_step"] = "+".join(
+                traced["mixed/" + phase]
+                for phase in ("prefill_chunk", "paged_decode"))
+        return out
 
     def stats(self) -> Dict[str, Any]:
         out = {"steps": self.steps, "decode_steps": self.decode_steps,
                "prefill_chunks": self.prefill_chunks,
+               "fused_chunks": self.fused_chunks,
                "tokens_generated": self.tokens_generated,
                "peak_active": self.peak_active,
                "cancelled": self.cancelled,

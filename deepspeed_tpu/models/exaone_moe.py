@@ -45,14 +45,17 @@ on it), the int8 pool, prefix caching and block transplant on a two-kind pool
 import copy
 import dataclasses
 import math
+from functools import partial
 from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
 from deepspeed_tpu.inference.kv_cache import CacheKind
-from deepspeed_tpu.models.gpt import (_attn_half, _embed, _lm_head,
-                                      _paged_attn_half, _residual_mlp)
+from deepspeed_tpu.models.gpt import (MixedTables, _attn_half, _embed,
+                                      _lm_head, _paged_attn_half,
+                                      _residual_mlp, decode_rows,
+                                      make_mixed_paged_fn)
 from deepspeed_tpu.models.moe_gpt import MoEGPTConfig
 from deepspeed_tpu.ops import attention_dispatch as attn_dispatch
 from deepspeed_tpu.parallel.moe import (HELD_ROUTED_COUNTERS, routed_experts,
@@ -400,7 +403,11 @@ def make_exaone_moe_decode_model(cfg: ExaoneMoEConfig, params=None,
 
     def _layers_paged(params, x, pool, block_tables, positions, routing):
         tables = dict(zip((FULL, WINDOW), block_tables))
-        site = "paged_decode" if x.shape[1] == 1 else "prefill_chunk"
+        # a mixed call (`gpt.py::MixedTables`): a chunk's rows, then a
+        # decode row a slot, each kind's tables the pair of the two groups'
+        mixed = isinstance(block_tables[0], MixedTables)
+        site = "mixed" if mixed else \
+            "paged_decode" if x.shape[1] == 1 else "prefill_chunk"
         in_place = all(
             attn_dispatch.kv_pool_writer({"k": pool[a], "v": pool[b]})
             == attn_dispatch.KV_POOL_WRITE_KERNEL
@@ -411,11 +418,11 @@ def make_exaone_moe_decode_model(cfg: ExaoneMoEConfig, params=None,
                      for kind, (a, _) in _POOL_LEAVES.items()}
         # one work list a KIND, built once a token, outside the layer loop
         work = {FULL: None, WINDOW: None}
-        if site == "paged_decode":
+        if site != "prefill_chunk":
             from deepspeed_tpu.ops.pallas.decode_attention import \
                 paged_decode_work
             work = {kind: paged_decode_work(
-                tables[kind], positions[:, 0], pool[a].shape[3],
+                *decode_rows(tables[kind], positions), pool[a].shape[3],
                 window=kcfg[kind].sliding_window)
                 for kind, (a, _) in _POOL_LEAVES.items()}
         # both kinds' leaves flat and CARRIED: layer i of a kind addresses
@@ -431,7 +438,8 @@ def make_exaone_moe_decode_model(cfg: ExaoneMoEConfig, params=None,
             # gather of the other form take tables already offset
             where = dict(block_base=base) if in_place else {}
             table = tables[attn_kind] if in_place \
-                else tables[attn_kind] + base
+                else jax.tree_util.tree_map(lambda t: t + base,
+                                            tables[attn_kind])
             with jax.named_scope("attn_window" if attn_kind == WINDOW
                                  else "attn_full"):
                 attn_out, pool_l = _paged_attn_half(
@@ -536,6 +544,8 @@ def make_exaone_moe_decode_model(cfg: ExaoneMoEConfig, params=None,
                            init_cache=unserved, params=params, name=name,
                            prefill_paged_fn=prefill_paged_fn,
                            decode_paged_fn=decode_paged_fn,
+                           mixed_paged_fn=make_mixed_paged_fn(
+                               cfg, partial(_layers_paged, routing=False)),
                            init_paged_pool=init_paged_pool,
                            paged_cache_kinds=lambda block_size: cache_kinds(
                                cfg, block_size),

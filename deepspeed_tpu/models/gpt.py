@@ -22,7 +22,7 @@ gpt2/gptj/neox/llama containers with one implementation.
 import dataclasses
 import math
 from functools import partial
-from typing import Any, Dict, Optional
+from typing import Any, Dict, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -1163,6 +1163,8 @@ def make_gpt_decode_model(cfg: GPTConfig = None, name="gpt2-125m", params=None, 
                            init_cache=init_cache, params=params, name=name,
                            prefill_paged_fn=prefill_paged_fn,
                            decode_paged_fn=decode_paged_fn,
+                           mixed_paged_fn=make_mixed_paged_fn(cfg,
+                                                              _scan_paged),
                            verify_paged_fn=verify_paged_fn,
                            init_paged_pool=init_paged_pool,
                            kv_pool_writers=pool_writers,
@@ -1209,6 +1211,64 @@ def init_paged_kv_pool(cfg: GPTConfig, num_blocks, block_size,
     return pool
 
 
+class MixedTables(NamedTuple):
+    """The block tables of a MIXED call (`make_mixed_paged_fn`): one prefill
+    chunk and a decode token of every slot as the rows of ONE tensor,
+    x [1, C + S, D] with positions [1, C + S] — the chunk's C rows first
+    (one sequence, its table `chunk` [1, nb]), then one row a slot (tables
+    `decode` [S, nb]). Every weight is applied to all the rows at once; only
+    `_paged_attn_half` tells the two groups apart, and it knows a mixed call
+    by its tables being this pair."""
+    chunk: Any
+    decode: Any
+
+
+def mixed_tables(chunk_table, block_tables):
+    """`MixedTables` of a chunk's table and the slots' tables — of each
+    kind, where a pool of two kinds passes its tables as a pair."""
+    if isinstance(block_tables, tuple):
+        return tuple(MixedTables(c, d)
+                     for c, d in zip(chunk_table, block_tables))
+    return MixedTables(chunk_table, block_tables)
+
+
+def decode_rows(block_tables, positions):
+    """(tables [S, nb], positions [S]) of a paged call's decode rows: every
+    row of a decode call, the last S of a mixed call."""
+    if isinstance(block_tables, MixedTables):
+        slots = block_tables.decode.shape[0]
+        return block_tables.decode, positions[0, -slots:]
+    return block_tables, positions[:, 0]
+
+
+def make_mixed_paged_fn(cfg, layers_paged):
+    """A family's `DecodeModelSpec.mixed_paged_fn` from its layer loop
+    `layers_paged(params, x, pool, block_tables, positions) -> (x, pool,
+    *counts)`, the one its `prefill_paged_fn` and `decode_paged_fn` run:
+    a chunk and a decode token a slot through embedding, layers, final norm
+    and head as one tensor, so each weight is read once where the two
+    programs read it twice. Logits [1 + S, V]: the chunk's `last_idx` row,
+    then the slots' rows."""
+
+    def mixed_paged_fn(params, chunk_tokens, start_pos, last_idx, chunk_table,
+                       token, pos, pool, block_tables):
+        C = chunk_tokens.shape[1]
+        tokens = jnp.concatenate([chunk_tokens, token[None]], axis=1)
+        positions = jnp.concatenate(
+            [start_pos[:, None] + jnp.arange(C, dtype=jnp.int32)[None],
+             pos[None]], axis=1)
+        x = _embed(params, tokens, positions, cfg)
+        x, pool, *counts = layers_paged(
+            params, x, pool, mixed_tables(chunk_table, block_tables),
+            positions)
+        last = jnp.take_along_axis(x[:, :C], last_idx[:, None, None], axis=1)
+        logits = _lm_head(params, jnp.concatenate([last, x[:, C:]], axis=1),
+                          cfg)[0]
+        return (logits, pool, *counts)
+
+    return mixed_paged_fn
+
+
 def scan_paged(cfg: GPTConfig, blocks, x, pool, block_tables, positions,
                phase=None, pool_writers=None, block_fn=None, aux=None,
                attn_programs=None):
@@ -1220,8 +1280,10 @@ def scan_paged(cfg: GPTConfig, blocks, x, pool, block_tables, positions,
     k_scale/v_scale), so the quantized and fp layouts share one scan body —
     a layer's pool arrives as a dict. `phase` labels the dispatch site
     ("verify" for the spec-decode chunk; None = derive decode/prefill from
-    the chunk width); `pool_writers[phase]` records the writer chosen and
-    `attn_programs[phase]` the attention program the layers select.
+    the chunk width; `block_tables` a `MixedTables` makes it "mixed", a
+    chunk and the slots' decode rows in one x); `pool_writers[phase]`
+    records the writer chosen and `attn_programs[phase]` the attention
+    program the layers select.
 
     `block_fn` (default `_block_paged`) is one layer: `(x, p, pool_l,
     positions, block_tables, cfg, local_flag=, phase=, block_base=,
@@ -1248,18 +1310,21 @@ def scan_paged(cfg: GPTConfig, blocks, x, pool, block_tables, positions,
     layer_ids = jnp.arange(L, dtype=jnp.int32)
     flags = _layer_local_flags(cfg)
     writer = attn_dispatch.kv_pool_writer(pool)
-    site = phase or ("paged_decode" if x.shape[1] == 1 else "prefill_chunk")
+    mixed = isinstance(block_tables, MixedTables)
+    site = "mixed" if mixed else phase or (
+        "paged_decode" if x.shape[1] == 1 else "prefill_chunk")
     if pool_writers is not None:
         pool_writers[site] = writer
     # the decode kernel's work list is the same for every layer (a layer only
     # offsets the physical blocks): built HERE, once a token, not in the loop
+    # (a mixed call's: of its decode rows)
     decode_work = None
-    if site == "paged_decode":
+    if site in ("paged_decode", "mixed"):
         from deepspeed_tpu.ops.pallas.decode_attention import \
             paged_decode_work
-        decode_work = paged_decode_work(block_tables, positions[:, 0],
-                                        pool["k"].shape[3],
-                                        window=_static_window(cfg))
+        decode_work = paged_decode_work(
+            *decode_rows(block_tables, positions), pool["k"].shape[3],
+            window=_static_window(cfg))
 
     def layer(x, p, pool_l, flag, acc, layer_id, block_base=None):
         x, pool_l, *counts = block_fn(
@@ -1343,6 +1408,11 @@ def _paged_attn_half(x, p, pool_l, positions, block_tables,
     row's blocks (logical position -> table -> physical block scatter), then
     attends over the row's whole table. Returns (attn_out, pool_l).
 
+    A MIXED call (`block_tables` a `MixedTables`; x [1, C + S, D]): one QKV
+    and one output projection over all the rows, and between them the
+    chunk's C rows and the slots' S rows each written and attended as their
+    own program would (`_paged_write_attend`, once a group).
+
     In-place form (`block_base` given; `_scan_paged` decides): `pool_l` is
     the WHOLE stack flattened to [L*N, Hkv, block, hd], this layer's blocks
     start at `block_base` (= layer * N, traced), the rows are written by the
@@ -1369,15 +1439,56 @@ def _paged_attn_half(x, p, pool_l, positions, block_tables,
     the dequantizing gather oracle — one shared numeric definition, so the
     two are parity-testable tile for tile.
     """
+    q, k, v = _decode_qkv(x, p, positions, cfg)
+    group = partial(_paged_write_attend, cfg=cfg, local_flag=local_flag,
+                    block_base=block_base, attn_programs=attn_programs)
+    if isinstance(block_tables, MixedTables):
+        # a mixed call: the chunk's rows [1, C, ...], then a row a slot,
+        # [S, 1, ...] as the decode program has them. Each group writes and
+        # attends as it would in its own program (same dispatch site, same
+        # kernels); their results meet again for ONE output projection
+        S = block_tables.decode.shape[0]
+        C = x.shape[1] - S
+        attn_c, pool_l = group(
+            q[:, :C], k[:, :C], v[:, :C], pool_l, positions[:, :C],
+            block_tables.chunk, phase="prefill_chunk",
+            record="mixed/prefill_chunk")
+        # the chunk's walk has READ the pool before the slots' rows are
+        # written into it in place — said as data. Nothing else orders the
+        # two (the slots' rows do not depend on the chunk's attention), and
+        # XLA, left free, keeps the walk's input alive by copying a whole
+        # pool leaf a layer (measured on the chip, PR 33: two copies of
+        # K-EXAONE's 1.5 GB full-layer leaf a mixed token, 23% of its time)
+        attn_c, pool_l = jax.lax.optimization_barrier((attn_c, pool_l))
+        attn_d, pool_l = group(
+            *(jnp.swapaxes(a[:, C:], 0, 1) for a in (q, k, v)), pool_l,
+            positions[:, C:].T, block_tables.decode, phase="paged_decode",
+            decode_work=decode_work, record="mixed/paged_decode")
+        attn = jnp.concatenate([attn_c, jnp.swapaxes(attn_d, 0, 1)], axis=1)
+    else:
+        attn, pool_l = group(q, k, v, pool_l, positions, block_tables,
+                             phase=phase, decode_work=decode_work)
+    attn_out = attn @ p["attn_out_w"] + p["attn_out_b"]
+    return attn_out, pool_l
+
+
+def _paged_write_attend(q, k, v, pool_l, positions, block_tables,
+                        cfg: GPTConfig, local_flag=None, phase=None,
+                        block_base=None, decode_work=None, attn_programs=None,
+                        record=None):
+    """`_paged_attn_half` between its two matmuls, for rows that share a
+    dispatch site: write k/v [B, C, Hkv, hd] through `block_tables` [B, nb]
+    at `positions` [B, C], then attend q [B, C, H, hd] over each row's
+    table. Returns (attn [B, C, H*hd], pool_l). `record`: the key the
+    selected program's name is kept under in `attn_programs` (default: the
+    site's phase)."""
     from deepspeed_tpu.inference.kv_cache import (gather_block_kv,
                                                   gather_block_kv_dequant)
 
-    B, C, D = x.shape
+    B, C = positions.shape
     bs = pool_l["k"].shape[2]
     nb = block_tables.shape[1]
     quantized = "k_scale" in pool_l
-
-    q, k, v = _decode_qkv(x, p, positions, cfg)
 
     # scatter the new k/v through the table: logical block = pos // bs,
     # physical block = table[row, logical], offset = pos % bs. Rows of
@@ -1427,7 +1538,7 @@ def _paged_attn_half(x, p, pool_l, positions, block_tables,
         block_size=bs, pool_in_place=block_base is not None)
     program = attn_dispatch.select(site)
     if attn_programs is not None:
-        attn_programs[site.phase] = program
+        attn_programs[record or site.phase] = program
     runner = attn_dispatch.get_program(program).runner
     if runner is not None:
         with jax.named_scope("attn"):
@@ -1457,7 +1568,7 @@ def _paged_attn_half(x, p, pool_l, positions, block_tables,
         with jax.named_scope("kv_pool_read"):
             if program == "paged_gather_quant":
                 k_ctx, v_ctx = gather_block_kv_dequant(pool_l, block_tables,
-                                                       x.dtype)
+                                                       q.dtype)
             elif block_base is not None:
                 # an XLA gather on the carried pool slices the WHOLE pool
                 # (see ops/pallas/kv_pool.py): reads are Mosaic calls too
@@ -1478,8 +1589,7 @@ def _paged_attn_half(x, p, pool_l, positions, block_tables,
             f"attention program {program!r} selected for the paged site "
             f"has no handler in models/gpt.py — non-train phases dispatch "
             f"by name; add a branch for it here")
-    attn_out = attn @ p["attn_out_w"] + p["attn_out_b"]
-    return attn_out, pool_l
+    return attn, pool_l
 
 
 def _block_paged(x, p, pool_l, positions, block_tables,

@@ -58,6 +58,7 @@ from deepspeed_tpu.models.gpt import (GPTConfig, _act, _attn_half, _block,
                                       gpt_cache_identity, gpt_init_fn,
                                       init_gpt_params, init_kv_cache,
                                       init_paged_kv_pool, gpt_param_specs,
+                                      make_mixed_paged_fn,
                                       scan_paged)
 from deepspeed_tpu.parallel.moe import (ROUTED_COUNTERS,
                                         can_use_expert_shard_map,
@@ -587,6 +588,8 @@ def make_moe_gpt_decode_model(cfg: MoEGPTConfig, params=None, name="moe-gpt", se
                            param_specs=moe_gpt_param_specs(cfg), name=name,
                            prefill_paged_fn=prefill_paged_fn,
                            decode_paged_fn=decode_paged_fn,
+                           mixed_paged_fn=make_mixed_paged_fn(cfg,
+                                                              _layers_paged),
                            verify_paged_fn=verify_paged_fn,
                            init_paged_pool=init_paged_pool,
                            kv_pool_writers=pool_writers,

@@ -1060,6 +1060,12 @@ class ServingMemScope(_MemScopeBase):
         yield "prefill_step", s._prefill_step, \
             (params, i32((1, chunk)), i32((1,)), i32((1,)), pool,
              np.asarray(s.tables[:1]), rng)
+        if s._mixed_step is not None:
+            W = s.window
+            yield "mixed_step", s._mixed_step, \
+                (params, i32((W, 1, chunk)), i32((W, 1)), i32((W, 1)),
+                 i32((W, 1, s.tables.shape[1])), np.int32(1), i32((S,)),
+                 i32((S,)), pool, np.asarray(s.tables), rng)
         if s._verify_step is not None:
             yield "verify_step", s._verify_step, \
                 (params, i32((S, s.draft_k + 1)), i32((S,)), pool,

@@ -75,7 +75,10 @@ StepRecord = collections.namedtuple("StepRecord", [
                         # would visit with no window (pos // block + 1)
     "prefill_window_live_blocks",  # the same two for this step's prefill
     "prefill_window_table_blocks",  # chunks; all four 0 for a one-kind pool
-], defaults=(0, 0, 0, 0, 0, 0, "", 0, (), 0, 0, 0, 0, 0, 0, 0, 0))
+    "fused_chunks",     # of `prefill_chunks`, the chunks that rode the
+                        # step's decode call (the scheduler's `mixed_step`:
+                        # one device call, every weight read once)
+], defaults=(0, 0, 0, 0, 0, 0, "", 0, (), 0, 0, 0, 0, 0, 0, 0, 0, 0))
 
 RequestRecord = collections.namedtuple("RequestRecord", [
     "uid", "t_submit", "t_admit",

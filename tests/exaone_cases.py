@@ -12,6 +12,7 @@ import jax.numpy as jnp
 
 import deepspeed_tpu
 from deepspeed_tpu.comm import mesh as mesh_mod
+from deepspeed_tpu.config.core import MeshConfig
 from deepspeed_tpu.models import exaone_moe as em
 
 L, G = em.WINDOW, em.FULL
@@ -61,8 +62,10 @@ def _params(cfg, seed=0, dtype=jnp.float32):
     return em.exaone_moe_init_fn(cfg, dtype=dtype)(jax.random.PRNGKey(seed))
 
 
-def _serving(cfg, params, dtype="float32", **knobs):
+def _serving(cfg, params, dtype="float32", one_device=False, **knobs):
     mesh_mod.clear_mesh()
+    if one_device:      # else `init_inference` spans every device there is
+        mesh_mod.init_mesh(MeshConfig(data=1), devices=jax.devices()[:1])
     spec = em.make_exaone_moe_decode_model(cfg, params=params, name="tiny")
     engine = deepspeed_tpu.init_inference(
         spec, config={"dtype": dtype, "kv_cache_dtype": dtype, "greedy": True,

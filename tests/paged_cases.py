@@ -1,7 +1,18 @@
 """Inputs shared by the paged decode kernels' oracle tests (the float kernel
-in test_serving.py, the int8 one in test_quant_serving.py)."""
+in test_serving.py, the int8 one in test_quant_serving.py), and the serving
+tests' compile-count assertion."""
 import jax.numpy as jnp
 import numpy as np
+
+
+def assert_one_compile_each(serving):
+    """The serving promise after a trace that ran chunks and decode calls:
+    `decode_step` and `prefill_step` compiled exactly once — and `mixed_step`
+    once too, listed if and only if a chunk rode a decode call."""
+    want = {"decode_step": 1, "prefill_step": 1}
+    if serving.fused_chunks:
+        want["mixed_step"] = 1
+    assert serving.compile_stats() == want, serving.compile_stats()
 
 
 def paged_kernel_case(heads, rows, seed=11):

@@ -23,6 +23,7 @@ import numpy as np
 import pytest
 
 from deepspeed_tpu.ops import attention_dispatch as ad
+from tests.paged_cases import assert_one_compile_each
 
 pytestmark = pytest.mark.longctx
 
@@ -247,6 +248,55 @@ class TestSingleHomePredicates:
                 jnp.zeros((1,), jnp.int32), pool, tables)
         assert "verify" in seen and "prefill_chunk" not in seen
 
+    @pytest.mark.parametrize("in_place", [False, True],
+                             ids=["scatter_form", "in_place_pool"])
+    def test_mixed_call_site_selects_what_its_two_groups_select_alone(
+            self, monkeypatch, in_place):
+        """A chunk riding the decode call (`mixed_paged_fn`) dispatches its
+        chunk rows as a `prefill_chunk` site and its slots' rows as a
+        `paged_decode` site — the SAME keys, so the same programs, as the
+        chunk-only and decode-only programs of the same shapes; and it keeps
+        its own record of them beside theirs."""
+        from deepspeed_tpu.models.gpt import (GPTConfig,
+                                              make_gpt_decode_model)
+        cfg = GPTConfig(n_layer=1, n_head=2, d_model=256, max_seq_len=1024,
+                        vocab_size=128, dtype=jnp.float32, remat=False,
+                        use_rotary=True, use_flash_attention=True)
+        spec = make_gpt_decode_model(cfg=cfg)
+        if in_place:    # the carried pool, its kernels in the interpreter
+            monkeypatch.setattr(ad, "kv_pool_writer",
+                                lambda pool: ad.KV_POOL_WRITE_KERNEL)
+        seen = []
+        orig = ad.select
+
+        def spy(site):
+            seen.append(site)
+            return orig(site)
+
+        monkeypatch.setattr(ad, "select", spy)
+        pool = spec.init_paged_pool(9, 128, jnp.float32)
+        i32 = jnp.int32
+        chunk = (jnp.zeros((1, 128), i32), jnp.zeros((1,), i32),
+                 jnp.zeros((1,), i32))
+        table, tables = jnp.zeros((1, 8), i32), jnp.zeros((3, 8), i32)
+        slots = (jnp.zeros((3,), i32), jnp.zeros((3,), i32))
+        jax.make_jaxpr(spec.prefill_paged_fn)(spec.params, *chunk, pool,
+                                              table)
+        jax.make_jaxpr(spec.decode_paged_fn)(spec.params, *slots, pool,
+                                             tables)
+        alone, seen[:] = list(seen), []
+        jax.make_jaxpr(spec.mixed_paged_fn)(spec.params, *chunk, table,
+                                            *slots, pool, tables)
+        assert [s.phase for s in alone] == ["prefill_chunk", "paged_decode"]
+        assert seen == alone
+        programs = spec.paged_attn_programs
+        assert programs["mixed/prefill_chunk"] == programs["prefill_chunk"] \
+            == ("paged_prefill_kernel" if in_place else "paged_gather")
+        assert programs["mixed/paged_decode"] == programs["paged_decode"] \
+            == "paged_kernel"
+        assert spec.kv_pool_writers["mixed"] \
+            == spec.kv_pool_writers["prefill_chunk"]
+
     def test_monkeypatched_flash_predicate_flips_training(self, monkeypatch):
         from deepspeed_tpu.models.gpt import (GPTConfig, gpt_forward,
                                               init_gpt_params)
@@ -360,8 +410,7 @@ class TestCompileStability:
                         max_new_tokens=8) for i in range(4)]
         done = serving.run(reqs)
         assert len(done) == 4
-        assert serving.compile_stats() == {"decode_step": 1,
-                                           "prefill_step": 1}
+        assert_one_compile_each(serving)
 
 
 class TestFlashRunsPerShard:

@@ -38,6 +38,7 @@ from deepspeed_tpu.telemetry.memscope import (
     PredictedOOMError, SERVING_PLAN_TOLERANCE, TRAIN_PLAN_TOLERANCE,
     dtype_bytes, fmt_bytes, max_kv_blocks, plan_serving, plan_training,
     plan_training_from_engine, serving_pool_bytes, tree_bytes)
+from tests.paged_cases import assert_one_compile_each
 
 pytestmark = pytest.mark.memscope
 
@@ -213,12 +214,12 @@ def test_int8_serving_planner_matches_xla_memory_analysis(tmp_path):
     assert plan.device_bytes["params"] == tree_bytes(engine.params)
     pred = plan.device_bytes["params"] + plan.device_bytes["kv_pool"]
     progs = serving.memscope.program_memory()
-    assert set(progs) == {"decode_step", "prefill_step"}
+    assert set(progs) == {"decode_step", "prefill_step", "mixed_step"}
     for name, ma in progs.items():
         rel = abs(ma["argument_bytes"] - pred) / pred
         assert rel < SERVING_PLAN_TOLERANCE, (name, ma["argument_bytes"],
                                               pred, rel)
-    assert serving.compile_stats() == {"decode_step": 1, "prefill_step": 1}
+    assert_one_compile_each(serving)
 
 
 # ----------------------------------------------------------------------
@@ -242,7 +243,7 @@ def test_serving_planner_matches_xla_memory_analysis(tmp_path):
     # resident prediction plus only small unmodeled args (tok/pos/tables/
     # rng) — within the documented tolerance
     progs = serving.memscope.program_memory()
-    assert set(progs) == {"decode_step", "prefill_step"}
+    assert set(progs) == {"decode_step", "prefill_step", "mixed_step"}
     for name, ma in progs.items():
         rel = abs(ma["argument_bytes"] - pred) / pred
         assert rel < SERVING_PLAN_TOLERANCE, (name, ma["argument_bytes"],
@@ -252,7 +253,7 @@ def test_serving_planner_matches_xla_memory_analysis(tmp_path):
         assert ma["alias_bytes"] >= tree_bytes(serving.pool)
 
     # the AOT memory_analysis pass never touched the jit CALL caches
-    assert serving.compile_stats() == {"decode_step": 1, "prefill_step": 1}
+    assert_one_compile_each(serving)
 
 
 def test_train_planner_matches_state_and_xla(tmp_path):
@@ -513,7 +514,7 @@ def test_disabled_default_no_scope_no_files(tmp_path, monkeypatch):
     serving = engine.serving(max_slots=2, max_context=128)
     assert serving.memscope is None
     serving.run(_reqs(2, np.random.default_rng(0)))
-    assert serving.compile_stats() == {"decode_step": 1, "prefill_step": 1}
+    assert_one_compile_each(serving)
     assert "memory" not in serving.stats()
     assert list(tmp_path.iterdir()) == []       # zero files
     # memscope flag without telemetry.enabled is also a no-op

@@ -17,6 +17,7 @@ from deepspeed_tpu.inference.kv_cache import BlockAllocator, TRASH_BLOCK
 from deepspeed_tpu.inference.prefix_cache import PrefixCache
 from deepspeed_tpu.inference.scheduler import Request
 from deepspeed_tpu.models.gpt import GPTConfig, make_gpt_decode_model
+from tests.paged_cases import assert_one_compile_each
 
 pytestmark = pytest.mark.prefix_cache
 
@@ -194,7 +195,7 @@ def test_greedy_parity_and_fewer_prefill_chunks_zero_new_compiles():
     assert on.prefill_chunks < off.prefill_chunks, \
         (on.prefill_chunks, off.prefill_chunks)
     assert on.prefill_chunks + on.prefill_chunks_skipped == off.prefill_chunks
-    assert on.compile_stats() == {"decode_step": 1, "prefill_step": 1}
+    assert_one_compile_each(on)
     st = on.stats()["prefix_cache"]
     assert st["hit_tokens"] == st["hit_blocks"] * BS > 0
     assert st["prefill_chunks_skipped"] == on.prefill_chunks_skipped
@@ -270,7 +271,7 @@ def test_eviction_under_pressure_still_admits():
         ref = engine.generate(p[None], max_new_tokens=4, stop_on_eos=False)
         np.testing.assert_array_equal(res[uid].tokens, ref[0])
     np.testing.assert_array_equal(r1b[3].tokens, r1[1].tokens)
-    assert serving.compile_stats() == {"decode_step": 1, "prefill_step": 1}
+    assert_one_compile_each(serving)
 
 
 def test_prompt_len_exactly_on_block_edge():
@@ -295,7 +296,7 @@ def test_prompt_len_exactly_on_block_edge():
     for uid, p in ((1, edge), (3, longer)):
         ref = engine.generate(p[None], max_new_tokens=4, stop_on_eos=False)
         np.testing.assert_array_equal(runs[uid].tokens, ref[0])
-    assert serving.compile_stats() == {"decode_step": 1, "prefill_step": 1}
+    assert_one_compile_each(serving)
 
 
 def test_hit_truncated_to_chunk_grid_when_chunk_exceeds_block():

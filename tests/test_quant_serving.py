@@ -22,7 +22,8 @@ from deepspeed_tpu.inference.quantization import (dequantize_kv,
 from deepspeed_tpu.inference.scheduler import Request
 from deepspeed_tpu.models.gpt import (GPTConfig, init_paged_kv_pool,
                                       make_gpt_decode_model)
-from tests.paged_cases import (PAGED_KERNEL_HEADS, PAGED_KERNEL_ROWS,
+from tests.paged_cases import (assert_one_compile_each,
+                               PAGED_KERNEL_HEADS, PAGED_KERNEL_ROWS,
                                paged_kernel_case)
 
 pytestmark = pytest.mark.quant
@@ -189,8 +190,8 @@ def test_int8_kv_kernel_engine_token_identical_to_dequant_reference():
                                       res_gather[i].tokens)
     # the serving compile contract survives quantization: one compile per
     # persistent program, watchdog silent
-    assert sk.compile_stats() == {"decode_step": 1, "prefill_step": 1}
-    assert sg.compile_stats() == {"decode_step": 1, "prefill_step": 1}
+    assert_one_compile_each(sk)
+    assert_one_compile_each(sg)
 
 
 def test_int8_kv_close_to_fp_pool_on_tiny_model():
@@ -235,7 +236,7 @@ def test_prefix_cache_hit_on_int8_pool_token_identical(tmp_path):
     np.testing.assert_array_equal(cold["c"].tokens, warm["w"].tokens)
     assert warm["w"].cached_prefix_tokens == 48
     assert chunks_warm < chunks_cold
-    assert serving.compile_stats() == {"decode_step": 1, "prefill_step": 1}
+    assert_one_compile_each(serving)
     assert serving.close().ok                       # clean invariant audit
 
 
@@ -323,7 +324,7 @@ def test_weight_only_serving_matches_generate(weights):
                               max_new_tokens=r.max_new_tokens,
                               stop_on_eos=False)
         np.testing.assert_array_equal(res[r.uid].tokens, ref[0])
-    assert serving.compile_stats() == {"decode_step": 1, "prefill_step": 1}
+    assert_one_compile_each(serving)
 
 
 def test_weight_quant_conflict_and_idempotence():

@@ -19,7 +19,8 @@ from deepspeed_tpu.inference.kv_cache import (BlockAllocator, TRASH_BLOCK,
                                               blocks_needed)
 from deepspeed_tpu.inference.scheduler import Request
 from deepspeed_tpu.models.gpt import GPTConfig, make_gpt_decode_model
-from tests.paged_cases import (PAGED_KERNEL_HEADS, PAGED_KERNEL_ROWS,
+from tests.paged_cases import (assert_one_compile_each,
+                               PAGED_KERNEL_HEADS, PAGED_KERNEL_ROWS,
                                paged_kernel_case)
 
 pytestmark = pytest.mark.serving
@@ -171,8 +172,7 @@ def test_serving_single_compile_per_program_across_mixed_trace():
                         max_new_tokens=2 + i * 3, stop_on_eos=False)
                 for i, p in enumerate(_ragged_prompts(rng, wave))]
         serving.run(reqs)
-    assert serving.compile_stats() == {"decode_step": 1, "prefill_step": 1}, \
-        serving.compile_stats()
+    assert_one_compile_each(serving)
 
 
 def test_eos_retirement_frees_slot_and_blocks_immediately():
@@ -198,7 +198,7 @@ def test_eos_retirement_frees_slot_and_blocks_immediately():
     res2 = serving.run([Request(uid="b", tokens=prompt, max_new_tokens=4,
                                 stop_on_eos=False)])
     np.testing.assert_array_equal(res2["b"].tokens, free[:4])
-    assert serving.compile_stats() == {"decode_step": 1, "prefill_step": 1}
+    assert_one_compile_each(serving)
 
 
 def test_pool_exhaustion_backpressure():
@@ -289,8 +289,7 @@ def test_decode_window_matches_per_step_and_generate():
                            for i, (p, n) in enumerate(zip(prompts, news))])
         for i in ref:
             np.testing.assert_array_equal(res[i].tokens, ref[i]), (window, i)
-        assert serving.compile_stats() == {"decode_step": 1,
-                                           "prefill_step": 1}
+        assert_one_compile_each(serving)
     # EOS mid-window: discover a token greedy emits, stop on it, and check
     # the output truncates exactly there (the window tail is discarded)
     eos = int(ref[0][3])

@@ -6,6 +6,7 @@ names in the compiled programs.
 Rides the `telemetry` marker (tier-1; `pytest -m telemetry`).
 """
 
+import collections
 import functools
 import gc
 import json
@@ -234,8 +235,9 @@ def test_serving_phases_tile_every_step_under_an_injected_clock():
     assert sum(r.prefill_chunks for r in recs) == serving.prefill_chunks
     assert sum(r.emitted for r in recs) == serving.tokens_generated == 9
     assert sum(1 for r in recs if r.decoding) == serving.decode_steps
-    # both step programs compiled once, in the steps that first ran them
-    assert sum(r.compiles for r in recs) == 2
+    # every step program compiled once, in the step that first ran it
+    assert sum(r.compiles for r in recs) == len(serving.compile_stats())
+    assert set(serving.compile_stats().values()) == {1}
     assert recs[-1].queued == 0 and recs[-1].free_blocks == \
         serving.allocator.available
     # set_clock moves the recorder with the engine
@@ -339,13 +341,16 @@ def test_compiles_names_the_step_that_compiled():
     serving.run(_requests(2))
     recs = serving.steptrace.records()
     # the first step runs a chunk and a decode call, so it compiles both
-    # programs; nothing after it compiles
-    assert [r.compiles for r in recs] == [2] + [0] * (len(recs) - 1)
-    assert sum(serving.compile_stats().values()) == 2
+    # programs; in the second the other prompt's chunk rides the first one's
+    # decode call, the mixed program; nothing after them compiles
+    assert [r.compiles for r in recs] == [2, 1] + [0] * (len(recs) - 2)
+    assert [r.fused_chunks for r in recs] == [0, 1] + [0] * (len(recs) - 2)
+    assert serving.compile_stats() == {"decode_step": 1, "prefill_step": 1,
+                                       "mixed_step": 1}
     # a program replaced by a plain function (fault injection) counts 0
     jitted = serving._decode_step
     serving._decode_step = lambda *a: jitted(*a)
-    assert serving._compiled_programs() == 1
+    assert serving._compiled_programs() == 2
     serving.run(_requests(1, seed=1))
     assert serving.steptrace.records()[-1].compiles == 0
 
@@ -639,10 +644,11 @@ def _assert_decode_walk_is_built_once_a_token(text):
     assert work and not set(work) & reached, (work, sorted(reached))
 
 
-def _compile_paged_programs(one_chip, pool_dtype, chunk=64):
+def _compile_paged_programs(one_chip, pool_dtype, chunk=64, mixed=False):
     """The paged decode and prefill programs of a 2-layer model at the
     served tile widths (Hkv 8, block 512, hd 128), pool donated; the prefill
-    chunk `chunk` rows over a table of 8 blocks."""
+    chunk `chunk` rows over a table of 8 blocks. `mixed`: the mixed program
+    too (the chunk and the 8 decode rows in one call, 16-wide tables)."""
     from deepspeed_tpu.models.gpt import gpt_init_fn
 
     def sds(shape, dtype):
@@ -668,8 +674,14 @@ def _compile_paged_programs(one_chip, pool_dtype, chunk=64):
     prefill = jax.jit(spec.prefill_paged_fn, donate_argnums=(4,)).lower(
         params, sds((1, chunk), i32), sds((1,), i32), sds((1,), i32), pool,
         sds((1, 8), i32)).compile()
-    return {"decode": decode, "prefill": prefill}, layer_leaf, \
-        dict(spec.kv_pool_writers)
+    programs = {"decode": decode, "prefill": prefill}
+    if mixed:
+        programs["mixed"] = jax.jit(
+            spec.mixed_paged_fn, donate_argnums=(7,)).lower(
+            params, sds((1, chunk), i32), sds((1,), i32), sds((1,), i32),
+            sds((1, 16), i32), sds((8,), i32), sds((8,), i32), pool,
+            sds((8, 16), i32)).compile()
+    return programs, layer_leaf, dict(spec.kv_pool_writers)
 
 
 def test_paged_programs_hold_nothing_of_the_pools_size(one_chip, monkeypatch):
@@ -750,10 +762,8 @@ def test_prefill_chunk_program_holds_nothing_as_wide_as_the_table(
         one_chip, jnp.bfloat16, chunk=512)
     assert writers["prefill_chunk"] == attention_dispatch.KV_POOL_WRITE_KERNEL
     text = programs["prefill"].as_text()
-    kernels = {line.split("=")[0].strip().lstrip("%").rsplit(".", 1)[0]
-               for line in text.splitlines()
-               if "custom-call(" in line and "tpu_custom_call" in line}
-    assert kernels == {"dstpu_kv_pool_write", "dstpu_paged_prefill"}
+    assert set(_mosaic_calls(text)) == {"dstpu_kv_pool_write",
+                                        "dstpu_paged_prefill"}
     assert _instructions_spanning(text, table_positions) == []
     large = _large_instructions(text, layer_leaf)
     assert [x for x in large if x[1] not in _NO_NEW_BUFFER] == []
@@ -761,6 +771,99 @@ def test_prefill_chunk_program_holds_nothing_as_wide_as_the_table(
                for n, opcode in large if opcode == "custom-call")
     assert programs["prefill"].memory_analysis().temp_size_in_bytes \
         < layer_leaf
+
+
+def _mosaic_calls(text):
+    """Names of a compiled program's Mosaic calls, the instruction suffix
+    (`.3`) dropped, one entry a call."""
+    return [line.split("=")[0].strip().lstrip("%").rsplit(".", 1)[0]
+            for line in text.splitlines()
+            if "custom-call(" in line and "tpu_custom_call" in line]
+
+
+def test_mixed_program_holds_both_walks_and_nothing_of_the_pools_size(
+        one_chip, monkeypatch):
+    """A chunk riding the decode call on the in-place pool (PERF.md §6, PR
+    33): the mixed program's layer writes each leaf TWICE (the chunk's rows,
+    the slots' rows: `dstpu_kv_pool_write`, aliased), attends the chunk with
+    `dstpu_paged_prefill` and the slots with `dstpu_paged_decode` (its work
+    list built once, outside the layer loop), and holds nothing of a pool
+    layer's size but those writes — PR 25's invariant, in the third
+    program."""
+    from deepspeed_tpu.ops import attention_dispatch
+    from deepspeed_tpu.platform import device
+    mesh_mod.clear_mesh()
+    monkeypatch.setattr(device, "on_tpu", lambda: True)   # what the chip sees
+    programs, layer_leaf, writers = _compile_paged_programs(
+        one_chip, jnp.bfloat16, chunk=512, mixed=True)
+    assert writers["mixed"] == attention_dispatch.KV_POOL_WRITE_KERNEL
+    text = programs["mixed"].as_text()
+    calls = _mosaic_calls(text)
+    assert sorted(calls) == ["dstpu_kv_pool_write"] * 4 + [
+        "dstpu_paged_decode", "dstpu_paged_prefill"]
+    large = _large_instructions(text, layer_leaf)
+    assert [x for x in large if x[1] not in _NO_NEW_BUFFER] == []
+    writes = [n for n, opcode in large if opcode == "custom-call"]
+    assert len(writes) == 4 and all(
+        n.startswith("dstpu_kv_pool_write") for n in writes), writes
+    assert programs["mixed"].memory_analysis().temp_size_in_bytes \
+        < layer_leaf
+    assert _instructions_spanning(text, 16 * 512) == []
+    _assert_decode_walk_is_built_once_a_token(text)
+
+
+# opcode -> instructions, fused ones included, of the guard model's decode
+# and 512-row prefill programs compiled for a described v5e on the in-place
+# pool: read on PR 32's tree AND on PR 33's (which put `_paged_attn_half`'s
+# write and attend into `_paged_write_attend` for the mixed program to call
+# twice), equal on both; at Mistral's real size the two trees' programs were
+# equal instruction for instruction (PERF.md §6, PR 33). A PR that means to
+# change these programs reads them again.
+_PARENT_OPCODES = {
+    "decode": {
+        "add": 31, "and": 3, "bitcast": 60, "broadcast": 74, "clamp": 4,
+        "compare": 19, "constant": 75, "convert": 46, "convolution": 5,
+        "copy": 13, "copy-done": 11, "copy-start": 11, "cosine": 1,
+        "custom-call": 9, "dynamic-slice": 11, "fusion": 55, "gather": 5,
+        "get-tuple-element": 53, "iota": 5, "maximum": 3, "minimum": 2,
+        "multiply": 30, "negate": 3, "or": 1, "pad": 7, "parameter": 160,
+        "reduce": 10, "reduce-window": 1, "reshape": 10, "rsqrt": 3,
+        "select": 18, "shift-right-logical": 1, "sign": 1, "sine": 1,
+        "slice": 17, "slice-done": 2, "slice-start": 2, "subtract": 4,
+        "tanh": 1, "transpose": 10, "tuple": 10, "while": 1},
+    "prefill": {
+        "add": 38, "and": 7, "bitcast": 53, "broadcast": 57, "clamp": 6,
+        "compare": 18, "constant": 81, "convert": 50, "convolution": 4,
+        "copy": 18, "copy-done": 12, "copy-start": 12, "cosine": 1,
+        "custom-call": 12, "dynamic-slice": 13, "fusion": 57, "gather": 7,
+        "get-tuple-element": 40, "iota": 7, "maximum": 2, "minimum": 1,
+        "multiply": 31, "negate": 5, "pad": 8, "parameter": 155,
+        "reduce": 11, "reshape": 16, "rsqrt": 3, "select": 17,
+        "shift-right-logical": 1, "sign": 1, "sine": 1, "slice": 12,
+        "slice-done": 6, "slice-start": 6, "subtract": 4, "tanh": 1,
+        "transpose": 14, "tuple": 8, "while": 1},
+}
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_chunk_only_and_decode_only_programs_are_the_parents(
+        one_chip, monkeypatch, program):
+    """The two programs that steps holding one kind of work still run take
+    the same branches through `_paged_attn_half` as before the mixed program
+    existed: same instructions, opcode for opcode, same Mosaic calls."""
+    from deepspeed_tpu.platform import device
+    mesh_mod.clear_mesh()
+    monkeypatch.setattr(device, "on_tpu", lambda: True)
+    programs, _, _ = _compile_paged_programs(one_chip, jnp.bfloat16,
+                                             chunk=512)
+    text = programs[program].as_text()
+    opcodes = collections.Counter(
+        found.group(3) for found in map(_HLO_LINE.match, text.splitlines())
+        if found)
+    assert dict(opcodes) == _PARENT_OPCODES[program]
+    assert sorted(set(_mosaic_calls(text))) == {
+        "decode": ["dstpu_kv_pool_write", "dstpu_paged_decode"],
+        "prefill": ["dstpu_kv_pool_write", "dstpu_paged_prefill"]}[program]
 
 
 def _compile_routed_paged_programs(one_chip, window):
@@ -852,25 +955,30 @@ def test_routed_paged_programs_hold_nothing_of_the_pools_or_experts_size(
 # ----------------------------------------------------------------------
 
 
-def _compile_two_kind_paged_programs(one_chip, window):
+def _compile_two_kind_paged_programs(one_chip, window, periods=2,
+                                     mixed=False):
     """The paged prefill program and the decode WINDOW program of a
-    K-EXAONE-shaped model (a dense window layer, then two periods of window,
-    window, window, full with 8 of 32 experts held) at the served tile
-    widths: full-kind blocks of 512, window-kind rings of 128-token blocks."""
+    K-EXAONE-shaped model (a dense window layer, then `periods` periods of
+    window, window, window, full with 8 of 32 experts held) at the served
+    tile widths: full-kind blocks of 512, window-kind rings of 128-token
+    blocks. `mixed`: the mixed program too (a chunk and the slots' decode
+    rows in one call)."""
     from deepspeed_tpu.inference.kv_cache import ring_blocks
     from deepspeed_tpu.models import exaone_moe as em
 
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    layers = (em.WINDOW,) + (em.WINDOW, em.WINDOW, em.WINDOW, em.FULL) * 2
+    layers = (em.WINDOW,) + (em.WINDOW, em.WINDOW, em.WINDOW,
+                             em.FULL) * periods
     cfg = em.ExaoneMoEConfig(
         vocab_size=512, n_layer=len(layers), n_head=16, n_kv_head=8,
         d_model=1024, attn_head_dim=128, d_ff=1024, d_ff_dense=2048,
         max_seq_len=8192, sliding_window=128, tie_embeddings=False,
         num_experts=32, experts_held=(8, 8), top_k=4, norm_topk_prob=True,
         routed_scaling_factor=2.5, layer_types=layers,
-        mlp_layer_types=(em.DENSE,) + (em.SPARSE,) * 8, pattern_period=4,
+        mlp_layer_types=(em.DENSE,) + (em.SPARSE,) * (len(layers) - 1),
+        pattern_period=4,
         window_block=128, use_flash_attention=True, dtype=jnp.bfloat16,
         remat=False)
     shapes = jax.eval_shape(em.exaone_moe_init_fn(cfg, dtype=jnp.bfloat16),
@@ -909,8 +1017,54 @@ def _compile_two_kind_paged_programs(one_chip, window):
     prefill = jax.jit(spec.prefill_paged_fn, donate_argnums=(4,)).lower(
         params, sds((1, chunk), i32), sds((1,), i32), sds((1,), i32), pool,
         (sds((1, 16), i32), sds((1, 64), i32))).compile()
-    return {"decode": decode, "prefill": prefill}, leaves, \
-        dict(spec.kv_pool_writers), dict(spec.paged_attn_programs)
+    programs = {"decode": decode, "prefill": prefill}
+    if mixed:
+        programs["mixed"] = jax.jit(
+            spec.mixed_paged_fn, donate_argnums=(7,)).lower(
+            params, sds((1, chunk), i32), sds((1,), i32), sds((1,), i32),
+            (sds((1, 16), i32), sds((1, 64), i32)), sds((slots,), i32),
+            sds((slots,), i32), pool,
+            (sds((slots, 16), i32), sds((slots, 64), i32))).compile()
+    return programs, leaves, dict(spec.kv_pool_writers), \
+        dict(spec.paged_attn_programs)
+
+
+@pytest.mark.parametrize("periods", [1, 2])
+def test_two_kind_mixed_program_holds_nothing_of_either_pools_size(
+        one_chip, monkeypatch, periods):
+    """The mixed program on a pool of two kinds (PERF.md §6, PR 33): both
+    kinds written twice a layer by the aliased `dstpu_kv_pool_write`, the
+    chunk walked by `dstpu_paged_prefill` and the slots by
+    `dstpu_paged_decode` from either kind's window, and nothing half as large
+    as a layer's leaf made by anything else. One period is the benchmark's
+    five layers, whose period scan XLA unrolls. The trap this holds: the
+    slots' rows do not depend on the chunk's attention, so without the
+    barrier between the two groups (`_paged_attn_half`) XLA may write them
+    before the chunk's walk has read the pool and keep the walk's input by
+    COPYING a leaf (on the chip at the served size: two copies of 1.5 GB a
+    mixed token, 23% of the cell's time)."""
+    from deepspeed_tpu.ops import attention_dispatch
+    from deepspeed_tpu.platform import device
+    mesh_mod.clear_mesh()
+    monkeypatch.setattr(device, "on_tpu", lambda: True)
+    programs, leaves, writers, attention = _compile_two_kind_paged_programs(
+        one_chip, window=4, periods=periods, mixed=True)
+    assert writers["mixed"] == attention_dispatch.KV_POOL_WRITE_KERNEL
+    assert attention["mixed/prefill_chunk"] == "paged_prefill_kernel"
+    assert attention["mixed/paged_decode"] == "paged_kernel"
+    text = programs["mixed"].as_text()
+    half = min(leaves.values()) // 2
+    large = _large_instructions(text, half)
+    assert [x for x in large if x[1] not in _NO_NEW_BUFFER] == []
+    assert {n.rsplit(".", 1)[0] for n, opcode in large
+            if opcode == "custom-call"} == {"dstpu_kv_pool_write"}
+    assert programs["mixed"].memory_analysis().temp_size_in_bytes < half
+    calls = collections.Counter(_mosaic_calls(text))
+    layers = 1 + 4 * periods if periods == 1 else 5     # unrolled | a period
+    assert calls["dstpu_kv_pool_write"] == 4 * layers
+    assert calls["dstpu_paged_prefill"] == calls["dstpu_paged_decode"] \
+        == layers
+    assert "dstpu_kv_pool_gather" not in text
 
 
 def test_two_kind_paged_programs_hold_nothing_of_either_pools_size(

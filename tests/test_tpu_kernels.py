@@ -616,6 +616,96 @@ def test_routed_moe_paged_programs_on_chip():
                 assert list(done[i].tokens) == tokens, (window, i)
 
 
+@pytest.mark.parametrize("cell", ["mistral", "olmoe"])
+def test_mixed_call_matches_the_two_calls_at_the_served_shapes(cell):
+    """A chunk riding the decode call (`mixed_paged_fn`) at the served widths
+    — Mistral's 512-row chunk beside 32 slots over 32-block tables, OLMoE's
+    256-row chunk beside 64 slots through 64 experts (2560 assignments,
+    whole 128-row tiles) — two layers deep: its logits and the pool it
+    leaves against the chunk program followed by the decode program, on a
+    shared state (free-running tokens of two programs are not comparable at
+    real widths). The in-place pool and both walks are what the chip's rule
+    builds."""
+    from deepspeed_tpu.models.gpt import (GPTConfig, gpt_init_fn,
+                                          make_gpt_decode_model)
+    from deepspeed_tpu.models.moe_gpt import (MoEGPTConfig,
+                                              make_moe_gpt_decode_model,
+                                              moe_gpt_init_fn)
+    if cell == "mistral":
+        cfg = GPTConfig(vocab_size=32000, n_layer=2, n_head=32, n_kv_head=8,
+                        d_model=4096, d_ff=14336, max_seq_len=16384,
+                        use_rotary=True, rope_theta=1e6, use_rmsnorm=True,
+                        use_swiglu=True, tie_embeddings=False,
+                        dtype=jnp.bfloat16, remat=False)
+        params = jax.jit(gpt_init_fn(cfg, dtype=jnp.bfloat16))(
+            jax.random.PRNGKey(3))
+        spec = make_gpt_decode_model(cfg, name="chip", params=params)
+        slots, nb, chunk, blocks = 32, 32, 512, 100
+    else:
+        cfg = MoEGPTConfig(vocab_size=50304, n_layer=2, n_head=16,
+                           n_kv_head=16, d_model=2048, d_ff=1024,
+                           max_seq_len=1536, use_rotary=True,
+                           use_rmsnorm=True, use_swiglu=True, qk_norm=True,
+                           tie_embeddings=False, num_experts=64, top_k=8,
+                           norm_topk_prob=False, moe_freq=1,
+                           use_flash_attention=True, dtype=jnp.bfloat16,
+                           remat=False)
+        params = jax.jit(moe_gpt_init_fn(cfg, dtype=jnp.bfloat16))(
+            jax.random.PRNGKey(3))
+        spec = make_moe_gpt_decode_model(cfg, params=params, name="chip")
+        slots, nb, chunk, blocks = 64, 3, 256, 130
+    rng = np.random.default_rng(7)
+    # the slots hold contexts of 1..nb-1 blocks; the chunk's slot is the last
+    # row's, prefilled to `start` already (zeros there: K/V of padding)
+    tables = np.zeros((slots, nb), np.int32)
+    free = iter(range(1, blocks))
+    live = min(slots - 1, (blocks - 1 - nb) // 2)
+    pos = np.zeros((slots,), np.int32)
+    for row in range(live):
+        need = 1 + row % 2
+        tables[row, :need] = [next(free) for _ in range(need)]
+        pos[row] = rng.integers(1, need * 512 - 1)
+    chunk_table = np.zeros((1, nb), np.int32)
+    start = 512 if cell == "mistral" else 0
+    chunk_table[0, :(start + chunk - 1) // 512 + 1] = [
+        next(free) for _ in range((start + chunk - 1) // 512 + 1)]
+    tok = jnp.asarray(rng.integers(0, cfg.vocab_size, (slots,)), jnp.int32)
+    toks = jnp.asarray(rng.integers(0, cfg.vocab_size, (1, chunk)), jnp.int32)
+    pos, tables = jnp.asarray(pos), jnp.asarray(tables)
+    chunk_args = (toks, jnp.asarray([start], jnp.int32),
+                  jnp.asarray([chunk - 7], jnp.int32))
+
+    def pool():
+        fresh = spec.init_paged_pool(blocks, 512, jnp.bfloat16)
+        key = jax.random.PRNGKey(11)
+        return {name: (jax.random.normal(key, leaf.shape, jnp.bfloat16) * 0.5)
+                .at[:, 0].set(0) for name, leaf in fresh.items()}
+
+    prefill = jax.jit(spec.prefill_paged_fn, donate_argnums=(4,))
+    decode = jax.jit(spec.decode_paged_fn, donate_argnums=(3,))
+    mixed = jax.jit(spec.mixed_paged_fn, donate_argnums=(7,))
+    first, two, *_ = prefill(params, *chunk_args, pool(),
+                             jnp.asarray(chunk_table))
+    rows, two, *_ = decode(params, tok, pos, two, tables)
+    both, one, *_ = mixed(params, *chunk_args, jnp.asarray(chunk_table), tok,
+                          pos, pool(), tables)
+    assert spec.kv_pool_writers["mixed"] == "dstpu_kv_pool_write"
+    assert spec.paged_attn_programs["mixed/prefill_chunk"] \
+        == "paged_prefill_kernel"
+    assert spec.paged_attn_programs["mixed/paged_decode"] == "paged_kernel"
+
+    def close(a, b):
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        assert np.isfinite(a).all()
+        np.testing.assert_allclose(a, b, rtol=2e-2,
+                                   atol=2e-2 * float(np.abs(b).max()))
+
+    close(both[0], first[0])
+    close(both[1:1 + live], rows[:live])
+    for leaf in one:
+        close(one[leaf][:, 1:], two[leaf][:, 1:])
+
+
 def test_quant_int4_kernels_refuse_on_tpu():
     """Recorded state, not a TODO: the packed-nibble kernels need stride-2
     lane indexing, which the Pallas TPU lowering refuses; the wrappers say
