@@ -41,6 +41,10 @@ fixed RING of `ring_blocks(...)` physical blocks a window layer, addressed
 `logical block mod ring` (`ring_tables`: the same `[slots, table]` form the
 kernels already take, so a walk needs a lower bound and nothing else), never
 allocated and never freed; its block 0 is a trash block too.
+
+A layer with RECURRENT state (a state-space layer) keeps a third kind
+(`CacheKind(state=True)`): one state a slot whatever the context's length,
+beside the blocks of the model's attention layers — see `CacheKind`.
 """
 
 import collections
@@ -60,12 +64,25 @@ class CacheKind:
     holding either the whole context in allocator blocks (`window` 0; `block`
     is then the engine's `kv_block_size`) or the last `window` positions in
     a per-slot ring of `block`-token blocks; `leaves` names its K and V
-    leaves in the pool pytree."""
+    leaves in the pool pytree. `state`: the kind keeps no token at all but
+    one recurrent STATE a slot (`block` and `window` 0; `leaves` its state
+    leaves, `[layers, 1 + slots, ...]` with row 0 the trash row): nothing is
+    allocated, freed or walked, a program finds a slot's row by `state_rows`,
+    zeroes it where a prompt begins (`start_pos == 0`) and hands it from one
+    call to the next."""
     name: str
     layers: int
     block: int
     window: int = 0
     leaves: tuple = ("k", "v")
+    state: bool = False
+
+
+def state_rows(slots: int) -> np.ndarray:
+    """The state kind's "tables", fixed for an engine's lifetime: slot s's
+    state is row `1 + s` of each of its layers (0 is the trash row).
+    `[slots, 1]` int32."""
+    return ring_tables(slots, 1, 1)
 
 
 def ring_blocks(window: int, block: int, chunk: int, decode_steps: int) -> int:

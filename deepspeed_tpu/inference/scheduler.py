@@ -64,6 +64,7 @@ from deepspeed_tpu.inference.engine import sample_logits
 from deepspeed_tpu.inference.kv_cache import (BlockAllocator, TRASH_BLOCK,
                                               blocks_needed, max_written_pos,
                                               ring_blocks, ring_tables,
+                                             state_rows,
                                               transplant_blocks)
 from deepspeed_tpu.inference.spec_decode import accept_greedy, make_drafter
 from deepspeed_tpu.telemetry import Telemetry
@@ -300,43 +301,64 @@ class ServingEngine:
                 f"contract (make_gpt_decode_model provides it)")
         num_blocks = int(scfg.num_kv_blocks or
                          (self.max_slots * self.nb + 1))
-        # a pool of two kinds (`DecodeModelSpec.paged_cache_kinds`): the
-        # window kind's per-slot rings and their tables, fixed for this
-        # engine's lifetime. What is not built on such a pool is refused
-        # HERE, with the reason, rather than run wrong.
+        # a pool of two kinds (`DecodeModelSpec.paged_cache_kinds`): beside
+        # the allocator's blocks a window kind's per-slot rings, or a state
+        # kind's per-slot rows, and their tables, fixed for this engine's
+        # lifetime. What is not built on such a pool is refused HERE, with
+        # the reason, rather than run wrong.
         self.cache_kinds = None
+        self.window_kind = self.state_kind = None
         self.ring = 0
-        self.ring_tables = None
+        self.ring_tables = None     # the second kind's tables, a row a slot
         kinds_of = getattr(spec, "paged_cache_kinds", None)
         if kinds_of is not None and not self.streamed:
             self.cache_kinds = kinds_of(bs)
-            _full, wkind = self.cache_kinds
-            unbuilt = {
-                "enable_prefix_caching": (
-                    scfg.enable_prefix_caching,
-                    "a registered block names a full layer's blocks only; "
-                    "the window layers' rings of the matching prefix are "
-                    "gone with the slot that wrote them"),
-                "kv_cache_dtype int8": (
-                    self.kv_quant,
-                    "the window kind's rings have no scale leaves and the "
-                    "windowed walks no dequantizing twin"),
-                "spec_decode": (
-                    self.spec_on,
-                    "a verify chunk writes k drafts ahead into a ring whose "
-                    "size counts prefill chunks and decode windows only"),
-            }
-            for what, (asked, why) in unbuilt.items():
-                if asked:
+            _full, second = self.cache_kinds
+            if second.state:
+                self.state_kind = second
+                beside = "per-slot recurrent state"
+                unbuilt = {
+                    "enable_prefix_caching":
+                        "a hit would need the state as it was at the block "
+                        "boundary, and a slot keeps only the state after "
+                        "its last position",
+                    "kv_cache_dtype int8":
+                        "a recurrent state has no scale leaves",
+                    "spec_decode":
+                        "a rejected draft would need the state rolled back, "
+                        "and a decode token rewrites it in place"}
+            else:
+                self.window_kind = second
+                beside = "window rings"
+                unbuilt = {
+                    "enable_prefix_caching":
+                        "a registered block names a full layer's blocks "
+                        "only; the window layers' rings of the matching "
+                        "prefix are gone with the slot that wrote them",
+                    "kv_cache_dtype int8":
+                        "the window kind's rings have no scale leaves and "
+                        "the windowed walks no dequantizing twin",
+                    "spec_decode":
+                        "a verify chunk writes k drafts ahead into a ring "
+                        "whose size counts prefill chunks and decode "
+                        "windows only"}
+            asked = {"enable_prefix_caching": scfg.enable_prefix_caching,
+                     "kv_cache_dtype int8": self.kv_quant,
+                     "spec_decode": self.spec_on}
+            for what, why in unbuilt.items():
+                if asked[what]:
                     raise ValueError(
                         f"model spec '{spec.name}' keeps a KV pool of two "
-                        f"kinds (window rings beside full-context blocks): "
+                        f"kinds ({beside} beside full-context blocks): "
                         f"{what} is not built for it — {why}")
-            self.ring = ring_blocks(wkind.window, wkind.block, self.chunk,
-                                    self.window)
-            self.ring_tables = ring_tables(
-                self.max_slots, -(-self.max_context // wkind.block),
-                self.ring)
+            if second.state:
+                self.ring_tables = state_rows(self.max_slots)
+            else:
+                self.ring = ring_blocks(second.window, second.block,
+                                        self.chunk, self.window)
+                self.ring_tables = ring_tables(
+                    self.max_slots, -(-self.max_context // second.block),
+                    self.ring)
 
         # telemetry (deepspeed_tpu/telemetry/): TTFT/TPOT/queue-wait/e2e
         # histograms + queue/slot/pool gauges + per-phase spans — built
@@ -422,6 +444,9 @@ class ServingEngine:
                     f"k_scale/v_scale leaves for dtype int8 — it does not "
                     f"implement the quantized-pool contract "
                     f"(init_paged_kv_pool in models/gpt.py is the reference)")
+        elif self.state_kind is not None:
+            pool = spec.init_paged_pool(num_blocks, bs, jnp.dtype(kvd),
+                                        state_rows=1 + self.max_slots)
         elif self.cache_kinds is not None:
             pool = spec.init_paged_pool(
                 num_blocks, bs, jnp.dtype(kvd),
@@ -438,6 +463,12 @@ class ServingEngine:
             self.prefix_cache = PrefixCache(
                 self.allocator, bs,
                 fingerprint=spec.cache_fingerprint or spec.name)
+        # what a decode token of one slot reads + writes of a state kind's
+        # state proper (its first leaf), all layers
+        self._state_token_bytes = 0
+        if self.state_kind is not None:
+            leaf = self.pool[self.state_kind.leaves[0]]
+            self._state_token_bytes = 2 * int(leaf.nbytes // leaf.shape[1])
         self.tables = np.full((self.max_slots, self.nb), TRASH_BLOCK, np.int32)
         self.slots = [_Slot(i) for i in range(self.max_slots)]
         self.queue = collections.deque()
@@ -965,11 +996,13 @@ class ServingEngine:
 
     def _refuse_transplant(self):
         if self.cache_kinds is not None:
+            what = "a layer's recurrent state" if self.state_kind is not None \
+                else "a window layer's ring"
             raise ValueError(
                 f"model spec '{self.engine.model_spec.name}' keeps a KV pool "
                 f"of two kinds: block transplant (prefill-only slots, "
                 f"handoff) is not built for it — `transplant_blocks` copies "
-                f"allocator blocks, and a window layer's ring belongs to "
+                f"allocator blocks, and {what} belongs to "
                 f"the slot, not to the request")
 
     def _tables_arg(self, tables, rows=None):
@@ -977,7 +1010,7 @@ class ServingEngine:
         for a pool of two kinds the pair (full tables, ring tables) — the
         ring rows of `rows` (slot indices; None = every slot) with those of
         slots whose full table is all trash (not in this call) at the ring
-        kind's trash block."""
+        kind's trash block (a state kind: its rows, and the trash row)."""
         if self.cache_kinds is None:
             return tables
         ring = self.ring_tables if rows is None else self.ring_tables[rows]
@@ -1653,8 +1686,9 @@ class ServingEngine:
         st.begin_step()
         compiled0 = self._compiled_programs()
         chunks0, tokens0 = self.prefill_chunks, self.tokens_generated
-        walk = (0, 0, 0, 0)  # the decode kernel's (live blocks, grid steps,
-                             # window layers' live blocks, ... unwindowed)
+        walk = (0, 0, 0, 0, 0)  # the decode kernel's (live blocks, grid
+                             # steps, window layers' live blocks, ...
+                             # unwindowed) and a state kind's bytes
         reach = [0, 0, 0, 0]  # the prefill kernel's (live, table) blocks
                               # and the window layers' (live, unwindowed)
 
@@ -1748,6 +1782,9 @@ class ServingEngine:
                     admitted=admitted,
                     prefill_chunks=self.prefill_chunks - chunks0,
                     fused_chunks=len(riding),
+                    ssm_state_bytes=walk[4],
+                    ssm_chunk_tokens=(self.prefill_chunks - chunks0)
+                    * self.chunk * (self.state_kind is not None),
                     decoding=len(dec),
                     emitted=self.tokens_generated - tokens0,
                     queued=len(self.queue),
@@ -1812,10 +1849,10 @@ class ServingEngine:
             full = paged_prefill_live_blocks(
                 start, self.chunk, self.block_size, table)
             reach = (full, table, 0, 0)
-            if self.cache_kinds is not None:
+            if self.window_kind is not None:
                 # what a window layer's walk visits, in ITS blocks, of
                 # what the same chunk's walk would visit with no window
-                wkind = self.cache_kinds[1]
+                wkind = self.window_kind
                 width = self.ring_tables.shape[1]
                 reach = (full, table, paged_prefill_live_blocks(
                     start, self.chunk, wkind.block, width, wkind.window),
@@ -1980,22 +2017,23 @@ class ServingEngine:
         tokens for the slots `dec`, and what it is launched with, a layer:
         (live (slot, block) pairs, grid steps, and for a pool of two kinds
         the pairs a WINDOW layer's walk visits and the pairs it would visit
-        with no window, both in the window kind's blocks), summed over the
-        call's tokens."""
+        with no window, both in the window kind's blocks, and the bytes of
+        a state kind's state the call's tokens read + write), summed over
+        the call's tokens."""
         from deepspeed_tpu.ops.pallas.decode_attention import (
             paged_decode_walk_steps, window_first_block)
         at = pos[[s.idx for s in dec]] + np.arange(win)[:, None]
         live = at // self.block_size + 1               # [win, slots]
         walk = (int(live.sum()),
                 sum(paged_decode_walk_steps(n) for n in live.sum(axis=1)),
-                0, 0)
-        if self.cache_kinds is not None:
-            wkind = self.cache_kinds[1]
+                0, 0, len(dec) * win * self._state_token_bytes)
+        if self.window_kind is not None:
+            wkind = self.window_kind
             whole = at // wkind.block + 1
             walk = walk[:2] + (
                 int((whole - window_first_block(
                     at, wkind.block, wkind.window)).sum()),
-                int(whole.sum()))
+                int(whole.sum())) + walk[4:]
         return walk
 
     def _emit_window(self, dec, nxt, win, ph, finished):
@@ -2159,6 +2197,7 @@ class ServingEngine:
         if self.cache_kinds is not None:
             # a kind of layer: its layers, its blocks (a window layer's are
             # the slots' rings and one trash block) and what they hold
+            # (a state kind's "blocks": its rows, one a slot and the trash)
             out["kv_pool_kinds"] = {
                 kind.name: {
                     "layers": kind.layers, "block": kind.block,
@@ -2167,7 +2206,9 @@ class ServingEngine:
                     "bytes": int(sum(self.pool[leaf].nbytes
                                      for leaf in kind.leaves))}
                 for kind in self.cache_kinds}
-            out["kv_pool_kinds"]["window"]["ring_blocks_per_slot"] = self.ring
+            if self.window_kind is not None:
+                out["kv_pool_kinds"]["window"]["ring_blocks_per_slot"] = \
+                    self.ring
         if self.step_counter_names:
             # the model's own counters (routed experts: calls, assignments,
             # active experts, the largest expert's load), summed over layers

@@ -1241,26 +1241,31 @@ def decode_rows(block_tables, positions):
     return block_tables, positions[:, 0]
 
 
-def make_mixed_paged_fn(cfg, layers_paged):
+def make_mixed_paged_fn(cfg, layers_paged, chunk_valid=False):
     """A family's `DecodeModelSpec.mixed_paged_fn` from its layer loop
     `layers_paged(params, x, pool, block_tables, positions) -> (x, pool,
     *counts)`, the one its `prefill_paged_fn` and `decode_paged_fn` run:
     a chunk and a decode token a slot through embedding, layers, final norm
     and head as one tensor, so each weight is read once where the two
     programs read it twice. Logits [1 + S, V]: the chunk's `last_idx` row,
-    then the slots' rows."""
+    then the slots' rows. `chunk_valid`: the loop also takes `valid=`, the
+    chunk's real positions `last_idx + 1` (a layer with recurrent state must
+    not run it over the chunk's padding). Further keywords of a call go to
+    the loop as they are (a routed family's `routing=True`: what it returns
+    beside the counters follows them)."""
 
     def mixed_paged_fn(params, chunk_tokens, start_pos, last_idx, chunk_table,
-                       token, pos, pool, block_tables):
+                       token, pos, pool, block_tables, **loop):
         C = chunk_tokens.shape[1]
         tokens = jnp.concatenate([chunk_tokens, token[None]], axis=1)
         positions = jnp.concatenate(
             [start_pos[:, None] + jnp.arange(C, dtype=jnp.int32)[None],
              pos[None]], axis=1)
         x = _embed(params, tokens, positions, cfg)
+        valid = dict(valid=last_idx + 1) if chunk_valid else {}
         x, pool, *counts = layers_paged(
             params, x, pool, mixed_tables(chunk_table, block_tables),
-            positions)
+            positions, **valid, **loop)
         last = jnp.take_along_axis(x[:, :C], last_idx[:, None, None], axis=1)
         logits = _lm_head(params, jnp.concatenate([last, x[:, C:]], axis=1),
                           cfg)[0]

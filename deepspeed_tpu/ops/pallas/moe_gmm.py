@@ -130,7 +130,13 @@ def _gmm_kernel(group_ref, tile_ref, starts_ref, ends_ref, pairs_ref,
 
 
 def _col_tile(N):
-    for tn in (_COL_TILE, 512, 256, 128):
+    for tn in (_COL_TILE, 512, 256):
+        if N % tn == 0:
+            return tn
+    # an odd number of lane tiles (2688 = 21 x 128): the widest whole-tile
+    # divisor (896), not 128 — a step's weight panel is then 7 tiles wide
+    # and the row tile is fetched 3 times, not 21
+    for tn in range(_COL_TILE - 128, 0, -128):
         if N % tn == 0:
             return tn
     return N

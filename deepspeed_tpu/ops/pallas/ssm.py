@@ -1,0 +1,290 @@
+"""The per-slot recurrent state of state-space (Mamba-2) layers, touched in
+place (Pallas) — and the chunked form of the recurrence a prefill chunk runs.
+
+    S_t = a_t S_(t-1) + dt_t * x_t (outer) B_t        a_t = exp(dt_t * A_h)
+    y_t = S_t C_t                                     (+ D_h x_t, the caller's)
+
+per head h, `S` in R^(P x N) float32, `x_t` in R^P, `B_t`, `C_t` in R^N shared
+by the heads of a group. A sequence keeps ONE `S` a layer whatever its
+length, so the serving pool holds it per SLOT (`inference/kv_cache.py`:
+`CacheKind(state=True)`): `[rows, H, P, N]` with `rows = layers * (1 + slots)`
+flat, row 0 of a layer its trash row, CARRIED through the layer loop and
+touched only by the calls here — the rule of `ops/pallas/kv_pool.py`: an XLA
+gather or scatter on a carried buffer copies it whole.
+
+- `ssm_update` (`dstpu_ssm_update`): a decode token of every row. A grid step
+  owns one row's whole state `[H, P, N]` (4 MiB at 128 x 64 x 128), reads it,
+  applies the recurrence and writes it back where it lies
+  (`input_output_aliases`): ONE read and ONE write of the state a token a
+  layer; its roofline is HBM bandwidth (`benchmark/roofline_ssm.py`).
+  The small per-row operands arrive with P on the sublanes and the heads on
+  the lanes (`[B, P, H]`), so a head's column broadcasts along the lanes —
+  the one shuffle Mosaic is asked for.
+- `state_read` / `state_write` (`dstpu_ssm_state_read|write`): a few rows
+  copied out of, or into, a carried buffer by index — what a prefill chunk
+  does with its slot's state and what both groups do with the convolution's
+  tail.
+- `ssm_chunk_scan`: the same recurrence over a whole chunk in its chunked
+  (matmul) form, plain `jax.numpy` under the caller's `ssm/scan` scope.
+  `dt = 0` at a position leaves the state alone there (decay 1, no input): a
+  chunk's padded tail.
+
+Off the TPU every entry runs its `jax.numpy` twin (`*_reference`), which is
+also the kernels' test oracle (`interpret=True` forces the interpreter). On
+a TPU there is no twin: a state the update kernel does not address raises.
+"""
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from deepspeed_tpu.platform.device import pallas_interpret
+
+KERNEL_NAME = "dstpu_ssm_update"
+# a row's state in and out, double-buffered: 4 x 4 MiB at the published
+# widths, past Mosaic's default scoped limit
+_VMEM_LIMIT = 48 * 1024 * 1024
+
+
+def state_in_place_supported(state) -> bool:
+    """Shapes the kernels address: float32 `[rows, H, P, N]` whose `(P, N)`
+    face is whole native tiles."""
+    return (state.ndim == 4 and state.dtype == jnp.float32
+            and state.shape[2] % 8 == 0 and state.shape[3] % 128 == 0)
+
+
+def _mode(interpret, state=None):
+    """(run the kernel?, in the interpreter?): `interpret` given forces the
+    kernel; else the kernel on a TPU and the `jax.numpy` twin everywhere
+    else. On a TPU a `state` the update kernel does not address is refused:
+    the twin there is a scatter that copies the whole carried state a
+    token."""
+    if interpret is not None:
+        return True, bool(interpret)
+    if pallas_interpret():
+        return False, False
+    if state is not None and not state_in_place_supported(state):
+        raise ValueError(
+            f"dstpu_ssm_update addresses float32 [rows, H, P, N] state whose "
+            f"(P, N) face is whole (8, 128) tiles, not {state.dtype}"
+            f"{list(state.shape)}: on a TPU there is no other in-place path")
+    return True, False
+
+
+# ----------------------------------------------------------------------
+# the decode token
+# ----------------------------------------------------------------------
+
+
+def ssm_update_reference(state, rows, a, dtx, B, C):
+    """The oracle and the off-TPU path, in `ssm_update`'s terms."""
+    H = state.shape[1]
+    per = H // B.shape[1]
+    Bh = jnp.repeat(B.astype(jnp.float32), per, axis=1)        # [b, H, N]
+    Ch = jnp.repeat(C.astype(jnp.float32), per, axis=1)
+    new = a[:, :, None, None] * state[rows] \
+        + dtx[:, :, :, None] * Bh[:, :, None, :]
+    y = jnp.sum(new * Ch[:, :, None, :], axis=-1)
+    return y, state.at[rows].set(new.astype(state.dtype))
+
+
+def _update_kernel(rows_ref, a_ref, dtx_ref, b_ref, c_ref, s_ref,
+                   y_ref, out_ref, *, heads, groups):
+    del rows_ref
+    per = heads // groups
+    P = s_ref.shape[2]
+    a = a_ref[0]                            # [P, H]: a head a lane
+    dtx = dtx_ref[0]
+    lane = jax.lax.broadcasted_iota(jnp.int32, (P, heads), 1)
+    y = jnp.zeros((P, heads), jnp.float32)
+    for h in range(heads):
+        g = h // per
+        new = a[:, h:h + 1] * s_ref[0, h] \
+            + dtx[:, h:h + 1] * b_ref[0, g:g + 1, :]
+        out_ref[0, h] = new
+        col = jnp.sum(new * c_ref[0, g:g + 1, :], axis=-1, keepdims=True)
+        y = jnp.where(lane == h, col, y)
+    y_ref[0] = y
+
+
+def ssm_update(state, rows, a, dtx, B, C, interpret=None):
+    """One token of the recurrence for b rows, the state updated IN PLACE.
+
+    state: `[M, H, P, N]` float32 (one layer's, or every layer's flat);
+    rows: `[b]` int32, row i's state is `state[rows[i]]` (rows that share an
+    index — dead slots at a trash row — leave garbage there); a: `[b, H]`
+    float32 decay `exp(dt * A)`; dtx: `[b, H, P]` float32 `dt * x`; B, C:
+    `[b, G, N]`, head h reads group `h // (H / G)`. Returns (y `[b, H, P]`
+    float32 `= S_new C`, state)."""
+    use, interpret = _mode(interpret, state)
+    rows = rows.astype(jnp.int32)
+    a, dtx = a.astype(jnp.float32), dtx.astype(jnp.float32)
+    if not use:
+        return ssm_update_reference(state, rows, a, dtx, B, C)
+    M, H, P, N = state.shape
+    b, G = B.shape[:2]
+    # P on the sublanes, a head a lane: a head's column broadcasts along N
+    a_t = jnp.broadcast_to(a[:, None, :], (b, P, H))
+    dtx_t = jnp.swapaxes(dtx, 1, 2)
+    small = pl.BlockSpec((1, P, H), lambda i, rows_ref: (i, 0, 0))
+    group = pl.BlockSpec((1, G, N), lambda i, rows_ref: (i, 0, 0))
+    whole = pl.BlockSpec((1, H, P, N), lambda i, rows_ref: (rows_ref[i], 0,
+                                                            0, 0))
+    y, state = pl.pallas_call(
+        functools.partial(_update_kernel, heads=H, groups=G),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(b,),
+            in_specs=[small, small, group, group, whole],
+            out_specs=[small, whole]),
+        out_shape=[jax.ShapeDtypeStruct((b, P, H), jnp.float32),
+                   jax.ShapeDtypeStruct(state.shape, state.dtype)],
+        # operands: rows, a, dtx, B, C, state
+        input_output_aliases={5: 1},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=_VMEM_LIMIT),
+        interpret=interpret,
+        name=KERNEL_NAME,
+    )(rows, a_t, dtx_t, B.astype(jnp.float32), C.astype(jnp.float32), state)
+    return jnp.swapaxes(y, 1, 2), state
+
+
+# ----------------------------------------------------------------------
+# rows of a carried buffer, by index
+# ----------------------------------------------------------------------
+
+
+def _copy_kernel(rows_ref, src_ref, out_ref):
+    del rows_ref
+    out_ref[...] = src_ref[...]
+
+
+def _write_kernel(rows_ref, new_ref, buf_ref, out_ref):
+    del rows_ref, buf_ref
+    out_ref[...] = new_ref[...]
+
+
+def _row_block(buf):
+    return (1,) + buf.shape[1:], (0,) * (buf.ndim - 1)
+
+
+def state_read(buf, rows, interpret=None):
+    """`buf[rows]` (`[b, ...]`) of a carried `[M, ...]` buffer, the named
+    rows the only part of it that is touched."""
+    use, interpret = _mode(interpret)
+    if not use:
+        return buf[rows]
+    block, rest = _row_block(buf)
+    return pl.pallas_call(
+        _copy_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(rows.shape[0],),
+            in_specs=[pl.BlockSpec(block, lambda i, r: (r[i],) + rest)],
+            out_specs=pl.BlockSpec(block, lambda i, r: (i,) + rest)),
+        out_shape=jax.ShapeDtypeStruct(rows.shape + buf.shape[1:], buf.dtype),
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT),
+        interpret=interpret,
+        name="dstpu_ssm_state_read",
+    )(rows.astype(jnp.int32), buf)
+
+
+def state_write(buf, rows, new, interpret=None):
+    """`buf.at[rows].set(new)` IN PLACE on a carried `[M, ...]` buffer (rows
+    that share an index: one of them wins)."""
+    use, interpret = _mode(interpret)
+    if not use:
+        return buf.at[rows].set(new.astype(buf.dtype))
+    block, rest = _row_block(buf)
+    return pl.pallas_call(
+        _write_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(rows.shape[0],),
+            in_specs=[pl.BlockSpec(block, lambda i, r: (i,) + rest),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec(block, lambda i, r: (r[i],) + rest)),
+        out_shape=jax.ShapeDtypeStruct(buf.shape, buf.dtype),
+        # operands: rows, new, buf
+        input_output_aliases={2: 0},
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT),
+        interpret=interpret,
+        name="dstpu_ssm_state_write",
+    )(rows.astype(jnp.int32), new.astype(buf.dtype), buf)
+
+
+# ----------------------------------------------------------------------
+# a whole chunk
+# ----------------------------------------------------------------------
+
+
+def ssm_scan_reference(x, dt, A, B, C, state):
+    """The recurrence a position at a time (`lax.scan`), float32: the
+    chunked form's oracle. Shapes as `ssm_chunk_scan`."""
+    per = x.shape[2] // B.shape[2]
+    f32 = lambda v: v.astype(jnp.float32)
+
+    def step(S, inputs):
+        x_t, dt_t, B_t, C_t = inputs
+        Bh, Ch = (jnp.repeat(v, per, axis=1) for v in (B_t, C_t))
+        S = jnp.exp(dt_t * A)[:, :, None, None] * S \
+            + (dt_t[:, :, None] * x_t)[..., None] * Bh[:, :, None, :]
+        return S, jnp.sum(S * Ch[:, :, None, :], axis=-1)
+
+    state, y = jax.lax.scan(
+        step, f32(state),
+        tuple(jnp.moveaxis(f32(v), 1, 0) for v in (x, dt, B, C)))
+    return jnp.moveaxis(y, 0, 1), state
+
+
+def ssm_chunk_scan(x, dt, A, B, C, state, chunk):
+    """The recurrence over T positions from a carried state, in its chunked
+    form: within a chunk of `chunk` positions every pair (i >= j) at once as
+    masked matrix products, between chunks one state a chunk.
+
+    x: `[b, T, H, P]`; dt: `[b, T, H]` float32, 0 where a position must leave
+    the state alone; A: `[H]` float32 (negative); B, C: `[b, T, G, N]`;
+    state: `[b, H, P, N]` float32. Returns (y `[b, T, H, P]` float32, the
+    state after position T - 1). T need not be a multiple of `chunk`.
+    Products take their inputs in `x.dtype` and accumulate in float32; the
+    decays are float32."""
+    b, T, H, P = x.shape
+    G, N = B.shape[2:]
+    per = H // G
+    pad = -T % chunk
+    if pad:
+        x, dt, B, C = (jnp.pad(v, [(0, 0), (0, pad)] + [(0, 0)] * (v.ndim - 2))
+                       for v in (x, dt, B, C))
+    c, Q = (T + pad) // chunk, chunk
+    dtype = x.dtype
+    dot = functools.partial(jnp.einsum, preferred_element_type=jnp.float32)
+    dt = dt.astype(jnp.float32).reshape(b, c, Q, G, per)
+    x = x.reshape(b, c, Q, G, per, P)
+    B, C = (v.astype(dtype).reshape(b, c, Q, G, N) for v in (B, C))
+    cs = jnp.cumsum(dt * A.reshape(G, per), axis=2)         # inclusive
+    dtx = (dt[..., None] * x.astype(jnp.float32)).astype(dtype)
+    # inside a chunk: position j reaches i >= j decayed by exp(cs_i - cs_j)
+    i_ge_j = jnp.tril(jnp.ones((Q, Q), bool))[:, :, None, None]
+    decay = jnp.exp(jnp.where(i_ge_j, cs[:, :, :, None] - cs[:, :, None],
+                              -jnp.inf))                    # [b,c,i,j,G,per]
+    scores = (decay * dot("bcign,bcjgn->bcijg", C, B)[..., None]).astype(dtype)
+    y = dot("bcijgh,bcjghp->bcighp", scores, dtx)
+    # what a chunk adds to the state, decayed to the chunk's end
+    last = cs[:, :, -1]                                     # [b, c, G, per]
+    to_end = jnp.exp(last[:, :, None] - cs)[..., None]
+    added = dot("bcjgn,bcjghp->bcghpn", B,
+                (dtx.astype(jnp.float32) * to_end).astype(dtype))
+
+    def carry(S, inputs):
+        keep, add = inputs
+        return keep[..., None, None] * S + add, S
+
+    state, before = jax.lax.scan(
+        carry, state.astype(jnp.float32).reshape(b, G, per, P, N),
+        (jnp.moveaxis(jnp.exp(last), 1, 0), jnp.moveaxis(added, 1, 0)))
+    before = jnp.moveaxis(before, 0, 1)                     # [b,c,G,per,P,N]
+    y = y + jnp.exp(cs)[..., None] * dot(
+        "bcign,bcghpn->bcighp", C, before.astype(dtype))
+    return (y.reshape(b, c * Q, H, P)[:, :T],
+            state.reshape(b, H, P, N))

@@ -345,6 +345,12 @@ ROUTED_COUNTERS = ("moe_router_calls", "moe_assignments",
 HELD_ROUTED_COUNTERS = ROUTED_COUNTERS + ("moe_routed_elsewhere",)
 
 
+def relu2(x):
+    """`relu(x)^2`, the plain experts' activation in the Nemotron-H family
+    (`mlp_hidden_act: relu2`)."""
+    return jnp.square(jax.nn.relu(x))
+
+
 def topk_routing(x, gate_w, top_k, normalize=False, scoring="softmax",
                  bias=None, scale=None):
     """The router without capacity: x [N, D] -> (weights [N, k] float32,
@@ -401,8 +407,11 @@ def routed_experts(x, top_p, top_e, experts, activation=None,
     verify chunk.
 
     `experts`: gated (SwiGLU) `{"w_gate_up": [E, D, 2F], "w_down":
-    [E, F, D]}`, or plain `{"w_up": [E, D, F], "b_up": [E, F], "w_down":
-    [E, F, D], "b_down": [E, D]}` with `activation` between. The leading
+    [E, F, D]}`, or plain `{"w_up": [E, D, F], "w_down": [E, F, D]}` with
+    `activation` between (`relu2` for the Nemotron-H family's experts, which
+    live in a latent space: `x` is then the tokens' LATENT rows and D the
+    latent width) and, where the tree has them, biases `"b_up": [E, F]`,
+    `"b_down": [E, D]` (per-layer trees only: they are indexed from 0). The leading
     dimension may be a longer stack (every layer's experts, `[L * E, ...]`):
     then `num_experts` is E and `expert_base` (traced: `layer * E`) is where
     this layer's begin — the whole stack goes to the kernel, nothing is
@@ -443,11 +452,13 @@ def routed_experts(x, top_p, top_e, experts, activation=None,
             gate, up = jnp.split(gmm(rows, experts["w_gate_up"]), 2, axis=-1)
             y = gmm(jax.nn.silu(gate) * up, experts["w_down"])
         else:
-            sorted_e = jnp.take(flat_e, order)      # per-layer trees: base 0
-            h = gmm(rows, experts["w_up"]) \
-                + jnp.take(experts["b_up"], sorted_e, axis=0)
-            y = gmm(activation(h), experts["w_down"]) \
-                + jnp.take(experts["b_down"], sorted_e, axis=0)
+            def biased(y, name):        # per-layer trees: base 0
+                if name not in experts:
+                    return y
+                return y + jnp.take(experts[name], jnp.take(flat_e, order),
+                                    axis=0)
+            h = biased(gmm(rows, experts["w_up"]), "b_up")
+            y = biased(gmm(activation(h), experts["w_down"]), "b_down")
     with jax.named_scope("moe/combine"):
         back = jnp.zeros((M,), jnp.int32).at[order].set(
             jnp.arange(M, dtype=jnp.int32))           # assignment -> sorted row

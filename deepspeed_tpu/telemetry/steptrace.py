@@ -78,7 +78,13 @@ StepRecord = collections.namedtuple("StepRecord", [
     "fused_chunks",     # of `prefill_chunks`, the chunks that rode the
                         # step's decode call (the scheduler's `mixed_step`:
                         # one device call, every weight read once)
-], defaults=(0, 0, 0, 0, 0, 0, "", 0, (), 0, 0, 0, 0, 0, 0, 0, 0, 0))
+    "ssm_state_bytes",  # a pool with a state kind (recurrent layers): bytes
+                        # of state this step's decode tokens read + wrote —
+                        # decoding slots x tokens x state layers x one
+                        # slot's state, twice; what `dstpu_ssm_update` moves
+    "ssm_chunk_tokens",  # ... and the positions this step's prefill chunks
+                        # ran the chunked scan over; both 0 with no state kind
+], defaults=(0, 0, 0, 0, 0, 0, "", 0, (), 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0))
 
 RequestRecord = collections.namedtuple("RequestRecord", [
     "uid", "t_submit", "t_admit",

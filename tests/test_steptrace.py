@@ -1130,3 +1130,98 @@ def test_two_kind_paged_programs_hold_nothing_of_either_pools_size(
     walks = {h: sum("dstpu_paged_decode" in l and "custom-call(" in l
                     for l in comps[h]) for h in holders}
     assert sorted(walks.values()) == [1, 4], walks
+
+
+# ----------------------------------------------------------------------
+# a pool with a STATE kind (recurrent layers): the state is touched in place
+# ----------------------------------------------------------------------
+
+
+def test_state_kind_programs_hold_nothing_of_the_states_size(
+        one_chip, monkeypatch):
+    """PR 25's guard for a pool with a state kind (a Nemotron-H-shaped model:
+    two (LatentMoE, Mamba-2) pairs scanned, then an attention layer, at the
+    served tile widths), in all three step programs: nothing half as large as
+    ONE LAYER's state leaf is made by an operation that is not an aliased
+    Mosaic call (`dstpu_ssm_update`, `dstpu_ssm_state_write` on the state;
+    `dstpu_kv_pool_write` on the attention layer's blocks), the temporaries
+    stay under that size, a decode token updates each layer's state in ONE
+    call and a chunk reads and writes its row once."""
+    from deepspeed_tpu.models import nemotron_h as nh
+    from deepspeed_tpu.ops import attention_dispatch
+    from deepspeed_tpu.platform import device
+    mesh_mod.clear_mesh()
+    monkeypatch.setattr(device, "on_tpu", lambda: True)
+
+    def sds(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip), tree)
+
+    cfg = nh.NemotronHConfig(
+        vocab_size=512, pattern="EMEM*", n_head=16, n_kv_head=2,
+        d_model=512, attn_head_dim=128, d_ff=256, shared_d_ff=512,
+        moe_latent_size=256, max_seq_len=8192, num_experts=32,
+        experts_held=(8, 8), top_k=4, norm_topk_prob=True,
+        routed_scaling_factor=5.0, mamba_num_heads=16, mamba_head_dim=64,
+        ssm_state_size=128, n_groups=2, chunk_size=128,
+        use_flash_attention=True, dtype=jnp.bfloat16, remat=False)
+    shapes = jax.eval_shape(nh.nemotron_h_init_fn(cfg, dtype=jnp.bfloat16),
+                            jax.random.PRNGKey(0))
+    spec = nh.make_nemotron_h_decode_model(cfg, name="guard", params=shapes)
+    params = sds(shapes)
+    slots, chunk, window, i32 = 64, 512, 4, jnp.int32
+    # attention blocks past the chip's fast memory: a small leaf XLA would
+    # prefetch there whole, which is not what is guarded
+    pool = sds(jax.eval_shape(lambda: spec.init_paged_pool(
+        1024, 512, jnp.bfloat16, state_rows=1 + slots)))
+    state_layer = pool["ssm"].size // pool["ssm"].shape[0] * 4
+    tables = lambda b: (jax.ShapeDtypeStruct((b, 16), i32, sharding=one_chip),
+                        jax.ShapeDtypeStruct((b, 1), i32, sharding=one_chip))
+    ints = lambda *s: jax.ShapeDtypeStruct(s, i32, sharding=one_chip)
+
+    def decode_window(params, tok, pos, pool, tables):
+        def body(carry, _):
+            tok, pos, pool = carry
+            logits, pool, counts = spec.decode_paged_fn(params, tok, pos,
+                                                        pool, tables)
+            nxt = jnp.argmax(logits, -1).astype(i32)
+            return (nxt, pos + 1, pool), (nxt, counts)
+        (_, _, pool), out = jax.lax.scan(body, (tok, pos, pool), None,
+                                         length=window)
+        return out, pool
+
+    programs = {
+        "decode": jax.jit(decode_window, donate_argnums=(3,)).lower(
+            params, ints(slots), ints(slots), pool, tables(slots)).compile(),
+        "prefill": jax.jit(spec.prefill_paged_fn, donate_argnums=(4,)).lower(
+            params, ints(1, chunk), ints(1), ints(1), pool,
+            tables(1)).compile(),
+        "mixed": jax.jit(spec.mixed_paged_fn, donate_argnums=(7,)).lower(
+            params, ints(1, chunk), ints(1), ints(1), tables(1), ints(slots),
+            ints(slots), pool, tables(slots)).compile()}
+    assert set(spec.kv_pool_writers.values()) \
+        == {attention_dispatch.KV_POOL_WRITE_KERNEL}
+    in_place = {"dstpu_ssm_update", "dstpu_ssm_state_write",
+                "dstpu_kv_pool_write"}
+    for name, program in programs.items():
+        text = program.as_text()
+        large = _large_instructions(text, state_layer // 2)
+        made = [x for x in large if x[1] not in _NO_NEW_BUFFER]
+        assert made == [], (name, [
+            line.strip()[:160] for line in text.splitlines()
+            if any(f"%{n} = " in line for n, _ in made)])
+        assert {n.rsplit(".", 1)[0] for n, opcode in large
+                if opcode == "custom-call"} <= in_place, name
+        assert program.memory_analysis().temp_size_in_bytes \
+            < state_layer // 2, name
+        calls = collections.Counter(_mosaic_calls(text))
+        # the scanned pair's one Mamba-2 layer: a call in the scan's body
+        assert calls["dstpu_ssm_update"] == (name != "prefill"), (name, calls)
+        # state and convolution tail: a chunk reads and writes its row of
+        # each once, a decode token reads and writes the tails' rows
+        assert calls["dstpu_ssm_state_read"] == \
+            {"decode": 1, "prefill": 2, "mixed": 3}[name], (name, calls)
+        assert calls["dstpu_ssm_state_write"] == \
+            {"decode": 1, "prefill": 2, "mixed": 3}[name], (name, calls)
+        assert "dstpu_moe_gmm" in text and "dstpu_kv_pool_gather" not in text
