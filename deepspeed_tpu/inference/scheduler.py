@@ -27,6 +27,19 @@ their blocks immediately. The result is one compile per program for the
 lifetime of the engine — the recompile tax and the convoy effect die
 together.
 
+The loop runs ONE CALL DEEP: `step()` k builds and dispatches call k and
+only then blocks on call k-1's tokens, so the device's queue holds the next
+call when the current one ends. Call k's input tokens are call k-1's
+outputs, selected on the device (`_build_step_fns`: `pick`); positions,
+tables and chunks never depended on token values. An end the host can count
+(`max_new`) is decided at dispatch — the request gives up its slot and
+blocks then, and takes its last tokens when its call is read (`_Call`, held
+apart from the slots) — and an end only the token decides (EOS) costs one
+speculative window, dropped at read-back. What needs the tokens first (spec
+decode, the streamed per-layer walk, a pressure ladder off rest, a prompt's
+last chunk as a call of its own, cancel, handoff, audit, close) reads the
+call in flight back before it goes on: `_drain` is the one place.
+
 Compile accounting is first-class: `compile_stats()` reads the jit caches,
 and the serving tests assert <= 1 compile per bucket across a mixed-length
 request trace.
@@ -125,20 +138,28 @@ class _Slot:
                  "max_new", "eos", "blocks", "cursor", "pos", "emitted",
                  "hashes", "reg", "cached", "prefill_only", "deadline",
                  "t_arrive", "t_admit", "t_first", "t_prev", "trace",
-                 "step_first", "req")
+                 "step_first", "req", "planned", "flying", "feed")
 
     def __init__(self, idx):
         self.idx = idx
-        self.reset()
-
-    def reset(self):
         self.state = _FREE
         self.uid = self.prompt = None
         self.prompt_len = self.padded_len = self.max_new = 0
         self.eos = None
         self.blocks = []
-        self.cursor = self.pos = 0
+        # FINISHED work, what a reader of the slot may count: `cursor` the
+        # prompt tokens whose chunks the device has run (it moves when the
+        # call that covers a chunk is read back), `emitted` the tokens
+        # delivered. What the next call is planned from is kept beside
+        # them: `planned` the prompt tokens whose chunks are dispatched,
+        # `pos` the position the next decode call writes at, `flying` the
+        # tokens dispatched and not yet delivered
+        self.cursor = self.planned = self.pos = self.flying = 0
         self.emitted = []
+        self.feed = None        # (`_Call.id` of the call that samples this
+                                # slot's next input token, where in its
+                                # output: 1 = the window's last token,
+                                # 2 + i = first token i)
         self.hashes = None      # prefix-cache hash chain (full prompt blocks)
         self.reg = 0            # blocks [0, reg) already registered/cached
         self.cached = 0         # blocks mapped from the cache at admission
@@ -150,6 +171,41 @@ class _Slot:
         self.trace = None       # TraceContext (None unless tracing is on)
         self.step_first = 0     # step-timeline index of the first token's step
         self.req = None         # open steptrace.RequestRecord
+
+
+class _Call:
+    """A dispatched decode or mixed call whose tokens nobody has read yet:
+    what `_read` needs to hand them out. Held apart from the slots, which
+    may belong to the next requests by then — a `_Slot` OBJECT is one
+    request's life (`_vacate` puts a new one in its place), so a row here
+    can only ever reach the request it was sampled for."""
+    __slots__ = ("id", "out", "prev", "mixed", "win", "rows", "firsts",
+                 "chunks", "riding", "leaving", "t0")
+
+    def __init__(self, id, out, prev, mixed, win, rows, firsts, chunks,
+                 riding, t0):
+        self.id = id            # what a slot's `feed` names (a number, not
+                                # the call: a slot holds no call alive)
+        self.out = out          # the program's output, still on the device
+        self.prev = prev        # of it, (first [W], nxt [S, win]) for the
+                                # next call's `pick`
+        self.mixed = mixed      # `out` has first tokens beside the window
+        self.win = win
+        self.rows = rows        # requests in the decode window, row `idx` each
+        self.firsts = firsts    # (request, i): first token i is its first
+        self.chunks = chunks    # (request, prompt tokens prefilled once this
+                                # call has run): its own and every chunk
+                                # dispatched before it
+        self.riding = riding    # (request, start) of the chunks that rode
+        self.leaving = []       # requests that gave up their slot at dispatch
+        self.t0 = t0
+
+    def awaited(self):
+        """Does anything wait for this call's read-back: a token a live
+        request takes, or a chunk whose progress it confirms."""
+        return bool(self.chunks) or any(
+            r.state != _FREE for r in self.rows) or any(
+            r.state != _FREE for r, _ in self.firsts)
 
 
 class ServingEngine:
@@ -453,8 +509,8 @@ class ServingEngine:
                 window_blocks=1 + self.max_slots * self.ring)
         else:
             pool = spec.init_paged_pool(num_blocks, bs, jnp.dtype(kvd))
-        self.pool = jax.device_put(
-            pool, NamedSharding(engine.mesh, PartitionSpec()))
+        self._replicated = NamedSharding(engine.mesh, PartitionSpec())
+        self.pool = jax.device_put(pool, self._replicated)
         self.allocator = BlockAllocator(
             num_blocks, policy=str(scfg.prefix_cache_policy or "lru"))
         self.prefix_cache = None
@@ -490,6 +546,22 @@ class ServingEngine:
                                             np.int64)
         self._step_counts = np.zeros_like(self.step_counter_totals)
         self._parked_counts = []
+        # the loop runs one call deep (`_step_impl`): the newest dispatched
+        # call whose tokens are unread, the completions a read-back outside
+        # `step()` produced (the next `step()` returns them), and the chunks
+        # dispatched since the last such call ((request, prompt tokens
+        # prefilled once they have run): the next read-back that covers them
+        # moves the requests' `cursor`)
+        self._pending = None
+        self._early: List[CompletedRequest] = []
+        self._unread_chunks = []
+        # what a step program's `pick` takes where no call is in flight: the
+        # shapes, dtype and sharding of a mixed call's (first tokens, window
+        # tokens), so that a call has ONE signature whatever came before it
+        self._no_prev = jax.device_put(
+            (np.zeros((self.window,), np.int32),
+             np.zeros((self.max_slots, self.window), np.int32)),
+            self._replicated)
         self._build_step_fns()
 
         # drafter AFTER pool/allocator: the draft-model drafter mirrors the
@@ -545,6 +617,11 @@ class ServingEngine:
 
         # observability
         self.steps = 0
+        self.device_calls = 0               # calls whose tokens a blocking read
+                                            # fetched (decode, mixed, verify, a
+                                            # prompt's last chunk on its own)
+        self.overlapped_calls = 0           # of them, calls dispatched while
+                                            # the call before was unread
         self.decode_steps = 0
         self.prefill_chunks = 0
         self.fused_chunks = 0               # of them, chunks that rode a decode
@@ -599,6 +676,21 @@ class ServingEngine:
                                  temperature=cfg.temperature, top_k=cfg.top_k,
                                  top_p=cfg.top_p)
 
+        def pick(tok):
+            """A call's input token a slot. A plain [S] array is the host's.
+            Else ((first [W], nxt [S, win]) of the call BEFORE, still on the
+            device, src [S], host [S]): per slot the host's value (src 0),
+            the last token that call sampled for it (1), or the first token
+            of the prompt whose last chunk rode that call at window position
+            src - 2 — call k's tokens are call k-1's outputs, and never make
+            the trip to the host and back between the two."""
+            if not isinstance(tok, tuple):
+                return tok
+            (first, nxt), src, host = tok
+            return jnp.where(
+                src == 0, host, jnp.where(
+                    src == 1, nxt[:, -1], first[jnp.maximum(src - 2, 0)]))
+
         def make_decode_step(window):
             """Build the decode-WINDOW program: `window` tokens per sync
             inside one lax.scan (multi-step scheduling). One device call +
@@ -611,6 +703,7 @@ class ServingEngine:
             of the same program built lazily at degradation time."""
 
             def decode_step(params, tok, pos, pool, tables, rng):
+                tok = pick(tok)
                 if window == 1:  # no scan wrapper: keep the 1-step hot path
                     logits, pool, *counts = decode_paged(params, tok, pos,
                                                          pool, tables)
@@ -663,6 +756,7 @@ class ServingEngine:
             so ONE compile serves every count. Returns ((first tokens [W]:
             what each chunk's last row sampled, window tokens [S, W]) and
             a counted model's counters), pool."""
+            tok = pick(tok)
 
             def ride(i, tok, pos, pool, rng):
                 def at(a):
@@ -715,9 +809,17 @@ class ServingEngine:
         # engine's lifetime, and any cache miss after that warmup is
         # recorded (program name, shapes, compile_ms) — with telemetry off,
         # wrap() returns the jitted function untouched.
+        # The tokens of a decode or mixed call are the next call's input
+        # (`pick`), so their sharding is part of that call's signature: it
+        # is SAID (replicated, what `_no_prev` is placed with) and not left
+        # to what the compiler's propagation happens to spell, or the call
+        # after an empty engine and the call behind another would be two
+        # signatures of one program.
+        toks_at = (self._replicated, None)
         wd = self.telemetry.watchdog
         self._decode_step = wd.wrap(
-            "decode_step", jax.jit(decode_step, donate_argnums=(3,)))
+            "decode_step", jax.jit(decode_step, donate_argnums=(3,),
+                                   out_shardings=toks_at))
         self._prefill_step = wd.wrap(
             "prefill_step", jax.jit(prefill_step, donate_argnums=(4,)))
         # a step's chunks ride its decode call where the model can run the
@@ -726,7 +828,8 @@ class ServingEngine:
         self._mixed_step = None
         if mixed_paged is not None and not self.spec_on:
             self._mixed_step = wd.wrap(
-                "mixed_step", jax.jit(mixed_step, donate_argnums=(8,)))
+                "mixed_step", jax.jit(mixed_step, donate_argnums=(8,),
+                                      out_shardings=toks_at))
 
         self._verify_step = None
         if self.spec_on:
@@ -1096,7 +1199,7 @@ class ServingEngine:
             # grid, because the hit was truncated to whole-chunk coverage
             # above. With the default prefill_chunk == kv_block_size every
             # hit block skips a whole chunk.
-            slot.cursor = len(hit) * self.block_size
+            slot.cursor = slot.planned = len(hit) * self.block_size
             slot.hashes = hashes
             slot.reg = len(hit)
             slot.cached = len(hit)
@@ -1145,7 +1248,12 @@ class ServingEngine:
             blocked_on = "slots"        # the loop ran out of free slots
         return admitted, blocked_on
 
-    def _retire(self, slot: _Slot, reason: str) -> CompletedRequest:
+    def _vacate(self, slot: _Slot):
+        """`slot`'s request gives up its place and its blocks, once: a new
+        `_Slot` takes the place (never the old object again — a call in
+        flight may still hold it as the request its tokens go to)."""
+        if self.slots[slot.idx] is not slot:
+            return                  # it left at dispatch (`_leave`)
         # blocks return to the pool the step the sequence finishes — a
         # DECREF: blocks shared through the prefix cache stay live until
         # their last reader retires, and registered refcount-0 blocks park
@@ -1158,6 +1266,19 @@ class ServingEngine:
         self.tables[slot.idx, :] = TRASH_BLOCK
         if self.drafter is not None:
             self.drafter.retire(slot)       # stateful drafters drop slot state
+        self.slots[slot.idx] = _Slot(slot.idx)
+
+    def _leave(self, slot: _Slot, call: _Call):
+        """`slot`'s request ends BY COUNT inside `call`, which is dispatched:
+        its slot and blocks are free for the next call's admission now (the
+        device runs calls in order, so a next owner's writes land after its
+        own), and it takes its last tokens, and returns its
+        `CompletedRequest`, when `call` is read back."""
+        self._vacate(slot)
+        call.leaving.append(slot)
+
+    def _retire(self, slot: _Slot, reason: str) -> CompletedRequest:
+        self._vacate(slot)
         timing = None
         t_finish = self._clock()
         self.steptrace.close_request(
@@ -1191,7 +1312,7 @@ class ServingEngine:
                                 cached_prefix_tokens=slot.cached
                                 * self.block_size,
                                 timing=timing)
-        slot.reset()
+        slot.state = _FREE      # a call in flight drops what it holds for it
         return done
 
     def _emit(self, slot: _Slot, tok: int, finished: List[CompletedRequest]):
@@ -1294,11 +1415,18 @@ class ServingEngine:
                 return CompletedRequest(uid=uid, prompt_len=rec[2],
                                         tokens=np.zeros((0,), np.int32),
                                         finish_reason=reason)
-        for slot in self.slots:
-            if slot.state == _FREE or slot.uid != uid:
+        for slot in self._live():
+            if slot.uid != uid:
                 continue
             if queued_only and slot.state != _HANDOFF:
                 return None
+            if self._pending is not None:
+                # its tokens may be in the call in flight: that is read
+                # first, and where the request ended there, that is its end
+                self._drain(self._early)
+                for i, done in enumerate(self._early):
+                    if done.uid == uid:
+                        return self._early.pop(i)
             self.cancelled += 1
             return self._retire(slot, reason)
         return None
@@ -1312,17 +1440,26 @@ class ServingEngine:
         self.queue.clear()
         return out
 
+    def _live(self) -> List[_Slot]:
+        """Every request that is not finished: those in slots, and those
+        that left theirs at dispatch and wait for their last tokens."""
+        live = [s for s in self.slots if s.state != _FREE]
+        if self._pending is not None:
+            live += [r for r in self._pending.leaving if r.state != _FREE]
+        return live
+
     def active_uids(self) -> List[Any]:
-        """Uids currently occupying slots (prefilling, decoding, or parked
-        for handoff) — in-flight work that dies with the engine."""
-        return [s.uid for s in self.slots if s.state != _FREE]
+        """Uids of requests that are not finished (prefilling, decoding,
+        parked for handoff, or waiting for their last tokens) — in-flight
+        work that dies with the engine."""
+        return [s.uid for s in self._live()]
 
     def has_output(self, uid) -> bool:
         """True once the request has emitted its first token here — the
         router's hedging probe: a dispatched request with no output past
         `hedge_after_ms` earns a speculative duplicate elsewhere."""
-        for s in self.slots:
-            if s.state != _FREE and s.uid == uid:
+        for s in self._live():
+            if s.uid == uid:
                 return len(s.emitted) > 0
         return False
 
@@ -1367,6 +1504,7 @@ class ServingEngine:
         LRU from the slot tables (ground truth) and re-audit; a repair
         that cannot reach a clean state raises `PoolCorruptionError`.
         Returns the (pre-repair) `AuditReport`."""
+        self._drain(self._early)        # the books and the device agree
         report = self._auditor.audit()
         self.audits_run += 1
         if report.ok:
@@ -1455,13 +1593,15 @@ class ServingEngine:
         """Uids of prefill-only slots whose prefill finished: their blocks
         hold the full prompt KV and their first sampled token is emitted —
         ready for `export_handoff` into a decode engine."""
-        return [s.uid for s in self.slots if s.state == _HANDOFF]
+        return [s.uid for s in self.slots
+                if s.state == _HANDOFF and not s.flying]
 
     def export_handoff(self, uid) -> Dict[str, Any]:
         """Snapshot a handoff-parked slot for transplant. The blocks stay
         OWNED by this engine (refcounts untouched) until `release_handoff`
         — the copy must complete before the source can be reclaimed, the
         same protocol as the checkpoint saver's tmp->rename commit."""
+        self._drain(self._early)        # its first token may be in flight
         slot = self._handoff_slot(uid)
         # blocks the prefill cursor actually wrote: the padded prompt only
         # (a prefill-only slot never decodes here, so no window tail)
@@ -1523,7 +1663,7 @@ class ServingEngine:
         slot.max_new = state["max_new"]
         slot.eos = state["eos"]
         slot.blocks = blocks
-        slot.cursor = state["padded_len"]
+        slot.cursor = slot.planned = state["padded_len"]
         slot.pos = state["pos"]
         slot.emitted = list(state["emitted"])
         slot.hashes = None          # adopted blocks stay private: this pool
@@ -1551,14 +1691,11 @@ class ServingEngine:
         slot's blocks (registered prefix blocks park reclaimable and stay
         matchable for affinity) and recycle the slot."""
         slot = self._handoff_slot(uid)
-        self.allocator.free(slot.blocks[::-1])
-        self.tables[slot.idx, :] = TRASH_BLOCK
-        if self.drafter is not None:
-            self.drafter.retire(slot)
+        self._vacate(slot)
         self.steptrace.close_request(
             slot.req, slot.t_first, slot.step_first, self._clock(),
             len(slot.emitted), "handoff")
-        slot.reset()
+        slot.state = _FREE
         self.handoffs_out += 1
 
     def _handoff_slot(self, uid) -> _Slot:
@@ -1591,6 +1728,7 @@ class ServingEngine:
             # the cap score as padding and land past the cursor (dead)
             dlens = np.minimum(dlens, self.pressure.draft_cap)
         toks = np.concatenate([tok[:, None], drafts], axis=1)
+        self._count_call()
         with self._phase("serving/verify") as ph:
             st.dispatched()
             tgt, self.pool = self._verify_step(self.engine.params, toks,
@@ -1671,26 +1809,40 @@ class ServingEngine:
         The device calls of a step: each chunk a `prefill_step` call and then
         one `decode_step` call — or, where slots were decoding as the step
         began, the last min(chunks due, window) chunks and the decode window
-        as ONE `mixed_step` call (`_chunks_riding`, `_mixed_window`), the
-        chunks before them as their own calls first. One blocking read-back
-        a decode or mixed call, one for a prompt's last chunk where that was
-        a call of its own."""
-        finished: List[CompletedRequest] = []
+        as ONE `mixed_step` call (`_chunks_riding`, `_launch`), the chunks
+        before them as their own calls first. One blocking read-back a
+        decode or mixed call, one for a prompt's last chunk where that was
+        a call of its own.
+
+        The read-back of a decode or mixed call comes one step LATE: this
+        step dispatches its call and then reads the call the step before
+        left in flight (`_launch`), so the device always has the next call
+        queued. `_overlaps` says which steps may leave a call in flight; the
+        others read their own call back before they return, through the
+        same code with nothing pending."""
+        finished, self._early = self._early, []
         self.steps += 1
         params = self.engine.params
         # the step timeline: phases tile the step (admit, each prefill
         # chunk that is a call of its own, decode_build, decode_window — the
-        # decode call, with or without chunks riding it — or draft + verify,
-        # emit, housekeeping); dispatched()/ready() bracket the device calls
+        # decode call's dispatch, with or without chunks riding it, and the
+        # blocking read of the call in flight — or draft + verify, emit,
+        # housekeeping); dispatched()/ready() bracket the device calls
         st = self.steptrace
         st.begin_step()
         compiled0 = self._compiled_programs()
         chunks0, tokens0 = self.prefill_chunks, self.tokens_generated
+        calls0, overlapped0 = self.device_calls, self.overlapped_calls
         walk = (0, 0, 0, 0, 0)  # the decode kernel's (live blocks, grid
                              # steps, window layers' live blocks, ...
                              # unwindowed) and a state kind's bytes
         reach = [0, 0, 0, 0]  # the prefill kernel's (live, table) blocks
                               # and the window layers' (live, unwindowed)
+
+        overlap = self._overlaps()
+        if not overlap:
+            # this step's decisions need the tokens: nothing stays in flight
+            self._drain(finished)
 
         with self._phase("serving/admit"):
             admitted, blocked_on = self._admit(finished)
@@ -1717,39 +1869,50 @@ class ServingEngine:
         # blocks_needed window padding covers it). With spec decode on,
         # the verify step replaces this call entirely. A slot whose final
         # chunk ran above decodes in this call; one whose final chunk RIDES
-        # this call takes its first token from the call's read-back and
-        # decodes from the next.
+        # this call decodes from the next, on that call's first token.
         dec = [s for s in self.slots if s.state == _DECODE]
         if dec:
             with self._phase("serving/decode_build"):
                 self.peak_active = max(self.peak_active, len(dec))
+                prior = self._pending
                 tok = np.zeros((self.max_slots,), np.int32)
+                src = np.zeros((self.max_slots,), np.int32)
                 pos = np.zeros((self.max_slots,), np.int32)
                 tables = np.full_like(self.tables, TRASH_BLOCK)
                 for s in dec:
-                    tok[s.idx] = s.emitted[-1]
+                    if prior is not None and s.feed is not None \
+                            and s.feed[0] == prior.id:
+                        src[s.idx] = s.feed[1]      # still on the device
+                    else:
+                        tok[s.idx] = s.emitted[-1]
                     pos[s.idx] = s.pos
                     tables[s.idx] = self.tables[s.idx]
             spec_active = self.spec_on and not (
                 self.pressure is not None and self.pressure.spec_disabled)
             if spec_active:
                 self._verify_decode(dec, tok, pos, tables, finished)
-            elif riding:
-                walk, rode = self._mixed_window(dec, riding, params, tok,
-                                                pos, tables, finished)
-                reach = [a + b for a, b in zip(reach, rode)]
             else:
-                walk = self._decode_window(dec, params, tok, pos, tables,
-                                           finished)
+                walk, rode = self._launch(dec, riding, params, (src, tok),
+                                          pos, tables, finished)
+                reach = [a + b for a, b in zip(reach, rode)]
+        if not (overlap and dec) or not self._pending.awaited():
+            # nothing was put behind the call in flight, this step may leave
+            # none, or no live request waits for what it left (every row
+            # ended while it ran): it is read now
+            self._drain(finished)
 
         # sync-point housekeeping: hard deadlines, the pressure ladder, and
         # the scheduled pool audit all run here — between compiled calls,
         # on host state only
+        audit_due = self.audit_interval and \
+            self.steps % self.audit_interval == 0
+        if audit_due:
+            self._drain(finished)
         with self._phase("serving/housekeeping"):
             self._sweep_deadlines(finished)
             if self.pressure is not None:
                 self.pressure.update(finished)
-            if self.audit_interval and self.steps % self.audit_interval == 0:
+            if audit_due:
                 self._scheduled_audit()
 
             if self.telemetry.enabled:
@@ -1768,6 +1931,8 @@ class ServingEngine:
 
         counters = ()
         if self.step_counter_names:
+            # of the calls READ in this step (a call in flight brings its
+            # counters with its tokens, a step after the one it ran in)
             counters = tuple(int(v) for v in self._step_counts)
             self.step_counter_totals += self._step_counts
             self._step_counts[:] = 0
@@ -1790,8 +1955,37 @@ class ServingEngine:
                     queued=len(self.queue),
                     free_blocks=self.allocator.available,
                     blocked_on=blocked_on,
-                    compiles=self._compiled_programs() - compiled0)
+                    compiles=self._compiled_programs() - compiled0,
+                    device_calls=self.device_calls - calls0,
+                    overlapped_calls=self.overlapped_calls - overlapped0)
         return finished
+
+    def _overlaps(self):
+        """May this step leave its decode or mixed call in flight, to be
+        read by the next step after THAT step's call is dispatched? The
+        whole rule, from what the step holds: the call is a resident
+        engine's `_decode_step` / `_mixed_step` (the streamed walk runs host
+        numpy between its layers), spec decode is off (acceptance decides
+        the next input), and the pressure ladder is at rest (its rungs
+        reshape the call and read the pool's state). Nothing is set."""
+        return not self.streamed and not self.spec_on and not (
+            self.pressure is not None and self.pressure.level)
+
+    def _count_call(self):
+        """A call whose tokens a blocking read fetches is about to go out."""
+        self.device_calls += 1
+        self.overlapped_calls += self._pending is not None
+
+    def _drain(self, finished):
+        """THE place a call in flight is read back ahead of its turn: by a
+        step that may not overlap, a prompt's last chunk as a call of its
+        own, a step with nothing to put behind it, the scheduled audit — and
+        outside `step()` (`cancel`, `export_handoff`, `audit`, `close`, the
+        end of `run()`), where the completions go to `_early` and the next
+        `step()` returns them."""
+        call, self._pending = self._pending, None
+        if call is not None:
+            self._read(call, finished)
 
     def _chunks_due(self):
         """This step's prefill chunks in dispatch order, (slot, start) each:
@@ -1801,7 +1995,7 @@ class ServingEngine:
         for slot in self.slots:
             if slot.state != _PREFILL:
                 continue
-            for start in range(slot.cursor, slot.padded_len, self.chunk):
+            for start in range(slot.planned, slot.padded_len, self.chunk):
                 if len(due) == self.prefill_budget:
                     return due
                 due.append((slot, start))
@@ -1833,8 +2027,9 @@ class ServingEngine:
         return chunk, last, final
 
     def _chunk_written(self, slot, start, program):
-        """Book a dispatched chunk of `slot`: cursor, counter, the prefix
-        cache registrations it completes. Returns what the prefill kernel's
+        """Book a dispatched chunk of `slot`: what is planned of its prompt
+        (`cursor` follows at the read-back that covers the chunk,
+        `_chunks_run`), counter, the prefix cache registrations it completes. Returns what the prefill kernel's
         walk attends, a layer: (logical blocks under the chunk's frontier,
         blocks in its table, and for a pool of two kinds the blocks a WINDOW
         layer's walk visits and the blocks the same walk would visit with no
@@ -1858,22 +2053,29 @@ class ServingEngine:
                     start, self.chunk, wkind.block, width, wkind.window),
                     paged_prefill_live_blocks(
                         start, self.chunk, wkind.block, width))
-        slot.cursor = start + self.chunk
+        slot.planned = start + self.chunk
+        self._unread_chunks.append((slot, slot.planned))
         self.prefill_chunks += 1
         if self.prefix_cache is not None and slot.hashes:
-            # register blocks the cursor just finished writing —
+            # register blocks the chunk just dispatched writes —
             # full blocks strictly below prompt_len only (the
             # padded tail and decode-written blocks stay private,
             # so shared blocks are immutable by construction). A
             # block becomes matchable only here, AFTER the call that
             # writes its content is dispatched: registering at admission
             # would let a same-step sibling map garbage.
-            hi = min(slot.cursor, slot.prompt_len) // self.block_size
+            hi = min(slot.planned, slot.prompt_len) // self.block_size
             for i in range(slot.reg, hi):
                 self.prefix_cache.register(slot.hashes[i],
                                            slot.blocks[i])
             slot.reg = max(slot.reg, hi)
         return reach
+
+    def _chunks_run(self, chunks):
+        """A read-back returned that was dispatched after `chunks`
+        ((request, prompt tokens prefilled) each): the device has run them."""
+        for slot, upto in chunks:
+            slot.cursor = max(slot.cursor, upto)
 
     def _first_token(self, slot, tok, finished):
         """A prompt's last chunk is in: the slot decodes from here (or parks
@@ -1887,14 +2089,18 @@ class ServingEngine:
 
     def _prefill_chunk(self, slot, start, params, finished):
         """One prefill chunk of `slot` (its prompt from `start`) as a call of
-        its own — a chunk that does not ride the decode call
-        (`_mixed_window`): input build, dispatch, the cache registrations
-        it completes and, after the final chunk, the first-token read-back.
-        Returns `_chunk_written`'s walk counts."""
+        its own — a chunk that does not ride the decode call (`_launch`):
+        input build, dispatch, the cache registrations it completes and,
+        after the final chunk, the first-token read-back — a blocking read
+        of this step's own, so a call in flight is read before it
+        (`_drain`). Returns `_chunk_written`'s walk counts."""
         st = self.steptrace
         ctx = slot.trace                      # _emit may retire the slot
         with self._phase("serving/prefill_chunk") as ph:
+            t0 = ph.t0
             chunk, last, final = self._chunk_input(slot, start)
+            if final:
+                self._count_call()
             st.dispatched()
             tok, self.pool = self._prefill_step(
                 params, chunk, np.asarray([start], np.int32),
@@ -1915,21 +2121,40 @@ class ServingEngine:
             if self.step_counter_names and not final:
                 tok, counts = tok
                 self._parked_counts.append(counts)
-            if final:
+        if final:
+            self._drain(finished)       # in the device's order, and its own
+                                        # phases: the call in flight first
+            with self._phase("serving/prefill_chunk") as ph1:
                 # first-token readback at prefill completion — one scalar per prompt, the TTFT emission point
                 first = int(np.asarray(self._read_back(tok))[0])
                 st.ready()
+                self._chunks_run(self._unread_chunks)
+                self._unread_chunks = []
                 self._first_token(slot, first, finished)
+            ph = ph1
         if self.tracer.enabled and ctx is not None:
-            self.tracer.record(ctx, "prefill_chunk", ph.t0, ph.t1 - ph.t0,
+            self.tracer.record(ctx, "prefill_chunk", t0, ph.t1 - t0,
                                tid=self.trace_tid,
                                attrs={"start": start, "chunk": self.chunk})
         return reach
 
-    def _decode_window(self, dec, params, tok, pos, tables, finished):
-        """The decode call for every slot in `dec` (no chunk riding it), its
-        read-back, and the emission of what it sampled. Returns
-        `_decode_walk`'s counts."""
+    def _launch(self, dec, riding, params, tok, pos, tables, finished):
+        """Dispatch the decode call for every slot in `dec` — `decode_step`,
+        or with the chunks `riding` it ((slot, start) each, one a token of
+        the window from the first) ONE `mixed_step` call — and only then
+        read the call the step before left in flight (`_read`): this one is
+        queued behind it on the device meanwhile. `tok` is (src, host
+        tokens): a slot's input token is the host's, or stays on the device
+        as the output of the call in flight (`pick`). What the host can
+        count is booked at dispatch: positions, each chunk as
+        `_prefill_chunk` books its own, a prompt whose last chunk rides
+        (it decodes from the next call, on this call's first token), and a
+        request that reaches `max_new` inside this call (`_leave`). The read-
+        back brings the window's tokens, those first tokens and a counted
+        model's counters; it is left to the next step, which `_step_impl`
+        decides. Returns (`_decode_walk`'s counts, the chunks'
+        `_chunk_written` counts summed)."""
+        st = self.steptrace
         # the degraded paths run the 1-STEP decode program: with
         # spec decode pressure-disabled the blocks were sized for
         # the k-draft overhang (no window-rounding tail, so a K-step
@@ -1939,78 +2164,126 @@ class ServingEngine:
         use_w1 = self.spec_on or (
             self.pressure is not None
             and self.pressure.force_window_1)
-        step_fn = self._degraded_decode_step() if use_w1 \
-            else self._decode_step
-        win = 1 if use_w1 else self.window
-        st = self.steptrace
+        win, n = 1 if use_w1 else self.window, len(riding)
+        prior = self._pending
+        if self.streamed:
+            tok = tok[1]        # the host walk takes the host's tokens
+        else:
+            tok = (self._no_prev if prior is None else prior.prev,) + tok
+        finals = []
+        if riding:
+            with self._phase("serving/decode_build"):
+                chunks = np.zeros((win, 1, self.chunk), np.int32)
+                starts = np.zeros((win, 1), np.int32)
+                lasts = np.zeros((win, 1), np.int32)
+                for i, (slot, start) in enumerate(riding):
+                    chunks[i], lasts[i, 0], final = self._chunk_input(slot,
+                                                                      start)
+                    starts[i, 0] = start
+                    if final:
+                        finals.append((slot, i))
+                # positions of the window past `n` are never read: any
+                # slot's row fills them
+                idx = [slot.idx for slot, _ in riding]
+                idx += idx[-1:] * (win - n)
+                chunk_tables = jax.tree_util.tree_map(
+                    lambda t: t[:, None],
+                    self._tables_arg(self.tables[idx], idx))
+        self._count_call()
+        rode = [0, 0, 0, 0]
         with self._phase("serving/decode_window") as ph:
             st.dispatched()
-            nxt, self.pool = step_fn(params, tok, pos,
-                                     self.pool, self._tables_arg(tables),
-                                     self._next_rng())
+            if riding:
+                out, self.pool = self._mixed_step(
+                    params, chunks, starts, lasts, chunk_tables, np.int32(n),
+                    tok, pos, self.pool, self._tables_arg(tables),
+                    self._next_rng())
+                program = (self.engine.model_spec.paged_attn_programs
+                           or {}).get("mixed/prefill_chunk")
+                rode = [sum(counts) for counts in zip(*(
+                    self._chunk_written(slot, start, program)
+                    for slot, start in riding))]
+                self.fused_chunks += n
+            else:
+                step_fn = self._degraded_decode_step() if use_w1 \
+                    else self._decode_step
+                out, self.pool = step_fn(params, tok, pos, self.pool,
+                                         self._tables_arg(tables),
+                                         self._next_rng())
             # counted here, while the device runs
             walk = self._decode_walk(dec, pos, win)
-            # THE one host roundtrip per decode window — EOS/retirement decisions are host-side, amortized over `win` tokens
-            nxt = np.asarray(self._read_back(nxt))  # [S, win]
-            st.ready()
-        self._emit_window(dec, nxt, win, ph, finished)
-        return walk
+            toks = out[0] if self.step_counter_names else out
+            call = _Call(self.device_calls, out,
+                         toks if riding else (self._no_prev[0], toks),
+                         bool(riding), win, dec, finals,
+                         self._unread_chunks, riding, ph.t0)
+            self._unread_chunks = []
+            self._pending = call
+            for s in dec:
+                s.pos += win
+                s.flying += win
+                s.feed = (call.id, 1)
+                if len(s.emitted) + s.flying >= s.max_new:
+                    self._leave(s, call)
+            for slot, i in finals:
+                slot.state = _HANDOFF if slot.prefill_only else _DECODE
+                slot.flying = 1
+                slot.feed = (call.id, 2 + i)
+                if slot.max_new <= 1:
+                    self._leave(slot, call)
+        if prior is not None:
+            self._read(prior, finished)
+        return walk, rode
 
-    def _mixed_window(self, dec, riding, params, tok, pos, tables, finished):
-        """The decode call for every slot in `dec` WITH the chunks `riding`
-        it ((slot, start) each, one a token of the window from the first):
-        ONE device call (`mixed_step`) and one read-back, which brings the
-        window's tokens, the first token of every prompt whose last chunk
-        rode, and a counted model's counters. Each chunk is booked as
-        `_prefill_chunk` books its own. Returns (`_decode_walk`'s counts,
-        the chunks' `_chunk_written` counts summed)."""
+    def _read(self, call, finished):
+        """THE one host roundtrip of a decode or mixed call — its blocking
+        read-back, and the emission of what it sampled: the window's tokens
+        to the requests that were in it, the first token of every prompt
+        whose last chunk rode it. EOS, retirement and the stamps (`t_first`,
+        TPOT, a request's close) happen here, at the read-back that
+        delivered the token. A request that ended while the call ran (EOS in
+        the call before, a deadline, a cancel) takes nothing: that was its
+        one speculative window."""
         st = self.steptrace
-        win, n = self.window, len(riding)
-        with self._phase("serving/decode_build"):
-            chunks = np.zeros((win, 1, self.chunk), np.int32)
-            starts = np.zeros((win, 1), np.int32)
-            lasts = np.zeros((win, 1), np.int32)
-            finals = []
-            for i, (slot, start) in enumerate(riding):
-                chunks[i], lasts[i, 0], final = self._chunk_input(slot, start)
-                starts[i, 0] = start
-                finals.append(final)
-            # positions of the window past `n` are never read: any slot's
-            # row fills them
-            idx = [slot.idx for slot, _ in riding]
-            idx += idx[-1:] * (win - n)
-            chunk_tables = jax.tree_util.tree_map(
-                lambda t: t[:, None], self._tables_arg(self.tables[idx], idx))
         with self._phase("serving/decode_window") as ph:
-            st.dispatched()
-            out, self.pool = self._mixed_step(
-                params, chunks, starts, lasts, chunk_tables, np.int32(n),
-                tok, pos, self.pool, self._tables_arg(tables),
-                self._next_rng())
-            # counted here, while the device runs
-            walk = self._decode_walk(dec, pos, win)
-            program = (self.engine.model_spec.paged_attn_programs
-                       or {}).get("mixed/prefill_chunk")
-            rode = [sum(counts) for counts in zip(*(
-                self._chunk_written(slot, start, program)
-                for slot, start in riding))]
-            # THE one host roundtrip of the call: window tokens and first tokens together
-            first, nxt = self._read_back(out)       # [win], [S, win]
-            st.ready()
-        self.fused_chunks += n
-        if self.tracer.enabled:
-            for slot, start in riding:
+            # THE one host roundtrip per decode window — EOS/retirement decisions are host-side, amortized over `win` tokens
+            toks = self._read_back(call.out)
+            if self._pending is None:
+                st.ready()      # else the next call is queued behind it
+        self._chunks_run(call.chunks)
+        first, nxt = toks if call.mixed else ((), toks)
+        nxt = np.asarray(nxt)                       # [S, win]
+        tr_on = self.tracer.enabled
+        if tr_on:
+            for slot, start in call.riding:
                 if slot.trace is not None:
                     self.tracer.record(
-                        slot.trace, "prefill_chunk", ph.t0, ph.t1 - ph.t0,
-                        tid=self.trace_tid,
+                        slot.trace, "prefill_chunk", call.t0,
+                        ph.t1 - call.t0, tid=self.trace_tid,
                         attrs={"start": start, "chunk": self.chunk,
                                "fused": True})
-        for (slot, _), final, tok1 in zip(riding, finals, first):
-            if final:
-                self._first_token(slot, int(tok1), finished)
-        self._emit_window(dec, np.asarray(nxt), win, ph, finished)
-        return walk, rode
+        for slot, i in call.firsts:
+            if slot.state != _FREE:
+                slot.flying -= 1
+                self._emit(slot, int(first[i]), finished)
+        self.decode_steps += 1
+        with self._phase("serving/emit"):
+            for s in call.rows:
+                if s.state == _FREE:
+                    continue
+                s.flying -= call.win
+                ctx = s.trace             # _retire closes the request
+                anchor, j = s.t_prev, 0
+                for t in nxt[s.idx]:
+                    self._emit(s, int(t), finished)
+                    j += 1
+                    if s.state == _FREE:            # retired mid-window
+                        break
+                self._observe_tpot(s, anchor, j, ph.t1)
+                if tr_on and ctx is not None:
+                    self.tracer.record(ctx, "decode_window", call.t0,
+                                       ph.t1 - call.t0, tid=self.trace_tid,
+                                       attrs={"emitted": j})
 
     def _decode_walk(self, dec, pos, win):
         """What the paged decode kernel's walk has to do in a call of `win`
@@ -2035,27 +2308,6 @@ class ServingEngine:
                     at, wkind.block, wkind.window)).sum()),
                 int(whole.sum())) + walk[4:]
         return walk
-
-    def _emit_window(self, dec, nxt, win, ph, finished):
-        """Hand the tokens `nxt` [S, win] of the decode call timed by `ph`
-        to the slots `dec` that were in it."""
-        self.decode_steps += 1
-        tr_on = self.tracer.enabled
-        with self._phase("serving/emit"):
-            for s in dec:
-                s.pos += win
-                ctx = s.trace             # _retire resets the slot
-                anchor, j = s.t_prev, 0
-                for t in nxt[s.idx]:
-                    self._emit(s, int(t), finished)
-                    j += 1
-                    if s.state == _FREE:            # retired mid-window
-                        break
-                self._observe_tpot(s, anchor, j, ph.t1)
-                if tr_on and ctx is not None:
-                    self.tracer.record(ctx, "decode_window", ph.t0,
-                                       ph.t1 - ph.t0, tid=self.trace_tid,
-                                       attrs={"emitted": j})
 
     def _read_back(self, out):
         """THE blocking read of a step program's tokens (the mixed step's: a
@@ -2094,7 +2346,10 @@ class ServingEngine:
 
     @property
     def num_active(self):
-        return sum(1 for s in self.slots if s.state != _FREE)
+        """Requests that are not finished: the slots in use, and the
+        requests that left theirs at dispatch and whose last tokens the
+        call in flight still holds."""
+        return len(self._live())
 
     def run(self, requests: Sequence[Request]) -> Dict[Any, CompletedRequest]:
         """Submit a batch of requests and drain the engine."""
@@ -2102,15 +2357,20 @@ class ServingEngine:
             self.submit(r)
         out: Dict[Any, CompletedRequest] = {}
         while self.queue or self.num_active:
-            before = (self.prefill_chunks, self.decode_steps, len(self.queue))
+            before = (self.prefill_chunks, self.decode_steps,
+                      self.device_calls, len(self.queue))
             for done in self.step():
                 out[done.uid] = done
-            after = (self.prefill_chunks, self.decode_steps, len(self.queue))
+            after = (self.prefill_chunks, self.decode_steps,
+                     self.device_calls, len(self.queue))
             if after == before:                     # defensive: cannot happen
                 raise RuntimeError(
                     f"serving scheduler made no progress: queue="
                     f"{len(self.queue)} active={self.num_active} "
                     f"free_blocks={self.allocator.num_free}")
+        # a call nothing waits for (every row ended while it ran) is read,
+        # not left: the engine rests with nothing in flight
+        self._drain(self._early)
         # drained: flush the tail of the trace into the exporters (a run
         # shorter than export_interval would otherwise leave no files)
         if self.telemetry.enabled:
@@ -2178,7 +2438,9 @@ class ServingEngine:
         return out
 
     def stats(self) -> Dict[str, Any]:
-        out = {"steps": self.steps, "decode_steps": self.decode_steps,
+        out = {"steps": self.steps, "device_calls": self.device_calls,
+               "overlapped_calls": self.overlapped_calls,
+               "decode_steps": self.decode_steps,
                "prefill_chunks": self.prefill_chunks,
                "fused_chunks": self.fused_chunks,
                "tokens_generated": self.tokens_generated,

@@ -260,7 +260,10 @@ class InProcessReplica(ReplicaHandle):
 
     def progress(self):
         e = self.engine
-        return e.tokens_generated + e.prefill_chunks + e.handoffs_in
+        # a step that dispatches a call and reads none back (the engine's
+        # loop runs one call deep) has made progress too
+        return e.tokens_generated + e.prefill_chunks + e.handoffs_in \
+            + e.device_calls
 
     @property
     def prefill_chunk(self):

@@ -1055,8 +1055,11 @@ class ServingMemScope(_MemScopeBase):
         def i32(shape):
             return np.zeros(shape, np.int32)
 
+        # a call's input tokens as the scheduler hands them: the call
+        # before's outputs (on the device), the source a slot, the host's
+        tok = (s._no_prev, i32((S,)), i32((S,)))
         yield "decode_step", s._decode_step, \
-            (params, i32((S,)), i32((S,)), pool, np.asarray(s.tables), rng)
+            (params, tok, i32((S,)), pool, np.asarray(s.tables), rng)
         yield "prefill_step", s._prefill_step, \
             (params, i32((1, chunk)), i32((1,)), i32((1,)), pool,
              np.asarray(s.tables[:1]), rng)
@@ -1064,7 +1067,7 @@ class ServingMemScope(_MemScopeBase):
             W = s.window
             yield "mixed_step", s._mixed_step, \
                 (params, i32((W, 1, chunk)), i32((W, 1)), i32((W, 1)),
-                 i32((W, 1, s.tables.shape[1])), np.int32(1), i32((S,)),
+                 i32((W, 1, s.tables.shape[1])), np.int32(1), tok,
                  i32((S,)), pool, np.asarray(s.tables), rng)
         if s._verify_step is not None:
             yield "verify_step", s._verify_step, \

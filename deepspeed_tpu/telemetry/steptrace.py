@@ -12,8 +12,10 @@ the one before ended, so one clock read a phase), `dispatched()` / `ready()`
 marks around device work, and `end_step(**counts)`. Its record holds the
 seconds per phase and the EXPOSED HOST SECONDS: the step's wall time less the
 time a device call was in flight (from a `dispatched()` to the next blocking
-read-back's `ready()`, carried across steps while no read-back came) — the
-time the chip provably had nothing of this step to run. Requests get a
+read-back's `ready()`, carried across steps while no read-back came, or while
+the caller read one call back with the next already dispatched behind it and
+so did not call `ready()`) — the time the chip provably had nothing of this
+step queued. Requests get a
 record when they are admitted and a completed one, under the same `uid`, when
 they retire.
 
@@ -84,7 +86,14 @@ StepRecord = collections.namedtuple("StepRecord", [
                         # slot's state, twice; what `dstpu_ssm_update` moves
     "ssm_chunk_tokens",  # ... and the positions this step's prefill chunks
                         # ran the chunked scan over; both 0 with no state kind
-], defaults=(0, 0, 0, 0, 0, 0, "", 0, (), 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0))
+    "device_calls",     # calls this step dispatched whose tokens a blocking
+                        # read fetches (a decode or mixed call, a verify
+                        # call, a prompt's last chunk as a call of its own)
+    "overlapped_calls",  # ... of them, the calls dispatched while the call
+                        # before was unread: queued behind it on the device,
+                        # so the chip never waited for this step's host work
+], defaults=(0, 0, 0, 0, 0, 0, "", 0, (), 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0,
+             0))
 
 RequestRecord = collections.namedtuple("RequestRecord", [
     "uid", "t_submit", "t_admit",

@@ -229,8 +229,12 @@ def test_a_prompts_chunks_ride_consecutive_tokens_of_one_window():
     # back with the window's tokens
     assert (rec.prefill_chunks, rec.fused_chunks, rec.decoding) == (4, 4, 1)
     slot = next(s for s in serving.slots if s.uid == long.uid)
-    assert slot.state == _DECODE and len(slot.emitted) == 1
-    assert slot.cursor == 64 and slot.pos == 60
+    # the call is in flight: the slot decodes from the next one, on a token
+    # that stays on the device; what a reader may count has not moved yet
+    assert slot.state == _DECODE and slot.flying == 1 and slot.pos == 60
+    assert not slot.emitted and slot.cursor == 0 and slot.planned == 64
+    serving.step()          # ... which the next step reads back
+    assert len(slot.emitted) == 1 and slot.cursor == 64
     done = {}
     while serving.queue or serving.num_active:
         done.update({d.uid: d for d in serving.step()})
@@ -254,7 +258,9 @@ def test_chunks_beyond_the_window_run_first_as_their_own_calls():
     phases = [name for name, _ in rec.phases]
     assert phases.count("serving/decode_window") == 1
     slot = next(s for s in serving.slots if s.uid == long.uid)
-    assert slot.state == _DECODE and slot.cursor == 80
+    assert slot.state == _DECODE and slot.planned == 80
+    serving.step()          # the read-back that covers the five chunks
+    assert slot.cursor == 80
 
 
 def test_a_slot_that_retires_mid_window_while_a_chunk_rides():
@@ -264,9 +270,11 @@ def test_a_slot_that_retires_mid_window_while_a_chunk_rides():
     fused.submit(reqs[0])
     _step_until(fused, lambda: any(s.state == _DECODE for s in fused.slots))
     fused.submit(reqs[1])
-    finished = fused.step()
+    assert fused.step() == []       # the end by count is decided at dispatch
     rec = fused.steptrace.records()[-1]
     assert rec.fused_chunks == 1 and rec.decoding == 1
+    assert 0 not in [s.uid for s in fused.slots] and fused.num_active == 2
+    finished = fused.step()         # ... and delivered when the call is read
     assert [d.uid for d in finished] == [0] and len(finished[0].tokens) == 7
     done = {0: finished[0]}
     while fused.queue or fused.num_active:
@@ -287,6 +295,7 @@ def test_eos_on_a_first_token_that_rode_retires_the_slot_at_once():
     serving.submit(Request(uid=1, tokens=reqs[1].tokens, max_new_tokens=8,
                            eos_token_id=eos))
     _step_until(serving, lambda: serving.fused_chunks == 2)
+    serving.step()          # the read-back that brings the first token
     done = serving.steptrace.requests()[-1]
     assert (done.uid, done.finish_reason, done.emitted) == (1, "eos", 1)
 

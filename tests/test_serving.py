@@ -412,3 +412,292 @@ def test_engine_reuses_kv_cache_across_calls():
     h = engine._cache_hits
     engine.forward(toks)
     assert engine._cache_hits == h + 1
+
+
+# ----------------------------------------------------------------------
+# the loop runs one call deep: step k dispatches call k, THEN reads call
+# k-1 back — token for token the contiguous generate() all the same
+# ----------------------------------------------------------------------
+
+
+def _greedy(engine, prompt, n):
+    return np.asarray(engine.generate(prompt[None, :], max_new_tokens=n,
+                                      stop_on_eos=False)[0])
+
+
+def _mk_talkative_engine():
+    """TINY with its matrices scaled up: the plain random model repeats its
+    last token for ever, and an EOS in mid-stream needs a stream."""
+    from deepspeed_tpu.models.gpt import gpt_init_fn
+    _mk_mesh(data=1)
+    params = jax.tree_util.tree_map(
+        lambda a: a * 3.0 if a.ndim >= 2 else a,
+        gpt_init_fn(TINY, dtype=jnp.float32)(jax.random.PRNGKey(7)))
+    return init_inference(
+        model=make_gpt_decode_model(cfg=TINY, name="tiny", params=params),
+        config={"dtype": "float32", "kv_cache_dtype": "float32",
+                "greedy": True, "kv_block_size": 16, "max_out_tokens": 64})
+
+
+def _ends_at(engine, length, n, where, seed):
+    """A prompt whose greedy continuation of `n` tokens has, at an index
+    `where` accepts, a token that appears nowhere before it: an EOS of that
+    token ends the request exactly there. Returns (prompt, reference, k)."""
+    for s in range(seed, seed + 64):
+        prompt = _ragged_prompts(np.random.default_rng(s), (length,))[0]
+        ref = _greedy(engine, prompt, n)
+        for k in range(len(ref)):
+            if where(k) and ref[k] not in ref[:k]:
+                return prompt, ref, k
+    raise AssertionError("no seed gives a fresh token there")
+
+
+def _steps(serving, done, cond=None, limit=400):
+    """Step until `cond()` holds (or the engine is empty); completions go
+    to `done`."""
+    for _ in range(limit):
+        if cond() if cond else not (serving.queue or serving.num_active):
+            return
+        done.update({d.uid: d for d in serving.step()})
+    raise AssertionError("condition never held")
+
+
+def _slot_uids(serving):
+    return [s.uid for s in serving.slots if s.uid is not None]
+
+
+def _all_free(serving):
+    assert serving._pending is None and serving.num_active == 0
+    assert serving.audit().ok
+    assert serving.allocator.available == serving.allocator.capacity
+
+
+def _case_count_end_readmits_the_slot_in_the_next_call():
+    """An end the host can count is decided at dispatch: the request gives
+    up its one slot while its last tokens are in flight, and the step that
+    returns it is the step whose call already carries the next request."""
+    engine = _mk_engine()
+    a, b = _ragged_prompts(np.random.default_rng(11), (5, 9))
+    serving = engine.serving(max_slots=1, max_context=64, prefill_chunk=16)
+    serving.submit(Request(uid="a", tokens=a, max_new_tokens=4,
+                           stop_on_eos=False))
+    serving.submit(Request(uid="b", tokens=b, max_new_tokens=3,
+                           stop_on_eos=False))
+    done = {}
+    _steps(serving, done, lambda: "a" in serving.active_uids()
+           and "a" not in _slot_uids(serving))
+    # it left at dispatch: still active, its tokens undelivered, slot free
+    assert not done and serving.active_uids() == ["a"]
+    assert serving.num_active == 1 and serving.has_free_slot
+    assert len(serving._live()[0].emitted) < 4
+    finished = serving.step()
+    rec = serving.steptrace.records()[-1]
+    assert [d.uid for d in finished] == ["a"] and rec.admitted == 1
+    assert _slot_uids(serving) == ["b"]
+    done["a"] = finished[0]
+    _steps(serving, done)
+    for uid, prompt, n in (("a", a, 4), ("b", b, 3)):
+        np.testing.assert_array_equal(done[uid].tokens,
+                                      _greedy(engine, prompt, n))
+        assert done[uid].finish_reason == "length"
+    st = serving.stats()
+    assert 0 < st["overlapped_calls"] < st["device_calls"]
+    _all_free(serving)
+    assert_one_compile_each(serving)
+
+
+def _case_eos_mid_window_drops_one_speculative_window():
+    """An end only the token decides: the slot rides one more call, whose
+    tokens for it are dropped; slot and blocks are released once."""
+    engine = _mk_talkative_engine()
+    b, c = _ragged_prompts(np.random.default_rng(12), (11, 4))
+    # token 0 is the prompt's, then windows of 4: inside the second or third
+    a, ref, k = _ends_at(engine, 6, 14, lambda k: k >= 5 and (k - 1) % 4 < 3,
+                         seed=120)
+    serving = engine.serving(max_slots=2, max_context=64, prefill_chunk=16,
+                             decode_steps_per_sync=4)
+    serving.submit(Request(uid="a", tokens=a, max_new_tokens=14,
+                           eos_token_id=int(ref[k])))
+    serving.submit(Request(uid="b", tokens=b, max_new_tokens=30,
+                           stop_on_eos=False))
+    done = {}
+    _steps(serving, done, lambda: "a" in done)
+    # the call dispatched before the EOS was read still holds a row for it
+    assert serving._pending is not None and any(
+        r.uid == "a" for r in serving._pending.rows)
+    assert "a" not in serving.active_uids()
+    serving.submit(Request(uid="c", tokens=c, max_new_tokens=5,
+                           stop_on_eos=False))      # into the freed slot
+    _steps(serving, done)
+    np.testing.assert_array_equal(done["a"].tokens, ref[:k + 1])
+    assert done["a"].finish_reason == "eos"
+    np.testing.assert_array_equal(done["b"].tokens, _greedy(engine, b, 30))
+    np.testing.assert_array_equal(done["c"].tokens, _greedy(engine, c, 5))
+    _all_free(serving)
+    assert_one_compile_each(serving)
+
+
+def _case_a_prompts_last_chunk_rides_and_its_first_token_stays_on_device():
+    engine = _mk_engine()
+    a, b = _ragged_prompts(np.random.default_rng(13), (5, 40))
+    serving = engine.serving(max_slots=2, max_context=64, prefill_chunk=16)
+    done = {}
+    serving.submit(Request(uid="a", tokens=a, max_new_tokens=12,
+                           stop_on_eos=False))
+    _steps(serving, done, lambda: serving.decode_steps >= 1)
+    serving.submit(Request(uid="b", tokens=b, max_new_tokens=6,
+                           stop_on_eos=False))
+    _steps(serving, done, lambda: serving.fused_chunks == 3)
+    slot = next(s for s in serving.slots if s.uid == "b")
+    # its first token is call k's `first[0]`: call k+1 takes it from there
+    assert slot.flying == 1 and not slot.emitted
+    assert slot.feed == (serving._pending.id, 2)
+    _steps(serving, done)
+    np.testing.assert_array_equal(done["a"].tokens, _greedy(engine, a, 12))
+    np.testing.assert_array_equal(done["b"].tokens, _greedy(engine, b, 6))
+    _all_free(serving)
+    assert_one_compile_each(serving)
+
+
+def _case_cancel_with_a_call_in_flight_reads_it_first():
+    engine = _mk_engine()
+    a, b, c = _ragged_prompts(np.random.default_rng(14), (7, 12, 3))
+    serving = engine.serving(max_slots=3, max_context=64, prefill_chunk=16)
+    for uid, p, n in (("a", a, 12), ("b", b, 12), ("c", c, 4)):
+        serving.submit(Request(uid=uid, tokens=p, max_new_tokens=n,
+                               stop_on_eos=False))
+    done = {}
+    # c has left its slot: its last token is in the call in flight
+    _steps(serving, done, lambda: "c" not in _slot_uids(serving)
+           and "c" in serving.active_uids())
+    gone = serving.cancel("c")
+    assert serving._pending is None     # the call was read first ...
+    assert gone.finish_reason == "length"       # ... and c had ended in it
+    np.testing.assert_array_equal(gone.tokens, _greedy(engine, c, 4))
+    serving.step()
+    assert serving._pending is not None
+    gone = serving.cancel("a")
+    assert serving._pending is None and gone.finish_reason == "cancelled"
+    assert 0 < len(gone.tokens) < 12
+    np.testing.assert_array_equal(gone.tokens,
+                                  _greedy(engine, a, 12)[:len(gone.tokens)])
+    assert serving.cancel("a") is None and serving.cancelled == 1
+    _steps(serving, done)
+    np.testing.assert_array_equal(done["b"].tokens, _greedy(engine, b, 12))
+    assert "a" not in done and "c" not in done
+    _all_free(serving)
+
+
+def _case_a_hard_deadline_with_a_call_in_flight():
+    engine = _mk_engine()
+    a, b = _ragged_prompts(np.random.default_rng(15), (7, 12))
+    t = {"now": 0.0}
+    serving = engine.serving(max_slots=2, max_context=64, prefill_chunk=16,
+                             clock=lambda: t["now"])
+    serving.submit(Request(uid="a", tokens=a, max_new_tokens=20,
+                           stop_on_eos=False, deadline_ms=4500.0))
+    serving.submit(Request(uid="b", tokens=b, max_new_tokens=12,
+                           stop_on_eos=False))
+    done = {}
+    while serving.queue or serving.num_active:
+        t["now"] += 1.0
+        in_flight = serving._pending is not None
+        for d in serving.step():
+            done[d.uid] = d
+            if d.uid == "a":
+                assert in_flight and serving._pending is not None
+    assert done["a"].finish_reason == "deadline"
+    n = len(done["a"].tokens)
+    assert 0 < n < 20
+    np.testing.assert_array_equal(done["a"].tokens,
+                                  _greedy(engine, a, 20)[:n])
+    np.testing.assert_array_equal(done["b"].tokens, _greedy(engine, b, 12))
+    assert serving.deadline_cancelled == 1
+    _all_free(serving)
+
+
+def _case_spec_decode_never_leaves_a_call_in_flight():
+    """Acceptance decides the next input: `_verify_decode` steps stay
+    synchronous, through the same loop."""
+    engine = _mk_engine()
+    prompts = _ragged_prompts(np.random.default_rng(16), (5, 11, 3, 20))
+    serving = engine.serving(max_slots=2, max_context=64, prefill_chunk=16,
+                             spec_decode={"drafter": "ngram", "draft_k": 2})
+    for i, p in enumerate(prompts):
+        serving.submit(Request(uid=i, tokens=p, max_new_tokens=4 + i,
+                               stop_on_eos=False))
+    done = {}
+    while serving.queue or serving.num_active:
+        done.update({d.uid: d for d in serving.step()})
+        assert serving._pending is None
+    for i, p in enumerate(prompts):
+        np.testing.assert_array_equal(done[i].tokens,
+                                      _greedy(engine, p, 4 + i))
+    st = serving.stats()
+    assert st["overlapped_calls"] == 0 and st["device_calls"] > 0
+    assert st["spec_decode"]["verify_steps"] > 0
+    _all_free(serving)
+
+
+def _case_the_pressure_ladder_off_rest_reads_the_call_first():
+    engine = _mk_engine()
+    prompts = _ragged_prompts(np.random.default_rng(17),
+                              (5, 11, 3, 20, 9, 14, 6, 2))
+    serving = engine.serving(
+        max_slots=2, max_context=64, prefill_chunk=16,
+        decode_steps_per_sync=2, degradation={
+            "enabled": True, "eval_interval": 1, "queue_high": 2,
+            "queue_low": 1, "hold_steps": 2})
+    for i, p in enumerate(prompts):
+        serving.submit(Request(uid=i, tokens=p, max_new_tokens=3 + i,
+                               stop_on_eos=False))
+    done, levels, left_in_flight = {}, [], []
+    while serving.queue or serving.num_active:
+        levels.append(serving.pressure.level)
+        done.update({d.uid: d for d in serving.step()})
+        left_in_flight.append(serving._pending is not None)
+    # it went up the ladder (the 1-step window and beyond), and a step left
+    # a call in flight only while it was at rest
+    assert levels[0] == 0 and max(levels) >= 3
+    assert any(left_in_flight)
+    assert not any(f for lvl, f in zip(levels, left_in_flight) if lvl)
+    for i, p in enumerate(prompts):
+        np.testing.assert_array_equal(done[i].tokens,
+                                      _greedy(engine, p, 3 + i))
+    _all_free(serving)
+
+
+def _case_run_ends_with_nothing_in_flight():
+    """Every row of the last call ended while it ran (an EOS read a step
+    late): nobody waits for it, and it is read all the same."""
+    engine = _mk_talkative_engine()
+    a, ref, k = _ends_at(engine, 6, 10, lambda k: 3 <= k < 9, seed=180)
+    serving = engine.serving(max_slots=1, max_context=64, prefill_chunk=16)
+    res = serving.run([Request(uid="a", tokens=a, max_new_tokens=10,
+                               eos_token_id=int(ref[k]))])
+    np.testing.assert_array_equal(res["a"].tokens, ref[:k + 1])
+    assert res["a"].finish_reason == "eos"
+    assert serving.steptrace._inflight_since is None
+    st = serving.stats()
+    # one call more than the tokens it delivered: the speculative one
+    assert st["device_calls"] == 1 + k + 1 and st["decode_steps"] == k + 1
+    _all_free(serving)
+    assert serving.close().ok
+
+
+ONE_CALL_DEEP = (
+    _case_count_end_readmits_the_slot_in_the_next_call,
+    _case_eos_mid_window_drops_one_speculative_window,
+    _case_a_prompts_last_chunk_rides_and_its_first_token_stays_on_device,
+    _case_cancel_with_a_call_in_flight_reads_it_first,
+    _case_a_hard_deadline_with_a_call_in_flight,
+    _case_spec_decode_never_leaves_a_call_in_flight,
+    _case_the_pressure_ladder_off_rest_reads_the_call_first,
+    _case_run_ends_with_nothing_in_flight,
+)
+
+
+@pytest.mark.parametrize("case", ONE_CALL_DEEP,
+                         ids=lambda f: f.__name__[len("_case_"):])
+def test_one_call_deep_emits_what_generate_emits(case):
+    case()

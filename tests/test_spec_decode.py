@@ -423,9 +423,11 @@ def test_tpot_interpolates_across_decode_window():
     """Injected clock: with a K-token decode window, each burst of K
     tokens must land K samples of (sync interval / K) — not one sample of
     the whole interval, and not a single per-request mean. Trace: window
-    4, max_new 9 -> prefill emits token 1 at t=1 (with tokens 2..5 in the
-    same sync: dt 0), the sync at t=2 emits tokens 6..9 -> four samples of
-    1000ms/4 = 250ms."""
+    4, max_new 9 -> prefill emits token 1 at t=1 and the first window is
+    dispatched; the sync at t=2 dispatches the second and reads the first
+    (tokens 2..5), the sync at t=3 reads the second (tokens 6..9) -> twice
+    four samples of 1000ms/4 = 250ms, stamped at the read-back that
+    delivered them."""
     t = {"now": 0.0}
     engine = _mk_telemetry_engine()
     serving = engine.serving(max_slots=1, max_context=64, prefill_chunk=16,
@@ -438,8 +440,8 @@ def test_tpot_interpolates_across_decode_window():
     # 8 decode-phase tokens -> 8 per-token samples
     assert lat["tpot_ms"]["count"] == 8
     assert lat["tpot_ms"]["max"] == pytest.approx(250.0)
-    assert lat["tpot_ms"]["min"] == pytest.approx(0.0)
-    assert lat["tpot_ms"]["mean"] == pytest.approx(125.0)
+    assert lat["tpot_ms"]["min"] == pytest.approx(250.0)
+    assert lat["tpot_ms"]["mean"] == pytest.approx(250.0)
 
 
 def test_tpot_acceptance_aware_under_spec_decode():

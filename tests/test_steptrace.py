@@ -218,6 +218,67 @@ def test_one_step_of_six_phases_costs_under_50_microseconds():
 # ----------------------------------------------------------------------
 
 
+def test_a_call_in_flight_over_a_whole_step_exposes_none_of_it():
+    """The caller reads call k-1 back with call k already dispatched behind
+    it and says nothing (`ready()` would claim the device ran dry): the
+    interval runs on, over the whole of the step, and the step's exposed
+    time is nil. The first read-back with nothing behind it ends it."""
+    t = {"now": 0.0}
+    st = StepTrace("unit", 8, clock=lambda: t["now"])
+    st.begin_step()
+    with st.phase("serving/decode_window"):
+        t["now"] += 1.0
+        st.dispatched()                     # call 1, left in flight
+        t["now"] += 1.0
+    assert st.end_step(device_calls=1).exposed_s == pytest.approx(1.0)
+    t["now"] += 3.0
+    st.begin_step()
+    with st.phase("serving/decode_window"):
+        t["now"] += 1.0
+        st.dispatched()                     # call 2 behind call 1 ...
+        t["now"] += 2.0                     # ... whose read-back: no ready()
+    with st.phase("serving/emit"):
+        t["now"] += 1.0
+    rec = st.end_step(device_calls=1, overlapped_calls=1)
+    assert rec.t_end - rec.t_start == pytest.approx(4.0)
+    assert rec.exposed_s == 0.0
+    assert (rec.device_calls, rec.overlapped_calls) == (1, 1)
+    st.begin_step()
+    with st.phase("serving/decode_window"):
+        t["now"] += 2.0
+        st.ready()                          # call 2 read, nothing behind it
+        t["now"] += 1.0
+    rec = st.end_step()
+    assert rec.exposed_s == pytest.approx(1.0)
+    assert (rec.device_calls, rec.overlapped_calls) == (0, 0)
+
+
+def test_overlapped_serving_steps_are_counted_and_expose_nothing():
+    """One request decoding alone, one token a call: after the step that
+    prefills it, every step dispatches its call behind the one in flight —
+    `overlapped_calls` says so, `exposed_s` reads 0 for each of those steps,
+    and `stats()` carries the sums of the step records."""
+    clock = Ticker()
+    serving = _engine().serving(max_slots=2, max_context=128, clock=clock)
+    serving.run(_requests(1, max_new=6))
+    recs = serving.steptrace.records()
+    # the prompt's one chunk is a call of its own (nobody decodes yet): it
+    # and the first decode call find nothing in flight; calls 2..5 do; the
+    # request left its slot at call 5's dispatch, and the last step reads
+    calls = [(r.device_calls, r.overlapped_calls) for r in recs]
+    assert calls == [(2, 0)] + [(1, 1)] * 4 + [(0, 0)]
+    assert [r.emitted for r in recs] == [1, 1, 1, 1, 1, 1]
+    for rec in recs[1:-1]:
+        assert rec.exposed_s == 0.0
+        assert dict(rec.phases)["serving/decode_window"] > 0
+    assert recs[0].exposed_s > 0 and recs[-1].exposed_s > 0
+    st = serving.stats()
+    assert st["device_calls"] == sum(r.device_calls for r in recs) == 6
+    assert st["overlapped_calls"] == sum(r.overlapped_calls
+                                         for r in recs) == 4
+    assert serving.steptrace._inflight_since is None
+
+
 def test_serving_phases_tile_every_step_under_an_injected_clock():
     clock = Ticker()
     serving = _engine().serving(max_slots=2, max_context=128, clock=clock)
