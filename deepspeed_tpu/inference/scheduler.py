@@ -39,6 +39,9 @@ speculative window, dropped at read-back. What needs the tokens first (spec
 decode, the streamed per-layer walk, a pressure ladder off rest, a prompt's
 last chunk as a call of its own, cancel, handoff, audit, close) reads the
 call in flight back before it goes on: `_drain` is the one place.
+Every such call has a record of its own in the step timeline's call ring
+(`telemetry/steptrace.py::CallRecord`): opened where it goes out
+(`_dispatching`), closed where it is read (`_read_back`).
 
 Compile accounting is first-class: `compile_stats()` reads the jit caches,
 and the serving tests assert <= 1 compile per bucket across a mixed-length
@@ -64,6 +67,7 @@ moves.
 """
 
 import collections
+import contextlib
 import dataclasses
 import time
 from typing import Any, Dict, List, Optional, Sequence
@@ -180,12 +184,14 @@ class _Call:
     request's life (`_vacate` puts a new one in its place), so a row here
     can only ever reach the request it was sampled for."""
     __slots__ = ("id", "out", "prev", "mixed", "win", "rows", "firsts",
-                 "chunks", "riding", "leaving", "t0")
+                 "chunks", "riding", "leaving")
 
     def __init__(self, id, out, prev, mixed, win, rows, firsts, chunks,
-                 riding, t0):
+                 riding):
         self.id = id            # what a slot's `feed` names (a number, not
-                                # the call: a slot holds no call alive)
+                                # the call: a slot holds no call alive) and
+                                # its `steptrace.CallRecord`, which has its
+                                # stamps
         self.out = out          # the program's output, still on the device
         self.prev = prev        # of it, (first [W], nxt [S, win]) for the
                                 # next call's `pick`
@@ -198,7 +204,6 @@ class _Call:
                                 # dispatched before it
         self.riding = riding    # (request, start) of the chunks that rode
         self.leaving = []       # requests that gave up their slot at dispatch
-        self.t0 = t0
 
     def awaited(self):
         """Does anything wait for this call's read-back: a token a live
@@ -1036,11 +1041,12 @@ class ServingEngine:
         across replicas because every engine shares the router's clock."""
         self._clock = self.steptrace.clock = clock
 
-    def _phase(self, name):
+    def _phase(self, name, **attrs):
         """One phase of the step timeline (`telemetry/steptrace.py::Phase`):
-        trace annotation, chrome event when that sink is on, the step ring,
-        and the `t0`/`t1` stamps the request tracer reuses."""
-        return self.steptrace.phase(name, tid=self.trace_tid)
+        trace annotation (which `attrs` ride), chrome event when that sink
+        is on, the step ring, and the `t0`/`t1` stamps a call's record
+        takes."""
+        return self.steptrace.phase(name, tid=self.trace_tid, **attrs)
 
     def submit(self, request: Request, prefill_only: bool = False,
                hashes: Optional[List[bytes]] = None, trace=None,
@@ -1728,15 +1734,20 @@ class ServingEngine:
             # the cap score as padding and land past the cursor (dead)
             dlens = np.minimum(dlens, self.pressure.draft_cap)
         toks = np.concatenate([tok[:, None], drafts], axis=1)
-        self._count_call()
-        with self._phase("serving/verify") as ph:
+        with self._dispatching("serving/verify", "verify", rows=len(dec),
+                               win=self.draft_k + 1):
             st.dispatched()
             tgt, self.pool = self._verify_step(self.engine.params, toks,
                                                pos, self.pool, tables,
                                                self._next_rng())
-            # THE one host roundtrip per verify step — acceptance runs host-side, amortized over k+1 tokens x all slots
-            tgt = np.asarray(self._read_back(tgt))      # [S, draft_k+1]
-            st.ready()
+        # THE one host roundtrip per verify step — acceptance runs host-side, amortized over k+1 tokens x all slots
+        with self._read_back(self.device_calls, tgt) as (scored, rec):
+            self._accept(dec, drafts, dlens, np.asarray(scored),  # [S, k+1]
+                         rec, finished)
+
+    def _accept(self, dec, drafts, dlens, tgt, rec, finished):
+        """Hand out what the verify call `rec` scored: a slot's longest
+        agreeing prefix and its bonus token."""
         tr_on = self.tracer.enabled
         self.verify_calls += 1
         self.decode_steps += 1
@@ -1771,12 +1782,13 @@ class ServingEngine:
                 # truncates the accepted tail — only tokens that actually
                 # reached the output count toward the tokens/step multiple
                 self.spec_emitted_tokens += j
-                self._observe_tpot(s, anchor, j, ph.t1)
+                self._observe_tpot(s, anchor, j, rec.t_wait1)
                 if tr_on and ctx is not None:
-                    self.tracer.record(ctx, "verify", ph.t0, ph.t1 - ph.t0,
+                    self.tracer.record(ctx, "verify", rec.t_launch0,
+                                       rec.t_wait1 - rec.t_launch0,
                                        tid=self.trace_tid,
                                        attrs={"drafted": dlen, "accepted": n,
-                                              "emitted": j})
+                                              "emitted": j, "call": rec.id})
                 if self.flightrec.enabled and n < dlen:
                     # spec-decode rollback: the cursor rewound past dlen-n
                     # rejected draft tokens — O(1), but worth the black box
@@ -1825,9 +1837,10 @@ class ServingEngine:
         params = self.engine.params
         # the step timeline: phases tile the step (admit, each prefill
         # chunk that is a call of its own, decode_build, decode_window — the
-        # decode call's dispatch, with or without chunks riding it, and the
-        # blocking read of the call in flight — or draft + verify, emit,
-        # housekeeping); dispatched()/ready() bracket the device calls
+        # decode call's dispatch, with or without chunks riding it — or
+        # draft + verify, read_back — the blocking read of the call in
+        # flight: the host's whole wait — emit, housekeeping);
+        # dispatched()/ready() bracket the device calls
         st = self.steptrace
         st.begin_step()
         compiled0 = self._compiled_programs()
@@ -1971,10 +1984,24 @@ class ServingEngine:
         return not self.streamed and not self.spec_on and not (
             self.pressure is not None and self.pressure.level)
 
-    def _count_call(self):
-        """A call whose tokens a blocking read fetches is about to go out."""
+    @contextlib.contextmanager
+    def _dispatching(self, phase, program, **work):
+        """THE place a call whose tokens a blocking read fetches goes out (a
+        decode or mixed call, a verify call, a prompt's last chunk as a call
+        of its own): counted, named — `device_calls` is its id from here —
+        and dispatched inside `phase`, whose trace annotation carries the
+        id; then its `steptrace.CallRecord` is opened with the phase's two
+        stamps, whether a call was unread as it went out, and its `work`
+        (`rows`, `win`, `firsts`, and `chunks`: the chunks riding it and
+        those dispatched before it whose progress its read-back confirms).
+        `_read_back` closes it."""
+        queued = self._pending is not None
         self.device_calls += 1
-        self.overlapped_calls += self._pending is not None
+        self.overlapped_calls += queued
+        with self._phase(phase, call=self.device_calls) as ph:
+            yield ph
+        self.steptrace.open_call(self.device_calls, program, ph.t0, ph.t1,
+                                 queued, **work)
 
     def _drain(self, finished):
         """THE place a call in flight is read back ahead of its turn: by a
@@ -2096,11 +2123,10 @@ class ServingEngine:
         (`_drain`). Returns `_chunk_written`'s walk counts."""
         st = self.steptrace
         ctx = slot.trace                      # _emit may retire the slot
-        with self._phase("serving/prefill_chunk") as ph:
-            t0 = ph.t0
-            chunk, last, final = self._chunk_input(slot, start)
-            if final:
-                self._count_call()
+        chunk, last, final = self._chunk_input(slot, start)
+        with (self._dispatching("serving/prefill_chunk", "prefill", firsts=1,
+                                chunks=len(self._unread_chunks) + 1)
+              if final else self._phase("serving/prefill_chunk")) as ph:
             st.dispatched()
             tok, self.pool = self._prefill_step(
                 params, chunk, np.asarray([start], np.int32),
@@ -2121,21 +2147,24 @@ class ServingEngine:
             if self.step_counter_names and not final:
                 tok, counts = tok
                 self._parked_counts.append(counts)
+        t1 = ph.t1
         if final:
             self._drain(finished)       # in the device's order, and its own
                                         # phases: the call in flight first
-            with self._phase("serving/prefill_chunk") as ph1:
-                # first-token readback at prefill completion — one scalar per prompt, the TTFT emission point
-                first = int(np.asarray(self._read_back(tok))[0])
-                st.ready()
-                self._chunks_run(self._unread_chunks)
-                self._unread_chunks = []
-                self._first_token(slot, first, finished)
-            ph = ph1
+            # first-token readback at prefill completion — one scalar per prompt, the TTFT emission point
+            with self._read_back(self.device_calls, tok) as (first, rec):
+                with self._phase("serving/emit"):
+                    self._chunks_run(self._unread_chunks)
+                    self._unread_chunks = []
+                    self._first_token(slot, int(np.asarray(first)[0]),
+                                      finished)
+            t1 = rec.t_wait1
         if self.tracer.enabled and ctx is not None:
-            self.tracer.record(ctx, "prefill_chunk", t0, ph.t1 - t0,
-                               tid=self.trace_tid,
-                               attrs={"start": start, "chunk": self.chunk})
+            attrs = {"start": start, "chunk": self.chunk}
+            if final:
+                attrs["call"] = rec.id
+            self.tracer.record(ctx, "prefill_chunk", ph.t0, t1 - ph.t0,
+                               tid=self.trace_tid, attrs=attrs)
         return reach
 
     def _launch(self, dec, riding, params, tok, pos, tables, finished):
@@ -2189,34 +2218,41 @@ class ServingEngine:
                 chunk_tables = jax.tree_util.tree_map(
                     lambda t: t[:, None],
                     self._tables_arg(self.tables[idx], idx))
-        self._count_call()
+        step_fn = self._mixed_step if riding else \
+            self._degraded_decode_step() if use_w1 else self._decode_step
         rode = [0, 0, 0, 0]
-        with self._phase("serving/decode_window") as ph:
+        # the dispatch phase holds the jitted call alone: its two stamps are
+        # the call record's launch, the arguments' hand-off and the enqueue
+        with self._dispatching(
+                "serving/decode_window",
+                "mixed" if riding else "decode_w1" if use_w1 else "decode",
+                rows=len(dec), win=win, firsts=len(finals),
+                chunks=len(self._unread_chunks) + n):
             st.dispatched()
             if riding:
-                out, self.pool = self._mixed_step(
+                out, self.pool = step_fn(
                     params, chunks, starts, lasts, chunk_tables, np.int32(n),
                     tok, pos, self.pool, self._tables_arg(tables),
                     self._next_rng())
+            else:
+                out, self.pool = step_fn(params, tok, pos, self.pool,
+                                         self._tables_arg(tables),
+                                         self._next_rng())
+        # what the call planned is booked here, while the device runs
+        with self._phase("serving/decode_build"):
+            if riding:
                 program = (self.engine.model_spec.paged_attn_programs
                            or {}).get("mixed/prefill_chunk")
                 rode = [sum(counts) for counts in zip(*(
                     self._chunk_written(slot, start, program)
                     for slot, start in riding))]
                 self.fused_chunks += n
-            else:
-                step_fn = self._degraded_decode_step() if use_w1 \
-                    else self._decode_step
-                out, self.pool = step_fn(params, tok, pos, self.pool,
-                                         self._tables_arg(tables),
-                                         self._next_rng())
-            # counted here, while the device runs
             walk = self._decode_walk(dec, pos, win)
             toks = out[0] if self.step_counter_names else out
             call = _Call(self.device_calls, out,
                          toks if riding else (self._no_prev[0], toks),
                          bool(riding), win, dec, finals,
-                         self._unread_chunks, riding, ph.t0)
+                         self._unread_chunks, riding)
             self._unread_chunks = []
             self._pending = call
             for s in dec:
@@ -2244,12 +2280,15 @@ class ServingEngine:
         delivered the token. A request that ended while the call ran (EOS in
         the call before, a deadline, a cancel) takes nothing: that was its
         one speculative window."""
-        st = self.steptrace
-        with self._phase("serving/decode_window") as ph:
-            # THE one host roundtrip per decode window — EOS/retirement decisions are host-side, amortized over `win` tokens
-            toks = self._read_back(call.out)
-            if self._pending is None:
-                st.ready()      # else the next call is queued behind it
+        # THE one host roundtrip per decode window — EOS/retirement decisions are host-side, amortized over `win` tokens
+        with self._read_back(call.id, call.out) as (toks, rec):
+            self._hand_out(call, toks, rec, finished)
+
+    def _hand_out(self, call, toks, rec, finished):
+        """Emit what `call`'s read-back brought; `rec` is its record, whose
+        stamps (launch to the read's return) the request tracer's spans and
+        the token gaps take."""
+        t0, t1 = rec.t_launch0, rec.t_wait1
         self._chunks_run(call.chunks)
         first, nxt = toks if call.mixed else ((), toks)
         nxt = np.asarray(nxt)                       # [S, win]
@@ -2258,10 +2297,10 @@ class ServingEngine:
             for slot, start in call.riding:
                 if slot.trace is not None:
                     self.tracer.record(
-                        slot.trace, "prefill_chunk", call.t0,
-                        ph.t1 - call.t0, tid=self.trace_tid,
+                        slot.trace, "prefill_chunk", t0, t1 - t0,
+                        tid=self.trace_tid,
                         attrs={"start": start, "chunk": self.chunk,
-                               "fused": True})
+                               "fused": True, "call": call.id})
         for slot, i in call.firsts:
             if slot.state != _FREE:
                 slot.flying -= 1
@@ -2279,11 +2318,11 @@ class ServingEngine:
                     j += 1
                     if s.state == _FREE:            # retired mid-window
                         break
-                self._observe_tpot(s, anchor, j, ph.t1)
+                self._observe_tpot(s, anchor, j, t1)
                 if tr_on and ctx is not None:
-                    self.tracer.record(ctx, "decode_window", call.t0,
-                                       ph.t1 - call.t0, tid=self.trace_tid,
-                                       attrs={"emitted": j})
+                    self.tracer.record(ctx, "decode_window", t0, t1 - t0,
+                                       tid=self.trace_tid,
+                                       attrs={"emitted": j, "call": call.id})
 
     def _decode_walk(self, dec, pos, win):
         """What the paged decode kernel's walk has to do in a call of `win`
@@ -2309,8 +2348,25 @@ class ServingEngine:
                 int(whole.sum())) + walk[4:]
         return walk
 
-    def _read_back(self, out):
-        """THE blocking read of a step program's tokens (the mixed step's: a
+    @contextlib.contextmanager
+    def _read_back(self, call_id, out):
+        """THE place a call is read, which every blocking read passes: the
+        wait is a phase of its own (`serving/read_back`: the host does
+        nothing else in it), the body hands out what came — (the tokens, the
+        call's record with the wait's stamps) — and the record is closed
+        with the tokens live requests took."""
+        st = self.steptrace
+        with self._phase("serving/read_back", call=call_id) as ph:
+            toks = self._fetch(out)
+            if self._pending is None:
+                st.ready()      # else the next call is queued behind it
+        rec = st.read_call(call_id, ph.t0, ph.t1)
+        tokens0 = self.tokens_generated
+        yield toks, rec
+        st.close_call(rec, self.tokens_generated - tokens0)
+
+    def _fetch(self, out):
+        """The `device_get` of a step program's tokens (the mixed step's: a
         pair, first tokens and window tokens). A counted model's program
         hands (tokens, counts): the counts, and those parked by programs
         whose tokens nobody read, come back in the same `device_get` and are

@@ -87,19 +87,21 @@ class Span:
     selects the chrome-trace track (default 0 — single-engine timelines; the
     serving stack passes its replica tid so pool timelines stay separated).
     The clock is read only for a sink; `telemetry/steptrace.py::Phase` adds
-    the step ring as a third destination."""
+    the step ring as a third destination. `attrs` ride the annotation alone
+    (`serving/decode_window#call=7#` in a profiler's trace)."""
 
-    __slots__ = ("name", "sink", "tid", "_t0", "_nvtx")
+    __slots__ = ("name", "sink", "tid", "attrs", "_t0", "_nvtx")
 
-    def __init__(self, name, sink=None, tid=0):
+    def __init__(self, name, sink=None, tid=0, **attrs):
         self.name = name
         self.sink = sink
         self.tid = tid
+        self.attrs = attrs
         self._t0 = 0.0
         self._nvtx = None
 
     def __enter__(self):
-        self._nvtx = nvtx.annotate(self.name)
+        self._nvtx = nvtx.annotate(self.name, **self.attrs)
         self._nvtx.__enter__()
         if self.sink is not None:
             self._t0 = time.perf_counter()

@@ -17,13 +17,25 @@ the caller read one call back with the next already dispatched behind it and
 so did not call `ready()`) — the time the chip provably had nothing of this
 step queued. Requests get a
 record when they are admitted and a completed one, under the same `uid`, when
-they retire.
+they retire. A DEVICE CALL whose output the host reads gets a record of its
+own (`CallRecord`): opened where it is dispatched, completed under the same
+`id` where it is read back, which is a step later where the serving loop runs
+one call deep. The step is the host's unit and the call the device's: a
+`StepRecord` holds what the host did between two stamps — some of it booked
+at a call's DISPATCH (`prefill_chunks`, `fused_chunks`, `decoding`,
+`device_calls`, `overlapped_calls`, the walk counts, `ssm_*`), some at the
+READ-BACK of the call the step before dispatched (`emitted`, `counters`) —
+and a `CallRecord` holds one call's work beside that call's four stamps: the
+host entering and leaving its launch, and entering and leaving the blocking
+read. The blocking read is a phase of its own (`serving/read_back`), so a
+step's phases say how long the host WAITED; the rest of the step is its work.
 
 Records are plain tuples of numbers and strings in bounded rings, selected by
-stamp (`records(since, until)`, `requests(since, until)`), so any window of a
-run can be read after the fact. A recorder holds the rings and never the
-engine: `latest("serving")` hands the rings of an engine that is gone to
-whoever asks in the same process (the benchmark's readers do).
+stamp (`records(since, until)`, `requests(since, until)`, `calls(since,
+until)`), so any window of a run can be read after the fact. A recorder
+holds the rings and never the engine: `latest("serving")` hands the rings of
+an engine that is gone to whoever asks in the same process (the benchmark's
+readers do).
 """
 
 import collections
@@ -32,33 +44,41 @@ import time
 
 from deepspeed_tpu.telemetry.spans import Span
 
-__all__ = ["StepTrace", "Phase", "StepRecord", "RequestRecord", "latest",
-           "DEFAULT_CAPACITY"]
+__all__ = ["StepTrace", "Phase", "StepRecord", "RequestRecord", "CallRecord",
+           "latest", "DEFAULT_CAPACITY"]
 
 DEFAULT_CAPACITY = 8192     # steps kept: about ten minutes of 76 ms steps
 
 StepRecord = collections.namedtuple("StepRecord", [
+    # Serving: a field is booked either at the DISPATCH of this step's call
+    # or at the READ-BACK this step made, which one call deep is of the call
+    # the step BEFORE dispatched. `CallRecord` has a call's work beside its
+    # own time; the step numbers in it say which two records it touched.
     "step",             # 1-based index of the step in this recorder
     "t_start", "t_end",  # on the engine's clock (time.perf_counter by default)
     "phases",           # ((name, seconds), ...) in order of first entry;
-                        # the seconds sum to t_end - t_start
+                        # the seconds sum to t_end - t_start;
+                        # "serving/read_back" is the host blocked on a read
     "exposed_s",        # wall time less the in-flight intervals
     "admitted",         # requests given a slot this step
-    "prefill_chunks",   # prefill chunks dispatched this step
+    "prefill_chunks",   # prefill chunks dispatched this step (dispatch)
     "decoding",         # slots in the decode call (0 = no decode call)
-    "emitted",          # tokens emitted this step
+                        # (dispatch)
+    "emitted",          # tokens emitted this step (read-back)
     "queued",           # requests waiting as the step ended
     "free_blocks",      # pool blocks free or reclaimable as the step ended
     "blocked_on",       # "" | "pool" | "slots": why the head of a non-empty
                         # queue was not admitted
     "compiles",         # growth of the step programs' compile caches
-    "counters",         # the model's own counters of this step's device
-                        # calls, in `ServingEngine.step_counter_names` order
-                        # (routed experts: `parallel.moe.ROUTED_COUNTERS`,
-                        # summed over layers); () for a model with none
-    "decode_live_blocks",   # live (slot, logical block) pairs of the decode
-                        # call: pos // block + 1 summed over its decoding
-                        # slots and its tokens
+    "counters",         # the model's own counters of the device calls READ
+                        # in this step, in
+                        # `ServingEngine.step_counter_names` order (routed
+                        # experts: `parallel.moe.ROUTED_COUNTERS`, summed
+                        # over layers); () for a model with none
+    "decode_live_blocks",   # (this and every count below: dispatch) live
+                        # (slot, logical block) pairs of the decode call:
+                        # pos // block + 1 summed over its decoding slots
+                        # and its tokens
     "decode_grid_steps",    # block-axis steps `dstpu_paged_decode`'s walk
                         # takes for those positions, a layer, summed over
                         # the call's tokens (KV heads folded out)
@@ -104,6 +124,33 @@ RequestRecord = collections.namedtuple("RequestRecord", [
                                         # the step that caused each; 0 = not yet
 ])
 
+CallRecord = collections.namedtuple("CallRecord", [
+    "id",               # the scheduler's name for the call (`_Call.id`, what
+                        # a slot's `feed` names): 1, 2, ... in dispatch order
+    "program",          # "decode" | "mixed" | "prefill" (a prompt's last
+                        # chunk as a call of its own) | "verify" | "decode_w1"
+    "step_launch", "step_read",     # StepRecord.step of the step that
+                        # dispatched it and of the one that read it (0 = not
+                        # read yet; equal where the step is synchronous)
+    "t_launch0", "t_launch1",   # the host enters and leaves the dispatch: the
+                        # jitted call's arguments handed over, the program
+                        # enqueued (the dispatch phase's own stamps)
+    "t_wait0", "t_wait1",   # the host enters and leaves the blocking read of
+                        # its output (`serving/read_back`'s stamps); None
+                        # while the call is in flight
+    "queued_behind",    # another call was unread at its dispatch: the device
+                        # had work queued ahead of this one
+    "rows",             # decoding slots in it ...
+    "win",              # ... each of which it gives this many tokens
+    "chunks",           # prefill chunks the device ran for it: those riding
+                        # it (or the one of a "prefill" call) and those
+                        # dispatched before it as calls nobody read
+    "firsts",           # ... of them a prompt's last: first tokens it samples
+    "emitted",          # tokens its read-back handed to live requests, first
+                        # tokens included (<= rows * win + firsts: a tail past
+                        # `max_new`, an EOS, a request that ended meanwhile)
+])
+
 _LATEST = {}        # subsystem -> the most recently created recorder
 
 
@@ -123,8 +170,8 @@ class Phase(Span):
 
     __slots__ = ("trace", "t0", "t1")
 
-    def __init__(self, name, trace, tid=0):
-        Span.__init__(self, name, sink=trace.sink, tid=tid)
+    def __init__(self, name, trace, tid=0, **attrs):
+        Span.__init__(self, name, sink=trace.sink, tid=tid, **attrs)
         self.trace = trace
         self.t0 = self.t1 = 0.0
 
@@ -145,7 +192,8 @@ class Phase(Span):
 
 
 class StepTrace:
-    """Ring of step records and ring of request records for one engine."""
+    """Rings of step records, request records and device-call records for
+    one engine."""
 
     def __init__(self, subsystem, capacity=DEFAULT_CAPACITY,
                  clock=time.perf_counter, sink=None):
@@ -158,6 +206,8 @@ class StepTrace:
         self.step = 0
         self._steps = collections.deque(maxlen=self.capacity)
         self._requests = collections.deque(maxlen=self.capacity)
+        self._calls = collections.deque(maxlen=self.capacity)
+        self._open_calls = {}       # id -> CallRecord dispatched, not yet read
         self._mark = None           # end of the last phase; None = no open step
         self._t_start = 0.0
         self._phases = {}
@@ -178,8 +228,10 @@ class StepTrace:
         if device_idle:
             self._inflight_since = None
 
-    def phase(self, name, tid=0):
-        return Phase(name, self, tid=tid)
+    def phase(self, name, tid=0, **attrs):
+        """`attrs` go to the trace annotation (`call=<id>` on a call's
+        dispatch and read-back), formatted only while a profiler runs."""
+        return Phase(name, self, tid=tid, **attrs)
 
     def dispatched(self):
         """A device call is about to be enqueued."""
@@ -234,6 +286,30 @@ class StepTrace:
         self._requests.append(rec)
         return rec
 
+    # ---- device calls --------------------------------------------------
+
+    def open_call(self, id, program, t_launch0, t_launch1,
+                  queued_behind=False, rows=0, win=0, chunks=0, firsts=0):
+        """A call whose output the host will read has been dispatched, by
+        the open step, between the two stamps (its dispatch phase's)."""
+        rec = CallRecord(id, program, self.step, 0, t_launch0, t_launch1,
+                         None, None, bool(queued_behind), rows, win, chunks,
+                         firsts, 0)
+        self._open_calls[id] = rec
+        return rec
+
+    def read_call(self, id, t_wait0, t_wait1):
+        """The blocking read of call `id` returned, in the open step,
+        between the two stamps; the record, which `close_call` takes back
+        once the host has handed out what the read brought."""
+        return self._open_calls.pop(id)._replace(
+            step_read=self.step, t_wait0=t_wait0, t_wait1=t_wait1)
+
+    def close_call(self, read, emitted):
+        rec = read._replace(emitted=emitted)
+        self._calls.append(rec)
+        return rec
+
     # ---- reading -------------------------------------------------------
 
     def records(self, since=None, until=None):
@@ -246,6 +322,17 @@ class StepTrace:
         """The newest `n` step records (all of them while the ring holds
         fewer), oldest first."""
         return list(itertools.islice(reversed(self._steps), n))[::-1]
+
+    def calls(self, since=None, until=None):
+        """Call records with `since < t_wait1 <= until`: the calls READ in
+        the window, in the order they were read."""
+        return [c for c in self._calls
+                if (since is None or c.t_wait1 > since)
+                and (until is None or c.t_wait1 <= until)]
+
+    def in_flight(self):
+        """The calls dispatched and not yet read, oldest first."""
+        return list(self._open_calls.values())
 
     def requests(self, since=None, until=None, stamp="t_admit"):
         """One record a request, its newest (completed if it has retired),

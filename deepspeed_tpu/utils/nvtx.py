@@ -63,8 +63,10 @@ def instrument_w_nvtx(func):
     return wrapped
 
 
-def annotate(name):
-    """Context manager for a named trace range (null when unavailable)."""
+def annotate(name, **attrs):
+    """Context manager for a named trace range (null when unavailable).
+    `attrs` are appended to the name as `#key=value#` by the profiler, and
+    only while a session runs: outside one they cost nothing."""
     if _TraceAnnotation is None:
         return contextlib.nullcontext()
-    return _TraceAnnotation(name)
+    return _TraceAnnotation(name, **attrs)

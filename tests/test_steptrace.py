@@ -10,7 +10,9 @@ import collections
 import functools
 import gc
 import json
+import os
 import re
+import sys
 import time
 import weakref
 
@@ -29,13 +31,23 @@ from deepspeed_tpu.models.gpt import (GPTConfig, make_gpt_decode_model,
 from deepspeed_tpu.telemetry import Telemetry, steptrace
 from deepspeed_tpu.telemetry.steptrace import StepTrace
 
+# the benchmark's readers of the call ring and of the wait phase, on a
+# hand-made ring: held beside the benchmark's own checks, run here too
+sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))), "benchmark", "checks"))
+from test_call_ring_readers import (  # noqa: E402,F401
+    test_a_stall_is_judged_within_its_kind_of_call,
+    test_call_ring_reader_on_a_hand_made_ring,
+    test_the_metrics_files_name_their_readers_and_arguments)
+
 pytestmark = pytest.mark.telemetry
 
 TINY = GPTConfig(n_layer=2, n_head=4, d_model=64, max_seq_len=256,
                  vocab_size=256, dtype=jnp.float32, remat=False)
 SERVING_PHASES = {"serving/admit", "serving/prefill_chunk",
                   "serving/decode_build", "serving/decode_window",
-                  "serving/emit", "serving/housekeeping"}
+                  "serving/read_back", "serving/emit",
+                  "serving/housekeeping"}
 
 
 def _mk_mesh():
@@ -193,7 +205,7 @@ def test_records_and_requests_are_selected_by_stamp():
     assert [r.uid for r in st.requests(3.0, 4.0, stamp="t_finish")] == ["r0"]
 
 
-def test_one_step_of_six_phases_costs_under_50_microseconds():
+def test_one_step_of_seven_phases_costs_under_50_microseconds():
     st = StepTrace("unit", 64)
     names = sorted(SERVING_PHASES)
     n = 2000
@@ -211,6 +223,86 @@ def test_one_step_of_six_phases_costs_under_50_microseconds():
     # the quietest of five rounds: the other test workers share these cores
     per_step = min(mean_of_n() for _ in range(5))
     assert per_step < 50e-6, f"{per_step * 1e6:.1f} us a step"
+
+
+def test_a_call_opened_in_one_step_and_read_in_the_next_is_one_record():
+    t = {"now": 0.0}
+    st = StepTrace("unit", 8, clock=lambda: t["now"])
+    st.begin_step()                         # step 1 dispatches call 7
+    with st.phase("serving/decode_window", call=7) as ph:
+        t["now"] += 1.0
+    opened = st.open_call(7, "mixed", ph.t0, ph.t1, queued_behind=True,
+                          rows=3, win=4, chunks=2, firsts=1)
+    assert opened.t_wait1 is None and opened.step_read == 0
+    assert st.calls() == [] and st.in_flight() == [opened]
+    st.end_step(device_calls=1)
+    t["now"] += 0.5
+    st.begin_step()                         # step 2 reads it back
+    with st.phase("serving/decode_build"):
+        t["now"] += 0.25
+    with st.phase("serving/read_back", call=7) as ph:
+        t["now"] += 2.0
+    read = st.read_call(7, ph.t0, ph.t1)
+    with st.phase("serving/emit"):
+        t["now"] += 0.5
+    rec = st.close_call(read, 11)
+    st.end_step()
+    assert st.calls() == [rec] and st.in_flight() == []
+    assert (rec.id, rec.program, rec.step_launch, rec.step_read) == \
+        (7, "mixed", 1, 2)
+    assert (rec.t_launch0, rec.t_launch1, rec.t_wait0, rec.t_wait1) == \
+        (0.0, 1.0, 1.75, 3.75)
+    assert (rec.queued_behind, rec.rows, rec.win, rec.chunks, rec.firsts,
+            rec.emitted) == (True, 3, 4, 2, 1, 11)
+    assert all(isinstance(v, (int, float, str, bool)) for v in rec)
+    # selected by the read's return, as steps are by their end
+    assert st.calls(since=3.75) == [] and st.calls(until=3.75) == [rec]
+    assert st.calls(since=1.0, until=3.0) == []
+
+
+def test_the_wait_is_a_phase_of_its_own_and_the_phases_still_tile():
+    t = {"now": 0.0}
+    st = StepTrace("unit", 8, clock=lambda: t["now"])
+    st.begin_step()
+    for name, took in (("serving/admit", 0.125), ("serving/decode_build", 0.5),
+                       ("serving/decode_window", 0.25),
+                       ("serving/read_back", 4.0), ("serving/emit", 1.0),
+                       ("serving/housekeeping", 0.125)):
+        with st.phase(name):
+            t["now"] += took
+    rec = st.end_step()
+    phases = dict(rec.phases)
+    assert phases["serving/read_back"] == 4.0
+    assert sum(phases.values()) == rec.t_end - rec.t_start == 6.0
+    # the host's own work is the rest of the step
+    assert rec.t_end - rec.t_start - phases["serving/read_back"] == 2.0
+
+
+def test_a_phases_attributes_ride_the_annotation_alone(tmp_path):
+    st = StepTrace("unit", 8)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        st.begin_step()
+        with st.phase("serving/decode_window", call=41):
+            pass
+        with st.phase("serving/read_back", call=40):
+            pass
+        rec = st.end_step()
+    finally:
+        jax.profiler.stop_trace()
+    assert [name for name, _ in rec.phases] == \
+        ["serving/decode_window", "serving/read_back"]
+    data = jax.profiler.ProfileData.from_file(
+        str(next(tmp_path.rglob("*.xplane.pb"))))
+    seen = {}
+    for plane in data.planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for ev in line.events:
+                    if ev.name.startswith("serving/"):
+                        seen[ev.name] = dict(ev.stats)
+    assert seen["serving/decode_window"]["call"] == 41
+    assert seen["serving/read_back"]["call"] == 40
 
 
 # ----------------------------------------------------------------------
@@ -277,6 +369,132 @@ def test_overlapped_serving_steps_are_counted_and_expose_nothing():
     assert st["overlapped_calls"] == sum(r.overlapped_calls
                                          for r in recs) == 4
     assert serving.steptrace._inflight_since is None
+
+
+def _overlapped(clock):
+    """A request decoding alone, a second prompt of three chunks arriving
+    behind it: a prompt's chunk as a call of its own (nobody decodes yet),
+    decode calls, mixed calls."""
+    serving = _engine().serving(max_slots=2, max_context=128, clock=clock,
+                                decode_steps_per_sync=2)
+    first, second = _requests(2, prompt_len=9, max_new=12)
+    second.tokens = np.resize(second.tokens, 40)
+    serving.submit(first)
+    for _ in range(3):                      # three steps one call deep
+        serving.step()
+    assert serving.overlapped_calls == 2
+    serving.submit(second)
+    return serving
+
+
+def _speculating(clock):
+    serving = _engine().serving(
+        max_slots=2, max_context=128, clock=clock,
+        spec_decode={"drafter": "ngram", "draft_k": 2})
+    for req in _requests(2, prompt_len=20, max_new=6):
+        serving.submit(req)
+    return serving
+
+
+def _shrunk_to_one_token(clock):
+    """The pressure ladder's window rung held on: the one-token program."""
+    serving = _engine().serving(max_slots=2, max_context=128, clock=clock,
+                                decode_steps_per_sync=4,
+                                degradation={"enabled": True})
+    serving.pressure.level = 3
+    serving.pressure.update = lambda finished: None
+    for req in _requests(2, prompt_len=9, max_new=5):
+        serving.submit(req)
+    return serving
+
+
+@pytest.mark.parametrize("scenario, programs, one_deep", [
+    (_overlapped, {"prefill", "decode", "mixed"}, True),
+    (_speculating, {"prefill", "verify"}, False),
+    (_shrunk_to_one_token, {"prefill", "decode_w1"}, False)],
+    ids=["overlapped", "spec_decode", "window_of_one"])
+def test_every_device_call_has_one_record_with_its_work_and_both_steps(
+        scenario, programs, one_deep):
+    serving = scenario(Ticker())
+    while serving.queue or serving.num_active:
+        serving.step()
+    st = serving.steptrace
+    recs, calls = st.records(), st.calls()
+    by_step = {r.step: r for r in recs}
+    assert st.in_flight() == []
+    assert [c.id for c in calls] == list(range(1, serving.device_calls + 1))
+    assert len(calls) == sum(r.device_calls for r in recs)
+    assert sum(c.queued_behind for c in calls) == serving.overlapped_calls \
+        == sum(r.overlapped_calls for r in recs)
+    assert sum(c.emitted for c in calls) == sum(r.emitted for r in recs) \
+        == serving.tokens_generated
+    assert {c.program for c in calls} == programs
+    for c in calls:
+        # four stamps, in order (a read that follows its dispatch at once
+        # starts where the dispatch ended: phases share their boundaries)
+        assert c.t_launch0 < c.t_launch1 <= c.t_wait0 < c.t_wait1
+        assert c.step_launch <= c.step_read <= c.step_launch + 1
+        assert by_step[c.step_launch].device_calls >= 1
+        assert by_step[c.step_launch].t_start <= c.t_launch0 \
+            and c.t_launch1 <= by_step[c.step_launch].t_end
+        assert dict(by_step[c.step_read].phases)["serving/read_back"] >= \
+            c.t_wait1 - c.t_wait0
+        assert by_step[c.step_read].t_start <= c.t_wait0 \
+            and c.t_wait1 <= by_step[c.step_read].t_end
+        assert 0 <= c.emitted <= c.rows * c.win + c.firsts
+        assert c.chunks > 0 or c.program not in ("mixed", "prefill")
+        assert c.firsts <= c.chunks
+    # every chunk's progress is confirmed by one call's read-back
+    assert sum(c.chunks for c in calls) == serving.prefill_chunks
+    assert sum(c.chunks for c in calls if c.program == "mixed") >= \
+        serving.fused_chunks > 0 or not one_deep
+    # a call put behind another is read by the NEXT step; the others by
+    # their own step or, a decode call nothing followed, by a drain
+    assert all(c.step_read == c.step_launch + 1
+               for c in calls if c.queued_behind and c.program != "prefill")
+    assert one_deep == any(c.queued_behind for c in calls)
+    assert one_deep or all(c.step_read == c.step_launch for c in calls)
+    # the wait is told from the work in every step that read a call
+    for rec in recs:
+        assert ("serving/read_back" in dict(rec.phases)) == any(
+            c.step_read == rec.step for c in calls)
+
+
+def test_request_spans_name_the_call_that_served_them(tmp_path):
+    from deepspeed_tpu.telemetry.tracing import load_spans
+    serving = _engine(enabled=True, tracing=True, prometheus=False,
+                      jsonl=False, output_path=str(tmp_path)).serving(
+        max_slots=2, max_context=128, decode_steps_per_sync=2)
+    first, second = _requests(2, prompt_len=9, max_new=8)
+    second.tokens = np.resize(second.tokens, 40)
+    serving.submit(first)
+    for _ in range(3):
+        serving.step()
+    serving.submit(second)
+    while serving.queue or serving.num_active:
+        serving.step()
+    serving.close()
+    calls = {c.id: c for c in serving.steptrace.calls()}
+    spans = load_spans(tmp_path / "serving.trace.jsonl")
+    windows = [s for s in spans if s["name"] == "decode_window"]
+    assert windows and serving.fused_chunks > 0
+    for s in windows + [s for s in spans if s["name"] == "prefill_chunk"
+                        and "call" in s["attrs"]]:
+        c = calls[s["attrs"]["call"]]
+        # a span runs from the call's launch to the return of its read
+        assert s["ts"] == pytest.approx(c.t_launch0, abs=1e-6)
+        assert s["ts"] + s["dur"] == pytest.approx(c.t_wait1, abs=1e-6)
+    assert sum(s["attrs"]["emitted"] for s in windows) == sum(
+        c.emitted - c.firsts for c in calls.values() if c.rows)
+    fused = [s for s in spans if s["name"] == "prefill_chunk"
+             and s["attrs"].get("fused")]
+    assert len(fused) == serving.fused_chunks
+    assert {calls[s["attrs"]["call"]].program for s in fused} == {"mixed"}
+    # a chunk that was a call of its own and not a prompt's last names none
+    alone = [s for s in spans if s["name"] == "prefill_chunk"
+             and not s["attrs"].get("fused")]
+    assert sum("call" in s["attrs"] for s in alone) == sum(
+        c.program == "prefill" for c in calls.values())
 
 
 def test_serving_phases_tile_every_step_under_an_injected_clock():
