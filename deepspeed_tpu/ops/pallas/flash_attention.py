@@ -8,18 +8,37 @@ custom VJP with the standard recomputation backward.
 
 Layout: [B, H, T, D] (wrapper transposes from the zoo's [B, T, H, D]).
 
-K/V STREAM from HBM: the grid carries a KV-block dimension and Pallas's
+K/V STREAM from HBM: the grid is (batch x head, live tile) and Pallas's
 pipeline DMAs one double-buffered [block_k, D] (resp. [block_q, D] in the
 dk/dv pass) tile into VMEM per grid step while the previous tile computes.
 The online-softmax state (acc/m/l) lives in VMEM scratch that persists
-across the sequential KV grid steps, so the kernel's VMEM working set is
-O(block), not O(T) — sequence length is bounded by HBM capacity
-(`flash_max_seq`), not the old ~14k-token whole-slab VMEM cap. Causal
-grids skip fully-masked tiles entirely: compute and output writes are
-predicated off (`pl.when`), and the block index maps clamp to the diagonal
-frontier so the dead steps' DMAs are elided too (repeated consecutive
-block indices fetch nothing — same trick as the decode kernel's prefix
-clamp).
+across the sequential steps of a q block, so the kernel's VMEM working set
+is O(block), not O(T) — sequence length is bounded by HBM capacity
+(`flash_max_seq`), not the old ~14k-token whole-slab VMEM cap.
+
+What a tile costs OUTSIDE its matrix products decides the speed (PERF.md
+§6, PR 37, measured on a v5e at [8, 16, 2048, 128] bf16):
+
+- The walk visits LIVE tiles only: the (qi, ki) pairs that hold a visible
+  pair are listed once (`_tile_tables`, scalar-prefetched) and the second
+  grid axis runs over the list — a causal grid launches no step for the
+  upper triangle (`flash_live_tiles` counts them).
+- A tile is worked in sub-blocks of at most `_SUB` x `_SUB`, each behind
+  its own predicate: a large tile (few grid steps, each ~0.2 us of
+  bookkeeping) still skips the wholly hidden quarter on the diagonal.
+- The forward's row statistics m, l (and alpha) stay LANE-REPLICATED
+  [rows, 128] from scratch to scratch; the one cross-lane reduction a row
+  is widened to 128 lanes once and repeated across the keys where `s - m`
+  needs it. Narrowing them to a one-lane column and broadcasting back three
+  times a tile was half of the forward's time.
+- The log-sum-exp leaves the forward through a transpose of the
+  lane-replicated tile (rows to lanes in one XLU pass).
+- The dk/dv kernel works on TRANSPOSED scores [keys, rows]: lse and delta
+  lie along the lanes as stored, and p^T, ds^T feed their products as
+  plain left operands (no [block, block] transposes).
+- The causal mask is applied on every live sub-block: the compare and
+  select ride in VALU slots that are free (masking only the diagonal
+  tiles, in a second body, measured no gain at head widths 128 and 64).
 """
 
 import functools
@@ -27,108 +46,80 @@ import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from deepspeed_tpu.platform.device import pallas_interpret
 
-DEFAULT_BLOCK_Q = 128
-DEFAULT_BLOCK_K = 128
 NEG_INF = -1e30
 # VPU lane width: m/l scratch rows are replicated across one lane tile so the
 # scratch stays 2D and tile-aligned regardless of block_q
 _LANES = 128
+# a tile is worked in sub-blocks of at most _SUB x _SUB (the size the MXU and
+# the softmax between its two products overlap best at; 256 measured 1.6x
+# slower, the whole 1024 x 1024 tile 1.16x)
+_SUB = 512
+_NT = (((1,), (1,)), ((), ()))      # a @ b.T
+_NN = (((1,), (0,)), ((), ()))      # a @ b
 
 
 # ----------------------------------------------------------------------
-# forward
+# the walk
 # ----------------------------------------------------------------------
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
-                *, sm_scale, causal, block_k):
-    # q_ref/o_ref: [block_q, D]; k_ref/v_ref: [block_k, D] (one streamed KV
-    # tile); lse_ref: [1, block_q]; scratch acc [block_q, D] fp32, m/l
-    # [block_q, _LANES] fp32 (row stats replicated across lanes — TPU scratch
-    # wants a 128-lane trailing dim). Grid: (BH, nq, nk), nk innermost and
-    # sequential, so scratch carries the online-softmax state across KV tiles.
-    #
-    # Dots run on NATIVE-dtype operands (bf16 in, fp32 out via
-    # preferred_element_type): casting inputs to fp32 first forces the MXU's
-    # fp32 path (~4x slower) and was measured to make the whole kernel lose
-    # to XLA attention at seq 512. `p` narrows back to the input dtype for
-    # the p@v dot — standard TPU flash practice; softmax stats stay fp32.
-    qi = pl.program_id(1)
-    ki = pl.program_id(2)
-    nk = pl.num_programs(2)
-    block_q, D = q_ref.shape
-    in_dtype = q_ref.dtype
-
-    @pl.when(ki == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-
-    if causal:
-        # any (q_pos >= k_pos) pair in this tile? max q_pos = (qi+1)*bq - 1
-        run = ki * block_k < (qi + 1) * block_q
-        last_ki = jnp.minimum(nk - 1, ((qi + 1) * block_q - 1) // block_k)
+def _live_pairs(T, block_q, block_k, causal, k_major=False):
+    """(qi, ki) of the tiles that hold a visible (query, key) pair, in the
+    order a kernel walks them: q blocks outermost with keys ascending, or
+    (`k_major`, the dk/dv pass) k blocks outermost with queries ascending."""
+    nq, nk = T // block_q, T // block_k
+    if k_major:
+        ki, qi = np.divmod(np.arange(nq * nk), nq)
     else:
-        run = ki >= 0          # traced always-true (Mosaic-friendly pl.when)
-        last_ki = nk - 1
-
-    @pl.when(run)
-    def _step():
-        q = q_ref[...]
-        k = k_ref[...]
-        v = v_ref[...]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * sm_scale
-        if causal:
-            q_pos = qi * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            k_pos = ki * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(q_pos >= k_pos, s, NEG_INF)
-        m_prev = m_ref[:, 0:1]
-        l_prev = l_ref[:, 0:1]
-        m_cur = jnp.max(s, axis=-1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new)
-        l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-            p.astype(in_dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
-        l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
-
-    @pl.when(ki == last_ki)
-    def _finish():
-        m = m_ref[:, 0]
-        l_safe = jnp.maximum(l_ref[:, 0], 1e-30)
-        o_ref[...] = (acc_ref[...] / l_safe[:, None]).astype(o_ref.dtype)
-        lse_ref[0, :] = (m + jnp.log(l_safe)).astype(jnp.float32)
+        qi, ki = np.divmod(np.arange(nq * nk), nk)
+    if causal:
+        live = ki * block_k <= (qi + 1) * block_q - 1
+        qi, ki = qi[live], ki[live]
+    return qi, ki
 
 
-def _kv_index_map(causal, block_q, block_k):
-    """KV-tile index for the (BH, nq, nk) grids. Causal grids clamp ki to the
-    q row's diagonal frontier: fully-masked tiles re-serve the frontier block,
-    and Pallas elides the DMA when consecutive block indices repeat — dead
-    grid steps cost neither MXU (pl.when) nor HBM traffic (same trick as the
-    decode kernel's prefix clamp)."""
+def flash_live_tiles(T, block_q, block_k, causal=True):
+    """(diagonal, interior, dead) tiles of one (batch x head): a tile is
+    DIAGONAL when the causal frontier passes through it (some of its pairs
+    are hidden), INTERIOR when every pair is visible, DEAD when none is.
+    The kernels walk diagonal + interior tiles and launch nothing for a dead
+    one; with `_SUB`-sized blocks this is also the count of sub-blocks the
+    default tiles compute. 4 / 6 / 6 at T 2048 in 512 x 512."""
+    qi, ki = _live_pairs(T, block_q, block_k, causal)
+    diagonal = int(np.sum((ki + 1) * block_k - 1 > qi * block_q)) if causal else 0
+    return diagonal, len(qi) - diagonal, (T // block_q) * (T // block_k) - len(qi)
+
+
+def _tile_tables(T, block_q, block_k, causal, k_major=False):
+    """The live pairs as the two scalar-prefetched int32 tables the index
+    maps and the kernels read a grid step's (qi, ki) from."""
+    qi, ki = _live_pairs(T, block_q, block_k, causal, k_major)
+    return jnp.asarray(qi, jnp.int32), jnp.asarray(ki, jnp.int32)
+
+
+def _q_tile(bh, step, qi_ref, ki_ref):
+    return (bh, qi_ref[step], 0)
+
+
+def _k_tile(bh, step, qi_ref, ki_ref):
+    return (bh, ki_ref[step], 0)
+
+
+def _last_k_tile(causal, qi, block_q, block_k, nk):
+    """The last k tile q block `qi` walks: the one its last row's own
+    position falls in, or the sequence's last."""
     if not causal:
-        return lambda bh, qi, ki: (bh, ki, 0)
-
-    def index(bh, qi, ki):
-        frontier = ((qi + 1) * block_q - 1) // block_k
-        return (bh, jnp.minimum(ki, frontier), 0)
-
-    return index
+        return nk - 1
+    return jnp.minimum(nk - 1, ((qi + 1) * block_q - 1) // block_k)
 
 
-def _row_stat_spec(block_q, q_index):
+def _row_stat_spec(block_q):
     """BlockSpec of one q block's row statistics (lse, delta), addressed by
     the q tile's own index map. The arrays are [BH, Tb, 1, block_q]: rows of
     a q block along the LANES, one block per (bh, qi). The unit third dim is
@@ -137,45 +128,130 @@ def _row_stat_spec(block_q, q_index):
     of a [BH, Tb, block_q] array is neither once Tb > 1 (it compiled under
     jax 0.4; jax 0.9 refuses it)."""
     return pl.BlockSpec((None, None, 1, block_q),
-                        lambda *grid: q_index(*grid) + (0,))
+                        lambda *grid: _q_tile(*grid) + (0,))
+
+
+def _sub_blocks(causal, qi, ki, block_q, block_k, update):
+    """Run `update(rows, keys)` (static slices of the tile) on every
+    sub-block of tile (qi, ki) that holds a visible pair, keys ascending
+    within a band of rows."""
+    for r0 in range(0, block_q, _SUB):
+        rows = slice(r0, min(r0 + _SUB, block_q))
+        for k0 in range(0, block_k, _SUB):
+            keys = slice(k0, min(k0 + _SUB, block_k))
+            if causal:
+                # its first key is no later than its last query
+                pl.when(ki * block_k + k0 <= qi * block_q + rows.stop - 1)(
+                    functools.partial(update, rows, keys))
+            else:
+                update(rows, keys)
+
+
+def _hide_future(s, q0, k0, transposed=False):
+    """Scores of pairs whose key lies after the query -> NEG_INF. s is
+    [rows, keys] from positions (q0, k0), or `transposed` [keys, rows]."""
+    q_pos = q0 + jax.lax.broadcasted_iota(jnp.int32, s.shape,
+                                          1 if transposed else 0)
+    k_pos = k0 + jax.lax.broadcasted_iota(jnp.int32, s.shape,
+                                          0 if transposed else 1)
+    return jnp.where(q_pos >= k_pos, s, NEG_INF)
+
+
+def _widen(stat, n):
+    """A lane-replicated [rows, 128] statistic as [rows, n]."""
+    return jnp.tile(stat, (1, pl.cdiv(n, _LANES)))[:, :n]
+
+
+# ----------------------------------------------------------------------
+# forward
+# ----------------------------------------------------------------------
+
+
+def _fwd_kernel(qi_ref, ki_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref,
+                m_ref, l_ref, *, sm_scale, causal, block_k, nk):
+    # q_ref/o_ref: [block_q, D]; k_ref/v_ref: [block_k, D] (one streamed KV
+    # tile); lse_ref: [1, block_q]; scratch acc [block_q, D] fp32, m/l
+    # [block_q, _LANES] fp32, every lane of a row the same value. Grid:
+    # (BH, live tiles), a q block's tiles consecutive with keys ascending,
+    # so scratch carries the online-softmax state across them.
+    #
+    # Dots run on NATIVE-dtype operands (bf16 in, fp32 out via
+    # preferred_element_type): casting inputs to fp32 first forces the MXU's
+    # fp32 path (~4x slower) and was measured to make the whole kernel lose
+    # to XLA attention at seq 512. `p` narrows back to the input dtype for
+    # the p@v dot — standard TPU flash practice; softmax stats stay fp32.
+    step = pl.program_id(1)
+    qi, ki = qi_ref[step], ki_ref[step]
+    block_q, D = q_ref.shape
+    last_ki = _last_k_tile(causal, qi, block_q, block_k, nk)
+
+    @pl.when(ki == 0)
+    def _init():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+
+    def update(rows, keys):
+        q, k, v = q_ref[rows, :], k_ref[keys, :], v_ref[keys, :]
+        s = jax.lax.dot_general(q, k, _NT,
+                                preferred_element_type=jnp.float32) * sm_scale
+        if causal:
+            s = _hide_future(s, qi * block_q + rows.start,
+                             ki * block_k + keys.start)
+        m_prev = m_ref[rows, :]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.exp(s - _widen(m_new, s.shape[1]))
+        l_ref[rows, :] = l_ref[rows, :] * alpha \
+            + jnp.sum(p, axis=-1, keepdims=True)
+        m_ref[rows, :] = m_new
+        acc_ref[rows, :] = acc_ref[rows, :] * _widen(alpha, D) \
+            + jax.lax.dot_general(p.astype(q.dtype), v, _NN,
+                                  preferred_element_type=jnp.float32)
+
+    _sub_blocks(causal, qi, ki, block_q, block_k, update)
+
+    @pl.when(ki == last_ki)
+    def _finish():
+        l_safe = jnp.maximum(l_ref[...], 1e-30)
+        o_ref[...] = (acc_ref[...] / _widen(l_safe, D)).astype(o_ref.dtype)
+        # rows -> lanes: every row of the transposed tile is the lse
+        lse_ref[...] = jnp.transpose(m_ref[...] + jnp.log(l_safe))[0:1, :]
 
 
 def _flash_fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret):
     B, H, T, D = q.shape
     BH = B * H
-    q2 = q.reshape(BH, T, D)
-    k2 = k.reshape(BH, T, D)
-    v2 = v.reshape(BH, T, D)
-    Tb = T // block_q
-    grid = (BH, Tb, T // block_k)
-    kv_index = _kv_index_map(causal, block_q, block_k)
-    q_index = lambda bh, qi, ki: (bh, qi, 0)
+    q2, k2, v2 = (x.reshape(BH, T, D) for x in (q, k, v))
+    qi, ki = _tile_tables(T, block_q, block_k, causal)
 
     out, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, sm_scale=sm_scale, causal=causal,
-                          block_k=block_k),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((None, block_q, D), q_index),
-            pl.BlockSpec((None, block_k, D), kv_index),
-            pl.BlockSpec((None, block_k, D), kv_index),
-        ],
-        out_specs=[
-            pl.BlockSpec((None, block_q, D), q_index),
-            _row_stat_spec(block_q, q_index),
-        ],
+                          block_k=block_k, nk=T // block_k),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(BH, qi.shape[0]),
+            in_specs=[
+                pl.BlockSpec((None, block_q, D), _q_tile),
+                pl.BlockSpec((None, block_k, D), _k_tile),
+                pl.BlockSpec((None, block_k, D), _k_tile),
+            ],
+            out_specs=[
+                pl.BlockSpec((None, block_q, D), _q_tile),
+                _row_stat_spec(block_q),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((block_q, D), jnp.float32),
+                pltpu.VMEM((block_q, _LANES), jnp.float32),
+                pltpu.VMEM((block_q, _LANES), jnp.float32),
+            ]),
         out_shape=[
             jax.ShapeDtypeStruct((BH, T, D), q.dtype),
-            jax.ShapeDtypeStruct((BH, Tb, 1, block_q), jnp.float32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((block_q, D), jnp.float32),
-            pltpu.VMEM((block_q, _LANES), jnp.float32),
-            pltpu.VMEM((block_q, _LANES), jnp.float32),
+            jax.ShapeDtypeStruct((BH, T // block_q, 1, block_q), jnp.float32),
         ],
         interpret=interpret,
         name="dstpu_flash_fwd",
-    )(q2, k2, v2)
+    )(qi, ki, q2, k2, v2)
     return out.reshape(B, H, T, D), lse
 
 
@@ -184,101 +260,82 @@ def _flash_fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret):
 # ----------------------------------------------------------------------
 
 
-def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
-                   dq_acc_ref, *, sm_scale, causal, block_k):
-    # streamed tiles: k/v [block_k, D] walk the KV grid dim; q/do/lse/delta
-    # ride the q block; dq accumulates in scratch across the KV walk
-    qi = pl.program_id(1)
-    ki = pl.program_id(2)
-    nk = pl.num_programs(2)
+def _bwd_dq_kernel(qi_ref, ki_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
+                   delta_ref, dq_ref, dq_acc_ref,
+                   *, sm_scale, causal, block_k, nk):
+    # the forward's walk: k/v [block_k, D] tiles stream past a q block's
+    # q/do/lse/delta; dq accumulates in scratch across them
+    step = pl.program_id(1)
+    qi, ki = qi_ref[step], ki_ref[step]
     block_q, D = q_ref.shape
-    in_dtype = q_ref.dtype
+    last_ki = _last_k_tile(causal, qi, block_q, block_k, nk)
 
     @pl.when(ki == 0)
     def _init():
         dq_acc_ref[...] = jnp.zeros_like(dq_acc_ref)
 
-    if causal:
-        run = ki * block_k < (qi + 1) * block_q
-        last_ki = jnp.minimum(nk - 1, ((qi + 1) * block_q - 1) // block_k)
-    else:
-        run = ki >= 0          # traced always-true (Mosaic-friendly pl.when)
-        last_ki = nk - 1
-
-    @pl.when(run)
-    def _step():
-        q = q_ref[...]
-        k = k_ref[...]
-        v = v_ref[...]
-        do = do_ref[...]
-        lse = lse_ref[0, :]
-        delta = delta_ref[0, :]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+    def update(rows, keys):
+        q, do = q_ref[rows, :], do_ref[rows, :]
+        k, v = k_ref[keys, :], v_ref[keys, :]
+        lse = lse_ref[0, rows]
+        delta = delta_ref[0, rows]
+        s = jax.lax.dot_general(q, k, _NT,
                                 preferred_element_type=jnp.float32) * sm_scale
         if causal:
-            q_pos = qi * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            k_pos = ki * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(q_pos >= k_pos, s, NEG_INF)
+            s = _hide_future(s, qi * block_q + rows.start,
+                             ki * block_k + keys.start)
         p = jnp.exp(s - lse[:, None])
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
+        dp = jax.lax.dot_general(do, v, _NT,
                                  preferred_element_type=jnp.float32)
-        ds = (p * (dp - delta[:, None])).astype(in_dtype)
-        dq_acc_ref[...] = dq_acc_ref[...] + jax.lax.dot_general(
-            ds, k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        ds = (p * (dp - delta[:, None])).astype(q.dtype)
+        dq_acc_ref[rows, :] = dq_acc_ref[rows, :] + jax.lax.dot_general(
+            ds, k, _NN, preferred_element_type=jnp.float32)
+
+    _sub_blocks(causal, qi, ki, block_q, block_k, update)
 
     @pl.when(ki == last_ki)
     def _finish():
         dq_ref[...] = (dq_acc_ref[...] * sm_scale).astype(dq_ref.dtype)
 
 
-def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                    dk_ref, dv_ref, dk_acc_ref, dv_acc_ref,
-                    *, sm_scale, causal, block_q):
-    # grid (BH, nk, nq), nq innermost: q/do/lse/delta tiles stream past a
-    # resident [block_k, D] k/v tile; dk/dv accumulate in scratch
-    ki = pl.program_id(1)
-    qi = pl.program_id(2)
-    nq = pl.num_programs(2)
+def _bwd_dkv_kernel(qi_ref, ki_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
+                    delta_ref, dk_ref, dv_ref, dk_acc_ref, dv_acc_ref,
+                    *, sm_scale, causal, block_q, nq):
+    # the mirrored walk: a k block's live q tiles consecutive, queries
+    # ascending; q/do/lse/delta tiles stream past a resident [block_k, D]
+    # k/v tile; dk/dv accumulate in scratch. The scores are TRANSPOSED,
+    # [keys, rows]: lse_ref/delta_ref [1, block_q] lie along the lanes as
+    # stored, and p^T / ds^T are the LEFT operands of their products.
+    step = pl.program_id(1)
+    qi, ki = qi_ref[step], ki_ref[step]
     block_k, D = k_ref.shape
-    in_dtype = k_ref.dtype
+    first_qi = (ki * block_k) // block_q if causal else 0
 
-    @pl.when(qi == 0)
+    @pl.when(qi == first_qi)
     def _init():
         dk_acc_ref[...] = jnp.zeros_like(dk_acc_ref)
         dv_acc_ref[...] = jnp.zeros_like(dv_acc_ref)
 
-    # causal: q blocks strictly before the diagonal see no (q_pos >= k_pos)
-    run = (qi + 1) * block_q > ki * block_k if causal else qi >= 0
-
-    @pl.when(run)
-    def _step():
-        k = k_ref[...]
-        v = v_ref[...]
-        q = q_ref[...]
-        do = do_ref[...]
-        lse = lse_ref[0, :]
-        delta = delta_ref[0, :]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * sm_scale
+    def update(rows, keys):
+        q, do = q_ref[rows, :], do_ref[rows, :]
+        k, v = k_ref[keys, :], v_ref[keys, :]
+        lse = lse_ref[:, rows]                                    # [1, rows]
+        delta = delta_ref[:, rows]
+        st = jax.lax.dot_general(k, q, _NT,
+                                 preferred_element_type=jnp.float32) * sm_scale
         if causal:
-            q_pos = qi * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            k_pos = ki * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(q_pos >= k_pos, s, NEG_INF)
-        p = jnp.exp(s - lse[:, None])                                 # [bq, bk]
-        dv_acc_ref[...] = dv_acc_ref[...] + jax.lax.dot_general(
-            p.astype(in_dtype), do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        ds = (p * (dp - delta[:, None])).astype(in_dtype)
-        dk_acc_ref[...] = dk_acc_ref[...] + jax.lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+            st = _hide_future(st, qi * block_q + rows.start,
+                              ki * block_k + keys.start, transposed=True)
+        pt = jnp.exp(st - lse)                                # [keys, rows]
+        dv_acc_ref[keys, :] = dv_acc_ref[keys, :] + jax.lax.dot_general(
+            pt.astype(q.dtype), do, _NN, preferred_element_type=jnp.float32)
+        dpt = jax.lax.dot_general(v, do, _NT,
+                                  preferred_element_type=jnp.float32)
+        dst = (pt * (dpt - delta)).astype(q.dtype)
+        dk_acc_ref[keys, :] = dk_acc_ref[keys, :] + jax.lax.dot_general(
+            dst, q, _NN, preferred_element_type=jnp.float32)
+
+    _sub_blocks(causal, qi, ki, block_q, block_k, update)
 
     @pl.when(qi == nq - 1)
     def _finish():
@@ -298,68 +355,57 @@ def _flash_bwd(res, g, sm_scale, causal, block_q, block_k, interpret,
         # the existing kernels run unchanged with delta' = delta - dlse
         delta = delta - delta_adjust
 
-    q2, k2, v2 = (x.reshape(BH, T, D) for x in (q, k, v))
-    do2 = do.reshape(BH, T, D)
+    q2, k2, v2, do2 = (x.reshape(BH, T, D) for x in (q, k, v, do))
     Tb = T // block_q
-    lse2 = lse                                   # [BH, Tb, 1, block_q]
-    delta2 = delta.reshape(BH, Tb, 1, block_q)
+    delta2 = delta.reshape(BH, Tb, 1, block_q)          # lse: [BH, Tb, 1, block_q]
+    operands = (q2, k2, v2, do2, lse, delta2)
+    in_specs = [
+        pl.BlockSpec((None, block_q, D), _q_tile),
+        pl.BlockSpec((None, block_k, D), _k_tile),
+        pl.BlockSpec((None, block_k, D), _k_tile),
+        pl.BlockSpec((None, block_q, D), _q_tile),
+        _row_stat_spec(block_q),
+        _row_stat_spec(block_q),
+    ]
 
-    kv_index = _kv_index_map(causal, block_q, block_k)
-    q_tile = lambda bh, qi, ki: (bh, qi, 0)
+    qi, ki = _tile_tables(T, block_q, block_k, causal)
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, sm_scale=sm_scale, causal=causal,
-                          block_k=block_k),
-        grid=(BH, Tb, T // block_k),
-        in_specs=[
-            pl.BlockSpec((None, block_q, D), q_tile),
-            pl.BlockSpec((None, block_k, D), kv_index),
-            pl.BlockSpec((None, block_k, D), kv_index),
-            pl.BlockSpec((None, block_q, D), q_tile),
-            _row_stat_spec(block_q, q_tile),
-            _row_stat_spec(block_q, q_tile),
-        ],
-        out_specs=pl.BlockSpec((None, block_q, D), q_tile),
+                          block_k=block_k, nk=T // block_k),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(BH, qi.shape[0]),
+            in_specs=in_specs,
+            out_specs=pl.BlockSpec((None, block_q, D), _q_tile),
+            scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)]),
         out_shape=jax.ShapeDtypeStruct((BH, T, D), q.dtype),
-        scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
         interpret=interpret,
         name="dstpu_flash_dq",
-    )(q2, k2, v2, do2, lse2, delta2)
+    )(qi, ki, *operands)
 
-    if causal:
-        # mirror of _kv_index_map for the transposed (BH, nk, nq) grid:
-        # pre-diagonal q tiles re-serve the diagonal block (DMA elided)
-        def q_index(bh, ki, qi):
-            first = (ki * block_k) // block_q
-            return (bh, jnp.maximum(qi, first), 0)
-    else:
-        q_index = lambda bh, ki, qi: (bh, qi, 0)
+    qi, ki = _tile_tables(T, block_q, block_k, causal, k_major=True)
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, sm_scale=sm_scale, causal=causal,
-                          block_q=block_q),
-        grid=(BH, T // block_k, Tb),
-        in_specs=[
-            pl.BlockSpec((None, block_q, D), q_index),
-            pl.BlockSpec((None, block_k, D), lambda bh, ki, qi: (bh, ki, 0)),
-            pl.BlockSpec((None, block_k, D), lambda bh, ki, qi: (bh, ki, 0)),
-            pl.BlockSpec((None, block_q, D), q_index),
-            _row_stat_spec(block_q, q_index),
-            _row_stat_spec(block_q, q_index),
-        ],
-        out_specs=[
-            pl.BlockSpec((None, block_k, D), lambda bh, ki, qi: (bh, ki, 0)),
-            pl.BlockSpec((None, block_k, D), lambda bh, ki, qi: (bh, ki, 0)),
-        ],
+                          block_q=block_q, nq=Tb),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(BH, qi.shape[0]),
+            in_specs=in_specs,
+            out_specs=[
+                pl.BlockSpec((None, block_k, D), _k_tile),
+                pl.BlockSpec((None, block_k, D), _k_tile),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((block_k, D), jnp.float32),
+                pltpu.VMEM((block_k, D), jnp.float32),
+            ]),
         out_shape=[
             jax.ShapeDtypeStruct((BH, T, D), q.dtype),
             jax.ShapeDtypeStruct((BH, T, D), q.dtype),
         ],
-        scratch_shapes=[
-            pltpu.VMEM((block_k, D), jnp.float32),
-            pltpu.VMEM((block_k, D), jnp.float32),
-        ],
         interpret=interpret,
         name="dstpu_flash_dkv",
-    )(q2, k2, v2, do2, lse2, delta2)
+    )(qi, ki, *operands)
 
     return (dq.reshape(B, H, T, D), dk.reshape(B, H, T, D), dv.reshape(B, H, T, D))
 
@@ -409,20 +455,28 @@ def flash_max_seq(d_head, itemsize=2, hbm_budget=12 * 2**30):
     return int(hbm_budget) // (8 * d_head * itemsize + 8)
 
 
-def _default_blocks(T, block_q, block_k):
-    """Measured-crossover default tiles (512/512 from T >= 1024 — see
-    flash_attention docstring), shrunk to the largest power-of-two divisor
-    of T >= the 128 lane width; explicit sizes pass through."""
-    if block_q is None:
-        block_q = 512 if T >= 1024 else DEFAULT_BLOCK_Q
-        while block_q > DEFAULT_BLOCK_Q and T % block_q != 0:
-            block_q //= 2
-    if block_k is None:
-        block_k = 512 if T >= 1024 else DEFAULT_BLOCK_K
-        while block_k > DEFAULT_BLOCK_K and T % block_k != 0:
-            block_k //= 2
-    block_q = min(block_q, T)
-    block_k = min(block_k, T)
+def _default_blocks(T, head_bytes, block_q, block_k):
+    """The tile of all three kernels, from T and the bytes of one head's row
+    (`head_dim * itemsize`): the largest power-of-two divisor of T from 128
+    up to 1024 — 512 where a row is wider than 512 bytes (at [1024, 256]
+    float32 tiles the dk/dv kernel passes the 16 MiB of scoped VMEM). A
+    tile of 1024 is still WORKED in `_SUB` = 512 sub-blocks, so it costs a
+    causal grid no more products than 512 x 512 tiles and a third of their
+    grid steps (v5e, [8, 16, 2048, 128] bf16, fwd / dq / dkv ms a call:
+    512 tiles 1.43 / 1.78 / 2.28, 1024 tiles 1.32 / 1.61 / 2.08, one
+    2048 tile 1.29 / 1.56 / 2.03 at four times the code; T 512 in 128
+    tiles 2.71 / 2.66 / 2.93, as one tile 0.67 / 0.72 / 0.93).
+    Explicit sizes pass through."""
+    largest = 1024 if head_bytes <= 512 else 512
+
+    def tile(block):
+        if block is None:
+            block = largest
+            while block > _LANES and T % block != 0:
+                block //= 2
+        return min(block, T)
+
+    block_q, block_k = tile(block_q), tile(block_k)
     assert T % block_q == 0 and T % block_k == 0, (T, block_q, block_k)
     return block_q, block_k
 
@@ -463,7 +517,8 @@ def flash_attention_with_lse(q, k, v, causal=True, sm_scale=None, block_q=None,
     if interpret is None:
         interpret = pallas_interpret()
     B, H, T, D = q.shape
-    block_q, block_k = _default_blocks(T, block_q, block_k)
+    block_q, block_k = _default_blocks(T, D * q.dtype.itemsize, block_q,
+                                       block_k)
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(D)
     out, lse = _flash_lse(q, k, v, float(sm_scale), bool(causal), int(block_q),
@@ -479,17 +534,19 @@ def flash_attention(q, k, v, causal=True, sm_scale=None, block_q=None,
     Sequence length must be a multiple of the block size (the zoo pads to 128
     multiples; MXU-friendly anyway) and is otherwise bounded only by HBM
     (`flash_max_seq`) — K/V stream through VMEM one [block_k, D] tile at a
-    time. Default blocks scale with T: 512/512 tiles from T >= 1024
-    (measured r4 with native-dtype dots, fwd+bwd vs materialized XLA
-    attention: 1.6x at 1k, 2.3x at 2k, 3.4x at 4k; 512/512 edged out
-    512/1024 at both 2k and 4k); short sequences keep 128/128.
+    time. The tiles follow from T and the head's width (`_default_blocks`:
+    1024 / 1024 where T allows, worked in 512 / 512 sub-blocks). Measured on
+    a v5e (PERF.md §6, PR 37), fwd / dq / dkv ms a call at [8, 16, 2048, 128]
+    bf16 causal: 1.32 / 1.61 / 2.08 — 53 / 65 / 67% of the least time the
+    MXU allows for the lower triangle's products.
     """
     if interpret is None:
         interpret = pallas_interpret()
     if layout == "BTHD":
         q, k, v = (jnp.swapaxes(x, 1, 2) for x in (q, k, v))
     B, H, T, D = q.shape
-    block_q, block_k = _default_blocks(T, block_q, block_k)
+    block_q, block_k = _default_blocks(T, D * q.dtype.itemsize, block_q,
+                                       block_k)
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(D)
     out = _flash(q, k, v, float(sm_scale), bool(causal), int(block_q), int(block_k),

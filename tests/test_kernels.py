@@ -21,36 +21,60 @@ def _ref_attention(q, k, v, causal=True):
     return jnp.einsum("bhts,bhsd->bhtd", p, v.astype(jnp.float32)).astype(q.dtype)
 
 
+# (B, H, T, D, block_q, block_k, causal): "small" is the historical case; the
+# others walk what the flat grid, the sub-block predicate and the transposed
+# dk/dv scores can get wrong — a diagonal that crosses several tiles
+# (block_q != block_k, both ways), T of exactly one tile, rows of blocks with
+# interior AND diagonal tiles, no mask at all, a head narrower than a lane
+# tile, and the default 1024 tiles worked in 512 sub-blocks (one of the four
+# a diagonal tile holds is wholly hidden and skipped)
+_FLASH_CASES = {
+    "small": (2, 2, 128, 32, 64, 64, True),
+    "small-full": (2, 2, 128, 32, 64, 64, False),
+    "bq128-bk256": (1, 2, 512, 128, 128, 256, True),
+    "bq256-bk128": (1, 2, 512, 128, 256, 128, True),
+    "one-tile": (1, 2, 128, 128, 128, 128, True),
+    "interior-and-diagonal": (1, 2, 512, 128, 128, 128, True),
+    "full": (1, 2, 256, 128, 128, 128, False),
+    "head-64": (1, 2, 256, 64, 128, 128, True),
+    "default-tiles": (1, 1, 2048, 128, None, None, True),
+}
+
+
+def _flash_case(case, seed, dtype=jnp.float32):
+    B, H, T, D, block_q, block_k, causal = _FLASH_CASES[case]
+    rng = np.random.default_rng(seed)
+    q, k, v = (jnp.asarray(rng.normal(0, 1, (B, H, T, D)), dtype)
+               for _ in range(3))
+    return q, k, v, dict(causal=causal, block_q=block_q, block_k=block_k), rng
+
+
 class TestFlashAttention:
-    @pytest.mark.parametrize("causal", [True, False])
-    def test_forward_matches(self, causal):
+    @pytest.mark.parametrize("case", list(_FLASH_CASES))
+    def test_forward_matches(self, case):
         from deepspeed_tpu.ops.pallas.flash_attention import flash_attention
-        rng = np.random.default_rng(0)
-        B, H, T, D = 2, 2, 128, 32
-        q, k, v = (jnp.asarray(rng.normal(0, 1, (B, H, T, D)), jnp.float32) for _ in range(3))
-        out = flash_attention(q, k, v, causal=causal, layout="BHTD", block_q=64, block_k=64)
-        ref = _ref_attention(q, k, v, causal=causal)
+        q, k, v, kw, _ = _flash_case(case, 0)
+        out = flash_attention(q, k, v, layout="BHTD", **kw)
+        ref = _ref_attention(q, k, v, causal=kw["causal"])
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=2e-3, atol=2e-3)
 
     # bf16 exercises the native-dtype MXU dot path (p/ds narrowed to bf16
     # inside the kernels — fp32 inputs make those casts no-ops); tolerances
     # widen to the bf16 rounding band
-    @pytest.mark.parametrize("dtype,rtol,atol", [
-        (jnp.float32, 5e-3, 5e-3),
-        (jnp.bfloat16, 4e-2, 4e-2),
-    ])
-    def test_backward_matches(self, dtype, rtol, atol):
+    @pytest.mark.parametrize("case,dtype,rtol,atol", [
+        ("small", jnp.bfloat16, 4e-2, 4e-2),
+    ] + [(case, jnp.float32, 5e-3, 5e-3) for case in _FLASH_CASES])
+    def test_backward_matches(self, case, dtype, rtol, atol):
         from deepspeed_tpu.ops.pallas.flash_attention import flash_attention
-        rng = np.random.default_rng(1)
-        B, H, T, D = 1, 2, 128, 32
-        q, k, v = (jnp.asarray(rng.normal(0, 1, (B, H, T, D)), dtype) for _ in range(3))
+        q, k, v, kw, _ = _flash_case(case, 1, dtype)
 
         def f_flash(q, k, v):
-            return jnp.sum(flash_attention(q, k, v, causal=True, layout="BHTD",
-                                           block_q=64, block_k=64).astype(jnp.float32) ** 2)
+            return jnp.sum(flash_attention(q, k, v, layout="BHTD", **kw)
+                           .astype(jnp.float32) ** 2)
 
         def f_ref(q, k, v):
-            return jnp.sum(_ref_attention(q, k, v, causal=True).astype(jnp.float32) ** 2)
+            return jnp.sum(_ref_attention(q, k, v, causal=kw["causal"])
+                           .astype(jnp.float32) ** 2)
 
         g_flash = jax.grad(f_flash, argnums=(0, 1, 2))(q, k, v)
         g_ref = jax.grad(f_ref, argnums=(0, 1, 2))(q, k, v)
@@ -58,29 +82,28 @@ class TestFlashAttention:
             np.testing.assert_allclose(np.asarray(a, np.float32), np.asarray(b, np.float32),
                                        rtol=rtol, atol=atol, err_msg=f"d{name}")
 
-    def test_with_lse_values_and_grads(self):
+    @pytest.mark.parametrize("case", list(_FLASH_CASES))
+    def test_with_lse_values_and_grads(self, case):
         """flash_attention_with_lse: lse matches logsumexp of the score rows,
         and an lse-DEPENDENT loss backprops correctly (the dlse cotangent
         folds into the kernels as delta - dlse — ring attention relies on
         this to differentiate its partial-merge weights)."""
-        rng = np.random.default_rng(7)
-        B, H, T, D = 1, 2, 128, 32
-        q, k, v = (jnp.asarray(rng.normal(0, 1, (B, H, T, D)), jnp.float32)
-                   for _ in range(3))
         from deepspeed_tpu.ops.pallas.flash_attention import \
             flash_attention_with_lse
+        q, k, v, kw, rng = _flash_case(case, 7)
+        B, H, T, D = q.shape
         sm = 1.0 / np.sqrt(D)
 
         def ref(q, k, v):
             s = jnp.einsum("bhtd,bhsd->bhts", q, k) * sm
-            mask = jnp.tril(jnp.ones((T, T), bool))[None, None]
-            s = jnp.where(mask, s, -jnp.inf)
+            if kw["causal"]:
+                mask = jnp.tril(jnp.ones((T, T), bool))[None, None]
+                s = jnp.where(mask, s, -jnp.inf)
             lse = jax.scipy.special.logsumexp(s, axis=-1)
             o = jnp.einsum("bhts,bhsd->bhtd", jax.nn.softmax(s, -1), v)
             return o, lse
 
-        o, lse = flash_attention_with_lse(q, k, v, causal=True, block_q=64,
-                                          block_k=64)
+        o, lse = flash_attention_with_lse(q, k, v, **kw)
         o_ref, lse_ref = ref(q, k, v)
         np.testing.assert_allclose(np.asarray(lse), np.asarray(lse_ref),
                                    rtol=2e-4, atol=2e-4)
@@ -96,12 +119,33 @@ class TestFlashAttention:
                 return jnp.sum(o ** 2) + jnp.sum(lse * wl)
             return jax.grad(f, argnums=(0, 1, 2))
 
-        g = loss(lambda q, k, v: flash_attention_with_lse(
-            q, k, v, causal=True, block_q=64, block_k=64))(q, k, v)
+        g = loss(lambda q, k, v: flash_attention_with_lse(q, k, v, **kw))(q, k, v)
         g_ref = loss(ref)(q, k, v)
         for a, b, name in zip(g, g_ref, "qkv"):
             np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                        rtol=5e-3, atol=5e-3, err_msg=f"d{name}")
+
+    # hand counts: (diagonal, interior, dead) tiles of one (batch x head)
+    @pytest.mark.parametrize("T,block_q,block_k,causal,want", [
+        (2048, 512, 512, True, (4, 6, 6)),       # the training cell's sub-blocks
+        (2048, 512, 512, False, (0, 16, 0)),
+        (2048, 1024, 1024, True, (2, 1, 1)),     # ... and its tiles
+        (512, 128, 256, True, (4, 2, 2)),
+        (512, 256, 128, True, (4, 2, 2)),
+        (128, 128, 128, True, (1, 0, 0)),
+    ])
+    def test_live_tiles_against_a_hand_count(self, T, block_q, block_k,
+                                             causal, want):
+        from deepspeed_tpu.ops.pallas.flash_attention import (
+            _tile_tables, flash_live_tiles)
+        assert flash_live_tiles(T, block_q, block_k, causal) == want
+        # the grid of each kernel is exactly the live tiles, every one once
+        for k_major in (False, True):
+            qi, ki = _tile_tables(T, block_q, block_k, causal, k_major)
+            assert len(qi) == want[0] + want[1]
+            assert len({(int(a), int(b)) for a, b in zip(qi, ki)}) == len(qi)
+            outer = np.asarray(ki if k_major else qi)
+            assert (np.diff(outer) >= 0).all()       # a block's tiles together
 
     def test_streaming_parity_beyond_legacy_cap(self):
         """Numerics + grads at a T strictly past the retired whole-slab VMEM

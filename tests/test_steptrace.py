@@ -804,11 +804,15 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
-def _kernel_instructions(fn, *args):
+def _kernel_lines(fn, *args):
     text = jax.jit(fn).lower(*args).compile().as_text()
-    return [line.split("=")[0].strip().lstrip("%")
-            for line in text.splitlines()
+    return [line.strip() for line in text.splitlines()
             if "custom-call(" in line and "tpu_custom_call" in line]
+
+
+def _kernel_instructions(fn, *args):
+    return [line.split("=")[0].strip().lstrip("%")
+            for line in _kernel_lines(fn, *args)]
 
 
 def test_kernels_keep_their_names_in_the_compiled_program(one_chip):
@@ -848,6 +852,52 @@ def test_kernels_keep_their_names_in_the_compiled_program(one_chip):
     names = _kernel_instructions(flash_grad, qkv, qkv, qkv)
     kinds = {n.rsplit(".", 1)[0] for n in names}
     assert kinds == {"dstpu_flash_fwd", "dstpu_flash_dq", "dstpu_flash_dkv"}
+
+
+def test_training_step_holds_the_three_flash_kernels_by_result_signature(
+        one_chip, monkeypatch):
+    """`flash_roofline.train` tells the three flash kernels apart by the
+    RESULT of their custom call (`benchmark/layer_metrics/flash_roofline.
+    train.json`: fwd a tuple that starts bf16, f32; dkv bf16, bf16; dq a
+    single bf16) and counts products by the calls it saw. So the compiled
+    training step — a remat'd GPT loss and its gradient — must hold exactly
+    the three kernels, each matched by its own pattern and by no other."""
+    from deepspeed_tpu.models.gpt import gpt_init_fn, gpt_loss
+    from deepspeed_tpu.platform import device
+    bench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark")
+    sys.path.insert(0, bench)
+    try:
+        import xplane
+    finally:
+        sys.path.remove(bench)
+    with open(os.path.join(bench, "layer_metrics",
+                           "flash_roofline.train.json")) as f:
+        patterns = json.load(f)["args"]["kernels"]
+    assert set(patterns) == {"fwd", "dq", "dkv"}
+
+    mesh_mod.clear_mesh()
+    monkeypatch.setattr(device, "on_tpu", lambda: True)   # what the chip sees
+    cfg = GPTConfig(n_layer=2, n_head=2, d_model=256, max_seq_len=2048,
+                    vocab_size=512, dtype=jnp.bfloat16, remat=True)
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    params = on_chip(jax.eval_shape(gpt_init_fn(cfg, dtype=jnp.bfloat16),
+                                    jax.random.PRNGKey(0)))
+    toks = jax.ShapeDtypeStruct((2, 2048), jnp.int32, sharding=one_chip)
+    # an operation as the reduced trace labels it
+    labels = [xplane.parse_op(line.removeprefix("ROOT "))[0]
+              for line in _kernel_lines(
+                  jax.grad(lambda p, b: gpt_loss(p, b, None, cfg)),
+                  params, {"tokens": toks, "labels": toks})]
+    assert {lab.split(".")[0] for lab in labels} == {
+        "dstpu_flash_fwd", "dstpu_flash_dq", "dstpu_flash_dkv"}
+    for lab in labels:
+        kinds = [k for k, rx in patterns.items() if re.search(rx, lab)]
+        assert kinds == [lab.split(".")[0].removeprefix("dstpu_flash_")], lab
 
 
 # ----------------------------------------------------------------------

@@ -234,13 +234,32 @@ def test_evoformer_attention_compiled_head_dim_32():
                                atol=2e-2, rtol=2e-2)  # MXU default precision
 
 
-def test_flash_attention_with_lse_compiled():
+# (B, H, T, D, block_q, block_k, causal), tiles of whole lane tiles: the
+# shapes `tests/test_kernels.py::_FLASH_CASES` walks in the interpreter,
+# through Mosaic — "tb-2" is the multi-q-block shape (Tb > 1) whose
+# row-statistics tile jax 0.9 refused before PR 21
+_FLASH_COMPILED_CASES = {
+    "tb-2": (1, 4, 1024, 128, 512, 512, True),
+    "bq128-bk256": (1, 2, 512, 128, 128, 256, True),
+    "bq256-bk128": (1, 2, 512, 128, 256, 128, True),
+    "one-tile": (1, 2, 128, 128, 128, 128, True),
+    "interior-and-diagonal": (1, 2, 512, 128, 128, 128, True),
+    "full": (1, 2, 256, 128, 128, 128, False),
+    "head-64": (1, 2, 256, 64, 128, 128, True),
+    "default-tiles": (1, 2, 2048, 128, None, None, True),
+}
+
+
+@pytest.mark.parametrize("case", list(_FLASH_COMPILED_CASES))
+def test_flash_attention_with_lse_compiled(case):
     """The ring programs' building block: (o, lse) forward and the lse
-    cotangent through the backward, at the multi-q-block shape (Tb > 1)
-    whose row-statistics tile jax 0.9 refused before PR 21."""
+    cotangent through the backward, compiled, over the shapes that walk
+    every branch of the tile walk (unequal tiles both ways, one tile, rows
+    of blocks with interior and diagonal tiles, no mask, a 64-wide head,
+    the default 1024 tiles worked in 512 sub-blocks)."""
     from deepspeed_tpu.ops.pallas.flash_attention import \
         flash_attention_with_lse
-    B, H, T, D = 1, 4, 1024, 128
+    B, H, T, D, block_q, block_k, causal = _FLASH_COMPILED_CASES[case]
     rng = np.random.default_rng(16)
     q, k, v = (jnp.asarray(rng.normal(0, 1, (B, H, T, D)), jnp.bfloat16)
                for _ in range(3))
@@ -248,14 +267,16 @@ def test_flash_attention_with_lse_compiled():
     def dense(q, k, v):
         s = jnp.einsum("bhqd,bhkd->bhqk", q.astype(jnp.float32),
                        k.astype(jnp.float32)) / np.sqrt(D)
-        s = jnp.where(jnp.tril(jnp.ones((T, T), bool)), s, -1e30)
+        if causal:
+            s = jnp.where(jnp.tril(jnp.ones((T, T), bool)), s, -1e30)
         lse = jax.nn.logsumexp(s, axis=-1)
         o = jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(s, axis=-1),
                        v.astype(jnp.float32))
         return o, lse
 
     def kernel(q, k, v):
-        o, lse = flash_attention_with_lse(q, k, v, causal=True,
+        o, lse = flash_attention_with_lse(q, k, v, causal=causal,
+                                          block_q=block_q, block_k=block_k,
                                           interpret=False)
         return o.astype(jnp.float32), lse
 
