@@ -35,6 +35,8 @@ def _json(*parts):
 
 
 BENCHMARK = _json(ROOT, "BENCHMARK.json")
+SPEC_KEYS = {"reader", "args"}
+PINNED = set(_json(HERE, "spec_keys_pinned.json")["files"])
 
 
 # ---------------------------------------------------------------- traffic
@@ -306,9 +308,14 @@ def test_every_layer_metric_moves_a_metric_its_cells_report():
         assert m["source"] in ("device_trace", "program_span",
                                "program_counter", "host_clock")
         spec = _json(BENCH, "layer_metrics", m["name"] + ".json")
-        assert (spec["unit"], spec["layer"], spec["moves"]) == \
-            (m["unit"], m["layer"], m["moves"])
-        assert spec.get("workloads") == m.get("workloads")
+        # a cell's name stands in ONE place, BENCHMARK.json: a spec file is
+        # its reader and the reader's arguments (`spec_keys_pinned.json`
+        # lists the files a test outside `benchmark/` still reads more from;
+        # what they repeat has to agree)
+        extra = set(spec) - SPEC_KEYS
+        assert SPEC_KEYS <= set(spec), m["name"]
+        assert not extra or m["name"] in PINNED, m["name"]
+        assert all(spec[k] == m.get(k) for k in extra), m["name"]
         assert os.path.exists(os.path.join(BENCH, "readers",
                                            spec["reader"] + ".py"))
         for cell in m.get("workloads", cells):

@@ -139,6 +139,14 @@ def test_a_stall_is_judged_within_its_kind_of_call():
      CASES["host_busy_ms_per_step"][2]),
     ("sched_call_stall_share.latency", CASES["call_stall_share"][2]),
     ("sched_call_stall_share.throughput", CASES["call_stall_share"][2]),
+    ("sched_host_busy_share.latency", CASES["host_busy_share"][2]),
+    ("sched_host_busy_share.throughput", CASES["host_busy_share"][2]),
+    ("sched_launch_ms_per_call.latency", CASES["launch_ms_per_call"][2]),
+    ("sched_launch_ms_per_call.throughput", CASES["launch_ms_per_call"][2]),
+    ("sched_decode_useful_token_share.latency",
+     CASES["decode_useful_token_share"][2]),
+    ("sched_decode_useful_token_share.throughput",
+     CASES["decode_useful_token_share"][2]),
     ("sched_admit_to_first_token_p50_ms.latency", 250.0)])
 def test_the_metrics_files_name_their_readers_and_arguments(name, want):
     ring, ends = hand_made_ring()

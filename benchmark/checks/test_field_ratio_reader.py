@@ -20,7 +20,7 @@ from deepspeed_tpu.telemetry.steptrace import StepTrace  # noqa: E402
 from test_steptrace_readers import _metric  # noqa: E402
 
 METRICS = ["paged_decode_live_step_share." + s
-           for s in ("latency", "backlog", "generate")]
+           for s in ("latency", "throughput", "generate")]
 
 
 def _ring(rows):

@@ -74,9 +74,10 @@ def serving_ring():
 def test_exposed_host_ms_is_the_mean_over_the_window(serving_ring):
     obs = {"opened": 1.0, "closed": 5.0}            # steps 2, 3, 4, 5
     assert [s.step for s in steprings.steps(obs, "serving")] == [2, 3, 4, 5]
-    assert _metric("sched_exposed_host_ms_per_step.latency", obs) == \
+    # the serving copy that is left: OLMoE's, pinned (`spec_keys_pinned.json`)
+    assert _metric("sched_exposed_host_ms_per_step.generate", obs) == \
         pytest.approx(1e3 * (0.1 + 0.4 + 0.1 + 0.1) / 4)
-    assert _metric("sched_exposed_host_ms_per_step.backlog",
+    assert _metric("sched_exposed_host_ms_per_step.generate",
                    {"opened": 0.0, "closed": 1.0}) == pytest.approx(500.0)
 
 
@@ -111,18 +112,17 @@ def test_request_ms_percentiles_cover_requests_admitted_in_the_window(
 
 def test_blocked_pool_share_counts_steps_that_left_a_queue(serving_ring):
     # steps 1, 2, 3, 5 ended with a queue; three of them waited for the pool
-    assert _metric("sched_admit_blocked_pool_share.backlog",
+    assert _metric("sched_admit_blocked_pool_share.throughput",
                    {"opened": 0.0, "closed": 6.0}) == pytest.approx(75.0)
-    assert _metric("sched_admit_blocked_pool_share.backlog",
+    assert _metric("sched_admit_blocked_pool_share.throughput",
                    {"opened": 3.0, "closed": 4.0}) is None   # no queue there
 
 
 def test_an_empty_window_gives_no_value(serving_ring):
     obs = {"opened": 10.0, "closed": 20.0}
-    for name in ("sched_exposed_host_ms_per_step.latency",
-                 "sched_exposed_host_ms_per_step.backlog",
+    for name in ("sched_exposed_host_ms_per_step.generate",
                  "sched_prefill_stall_share.latency",
-                 "sched_admit_blocked_pool_share.backlog"):
+                 "sched_admit_blocked_pool_share.throughput"):
         assert _metric(name, obs) is None
     # and so does a program with no recorder of that subsystem
     assert steprings.steps(obs, "no such subsystem") == []
