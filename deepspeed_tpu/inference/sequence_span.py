@@ -36,7 +36,6 @@ same contract as the paged Pallas kernel). The int8 pool composes naturally
 (scales ride the same sharded block axis) but is not wired here yet.
 """
 
-import math
 from functools import partial
 from typing import List, Optional
 
@@ -171,13 +170,13 @@ def make_span_gpt_fns(cfg, mesh=None, axis_name=SEQ_AXIS):
     `SpanKVPool`); `span_tables` hold LOCAL physical ids per shard. Layers
     scan exactly like `_scan_paged`, so depth stays out of compile time."""
     from deepspeed_tpu.models.gpt import (_decode_qkv, _embed, _lm_head,
-                                          _residual_mlp)
+                                          _residual_mlp, score_scale)
     mesh = mesh or mesh_mod.get_mesh()
     if cfg.use_alibi or cfg.sliding_window:
         raise ValueError(
             "sequence-spanning serving carries the plain-causal kernel "
             "contract: alibi / sliding-window archs are not supported")
-    scale = 1.0 / math.sqrt(cfg.head_dim) if cfg.scale_attn else 1.0
+    scale = score_scale(cfg, cfg.head_dim)
 
     rep = P(*([None] * 4))
     # one LAYER's pool slice [N, Hkv, block, hd]: block axis sharded

@@ -307,7 +307,7 @@ def _sparse_mlp(h, p, cfg: ExaoneMoEConfig, stacks=None, expert_base=0):
     xf = h.reshape(B * T, D)
     top_p, top_e = topk_routing(
         xf, p["moe_gate_w"], cfg.top_k, cfg.norm_topk_prob,
-        scoring=cfg.router_scoring, bias=p["moe_gate_bias"],
+        scoring=cfg.router_scoring, bias=p.get("moe_gate_bias"),
         scale=cfg.routed_scaling_factor)
     if stacks is None:
         stacks = {"w_gate_up": p["moe_w_gate_up"], "w_down": p["moe_w_down"]}

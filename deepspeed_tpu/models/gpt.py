@@ -62,7 +62,12 @@ class GPTConfig:
     attn_layer_types: Optional[tuple] = None  # GPT-Neo per-layer ("global",
                                      # "local", ...): "local" layers apply the
                                      # sliding_window mask, "global" full causal
-    scale_attn: bool = True          # GPT-Neo scores are NOT scaled by 1/sqrt(hd)
+    scale_attn: Any = True           # the score scale: True = 1/sqrt(hd),
+                                     # False = 1 (GPT-Neo scores are NOT
+                                     # scaled), a float = that VALUE (the
+                                     # Granite family's `attention_multiplier`)
+    embedding_multiplier: float = 1.0  # the token embedding times this
+    logits_scaling: float = 1.0      # the logits DIVIDED by this
     qk_norm: bool = False            # OLMoE/OLMo-2: RMSNorm over the WHOLE
                                      # projected query and key (all heads'
                                      # columns together) before the heads
@@ -465,12 +470,26 @@ FLASH_MIN_SEQ = attn_dispatch.FLASH_MIN_SEQ
 DECODE_KERNEL_MIN_CTX = attn_dispatch.DECODE_KERNEL_MIN_CTX
 
 
+def sm_scale(cfg):
+    """`cfg.scale_attn` as the kernels' `sm_scale`: None is their own default,
+    1 / sqrt(head_dim)."""
+    if cfg.scale_attn is True:
+        return None
+    return 1.0 if cfg.scale_attn is False else float(cfg.scale_attn)
+
+
+def score_scale(cfg, hd):
+    """... and as the number a dense path multiplies the scores by."""
+    scale = sm_scale(cfg)
+    return 1.0 / math.sqrt(hd) if scale is None else scale
+
+
 def _train_attn_site(cfg, T, S, has_bias, attn_fn):
     """Dispatch key for the training/prefill attention call sites."""
     return attn_dispatch.AttnSite(
         phase="train", q_len=T, kv_len=S, causal=True,
         has_bias=has_bias, has_window=bool(cfg.sliding_window),
-        scale_attn=cfg.scale_attn,
+        scale_attn=bool(cfg.scale_attn),
         mesh_axes=attn_dispatch.active_mesh_axes(),
         force_flash=cfg.use_flash_attention,
         chunk_min=getattr(cfg, "chunked_attn_min_seq", None),
@@ -497,14 +516,14 @@ def _attention(q, k, v, causal_mask, cfg, attn_fn=None, bias=None):
     if program not in ("dense", "external"):
         runner = attn_dispatch.get_program(program).runner
         attn_fn = partial(runner, causal=True,
-                          sm_scale=None if cfg.scale_attn else 1.0)
+                          sm_scale=sm_scale(cfg))
     if attn_fn is not None:
         if k.shape[2] != q.shape[2]:  # external kernels expect matched heads
             rep = q.shape[2] // k.shape[2]
             k = jnp.repeat(k, rep, axis=2)
             v = jnp.repeat(v, rep, axis=2)
         return attn_fn(q, k, v)
-    scale = 1.0 / math.sqrt(q.shape[-1]) if cfg.scale_attn else 1.0
+    scale = score_scale(cfg, q.shape[-1])
     B, T, H, hd = q.shape
     Hkv = k.shape[2]
     G = H // Hkv  # grouped einsum; G == 1 is plain MHA
@@ -669,7 +688,10 @@ def _lm_head(params, x, cfg: GPTConfig):
     """Final norm + (tied) LM head. x: [B, T, D] -> logits [B, T, V]."""
     x = _norm(x, params["lnf_scale"], params.get("lnf_bias"), cfg.use_rmsnorm,
               cfg.norm_eps)
-    return _head_logits(params, x, cfg)
+    logits = _head_logits(params, x, cfg)
+    if cfg.logits_scaling != 1.0:
+        logits = logits / cfg.logits_scaling
+    return logits
 
 
 def _embed(params, tokens, positions, cfg: GPTConfig):
@@ -685,6 +707,8 @@ def _embed(params, tokens, positions, cfg: GPTConfig):
     transition to batch/seq sharding is a cheap slice."""
     wte = shard_constraint(params["wte"], TENSOR_AXIS, None)
     x = jnp.take(wte, tokens, axis=0).astype(cfg.dtype)
+    if cfg.embedding_multiplier != 1.0:
+        x = x * cfg.embedding_multiplier
     if not cfg.use_rotary and not cfg.use_alibi:
         wpe = shard_constraint(params["wpe"], None, None)
         x = x + jnp.take(wpe, positions, axis=0).astype(cfg.dtype)
@@ -940,7 +964,8 @@ def _decode_attn_site(cfg: GPTConfig, phase, C, M, kv_dtype="bfloat16",
         has_bias=cfg.use_alibi,
         has_window=bool(cfg.sliding_window) and window is None,
         window=window or 0,
-        scale_attn=cfg.scale_attn, kv_dtype=kv_dtype, block_size=block_size,
+        scale_attn=bool(cfg.scale_attn), kv_dtype=kv_dtype,
+        block_size=block_size,
         pool_in_place=pool_in_place,
         mesh_axes=attn_dispatch.active_mesh_axes(),
         force_flash=cfg.use_flash_attention)
@@ -997,9 +1022,9 @@ def _decode_attn_half(x, p, cache_k, cache_v, pos, cfg: GPTConfig,
             q[:, 0], cache_k, cache_v, pos,
             # honor scale_attn=False (GPT-Neo): the kernel defaults to
             # 1/sqrt(hd) when sm_scale is None
-            sm_scale=None if cfg.scale_attn else 1.0).reshape(B, 1, D)
+            sm_scale=sm_scale(cfg)).reshape(B, 1, D)
     else:
-        scale = 1.0 / math.sqrt(hd) if cfg.scale_attn else 1.0
+        scale = score_scale(cfg, hd)
         m_pos = jnp.arange(M)
         valid = (m_pos[None, :] <= pos[:, None])              # [B, M]
         if cfg.sliding_window:
@@ -1381,7 +1406,7 @@ def _paged_attend(q, k_ctx, v_ctx, q_pos, cfg: GPTConfig, local_flag=None):
     B, C, H, hd = q.shape
     Hkv, S = k_ctx.shape[1], k_ctx.shape[2]
     G = H // Hkv
-    scale = 1.0 / math.sqrt(hd) if cfg.scale_attn else 1.0
+    scale = score_scale(cfg, hd)
     k_pos = jnp.arange(S, dtype=jnp.int32)
     valid = k_pos[None, None, :] <= q_pos[:, :, None]          # [B, C, S]
     if cfg.sliding_window:
@@ -1548,7 +1573,7 @@ def _paged_write_attend(q, k, v, pool_l, positions, block_tables,
     if runner is not None:
         with jax.named_scope("attn"):
             attn = runner(q, pool_l, block_tables, positions[:, 0],
-                          sm_scale=None if cfg.scale_attn else 1.0,
+                          sm_scale=sm_scale(cfg),
                           window=site.window or None)
     elif program == "paged_kernel_quant":
         from deepspeed_tpu.ops.pallas.decode_attention import \
@@ -1557,7 +1582,7 @@ def _paged_write_attend(q, k, v, pool_l, positions, block_tables,
             attn = paged_decode_attention_quant(
                 q[:, 0], pool_l["k"], pool_l["v"], pool_l["k_scale"],
                 pool_l["v_scale"], block_tables, positions[:, 0],
-                sm_scale=None if cfg.scale_attn else 1.0,
+                sm_scale=sm_scale(cfg),
                 work=decode_work,
                 window=site.window or None).reshape(B, 1, -1)
     elif program == "paged_kernel":
@@ -1566,7 +1591,7 @@ def _paged_write_attend(q, k, v, pool_l, positions, block_tables,
         with jax.named_scope("attn"):
             attn = paged_decode_attention(
                 q[:, 0], pool_l["k"], pool_l["v"], block_tables,
-                positions[:, 0], sm_scale=None if cfg.scale_attn else 1.0,
+                positions[:, 0], sm_scale=sm_scale(cfg),
                 work=decode_work,
                 window=site.window or None).reshape(B, 1, -1)
     elif program in ("paged_gather_quant", "paged_gather"):

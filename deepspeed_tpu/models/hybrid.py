@@ -1,0 +1,612 @@
+"""The hybrid layer loop — ONE loop for the families whose stack mixes
+Mamba-2 layers, a few attention layers and routed experts, on the paged
+serving path: `models/nemotron_h.py` (a layer is a mixer OR a feed-forward
+part alone) and `models/granite_moe_hybrid.py` (a layer is a mixer AND the
+routed experts) give it their data and run the same code.
+
+A stack is a list of HALVES, each `x <- x + r * f(RMSNorm(x))` with `r` the
+family's `residual_multiplier`, `f` by the half's letter:
+
+    M  Mamba-2:   [z | xBC | dt] = u W_in; xBC = silu(conv1d(xBC)) (causal,
+                  depthwise, width 4) = [x | B | C]; dt = softplus(dt + bias);
+                  S_t = exp(dt_t A_h) S_(t-1) + dt_t x_t (outer) B_t;
+                  y_t = S_t C_t + D_h x_t; out = RMSNorm_group(y * silu(z)) W_out
+    *  attention: grouped-query, causal, no rotary, no bias, the scores
+                  scaled by `scale_attn`
+    E  the family's expert half (`expert_half`): Nemotron-H's LatentMoE,
+       Granite's gated experts beside a shared one
+
+What a family gives: `HybridConfig.pattern`, a BLOCK a layer, each block a
+string of its halves' letters ("EMEM*": a half a layer; ("ME", "ME", "*E"):
+two); its `expert_half` and the names of that half's expert stacks; the
+scalars (`residual_multiplier` here, `scale_attn`, `embedding_multiplier`,
+`logits_scaling` and `tie_embeddings` in `gpt.py`).
+
+- THE STATE KIND (`inference/kv_cache.py::CacheKind(state=True)`): a
+  Mamba-2 half keeps, per slot and not per token, its state `ssm`
+  `[Lm, 1 + slots, H, P, N]` float32 and the last `conv_kernel - 1` inputs of
+  its convolution `conv` `[Lm, 1 + slots, K - 1, W]` (row 0 the trash row).
+  Nobody allocates, frees or walks it. A prefill chunk reads its slot's row
+  (zeros where the chunk starts at position 0: a slot newly admitted), runs
+  the recurrence in its chunked form from there (`ops/pallas/ssm.py::
+  ssm_chunk_scan`) and writes the row back; a decode token reads and
+  rewrites every row whole, in place (`dstpu_ssm_update`). A chunk's padded
+  tail leaves the state alone (`dt = 0` past the last real position, the
+  convolution's tail taken from the last REAL inputs); a dead slot's row is
+  the trash row. The paged programs take `block_tables` as the PAIR (KV
+  tables [B, nb], state rows [B, 1]).
+- THE PATTERN IS DATA (`models/layer_pattern.py::repeated_runs`, over the
+  blocks): the stack is a list of runs, each a unit of halves and how often
+  it repeats ("EMEMEMEMEM*": ("EM", 5), ("*", 1); nine `ME` blocks around
+  one `*E`: ("ME", 5), ("*E", 1), ("ME", 4)); a repeated unit is scanned,
+  every position of the unit traced for its own kind.
+- THE EXPERT SHARE, as K-EXAONE's: the router routes over all `num_experts`,
+  this chip holds `experts_held = (first, count)`, and what the others would
+  add is left out, here and in the references alike.
+
+Not here: training, the contiguous-cache `generate()` path, and on a pool
+with a state kind the int8 pool, prefix caching, speculative verify and block
+transplant (`ServingEngine` refuses them with the reason).
+"""
+
+import copy
+import dataclasses
+import math
+from functools import partial
+from typing import Any, Optional, Tuple
+
+import jax
+import jax.numpy as jnp
+
+from deepspeed_tpu.inference.kv_cache import CacheKind
+from deepspeed_tpu.models.gpt import (MixedTables, _attn_half, _embed,
+                                      _lm_head, _norm, _paged_attn_half,
+                                      decode_rows, make_mixed_paged_fn)
+from deepspeed_tpu.models.layer_pattern import repeated_runs
+from deepspeed_tpu.models.moe_gpt import MoEGPTConfig
+from deepspeed_tpu.ops import attention_dispatch as attn_dispatch
+from deepspeed_tpu.ops.pallas import ssm
+from deepspeed_tpu.parallel.moe import HELD_ROUTED_COUNTERS
+
+MAMBA, ATTENTION, MOE = "M", "*", "E"
+
+
+@dataclasses.dataclass
+class HybridConfig(MoEGPTConfig):
+    pattern: Any = ""                   # a block a layer: a string (a letter
+                                        # a layer) or a tuple of strings
+    mamba_num_heads: int = 128
+    mamba_head_dim: int = 64
+    ssm_state_size: int = 128
+    n_groups: int = 8                   # groups that share B and C
+    conv_kernel: int = 4
+    chunk_size: int = 128               # positions a chunk of the scan
+    shared_d_ff: int = 0                # the shared expert's, at full width
+    experts_held: Optional[Tuple[int, int]] = None  # (first, count); None=all
+    residual_multiplier: float = 1.0    # `r`: what a half adds, times this
+    time_step_min: float = 0.001        # `dt_bias` is drawn so that
+    time_step_max: float = 0.1          # softplus(dt_bias) is log-uniform
+    time_step_floor: float = 1e-4       # between these, floored
+
+    def __post_init__(self):
+        # what the families share. `use_rotary` is how `gpt.py::_embed` knows
+        # a model WITHOUT learned positions; the attention halves are traced
+        # on a copy with it off (`_attention_cfg`): they rotate nothing
+        self.use_rotary = self.use_rmsnorm = True
+        self.post_norm = self.use_alibi = False
+        self.qk_norm = self.qk_norm_per_head = False
+        self.sliding_window = None
+        self.moe_freq, self.n_layer = 1, len(self.pattern)
+        super().__post_init__()
+        if not self.pattern or set(self.halves) - {MAMBA, ATTENTION, MOE}:
+            raise ValueError(f"pattern {self.pattern!r}: a letter a layer, "
+                             f"or a block of them, of {MAMBA!r}, "
+                             f"{ATTENTION!r}, {MOE!r}")
+        if self.mamba_num_heads % self.n_groups \
+                or self.ssm_inner % self.n_groups:
+            raise ValueError("mamba_num_heads and the inner width divide "
+                             "into n_groups")
+        if self.experts_held is None:
+            self.experts_held = (0, self.num_experts)
+        first, count = self.experts_held
+        if first < 0 or count < 1 or first + count > self.num_experts:
+            raise ValueError(f"experts_held {self.experts_held} is not a "
+                             f"range of the {self.num_experts} experts")
+
+    @property
+    def halves(self):
+        """Every half's letter, in model order."""
+        return "".join(self.pattern)
+
+    @property
+    def ssm_inner(self):
+        return self.mamba_num_heads * self.mamba_head_dim
+
+    @property
+    def conv_width(self):
+        return self.ssm_inner + 2 * self.n_groups * self.ssm_state_size
+
+
+def _attention_cfg(cfg: HybridConfig):
+    acfg = copy.copy(cfg)                       # no `__post_init__`
+    acfg.use_rotary = False
+    return acfg
+
+
+def cache_kinds(cfg: HybridConfig, block_size: int):
+    """`CacheKind` a kind of cache: the attention halves' blocks, then the
+    Mamba-2 halves' per-slot state."""
+    return (CacheKind("full", cfg.halves.count(ATTENTION), block_size,
+                      leaves=("k", "v")),
+            CacheKind("state", cfg.halves.count(MAMBA), 0,
+                      leaves=("ssm", "conv"), state=True))
+
+
+def layer_runs(cfg: HybridConfig):
+    """The pattern as data: [(unit: its halves' letters, repeats), ...]."""
+    return [("".join(unit), repeats)
+            for unit, repeats in repeated_runs(cfg.pattern)]
+
+
+# ----------------------------------------------------------------------
+# parameters
+# ----------------------------------------------------------------------
+
+# leaves that stay float32 whatever the tree is served in
+_FLOAT32_LEAVES = ("dt_bias", "A_log", "ssm_D", "moe_gate_bias")
+
+
+def stream_range(cfg: HybridConfig):
+    """The range every projection back into the stream is drawn at
+    (`rescale_prenorm_residual`)."""
+    return 0.02 / math.sqrt(2 * cfg.n_layer)
+
+
+def mixer_shapes(cfg: HybridConfig, kind):
+    """A Mamba-2 or attention half's leaves -> (shape, init: a float =
+    normal of that range, 1.0 = ones, 0.0 = zeros, a name = `make_layer`'s
+    own rule). A family's `layer_shapes(cfg, kind, router_std)` adds its
+    expert half's."""
+    D, hd = cfg.d_model, cfg.head_dim
+    down = stream_range(cfg)
+    shapes = {"ln1_scale": ((D,), 1.0)}
+    if kind == ATTENTION:
+        shapes.update({
+            "attn_qkv_w": ((D, cfg.qkv_dim), 0.02),
+            "attn_qkv_b": ((cfg.qkv_dim,), 0.0),
+            "attn_out_w": ((cfg.n_head * hd, D), down),
+            "attn_out_b": ((D,), 0.0)})
+    elif kind == MAMBA:
+        H, inner, W = cfg.mamba_num_heads, cfg.ssm_inner, cfg.conv_width
+        shapes.update({
+            "ssm_in_w": ((D, inner + W + H), 0.02),
+            "conv_w": ((cfg.conv_kernel, W), "conv"),
+            "conv_b": ((W,), "conv"),
+            "dt_bias": ((H,), "dt_bias"), "A_log": ((H,), "A_log"),
+            "ssm_D": ((H,), 1.0),
+            "gate_norm_scale": ((inner,), 1.0),
+            "ssm_out_w": ((inner, D), down)})
+    return shapes
+
+
+def make_layer(rng, cfg, shapes, dtype, lead):
+    """One half's tree from `shapes` (`mixer_shapes`' form), every leaf with
+    the leading axes `lead`."""
+    tree = {}
+    for name, (shape, how) in sorted(shapes.items()):
+        rng, sub = jax.random.split(rng)
+        shape = tuple(lead) + shape
+        leaf_dtype = jnp.float32 if name in _FLOAT32_LEAVES else dtype
+        if how == "dt_bias":
+            # softplus(dt_bias) log-uniform in [time_step_min, time_step_max]
+            lo, hi = math.log(cfg.time_step_min), math.log(cfg.time_step_max)
+            dt = jnp.maximum(jnp.exp(jax.random.uniform(
+                sub, shape, jnp.float32, lo, hi)), cfg.time_step_floor)
+            tree[name] = dt + jnp.log(-jnp.expm1(-dt))
+        elif how == "conv":
+            # a depthwise convolution is left at its framework default in
+            # the published initialiser: uniform within 1 / sqrt(kernel)
+            bound = 1.0 / math.sqrt(cfg.conv_kernel)
+            tree[name] = jax.random.uniform(sub, shape, jnp.float32, -bound,
+                                            bound).astype(leaf_dtype)
+        elif how == "A_log":
+            tree[name] = jnp.log(jax.random.uniform(sub, shape, jnp.float32,
+                                                    1.0, 16.0))
+        elif how in (0.0, 1.0):
+            tree[name] = jnp.full(shape, how, leaf_dtype)
+        else:           # a Python float: the product stays in `dtype`
+            tree[name] = jax.random.normal(sub, shape, leaf_dtype) * how
+    return tree
+
+
+def hybrid_init_fn(cfg: HybridConfig, layer_shapes, dtype=jnp.float32,
+                   embedding_std=0.02, router_std=0.02):
+    """jax-traceable initializer (rng -> params): under one `jit` the whole
+    tree is made on the device in the type it is served in. Layout: `runs`:
+    a list, a run of `layer_runs(cfg)`, of one tree a position of the run's
+    unit, every leaf with a leading `[repeats]` axis; `wte`, `lnf_scale`
+    and, where the head is not tied, `lm_head`. `layer_shapes(cfg, kind,
+    router_std)`: the family's leaves of a half. `embedding_std` /
+    `router_std`: as `exaone_moe_init_fn`'s (a benchmark's way to the loads
+    of a trained router)."""
+    runs = layer_runs(cfg)
+
+    def init(rng):
+        keys = iter(jax.random.split(
+            rng, 2 + sum(len(unit) for unit, _ in runs)))
+        V, D = cfg.vocab_size, cfg.d_model
+        wte_key, head_key = next(keys), next(keys)
+        tree = {"wte": jax.random.normal(wte_key, (V, D), dtype)
+                * float(embedding_std),
+                "lnf_scale": jnp.ones((D,), dtype)}
+        if not cfg.tie_embeddings:
+            tree["lm_head"] = jax.random.normal(head_key, (V, D), dtype) * 0.02
+        tree["runs"] = [
+            [make_layer(next(keys), cfg,
+                        layer_shapes(cfg, kind, float(router_std)), dtype,
+                        (repeats,)) for kind in unit]
+            for unit, repeats in runs]
+        return tree
+
+    return init
+
+
+def _split_stacks(tree, names):
+    """(a scanned half's small leaves, its expert stacks `names`): the small
+    leaves keep their leading `[repeats]` axis (a scan slices them), the
+    experts are flat `[repeats * held, ...]` in `routed_experts`' names and
+    stay WHOLE — a layer finds its experts by index (`expert_base`), because
+    a slice of a stack in front of the grouped matmul is a copy of them."""
+    small = {k: v for k, v in tree.items() if k not in names}
+    stacks = {k[len("moe_"):]: v.reshape((-1,) + v.shape[2:])
+              for k, v in tree.items() if k in names}
+    return small, stacks
+
+
+# ----------------------------------------------------------------------
+# the Mamba-2 half
+# ----------------------------------------------------------------------
+
+
+def _conv(seq, p, T):
+    """The causal depthwise convolution and its SiLU: seq [b, T + K - 1, W]
+    (the K - 1 inputs before the first position, then the T positions')
+    -> [b, T, W] in `seq.dtype`."""
+    w = p["conv_w"].astype(jnp.float32)
+    out = p["conv_b"].astype(jnp.float32) + sum(
+        w[k] * seq[:, k:k + T].astype(jnp.float32)
+        for k in range(w.shape[0]))
+    return jax.nn.silu(out).astype(seq.dtype)
+
+
+def _ssm_inputs(xBC, dt, p, cfg):
+    """The convolved `xBC` [.., W] and raw `dt` [.., H] -> (x [.., H, P],
+    B, C [.., G, N], dt float32 after its bias and softplus, A [H])."""
+    H, P = cfg.mamba_num_heads, cfg.mamba_head_dim
+    G, N = cfg.n_groups, cfg.ssm_state_size
+    lead = xBC.shape[:-1]
+    x, B, C = jnp.split(xBC, [H * P, H * P + G * N], axis=-1)
+    dt = jax.nn.softplus(dt.astype(jnp.float32) + p["dt_bias"])
+    return (x.reshape(lead + (H, P)), B.reshape(lead + (G, N)),
+            C.reshape(lead + (G, N)), dt, -jnp.exp(p["A_log"]))
+
+
+def _skip(y, x, p):
+    return y + p["ssm_D"][:, None] * x.astype(jnp.float32)
+
+
+def _mamba_chunk(zxbcdt, p, cfg, cache, rows, start, valid):
+    """Positions `start .. start + T - 1` of b sequences, of which the first
+    `valid` [b] are real: (y [b, T, H, P] float32, cache). `cache`: the
+    carried `(ssm, conv)` pair, rows `rows` [b] of it this call's (None: no
+    cache, every sequence from zero — the whole-sequence forward)."""
+    b, T = zxbcdt.shape[:2]
+    K, W = cfg.conv_kernel, cfg.conv_width
+    _, xBC, dt = jnp.split(zxbcdt, [cfg.ssm_inner, cfg.ssm_inner + W], -1)
+    tail = jnp.zeros((b, K - 1, W), xBC.dtype)
+    S = jnp.zeros((b, cfg.mamba_num_heads, cfg.mamba_head_dim,
+                   cfg.ssm_state_size), jnp.float32)
+    if cache is not None:
+        # a chunk at position 0 is a slot newly admitted: nothing carried
+        fresh = (start == 0)[:, None, None]
+        tail = jnp.where(fresh, 0, ssm.state_read(cache[1], rows))
+        S = jnp.where(fresh[..., None], 0,
+                      ssm.state_read(cache[0], rows).astype(jnp.float32))
+    with jax.named_scope("ssm/conv"):
+        seq = jnp.concatenate([tail.astype(xBC.dtype), xBC], axis=1)
+        # the inputs of the last K - 1 REAL positions, earlier chunks' too
+        tail = jax.vmap(lambda s, n: jax.lax.dynamic_slice_in_dim(
+            s, n, K - 1))(seq, valid)
+        x, B, C, dt, A = _ssm_inputs(_conv(seq, p, T), dt, p, cfg)
+    with jax.named_scope("ssm/scan"):
+        # a padded tail leaves the state alone: decay 1, no input
+        dt = jnp.where(jnp.arange(T)[None, :, None] < valid[:, None, None],
+                       dt, 0.0)
+        y, S = ssm.ssm_chunk_scan(x, dt, A, B, C, S, cfg.chunk_size)
+    if cache is not None:
+        cache = (ssm.state_write(cache[0], rows, S),
+                 ssm.state_write(cache[1], rows, tail))
+    return _skip(y, x, p), cache
+
+
+def _mamba_token(zxbcdt, p, cfg, cache, rows):
+    """One decode token of every row: zxbcdt [S, .] -> (y [S, H, P] float32,
+    cache), each row's state read and rewritten whole, in place."""
+    K, W = cfg.conv_kernel, cfg.conv_width
+    _, xBC, dt = jnp.split(zxbcdt, [cfg.ssm_inner, cfg.ssm_inner + W], -1)
+    with jax.named_scope("ssm/conv"):
+        seq = jnp.concatenate([ssm.state_read(cache[1], rows).astype(xBC.dtype),
+                               xBC[:, None]], axis=1)       # [S, K, W]
+        conv = ssm.state_write(cache[1], rows, seq[:, 1:])
+        x, B, C, dt, A = _ssm_inputs(_conv(seq, p, 1)[:, 0], dt, p, cfg)
+    with jax.named_scope("ssm/update"):
+        y, state = ssm.ssm_update(
+            cache[0], rows, jnp.exp(dt * A),
+            dt[..., None] * x.astype(jnp.float32), B, C)
+    return _skip(y, x, p), (state, conv)
+
+
+def _mamba_half(x, p, cfg, cache=None, rows=None, positions=None, valid=None):
+    """`f` of a Mamba-2 half on x [B, T, D] -> (f(RMSNorm(x)), cache).
+    `rows`: each sequence's row of the cache, [B, 1] — or, of a mixed call,
+    a `MixedTables` of the chunk's and the slots'. `valid` [chunks]: a
+    chunk's real positions (default: all)."""
+    B, T, _ = x.shape
+    u = _norm(x, p["ln1_scale"], None, True, cfg.norm_eps)
+    with jax.named_scope("ssm/in_proj"):
+        zxbcdt = u @ p["ssm_in_w"]
+    if isinstance(rows, MixedTables):
+        # a chunk's rows [1, C, .], then a row a slot: the chunk first, whole
+        # (its state read, scanned and written back), then the slots' token
+        # on the buffer it returned — one chain, nothing for XLA to reorder
+        S = rows.decode.shape[0]
+        C = T - S
+        y_c, cache = _mamba_chunk(zxbcdt[:, :C], p, cfg, cache,
+                                  rows.chunk[:, 0], positions[:, 0], valid)
+        y_d, cache = _mamba_token(zxbcdt[0, C:], p, cfg, cache,
+                                  rows.decode[:, 0])
+        y = jnp.concatenate([y_c, y_d[None]], axis=1)
+    elif cache is not None and valid is None:
+        y, cache = _mamba_token(zxbcdt[:, 0], p, cfg, cache, rows[:, 0])
+        y = y[:, None]
+    else:
+        if valid is None:
+            valid = jnp.full((B,), T, jnp.int32)
+        y, cache = _mamba_chunk(
+            zxbcdt, p, cfg, cache, None if rows is None else rows[:, 0],
+            None if positions is None else positions[:, 0], valid)
+    with jax.named_scope("ssm/out_proj"):
+        z = zxbcdt[..., :cfg.ssm_inner].astype(jnp.float32)
+        gated = (y.reshape(B, T, cfg.n_groups, -1)
+                 * jax.nn.silu(z).reshape(B, T, cfg.n_groups, -1))
+        gated = gated * jax.lax.rsqrt(
+            jnp.mean(jnp.square(gated), -1, keepdims=True) + cfg.norm_eps)
+        out = (gated.reshape(B, T, -1).astype(x.dtype)
+               * p["gate_norm_scale"]) @ p["ssm_out_w"]
+    return out, cache
+
+
+def _residual(x, out, cfg):
+    """`x + r * out`; a family without the multiplier adds `out` as it is."""
+    if cfg.residual_multiplier != 1.0:
+        out = out * cfg.residual_multiplier
+    return x + out
+
+
+# ----------------------------------------------------------------------
+# the whole-sequence forward (no cache): what the tests and the reference
+# check read the program's own routing from
+# ----------------------------------------------------------------------
+
+
+def _layers(params, cfg):
+    """Every half in model order as (kind, its leaves)."""
+    for (unit, repeats), trees in zip(layer_runs(cfg), params["runs"]):
+        for n in range(repeats):
+            for kind, tree in zip(unit, trees):
+                yield kind, jax.tree_util.tree_map(lambda a: a[n], tree)
+
+
+def hybrid_forward(params, tokens, cfg: HybridConfig, expert_half,
+                   routing=None):
+    """tokens [B, T] -> logits [B, T, V]: dense masked attention, the scan
+    from a zero state, a Python loop over the halves. `routing`: a list that
+    takes each expert half's chosen experts [B*T, top_k]."""
+    B, T = tokens.shape
+    positions = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32)[None], (B, T))
+    acfg = _attention_cfg(cfg)
+    x = _embed(params, tokens, positions, cfg)
+    for kind, p in _layers(params, cfg):
+        if kind == ATTENTION:
+            out, _, _ = _attn_half(x, p, acfg, positions, constrain=False)
+        elif kind == MAMBA:
+            out, _ = _mamba_half(x, p, cfg)
+        else:
+            out, _, top_e = expert_half(x, p, cfg)
+            if routing is not None:
+                routing.append(top_e)
+        x = _residual(x, out, cfg)
+    return _lm_head(params, x, cfg)
+
+
+# ----------------------------------------------------------------------
+# the paged programs
+# ----------------------------------------------------------------------
+
+def make_hybrid_decode_model(cfg: HybridConfig, params, name, expert_half,
+                             expert_stacks, cache_fingerprint):
+    """The paged serving contract (`DecodeModelSpec`) of a hybrid family.
+    `expert_half(x, p, cfg, stacks=None, expert_base=0) -> (f(RMSNorm(x)),
+    counters int32[5] in `HELD_ROUTED_COUNTERS` order, chosen experts [B*T,
+    top_k])` is the family's `E` half, `expert_stacks` the leaves of it that
+    hold a weight an expert (`moe_w_*`). The paged programs take
+    `block_tables` as the PAIR (KV tables [B, nb], state rows [B, 1]) and a
+    pool of `cache_kinds`' leaves (module docstring).
+
+    `prefill_paged_fn` and `decode_paged_fn` take one keyword beside the
+    contract's arguments, as K-EXAONE's do: `routing=True` adds a FOURTH
+    result, the experts the call routed every row to — int32 `[expert
+    halves, B, C, top_k]` (`C` 1 for decode), ascending in a token."""
+    from deepspeed_tpu.inference.engine import DecodeModelSpec
+    runs = layer_runs(cfg)
+    acfg = _attention_cfg(cfg)
+    held = cfg.experts_held[1]
+    no_counts = jnp.zeros((len(HELD_ROUTED_COUNTERS),), jnp.int32)
+    pool_writers, attn_programs = {}, {}
+
+    def _layers_paged(params, x, pool, block_tables, positions, valid=None,
+                      routing=False):
+        tables, state_rows = block_tables
+        mixed = isinstance(tables, MixedTables)
+        decode = not mixed and valid is None
+        site = "mixed" if mixed else \
+            "paged_decode" if decode else "prefill_chunk"
+        in_place = attn_dispatch.kv_pool_writer(
+            {"k": pool["k"], "v": pool["v"]}) \
+            == attn_dispatch.KV_POOL_WRITE_KERNEL
+        pool_writers[site] = attn_dispatch.KV_POOL_WRITE_KERNEL if in_place \
+            else attn_dispatch.KV_POOL_WRITE_SCATTER
+        # rows of a layer's leaves: KV blocks, and 1 + slots state rows
+        kv_rows, state_n = pool["k"].shape[1], pool["ssm"].shape[1]
+        work = None
+        if site != "prefill_chunk":     # once a token, outside the layers
+            from deepspeed_tpu.ops.pallas.decode_attention import \
+                paged_decode_work
+            work = paged_decode_work(*decode_rows(tables, positions),
+                                     pool["k"].shape[3])
+        # every leaf flat and CARRIED: layer i of a kind addresses its rows
+        # as `row + i * rows a layer`
+        flat = {k: v.reshape((-1,) + v.shape[2:]) for k, v in pool.items()}
+        offset = lambda t, base: jax.tree_util.tree_map(lambda a: a + base, t)
+
+        def half(x, flat, p, kind, index, counts, chosen, **experts):
+            if kind == ATTENTION:
+                base = index * kv_rows
+                # the kernels take the layer's offset; the scatter and the
+                # gather of the other form take tables already offset
+                where = dict(block_base=base) if in_place else {}
+                with jax.named_scope("attn_full"):
+                    out, kv = _paged_attn_half(
+                        x, p, {"k": flat["k"], "v": flat["v"]}, positions,
+                        tables if in_place else offset(tables, base), acfg,
+                        decode_work=work, attn_programs=attn_programs,
+                        phase=None if mixed else site, **where)
+                flat = {**flat, **kv}
+            elif kind == MAMBA:
+                with jax.named_scope("ssm"):
+                    out, (state, conv) = _mamba_half(
+                        x, p, cfg, (flat["ssm"], flat["conv"]),
+                        offset(state_rows, index * state_n), positions, valid)
+                flat = {**flat, "ssm": state, "conv": conv}
+            else:
+                with jax.named_scope("mlp"):
+                    out, counted, top_e = expert_half(x, p, cfg, **experts)
+                counts.append(counted)
+                if chosen is not None:
+                    chosen.append(top_e)
+            return _residual(x, out, cfg), flat
+
+        acc = no_counts
+        chosen = [] if routing else None    # an expert half's [B*C, top_k]
+        seen = {MAMBA: 0, ATTENTION: 0, MOE: 0}
+        for (unit, repeats), trees in zip(runs, params["runs"]):
+            split = [_split_stacks(tree, expert_stacks) for tree in trees]
+            small = [s for s, _ in split]
+
+            def body(carry, inputs, unit=unit, split=split, seen=dict(seen)):
+                x, flat, acc = carry
+                trees, n = inputs
+                counts, routed = [], [] if routing else None
+                rank = {MAMBA: 0, ATTENTION: 0, MOE: 0}
+                for i, kind in enumerate(unit):
+                    index = seen[kind] + n * unit.count(kind) + rank[kind]
+                    rank[kind] += 1
+                    experts = dict(stacks=split[i][1], expert_base=n * held) \
+                        if kind == MOE else {}
+                    x, flat = half(x, flat, trees[i], kind, index, counts,
+                                   routed, **experts)
+                return (x, flat, acc + sum(counts, no_counts)), routed
+
+            if repeats == 1:
+                (x, flat, acc), routed = body(
+                    (x, flat, acc),
+                    (jax.tree_util.tree_map(lambda a: a[0], small),
+                     jnp.int32(0)))
+                if routing:
+                    chosen += routed
+            else:
+                # the scan slices the small leaves a repeat; the expert
+                # stacks stay whole (closed over, like the carried pool)
+                (x, flat, acc), routed = jax.lax.scan(
+                    body, (x, flat, acc),
+                    (small, jnp.arange(repeats, dtype=jnp.int32)))
+                if routing and routed:
+                    # [repeats, B*C, k] a position -> model order
+                    chosen += [r[n] for n in range(repeats) for r in routed]
+            for kind in seen:
+                seen[kind] += repeats * unit.count(kind)
+        pool = {k: v.reshape(pool[k].shape) for k, v in flat.items()}
+        if routing:
+            B, C = positions.shape
+            return x, pool, acc, jnp.stack(
+                [jnp.sort(e, axis=-1).reshape(B, C, -1) for e in chosen])
+        return x, pool, acc
+
+    def prefill_paged_fn(params, tokens, start_pos, last_idx, pool,
+                         block_tables, routing=False):
+        B, C = tokens.shape
+        positions = start_pos[:, None] + jnp.arange(C, dtype=jnp.int32)[None]
+        x = _embed(params, tokens, positions, cfg)
+        x, pool, *counted = _layers_paged(params, x, pool, block_tables,
+                                          positions, valid=last_idx + 1,
+                                          routing=routing)
+        last = jnp.take_along_axis(x, last_idx[:, None, None], axis=1)
+        return (_lm_head(params, last, cfg)[:, 0], pool, *counted)
+
+    def decode_paged_fn(params, token, pos, pool, block_tables,
+                        routing=False):
+        x = _embed(params, token[:, None], pos[:, None], cfg)
+        x, pool, *counted = _layers_paged(params, x, pool, block_tables,
+                                          pos[:, None], routing=routing)
+        return (_lm_head(params, x, cfg)[:, 0], pool, *counted)
+
+    def init_paged_pool(num_blocks, block_size, dtype=jnp.bfloat16,
+                        kv_group_size=0, state_rows=None):
+        if jnp.dtype(dtype) == jnp.int8:
+            raise ValueError(
+                f"model spec '{name}': the int8 pool is not built for a pool "
+                f"with a state kind (a recurrent state has no scale leaves)")
+        if state_rows is None:
+            raise ValueError(
+                f"model spec '{name}' keeps per-slot recurrent state: "
+                f"init_paged_pool needs `state_rows` (1 + slots), as "
+                f"ServingEngine passes it")
+        full, state = cache_kinds(cfg, block_size)
+        kv = (full.layers, num_blocks, cfg.n_kv_head, block_size,
+              cfg.head_dim)
+        return {
+            "k": jnp.zeros(kv, dtype), "v": jnp.zeros(kv, dtype),
+            "ssm": jnp.zeros((state.layers, state_rows, cfg.mamba_num_heads,
+                              cfg.mamba_head_dim, cfg.ssm_state_size),
+                             jnp.float32),
+            "conv": jnp.zeros((state.layers, state_rows, cfg.conv_kernel - 1,
+                               cfg.conv_width), dtype)}
+
+    def unserved(*_args, **_kwargs):
+        raise NotImplementedError(
+            f"model spec '{name}' is served through the paged scheduler only "
+            f"(`engine.serving(...)`): the contiguous-cache generate() path "
+            f"is not built for layers with recurrent state")
+
+    return DecodeModelSpec(prefill_fn=unserved, decode_fn=unserved,
+                           init_cache=unserved, params=params, name=name,
+                           prefill_paged_fn=prefill_paged_fn,
+                           decode_paged_fn=decode_paged_fn,
+                           mixed_paged_fn=make_mixed_paged_fn(
+                               cfg, _layers_paged, chunk_valid=True),
+                           init_paged_pool=init_paged_pool,
+                           paged_cache_kinds=partial(cache_kinds, cfg),
+                           kv_pool_writers=pool_writers,
+                           paged_attn_programs=attn_programs,
+                           step_counters=HELD_ROUTED_COUNTERS,
+                           cache_fingerprint=cache_fingerprint)

@@ -854,6 +854,43 @@ def test_kernels_keep_their_names_in_the_compiled_program(one_chip):
     assert kinds == {"dstpu_flash_fwd", "dstpu_flash_dq", "dstpu_flash_dkv"}
 
 
+def test_the_state_update_with_one_group_and_the_gated_widths_compile(
+        one_chip):
+    """What the Granite 4.0-H cut brings the kernels (PR 41), at its served
+    shapes: `dstpu_ssm_update` with ONE group of B and C — a `(1, 1, N)`
+    block all 128 heads read — on the nine layers' flat state, IN PLACE (the
+    state is the call's aliased result, nothing of its size beside it); and
+    `dstpu_moe_gmm` over 18 held experts at 1536 columns (the 512 tile) and
+    768 deep."""
+    from deepspeed_tpu.ops.pallas import moe_gmm, ssm
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    rows, b, H, P, N = 9 * 129, 128, 128, 64, 128
+    update = jax.jit(functools.partial(ssm.ssm_update, interpret=False),
+                     donate_argnums=(0,))
+    compiled = update.lower(
+        sds((rows, H, P, N), jnp.float32), sds((b,), jnp.int32),
+        sds((b, H), jnp.float32), sds((b, H, P), jnp.float32),
+        sds((b, 1, N), jnp.bfloat16), sds((b, 1, N), jnp.bfloat16)).compile()
+    lines = [line for line in compiled.as_text().splitlines()
+             if "custom-call(" in line and "tpu_custom_call" in line]
+    assert len(lines) == 1 and "dstpu_ssm_update" in lines[0]
+    memory = compiled.memory_analysis()
+    state = rows * H * P * N * 4
+    assert memory.alias_size_in_bytes >= state
+    assert memory.temp_size_in_bytes < state // 64
+
+    for M in (1280, 6400):              # a decode step's rows, a mixed call's
+        for K, cols in ((4096, 1536), (768, 4096)):
+            lines = _kernel_lines(
+                functools.partial(moe_gmm.moe_gmm, interpret=False),
+                sds((M, K), jnp.bfloat16), sds((5 * 18, K, cols), jnp.bfloat16),
+                sds((18,), jnp.int32), sds((), jnp.int32))
+            assert len(lines) == 1 and "%dstpu_moe_gmm" in lines[0]
+
+
 def test_training_step_holds_the_three_flash_kernels_by_result_signature(
         one_chip, monkeypatch):
     """`flash_roofline.train` tells the three flash kernels apart by the
