@@ -354,7 +354,10 @@ def _mamba_half(x, p, cfg, cache=None, rows=None, positions=None, valid=None):
     B, T, _ = x.shape
     u = _norm(x, p["ln1_scale"], None, True, cfg.norm_eps)
     with jax.named_scope("ssm/in_proj"):
-        zxbcdt = u @ p["ssm_in_w"]
+        # xBC and dt are read at the half's start, z at its end: the barrier
+        # HOLDS the product between them — left alone, XLA frees it after the
+        # convolution and computes it again for the gate (`fusion.N.remat`)
+        zxbcdt = jax.lax.optimization_barrier(u @ p["ssm_in_w"])
     if isinstance(rows, MixedTables):
         # a chunk's rows [1, C, .], then a row a slot: the chunk first, whole
         # (its state read, scanned and written back), then the slots' token

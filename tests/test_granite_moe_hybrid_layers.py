@@ -188,3 +188,26 @@ def test_grouped_matmul_at_the_gated_experts_widths_with_18_groups(K, N):
     want = moe_gmm.moe_gmm_reference(lhs, rhs, sizes, group_offset=E)
     got = moe_gmm.moe_gmm(lhs, rhs, sizes, group_offset=E, interpret=True)
     np.testing.assert_allclose(got[:rows], want[:rows], rtol=2e-4, atol=2e-4)
+
+
+def test_holding_the_in_projection_changes_no_logit(monkeypatch):
+    """`_mamba_half` holds `u @ ssm_in_w` behind an `optimization_barrier`
+    from the convolution to the gate, so that XLA computes it once (PR 42).
+    The barrier is an identity: every Mamba-2 half passes its product through
+    one, and the whole-sequence forward's logits are bit-equal to the same
+    formula without it."""
+    cfg = _cfg()
+    params = _params(cfg)
+    tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 24), 0,
+                                cfg.vocab_size)
+    barrier, held = jax.lax.optimization_barrier, []
+    monkeypatch.setattr(jax.lax, "optimization_barrier",
+                        lambda x: held.append(x.shape) or barrier(x))
+    with_it = np.asarray(jax.jit(
+        lambda p, t: gh.granite_moe_hybrid_forward(p, t, cfg))(params, tokens))
+    width = hybrid.mixer_shapes(cfg, hybrid.MAMBA)["ssm_in_w"][0][-1]
+    assert held == [(2, 24, width)] * cfg.halves.count(hybrid.MAMBA)
+    monkeypatch.setattr(jax.lax, "optimization_barrier", lambda x: x)
+    without = np.asarray(jax.jit(
+        lambda p, t: gh.granite_moe_hybrid_forward(p, t, cfg))(params, tokens))
+    assert np.ptp(with_it) > 0 and np.array_equal(with_it, without)

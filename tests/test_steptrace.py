@@ -9,6 +9,7 @@ Rides the `telemetry` marker (tier-1; `pytest -m telemetry`).
 import collections
 import functools
 import gc
+import importlib
 import json
 import os
 import re
@@ -1591,3 +1592,76 @@ def test_state_kind_programs_hold_nothing_of_the_states_size(
         assert calls["dstpu_ssm_state_write"] == \
             {"decode": 1, "prefill": 2, "mixed": 3}[name], (name, calls)
         assert "dstpu_moe_gmm" in text and "dstpu_kv_pool_gather" not in text
+
+
+# ----------------------------------------------------------------------
+# the Mamba-2 in-projection is computed ONCE a layer a call (PR 42)
+# ----------------------------------------------------------------------
+
+_BENCHMARK = os.path.join(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))), "benchmark")
+
+
+@pytest.mark.parametrize("family, driver, configuration", [
+    ("granite_moe_hybrid", "serve_granite_moe_hybrid",
+     "granite-4.0-h-small-10l-ep4"),
+    ("nemotron_h", "serve_nemotron_h", "nemotron-3-super-120b-a12b-11l-ep4"),
+], ids=["granite", "nemotron"])
+def test_served_mixed_program_computes_each_in_projection_once(
+        one_chip, monkeypatch, family, driver, configuration):
+    """The mixed program of each hybrid family at the SERVED sizes of its
+    configuration file: `[z | xBC | dt] = u @ ssm_in_w` has readers at both
+    ends of the half (the convolution takes xBC and dt, the gate z), and
+    left alone XLA freed the 20 MiB product in between and computed it a
+    second time (`fusion.N.remat`, 7% of Granite's cell; PR 42). No
+    instruction under `ssm/in_proj` is a rematerialised clone, and each
+    scanned run makes the product in exactly one fusion."""
+    from deepspeed_tpu.models import hybrid
+    from deepspeed_tpu.platform import device
+    mesh_mod.clear_mesh()
+    monkeypatch.setattr(device, "on_tpu", lambda: True)
+    monkeypatch.syspath_prepend(_BENCHMARK)
+    model = importlib.import_module(f"deepspeed_tpu.models.{family}")
+    with open(os.path.join(_BENCHMARK, "configs", configuration + ".json")) \
+            as f:
+        served = json.load(f)
+    knobs = served["serving"]
+    cfg = importlib.import_module(f"drivers.{driver}").model_config(
+        served, knobs["max_context"])
+
+    def sds(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip), tree)
+
+    shapes = jax.eval_shape(
+        getattr(model, f"{family}_init_fn")(cfg, dtype=jnp.bfloat16),
+        jax.random.PRNGKey(0))
+    spec = getattr(model, f"make_{family}_decode_model")(
+        cfg, name="served", params=shapes)
+    slots, chunk = knobs["max_slots"], knobs["prefill_chunk"]
+    block = knobs["kv_block_size"]
+    pool = sds(jax.eval_shape(lambda: spec.init_paged_pool(
+        knobs["num_kv_blocks"], block, jnp.bfloat16, state_rows=1 + slots)))
+    ints = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32, sharding=one_chip)
+    tables = lambda b: (ints(b, knobs["max_context"] // block), ints(b, 1))
+    text = jax.jit(spec.mixed_paged_fn, donate_argnums=(7,)).lower(
+        sds(shapes), ints(1, chunk), ints(1), ints(1), tables(1),
+        ints(slots), ints(slots), pool, tables(slots)).compile().as_text()
+
+    width = hybrid.mixer_shapes(cfg, hybrid.MAMBA)["ssm_in_w"][0][-1]
+    product = f"bf16[1,{chunk + slots},{width}]"
+    made = collections.Counter()
+    for name, lines in _computations(text).items():
+        for line in lines:
+            found = _HLO_LINE.match(line)
+            if not found or "ssm/in_proj" not in line:
+                continue
+            assert not found.group(1).endswith(".remat"), line.strip()[:200]
+            if found.group(3) == "fusion" \
+                    and found.group(2).startswith(product):
+                made[name] += 1
+    # a scanned run is one loop body: a product for each `M` of its unit
+    assert sorted(made.values()) == sorted(
+        unit.count(hybrid.MAMBA) for unit, _ in hybrid.layer_runs(cfg)
+        if hybrid.MAMBA in unit), made
