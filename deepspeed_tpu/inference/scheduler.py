@@ -362,11 +362,13 @@ class ServingEngine:
                 f"contract (make_gpt_decode_model provides it)")
         num_blocks = int(scfg.num_kv_blocks or
                          (self.max_slots * self.nb + 1))
-        # a pool of two kinds (`DecodeModelSpec.paged_cache_kinds`): beside
-        # the allocator's blocks a window kind's per-slot rings, or a state
-        # kind's per-slot rows, and their tables, fixed for this engine's
-        # lifetime. What is not built on such a pool is refused HERE, with
-        # the reason, rather than run wrong.
+        # a pool of kinds (`DecodeModelSpec.paged_cache_kinds`): the first
+        # is the allocator's blocks (full-context K/V, or a latent kind's
+        # one entry a token under leaves of its own); a second is a window
+        # kind's per-slot rings, or a state kind's per-slot rows, and their
+        # tables, fixed for this engine's lifetime. What is not built on
+        # such a pool is refused HERE, with the reason, rather than run
+        # wrong.
         self.cache_kinds = None
         self.window_kind = self.state_kind = None
         self.ring = 0
@@ -374,10 +376,20 @@ class ServingEngine:
         kinds_of = getattr(spec, "paged_cache_kinds", None)
         if kinds_of is not None and not self.streamed:
             self.cache_kinds = kinds_of(bs)
-            _full, second = self.cache_kinds
-            if second.state:
+            first, second = (*self.cache_kinds, None)[:2]
+            if second is None:
+                # one kind of allocator blocks: what the host keeps of a
+                # sequence (tables, refcounts, registered prefixes, blocks
+                # to transplant) is what it keeps of K/V blocks
+                kept = f"a KV pool of the {first.name} kind"
+                unbuilt = {
+                    "kv_cache_dtype int8":
+                        f"its leaves ({', '.join(first.leaves)}) have no "
+                        f"scale leaves and its walks no dequantizing twin"}
+            elif second.state:
                 self.state_kind = second
-                beside = "per-slot recurrent state"
+                kept = ("a KV pool of two kinds (per-slot recurrent state "
+                        "beside full-context blocks)")
                 unbuilt = {
                     "enable_prefix_caching":
                         "a hit would need the state as it was at the block "
@@ -390,7 +402,8 @@ class ServingEngine:
                         "and a decode token rewrites it in place"}
             else:
                 self.window_kind = second
-                beside = "window rings"
+                kept = ("a KV pool of two kinds (window rings beside "
+                        "full-context blocks)")
                 unbuilt = {
                     "enable_prefix_caching":
                         "a registered block names a full layer's blocks "
@@ -409,12 +422,11 @@ class ServingEngine:
             for what, why in unbuilt.items():
                 if asked[what]:
                     raise ValueError(
-                        f"model spec '{spec.name}' keeps a KV pool of two "
-                        f"kinds ({beside} beside full-context blocks): "
+                        f"model spec '{spec.name}' keeps {kept}: "
                         f"{what} is not built for it — {why}")
-            if second.state:
+            if self.state_kind is not None:
                 self.ring_tables = state_rows(self.max_slots)
-            else:
+            elif self.window_kind is not None:
                 self.ring = ring_blocks(second.window, second.block,
                                         self.chunk, self.window)
                 self.ring_tables = ring_tables(
@@ -508,7 +520,7 @@ class ServingEngine:
         elif self.state_kind is not None:
             pool = spec.init_paged_pool(num_blocks, bs, jnp.dtype(kvd),
                                         state_rows=1 + self.max_slots)
-        elif self.cache_kinds is not None:
+        elif self.window_kind is not None:
             pool = spec.init_paged_pool(
                 num_blocks, bs, jnp.dtype(kvd),
                 window_blocks=1 + self.max_slots * self.ring)
@@ -524,6 +536,10 @@ class ServingEngine:
             self.prefix_cache = PrefixCache(
                 self.allocator, bs,
                 fingerprint=spec.cache_fingerprint or spec.name)
+        # the allocator's blocks hold a latent kind's entries (the step ring
+        # then books the decode walk's pairs as the latent walk's)
+        self._latent = bool(self.cache_kinds) \
+            and self.cache_kinds[0].name == "latent"
         # what a decode token of one slot reads + writes of a state kind's
         # state proper (its first leaf), all layers
         self._state_token_bytes = 0
@@ -1104,7 +1120,7 @@ class ServingEngine:
                            t_arrive, prefill_only, trace, deadline_at))
 
     def _refuse_transplant(self):
-        if self.cache_kinds is not None:
+        if self.ring_tables is not None:
             what = "a layer's recurrent state" if self.state_kind is not None \
                 else "a window layer's ring"
             raise ValueError(
@@ -1120,7 +1136,7 @@ class ServingEngine:
         ring rows of `rows` (slot indices; None = every slot) with those of
         slots whose full table is all trash (not in this call) at the ring
         kind's trash block (a state kind: its rows, and the trash row)."""
-        if self.cache_kinds is None:
+        if self.ring_tables is None:
             return tables
         ring = self.ring_tables if rows is None else self.ring_tables[rows]
         live = (tables != TRASH_BLOCK).any(axis=1, keepdims=True)
@@ -1849,7 +1865,7 @@ class ServingEngine:
         walk = (0, 0, 0, 0, 0)  # the decode kernel's (live blocks, grid
                              # steps, window layers' live blocks, ...
                              # unwindowed) and a state kind's bytes
-        reach = [0, 0, 0, 0]  # the prefill kernel's (live, table) blocks
+        reach = [0, 0, 0, 0, 0]  # the prefill kernel's (live, table) blocks
                               # and the window layers' (live, unwindowed)
 
         overlap = self._overlaps()
@@ -1957,6 +1973,8 @@ class ServingEngine:
                     decode_window_table_blocks=walk[3],
                     prefill_window_live_blocks=reach[2],
                     prefill_window_table_blocks=reach[3],
+                    latent_walk_blocks=walk[0] * self._latent,
+                    latent_chunk_positions=reach[4],
                     admitted=admitted,
                     prefill_chunks=self.prefill_chunks - chunks0,
                     fused_chunks=len(riding),
@@ -2060,17 +2078,19 @@ class ServingEngine:
         walk attends, a layer: (logical blocks under the chunk's frontier,
         blocks in its table, and for a pool of two kinds the blocks a WINDOW
         layer's walk visits and the blocks the same walk would visit with no
-        window, both in the window kind's blocks) — zeros where `program`,
-        the attention program the chunk was traced with, is not that
-        kernel."""
-        reach = (0, 0, 0, 0)
-        if program == "paged_prefill_kernel":
+        window, both in the window kind's blocks, and the cached positions a
+        chunk over a LATENT kind attends, `start + chunk`) — zeros where
+        `program`, the attention program the chunk was traced with, is not
+        such a kernel."""
+        reach = (0, 0, 0, 0, 0)
+        if program in ("paged_prefill_kernel", "mla_prefill_kernel"):
             from deepspeed_tpu.ops.pallas.prefill_attention import \
                 paged_prefill_live_blocks
             table = self.tables.shape[1]
             full = paged_prefill_live_blocks(
                 start, self.chunk, self.block_size, table)
-            reach = (full, table, 0, 0)
+            reach = (full, table, 0, 0, (start + self.chunk)
+                     * (program == "mla_prefill_kernel"))
             if self.window_kind is not None:
                 # what a window layer's walk visits, in ITS blocks, of
                 # what the same chunk's walk would visit with no window
@@ -2079,7 +2099,7 @@ class ServingEngine:
                 reach = (full, table, paged_prefill_live_blocks(
                     start, self.chunk, wkind.block, width, wkind.window),
                     paged_prefill_live_blocks(
-                        start, self.chunk, wkind.block, width))
+                        start, self.chunk, wkind.block, width), 0)
         slot.planned = start + self.chunk
         self._unread_chunks.append((slot, slot.planned))
         self.prefill_chunks += 1
@@ -2220,7 +2240,7 @@ class ServingEngine:
                     self._tables_arg(self.tables[idx], idx))
         step_fn = self._mixed_step if riding else \
             self._degraded_decode_step() if use_w1 else self._decode_step
-        rode = [0, 0, 0, 0]
+        rode = [0, 0, 0, 0, 0]
         # the dispatch phase holds the jitted call alone: its two stamps are
         # the call record's launch, the arguments' hand-off and the enqueue
         with self._dispatching(
@@ -2522,7 +2542,13 @@ class ServingEngine:
                     "window": kind.window,
                     "blocks": int(self.pool[kind.leaves[0]].shape[1]),
                     "bytes": int(sum(self.pool[leaf].nbytes
-                                     for leaf in kind.leaves))}
+                                     for leaf in kind.leaves)),
+                    # what a cached token costs, all the kind's layers (a
+                    # state kind keeps no token)
+                    "bytes_per_token": 0 if kind.state else int(sum(
+                        self.pool[leaf].nbytes // (self.pool[leaf].shape[1]
+                                                   * kind.block)
+                        for leaf in kind.leaves))}
                 for kind in self.cache_kinds}
             if self.window_kind is not None:
                 out["kv_pool_kinds"]["window"]["ring_blocks_per_slot"] = \

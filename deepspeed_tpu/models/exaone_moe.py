@@ -46,7 +46,7 @@ import copy
 import dataclasses
 import math
 from functools import partial
-from typing import Optional, Tuple
+from typing import Callable, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -56,12 +56,15 @@ from deepspeed_tpu.models.gpt import (MixedTables, _attn_half, _embed,
                                       _lm_head, _paged_attn_half,
                                       _residual_mlp, decode_rows,
                                       make_mixed_paged_fn)
+from deepspeed_tpu.models.mla import (LATENT_LEAF, entry_width, mla_attn_half,
+                                      mla_shapes, paged_mla_half)
 from deepspeed_tpu.models.moe_gpt import MoEGPTConfig
 from deepspeed_tpu.ops import attention_dispatch as attn_dispatch
 from deepspeed_tpu.parallel.moe import (HELD_ROUTED_COUNTERS, routed_experts,
                                         topk_routing)
 
 WINDOW, FULL = "sliding_attention", "full_attention"
+LATENT = "latent_attention"     # MLA (`models/mla.py`): one entry a token
 DENSE, SPARSE = "dense", "sparse"
 
 
@@ -106,8 +109,68 @@ class ExaoneMoEConfig(MoEGPTConfig):
                              f"range of the {self.num_experts} experts")
 
 
-# the pool's K and V leaves, a kind of attention layer
-_POOL_LEAVES = {FULL: ("k", "v"), WINDOW: ("wk", "wv")}
+def _gqa_shapes(cfg):
+    D, hd = cfg.d_model, cfg.head_dim
+    return {
+        "attn_qkv_w": ((D, cfg.qkv_dim), 0.02),
+        "attn_qkv_b": ((cfg.qkv_dim,), 0.0),
+        "attn_out_w": ((cfg.n_head * hd, D),
+                       0.02 / math.sqrt(2 * cfg.n_layer)),
+        "attn_out_b": ((D,), 0.0),
+        "q_norm_scale": ((hd,), 1.0), "k_norm_scale": ((hd,), 1.0),
+    }
+
+
+def _window_cfg(cfg):
+    window = copy.copy(cfg)                 # no `__post_init__`
+    window.attn_layer_types = None
+    return window
+
+
+def _full_cfg(cfg):
+    full = _window_cfg(cfg)
+    full.use_rotary, full.sliding_window = False, None
+    return full
+
+
+@dataclasses.dataclass(frozen=True)
+class AttnKind:
+    """A kind of attention layer AS DATA — everything the parameter tree,
+    the pool and the layer loops below need of it, so that a kind is an
+    entry of `ATTN_KINDS` and not a branch in them."""
+    leaves: Tuple[str, ...]     # its leaves of the pool pytree ...
+    names: Tuple[str, ...]      # ... under the names its paged half reads
+    shapes: Callable            # cfg -> its attention leaves' (shape, scale)
+    dense: Callable             # the whole-sequence half (`gpt._attn_half`)
+    paged: Callable             # the paged half (`gpt._paged_attn_half`)
+    cfg_of: Callable            # cfg -> the configuration both are traced
+                                # with (a kind's rotary and window are its)
+    entry: Callable             # cfg -> (heads, width) of a cached position
+    name: str                   # its `CacheKind`'s name; the layers run
+                                # under the `jax.named_scope` `attn_<name>`
+
+
+# window and full layers: rotary and the window belong to the window layers,
+# a full layer has neither; a latent layer (`models/mla.py`) rotates inside
+# its half and caches one entry a token for all heads
+ATTN_KINDS = {
+    FULL: AttnKind(("k", "v"), ("k", "v"), _gqa_shapes, _attn_half,
+                   _paged_attn_half, _full_cfg,
+                   lambda cfg: (cfg.n_kv_head, cfg.head_dim), "full"),
+    WINDOW: AttnKind(("wk", "wv"), ("k", "v"), _gqa_shapes, _attn_half,
+                     _paged_attn_half, _window_cfg,
+                     lambda cfg: (cfg.n_kv_head, cfg.head_dim), "window"),
+    LATENT: AttnKind((LATENT_LEAF,), (LATENT_LEAF,), mla_shapes,
+                     mla_attn_half, paged_mla_half, _window_cfg,
+                     lambda cfg: (1, entry_width(cfg)), "latent"),
+}
+
+
+def pool_kinds(cfg):
+    """The kinds of the model's POOL, the allocator's first: window and full
+    layers keep a pool of two kinds (either may have no layer), latent
+    layers a pool of one."""
+    return (LATENT,) if LATENT in cfg.layer_types else (FULL, WINDOW)
 
 
 def layer_plan(cfg: ExaoneMoEConfig):
@@ -144,21 +207,20 @@ def layer_plan(cfg: ExaoneMoEConfig):
 
 
 def cache_kinds(cfg: ExaoneMoEConfig, block_size: int):
-    """`CacheKind` a kind of attention layer, full first."""
-    return (CacheKind("full", cfg.layer_types.count(FULL), block_size,
-                      leaves=_POOL_LEAVES[FULL]),
-            CacheKind("window", cfg.layer_types.count(WINDOW),
-                      cfg.window_block, int(cfg.sliding_window or 0),
-                      leaves=_POOL_LEAVES[WINDOW]))
+    """`CacheKind` a kind of the pool (`pool_kinds`)."""
+    def kind(layer_type):
+        window = layer_type == WINDOW
+        attn = ATTN_KINDS[layer_type]
+        return CacheKind(attn.name, cfg.layer_types.count(layer_type),
+                         cfg.window_block if window else block_size,
+                         int(cfg.sliding_window or 0) if window else 0,
+                         leaves=attn.leaves)
+    return tuple(kind(layer_type) for layer_type in pool_kinds(cfg))
 
 
 def _kind_cfgs(cfg: ExaoneMoEConfig):
-    """The two configurations the attention halves are traced with: rotary
-    and the window belong to the window layers, a full layer has neither."""
-    window, full = copy.copy(cfg), copy.copy(cfg)   # no `__post_init__`
-    window.attn_layer_types = full.attn_layer_types = None
-    full.use_rotary, full.sliding_window = False, None
-    return {WINDOW: window, FULL: full}
+    """The configuration each kind's attention halves are traced with."""
+    return {name: ATTN_KINDS[name].cfg_of(cfg) for name in pool_kinds(cfg)}
 
 
 # ----------------------------------------------------------------------
@@ -166,19 +228,13 @@ def _kind_cfgs(cfg: ExaoneMoEConfig):
 # ----------------------------------------------------------------------
 
 
-def _layer_shapes(cfg: ExaoneMoEConfig, mlp_kind, router_std=0.02):
+def _layer_shapes(cfg: ExaoneMoEConfig, attn_kind, mlp_kind,
+                  router_std=0.02):
     """One layer's leaves -> (shape, init scale; 1.0 = ones, 0.0 = zeros)."""
-    D, hd = cfg.d_model, cfg.head_dim
-    attn = cfg.n_head * hd
+    D = cfg.d_model
     down = 0.02 / math.sqrt(2 * cfg.n_layer)
-    shapes = {
-        "attn_qkv_w": ((D, cfg.qkv_dim), 0.02),
-        "attn_qkv_b": ((cfg.qkv_dim,), 0.0),
-        "attn_out_w": ((attn, D), down),
-        "attn_out_b": ((D,), 0.0),
-        "q_norm_scale": ((hd,), 1.0), "k_norm_scale": ((hd,), 1.0),
-        "ln1_scale": ((D,), 1.0), "ln2_scale": ((D,), 1.0),
-    }
+    shapes = {**ATTN_KINDS[attn_kind].shapes(cfg),
+              "ln1_scale": ((D,), 1.0), "ln2_scale": ((D,), 1.0)}
     if mlp_kind == DENSE:
         F = cfg.d_ff_dense
         shapes.update({"mlp_gate_w": ((D, F), 0.02), "mlp_up_w": ((D, F), 0.02),
@@ -197,9 +253,9 @@ def _layer_shapes(cfg: ExaoneMoEConfig, mlp_kind, router_std=0.02):
     return shapes
 
 
-def _make_layer(rng, cfg, mlp_kind, dtype, lead=(), router_std=0.02):
+def _make_layer(rng, cfg, kinds, dtype, lead=(), router_std=0.02):
     tree = {}
-    shapes = _layer_shapes(cfg, mlp_kind, float(router_std))
+    shapes = _layer_shapes(cfg, *kinds, float(router_std))
     for name, (shape, scale) in sorted(shapes.items()):
         rng, sub = jax.random.split(rng)
         shape = tuple(lead) + shape
@@ -241,12 +297,12 @@ def exaone_moe_init_fn(cfg: ExaoneMoEConfig, dtype=jnp.float32,
             * float(embedding_std),
             "lm_head": jax.random.normal(keys[1], (V, D), dtype) * 0.02,
             "lnf_scale": jnp.ones((D,), dtype),
-            "prologue": [_make_layer(k, cfg, mlp, dtype,
+            "prologue": [_make_layer(k, cfg, kinds, dtype,
                                      router_std=router_std)
-                         for k, (_, mlp) in zip(keys[2:], prologue)],
-            "period": [_make_layer(k, cfg, mlp, dtype, lead=(periods,),
+                         for k, kinds in zip(keys[2:], prologue)],
+            "period": [_make_layer(k, cfg, kinds, dtype, lead=(periods,),
                                    router_std=router_std)
-                       for k, (_, mlp)
+                       for k, kinds
                        in zip(keys[2 + len(prologue):], period)],
         }
         return params
@@ -355,8 +411,8 @@ def exaone_moe_forward(params, tokens, cfg: ExaoneMoEConfig, routing=None):
     kinds = zip(cfg.layer_types, cfg.mlp_layer_types)
     for (p, experts), (attn_kind, mlp_kind) in zip(_layers(params, cfg),
                                                    kinds):
-        attn_out, _, _ = _attn_half(x, p, kcfg[attn_kind], positions,
-                                    constrain=False)
+        attn_out, _, _ = ATTN_KINDS[attn_kind].dense(
+            x, p, kcfg[attn_kind], positions, constrain=False)
         x = _residual_mlp(x, attn_out, p, cfg, constrain=False,
                           mlp_fn=_mlp_fn(p, cfg, mlp_kind, routing=routing,
                                          **experts))
@@ -378,7 +434,8 @@ def exaone_moe_cache_identity(cfg: ExaoneMoEConfig, name: str = "") -> str:
 # ----------------------------------------------------------------------
 
 def make_exaone_moe_decode_model(cfg: ExaoneMoEConfig, params=None,
-                                 name="exaone-moe", seed=0):
+                                 name="exaone-moe", seed=0,
+                                 family="exaone_moe", fingerprint=None):
     """The paged serving contract (`DecodeModelSpec`) of the family. The
     paged programs take `block_tables` as the PAIR `(full tables [B, nb],
     ring tables [B, nbw])` and a pool of two kinds (module docstring).
@@ -394,6 +451,7 @@ def make_exaone_moe_decode_model(cfg: ExaoneMoEConfig, params=None,
         params = exaone_moe_init_fn(cfg)(jax.random.PRNGKey(seed))
     prologue, period, periods = layer_plan(cfg)
     kcfg = _kind_cfgs(cfg)
+    kinds = {name: ATTN_KINDS[name] for name in pool_kinds(cfg)}
     held = cfg.experts_held[1]
     no_counts = jnp.zeros((len(HELD_ROUTED_COUNTERS),), jnp.int32)
     pool_writers, attn_programs = {}, {}
@@ -402,37 +460,42 @@ def make_exaone_moe_decode_model(cfg: ExaoneMoEConfig, params=None,
         return sum(1 for attn, _ in period if attn == kind)
 
     def _layers_paged(params, x, pool, block_tables, positions, routing):
-        tables = dict(zip((FULL, WINDOW), block_tables))
+        # a pool of one kind takes its tables bare, of two as the pair
+        if len(kinds) == 1:
+            block_tables = (block_tables,)
+        tables = dict(zip(kinds, block_tables))
         # a mixed call (`gpt.py::MixedTables`): a chunk's rows, then a
         # decode row a slot, each kind's tables the pair of the two groups'
         mixed = isinstance(block_tables[0], MixedTables)
         site = "mixed" if mixed else \
             "paged_decode" if x.shape[1] == 1 else "prefill_chunk"
         in_place = all(
-            attn_dispatch.kv_pool_writer({"k": pool[a], "v": pool[b]})
+            attn_dispatch.kv_pool_writer(
+                dict(zip(kind.names, (pool[leaf] for leaf in kind.leaves))))
             == attn_dispatch.KV_POOL_WRITE_KERNEL
-            for a, b in _POOL_LEAVES.values())
+            for kind in kinds.values())
         pool_writers[site] = attn_dispatch.KV_POOL_WRITE_KERNEL if in_place \
             else attn_dispatch.KV_POOL_WRITE_SCATTER
-        blocks_of = {kind: pool[a].shape[1]
-                     for kind, (a, _) in _POOL_LEAVES.items()}
+        blocks_of = {name: pool[kind.leaves[0]].shape[1]
+                     for name, kind in kinds.items()}
         # one work list a KIND, built once a token, outside the layer loop
-        work = {FULL: None, WINDOW: None}
+        work = dict.fromkeys(kinds)
         if site != "prefill_chunk":
             from deepspeed_tpu.ops.pallas.decode_attention import \
                 paged_decode_work
-            work = {kind: paged_decode_work(
-                *decode_rows(tables[kind], positions), pool[a].shape[3],
-                window=kcfg[kind].sliding_window)
-                for kind, (a, _) in _POOL_LEAVES.items()}
-        # both kinds' leaves flat and CARRIED: layer i of a kind addresses
+            work = {name: paged_decode_work(
+                *decode_rows(tables[name], positions),
+                pool[kind.leaves[0]].shape[3],
+                window=kcfg[name].sliding_window)
+                for name, kind in kinds.items()}
+        # every kind's leaves flat and CARRIED: layer i of a kind addresses
         # its blocks as `table + i * N`
         flat = {k: v.reshape((-1,) + v.shape[2:]) for k, v in pool.items()}
 
-        def layer(x, flat, p, kinds, kind_index, counts, **experts):
+        def layer(x, flat, p, layer_kinds, kind_index, counts, **experts):
             # `experts`: `_mlp_fn`'s keywords (routing=, stacks=, expert_base=)
-            attn_kind, mlp_kind = kinds
-            a, b = _POOL_LEAVES[attn_kind]
+            attn_kind, mlp_kind = layer_kinds
+            kind = kinds[attn_kind]
             base = kind_index * blocks_of[attn_kind]
             # the kernels take the layer's offset; the scatter and the
             # gather of the other form take tables already offset
@@ -440,13 +503,15 @@ def make_exaone_moe_decode_model(cfg: ExaoneMoEConfig, params=None,
             table = tables[attn_kind] if in_place \
                 else jax.tree_util.tree_map(lambda t: t + base,
                                             tables[attn_kind])
-            with jax.named_scope("attn_window" if attn_kind == WINDOW
-                                 else "attn_full"):
-                attn_out, pool_l = _paged_attn_half(
-                    x, p, {"k": flat[a], "v": flat[b]}, positions, table,
-                    kcfg[attn_kind], decode_work=work[attn_kind],
+            with jax.named_scope(f"attn_{kind.name}"):
+                attn_out, pool_l = kind.paged(
+                    x, p, {n: flat[leaf]
+                           for n, leaf in zip(kind.names, kind.leaves)},
+                    positions, table, kcfg[attn_kind],
+                    decode_work=work[attn_kind],
                     attn_programs=attn_programs, **where)
-            flat = {**flat, a: pool_l["k"], b: pool_l["v"]}
+            flat = {**flat, **{leaf: pool_l[n]
+                               for n, leaf in zip(kind.names, kind.leaves)}}
             with jax.named_scope("mlp"):
                 x = _residual_mlp(x, attn_out, p, cfg, constrain=False,
                                   mlp_fn=_mlp_fn(p, cfg, mlp_kind, counts,
@@ -455,11 +520,11 @@ def make_exaone_moe_decode_model(cfg: ExaoneMoEConfig, params=None,
 
         counts = []
         chosen = [] if routing else None     # a layer's [B*C, top_k]
-        seen = {FULL: 0, WINDOW: 0}
-        for p, kinds in zip(params["prologue"], prologue):
-            x, flat = layer(x, flat, p, kinds, seen[kinds[0]], counts,
-                            routing=chosen)
-            seen[kinds[0]] += 1
+        seen = dict.fromkeys(kinds, 0)
+        for p, layer_kinds in zip(params["prologue"], prologue):
+            x, flat = layer(x, flat, p, layer_kinds, seen[layer_kinds[0]],
+                            counts, routing=chosen)
+            seen[layer_kinds[0]] += 1
         acc = sum(counts, no_counts)
 
         if periods:
@@ -473,15 +538,15 @@ def make_exaone_moe_decode_model(cfg: ExaoneMoEConfig, params=None,
                 trees, n = inputs
                 counts = []
                 routed = [] if routing else None
-                rank = {FULL: 0, WINDOW: 0}
-                for i, kinds in enumerate(period):
-                    kind = kinds[0]
+                rank = dict.fromkeys(kinds, 0)
+                for i, layer_kinds in enumerate(period):
+                    kind = layer_kinds[0]
                     index = seen[kind] + n * per_period(kind) + rank[kind]
                     rank[kind] += 1
                     experts = dict(stacks=stacks[i], expert_base=n * held) \
-                        if kinds[1] == SPARSE else {}
-                    x, flat = layer(x, flat, trees[i], kinds, index, counts,
-                                    routing=routed, **experts)
+                        if layer_kinds[1] == SPARSE else {}
+                    x, flat = layer(x, flat, trees[i], layer_kinds, index,
+                                    counts, routing=routed, **experts)
                 return (x, flat, acc + sum(counts, no_counts)), routed
 
             (x, flat, acc), routed = jax.lax.scan(
@@ -519,26 +584,29 @@ def make_exaone_moe_decode_model(cfg: ExaoneMoEConfig, params=None,
         if jnp.dtype(dtype) == jnp.int8:
             raise ValueError(
                 f"model spec '{name}': the int8 pool is not built for a pool "
-                f"of two kinds (the window kind's rings have no scale leaves "
-                f"and the windowed walks no dequantizing twin)")
-        if window_blocks is None:
+                f"of kinds {'/'.join(k.name for k in kinds.values())} (a "
+                f"window kind's rings and a latent kind's entries have no "
+                f"scale leaves, their walks no dequantizing twin)")
+        if WINDOW in kinds and window_blocks is None:
             raise ValueError(
                 f"model spec '{name}' keeps a pool of two kinds: "
                 f"init_paged_pool needs `window_blocks` (1 + slots * "
                 f"kv_cache.ring_blocks(...)), as ServingEngine passes it")
         pool = {}
-        for kind, n in zip(cache_kinds(cfg, block_size),
-                           (num_blocks, window_blocks)):
-            shape = (kind.layers, n, cfg.n_kv_head, kind.block, cfg.head_dim)
+        for attn, kind in zip(kinds.values(), cache_kinds(cfg, block_size)):
+            heads, width = attn.entry(cfg)
+            shape = (kind.layers,
+                     window_blocks if kind.window else num_blocks,
+                     heads, kind.block, width)
             pool.update({leaf: jnp.zeros(shape, dtype)
                          for leaf in kind.leaves})
         return pool
 
     def unserved(*_args, **_kwargs):
         raise NotImplementedError(
-            f"model spec '{name}' (exaone_moe) is served through the paged "
+            f"model spec '{name}' ({family}) is served through the paged "
             f"scheduler only (`engine.serving(...)`): the contiguous-cache "
-            f"generate() path is not built for mixed window and full layers")
+            f"generate() path is not built for a pool of kinds")
 
     return DecodeModelSpec(prefill_fn=unserved, decode_fn=unserved,
                            init_cache=unserved, params=params, name=name,
@@ -552,5 +620,5 @@ def make_exaone_moe_decode_model(cfg: ExaoneMoEConfig, params=None,
                            kv_pool_writers=pool_writers,
                            paged_attn_programs=attn_programs,
                            step_counters=HELD_ROUTED_COUNTERS,
-                           cache_fingerprint=exaone_moe_cache_identity(
-                               cfg, name))
+                           cache_fingerprint=fingerprint
+                           or exaone_moe_cache_identity(cfg, name))

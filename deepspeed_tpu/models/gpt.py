@@ -161,7 +161,8 @@ class GPTConfig:
             self.d_ff = int(8 * self.d_model / 3) if self.use_swiglu else 4 * self.d_model
         if self.n_kv_head is None:
             self.n_kv_head = self.n_head
-        assert self.d_model % self.n_head == 0
+        # a head width given apart (`attn_head_dim`) need not divide d_model
+        assert self.attn_head_dim or self.d_model % self.n_head == 0
         assert self.n_head % self.n_kv_head == 0
 
     @property

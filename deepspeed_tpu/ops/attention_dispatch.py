@@ -109,6 +109,10 @@ class AttnSite:
     pool_in_place: bool = False   # paged phases: the pool is the carried,
                                   # flat, Mosaic-only form (`kv_pool_writer`
                                   # named KV_POOL_WRITE_KERNEL for it)
+    latent: bool = False          # paged phases: the pool holds ONE latent
+                                  # entry a position for all heads (MLA,
+                                  # `models/mla.py`): only the `mla_*`
+                                  # programs read it
     mesh_axes: Tuple[str, ...] = ()  # active (size>1) mesh axes
     force_flash: Optional[bool] = None  # GPTConfig.use_flash_attention
     chunk_min: Optional[int] = None     # GPTConfig.chunked_attn_min_seq
@@ -413,7 +417,8 @@ KV_POOL_WRITE_SCATTER = "xla_scatter"
 
 def kv_pool_writer(pool) -> str:
     """Name the writer for `pool` (the `[L, N, Hkv, block, hd]` pytree of
-    `init_paged_kv_pool`) from what can be seen at trace time: the in-place
+    `init_paged_kv_pool`, or a latent kind's one leaf `{"ckv": [L, N, 1,
+    block, width]}`) from what can be seen at trace time: the in-place
     kernel for a float pool made of whole native tiles, on a TPU, in a
     single-device program (a bare Mosaic call cannot be partitioned — the
     same limit as `dstpu_paged_decode`); the scatter everywhere else: the
@@ -421,12 +426,39 @@ def kv_pool_writer(pool) -> str:
     lane tile, a multi-device mesh."""
     from deepspeed_tpu.ops.pallas.kv_pool import pool_in_place_supported
     from deepspeed_tpu.platform.device import pallas_interpret
-    k = pool["k"]
-    if (set(pool) == {"k", "v"} and not pallas_interpret()
+    leaves = set(pool)
+    k = pool["ckv" if leaves == {"ckv"} else "k"]
+    if (leaves in ({"k", "v"}, {"ckv"}) and not pallas_interpret()
             and not active_mesh_axes()
             and pool_in_place_supported(k.dtype, k.shape[-2], k.shape[-1])):
         return KV_POOL_WRITE_KERNEL
     return KV_POOL_WRITE_SCATTER
+
+
+# A latent pool (MLA): the absorbed walks of `ops/pallas/mla_attention.py`.
+# They outrank every program above and match latent sites only, so a latent
+# site never selects a K/V program and no other site selects these.
+# `models/mla.py` dispatches them by name.
+register_program(AttentionProgram(
+    name="mla_decode_kernel", phases=("paged_decode",), priority=90,
+    matches=lambda s: s.latent and _paged_kernel_ok(s),
+    when="latent pool + the paged kernel's conditions: absorbed walk over "
+         "the live (slot, block) pairs, a block read once for scores and "
+         "values (dstpu_mla_decode)"))
+
+register_program(AttentionProgram(
+    name="mla_prefill_kernel", phases=("prefill_chunk",), priority=90,
+    matches=lambda s: s.latent and _paged_prefill_ok(s),
+    when="latent pool in the in-place form, C % 128 == 0, block % 128 == "
+         "0: absorbed flash walk over the blocks under the chunk's frontier "
+         "(dstpu_mla_prefill)"))
+
+register_program(AttentionProgram(
+    name="mla_gather", phases=("paged_decode", "prefill_chunk"), priority=80,
+    matches=lambda s: s.latent,
+    when="latent pool, fallback: table gather + dense ABSORBED attend over "
+         "the whole table (the kernels' oracle; the CPU, a mesh, a chunk "
+         "or block off the lane tile)"))
 
 
 register_program(AttentionProgram(

@@ -337,7 +337,8 @@ def _paged_walk_kernel(cnt_ref, slot_ref, blk_ref, pos_ref, bt_ref, q_ref,
 
 
 def _paged_walk(load_head, q, leaves, block_tables, pos, work, sm_scale,
-                interpret, window=None):
+                interpret, window=None, out_dim=None,
+                name="dstpu_paged_decode"):
     """THE walk over a paged pool, shared by the float and the int8 kernel:
     a 1-D list of the live (slot, logical block) pairs (`paged_decode_work`),
     its length the grid's DYNAMIC bound, so a dead slot and a block past a
@@ -347,7 +348,10 @@ def _paged_walk(load_head, q, leaves, block_tables, pos, work, sm_scale,
     whole; `load_head(pool_refs, h, dtype)` hands head h's K and V tiles
     [block, hd] in the compute dtype to `_online_softmax_tile`. Rows of dead
     slots come back ZERO (they ride on through the MLP, and through a routed
-    model's router and its counters)."""
+    model's router and its counters). `out_dim`: the width of V's tiles and
+    of the result where it is not q's (a latent pool's values are a slice of
+    its keys' tile, `ops/pallas/mla_attention.py`); `name`: the call's name
+    in a compiled program."""
     if interpret is None:
         interpret = pallas_interpret()
     B, H, hd = q.shape
@@ -355,6 +359,7 @@ def _paged_walk(load_head, q, leaves, block_tables, pos, work, sm_scale,
     nb = block_tables.shape[1]
     assert H % Hkv == 0
     G = H // Hkv
+    out_dim = out_dim or hd
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(hd)
     if work is None:
@@ -378,20 +383,20 @@ def _paged_walk(load_head, q, leaves, block_tables, pos, work, sm_scale,
             in_specs=[pl.BlockSpec((1, heads, G, hd), slot_index)] + [
                 pl.BlockSpec((1, heads, block_m, x.shape[-1]), pair_index)
                 for x in leaves],
-            out_specs=pl.BlockSpec((1, heads, G, hd), slot_index),
+            out_specs=pl.BlockSpec((1, heads, G, out_dim), slot_index),
             scratch_shapes=[
-                pltpu.VMEM((heads, G, hd), jnp.float32),
+                pltpu.VMEM((heads, G, out_dim), jnp.float32),
                 pltpu.VMEM((heads, G, _LANES), jnp.float32),
                 pltpu.VMEM((heads, G, _LANES), jnp.float32),
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct((B, Hkv, G, hd), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, Hkv, G, out_dim), q.dtype),
         interpret=interpret,
-        name="dstpu_paged_decode",
+        name=name,
     )(work.count, work.slot, work.block, pos.astype(jnp.int32),
       block_tables.astype(jnp.int32), q.reshape(B, Hkv, G, hd), *leaves)
     # a slot the walk never visits is memory nobody wrote
-    return jnp.where(work.live[:, None, None], out.reshape(B, H, hd), 0)
+    return jnp.where(work.live[:, None, None], out.reshape(B, H, out_dim), 0)
 
 
 def _load_float_head(pool_refs, h, dtype):

@@ -104,11 +104,14 @@ def _prefill_kernel(start_ref, bt_ref, q_ref, k_ref, v_ref, o_ref, acc_ref,
     # fp32 carry the online softmax over the row's blocks, innermost and
     # ascending. With a `window` the KV axis counts from the block the
     # tile's first row's window begins in (`window_first_block`), not from 0.
+    # `v_ref` None (a latent pool, `ops/pallas/mla_attention.py`): a key
+    # tile's first `acc_ref.shape[-1]` columns are its values.
     del bt_ref
     b = pl.program_id(0)
     qi = pl.program_id(2)
     tq = q_ref.shape[1]
     heads, hd = k_ref.shape[1], k_ref.shape[3]
+    dv = acc_ref.shape[-1]                  # the values' width: a result's
     q_lo = start_ref[b] + qi * tq           # this tile's first position
     q_hi = q_lo + tq - 1
     frontier = jnp.minimum(q_hi // block, last_block)
@@ -137,7 +140,7 @@ def _prefill_kernel(start_ref, bt_ref, q_ref, k_ref, v_ref, o_ref, acc_ref,
         for i in range(heads * G):
             q = q_ref[0, :, i * hd:(i + 1) * hd]
             k = k_ref[0, i // G, keys, :]
-            v = v_ref[0, i // G, keys, :]
+            v = k[:, :dv] if v_ref is None else v_ref[0, i // G, keys, :]
             s = jax.lax.dot_general(
                 q, k, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32) * sm_scale
@@ -171,7 +174,7 @@ def _prefill_kernel(start_ref, bt_ref, q_ref, k_ref, v_ref, o_ref, acc_ref,
     def _finish():
         for i in range(heads * G):
             l_safe = jnp.maximum(l_ref[i][:, 0:1], 1e-30)
-            o_ref[0, :, i * hd:(i + 1) * hd] = \
+            o_ref[0, :, i * dv:(i + 1) * dv] = \
                 (acc_ref[i] / l_safe).astype(o_ref.dtype)
 
 

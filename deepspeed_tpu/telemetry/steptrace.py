@@ -97,6 +97,12 @@ StepRecord = collections.namedtuple("StepRecord", [
                         # would visit with no window (pos // block + 1)
     "prefill_window_live_blocks",  # the same two for this step's prefill
     "prefill_window_table_blocks",  # chunks; all four 0 for a one-kind pool
+    "latent_walk_blocks",   # a pool of the LATENT kind (MLA): the (slot,
+                        # block) pairs `dstpu_mla_decode`'s walk visits, a
+                        # layer (`decode_live_blocks` of such a pool) ...
+    "latent_chunk_positions",  # ... and the cached positions this step's
+                        # chunks attend, a layer: `start + chunk` a chunk,
+                        # where `dstpu_mla_prefill` runs; both 0 elsewhere
     "fused_chunks",     # of `prefill_chunks`, the chunks that rode the
                         # step's decode call (the scheduler's `mixed_step`:
                         # one device call, every weight read once)
@@ -113,7 +119,7 @@ StepRecord = collections.namedtuple("StepRecord", [
                         # before was unread: queued behind it on the device,
                         # so the chip never waited for this step's host work
 ], defaults=(0, 0, 0, 0, 0, 0, "", 0, (), 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0,
-             0))
+             0, 0, 0))
 
 RequestRecord = collections.namedtuple("RequestRecord", [
     "uid", "t_submit", "t_admit",

@@ -18,6 +18,7 @@ from deepspeed_tpu.ops.pallas.decode_attention import (
 from deepspeed_tpu.ops.pallas.prefill_attention import (
     paged_prefill_attention, paged_prefill_live_blocks)
 from deepspeed_tpu.parallel.moe import routed_experts, topk_routing
+from tests import glm_cases
 from tests.exaone_cases import _arch, _cfg, ref
 
 # ----------------------------------------------------------------------
@@ -36,19 +37,31 @@ def _sparse_layer(seed=0, E=16, D=32, F=16, rows=24):
     return p, normal(rows, D)
 
 
-def test_eight_shares_and_the_shared_expert_once_add_up_to_the_whole_layer():
+# the two families that serve one chip's share of a sigmoid-routed layer: the
+# family's test cases (configuration, reference and its `Arch`)
+_SHARED_FAMILIES = {
+    "exaone_moe": (_cfg, _arch, ref),
+    "glm4_moe_lite": (glm_cases._cfg, glm_cases._arch, glm_cases.ref),
+}
+
+
+@pytest.mark.parametrize("family", sorted(_SHARED_FAMILIES))
+def test_eight_shares_and_the_shared_expert_once_add_up_to_the_whole_layer(
+        family):
     """Guide §4's one test: the routed parts that the eight shares compute
     (`held` = 0-1, 2-3, ... of 16 experts), summed, plus the shared expert
     ONCE, equal the reference's whole sparse layer with every expert."""
+    make_cfg, make_arch, ref = _SHARED_FAMILIES[family]
     p, h = _sparse_layer()
-    cfg = _cfg()
-    arch = _arch(cfg, held=None)
+    cfg = make_cfg()
+    arch = make_arch(cfg, held=None)
     with jax.default_matmul_precision("highest"):
         whole, _ = ref.routed_sum(h, p, arch)
         whole = whole + ref.shared_expert(h, p, arch)
         top_p, top_e = topk_routing(h, p["moe_gate_w"], cfg.top_k, True,
                                     scoring="sigmoid",
-                                    bias=p["moe_gate_bias"], scale=2.5)
+                                    bias=p["moe_gate_bias"],
+                                    scale=cfg.routed_scaling_factor)
         total = jnp.zeros_like(h)
         elsewhere = 0
         for share in range(8):

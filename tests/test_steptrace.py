@@ -1665,3 +1665,95 @@ def test_served_mixed_program_computes_each_in_projection_once(
     assert sorted(made.values()) == sorted(
         unit.count(hybrid.MAMBA) for unit, _ in hybrid.layer_runs(cfg)
         if hybrid.MAMBA in unit), made
+
+
+# ----------------------------------------------------------------------
+# a pool of the LATENT kind (MLA): written in place, projected once (PR 43)
+# ----------------------------------------------------------------------
+
+
+def test_served_latent_mixed_program_writes_in_place_and_projects_once(
+        one_chip, monkeypatch):
+    """GLM-4.7-Flash's mixed program at the SERVED sizes of its configuration
+    file, compiled for the chip: nothing half as large as ONE LAYER's latent
+    leaf is made by an operation that is not an aliased Mosaic call
+    (`dstpu_kv_pool_write`; the walks `dstpu_mla_prefill` and
+    `dstpu_mla_decode` read the leaf where it lies), the temporaries stay
+    under that size, a layer body writes the leaf twice (the chunk's rows,
+    the slots') and walks it twice, and the `[2048, 576]` down-projection and
+    the query's two projections, which have readers on both sides of the
+    chunk's walk, are each computed ONCE a layer body: no rematerialised
+    clone beside its original (PR 42's lesson)."""
+    from deepspeed_tpu.models import glm4_moe_lite as glm
+    from deepspeed_tpu.ops import attention_dispatch
+    from deepspeed_tpu.platform import device
+    mesh_mod.clear_mesh()
+    monkeypatch.setattr(device, "on_tpu", lambda: True)
+    monkeypatch.syspath_prepend(_BENCHMARK)
+    with open(os.path.join(_BENCHMARK, "configs",
+                           "glm-4.7-flash-12l-ep8.json")) as f:
+        served = json.load(f)
+    knobs = served["serving"]
+    cfg = importlib.import_module("drivers.serve_glm4_moe_lite").model_config(
+        served, knobs["max_context"])
+
+    def sds(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip), tree)
+
+    shapes = jax.eval_shape(
+        glm.glm4_moe_lite_init_fn(cfg, dtype=jnp.bfloat16),
+        jax.random.PRNGKey(0))
+    spec = glm.make_glm4_moe_lite_decode_model(cfg, name="served",
+                                               params=shapes)
+    slots, chunk = knobs["max_slots"], knobs["prefill_chunk"]
+    block = knobs["kv_block_size"]
+    pool = sds(jax.eval_shape(lambda: spec.init_paged_pool(
+        knobs["num_kv_blocks"], block, jnp.bfloat16)))
+    ints = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32, sharding=one_chip)
+    tables = lambda b: ints(b, -(-knobs["max_context"] // block))
+    program = jax.jit(spec.mixed_paged_fn, donate_argnums=(7,)).lower(
+        sds(shapes), ints(1, chunk), ints(1), ints(1), tables(1),
+        ints(slots), ints(slots), pool, tables(slots)).compile()
+    text = program.as_text()
+    assert spec.kv_pool_writers == {
+        "mixed": attention_dispatch.KV_POOL_WRITE_KERNEL}
+    assert spec.paged_attn_programs == {
+        "mixed/prefill_chunk": "mla_prefill_kernel",
+        "mixed/paged_decode": "mla_decode_kernel"}
+    # 576 values a token a layer, stored in whole lane tiles
+    assert pool["ckv"].shape == (12, knobs["num_kv_blocks"], 1, block, 640)
+    layer_leaf = pool["ckv"].size // pool["ckv"].shape[0] * 2
+    large = _large_instructions(text, layer_leaf // 2)
+    made = [x for x in large if x[1] not in _NO_NEW_BUFFER]
+    assert made == [], [line.strip()[:160] for line in text.splitlines()
+                        if any(f"%{n} = " in line for n, _ in made)]
+    assert {n.rsplit(".", 1)[0] for n, opcode in large
+            if opcode == "custom-call"} == {"dstpu_kv_pool_write"}
+    assert program.memory_analysis().temp_size_in_bytes < layer_leaf // 2
+    # the dense layer's body and the scanned layers': two writes, two walks
+    calls = collections.Counter(_mosaic_calls(text))
+    assert (calls["dstpu_kv_pool_write"], calls["dstpu_mla_prefill"],
+            calls["dstpu_mla_decode"]) == (4, 2, 2), calls
+    assert "dstpu_kv_pool_gather" not in text
+    rows = chunk + slots
+    products = {"mla/kv_down": f"bf16[1,{rows},576]",
+                "mla/q_proj": f"bf16[1,{rows},20,256]"}
+    made = collections.Counter()
+    for name, lines in _computations(text).items():
+        originals = {_HLO_LINE.match(line).group(1) for line in lines
+                     if _HLO_LINE.match(line)}
+        for line in lines:
+            found = _HLO_LINE.match(line)
+            if not found or found.group(3) != "fusion":
+                continue
+            for scope, product in products.items():
+                if scope in line and found.group(2).startswith(product):
+                    made[name, scope] += 1
+                    # a clone is a second computation only beside its
+                    # original (XLA keeps the name when it moves one)
+                    clone = re.sub(r"\.remat\d*$", "", found.group(1))
+                    assert clone == found.group(1) \
+                        or clone not in originals, line.strip()[:200]
+    assert sorted(made.values()) == [1, 1, 1, 1], made
