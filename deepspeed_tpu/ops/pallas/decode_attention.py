@@ -22,6 +22,16 @@ supported by attending one kv head's group of query heads per grid cell.
 Layout: q [B, H, hd]; k/v cache [B, Hkv, M, hd]; pos [B] (current position,
 inclusive — the new token's k/v must already be scattered at pos).
 
+The online softmax has ONE definition, here: `_online_softmax_update`. Its
+row statistics m, l (and alpha) are lane-replicated [R, 128] from scratch to
+scratch, widened with `_widen` where they meet the scores and the
+accumulator — never narrowed to a one-lane column and broadcast back. Its
+callers: the decode kernels of this file (`_online_softmax_tile`: contiguous,
+paged, int8-paged, and `mla_attention.py`'s `dstpu_mla_decode` through
+`_paged_walk`), the chunk walks (`prefill_attention.py::_prefill_kernel`:
+`dstpu_paged_prefill`, `dstpu_mla_prefill`) and the training forward
+(`flash_attention.py::_fwd_kernel`).
+
 The PAGED kernels (a pool of physical blocks and a table a row) go further:
 their grid is a list of the live (row, block) pairs and nothing else, every
 KV head of a block in one step (`_paged_walk`). Measured alone on a v5e (PR
@@ -47,29 +57,46 @@ NEG_INF = -1e30
 _LANES = 128
 
 
+def _widen(stat, n):
+    """A lane-replicated [..., rows, 128] statistic as [..., rows, n], `n`
+    under or over a lane tile."""
+    return jnp.tile(stat, (1, pl.cdiv(n, _LANES)))[..., :n]
+
+
 def _online_softmax_update(s, v, in_dtype, acc_ref, m_ref, l_ref):
     """Fold one tile of MASKED float32 scores into the online softmax — the
     single definition of that arithmetic for every streaming attention
-    kernel over a KV cache (the decode kernels below through
-    `_online_softmax_tile`, `prefill_attention.py`'s chunk kernel directly).
+    kernel: the decode kernels below through `_online_softmax_tile`,
+    `prefill_attention.py`'s chunk kernel (and through it the latent chunk
+    walk of `mla_attention.py`) directly, and the training forward
+    (`flash_attention.py::_fwd_kernel`) on `.at[rows]` views of its scratch.
 
-    s: [R, n] float32, masked entries at NEG_INF; v: [n, hd] in the compute
-    dtype; scratch acc [R, hd] fp32, m/l [R, _LANES] fp32 (row statistics
-    replicated across one lane tile) carried across the tiles of a row's
-    walk. The probabilities narrow to `in_dtype` (the queries') for the p @ v
-    dot, which accumulates in float32."""
-    m_prev = m_ref[:, 0:1]
-    l_prev = l_ref[:, 0:1]
-    m_cur = jnp.max(s, axis=-1, keepdims=True)
-    m_new = jnp.maximum(m_prev, m_cur)
+    s: [R, n] float32, masked entries at NEG_INF; v: [n, dv] in the compute
+    dtype; scratch acc [R, dv] fp32, m/l [R, _LANES] fp32 carried across the
+    tiles of a row's walk. The row statistics m, l (and alpha) stay
+    LANE-REPLICATED [R, 128] from scratch to scratch: a row's one cross-lane
+    reduction comes back 128 lanes wide and is repeated (`_widen`) across
+    the keys and the accumulator's columns where they need it. Narrowing
+    them to a one-lane column and broadcasting back every tile was half of
+    a tile's time (PERF.md §6, PR 37 and PR 44). The probabilities narrow to
+    `in_dtype` (the queries') for the p @ v dot, which accumulates in
+    float32."""
+    m_prev = m_ref[...]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
     alpha = jnp.exp(m_prev - m_new)
-    p = jnp.exp(s - m_new)
-    l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
-    acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-        p.astype(in_dtype), v, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
-    l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
+    p = jnp.exp(s - _widen(m_new, s.shape[1]))
+    l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+    m_ref[...] = m_new
+    acc_ref[...] = acc_ref[...] * _widen(alpha, acc_ref.shape[-1]) \
+        + jax.lax.dot_general(p.astype(in_dtype), v, (((1,), (0,)), ((), ())),
+                              preferred_element_type=jnp.float32)
+
+
+def _softmax_result(acc_ref, l_ref):
+    """The walk's result from its scratch, acc / l, float32: what a kernel's
+    `_finish` stores. A row nothing was folded into (l = 0) comes out 0."""
+    l_safe = jnp.maximum(l_ref[...], 1e-30)
+    return acc_ref[...] / _widen(l_safe, acc_ref.shape[-1])
 
 
 def _online_softmax_tile(q, k, v, pos, j, acc_ref, m_ref, l_ref, *,
@@ -127,8 +154,7 @@ def _decode_kernel(pos_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref,
 
     @pl.when(j == nm - 1)
     def _finish():
-        l_safe = jnp.maximum(l_ref[:, 0], 1e-30)
-        o_ref[0, 0] = (acc_ref[...] / l_safe[:, None]).astype(o_ref.dtype)
+        o_ref[0, 0] = _softmax_result(acc_ref, l_ref).astype(o_ref.dtype)
 
 
 def decode_attention(q, k, v, pos, sm_scale=None, block_m=None, interpret=None):
@@ -332,8 +358,7 @@ def _paged_walk_kernel(cnt_ref, slot_ref, blk_ref, pos_ref, bt_ref, q_ref,
 
     @pl.when(j == jnp.minimum(pos // block_m, last_block))
     def _finish():
-        l_safe = jnp.maximum(l_ref[:, :, 0:1], 1e-30)
-        o_ref[0] = (acc_ref[...] / l_safe).astype(o_ref.dtype)
+        o_ref[0] = _softmax_result(acc_ref, l_ref).astype(o_ref.dtype)
 
 
 def _paged_walk(load_head, q, leaves, block_tables, pos, work, sm_scale,

@@ -30,7 +30,8 @@ What a tile costs OUTSIDE its matrix products decides the speed (PERF.md
   [rows, 128] from scratch to scratch; the one cross-lane reduction a row
   is widened to 128 lanes once and repeated across the keys where `s - m`
   needs it. Narrowing them to a one-lane column and broadcasting back three
-  times a tile was half of the forward's time.
+  times a tile was half of the forward's time. The update is `decode_
+  attention.py::_online_softmax_update`, the one the serving walks call.
 - The log-sum-exp leaves the forward through a transpose of the
   lane-replicated tile (rows to lanes in one XLU pass).
 - The dk/dv kernel works on TRANSPOSED scores [keys, rows]: lse and delta
@@ -50,12 +51,11 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from deepspeed_tpu.ops.pallas.decode_attention import (NEG_INF, _LANES,
+                                                       _online_softmax_update,
+                                                       _widen)
 from deepspeed_tpu.platform.device import pallas_interpret
 
-NEG_INF = -1e30
-# VPU lane width: m/l scratch rows are replicated across one lane tile so the
-# scratch stays 2D and tile-aligned regardless of block_q
-_LANES = 128
 # a tile is worked in sub-blocks of at most _SUB x _SUB (the size the MXU and
 # the softmax between its two products overlap best at; 256 measured 1.6x
 # slower, the whole 1024 x 1024 tile 1.16x)
@@ -157,11 +157,6 @@ def _hide_future(s, q0, k0, transposed=False):
     return jnp.where(q_pos >= k_pos, s, NEG_INF)
 
 
-def _widen(stat, n):
-    """A lane-replicated [rows, 128] statistic as [rows, n]."""
-    return jnp.tile(stat, (1, pl.cdiv(n, _LANES)))[:, :n]
-
-
 # ----------------------------------------------------------------------
 # forward
 # ----------------------------------------------------------------------
@@ -198,16 +193,8 @@ def _fwd_kernel(qi_ref, ki_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref,
         if causal:
             s = _hide_future(s, qi * block_q + rows.start,
                              ki * block_k + keys.start)
-        m_prev = m_ref[rows, :]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - _widen(m_new, s.shape[1]))
-        l_ref[rows, :] = l_ref[rows, :] * alpha \
-            + jnp.sum(p, axis=-1, keepdims=True)
-        m_ref[rows, :] = m_new
-        acc_ref[rows, :] = acc_ref[rows, :] * _widen(alpha, D) \
-            + jax.lax.dot_general(p.astype(q.dtype), v, _NN,
-                                  preferred_element_type=jnp.float32)
+        _online_softmax_update(s, v, q.dtype, acc_ref.at[rows],
+                               m_ref.at[rows], l_ref.at[rows])
 
     _sub_blocks(causal, qi, ki, block_q, block_k, update)
 

@@ -23,10 +23,12 @@ the row's LIVE logical blocks, read where they lie:
   (or a query tile) whose own frontier is nearer re-serves its frontier
   block, which fetches nothing, and computes nothing there;
 - the G query heads of a KV head share its K/V tile, the softmax statistics
-  and the accumulator are float32 scratch (`_online_softmax_update`, the
-  decode kernels' arithmetic), and the absolute-position causal mask is
-  applied only in the tiles that overlap the chunk's own positions: a tile
-  wholly below `start` needs none;
+  and the accumulator are float32 scratch, the statistics lane-replicated
+  [R, 128] and widened with `_widen` (`decode_attention.py::
+  _online_softmax_update`, the ONE definition the decode kernels and the
+  training forward share: every (head, key tile) update is a call of it),
+  and the absolute-position causal mask is applied only in the tiles that
+  overlap the chunk's own positions: a tile wholly below `start` needs none;
 - q and the result keep the model's `[B, C, H*hd]` layout (a step's q tile
   is the lane-aligned `[tq, heads*G*hd]` slab of its KV heads), so nothing
   is transposed on the way in or out.
@@ -45,6 +47,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from deepspeed_tpu.ops.pallas.decode_attention import (NEG_INF, _LANES,
                                                        _online_softmax_update,
+                                                       _softmax_result,
                                                        window_first_block)
 from deepspeed_tpu.platform.device import pallas_interpret
 
@@ -173,9 +176,8 @@ def _prefill_kernel(start_ref, bt_ref, q_ref, k_ref, v_ref, o_ref, acc_ref,
     @pl.when(j == frontier)
     def _finish():
         for i in range(heads * G):
-            l_safe = jnp.maximum(l_ref[i][:, 0:1], 1e-30)
-            o_ref[0, :, i * dv:(i + 1) * dv] = \
-                (acc_ref[i] / l_safe).astype(o_ref.dtype)
+            o_ref[0, :, i * dv:(i + 1) * dv] = _softmax_result(
+                acc_ref.at[i], l_ref.at[i]).astype(o_ref.dtype)
 
 
 def paged_prefill_live_blocks(start, chunk, block, table_blocks, window=None):

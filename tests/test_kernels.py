@@ -339,3 +339,71 @@ class TestQuant:
         y = dequantize_int8(qg[:8], sg[:8], dtype=jnp.float32, group_size=128)
         err = np.abs(np.asarray(y) - np.asarray(x)).max()
         assert err <= np.asarray(s).max() * 0.51 + 1e-6
+
+
+class TestTheOnlineSoftmaxHasOneForm:
+    """`decode_attention.py::_online_softmax_update` carries the row
+    statistics lane-replicated (PR 44); the column form it replaced is
+    frozen in `tests/softmax_oracle.py`. Same float32 operations on the same
+    values in the same order: every kernel that shares the update gives the
+    column form's BITS."""
+
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                             ids=["float32", "bfloat16"])
+    @pytest.mark.parametrize("case", ["head-64", "bq128-bk256",
+                                      "bq256-bk128", "full"])
+    def test_flash_forward(self, case, dtype, monkeypatch):
+        from deepspeed_tpu.ops.pallas.flash_attention import \
+            flash_attention_with_lse
+        from tests.softmax_oracle import assert_same_bits_as_the_column_form
+        q, k, v, kw, _ = _flash_case(case, 5, dtype)
+        assert_same_bits_as_the_column_form(
+            monkeypatch, lambda *a: flash_attention_with_lse(*a, **kw),
+            q, k, v)
+
+    # (Hkv, G) by head width: the rows a decode tile carries at the served
+    # models (MHA 1, Mistral 4, K-EXAONE 8) and the latent walk's twenty,
+    # which is not a sublane multiple
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                             ids=["float32", "bfloat16"])
+    @pytest.mark.parametrize("hd", [64, 128])
+    @pytest.mark.parametrize("heads", [(2, 1), (2, 4), (1, 8), (1, 20)],
+                             ids=["g1", "g4", "g8", "g20"])
+    @pytest.mark.parametrize("kernel", ["contiguous", "paged", "paged-int8"])
+    def test_decode_walks(self, kernel, heads, hd, dtype, monkeypatch):
+        from deepspeed_tpu.inference.quantization import quantize_kv
+        from deepspeed_tpu.ops.pallas import decode_attention as da
+        from tests.softmax_oracle import assert_same_bits_as_the_column_form
+        Hkv, G = heads
+        block, nb = 128, 3
+        rng = np.random.default_rng(31)
+        pos = jnp.asarray([0, 127, 128, 300], jnp.int32)
+        q = jnp.asarray(rng.normal(size=(4, Hkv * G, hd)), dtype)
+        if kernel == "contiguous":
+            k, v = (jnp.asarray(rng.normal(size=(4, Hkv, nb * block, hd)),
+                                dtype) for _ in range(2))
+            assert_same_bits_as_the_column_form(
+                monkeypatch,
+                lambda *a: da.decode_attention(*a, block_m=block,
+                                               interpret=True), q, k, v, pos)
+            return
+        k, v = (jnp.asarray(rng.normal(size=(1 + 4 * nb, Hkv, block, hd)),
+                            dtype) for _ in range(2))
+        # row 2 is a dead slot: its table is the trash block throughout
+        tables = jnp.asarray([[5, 0, 0], [7, 0, 0], [0, 0, 0], [2, 9, 4]],
+                             jnp.int32)
+        if kernel == "paged":
+            # a window that begins inside the walk's first block, too
+            for window in (None, 200):
+                assert_same_bits_as_the_column_form(
+                    monkeypatch,
+                    lambda *a: da.paged_decode_attention(
+                        *a, interpret=True, window=window),
+                    q, k, v, tables, pos)
+            return
+        kq, ks = quantize_kv(k, 32)
+        vq, vs = quantize_kv(v, 32)
+        assert_same_bits_as_the_column_form(
+            monkeypatch,
+            lambda *a: da.paged_decode_attention_quant(*a, interpret=True),
+            q, kq, vq, ks, vs, tables, pos)

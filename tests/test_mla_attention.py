@@ -129,3 +129,46 @@ def test_bfloat16_walks_stay_in_the_dense_twins_band():
         RANK, SCALE).reshape(2, H, RANK)
     np.testing.assert_allclose(np.asarray(got, np.float32), np.asarray(want),
                                rtol=0.05, atol=0.02)
+
+
+# (width, rank, rope, heads): the file's small entry, and GLM-4.7-Flash's
+# served one — twenty query rows a slot against 640-wide keys whose first
+# 512 columns are the values (scores and accumulator both over a lane tile)
+_COLUMN_FORM_ENTRIES = {"small": (RANK, ROPE, H), "glm": (512, 64, 20)}
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("walk", ["decode", "chunk"])
+@pytest.mark.parametrize("entry", sorted(_COLUMN_FORM_ENTRIES))
+def test_the_latent_walks_give_the_column_forms_bits(entry, walk, dtype,
+                                                     monkeypatch):
+    """The lane-replicated update (PR 44) against the frozen column form:
+    the same bits out of `dstpu_mla_decode` and `dstpu_mla_prefill`."""
+    from tests.softmax_oracle import assert_same_bits_as_the_column_form
+    rank, rope, heads = _COLUMN_FORM_ENTRIES[entry]
+    width = ma.latent_entry_width(rank, rope)
+    rng = np.random.default_rng(23)
+    pool = rng.normal(size=(6, 1, BLOCK, width))
+    pool[..., rank + rope:] = 0
+    pool = jnp.asarray(pool, dtype)
+    if walk == "decode":
+        tables = jnp.asarray([[3, 5, 0], [0, 0, 0], [1, 2, 4]], jnp.int32)
+        pos = jnp.asarray([130, 0, 300], jnp.int32)
+        q = jnp.asarray(rng.normal(size=(3, heads, width)), dtype)
+        got = assert_same_bits_as_the_column_form(
+            monkeypatch,
+            lambda *a: ma.mla_decode_attention(*a, rank, SCALE,
+                                               interpret=True),
+            q, pool, tables, pos)
+        assert got.shape == (3, heads, rank)
+    else:
+        tables = jnp.asarray([[3, 5, 2]], jnp.int32)
+        start = jnp.asarray([200], jnp.int32)
+        q = jnp.asarray(rng.normal(size=(1, 128, heads, width)), dtype)
+        got = assert_same_bits_as_the_column_form(
+            monkeypatch,
+            lambda *a: ma.mla_prefill_attention(*a, rank, SCALE,
+                                                interpret=True),
+            q, pool, tables, start)
+        assert got.shape == (1, 128, heads * rank)

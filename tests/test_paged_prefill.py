@@ -189,3 +189,40 @@ def test_the_scheduler_counts_the_blocks_each_chunk_walks(monkeypatch):
     np.testing.assert_array_equal(np.asarray(tokens), want)
     assert all(r.prefill_live_blocks == r.prefill_table_blocks == 0
                for r in serving.steptrace.records())
+
+
+# (head width, Hkv, G, window): the widths and group sizes the served models
+# bring the chunk walk (OLMoE G 1, Mistral 4, K-EXAONE 8 and its window
+# layers), and a head narrower than a lane tile
+_COLUMN_FORM_CASES = {
+    "hd64-mha": (64, 2, 1, None),
+    "hd64-gqa8": (64, 1, 8, None),
+    "hd128-mha": (128, 2, 1, None),
+    "hd128-gqa4": (128, 2, 4, None),
+    "hd128-gqa8": (128, 1, 8, None),
+    "hd128-gqa8-window": (128, 1, 8, 128),
+}
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("case", sorted(_COLUMN_FORM_CASES))
+def test_the_chunk_walk_gives_the_column_forms_bits(case, dtype, monkeypatch):
+    """The lane-replicated update (PR 44) against the frozen column form:
+    the same bits out of `dstpu_paged_prefill`, over a walk with a tile
+    under the chunk (no mask), diagonal tiles and — with a window — tiles
+    its first rows no longer see."""
+    from tests.softmax_oracle import assert_same_bits_as_the_column_form
+    hd, Hkv, G, window = _COLUMN_FORM_CASES[case]
+    block = chunk = 128
+    rng = np.random.default_rng(17)
+    k, v = (jnp.asarray(rng.normal(size=(5, Hkv, block, hd)), dtype)
+            for _ in range(2))
+    q = jnp.asarray(rng.normal(size=(2, chunk, Hkv * G, hd)), dtype)
+    tables = jnp.asarray([[3, 1, 4], [2, 4, TRASH_BLOCK]], jnp.int32)
+    start = jnp.asarray([block + 64, 32], jnp.int32)
+    got = assert_same_bits_as_the_column_form(
+        monkeypatch,
+        lambda *a: paged_prefill_attention(*a, interpret=True, window=window),
+        q, k, v, tables, start)
+    assert got.shape == (2, chunk, Hkv * G * hd)

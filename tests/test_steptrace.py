@@ -855,6 +855,103 @@ def test_kernels_keep_their_names_in_the_compiled_program(one_chip):
     assert kinds == {"dstpu_flash_fwd", "dstpu_flash_dq", "dstpu_flash_dkv"}
 
 
+def _mosaic_bodies(fn, *args):
+    """The Mosaic kernels of `fn` lowered for the described chip, as MLIR
+    text without locations (the custom call carries each as base64
+    bytecode), in program order."""
+    import base64
+
+    from jax._src.interpreters import mlir as jax_mlir
+    from jax._src.lib.mlir import ir
+    text = jax.jit(fn).lower(*args).as_text()
+    context = jax_mlir.make_ir_context()
+    context.allow_unregistered_dialects = True
+    with context:
+        return [ir.Module.parse(base64.b64decode(body)).operation.get_asm(
+                    enable_debug_info=False)
+                for body in re.findall(
+                    r'\\22body\\22: \\22([A-Za-z0-9+/=]+)\\22', text)]
+
+
+_COLUMN = re.compile(r"vector<(?:\d+x)*1xf32>")
+_DEFINES = re.compile(r'^\s*(%\w+) = "stable_mosaic\.([\w.]+)"\((%\w+)?')
+_STORES = re.compile(r'^\s*"stable_mosaic\.tpu\.vector_store"\((%\w+), (%\w+)')
+
+
+@pytest.mark.parametrize("walk", ["dstpu_paged_prefill", "dstpu_mla_prefill",
+                                  "dstpu_paged_decode", "dstpu_mla_decode"])
+def test_the_walks_carry_their_row_statistics_lane_replicated(one_chip, walk):
+    """The online softmax's m, l and alpha stay `[R, 128]` from scratch to
+    scratch (PR 44; `decode_attention.py::_online_softmax_update`), in the
+    chunk walks at Mistral's and GLM-4.7-Flash's served shapes and in the
+    decode walks: the kernel lowered for the described v5e reads NO one-lane
+    column of anything (the column form began with `m_ref[:, 0:1]`), a
+    `[R, 1]` value exists only as a row reduction's result on its way to one
+    lane tile, and nothing stored is a vector's broadcast — the form that
+    cost the chunk walk 44% of its bundles (85303 cross-lane operations
+    against 34416) cannot come back unseen."""
+    from deepspeed_tpu.ops.pallas import (decode_attention, mla_attention,
+                                          prefill_attention)
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    bf16, i32 = jnp.bfloat16, jnp.int32
+    pool = sds((40, 8, 512, 128), bf16)
+    latent = sds((40, 1, 512, 640), bf16)
+    fn, args = {
+        "dstpu_paged_prefill": (
+            prefill_attention.paged_prefill_attention,
+            (sds((1, 512, 32, 128), bf16), pool, pool, sds((1, 32), i32),
+             sds((1,), i32))),
+        "dstpu_mla_prefill": (
+            functools.partial(mla_attention.mla_prefill_attention, rank=512,
+                              sm_scale=0.07),
+            (sds((1, 1024, 20, 640), bf16), latent, sds((1, 32), i32),
+             sds((1,), i32))),
+        "dstpu_paged_decode": (
+            decode_attention.paged_decode_attention,
+            (sds((32, 32, 128), bf16), pool, pool, sds((32, 8), i32),
+             sds((32,), i32))),
+        "dstpu_mla_decode": (
+            functools.partial(mla_attention.mla_decode_attention, rank=512,
+                              sm_scale=0.07),
+            (sds((128, 20, 640), bf16), latent, sds((128, 16), i32),
+             sds((128,), i32))),
+    }[walk]
+    (body,) = _mosaic_bodies(functools.partial(fn, interpret=False), *args)
+
+    defined_by, reductions, stores = {}, 0, 0
+    for line in body.splitlines():
+        stored = _STORES.match(line)
+        if stored:
+            value = stored.group(1)
+            while defined_by[value][0] == "vector.shape_cast":
+                value = defined_by[value][1]
+            # (a scalar's broadcast is `_init`'s zeros and NEG_INF)
+            assert not (defined_by[value][0] == "vector.broadcast"
+                        and "(vector<" in defined_by[value][2]), line
+            stores += 1
+            continue
+        defines = _DEFINES.match(line)
+        if not defines:
+            continue
+        name, op, operand = defines.groups()
+        defined_by[name] = (op, operand, line)
+        if not _COLUMN.search(line):
+            continue
+        # a [R, 1] float: a reduction's result, or that widened to 128 lanes
+        if op == "vector.shape_cast":
+            assert defined_by[operand][0] == "vector.multi_reduction", line
+            reductions += 1
+        else:
+            assert op == "vector.broadcast" and "x128xf32>" in \
+                line.rsplit("->", 1)[1], line
+    # two reductions an update (the row maximum and the row sum), and the
+    # statistics are stored
+    assert reductions >= 2 and reductions % 2 == 0 and stores >= 3
+
+
 def test_the_state_update_with_one_group_and_the_gated_widths_compile(
         one_chip):
     """What the Granite 4.0-H cut brings the kernels (PR 41), at its served
