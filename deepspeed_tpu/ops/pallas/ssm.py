@@ -12,14 +12,22 @@ flat, row 0 of a layer its trash row, CARRIED through the layer loop and
 touched only by the calls here — the rule of `ops/pallas/kv_pool.py`: an XLA
 gather or scatter on a carried buffer copies it whole.
 
-- `ssm_update` (`dstpu_ssm_update`): a decode token of every row. A grid step
-  owns one row's whole state `[H, P, N]` (4 MiB at 128 x 64 x 128), reads it,
-  applies the recurrence and writes it back where it lies
-  (`input_output_aliases`): ONE read and ONE write of the state a token a
-  layer; its roofline is HBM bandwidth (`benchmark/roofline_ssm.py`).
-  The small per-row operands arrive with P on the sublanes and the heads on
-  the lanes (`[B, P, H]`), so a head's column broadcasts along the lanes —
-  the one shuffle Mosaic is asked for.
+- `ssm_update` (`dstpu_ssm_update`): a decode token of every row: ONE read
+  and ONE write of the state a token a layer, where it lies
+  (`input_output_aliases`); its roofline is HBM bandwidth
+  (`benchmark/roofline_ssm.py`). What the chip taught (PERF.md, PR 45) is the
+  kernel's shape. (1) A 4 MiB row read and written with both copies in
+  flight TOGETHER takes 12.8-13.3 us, one after the other 5.6 + 6.4: the
+  state stays in HBM (`pl.ANY`) and the kernel copies it itself, a grid
+  step's rows (`_rows_per_step`: two at 128 x 64 x 128) read in one burst
+  and written in another, never both at once — the step's arithmetic, in
+  two halves, hides behind one burst each. (2) A head's `dt x` column
+  broadcast along N and its `y = S C` summed along N both cross lanes;
+  taking turns a head they stall each other (15.7 us a row against 4.2):
+  every broadcast of a row first, then every sum, in loops over blocks of
+  sixteen heads (`_heads_per_block`). The small per-row operands arrive
+  with P on the sublanes and the heads on the lanes (`[B, P, H]`), a head's
+  decay as a scalar (SMEM).
 - `state_read` / `state_write` (`dstpu_ssm_state_read|write`): a few rows
   copied out of, or into, a carried buffer by index — what a prefill chunk
   does with its slot's state and what both groups do with the convolution's
@@ -44,9 +52,15 @@ from jax.experimental.pallas import tpu as pltpu
 from deepspeed_tpu.platform.device import pallas_interpret
 
 KERNEL_NAME = "dstpu_ssm_update"
-# a row's state in and out, double-buffered: 4 x 4 MiB at the published
-# widths, past Mosaic's default scoped limit
+# `state_read` / `state_write`: a row in and out, double-buffered, 4 x 4 MiB
+# at the published widths, past Mosaic's default scoped limit
 _VMEM_LIMIT = 48 * 1024 * 1024
+# `ssm_update`: a step's rows (`_BURST_BYTES` of state at most) twice in VMEM,
+# and `_VMEM_SMALL` for the small operands' blocks and Mosaic's own scratch;
+# a loop iteration unrolls `_BLOCK_HEADS` heads
+_BURST_BYTES = 8 * 1024 * 1024
+_BLOCK_HEADS = 16
+_VMEM_SMALL = 8 * 1024 * 1024
 
 
 def state_in_place_supported(state) -> bool:
@@ -91,23 +105,127 @@ def ssm_update_reference(state, rows, a, dtx, B, C):
     return y, state.at[rows].set(new.astype(state.dtype))
 
 
-def _update_kernel(rows_ref, a_ref, dtx_ref, b_ref, c_ref, s_ref,
-                   y_ref, out_ref, *, heads, groups):
-    del rows_ref
+def _rows_per_step(b, row_bytes):
+    """Rows a grid step owns: the most that divide b within `_BURST_BYTES`
+    of state."""
+    most = max(1, _BURST_BYTES // row_bytes)
+    return max(k for k in range(1, min(b, most) + 1) if b % k == 0)
+
+
+def _heads_per_block(heads, groups):
+    """Heads a loop iteration unrolls: `_BLOCK_HEADS` where the heads are
+    whole lane tiles (a block's `dt x` columns come to the first lanes by one
+    dynamic rotate) and a block is whole groups or a part of one; else all."""
     per = heads // groups
-    P = s_ref.shape[2]
-    a = a_ref[0]                            # [P, H]: a head a lane
-    dtx = dtx_ref[0]
-    lane = jax.lax.broadcasted_iota(jnp.int32, (P, heads), 1)
-    y = jnp.zeros((P, heads), jnp.float32)
-    for h in range(heads):
-        g = h // per
-        new = a[:, h:h + 1] * s_ref[0, h] \
-            + dtx[:, h:h + 1] * b_ref[0, g:g + 1, :]
-        out_ref[0, h] = new
-        col = jnp.sum(new * c_ref[0, g:g + 1, :], axis=-1, keepdims=True)
-        y = jnp.where(lane == h, col, y)
-    y_ref[0] = y
+    hb = _BLOCK_HEADS
+    if heads % 128 or heads % hb or (hb % per and per % hb):
+        return heads
+    return hb
+
+
+def _update_kernel(rows_ref, a_ref, dtx_ref, b_ref, c_ref, s_hbm,
+                   y_ref, out_hbm, buf, read_sem, write_sem, *, groups):
+    step, steps = pl.program_id(0), pl.num_programs(0)
+    K, H, P = buf.shape[1:4]
+    per = H // groups
+    hb = _heads_per_block(H, groups)
+    blocks = H // hb
+    slot = step % 2
+    lane = jax.lax.broadcasted_iota(jnp.int32, (P, H), 1)
+
+    def reads(at, slot):
+        """The copies of step `at`'s rows into half `slot` of the buffer."""
+        return [pltpu.make_async_copy(s_hbm.at[rows_ref[at * K + r]],
+                                      buf.at[slot, r], read_sem.at[slot])
+                for r in range(K)]
+
+    def writes(at, slot):
+        return [pltpu.make_async_copy(buf.at[slot, r],
+                                      out_hbm.at[rows_ref[at * K + r]],
+                                      write_sem.at[slot])
+                for r in range(K)]
+
+    def heads_of(j):
+        """(first head, group of its head hh) of block j."""
+        h0 = j * hb
+        if hb >= per:
+            return h0, lambda hh: h0 // per + hh // per
+        return h0, lambda hh: h0 // per
+
+    def update(r, lo, hi):
+        """Blocks lo..hi of the step's row r, where they lie: EVERY lane
+        broadcast (a head's `dt x` column along N), then EVERY lane sum
+        (`y`). Taking turns a head, the two kinds stall each other in the
+        cross-lane units: 15.7 us a row against 4.2 (PERF.md, PR 45)."""
+        tile = buf.at[slot, r]
+
+        def spread(j, _):
+            h0, group = heads_of(j)
+            dtx = dtx_ref[r]
+            if blocks > 1:              # the block's columns to lanes 0..hb-1
+                dtx = pltpu.roll(dtx, (H - h0) % H, 1)
+            for hh in range(hb):
+                tile[h0 + hh] = a_ref[r, 0, h0 + hh] * tile[h0 + hh] \
+                    + dtx[:, hh:hh + 1] * b_ref[r, pl.ds(group(hh), 1), :]
+            return 0
+
+        def gather(j, y):
+            h0, group = heads_of(j)
+            for hh in range(hb):
+                col = jnp.sum(
+                    tile[h0 + hh] * c_ref[r, pl.ds(group(hh), 1), :],
+                    axis=-1, keepdims=True)
+                y = jnp.where(lane == h0 + hh, col, y)
+            return y
+
+        jax.lax.fori_loop(lo, hi, spread, 0)
+        y_ref[r] = jax.lax.fori_loop(
+            lo, hi, gather,
+            y_ref[r] if lo else jnp.zeros((P, H), jnp.float32))
+
+    def half(k):
+        """The step's work in two halves (k = 0, 1), one for each stream to
+        hide behind: its rows' halves, or the halves of its one row's
+        blocks."""
+        if K > 1:
+            lo, hi = (0, K // 2, K)[k:k + 2]
+            jax.lax.fori_loop(lo, hi, lambda r, _: update(r, 0, blocks), None)
+        else:
+            update(0, *(0, blocks // 2, blocks)[k:k + 2])
+
+    @pl.when(step == 0)
+    def _():
+        for copy in reads(0, 0):
+            copy.start()
+
+    for copy in reads(step, slot):
+        copy.wait()
+
+    @pl.when(step > 0)
+    def _():
+        for copy in writes(step - 1, 1 - slot):
+            copy.start()
+
+    half(0)
+
+    @pl.when(step > 0)
+    def _():
+        for copy in writes(step - 1, 1 - slot):
+            copy.wait()
+
+    @pl.when(step + 1 < steps)
+    def _():
+        for copy in reads(step + 1, 1 - slot):
+            copy.start()
+
+    half(1)
+
+    @pl.when(step + 1 == steps)
+    def _():
+        for copy in writes(step, slot):
+            copy.start()
+        for copy in writes(step, slot):
+            copy.wait()
 
 
 def ssm_update(state, rows, a, dtx, B, C, interpret=None):
@@ -126,29 +244,36 @@ def ssm_update(state, rows, a, dtx, B, C, interpret=None):
         return ssm_update_reference(state, rows, a, dtx, B, C)
     M, H, P, N = state.shape
     b, G = B.shape[:2]
-    # P on the sublanes, a head a lane: a head's column broadcasts along N
-    a_t = jnp.broadcast_to(a[:, None, :], (b, P, H))
+    row_bytes = H * P * N * state.dtype.itemsize
+    K = _rows_per_step(b, row_bytes)
+    # P on the sublanes, a head a lane: a head's column broadcasts along N;
+    # a head's decay is a scalar
     dtx_t = jnp.swapaxes(dtx, 1, 2)
-    small = pl.BlockSpec((1, P, H), lambda i, rows_ref: (i, 0, 0))
-    group = pl.BlockSpec((1, G, N), lambda i, rows_ref: (i, 0, 0))
-    whole = pl.BlockSpec((1, H, P, N), lambda i, rows_ref: (rows_ref[i], 0,
-                                                            0, 0))
+    small = pl.BlockSpec((K, P, H), lambda i, rows_ref: (i, 0, 0))
+    group = pl.BlockSpec((K, G, N), lambda i, rows_ref: (i, 0, 0))
+    decay = pl.BlockSpec((K, 1, H), lambda i, rows_ref: (i, 0, 0),
+                         memory_space=pltpu.SMEM)
+    in_hbm = pl.BlockSpec(memory_space=pl.ANY)
     y, state = pl.pallas_call(
-        functools.partial(_update_kernel, heads=H, groups=G),
+        functools.partial(_update_kernel, groups=G),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1, grid=(b,),
-            in_specs=[small, small, group, group, whole],
-            out_specs=[small, whole]),
+            num_scalar_prefetch=1, grid=(b // K,),
+            in_specs=[decay, small, group, group, in_hbm],
+            out_specs=[small, in_hbm],
+            scratch_shapes=[pltpu.VMEM((2, K, H, P, N), state.dtype),
+                            pltpu.SemaphoreType.DMA((2,)),
+                            pltpu.SemaphoreType.DMA((2,))]),
         out_shape=[jax.ShapeDtypeStruct((b, P, H), jnp.float32),
                    jax.ShapeDtypeStruct(state.shape, state.dtype)],
         # operands: rows, a, dtx, B, C, state
         input_output_aliases={5: 1},
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
-            vmem_limit_bytes=_VMEM_LIMIT),
+            vmem_limit_bytes=2 * K * row_bytes + _VMEM_SMALL),
         interpret=interpret,
         name=KERNEL_NAME,
-    )(rows, a_t, dtx_t, B.astype(jnp.float32), C.astype(jnp.float32), state)
+    )(rows, a[:, None, :], dtx_t, B.astype(jnp.float32),
+      C.astype(jnp.float32), state)
     return jnp.swapaxes(y, 1, 2), state
 
 

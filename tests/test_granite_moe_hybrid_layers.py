@@ -15,6 +15,7 @@ from deepspeed_tpu.models import hybrid
 from deepspeed_tpu.models.gpt import _attn_half
 from deepspeed_tpu.ops.pallas import moe_gmm, ssm
 from tests.granite_cases import _arch, _cfg, _params, ref
+from tests.nemotron_cases import assert_update_kernel_is_the_jnp_update
 
 PERIOD = ("mamba",) * 5 + ("attention",) + ("mamba",) * 4
 
@@ -138,21 +139,19 @@ def test_four_shares_and_the_shared_expert_once_add_up_to_the_whole_layer():
 # ----------------------------------------------------------------------
 
 
-def test_ssm_update_kernel_with_one_group_is_the_jnp_update():
-    """G = 1: a `(1, 1, N)` block of B and C that all H heads read."""
-    H, P, N, M, b = 16, 8, 128, 7, 4
-    k = jax.random.split(jax.random.PRNGKey(1), 5)
-    state = jax.random.normal(k[0], (M, H, P, N))
-    rows = jnp.array([3, 1, 6, 2], jnp.int32)
-    a = jax.random.uniform(k[1], (b, H))
-    dtx = jax.random.normal(k[2], (b, H, P))
-    B, C = (jax.random.normal(key, (b, 1, N)) for key in k[3:])
-    want_y, want_s = ssm.ssm_update_reference(state, rows, a, dtx, B, C)
-    got_y, got_s = ssm.ssm_update(state, rows, a, dtx, B, C, interpret=True)
-    np.testing.assert_allclose(got_y, want_y, rtol=1e-5, atol=1e-5)
-    np.testing.assert_allclose(got_s, want_s, rtol=1e-6, atol=1e-6)
-    np.testing.assert_array_equal(got_s[jnp.array([0, 4, 5])],
-                                  state[jnp.array([0, 4, 5])])
+@pytest.mark.parametrize("rows, rows_a_step, H", [
+    ([3, 1], None, 16), ([3, 1, 6, 2], 1, 16), ([3, 1, 6, 2], 2, 16),
+    ([0, 5, 0, 0, 2, 8], 2, 16), ([4, 0, 0, 7, 0], 1, 16),
+    ([6, 0, 3], 1, 128),
+], ids=["one-step", "a-row-a-step", "two-rows-a-step", "trash-2-a-step",
+        "trash-1-a-step", "eight-blocks-of-heads"])
+def test_ssm_update_kernel_with_one_group_is_the_jnp_update(
+        monkeypatch, rows, rows_a_step, H):
+    """G = 1: a `(., 1, N)` block of B and C that all H heads read — the
+    group spans both halves of a row's heads, every block of them, and
+    every row of a step."""
+    assert_update_kernel_is_the_jnp_update(monkeypatch, H, 8, 128, 1, rows,
+                                           rows_a_step)
 
 
 @pytest.mark.parametrize("T, chunk", [(32, 8), (37, 16)],

@@ -17,7 +17,8 @@ from deepspeed_tpu.models import nemotron_h as nh
 from deepspeed_tpu.models.layer_pattern import repeated_runs
 from deepspeed_tpu.ops.pallas import ssm
 from deepspeed_tpu.parallel.moe import relu2, routed_experts, topk_routing
-from tests.nemotron_cases import _arch, _cfg, _params, ref
+from tests.nemotron_cases import (_arch, _cfg, _params,
+                                  assert_update_kernel_is_the_jnp_update, ref)
 
 PUBLISHED = ("MEMEMEM*EMEMEMEM*EMEMEMEM*EMEMEMEMEM*EMEMEMEMEM*EMEMEMEMEM*"
              "EMEMEMEMEM*EMEMEMEM*EMEMEMEME")
@@ -230,22 +231,53 @@ def test_two_chunks_hand_the_state_and_the_convolutions_tail_forward():
 # ----------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("H, P, N, G", [(8, 16, 128, 2), (16, 8, 128, 16)])
-def test_ssm_update_kernel_is_the_jnp_update(H, P, N, G):
-    k = jax.random.split(jax.random.PRNGKey(H), 5)
-    M, b = 7, 4
-    state = jax.random.normal(k[0], (M, H, P, N))
-    rows = jnp.array([3, 1, 6, 2], jnp.int32)
-    a = jax.random.uniform(k[1], (b, H))
-    dtx = jax.random.normal(k[2], (b, H, P))
-    B, C = (jax.random.normal(key, (b, G, N)) for key in k[3:])
-    want_y, want_s = ssm.ssm_update_reference(state, rows, a, dtx, B, C)
-    got_y, got_s = ssm.ssm_update(state, rows, a, dtx, B, C, interpret=True)
-    np.testing.assert_allclose(got_y, want_y, rtol=1e-5, atol=1e-5)
-    np.testing.assert_allclose(got_s, want_s, rtol=1e-6, atol=1e-6)
-    # rows nobody named are untouched
-    np.testing.assert_array_equal(got_s[jnp.array([0, 4, 5])],
-                                  state[jnp.array([0, 4, 5])])
+@pytest.mark.parametrize("H, P, N, G, rows, rows_a_step", [
+    (8, 16, 128, 2, [3, 1, 6, 2], None),
+    (16, 8, 128, 16, [3, 1, 6, 2], 2),
+    # a row a step, its heads in two halves: a half spans four groups
+    (16, 8, 128, 8, [3, 1, 6, 2], 1),
+    # ... and a half is one group's heads
+    (16, 8, 128, 2, [5, 8, 2], 1),
+    # two rows a step over three steps; dead slots share the trash row, next
+    # to each other (across a step's edge, and inside a step) and apart
+    (16, 8, 128, 4, [4, 0, 0, 2, 6, 0], 2),
+    (8, 16, 128, 2, [0, 0, 3, 5, 0, 1, 7, 0], 2),
+    # the same with a row a step, and with every row in ONE step
+    (8, 16, 128, 4, [4, 0, 0, 2, 6, 0], 1),
+    (8, 16, 128, 2, [0, 7, 0], None),
+    # whole lane tiles of heads: a loop over blocks of sixteen, a block one
+    # group (the served G = 8), four groups, or an eighth of one
+    (128, 8, 128, 8, [3, 0, 1, 0], 2),
+    (128, 8, 128, 32, [2, 5, 1], 1),
+    (128, 8, 128, 1, [4, 0, 0, 2], 2),
+], ids=["2-groups", "a-group-a-head", "half-spans-groups", "half-is-a-group",
+        "trash-2-a-step", "trash-inside-a-step", "trash-1-a-step",
+        "trash-one-step", "block-is-a-group", "block-spans-groups",
+        "group-spans-blocks"])
+def test_ssm_update_kernel_is_the_jnp_update(monkeypatch, H, P, N, G, rows,
+                                             rows_a_step):
+    assert_update_kernel_is_the_jnp_update(monkeypatch, H, P, N, G, rows,
+                                           rows_a_step)
+
+
+def test_a_steps_rows_and_a_loops_heads_come_from_the_shapes_the_call_sees():
+    """Two rows of the served face (4 MiB a row) a step, a row where the
+    count is odd, never more rows than the call has; sixteen heads a loop
+    iteration where the heads are whole lane tiles and a block is whole
+    groups or a part of one, else every head unrolled."""
+    served = 128 * 64 * 128 * 4
+    assert ssm._rows_per_step(128, served) == 2
+    assert ssm._rows_per_step(127, served) == 1
+    assert ssm._rows_per_step(128, served // 4) == 8
+    assert ssm._rows_per_step(6, served // 16) == 6
+    assert ssm._rows_per_step(9, served) == 1
+    assert ssm._rows_per_step(1, 2 * served) == 1
+    assert [ssm._heads_per_block(128, G) for G in (1, 8, 32, 128)] == [16] * 4
+    assert ssm._heads_per_block(256, 2) == 16
+    assert ssm._heads_per_block(128, 4) == 16       # half a group a block
+    assert ssm._heads_per_block(96, 2) == 96        # not whole lane tiles
+    assert ssm._heads_per_block(384, 128) == 384    # a group of three heads
+    assert ssm._heads_per_block(16, 2) == 16
 
 
 def test_ssm_update_is_one_step_of_the_sequential_scan():

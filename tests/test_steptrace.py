@@ -989,6 +989,44 @@ def test_the_state_update_with_one_group_and_the_gated_widths_compile(
             assert len(lines) == 1 and "%dstpu_moe_gmm" in lines[0]
 
 
+@pytest.mark.parametrize("G", [1, 8], ids=["granite", "nemotron"])
+def test_the_state_update_is_one_aliased_call_with_two_rows_of_vmem(
+        one_chip, G):
+    """`ssm_update` at the served face (PR 45), one group of B and C and
+    eight: ONE `dstpu_ssm_update` call, result types `(f32[b, P, H],
+    f32[rows, H, P, N])` — what the trace readers (`ssm_update_roofline.
+    hybrid`) and `test_nemotron_h.py`'s breakdown match — whose state is the
+    call's aliased result and never a block of the pipeline: the kernel
+    copies it itself, a step's two rows read in one burst and written in
+    another, so the scoped VMEM it uses is those two rows twice (16 MiB) and
+    the small operands' blocks."""
+    from deepspeed_tpu.ops.pallas import ssm
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    rows, b, H, P, N = 9 * 129, 128, 128, 64, 128
+    lines = _kernel_lines(
+        functools.partial(ssm.ssm_update, interpret=False),
+        sds((rows, H, P, N), jnp.float32), sds((b,), jnp.int32),
+        sds((b, H), jnp.float32), sds((b, H, P), jnp.float32),
+        sds((b, G, N), jnp.bfloat16), sds((b, G, N), jnp.bfloat16))
+    assert len(lines) == 1, lines
+    call = re.sub(r"\{[^{}]*\}", "", lines[0].split(" custom-call(")[0])
+    assert call == (f"%dstpu_ssm_update.1 = (f32[{b},{P},{H}], "
+                    f"f32[{rows},{H},{P},{N}])"), call
+    # operands: rows, a, dtx, B, C, state
+    assert "output_to_operand_aliasing={{1}: (5, {})}" in lines[0]
+    row = H * P * N * 4
+    assert ssm._rows_per_step(b, row) == 2
+    limit, used = (int(re.search(
+        rf'"{key}":\[\{{"memory_space":"1","offset":"0","size":"(\d+)"',
+        lines[0]).group(1))
+        for key in ("scoped_memory_configs", "used_scoped_memory_configs"))
+    assert limit == 2 * 2 * row + ssm._VMEM_SMALL
+    assert 2 * 2 * row < used <= limit
+
+
 def test_training_step_holds_the_three_flash_kernels_by_result_signature(
         one_chip, monkeypatch):
     """`flash_roofline.train` tells the three flash kernels apart by the
