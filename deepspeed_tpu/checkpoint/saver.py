@@ -336,8 +336,8 @@ def _emit_ckpt_events(engine, events):
             logger.warning(f"checkpoint telemetry events not recorded: {e}")
     mon = getattr(engine, "monitor", None)
     try:
-        from deepspeed_tpu.monitor.monitor import write_recovery_events
-        write_recovery_events(mon, events)
+        from deepspeed_tpu.monitor.monitor import write_events_safe
+        write_events_safe(mon, events)
     except Exception as e:
         logger.warning(f"checkpoint monitor events not written: {e}")
 

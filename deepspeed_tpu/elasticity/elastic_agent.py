@@ -172,11 +172,11 @@ class ElasticAgent:
         return ok
 
     def _emit_restart_events(self):
-        from deepspeed_tpu.monitor.monitor import write_recovery_events
+        from deepspeed_tpu.monitor.monitor import write_events_safe
         events = [("Recovery/restarts_total", float(self.restarts), self.restarts)]
         events += [(f"Recovery/restarts/{c}", float(n), self.restarts)
                    for c, n in self.restart_causes.items() if n]
-        write_recovery_events(self.spec.monitor, events)
+        write_events_safe(self.spec.monitor, events)
 
     def _pause_then_continue(self, cause):
         """Account the restart against its cause's budget; back off. Returns

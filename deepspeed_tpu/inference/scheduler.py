@@ -4,20 +4,17 @@ The Orca insight, TPU-style: a static-batch `generate()` call stalls its
 whole batch on the slowest sequence and pays one XLA compile per request
 shape. This scheduler instead owns `max_slots` fixed sequence slots and ONE
 paged KV pool (`inference/kv_cache.py`), and drives every request through
-persistent jitted programs whose shapes never change:
+persistent jitted programs whose shapes never change
+(`inference/step_programs.py` builds them; the loop holds `self.programs`):
 
-  * `prefill_step` — [1, chunk] slice of a prompt: chunked prefill writes
-    the chunk's K/V through the slot's block table and interleaves with
-    in-flight decode (`prefill_chunks_per_step` bounds the stall an
-    arriving prompt can impose on the running batch);
+  * `prefill_step` — [1, chunk] slice of a prompt, its K/V written through
+    the slot's block table; chunks interleave with in-flight decode
+    (`prefill_chunks_per_step` bounds the stall an arriving prompt imposes);
   * `decode_step` — one token for ALL slots at once: inactive slots ride
-    along pointed at the trash block, so slot liveness never changes the
-    program shape;
-  * `mixed_step` — the two as ONE call: a step that has a chunk due and
-    slots already decoding sends the chunk's rows and the slots' rows
-    through the model as one tensor, so every weight is read once where the
-    two calls read it twice (`_chunks_riding` is the whole rule; steps that
-    hold one kind of work run the program of that kind).
+    along pointed at the trash block, so liveness never changes the shape;
+  * `mixed_step` — the two as ONE call: a step with a chunk due and slots
+    already decoding sends both groups' rows through the model as one
+    tensor, every weight read once (`_chunks_riding` is the whole rule).
 
 Iteration-level scheduling happens between the calls, on the host, in
 plain Python: admit queued requests into freed slots (admission is a
@@ -30,7 +27,7 @@ together.
 The loop runs ONE CALL DEEP: `step()` k builds and dispatches call k and
 only then blocks on call k-1's tokens, so the device's queue holds the next
 call when the current one ends. Call k's input tokens are call k-1's
-outputs, selected on the device (`_build_step_fns`: `pick`); positions,
+outputs, selected on the device (`step_programs.py`: `pick`); positions,
 tables and chunks never depended on token values. An end the host can count
 (`max_new`) is decided at dispatch — the request gives up its slot and
 blocks then, and takes its last tokens when its call is read (`_Call`, held
@@ -44,26 +41,21 @@ Every such call has a record of its own in the step timeline's call ring
 (`_dispatching`), closed where it is read (`_read_back`).
 
 Compile accounting is first-class: `compile_stats()` reads the jit caches,
-and the serving tests assert <= 1 compile per bucket across a mixed-length
-request trace.
+and the serving tests assert <= 1 compile per bucket across any trace.
 
 Automatic prefix caching (`serving.enable_prefix_caching`,
 `inference/prefix_cache.py`) rides the same machinery: at admission the
 prompt's hash chain is matched against previously written full blocks, hit
 blocks are mapped into the new slot's table with a refcount bump, and the
-chunked-prefill cursor starts at the cached boundary — a shared system
-prompt prefills once per engine, not once per request. Only host-side state
+chunked-prefill cursor starts at the cached boundary. Only host-side state
 changes; the compiled programs and their shapes are untouched.
 
 Speculative decoding (`serving.spec_decode`, `inference/spec_decode.py`)
-swaps the decode step for a draft+verify loop: a drafter (model-free n-gram
-prompt lookup, or a second smaller model) proposes `draft_k` tokens per
-slot, ONE fixed-shape jitted verify call scores them for all slots at once
-(chunked prefill at positions pos..pos+k), and the longest agreeing prefix
-plus a bonus token is emitted — 1..k+1 tokens per model step. Rejection is
-an O(1) rewind of the slot's length cursor: the rejected tokens' k/v sits
-past the cursor where later writes overwrite it, and the block table never
-moves.
+swaps the decode step for a draft+verify loop: a drafter proposes `draft_k`
+tokens per slot, ONE fixed-shape verify call scores them for all slots, and
+the longest agreeing prefix plus a bonus token is emitted — 1..k+1 tokens
+per model step. Rejection is an O(1) rewind of the slot's length cursor
+(`_verify_decode`).
 """
 
 import collections
@@ -76,8 +68,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from deepspeed_tpu.inference import step_programs
 from deepspeed_tpu.inference.audit import PoolAuditor, PoolCorruptionError
-from deepspeed_tpu.inference.engine import sample_logits
 from deepspeed_tpu.inference.kv_cache import (BlockAllocator, TRASH_BLOCK,
                                               blocks_needed, max_written_pos,
                                               ring_blocks, ring_tables,
@@ -231,11 +223,9 @@ class ServingEngine:
     def __init__(self, engine, draft_spec=None, clock=None, **overrides):
         spec = engine.model_spec
         # streamed (offloaded-weights) mode: a LayeredModelSpec served
-        # through a ZeroInferenceEngine — the stacked blocks live in the
-        # host/disk store and ONE jitted per-layer program walks the paged
-        # pool with weights fed by the async staging pool. The resident
-        # mode's whole-model paged contract is replaced by the per-layer
-        # one (layer_paged_fn + embed/final).
+        # through a ZeroInferenceEngine — the per-layer paged contract
+        # (layer_paged_fn + embed/final) replaces the whole-model one
+        # (`step_programs.build_streamed`)
         self.streamed = getattr(spec, "layer_paged_fn", None) is not None \
             and getattr(spec, "prefill_paged_fn", None) is None
         if self.streamed:
@@ -259,23 +249,16 @@ class ServingEngine:
         self.engine = engine
         self.config = engine.config
         scfg = dataclasses.replace(engine.config.serving, **overrides)
-        if isinstance(scfg.spec_decode, dict):
-            # `serving(spec_decode={"drafter": "ngram", ...})` overrides
-            from deepspeed_tpu.inference.config import SpecDecodeConfig
-            scfg = dataclasses.replace(
-                scfg, spec_decode=SpecDecodeConfig.from_dict(scfg.spec_decode))
-        if isinstance(scfg.degradation, dict):
-            # `serving(degradation={"enabled": True, ...})` overrides
-            from deepspeed_tpu.inference.config import DegradationConfig
-            scfg = dataclasses.replace(
-                scfg, degradation=DegradationConfig.from_dict(scfg.degradation))
-        if isinstance(scfg.quantization, dict):
-            # `serving(quantization={"kv_cache_dtype": "int8", ...})` overrides
-            from deepspeed_tpu.inference.config import ServingQuantizationConfig
-            scfg = dataclasses.replace(
-                scfg,
-                quantization=ServingQuantizationConfig.from_dict(
-                    scfg.quantization))
+        # `serving(spec_decode={"drafter": "ngram", ...})`, `degradation=
+        # {"enabled": True, ...}`, `quantization={"kv_cache_dtype": "int8",
+        # ...}`: an override given as a dict
+        from deepspeed_tpu.inference import config as icfg
+        for block, cls in (("spec_decode", icfg.SpecDecodeConfig),
+                           ("degradation", icfg.DegradationConfig),
+                           ("quantization", icfg.ServingQuantizationConfig)):
+            if isinstance(getattr(scfg, block), dict):
+                scfg = dataclasses.replace(
+                    scfg, **{block: cls.from_dict(getattr(scfg, block))})
         self.serving_config = scfg
 
         # quantized serving (inference/quantization.py). Weight-only quant
@@ -338,10 +321,9 @@ class ServingEngine:
         # (and its window) when a drafter is configured
         self.spec_on = str(scfg.spec_decode.drafter or "off") != "off"
         if self.streamed:
-            # streamed-mode envelope: every decode token already walks the
-            # host link once (the cost model of the tier) — a K-step jitted
-            # window or a verify chunk cannot host a per-layer Python walk,
-            # so both are refused rather than silently degraded
+            # streamed-mode envelope: a K-step jitted window or a verify
+            # chunk cannot host a per-layer Python walk — both are refused
+            # rather than silently degraded
             if self.spec_on:
                 raise ValueError(
                     "speculative decoding is a resident-engine feature: the "
@@ -488,35 +470,31 @@ class ServingEngine:
 
         # place the pool with the engine mesh's (replicated) NamedSharding up
         # front: the step programs RETURN pools with exactly this sharding,
-        # so a plain uncommitted jnp.zeros pool would give the very first
-        # call of each program a different arg signature than every later
-        # call — one phantom extra compile, which the serving compile-count
-        # guarantee (and its test) would flag
+        # so an uncommitted pool would give the first call of each program a
+        # different signature than every later call — a phantom extra compile
         from jax.sharding import NamedSharding, PartitionSpec
         if self.kv_quant:
             # int8 pool: payload + per-group scale leaves. The 4-arg call is
             # part of the quantized paged contract — a 3-arg legacy spec
             # raises TypeError right here, and a spec that accepts the group
             # arg but returns a scale-less pool is caught just below; both
-            # get the same pointer at the contract instead of a bare
-            # arity/shape error
+            # get the same pointer at the contract
+            contract = ("it does not implement the quantized-pool contract "
+                        "(init_paged_kv_pool in models/gpt.py is the "
+                        "reference)")
             try:
                 pool = spec.init_paged_pool(num_blocks, bs, jnp.int8,
                                             self.kv_group_size)
             except TypeError as e:
                 raise ValueError(
                     f"model spec '{spec.name}' init_paged_pool does not "
-                    f"accept the 4-arg quantized form "
-                    f"(num_blocks, block_size, dtype, kv_group_size) — it "
-                    f"does not implement the quantized-pool contract "
-                    f"(init_paged_kv_pool in models/gpt.py is the "
-                    f"reference): {e}") from e
+                    f"accept the 4-arg quantized form (num_blocks, "
+                    f"block_size, dtype, kv_group_size) — {contract}: {e}"
+                ) from e
             if not (isinstance(pool, dict) and "k_scale" in pool):
                 raise ValueError(
                     f"model spec '{spec.name}' init_paged_pool returned no "
-                    f"k_scale/v_scale leaves for dtype int8 — it does not "
-                    f"implement the quantized-pool contract "
-                    f"(init_paged_kv_pool in models/gpt.py is the reference)")
+                    f"k_scale/v_scale leaves for dtype int8 — {contract}")
         elif self.state_kind is not None:
             pool = spec.init_paged_pool(num_blocks, bs, jnp.dtype(kvd),
                                         state_rows=1 + self.max_slots)
@@ -557,10 +535,11 @@ class ServingEngine:
             engine.streamer.telemetry = self.telemetry
             engine.store.telemetry = self.telemetry
         # a model's own per-call counters (`DecodeModelSpec.step_counters`,
-        # e.g. the routed experts'): computed on the device by each step
-        # program, read back WITH the tokens (a program whose tokens are
-        # not read — a prompt's earlier chunks — parks its counts until the
-        # next read-back), summed here and put on the step ring
+        # e.g. the routed experts'; `()` where it names none): every step
+        # program returns them beside its tokens, they are read back WITH
+        # the tokens (a program whose tokens are not read — a prompt's
+        # earlier chunks — parks its counts until the next read-back),
+        # summed here and put on the step ring
         self.step_counter_names = tuple(
             getattr(engine.model_spec, "step_counters", None) or ())
         self.step_counter_totals = np.zeros(len(self.step_counter_names),
@@ -576,14 +555,20 @@ class ServingEngine:
         self._pending = None
         self._early: List[CompletedRequest] = []
         self._unread_chunks = []
-        # what a step program's `pick` takes where no call is in flight: the
-        # shapes, dtype and sharding of a mixed call's (first tokens, window
-        # tokens), so that a call has ONE signature whatever came before it
-        self._no_prev = jax.device_put(
-            (np.zeros((self.window,), np.int32),
-             np.zeros((self.max_slots, self.window), np.int32)),
-            self._replicated)
-        self._build_step_fns()
+        # the compiled step programs (`inference/step_programs.py`): built
+        # once, shapes pinned for the engine's lifetime; every one returns
+        # ((tokens...), counts), pool
+        wd = self.telemetry.watchdog
+        if self.streamed:
+            self.programs = step_programs.build_streamed(
+                spec, engine.config, num_layers=engine.store.num_layers,
+                streamer=engine.streamer, watchdog=wd)
+        else:
+            self.programs = step_programs.build_resident(
+                spec, engine.config, engine._fn_transform,
+                window=self.window, max_slots=self.max_slots,
+                chunk=self.chunk, spec_on=self.spec_on, draft_k=self.draft_k,
+                replicated=self._replicated, watchdog=wd)
 
         # drafter AFTER pool/allocator: the draft-model drafter mirrors the
         # pool geometry and shares the block tables (spec_decode.py)
@@ -630,10 +615,6 @@ class ServingEngine:
         if scfg.degradation.enabled:
             from deepspeed_tpu.serving.degradation import PressureController
             self.pressure = PressureController(self, scfg.degradation)
-        self._decode_step_w1 = None   # lazily-built 1-step decode program
-                                      # (degradation fallback; also the spec-
-                                      # decode-disabled path, whose block
-                                      # sizing has no window-rounding tail)
         self._deadlines = False       # any live request carries a deadline
 
         # observability
@@ -659,9 +640,8 @@ class ServingEngine:
         self.handoffs_out = 0               # slots exported to a decode engine
         self.handoffs_in = 0                # slots adopted from a prefill engine
         self.verify_calls = 0               # spec decode: jitted verify steps
-        self.verify_slot_steps = 0          # per-slot verify participations —
-                                            # the denominator of the per-
-                                            # sequence tokens/step multiple
+        self.verify_slot_steps = 0          # per-slot verify participations
+                                            # (tokens/step's denominator)
         self.drafted_tokens = 0             # real (non-padding) proposals scored
         self.accepted_tokens = 0            # drafts that matched the target
         self.spec_emitted_tokens = 0        # tokens emitted by verify steps
@@ -674,314 +654,6 @@ class ServingEngine:
                  f"kv={self.kv_cache_dtype}) table_width={self.nb} "
                  f"prefill_chunk={self.chunk} weights={self.weight_quant}",
                  ranks=[0])
-
-    # ------------------------------------------------------------------
-    # compiled step programs — built once, shapes pinned for the lifetime
-    # ------------------------------------------------------------------
-
-    def _build_step_fns(self):
-        if self.streamed:
-            self._build_streamed_step_fns()
-            return
-        spec = self.engine.model_spec
-        cfg = self.engine.config
-        decode_paged = self.engine._fn_transform(spec.decode_paged_fn)
-        prefill_paged = self.engine._fn_transform(spec.prefill_paged_fn)
-        # a counted model's paged programs return (logits, pool, counts);
-        # its step programs then return (tokens, counts) where the others
-        # return tokens — the uncounted programs are unchanged
-        counted = bool(self.step_counter_names)
-
-        def sample(logits, rng):
-            return sample_logits(logits, rng, greedy=cfg.greedy,
-                                 temperature=cfg.temperature, top_k=cfg.top_k,
-                                 top_p=cfg.top_p)
-
-        def pick(tok):
-            """A call's input token a slot. A plain [S] array is the host's.
-            Else ((first [W], nxt [S, win]) of the call BEFORE, still on the
-            device, src [S], host [S]): per slot the host's value (src 0),
-            the last token that call sampled for it (1), or the first token
-            of the prompt whose last chunk rode that call at window position
-            src - 2 — call k's tokens are call k-1's outputs, and never make
-            the trip to the host and back between the two."""
-            if not isinstance(tok, tuple):
-                return tok
-            (first, nxt), src, host = tok
-            return jnp.where(
-                src == 0, host, jnp.where(
-                    src == 1, nxt[:, -1], first[jnp.maximum(src - 2, 0)]))
-
-        def make_decode_step(window):
-            """Build the decode-WINDOW program: `window` tokens per sync
-            inside one lax.scan (multi-step scheduling). One device call +
-            one host roundtrip amortize over the whole window — the
-            dispatch-latency lever. Returns emitted tokens [S, window]: the
-            window of successors of the input token, with the input's k/v
-            (and each successor's but the last) written into the pool along
-            the way. A builder, not a single closure, because the pressure
-            controller's window-shrink rung needs a second, 1-step variant
-            of the same program built lazily at degradation time."""
-
-            def decode_step(params, tok, pos, pool, tables, rng):
-                tok = pick(tok)
-                if window == 1:  # no scan wrapper: keep the 1-step hot path
-                    logits, pool, *counts = decode_paged(params, tok, pos,
-                                                         pool, tables)
-                    toks = sample(logits, rng)[:, None]
-                    return ((toks, counts[0]) if counted else toks), pool
-
-                def body(carry, _):
-                    tok, pos, pool, rng, acc = carry
-                    rng, sub = jax.random.split(rng)
-                    logits, pool, *counts = decode_paged(params, tok, pos,
-                                                         pool, tables)
-                    nxt = sample(logits, sub)
-                    if counted:
-                        acc = acc + counts[0]
-                    return (nxt, pos + 1, pool, rng, acc), nxt
-
-                acc = jnp.zeros((len(self.step_counter_names),), jnp.int32) \
-                    if counted else None
-                (_, _, pool, _, acc), toks = jax.lax.scan(
-                    body, (tok, pos, pool, rng, acc), None, length=window)
-                toks = jnp.moveaxis(toks, 0, 1)
-                return ((toks, acc) if counted else toks), pool
-
-            return decode_step
-
-        self._make_decode_fn = make_decode_step
-        decode_step = make_decode_step(self.window)
-
-        def prefill_step(params, toks, start, last_idx, pool, table, rng):
-            logits, pool, *counts = prefill_paged(params, toks, start,
-                                                  last_idx, pool, table)
-            tok = sample(logits, rng)
-            return ((tok, counts[0]) if counted else tok), pool
-
-        mixed_paged = getattr(spec, "mixed_paged_fn", None)
-        if mixed_paged is not None:
-            mixed_paged = self.engine._fn_transform(mixed_paged)
-        window = self.window
-
-        def mixed_step(params, chunks, starts, lasts, chunk_tables, n, tok,
-                       pos, pool, tables, rng):
-            """The MIXED program: a decode window whose first `n` tokens each
-            carry a prefill chunk through the model with them
-            (`DecodeModelSpec.mixed_paged_fn`: the chunk's rows and the
-            slots' rows as one tensor, every weight read once), and whose
-            other tokens are plain decode tokens. `chunks` [W, 1, C],
-            `starts` / `lasts` [W, 1] and `chunk_tables` [W, 1, nb] hold a
-            chunk a window position, of which the first `n` (traced, 1..W)
-            are real: two loops with dynamic bounds over one carried pool,
-            so ONE compile serves every count. Returns ((first tokens [W]:
-            what each chunk's last row sampled, window tokens [S, W]) and
-            a counted model's counters), pool."""
-            tok = pick(tok)
-
-            def ride(i, tok, pos, pool, rng):
-                def at(a):
-                    return jax.lax.dynamic_index_in_dim(a, i, 0,
-                                                        keepdims=False)
-                logits, pool, *counts = mixed_paged(
-                    params, at(chunks), at(starts), at(lasts),
-                    jax.tree_util.tree_map(at, chunk_tables), tok, pos, pool,
-                    tables)
-                sampled = sample(logits, rng)
-                return sampled[0], sampled[1:], pool, counts
-
-            if window == 1:     # as `decode_step`: no loop around one token
-                first, nxt, pool, counts = ride(0, tok, pos, pool, rng)
-                toks = (first[None], nxt[:, None])
-                return ((toks, counts[0]) if counted else toks), pool
-
-            def body(i, carry, riding):
-                tok, pos, pool, rng, acc, first, toks = carry
-                rng, sub = jax.random.split(rng)
-                if riding:
-                    head, nxt, pool, counts = ride(i, tok, pos, pool, sub)
-                    first = first.at[i].set(head)
-                else:
-                    logits, pool, *counts = decode_paged(params, tok, pos,
-                                                         pool, tables)
-                    nxt = sample(logits, sub)
-                if counted:
-                    acc = acc + counts[0]
-                return (nxt, pos + 1, pool, rng, acc, first,
-                        toks.at[:, i].set(nxt))
-
-            acc = jnp.zeros((len(self.step_counter_names),), jnp.int32) \
-                if counted else None
-            carry = (tok, pos, pool, rng, acc,
-                     jnp.zeros((window,), jnp.int32),
-                     jnp.zeros((tok.shape[0], window), jnp.int32))
-            carry = jax.lax.fori_loop(
-                0, n, lambda i, c: body(i, c, True), carry)
-            carry = jax.lax.fori_loop(
-                n, window, lambda i, c: body(i, c, False), carry)
-            _, _, pool, _, acc, first, toks = carry
-            toks = (first, toks)
-            return ((toks, acc) if counted else toks), pool
-
-        # the pool is donated: the update is in-place in HBM, the old buffer
-        # is dead the moment the step returns the new one. The compile
-        # watchdog (telemetry/flight_recorder.py) wraps each program when
-        # telemetry is on: the serving promise is ONE compile each for the
-        # engine's lifetime, and any cache miss after that warmup is
-        # recorded (program name, shapes, compile_ms) — with telemetry off,
-        # wrap() returns the jitted function untouched.
-        # The tokens of a decode or mixed call are the next call's input
-        # (`pick`), so their sharding is part of that call's signature: it
-        # is SAID (replicated, what `_no_prev` is placed with) and not left
-        # to what the compiler's propagation happens to spell, or the call
-        # after an empty engine and the call behind another would be two
-        # signatures of one program.
-        toks_at = (self._replicated, None)
-        wd = self.telemetry.watchdog
-        self._decode_step = wd.wrap(
-            "decode_step", jax.jit(decode_step, donate_argnums=(3,),
-                                   out_shardings=toks_at))
-        self._prefill_step = wd.wrap(
-            "prefill_step", jax.jit(prefill_step, donate_argnums=(4,)))
-        # a step's chunks ride its decode call where the model can run the
-        # two as one (`_chunks_riding` says when); spec decode has no decode
-        # call to ride
-        self._mixed_step = None
-        if mixed_paged is not None and not self.spec_on:
-            self._mixed_step = wd.wrap(
-                "mixed_step", jax.jit(mixed_step, donate_argnums=(8,),
-                                      out_shardings=toks_at))
-
-        self._verify_step = None
-        if self.spec_on:
-            verify_paged = self.engine._fn_transform(spec.verify_paged_fn)
-            K1 = self.draft_k + 1
-
-            def verify_step(params, toks, pos, pool, tables, rng):
-                """Fixed-shape verify: score the k drafts of every slot in
-                ONE call — tokens [S, k+1] (col 0 = last emitted token at
-                the cursor, cols 1..k = drafts), positions pos..pos+k per
-                row, all k+1 tokens' k/v written through the tables along
-                the way. Returns the SAMPLED token per position [S, k+1]:
-                under greedy config that is the argmax — the exact-match
-                acceptance target; under stochastic sampling it is the
-                target model's own draw, so exact-match acceptance is the
-                conservative sample-and-match scheme (output distribution
-                preserved; the true rejection-sampling upgrade would
-                return per-position probabilities here instead)."""
-                logits, pool, *counts = verify_paged(params, toks, pos, pool,
-                                                     tables)
-                S, V = logits.shape[0], logits.shape[-1]
-                tgt = sample(logits.reshape(S * K1, V),
-                             rng).reshape(S, K1)
-                return ((tgt, counts[0]) if counted else tgt), pool
-
-            self._verify_step = wd.wrap(
-                "verify_step", jax.jit(verify_step, donate_argnums=(3,)))
-
-    def _build_streamed_step_fns(self):
-        """Step programs for the offloaded-weights (streamed) mode: the
-        whole-model paged programs are replaced by SIX single-signature
-        jitted programs — {embed, layer, head} x {prefill, decode} — and a
-        host loop that walks the layer program L times per call, weights
-        fed by the engine's async staging pool (layer i computes while
-        layer i+1's upload and layer i+2's disk read are in flight). The
-        layer index is TRACED (the pool's layer axis is dynamic-sliced and
-        written back in place via donation), so every layer of the walk
-        shares one compile; the serving promise becomes one compile per
-        PROGRAM, six programs total, asserted by compile_stats() exactly
-        like the resident mode's two."""
-        spec = self.engine.model_spec
-        cfg = self.engine.config
-        L = self.engine.store.num_layers
-        streamer = self.engine.streamer
-
-        def sample(logits, rng):
-            return sample_logits(logits, rng, greedy=cfg.greedy,
-                                 temperature=cfg.temperature, top_k=cfg.top_k,
-                                 top_p=cfg.top_p)
-
-        # separate prefill/decode jits per role: each program then has
-        # exactly ONE call signature for the engine's lifetime, keeping the
-        # compile-watchdog contract as sharp as the resident mode's. The
-        # factories mint DISTINCT function objects per phase — jax.jit
-        # wrappers over one function share a single compile cache, which
-        # would double every program's reported count.
-
-        def make_embed():
-            def embed(res, toks, positions):
-                return spec.embed_fn(res, toks, positions)
-            return embed
-
-        def make_layer():
-            def layer(p, x, layer_idx, pool, tables, positions):
-                return spec.layer_paged_fn(p, x, layer_idx, pool, tables,
-                                           positions)
-            return layer
-
-        def make_head():
-            def head(res, x, last_idx, rng):
-                last = jnp.take_along_axis(x, last_idx[:, None, None],
-                                           axis=1)
-                logits = spec.final_fn(res, last)[:, 0]
-                return sample(logits, rng)
-            return head
-
-        wd = self.telemetry.watchdog
-        self._embed_prefill = wd.wrap("embed_prefill", jax.jit(make_embed()))
-        self._embed_decode = wd.wrap("embed_decode", jax.jit(make_embed()))
-        self._layer_prefill = wd.wrap(
-            "layer_prefill", jax.jit(make_layer(), donate_argnums=(3,)))
-        self._layer_decode = wd.wrap(
-            "layer_decode", jax.jit(make_layer(), donate_argnums=(3,)))
-        self._head_prefill = wd.wrap("head_prefill", jax.jit(make_head()))
-        self._head_decode = wd.wrap("head_decode", jax.jit(make_head()))
-
-        def prefill_step(params, toks, start, last_idx, pool, table, rng):
-            B, C = toks.shape
-            positions = np.asarray(start, np.int32)[:, None] + \
-                np.arange(C, dtype=np.int32)[None]
-            x = self._embed_prefill(params, toks, positions)
-            for i in range(L):
-                x, pool = self._layer_prefill(streamer.layer(i), x,
-                                              np.int32(i), pool, table,
-                                              positions)
-            return self._head_prefill(params, x,
-                                      np.asarray(last_idx, np.int32),
-                                      rng), pool
-
-        def decode_step(params, tok, pos, pool, tables, rng):
-            S = np.shape(tok)[0]
-            positions = np.asarray(pos, np.int32)[:, None]
-            x = self._embed_decode(params, np.asarray(tok, np.int32)[:, None],
-                                   positions)
-            for i in range(L):
-                x, pool = self._layer_decode(streamer.layer(i), x,
-                                             np.int32(i), pool, tables,
-                                             positions)
-            tok_next = self._head_decode(params, x, np.zeros(S, np.int32),
-                                         rng)
-            return tok_next[:, None], pool
-
-        self._prefill_step = prefill_step
-        self._decode_step = decode_step
-        self._verify_step = self._mixed_step = None
-
-    def _degraded_decode_step(self):
-        """The 1-step decode program, built lazily the first time a
-        degraded path needs it: the spec-decode-disabled fallback (whose
-        block sizing carries a k-draft overhang, not a window-rounding
-        tail, so running the K-step window could write past the allocated
-        blocks) and the pressure ladder's window-shrink rung. One extra
-        warmup compile at first engagement; `compile_stats()` reports it
-        as `decode_step_w1` from then on."""
-        if self.window == 1:
-            return self._decode_step
-        if self._decode_step_w1 is None:
-            self._decode_step_w1 = self.telemetry.watchdog.wrap(
-                "decode_step_w1",
-                jax.jit(self._make_decode_fn(1), donate_argnums=(3,)))
-        return self._decode_step_w1
 
     def _next_rng(self):
         if self.config.greedy:
@@ -1753,11 +1425,11 @@ class ServingEngine:
         with self._dispatching("serving/verify", "verify", rows=len(dec),
                                win=self.draft_k + 1):
             st.dispatched()
-            tgt, self.pool = self._verify_step(self.engine.params, toks,
-                                               pos, self.pool, tables,
-                                               self._next_rng())
+            out, self.pool = self.programs.verify(
+                self.engine.params, toks, pos, self.pool, tables,
+                self._next_rng())
         # THE one host roundtrip per verify step — acceptance runs host-side, amortized over k+1 tokens x all slots
-        with self._read_back(self.device_calls, tgt) as (scored, rec):
+        with self._read_back(self.device_calls, out) as (scored, rec):
             self._accept(dec, drafts, dlens, np.asarray(scored),  # [S, k+1]
                          rec, finished)
 
@@ -1995,7 +1667,7 @@ class ServingEngine:
         """May this step leave its decode or mixed call in flight, to be
         read by the next step after THAT step's call is dispatched? The
         whole rule, from what the step holds: the call is a resident
-        engine's `_decode_step` / `_mixed_step` (the streamed walk runs host
+        engine's decode or mixed program (the streamed walk runs host
         numpy between its layers), spec decode is off (acceptance decides
         the next input), and the pressure ladder is at rest (its rungs
         reshape the call and read the pool's state). Nothing is set."""
@@ -2055,7 +1727,7 @@ class ServingEngine:
         the program is one device's, and the pressure ladder is at rest (its
         rungs reshape the decode call). One chunk a token of the window —
         what a step holds decides, nothing is set."""
-        if not (due and decoding) or self._mixed_step is None \
+        if not (due and decoding) or self.programs.mixed is None \
                 or self.engine.mesh.size != 1 \
                 or (self.pressure is not None and self.pressure.level):
             return 0
@@ -2148,7 +1820,7 @@ class ServingEngine:
                                 chunks=len(self._unread_chunks) + 1)
               if final else self._phase("serving/prefill_chunk")) as ph:
             st.dispatched()
-            tok, self.pool = self._prefill_step(
+            out, self.pool = self.programs.prefill(
                 params, chunk, np.asarray([start], np.int32),
                 np.asarray([last], np.int32), self.pool,
                 self._tables_arg(self.tables[slot.idx][None], [slot.idx]),
@@ -2164,15 +1836,16 @@ class ServingEngine:
             # counted here, while the device runs
             reach = self._chunk_written(
                 slot, start, self.attention_programs().get("prefill_step"))
-            if self.step_counter_names and not final:
-                tok, counts = tok
-                self._parked_counts.append(counts)
+            if not final:
+                # nobody reads this chunk's token: its counts wait for the
+                # next read-back
+                self._parked_counts.append(out[1])
         t1 = ph.t1
         if final:
             self._drain(finished)       # in the device's order, and its own
                                         # phases: the call in flight first
             # first-token readback at prefill completion — one scalar per prompt, the TTFT emission point
-            with self._read_back(self.device_calls, tok) as (first, rec):
+            with self._read_back(self.device_calls, out) as (first, rec):
                 with self._phase("serving/emit"):
                     self._chunks_run(self._unread_chunks)
                     self._unread_chunks = []
@@ -2199,26 +1872,20 @@ class ServingEngine:
         `_prefill_chunk` books its own, a prompt whose last chunk rides
         (it decodes from the next call, on this call's first token), and a
         request that reaches `max_new` inside this call (`_leave`). The read-
-        back brings the window's tokens, those first tokens and a counted
-        model's counters; it is left to the next step, which `_step_impl`
-        decides. Returns (`_decode_walk`'s counts, the chunks'
-        `_chunk_written` counts summed)."""
+        back brings the window's tokens, those first tokens and the model's
+        counters; it is left to the next step, which `_step_impl` decides.
+        Returns (`_decode_walk`'s counts, the chunks' `_chunk_written`
+        counts summed)."""
         st = self.steptrace
-        # the degraded paths run the 1-STEP decode program: with
-        # spec decode pressure-disabled the blocks were sized for
-        # the k-draft overhang (no window-rounding tail, so a K-step
-        # window could write past them), and the ladder's window-
-        # shrink rung trades dispatch amortization for K-times finer
-        # retirement/admission granularity under pool pressure
+        # the degraded paths (spec decode pressure-disabled, the ladder's
+        # window-shrink rung) run the 1-STEP program: `programs.decode_w1`
         use_w1 = self.spec_on or (
             self.pressure is not None
             and self.pressure.force_window_1)
         win, n = 1 if use_w1 else self.window, len(riding)
         prior = self._pending
-        if self.streamed:
-            tok = tok[1]        # the host walk takes the host's tokens
-        else:
-            tok = (self._no_prev if prior is None else prior.prev,) + tok
+        no_prev = self.programs.no_prev
+        tok = (no_prev if prior is None else prior.prev,) + tok
         finals = []
         if riding:
             with self._phase("serving/decode_build"):
@@ -2238,8 +1905,8 @@ class ServingEngine:
                 chunk_tables = jax.tree_util.tree_map(
                     lambda t: t[:, None],
                     self._tables_arg(self.tables[idx], idx))
-        step_fn = self._mixed_step if riding else \
-            self._degraded_decode_step() if use_w1 else self._decode_step
+        step_fn = self.programs.mixed if riding else \
+            self.programs.decode_w1() if use_w1 else self.programs.decode
         rode = [0, 0, 0, 0, 0]
         # the dispatch phase holds the jitted call alone: its two stamps are
         # the call record's launch, the arguments' hand-off and the enqueue
@@ -2268,9 +1935,8 @@ class ServingEngine:
                     for slot, start in riding))]
                 self.fused_chunks += n
             walk = self._decode_walk(dec, pos, win)
-            toks = out[0] if self.step_counter_names else out
             call = _Call(self.device_calls, out,
-                         toks if riding else (self._no_prev[0], toks),
+                         out[0] if riding else (no_prev[0], out[0]),
                          bool(riding), win, dec, finals,
                          self._unread_chunks, riding)
             self._unread_chunks = []
@@ -2386,17 +2052,13 @@ class ServingEngine:
         st.close_call(rec, self.tokens_generated - tokens0)
 
     def _fetch(self, out):
-        """The `device_get` of a step program's tokens (the mixed step's: a
-        pair, first tokens and window tokens). A counted model's program
-        hands (tokens, counts): the counts, and those parked by programs
-        whose tokens nobody read, come back in the same `device_get` and are
-        added to this step's sums."""
-        if not self.step_counter_names:
-            # dstpu: ignore[DT001]: the scheduler's one host roundtrip per device call (decode window, verify step, a prompt's first token) — retirement and acceptance are host-side
-            return jax.device_get(out)
-        toks, counts = out
-        # dstpu: ignore[DT001]: the same roundtrip for a counted model — tokens and counters in ONE device_get, no second sync
-        toks, *counts = jax.device_get([toks, counts] + self._parked_counts)
+        """The `device_get` of a step program's `(tokens, counts)` (the mixed
+        step's tokens: a pair, first tokens and window tokens). The counts,
+        and those parked by programs whose tokens nobody read, come back in
+        the same `device_get` and are added to this step's sums (a model
+        without counters: empty pytrees, nothing to fetch or add)."""
+        # dstpu: ignore[DT001]: the scheduler's one host roundtrip per device call (decode window, verify step, a prompt's first token) — retirement and acceptance are host-side; tokens and the model's counters in ONE device_get, no second sync
+        toks, *counts = jax.device_get([*out] + self._parked_counts)
         self._parked_counts = []
         self._step_counts += np.sum(counts, axis=0, dtype=np.int64)
         return toks
@@ -2404,17 +2066,7 @@ class ServingEngine:
     def _compiled_programs(self) -> int:
         """Compiled-program count over the persistent step functions: its
         growth during a step says that step recompiled."""
-        fns = (self._embed_prefill, self._layer_prefill, self._head_prefill,
-               self._embed_decode, self._layer_decode, self._head_decode) \
-            if self.streamed else (self._decode_step, self._prefill_step,
-                                   self._mixed_step, self._verify_step,
-                                   self._decode_step_w1)
-        # a program not built (None) or replaced by a plain function (fault
-        # injection) has no cache and counts int() = 0
-        total = sum(getattr(fn, "_cache_size", int)() for fn in fns)
-        if self.drafter is not None:
-            total += sum(self.drafter.compile_stats().values())
-        return total
+        return sum(self.compile_stats().values())
 
     # ------------------------------------------------------------------
     # batch front-end + introspection
@@ -2456,49 +2108,27 @@ class ServingEngine:
     def compile_stats(self) -> Dict[str, int]:
         """Compiled-program counts of the persistent step functions — the
         serving promise is that these stay at 1 each for the engine's
-        lifetime, across any mix of request shapes (the verify and draft
-        programs appear, and join the promise, when spec decode is on; the
-        streamed mode's six per-phase programs replace the resident two,
-        each still pinned at one)."""
-        if self.streamed:
-            return {name: int(fn._cache_size()) for name, fn in (
-                ("embed_prefill", self._embed_prefill),
-                ("layer_prefill", self._layer_prefill),
-                ("head_prefill", self._head_prefill),
-                ("embed_decode", self._embed_decode),
-                ("layer_decode", self._layer_decode),
-                ("head_decode", self._head_decode))}
-        out = {"decode_step": int(self._decode_step._cache_size()),
-               "prefill_step": int(self._prefill_step._cache_size())}
-        if self._mixed_step is not None and self._mixed_step._cache_size():
-            # appears once a chunk has ridden a decode call; until then the
-            # program is a jit wrapper nothing has traced
-            out["mixed_step"] = int(self._mixed_step._cache_size())
+        lifetime, across any mix of request shapes (the draft programs join
+        the promise when spec decode is on; the streamed mode's six
+        per-phase programs replace the resident ones)."""
+        out = self.programs.compile_counts()
         if self.spec_on:
-            out["verify_step"] = int(self._verify_step._cache_size())
             out.update(self.drafter.compile_stats())
-        if self._decode_step_w1 is not None:
-            # appears only once the degradation ladder (or the spec-decode
-            # fallback) actually built it — absent means never engaged
-            out["decode_step_w1"] = int(self._decode_step_w1._cache_size())
         return out
 
     def kv_pool_writers(self) -> Dict[str, str]:
         """Step program -> how it writes its new K/V rows into the pool:
         `dstpu_kv_pool_write` (the in-place kernel on a carried pool) or
-        `xla_scatter` (`ops/attention_dispatch.py::kv_pool_writer` decides
-        from the pool's dtype and shape and the platform; there is nothing
-        to set). A program appears once it has been traced. Empty for a
-        model that keeps no record: the streamed layers and the per-layer
-        (`moe_freq` >= 2) MoE stack have the scatter form only."""
+        `xla_scatter` (`ops/attention_dispatch.py::kv_pool_writer` decides;
+        there is nothing to set). A program appears once it has been traced.
+        Empty for a model that keeps no record (the streamed layers, the
+        `moe_freq` >= 2 MoE stack: the scatter form only)."""
         return self._by_step_program("kv_pool_writers")
 
     def attention_programs(self) -> Dict[str, str]:
         """Step program -> the attention program its layers were traced with
-        (`ops/attention_dispatch.py`'s registry names: the prefill step's is
-        `paged_prefill_kernel` where the chunk walks the blocks under its
-        frontier, `paged_gather` where it attends its whole table). As
-        `kv_pool_writers`: nothing to set, a program appears once traced."""
+        (`ops/attention_dispatch.py`'s registry names). As `kv_pool_writers`:
+        nothing to set, a program appears once traced."""
         return self._by_step_program("paged_attn_programs")
 
     def _by_step_program(self, record) -> Dict[str, str]:
@@ -2636,11 +2266,10 @@ class ServingEngine:
 
     def write_monitor_events(self, monitor):
         """Serving cache/pool observability through the experiment monitor
-        (same guarded best-effort contract as the PR 2 recovery events):
-        Serving/prefix_hit_tokens, Serving/prefix_evictions,
-        Serving/pool_free_blocks, stepped by the scheduler iteration."""
-        from deepspeed_tpu.monitor.monitor import write_serving_events
-        write_serving_events(monitor, [
+        (guarded, best-effort: `write_events_safe`), stepped by the
+        scheduler iteration."""
+        from deepspeed_tpu.monitor.monitor import write_events_safe
+        write_events_safe(monitor, [
             ("Serving/prefix_hit_tokens", self.prefix_hit_tokens, self.steps),
             ("Serving/prefix_evictions", self.allocator.evictions, self.steps),
             ("Serving/pool_free_blocks", self.allocator.available, self.steps),

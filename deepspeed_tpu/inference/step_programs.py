@@ -1,0 +1,368 @@
+"""The serving step's compiled programs — built once, shapes pinned for the
+engine's lifetime, and the ONE module that calls `jax.jit` on them.
+
+`build_resident(...)` and `build_streamed(...)` each build a `StepPrograms`, the object
+the scheduler's loop holds (`ServingEngine.programs`). What a builder needs
+of the engine it takes as arguments; nothing here imports the scheduler.
+
+ONE shape: every step program returns `((tokens...), counts), pool`.
+`counts` is the model's own per-call counters (`DecodeModelSpec.
+step_counters`, e.g. the routed experts'), summed over the call's tokens: an
+int32 `[len(step_counters)]` where the model names some, the EMPTY pytree
+`()` where it names none (`build_resident`'s `paged`). An empty pytree adds nothing to a
+carry, an output or a `device_get`: the uncounted programs lower to the text
+they lowered to when they returned bare tokens.
+"""
+
+import functools
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from deepspeed_tpu.inference.engine import sample_logits
+
+# the whole-step programs, as `compile_stats()` and the watchdog name them
+_NAMES = ("decode_step", "prefill_step", "mixed_step", "verify_step",
+          "decode_step_w1")
+
+
+class StepPrograms:
+    """One serving engine's step programs: the callables `decode`,
+    `prefill`, `mixed` and `verify` (None where not built) and `decode_w1()`;
+    `no_prev`, what `pick` takes where no call is in flight (the shapes,
+    dtype and sharding of a mixed call's (first tokens, window tokens), so
+    that a call has ONE signature whatever came before it); each built
+    program's name (`built`, `compile_counts`) and example arguments
+    (`examples`). A test that swaps a program for a stub assigns the
+    attribute: the loop reads it a call."""
+
+    def __init__(self, decode, prefill, mixed, verify, no_prev, parts,
+                 make_w1, example_args):
+        self.decode, self.prefill = decode, prefill
+        self.mixed, self.verify, self.no_prev = mixed, verify, no_prev
+        self.w1 = None          # `decode_w1()`'s, once built
+        self._parts = parts     # name -> program: the streamed mode's six
+                                # per-layer programs (its steps are host loops)
+        self._make_w1, self._example_args = make_w1, example_args
+
+    def decode_w1(self):
+        """The 1-step decode program, built the first time a degraded path
+        needs it: the spec-decode-disabled fallback (its blocks are sized
+        for a k-draft overhang, not a window-rounding tail: a K-step window
+        could write past them) and the pressure ladder's window-shrink
+        rung. One warmup compile at first engagement (`decode_step_w1` from
+        then on). Where the window is one token already, it is `decode`."""
+        if self._make_w1 is None:
+            return self.decode
+        if self.w1 is None:
+            self.w1 = self._make_w1()
+        return self.w1
+
+    def built(self):
+        """[(name, program)] of the programs built so far, under the names
+        `compile_stats()` and the compile watchdog report."""
+        if self._parts:
+            return list(self._parts.items())
+        whole = (self.decode, self.prefill, self.mixed, self.verify, self.w1)
+        return [(name, fn) for name, fn in zip(_NAMES, whole)
+                if fn is not None]
+
+    def compile_counts(self):
+        """name -> compiled signatures (the serving promise: 1 each for the
+        engine's lifetime). `mixed_step` appears once a chunk has ridden a
+        decode call: until then it is a jit wrapper nothing has traced. A
+        program replaced by a plain function (fault injection) has no cache
+        and counts int() = 0."""
+        counts = {name: int(getattr(fn, "_cache_size", int)())
+                  for name, fn in self.built()}
+        if not counts.get("mixed_step", 1):
+            del counts["mixed_step"]
+        return counts
+
+    def examples(self, params, pool, tables, rng):
+        """[(name, program, example arguments)] of the built whole-step
+        programs, as the scheduler calls them: `tables` is a decode call's
+        tables argument (a row a slot; a pair for a pool of two kinds), of
+        which a chunk takes one row. Empty in the streamed mode: no
+        whole-step executable exists to analyse."""
+        args = self._example_args(params, pool, tables, rng)
+        return [(name, fn, args[name]) for name, fn in self.built()
+                if name in args]
+
+
+def _sampler(cfg):
+    return functools.partial(sample_logits, greedy=cfg.greedy,
+                             temperature=cfg.temperature, top_k=cfg.top_k,
+                             top_p=cfg.top_p)
+
+
+def build_resident(spec, cfg, transform, *, window, max_slots, chunk,
+                   spec_on, draft_k, replicated, watchdog):
+    """The whole-model programs of a resident engine: `cfg` its config (the
+    sampler's settings), `transform` its dequantize-on-use wrapper of a model
+    function, `replicated` its mesh's replicated sharding, `watchdog` its
+    telemetry's compile watchdog."""
+    counters = tuple(getattr(spec, "step_counters", None) or ())
+    sample = _sampler(cfg)
+
+    def paged(fn):
+        """A spec's paged function under the ONE arity `(logits, pool,
+        counts)`: a counted model's returns its counters already, the
+        others get the empty pytree."""
+        fn = transform(fn)
+        return fn if counters else lambda *args: (*fn(*args), ())
+
+    # what a window's sum of counts starts from: zeros, or the empty pytree
+    no_counts = functools.partial(jnp.zeros, (len(counters),), jnp.int32) \
+        if counters else tuple
+
+    decode_paged = paged(spec.decode_paged_fn)
+    prefill_paged = paged(spec.prefill_paged_fn)
+
+    def pick(tok):
+        """A call's input token a slot. A plain [S] array is the host's.
+        Else ((first [W], nxt [S, win]) of the call BEFORE, still on the
+        device, src [S], host [S]): per slot the host's value (src 0),
+        the last token that call sampled for it (1), or the first token
+        of the prompt whose last chunk rode that call at window position
+        src - 2 — call k's tokens are call k-1's outputs, and never make
+        the trip to the host and back between the two."""
+        if not isinstance(tok, tuple):
+            return tok
+        (first, nxt), src, host = tok
+        return jnp.where(
+            src == 0, host, jnp.where(
+                src == 1, nxt[:, -1], first[jnp.maximum(src - 2, 0)]))
+
+    def make_decode_step(window):
+        """The decode-WINDOW program: `window` tokens per sync inside one
+        lax.scan, so one device call + one host roundtrip amortize over
+        the window — the dispatch-latency lever. Emits tokens [S, window]:
+        the successors of the input token, with the input's k/v (and each
+        successor's but the last) written into the pool along the way. A
+        builder: `decode_w1()` is the same program at one token."""
+
+        def decode_step(params, tok, pos, pool, tables, rng):
+            tok = pick(tok)
+            if window == 1:  # no scan wrapper: keep the 1-step hot path
+                logits, pool, counts = decode_paged(params, tok, pos, pool,
+                                                    tables)
+                return (sample(logits, rng)[:, None], counts), pool
+
+            def body(carry, _):
+                tok, pos, pool, rng, acc = carry
+                rng, sub = jax.random.split(rng)
+                logits, pool, counts = decode_paged(params, tok, pos, pool,
+                                                    tables)
+                nxt = sample(logits, sub)
+                acc = jax.tree_util.tree_map(jnp.add, acc, counts)
+                return (nxt, pos + 1, pool, rng, acc), nxt
+
+            (_, _, pool, _, acc), toks = jax.lax.scan(
+                body, (tok, pos, pool, rng, no_counts()), None, length=window)
+            return (jnp.moveaxis(toks, 0, 1), acc), pool
+
+        return decode_step
+
+    def prefill_step(params, toks, start, last_idx, pool, table, rng):
+        logits, pool, counts = prefill_paged(params, toks, start, last_idx,
+                                             pool, table)
+        return (sample(logits, rng), counts), pool
+
+    mixed_paged = getattr(spec, "mixed_paged_fn", None)
+    if mixed_paged is not None:
+        mixed_paged = paged(mixed_paged)
+
+    def mixed_step(params, chunks, starts, lasts, chunk_tables, n, tok,
+                   pos, pool, tables, rng):
+        """The MIXED program: a decode window whose first `n` tokens each
+        carry a prefill chunk through the model with them
+        (`DecodeModelSpec.mixed_paged_fn`: the chunk's rows and the slots'
+        rows as one tensor, every weight read once), the others plain decode
+        tokens. `chunks` [W, 1, C], `starts` / `lasts` [W, 1] and
+        `chunk_tables` [W, 1, nb] hold a chunk a window position, of which
+        the first `n` (traced, 1..W) are real: two loops with dynamic bounds
+        over one carried pool, so ONE compile serves every count. Returns
+        ((first tokens [W]: what each chunk's last row sampled, window
+        tokens [S, W]), counts), pool."""
+        tok = pick(tok)
+
+        def ride(i, tok, pos, pool, rng):
+            def at(a):
+                return jax.lax.dynamic_index_in_dim(a, i, 0,
+                                                    keepdims=False)
+            logits, pool, counts = mixed_paged(
+                params, at(chunks), at(starts), at(lasts),
+                jax.tree_util.tree_map(at, chunk_tables), tok, pos, pool,
+                tables)
+            sampled = sample(logits, rng)
+            return sampled[0], sampled[1:], pool, counts
+
+        if window == 1:     # as `decode_step`: no loop around one token
+            first, nxt, pool, counts = ride(0, tok, pos, pool, rng)
+            return ((first[None], nxt[:, None]), counts), pool
+
+        def body(i, carry, riding):
+            tok, pos, pool, rng, acc, first, toks = carry
+            rng, sub = jax.random.split(rng)
+            if riding:
+                head, nxt, pool, counts = ride(i, tok, pos, pool, sub)
+                first = first.at[i].set(head)
+            else:
+                logits, pool, counts = decode_paged(params, tok, pos, pool,
+                                                    tables)
+                nxt = sample(logits, sub)
+            acc = jax.tree_util.tree_map(jnp.add, acc, counts)
+            return (nxt, pos + 1, pool, rng, acc, first,
+                    toks.at[:, i].set(nxt))
+
+        carry = (tok, pos, pool, rng, no_counts(),
+                 jnp.zeros((window,), jnp.int32),
+                 jnp.zeros((tok.shape[0], window), jnp.int32))
+        carry = jax.lax.fori_loop(
+            0, n, lambda i, c: body(i, c, True), carry)
+        carry = jax.lax.fori_loop(
+            n, window, lambda i, c: body(i, c, False), carry)
+        _, _, pool, _, acc, first, toks = carry
+        return ((first, toks), acc), pool
+
+    # the pool is donated: the update is in-place in HBM. The compile
+    # watchdog (telemetry/flight_recorder.py) wraps each program when
+    # telemetry is on: any cache miss after the ONE warmup compile is
+    # recorded — with telemetry off, wrap() returns the jitted function.
+    # The tokens of a decode or mixed call are the next call's input
+    # (`pick`), so their sharding is part of that call's signature: it is
+    # SAID (replicated, what `no_prev` is placed with) and not left to the
+    # compiler's propagation, or the call after an empty engine and the
+    # call behind another would be two signatures of one program.
+    toks_at = (replicated, None)
+    decode = watchdog.wrap(
+        "decode_step", jax.jit(make_decode_step(window), donate_argnums=(3,),
+                               out_shardings=toks_at))
+    prefill = watchdog.wrap(
+        "prefill_step", jax.jit(prefill_step, donate_argnums=(4,)))
+    # a step's chunks ride its decode call where the model can run the
+    # two as one (the scheduler's `_chunks_riding` says when); spec decode
+    # has no decode call to ride
+    mixed = None
+    if mixed_paged is not None and not spec_on:
+        mixed = watchdog.wrap(
+            "mixed_step", jax.jit(mixed_step, donate_argnums=(8,),
+                                  out_shardings=toks_at))
+
+    verify = None
+    K1 = draft_k + 1
+    if spec_on:
+        verify_paged = paged(spec.verify_paged_fn)
+
+        def verify_step(params, toks, pos, pool, tables, rng):
+            """Fixed-shape verify: score the k drafts of every slot in ONE
+            call — tokens [S, k+1] (col 0 = last emitted token at the
+            cursor, cols 1..k = drafts), positions pos..pos+k per row, all
+            k+1 tokens' k/v written through the tables along the way.
+            Returns the SAMPLED token per position [S, k+1]: the argmax
+            under greedy config (the exact-match acceptance target), the
+            target model's own draw otherwise — the conservative
+            sample-and-match scheme (output distribution preserved; true
+            rejection sampling would return probabilities here)."""
+            logits, pool, counts = verify_paged(params, toks, pos, pool,
+                                                tables)
+            S, V = logits.shape[0], logits.shape[-1]
+            tgt = sample(logits.reshape(S * K1, V), rng).reshape(S, K1)
+            return (tgt, counts), pool
+
+        verify = watchdog.wrap(
+            "verify_step", jax.jit(verify_step, donate_argnums=(3,)))
+
+    make_w1 = None if window == 1 else lambda: watchdog.wrap(
+        "decode_step_w1", jax.jit(make_decode_step(1), donate_argnums=(3,)))
+
+    no_prev = jax.device_put(
+        (np.zeros((window,), np.int32),
+         np.zeros((max_slots, window), np.int32)), replicated)
+
+    def example_args(params, pool, tables, rng):
+        S, W = max_slots, window
+
+        def i32(*shape):
+            return np.zeros(shape, np.int32)
+
+        one = jax.tree_util.tree_map(lambda t: np.asarray(t)[:1], tables)
+        # a call's input tokens as the scheduler hands them: the call
+        # before's outputs (on the device), the source a slot, the host's
+        tok = (no_prev, i32(S), i32(S))
+        decode_args = (params, tok, i32(S), pool, tables, rng)
+        return {
+            "decode_step": decode_args, "decode_step_w1": decode_args,
+            "prefill_step": (params, i32(1, chunk), i32(1), i32(1), pool,
+                             one, rng),
+            "mixed_step": (
+                params, i32(W, 1, chunk), i32(W, 1), i32(W, 1),
+                jax.tree_util.tree_map(lambda t: np.repeat(t[None], W, 0),
+                                       one),
+                np.int32(1), tok, i32(S), pool, tables, rng),
+            "verify_step": (params, i32(S, K1), i32(S), pool, tables, rng)}
+
+    return StepPrograms(decode, prefill, mixed, verify, no_prev, parts={},
+                        make_w1=make_w1, example_args=example_args)
+
+
+def build_streamed(spec, cfg, *, num_layers, streamer, watchdog):
+    """The offloaded-weights (streamed) mode: SIX single-signature jitted
+    programs — {embed, layer, head} x {prefill, decode} — and a host loop
+    that walks the layer program L times per call, weights fed by the
+    engine's async staging pool `streamer` (layer i computes while layer
+    i+1's upload and layer i+2's disk read are in flight). The layer index
+    is TRACED (the pool's layer axis is dynamic-sliced and written back in
+    place via donation), so every layer of the walk shares one compile: one
+    compile per PROGRAM, six in all. The window is one token, there is no
+    mixed and no verify program, and `decode` takes what the resident one
+    takes and ignores the device half of its tokens."""
+    sample = _sampler(cfg)
+    L = num_layers
+
+    def head(res, x, last_idx, rng):
+        last = jnp.take_along_axis(x, last_idx[:, None, None], axis=1)
+        return sample(spec.final_fn(res, last)[:, 0], rng)
+
+    # a jit a role AND a phase: each program then has ONE call signature for
+    # the engine's lifetime. Each jit wraps a `partial` of its own, a
+    # DISTINCT function object — jax.jit wrappers over one function share a
+    # compile cache, which would double every program's reported count.
+    parts = {f"{role}_{phase}": watchdog.wrap(
+        f"{role}_{phase}", jax.jit(functools.partial(fn),
+                                   donate_argnums=donate))
+        for phase in ("prefill", "decode")
+        for role, fn, donate in (("embed", spec.embed_fn, ()),
+                                 ("layer", spec.layer_paged_fn, (3,)),
+                                 ("head", head, ()))}
+
+    def walk(phase, params, toks, positions, last_idx, pool, tables, rng):
+        embed, layer, head = (parts[f"{role}_{phase}"]
+                              for role in ("embed", "layer", "head"))
+        x = embed(params, toks, positions)
+        for i in range(L):
+            x, pool = layer(streamer.layer(i), x, np.int32(i), pool, tables,
+                            positions)
+        return head(params, x, last_idx, rng), pool
+
+    def prefill_step(params, toks, start, last_idx, pool, table, rng):
+        positions = np.asarray(start, np.int32)[:, None] + \
+            np.arange(toks.shape[1], dtype=np.int32)[None]
+        tok, pool = walk("prefill", params, toks, positions,
+                         np.asarray(last_idx, np.int32), pool, table, rng)
+        return (tok, ()), pool
+
+    def decode_step(params, tok, pos, pool, tables, rng):
+        if isinstance(tok, tuple):
+            tok = tok[2]        # the host walk takes the host's tokens
+        tok = np.asarray(tok, np.int32)[:, None]
+        nxt, pool = walk("decode", params, tok,
+                         np.asarray(pos, np.int32)[:, None],
+                         np.zeros(len(tok), np.int32), pool, tables, rng)
+        return (nxt[:, None], ()), pool
+
+    return StepPrograms(decode_step, prefill_step, None, None,
+                        no_prev=(None, None), parts=parts, make_w1=None,
+                        example_args=lambda *live: {})

@@ -118,20 +118,11 @@ class GPTConfig:
                                      # axis (K/V shards rotate via ppermute;
                                      # the hybrid adds the Ulysses head
                                      # all-to-all, sp = ulysses x ring).
-                                     # None = auto (flash/chunked/dense by
+                                     # None = auto (flash/dense by
                                      # the measured crossovers). Ignored
                                      # when no `sequence` axis > 1 is
                                      # installed — the request falls through
                                      # to the auto programs
-    chunked_attn_min_seq: Optional[int] = None  # remat/memory escape hatch:
-                                     # T >= this routes to the q-chunked
-                                     # rematerialized XLA path
-                                     # (ops/chunked_attention.py) instead of
-                                     # the flash kernel. None (default) =
-                                     # never — the streaming kernel has no
-                                     # sequence cap, so only an HBM squeeze
-                                     # (activation residuals at extreme T)
-                                     # justifies the ~2.8x-slower fallback
     act_quant: Any = None            # ActQuantGate (compression/pruners.py):
                                      # when .active, each block linear's INPUT
                                      # is fake-quantized to .bits with STE
@@ -493,7 +484,6 @@ def _train_attn_site(cfg, T, S, has_bias, attn_fn):
         scale_attn=bool(cfg.scale_attn),
         mesh_axes=attn_dispatch.active_mesh_axes(),
         force_flash=cfg.use_flash_attention,
-        chunk_min=getattr(cfg, "chunked_attn_min_seq", None),
         backend=getattr(cfg, "attention_backend", None),
         external_fn=attn_fn is not None)
 
@@ -506,16 +496,17 @@ def _attention(q, k, v, causal_mask, cfg, attn_fn=None, bias=None):
     `module_inject/containers/llama2.py`). `bias`: additive [H, T, S] (alibi).
 
     Program selection goes through the unified dispatch layer
-    (`ops/attention_dispatch.py`): flash at the measured crossover, the
-    chunked escape hatch, ring / ring∘Ulysses context parallelism on
-    request (`GPTConfig.attention_backend`), dense XLA otherwise — every
+    (`ops/attention_dispatch.py`): flash at the measured crossover, ring /
+    ring∘Ulysses context parallelism on request
+    (`GPTConfig.attention_backend`), dense XLA otherwise — every
     registered program's runner is invoked through the same matched-heads
-    external-fn path, so a new variant plugs in at the registry, not here."""
-    program = attn_dispatch.select(
+    external-fn path, so a new variant plugs in at the registry, not here
+    (a program without a runner: the caller's `attn_fn`, or the dense form
+    below)."""
+    runner = attn_dispatch.get_program(attn_dispatch.select(
         _train_attn_site(cfg, q.shape[1], k.shape[1], bias is not None,
-                         attn_fn))
-    if program not in ("dense", "external"):
-        runner = attn_dispatch.get_program(program).runner
+                         attn_fn))).runner
+    if runner is not None:
         attn_fn = partial(runner, causal=True,
                           sm_scale=sm_scale(cfg))
     if attn_fn is not None:
@@ -1006,24 +997,15 @@ def _decode_attn_half(x, p, cache_k, cache_v, pos, cfg: GPTConfig,
     # unrounded cache would otherwise pay a whole-cache pad-to-block copy
     # INSIDE every jitted decode step (the engine's kv_block_size rounding
     # guarantees this; direct callers with odd M stay on XLA). Alibi/window
-    # archs disqualify the kernel — all through the dispatch registry.
-    program = attn_dispatch.select(_decode_attn_site(cfg, "decode", 1, M))
-    if program not in ("decode_kernel", "decode_dense"):
-        # the decode/paged sites dispatch BY NAME (their call signatures
-        # carry cache state the train-phase runner protocol doesn't):
-        # an unknown registered program must fail loudly here, not fall
-        # into a numerically-different path
-        raise NotImplementedError(
-            f"attention program {program!r} selected for the contiguous "
-            f"decode site has no handler in models/gpt.py — non-train "
-            f"phases dispatch by name; add a branch for it here")
-    if program == "decode_kernel":
-        from deepspeed_tpu.ops.pallas.decode_attention import decode_attention
-        attn = decode_attention(
-            q[:, 0], cache_k, cache_v, pos,
-            # honor scale_attn=False (GPT-Neo): the kernel defaults to
-            # 1/sqrt(hd) when sm_scale is None
-            sm_scale=sm_scale(cfg)).reshape(B, 1, D)
+    # archs disqualify the kernel — all through the dispatch registry: a
+    # program with a runner is run by it, one without is the einsum below.
+    runner = attn_dispatch.get_program(attn_dispatch.select(
+        _decode_attn_site(cfg, "decode", 1, M))).runner
+    if runner is not None:
+        # honor scale_attn=False (GPT-Neo): the kernel defaults to
+        # 1/sqrt(hd) when sm_scale is None
+        attn = runner(q[:, 0], cache_k, cache_v, pos,
+                      sm_scale=sm_scale(cfg)).reshape(B, 1, D)
     else:
         scale = score_scale(cfg, hd)
         m_pos = jnp.arange(M)
@@ -1559,9 +1541,12 @@ def _paged_write_attend(q, k, v, pool_l, positions, block_tables,
     # kernel pads a whole cache), and C == 1. The int8-pool kernel is an
     # ordinary REGISTERED program keyed on kv_dtype, not a special case
     # here. A prefill chunk on the in-place pool walks the blocks under its
-    # frontier (`paged_prefill_kernel`, a program with a runner: no branch
-    # of its own below); the spec-decode verify chunk, and a prefill chunk
-    # anywhere else, gather the row's whole table and attend it densely.
+    # frontier (`paged_prefill_kernel`). Each of them is a program with a
+    # RUNNER: no branch of its own below. A program without one (the
+    # spec-decode verify chunk, a prefill chunk anywhere else) is this
+    # site's oracle: gather the row's whole table — dequantized where the
+    # pool has scale leaves, whatever the program is called — and attend it
+    # densely.
     site = _decode_attn_site(
         cfg,
         phase or ("paged_decode" if C == 1 else "prefill_chunk"), C, nb * bs,
@@ -1575,29 +1560,10 @@ def _paged_write_attend(q, k, v, pool_l, positions, block_tables,
         with jax.named_scope("attn"):
             attn = runner(q, pool_l, block_tables, positions[:, 0],
                           sm_scale=sm_scale(cfg),
-                          window=site.window or None)
-    elif program == "paged_kernel_quant":
-        from deepspeed_tpu.ops.pallas.decode_attention import \
-            paged_decode_attention_quant
-        with jax.named_scope("attn"):
-            attn = paged_decode_attention_quant(
-                q[:, 0], pool_l["k"], pool_l["v"], pool_l["k_scale"],
-                pool_l["v_scale"], block_tables, positions[:, 0],
-                sm_scale=sm_scale(cfg),
-                work=decode_work,
-                window=site.window or None).reshape(B, 1, -1)
-    elif program == "paged_kernel":
-        from deepspeed_tpu.ops.pallas.decode_attention import \
-            paged_decode_attention
-        with jax.named_scope("attn"):
-            attn = paged_decode_attention(
-                q[:, 0], pool_l["k"], pool_l["v"], block_tables,
-                positions[:, 0], sm_scale=sm_scale(cfg),
-                work=decode_work,
-                window=site.window or None).reshape(B, 1, -1)
-    elif program in ("paged_gather_quant", "paged_gather"):
+                          window=site.window or None, work=decode_work)
+    else:
         with jax.named_scope("kv_pool_read"):
-            if program == "paged_gather_quant":
+            if quantized:
                 k_ctx, v_ctx = gather_block_kv_dequant(pool_l, block_tables,
                                                        q.dtype)
             elif block_base is not None:
@@ -1612,14 +1578,6 @@ def _paged_write_attend(q, k, v, pool_l, positions, block_tables,
         with jax.named_scope("attn"):
             attn = _paged_attend(q, k_ctx, v_ctx, positions, cfg,
                                  local_flag=local_flag)
-    else:
-        # see the contiguous decode site: by-name dispatch, loud failure
-        # for programs without a handler (an unknown name silently taking
-        # the fp gather would read int8 payload as K/V on quantized pools)
-        raise NotImplementedError(
-            f"attention program {program!r} selected for the paged site "
-            f"has no handler in models/gpt.py — non-train phases dispatch "
-            f"by name; add a branch for it here")
     return attn, pool_l
 
 

@@ -42,9 +42,7 @@ from deepspeed_tpu.models.gpt import (MixedTables, _half_input, _norm, _rope,
 from deepspeed_tpu.ops import attention_dispatch as attn_dispatch
 from deepspeed_tpu.ops.pallas.mla_attention import (gather_latent,
                                                     latent_entry_width,
-                                                    mla_attend_gathered,
-                                                    mla_decode_attention,
-                                                    mla_prefill_attention)
+                                                    mla_attend_gathered)
 
 LATENT_LEAF = "ckv"
 
@@ -217,14 +215,14 @@ def _write_attend(q_n, q_r, c, k_r, p, pool_l, positions, block_tables, cfg,
     if attn_programs is not None:
         attn_programs[record or phase] = program
     scale = score_scale(cfg, cfg.head_dim)
+    runner = attn_dispatch.get_program(program).runner
     with jax.named_scope("attn"):
-        if program == "mla_decode_kernel":
-            u = mla_decode_attention(q[:, 0], pool, block_tables,
-                                     positions[:, 0], r, scale, work=work)
-        elif program == "mla_prefill_kernel":
-            u = mla_prefill_attention(q, pool, block_tables, positions[:, 0],
-                                      r, scale)
-        elif program == "mla_gather":
+        if runner is not None:
+            u = runner(q, {LATENT_LEAF: pool}, block_tables, positions[:, 0],
+                       sm_scale=scale, window=None, work=work, rank=r)
+        else:
+            # the site's oracle: each row's whole table gathered, attended
+            # densely in the absorbed form
             if block_base is not None:
                 # reads of a carried pool are Mosaic calls too
                 from deepspeed_tpu.ops.pallas.kv_pool import kv_pool_gather
@@ -232,10 +230,6 @@ def _write_attend(q_n, q_r, c, k_r, p, pool_l, positions, block_tables, cfg,
             else:
                 ctx = gather_latent(pool, block_tables)
             u = mla_attend_gathered(q, ctx, positions, r, scale)
-        else:
-            raise NotImplementedError(
-                f"attention program {program!r} selected for a latent site "
-                f"has no handler in models/mla.py")
     with jax.named_scope("mla/absorb"):
         o = jnp.einsum("bthr,rhv->bthv", u.reshape(q_n.shape[:3] + (r,)), w_v)
     return o.reshape(q_n.shape[:2] + (H * dv,)), {LATENT_LEAF: pool}

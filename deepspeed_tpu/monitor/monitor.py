@@ -158,12 +158,6 @@ def write_events_safe(monitor, event_list):
         logger.warning(f"monitor event emission failed: {e}")
 
 
-# Historical aliases (PR 2 recovery events, PR 4 serving events) — one
-# implementation, kept importable under both names.
-write_recovery_events = write_events_safe
-write_serving_events = write_events_safe
-
-
 class MonitorMaster(Monitor):
     """Fans events out to every enabled monitor (reference same name)."""
 

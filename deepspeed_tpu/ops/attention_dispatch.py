@@ -1,6 +1,6 @@
 """Unified attention dispatch — ONE decision layer for every attention call.
 
-The model zoo has five attention entry points (training flash/chunked/dense,
+The model zoo has five attention entry points (training flash/dense,
 chunked paged prefill, paged single-token decode, paged spec-decode verify,
 contiguous-cache decode) and until PR 14 each call site carried its own copy
 of the engage predicate: the training `use_flash_attention` check lived at
@@ -14,14 +14,13 @@ plus the masking flags that disqualify kernels — and `select()` walks the
 PROGRAM REGISTRY (highest priority first) to name the program that runs.
 Variants register once here instead of branching at five call sites:
 
-  * the ring / ring∘Ulysses context-parallel programs (`parallel/ring.py`)
-    register with a `runner` — the training forward invokes them through
-    the registry without knowing their internals;
-  * the PR 12 quantized paged kernels register as ordinary programs keyed
-    on `kv_dtype`, not as an if/else inside the paged attention half;
-  * the chunked-prefill kernel (`paged_prefill_kernel`) registers with a
-    `runner` too, keyed on the pool's form and the chunk's shape: the paged
-    attention half invokes it without a branch of its own.
+  * a KERNEL program (flash, the ring family, the paged and latent walks,
+    the contiguous decode kernel) registers with a `runner`, and its site
+    invokes it through the registry without knowing its internals;
+  * a program WITHOUT a runner is the site's own dense / gather oracle,
+    which reads what the pool holds (its int8 twin from the pool's scale
+    leaves), never a program's name — so adding a program is an entry
+    here and no edit of a caller.
 
 Every predicate reads only TRACE-TIME-STATIC inputs (shapes, config
 fields, the installed mesh spec), so dispatch can never cause a recompile:
@@ -115,7 +114,6 @@ class AttnSite:
                                   # programs read it
     mesh_axes: Tuple[str, ...] = ()  # active (size>1) mesh axes
     force_flash: Optional[bool] = None  # GPTConfig.use_flash_attention
-    chunk_min: Optional[int] = None     # GPTConfig.chunked_attn_min_seq
     backend: Optional[str] = None       # GPTConfig.attention_backend request
     external_fn: bool = False     # caller supplied its own attn_fn — only
                                   # the "external" pseudo-program may match
@@ -134,15 +132,24 @@ class AttnSite:
 class AttentionProgram:
     """One registered attention implementation.
 
-    `matches` decides eligibility from the AttnSite alone; `runner`, when
-    set, is the callable the site invokes without knowing the program: for
-    the train phase the zoo-layout `(q, k, v, *, causal, sm_scale)` with
-    [B, T, H, hd] operands and matched heads; for a paged phase
-    `(q, pool_l, block_tables, start, *, sm_scale, window)` with q
-    [B, C, H, hd], the float pool's leaves WHOLE, the tables in the pool's
-    numbering, each row's first position and the site's static window (None
-    = none), returning [B, C, H*hd]. The other paged and
-    decode programs still dispatch by NAME and are invoked at the call site.
+    `matches` decides eligibility from the AttnSite alone. A program is run
+    by its `runner`, never by its name: where `runner` is set, the site
+    invokes it without knowing the program; where it is None, the site runs
+    its own dense / gather oracle, which picks its dequantizing twin from
+    the pool's leaves. One signature a kind of site:
+
+      * train: `(q, k, v, *, causal, sm_scale)`, zoo layout [B, T, H, hd],
+        matched heads -> [B, T, H, hd];
+      * a paged phase: `(q, pool_l, block_tables, start, *, sm_scale,
+        window, work=None, rank=None)` with q [B, C, H, hd], the pool's
+        leaves WHOLE, the tables in the pool's numbering, each row's first
+        position, the site's static window (None = none), the decode
+        walks' work list where the caller built it outside its layer loop,
+        and for a latent pool the latent rank (q then `[q~ | q_r | 0]`, the
+        result the un-absorbed values) -> [B, C, H * hd] ([B, C, H * rank]);
+      * contiguous decode: `(q, cache_k, cache_v, pos, *, sm_scale)` with q
+        [B, H, hd] and the head-major cache -> [B, H, hd].
+
     `when` is the human-readable engage condition for `dispatch_table()`
     and docs/kernels.md."""
     name: str
@@ -229,11 +236,11 @@ def dispatch_table() -> Dict[str, list]:
 # built-in programs
 # ----------------------------------------------------------------------
 # Priorities: 100s = explicit backend requests (ring family), 50s =
-# kernel/escape-hatch engagement, 0 = the always-eligible dense fallback.
+# kernel engagement, 0 = the always-eligible dense fallback.
 
 
 def _kernel_shape_ok(site: AttnSite) -> bool:
-    """Kernel-path disqualifiers shared by flash/chunked/ring: the Pallas
+    """Kernel-path disqualifiers shared by flash/ring: the Pallas
     contract is plain (un-biased, un-windowed, scaled) square causal-or-not
     attention on 128-multiple sequences."""
     return (not site.has_bias and not site.has_window and site.square
@@ -248,13 +255,6 @@ def _train_ring(site):
     return (site.backend in ("ring", "ring_ulysses")
             and "sequence" in site.mesh_axes
             and not site.has_bias and not site.has_window and site.square)
-
-
-def _train_chunked(site):
-    return (site.phase == "train" and _kernel_shape_ok(site)
-            and site.scale_attn and site.causal
-            and flash_wanted(site.force_flash, site.q_len)
-            and site.chunk_min is not None and site.q_len >= site.chunk_min)
 
 
 def _train_flash(site):
@@ -318,15 +318,6 @@ def _run_flash(q, k, v, *, causal=True, sm_scale=None):
     return _per_shard(kernel, q)(q, k, v)
 
 
-def _run_chunked(q, k, v, *, causal=True, sm_scale=None):
-    import jax.numpy as jnp
-    from deepspeed_tpu.ops.chunked_attention import chunked_attention
-    out = chunked_attention(jnp.swapaxes(q, 1, 2), jnp.swapaxes(k, 1, 2),
-                            jnp.swapaxes(v, 1, 2), causal=causal,
-                            sm_scale=sm_scale)
-    return jnp.swapaxes(out, 1, 2)
-
-
 register_program(AttentionProgram(
     name="external", phases=("train",), priority=1000,
     matches=_train_external,
@@ -348,13 +339,6 @@ register_program(AttentionProgram(
     runner=_run_ring))
 
 register_program(AttentionProgram(
-    name="chunked", phases=("train",), priority=60,
-    matches=_train_chunked,
-    when="chunked_attn_min_seq set and T >= it (remat/memory escape "
-         "hatch; ~2.8x slower than flash)",
-    runner=_run_chunked))
-
-register_program(AttentionProgram(
     name="flash", phases=("train",), priority=50,
     matches=_train_flash,
     when=f"T >= {FLASH_MIN_SEQ} (auto) or use_flash_attention=True; "
@@ -370,12 +354,19 @@ register_program(AttentionProgram(
 
 # -- contiguous-cache decode ------------------------------------------------
 
+
+def _run_decode(q, cache_k, cache_v, pos, *, sm_scale=None):
+    from deepspeed_tpu.ops.pallas.decode_attention import decode_attention
+    return decode_attention(q, cache_k, cache_v, pos, sm_scale=sm_scale)
+
+
 register_program(AttentionProgram(
     name="decode_kernel", phases=("decode",), priority=50,
     matches=lambda s: (not s.has_bias and not s.has_window
                        and decode_kernel_wanted(s.force_flash, s.kv_len)),
     when=f"M >= {DECODE_KERNEL_MIN_CTX} and M % 128 == 0 (auto) or "
-         "use_flash_attention=True; no alibi/window"))
+         "use_flash_attention=True; no alibi/window",
+    runner=_run_decode))
 
 register_program(AttentionProgram(
     name="decode_dense", phases=("decode",), priority=0,
@@ -435,23 +426,72 @@ def kv_pool_writer(pool) -> str:
     return KV_POOL_WRITE_SCATTER
 
 
+# The paged runners, ONE signature (`AttentionProgram`): a decode walk takes
+# the one query row a slot and hands back the chunk layout.
+
+
+def _run_paged_decode(q, pool_l, block_tables, start, *, sm_scale=None,
+                      window=None, work=None, rank=None):
+    from deepspeed_tpu.ops.pallas.decode_attention import \
+        paged_decode_attention
+    B = q.shape[0]
+    return paged_decode_attention(
+        q[:, 0], pool_l["k"], pool_l["v"], block_tables, start,
+        sm_scale=sm_scale, work=work, window=window).reshape(B, 1, -1)
+
+
+def _run_paged_decode_quant(q, pool_l, block_tables, start, *, sm_scale=None,
+                            window=None, work=None, rank=None):
+    from deepspeed_tpu.ops.pallas.decode_attention import \
+        paged_decode_attention_quant
+    B = q.shape[0]
+    return paged_decode_attention_quant(
+        q[:, 0], pool_l["k"], pool_l["v"], pool_l["k_scale"],
+        pool_l["v_scale"], block_tables, start, sm_scale=sm_scale, work=work,
+        window=window).reshape(B, 1, -1)
+
+
+def _run_paged_prefill(q, pool_l, block_tables, start, *, sm_scale=None,
+                       window=None, work=None, rank=None):
+    from deepspeed_tpu.ops.pallas.prefill_attention import \
+        paged_prefill_attention
+    return paged_prefill_attention(q, pool_l["k"], pool_l["v"], block_tables,
+                                   start, sm_scale=sm_scale, window=window)
+
+
+def _run_mla_decode(q, pool_l, block_tables, start, *, sm_scale=None,
+                    window=None, work=None, rank=None):
+    from deepspeed_tpu.ops.pallas.mla_attention import mla_decode_attention
+    B = q.shape[0]
+    return mla_decode_attention(q[:, 0], pool_l["ckv"], block_tables, start,
+                                rank, sm_scale, work=work).reshape(B, 1, -1)
+
+
+def _run_mla_prefill(q, pool_l, block_tables, start, *, sm_scale=None,
+                     window=None, work=None, rank=None):
+    from deepspeed_tpu.ops.pallas.mla_attention import mla_prefill_attention
+    return mla_prefill_attention(q, pool_l["ckv"], block_tables, start, rank,
+                                 sm_scale)
+
+
 # A latent pool (MLA): the absorbed walks of `ops/pallas/mla_attention.py`.
 # They outrank every program above and match latent sites only, so a latent
 # site never selects a K/V program and no other site selects these.
-# `models/mla.py` dispatches them by name.
 register_program(AttentionProgram(
     name="mla_decode_kernel", phases=("paged_decode",), priority=90,
     matches=lambda s: s.latent and _paged_kernel_ok(s),
     when="latent pool + the paged kernel's conditions: absorbed walk over "
          "the live (slot, block) pairs, a block read once for scores and "
-         "values (dstpu_mla_decode)"))
+         "values (dstpu_mla_decode)",
+    runner=_run_mla_decode))
 
 register_program(AttentionProgram(
     name="mla_prefill_kernel", phases=("prefill_chunk",), priority=90,
     matches=lambda s: s.latent and _paged_prefill_ok(s),
     when="latent pool in the in-place form, C % 128 == 0, block % 128 == "
          "0: absorbed flash walk over the blocks under the chunk's frontier "
-         "(dstpu_mla_prefill)"))
+         "(dstpu_mla_prefill)",
+    runner=_run_mla_prefill))
 
 register_program(AttentionProgram(
     name="mla_gather", phases=("paged_decode", "prefill_chunk"), priority=80,
@@ -465,15 +505,16 @@ register_program(AttentionProgram(
     name="paged_kernel_quant", phases=("paged_decode",), priority=60,
     matches=lambda s: _paged_kernel_ok(s) and s.kv_dtype == "int8",
     when="int8 pool + kernel conditions: streamed tiles dequantize "
-         "in-kernel (paged_decode_attention_quant)"))
+         "in-kernel (paged_decode_attention_quant)",
+    runner=_run_paged_decode_quant))
 
 register_program(AttentionProgram(
     name="paged_kernel", phases=("paged_decode",), priority=50,
     matches=_paged_kernel_ok,
     when="C == 1, block % 128 == 0, effective context nb*block past the "
          "decode crossover; no alibi, no per-layer local flag (a static "
-         "window is the walk's lower bound and a mask)"))
-
+         "window is the walk's lower bound and a mask)",
+    runner=_run_paged_decode))
 
 
 def _paged_prefill_ok(site):
@@ -484,14 +525,6 @@ def _paged_prefill_ok(site):
     # walk costs what the blocks under the frontier cost.
     return (site.pool_in_place and not site.has_bias and not site.has_window
             and site.block_size % 128 == 0 and site.q_len % 128 == 0)
-
-
-def _run_paged_prefill(q, pool_l, block_tables, start, *, sm_scale=None,
-                       window=None):
-    from deepspeed_tpu.ops.pallas.prefill_attention import \
-        paged_prefill_attention
-    return paged_prefill_attention(q, pool_l["k"], pool_l["v"], block_tables,
-                                   start, sm_scale=sm_scale, window=window)
 
 
 register_program(AttentionProgram(

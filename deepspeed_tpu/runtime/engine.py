@@ -1752,8 +1752,8 @@ class Engine:
                 ]
                 self.telemetry.record_events(events)
                 if self.monitor is not None and self.monitor.enabled:
-                    from deepspeed_tpu.monitor.monitor import write_recovery_events
-                    write_recovery_events(self.monitor, events)
+                    from deepspeed_tpu.monitor.monitor import write_events_safe
+                    write_events_safe(self.monitor, events)
                 log_dist(f"rollback #{self.rollbacks} complete: resumed at "
                          f"step {self.global_steps} (cause: {cause})", ranks=[0])
                 return

@@ -1040,39 +1040,13 @@ class ServingMemScope(_MemScopeBase):
         return cats, info
 
     def _program_args(self):
-        import numpy as np
+        # the programs say what they are called with; a streamed engine's
+        # steps are host loops over per-layer jits — no whole-step
+        # executable to memory_analyze (the pool + resident categories,
+        # and the staging window the planner prices, cover the residents)
         s = self.serving
-        if getattr(s, "streamed", False):
-            # streamed (offloaded-weights) mode: the step "programs" are
-            # host loops over per-layer jits — no single whole-step
-            # executable exists to memory_analyze; the pool + resident
-            # categories (and the staging window, priced by the planner)
-            # still cover the residents
-            return
-        params, pool, rng = s.engine.params, s.pool, s._rng
-        S, chunk = s.max_slots, s.chunk
-
-        def i32(shape):
-            return np.zeros(shape, np.int32)
-
-        # a call's input tokens as the scheduler hands them: the call
-        # before's outputs (on the device), the source a slot, the host's
-        tok = (s._no_prev, i32((S,)), i32((S,)))
-        yield "decode_step", s._decode_step, \
-            (params, tok, i32((S,)), pool, np.asarray(s.tables), rng)
-        yield "prefill_step", s._prefill_step, \
-            (params, i32((1, chunk)), i32((1,)), i32((1,)), pool,
-             np.asarray(s.tables[:1]), rng)
-        if s._mixed_step is not None:
-            W = s.window
-            yield "mixed_step", s._mixed_step, \
-                (params, i32((W, 1, chunk)), i32((W, 1)), i32((W, 1)),
-                 i32((W, 1, s.tables.shape[1])), np.int32(1), tok,
-                 i32((S,)), pool, np.asarray(s.tables), rng)
-        if s._verify_step is not None:
-            yield "verify_step", s._verify_step, \
-                (params, i32((S, s.draft_k + 1)), i32((S,)), pool,
-                 np.asarray(s.tables), rng)
+        return s.programs.examples(s.engine.params, s.pool,
+                                   s._tables_arg(s.tables), s._rng)
 
     @staticmethod
     def _pool_geometry(pool):

@@ -1,7 +1,7 @@
 """Unified attention dispatch layer (`ops/attention_dispatch.py`).
 
 The PR 14 refactor: ONE registry decides which attention program every call
-site runs — training flash/chunked/ring/dense, contiguous decode, paged
+site runs — training flash/ring/dense, contiguous decode, paged
 decode (fp + int8), chunked prefill, spec-decode verify. These tests pin
 
   * the selection table (phase × shape × flags × backend → program),
@@ -9,7 +9,8 @@ decode (fp + int8), chunked prefill, spec-decode verify. These tests pin
     copy of the flash/decode engage predicates anymore, so the historical
     two-copies-drift failure mode (gpt.py:436 vs :855) is structurally
     impossible — monkeypatching the ONE predicate flips every call site,
-  * registry extensibility (a program registered at runtime is selectable),
+  * registry extensibility (a program registered at runtime is selectable,
+    and RUN BY ITS RUNNER at every kind of site: no site knows a name),
   * compile-stability: selection is pure trace-time — a serving engine
     still compiles exactly one program per bucket.
 """
@@ -31,7 +32,7 @@ pytestmark = pytest.mark.longctx
 def site(**kw):
     base = dict(phase="train", q_len=2048, kv_len=2048, causal=True,
                 has_bias=False, has_window=False, scale_attn=True,
-                mesh_axes=(), force_flash=None, chunk_min=None,
+                mesh_axes=(), force_flash=None,
                 backend=None, external_fn=False)
     base.update(kw)
     return ad.AttnSite(**base)
@@ -52,10 +53,6 @@ class TestSelectionTable:
         assert ad.select(site(scale_attn=False)) == "dense"    # GPT-Neo
         assert ad.select(site(q_len=2000, kv_len=2000)) == "dense"  # %128
         assert ad.select(site(kv_len=4096)) == "dense"         # non-square
-
-    def test_train_chunked_escape_hatch(self):
-        assert ad.select(site(chunk_min=2048)) == "chunked"
-        assert ad.select(site(chunk_min=4096)) == "flash"      # below it
 
     def test_train_external_fn_always_wins(self):
         assert ad.select(site(external_fn=True)) == "external"
@@ -151,15 +148,34 @@ class TestSelectionTable:
                     block_size=512, pool_in_place=True)
         assert ad.select(site(**{**base, **changes})) == program
 
-    def test_the_prefill_kernel_is_the_paged_program_with_a_runner(self):
-        paged = [p for p in ad.registered_programs()
-                 if set(p.phases) & {"prefill_chunk", "paged_decode",
-                                     "verify"}]
-        assert [p.name for p in paged if p.runner is not None] \
-            == ["paged_prefill_kernel"]
-        names = [n for n, _ in ad.dispatch_table()["prefill_chunk"]]
-        assert names.index("paged_prefill_kernel") \
-            < names.index("paged_gather")
+    # a kernel program and a site it is built for
+    KERNEL_SITES = {
+        "paged_prefill_kernel": dict(phase="prefill_chunk", q_len=512,
+                                     kv_len=16384, block_size=512,
+                                     pool_in_place=True),
+        "paged_kernel": dict(phase="paged_decode", q_len=1, kv_len=8192,
+                             block_size=128),
+        "paged_kernel_quant": dict(phase="paged_decode", q_len=1, kv_len=8192,
+                                   block_size=128, kv_dtype="int8"),
+        "mla_decode_kernel": dict(phase="paged_decode", q_len=1, kv_len=8192,
+                                  block_size=128, latent=True),
+        "mla_prefill_kernel": dict(phase="prefill_chunk", q_len=512,
+                                   kv_len=16384, block_size=512,
+                                   pool_in_place=True, latent=True),
+        "decode_kernel": dict(phase="decode", q_len=1, kv_len=8192),
+    }
+
+    @pytest.mark.parametrize("name", sorted(KERNEL_SITES))
+    def test_a_kernel_program_has_a_runner_and_outranks_the_oracles(self,
+                                                                    name):
+        """Every kernel is run by its runner; what has none is a site's own
+        dense / gather oracle, and ranks under every kernel of its sites."""
+        prog, at = ad.get_program(name), site(**self.KERNEL_SITES[name])
+        assert prog.runner is not None
+        assert ad.select(at) == name
+        oracles = [p for p in ad.registered_programs(at.phase)
+                   if p.runner is None and p.matches(at)]
+        assert oracles and all(p.priority < prog.priority for p in oracles)
 
     def test_dispatch_table_is_total_and_ordered(self):
         table = ad.dispatch_table()
@@ -344,6 +360,137 @@ class TestRegistryExtensibility:
         finally:
             ad._REGISTRY.pop("test_variant", None)
 
+    # a site of each kind beside the training one: how the test's program
+    # knows it (a shape nothing else uses), the model function that holds
+    # it, and what the runner must be handed
+    @staticmethod
+    def _gpt_spec():
+        from deepspeed_tpu.models.gpt import GPTConfig, make_gpt_decode_model
+        return make_gpt_decode_model(cfg=GPTConfig(
+            n_layer=1, n_head=2, d_model=64, max_seq_len=256, vocab_size=128,
+            dtype=jnp.float32, remat=False))
+
+    @staticmethod
+    def _latent_spec():
+        from deepspeed_tpu.models import glm4_moe_lite as gm
+        from tests import glm_cases
+        cfg = glm_cases._cfg(layers=2)
+        return gm.make_glm4_moe_lite_decode_model(
+            cfg, params=glm_cases._params(cfg), name="tiny"), cfg
+
+    def _paged_call(self, spec, pool, C):
+        """Trace `spec`'s decode (C == 1) or chunk program over `pool`."""
+        i32 = jnp.int32
+        if C == 1:
+            jax.eval_shape(spec.decode_paged_fn, spec.params,
+                           jnp.zeros((3,), i32), jnp.full((3,), 50, i32),
+                           pool, jnp.ones((3, 2), i32))
+        else:
+            jax.eval_shape(spec.prefill_paged_fn, spec.params,
+                           jnp.zeros((1, C), i32), jnp.zeros((1,), i32),
+                           jnp.zeros((1,), i32), pool, jnp.ones((1, 2), i32))
+
+    @pytest.mark.parametrize("case", ["paged_decode", "prefill_chunk",
+                                      "latent_decode", "latent_chunk"])
+    def test_a_paged_site_runs_a_registered_runner(self, case):
+        """`(q, pool_l, block_tables, start, *, sm_scale, window, work)`,
+        and `rank` at a latent site: q [B, C, H, hd], the pool's leaves, the
+        tables, each row's first position; the decode sites hand the work
+        list they built outside their layer loop."""
+        latent = case.startswith("latent")
+        C = 1 if case.endswith("decode") else 48
+        B = 3 if C == 1 else 1
+        calls = []
+
+        def runner(q, pool_l, block_tables, start, *, sm_scale, window,
+                   work=None, rank=None):
+            calls.append(dict(q=q.shape, leaves=sorted(pool_l),
+                              tables=block_tables.shape, start=start.shape,
+                              sm_scale=sm_scale, window=window,
+                              work=work is not None, rank=rank))
+            return jnp.zeros(q.shape[:2] + (q.shape[2] * (rank or q.shape[3]),),
+                             q.dtype)
+
+        ad.register_program(ad.AttentionProgram(
+            name="test_variant", phases=("paged_decode", "prefill_chunk"),
+            priority=999, when="test fixture", runner=runner,
+            matches=lambda s: s.block_size == 48 and s.latent == latent))
+        try:
+            if latent:
+                spec, cfg = self._latent_spec()
+                pool = spec.init_paged_pool(5, 48, jnp.float32)
+                self._paged_call(spec, pool, C)
+                assert len(calls) == cfg.n_layer
+                want = dict(q=(B, C, cfg.n_head, pool["ckv"].shape[-1]),
+                            leaves=["ckv"],
+                            rank=cfg.kv_lora_rank,
+                            sm_scale=1 / np.sqrt(cfg.head_dim))
+            else:
+                spec = self._gpt_spec()
+                self._paged_call(spec, spec.init_paged_pool(5, 48,
+                                                            jnp.float32), C)
+                assert len(calls) == 1
+                want = dict(q=(B, C, 2, 32), leaves=["k", "v"], rank=None,
+                            sm_scale=None)
+            assert calls[0] == dict(want, tables=(B, 2), start=(B,),
+                                    window=None, work=C == 1)
+            assert spec.paged_attn_programs[case.replace("latent_", "paged_")
+                                            if C == 1 else "prefill_chunk"] \
+                == "test_variant"
+        finally:
+            ad._REGISTRY.pop("test_variant", None)
+
+    def test_the_contiguous_decode_site_runs_a_registered_runner(self):
+        """`(q, cache_k, cache_v, pos, *, sm_scale)`: q [B, H, hd] and the
+        head-major cache, the new token already written."""
+        calls = []
+
+        def runner(q, cache_k, cache_v, pos, *, sm_scale):
+            calls.append((q.shape, cache_k.shape, cache_v.shape, pos.shape,
+                          sm_scale))
+            return jnp.zeros_like(q)
+
+        ad.register_program(ad.AttentionProgram(
+            name="test_variant", phases=("decode",), priority=999,
+            matches=lambda s: s.kv_len == 136, when="test fixture",
+            runner=runner))
+        try:
+            spec = self._gpt_spec()
+            jax.eval_shape(spec.decode_fn, spec.params,
+                           jnp.zeros((3,), jnp.int32),
+                           jnp.zeros((3,), jnp.int32),
+                           spec.init_cache(3, 136, jnp.float32))
+            assert calls == [((3, 2, 32), (3, 2, 136, 32), (3, 2, 136, 32),
+                              (3,), None)]
+        finally:
+            ad._REGISTRY.pop("test_variant", None)
+
+    def test_a_runnerless_program_on_an_int8_pool_dequantizes(self):
+        """What has no runner is the site's gather oracle, which reads what
+        the POOL holds: a program of any name on an int8 pool attends the
+        dequantized K/V (no name can read an int8 payload as K/V)."""
+        spec = self._gpt_spec()
+        rng = np.random.default_rng(0)
+        pool = spec.init_paged_pool(5, 48, jnp.int8, 32)
+        pool = {leaf: jnp.asarray(
+            rng.integers(-127, 128, x.shape) if x.dtype == jnp.int8
+            else rng.uniform(0.01, 0.02, x.shape), x.dtype)
+            for leaf, x in pool.items()}
+        args = (spec.params, jnp.asarray([5, 9, 3], jnp.int32),
+                jnp.full((3,), 50, jnp.int32), pool,
+                jnp.asarray([[1, 2], [3, 4], [1, 3]], jnp.int32))
+        want, _ = spec.decode_paged_fn(*args)
+        assert spec.paged_attn_programs["paged_decode"] == "paged_gather_quant"
+        ad.register_program(ad.AttentionProgram(
+            name="test_variant", phases=("paged_decode",), priority=999,
+            matches=lambda s: s.block_size == 48, when="test fixture"))
+        try:
+            got, _ = spec.decode_paged_fn(*args)
+            assert spec.paged_attn_programs["paged_decode"] == "test_variant"
+            np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+        finally:
+            ad._REGISTRY.pop("test_variant", None)
+
     def test_selection_is_total(self):
         for phase in ("train", "decode", "paged_decode", "prefill_chunk",
                       "verify"):
@@ -469,3 +616,57 @@ class TestFlashRunsPerShard:
         assert "shard_map" in self._jaxpr(q, k, v)
         with mesh_mod.constraints_disabled():          # a shard_map body
             assert "shard_map" not in self._jaxpr(q, k, v)
+
+
+# ----------------------------------------------------------------------
+# the streaming flash kernel has no sequence cap (the bound the chunked
+# escape hatch, deleted in PR 46, existed for)
+# ----------------------------------------------------------------------
+
+
+# the retired whole-slab VMEM cap: 4 double-buffered [T, D] k/v slabs in
+# ~14 MiB of scoped VMEM (the bound the streaming kernels removed)
+def _legacy_vmem_cap(d_head, itemsize):
+    return (14 * 2**20) // (4 * d_head * itemsize)
+
+
+def test_flash_streams_past_legacy_vmem_domain():
+    """The HBM-streaming kernel has no whole-slab VMEM cap: seq 16384 at
+    head_dim 128 bf16 (the shape that used to raise "VMEM domain") traces
+    through the Pallas kernel, and flash_max_seq now reports the HBM-scale
+    bound."""
+    from deepspeed_tpu.ops.pallas.flash_attention import (flash_attention,
+                                                          flash_max_seq)
+    legacy = _legacy_vmem_cap(128, 2)
+    assert 8192 <= legacy < 16384, legacy
+    cap = flash_max_seq(128, 2)
+    assert cap > 1_000_000, cap  # HBM-bound: millions of tokens, not ~14k
+    q = jnp.zeros((1, 16384, 2, 128), jnp.bfloat16)
+    jaxpr = str(jax.make_jaxpr(
+        lambda q: flash_attention(q, q, q, causal=True))(q))
+    assert "pallas_call" in jaxpr
+
+
+def test_gpt_auto_dispatch_stays_in_kernel_beyond_legacy_cap():
+    """models/gpt._attention: T past the legacy VMEM cap stays on the
+    streaming flash kernel (the old routing degraded to a ~2.8x-slower
+    rematerialized XLA fallback)."""
+    from deepspeed_tpu.models.gpt import GPTConfig, gpt_forward, gpt_loss
+    from deepspeed_tpu.models.gpt import init_gpt_params
+    # tiny dims but a REAL beyond-legacy-cap T for head_dim 512 (the cap
+    # scaled with 1/head_dim, so a modest T exercises the branch cheaply)
+    hd = 512
+    legacy = _legacy_vmem_cap(hd, 4)  # fp32 params -> itemsize 4
+    T = 2048
+    assert T > legacy, (T, legacy)
+    cfg = GPTConfig(n_layer=1, n_head=1, d_model=hd, d_ff=512, max_seq_len=T,
+                    vocab_size=256, dtype=jnp.float32, remat=False)
+    params = init_gpt_params(cfg, seed=0)
+    toks = jnp.zeros((1, T), jnp.int32)
+    jaxpr = str(jax.make_jaxpr(
+        lambda p, t: gpt_forward(p, t, cfg))(params, toks))
+    assert "pallas_call" in jaxpr, "beyond-legacy-cap T left the kernel path"
+    # and the kernel path trains: finite loss at a beyond-legacy-cap T
+    rtoks = np.random.default_rng(0).integers(0, 256, (1, T + 1)).astype(np.int32)
+    loss = float(gpt_loss(params, {"tokens": rtoks}, None, cfg=cfg))
+    assert np.isfinite(loss)

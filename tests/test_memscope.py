@@ -468,7 +468,7 @@ def test_injected_oom_dumps_ledger_and_flight_events(tmp_path):
         raise RuntimeError("RESOURCE_EXHAUSTED: Out of memory "
                            "allocating 12345 bytes")
 
-    serving._decode_step = boom
+    serving.programs.decode = boom
     serving.submit(Request(uid=99, tokens=np.arange(9, dtype=np.int32),
                            max_new_tokens=4, stop_on_eos=False))
     with pytest.raises(RuntimeError, match="RESOURCE_EXHAUSTED"):
@@ -493,7 +493,7 @@ def test_injected_oom_dumps_ledger_and_flight_events(tmp_path):
     serving2 = _mk_engine(telemetry=_tel(tmp_path / "b",
                                          memscope_programs=False)) \
         .serving(max_slots=2, max_context=128)
-    serving2._decode_step = lambda *a, **k: (_ for _ in ()).throw(
+    serving2.programs.decode = lambda *a, **k: (_ for _ in ()).throw(
         ValueError("not an OOM"))
     serving2.submit(Request(uid=1, tokens=np.arange(9, dtype=np.int32),
                             max_new_tokens=4, stop_on_eos=False))

@@ -389,7 +389,7 @@ def test_the_rule_is_what_the_step_holds():
 
 def test_spec_decode_never_engages_it():
     serving = _dense(spec_decode={"drafter": "ngram", "draft_k": 2})
-    assert serving._mixed_step is None
+    assert serving.programs.mixed is None
     serving.run(_requests())
     assert serving.fused_chunks == 0
     assert "mixed_step" not in serving.compile_stats()
@@ -438,7 +438,7 @@ def test_streamed_serving_never_engages_it():
                                              "lookahead": 2}})
     serving = engine.serving(max_slots=2, max_context=128,
                              prefill_chunk=CHUNK)
-    assert serving.streamed and serving._mixed_step is None
+    assert serving.streamed and serving.programs.mixed is None
     serving.run(_requests(LENGTHS[:3], NEW[:3]))
     assert serving.fused_chunks == 0
     assert all(r.fused_chunks == 0 for r in serving.steptrace.records())
@@ -455,3 +455,106 @@ def test_a_model_without_the_mixed_program_keeps_its_two_calls():
     done = serving.run(_requests())
     assert serving.fused_chunks == 0
     assert _tokens(done) == _tokens(_dense().run(_requests()))
+
+
+# ----------------------------------------------------------------------
+# one shape, one home (`inference/step_programs.py`): every step program
+# returns ((tokens...), counts), pool — counts the model's counters or ()
+# ----------------------------------------------------------------------
+
+
+def _examples(serving):
+    return {name: (fn, args) for name, fn, args in serving.programs.examples(
+        serving.engine.params, serving.pool,
+        serving._tables_arg(serving.tables), serving._rng)}
+
+
+@pytest.mark.parametrize("family", ["dense", "routed"])
+@pytest.mark.parametrize("program", ["decode_step", "prefill_step",
+                                     "mixed_step"])
+def test_a_step_program_returns_tokens_counts_and_pool(family, program):
+    serving = FAMILIES[family](decode_steps_per_sync=2)
+    fn, args = _examples(serving)[program]
+    (toks, counts), pool = jax.eval_shape(fn, *args)
+    S, W = serving.max_slots, serving.window
+    assert jax.tree_util.tree_map(lambda t: t.shape, toks) == {
+        "decode_step": (S, W), "prefill_step": (1,),
+        "mixed_step": ((W,), (S, W))}[program]
+    names = serving.step_counter_names
+    assert bool(names) == (family == "routed")
+    if names:
+        assert counts.shape == (len(names),) and counts.dtype == jnp.int32
+    else:
+        assert counts == ()
+    assert jax.tree_util.tree_map(lambda x: (x.shape, x.dtype), pool) \
+        == jax.tree_util.tree_map(lambda x: (x.shape, x.dtype), serving.pool)
+
+
+@pytest.mark.parametrize("family", ["dense", "routed"])
+def test_counters_are_reported_only_where_the_model_names_some(family):
+    """The programs' arity is one; what is REPORTED is not: a model without
+    counters keeps `StepRecord.counters == ()` and no `step_counters` key."""
+    serving = FAMILIES[family](decode_steps_per_sync=2)
+    requests = _requests(LENGTHS[:4], NEW[:4])
+    done = serving.run(requests)
+    assert {uid: len(d.tokens) for uid, d in done.items()} \
+        == {r.uid: r.max_new_tokens for r in requests}
+    assert serving.fused_chunks and serving._parked_counts == []
+    records, names = serving.steptrace.records(), serving.step_counter_names
+    if family == "dense":
+        assert names == () and all(r.counters == () for r in records)
+        assert "step_counters" not in serving.stats()
+    else:
+        assert all(len(r.counters) == len(names) for r in records)
+        totals = np.sum([r.counters for r in records], axis=0)
+        assert dict(zip(names, totals)) == serving.stats()["step_counters"]
+        # every token of every call routed once a layer, chunks included
+        assert totals[names.index("moe_router_calls")] > 0
+
+
+def _memscope_programs(serving):
+    from deepspeed_tpu.telemetry.memscope import ServingMemScope
+    return [name for name, _, _ in ServingMemScope(serving)._program_args()]
+
+
+def test_the_programs_name_themselves_once_resident():
+    """`compile_stats()`, the compile count a step and memscope read ONE
+    list, the programs' own."""
+    serving = _dense(decode_steps_per_sync=2)
+    built = [name for name, _ in serving.programs.built()]
+    assert built == ["decode_step", "prefill_step", "mixed_step"]
+    assert _memscope_programs(serving) == built
+    assert serving.compile_stats() == {"decode_step": 0, "prefill_step": 0}
+    serving.run(_requests(LENGTHS[:3], NEW[:3]))
+    assert serving.fused_chunks
+    assert list(serving.compile_stats()) == built
+    assert serving._compiled_programs() == 3
+    w1 = serving.programs.decode_w1()
+    assert w1 is serving.programs.decode_w1() is not serving.programs.decode
+    assert [name for name, _ in serving.programs.built()] \
+        == built + ["decode_step_w1"]
+    assert _memscope_programs(serving) == built + ["decode_step_w1"]
+    assert serving.compile_stats()["decode_step_w1"] == 0
+
+
+def test_the_programs_name_themselves_once_streamed():
+    from deepspeed_tpu.models.gpt import make_gpt_layered_model
+    _one_device()
+    plain = GPTConfig(n_layer=2, n_head=4, d_model=64, max_seq_len=256,
+                      vocab_size=256, dtype=jnp.float32, remat=False)
+    engine = _engine(make_gpt_layered_model(cfg=plain, name="spill"),
+                     zero={"offload_param": {"device": "cpu",
+                                             "lookahead": 2}})
+    serving = engine.serving(max_slots=2, max_context=128,
+                             prefill_chunk=CHUNK)
+    built = [name for name, _ in serving.programs.built()]
+    assert sorted(built) == sorted(
+        f"{role}_{phase}" for role in ("embed", "layer", "head")
+        for phase in ("prefill", "decode"))
+    serving.run(_requests(LENGTHS[:2], NEW[:2]))
+    assert serving.compile_stats() == dict.fromkeys(built, 1)
+    # its steps are host loops: no whole-step executable to analyse, and
+    # the window is one token already
+    assert _memscope_programs(serving) == []
+    assert serving.programs.decode_w1() is serving.programs.decode
+    assert all(r.counters == () for r in serving.steptrace.records())

@@ -628,8 +628,8 @@ def test_compiles_names_the_step_that_compiled():
     assert serving.compile_stats() == {"decode_step": 1, "prefill_step": 1,
                                        "mixed_step": 1}
     # a program replaced by a plain function (fault injection) counts 0
-    jitted = serving._decode_step
-    serving._decode_step = lambda *a: jitted(*a)
+    jitted = serving.programs.decode
+    serving.programs.decode = lambda *a: jitted(*a)
     assert serving._compiled_programs() == 2
     serving.run(_requests(1, seed=1))
     assert serving.steptrace.records()[-1].compiles == 0

@@ -394,10 +394,10 @@ def test_monitor_bridge_flattens_and_never_dies(tmp_path):
     MonitorBridge(bad).export(reg, step=6)     # does not raise
 
 
-def test_write_events_safe_aliases():
+def test_write_events_safe_is_the_one_name():
     from deepspeed_tpu.monitor import monitor as M
-    assert M.write_recovery_events is M.write_events_safe
-    assert M.write_serving_events is M.write_events_safe
+    assert not hasattr(M, "write_recovery_events")
+    assert not hasattr(M, "write_serving_events")
     M.write_events_safe(None, [("a", 1.0, 0)])          # no monitor: no-op
     def boom(_evs):
         raise RuntimeError("die")

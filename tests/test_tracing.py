@@ -293,8 +293,8 @@ def test_disabled_default_no_tracing_work(tmp_path, monkeypatch):
     # the shared disabled singletons, and every record site gates on them
     assert serving.tracer is NULL_TRACER and not serving.tracer.enabled
     assert not serving.flightrec.enabled
-    assert not isinstance(serving._decode_step, _WatchedProgram)
-    assert not isinstance(serving._prefill_step, _WatchedProgram)
+    assert not isinstance(serving.programs.decode, _WatchedProgram)
+    assert not isinstance(serving.programs.prefill, _WatchedProgram)
     rng = np.random.default_rng(0)
     serving.submit(Request(uid=0,
                            tokens=rng.integers(0, 256, (9,)).astype(np.int32),
@@ -340,9 +340,9 @@ def test_compile_watchdog_names_recompiled_program(engine, tmp_path):
     tok = np.zeros((S1,), np.int32)
     pos = np.ones((S1,), np.int32)
     tables = np.full((S1, serving.nb), TRASH_BLOCK, np.int32)
-    _, serving.pool = serving._decode_step(eng2.params, tok, pos,
-                                           serving.pool, tables,
-                                           serving._next_rng())
+    _, serving.pool = serving.programs.decode(eng2.params, tok, pos,
+                                               serving.pool, tables,
+                                               serving._next_rng())
     wd = serving.stats()["watchdog"]
     assert wd["recompiles"] == 1
     assert wd["programs"]["decode_step"]["recompiles"] == 1
