@@ -169,8 +169,9 @@ def make_span_gpt_fns(cfg, mesh=None, axis_name=SEQ_AXIS):
     P(None, `sequence`, ...) (the physical-block axis sharded — see
     `SpanKVPool`); `span_tables` hold LOCAL physical ids per shard. Layers
     scan exactly like `_scan_paged`, so depth stays out of compile time."""
-    from deepspeed_tpu.models.gpt import (_decode_qkv, _embed, _lm_head,
-                                          _residual_mlp, score_scale)
+    from deepspeed_tpu.models.gpt import (_decode_qkv, _embed, _gate_output,
+                                          _lm_head, _residual_mlp,
+                                          score_scale)
     mesh = mesh or mesh_mod.get_mesh()
     if cfg.use_alibi or cfg.sliding_window:
         raise ValueError(
@@ -184,7 +185,7 @@ def make_span_gpt_fns(cfg, mesh=None, axis_name=SEQ_AXIS):
 
     def _span_half(x, p, pool_l, positions, span_tables):
         bs = pool_l["k"].shape[2]
-        q, k, v = _decode_qkv(x, p, positions, cfg)
+        q, k, v, gate = _decode_qkv(x, p, positions, cfg)
         fn = shard_map(
             partial(_span_attn_local, axis_name=axis_name, bs=bs,
                     scale=scale),
@@ -196,7 +197,8 @@ def make_span_gpt_fns(cfg, mesh=None, axis_name=SEQ_AXIS):
         attn, pk, pv = fn(q, k, v, pool_l["k"], pool_l["v"], span_tables,
                           positions)
         pool_l = dict(pool_l, k=pk, v=pv)
-        attn_out = attn @ p["attn_out_w"] + p["attn_out_b"]
+        attn_out = _gate_output(attn, gate) @ p["attn_out_w"] \
+            + p["attn_out_b"]
         return attn_out, pool_l
 
     def _scan_span(params, x, pool, span_tables, positions):

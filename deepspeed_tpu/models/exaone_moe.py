@@ -355,7 +355,9 @@ def _swiglu(h, gate_w, up_w, down_w):
 
 def _sparse_mlp(h, p, cfg: ExaoneMoEConfig, stacks=None, expert_base=0):
     """The routed half of a sparse layer on h [B, T, D] -> (out, counters
-    int32[5] in `HELD_ROUTED_COUNTERS` order, chosen experts [B*T, top_k]).
+    int32[5] in `HELD_ROUTED_COUNTERS` order, chosen experts [B*T, top_k]);
+    the shared expert's result times `sigmoid(h w_s)` where `p` has a
+    `shared_scale_w` [D].
     `stacks`: the experts' weights where they are not `p`'s own leaves —
     `{"w_gate_up": [n * held, D, 2F], "w_down": ...}`, a whole stack of the
     scanned layers' experts, with `expert_base` where this layer's begin."""
@@ -371,8 +373,13 @@ def _sparse_mlp(h, p, cfg: ExaoneMoEConfig, stacks=None, expert_base=0):
                                    expert_base=expert_base,
                                    held=cfg.experts_held)
     with jax.named_scope("moe/shared_expert"):
-        out = out + _swiglu(xf, p["shared_gate_w"], p["shared_up_w"],
-                            p["shared_down_w"])
+        shared = _swiglu(xf, p["shared_gate_w"], p["shared_up_w"],
+                         p["shared_down_w"])
+        if "shared_scale_w" in p:       # Qwen3-Next: times a scalar a token
+            shared = shared * jax.nn.sigmoid(
+                (xf @ p["shared_scale_w"]).astype(jnp.float32)
+            )[:, None].astype(shared.dtype)
+        out = out + shared
     return out.reshape(B, T, D), counters, top_e
 
 

@@ -76,6 +76,11 @@ class GPTConfig:
                                      # head's columns of q and k (one scale
                                      # vector of head_dim shared by the
                                      # heads), after the split, before rotary
+    attn_output_gate: bool = False   # Qwen3-Next: the query projection is twice
+                                     # as wide and its second half GATES the
+                                     # attention's result, `attn *
+                                     # sigmoid(gate)` before the out-projection
+                                     # (`attn_qkv_w`'s columns: q | k | v | gate)
     post_norm: bool = False          # EXAONE-4 family: no norm in front of a
                                      # half; `x + norm(attn(x))`, then
                                      # `h + norm(mlp(h))` (ln1/ln2 scale the
@@ -162,8 +167,10 @@ class GPTConfig:
 
     @property
     def qkv_dim(self):
-        """Fused qkv output width: H*hd for q + 2*Hkv*hd for k,v (GQA-aware)."""
-        return (self.n_head + 2 * self.n_kv_head) * self.head_dim
+        """Fused qkv output width: H*hd for q + 2*Hkv*hd for k,v (GQA-aware),
+        and H*hd more where the output is gated (`attn_output_gate`)."""
+        return ((1 + self.attn_output_gate) * self.n_head
+                + 2 * self.n_kv_head) * self.head_dim
 
     def num_params(self):
         wpe = 0 if self.use_rotary else self.max_seq_len * self.d_model
@@ -580,6 +587,25 @@ def _qk_norm(q, k, p, cfg: GPTConfig, heads_split=False):
             _norm(k, p["k_norm_scale"], None, True, cfg.norm_eps))
 
 
+def _split_qkv(qkv, cfg: GPTConfig):
+    """The fused projection's columns -> (q, k, v, gate): `gate` [.., H*hd]
+    under `cfg.attn_output_gate`, else None."""
+    H, Hkv, hd = cfg.n_head, cfg.n_kv_head, cfg.head_dim
+    q, k, v, gate = jnp.split(
+        qkv, [H * hd, (H + Hkv) * hd, (H + 2 * Hkv) * hd], axis=-1)
+    return q, k, v, (gate if cfg.attn_output_gate else None)
+
+
+def _gate_output(attn, gate):
+    """`attn * sigmoid(gate)` on the heads' results [.., H*hd] in front of
+    the out-projection; `gate` None (no `attn_output_gate`): `attn`."""
+    if gate is None:
+        return attn
+    with jax.named_scope("attn/gate"):
+        return attn * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(
+            attn.dtype)
+
+
 def _half_input(x, p, cfg: GPTConfig):
     """What the attention half reads: ln1(x), or x itself under
     `cfg.post_norm` (the norm then follows the half, `_residual_mlp`)."""
@@ -611,7 +637,7 @@ def _attn_half(x, p, cfg: GPTConfig, positions, attn_fn=None, constrain=True,
     h = _half_input(x, p, cfg)
     h = _act_quant(h, cfg)
     qkv = _ckpt_name(h @ p["attn_qkv_w"] + p["attn_qkv_b"], "qkv_proj")
-    q, k, v = jnp.split(qkv, [H * hd, (H + Hkv) * hd], axis=-1)
+    q, k, v, gate = _split_qkv(qkv, cfg)
     q, k = _qk_norm(q, k, p, cfg)
     q = q.reshape(B, T, H, hd)
     k = k.reshape(B, T, Hkv, hd)
@@ -638,7 +664,8 @@ def _attn_half(x, p, cfg: GPTConfig, positions, attn_fn=None, constrain=True,
     # alibi uses in-sequence distances (standard unpadded formulation)
     bias = _alibi_bias(cfg, t_pos, t_pos) if cfg.use_alibi else None
     attn = _attention(q, k, v, causal, cfg, attn_fn=attn_fn, bias=bias)
-    attn_flat = _act_quant(attn.reshape(B, T, H * hd), cfg)
+    attn_flat = _act_quant(_gate_output(attn.reshape(B, T, H * hd), gate),
+                           cfg)
     attn_out = _ckpt_name(
         attn_flat @ p["attn_out_w"] + p["attn_out_b"], "attn_out")
     return attn_out, k, v
@@ -913,14 +940,15 @@ def _decode_qkv(x, p, positions, cfg: GPTConfig):
     """Shared decode-path preamble: ln1 -> fused qkv -> split/reshape ->
     rope at absolute positions. One definition for the contiguous-cache
     half AND the paged half — a rope/GQA change cannot diverge them.
-    x: [B, C, D]; positions: [B, C]. Returns q [B,C,H,hd], k/v [B,C,Hkv,hd].
+    x: [B, C, D]; positions: [B, C]. Returns q [B,C,H,hd], k/v [B,C,Hkv,hd]
+    and the output's gate [B,C,H*hd] (`_gate_output`'s; None without one).
     (The training `_attn_half` stays separate: it additionally threads
     act-quant gates, remat checkpoint names, and shard constraints.)"""
     B, C, _ = x.shape
     H, Hkv, hd = cfg.n_head, cfg.n_kv_head, cfg.head_dim
     h = _half_input(x, p, cfg)
     qkv = h @ p["attn_qkv_w"] + p["attn_qkv_b"]
-    q, k, v = jnp.split(qkv, [H * hd, (H + Hkv) * hd], axis=-1)
+    q, k, v, gate = _split_qkv(qkv, cfg)
     q, k = _qk_norm(q, k, p, cfg)
     q = q.reshape(B, C, H, hd)
     k = k.reshape(B, C, Hkv, hd)
@@ -930,7 +958,7 @@ def _decode_qkv(x, p, positions, cfg: GPTConfig):
         rd = int(cfg.rotary_pct * hd) // 2 * 2
         q = _rope(q, positions, rd, cfg.rope_theta)
         k = _rope(k, positions, rd, cfg.rope_theta)
-    return q, k, v
+    return q, k, v, gate
 
 
 def _static_window(cfg: GPTConfig):
@@ -971,7 +999,7 @@ def _decode_attn_half(x, p, cache_k, cache_v, pos, cfg: GPTConfig,
     B, _, D = x.shape
     H, Hkv, hd = cfg.n_head, cfg.n_kv_head, cfg.head_dim
     M = cache_k.shape[2]
-    q, k, v = _decode_qkv(x, p, pos[:, None], cfg)
+    q, k, v, gate = _decode_qkv(x, p, pos[:, None], cfg)
 
     # write k,v at pos via one-hot masked rewrite. Counterintuitive but
     # measured: streaming the whole [B,Hkv,M,hd] cache through fused
@@ -1024,7 +1052,7 @@ def _decode_attn_half(x, p, cache_k, cache_v, pos, cfg: GPTConfig,
         logits = jnp.where(valid[:, None, None, :], logits, -1e30)
         probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
         attn = jnp.einsum("bkgm,bkmd->bkgd", probs, cache_v).reshape(B, 1, D)
-    attn_out = attn @ p["attn_out_w"] + p["attn_out_b"]
+    attn_out = _gate_output(attn, gate) @ p["attn_out_w"] + p["attn_out_b"]
     return attn_out, cache_k, cache_v
 
 
@@ -1452,7 +1480,7 @@ def _paged_attn_half(x, p, pool_l, positions, block_tables,
     the dequantizing gather oracle — one shared numeric definition, so the
     two are parity-testable tile for tile.
     """
-    q, k, v = _decode_qkv(x, p, positions, cfg)
+    q, k, v, gate = _decode_qkv(x, p, positions, cfg)
     group = partial(_paged_write_attend, cfg=cfg, local_flag=local_flag,
                     block_base=block_base, attn_programs=attn_programs)
     if isinstance(block_tables, MixedTables):
@@ -1481,7 +1509,7 @@ def _paged_attn_half(x, p, pool_l, positions, block_tables,
     else:
         attn, pool_l = group(q, k, v, pool_l, positions, block_tables,
                              phase=phase, decode_work=decode_work)
-    attn_out = attn @ p["attn_out_w"] + p["attn_out_b"]
+    attn_out = _gate_output(attn, gate) @ p["attn_out_w"] + p["attn_out_b"]
     return attn_out, pool_l
 
 

@@ -1,8 +1,9 @@
 """The hybrid layer loop — ONE loop for the families whose stack mixes
-Mamba-2 layers, a few attention layers and routed experts, on the paged
-serving path: `models/nemotron_h.py` (a layer is a mixer OR a feed-forward
-part alone) and `models/granite_moe_hybrid.py` (a layer is a mixer AND the
-routed experts) give it their data and run the same code.
+recurrent layers (Mamba-2, or Gated DeltaNet), a few attention layers and
+routed experts, on the paged serving path: `models/nemotron_h.py` (a layer
+is a mixer OR a feed-forward part alone), `models/granite_moe_hybrid.py` and
+`models/qwen3_next.py` (a layer is a mixer AND the routed experts) give it
+their data and run the same code.
 
 A stack is a list of HALVES, each `x <- x + r * f(RMSNorm(x))` with `r` the
 family's `residual_multiplier`, `f` by the half's letter:
@@ -11,8 +12,17 @@ family's `residual_multiplier`, `f` by the half's letter:
                   depthwise, width 4) = [x | B | C]; dt = softplus(dt + bias);
                   S_t = exp(dt_t A_h) S_(t-1) + dt_t x_t (outer) B_t;
                   y_t = S_t C_t + D_h x_t; out = RMSNorm_group(y * silu(z)) W_out
-    *  attention: grouped-query, causal, no rotary, no bias, the scores
-                  scaled by `scale_attn`
+    D  Gated DeltaNet: [q | k | v | z] = u W_qkvz, [b | a] = u W_ba;
+                  [q | k | v] = silu(conv1d(.)) (causal, depthwise, no bias);
+                  q = q / |q| / sqrt(K), k = k / |k| a key head, each serving
+                  H / G value heads; beta = sigmoid(b); g = -exp(A_log)
+                  softplus(a + dt_bias); S_t = exp(g_t) S_(t-1) + k_t (outer)
+                  beta_t (v_t - exp(g_t) S_(t-1)^T k_t); o_t = S_t^T q_t;
+                  out = (RMSNorm_V(o) * w * silu(z)) W_out
+    *  attention: grouped-query, causal, no bias, the scores scaled by
+                  `scale_attn`; without positions, or (`rotary_attention`)
+                  rotated over `rotary_pct` of a head, with `gpt.py`'s
+                  per-head q/k norm and output gate where the family has them
     E  the family's expert half (`expert_half`): Nemotron-H's LatentMoE,
        Granite's gated experts beside a shared one
 
@@ -23,15 +33,19 @@ scalars (`residual_multiplier` here, `scale_attn`, `embedding_multiplier`,
 `logits_scaling` and `tie_embeddings` in `gpt.py`).
 
 - THE STATE KIND (`inference/kv_cache.py::CacheKind(state=True)`): a
-  Mamba-2 half keeps, per slot and not per token, its state `ssm`
-  `[Lm, 1 + slots, H, P, N]` float32 and the last `conv_kernel - 1` inputs of
-  its convolution `conv` `[Lm, 1 + slots, K - 1, W]` (row 0 the trash row).
+  recurrent half keeps, per slot and not per token, its state `ssm`
+  `[Lm, 1 + slots, H, P, N]` float32 (Gated DeltaNet: `[.., H, K, V]`) and
+  the last `conv_kernel - 1` inputs of its convolution `conv`
+  `[Lm, 1 + slots, K - 1, W]` (row 0 the trash row): `state_leaves` has a
+  family's shapes, and a stack has ONE recurrent kind.
   Nobody allocates, frees or walks it. A prefill chunk reads its slot's row
   (zeros where the chunk starts at position 0: a slot newly admitted), runs
   the recurrence in its chunked form from there (`ops/pallas/ssm.py::
   ssm_chunk_scan`) and writes the row back; a decode token reads and
-  rewrites every row whole, in place (`dstpu_ssm_update`). A chunk's padded
-  tail leaves the state alone (`dt = 0` past the last real position, the
+  rewrites every row whole, in place (`dstpu_ssm_update`; the delta rule's
+  `dstpu_gdn_update` and `ops/pallas/gdn.py::gdn_chunk_scan`). A chunk's
+  padded tail leaves the state alone (`dt = 0` past the last real position —
+  the delta rule: `g = 0` and `beta = 0` — and the
   convolution's tail taken from the last REAL inputs); a dead slot's row is
   the trash row. The paged programs take `block_tables` as the PAIR (KV
   tables [B, nb], state rows [B, 1]).
@@ -65,10 +79,10 @@ from deepspeed_tpu.models.gpt import (MixedTables, _attn_half, _embed,
 from deepspeed_tpu.models.layer_pattern import repeated_runs
 from deepspeed_tpu.models.moe_gpt import MoEGPTConfig
 from deepspeed_tpu.ops import attention_dispatch as attn_dispatch
-from deepspeed_tpu.ops.pallas import ssm
+from deepspeed_tpu.ops.pallas import gdn, ssm
 from deepspeed_tpu.parallel.moe import HELD_ROUTED_COUNTERS
 
-MAMBA, ATTENTION, MOE = "M", "*", "E"
+MAMBA, DELTANET, ATTENTION, MOE = "M", "D", "*", "E"
 
 
 @dataclasses.dataclass
@@ -81,6 +95,11 @@ class HybridConfig(MoEGPTConfig):
     n_groups: int = 8                   # groups that share B and C
     conv_kernel: int = 4
     chunk_size: int = 128               # positions a chunk of the scan
+    gdn_key_heads: int = 16             # Gated DeltaNet: G key heads (q, k)
+    gdn_value_heads: int = 32           # serve H value heads, of widths
+    gdn_key_dim: int = 128              # K and
+    gdn_value_dim: int = 128            # V
+    rotary_attention: bool = False      # the attention halves rotate q and k
     shared_d_ff: int = 0                # the shared expert's, at full width
     experts_held: Optional[Tuple[int, int]] = None  # (first, count); None=all
     residual_multiplier: float = 1.0    # `r`: what a half adds, times this
@@ -98,14 +117,19 @@ class HybridConfig(MoEGPTConfig):
         self.sliding_window = None
         self.moe_freq, self.n_layer = 1, len(self.pattern)
         super().__post_init__()
-        if not self.pattern or set(self.halves) - {MAMBA, ATTENTION, MOE}:
+        if not self.pattern \
+                or set(self.halves) - {MAMBA, DELTANET, ATTENTION, MOE} \
+                or {MAMBA, DELTANET} <= set(self.halves):
             raise ValueError(f"pattern {self.pattern!r}: a letter a layer, "
-                             f"or a block of them, of {MAMBA!r}, "
+                             f"or a block of them, of {MAMBA!r} or "
+                             f"{DELTANET!r} (one recurrent kind a stack), "
                              f"{ATTENTION!r}, {MOE!r}")
         if self.mamba_num_heads % self.n_groups \
-                or self.ssm_inner % self.n_groups:
+                or self.ssm_inner % self.n_groups \
+                or self.gdn_value_heads % self.gdn_key_heads:
             raise ValueError("mamba_num_heads and the inner width divide "
-                             "into n_groups")
+                             "into n_groups, gdn_value_heads into "
+                             "gdn_key_heads")
         if self.experts_held is None:
             self.experts_held = (0, self.num_experts)
         first, count = self.experts_held
@@ -126,20 +150,40 @@ class HybridConfig(MoEGPTConfig):
     def conv_width(self):
         return self.ssm_inner + 2 * self.n_groups * self.ssm_state_size
 
+    @property
+    def gdn_widths(self):
+        """(q, k, v) columns of a Gated DeltaNet half; z is v's again."""
+        qk = self.gdn_key_heads * self.gdn_key_dim
+        return qk, qk, self.gdn_value_heads * self.gdn_value_dim
+
 
 def _attention_cfg(cfg: HybridConfig):
     acfg = copy.copy(cfg)                       # no `__post_init__`
-    acfg.use_rotary = False
+    acfg.use_rotary = cfg.rotary_attention
     return acfg
+
+
+def state_leaves(cfg: HybridConfig):
+    """The state kind's leaves, a slot's row of a layer -> shape: the
+    recurrent state (float32) and the convolution's tail, by the stack's
+    recurrent kind."""
+    if DELTANET in cfg.halves:
+        return {"ssm": (cfg.gdn_value_heads, cfg.gdn_key_dim,
+                        cfg.gdn_value_dim),
+                "conv": (cfg.conv_kernel - 1, sum(cfg.gdn_widths))}
+    return {"ssm": (cfg.mamba_num_heads, cfg.mamba_head_dim,
+                    cfg.ssm_state_size),
+            "conv": (cfg.conv_kernel - 1, cfg.conv_width)}
 
 
 def cache_kinds(cfg: HybridConfig, block_size: int):
     """`CacheKind` a kind of cache: the attention halves' blocks, then the
-    Mamba-2 halves' per-slot state."""
+    recurrent halves' per-slot state."""
     return (CacheKind("full", cfg.halves.count(ATTENTION), block_size,
                       leaves=("k", "v")),
-            CacheKind("state", cfg.halves.count(MAMBA), 0,
-                      leaves=("ssm", "conv"), state=True))
+            CacheKind("state",
+                      cfg.halves.count(MAMBA) + cfg.halves.count(DELTANET),
+                      0, leaves=tuple(state_leaves(cfg)), state=True))
 
 
 def layer_runs(cfg: HybridConfig):
@@ -163,7 +207,7 @@ def stream_range(cfg: HybridConfig):
 
 
 def mixer_shapes(cfg: HybridConfig, kind):
-    """A Mamba-2 or attention half's leaves -> (shape, init: a float =
+    """A recurrent or attention half's leaves -> (shape, init: a float =
     normal of that range, 1.0 = ones, 0.0 = zeros, a name = `make_layer`'s
     own rule). A family's `layer_shapes(cfg, kind, router_std)` adds its
     expert half's."""
@@ -176,6 +220,9 @@ def mixer_shapes(cfg: HybridConfig, kind):
             "attn_qkv_b": ((cfg.qkv_dim,), 0.0),
             "attn_out_w": ((cfg.n_head * hd, D), down),
             "attn_out_b": ((D,), 0.0)})
+        if cfg.qk_norm_per_head:
+            shapes.update({"q_norm_scale": ((hd,), 1.0),
+                           "k_norm_scale": ((hd,), 1.0)})
     elif kind == MAMBA:
         H, inner, W = cfg.mamba_num_heads, cfg.ssm_inner, cfg.conv_width
         shapes.update({
@@ -186,6 +233,15 @@ def mixer_shapes(cfg: HybridConfig, kind):
             "ssm_D": ((H,), 1.0),
             "gate_norm_scale": ((inner,), 1.0),
             "ssm_out_w": ((inner, D), down)})
+    elif kind == DELTANET:
+        H, (Wq, Wk, Wv) = cfg.gdn_value_heads, cfg.gdn_widths
+        shapes.update({
+            "gdn_qkvz_w": ((D, Wq + Wk + 2 * Wv), 0.02),
+            "gdn_ba_w": ((D, 2 * H), 0.02),
+            "conv_w": ((cfg.conv_kernel, Wq + Wk + Wv), "conv"),
+            "dt_bias": ((H,), "dt_bias"), "A_log": ((H,), "A_log"),
+            "gate_norm_scale": ((cfg.gdn_value_dim,), 1.0),
+            "gdn_out_w": ((Wv, D), down)})
     return shapes
 
 
@@ -273,9 +329,10 @@ def _conv(seq, p, T):
     (the K - 1 inputs before the first position, then the T positions')
     -> [b, T, W] in `seq.dtype`."""
     w = p["conv_w"].astype(jnp.float32)
-    out = p["conv_b"].astype(jnp.float32) + sum(
-        w[k] * seq[:, k:k + T].astype(jnp.float32)
-        for k in range(w.shape[0]))
+    out = sum(w[k] * seq[:, k:k + T].astype(jnp.float32)
+              for k in range(w.shape[0]))
+    if "conv_b" in p:                   # Gated DeltaNet's has none
+        out = out + p["conv_b"].astype(jnp.float32)
     return jax.nn.silu(out).astype(seq.dtype)
 
 
@@ -295,49 +352,75 @@ def _skip(y, x, p):
     return y + p["ssm_D"][:, None] * x.astype(jnp.float32)
 
 
-def _mamba_chunk(zxbcdt, p, cfg, cache, rows, start, valid):
-    """Positions `start .. start + T - 1` of b sequences, of which the first
-    `valid` [b] are real: (y [b, T, H, P] float32, cache). `cache`: the
+def _chunk_start(conv_in, cfg, cache, rows, start):
+    """What a chunk of b sequences starts from: conv_in [b, T, W], the
+    convolution's inputs at its positions -> (seq [b, K - 1 + T, W]: the
+    K - 1 inputs before the first position, then the chunk's; S [b, ...]
+    float32, the state before it, `state_leaves`' shape). `cache`: the
     carried `(ssm, conv)` pair, rows `rows` [b] of it this call's (None: no
     cache, every sequence from zero — the whole-sequence forward)."""
-    b, T = zxbcdt.shape[:2]
-    K, W = cfg.conv_kernel, cfg.conv_width
-    _, xBC, dt = jnp.split(zxbcdt, [cfg.ssm_inner, cfg.ssm_inner + W], -1)
-    tail = jnp.zeros((b, K - 1, W), xBC.dtype)
-    S = jnp.zeros((b, cfg.mamba_num_heads, cfg.mamba_head_dim,
-                   cfg.ssm_state_size), jnp.float32)
+    b, _, W = conv_in.shape
+    tail = jnp.zeros((b, cfg.conv_kernel - 1, W), conv_in.dtype)
+    S = jnp.zeros((b,) + state_leaves(cfg)["ssm"], jnp.float32)
     if cache is not None:
         # a chunk at position 0 is a slot newly admitted: nothing carried
         fresh = (start == 0)[:, None, None]
         tail = jnp.where(fresh, 0, ssm.state_read(cache[1], rows))
         S = jnp.where(fresh[..., None], 0,
                       ssm.state_read(cache[0], rows).astype(jnp.float32))
+    return jnp.concatenate([tail.astype(conv_in.dtype), conv_in], axis=1), S
+
+
+def _chunk_end(seq, S, cfg, cache, rows, valid):
+    """The cache after a chunk: the state `S` after its last REAL position
+    and the convolution's inputs of the last K - 1 real positions (earlier
+    chunks' too) of `seq`, where the first `valid` [b] positions are real."""
+    if cache is None:
+        return None
+    tail = jax.vmap(lambda s, n: jax.lax.dynamic_slice_in_dim(
+        s, n, cfg.conv_kernel - 1))(seq, valid)
+    return (ssm.state_write(cache[0], rows, S),
+            ssm.state_write(cache[1], rows, tail))
+
+
+def _token_start(conv_in, cache, rows):
+    """A decode token of every row: conv_in [S, W] -> (seq [S, K, W], the
+    convolution's K inputs that end at it; the tails' buffer after it)."""
+    seq = jnp.concatenate([ssm.state_read(cache[1], rows).astype(
+        conv_in.dtype), conv_in[:, None]], axis=1)
+    return seq, ssm.state_write(cache[1], rows, seq[:, 1:])
+
+
+def _real(valid, T):
+    """[b, T, 1]: the chunk's positions that are not its padded tail."""
+    return jnp.arange(T)[None, :, None] < valid[:, None, None]
+
+
+def _mamba_chunk(p, cfg, proj, cache, rows, start, valid):
+    """Positions `start .. start + T - 1` of b sequences, of which the first
+    `valid` [b] are real: (y [b, T, H, P] float32, cache)."""
+    zxbcdt, = proj
+    T = zxbcdt.shape[1]
+    _, xBC, dt = jnp.split(
+        zxbcdt, [cfg.ssm_inner, cfg.ssm_inner + cfg.conv_width], -1)
     with jax.named_scope("ssm/conv"):
-        seq = jnp.concatenate([tail.astype(xBC.dtype), xBC], axis=1)
-        # the inputs of the last K - 1 REAL positions, earlier chunks' too
-        tail = jax.vmap(lambda s, n: jax.lax.dynamic_slice_in_dim(
-            s, n, K - 1))(seq, valid)
+        seq, S = _chunk_start(xBC, cfg, cache, rows, start)
         x, B, C, dt, A = _ssm_inputs(_conv(seq, p, T), dt, p, cfg)
     with jax.named_scope("ssm/scan"):
         # a padded tail leaves the state alone: decay 1, no input
-        dt = jnp.where(jnp.arange(T)[None, :, None] < valid[:, None, None],
-                       dt, 0.0)
-        y, S = ssm.ssm_chunk_scan(x, dt, A, B, C, S, cfg.chunk_size)
-    if cache is not None:
-        cache = (ssm.state_write(cache[0], rows, S),
-                 ssm.state_write(cache[1], rows, tail))
-    return _skip(y, x, p), cache
+        y, S = ssm.ssm_chunk_scan(x, jnp.where(_real(valid, T), dt, 0.0), A,
+                                  B, C, S, cfg.chunk_size)
+    return _skip(y, x, p), _chunk_end(seq, S, cfg, cache, rows, valid)
 
 
-def _mamba_token(zxbcdt, p, cfg, cache, rows):
+def _mamba_token(p, cfg, proj, cache, rows):
     """One decode token of every row: zxbcdt [S, .] -> (y [S, H, P] float32,
     cache), each row's state read and rewritten whole, in place."""
-    K, W = cfg.conv_kernel, cfg.conv_width
-    _, xBC, dt = jnp.split(zxbcdt, [cfg.ssm_inner, cfg.ssm_inner + W], -1)
+    zxbcdt, = proj
+    _, xBC, dt = jnp.split(
+        zxbcdt, [cfg.ssm_inner, cfg.ssm_inner + cfg.conv_width], -1)
     with jax.named_scope("ssm/conv"):
-        seq = jnp.concatenate([ssm.state_read(cache[1], rows).astype(xBC.dtype),
-                               xBC[:, None]], axis=1)       # [S, K, W]
-        conv = ssm.state_write(cache[1], rows, seq[:, 1:])
+        seq, conv = _token_start(xBC, cache, rows)
         x, B, C, dt, A = _ssm_inputs(_conv(seq, p, 1)[:, 0], dt, p, cfg)
     with jax.named_scope("ssm/update"):
         y, state = ssm.ssm_update(
@@ -346,11 +429,36 @@ def _mamba_token(zxbcdt, p, cfg, cache, rows):
     return _skip(y, x, p), (state, conv)
 
 
+def _recurrent(proj, chunk, token, cache, rows, positions, valid):
+    """A recurrent half between its projections, the three ways a program
+    runs it: `proj`, the in-projections' products [B, T, .] -> (y [B, T, H,
+    .] float32, cache) by `chunk(proj, cache, rows, start, valid)` and
+    `token(proj, cache, rows)`. `rows`: each sequence's row of the cache,
+    [B, 1] — or, of a mixed call, a `MixedTables` of the chunk's and the
+    slots'. `valid` [chunks]: a chunk's real positions (default: all)."""
+    B, T = proj[0].shape[:2]
+    part = lambda *at: tuple(a[at] for a in proj)
+    if isinstance(rows, MixedTables):
+        # a chunk's rows [1, C, .], then a row a slot: the chunk first, whole
+        # (its state read, scanned and written back), then the slots' token
+        # on the buffer it returned — one chain, nothing for XLA to reorder
+        C = T - rows.decode.shape[0]
+        y_c, cache = chunk(part(slice(None), slice(C)), cache,
+                           rows.chunk[:, 0], positions[:, 0], valid)
+        y_d, cache = token(part(0, slice(C, None)), cache, rows.decode[:, 0])
+        return jnp.concatenate([y_c, y_d[None]], axis=1), cache
+    if cache is not None and valid is None:
+        y, cache = token(part(slice(None), 0), cache, rows[:, 0])
+        return y[:, None], cache
+    if valid is None:
+        valid = jnp.full((B,), T, jnp.int32)
+    return chunk(proj, cache, None if rows is None else rows[:, 0],
+                 None if positions is None else positions[:, 0], valid)
+
+
 def _mamba_half(x, p, cfg, cache=None, rows=None, positions=None, valid=None):
-    """`f` of a Mamba-2 half on x [B, T, D] -> (f(RMSNorm(x)), cache).
-    `rows`: each sequence's row of the cache, [B, 1] — or, of a mixed call,
-    a `MixedTables` of the chunk's and the slots'. `valid` [chunks]: a
-    chunk's real positions (default: all)."""
+    """`f` of a Mamba-2 half on x [B, T, D] -> (f(RMSNorm(x)), cache);
+    `cache`, `rows`, `positions`, `valid`: `_recurrent`'s."""
     B, T, _ = x.shape
     u = _norm(x, p["ln1_scale"], None, True, cfg.norm_eps)
     with jax.named_scope("ssm/in_proj"):
@@ -358,26 +466,9 @@ def _mamba_half(x, p, cfg, cache=None, rows=None, positions=None, valid=None):
         # HOLDS the product between them — left alone, XLA frees it after the
         # convolution and computes it again for the gate (`fusion.N.remat`)
         zxbcdt = jax.lax.optimization_barrier(u @ p["ssm_in_w"])
-    if isinstance(rows, MixedTables):
-        # a chunk's rows [1, C, .], then a row a slot: the chunk first, whole
-        # (its state read, scanned and written back), then the slots' token
-        # on the buffer it returned — one chain, nothing for XLA to reorder
-        S = rows.decode.shape[0]
-        C = T - S
-        y_c, cache = _mamba_chunk(zxbcdt[:, :C], p, cfg, cache,
-                                  rows.chunk[:, 0], positions[:, 0], valid)
-        y_d, cache = _mamba_token(zxbcdt[0, C:], p, cfg, cache,
-                                  rows.decode[:, 0])
-        y = jnp.concatenate([y_c, y_d[None]], axis=1)
-    elif cache is not None and valid is None:
-        y, cache = _mamba_token(zxbcdt[:, 0], p, cfg, cache, rows[:, 0])
-        y = y[:, None]
-    else:
-        if valid is None:
-            valid = jnp.full((B,), T, jnp.int32)
-        y, cache = _mamba_chunk(
-            zxbcdt, p, cfg, cache, None if rows is None else rows[:, 0],
-            None if positions is None else positions[:, 0], valid)
+    y, cache = _recurrent(
+        (zxbcdt,), partial(_mamba_chunk, p, cfg),
+        partial(_mamba_token, p, cfg), cache, rows, positions, valid)
     with jax.named_scope("ssm/out_proj"):
         z = zxbcdt[..., :cfg.ssm_inner].astype(jnp.float32)
         gated = (y.reshape(B, T, cfg.n_groups, -1)
@@ -387,6 +478,90 @@ def _mamba_half(x, p, cfg, cache=None, rows=None, positions=None, valid=None):
         out = (gated.reshape(B, T, -1).astype(x.dtype)
                * p["gate_norm_scale"]) @ p["ssm_out_w"]
     return out, cache
+
+
+# ----------------------------------------------------------------------
+# the Gated DeltaNet half
+# ----------------------------------------------------------------------
+
+_L2_EPS = 1e-6          # q / |q|, k / |k|: x rsqrt(sum x^2 + eps)
+
+
+def _gdn_inputs(qkv, ba, p, cfg):
+    """The convolved `[q | k | v]` [.., W] and raw `[b | a]` [.., 2 H] -> (q,
+    k [.., G, K] float32, each key head's of unit length, q over sqrt(K)
+    besides; v [.., H, V]; g [.., H] float32 log-decay; beta [.., H]
+    float32)."""
+    G, H = cfg.gdn_key_heads, cfg.gdn_value_heads
+    K, V = cfg.gdn_key_dim, cfg.gdn_value_dim
+    lead = qkv.shape[:-1]
+    q, k, v = jnp.split(qkv, [G * K, 2 * G * K], axis=-1)
+
+    def unit(x):
+        x = x.astype(jnp.float32).reshape(lead + (G, K))
+        return x * jax.lax.rsqrt(jnp.sum(x * x, -1, keepdims=True) + _L2_EPS)
+
+    b, a = jnp.split(ba.astype(jnp.float32), 2, axis=-1)
+    g = -jnp.exp(p["A_log"]) * jax.nn.softplus(a + p["dt_bias"])
+    return (unit(q) * K ** -0.5, unit(k), v.reshape(lead + (H, V)), g,
+            jax.nn.sigmoid(b))
+
+
+def _gdn_chunk(p, cfg, proj, cache, rows, start, valid):
+    """`_mamba_chunk` of the delta rule: (o [b, T, H, V] float32, cache)."""
+    qkvz, ba = proj
+    T = qkvz.shape[1]
+    with jax.named_scope("gdn/conv"):
+        seq, S = _chunk_start(qkvz[..., :sum(cfg.gdn_widths)], cfg, cache,
+                              rows, start)
+        q, k, v, g, beta = _gdn_inputs(_conv(seq, p, T), ba, p, cfg)
+    with jax.named_scope("gdn/scan"):
+        # a padded tail leaves the state alone: decay 1 AND nothing written
+        real = _real(valid, T)
+        o, S = gdn.gdn_chunk_scan(
+            q.astype(v.dtype), k.astype(v.dtype), v, jnp.where(real, g, 0.0),
+            jnp.where(real, beta, 0.0), S, cfg.chunk_size)
+    return o, _chunk_end(seq, S, cfg, cache, rows, valid)
+
+
+def _gdn_token(p, cfg, proj, cache, rows):
+    """`_mamba_token` of the delta rule: (o [S, H, V] float32, cache)."""
+    qkvz, ba = proj
+    with jax.named_scope("gdn/conv"):
+        seq, conv = _token_start(qkvz[..., :sum(cfg.gdn_widths)], cache, rows)
+        q, k, v, g, beta = _gdn_inputs(_conv(seq, p, 1)[:, 0], ba, p, cfg)
+    with jax.named_scope("gdn/update"):
+        o, state = gdn.gdn_update(cache[0], rows, jnp.exp(g), beta, q, k, v)
+    return o, (state, conv)
+
+
+def _gdn_half(x, p, cfg, cache=None, rows=None, positions=None, valid=None):
+    """`f` of a Gated DeltaNet half on x [B, T, D] -> (f(RMSNorm(x)),
+    cache); `cache`, `rows`, `positions`, `valid`: `_recurrent`'s."""
+    B, T, _ = x.shape
+    H, V = cfg.gdn_value_heads, cfg.gdn_value_dim
+    u = _norm(x, p["ln1_scale"], None, True, cfg.norm_eps)
+    with jax.named_scope("gdn/in_proj"):
+        # q, k and v are read at the half's start, z at its end: held, as
+        # `_mamba_half` holds its product and for its reason
+        qkvz = jax.lax.optimization_barrier(u @ p["gdn_qkvz_w"])
+        ba = u @ p["gdn_ba_w"]
+    o, cache = _recurrent(
+        (qkvz, ba), partial(_gdn_chunk, p, cfg),
+        partial(_gdn_token, p, cfg), cache, rows, positions, valid)
+    with jax.named_scope("gdn/out_proj"):
+        # the norm FIRST (a head's V columns, one scale for all heads), then
+        # the gate
+        z = qkvz[..., -H * V:].astype(jnp.float32).reshape(B, T, H, V)
+        o = o * jax.lax.rsqrt(
+            jnp.mean(jnp.square(o), -1, keepdims=True) + cfg.norm_eps)
+        gated = o * p["gate_norm_scale"].astype(jnp.float32) * jax.nn.silu(z)
+        out = gated.reshape(B, T, H * V).astype(x.dtype) @ p["gdn_out_w"]
+    return out, cache
+
+
+# a recurrent half's letter -> (its scope in a paged program, its `f`)
+RECURRENT = {MAMBA: ("ssm", _mamba_half), DELTANET: ("gdn", _gdn_half)}
 
 
 def _residual(x, out, cfg):
@@ -422,8 +597,8 @@ def hybrid_forward(params, tokens, cfg: HybridConfig, expert_half,
     for kind, p in _layers(params, cfg):
         if kind == ATTENTION:
             out, _, _ = _attn_half(x, p, acfg, positions, constrain=False)
-        elif kind == MAMBA:
-            out, _ = _mamba_half(x, p, cfg)
+        elif kind in RECURRENT:
+            out, _ = RECURRENT[kind][1](x, p, cfg)
         else:
             out, _, top_e = expert_half(x, p, cfg)
             if routing is not None:
@@ -495,9 +670,10 @@ def make_hybrid_decode_model(cfg: HybridConfig, params, name, expert_half,
                         decode_work=work, attn_programs=attn_programs,
                         phase=None if mixed else site, **where)
                 flat = {**flat, **kv}
-            elif kind == MAMBA:
-                with jax.named_scope("ssm"):
-                    out, (state, conv) = _mamba_half(
+            elif kind in RECURRENT:
+                scope, mixer = RECURRENT[kind]
+                with jax.named_scope(scope):
+                    out, (state, conv) = mixer(
                         x, p, cfg, (flat["ssm"], flat["conv"]),
                         offset(state_rows, index * state_n), positions, valid)
                 flat = {**flat, "ssm": state, "conv": conv}
@@ -511,7 +687,7 @@ def make_hybrid_decode_model(cfg: HybridConfig, params, name, expert_half,
 
         acc = no_counts
         chosen = [] if routing else None    # an expert half's [B*C, top_k]
-        seen = {MAMBA: 0, ATTENTION: 0, MOE: 0}
+        seen = dict.fromkeys(RECURRENT, 0) | {ATTENTION: 0, MOE: 0}
         for (unit, repeats), trees in zip(runs, params["runs"]):
             split = [_split_stacks(tree, expert_stacks) for tree in trees]
             small = [s for s, _ in split]
@@ -520,7 +696,7 @@ def make_hybrid_decode_model(cfg: HybridConfig, params, name, expert_half,
                 x, flat, acc = carry
                 trees, n = inputs
                 counts, routed = [], [] if routing else None
-                rank = {MAMBA: 0, ATTENTION: 0, MOE: 0}
+                rank = dict.fromkeys(seen, 0)
                 for i, kind in enumerate(unit):
                     index = seen[kind] + n * unit.count(kind) + rank[kind]
                     rank[kind] += 1
@@ -587,13 +763,13 @@ def make_hybrid_decode_model(cfg: HybridConfig, params, name, expert_half,
         full, state = cache_kinds(cfg, block_size)
         kv = (full.layers, num_blocks, cfg.n_kv_head, block_size,
               cfg.head_dim)
+        row = state_leaves(cfg)
         return {
             "k": jnp.zeros(kv, dtype), "v": jnp.zeros(kv, dtype),
-            "ssm": jnp.zeros((state.layers, state_rows, cfg.mamba_num_heads,
-                              cfg.mamba_head_dim, cfg.ssm_state_size),
+            "ssm": jnp.zeros((state.layers, state_rows) + row["ssm"],
                              jnp.float32),
-            "conv": jnp.zeros((state.layers, state_rows, cfg.conv_kernel - 1,
-                               cfg.conv_width), dtype)}
+            "conv": jnp.zeros((state.layers, state_rows) + row["conv"],
+                              dtype)}
 
     def unserved(*_args, **_kwargs):
         raise NotImplementedError(

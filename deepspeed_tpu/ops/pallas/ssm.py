@@ -28,6 +28,11 @@ gather or scatter on a carried buffer copies it whole.
   sixteen heads (`_heads_per_block`). The small per-row operands arrive
   with P on the sublanes and the heads on the lanes (`[B, P, H]`), a head's
   decay as a scalar (SMEM).
+  THE SHELL IS NOT MAMBA-2'S: `stream_rows` (the bursts and the two halves)
+  and `streamed_update` (the call: the aliased state, a step's rows, the
+  scratch) take the recurrence as a BODY — this file's, and the gated delta
+  rule's in `ops/pallas/gdn.py` (`dstpu_gdn_update`), which runs on the same
+  state kind.
 - `state_read` / `state_write` (`dstpu_ssm_state_read|write`): a few rows
   copied out of, or into, a carried buffer by index — what a prefill chunk
   does with its slot's state and what both groups do with the convolution's
@@ -43,6 +48,7 @@ a TPU there is no twin: a state the update kernel does not address raises.
 """
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -82,9 +88,10 @@ def _mode(interpret, state=None):
         return False, False
     if state is not None and not state_in_place_supported(state):
         raise ValueError(
-            f"dstpu_ssm_update addresses float32 [rows, H, P, N] state whose "
-            f"(P, N) face is whole (8, 128) tiles, not {state.dtype}"
-            f"{list(state.shape)}: on a TPU there is no other in-place path")
+            f"the in-place update (dstpu_ssm_update, dstpu_gdn_update) "
+            f"addresses float32 [rows, H, P, N] state whose (P, N) face is "
+            f"whole (8, 128) tiles, not {state.dtype}{list(state.shape)}: on "
+            f"a TPU there is no other in-place path")
     return True, False
 
 
@@ -123,15 +130,17 @@ def _heads_per_block(heads, groups):
     return hb
 
 
-def _update_kernel(rows_ref, a_ref, dtx_ref, b_ref, c_ref, s_hbm,
-                   y_ref, out_hbm, buf, read_sem, write_sem, *, groups):
+def stream_rows(rows_ref, s_hbm, out_hbm, buf, read_sem, write_sem, update,
+                blocks):
+    """THE SHELL of an in-place decode update, whatever the recurrence: the
+    state stays in HBM, a grid step's K rows (`buf`: `[2, K, ...]`) come in
+    one burst and leave in another, never both at once, and the step's
+    arithmetic, in two halves, hides behind one burst each. `update(tile, r,
+    lo, hi)`: the body — blocks lo..hi (of `blocks`, the body's own unit) of
+    the step's row r, where it lies (`tile`, the row in VMEM)."""
     step, steps = pl.program_id(0), pl.num_programs(0)
-    K, H, P = buf.shape[1:4]
-    per = H // groups
-    hb = _heads_per_block(H, groups)
-    blocks = H // hb
+    K = buf.shape[1]
     slot = step % 2
-    lane = jax.lax.broadcasted_iota(jnp.int32, (P, H), 1)
 
     def reads(at, slot):
         """The copies of step `at`'s rows into half `slot` of the buffer."""
@@ -145,53 +154,17 @@ def _update_kernel(rows_ref, a_ref, dtx_ref, b_ref, c_ref, s_hbm,
                                       write_sem.at[slot])
                 for r in range(K)]
 
-    def heads_of(j):
-        """(first head, group of its head hh) of block j."""
-        h0 = j * hb
-        if hb >= per:
-            return h0, lambda hh: h0 // per + hh // per
-        return h0, lambda hh: h0 // per
-
-    def update(r, lo, hi):
-        """Blocks lo..hi of the step's row r, where they lie: EVERY lane
-        broadcast (a head's `dt x` column along N), then EVERY lane sum
-        (`y`). Taking turns a head, the two kinds stall each other in the
-        cross-lane units: 15.7 us a row against 4.2 (PERF.md, PR 45)."""
-        tile = buf.at[slot, r]
-
-        def spread(j, _):
-            h0, group = heads_of(j)
-            dtx = dtx_ref[r]
-            if blocks > 1:              # the block's columns to lanes 0..hb-1
-                dtx = pltpu.roll(dtx, (H - h0) % H, 1)
-            for hh in range(hb):
-                tile[h0 + hh] = a_ref[r, 0, h0 + hh] * tile[h0 + hh] \
-                    + dtx[:, hh:hh + 1] * b_ref[r, pl.ds(group(hh), 1), :]
-            return 0
-
-        def gather(j, y):
-            h0, group = heads_of(j)
-            for hh in range(hb):
-                col = jnp.sum(
-                    tile[h0 + hh] * c_ref[r, pl.ds(group(hh), 1), :],
-                    axis=-1, keepdims=True)
-                y = jnp.where(lane == h0 + hh, col, y)
-            return y
-
-        jax.lax.fori_loop(lo, hi, spread, 0)
-        y_ref[r] = jax.lax.fori_loop(
-            lo, hi, gather,
-            y_ref[r] if lo else jnp.zeros((P, H), jnp.float32))
-
     def half(k):
         """The step's work in two halves (k = 0, 1), one for each stream to
         hide behind: its rows' halves, or the halves of its one row's
         blocks."""
         if K > 1:
             lo, hi = (0, K // 2, K)[k:k + 2]
-            jax.lax.fori_loop(lo, hi, lambda r, _: update(r, 0, blocks), None)
+            jax.lax.fori_loop(
+                lo, hi,
+                lambda r, _: update(buf.at[slot, r], r, 0, blocks), None)
         else:
-            update(0, *(0, blocks // 2, blocks)[k:k + 2])
+            update(buf.at[slot, 0], 0, *(0, blocks // 2, blocks)[k:k + 2])
 
     @pl.when(step == 0)
     def _():
@@ -228,6 +201,94 @@ def _update_kernel(rows_ref, a_ref, dtx_ref, b_ref, c_ref, s_hbm,
             copy.wait()
 
 
+def streamed_update(kernel, name, state, rows, operands, result, interpret):
+    """`kernel` on the shell: `kernel(rows_ref, *operand refs, s_hbm,
+    result_ref, out_hbm, buf, read_sem, write_sem)`, a grid step the K rows
+    `_rows_per_step` gives it. `operands`: `[(array [b, ...], scalars?)]`, a
+    row's small inputs, blocked K rows a step — in SMEM where `scalars`;
+    `result`: the shape a row of the small float32 result. Returns (result
+    `[b, ...]`, the state, updated where it lay)."""
+    b = rows.shape[0]
+    row_bytes = math.prod(state.shape[1:]) * state.dtype.itemsize
+    K = _rows_per_step(b, row_bytes)
+
+    def rows_of(tail, scalars=False):
+        at = lambda i, rows_ref: (i,) + (0,) * len(tail)
+        return pl.BlockSpec((K,) + tuple(tail), at,
+                            **(dict(memory_space=pltpu.SMEM) if scalars
+                               else {}))
+
+    in_hbm = pl.BlockSpec(memory_space=pl.ANY)
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(b // K,),
+            in_specs=[rows_of(a.shape[1:], scalars)
+                      for a, scalars in operands] + [in_hbm],
+            out_specs=[rows_of(result), in_hbm],
+            scratch_shapes=[pltpu.VMEM((2, K) + state.shape[1:], state.dtype),
+                            pltpu.SemaphoreType.DMA((2,)),
+                            pltpu.SemaphoreType.DMA((2,))]),
+        out_shape=[jax.ShapeDtypeStruct((b,) + tuple(result), jnp.float32),
+                   jax.ShapeDtypeStruct(state.shape, state.dtype)],
+        # operands: rows, the small ones, state
+        input_output_aliases={1 + len(operands): 1},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=2 * K * row_bytes + _VMEM_SMALL),
+        interpret=interpret,
+        name=name,
+    )(rows, *(a for a, _ in operands), state)
+
+
+def _update_kernel(rows_ref, a_ref, dtx_ref, b_ref, c_ref, s_hbm,
+                   y_ref, out_hbm, buf, read_sem, write_sem, *, groups):
+    H, P = buf.shape[2:4]
+    per = H // groups
+    hb = _heads_per_block(H, groups)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (P, H), 1)
+
+    def heads_of(j):
+        """(first head, group of its head hh) of block j."""
+        h0 = j * hb
+        if hb >= per:
+            return h0, lambda hh: h0 // per + hh // per
+        return h0, lambda hh: h0 // per
+
+    def update(tile, r, lo, hi):
+        """Blocks lo..hi of the step's row r, where they lie: EVERY lane
+        broadcast (a head's `dt x` column along N), then EVERY lane sum
+        (`y`). Taking turns a head, the two kinds stall each other in the
+        cross-lane units: 15.7 us a row against 4.2 (PERF.md, PR 45)."""
+
+        def spread(j, _):
+            h0, group = heads_of(j)
+            dtx = dtx_ref[r]
+            if H > hb:                  # the block's columns to lanes 0..hb-1
+                dtx = pltpu.roll(dtx, (H - h0) % H, 1)
+            for hh in range(hb):
+                tile[h0 + hh] = a_ref[r, 0, h0 + hh] * tile[h0 + hh] \
+                    + dtx[:, hh:hh + 1] * b_ref[r, pl.ds(group(hh), 1), :]
+            return 0
+
+        def gather(j, y):
+            h0, group = heads_of(j)
+            for hh in range(hb):
+                col = jnp.sum(
+                    tile[h0 + hh] * c_ref[r, pl.ds(group(hh), 1), :],
+                    axis=-1, keepdims=True)
+                y = jnp.where(lane == h0 + hh, col, y)
+            return y
+
+        jax.lax.fori_loop(lo, hi, spread, 0)
+        y_ref[r] = jax.lax.fori_loop(
+            lo, hi, gather,
+            y_ref[r] if lo else jnp.zeros((P, H), jnp.float32))
+
+    stream_rows(rows_ref, s_hbm, out_hbm, buf, read_sem, write_sem, update,
+                H // hb)
+
+
 def ssm_update(state, rows, a, dtx, B, C, interpret=None):
     """One token of the recurrence for b rows, the state updated IN PLACE.
 
@@ -242,38 +303,15 @@ def ssm_update(state, rows, a, dtx, B, C, interpret=None):
     a, dtx = a.astype(jnp.float32), dtx.astype(jnp.float32)
     if not use:
         return ssm_update_reference(state, rows, a, dtx, B, C)
-    M, H, P, N = state.shape
-    b, G = B.shape[:2]
-    row_bytes = H * P * N * state.dtype.itemsize
-    K = _rows_per_step(b, row_bytes)
+    H, P = state.shape[1:3]
     # P on the sublanes, a head a lane: a head's column broadcasts along N;
     # a head's decay is a scalar
-    dtx_t = jnp.swapaxes(dtx, 1, 2)
-    small = pl.BlockSpec((K, P, H), lambda i, rows_ref: (i, 0, 0))
-    group = pl.BlockSpec((K, G, N), lambda i, rows_ref: (i, 0, 0))
-    decay = pl.BlockSpec((K, 1, H), lambda i, rows_ref: (i, 0, 0),
-                         memory_space=pltpu.SMEM)
-    in_hbm = pl.BlockSpec(memory_space=pl.ANY)
-    y, state = pl.pallas_call(
-        functools.partial(_update_kernel, groups=G),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1, grid=(b // K,),
-            in_specs=[decay, small, group, group, in_hbm],
-            out_specs=[small, in_hbm],
-            scratch_shapes=[pltpu.VMEM((2, K, H, P, N), state.dtype),
-                            pltpu.SemaphoreType.DMA((2,)),
-                            pltpu.SemaphoreType.DMA((2,))]),
-        out_shape=[jax.ShapeDtypeStruct((b, P, H), jnp.float32),
-                   jax.ShapeDtypeStruct(state.shape, state.dtype)],
-        # operands: rows, a, dtx, B, C, state
-        input_output_aliases={5: 1},
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",),
-            vmem_limit_bytes=2 * K * row_bytes + _VMEM_SMALL),
-        interpret=interpret,
-        name=KERNEL_NAME,
-    )(rows, a[:, None, :], dtx_t, B.astype(jnp.float32),
-      C.astype(jnp.float32), state)
+    y, state = streamed_update(
+        functools.partial(_update_kernel, groups=B.shape[1]), KERNEL_NAME,
+        state, rows,
+        [(a[:, None, :], True), (jnp.swapaxes(dtx, 1, 2), False),
+         (B.astype(jnp.float32), False), (C.astype(jnp.float32), False)],
+        (P, H), interpret)
     return jnp.swapaxes(y, 1, 2), state
 
 

@@ -339,7 +339,7 @@ def test_benchmark_holds_the_cells_files():
         "mla_decode_time_share.longctx", "mla_prefill_roofline.longctx",
         "mla_prefill_time_share.longctx", "mla_proj_time_share.longctx",
         "moe_dispatch_time_share.longctx"]
-    assert len(bench["per_layer"]) <= 115
+    assert len(bench["per_layer"]) <= 128    # the contract's cap
     for metric in own:
         with open(os.path.join(BENCH, "layer_metrics",
                                metric["name"] + ".json")) as f:

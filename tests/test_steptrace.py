@@ -1027,6 +1027,45 @@ def test_the_state_update_is_one_aliased_call_with_two_rows_of_vmem(
     assert 2 * 2 * row < used <= limit
 
 
+def test_the_delta_rule_update_is_one_aliased_call_on_the_same_shell(
+        one_chip):
+    """`gdn_update` at Qwen3-Next's served face (PR 47): ONE
+    `dstpu_gdn_update` call, result types `(f32[b, H, V], f32[rows, H, K,
+    V])` — what `gdn_update_roofline.deltanet` and the breakdown match —
+    on `ssm.stream_rows`, the shell `dstpu_ssm_update` runs on: the state
+    is the call's aliased result and never a block of the pipeline, a step's
+    FOUR 2 MiB rows are read in one burst and written in another, so the
+    scoped VMEM is those four rows twice (16 MiB) and the small operands'
+    blocks."""
+    from deepspeed_tpu.ops.pallas import gdn, ssm
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    rows, b, H, G, K, V = 9 * 193, 192, 32, 16, 128, 128
+    lines = _kernel_lines(
+        functools.partial(gdn.gdn_update, interpret=False),
+        sds((rows, H, K, V), jnp.float32), sds((b,), jnp.int32),
+        sds((b, H), jnp.float32), sds((b, H), jnp.float32),
+        sds((b, G, K), jnp.float32), sds((b, G, K), jnp.float32),
+        sds((b, H, V), jnp.bfloat16))
+    assert len(lines) == 1, lines
+    call = re.sub(r"\{[^{}]*\}", "", lines[0].split(" custom-call(")[0])
+    # no swap of axes follows it (the ROOT): o leaves as [b, H, V]
+    assert call == (f"ROOT %dstpu_gdn_update.1 = (f32[{b},{H},{V}], "
+                    f"f32[{rows},{H},{K},{V}])"), call
+    # operands: rows, a head's scalars, k, q, v, state
+    assert "output_to_operand_aliasing={{1}: (5, {})}" in lines[0]
+    row = H * K * V * 4
+    assert ssm._rows_per_step(b, row) == 4
+    limit, used = (int(re.search(
+        rf'"{key}":\[\{{"memory_space":"1","offset":"0","size":"(\d+)"',
+        lines[0]).group(1))
+        for key in ("scoped_memory_configs", "used_scoped_memory_configs"))
+    assert limit == 2 * 4 * row + ssm._VMEM_SMALL
+    assert 2 * 4 * row < used <= limit
+
+
 def test_training_step_holds_the_three_flash_kernels_by_result_signature(
         one_chip, monkeypatch):
     """`flash_roofline.train` tells the three flash kernels apart by the
@@ -1639,17 +1678,50 @@ def test_two_kind_paged_programs_hold_nothing_of_either_pools_size(
 # ----------------------------------------------------------------------
 
 
+def _nemotron_shaped_guard():
+    """Two (LatentMoE, Mamba-2) pairs scanned, then an attention layer."""
+    from deepspeed_tpu.models import nemotron_h as nh
+    cfg = nh.NemotronHConfig(
+        vocab_size=512, pattern="EMEM*", n_head=16, n_kv_head=2,
+        d_model=512, attn_head_dim=128, d_ff=256, shared_d_ff=512,
+        moe_latent_size=256, max_seq_len=8192, num_experts=32,
+        experts_held=(8, 8), top_k=4, norm_topk_prob=True,
+        routed_scaling_factor=5.0, mamba_num_heads=16, mamba_head_dim=64,
+        ssm_state_size=128, n_groups=2, chunk_size=128,
+        use_flash_attention=True, dtype=jnp.bfloat16, remat=False)
+    return (cfg, nh.nemotron_h_init_fn, nh.make_nemotron_h_decode_model,
+            "dstpu_ssm_update")
+
+
+def _qwen3_next_shaped_guard():
+    """Two (Gated DeltaNet, experts) layers scanned, then a gated-attention
+    layer at head width 256 with eight query heads a key-value head."""
+    from deepspeed_tpu.models import qwen3_next as qn
+    cfg = qn.Qwen3NextConfig(
+        vocab_size=512, pattern=("DE", "DE", "*E"), n_head=16, n_kv_head=2,
+        d_model=512, attn_head_dim=256, d_ff=256, shared_d_ff=256,
+        max_seq_len=8192, num_experts=32, experts_held=(8, 8), top_k=4,
+        gdn_key_heads=8, gdn_value_heads=16, gdn_key_dim=128,
+        gdn_value_dim=128, chunk_size=64, use_flash_attention=True,
+        dtype=jnp.bfloat16, remat=False)
+    return (cfg, qn.qwen3_next_init_fn, qn.make_qwen3_next_decode_model,
+            "dstpu_gdn_update")
+
+
+@pytest.mark.parametrize("family", [_nemotron_shaped_guard,
+                                    _qwen3_next_shaped_guard],
+                         ids=["mamba2", "gated-deltanet"])
 def test_state_kind_programs_hold_nothing_of_the_states_size(
-        one_chip, monkeypatch):
-    """PR 25's guard for a pool with a state kind (a Nemotron-H-shaped model:
-    two (LatentMoE, Mamba-2) pairs scanned, then an attention layer, at the
-    served tile widths), in all three step programs: nothing half as large as
+        one_chip, monkeypatch, family):
+    """PR 25's guard for a pool with a state kind (a Nemotron-H-shaped model
+    and a Qwen3-Next-shaped one, at the served tile widths), in all three
+    step programs: nothing half as large as
     ONE LAYER's state leaf is made by an operation that is not an aliased
-    Mosaic call (`dstpu_ssm_update`, `dstpu_ssm_state_write` on the state;
+    Mosaic call (`dstpu_ssm_update` or `dstpu_gdn_update`,
+    `dstpu_ssm_state_write` on the state;
     `dstpu_kv_pool_write` on the attention layer's blocks), the temporaries
     stay under that size, a decode token updates each layer's state in ONE
     call and a chunk reads and writes its row once."""
-    from deepspeed_tpu.models import nemotron_h as nh
     from deepspeed_tpu.ops import attention_dispatch
     from deepspeed_tpu.platform import device
     mesh_mod.clear_mesh()
@@ -1660,17 +1732,10 @@ def test_state_kind_programs_hold_nothing_of_the_states_size(
             lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
                                            sharding=one_chip), tree)
 
-    cfg = nh.NemotronHConfig(
-        vocab_size=512, pattern="EMEM*", n_head=16, n_kv_head=2,
-        d_model=512, attn_head_dim=128, d_ff=256, shared_d_ff=512,
-        moe_latent_size=256, max_seq_len=8192, num_experts=32,
-        experts_held=(8, 8), top_k=4, norm_topk_prob=True,
-        routed_scaling_factor=5.0, mamba_num_heads=16, mamba_head_dim=64,
-        ssm_state_size=128, n_groups=2, chunk_size=128,
-        use_flash_attention=True, dtype=jnp.bfloat16, remat=False)
-    shapes = jax.eval_shape(nh.nemotron_h_init_fn(cfg, dtype=jnp.bfloat16),
+    cfg, init_fn, make_model, update = family()
+    shapes = jax.eval_shape(init_fn(cfg, dtype=jnp.bfloat16),
                             jax.random.PRNGKey(0))
-    spec = nh.make_nemotron_h_decode_model(cfg, name="guard", params=shapes)
+    spec = make_model(cfg, name="guard", params=shapes)
     params = sds(shapes)
     slots, chunk, window, i32 = 64, 512, 4, jnp.int32
     # attention blocks past the chip's fast memory: a small leaf XLA would
@@ -1704,8 +1769,7 @@ def test_state_kind_programs_hold_nothing_of_the_states_size(
             ints(slots), pool, tables(slots)).compile()}
     assert set(spec.kv_pool_writers.values()) \
         == {attention_dispatch.KV_POOL_WRITE_KERNEL}
-    in_place = {"dstpu_ssm_update", "dstpu_ssm_state_write",
-                "dstpu_kv_pool_write"}
+    in_place = {update, "dstpu_ssm_state_write", "dstpu_kv_pool_write"}
     for name, program in programs.items():
         text = program.as_text()
         large = _large_instructions(text, state_layer // 2)
@@ -1718,8 +1782,8 @@ def test_state_kind_programs_hold_nothing_of_the_states_size(
         assert program.memory_analysis().temp_size_in_bytes \
             < state_layer // 2, name
         calls = collections.Counter(_mosaic_calls(text))
-        # the scanned pair's one Mamba-2 layer: a call in the scan's body
-        assert calls["dstpu_ssm_update"] == (name != "prefill"), (name, calls)
+        # the scanned pair's one recurrent layer: a call in the scan's body
+        assert calls[update] == (name != "prefill"), (name, calls)
         # state and convolution tail: a chunk reads and writes its row of
         # each once, a decode token reads and writes the tails' rows
         assert calls["dstpu_ssm_state_read"] == \
@@ -1737,20 +1801,26 @@ _BENCHMARK = os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "benchmark")
 
 
-@pytest.mark.parametrize("family, driver, configuration", [
+@pytest.mark.parametrize("family, driver, configuration, half", [
     ("granite_moe_hybrid", "serve_granite_moe_hybrid",
-     "granite-4.0-h-small-10l-ep4"),
-    ("nemotron_h", "serve_nemotron_h", "nemotron-3-super-120b-a12b-11l-ep4"),
-], ids=["granite", "nemotron"])
+     "granite-4.0-h-small-10l-ep4", ("M", "ssm_in_w", "ssm/in_proj")),
+    ("nemotron_h", "serve_nemotron_h", "nemotron-3-super-120b-a12b-11l-ep4",
+     ("M", "ssm_in_w", "ssm/in_proj")),
+    ("qwen3_next", "serve_qwen3_next", "qwen3-next-80b-a3b-12l-ep8",
+     ("D", "gdn_qkvz_w", "gdn/in_proj")),
+], ids=["granite", "nemotron", "qwen3-next"])
 def test_served_mixed_program_computes_each_in_projection_once(
-        one_chip, monkeypatch, family, driver, configuration):
+        one_chip, monkeypatch, family, driver, configuration, half):
     """The mixed program of each hybrid family at the SERVED sizes of its
-    configuration file: `[z | xBC | dt] = u @ ssm_in_w` has readers at both
+    configuration file: `[z | xBC | dt] = u @ ssm_in_w` (Gated DeltaNet:
+    `[q | k | v | z] = u @ gdn_qkvz_w`) has readers at both
     ends of the half (the convolution takes xBC and dt, the gate z), and
     left alone XLA freed the 20 MiB product in between and computed it a
     second time (`fusion.N.remat`, 7% of Granite's cell; PR 42). No
-    instruction under `ssm/in_proj` is a rematerialised clone, and each
-    scanned run makes the product in exactly one fusion."""
+    instruction under the half's `in_proj` scope is a rematerialised clone,
+    and each scanned run makes the product in exactly one fusion. And PR 43's
+    lesson, on the served tree and pool: every leaf a Mosaic call reads
+    (the pool's four, the expert stacks) ends in whole lane tiles."""
     from deepspeed_tpu.models import hybrid
     from deepspeed_tpu.platform import device
     mesh_mod.clear_mesh()
@@ -1784,13 +1854,19 @@ def test_served_mixed_program_computes_each_in_projection_once(
         sds(shapes), ints(1, chunk), ints(1), ints(1), tables(1),
         ints(slots), ints(slots), pool, tables(slots)).compile().as_text()
 
-    width = hybrid.mixer_shapes(cfg, hybrid.MAMBA)["ssm_in_w"][0][-1]
+    letter, leaf, scope = half
+    read_by_mosaic = list(pool.values()) + [
+        a for trees in shapes["runs"] for tree in trees
+        for name, a in tree.items() if name.startswith("moe_w_")]
+    assert all(a.shape[-1] % 128 == 0 for a in read_by_mosaic), [
+        a.shape for a in read_by_mosaic if a.shape[-1] % 128]
+    width = hybrid.mixer_shapes(cfg, letter)[leaf][0][-1]
     product = f"bf16[1,{chunk + slots},{width}]"
     made = collections.Counter()
     for name, lines in _computations(text).items():
         for line in lines:
             found = _HLO_LINE.match(line)
-            if not found or "ssm/in_proj" not in line:
+            if not found or scope not in line:
                 continue
             assert not found.group(1).endswith(".remat"), line.strip()[:200]
             if found.group(3) == "fusion" \
@@ -1798,8 +1874,8 @@ def test_served_mixed_program_computes_each_in_projection_once(
                 made[name] += 1
     # a scanned run is one loop body: a product for each `M` of its unit
     assert sorted(made.values()) == sorted(
-        unit.count(hybrid.MAMBA) for unit, _ in hybrid.layer_runs(cfg)
-        if hybrid.MAMBA in unit), made
+        unit.count(letter) for unit, _ in hybrid.layer_runs(cfg)
+        if letter in unit), made
 
 
 # ----------------------------------------------------------------------
