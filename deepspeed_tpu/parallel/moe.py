@@ -31,7 +31,13 @@ routing runs one of three ways:
 
 Serving routes a fourth way, `topk_routing` + `routed_experts`: top-k without
 capacity on a sorted, grouped dispatch (`ops/pallas/moe_gmm.py`), whose work
-grows with the N·k assignments and not with E·C.
+grows with the N·k assignments and not with E·C. The assignments are carried
+K-MAJOR (assignment `a` is choice `a // N` of token `a % N`): a token's k
+results come back as `[k, N, D]`, a bitcast of the gathered `[N·k, D]` rows,
+and are summed over the LEADING axis. Token-major they would be `[N, k, D]`
+with k on the sublanes, which the chip's (8, 128) tiling pads to the next
+multiple of 8 (10 -> 16, 4 -> 8): the reshape became a relayout of every row
+and the sum a reduction across sublanes over the padded tensor.
 """
 
 import dataclasses
@@ -398,13 +404,19 @@ def routed_experts(x, top_p, top_e, experts, activation=None,
     capacity and no dropped token: x [N, D] -> (out [N, D] in `x.dtype`,
     counters int32[4] in `ROUTED_COUNTERS` order).
 
-    The N*k assignments are sorted by expert (stable), the token rows are
-    gathered in that order, each projection is ONE grouped matmul over the
-    sorted rows (`ops/pallas/moe_gmm.py`: an expert with no rows is never
-    read), and each token's k results are weighted and summed in float32 in
-    the token's own top-k order, so the sum does not depend on what else is
-    in the batch. The same path for a prefill chunk, a decode row and a
-    verify chunk.
+    The N*k assignments, K-MAJOR (assignment `a` is choice `a // N` of token
+    `a % N`), are sorted by expert (stable), the token rows are gathered in
+    that order, each projection is ONE grouped matmul over the sorted rows
+    (`ops/pallas/moe_gmm.py`: an expert with no rows is never read; a row's
+    result depends on that row alone, so its place within its expert's run
+    does not matter), and the results go back to assignment order as
+    `[k, N, D]`: each token's k results are weighted and summed in float32
+    over that LEADING axis, in the token's own top-k order, so the sum does
+    not depend on what else is in the batch. Nothing here has the shape
+    `[N, k, D]` (the module's header says what k on the sublanes costs);
+    where N is a multiple of the tile, as in every served program,
+    `[k, N, D]` is a bitcast of the gathered rows. The same path for a
+    prefill chunk, a decode row and a verify chunk.
 
     `experts`: gated (SwiGLU) `{"w_gate_up": [E, D, 2F], "w_down":
     [E, F, D]}`, or plain `{"w_up": [E, D, F], "w_down": [E, F, D]}` with
@@ -434,7 +446,7 @@ def routed_experts(x, top_p, top_e, experts, activation=None,
     gated = "w_gate_up" in experts
     gmm = lambda rows, w: moe_gmm(rows, w, sizes, expert_base)
     with jax.named_scope("moe/dispatch"):
-        flat_e = top_e.reshape(M)
+        flat_e = top_e.T.reshape(M)                   # k-major: [choice, token]
         if held is None:
             E = num_experts or experts["w_down"].shape[0]
         else:
@@ -446,7 +458,10 @@ def routed_experts(x, top_p, top_e, experts, activation=None,
         order = jnp.argsort(flat_e, stable=True)      # sorted row -> assignment
         sizes = jnp.sum(flat_e[:, None] == jnp.arange(E, dtype=jnp.int32),
                         axis=0, dtype=jnp.int32)
-        rows = jnp.take(x, order // k, axis=0)        # [M, D]
+        # both gathers' indices are in bounds by construction (`order` and
+        # `back` are permutations of the assignments): "clip" spares the
+        # default mode's fill, a second pass over the gathered [M, D]
+        rows = jnp.take(x, order % N, axis=0, mode="clip")    # [M, D]
     with jax.named_scope("moe/experts"):
         if gated:
             gate, up = jnp.split(gmm(rows, experts["w_gate_up"]), 2, axis=-1)
@@ -462,11 +477,12 @@ def routed_experts(x, top_p, top_e, experts, activation=None,
     with jax.named_scope("moe/combine"):
         back = jnp.zeros((M,), jnp.int32).at[order].set(
             jnp.arange(M, dtype=jnp.int32))           # assignment -> sorted row
-        y = jnp.take(y, back, axis=0).reshape(N, k, D).astype(jnp.float32)
+        y = jnp.take(y, back, axis=0, mode="clip").reshape(k, N, D)
         if held is not None:
             # rows past the held ones are memory nobody wrote
-            y = jnp.where((flat_e < E).reshape(N, k, 1), y, 0.0)
-        out = jnp.sum(y * top_p[:, :, None], axis=1).astype(x.dtype)
+            y = jnp.where((flat_e < E).reshape(k, N, 1), y, 0)
+        out = jnp.sum(y.astype(jnp.float32) * top_p.T[:, :, None],
+                      axis=0).astype(x.dtype)
     if held is None:
         counters = jnp.stack([jnp.int32(1), jnp.int32(M),
                               jnp.sum(sizes > 0, dtype=jnp.int32),
