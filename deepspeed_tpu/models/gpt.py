@@ -27,8 +27,10 @@ from typing import Any, Dict, NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import PartitionSpec as P
 
+from deepspeed_tpu.comm import mesh as mesh_mod
 from deepspeed_tpu.comm.mesh import BATCH_AXES, SEQ_AXIS, TENSOR_AXIS, shard_constraint
 from deepspeed_tpu.ops import attention_dispatch as attn_dispatch
 from deepspeed_tpu.runtime.engine import ModelSpec
@@ -87,14 +89,21 @@ class GPTConfig:
                                      # halves' OUTPUTS)
     tie_embeddings: bool = True
     remat: bool = True               # jax.checkpoint each block
-    remat_policy: str = "nothing_saveable"  # jax.checkpoint_policies name, or
-                                     # "save_matmuls": save every big matmul
-                                     # output (named checkpoints) so backward
-                                     # recomputes only norms/softmax/elementwise.
-                                     # Measured on v5e: full remat WINS anyway —
-                                     # recompute is cheaper than reloading the
-                                     # saved ~150MB/layer from HBM; kept as an
-                                     # option for bandwidth-rich parts
+    remat_policy: Any = None         # None: the block HOLDS what its backward
+                                     # reads of the forward (the flash
+                                     # kernel's output and log-sum-exp, the
+                                     # MLP's product before its activation,
+                                     # the QKV product; the out-projection's
+                                     # result where the residual is
+                                     # sequential), as many of them, in that
+                                     # order, as the device's free memory
+                                     # allows: `held_candidates`, chosen where
+                                     # the engine traces its step
+                                     # (docs/activation_checkpointing.md);
+                                     # nothing fits or nobody gave a budget:
+                                     # every block is recomputed. A
+                                     # `jax.checkpoint_policies` name, or a
+                                     # policy itself, overrides the choice
     use_flash_attention: Optional[bool] = None  # None = AUTO by sequence
                                      # length: the Pallas kernel engages at
                                      # T >= FLASH_MIN_SEQ (measured r4, bf16
@@ -438,28 +447,80 @@ def _rope(x, positions, rotary_dims, theta=10000.0):
         else rotated.astype(x.dtype)
 
 
-SAVE_MATMULS_NAMES = ("qkv_proj", "attn_out", "mlp_up", "mlp_down")
+# What a block's backward reads of its forward is named where it is made
+# (`jax.ad_checkpoint.checkpoint_name`; the flash kernel names its own two
+# residuals, `ops/pallas/flash_attention.py::FLASH_RESIDUALS`). Outside a
+# `jax.checkpoint` a name lowers to nothing: the serving programs run the same
+# halves and do not change by it.
+MLP_PRODUCT = "mlp_product"     # h @ w_up (+ b) BEFORE the activation; SwiGLU:
+                                # both products
+QKV_PRODUCT = "qkv_product"     # the fused q | k | v (| gate) projection
+ATTN_OUT = "attn_out"           # the out-projection's result: the second
+                                # half's input reads it where the residual is
+                                # sequential, nothing does where it is parallel
 
 
-def _ckpt_name(x, name):
-    """Tag a tensor for the "save_matmuls" selective-remat policy."""
-    from jax.ad_checkpoint import checkpoint_name
-    return checkpoint_name(x, name)
+def held_candidates(cfg: GPTConfig, B, T, attn_fn=None):
+    """({name: bytes a layer on ONE device}, the step's working set with
+    nothing named) for a `[B, T]` batch as the traced program sees it
+    (global under the engine's partitioned `jit`: the mesh's batch, sequence
+    and tensor axes divide it here, as `shard_constraint` lays the
+    activations out).
+
+    The names are in the order `fit_held` takes them, by the milliseconds of
+    recompute a held GiB saves on the v5e (PERF.md section 6, PR 49): the
+    flash forward 14 on one chip and 28 on four (a kernel at half its
+    roofline), the MLP's and the QKV products 12 and 13 (matmuls near the
+    peak at the same FLOPs a byte: the wider one first, it strands less of
+    the room), then the out-projection's, which has a reader only in a
+    sequential block. The second result is the step's working set whatever
+    is named, as `held_policy` takes it: every layer's input; the loss's
+    (the logits and a quarter again); one block's backward (the MLP's
+    product, its gradient and the QKV product)."""
+    divide = lambda n, axis: n // mesh_mod.axis_size(axis) \
+        if mesh_mod.has_mesh() and n % mesh_mod.axis_size(axis) == 0 else n
+    tokens = divide(B, BATCH_AXES) * divide(T, SEQ_AXIS)
+    item = jnp.dtype(cfg.dtype).itemsize
+    H, Hkv, hd = cfg.n_head, cfg.n_kv_head, cfg.head_dim
+    heads = divide(H, TENSOR_AXIS)
+    held = {}
+    site = _train_attn_site(cfg, T, T, cfg.use_alibi, attn_fn)
+    if attn_dispatch.select(site) == "flash":
+        from deepspeed_tpu.ops.pallas.flash_attention import FLASH_RESIDUALS
+        # the output, and a float32 log-sum-exp a row in the kernels' own
+        # `[BH, T / bq, 1, bq]` tile, whose unit sublane the device pads to 8
+        held[FLASH_RESIDUALS] = tokens * heads * (hd * item + 8 * 4)
+    up = tokens * divide(cfg.d_ff, TENSOR_AXIS) * item
+    held[MLP_PRODUCT] = up * (2 if cfg.use_swiglu else 1)
+    qkv_heads = H + 2 * Hkv + (H if cfg.attn_output_gate else 0)
+    held[QKV_PRODUCT] = tokens * divide(qkv_heads, TENSOR_AXIS) * hd * item
+    if cfg.post_norm or not cfg.parallel_residual:
+        held[ATTN_OUT] = tokens * cfg.d_model * item
+    logits = 0 if cfg.loss_chunks else \
+        tokens * divide(cfg.vocab_size, TENSOR_AXIS) * item * 5 // 4
+    return held, dict(
+        carried_bytes=cfg.n_layer * tokens * cfg.d_model * item,
+        loss_bytes=logits,
+        backward_bytes=2 * up + held[QKV_PRODUCT])
 
 
-def resolve_remat_policy(name):
-    """remat_policy string → jax.checkpoint policy. "save_matmuls" keeps every
-    tagged matmul output (the MXU-heavy tensors) so the backward recomputes
-    only norms/softmax/elementwise — the cheap fraction of a block.
-    "save_matmuls_probs" additionally keeps the [B,H,T,S] softmax probs, so
-    the backward skips the attention-score recompute entirely — the fastest
-    policy when HBM has room for ~B*H*T*S*2 bytes per layer (bf16 softmax)."""
-    if name == "save_matmuls":
-        return jax.checkpoint_policies.save_only_these_names(*SAVE_MATMULS_NAMES)
-    if name == "save_matmuls_probs":
-        return jax.checkpoint_policies.save_only_these_names(
-            *SAVE_MATMULS_NAMES, "attn_probs")
-    return getattr(jax.checkpoint_policies, name, None)
+def _remat_policy(cfg: GPTConfig, B, T, attn_fn):
+    """The `jax.checkpoint` policy of the scanned block: the held set that
+    fits (`cfg.remat_policy` None), else the `jax.checkpoint_policies` name
+    or the policy that field carries."""
+    policy = cfg.remat_policy
+    if policy is None:
+        from deepspeed_tpu.runtime.activation_checkpointing import held_policy
+        held, working_set = held_candidates(cfg, B, T, attn_fn)
+        return held_policy(held, cfg.n_layer, **working_set)
+    if callable(policy):
+        return policy
+    try:
+        return getattr(jax.checkpoint_policies, policy)
+    except (AttributeError, TypeError):
+        raise ValueError(
+            f"remat_policy {policy!r} is no jax.checkpoint_policies name; "
+            f"leave it None for the held set the engine derives") from None
 
 
 # Dispatch crossovers live in ops/attention_dispatch.py (ONE home for the
@@ -544,7 +605,6 @@ def _attention(q, k, v, causal_mask, cfg, attn_fn=None, bias=None):
         e = jnp.exp((logits - m).astype(jnp.float32)).astype(q.dtype)
         denom = jnp.sum(e, axis=-1, keepdims=True, dtype=jnp.float32)
         probs = (e.astype(jnp.float32) / denom).astype(q.dtype)
-    probs = _ckpt_name(probs, "attn_probs")
     out = jnp.einsum("bkgts,bskd->btkgd", probs, v)
     return out.reshape(B, T, H, hd)
 
@@ -563,15 +623,18 @@ def _mlp(h, p, cfg, constrain=True):
     """MLP half-block: gated (swiglu) or plain with configurable activation.
     `constrain=False` on the decode path ([B, 1, F] can't shard on sequence)."""
     h = _act_quant(h, cfg)
+    # the products are named BEFORE the activation: its backward reads them,
+    # so a block that held the activation's result would still make them again
     if cfg.use_swiglu:
-        up = jax.nn.silu(h @ p["mlp_gate_w"]) * (h @ p["mlp_up_w"])
+        up = jax.nn.silu(checkpoint_name(h @ p["mlp_gate_w"], MLP_PRODUCT)) \
+            * checkpoint_name(h @ p["mlp_up_w"], MLP_PRODUCT)
     else:
-        up = _act(h @ p["mlp_up_w"] + p["mlp_up_b"], cfg)
-    up = _ckpt_name(up, "mlp_up")
+        up = _act(checkpoint_name(h @ p["mlp_up_w"] + p["mlp_up_b"],
+                                  MLP_PRODUCT), cfg)
     if constrain:
         up = shard_constraint(up, BATCH_AXES, SEQ_AXIS, TENSOR_AXIS)
     up = _act_quant(up, cfg)
-    return _ckpt_name(up @ p["mlp_down_w"] + p["mlp_out_b"], "mlp_down")
+    return up @ p["mlp_down_w"] + p["mlp_out_b"]
 
 
 def _qk_norm(q, k, p, cfg: GPTConfig, heads_split=False):
@@ -636,7 +699,7 @@ def _attn_half(x, p, cfg: GPTConfig, positions, attn_fn=None, constrain=True,
 
     h = _half_input(x, p, cfg)
     h = _act_quant(h, cfg)
-    qkv = _ckpt_name(h @ p["attn_qkv_w"] + p["attn_qkv_b"], "qkv_proj")
+    qkv = checkpoint_name(h @ p["attn_qkv_w"] + p["attn_qkv_b"], QKV_PRODUCT)
     q, k, v, gate = _split_qkv(qkv, cfg)
     q, k = _qk_norm(q, k, p, cfg)
     q = q.reshape(B, T, H, hd)
@@ -666,8 +729,8 @@ def _attn_half(x, p, cfg: GPTConfig, positions, attn_fn=None, constrain=True,
     attn = _attention(q, k, v, causal, cfg, attn_fn=attn_fn, bias=bias)
     attn_flat = _act_quant(_gate_output(attn.reshape(B, T, H * hd), gate),
                            cfg)
-    attn_out = _ckpt_name(
-        attn_flat @ p["attn_out_w"] + p["attn_out_b"], "attn_out")
+    attn_out = checkpoint_name(
+        attn_flat @ p["attn_out_w"] + p["attn_out_b"], ATTN_OUT)
     return attn_out, k, v
 
 
@@ -777,8 +840,10 @@ def gpt_hidden(params, tokens, cfg: GPTConfig, positions=None, attn_fn=None,
             return _block(x, layer_params, cfg=cfg, positions=positions,
                           attn_fn=attn_fn, local_flag=flag)
     if cfg.remat:
-        block_fn = jax.checkpoint(block_fn, policy=resolve_remat_policy(cfg.remat_policy),
-                                  prevent_cse=cfg.remat_prevent_cse)
+        remat = partial(jax.checkpoint,
+                        policy=_remat_policy(cfg, B, T, attn_fn),
+                        prevent_cse=cfg.remat_prevent_cse)
+        block_fn = remat(block_fn)
 
     if pld is not None:
         assert flags is None and ltd is None, \
@@ -810,9 +875,7 @@ def gpt_hidden(params, tokens, cfg: GPTConfig, positions=None, attn_fn=None,
         def sub_block(sx, lp, pos):
             return _block(sx, lp, cfg=cfg, positions=pos, attn_fn=None)
         if cfg.remat:
-            sub_block = jax.checkpoint(
-                sub_block, policy=resolve_remat_policy(cfg.remat_policy),
-                prevent_cse=cfg.remat_prevent_cse)
+            sub_block = remat(sub_block)
 
         def plain_body(x, layer_params):
             return block_fn(x, layer_params), None

@@ -48,6 +48,7 @@ import math
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -408,8 +409,16 @@ def _flash(q, k, v, sm_scale, causal, block_q, block_k, interpret):
     return out
 
 
+# The name of the two residuals the backward kernels read of the forward
+# one: under a `jax.checkpoint` whose policy holds this name the backward
+# never runs `dstpu_flash_fwd` again (`models/gpt.py::held_candidates`).
+FLASH_RESIDUALS = "flash_residuals"
+
+
 def _flash_vjp_fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret):
     out, lse = _flash_fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret)
+    out = checkpoint_name(out, FLASH_RESIDUALS)
+    lse = checkpoint_name(lse, FLASH_RESIDUALS)
     return out, (q, k, v, out, lse)
 
 

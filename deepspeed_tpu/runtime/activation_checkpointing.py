@@ -15,9 +15,15 @@ On TPU the mechanism collapses into `jax.checkpoint` policies:
 
 `configure()`/`is_configured()` keep the reference's module-level API so ported
 client code (Megatron-style) runs unchanged.
+
+The zoo's blocks do not take a policy by name by default: they HOLD what their
+backward reads of the forward, as many of those results as the device's free
+memory allows (`fit_held`, `held_policy` below; docs/activation_checkpointing.md).
 """
 
-from functools import partial
+import contextlib
+import dataclasses
+from typing import Callable, Dict, Mapping, Optional, Tuple
 
 import jax
 
@@ -148,3 +154,110 @@ def get_cuda_rng_tracker():
 
 def model_parallel_cuda_manual_seed(seed):
     _RNG_TRACKER.add("model-parallel-rng", seed)
+
+
+# ----------------------------------------------------------------------
+# Held residuals: the remat policy of a scanned block, derived from what fits
+# ----------------------------------------------------------------------
+
+# Of the device's limit, what the fit leaves alone: the runtime's own 258 MiB,
+# what the arithmetic below misses of a step's true peak (it came within 0.03
+# GiB of `memory_analysis().peak_memory_in_bytes` on both of the v5e's
+# training cells), and room for the compiler's packing, which wastes a
+# quarter of a full program's temporaries before it repacks (PERF.md
+# section 7, PR 49).
+HELD_MARGIN_SHARE = 1 / 16
+
+
+@dataclasses.dataclass(frozen=True)
+class HeldPlan:
+    """What a block holds for its backward, and why no more: the names taken
+    (a prefix of `bytes_per_layer`'s order), every candidate's bytes a layer
+    on one device, the bytes that were free for them (`margin_bytes`
+    already taken off) and the first name that did not fit (None: all did)."""
+    names: Tuple[str, ...]
+    bytes_per_layer: Dict[str, int]
+    layers: int
+    free_bytes: int
+    margin_bytes: int
+    first_unfit: Optional[str]
+
+    @property
+    def held_bytes(self) -> int:
+        return self.layers * sum(self.bytes_per_layer[n] for n in self.names)
+
+    def to_dict(self):
+        return {**dataclasses.asdict(self), "names": list(self.names),
+                "held_bytes": self.held_bytes}
+
+    def render(self) -> str:
+        from deepspeed_tpu.telemetry.memscope import fmt_bytes
+        over_layers = lambda n: fmt_bytes(self.layers * self.bytes_per_layer[n])
+        held = ", ".join(f"{n} {over_layers(n)}" for n in self.names) \
+            or "nothing"
+        why = "every candidate fits" if self.first_unfit is None else \
+            f"{self.first_unfit} ({over_layers(self.first_unfit)}) does not fit"
+        return (f"held for the backward over {self.layers} layers: {held} = "
+                f"{fmt_bytes(self.held_bytes)} of "
+                f"{fmt_bytes(self.free_bytes)} free (margin "
+                f"{fmt_bytes(self.margin_bytes)} kept); {why}")
+
+
+def fit_held(free_bytes: int, bytes_per_layer: Mapping[str, int],
+             layers: int, margin_bytes: int = 0) -> HeldPlan:
+    """The names to hold: `bytes_per_layer`'s names in ITS order (the caller
+    ranks them by the recompute a held byte saves) while their sum over
+    `layers` fits under `free_bytes - margin_bytes`; the first that does not
+    fit ends the list, so the sets are nested as the free bytes grow. Pure
+    arithmetic: nothing is compiled or allocated."""
+    room = max(0, int(free_bytes) - int(margin_bytes))
+    names, used, unfit = [], 0, None
+    for name, nbytes in bytes_per_layer.items():
+        if used + layers * nbytes > room:
+            unfit = name
+            break
+        used += layers * nbytes
+        names.append(name)
+    return HeldPlan(tuple(names), dict(bytes_per_layer), int(layers), room,
+                    int(margin_bytes), unfit)
+
+
+_BUDGET = None      # (free bytes, gradient bytes, margin, report)
+
+
+@contextlib.contextmanager
+def held_budget(free_bytes: int, grad_bytes: int = 0, margin_bytes: int = 0,
+                report: Optional[Callable[[HeldPlan], None]] = None):
+    """While a step is TRACED inside this, a block that derives its policy
+    (`held_policy`) may spend `free_bytes` of one device — its limit less
+    the state it holds — on the step's temporaries, of which `grad_bytes`
+    are the gradients', live through the backward; `report` hears the plan
+    it made. The training engine enters it around the model's loss
+    (`Engine._budgeted_loss`); with no budget installed a block holds
+    nothing."""
+    global _BUDGET
+    before = _BUDGET
+    _BUDGET = (int(free_bytes), int(grad_bytes), int(margin_bytes), report)
+    try:
+        yield
+    finally:
+        _BUDGET = before
+
+
+def held_policy(bytes_per_layer: Mapping[str, int], layers: int,
+                carried_bytes: int = 0, loss_bytes: int = 0,
+                backward_bytes: int = 0):
+    """The `jax.checkpoint` policy of a block scanned over `layers`: hold the
+    named results that fit in the installed budget beside what the step
+    keeps with NOTHING held — the layers' inputs (`carried_bytes`) and the
+    larger of its two working sets, the loss's (`loss_bytes`) or one block's
+    backward beside the gradients (`backward_bytes`). No budget, or no room:
+    `nothing_saveable`, the program a block has always lowered to."""
+    free, grads, margin, report = _BUDGET or (0, 0, 0, None)
+    floor = carried_bytes + max(loss_bytes, grads + backward_bytes)
+    plan = fit_held(free - floor, bytes_per_layer, layers, margin)
+    if report is not None:
+        report(plan)
+    if not plan.names:
+        return jax.checkpoint_policies.nothing_saveable
+    return jax.checkpoint_policies.save_only_these_names(*plan.names)

@@ -282,10 +282,14 @@ class Engine:
         self.host_optimizer = None
 
         # ---- loss fn
-        self._loss_fn = _wrap_loss_fn(model.loss_fn, model.has_aux)
+        self._loss_fn = self._budgeted_loss(
+            _wrap_loss_fn(model.loss_fn, model.has_aux))
+        self.held_plan = None         # what the model's blocks hold for their
+                                      # backward (HeldPlan), once a step is traced
 
         # ---- state init (sharded placement)
         self.state = self._init_state(model.params, model.param_specs)
+        self._activation_budget = self._free_bytes_beside_state()
         n_params = tree_num_params(self.state.params)
         log_dist(f"engine: {model.name} | params={n_params/1e6:.2f}M | "
                  f"dtype={jnp.dtype(self.compute_dtype).name} | zero_stage={self.zero_stage} | "
@@ -691,6 +695,58 @@ class Engine:
     # ------------------------------------------------------------------
     # compiled step programs
     # ------------------------------------------------------------------
+
+    def _free_bytes_beside_state(self):
+        """(bytes of one device the step's temporaries may take, those of
+        them the gradients take, the margin a fit keeps): the allocator's
+        limit less this device's shard of the state, and its shard of the
+        gradients. All 0 where the device reports no limit (the CPU harness),
+        and where optimizer state transits the device on its way from the
+        host: a model then holds nothing for its backward, the program it
+        has always been."""
+        from deepspeed_tpu.platform.accelerator import get_accelerator
+        from deepspeed_tpu.runtime.activation_checkpointing import \
+            HELD_MARGIN_SHARE
+        from deepspeed_tpu.telemetry.memscope import device_tree_bytes
+        limit = int(get_accelerator().total_memory() or 0)
+        if not limit or self.offload_optimizer_states or self.nvme_offload:
+            return 0, 0, 0
+        grads = jax.tree_util.tree_map(
+            lambda p, s: jax.ShapeDtypeStruct(p.shape, p.dtype, sharding=s),
+            self.state.params, self._grad_shardings())
+        grad_bytes = device_tree_bytes(grads)
+        if self.gradient_accumulation_steps_value > 1:
+            # the accumulator beside a micro-batch's gradients
+            grad_bytes += grad_bytes * jnp.dtype(
+                self._grad_accum_dtype()).itemsize \
+                // jnp.dtype(self.compute_dtype).itemsize
+        state_bytes = device_tree_bytes(
+            (self.state.params, self.state.master, self.state.opt_state))
+        return (limit - state_bytes, grad_bytes,
+                int(limit * HELD_MARGIN_SHARE))
+
+    def _budgeted_loss(self, loss_fn):
+        """`loss_fn` traced with the device's free bytes on offer
+        (`activation_checkpointing.held_budget`): a zoo model's blocks hold
+        what of their forward fits there, and say so to `_record_held_plan`."""
+        from deepspeed_tpu.runtime.activation_checkpointing import held_budget
+
+        def budgeted(params, batch, rng):
+            with held_budget(*self._activation_budget,
+                             report=self._record_held_plan):
+                return loss_fn(params, batch, rng)
+
+        return budgeted
+
+    def _record_held_plan(self, plan):
+        """Keep the plan a traced block made where the tracing reads it
+        (`engine.held_plan`, which memscope's ledger shows; the step ring's
+        `facts`) and log it when it is new."""
+        if plan == self.held_plan:
+            return
+        self.held_plan = plan
+        self.steptrace.facts["held_residuals"] = plan.to_dict()
+        log_dist(f"engine: {plan.render()}", ranks=[0])
 
     def _grad_shardings(self):
         master_like = self.master_shardings

@@ -45,6 +45,7 @@ the planner half runs anywhere `bin/dstpu_memscope --plan` does.
 
 import dataclasses
 import json
+import math
 import os
 import time
 from typing import Any, Dict, Iterable, List, Optional, Tuple
@@ -54,6 +55,7 @@ from deepspeed_tpu.utils.logging import logger
 __all__ = [
     "MemoryPlan", "PredictedOOMError", "ServingMemScope", "TrainMemScope",
     "plan_training", "plan_serving", "plan_training_from_engine",
+    "device_tree_bytes",
     "plan_training_from_infinity",
     "plan_serving_prealloc", "serving_pool_bytes", "max_kv_blocks",
     "estimate_zero2_model_states_mem_needs",
@@ -131,6 +133,24 @@ def tree_bytes(tree) -> int:
         if size is None or dt is None:
             continue
         total += int(size) * dtype_bytes(dt)
+    return total
+
+
+def device_tree_bytes(tree) -> int:
+    """Bytes ONE device holds of a pytree's array leaves (or of their
+    `ShapeDtypeStruct`s): each leaf's shard by its own sharding, the whole
+    leaf where it has none. What ZeRO and tensor parallelism leave on a
+    chip, read off the placement instead of priced from the config."""
+    import jax
+    total = 0
+    for leaf in jax.tree_util.tree_leaves(tree):
+        shape, dt = getattr(leaf, "shape", None), getattr(leaf, "dtype", None)
+        if shape is None or dt is None:
+            continue
+        sharding = getattr(leaf, "sharding", None)
+        if sharding is not None:
+            shape = sharding.shard_shape(tuple(shape))
+        total += math.prod(shape) * dtype_bytes(dt)
     return total
 
 
@@ -1110,6 +1130,12 @@ class TrainMemScope(_MemScopeBase):
             # a VIEW of params_bytes (the expert-weights slice the planner
             # prices per ep_size), never added to the attribution sum
             info["moe_expert_params_bytes"] = tree_bytes(st.params["moe"])
+        plan = getattr(self.engine, "held_plan", None)
+        if plan is not None:
+            # a VIEW of the train step's temp: what the blocks hold of their
+            # forward for the backward, and the room it was fitted into
+            info["held_residual_bytes"] = plan.held_bytes
+            info["held_residual_free_bytes"] = plan.free_bytes
         return ({"params_bytes": tree_bytes(st.params),
                  "master_bytes": tree_bytes(st.master),
                  "opt_state_bytes": tree_bytes(st.opt_state)}, info)

@@ -210,6 +210,9 @@ class StepTrace:
         self.clock = clock
         self.sink = sink            # ChromeTraceSink or None
         self.step = 0
+        self.facts = {}             # what holds for every step of the run
+                                    # (training: "held_residuals", the plan
+                                    # of what the blocks keep for the backward)
         self._steps = collections.deque(maxlen=self.capacity)
         self._requests = collections.deque(maxlen=self.capacity)
         self._calls = collections.deque(maxlen=self.capacity)
