@@ -63,8 +63,12 @@ class CacheKind:
     """What one kind of layer keeps of a sequence: `layers` layers, each
     holding either the whole context in allocator blocks (`window` 0; `block`
     is then the engine's `kv_block_size`) or the last `window` positions in
-    a per-slot ring of `block`-token blocks; `leaves` names its K and V
-    leaves in the pool pytree. `state`: the kind keeps no token at all but
+    a per-slot ring of `block`-token blocks; `leaves` names its leaves in
+    the pool pytree (K and V, each with the kind's own heads and width; the
+    keys in two leaves where `ops/pallas/kv_pool.py::kv_leaf_shapes` splits
+    them; a latent kind's one) and `entry_values` counts the values the
+    MODEL's entry has a position a layer (what the leaves store may pad it;
+    0: not said). `state`: the kind keeps no token at all but
     one recurrent STATE a slot (`block` and `window` 0; `leaves` its state
     leaves, `[layers, 1 + slots, ...]` with row 0 the trash row): nothing is
     allocated, freed or walked, a program finds a slot's row by `state_rows`,
@@ -76,6 +80,7 @@ class CacheKind:
     window: int = 0
     leaves: tuple = ("k", "v")
     state: bool = False
+    entry_values: int = 0
 
 
 def state_rows(slots: int) -> np.ndarray:
@@ -342,13 +347,20 @@ def gather_block_kv(pool_k_l, pool_v_l, block_tables):
     the block index map — but the gathered form keeps a dense oracle for
     numerics and covers every arch flag.
 
-    pool_[kv]_l: [N, Hkv, block, hd] (one layer's pool); block_tables: [B, nb].
+    pool_[kv]_l: [N, Hkv, block, hd] (one layer's pool; each leaf has its
+    own heads and width); block_tables: [B, nb].
     """
+    return (gather_block_leaf(pool_k_l, block_tables),
+            gather_block_leaf(pool_v_l, block_tables))
+
+
+def gather_block_leaf(pool_l, block_tables):
+    """`gather_block_kv` for one leaf [N, heads, block, width] ->
+    [B, heads, nb*block, width]."""
     B, nb = block_tables.shape
-    N, Hkv, bm, hd = pool_k_l.shape
-    k = jnp.moveaxis(pool_k_l[block_tables], 2, 1).reshape(B, Hkv, nb * bm, hd)
-    v = jnp.moveaxis(pool_v_l[block_tables], 2, 1).reshape(B, Hkv, nb * bm, hd)
-    return k, v
+    _, heads, bm, width = pool_l.shape
+    return jnp.moveaxis(pool_l[block_tables], 2, 1).reshape(
+        B, heads, nb * bm, width)
 
 
 def gather_block_kv_dequant(pool_l, block_tables, dtype):

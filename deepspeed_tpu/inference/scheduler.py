@@ -1537,8 +1537,10 @@ class ServingEngine:
         walk = (0, 0, 0, 0, 0)  # the decode kernel's (live blocks, grid
                              # steps, window layers' live blocks, ...
                              # unwindowed) and a state kind's bytes
-        reach = [0, 0, 0, 0, 0]  # the prefill kernel's (live, table) blocks
-                              # and the window layers' (live, unwindowed)
+        reach = [0] * 7     # the prefill kernel's (live, table) blocks, the
+                            # window layers' (live, unwindowed), a latent
+                            # kind's positions, the (query, position) pairs
+                            # a full and a window layer's masks keep
 
         overlap = self._overlaps()
         if not overlap:
@@ -1647,6 +1649,8 @@ class ServingEngine:
                     prefill_window_table_blocks=reach[3],
                     latent_walk_blocks=walk[0] * self._latent,
                     latent_chunk_positions=reach[4],
+                    prefill_kept_pairs=reach[5],
+                    prefill_window_kept_pairs=reach[6],
                     admitted=admitted,
                     prefill_chunks=self.prefill_chunks - chunks0,
                     fused_chunks=len(riding),
@@ -1750,28 +1754,36 @@ class ServingEngine:
         walk attends, a layer: (logical blocks under the chunk's frontier,
         blocks in its table, and for a pool of two kinds the blocks a WINDOW
         layer's walk visits and the blocks the same walk would visit with no
-        window, both in the window kind's blocks, and the cached positions a
-        chunk over a LATENT kind attends, `start + chunk`) — zeros where
+        window, both in the window kind's blocks, the cached positions a
+        chunk over a LATENT kind attends, `start + chunk`, and the (query,
+        position) pairs the causal mask keeps of the chunk's walk, in a full
+        layer and, with the window, in a window layer) — zeros where
         `program`, the attention program the chunk was traced with, is not
         such a kernel."""
-        reach = (0, 0, 0, 0, 0)
+        reach = (0,) * 7
         if program in ("paged_prefill_kernel", "mla_prefill_kernel"):
             from deepspeed_tpu.ops.pallas.prefill_attention import \
                 paged_prefill_live_blocks
             table = self.tables.shape[1]
+            C = self.chunk
             full = paged_prefill_live_blocks(
-                start, self.chunk, self.block_size, table)
-            reach = (full, table, 0, 0, (start + self.chunk)
-                     * (program == "mla_prefill_kernel"))
+                start, C, self.block_size, table)
+            kept = C * start + C * (C + 1) // 2
+            reach = (full, table, 0, 0,
+                     (start + C) * (program == "mla_prefill_kernel"), kept, 0)
             if self.window_kind is not None:
                 # what a window layer's walk visits, in ITS blocks, of
                 # what the same chunk's walk would visit with no window
                 wkind = self.window_kind
                 width = self.ring_tables.shape[1]
+                # rows that see fewer than `window` positions: all they have
+                short = min(max(wkind.window - 1 - start, 0), C)
                 reach = (full, table, paged_prefill_live_blocks(
-                    start, self.chunk, wkind.block, width, wkind.window),
+                    start, C, wkind.block, width, wkind.window),
                     paged_prefill_live_blocks(
-                        start, self.chunk, wkind.block, width), 0)
+                        start, C, wkind.block, width), 0, kept,
+                    short * start + short * (short + 1) // 2
+                    + (C - short) * wkind.window)
         slot.planned = start + self.chunk
         self._unread_chunks.append((slot, slot.planned))
         self.prefill_chunks += 1
@@ -1907,7 +1919,7 @@ class ServingEngine:
                     self._tables_arg(self.tables[idx], idx))
         step_fn = self.programs.mixed if riding else \
             self.programs.decode_w1() if use_w1 else self.programs.decode
-        rode = [0, 0, 0, 0, 0]
+        rode = [0] * 7
         # the dispatch phase holds the jitted call alone: its two stamps are
         # the call record's launch, the arguments' hand-off and the enqueue
         with self._dispatching(
@@ -2174,11 +2186,15 @@ class ServingEngine:
                     "bytes": int(sum(self.pool[leaf].nbytes
                                      for leaf in kind.leaves)),
                     # what a cached token costs, all the kind's layers (a
-                    # state kind keeps no token)
+                    # state kind keeps no token): as STORED, and as the
+                    # model's entry has it (a kind that does not say: 0)
                     "bytes_per_token": 0 if kind.state else int(sum(
                         self.pool[leaf].nbytes // (self.pool[leaf].shape[1]
                                                    * kind.block)
-                        for leaf in kind.leaves))}
+                        for leaf in kind.leaves)),
+                    "model_bytes_per_token": int(
+                        kind.layers * kind.entry_values
+                        * self.pool[kind.leaves[0]].dtype.itemsize)}
                 for kind in self.cache_kinds}
             if self.window_kind is not None:
                 out["kv_pool_kinds"]["window"]["ring_blocks_per_slot"] = \

@@ -30,7 +30,14 @@ What is new here beside `models/moe_gpt.py`, and where each piece lives:
   `[Lf, N, Hkv, block, hd]`); window layers keep a per-slot ring
   (`wk`/`wv` `[Lw, 1 + slots*ring, Hkv, window_block, hd]`) that nobody
   allocates or frees. The paged programs take the tables as a PAIR
-  `(full tables, ring tables)`.
+  `(full tables, ring tables)`. A KIND OWNS ITS ENTRY: its KV heads, its
+  key and value widths (and with them its leaves: `AttnKind.entry`), its
+  rotary base, its window and its sink are values of the configuration the
+  kind's layers are traced with (`_kind_cfg`: the model's, with
+  `ExaoneMoEConfig.kind_values[kind]` laid over it), so two kinds of one
+  model may differ in any of them (`models/mimo_v2_flash.py`: 4 KV heads
+  beside 8) and the parameter tree, the pool and both loops stay data over
+  `ATTN_KINDS`.
 - THE EXPERT SHARE: the router routes over all `num_experts`, this chip holds
   `experts_held = (first, count)` of them (`parallel/moe.py::routed_experts(
   held=)`), and what the others would add is left out, here and in the
@@ -60,6 +67,7 @@ from deepspeed_tpu.models.mla import (LATENT_LEAF, entry_width, mla_attn_half,
                                       mla_shapes, paged_mla_half)
 from deepspeed_tpu.models.moe_gpt import MoEGPTConfig
 from deepspeed_tpu.ops import attention_dispatch as attn_dispatch
+from deepspeed_tpu.ops.pallas.kv_pool import kv_leaf_shapes
 from deepspeed_tpu.parallel.moe import (HELD_ROUTED_COUNTERS, routed_experts,
                                         topk_routing)
 
@@ -75,7 +83,8 @@ class ExaoneMoEConfig(MoEGPTConfig):
     d_ff_dense: int = 0                     # the dense layers' SwiGLU width
                                             # (`d_ff` is ONE expert's, and
                                             # the shared expert's a unit)
-    num_shared_experts: int = 1
+    num_shared_experts: int = 1             # 0: no shared expert (no leaves,
+                                            # no call)
     experts_held: Optional[Tuple[int, int]] = None   # (first, count) of the
                                             # `num_experts` the router
                                             # chooses among; None = all
@@ -85,6 +94,12 @@ class ExaoneMoEConfig(MoEGPTConfig):
     pattern_period: int = 0                 # layers in a period of the
                                             # pattern (`layer_plan`); 0 =
                                             # found from the lists
+    kind_values: Optional[dict] = None      # {kind: {field: value}}: what a
+                                            # kind's layers are traced with
+                                            # where it is not the model's
+                                            # (`_kind_cfg`). None: the
+                                            # family's — a full layer has
+                                            # neither rotary nor a window
 
     def __post_init__(self):
         # what the family fixes (the published config has no key for them)
@@ -101,6 +116,9 @@ class ExaoneMoEConfig(MoEGPTConfig):
                 f"{len(self.mlp_layer_types)})")
         if WINDOW in self.layer_types and not self.sliding_window:
             raise ValueError("window layers need `sliding_window`")
+        if self.kind_values is None:
+            self.kind_values = {FULL: dict(use_rotary=False,
+                                           sliding_window=None)}
         if self.experts_held is None:
             self.experts_held = (0, self.num_experts)
         first, count = self.experts_held
@@ -109,60 +127,86 @@ class ExaoneMoEConfig(MoEGPTConfig):
                              f"range of the {self.num_experts} experts")
 
 
+UNIT_NORMAL = "unit normal"     # an init "scale": drawn normal(0, 1), float32
+
+
 def _gqa_shapes(cfg):
+    """The attention leaves of a K/V kind, `cfg` the KIND's configuration."""
     D, hd = cfg.d_model, cfg.head_dim
-    return {
+    shapes = {
         "attn_qkv_w": ((D, cfg.qkv_dim), 0.02),
         "attn_qkv_b": ((cfg.qkv_dim,), 0.0),
-        "attn_out_w": ((cfg.n_head * hd, D),
+        "attn_out_w": ((cfg.n_head * cfg.value_dim, D),
                        0.02 / math.sqrt(2 * cfg.n_layer)),
         "attn_out_b": ((D,), 0.0),
-        "q_norm_scale": ((hd,), 1.0), "k_norm_scale": ((hd,), 1.0),
     }
+    if cfg.qk_norm_per_head:
+        shapes.update({"q_norm_scale": ((hd,), 1.0),
+                       "k_norm_scale": ((hd,), 1.0)})
+    if cfg.attn_sink:
+        # drawn, not zero: a program that left the sink out would agree with
+        # a zero sink's reference to within exp(0) in the denominator
+        shapes["attn_sink"] = ((cfg.n_head,), UNIT_NORMAL)
+    return shapes
 
 
-def _window_cfg(cfg):
-    window = copy.copy(cfg)                 # no `__post_init__`
-    window.attn_layer_types = None
-    return window
+def _kind_cfg(cfg, kind):
+    """The configuration a kind's attention halves are traced with (and its
+    leaves and its pool entry sized from): the model's with the kind's own
+    values (`cfg.kind_values`) laid over it."""
+    kcfg = copy.copy(cfg)                   # no `__post_init__`
+    kcfg.attn_layer_types = None
+    for field, value in cfg.kind_values.get(kind, {}).items():
+        setattr(kcfg, field, value)
+    return kcfg
 
 
-def _full_cfg(cfg):
-    full = _window_cfg(cfg)
-    full.use_rotary, full.sliding_window = False, None
-    return full
+def _kv_entry(cfg):
+    return kv_leaf_shapes(cfg.n_kv_head, cfg.head_dim, cfg.value_dim)
 
 
 @dataclasses.dataclass(frozen=True)
 class AttnKind:
     """A kind of attention layer AS DATA — everything the parameter tree,
     the pool and the layer loops below need of it, so that a kind is an
-    entry of `ATTN_KINDS` and not a branch in them."""
-    leaves: Tuple[str, ...]     # its leaves of the pool pytree ...
-    names: Tuple[str, ...]      # ... under the names its paged half reads
+    entry of `ATTN_KINDS` and not a branch in them. `shapes`, `entry` and
+    `values` take the KIND's configuration (`_kind_cfg`), as `dense` and
+    `paged` do: a kind owns its heads, its widths, its rotary base, its
+    window and its sink."""
+    prefix: str                 # its leaves of the pool pytree: this before
+                                # each name of `entry` (`leaves`)
     shapes: Callable            # cfg -> its attention leaves' (shape, scale)
     dense: Callable             # the whole-sequence half (`gpt._attn_half`)
     paged: Callable             # the paged half (`gpt._paged_attn_half`)
-    cfg_of: Callable            # cfg -> the configuration both are traced
-                                # with (a kind's rotary and window are its)
-    entry: Callable             # cfg -> (heads, width) of a cached position
+    entry: Callable             # cfg -> {leaf name as the paged half reads
+                                # it: (heads, width)} of a cached position
+    values: Callable            # cfg -> the values the MODEL's entry has, a
+                                # position a layer (what is stored may pad)
     name: str                   # its `CacheKind`'s name; the layers run
                                 # under the `jax.named_scope` `attn_<name>`
 
+    def leaves(self, cfg):
+        """{name as the paged half reads it: the pool's leaf}."""
+        return {name: self.prefix + name for name in self.entry(cfg)}
 
-# window and full layers: rotary and the window belong to the window layers,
-# a full layer has neither; a latent layer (`models/mla.py`) rotates inside
-# its half and caches one entry a token for all heads
+
+def _kv_values(cfg):
+    return cfg.n_kv_head * (cfg.head_dim + cfg.value_dim)
+
+
+# window and full layers (rotary and the window belong to the window layers,
+# a full layer has neither, unless the model's `kind_values` say otherwise);
+# a latent layer (`models/mla.py`) rotates inside its half and caches one
+# entry a token for all heads
 ATTN_KINDS = {
-    FULL: AttnKind(("k", "v"), ("k", "v"), _gqa_shapes, _attn_half,
-                   _paged_attn_half, _full_cfg,
-                   lambda cfg: (cfg.n_kv_head, cfg.head_dim), "full"),
-    WINDOW: AttnKind(("wk", "wv"), ("k", "v"), _gqa_shapes, _attn_half,
-                     _paged_attn_half, _window_cfg,
-                     lambda cfg: (cfg.n_kv_head, cfg.head_dim), "window"),
-    LATENT: AttnKind((LATENT_LEAF,), (LATENT_LEAF,), mla_shapes,
-                     mla_attn_half, paged_mla_half, _window_cfg,
-                     lambda cfg: (1, entry_width(cfg)), "latent"),
+    FULL: AttnKind("", _gqa_shapes, _attn_half, _paged_attn_half, _kv_entry,
+                   _kv_values, "full"),
+    WINDOW: AttnKind("w", _gqa_shapes, _attn_half, _paged_attn_half,
+                     _kv_entry, _kv_values, "window"),
+    LATENT: AttnKind("", mla_shapes, mla_attn_half, paged_mla_half,
+                     lambda cfg: {LATENT_LEAF: (1, entry_width(cfg))},
+                     lambda cfg: cfg.kv_lora_rank + cfg.qk_rope_head_dim,
+                     "latent"),
 }
 
 
@@ -207,20 +251,25 @@ def layer_plan(cfg: ExaoneMoEConfig):
 
 
 def cache_kinds(cfg: ExaoneMoEConfig, block_size: int):
-    """`CacheKind` a kind of the pool (`pool_kinds`)."""
+    """`CacheKind` a kind of the pool (`pool_kinds`), each with its own
+    leaves and the values its entry has."""
+    kcfg = _kind_cfgs(cfg)
+
     def kind(layer_type):
         window = layer_type == WINDOW
         attn = ATTN_KINDS[layer_type]
-        return CacheKind(attn.name, cfg.layer_types.count(layer_type),
-                         cfg.window_block if window else block_size,
-                         int(cfg.sliding_window or 0) if window else 0,
-                         leaves=attn.leaves)
+        return CacheKind(
+            attn.name, cfg.layer_types.count(layer_type),
+            cfg.window_block if window else block_size,
+            int(kcfg[layer_type].sliding_window or 0) if window else 0,
+            leaves=tuple(attn.leaves(kcfg[layer_type]).values()),
+            entry_values=attn.values(kcfg[layer_type]))
     return tuple(kind(layer_type) for layer_type in pool_kinds(cfg))
 
 
 def _kind_cfgs(cfg: ExaoneMoEConfig):
     """The configuration each kind's attention halves are traced with."""
-    return {name: ATTN_KINDS[name].cfg_of(cfg) for name in pool_kinds(cfg)}
+    return {name: _kind_cfg(cfg, name) for name in pool_kinds(cfg)}
 
 
 # ----------------------------------------------------------------------
@@ -233,7 +282,7 @@ def _layer_shapes(cfg: ExaoneMoEConfig, attn_kind, mlp_kind,
     """One layer's leaves -> (shape, init scale; 1.0 = ones, 0.0 = zeros)."""
     D = cfg.d_model
     down = 0.02 / math.sqrt(2 * cfg.n_layer)
-    shapes = {**ATTN_KINDS[attn_kind].shapes(cfg),
+    shapes = {**ATTN_KINDS[attn_kind].shapes(_kind_cfg(cfg, attn_kind)),
               "ln1_scale": ((D,), 1.0), "ln2_scale": ((D,), 1.0)}
     if mlp_kind == DENSE:
         F = cfg.d_ff_dense
@@ -247,9 +296,12 @@ def _layer_shapes(cfg: ExaoneMoEConfig, attn_kind, mlp_kind,
             "moe_gate_w": ((D, cfg.num_experts), router_std),
             "moe_gate_bias": ((cfg.num_experts,), 0.0),
             "moe_w_gate_up": ((held, D, 2 * F), 0.02),
-            "moe_w_down": ((held, F, D), down),
-            "shared_gate_w": ((D, Fs), 0.02), "shared_up_w": ((D, Fs), 0.02),
-            "shared_down_w": ((Fs, D), down)})
+            "moe_w_down": ((held, F, D), down)})
+        if Fs:
+            shapes.update({
+                "shared_gate_w": ((D, Fs), 0.02),
+                "shared_up_w": ((D, Fs), 0.02),
+                "shared_down_w": ((Fs, D), down)})
     return shapes
 
 
@@ -261,6 +313,8 @@ def _make_layer(rng, cfg, kinds, dtype, lead=(), router_std=0.02):
         shape = tuple(lead) + shape
         if name == "moe_gate_bias":
             tree[name] = jnp.zeros(shape, jnp.float32)
+        elif scale == UNIT_NORMAL:
+            tree[name] = jax.random.normal(sub, shape, jnp.float32)
         elif scale in (0.0, 1.0):
             tree[name] = jnp.full(shape, scale, dtype)
         else:           # a Python float: the product stays in `dtype`
@@ -356,8 +410,8 @@ def _swiglu(h, gate_w, up_w, down_w):
 def _sparse_mlp(h, p, cfg: ExaoneMoEConfig, stacks=None, expert_base=0):
     """The routed half of a sparse layer on h [B, T, D] -> (out, counters
     int32[5] in `HELD_ROUTED_COUNTERS` order, chosen experts [B*T, top_k]);
-    the shared expert's result times `sigmoid(h w_s)` where `p` has a
-    `shared_scale_w` [D].
+    the shared expert's result (where `p` has one) times `sigmoid(h w_s)`
+    where `p` has a `shared_scale_w` [D].
     `stacks`: the experts' weights where they are not `p`'s own leaves —
     `{"w_gate_up": [n * held, D, 2F], "w_down": ...}`, a whole stack of the
     scanned layers' experts, with `expert_base` where this layer's begin."""
@@ -372,6 +426,8 @@ def _sparse_mlp(h, p, cfg: ExaoneMoEConfig, stacks=None, expert_base=0):
     out, counters = routed_experts(xf, top_p, top_e, stacks,
                                    expert_base=expert_base,
                                    held=cfg.experts_held)
+    if "shared_gate_w" not in p:        # `num_shared_experts` 0
+        return out.reshape(B, T, D), counters, top_e
     with jax.named_scope("moe/shared_expert"):
         shared = _swiglu(xf, p["shared_gate_w"], p["shared_up_w"],
                          p["shared_down_w"])
@@ -459,6 +515,8 @@ def make_exaone_moe_decode_model(cfg: ExaoneMoEConfig, params=None,
     prologue, period, periods = layer_plan(cfg)
     kcfg = _kind_cfgs(cfg)
     kinds = {name: ATTN_KINDS[name] for name in pool_kinds(cfg)}
+    # a kind's leaves: {name its paged half reads: the pool's leaf}
+    leaves = {name: kind.leaves(kcfg[name]) for name, kind in kinds.items()}
     held = cfg.experts_held[1]
     no_counts = jnp.zeros((len(HELD_ROUTED_COUNTERS),), jnp.int32)
     pool_writers, attn_programs = {}, {}
@@ -478,23 +536,23 @@ def make_exaone_moe_decode_model(cfg: ExaoneMoEConfig, params=None,
             "paged_decode" if x.shape[1] == 1 else "prefill_chunk"
         in_place = all(
             attn_dispatch.kv_pool_writer(
-                dict(zip(kind.names, (pool[leaf] for leaf in kind.leaves))))
+                {n: pool[leaf] for n, leaf in leaves[name].items()})
             == attn_dispatch.KV_POOL_WRITE_KERNEL
-            for kind in kinds.values())
+            for name in kinds)
         pool_writers[site] = attn_dispatch.KV_POOL_WRITE_KERNEL if in_place \
             else attn_dispatch.KV_POOL_WRITE_SCATTER
-        blocks_of = {name: pool[kind.leaves[0]].shape[1]
-                     for name, kind in kinds.items()}
+        first = {name: pool[next(iter(leaves[name].values()))]
+                 for name in kinds}
+        blocks_of = {name: leaf.shape[1] for name, leaf in first.items()}
         # one work list a KIND, built once a token, outside the layer loop
         work = dict.fromkeys(kinds)
         if site != "prefill_chunk":
             from deepspeed_tpu.ops.pallas.decode_attention import \
                 paged_decode_work
             work = {name: paged_decode_work(
-                *decode_rows(tables[name], positions),
-                pool[kind.leaves[0]].shape[3],
+                *decode_rows(tables[name], positions), first[name].shape[3],
                 window=kcfg[name].sliding_window)
-                for name, kind in kinds.items()}
+                for name in kinds}
         # every kind's leaves flat and CARRIED: layer i of a kind addresses
         # its blocks as `table + i * N`
         flat = {k: v.reshape((-1,) + v.shape[2:]) for k, v in pool.items()}
@@ -513,12 +571,12 @@ def make_exaone_moe_decode_model(cfg: ExaoneMoEConfig, params=None,
             with jax.named_scope(f"attn_{kind.name}"):
                 attn_out, pool_l = kind.paged(
                     x, p, {n: flat[leaf]
-                           for n, leaf in zip(kind.names, kind.leaves)},
+                           for n, leaf in leaves[attn_kind].items()},
                     positions, table, kcfg[attn_kind],
                     decode_work=work[attn_kind],
                     attn_programs=attn_programs, **where)
             flat = {**flat, **{leaf: pool_l[n]
-                               for n, leaf in zip(kind.names, kind.leaves)}}
+                               for n, leaf in leaves[attn_kind].items()}}
             with jax.named_scope("mlp"):
                 x = _residual_mlp(x, attn_out, p, cfg, constrain=False,
                                   mlp_fn=_mlp_fn(p, cfg, mlp_kind, counts,
@@ -600,13 +658,11 @@ def make_exaone_moe_decode_model(cfg: ExaoneMoEConfig, params=None,
                 f"init_paged_pool needs `window_blocks` (1 + slots * "
                 f"kv_cache.ring_blocks(...)), as ServingEngine passes it")
         pool = {}
-        for attn, kind in zip(kinds.values(), cache_kinds(cfg, block_size)):
-            heads, width = attn.entry(cfg)
-            shape = (kind.layers,
-                     window_blocks if kind.window else num_blocks,
-                     heads, kind.block, width)
-            pool.update({leaf: jnp.zeros(shape, dtype)
-                         for leaf in kind.leaves})
+        for attn, kind in zip(kinds, cache_kinds(cfg, block_size)):
+            blocks = window_blocks if kind.window else num_blocks
+            for n, (heads, width) in kinds[attn].entry(kcfg[attn]).items():
+                pool[leaves[attn][n]] = jnp.zeros(
+                    (kind.layers, blocks, heads, kind.block, width), dtype)
         return pool
 
     def unserved(*_args, **_kwargs):

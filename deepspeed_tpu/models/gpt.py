@@ -47,6 +47,21 @@ class GPTConfig:
                                      # d_model // n_head (K-EXAONE: 64 heads
                                      # of 128 on a 6144 stream); the
                                      # projections are then [D, H*hd] / [H*hd, D]
+    attn_value_dim: Optional[int] = None  # a head's VALUE width where it is
+                                     # not its query-key width `head_dim`
+                                     # (192-wide keys beside 128-wide values):
+                                     # the out-projection is then
+                                     # [H*value_dim, D]
+    attn_value_scale: float = 1.0    # the values times this, where they are
+                                     # projected (so the cache holds them
+                                     # scaled)
+    attn_sink: bool = False          # a learned logit a head (`attn_sink` [H],
+                                     # float32) joins the softmax's
+                                     # denominator and has no value: a row's
+                                     # weights sum to less than 1. Dense
+                                     # forms: one more column; the streaming
+                                     # kernels: the online softmax's INITIAL
+                                     # state (m = sink, l = 1, acc = 0)
     d_ff: Optional[int] = None       # default 4*d_model (or 8/3 for swiglu)
     max_seq_len: int = 1024
     dropout: float = 0.0
@@ -175,11 +190,17 @@ class GPTConfig:
         return self.attn_head_dim or self.d_model // self.n_head
 
     @property
+    def value_dim(self):
+        return self.attn_value_dim or self.head_dim
+
+    @property
     def qkv_dim(self):
-        """Fused qkv output width: H*hd for q + 2*Hkv*hd for k,v (GQA-aware),
-        and H*hd more where the output is gated (`attn_output_gate`)."""
-        return ((1 + self.attn_output_gate) * self.n_head
-                + 2 * self.n_kv_head) * self.head_dim
+        """Fused qkv output width: H*hd for q + Hkv*hd for k + Hkv*value_dim
+        for v (GQA-aware), and H*value_dim more where the output is gated
+        (`attn_output_gate`)."""
+        return (self.n_head + self.n_kv_head) * self.head_dim \
+            + (self.attn_output_gate * self.n_head
+               + self.n_kv_head) * self.value_dim
 
     def num_params(self):
         wpe = 0 if self.use_rotary else self.max_seq_len * self.d_model
@@ -550,14 +571,32 @@ def _train_attn_site(cfg, T, S, has_bias, attn_fn):
         phase="train", q_len=T, kv_len=S, causal=True,
         has_bias=has_bias, has_window=bool(cfg.sliding_window),
         scale_attn=bool(cfg.scale_attn),
+        sink=cfg.attn_sink, head_dim=cfg.head_dim, value_dim=cfg.value_dim,
         mesh_axes=attn_dispatch.active_mesh_axes(),
         force_flash=cfg.use_flash_attention,
         backend=getattr(cfg, "attention_backend", None),
         external_fn=attn_fn is not None)
 
 
-def _attention(q, k, v, causal_mask, cfg, attn_fn=None, bias=None):
-    """q: [B, T, H, hd]; k,v: [B, S, Hkv, hd] → [B, T, H, hd]. fp32 softmax.
+def _softmax_with_sink(logits, sink):
+    """float32 softmax over the keys of grouped scores `logits` [B, Hkv, G,
+    rows, S] with one more column, the logit `sink` [H] a head, that joins
+    the denominator and is dropped: the rows sum to less than 1. `sink`
+    None: the plain softmax."""
+    if sink is None:
+        return jax.nn.softmax(logits, axis=-1)
+    column = jnp.broadcast_to(
+        sink.astype(logits.dtype).reshape(logits.shape[1:3])[:, :, None,
+                                                             None],
+        logits.shape[:-1] + (1,))
+    return jax.nn.softmax(jnp.concatenate([logits, column], axis=-1),
+                          axis=-1)[..., :-1]
+
+
+def _attention(q, k, v, causal_mask, cfg, attn_fn=None, bias=None,
+               sink=None):
+    """q: [B, T, H, hd]; k: [B, S, Hkv, hd]; v: [B, S, Hkv, vd] → [B, T, H,
+    vd]. fp32 softmax; `sink` [H]: `cfg.attn_sink`'s logit a head.
 
     GQA (Hkv < H): query heads are grouped per kv head and contracted without
     materializing repeated k/v (reference serves GQA models like llama2-70b via
@@ -596,8 +635,9 @@ def _attention(q, k, v, causal_mask, cfg, attn_fn=None, bias=None):
     neg = jnp.asarray(-1e30 if sm_dtype == jnp.float32 else -3e38, sm_dtype)
     logits = jnp.where(causal_mask[:, None], logits, neg)
     if sm_dtype == jnp.float32:
-        probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
+        probs = _softmax_with_sink(logits, sink).astype(q.dtype)
     else:
+        assert sink is None, "a sink logit needs the float32 softmax"
         # reduced-precision softmax: the [T,S] score tensor stays bf16 (the
         # HBM-traffic hot spot); max-subtraction keeps exp well-conditioned
         # and the exp itself runs in fp32 before narrowing back
@@ -606,7 +646,7 @@ def _attention(q, k, v, causal_mask, cfg, attn_fn=None, bias=None):
         denom = jnp.sum(e, axis=-1, keepdims=True, dtype=jnp.float32)
         probs = (e.astype(jnp.float32) / denom).astype(q.dtype)
     out = jnp.einsum("bkgts,bskd->btkgd", probs, v)
-    return out.reshape(B, T, H, hd)
+    return out.reshape(B, T, H, v.shape[-1])
 
 
 def _act_quant(x, cfg):
@@ -654,9 +694,17 @@ def _split_qkv(qkv, cfg: GPTConfig):
     """The fused projection's columns -> (q, k, v, gate): `gate` [.., H*hd]
     under `cfg.attn_output_gate`, else None."""
     H, Hkv, hd = cfg.n_head, cfg.n_kv_head, cfg.head_dim
+    keys = (H + Hkv) * hd
     q, k, v, gate = jnp.split(
-        qkv, [H * hd, (H + Hkv) * hd, (H + 2 * Hkv) * hd], axis=-1)
+        qkv, [H * hd, keys, keys + Hkv * cfg.value_dim], axis=-1)
     return q, k, v, (gate if cfg.attn_output_gate else None)
+
+
+def _scale_values(v, cfg: GPTConfig):
+    """`cfg.attn_value_scale` on the projected values."""
+    if cfg.attn_value_scale == 1.0:
+        return v
+    return (v * cfg.attn_value_scale).astype(v.dtype)
 
 
 def _gate_output(attn, gate):
@@ -704,7 +752,7 @@ def _attn_half(x, p, cfg: GPTConfig, positions, attn_fn=None, constrain=True,
     q, k = _qk_norm(q, k, p, cfg)
     q = q.reshape(B, T, H, hd)
     k = k.reshape(B, T, Hkv, hd)
-    v = v.reshape(B, T, Hkv, hd)
+    v = _scale_values(v.reshape(B, T, Hkv, cfg.value_dim), cfg)
     q, k = _qk_norm(q, k, p, cfg, heads_split=True)
     if constrain:
         # activations: heads on tensor axis (Megatron), seq on sequence axis
@@ -726,9 +774,10 @@ def _attn_half(x, p, cfg: GPTConfig, positions, attn_fn=None, constrain=True,
     causal = causal[None, None, :, :]
     # alibi uses in-sequence distances (standard unpadded formulation)
     bias = _alibi_bias(cfg, t_pos, t_pos) if cfg.use_alibi else None
-    attn = _attention(q, k, v, causal, cfg, attn_fn=attn_fn, bias=bias)
-    attn_flat = _act_quant(_gate_output(attn.reshape(B, T, H * hd), gate),
-                           cfg)
+    attn = _attention(q, k, v, causal, cfg, attn_fn=attn_fn, bias=bias,
+                      sink=p["attn_sink"] if cfg.attn_sink else None)
+    attn_flat = _act_quant(
+        _gate_output(attn.reshape(B, T, H * cfg.value_dim), gate), cfg)
     attn_out = checkpoint_name(
         attn_flat @ p["attn_out_w"] + p["attn_out_b"], ATTN_OUT)
     return attn_out, k, v
@@ -1015,7 +1064,7 @@ def _decode_qkv(x, p, positions, cfg: GPTConfig):
     q, k = _qk_norm(q, k, p, cfg)
     q = q.reshape(B, C, H, hd)
     k = k.reshape(B, C, Hkv, hd)
-    v = v.reshape(B, C, Hkv, hd)
+    v = _scale_values(v.reshape(B, C, Hkv, cfg.value_dim), cfg)
     q, k = _qk_norm(q, k, p, cfg, heads_split=True)
     if cfg.use_rotary:
         rd = int(cfg.rotary_pct * hd) // 2 * 2
@@ -1048,6 +1097,7 @@ def _decode_attn_site(cfg: GPTConfig, phase, C, M, kv_dtype="bfloat16",
         has_window=bool(cfg.sliding_window) and window is None,
         window=window or 0,
         scale_attn=bool(cfg.scale_attn), kv_dtype=kv_dtype,
+        sink=cfg.attn_sink, head_dim=cfg.head_dim, value_dim=cfg.value_dim,
         block_size=block_size,
         pool_in_place=pool_in_place,
         mesh_axes=attn_dispatch.active_mesh_axes(),
@@ -1468,15 +1518,17 @@ def scan_paged(cfg: GPTConfig, blocks, x, pool, block_tables, positions,
     return result(x, pool, aux)
 
 
-def _paged_attend(q, k_ctx, v_ctx, q_pos, cfg: GPTConfig, local_flag=None):
+def _paged_attend(q, k_ctx, v_ctx, q_pos, cfg: GPTConfig, local_flag=None,
+                  sink=None):
     """Attend q over table-gathered KV with ABSOLUTE positions.
 
     q: [B, C, H, hd] (C = 1 for decode, = chunk length for chunked prefill);
-    k_ctx/v_ctx: [B, Hkv, S, hd] in logical order (S = nb * block — gathered
-    rows ARE position order, so k index == absolute position); q_pos: [B, C].
+    k_ctx [B, Hkv, S, hd] / v_ctx [B, Hkv, S, vd] in logical order (S = nb *
+    block — gathered rows ARE position order, so k index == absolute
+    position); q_pos: [B, C]; `sink` [H]: `cfg.attn_sink`'s logit a head.
     Causal/window masks and alibi bias are built from absolute positions
     per row — unlike the training path, two rows of a serving batch sit at
-    different positions. Returns [B, C, H*hd]; fp32 softmax."""
+    different positions. Returns [B, C, H*vd]; fp32 softmax."""
     B, C, H, hd = q.shape
     Hkv, S = k_ctx.shape[1], k_ctx.shape[2]
     G = H // Hkv
@@ -1495,9 +1547,9 @@ def _paged_attend(q, k_ctx, v_ctx, q_pos, cfg: GPTConfig, local_flag=None):
         logits = logits - (_alibi_slopes(H).reshape(Hkv, G)[None, :, :, None, None]
                            * dist[:, None, None, :, :])
     logits = jnp.where(valid[:, None, None, :, :], logits, -1e30)
-    probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
+    probs = _softmax_with_sink(logits, sink).astype(q.dtype)
     out = jnp.einsum("bkgcs,bksd->bckgd", probs, v_ctx)
-    return out.reshape(B, C, H * hd)
+    return out.reshape(B, C, H * v_ctx.shape[-1])
 
 
 def _paged_attn_half(x, p, pool_l, positions, block_tables,
@@ -1507,7 +1559,10 @@ def _paged_attn_half(x, p, pool_l, positions, block_tables,
 
     x: [B, C, D]; pool_l: one layer's pool slice — ``k``/``v``
     [N, Hkv, block, hd] plus, for the int8 quantized pool,
-    ``k_scale``/``v_scale`` [N, Hkv, block, hd//g]; positions: [B, C]
+    ``k_scale``/``v_scale`` [N, Hkv, block, hd//g] (a head whose keys are a
+    lane tile and a half wide keeps them in two leaves, ``k``/``kr``, and
+    values of their own width: `ops/pallas/kv_pool.py::kv_leaf_shapes`);
+    positions: [B, C]
     absolute; block_tables: [B, nb]. Writes the C new tokens' k/v into each
     row's blocks (logical position -> table -> physical block scatter), then
     attends over the row's whole table. Returns (attn_out, pool_l).
@@ -1545,7 +1600,8 @@ def _paged_attn_half(x, p, pool_l, positions, block_tables,
     """
     q, k, v, gate = _decode_qkv(x, p, positions, cfg)
     group = partial(_paged_write_attend, cfg=cfg, local_flag=local_flag,
-                    block_base=block_base, attn_programs=attn_programs)
+                    block_base=block_base, attn_programs=attn_programs,
+                    sink=p["attn_sink"] if cfg.attn_sink else None)
     if isinstance(block_tables, MixedTables):
         # a mixed call: the chunk's rows [1, C, ...], then a row a slot,
         # [S, 1, ...] as the decode program has them. Each group writes and
@@ -1579,15 +1635,18 @@ def _paged_attn_half(x, p, pool_l, positions, block_tables,
 def _paged_write_attend(q, k, v, pool_l, positions, block_tables,
                         cfg: GPTConfig, local_flag=None, phase=None,
                         block_base=None, decode_work=None, attn_programs=None,
-                        record=None):
+                        record=None, sink=None):
     """`_paged_attn_half` between its two matmuls, for rows that share a
-    dispatch site: write k/v [B, C, Hkv, hd] through `block_tables` [B, nb]
-    at `positions` [B, C], then attend q [B, C, H, hd] over each row's
-    table. Returns (attn [B, C, H*hd], pool_l). `record`: the key the
-    selected program's name is kept under in `attn_programs` (default: the
-    site's phase)."""
-    from deepspeed_tpu.inference.kv_cache import (gather_block_kv,
-                                                  gather_block_kv_dequant)
+    dispatch site: write k [B, C, Hkv, hd] / v [B, C, Hkv, vd] through
+    `block_tables` [B, nb] at `positions` [B, C], then attend q [B, C, H,
+    hd] over each row's table. Returns (attn [B, C, H*vd], pool_l).
+    `record`: the key the selected program's name is kept under in
+    `attn_programs` (default: the site's phase); `sink` [H]: the sink logit
+    a head (`cfg.attn_sink`)."""
+    from deepspeed_tpu.inference.kv_cache import (gather_block_kv_dequant,
+                                                  gather_block_leaf)
+    from deepspeed_tpu.ops.pallas.kv_pool import (kv_pool_gather, merge_keys,
+                                                  pool_rows)
 
     B, C = positions.shape
     bs = pool_l["k"].shape[2]
@@ -1605,7 +1664,7 @@ def _paged_write_attend(q, k, v, pool_l, positions, block_tables,
         if block_base is not None:
             from deepspeed_tpu.ops.pallas.kv_pool import kv_pool_write
             block_tables = block_tables + block_base
-            for leaf, rows in (("k", k), ("v", v)):
+            for leaf, rows in pool_rows(k, v, pool_l).items():
                 pool_l[leaf] = kv_pool_write(pool_l[leaf], rows,
                                              positions[:, 0], block_tables)
         else:
@@ -1619,7 +1678,7 @@ def _paged_write_attend(q, k, v, pool_l, positions, block_tables,
                 qv, sv = quantize_kv(v, g)
                 new_rows = {"k": qk, "v": qv, "k_scale": sk, "v_scale": sv}
             else:
-                new_rows = {"k": k, "v": v}
+                new_rows = pool_rows(k, v, pool_l)
             for leaf, rows in new_rows.items():
                 pool_l[leaf] = pool_l[leaf].at[blk, :, off, :].set(
                     rows.astype(pool_l[leaf].dtype))
@@ -1643,6 +1702,7 @@ def _paged_write_attend(q, k, v, pool_l, positions, block_tables,
         phase or ("paged_decode" if C == 1 else "prefill_chunk"), C, nb * bs,
         kv_dtype="int8" if quantized else str(jnp.dtype(pool_l["k"].dtype)),
         block_size=bs, pool_in_place=block_base is not None)
+    sunk = {} if sink is None else dict(sink=sink)
     program = attn_dispatch.select(site)
     if attn_programs is not None:
         attn_programs[record or site.phase] = program
@@ -1651,24 +1711,25 @@ def _paged_write_attend(q, k, v, pool_l, positions, block_tables,
         with jax.named_scope("attn"):
             attn = runner(q, pool_l, block_tables, positions[:, 0],
                           sm_scale=sm_scale(cfg),
-                          window=site.window or None, work=decode_work)
+                          window=site.window or None, work=decode_work,
+                          **sunk)
     else:
         with jax.named_scope("kv_pool_read"):
             if quantized:
                 k_ctx, v_ctx = gather_block_kv_dequant(pool_l, block_tables,
                                                        q.dtype)
-            elif block_base is not None:
+            else:
                 # an XLA gather on the carried pool slices the WHOLE pool
                 # (see ops/pallas/kv_pool.py): reads are Mosaic calls too
-                from deepspeed_tpu.ops.pallas.kv_pool import kv_pool_gather
-                k_ctx = kv_pool_gather(pool_l["k"], block_tables)
-                v_ctx = kv_pool_gather(pool_l["v"], block_tables)
-            else:
-                k_ctx, v_ctx = gather_block_kv(pool_l["k"], pool_l["v"],
-                                               block_tables)
+                gather = kv_pool_gather if block_base is not None \
+                    else gather_block_leaf
+                ctx = {leaf: gather(rows, block_tables)
+                       for leaf, rows in pool_l.items()}
+                v_ctx = ctx.pop("v")
+                k_ctx = merge_keys(ctx)
         with jax.named_scope("attn"):
             attn = _paged_attend(q, k_ctx, v_ctx, positions, cfg,
-                                 local_flag=local_flag)
+                                 local_flag=local_flag, sink=sink)
     return attn, pool_l
 
 

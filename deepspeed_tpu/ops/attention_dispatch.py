@@ -103,6 +103,14 @@ class AttnSite:
                                   # the paged walks take as a lower bound and
                                   # a mask; 0 = none
     scale_attn: bool = True       # False = unscaled scores (GPT-Neo)
+    sink: bool = False            # a learned logit a head joins the softmax's
+                                  # denominator (`GPTConfig.attn_sink`): the
+                                  # paged walks take it as the online
+                                  # softmax's initial state, flash does not
+    head_dim: int = 0             # a head's query-key width and its value
+    value_dim: int = 0            # width (0 / 0: a site that does not say
+                                  # has one width): the paged walks take
+                                  # values of their own width, flash does not
     kv_dtype: str = "bfloat16"    # KV storage dtype ("int8" = quantized pool)
     block_size: int = 0           # paged pool physical block (paged phases)
     pool_in_place: bool = False   # paged phases: the pool is the carried,
@@ -146,7 +154,9 @@ class AttentionProgram:
         position, the site's static window (None = none), the decode
         walks' work list where the caller built it outside its layer loop,
         and for a latent pool the latent rank (q then `[q~ | q_r | 0]`, the
-        result the un-absorbed values) -> [B, C, H * hd] ([B, C, H * rank]);
+        result the un-absorbed values) -> [B, C, H * hd] ([B, C, H * rank];
+        H * the values' width where that is their own); a K/V walk also
+        takes `sink=` [H], given only at a site with a sink logit;
       * contiguous decode: `(q, cache_k, cache_v, pos, *, sm_scale)` with q
         [B, H, hd] and the head-major cache -> [B, H, hd].
 
@@ -260,6 +270,7 @@ def _train_ring(site):
 def _train_flash(site):
     return (site.phase == "train" and _kernel_shape_ok(site)
             and site.scale_attn and site.causal
+            and not site.sink and site.head_dim == site.value_dim
             and flash_wanted(site.force_flash, site.q_len))
 
 
@@ -342,7 +353,8 @@ register_program(AttentionProgram(
     name="flash", phases=("train",), priority=50,
     matches=_train_flash,
     when=f"T >= {FLASH_MIN_SEQ} (auto) or use_flash_attention=True; "
-         "plain scaled causal, T % 128 == 0",
+         "plain scaled causal, T % 128 == 0, no sink logit, values as wide "
+         "as the keys",
     runner=_run_flash))
 
 register_program(AttentionProgram(
@@ -408,8 +420,10 @@ KV_POOL_WRITE_SCATTER = "xla_scatter"
 
 def kv_pool_writer(pool) -> str:
     """Name the writer for `pool` (the `[L, N, Hkv, block, hd]` pytree of
-    `init_paged_kv_pool`, or a latent kind's one leaf `{"ckv": [L, N, 1,
-    block, width]}`) from what can be seen at trace time: the in-place
+    `init_paged_kv_pool` — `k`/`v`, each leaf with its own width, and `kr`
+    where the keys' half tile is kept apart, `kv_pool.py::kv_leaf_shapes` —
+    or a latent kind's one leaf `{"ckv": [L, N, 1, block, width]}`) from
+    what can be seen at trace time: the in-place
     kernel for a float pool made of whole native tiles, on a TPU, in a
     single-device program (a bare Mosaic call cannot be partitioned — the
     same limit as `dstpu_paged_decode`); the scatter everywhere else: the
@@ -417,11 +431,10 @@ def kv_pool_writer(pool) -> str:
     lane tile, a multi-device mesh."""
     from deepspeed_tpu.ops.pallas.kv_pool import pool_in_place_supported
     from deepspeed_tpu.platform.device import pallas_interpret
-    leaves = set(pool)
-    k = pool["ckv" if leaves == {"ckv"} else "k"]
-    if (leaves in ({"k", "v"}, {"ckv"}) and not pallas_interpret()
-            and not active_mesh_axes()
-            and pool_in_place_supported(k.dtype, k.shape[-2], k.shape[-1])):
+    if (set(pool) in ({"k", "v"}, {"k", "kr", "v"}, {"ckv"})
+            and not pallas_interpret() and not active_mesh_axes()
+            and all(pool_in_place_supported(x.dtype, x.shape[-2], x.shape[-1])
+                    for x in pool.values())):
         return KV_POOL_WRITE_KERNEL
     return KV_POOL_WRITE_SCATTER
 
@@ -430,14 +443,32 @@ def kv_pool_writer(pool) -> str:
 # the one query row a slot and hands back the chunk layout.
 
 
+def _walk_args(q, pool_l, sm_scale, sink):
+    """(q, sm_scale, the K/V walks' further keywords) of a site: for a pool
+    that keeps the keys' half tile apart (`kv_pool.py::kv_leaf_shapes`) the
+    query laid out against `[k | kr]`, the scale the MODEL's head width's and
+    `kr_pool=`; `sink=` where the site has a sink logit."""
+    more = {} if sink is None else dict(sink=sink)
+    if "kr" not in pool_l:
+        return q, sm_scale, more
+    import math
+
+    from deepspeed_tpu.ops.pallas.kv_pool import split_query
+    return (split_query(q, pool_l["k"].shape[1]),
+            sm_scale or 1.0 / math.sqrt(q.shape[-1]),
+            dict(more, kr_pool=pool_l["kr"]))
+
+
 def _run_paged_decode(q, pool_l, block_tables, start, *, sm_scale=None,
-                      window=None, work=None, rank=None):
+                      window=None, work=None, rank=None, sink=None):
     from deepspeed_tpu.ops.pallas.decode_attention import \
         paged_decode_attention
     B = q.shape[0]
+    q, sm_scale, more = _walk_args(q, pool_l, sm_scale, sink)
     return paged_decode_attention(
         q[:, 0], pool_l["k"], pool_l["v"], block_tables, start,
-        sm_scale=sm_scale, work=work, window=window).reshape(B, 1, -1)
+        sm_scale=sm_scale, work=work, window=window,
+        **more).reshape(B, 1, -1)
 
 
 def _run_paged_decode_quant(q, pool_l, block_tables, start, *, sm_scale=None,
@@ -452,11 +483,13 @@ def _run_paged_decode_quant(q, pool_l, block_tables, start, *, sm_scale=None,
 
 
 def _run_paged_prefill(q, pool_l, block_tables, start, *, sm_scale=None,
-                       window=None, work=None, rank=None):
+                       window=None, work=None, rank=None, sink=None):
     from deepspeed_tpu.ops.pallas.prefill_attention import \
         paged_prefill_attention
+    q, sm_scale, more = _walk_args(q, pool_l, sm_scale, sink)
     return paged_prefill_attention(q, pool_l["k"], pool_l["v"], block_tables,
-                                   start, sm_scale=sm_scale, window=window)
+                                   start, sm_scale=sm_scale, window=window,
+                                   **more)
 
 
 def _run_mla_decode(q, pool_l, block_tables, start, *, sm_scale=None,
@@ -503,9 +536,10 @@ register_program(AttentionProgram(
 
 register_program(AttentionProgram(
     name="paged_kernel_quant", phases=("paged_decode",), priority=60,
-    matches=lambda s: _paged_kernel_ok(s) and s.kv_dtype == "int8",
-    when="int8 pool + kernel conditions: streamed tiles dequantize "
-         "in-kernel (paged_decode_attention_quant)",
+    matches=lambda s: (_paged_kernel_ok(s) and s.kv_dtype == "int8"
+                       and not s.sink),
+    when="int8 pool + kernel conditions, no sink logit: streamed tiles "
+         "dequantize in-kernel (paged_decode_attention_quant)",
     runner=_run_paged_decode_quant))
 
 register_program(AttentionProgram(
@@ -513,7 +547,9 @@ register_program(AttentionProgram(
     matches=_paged_kernel_ok,
     when="C == 1, block % 128 == 0, effective context nb*block past the "
          "decode crossover; no alibi, no per-layer local flag (a static "
-         "window is the walk's lower bound and a mask)",
+         "window is the walk's lower bound and a mask; a sink logit its "
+         "softmax's initial state; values of their own width and keys in "
+         "two leaves as the pool holds them)",
     runner=_run_paged_decode))
 
 
@@ -533,8 +569,9 @@ register_program(AttentionProgram(
     when="in-place pool form (float pool of whole tiles, TPU, single "
          "device), C % 128 == 0, block % 128 == 0, no alibi, no per-layer "
          "local flag: flash walk over the blocks under the chunk's frontier "
-         "and, with a static window, from the block the window begins in "
-         "(dstpu_paged_prefill)",
+         "and, with a static window, from the block the window begins in; "
+         "a sink logit, values of their own width and keys in two leaves "
+         "as the decode walk takes them (dstpu_paged_prefill)",
     runner=_run_paged_prefill))
 
 register_program(AttentionProgram(

@@ -92,6 +92,38 @@ def _online_softmax_update(s, v, in_dtype, acc_ref, m_ref, l_ref):
                               preferred_element_type=jnp.float32)
 
 
+def _start_softmax(acc_ref, m_ref, l_ref, sink=None):
+    """The online softmax's state before its first tile. `sink` (a
+    lane-replicated float32 tile of m's shape: a learned logit a row that
+    joins the denominator and has no value) IS that state — m = sink, l = 1,
+    acc = 0, exactly one more column whose value is zero — and None the
+    plain softmax's (m = NEG_INF, l = 0)."""
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    if sink is None:
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+    else:
+        m_ref[...] = sink
+        l_ref[...] = jnp.ones_like(l_ref)
+
+
+def _scores(q, k):
+    """q [R, hd] against a key tile [n, hd] -> [R, n] float32; keys kept in
+    several leaves (`kv_pool.py::kv_leaf_shapes`) come as a tuple of tiles,
+    whose widths add up to q's, and are scored a leaf at a time."""
+    dot = functools.partial(jax.lax.dot_general,
+                            dimension_numbers=(((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32)
+    if not isinstance(k, tuple):
+        return dot(q, k)
+    s, at = None, 0
+    for tile in k:
+        part = dot(q[:, at:at + tile.shape[-1]], tile)
+        s = part if s is None else s + part
+        at += tile.shape[-1]
+    return s
+
+
 def _softmax_result(acc_ref, l_ref):
     """The walk's result from its scratch, acc / l, float32: what a kernel's
     `_finish` stores. A row nothing was folded into (l = 0) comes out 0."""
@@ -107,7 +139,8 @@ def _online_softmax_tile(q, k, v, pos, j, acc_ref, m_ref, l_ref, *,
     dequantized tiles; everything after the load is identical, so the
     variants cannot drift numerically).
 
-    q: [G, hd]; k/v: [block_m, hd] in the compute dtype; scratch as in
+    q: [G, hd]; k/v: [block_m, hd] in the compute dtype (k a tuple of tiles
+    where the keys lie in several leaves, `_scores`); scratch as in
     `_online_softmax_update`, carried across the (sequential, innermost)
     block axis. `window` (static; None = none): keys more than `window - 1`
     positions behind `pos` are masked too — the mask inside the first live
@@ -117,8 +150,7 @@ def _online_softmax_tile(q, k, v, pos, j, acc_ref, m_ref, l_ref, *,
     pre-casting K/V blocks to fp32 doubles the VMEM working set and VPU
     traffic (same fix as flash_attention.py)."""
     G = q.shape[0]
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * sm_scale
+    s = _scores(q, k) * sm_scale
     k_pos = j * block_m + jax.lax.broadcasted_iota(jnp.int32, (G, block_m), 1)
     seen = k_pos <= pos
     if window is not None:
@@ -303,16 +335,29 @@ def paged_decode_walk_steps(live_blocks):
     return max(int(live_blocks), 1)
 
 
-def _heads_per_step(Hkv, head_tile_bytes):
+def _heads_per_step(Hkv, head_tile_bytes, share=1):
     """KV heads a grid step carries: the most (a divisor of Hkv) whose K and
     V tiles, double-buffered, fit `_WALK_TILE_BYTES`. All of them at the
     served widths (8 x 512 x 128 bfloat16: 4 MiB; 16 heads: 8 MiB), so a
-    step moves ONE contiguous `[Hkv, block, hd]` run of the pool per leaf."""
+    step moves ONE contiguous `[Hkv, block, hd]` run of the pool per leaf.
+    `share`: the most KV heads that share a head of some leaf (2 where the
+    keys' half tile pairs them): a step carries whole groups of them, or
+    one head."""
     heads = Hkv
-    while heads > 1 and (Hkv % heads
+    while heads > 1 and (Hkv % heads or heads % share
                          or 2 * heads * head_tile_bytes > _WALK_TILE_BYTES):
         heads -= 1
     return heads
+
+
+def _leaf_heads(leaf_heads, Hkv, heads):
+    """(heads of a leaf's step tile, what the step's head-group index `g`
+    becomes on that leaf's head axis, in tiles) for a leaf of `leaf_heads`
+    heads (all `Hkv`, or one a pair) in a walk of `heads` KV heads a step."""
+    mine = heads * leaf_heads // Hkv
+    if mine:
+        return mine, lambda g: g
+    return 1, lambda g: g * leaf_heads // Hkv      # one KV head a step
 
 
 def _vmem_tile_bytes(rows, cols, dtype):
@@ -323,15 +368,17 @@ def _vmem_tile_bytes(rows, cols, dtype):
 
 def _paged_walk_kernel(cnt_ref, slot_ref, blk_ref, pos_ref, bt_ref, q_ref,
                        *refs, load_head, sm_scale, block_m, last_block,
-                       window=None):
+                       window=None, sink=False):
     # grid (head groups, work items); a step holds every pool leaf's
     # [1, heads, block_m, ...] tile of ONE live (slot, logical block) pair,
     # resolved to its physical block by the index map (so bt_ref is unused
     # here). q_ref / o_ref: [1, heads, G, hd] of the pair's slot; scratch acc
     # [heads, G, hd] fp32, m/l [heads, G, _LANES] fp32 carry the online
     # softmax over a slot's pairs, which are consecutive and ascending.
+    # `sink`: one more input after the pool's, [heads, G, _LANES] float32.
     del bt_ref
     *pool_refs, o_ref, acc_ref, m_ref, l_ref = refs
+    sink_ref = pool_refs.pop() if sink else None
     i = pl.program_id(1)
     b = slot_ref[i]
     j = blk_ref[i]
@@ -342,9 +389,8 @@ def _paged_walk_kernel(cnt_ref, slot_ref, blk_ref, pos_ref, bt_ref, q_ref,
                    else jnp.minimum(window_first_block(pos, block_m, window),
                                     last_block)))
     def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
+        _start_softmax(acc_ref, m_ref, l_ref,
+                       None if sink_ref is None else sink_ref[...])
 
     # false only in the one step of a call with nothing live
     @pl.when(i < cnt_ref[0])
@@ -363,7 +409,7 @@ def _paged_walk_kernel(cnt_ref, slot_ref, blk_ref, pos_ref, bt_ref, q_ref,
 
 def _paged_walk(load_head, q, leaves, block_tables, pos, work, sm_scale,
                 interpret, window=None, out_dim=None,
-                name="dstpu_paged_decode"):
+                name="dstpu_paged_decode", sink=None):
     """THE walk over a paged pool, shared by the float and the int8 kernel:
     a 1-D list of the live (slot, logical block) pairs (`paged_decode_work`),
     its length the grid's DYNAMIC bound, so a dead slot and a block past a
@@ -375,8 +421,12 @@ def _paged_walk(load_head, q, leaves, block_tables, pos, work, sm_scale,
     slots come back ZERO (they ride on through the MLP, and through a routed
     model's router and its counters). `out_dim`: the width of V's tiles and
     of the result where it is not q's (a latent pool's values are a slice of
-    its keys' tile, `ops/pallas/mla_attention.py`); `name`: the call's name
-    in a compiled program."""
+    its keys' tile, `ops/pallas/mla_attention.py`; values narrower than the
+    keys); `name`: the call's name in a compiled program. A leaf may have
+    half the first leaf's heads (the keys' half tile, `kv_pool.py::
+    kv_leaf_shapes`): its step tile is the heads its step's KV heads share.
+    `sink` [H] float32: a learned logit a head, the INITIAL state of every
+    row's online softmax (`_start_softmax`)."""
     if interpret is None:
         interpret = pallas_interpret()
     B, H, hd = q.shape
@@ -389,25 +439,38 @@ def _paged_walk(load_head, q, leaves, block_tables, pos, work, sm_scale,
         sm_scale = 1.0 / math.sqrt(hd)
     if work is None:
         work = paged_decode_work(block_tables, pos, block_m, window)
-    heads = _heads_per_step(Hkv, sum(
-        _vmem_tile_bytes(block_m, x.shape[-1], x.dtype) for x in leaves))
+    heads = _heads_per_step(
+        Hkv, sum(_vmem_tile_bytes(block_m, x.shape[-1], x.dtype)
+                 * x.shape[1] // Hkv for x in leaves),
+        share=max(Hkv // x.shape[1] for x in leaves))
 
-    def pair_index(g, i, cnt_ref, slot_ref, blk_ref, pos_ref, bt_ref):
-        return (bt_ref[slot_ref[i], blk_ref[i]], g, 0, 0)
+    def pair_spec(x):
+        mine, group = _leaf_heads(x.shape[1], Hkv, heads)
+        return pl.BlockSpec(
+            (1, mine, block_m, x.shape[-1]),
+            lambda g, i, cnt_ref, slot_ref, blk_ref, pos_ref, bt_ref:
+            (bt_ref[slot_ref[i], blk_ref[i]], group(g), 0, 0))
 
     def slot_index(g, i, cnt_ref, slot_ref, blk_ref, pos_ref, bt_ref):
         return (slot_ref[i], g, 0, 0)
 
+    sunk, sunk_specs = (), []
+    if sink is not None:
+        sunk = (jnp.broadcast_to(
+            sink.astype(jnp.float32).reshape(Hkv, G, 1), (Hkv, G, _LANES)),)
+        sunk_specs = [pl.BlockSpec((heads, G, _LANES),
+                                   lambda g, i, *_: (g, 0, 0))]
+
     out = pl.pallas_call(
         functools.partial(_paged_walk_kernel, load_head=load_head,
                           sm_scale=sm_scale, block_m=block_m,
-                          last_block=nb - 1, window=window),
+                          last_block=nb - 1, window=window,
+                          **(dict(sink=True) if sunk else {})),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=5,
             grid=(Hkv // heads, jnp.maximum(work.count[0], 1)),
-            in_specs=[pl.BlockSpec((1, heads, G, hd), slot_index)] + [
-                pl.BlockSpec((1, heads, block_m, x.shape[-1]), pair_index)
-                for x in leaves],
+            in_specs=[pl.BlockSpec((1, heads, G, hd), slot_index)]
+            + [pair_spec(x) for x in leaves] + sunk_specs,
             out_specs=pl.BlockSpec((1, heads, G, out_dim), slot_index),
             scratch_shapes=[
                 pltpu.VMEM((heads, G, out_dim), jnp.float32),
@@ -419,7 +482,8 @@ def _paged_walk(load_head, q, leaves, block_tables, pos, work, sm_scale,
         interpret=interpret,
         name=name,
     )(work.count, work.slot, work.block, pos.astype(jnp.int32),
-      block_tables.astype(jnp.int32), q.reshape(B, Hkv, G, hd), *leaves)
+      block_tables.astype(jnp.int32), q.reshape(B, Hkv, G, hd), *leaves,
+      *sunk)
     # a slot the walk never visits is memory nobody wrote
     return jnp.where(work.live[:, None, None], out.reshape(B, H, out_dim), 0)
 
@@ -429,8 +493,17 @@ def _load_float_head(pool_refs, h, dtype):
     return k_ref[0, h], v_ref[0, h]
 
 
+def _load_split_head(pool_refs, h, dtype):
+    # keys in two leaves: head h's own tile and the lane tile it shares
+    # with its pair (a step tile of one head where the step carries one)
+    k_ref, kr_ref, v_ref = pool_refs
+    pair = h // 2 if kr_ref.shape[1] > 1 else 0
+    return (k_ref[0, h], kr_ref[0, pair]), v_ref[0, h]
+
+
 def paged_decode_attention(q, k_pool, v_pool, block_tables, pos, sm_scale=None,
-                           interpret=None, work=None, window=None):
+                           interpret=None, work=None, window=None,
+                           kr_pool=None, sink=None):
     """Decode attention over a PAGED KV pool (vLLM's PagedAttention layout).
 
     q: [B, H, hd]; k_pool/v_pool: [N, Hkv, block, hd] physical blocks shared
@@ -457,9 +530,18 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, pos, sm_scale=None,
     and masks what lies before that position inside it. The table may then
     be a RING (`inference/kv_cache.py::ring_tables`: logical block j at
     physical `j mod R` of the slot's ring): the blocks the walk visits are
-    distinct physical blocks as long as the ring covers the window."""
-    return _paged_walk(_load_float_head, q, (k_pool, v_pool), block_tables,
-                       pos, work, sm_scale, interpret, window or None)
+    distinct physical blocks as long as the ring covers the window.
+
+    `v_pool`'s width may differ from the keys' (the result is
+    [B, H, v width]). `kr_pool` [N, Hkv / 2, block, 128]: the keys' half
+    tile where the pool keeps it apart (`kv_pool.py::kv_leaf_shapes`); q is
+    then `kv_pool.split_query`'s and `sm_scale` the caller's. `sink` [H]:
+    `_paged_walk`'s."""
+    load, leaves = (_load_float_head, (k_pool, v_pool)) if kr_pool is None \
+        else (_load_split_head, (k_pool, kr_pool, v_pool))
+    return _paged_walk(load, q, leaves, block_tables, pos, work, sm_scale,
+                       interpret, window or None, out_dim=v_pool.shape[-1],
+                       sink=sink)
 
 
 def _dequant_tile(q, scale, dtype):

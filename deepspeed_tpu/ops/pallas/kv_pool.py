@@ -62,6 +62,64 @@ def pool_in_place_supported(dtype, block_size: int, head_dim: int) -> bool:
             and block_size % pool_tile_rows(dtype) == 0)
 
 
+# A head whose KEYS are a lane tile and a half wide (192 columns beside
+# 128-wide values) would be stored padded to two tiles, a fifth more of every
+# block and of every walk's bytes. Its first `_HALF` columns go to a leaf of
+# their own instead, `kr`, TWO KV heads side by side in one lane tile (a
+# reshape: the heads are adjacent), and the rest stay in `k`: every leaf is
+# whole lane tiles, the writer and the gather apply to each as they are, and
+# a position is stored at exactly the model's width. A walk scores head h
+# against `[k[h] | kr[h // 2]]` with a query whose first `_HALF` columns lie
+# in its own head's half of the pair and zeros in the neighbour's
+# (`split_query`), so no kernel slices a lane tile.
+_HALF = _LANES // 2
+
+
+def kv_leaf_shapes(kv_heads: int, key_dim: int, value_dim: int) -> dict:
+    """{leaf name as a paged half reads it: (heads, width)} of a cached
+    position of a K/V kind — `k`/`v`, and the half tile of the keys apart
+    (`kr`) where their width is whole tiles and a half and the heads pair."""
+    if key_dim % _LANES == _HALF and key_dim > _LANES and kv_heads % 2 == 0:
+        return {"k": (kv_heads, key_dim - _HALF),
+                "kr": (kv_heads // 2, _LANES), "v": (kv_heads, value_dim)}
+    return {"k": (kv_heads, key_dim), "v": (kv_heads, value_dim)}
+
+
+def pool_rows(k, v, pool_l) -> dict:
+    """New rows k [B, C, Hkv, hd] / v [B, C, Hkv, vd] as the leaves of
+    `pool_l` hold them."""
+    if "kr" not in pool_l:
+        return {"k": k, "v": v}
+    B, C, Hkv, _ = k.shape
+    return {"k": k[..., _HALF:],
+            "kr": k[..., :_HALF].reshape(B, C, Hkv // 2, _LANES), "v": v}
+
+
+def merge_keys(ctx: dict):
+    """The keys [B, Hkv, S, hd] of gathered key leaves (`k`, and `kr`
+    [B, Hkv / 2, S, 128] where the pool splits them)."""
+    if "kr" not in ctx:
+        return ctx["k"]
+    kr = ctx["kr"]
+    B, pairs, S, _ = kr.shape
+    kr = jnp.moveaxis(kr.reshape(B, pairs, S, 2, _HALF), 3, 2)
+    return jnp.concatenate([kr.reshape(B, 2 * pairs, S, _HALF), ctx["k"]],
+                           axis=-1)
+
+
+def split_query(q, kv_heads: int):
+    """q [..., H, hd] for a walk over split keys: `[q[_HALF:] | the first
+    _HALF columns in this head's half of its KV pair's tile, zeros in the
+    other]`, [..., H, hd + _HALF]."""
+    H = q.shape[-2]
+    odd = (jnp.arange(H) // (H // kv_heads)) % 2 == 1
+    first, zeros = q[..., :_HALF], jnp.zeros_like(q[..., :_HALF])
+    pair = jnp.where(odd[:, None],
+                     jnp.concatenate([zeros, first], axis=-1),
+                     jnp.concatenate([first, zeros], axis=-1))
+    return jnp.concatenate([q[..., _HALF:], pair], axis=-1)
+
+
 def _num_tiles(C: int, tile: int) -> int:
     # C consecutive rows starting anywhere in a tile
     return (C + tile - 2) // tile + 1

@@ -82,8 +82,8 @@ def mla_decode_attention(q, pool, block_tables, pos, rank, sm_scale,
 
 def _latent_prefill_kernel(start_ref, bt_ref, q_ref, c_ref, o_ref, *scratch,
                            **static):
-    _prefill_kernel(start_ref, bt_ref, q_ref, c_ref, None, o_ref, *scratch,
-                    **static)
+    _prefill_kernel(start_ref, bt_ref, q_ref, c_ref, o_ref, *scratch,
+                    values=False, **static)
 
 
 def mla_prefill_attention(q, pool, block_tables, start, rank, sm_scale,

@@ -46,8 +46,11 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from deepspeed_tpu.ops.pallas.decode_attention import (NEG_INF, _LANES,
+                                                       _leaf_heads,
                                                        _online_softmax_update,
+                                                       _scores,
                                                        _softmax_result,
+                                                       _start_softmax,
                                                        window_first_block)
 from deepspeed_tpu.platform.device import pallas_interpret
 
@@ -75,12 +78,14 @@ def _step_vmem_bytes(tq, tk, heads, G, block, hd, itemsize):
     return tiles + scratch + scores
 
 
-def _tiles(C, block, Hkv, G, hd, itemsize):
+def _tiles(C, block, Hkv, G, hd, itemsize, share=1):
     """(query rows, keys, KV heads) a grid step carries, from the shapes: the
     largest 128-multiple tiles that divide the chunk and the block, then as
-    many KV heads (a divisor of Hkv) as keep a step's query heads under
-    `_MAX_Q_HEADS` and its VMEM under `_STEP_VMEM_BYTES`, halving the query
-    tile when one head alone is over (G of 16 and more)."""
+    many KV heads (a divisor of Hkv; whole groups of `share`, the KV heads
+    that share a head of the keys' half-tile leaf, or one) as keep a step's
+    query heads under `_MAX_Q_HEADS` and its VMEM under `_STEP_VMEM_BYTES`,
+    halving the query tile when one head alone is over (G of 16 and
+    more)."""
     tq = next((t for t in _Q_TILES if C % t == 0), C)
     tk = next((t for t in _KV_TILES if block % t == 0), block)
 
@@ -91,29 +96,40 @@ def _tiles(C, block, Hkv, G, hd, itemsize):
     while tq % 256 == 0 and not fits(tq, 1):
         tq //= 2
     heads = max([h for h in range(1, Hkv + 1)
-                 if Hkv % h == 0 and h * G <= _MAX_Q_HEADS and fits(tq, h)],
+                 if Hkv % h == 0 and h * G <= _MAX_Q_HEADS and fits(tq, h)
+                 and (h == 1 or h % share == 0)],
                 default=1)
     return tq, tk, heads
 
 
-def _prefill_kernel(start_ref, bt_ref, q_ref, k_ref, v_ref, o_ref, acc_ref,
-                    m_ref, l_ref, *, sm_scale, G, block, tk, last_block,
-                    window=None):
+def _prefill_kernel(start_ref, bt_ref, q_ref, *refs, sm_scale, G, block, tk,
+                    last_block, window=None, keys=1, values=True,
+                    sink=False):
     # grid (B, Hkv // heads, C // tq, live blocks); q_ref / o_ref:
     # [1, tq, heads*G*hd], the step's query heads side by side in the lanes;
-    # k_ref / v_ref: [1, heads, block, hd], ONE logical block of the row,
-    # resolved to its physical block by the index map (so bt_ref is unused
-    # here); scratch acc [heads*G, tq, hd] fp32, m/l [heads*G, tq, _LANES]
-    # fp32 carry the online softmax over the row's blocks, innermost and
+    # the `keys` key leaves' refs and v_ref: [1, heads, block, hd], ONE
+    # logical block of the row, resolved to its physical block by the index
+    # map (so bt_ref is unused here; a key leaf of half the heads,
+    # `kv_pool.py::kv_leaf_shapes`: the heads the step's KV heads share);
+    # scratch acc [heads*G, tq, dv] fp32, m/l [heads*G, tq, _LANES] fp32
+    # carry the online softmax over the row's blocks, innermost and
     # ascending. With a `window` the KV axis counts from the block the
     # tile's first row's window begins in (`window_first_block`), not from 0.
-    # `v_ref` None (a latent pool, `ops/pallas/mla_attention.py`): a key
-    # tile's first `acc_ref.shape[-1]` columns are its values.
+    # `values` False (a latent pool, `ops/pallas/mla_attention.py`): no
+    # v_ref, a key tile's first `acc_ref.shape[-1]` columns are its values.
+    # `sink`: one more input, [heads*G, 1, _LANES] float32, a learned logit a
+    # query head: every row's INITIAL softmax state (`_start_softmax`).
+    *k_refs, o_ref, acc_ref, m_ref, l_ref = refs
+    sink_ref = k_refs.pop() if sink else None
+    v_ref = k_refs.pop() if values else None
+    assert len(k_refs) == keys
+    k_ref = k_refs[0]
     del bt_ref
     b = pl.program_id(0)
     qi = pl.program_id(2)
     tq = q_ref.shape[1]
-    heads, hd = k_ref.shape[1], k_ref.shape[3]
+    heads = k_ref.shape[1]
+    hd = sum(ref.shape[3] for ref in k_refs)    # a query head's columns
     dv = acc_ref.shape[-1]                  # the values' width: a result's
     q_lo = start_ref[b] + qi * tq           # this tile's first position
     q_hi = q_lo + tq - 1
@@ -125,12 +141,12 @@ def _prefill_kernel(start_ref, bt_ref, q_ref, k_ref, v_ref, o_ref, acc_ref,
 
     @pl.when(first_step)
     def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
+        _start_softmax(acc_ref, m_ref, l_ref,
+                       None if sink_ref is None
+                       else jnp.broadcast_to(sink_ref[...], m_ref.shape))
 
     def update(t, masked):
-        keys = slice(t * tk, (t + 1) * tk)
+        rows = slice(t * tk, (t + 1) * tk)
         if masked:
             # key position - query position, the same for every head
             ahead = (j * block + t * tk - q_lo) \
@@ -142,11 +158,14 @@ def _prefill_kernel(start_ref, bt_ref, q_ref, k_ref, v_ref, o_ref, acc_ref,
         # them halves the kernel's speed; measured, PERF.md §6, PR 30)
         for i in range(heads * G):
             q = q_ref[0, :, i * hd:(i + 1) * hd]
-            k = k_ref[0, i // G, keys, :]
-            v = k[:, :dv] if v_ref is None else v_ref[0, i // G, keys, :]
-            s = jax.lax.dot_general(
-                q, k, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32) * sm_scale
+            k = k_ref[0, i // G, rows, :]
+            v = k[:, :dv] if v_ref is None else v_ref[0, i // G, rows, :]
+            if keys > 1:
+                # a leaf of fewer heads: the one head i's KV head shares
+                k = (k,) + tuple(
+                    ref[0, (i // G) * ref.shape[1] // heads, rows, :]
+                    for ref in k_refs[1:])
+            s = _scores(q, k) * sm_scale
             if masked:
                 seen = ahead <= 0
                 if window is not None:
@@ -191,7 +210,8 @@ def paged_prefill_live_blocks(start, chunk, block, table_blocks, window=None):
 
 
 def paged_prefill_attention(q, k_pool, v_pool, block_tables, start,
-                            sm_scale=None, interpret=None, window=None):
+                            sm_scale=None, interpret=None, window=None,
+                            kr_pool=None, sink=None):
     """Causal attention of a prefill chunk over a PAGED KV pool, the live
     blocks only.
 
@@ -212,17 +232,27 @@ def paged_prefill_attention(q, k_pool, v_pool, block_tables, start,
     window` — what `_paged_attend` gives with `cfg.sliding_window`. A query
     tile's walk then starts at the block its first row's window begins in
     (the KV axis is a STATIC few blocks long), and the table may be a ring
-    (`inference/kv_cache.py::ring_tables`) that covers window + chunk."""
+    (`inference/kv_cache.py::ring_tables`) that covers window + chunk.
+
+    `v_pool`'s width may differ from the keys' (the result is [B, C, H * v
+    width]). `kr_pool` [M, Hkv / 2, block, 128]: the keys' half tile where
+    the pool keeps it apart (`kv_pool.py::kv_leaf_shapes`); q is then
+    `kv_pool.split_query`'s and `sm_scale` the caller's. `sink` [H]
+    float32: a learned logit a head that joins every row's denominator —
+    the INITIAL state of the online softmax."""
     if interpret is None:
         interpret = pallas_interpret()
     B, C, H, hd = q.shape
     _, Hkv, block, _ = k_pool.shape
+    dv = v_pool.shape[-1]
     nb = block_tables.shape[1]
     assert H % Hkv == 0
     G = H // Hkv
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(hd)
-    tq, tk, heads = _tiles(C, block, Hkv, G, hd, q.dtype.itemsize)
+    key_pools = (k_pool,) if kr_pool is None else (k_pool, kr_pool)
+    tq, tk, heads = _tiles(C, block, Hkv, G, hd, q.dtype.itemsize,
+                           share=len(key_pools))
     width = heads * G * hd
 
     window = window or None
@@ -246,27 +276,41 @@ def paged_prefill_attention(q, k_pool, v_pool, block_tables, start,
             j = j + window_first_block(start_ref[b] + qi * tq, block, window)
         return (bt_ref[b, jnp.minimum(j, frontier)], g, 0, 0)
 
+    def pool_spec(x):
+        mine, group = _leaf_heads(x.shape[1], Hkv, heads)
+        return pl.BlockSpec(
+            (1, mine, block, x.shape[-1]),
+            lambda b, g, *rest: kv_index(b, group(g), *rest))
+
+    static, sunk, sunk_specs = {}, (), []
+    if kr_pool is not None:
+        static["keys"] = 2
+    if sink is not None:
+        static["sink"] = True
+        sunk = (jnp.broadcast_to(sink.astype(jnp.float32)[:, None, None],
+                                 (H, 1, _LANES)),)
+        sunk_specs = [pl.BlockSpec((heads * G, 1, _LANES),
+                                   lambda b, g, qi, j, *_: (g, 0, 0))]
     return pl.pallas_call(
         functools.partial(_prefill_kernel, sm_scale=sm_scale, G=G,
                           block=block, tk=tk, last_block=nb - 1,
-                          window=window),
+                          window=window, **static),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(B, Hkv // heads, C // tq, live_blocks),
-            in_specs=[pl.BlockSpec((1, tq, width), q_index),
-                      pl.BlockSpec((1, heads, block, hd), kv_index),
-                      pl.BlockSpec((1, heads, block, hd), kv_index)],
-            out_specs=pl.BlockSpec((1, tq, width), q_index),
+            in_specs=[pl.BlockSpec((1, tq, width), q_index)]
+            + [pool_spec(x) for x in (*key_pools, v_pool)] + sunk_specs,
+            out_specs=pl.BlockSpec((1, tq, heads * G * dv), q_index),
             scratch_shapes=[
-                pltpu.VMEM((heads * G, tq, hd), jnp.float32),
+                pltpu.VMEM((heads * G, tq, dv), jnp.float32),
                 pltpu.VMEM((heads * G, tq, _LANES), jnp.float32),
                 pltpu.VMEM((heads * G, tq, _LANES), jnp.float32),
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct((B, C, H * hd), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, C, H * dv), q.dtype),
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=_VMEM_LIMIT_BYTES),
         interpret=interpret,
         name="dstpu_paged_prefill",
     )(start, block_tables.astype(jnp.int32), q.reshape(B, C, H * hd),
-      k_pool, v_pool)
+      *key_pools, v_pool, *sunk)

@@ -103,6 +103,14 @@ StepRecord = collections.namedtuple("StepRecord", [
     "latent_chunk_positions",  # ... and the cached positions this step's
                         # chunks attend, a layer: `start + chunk` a chunk,
                         # where `dstpu_mla_prefill` runs; both 0 elsewhere
+    "prefill_kept_pairs",   # the (query, position) pairs the causal mask
+                        # keeps of this step's chunk walks, a FULL layer:
+                        # `chunk * start + chunk * (chunk + 1) / 2` a chunk,
+                        # where a chunk-walk kernel runs ...
+    "prefill_window_kept_pairs",  # ... and the pairs the mask AND the
+                        # window keep, a WINDOW layer of a two-kind pool
+                        # (0 for a one-kind pool): what the chunk walks'
+                        # roofline counts as work
     "fused_chunks",     # of `prefill_chunks`, the chunks that rode the
                         # step's decode call (the scheduler's `mixed_step`:
                         # one device call, every weight read once)
@@ -119,7 +127,7 @@ StepRecord = collections.namedtuple("StepRecord", [
                         # before was unread: queued behind it on the device,
                         # so the chip never waited for this step's host work
 ], defaults=(0, 0, 0, 0, 0, 0, "", 0, (), 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0,
-             0, 0, 0))
+             0, 0, 0, 0, 0))
 
 RequestRecord = collections.namedtuple("RequestRecord", [
     "uid", "t_submit", "t_admit",

@@ -140,7 +140,10 @@ def test_chunks_and_decoding_through_the_latent_pool_match_the_reference(
     assert stats["kv_pool_kinds"] == {"latent": {
         "layers": 3, "block": 16, "window": 0, "blocks": 40,
         "bytes": 3 * 40 * 16 * width * 4,
-        "bytes_per_token": 3 * width * 4}}
+        # stored in whole lane tiles; the model's entry is `[c | k_r]`
+        "bytes_per_token": 3 * width * 4,
+        "model_bytes_per_token":
+            3 * (cfg.kv_lora_rank + cfg.qk_rope_head_dim) * 4}}
     records = srv.steptrace.records()
     # the latent walk's pairs are the decode walk's; no chunk kernel here
     assert sum(r.latent_walk_blocks for r in records) \

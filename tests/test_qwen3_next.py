@@ -425,7 +425,9 @@ def test_benchmark_holds_the_cells_files():
     assert all(m["name"].endswith(".deltanet") for m in own)
     assert {"gdn_update_kernel_time_share.deltanet",
             "gdn_update_roofline.deltanet"} <= {m["name"] for m in own}
-    assert len(own) <= 14 and len(bench["per_layer"]) <= 126
+    # this cell's own entries, and the contract's cap on the whole table
+    # (PR 50's cell took it to 127)
+    assert len(own) <= 14 and len(bench["per_layer"]) <= 128
     for metric in own:
         with open(os.path.join(BENCH, "layer_metrics",
                                metric["name"] + ".json")) as f:
