@@ -160,7 +160,10 @@ class ServingConfig(ConfigModel):
                                   # total); 0 = kv_block_size
     prefill_chunks_per_step: int = 1  # prefill work interleaved per decode
                                   # step — bounds how long an arriving prompt
-                                  # can stall the running batch
+                                  # can stall the running batch. Over
+                                  # decode_steps_per_sync it is also how many
+                                  # chunks ONE token of a mixed call carries
+                                  # (their ceil ratio; docs/inference.md)
     decode_steps_per_sync: int = 1  # decode WINDOW: tokens decoded per
                                   # scheduler sync, inside one jitted
                                   # lax.scan (vLLM's multi-step scheduling).

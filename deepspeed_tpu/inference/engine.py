@@ -97,15 +97,20 @@ class DecodeModelSpec:
     #     final prompt token on the last chunk; ignored on earlier chunks)
     #   decode_paged_fn(params, token[B], pos[B], pool, block_tables[B,nb])
     #       -> (logits[B,V], pool)
-    #   mixed_paged_fn(params, chunk_tokens[1,C], start_pos[1], last_idx[1],
-    #                  chunk_table[1,nb], token[S], pos[S], pool,
-    #                  block_tables[S,nb]) -> (logits[1+S,V], pool)
-    #     a prefill chunk AND a decode token of every slot in one call, all
-    #     their rows through every weight as ONE tensor (the scheduler's
-    #     `mixed_step`: a step's chunk rides its decode call, and the weights
-    #     are read once where the two programs read them twice); logits of
-    #     the chunk's last_idx row, then the slots'. The chunk's slot is not
+    #   mixed_paged_fn(params, chunk_tokens[G,C], start_pos[G], last_idx[G],
+    #                  chunk_table[G,nb], token[S], pos[S], pool,
+    #                  block_tables[S,nb][, count]) -> (logits[G+S,V], pool)
+    #     a GROUP of up to G prefill chunks AND a decode token of every slot
+    #     in one call, all their rows through every weight as ONE tensor (the
+    #     scheduler's `mixed_step`: a step's chunks ride its decode call, up
+    #     to G chunks a token, and the weights are read once where the
+    #     programs of their own read them 1 + G times); the attention half
+    #     runs the chunks in order, each as a chunk of its own. Logits of
+    #     each chunk's last_idx row, then the slots'. `count` (traced, 1..G):
+    #     the group's real chunks, passed where G > 1. No chunk's slot is
     #     among the decoding ones. None: chunks and decode stay two calls.
+    #   mixed_chunk_groups: the mixed program takes G > 1. False: the
+    #     scheduler hands it one chunk a token whatever its budget.
     #   init_paged_pool(num_blocks, block_size, dtype[, kv_group_size])
     #       -> pool pytree. dtype int8 selects the QUANTIZED pool: the
     #     k/v payload leaves stay [L, N, Hkv, block, hd] but int8, and the
@@ -124,6 +129,7 @@ class DecodeModelSpec:
     prefill_paged_fn: Optional[Callable] = None
     decode_paged_fn: Optional[Callable] = None
     mixed_paged_fn: Optional[Callable] = None
+    mixed_chunk_groups: bool = False
     verify_paged_fn: Optional[Callable] = None
     init_paged_pool: Optional[Callable] = None
     # dispatch phase ("paged_decode" | "prefill_chunk" | "verify" | "mixed")

@@ -12,9 +12,10 @@ persistent jitted programs whose shapes never change
     (`prefill_chunks_per_step` bounds the stall an arriving prompt imposes);
   * `decode_step` — one token for ALL slots at once: inactive slots ride
     along pointed at the trash block, so liveness never changes the shape;
-  * `mixed_step` — the two as ONE call: a step with a chunk due and slots
-    already decoding sends both groups' rows through the model as one
-    tensor, every weight read once (`_chunks_riding` is the whole rule).
+  * `mixed_step` — the two as ONE call: a step with chunks due and slots
+    already decoding sends their rows through the model as one tensor, up
+    to G chunks a token of the decode window, every weight read once
+    (`_chunks_riding` is the whole rule).
 
 Iteration-level scheduling happens between the calls, on the host, in
 plain Python: admit queued requests into freed slots (admission is a
@@ -185,12 +186,13 @@ class _Call:
                                 # its `steptrace.CallRecord`, which has its
                                 # stamps
         self.out = out          # the program's output, still on the device
-        self.prev = prev        # of it, (first [W], nxt [S, win]) for the
-                                # next call's `pick`
+        self.prev = prev        # of it, (first [W * G], nxt [S, win]) for
+                                # the next call's `pick`
         self.mixed = mixed      # `out` has first tokens beside the window
         self.win = win
         self.rows = rows        # requests in the decode window, row `idx` each
-        self.firsts = firsts    # (request, i): first token i is its first
+        self.firsts = firsts    # (request, i): first token i (riding chunk
+                                # i's) is its first
         self.chunks = chunks    # (request, prompt tokens prefilled once this
                                 # call has run): its own and every chunk
                                 # dispatched before it
@@ -564,11 +566,16 @@ class ServingEngine:
                 spec, engine.config, num_layers=engine.store.num_layers,
                 streamer=engine.streamer, watchdog=wd)
         else:
+            # the chunks a token of a mixed call takes: what the settings
+            # already say (at most `prefill_budget` chunks a step, `window`
+            # tokens a call), where the model's mixed program takes a group
+            group = -(-self.prefill_budget // self.window) \
+                if getattr(spec, "mixed_chunk_groups", False) else 1
             self.programs = step_programs.build_resident(
                 spec, engine.config, engine._fn_transform,
                 window=self.window, max_slots=self.max_slots,
                 chunk=self.chunk, spec_on=self.spec_on, draft_k=self.draft_k,
-                replicated=self._replicated, watchdog=wd)
+                replicated=self._replicated, watchdog=wd, group=group)
 
         # drafter AFTER pool/allocator: the draft-model drafter mirrors the
         # pool geometry and shares the block tables (spec_decode.py)
@@ -627,7 +634,10 @@ class ServingEngine:
         self.decode_steps = 0
         self.prefill_chunks = 0
         self.fused_chunks = 0               # of them, chunks that rode a decode
-                                            # call (`mixed_step`)
+                                            # call (`mixed_step`) ...
+        self.chunk_groups = 0               # ... in this many groups (one a
+                                            # window token, up to G chunks)
+        self.padded_chunks = 0              # ... with this many absent chunks
         self.prefill_chunks_skipped = 0     # chunks the prefix cache elided
         self.prefix_hit_blocks = 0
         self.prefix_hit_tokens = 0
@@ -1508,8 +1518,9 @@ class ServingEngine:
         """Admit, prefill up to `prefill_budget` chunks, decode every slot.
         The device calls of a step: each chunk a `prefill_step` call and then
         one `decode_step` call — or, where slots were decoding as the step
-        began, the last min(chunks due, window) chunks and the decode window
-        as ONE `mixed_step` call (`_chunks_riding`, `_launch`), the chunks
+        began, the last min(chunks due, window * G) chunks and the decode
+        window as ONE `mixed_step` call, up to G chunks a token
+        (`_chunks_riding`, `_launch`; G = `programs.group`), the chunks
         before them as their own calls first. One blocking read-back a
         decode or mixed call, one for a prompt's last chunk where that was
         a call of its own.
@@ -1534,6 +1545,7 @@ class ServingEngine:
         compiled0 = self._compiled_programs()
         chunks0, tokens0 = self.prefill_chunks, self.tokens_generated
         calls0, overlapped0 = self.device_calls, self.overlapped_calls
+        groups0, padded0 = self.chunk_groups, self.padded_chunks
         walk = (0, 0, 0, 0, 0)  # the decode kernel's (live blocks, grid
                              # steps, window layers' live blocks, ...
                              # unwindowed) and a state kind's bytes
@@ -1552,8 +1564,8 @@ class ServingEngine:
 
         # chunked prefill, bounded per step so arriving prompts cannot stall
         # the running batch for more than prefill_budget chunk-times. Where
-        # slots are decoding already, the step's last chunks (one a token of
-        # the decode window) RIDE the decode call below as one mixed
+        # slots are decoding already, the step's last chunks (up to G a token
+        # of the decode window) RIDE the decode call below as one mixed
         # program; the others, and every chunk of a step that fuses
         # nothing, are a `prefill_step` call each
         due = self._chunks_due()
@@ -1664,7 +1676,9 @@ class ServingEngine:
                     blocked_on=blocked_on,
                     compiles=self._compiled_programs() - compiled0,
                     device_calls=self.device_calls - calls0,
-                    overlapped_calls=self.overlapped_calls - overlapped0)
+                    overlapped_calls=self.overlapped_calls - overlapped0,
+                    chunk_groups=self.chunk_groups - groups0,
+                    padded_chunks=self.padded_chunks - padded0)
         return finished
 
     def _overlaps(self):
@@ -1729,13 +1743,15 @@ class ServingEngine:
         slot that decodes, the model can run the two as one
         (`mixed_paged_fn`; built for resident engines without spec decode),
         the program is one device's, and the pressure ladder is at rest (its
-        rungs reshape the decode call). One chunk a token of the window —
-        what a step holds decides, nothing is set."""
+        rungs reshape the decode call). Up to G chunks a token of the window
+        (`programs.group`: ceil(prefill budget / window) where the model's
+        mixed program takes a group, else one) — what a step holds decides,
+        nothing is set."""
         if not (due and decoding) or self.programs.mixed is None \
                 or self.engine.mesh.size != 1 \
                 or (self.pressure is not None and self.pressure.level):
             return 0
-        return min(due, self.window)
+        return min(due, self.window * self.programs.group)
 
     def _chunk_input(self, slot, start):
         """The chunk of `slot`'s prompt at `start`: (tokens [1, chunk], index
@@ -1874,8 +1890,11 @@ class ServingEngine:
 
     def _launch(self, dec, riding, params, tok, pos, tables, finished):
         """Dispatch the decode call for every slot in `dec` — `decode_step`,
-        or with the chunks `riding` it ((slot, start) each, one a token of
-        the window from the first) ONE `mixed_step` call — and only then
+        or with the chunks `riding` it ((slot, start) each; up to G a token
+        of the window, full groups first: token i takes chunks [i * G,
+        (i + 1) * G), so only the last riding token may carry fewer and the
+        tokens after it are plain decode tokens) ONE `mixed_step` call — and
+        only then
         read the call the step before left in flight (`_read`): this one is
         queued behind it on the device meanwhile. `tok` is (src, host
         tokens): a slot's input token is the host's, or stays on the device
@@ -1895,27 +1914,32 @@ class ServingEngine:
             self.pressure is not None
             and self.pressure.force_window_1)
         win, n = 1 if use_w1 else self.window, len(riding)
+        G = self.programs.group
         prior = self._pending
         no_prev = self.programs.no_prev
         tok = (no_prev if prior is None else prior.prev,) + tok
         finals = []
         if riding:
             with self._phase("serving/decode_build"):
-                chunks = np.zeros((win, 1, self.chunk), np.int32)
-                starts = np.zeros((win, 1), np.int32)
-                lasts = np.zeros((win, 1), np.int32)
+                # chunk i of `riding` is chunk i % G of window token i // G:
+                # row i of the arrays' flat [win * G, ...] view, and first
+                # token i of the call's
+                chunks = np.zeros((win * G, self.chunk), np.int32)
+                starts = np.zeros((win * G,), np.int32)
+                lasts = np.zeros((win * G,), np.int32)
                 for i, (slot, start) in enumerate(riding):
-                    chunks[i], lasts[i, 0], final = self._chunk_input(slot,
-                                                                      start)
-                    starts[i, 0] = start
+                    chunks[i:i + 1], lasts[i], final = self._chunk_input(
+                        slot, start)
+                    starts[i] = start
                     if final:
                         finals.append((slot, i))
-                # positions of the window past `n` are never read: any
-                # slot's row fills them
+                chunks = chunks.reshape(win, G, self.chunk)
+                starts, lasts = starts.reshape(win, G), lasts.reshape(win, G)
+                # chunks past `n` are never read: any slot's row fills them
                 idx = [slot.idx for slot, _ in riding]
-                idx += idx[-1:] * (win - n)
+                idx += idx[-1:] * (win * G - n)
                 chunk_tables = jax.tree_util.tree_map(
-                    lambda t: t[:, None],
+                    lambda t: t.reshape(win, G, -1),
                     self._tables_arg(self.tables[idx], idx))
         step_fn = self.programs.mixed if riding else \
             self.programs.decode_w1() if use_w1 else self.programs.decode
@@ -1946,6 +1970,8 @@ class ServingEngine:
                     self._chunk_written(slot, start, program)
                     for slot, start in riding))]
                 self.fused_chunks += n
+                self.chunk_groups += -(-n // G)
+                self.padded_chunks += -n % G
             walk = self._decode_walk(dec, pos, win)
             call = _Call(self.device_calls, out,
                          out[0] if riding else (no_prev[0], out[0]),
@@ -2161,6 +2187,8 @@ class ServingEngine:
                "decode_steps": self.decode_steps,
                "prefill_chunks": self.prefill_chunks,
                "fused_chunks": self.fused_chunks,
+               "chunk_groups": self.chunk_groups,
+               "padded_chunks": self.padded_chunks,
                "tokens_generated": self.tokens_generated,
                "peak_active": self.peak_active,
                "cancelled": self.cancelled,

@@ -32,15 +32,17 @@ class StepPrograms:
     `prefill`, `mixed` and `verify` (None where not built) and `decode_w1()`;
     `no_prev`, what `pick` takes where no call is in flight (the shapes,
     dtype and sharding of a mixed call's (first tokens, window tokens), so
-    that a call has ONE signature whatever came before it); each built
+    that a call has ONE signature whatever came before it); `group`, the
+    chunks a token of a mixed call takes (G); each built
     program's name (`built`, `compile_counts`) and example arguments
     (`examples`). A test that swaps a program for a stub assigns the
     attribute: the loop reads it a call."""
 
     def __init__(self, decode, prefill, mixed, verify, no_prev, parts,
-                 make_w1, example_args):
+                 make_w1, example_args, group=1):
         self.decode, self.prefill = decode, prefill
         self.mixed, self.verify, self.no_prev = mixed, verify, no_prev
+        self.group = group
         self.w1 = None          # `decode_w1()`'s, once built
         self._parts = parts     # name -> program: the streamed mode's six
                                 # per-layer programs (its steps are host loops)
@@ -98,11 +100,14 @@ def _sampler(cfg):
 
 
 def build_resident(spec, cfg, transform, *, window, max_slots, chunk,
-                   spec_on, draft_k, replicated, watchdog):
+                   spec_on, draft_k, replicated, watchdog, group=1):
     """The whole-model programs of a resident engine: `cfg` its config (the
     sampler's settings), `transform` its dequantize-on-use wrapper of a model
     function, `replicated` its mesh's replicated sharding, `watchdog` its
-    telemetry's compile watchdog."""
+    telemetry's compile watchdog, `group` the chunks a token of the mixed
+    program takes (G; more than 1 only for a model whose
+    `mixed_chunk_groups` says its mixed program runs a group)."""
+    G = group
     counters = tuple(getattr(spec, "step_counters", None) or ())
     sample = _sampler(cfg)
 
@@ -122,10 +127,10 @@ def build_resident(spec, cfg, transform, *, window, max_slots, chunk,
 
     def pick(tok):
         """A call's input token a slot. A plain [S] array is the host's.
-        Else ((first [W], nxt [S, win]) of the call BEFORE, still on the
-        device, src [S], host [S]): per slot the host's value (src 0),
+        Else ((first [W * G], nxt [S, win]) of the call BEFORE, still on
+        the device, src [S], host [S]): per slot the host's value (src 0),
         the last token that call sampled for it (1), or the first token
-        of the prompt whose last chunk rode that call at window position
+        of the prompt whose last chunk rode that call as its chunk
         src - 2 — call k's tokens are call k-1's outputs, and never make
         the trip to the host and back between the two."""
         if not isinstance(tok, tuple):
@@ -176,39 +181,47 @@ def build_resident(spec, cfg, transform, *, window, max_slots, chunk,
 
     def mixed_step(params, chunks, starts, lasts, chunk_tables, n, tok,
                    pos, pool, tables, rng):
-        """The MIXED program: a decode window whose first `n` tokens each
-        carry a prefill chunk through the model with them
-        (`DecodeModelSpec.mixed_paged_fn`: the chunk's rows and the slots'
+        """The MIXED program: a decode window whose first tokens each carry
+        up to G prefill chunks through the model with them
+        (`DecodeModelSpec.mixed_paged_fn`: the chunks' rows and the slots'
         rows as one tensor, every weight read once), the others plain decode
-        tokens. `chunks` [W, 1, C], `starts` / `lasts` [W, 1] and
-        `chunk_tables` [W, 1, nb] hold a chunk a window position, of which
-        the first `n` (traced, 1..W) are real: two loops with dynamic bounds
-        over one carried pool, so ONE compile serves every count. Returns
-        ((first tokens [W]: what each chunk's last row sampled, window
-        tokens [S, W]), counts), pool."""
+        tokens. `chunks` [W, G, C], `starts` / `lasts` [W, G] and
+        `chunk_tables` [W, G, nb] hold a group of chunks a window position,
+        of which the first `n` CHUNKS (traced, 1..W * G) are real, full
+        groups first: token i takes chunks [i * G, (i + 1) * G), so the
+        first ceil(n / G) tokens ride and only the last of them may carry
+        fewer than G. Two loops with dynamic bounds over one carried pool,
+        so ONE compile serves every count. Returns ((first tokens [W * G]:
+        what each chunk's last row sampled, window tokens [S, W]), counts),
+        pool."""
         tok = pick(tok)
 
         def ride(i, tok, pos, pool, rng):
             def at(a):
                 return jax.lax.dynamic_index_in_dim(a, i, 0,
                                                     keepdims=False)
+            count = () if G == 1 else (jnp.minimum(n - i * G, G),)
             logits, pool, counts = mixed_paged(
                 params, at(chunks), at(starts), at(lasts),
                 jax.tree_util.tree_map(at, chunk_tables), tok, pos, pool,
-                tables)
+                tables, *count)
             sampled = sample(logits, rng)
-            return sampled[0], sampled[1:], pool, counts
+            # (one chunk a token: the scalar, as it has always lowered)
+            head = sampled[0] if G == 1 else sampled[:G]
+            return head, sampled[G:], pool, counts
 
         if window == 1:     # as `decode_step`: no loop around one token
             first, nxt, pool, counts = ride(0, tok, pos, pool, rng)
-            return ((first[None], nxt[:, None]), counts), pool
+            return ((first[None] if G == 1 else first, nxt[:, None]),
+                    counts), pool
 
         def body(i, carry, riding):
             tok, pos, pool, rng, acc, first, toks = carry
             rng, sub = jax.random.split(rng)
             if riding:
                 head, nxt, pool, counts = ride(i, tok, pos, pool, sub)
-                first = first.at[i].set(head)
+                first = first.at[i].set(head) if G == 1 else \
+                    jax.lax.dynamic_update_slice(first, head, (i * G,))
             else:
                 logits, pool, counts = decode_paged(params, tok, pos, pool,
                                                     tables)
@@ -218,12 +231,13 @@ def build_resident(spec, cfg, transform, *, window, max_slots, chunk,
                     toks.at[:, i].set(nxt))
 
         carry = (tok, pos, pool, rng, no_counts(),
-                 jnp.zeros((window,), jnp.int32),
+                 jnp.zeros((window * G,), jnp.int32),
                  jnp.zeros((tok.shape[0], window), jnp.int32))
+        riding = n if G == 1 else (n + G - 1) // G
         carry = jax.lax.fori_loop(
-            0, n, lambda i, c: body(i, c, True), carry)
+            0, riding, lambda i, c: body(i, c, True), carry)
         carry = jax.lax.fori_loop(
-            n, window, lambda i, c: body(i, c, False), carry)
+            riding, window, lambda i, c: body(i, c, False), carry)
         _, _, pool, _, acc, first, toks = carry
         return ((first, toks), acc), pool
 
@@ -279,7 +293,7 @@ def build_resident(spec, cfg, transform, *, window, max_slots, chunk,
         "decode_step_w1", jax.jit(make_decode_step(1), donate_argnums=(3,)))
 
     no_prev = jax.device_put(
-        (np.zeros((window,), np.int32),
+        (np.zeros((window * G,), np.int32),
          np.zeros((max_slots, window), np.int32)), replicated)
 
     def example_args(params, pool, tables, rng):
@@ -298,14 +312,14 @@ def build_resident(spec, cfg, transform, *, window, max_slots, chunk,
             "prefill_step": (params, i32(1, chunk), i32(1), i32(1), pool,
                              one, rng),
             "mixed_step": (
-                params, i32(W, 1, chunk), i32(W, 1), i32(W, 1),
-                jax.tree_util.tree_map(lambda t: np.repeat(t[None], W, 0),
-                                       one),
+                params, i32(W, G, chunk), i32(W, G), i32(W, G),
+                jax.tree_util.tree_map(
+                    lambda t: np.tile(t[None], (W, G, 1)), one),
                 np.int32(1), tok, i32(S), pool, tables, rng),
             "verify_step": (params, i32(S, K1), i32(S), pool, tables, rng)}
 
     return StepPrograms(decode, prefill, mixed, verify, no_prev, parts={},
-                        make_w1=make_w1, example_args=example_args)
+                        make_w1=make_w1, example_args=example_args, group=G)
 
 
 def build_streamed(spec, cfg, *, num_layers, streamer, watchdog):

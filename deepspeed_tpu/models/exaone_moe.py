@@ -62,7 +62,7 @@ from deepspeed_tpu.inference.kv_cache import CacheKind
 from deepspeed_tpu.models.gpt import (MixedTables, _attn_half, _embed,
                                       _lm_head, _paged_attn_half,
                                       _residual_mlp, decode_rows,
-                                      make_mixed_paged_fn)
+                                      make_mixed_paged_fn, offset_tables)
 from deepspeed_tpu.models.mla import (LATENT_LEAF, entry_width, mla_attn_half,
                                       mla_shapes, paged_mla_half)
 from deepspeed_tpu.models.moe_gpt import MoEGPTConfig
@@ -184,6 +184,8 @@ class AttnKind:
                                 # position a layer (what is stored may pad)
     name: str                   # its `CacheKind`'s name; the layers run
                                 # under the `jax.named_scope` `attn_<name>`
+    chunk_groups: bool = False  # its paged half runs a mixed call's GROUP
+                                # of chunks (`gpt.MixedTables.count`)
 
     def leaves(self, cfg):
         """{name as the paged half reads it: the pool's leaf}."""
@@ -200,9 +202,9 @@ def _kv_values(cfg):
 # entry a token for all heads
 ATTN_KINDS = {
     FULL: AttnKind("", _gqa_shapes, _attn_half, _paged_attn_half, _kv_entry,
-                   _kv_values, "full"),
+                   _kv_values, "full", chunk_groups=True),
     WINDOW: AttnKind("w", _gqa_shapes, _attn_half, _paged_attn_half,
-                     _kv_entry, _kv_values, "window"),
+                     _kv_entry, _kv_values, "window", chunk_groups=True),
     LATENT: AttnKind("", mla_shapes, mla_attn_half, paged_mla_half,
                      lambda cfg: {LATENT_LEAF: (1, entry_width(cfg))},
                      lambda cfg: cfg.kv_lora_rank + cfg.qk_rope_head_dim,
@@ -566,8 +568,7 @@ def make_exaone_moe_decode_model(cfg: ExaoneMoEConfig, params=None,
             # gather of the other form take tables already offset
             where = dict(block_base=base) if in_place else {}
             table = tables[attn_kind] if in_place \
-                else jax.tree_util.tree_map(lambda t: t + base,
-                                            tables[attn_kind])
+                else offset_tables(tables[attn_kind], base)
             with jax.named_scope(f"attn_{kind.name}"):
                 attn_out, pool_l = kind.paged(
                     x, p, {n: flat[leaf]
@@ -677,6 +678,8 @@ def make_exaone_moe_decode_model(cfg: ExaoneMoEConfig, params=None,
                            decode_paged_fn=decode_paged_fn,
                            mixed_paged_fn=make_mixed_paged_fn(
                                cfg, partial(_layers_paged, routing=False)),
+                           mixed_chunk_groups=all(
+                               kind.chunk_groups for kind in kinds.values()),
                            init_paged_pool=init_paged_pool,
                            paged_cache_kinds=lambda block_size: cache_kinds(
                                cfg, block_size),

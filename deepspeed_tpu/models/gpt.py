@@ -1048,18 +1048,22 @@ def init_kv_cache(cfg: GPTConfig, batch_size, max_len, dtype=jnp.bfloat16):
             "length": jnp.zeros((batch_size,), jnp.int32)}
 
 
-def _decode_qkv(x, p, positions, cfg: GPTConfig):
+def _decode_qkv(x, p, positions, cfg: GPTConfig, hold=False):
     """Shared decode-path preamble: ln1 -> fused qkv -> split/reshape ->
     rope at absolute positions. One definition for the contiguous-cache
     half AND the paged half — a rope/GQA change cannot diverge them.
     x: [B, C, D]; positions: [B, C]. Returns q [B,C,H,hd], k/v [B,C,Hkv,hd]
     and the output's gate [B,C,H*hd] (`_gate_output`'s; None without one).
+    `hold`: the fused product is HELD where it is made (a caller whose
+    readers of q, k and v lie far apart: see `_paged_attn_half`).
     (The training `_attn_half` stays separate: it additionally threads
     act-quant gates, remat checkpoint names, and shard constraints.)"""
     B, C, _ = x.shape
     H, Hkv, hd = cfg.n_head, cfg.n_kv_head, cfg.head_dim
     h = _half_input(x, p, cfg)
     qkv = h @ p["attn_qkv_w"] + p["attn_qkv_b"]
+    if hold:
+        qkv = jax.lax.optimization_barrier(qkv)
     q, k, v, gate = _split_qkv(qkv, cfg)
     q, k = _qk_norm(q, k, p, cfg)
     q = q.reshape(B, C, H, hd)
@@ -1314,6 +1318,7 @@ def make_gpt_decode_model(cfg: GPTConfig = None, name="gpt2-125m", params=None, 
                            decode_paged_fn=decode_paged_fn,
                            mixed_paged_fn=make_mixed_paged_fn(cfg,
                                                               _scan_paged),
+                           mixed_chunk_groups=True,
                            verify_paged_fn=verify_paged_fn,
                            init_paged_pool=init_paged_pool,
                            kv_pool_writers=pool_writers,
@@ -1361,24 +1366,53 @@ def init_paged_kv_pool(cfg: GPTConfig, num_blocks, block_size,
 
 
 class MixedTables(NamedTuple):
-    """The block tables of a MIXED call (`make_mixed_paged_fn`): one prefill
-    chunk and a decode token of every slot as the rows of ONE tensor,
-    x [1, C + S, D] with positions [1, C + S] — the chunk's C rows first
-    (one sequence, its table `chunk` [1, nb]), then one row a slot (tables
-    `decode` [S, nb]). Every weight is applied to all the rows at once; only
-    `_paged_attn_half` tells the two groups apart, and it knows a mixed call
-    by its tables being this pair."""
+    """The block tables of a MIXED call (`make_mixed_paged_fn`): a GROUP of up
+    to G prefill chunks and a decode token of every slot as the rows of ONE
+    tensor, x [1, G*C + S, D] with positions [1, G*C + S] — the chunks' C
+    rows each first, in the order they run (one sequence a chunk, its table
+    a row of `chunk` [G, nb]), then one row a slot (tables `decode`
+    [S, nb]). Every weight is applied to all the rows at once; only
+    `_paged_attn_half` tells the groups apart, and it knows a mixed call by
+    its tables being this tuple. `count`: how many of the G chunks are real
+    (traced, 1..G; the rows of the others are padding, never written and
+    never walked) — None where G is 1."""
     chunk: Any
     decode: Any
+    count: Any = None
 
 
-def mixed_tables(chunk_table, block_tables):
-    """`MixedTables` of a chunk's table and the slots' tables — of each
+def mixed_tables(chunk_table, block_tables, count=None):
+    """`MixedTables` of the chunks' tables and the slots' tables — of each
     kind, where a pool of two kinds passes its tables as a pair."""
     if isinstance(block_tables, tuple):
-        return tuple(MixedTables(c, d)
+        return tuple(MixedTables(c, d, count)
                      for c, d in zip(chunk_table, block_tables))
-    return MixedTables(chunk_table, block_tables)
+    return MixedTables(chunk_table, block_tables, count)
+
+
+def offset_tables(block_tables, base):
+    """A paged call's tables `base` physical blocks further on (a layer's
+    blocks in a flat stack): the tables move, a mixed call's count stays."""
+    if isinstance(block_tables, MixedTables):
+        return block_tables._replace(chunk=block_tables.chunk + base,
+                                     decode=block_tables.decode + base)
+    return block_tables + base
+
+
+def over_chunk_group(count, C, out, carry, run):
+    """A mixed call's GROUP of chunks in order, by ONE traced copy of `run`:
+    for chunk i = 0 .. `count` - 1 (traced) `run(rows, i, carry) -> (the
+    chunk's result [1, C, ...], carry)`, where `rows(a)` is chunk i's C rows
+    of an `a` [1, G*C (+ S), ...]. The results land in `out` [1, G*C, ...]
+    (an absent chunk's rows stay as given). Returns (out, carry)."""
+    def body(i, state):
+        out, carry = state
+        y, carry = run(
+            lambda a: jax.lax.dynamic_slice_in_dim(a, i * C, C, 1), i, carry)
+        return jax.lax.dynamic_update_slice_in_dim(
+            out, y.astype(out.dtype), i * C, 1), carry
+
+    return jax.lax.fori_loop(0, count, body, (out, carry))
 
 
 def decode_rows(block_tables, positions):
@@ -1394,30 +1428,39 @@ def make_mixed_paged_fn(cfg, layers_paged, chunk_valid=False):
     """A family's `DecodeModelSpec.mixed_paged_fn` from its layer loop
     `layers_paged(params, x, pool, block_tables, positions) -> (x, pool,
     *counts)`, the one its `prefill_paged_fn` and `decode_paged_fn` run:
-    a chunk and a decode token a slot through embedding, layers, final norm
-    and head as one tensor, so each weight is read once where the two
-    programs read it twice. Logits [1 + S, V]: the chunk's `last_idx` row,
-    then the slots' rows. `chunk_valid`: the loop also takes `valid=`, the
-    chunk's real positions `last_idx + 1` (a layer with recurrent state must
-    not run it over the chunk's padding). Further keywords of a call go to
-    the loop as they are (a routed family's `routing=True`: what it returns
-    beside the counters follows them)."""
+    a group of up to G chunks (`chunk_tokens` [G, C], `start_pos` /
+    `last_idx` [G], `chunk_table` [G, nb] of each kind; `count`, traced, how
+    many of them are real where G > 1) and a decode token a slot through
+    embedding, layers, final norm and head as one tensor [1, G*C + S, D], so
+    each weight is read once where the programs of their own read it 1 + G
+    times. Only the attention half runs the chunks one after another
+    (`_paged_attn_half`). Logits [G + S, V]: each chunk's `last_idx` row,
+    then the slots' rows. A family whose loop takes G > 1 says so
+    (`DecodeModelSpec.mixed_chunk_groups`); the others are handed G = 1.
+    `chunk_valid`: the loop also takes `valid=`, the chunk's real positions
+    `last_idx + 1` (a layer with recurrent state must not run it over the
+    chunk's padding). Further keywords of a call go to the loop as they are
+    (a routed family's `routing=True`: what it returns beside the counters
+    follows them)."""
 
     def mixed_paged_fn(params, chunk_tokens, start_pos, last_idx, chunk_table,
-                       token, pos, pool, block_tables, **loop):
-        C = chunk_tokens.shape[1]
-        tokens = jnp.concatenate([chunk_tokens, token[None]], axis=1)
+                       token, pos, pool, block_tables, count=None, **loop):
+        G, C = chunk_tokens.shape
+        tokens = jnp.concatenate([chunk_tokens.reshape(1, G * C),
+                                  token[None]], axis=1)
         positions = jnp.concatenate(
-            [start_pos[:, None] + jnp.arange(C, dtype=jnp.int32)[None],
-             pos[None]], axis=1)
+            [(start_pos[:, None] + jnp.arange(C, dtype=jnp.int32)[None]
+              ).reshape(1, G * C), pos[None]], axis=1)
         x = _embed(params, tokens, positions, cfg)
         valid = dict(valid=last_idx + 1) if chunk_valid else {}
         x, pool, *counts = layers_paged(
-            params, x, pool, mixed_tables(chunk_table, block_tables),
+            params, x, pool, mixed_tables(chunk_table, block_tables, count),
             positions, **valid, **loop)
-        last = jnp.take_along_axis(x[:, :C], last_idx[:, None, None], axis=1)
-        logits = _lm_head(params, jnp.concatenate([last, x[:, C:]], axis=1),
-                          cfg)[0]
+        D = x.shape[-1]
+        last = jnp.take_along_axis(x[:, :G * C].reshape(G, C, D),
+                                   last_idx[:, None, None], axis=1)
+        logits = _lm_head(params, jnp.concatenate(
+            [last.reshape(1, G, D), x[:, G * C:]], axis=1), cfg)[0]
         return (logits, pool, *counts)
 
     return mixed_paged_fn
@@ -1567,10 +1610,13 @@ def _paged_attn_half(x, p, pool_l, positions, block_tables,
     row's blocks (logical position -> table -> physical block scatter), then
     attends over the row's whole table. Returns (attn_out, pool_l).
 
-    A MIXED call (`block_tables` a `MixedTables`; x [1, C + S, D]): one QKV
-    and one output projection over all the rows, and between them the
-    chunk's C rows and the slots' S rows each written and attended as their
-    own program would (`_paged_write_attend`, once a group).
+    A MIXED call (`block_tables` a `MixedTables`; x [1, G*C + S, D]): one
+    QKV and one output projection over all the rows, and between them each
+    of the group's chunks in order, then the slots' S rows, written and
+    attended as their own program would (`_paged_write_attend`, once a
+    chunk and once for the slots): a later chunk of a sequence finds the
+    earlier one's keys in the pool, and a window layer's ring holds one
+    chunk in flight as it does between two calls.
 
     In-place form (`block_base` given; `_scan_paged` decides): `pool_l` is
     the WHOLE stack flattened to [L*N, Hkv, block, hd], this layer's blocks
@@ -1598,21 +1644,42 @@ def _paged_attn_half(x, p, pool_l, positions, block_tables,
     the dequantizing gather oracle — one shared numeric definition, so the
     two are parity-testable tile for tile.
     """
-    q, k, v, gate = _decode_qkv(x, p, positions, cfg)
+    mixed = isinstance(block_tables, MixedTables)
+    G = block_tables.chunk.shape[0] if mixed else 1
+    # a group of chunks: the product's readers are the chunk loop, the
+    # slots' rows behind the barrier below and (a gate) the output. Left
+    # alone XLA frees it in between and computes it again for each (compiled
+    # for a described v5e at MiMo's size: `fusion.N.remat`, `.remat2`, the
+    # 116 MiB QKV matrix read three times a layer)
+    q, k, v, gate = _decode_qkv(x, p, positions, cfg, hold=G > 1)
     group = partial(_paged_write_attend, cfg=cfg, local_flag=local_flag,
                     block_base=block_base, attn_programs=attn_programs,
                     sink=p["attn_sink"] if cfg.attn_sink else None)
-    if isinstance(block_tables, MixedTables):
-        # a mixed call: the chunk's rows [1, C, ...], then a row a slot,
+    if mixed:
+        # a mixed call: each chunk's rows [1, C, ...], then a row a slot,
         # [S, 1, ...] as the decode program has them. Each group writes and
         # attends as it would in its own program (same dispatch site, same
         # kernels); their results meet again for ONE output projection
         S = block_tables.decode.shape[0]
-        C = x.shape[1] - S
-        attn_c, pool_l = group(
-            q[:, :C], k[:, :C], v[:, :C], pool_l, positions[:, :C],
-            block_tables.chunk, phase="prefill_chunk",
-            record="mixed/prefill_chunk")
+        R = x.shape[1] - S          # the chunks' rows, C a chunk
+        C = R // G
+        chunk = partial(group, phase="prefill_chunk",
+                        record="mixed/prefill_chunk")
+        if G == 1:
+            attn_c, pool_l = chunk(
+                q[:, :C], k[:, :C], v[:, :C], pool_l, positions[:, :C],
+                block_tables.chunk)
+        else:
+            # the group's real chunks in order on the carried pool; the rows
+            # of the absent ones stay zeros, padding through the projection
+            attn_c, pool_l = over_chunk_group(
+                block_tables.count, C,
+                jnp.zeros((1, R, q.shape[2] * v.shape[3]), q.dtype),
+                dict(pool_l),
+                lambda rows, i, pool_l: chunk(
+                    rows(q), rows(k), rows(v), pool_l, rows(positions),
+                    jax.lax.dynamic_slice_in_dim(block_tables.chunk, i, 1,
+                                                 0)))
         # the chunk's walk has READ the pool before the slots' rows are
         # written into it in place — said as data. Nothing else orders the
         # two (the slots' rows do not depend on the chunk's attention), and
@@ -1621,8 +1688,8 @@ def _paged_attn_half(x, p, pool_l, positions, block_tables,
         # K-EXAONE's 1.5 GB full-layer leaf a mixed token, 23% of its time)
         attn_c, pool_l = jax.lax.optimization_barrier((attn_c, pool_l))
         attn_d, pool_l = group(
-            *(jnp.swapaxes(a[:, C:], 0, 1) for a in (q, k, v)), pool_l,
-            positions[:, C:].T, block_tables.decode, phase="paged_decode",
+            *(jnp.swapaxes(a[:, R:], 0, 1) for a in (q, k, v)), pool_l,
+            positions[:, R:].T, block_tables.decode, phase="paged_decode",
             decode_work=decode_work, record="mixed/paged_decode")
         attn = jnp.concatenate([attn_c, jnp.swapaxes(attn_d, 0, 1)], axis=1)
     else:

@@ -75,7 +75,8 @@ import jax.numpy as jnp
 from deepspeed_tpu.inference.kv_cache import CacheKind
 from deepspeed_tpu.models.gpt import (MixedTables, _attn_half, _embed,
                                       _lm_head, _norm, _paged_attn_half,
-                                      decode_rows, make_mixed_paged_fn)
+                                      decode_rows, make_mixed_paged_fn,
+                                      offset_tables, over_chunk_group)
 from deepspeed_tpu.models.layer_pattern import repeated_runs
 from deepspeed_tpu.models.moe_gpt import MoEGPTConfig
 from deepspeed_tpu.ops import attention_dispatch as attn_dispatch
@@ -442,10 +443,28 @@ def _recurrent(proj, chunk, token, cache, rows, positions, valid):
         # a chunk's rows [1, C, .], then a row a slot: the chunk first, whole
         # (its state read, scanned and written back), then the slots' token
         # on the buffer it returned — one chain, nothing for XLA to reorder
-        C = T - rows.decode.shape[0]
-        y_c, cache = chunk(part(slice(None), slice(C)), cache,
-                           rows.chunk[:, 0], positions[:, 0], valid)
-        y_d, cache = token(part(0, slice(C, None)), cache, rows.decode[:, 0])
+        G = rows.chunk.shape[0]
+        R = T - rows.decode.shape[0]    # the chunks' rows, C a chunk
+        if G == 1:
+            y_c, cache = chunk(part(slice(None), slice(R)), cache,
+                               rows.chunk[:, 0], positions[:, 0], valid)
+        else:
+            # a GROUP of chunks: each in turn, whole, on the state the one
+            # before it wrote back (an absent chunk's rows stay zeros)
+            C = R // G
+
+            def nth(at, i, cache):
+                one = jax.lax.dynamic_slice_in_dim
+                return chunk(tuple(at(a) for a in proj), cache,
+                             one(rows.chunk[:, 0], i, 1), at(positions)[:, 0],
+                             one(valid, i, 1))
+
+            y = jax.eval_shape(
+                lambda cache: nth(lambda a: a[:, :C], 0, cache)[0], cache)
+            y_c, cache = over_chunk_group(
+                rows.count, C, jnp.zeros((1, R) + y.shape[2:], y.dtype),
+                cache, nth)
+        y_d, cache = token(part(0, slice(R, None)), cache, rows.decode[:, 0])
         return jnp.concatenate([y_c, y_d[None]], axis=1), cache
     if cache is not None and valid is None:
         y, cache = token(part(slice(None), 0), cache, rows[:, 0])
@@ -655,7 +674,7 @@ def make_hybrid_decode_model(cfg: HybridConfig, params, name, expert_half,
         # every leaf flat and CARRIED: layer i of a kind addresses its rows
         # as `row + i * rows a layer`
         flat = {k: v.reshape((-1,) + v.shape[2:]) for k, v in pool.items()}
-        offset = lambda t, base: jax.tree_util.tree_map(lambda a: a + base, t)
+        offset = offset_tables
 
         def half(x, flat, p, kind, index, counts, chosen, **experts):
             if kind == ATTENTION:
@@ -783,6 +802,7 @@ def make_hybrid_decode_model(cfg: HybridConfig, params, name, expert_half,
                            decode_paged_fn=decode_paged_fn,
                            mixed_paged_fn=make_mixed_paged_fn(
                                cfg, _layers_paged, chunk_valid=True),
+                           mixed_chunk_groups=True,
                            init_paged_pool=init_paged_pool,
                            paged_cache_kinds=partial(cache_kinds, cfg),
                            kv_pool_writers=pool_writers,

@@ -590,6 +590,7 @@ def make_moe_gpt_decode_model(cfg: MoEGPTConfig, params=None, name="moe-gpt", se
                            decode_paged_fn=decode_paged_fn,
                            mixed_paged_fn=make_mixed_paged_fn(cfg,
                                                               _layers_paged),
+                           mixed_chunk_groups=True,
                            verify_paged_fn=verify_paged_fn,
                            init_paged_pool=init_paged_pool,
                            kv_pool_writers=pool_writers,

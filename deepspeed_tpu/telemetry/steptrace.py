@@ -126,8 +126,15 @@ StepRecord = collections.namedtuple("StepRecord", [
     "overlapped_calls",  # ... of them, the calls dispatched while the call
                         # before was unread: queued behind it on the device,
                         # so the chip never waited for this step's host work
+    "chunk_groups",     # the chunk groups this step's mixed call carried: a
+                        # window token takes up to G of `fused_chunks` through
+                        # every weight with it (G = ceil(prefill budget /
+                        # window) where the model's mixed program takes a
+                        # group, else 1) ...
+    "padded_chunks",    # ... and the absent chunks in them (rows of padding
+                        # through the matmuls): groups * G - fused_chunks
 ], defaults=(0, 0, 0, 0, 0, 0, "", 0, (), 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0,
-             0, 0, 0, 0, 0))
+             0, 0, 0, 0, 0, 0, 0))
 
 RequestRecord = collections.namedtuple("RequestRecord", [
     "uid", "t_submit", "t_admit",

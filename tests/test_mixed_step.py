@@ -21,7 +21,8 @@ from deepspeed_tpu.inference.scheduler import Request, _DECODE, _HANDOFF
 from deepspeed_tpu.models.gpt import GPTConfig, make_gpt_decode_model
 from deepspeed_tpu.models.moe_gpt import (MoEGPTConfig, init_moe_gpt_params,
                                           make_moe_gpt_decode_model)
-from tests import exaone_cases
+from tests import (exaone_cases, mimo_cases, nemotron_cases,
+                   qwen3_next_cases)
 
 pytestmark = pytest.mark.serving
 
@@ -38,6 +39,11 @@ ROUTED = MoEGPTConfig(n_layer=2, n_head=4, d_model=64, d_ff=32,
 # the grid; budgets of one token and of more than a window
 LENGTHS = (5, 40, 17, 33, 64, 9, 16, 48)
 NEW = (7, 3, 12, 5, 9, 1, 6, 10)
+# ... and prompts of many chunks, for tokens that carry GROUPS of them: a
+# group's three chunks are 48 positions, more than the small two-kind models'
+# ring of 32 holds (window 8 + a chunk of 16, in blocks of 8, + 1), so one
+# group's chunks wrap it; 100 and 90 end mid-chunk and mid-group
+LONG = (5, 100, 17, 90, 64, 9, 112, 48)
 
 
 def _one_device():
@@ -74,7 +80,38 @@ def _two_kinds(**knobs):
                                  one_device=True, max_slots=4, **knobs)[1]
 
 
-FAMILIES = {"dense": _dense, "routed": _routed, "two_kinds": _two_kinds}
+def _sink_kinds(**knobs):
+    """The MiMo family at its small size: the two kinds with their own KV
+    heads, a learned sink in the window layers, keys wider than values."""
+    cfg = mimo_cases._cfg()
+    return mimo_cases._serving(cfg, mimo_cases._params(cfg), one_device=True,
+                               max_slots=4, **knobs)[1]
+
+
+def _hybrid(cases):
+    """A hybrid family at its small size: recurrent layers' state rows (and
+    convolution tails) beside attention layers' blocks, routed experts."""
+    def serving(**knobs):
+        cfg = cases._cfg()
+        return cases._serving(cfg, cases._params(cfg), one_device=True,
+                              max_slots=4, **knobs)[1]
+    return serving
+
+
+def _one_chunk_a_token(**knobs):
+    """The dense family as a family whose mixed program takes no group."""
+    import dataclasses
+    _one_device()
+    spec = dataclasses.replace(make_gpt_decode_model(cfg=DENSE, name="tiny"),
+                               mixed_chunk_groups=False)
+    return _engine(spec).serving(**{"max_slots": 4, "max_context": 128,
+                                    "prefill_chunk": CHUNK, **knobs})
+
+
+FAMILIES = {"dense": _dense, "routed": _routed, "two_kinds": _two_kinds,
+            "sink_kinds": _sink_kinds, "ungrouped": _one_chunk_a_token,
+            "mamba": _hybrid(nemotron_cases),
+            "deltanet": _hybrid(qwen3_next_cases)}
 
 
 def _two_calls(serving):
@@ -104,22 +141,61 @@ def _ring_sums(serving, fields):
 # ----------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("family,knobs", [
-    ("dense", dict(decode_steps_per_sync=1)),
-    ("dense", dict(decode_steps_per_sync=4)),
-    ("dense", dict(decode_steps_per_sync=4, prefill_chunks_per_step=6)),
-    ("dense", dict(decode_steps_per_sync=1, prefill_chunks_per_step=3)),
-    ("routed", dict(decode_steps_per_sync=1)),
-    ("routed", dict(decode_steps_per_sync=3, prefill_chunks_per_step=2)),
-    ("two_kinds", dict(decode_steps_per_sync=1)),
-    ("two_kinds", dict(decode_steps_per_sync=3, prefill_chunks_per_step=4)),
-], ids=lambda v: v if isinstance(v, str) else "-".join(
-    f"{k[0]}{n}" for k, n in v.items()))
-def test_fused_tokens_and_counts_equal_the_two_call_paths(family, knobs):
+def _group(knobs):
+    """G of an engine with `knobs` whose mixed program takes a group."""
+    return -(-knobs.get("prefill_chunks_per_step", 1)
+             // knobs["decode_steps_per_sync"])
+
+
+@pytest.mark.parametrize("family,knobs,lengths", [
+    ("dense", dict(decode_steps_per_sync=1), LENGTHS),
+    ("dense", dict(decode_steps_per_sync=4), LENGTHS),
+    ("dense", dict(decode_steps_per_sync=4, prefill_chunks_per_step=6),
+     LENGTHS),
+    ("dense", dict(decode_steps_per_sync=1, prefill_chunks_per_step=3),
+     LENGTHS),
+    ("routed", dict(decode_steps_per_sync=1), LENGTHS),
+    ("routed", dict(decode_steps_per_sync=3, prefill_chunks_per_step=2),
+     LENGTHS),
+    ("two_kinds", dict(decode_steps_per_sync=1), LENGTHS),
+    ("two_kinds", dict(decode_steps_per_sync=3, prefill_chunks_per_step=4),
+     LENGTHS),
+    # tokens that carry groups of up to three chunks (G = ceil(budget /
+    # window)), the last group of a step partial
+    ("dense", dict(decode_steps_per_sync=2, prefill_chunks_per_step=5), LONG),
+    ("dense", dict(decode_steps_per_sync=1, prefill_chunks_per_step=3), LONG),
+    ("routed", dict(decode_steps_per_sync=2, prefill_chunks_per_step=5),
+     LONG),
+    ("two_kinds", dict(decode_steps_per_sync=2, prefill_chunks_per_step=6),
+     LONG),
+    ("two_kinds", dict(decode_steps_per_sync=1, prefill_chunks_per_step=3),
+     LONG),
+    ("sink_kinds", dict(decode_steps_per_sync=2, prefill_chunks_per_step=6),
+     LONG),
+    ("sink_kinds", dict(decode_steps_per_sync=3, prefill_chunks_per_step=4),
+     LONG),
+    # the state halves run a group's chunks in order too, each on the state
+    # and the convolution tail the one before it wrote back
+    ("mamba", dict(decode_steps_per_sync=2, prefill_chunks_per_step=5), LONG),
+    ("deltanet", dict(decode_steps_per_sync=2, prefill_chunks_per_step=6),
+     LONG),
+    ("deltanet", dict(decode_steps_per_sync=3, prefill_chunks_per_step=3),
+     LONG),
+    # a family whose mixed program takes no group: one chunk a token
+    ("ungrouped", dict(decode_steps_per_sync=2, prefill_chunks_per_step=5),
+     LONG),
+], ids=lambda v: v if isinstance(v, str) else "long" if v is LONG
+    else "short" if v is LENGTHS else "-".join(
+        f"{k[0]}{n}" for k, n in v.items()))
+def test_fused_tokens_and_counts_equal_the_two_call_paths(family, knobs,
+                                                          lengths):
     fused = FAMILIES[family](**knobs)
     oracle = _two_calls(FAMILIES[family](**knobs))
-    got, want = fused.run(_requests()), oracle.run(_requests())
+    got = fused.run(_requests(lengths))
+    want = oracle.run(_requests(lengths))
     assert _tokens(got) == _tokens(want)
+    G = 1 if family == "ungrouped" else _group(knobs)
+    assert fused.programs.group == G
 
     # it engaged, and often: all but the chunks that found nobody decoding
     assert oracle.fused_chunks == 0 and "mixed_step" not in \
@@ -133,27 +209,42 @@ def test_fused_tokens_and_counts_equal_the_two_call_paths(family, knobs):
     assert sum(r.compiles for r in recs) == sum(
         fused.compile_stats().values())
 
-    # a fused call is one chunk (a token) AND one decode step
+    # a fused call is up to G chunks a token AND one decode step
     assert fused.prefill_chunks == oracle.prefill_chunks
     assert fused.tokens_generated == oracle.tokens_generated
     assert fused.decode_steps == sum(1 for r in recs if r.decoding)
-    assert all(r.fused_chunks <= min(r.prefill_chunks, fused.window)
+    assert all(r.fused_chunks <= min(r.prefill_chunks, fused.window * G)
                and (r.decoding or not r.fused_chunks) for r in recs)
+    # full groups first: a step's groups are the fewest that hold its riding
+    # chunks, and what they lack of G chunks each is padding
+    assert all(r.chunk_groups == -(-r.fused_chunks // G)
+               and r.padded_chunks == r.chunk_groups * G - r.fused_chunks
+               for r in recs)
+    assert fused.stats()["chunk_groups"] == sum(r.chunk_groups for r in recs)
+    assert fused.stats()["padded_chunks"] == sum(r.padded_chunks
+                                                 for r in recs)
+    if G > 1 and lengths is LONG:
+        assert any(r.fused_chunks > fused.window for r in recs)
+        assert fused.padded_chunks > 0
+    else:
+        assert G > 1 or fused.padded_chunks == 0
     sums = ("prefill_chunks", "emitted", "admitted", "decode_live_blocks",
             "decode_window_live_blocks", "decode_window_table_blocks",
-            "prefill_live_blocks", "prefill_table_blocks")
+            "prefill_live_blocks", "prefill_table_blocks",
+            "prefill_kept_pairs", "prefill_window_kept_pairs")
     assert _ring_sums(fused, sums) == _ring_sums(oracle, sums)
     assert _ring_sums(fused, ("fused_chunks",)) == {
         "fused_chunks": fused.fused_chunks}
     if fused.step_counter_names:
-        # the routed experts see the same assignments; a fused call's router
-        # runs once a layer over the chunk's rows and the slots' together
+        # the routed experts see the same assignments (and a padded chunk's
+        # rows); a fused call's router runs once a layer over the chunks'
+        # rows and the slots' together
         for serving in (fused, oracle):
             counted = serving.stats()["step_counters"]
             calls = serving.prefill_chunks - serving.fused_chunks \
                 + serving.decode_steps * serving.window
-            rows = serving.prefill_chunks * CHUNK + serving.decode_steps \
-                * serving.window * serving.max_slots
+            rows = (serving.prefill_chunks + serving.padded_chunks) * CHUNK \
+                + serving.decode_steps * serving.window * serving.max_slots
             assert counted["moe_router_calls"] % calls == 0
             routed_layers = counted["moe_router_calls"] // calls
             assert counted["moe_assignments"] % (rows * routed_layers) == 0
@@ -244,9 +335,19 @@ def test_a_prompts_chunks_ride_consecutive_tokens_of_one_window():
     assert serving.compile_stats()["mixed_step"] == 1
 
 
-def test_chunks_beyond_the_window_run_first_as_their_own_calls():
-    serving = _dense(max_slots=2, decode_steps_per_sync=2,
-                     prefill_chunks_per_step=5)
+@pytest.mark.parametrize("family,rode,groups,padded", [
+    # one chunk a token (G = 1): the last two of the five ride the window's
+    # two tokens, the three before them run first as calls of their own
+    ("ungrouped", 2, 2, 0),
+    # G = ceil(5 / 2) = 3: window * G covers every budget's worth of chunks,
+    # so all five ride — three on the first token, two and a padded one on
+    # the second — and no `prefill_step` call goes out
+    ("dense", 5, 2, 1),
+])
+def test_chunks_beyond_window_times_g_run_first_as_their_own_calls(
+        family, rode, groups, padded):
+    serving = FAMILIES[family](max_slots=2, decode_steps_per_sync=2,
+                               prefill_chunks_per_step=5)
     short, long = _requests((5, 80), (40, 4))
     serving.submit(short)
     _step_until(serving, lambda: any(s.state == _DECODE
@@ -254,13 +355,52 @@ def test_chunks_beyond_the_window_run_first_as_their_own_calls():
     serving.submit(long)
     serving.step()
     rec = serving.steptrace.records()[-1]
-    assert (rec.prefill_chunks, rec.fused_chunks) == (5, 2)
+    assert (rec.prefill_chunks, rec.fused_chunks) == (5, rode)
+    assert (rec.chunk_groups, rec.padded_chunks) == (groups, padded)
     phases = [name for name, _ in rec.phases]
     assert phases.count("serving/decode_window") == 1
+    assert phases.count("serving/prefill_chunk") == (5 > rode)
     slot = next(s for s in serving.slots if s.uid == long.uid)
     assert slot.state == _DECODE and slot.planned == 80
     serving.step()          # the read-back that covers the five chunks
     assert slot.cursor == 80
+
+
+def test_a_final_chunk_mid_group_feeds_the_next_call_its_first_token():
+    """Two prompts' chunks in one step's groups (G = 3), the slots in order:
+    [B0 B1 A0] ride the window's first token and [A1 A2 A3] its second — B's
+    last chunk in the middle of its group, A's last chunk last — and both
+    prompts decode from the NEXT call on first tokens that stay on the
+    device (chunks 1 and 5 of the call's six)."""
+    knobs = dict(max_slots=3, decode_steps_per_sync=2,
+                 prefill_chunks_per_step=6)
+    serving = _dense(**knobs)
+    assert serving.programs.group == 3
+    short, a, b = _requests((5, 60, 20), (40, 4, 5))
+    serving.submit(short)
+    _step_until(serving, lambda: any(s.state == _DECODE
+                                     for s in serving.slots))
+    serving.submit(a)
+    serving.submit(b)
+    serving.step()
+    rec = serving.steptrace.records()[-1]
+    assert (rec.prefill_chunks, rec.fused_chunks, rec.chunk_groups,
+            rec.padded_chunks, rec.decoding) == (6, 6, 2, 0, 1)
+    call = serving._pending
+    slots = {s.uid: s for s in serving.slots}
+    assert [s.uid for s in serving.slots] == [b.uid, a.uid, short.uid]
+    assert [(s.uid, i) for s, i in call.firsts] == [(b.uid, 1), (a.uid, 5)]
+    assert slots[b.uid].feed == (call.id, 2 + 1)
+    assert slots[a.uid].feed == (call.id, 2 + 5)
+    assert all(slots[r.uid].state == _DECODE and slots[r.uid].flying == 1
+               and not slots[r.uid].emitted for r in (a, b))
+    assert jax.tree_util.tree_map(np.shape, call.out[0]) == ((6,), (3, 2))
+    done = {}
+    while serving.queue or serving.num_active:
+        done.update({d.uid: d for d in serving.step()})
+    want = _two_calls(_dense(**knobs)).run([short, a, b])
+    assert _tokens(done) == _tokens(want)
+    assert serving.compile_stats()["mixed_step"] == 1
 
 
 def test_a_slot_that_retires_mid_window_while_a_chunk_rides():
@@ -385,6 +525,53 @@ def test_the_rule_is_what_the_step_holds():
     assert serving._chunks_riding(2, 0) == 0         # nobody decoding
     assert serving._chunks_riding(1, 1) == 1
     assert serving._chunks_riding(9, 2) == 4         # one a window token
+    grouped = _dense(decode_steps_per_sync=4, prefill_chunks_per_step=10)
+    assert grouped.programs.group == 3               # ceil(10 / 4)
+    assert grouped._chunks_riding(9, 2) == 9
+    assert grouped._chunks_riding(40, 2) == 12       # window * G
+    # a family whose mixed program takes no group: one a token, whatever
+    # the budget
+    single = _one_chunk_a_token(decode_steps_per_sync=4,
+                                prefill_chunks_per_step=10)
+    assert single.programs.group == 1
+    assert single._chunks_riding(9, 2) == 4
+
+
+@pytest.mark.parametrize("family,knobs", [
+    ("dense", dict(decode_steps_per_sync=2)),
+    ("dense", dict(decode_steps_per_sync=4, prefill_chunks_per_step=4)),
+    ("routed", dict(decode_steps_per_sync=3, prefill_chunks_per_step=2)),
+    ("two_kinds", dict(decode_steps_per_sync=3, prefill_chunks_per_step=3)),
+    ("ungrouped", dict(decode_steps_per_sync=2, prefill_chunks_per_step=7)),
+], ids=lambda v: v if isinstance(v, str) else "-".join(
+    f"{k[0]}{n}" for k, n in v.items()))
+def test_within_the_window_the_mixed_programs_arguments_are_one_chunk_a_token(
+        monkeypatch, family, knobs):
+    """`prefill_chunks_per_step` <= `decode_steps_per_sync` (or a family
+    without `mixed_chunk_groups`): G = 1, and the mixed program takes what
+    it took before a token could carry a group — a chunk a window position,
+    no count — at one compile."""
+    serving = FAMILIES[family](**knobs)
+    S, W, C = serving.max_slots, serving.window, serving.chunk
+    assert serving.programs.group == 1
+    assert jax.tree_util.tree_map(np.shape, serving.programs.no_prev) \
+        == ((W,), (S, W))
+    fn, args = _examples(serving)["mixed_step"]
+    _, chunks, starts, lasts, chunk_tables, n = args[:6]
+    assert (chunks.shape, starts.shape, lasts.shape, np.shape(n)) \
+        == ((W, 1, C), (W, 1), (W, 1), ())
+    assert all(t.shape[:2] == (W, 1)
+               for t in jax.tree_util.tree_leaves(chunk_tables))
+    # no traced count reaches the model: its tables' `count` stays None
+    from deepspeed_tpu.models import gpt
+    counts, tables = [], gpt.mixed_tables
+    monkeypatch.setattr(gpt, "mixed_tables", lambda chunk, slots, count: (
+        counts.append(count), tables(chunk, slots, count))[1])
+    serving.run(_requests())
+    assert counts == [None]
+    assert serving.fused_chunks > 0 and serving.padded_chunks == 0
+    assert serving.chunk_groups == serving.fused_chunks
+    assert serving.compile_stats()["mixed_step"] == 1
 
 
 def test_spec_decode_never_engages_it():

@@ -1497,13 +1497,13 @@ def test_routed_paged_programs_hold_nothing_of_the_pools_or_experts_size(
 
 
 def _compile_two_kind_paged_programs(one_chip, window, periods=2,
-                                     mixed=False):
+                                     mixed=False, group=1):
     """The paged prefill program and the decode WINDOW program of a
     K-EXAONE-shaped model (a dense window layer, then `periods` periods of
     window, window, window, full with 8 of 32 experts held) at the served
     tile widths: full-kind blocks of 512, window-kind rings of 128-token
-    blocks. `mixed`: the mixed program too (a chunk and the slots' decode
-    rows in one call)."""
+    blocks. `mixed`: the mixed program too (a group of `group` chunks and
+    the slots' decode rows in one call)."""
     from deepspeed_tpu.inference.kv_cache import ring_blocks
     from deepspeed_tpu.models import exaone_moe as em
 
@@ -1560,19 +1560,21 @@ def _compile_two_kind_paged_programs(one_chip, window, periods=2,
         (sds((1, 16), i32), sds((1, 64), i32))).compile()
     programs = {"decode": decode, "prefill": prefill}
     if mixed:
+        G = group       # a traced count of the real chunks where G > 1
         programs["mixed"] = jax.jit(
             spec.mixed_paged_fn, donate_argnums=(7,)).lower(
-            params, sds((1, chunk), i32), sds((1,), i32), sds((1,), i32),
-            (sds((1, 16), i32), sds((1, 64), i32)), sds((slots,), i32),
+            params, sds((G, chunk), i32), sds((G,), i32), sds((G,), i32),
+            (sds((G, 16), i32), sds((G, 64), i32)), sds((slots,), i32),
             sds((slots,), i32), pool,
-            (sds((slots, 16), i32), sds((slots, 64), i32))).compile()
+            (sds((slots, 16), i32), sds((slots, 64), i32)),
+            *([sds((), i32)] if G > 1 else [])).compile()
     return programs, leaves, dict(spec.kv_pool_writers), \
         dict(spec.paged_attn_programs)
 
 
-@pytest.mark.parametrize("periods", [1, 2])
+@pytest.mark.parametrize("periods,group", [(1, 1), (2, 1), (1, 3), (2, 2)])
 def test_two_kind_mixed_program_holds_nothing_of_either_pools_size(
-        one_chip, monkeypatch, periods):
+        one_chip, monkeypatch, periods, group):
     """The mixed program on a pool of two kinds (PERF.md §6, PR 33): both
     kinds written twice a layer by the aliased `dstpu_kv_pool_write`, the
     chunk walked by `dstpu_paged_prefill` and the slots by
@@ -1583,13 +1585,17 @@ def test_two_kind_mixed_program_holds_nothing_of_either_pools_size(
     barrier between the two groups (`_paged_attn_half`) XLA may write them
     before the chunk's walk has read the pool and keep the walk's input by
     COPYING a leaf (on the chip at the served size: two copies of 1.5 GB a
-    mixed token, 23% of the cell's time)."""
+    mixed token, 23% of the cell's time). `group` > 1 (PR 51): a token
+    carries a group of chunks, written and walked one after another by ONE
+    traced copy in a loop that carries the flat leaves — still the same
+    Mosaic calls a layer, still nothing of a leaf's size beside them, and
+    the fused QKV product made once a layer (no clone of its matmul)."""
     from deepspeed_tpu.ops import attention_dispatch
     from deepspeed_tpu.platform import device
     mesh_mod.clear_mesh()
     monkeypatch.setattr(device, "on_tpu", lambda: True)
     programs, leaves, writers, attention = _compile_two_kind_paged_programs(
-        one_chip, window=4, periods=periods, mixed=True)
+        one_chip, window=4, periods=periods, mixed=True, group=group)
     assert writers["mixed"] == attention_dispatch.KV_POOL_WRITE_KERNEL
     assert attention["mixed/prefill_chunk"] == "paged_prefill_kernel"
     assert attention["mixed/paged_decode"] == "paged_kernel"
@@ -1606,6 +1612,7 @@ def test_two_kind_mixed_program_holds_nothing_of_either_pools_size(
     assert calls["dstpu_paged_prefill"] == calls["dstpu_paged_decode"] \
         == layers
     assert "dstpu_kv_pool_gather" not in text
+    assert not re.search(r"\.remat\d* = [^\n]*attn_\w+/dot_general", text)
 
 
 def test_two_kind_paged_programs_hold_nothing_of_either_pools_size(

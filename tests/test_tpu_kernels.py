@@ -637,8 +637,9 @@ def test_routed_moe_paged_programs_on_chip():
                 assert list(done[i].tokens) == tokens, (window, i)
 
 
-@pytest.mark.parametrize("cell", ["mistral", "olmoe"])
-def test_mixed_call_matches_the_two_calls_at_the_served_shapes(cell):
+@pytest.mark.parametrize("cell,group", [("mistral", 1), ("olmoe", 1),
+                                        ("mistral", 3), ("olmoe", 2)])
+def test_mixed_call_matches_the_two_calls_at_the_served_shapes(cell, group):
     """A chunk riding the decode call (`mixed_paged_fn`) at the served widths
     — Mistral's 512-row chunk beside 32 slots over 32-block tables, OLMoE's
     256-row chunk beside 64 slots through 64 experts (2560 assignments,
@@ -646,7 +647,8 @@ def test_mixed_call_matches_the_two_calls_at_the_served_shapes(cell):
     leaves against the chunk program followed by the decode program, on a
     shared state (free-running tokens of two programs are not comparable at
     real widths). The in-place pool and both walks are what the chip's rule
-    builds."""
+    builds. `group` > 1: a GROUP of consecutive chunks of the one prompt
+    rides (every chunk's logits against the chunk program's, run in turn)."""
     from deepspeed_tpu.models.gpt import (GPTConfig, gpt_init_fn,
                                           make_gpt_decode_model)
     from deepspeed_tpu.models.moe_gpt import (MoEGPTConfig,
@@ -686,15 +688,17 @@ def test_mixed_call_matches_the_two_calls_at_the_served_shapes(cell):
         need = 1 + row % 2
         tables[row, :need] = [next(free) for _ in range(need)]
         pos[row] = rng.integers(1, need * 512 - 1)
+    G = group
     chunk_table = np.zeros((1, nb), np.int32)
     start = 512 if cell == "mistral" else 0
-    chunk_table[0, :(start + chunk - 1) // 512 + 1] = [
-        next(free) for _ in range((start + chunk - 1) // 512 + 1)]
+    under = (start + G * chunk - 1) // 512 + 1
+    chunk_table[0, :under] = [next(free) for _ in range(under)]
     tok = jnp.asarray(rng.integers(0, cfg.vocab_size, (slots,)), jnp.int32)
-    toks = jnp.asarray(rng.integers(0, cfg.vocab_size, (1, chunk)), jnp.int32)
+    toks = jnp.asarray(rng.integers(0, cfg.vocab_size, (G, chunk)), jnp.int32)
     pos, tables = jnp.asarray(pos), jnp.asarray(tables)
-    chunk_args = (toks, jnp.asarray([start], jnp.int32),
-                  jnp.asarray([chunk - 7], jnp.int32))
+    starts = jnp.asarray(start + chunk * np.arange(G), jnp.int32)
+    lasts = jnp.asarray([chunk - 7] * G, jnp.int32)
+    chunk_table = jnp.asarray(chunk_table)
 
     def pool():
         fresh = spec.init_paged_pool(blocks, 512, jnp.bfloat16)
@@ -705,11 +709,15 @@ def test_mixed_call_matches_the_two_calls_at_the_served_shapes(cell):
     prefill = jax.jit(spec.prefill_paged_fn, donate_argnums=(4,))
     decode = jax.jit(spec.decode_paged_fn, donate_argnums=(3,))
     mixed = jax.jit(spec.mixed_paged_fn, donate_argnums=(7,))
-    first, two, *_ = prefill(params, *chunk_args, pool(),
-                             jnp.asarray(chunk_table))
+    firsts, two = [], pool()
+    for j in range(G):
+        first, two, *_ = prefill(params, toks[j:j + 1], starts[j:j + 1],
+                                 lasts[j:j + 1], two, chunk_table)
+        firsts.append(first[0])
     rows, two, *_ = decode(params, tok, pos, two, tables)
-    both, one, *_ = mixed(params, *chunk_args, jnp.asarray(chunk_table), tok,
-                          pos, pool(), tables)
+    both, one, *_ = mixed(params, toks, starts, lasts,
+                          jnp.tile(chunk_table, (G, 1)), tok, pos, pool(),
+                          tables, *([jnp.int32(G)] if G > 1 else []))
     assert spec.kv_pool_writers["mixed"] == "dstpu_kv_pool_write"
     assert spec.paged_attn_programs["mixed/prefill_chunk"] \
         == "paged_prefill_kernel"
@@ -721,10 +729,109 @@ def test_mixed_call_matches_the_two_calls_at_the_served_shapes(cell):
         np.testing.assert_allclose(a, b, rtol=2e-2,
                                    atol=2e-2 * float(np.abs(b).max()))
 
-    close(both[0], first[0])
-    close(both[1:1 + live], rows[:live])
+    close(both[:G], jnp.stack(firsts))
+    close(both[G:G + live], rows[:live])
     for leaf in one:
         close(one[leaf][:, 1:], two[leaf][:, 1:])
+
+
+def test_grouped_mixed_call_on_two_kinds_matches_the_calls_of_its_own():
+    """A GROUP of three chunks of one prompt riding a decode token on the
+    pool of two kinds, MiMo-V2-Flash's published widths three layers deep
+    (full, window, window; keys 192 wide beside values of 128, 4 and 8 KV
+    heads, the window layers' learned sink, 8 of 256 experts held): the
+    group's 768 positions wrap the window kind's ring of four 128-token
+    blocks, one chunk in flight. Its logits and both kinds' leaves against
+    three chunk programs in turn followed by the decode program, on a shared
+    state."""
+    from deepspeed_tpu.inference.kv_cache import ring_blocks, ring_tables
+    from deepspeed_tpu.models import mimo_v2_flash as mm
+    pattern = (0, 1, 1)
+    cfg = mm.MiMoV2FlashConfig(
+        vocab_size=4096, n_layer=3, n_head=64, d_model=4096,
+        attn_head_dim=192, attn_value_dim=128, attn_value_scale=0.707,
+        rotary_pct=0.334, n_kv_head=4, swa_n_kv_head=8, rope_theta=5e6,
+        swa_rope_theta=1e4, swa_sink=True, full_sink=False,
+        sliding_window=128, window_block=128,
+        layer_types=mm.layer_types(pattern),
+        mlp_layer_types=mm.mlp_layer_types((0, 1, 1)), d_ff=2048,
+        d_ff_dense=16384, max_seq_len=4096, norm_eps=1e-5,
+        tie_embeddings=False, num_experts=256, experts_held=(0, 8), top_k=8,
+        norm_topk_prob=True, router_scoring="sigmoid",
+        routed_scaling_factor=1.0, use_flash_attention=True,
+        dtype=jnp.bfloat16)
+    params = jax.jit(mm.mimo_v2_flash_init_fn(
+        cfg, dtype=jnp.bfloat16, embedding_std=1.0))(jax.random.PRNGKey(3))
+    spec = mm.make_mimo_v2_flash_decode_model(cfg, params=params, name="chip")
+    slots, chunk, G, block, blocks = 16, 256, 3, 512, 40
+    nb, nbw = 4096 // block, 4096 // 128
+    ring = ring_blocks(128, 128, chunk, 1)
+    assert ring == 4
+    rings = ring_tables(slots, nbw, ring)
+    rng = np.random.default_rng(7)
+    tables = np.zeros((slots, nb), np.int32)
+    free = iter(range(1, blocks))
+    pos = np.zeros((slots,), np.int32)
+    live = slots - 1            # the last slot is the prompt's
+    for row in range(live):
+        need = 1 + row % 2
+        tables[row, :need] = [next(free) for _ in range(need)]
+        pos[row] = rng.integers(1, need * block - 1)
+    start = 512
+    chunk_table = np.zeros((1, nb), np.int32)
+    under = (start + G * chunk - 1) // block + 1
+    chunk_table[0, :under] = [next(free) for _ in range(under)]
+    chunk_tables = (jnp.asarray(chunk_table), jnp.asarray(rings[live:]))
+    slot_tables = (jnp.asarray(tables), jnp.asarray(
+        np.where(np.arange(slots)[:, None] < live, rings, 0).astype(np.int32)))
+    tok = jnp.asarray(rng.integers(0, cfg.vocab_size, (slots,)), jnp.int32)
+    toks = jnp.asarray(rng.integers(0, cfg.vocab_size, (G, chunk)), jnp.int32)
+    starts = jnp.asarray(start + chunk * np.arange(G), jnp.int32)
+    lasts = jnp.asarray([chunk - 1, chunk - 7, chunk - 30], jnp.int32)
+    pos = jnp.asarray(pos)
+
+    def pool():
+        fresh = spec.init_paged_pool(blocks, block, jnp.bfloat16,
+                                     window_blocks=1 + slots * ring)
+        key = jax.random.PRNGKey(11)
+        return {name: (jax.random.normal(key, leaf.shape, jnp.bfloat16) * 0.5)
+                .at[:, 0].set(0) for name, leaf in fresh.items()}
+
+    prefill = jax.jit(spec.prefill_paged_fn, donate_argnums=(4,))
+    decode = jax.jit(spec.decode_paged_fn, donate_argnums=(3,))
+    mixed = jax.jit(spec.mixed_paged_fn, donate_argnums=(7,))
+    firsts, two = [], pool()
+    for j in range(G):
+        first, two, *_ = prefill(params, toks[j:j + 1], starts[j:j + 1],
+                                 lasts[j:j + 1], two, chunk_tables)
+        firsts.append(first[0])
+    rows, two, *_ = decode(params, tok, pos, two, slot_tables)
+    both, one, *_ = mixed(
+        params, toks, starts, lasts,
+        tuple(jnp.tile(t, (G, 1)) for t in chunk_tables), tok, pos, pool(),
+        slot_tables, jnp.int32(G))
+    assert spec.kv_pool_writers["mixed"] == "dstpu_kv_pool_write"
+    assert spec.paged_attn_programs["mixed/prefill_chunk"] \
+        == "paged_prefill_kernel"
+    assert spec.paged_attn_programs["mixed/paged_decode"] == "paged_kernel"
+
+    def close(a, b):
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        assert np.isfinite(a).all()
+        np.testing.assert_allclose(a, b, rtol=2e-2,
+                                   atol=2e-2 * float(np.abs(b).max()))
+
+    close(both[:G], jnp.stack(firsts))
+    close(both[G:G + live], rows[:live])
+    for leaf in one:
+        close(one[leaf][:, 1:], two[leaf][:, 1:])
+    # a partial group: the third chunk absent, its rows padding — the first
+    # two chunks' logits and the slots' as before, its positions unwritten
+    part, _, *_ = mixed(
+        params, toks, starts, lasts,
+        tuple(jnp.tile(t, (G, 1)) for t in chunk_tables), tok, pos, pool(),
+        slot_tables, jnp.int32(G - 1))
+    close(part[:G - 1], jnp.stack(firsts[:G - 1]))
 
 
 def test_quant_int4_kernels_refuse_on_tpu():
