@@ -78,6 +78,7 @@ from deepspeed_tpu.inference.kv_cache import (BlockAllocator, TRASH_BLOCK,
                                               transplant_blocks)
 from deepspeed_tpu.inference.spec_decode import accept_greedy, make_drafter
 from deepspeed_tpu.telemetry import Telemetry
+from deepspeed_tpu.telemetry.device_scopes import ProgramTable
 from deepspeed_tpu.utils.logging import log_dist
 
 
@@ -576,6 +577,16 @@ class ServingEngine:
                 window=self.window, max_slots=self.max_slots,
                 chunk=self.chunk, spec_on=self.spec_on, draft_k=self.draft_k,
                 replicated=self._replicated, watchdog=wd, group=group)
+        # the device's side of the timeline, on demand: the recorder is
+        # handed the built programs (`mixed_step` too, before any chunk has
+        # ridden) and the SHAPES of their arguments, and lowers nothing
+        # until `steptrace.device_scopes()` is asked (a streamed engine has
+        # no whole-step program to hand over)
+        self.steptrace.scope_provider = table = ProgramTable()
+        for name, fn, args in self.programs.examples(
+                engine.params, self.pool, self._tables_arg(self.tables),
+                self._rng):
+            table.add(name, fn, args)
 
         # drafter AFTER pool/allocator: the draft-model drafter mirrors the
         # pool geometry and shares the block tables (spec_decode.py)

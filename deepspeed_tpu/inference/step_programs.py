@@ -94,9 +94,16 @@ class StepPrograms:
 
 
 def _sampler(cfg):
-    return functools.partial(sample_logits, greedy=cfg.greedy,
+    draw = functools.partial(sample_logits, greedy=cfg.greedy,
                              temperature=cfg.temperature, top_k=cfg.top_k,
                              top_p=cfg.top_p)
+
+    def sample(logits, rng):
+        # (named for `telemetry/device_scopes.py`, as the model's halves are)
+        with jax.named_scope("sample"):
+            return draw(logits, rng)
+
+    return sample
 
 
 def build_resident(spec, cfg, transform, *, window, max_slots, chunk,

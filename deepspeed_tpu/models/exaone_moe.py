@@ -60,7 +60,7 @@ import jax.numpy as jnp
 
 from deepspeed_tpu.inference.kv_cache import CacheKind
 from deepspeed_tpu.models.gpt import (MixedTables, _attn_half, _embed,
-                                      _lm_head, _paged_attn_half,
+                                      _last_rows, _lm_head, _paged_attn_half,
                                       _residual_mlp, decode_rows,
                                       make_mixed_paged_fn, offset_tables)
 from deepspeed_tpu.models.mla import (LATENT_LEAF, entry_width, mla_attn_half,
@@ -635,8 +635,8 @@ def make_exaone_moe_decode_model(cfg: ExaoneMoEConfig, params=None,
         x = _embed(params, tokens, positions, cfg)
         x, pool, *counted = _layers_paged(params, x, pool, block_tables,
                                           positions, routing)
-        last = jnp.take_along_axis(x, last_idx[:, None, None], axis=1)
-        return (_lm_head(params, last, cfg)[:, 0], pool, *counted)
+        return (_lm_head(params, _last_rows(x, last_idx), cfg)[:, 0], pool,
+                *counted)
 
     def decode_paged_fn(params, token, pos, pool, block_tables,
                         routing=False):

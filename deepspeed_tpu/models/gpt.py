@@ -712,7 +712,7 @@ def _gate_output(attn, gate):
     the out-projection; `gate` None (no `attn_output_gate`): `attn`."""
     if gate is None:
         return attn
-    with jax.named_scope("attn/gate"):
+    with jax.named_scope("gate"):
         return attn * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(
             attn.dtype)
 
@@ -745,24 +745,27 @@ def _attn_half(x, p, cfg: GPTConfig, positions, attn_fn=None, constrain=True,
     B, T, D = x.shape
     H, Hkv, hd = cfg.n_head, cfg.n_kv_head, cfg.head_dim
 
-    h = _half_input(x, p, cfg)
-    h = _act_quant(h, cfg)
-    qkv = checkpoint_name(h @ p["attn_qkv_w"] + p["attn_qkv_b"], QKV_PRODUCT)
-    q, k, v, gate = _split_qkv(qkv, cfg)
-    q, k = _qk_norm(q, k, p, cfg)
-    q = q.reshape(B, T, H, hd)
-    k = k.reshape(B, T, Hkv, hd)
-    v = _scale_values(v.reshape(B, T, Hkv, cfg.value_dim), cfg)
-    q, k = _qk_norm(q, k, p, cfg, heads_split=True)
-    if constrain:
-        # activations: heads on tensor axis (Megatron), seq on sequence axis
-        q = shard_constraint(q, BATCH_AXES, SEQ_AXIS, TENSOR_AXIS, None)
-        k = shard_constraint(k, BATCH_AXES, SEQ_AXIS, TENSOR_AXIS, None)
-        v = shard_constraint(v, BATCH_AXES, SEQ_AXIS, TENSOR_AXIS, None)
-    if cfg.use_rotary:
-        rd = int(cfg.rotary_pct * hd) // 2 * 2
-        q = _rope(q, positions, rd, cfg.rope_theta)
-        k = _rope(k, positions, rd, cfg.rope_theta)
+    with jax.named_scope("qkv"):
+        h = _half_input(x, p, cfg)
+        h = _act_quant(h, cfg)
+        qkv = checkpoint_name(h @ p["attn_qkv_w"] + p["attn_qkv_b"],
+                              QKV_PRODUCT)
+        q, k, v, gate = _split_qkv(qkv, cfg)
+        q, k = _qk_norm(q, k, p, cfg)
+        q = q.reshape(B, T, H, hd)
+        k = k.reshape(B, T, Hkv, hd)
+        v = _scale_values(v.reshape(B, T, Hkv, cfg.value_dim), cfg)
+        q, k = _qk_norm(q, k, p, cfg, heads_split=True)
+        if constrain:
+            # activations: heads on tensor axis (Megatron), seq on sequence
+            # axis
+            q = shard_constraint(q, BATCH_AXES, SEQ_AXIS, TENSOR_AXIS, None)
+            k = shard_constraint(k, BATCH_AXES, SEQ_AXIS, TENSOR_AXIS, None)
+            v = shard_constraint(v, BATCH_AXES, SEQ_AXIS, TENSOR_AXIS, None)
+        if cfg.use_rotary:
+            rd = int(cfg.rotary_pct * hd) // 2 * 2
+            q = _rope(q, positions, rd, cfg.rope_theta)
+            k = _rope(k, positions, rd, cfg.rope_theta)
     t_pos = jnp.arange(T, dtype=jnp.int32)
     causal = jnp.tril(jnp.ones((T, T), bool))
     if cfg.sliding_window:
@@ -778,8 +781,9 @@ def _attn_half(x, p, cfg: GPTConfig, positions, attn_fn=None, constrain=True,
                       sink=p["attn_sink"] if cfg.attn_sink else None)
     attn_flat = _act_quant(
         _gate_output(attn.reshape(B, T, H * cfg.value_dim), gate), cfg)
-    attn_out = checkpoint_name(
-        attn_flat @ p["attn_out_w"] + p["attn_out_b"], ATTN_OUT)
+    with jax.named_scope("out"):
+        attn_out = checkpoint_name(
+            attn_flat @ p["attn_out_w"] + p["attn_out_b"], ATTN_OUT)
     return attn_out, k, v
 
 
@@ -815,13 +819,21 @@ def _head_logits(params, x, cfg: GPTConfig):
     return logits
 
 
+def _last_rows(x, last_idx):
+    """x [B, C, D] -> [B, 1, D], each row's position `last_idx` [B]: what
+    the head reads of a prefill chunk (named with the head)."""
+    with jax.named_scope("head"):
+        return jnp.take_along_axis(x, last_idx[:, None, None], axis=1)
+
+
 def _lm_head(params, x, cfg: GPTConfig):
     """Final norm + (tied) LM head. x: [B, T, D] -> logits [B, T, V]."""
-    x = _norm(x, params["lnf_scale"], params.get("lnf_bias"), cfg.use_rmsnorm,
-              cfg.norm_eps)
-    logits = _head_logits(params, x, cfg)
-    if cfg.logits_scaling != 1.0:
-        logits = logits / cfg.logits_scaling
+    with jax.named_scope("head"):
+        x = _norm(x, params["lnf_scale"], params.get("lnf_bias"),
+                  cfg.use_rmsnorm, cfg.norm_eps)
+        logits = _head_logits(params, x, cfg)
+        if cfg.logits_scaling != 1.0:
+            logits = logits / cfg.logits_scaling
     return logits
 
 
@@ -836,26 +848,32 @@ def _embed(params, tokens, positions, cfg: GPTConfig):
     exactly ZeRO-3's gather-before-use (reference
     `zero/partitioned_param_coordinator.py:256`) — after which the output
     transition to batch/seq sharding is a cheap slice."""
-    wte = shard_constraint(params["wte"], TENSOR_AXIS, None)
-    x = jnp.take(wte, tokens, axis=0).astype(cfg.dtype)
-    if cfg.embedding_multiplier != 1.0:
-        x = x * cfg.embedding_multiplier
-    if not cfg.use_rotary and not cfg.use_alibi:
-        wpe = shard_constraint(params["wpe"], None, None)
-        x = x + jnp.take(wpe, positions, axis=0).astype(cfg.dtype)
-    if cfg.use_emb_ln:  # BLOOM word-embedding LayerNorm
-        x = _norm(x, params["emb_ln_scale"], params.get("emb_ln_bias"),
-                  use_rms=False, eps=cfg.norm_eps)
+    with jax.named_scope("embed"):
+        wte = shard_constraint(params["wte"], TENSOR_AXIS, None)
+        x = jnp.take(wte, tokens, axis=0).astype(cfg.dtype)
+        if cfg.embedding_multiplier != 1.0:
+            x = x * cfg.embedding_multiplier
+        if not cfg.use_rotary and not cfg.use_alibi:
+            wpe = shard_constraint(params["wpe"], None, None)
+            x = x + jnp.take(wpe, positions, axis=0).astype(cfg.dtype)
+        if cfg.use_emb_ln:  # BLOOM word-embedding LayerNorm
+            x = _norm(x, params["emb_ln_scale"], params.get("emb_ln_bias"),
+                      use_rms=False, eps=cfg.norm_eps)
     return x
 
 
 def _block(x, p, cfg: GPTConfig, positions, dropout_rng=None, attn_fn=None,
            local_flag=None):
-    """One transformer block. x: [B, T, D]."""
-    attn_out, _, _ = _attn_half(x, p, cfg, positions, attn_fn=attn_fn,
-                                local_flag=local_flag)
-    x = _residual_mlp(x, attn_out, p, cfg)
-    return shard_constraint(x, BATCH_AXES, SEQ_AXIS, None)
+    """One transformer block. x: [B, T, D]. The halves stand under the
+    scopes the paged path gives them (`_block_paged`): a device trace of the
+    training step reads `attn` and `mlp` too, its backward `transpose(...)`
+    of the same names (`telemetry/device_scopes.py`)."""
+    with jax.named_scope("attn"):
+        attn_out, _, _ = _attn_half(x, p, cfg, positions, attn_fn=attn_fn,
+                                    local_flag=local_flag)
+    with jax.named_scope("mlp"):
+        x = _residual_mlp(x, attn_out, p, cfg)
+        return shard_constraint(x, BATCH_AXES, SEQ_AXIS, None)
 
 
 def gpt_hidden(params, tokens, cfg: GPTConfig, positions=None, attn_fn=None,
@@ -953,8 +971,9 @@ def gpt_hidden(params, tokens, cfg: GPTConfig, positions=None, attn_fn=None,
         x, _ = jax.lax.scan(scan_body, x, (params["blocks"], flags),
                             unroll=cfg.scan_unroll)
 
-    return _norm(x, params["lnf_scale"], params.get("lnf_bias"), cfg.use_rmsnorm,
-                 cfg.norm_eps)
+    with jax.named_scope("head"):
+        return _norm(x, params["lnf_scale"], params.get("lnf_bias"),
+                     cfg.use_rmsnorm, cfg.norm_eps)
 
 
 def gpt_forward(params, tokens, cfg: GPTConfig, positions=None, attn_fn=None):
@@ -985,12 +1004,20 @@ def gpt_loss(params, batch, rng, cfg: GPTConfig, attn_fn=None):
         assert "lm_head_bias" not in params, \
             "chunked CE does not support a tied LM-head bias"
         head = _head_table(params, cfg)
-        nll = chunked_softmax_xent(x.reshape(B * T, -1), head.astype(x.dtype),
-                                   labels.reshape(B * T), cfg.loss_chunks)
-        mask = (labels.reshape(B * T) >= 0).astype(jnp.float32)
-        return (nll * mask).sum() / jnp.maximum(mask.sum(), 1.0)
+        with jax.named_scope("head_loss"):
+            nll = chunked_softmax_xent(
+                x.reshape(B * T, -1), head.astype(x.dtype),
+                labels.reshape(B * T), cfg.loss_chunks)
+            mask = (labels.reshape(B * T) >= 0).astype(jnp.float32)
+            return (nll * mask).sum() / jnp.maximum(mask.sum(), 1.0)
     x = gpt_hidden(params, inputs, cfg, attn_fn=attn_fn, pld=pld, ltd=ltd)
-    logits = _head_logits(params, x, cfg)
+    with jax.named_scope("head_loss"):
+        return _xent(_head_logits(params, x, cfg), labels)
+
+
+def _xent(logits, labels):
+    """Mean causal-LM cross entropy of logits [B, T, V] against labels
+    [B, T] (negative = ignored)."""
     # cross entropy WITHOUT materializing an fp32 [B,T,V] buffer (1.65G at
     # mbs16/seq512/50k vocab): logits stay in compute dtype, the exp/sum runs
     # with an fp32 accumulator fused into the reduction, and only [B,T]
@@ -1060,20 +1087,21 @@ def _decode_qkv(x, p, positions, cfg: GPTConfig, hold=False):
     act-quant gates, remat checkpoint names, and shard constraints.)"""
     B, C, _ = x.shape
     H, Hkv, hd = cfg.n_head, cfg.n_kv_head, cfg.head_dim
-    h = _half_input(x, p, cfg)
-    qkv = h @ p["attn_qkv_w"] + p["attn_qkv_b"]
-    if hold:
-        qkv = jax.lax.optimization_barrier(qkv)
-    q, k, v, gate = _split_qkv(qkv, cfg)
-    q, k = _qk_norm(q, k, p, cfg)
-    q = q.reshape(B, C, H, hd)
-    k = k.reshape(B, C, Hkv, hd)
-    v = _scale_values(v.reshape(B, C, Hkv, cfg.value_dim), cfg)
-    q, k = _qk_norm(q, k, p, cfg, heads_split=True)
-    if cfg.use_rotary:
-        rd = int(cfg.rotary_pct * hd) // 2 * 2
-        q = _rope(q, positions, rd, cfg.rope_theta)
-        k = _rope(k, positions, rd, cfg.rope_theta)
+    with jax.named_scope("qkv"):
+        h = _half_input(x, p, cfg)
+        qkv = h @ p["attn_qkv_w"] + p["attn_qkv_b"]
+        if hold:
+            qkv = jax.lax.optimization_barrier(qkv)
+        q, k, v, gate = _split_qkv(qkv, cfg)
+        q, k = _qk_norm(q, k, p, cfg)
+        q = q.reshape(B, C, H, hd)
+        k = k.reshape(B, C, Hkv, hd)
+        v = _scale_values(v.reshape(B, C, Hkv, cfg.value_dim), cfg)
+        q, k = _qk_norm(q, k, p, cfg, heads_split=True)
+        if cfg.use_rotary:
+            rd = int(cfg.rotary_pct * hd) // 2 * 2
+            q = _rope(q, positions, rd, cfg.rope_theta)
+            k = _rope(k, positions, rd, cfg.rope_theta)
     return q, k, v, gate
 
 
@@ -1282,8 +1310,7 @@ def make_gpt_decode_model(cfg: GPTConfig = None, name="gpt2-125m", params=None, 
         positions = start_pos[:, None] + jnp.arange(C, dtype=jnp.int32)[None]
         x = _embed(params, tokens, positions, cfg)
         x, pool = _scan_paged(params, x, pool, block_tables, positions)
-        last = jnp.take_along_axis(x, last_idx[:, None, None], axis=1)
-        logits = _lm_head(params, last, cfg)[:, 0]
+        logits = _lm_head(params, _last_rows(x, last_idx), cfg)[:, 0]
         return logits, pool
 
     def decode_paged_fn(params, token, pos, pool, block_tables):
@@ -1457,10 +1484,11 @@ def make_mixed_paged_fn(cfg, layers_paged, chunk_valid=False):
             params, x, pool, mixed_tables(chunk_table, block_tables, count),
             positions, **valid, **loop)
         D = x.shape[-1]
-        last = jnp.take_along_axis(x[:, :G * C].reshape(G, C, D),
-                                   last_idx[:, None, None], axis=1)
-        logits = _lm_head(params, jnp.concatenate(
-            [last.reshape(1, G, D), x[:, G * C:]], axis=1), cfg)[0]
+        last = _last_rows(x[:, :G * C].reshape(G, C, D), last_idx)
+        with jax.named_scope("head"):
+            rows = jnp.concatenate([last.reshape(1, G, D), x[:, G * C:]],
+                                   axis=1)
+        logits = _lm_head(params, rows, cfg)[0]
         return (logits, pool, *counts)
 
     return mixed_paged_fn
@@ -1695,7 +1723,9 @@ def _paged_attn_half(x, p, pool_l, positions, block_tables,
     else:
         attn, pool_l = group(q, k, v, pool_l, positions, block_tables,
                              phase=phase, decode_work=decode_work)
-    attn_out = _gate_output(attn, gate) @ p["attn_out_w"] + p["attn_out_b"]
+    attn = _gate_output(attn, gate)
+    with jax.named_scope("out"):
+        attn_out = attn @ p["attn_out_w"] + p["attn_out_b"]
     return attn_out, pool_l
 
 
@@ -1725,7 +1755,8 @@ def _paged_write_attend(q, k, v, pool_l, positions, block_tables,
     # inactive slots (all-trash tables, pos 0) collide in the trash block —
     # duplicate-index scatter order is unspecified there and irrelevant.
     # (`jax.named_scope`s below cost nothing: they name these regions in the
-    # operations' `op_name`, which xprof shows)
+    # operations' `op_name`, which xprof shows and `telemetry/device_scopes.py`
+    # reads back out of the compiled program)
     with jax.named_scope("kv_pool_write"):
         pool_l = dict(pool_l)
         if block_base is not None:
@@ -1775,7 +1806,7 @@ def _paged_write_attend(q, k, v, pool_l, positions, block_tables,
         attn_programs[record or site.phase] = program
     runner = attn_dispatch.get_program(program).runner
     if runner is not None:
-        with jax.named_scope("attn"):
+        with jax.named_scope("walk"):
             attn = runner(q, pool_l, block_tables, positions[:, 0],
                           sm_scale=sm_scale(cfg),
                           window=site.window or None, work=decode_work,
@@ -1794,7 +1825,7 @@ def _paged_write_attend(q, k, v, pool_l, positions, block_tables,
                        for leaf, rows in pool_l.items()}
                 v_ctx = ctx.pop("v")
                 k_ctx = merge_keys(ctx)
-        with jax.named_scope("attn"):
+        with jax.named_scope("walk"):
             attn = _paged_attend(q, k_ctx, v_ctx, positions, cfg,
                                  local_flag=local_flag, sink=sink)
     return attn, pool_l
@@ -1812,10 +1843,11 @@ def _block_paged(x, p, pool_l, positions, block_tables,
     `_residual_mlp`; `layer`, `scan_paged`'s layer index, is for blocks that
     need it)."""
     del layer
-    attn_out, pool_l = _paged_attn_half(
-        x, p, pool_l, positions, block_tables, cfg, local_flag=local_flag,
-        phase=phase, block_base=block_base, decode_work=decode_work,
-        attn_programs=attn_programs)
+    with jax.named_scope("attn"):
+        attn_out, pool_l = _paged_attn_half(
+            x, p, pool_l, positions, block_tables, cfg, local_flag=local_flag,
+            phase=phase, block_base=block_base, decode_work=decode_work,
+            attn_programs=attn_programs)
     with jax.named_scope("mlp"):
         x = _residual_mlp(x, attn_out, p, cfg, constrain=False, mlp_fn=mlp_fn)
     return x, pool_l

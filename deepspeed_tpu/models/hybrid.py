@@ -74,9 +74,10 @@ import jax.numpy as jnp
 
 from deepspeed_tpu.inference.kv_cache import CacheKind
 from deepspeed_tpu.models.gpt import (MixedTables, _attn_half, _embed,
-                                      _lm_head, _norm, _paged_attn_half,
-                                      decode_rows, make_mixed_paged_fn,
-                                      offset_tables, over_chunk_group)
+                                      _last_rows, _lm_head, _norm,
+                                      _paged_attn_half, decode_rows,
+                                      make_mixed_paged_fn, offset_tables,
+                                      over_chunk_group)
 from deepspeed_tpu.models.layer_pattern import repeated_runs
 from deepspeed_tpu.models.moe_gpt import MoEGPTConfig
 from deepspeed_tpu.ops import attention_dispatch as attn_dispatch
@@ -404,10 +405,10 @@ def _mamba_chunk(p, cfg, proj, cache, rows, start, valid):
     T = zxbcdt.shape[1]
     _, xBC, dt = jnp.split(
         zxbcdt, [cfg.ssm_inner, cfg.ssm_inner + cfg.conv_width], -1)
-    with jax.named_scope("ssm/conv"):
+    with jax.named_scope("conv"):
         seq, S = _chunk_start(xBC, cfg, cache, rows, start)
         x, B, C, dt, A = _ssm_inputs(_conv(seq, p, T), dt, p, cfg)
-    with jax.named_scope("ssm/scan"):
+    with jax.named_scope("scan"):
         # a padded tail leaves the state alone: decay 1, no input
         y, S = ssm.ssm_chunk_scan(x, jnp.where(_real(valid, T), dt, 0.0), A,
                                   B, C, S, cfg.chunk_size)
@@ -420,10 +421,10 @@ def _mamba_token(p, cfg, proj, cache, rows):
     zxbcdt, = proj
     _, xBC, dt = jnp.split(
         zxbcdt, [cfg.ssm_inner, cfg.ssm_inner + cfg.conv_width], -1)
-    with jax.named_scope("ssm/conv"):
+    with jax.named_scope("conv"):
         seq, conv = _token_start(xBC, cache, rows)
         x, B, C, dt, A = _ssm_inputs(_conv(seq, p, 1)[:, 0], dt, p, cfg)
-    with jax.named_scope("ssm/update"):
+    with jax.named_scope("update"):
         y, state = ssm.ssm_update(
             cache[0], rows, jnp.exp(dt * A),
             dt[..., None] * x.astype(jnp.float32), B, C)
@@ -480,7 +481,7 @@ def _mamba_half(x, p, cfg, cache=None, rows=None, positions=None, valid=None):
     `cache`, `rows`, `positions`, `valid`: `_recurrent`'s."""
     B, T, _ = x.shape
     u = _norm(x, p["ln1_scale"], None, True, cfg.norm_eps)
-    with jax.named_scope("ssm/in_proj"):
+    with jax.named_scope("in_proj"):
         # xBC and dt are read at the half's start, z at its end: the barrier
         # HOLDS the product between them — left alone, XLA frees it after the
         # convolution and computes it again for the gate (`fusion.N.remat`)
@@ -488,7 +489,7 @@ def _mamba_half(x, p, cfg, cache=None, rows=None, positions=None, valid=None):
     y, cache = _recurrent(
         (zxbcdt,), partial(_mamba_chunk, p, cfg),
         partial(_mamba_token, p, cfg), cache, rows, positions, valid)
-    with jax.named_scope("ssm/out_proj"):
+    with jax.named_scope("out_proj"):
         z = zxbcdt[..., :cfg.ssm_inner].astype(jnp.float32)
         gated = (y.reshape(B, T, cfg.n_groups, -1)
                  * jax.nn.silu(z).reshape(B, T, cfg.n_groups, -1))
@@ -530,11 +531,11 @@ def _gdn_chunk(p, cfg, proj, cache, rows, start, valid):
     """`_mamba_chunk` of the delta rule: (o [b, T, H, V] float32, cache)."""
     qkvz, ba = proj
     T = qkvz.shape[1]
-    with jax.named_scope("gdn/conv"):
+    with jax.named_scope("conv"):
         seq, S = _chunk_start(qkvz[..., :sum(cfg.gdn_widths)], cfg, cache,
                               rows, start)
         q, k, v, g, beta = _gdn_inputs(_conv(seq, p, T), ba, p, cfg)
-    with jax.named_scope("gdn/scan"):
+    with jax.named_scope("scan"):
         # a padded tail leaves the state alone: decay 1 AND nothing written
         real = _real(valid, T)
         o, S = gdn.gdn_chunk_scan(
@@ -546,10 +547,10 @@ def _gdn_chunk(p, cfg, proj, cache, rows, start, valid):
 def _gdn_token(p, cfg, proj, cache, rows):
     """`_mamba_token` of the delta rule: (o [S, H, V] float32, cache)."""
     qkvz, ba = proj
-    with jax.named_scope("gdn/conv"):
+    with jax.named_scope("conv"):
         seq, conv = _token_start(qkvz[..., :sum(cfg.gdn_widths)], cache, rows)
         q, k, v, g, beta = _gdn_inputs(_conv(seq, p, 1)[:, 0], ba, p, cfg)
-    with jax.named_scope("gdn/update"):
+    with jax.named_scope("update"):
         o, state = gdn.gdn_update(cache[0], rows, jnp.exp(g), beta, q, k, v)
     return o, (state, conv)
 
@@ -560,7 +561,7 @@ def _gdn_half(x, p, cfg, cache=None, rows=None, positions=None, valid=None):
     B, T, _ = x.shape
     H, V = cfg.gdn_value_heads, cfg.gdn_value_dim
     u = _norm(x, p["ln1_scale"], None, True, cfg.norm_eps)
-    with jax.named_scope("gdn/in_proj"):
+    with jax.named_scope("in_proj"):
         # q, k and v are read at the half's start, z at its end: held, as
         # `_mamba_half` holds its product and for its reason
         qkvz = jax.lax.optimization_barrier(u @ p["gdn_qkvz_w"])
@@ -568,7 +569,7 @@ def _gdn_half(x, p, cfg, cache=None, rows=None, positions=None, valid=None):
     o, cache = _recurrent(
         (qkvz, ba), partial(_gdn_chunk, p, cfg),
         partial(_gdn_token, p, cfg), cache, rows, positions, valid)
-    with jax.named_scope("gdn/out_proj"):
+    with jax.named_scope("out_proj"):
         # the norm FIRST (a head's V columns, one scale for all heads), then
         # the gate
         z = qkvz[..., -H * V:].astype(jnp.float32).reshape(B, T, H, V)
@@ -579,7 +580,9 @@ def _gdn_half(x, p, cfg, cache=None, rows=None, positions=None, valid=None):
     return out, cache
 
 
-# a recurrent half's letter -> (its scope in a paged program, its `f`)
+# a recurrent half's letter -> (the scope a program runs it under, its `f`):
+# the half's pieces are named inside it (`in_proj`, `conv`, `scan` or `update`,
+# `out_proj`), so a program's instructions read `ssm/in_proj`, `gdn/scan`
 RECURRENT = {MAMBA: ("ssm", _mamba_half), DELTANET: ("gdn", _gdn_half)}
 
 
@@ -617,7 +620,9 @@ def hybrid_forward(params, tokens, cfg: HybridConfig, expert_half,
         if kind == ATTENTION:
             out, _, _ = _attn_half(x, p, acfg, positions, constrain=False)
         elif kind in RECURRENT:
-            out, _ = RECURRENT[kind][1](x, p, cfg)
+            scope, mixer = RECURRENT[kind]
+            with jax.named_scope(scope):
+                out, _ = mixer(x, p, cfg)
         else:
             out, _, top_e = expert_half(x, p, cfg)
             if routing is not None:
@@ -688,6 +693,7 @@ def make_hybrid_decode_model(cfg: HybridConfig, params, name, expert_half,
                         tables if in_place else offset(tables, base), acfg,
                         decode_work=work, attn_programs=attn_programs,
                         phase=None if mixed else site, **where)
+                    x = _residual(x, out, cfg)
                 flat = {**flat, **kv}
             elif kind in RECURRENT:
                 scope, mixer = RECURRENT[kind]
@@ -695,14 +701,17 @@ def make_hybrid_decode_model(cfg: HybridConfig, params, name, expert_half,
                     out, (state, conv) = mixer(
                         x, p, cfg, (flat["ssm"], flat["conv"]),
                         offset(state_rows, index * state_n), positions, valid)
+                    x = _residual(x, out, cfg)
                 flat = {**flat, "ssm": state, "conv": conv}
             else:
                 with jax.named_scope("mlp"):
                     out, counted, top_e = expert_half(x, p, cfg, **experts)
+                    x = _residual(x, out, cfg)
                 counts.append(counted)
                 if chosen is not None:
                     chosen.append(top_e)
-            return _residual(x, out, cfg), flat
+            # (a half's residual stands under the half's scope)
+            return x, flat
 
         acc = no_counts
         chosen = [] if routing else None    # an expert half's [B*C, top_k]
@@ -758,8 +767,8 @@ def make_hybrid_decode_model(cfg: HybridConfig, params, name, expert_half,
         x, pool, *counted = _layers_paged(params, x, pool, block_tables,
                                           positions, valid=last_idx + 1,
                                           routing=routing)
-        last = jnp.take_along_axis(x, last_idx[:, None, None], axis=1)
-        return (_lm_head(params, last, cfg)[:, 0], pool, *counted)
+        return (_lm_head(params, _last_rows(x, last_idx), cfg)[:, 0], pool,
+                *counted)
 
     def decode_paged_fn(params, token, pos, pool, block_tables,
                         routing=False):

@@ -216,7 +216,7 @@ def _write_attend(q_n, q_r, c, k_r, p, pool_l, positions, block_tables, cfg,
         attn_programs[record or phase] = program
     scale = score_scale(cfg, cfg.head_dim)
     runner = attn_dispatch.get_program(program).runner
-    with jax.named_scope("attn"):
+    with jax.named_scope("walk"):
         if runner is not None:
             u = runner(q, {LATENT_LEAF: pool}, block_tables, positions[:, 0],
                        sm_scale=scale, window=None, work=work, rank=r)

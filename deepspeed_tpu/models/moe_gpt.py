@@ -53,8 +53,8 @@ from deepspeed_tpu.comm.mesh import (BATCH_AXES, EXPERT_AXIS, SEQ_AXIS,
                                      shard_constraint)
 from deepspeed_tpu.models.gpt import (GPTConfig, _act, _attn_half, _block,
                                       _block_decode, _block_paged,
-                                      _decode_attn_half, _embed, _lm_head,
-                                      _norm, _residual_mlp,
+                                      _decode_attn_half, _embed, _last_rows,
+                                      _lm_head, _norm, _residual_mlp,
                                       gpt_cache_identity, gpt_init_fn,
                                       init_gpt_params, init_kv_cache,
                                       init_paged_kv_pool, gpt_param_specs,
@@ -558,8 +558,7 @@ def make_moe_gpt_decode_model(cfg: MoEGPTConfig, params=None, name="moe-gpt", se
         x = _embed(params, tokens, positions, cfg)
         x, pool, counts = _layers_paged(params, x, pool, block_tables,
                                         positions)
-        last = jnp.take_along_axis(x, last_idx[:, None, None], axis=1)
-        logits = _lm_head(params, last, cfg)[:, 0]
+        logits = _lm_head(params, _last_rows(x, last_idx), cfg)[:, 0]
         return logits, pool, counts
 
     def decode_paged_fn(params, token, pos, pool, block_tables):

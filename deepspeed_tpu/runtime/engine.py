@@ -39,6 +39,7 @@ from deepspeed_tpu.runtime.precision import LossScaler, LossScaleState, masked_u
 from deepspeed_tpu.runtime.sentinel import BadStateError, BadStateSentinel
 from deepspeed_tpu.runtime.zero import ZeroShardingPolicy
 from deepspeed_tpu.telemetry import Telemetry
+from deepspeed_tpu.telemetry.device_scopes import ProgramTable
 from deepspeed_tpu.utils.logging import logger, log_dist
 from deepspeed_tpu.utils.tree import tree_cast, tree_global_norm, tree_num_params
 
@@ -366,6 +367,11 @@ class Engine:
         # the block: one in-memory record a `train_batch`, with the phases
         # train/place, train/dispatch, train/fence, train/after_step
         self.steptrace = self.telemetry.new_steptrace(time.perf_counter)
+        # ... and the device's side on demand: the fused train step and the
+        # shapes it compiled for, noted by the step that compiled it
+        # (`_note_train_program`), lowered again only if
+        # `steptrace.device_scopes()` is asked
+        self.steptrace.scope_provider = ProgramTable()
         self._program_flops = None   # per-train_batch flops, measured once
         # comm facade stats mirror into this registry: comm/<op>_bytes,
         # comm/<op>_calls, comm/<op>_ms rows (see comm/collectives.py)
@@ -815,9 +821,10 @@ class Engine:
         opt_dev_shardings = self.opt_shardings
         opt_host_shardings = self._host_opt_shardings() if offload_opt else None
 
-        def apply_grads(state, grads, loss):
-            # ZeRO: constrain grads → reduce-scatter (stage>=2) or allreduce layout
-            grads = jax.lax.with_sharding_constraint(grads, grad_shardings)
+        def update(state, grads):
+            """(new target, new optimizer state, gradient norm, finite):
+            unscale, overflow check, norm and clip, the optimizer's update,
+            the masked skip on overflow — the `optimizer` scope."""
             grads = scaler.unscale_grads(grads, state.scaler)
 
             finite = scaler.check_overflow(grads)
@@ -843,6 +850,15 @@ class Engine:
             new_opt = masked_update(new_opt, opt_in, finite)
             if offload_opt:
                 new_opt = jax.device_put(new_opt, opt_host_shardings)
+            return new_target, new_opt, grad_norm, finite
+
+        def apply_grads(state, grads, loss):
+            # ZeRO: constrain grads → reduce-scatter (stage>=2) or allreduce layout
+            # (no scope of its own: the partitioner's collectives carry the
+            # name of the instruction they were made for, a constraint's none)
+            grads = jax.lax.with_sharding_constraint(grads, grad_shardings)
+            with jax.named_scope("optimizer"):
+                new_target, new_opt, grad_norm, finite = update(state, grads)
 
             if keep_master:
                 new_params = tree_cast(new_target, compute_dtype)
@@ -853,7 +869,8 @@ class Engine:
             # re-materialize params in their (replicated or fsdp) layout → all-gather
             new_params = jax.lax.with_sharding_constraint(new_params, param_shardings)
 
-            new_scaler = scaler.update(state.scaler, finite)
+            with jax.named_scope("optimizer"):
+                new_scaler = scaler.update(state.scaler, finite)
             new_step = state.step + jnp.where(finite, 1, 0).astype(jnp.int32)
             rng, _ = jax.random.split(state.rng)
 
@@ -917,19 +934,23 @@ class Engine:
                     if qw:
                         return qc.quantized_all_gather_dim(p, ax, d, group_size)
                     return jax.lax.all_gather(p, ax, axis=d, tiled=True)
-                params = jax.tree_util.tree_map(gather, params, param_specs)
+                with jax.named_scope("zero/param_gather"):
+                    params = jax.tree_util.tree_map(gather, params,
+                                                    param_specs)
             with mesh_mod.constraints_disabled():
                 grads, loss = micro_grad(params, micro_batch, rng, scale_state)
             n = 1
             for a in axes:
                 n *= sizes[a]
-            if qg:
-                # qgZ sums over the domain; grad semantics here are mean
-                grads = jax.tree_util.tree_map(
-                    lambda g: qc.qgz_allreduce(g.astype(jnp.float32), axes,
-                                               group_size) / n, grads)
-            else:
-                grads = jax.lax.pmean(grads, axes)
+            with jax.named_scope("zero/grad_reduce"):
+                if qg:
+                    # qgZ sums over the domain; grad semantics here are mean
+                    grads = jax.tree_util.tree_map(
+                        lambda g: qc.qgz_allreduce(g.astype(jnp.float32),
+                                                   axes, group_size) / n,
+                        grads)
+                else:
+                    grads = jax.lax.pmean(grads, axes)
             loss = jax.lax.pmean(loss, axes)
             return grads, loss
 
@@ -999,7 +1020,9 @@ class Engine:
                         return qc.quantized_all_gather_dim(p, ax, d,
                                                            group_size)
                     return coll.all_gather(p, ax, axis=d, tiled=True)
-                params = jax.tree_util.tree_map(gather, params, param_specs)
+                with jax.named_scope("zero/param_gather"):
+                    params = jax.tree_util.tree_map(gather, params,
+                                                    param_specs)
             with mesh_mod.constraints_disabled():
                 if gas > 1:
                     def scan_body(carry, mb):
@@ -1028,27 +1051,28 @@ class Engine:
                     grads = jax.tree_util.tree_map(
                         lambda g: g.astype(jnp.float32), grads)
             # hierarchical reduce: fast axes in plain fp32, slow axis wired
-            if fast:
-                grads = jax.tree_util.tree_map(
-                    lambda g: coll.psum(g, fast), grads)
-            new_err = err
-            if onebit:
-                err_local = jax.tree_util.tree_map(lambda e: e[0], err)
-                flat_g, treedef = jax.tree_util.tree_flatten(grads)
-                flat_e = jax.tree_util.tree_leaves(err_local)
-                outs = [coll.compressed_all_reduce(g, slow, "onebit", err=e)
-                        for g, e in zip(flat_g, flat_e)]
-                grads = jax.tree_util.tree_unflatten(
-                    treedef, [o[0] for o in outs])
-                new_err = jax.tree_util.tree_unflatten(
-                    treedef, [o[1][None] for o in outs])
-            else:
-                # same 2-hop reduce-scatter + all-gather structure for the
-                # fp32 and int8 wires — the facade byte stats then compare
-                # the ENCODING alone (tests/test_comm_volume.py's wire ratio)
-                grads = jax.tree_util.tree_map(
-                    lambda g: coll.compressed_all_reduce(
-                        g, slow, wire, group_size=group_size), grads)
+            with jax.named_scope("zero/grad_reduce"):
+                if fast:
+                    grads = jax.tree_util.tree_map(
+                        lambda g: coll.psum(g, fast), grads)
+                new_err = err
+                if onebit:
+                    err_local = jax.tree_util.tree_map(lambda e: e[0], err)
+                    flat_g, treedef = jax.tree_util.tree_flatten(grads)
+                    flat_e = jax.tree_util.tree_leaves(err_local)
+                    outs = [coll.compressed_all_reduce(g, slow, "onebit", err=e)
+                            for g, e in zip(flat_g, flat_e)]
+                    grads = jax.tree_util.tree_unflatten(
+                        treedef, [o[0] for o in outs])
+                    new_err = jax.tree_util.tree_unflatten(
+                        treedef, [o[1][None] for o in outs])
+                else:
+                    # same 2-hop reduce-scatter + all-gather structure for the
+                    # fp32 and int8 wires — the facade byte stats then compare
+                    # the ENCODING alone (tests/test_comm_volume.py's wire ratio)
+                    grads = jax.tree_util.tree_map(
+                        lambda g: coll.compressed_all_reduce(
+                            g, slow, wire, group_size=group_size), grads)
             grads = jax.tree_util.tree_map(lambda g: g / n_total, grads)
             loss = jax.lax.pmean(loss, axes)
             return grads, loss, new_err
@@ -1425,9 +1449,23 @@ class Engine:
             self._after_step(metrics, count_micro=True)
             self._maybe_step_moq(batch)
             self._maybe_step_compression()
-        st.end_step(compiles=self._compiled_train_programs() - compiled0)
+        compiles = self._compiled_train_programs() - compiled0
+        if compiles > 0 and placed is not None:
+            self._note_train_program(placed)
+        st.end_step(compiles=compiles)
         self._report_steps(batch, placed)
         return metrics["loss"]
+
+    def _note_train_program(self, placed):
+        """The step that compiled the fused train step hands the recorder
+        the program and the SHAPES it was compiled for (the state's and the
+        placed batch's, shardings kept): what `steptrace.device_scopes()`
+        lowers again when somebody asks."""
+        args = (self.state, placed)
+        if self._comm_err is not None:
+            args += (self._comm_err,)
+        self.steptrace.scope_provider.add("train_step", self._train_step,
+                                          args)
 
     @contextlib.contextmanager
     def _oom_forensics(self):

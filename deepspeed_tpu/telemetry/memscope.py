@@ -191,32 +191,21 @@ def aot_memory_analysis(fn, *args) -> Dict[str, int]:
 
     Goes through the AOT ``lower().compile()`` path with abstract
     `ShapeDtypeStruct`s (shardings preserved when the example carries
-    them), so nothing executes, no buffer materializes, and — crucial for
-    the serving compile contract — the jit CALL cache is untouched:
+    them; `device_scopes.aot_compile`, the one way to a step program's
+    compiled object), so nothing executes, no buffer materializes, and —
+    crucial for the serving compile contract — the jit CALL cache is untouched:
     ``compile_stats()`` reads the same before and after. `fn` may be the
     compile watchdog's `_WatchedProgram` wrapper (unwrapped here). Returns
     {} when the backend exposes no analysis. One extra XLA compile per
     distinct (fn, shapes) — callers cache the result.
     """
-    import jax
-
-    if not hasattr(fn, "lower"):
-        fn = getattr(fn, "fn", fn)          # _WatchedProgram passthrough
-    if not hasattr(fn, "lower"):
-        return {}
-
-    def sds(x):
-        try:
-            return jax.ShapeDtypeStruct(x.shape, x.dtype,
-                                        sharding=getattr(x, "sharding", None))
-        except Exception:
-            import numpy as np
-            a = np.asarray(x)
-            return jax.ShapeDtypeStruct(a.shape, a.dtype)
+    from deepspeed_tpu.telemetry.device_scopes import aot_compile
 
     try:
-        abstract = jax.tree_util.tree_map(sds, args)
-        ma = fn.lower(*abstract).compile().memory_analysis()
+        compiled = aot_compile(fn, *args)
+        if compiled is None:
+            return {}
+        ma = compiled.memory_analysis()
     except Exception as e:
         logger.warning(f"memscope: memory_analysis unavailable ({e})")
         return {}

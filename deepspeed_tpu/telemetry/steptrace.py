@@ -32,8 +32,12 @@ step's phases say how long the host WAITED; the rest of the step is its work.
 
 Records are plain tuples of numbers and strings in bounded rings, selected by
 stamp (`records(since, until)`, `requests(since, until)`, `calls(since,
-until)`), so any window of a run can be read after the fact. A recorder
-holds the rings and never the engine: `latest("serving")` hands the rings of
+until)`), so any window of a run can be read after the fact. The device's
+side of a step is read the same way, after the fact and on demand:
+`device_scopes()` gives, for every instruction of the engine's compiled step
+programs, the `jax.named_scope` the program put it under
+(`telemetry/device_scopes.py`), which a device trace's events are joined to.
+A recorder holds the rings and never the engine: `latest("serving")` hands the rings of
 an engine that is gone to whoever asks in the same process (the benchmark's
 readers do).
 """
@@ -228,6 +232,10 @@ class StepTrace:
         self.facts = {}             # what holds for every step of the run
                                     # (training: "held_residuals", the plan
                                     # of what the blocks keep for the backward)
+        self.scope_provider = None  # the engine's `device_scopes.
+                                    # ProgramTable`: its jitted step programs
+                                    # and the abstract shapes of their
+                                    # arguments, called by `device_scopes()`
         self._steps = collections.deque(maxlen=self.capacity)
         self._requests = collections.deque(maxlen=self.capacity)
         self._calls = collections.deque(maxlen=self.capacity)
@@ -335,6 +343,18 @@ class StepTrace:
         return rec
 
     # ---- reading -------------------------------------------------------
+
+    def device_scopes(self):
+        """The `device_scopes.ScopeRow`s of every step program the engine
+        built: (program, instruction name, opcode, custom-call target,
+        result type, scope, backward, straddles), read from each program's
+        own compiled text. The first call lowers and compiles the programs
+        through the AOT path (the persistent compilation cache serves it
+        where it holds them; the jit call caches are not touched) and the
+        provider keeps the rows; a run that never asks pays nothing. () for
+        an engine that handed over no program (a streamed one: its steps
+        are host loops)."""
+        return self.scope_provider() if self.scope_provider else ()
 
     def records(self, since=None, until=None):
         """Step records with `since < t_end <= until` (None = unbounded)."""

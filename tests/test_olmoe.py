@@ -373,16 +373,19 @@ def test_benchmark_holds_the_cells_files():
                 if cell["name"] in m.get("workloads", [cell["name"]])]
     assert sorted(reported) == ["serve_tokens_per_s", "setup_s"]
     layered = [m for m in bench["per_layer"]
-               if m.get("workloads") == [cell["name"]]]
+               if cell["name"] in m.get("workloads", ())]
     # PR 27's 15, PR 28's live-step share, PR 30's two of the prefill kernel,
     # PR 33's two of the mixed step (its program's share, the chunks riding),
-    # PR 35's share of calls dispatched behind the call in flight
-    assert len(layered) == 21
+    # PR 35's share of calls dispatched behind the call in flight: 21 the
+    # cell has to itself today, and as many at least once the `.generate`
+    # copies fold into entries it shares with the other backlog cells
+    assert len(layered) >= 21
     for metric in layered:
         with open(os.path.join(BENCH, "layer_metrics",
                                metric["name"] + ".json")) as f:
             spec = json.load(f)
-        assert spec["workloads"] == [cell["name"]]
+        if "workloads" in spec:
+            assert spec["workloads"] == metric["workloads"]
         assert os.path.exists(os.path.join(BENCH, "readers",
                                            spec["reader"] + ".py"))
     with open(os.path.join(BENCH, "traffic", "generate_backlog.json")) as f:
