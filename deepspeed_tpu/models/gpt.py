@@ -450,22 +450,69 @@ def _window_mask(q_positions, k_positions, window):
     return dist < window
 
 
+@partial(jax.custom_vjp, nondiff_argnums=(2, 3))
 def _rope(x, positions, rotary_dims, theta=10000.0):
     """Rotary position embedding over the first `rotary_dims` of the head dim.
-    x: [B, T, H, hd]; positions: [B, T]."""
+    x: [B, T, H, hd]; positions: [B, T].
+
+    The pairing is (even, odd) INTERLEAVED: columns (2i, 2i+1) turn by
+    `positions * theta**(-2i / rotary_dims)` (HF's rotate-half order is
+    re-ordered to it at import, `inference/adapters.py::_unpermute_rope_rows`;
+    `benchmark/references/decoder.py::_rope` pairs the same way). It is ONE
+    multiply-add over the whole head:
+
+        rope(x) = x * C + swap(x) * S
+        C[j] = cos(angle[j // 2]) for j < rd, 1 beyond
+        S[j] = -sin(..) for even j < rd, +sin(..) for odd j < rd, 0 beyond
+        swap(x)[j] = x[j ^ 1] for j < rd
+
+    `swap` is a PRODUCT with a constant 0/1 matrix in x's dtype with a float32
+    result: exact for finite x (an output is one input times 1, plus zeros).
+    The products and the sum are float32 and round to x's dtype once. An
+    `inf` or `nan` in one column of a head reaches every column of that head
+    (of that row alone) as `nan` (`inf * 0`); both are non-finite to whoever
+    checks (the loss scaler's overflow test reads `isfinite`).
+
+    The transpose of a rotation turns the other way, so the backward pass IS
+    this function at `-positions` (a `custom_vjp`), rounded once like the
+    forward. Reverse mode passes it any number of times; forward mode
+    (`jax.jvp`) does not pass a `custom_vjp`."""
+    return _rotate(x, positions, rotary_dims, theta)
+
+
+def _rotate(x, positions, rd, theta):
+    """`_rope`'s multiply-add (its docstring has the formula)."""
     hd = x.shape[-1]
-    rd = rotary_dims
-    freqs = 1.0 / (theta**(jnp.arange(0, rd, 2, dtype=jnp.float32) / rd))
-    angles = positions[..., None].astype(jnp.float32) * freqs  # [B,T,rd/2]
+    lane = jnp.arange(hd, dtype=jnp.int32)
+    freqs = 1.0 / (theta**((lane // 2 * 2).astype(jnp.float32) / rd))
+    # a lane beyond rd turns by the angle 0: C = 1 and S = 0 there, exactly
+    angles = positions[..., None].astype(jnp.float32) * jnp.where(
+        lane < rd, freqs, 0.0)                                  # [B,T,hd]
     cos = jnp.cos(angles)[:, :, None, :]
-    sin = jnp.sin(angles)[:, :, None, :]
-    x_rot, x_pass = x[..., :rd], x[..., rd:]
-    x1, x2 = x_rot[..., ::2], x_rot[..., 1::2]
-    out1 = x1 * cos - x2 * sin
-    out2 = x1 * sin + x2 * cos
-    rotated = jnp.stack([out1, out2], axis=-1).reshape(x_rot.shape)
-    return jnp.concatenate([rotated, x_pass], axis=-1).astype(x.dtype) if rd < hd \
-        else rotated.astype(x.dtype)
+    sin = (jnp.where(lane % 2 == 0, -1.0, 1.0) * jnp.sin(angles))[:, :, None, :]
+    pair = np.arange(rd)
+    swap = np.zeros((hd, hd), np.float32)
+    swap[pair ^ 1, pair] = 1.0
+    # bfloat16 goes through the MXU as it is, in one pass; a wider mantissa
+    # is kept whole only at the highest precision
+    wide = x.dtype != jnp.bfloat16
+    swapped = jnp.matmul(
+        x, jnp.asarray(swap, x.dtype), preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST if wide else None)
+    kept, turned = x * cos, swapped * sin
+    if wide:
+        # float32's last bit shows whether the compiler contracted a product
+        # into the sum, which XLA's CPU backend does or not by what surrounds
+        # the call: each product is rounded where it is made, so every
+        # program that rotates the same rows writes the same bits
+        kept, turned = (jax.lax.reduce_precision(t, 8, 23)
+                        for t in (kept, turned))
+    return (kept + turned).astype(x.dtype)
+
+
+_rope.defvjp(
+    lambda x, positions, rd, theta: (_rotate(x, positions, rd, theta), positions),
+    lambda rd, theta, positions, g: (_rotate(g, -positions, rd, theta), None))
 
 
 # What a block's backward reads of its forward is named where it is made

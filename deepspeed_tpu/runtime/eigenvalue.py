@@ -2,7 +2,7 @@
 
 Reference: `runtime/eigenvalue.py:1` — per-layer power iteration using repeated
 autograd passes, feeding the compression scheduler's quantization period.
-TPU-native: the Hessian-vector product is a single `jax.jvp`-of-`jax.grad`
+TPU-native: the Hessian-vector product is a single `jax.grad`-of-`jax.grad`
 composition inside one jitted loop (`lax.while_loop` with a tolerance), so the
 whole estimation compiles to one XLA program instead of N python-side backward
 passes.
@@ -35,13 +35,26 @@ class Eigenvalue:
                                        stability=self.stability, seed=seed)
 
 
+def hessian_vector_product(grad_fn, params, v):
+    """H v as the gradient of <grad L, v>: reverse over reverse, because a
+    `custom_vjp` in the loss (the rotation, the chunked loss) passes reverse
+    mode any number of times and forward mode (`jax.jvp` of the gradient)
+    never."""
+    def along(p):
+        return sum(jnp.vdot(t, g.astype(t.dtype)) for t, g in zip(
+            jax.tree_util.tree_leaves(v),
+            jax.tree_util.tree_leaves(grad_fn(p))))
+
+    return jax.grad(along)(params)
+
+
 @functools.partial(jax.jit, static_argnums=(0, 3, 4, 5))
 def power_iteration_hessian(loss_fn, params, batch, max_iter=100, tol=1e-2,
                             stability=1e-6, seed=0):
     grad_fn = jax.grad(lambda p: loss_fn(p, batch))
 
     def hvp(v):
-        return jax.jvp(grad_fn, (params,), (v,))[1]
+        return hessian_vector_product(grad_fn, params, v)
 
     leaves, treedef = jax.tree_util.tree_flatten(params)
     key = jax.random.PRNGKey(seed)

@@ -26,6 +26,7 @@ from typing import Optional, Sequence
 import jax
 import jax.numpy as jnp
 
+from deepspeed_tpu.runtime.eigenvalue import hessian_vector_product
 from deepspeed_tpu.utils.logging import log_dist
 
 TWO_D_PARAMS = 6  # reference quantize.py:17 — schedule granularity constant
@@ -122,7 +123,7 @@ def block_eigenvalues(loss_fn, params, batch, max_iter: int = 100,
         # is supported on layer i only), and returning just that row keeps the
         # mapped output at [L, ...] — one model's worth — instead of an
         # [L, L, ...] stack of masked copies.
-        hv = jax.jvp(grad_fn, (blocks,), (layer_mask(i, v),))[1]
+        hv = hessian_vector_product(grad_fn, blocks, layer_mask(i, v))
         return jax.tree_util.tree_map(lambda l: l[i], hv)
 
     def norms(v):

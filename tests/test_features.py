@@ -178,15 +178,20 @@ class TestCompression:
         evs = block_eigenvalues(loss_fn, params, batch=None, max_iter=50)
         np.testing.assert_allclose(evs, [2.0, 6.0, 1.0], rtol=1e-3)
 
-    def test_moq_engine_end_to_end(self):
+    @pytest.mark.parametrize("rotary", [False, True],
+                             ids=["learned_positions", "rotary"])
+    def test_moq_engine_end_to_end(self, rotary):
         """MoQ through the engine: eigenvalue-driven schedule advances, bits
         drop toward target, training still converges, and the retraced step
-        keeps working (reference engine.py:1769-1780 + 2116-2127)."""
+        keeps working (reference engine.py:1769-1780 + 2116-2127). A rotary
+        model's curvature is read too: the rotation is a `custom_vjp`, which
+        the estimate's reverse-over-reverse product passes."""
         _reset()
         from deepspeed_tpu.compression import init_compression
         from deepspeed_tpu.models.gpt import GPTConfig, make_gpt_model
         gcfg = GPTConfig(n_layer=2, n_head=2, d_model=32, max_seq_len=16,
-                         vocab_size=64, dtype=jnp.float32, remat=False)
+                         vocab_size=64, dtype=jnp.float32, remat=False,
+                         use_rotary=rotary, rotary_pct=0.25)
         cfg = {
             "train_micro_batch_size_per_gpu": 4,
             "optimizer": {"type": "Adam", "params": {"lr": 1e-3}},
