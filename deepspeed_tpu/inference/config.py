@@ -173,6 +173,12 @@ class ServingConfig(ConfigModel):
                                   # the cost of K-step retirement/admission
                                   # granularity (a sequence finishing
                                   # mid-window wastes the window's tail)
+    blocks_per_call: int = 1      # a model that generates by diffusion over
+                                  # blocks (`DecodeModelSpec.generator`): the
+                                  # blocks of `block_length` tokens a decode
+                                  # call commits a slot — its window, in
+                                  # blocks (`decode_steps_per_sync` is the
+                                  # other generators')
     enable_prefix_caching: bool = False  # automatic prefix caching
                                   # (inference/prefix_cache.py): full prompt
                                   # blocks are content-hashed and reused

@@ -78,6 +78,86 @@ def sample_logits(logits, rng, greedy=True, temperature=1.0, top_k=0,
     return jax.random.categorical(rng, logits, axis=-1).astype(jnp.int32)
 
 
+# what a block-diffusion call counts beside the model's own counters, in this
+# order (int32[4], summed over a call's forwards and read back with its
+# tokens): forwards whose rows the unmask rule read, forwards that wrote a
+# block's K/V for good, (slot, row) pairs the rule unmasked, (slot, block)
+# pairs committed for live slots
+BLOCK_DIFFUSION_COUNTERS = ("denoise_forwards", "commit_forwards",
+                            "rows_unmasked", "blocks_committed")
+
+
+@dataclasses.dataclass(frozen=True)
+class BlockDiffusion:
+    """The generator of a model that generates by DIFFUSION OVER BLOCKS (the
+    SDAR family, `models/sdar_moe.py`), as data on its `DecodeModelSpec`.
+
+    Under the block-causal mask (`GPTConfig.block_length`) a block of
+    `block_length` positions is generated together: it starts as mask tokens
+    (a prompt's last `L mod B` tokens open the first one as clean tokens),
+    up to `denoising_steps` DENOISE forwards of its B rows each unmask some
+    rows (`unmask`), and when no mask is left ONE COMMIT forward of the clean
+    tokens writes the block's K/V. A masked row's own logits predict its
+    token (no shift). Greedy: x0 = argmax, confidence = softmax(logits)[x0],
+    the mask token itself never drawn."""
+    block_length: int
+    mask_token_id: int
+    denoising_steps: int = 0        # S: quality against speed; 0 =
+                                    # `block_length` (a row a step): `steps`
+    remasking: str = "low_confidence_dynamic"   # the one rule built
+    confidence_threshold: float = 0.9
+
+    def __post_init__(self):
+        if self.remasking != "low_confidence_dynamic":
+            raise ValueError(f"remasking rule {self.remasking!r} is not "
+                             f"built (only 'low_confidence_dynamic' is)")
+
+    @property
+    def steps(self):
+        """S: the most denoise forwards a block takes before its commit."""
+        return self.denoising_steps or self.block_length
+
+    def transfers(self, steps):
+        """n_s for s = 0 .. steps - 1: the rows a step unmasks at least,
+        `B // S` and one more in the first `B mod S` steps."""
+        B = self.block_length
+        return [B // steps + (s < B % steps) for s in range(steps)]
+
+    def unmask(self, logits, x, masked, n):
+        """One denoise step on the device: `logits` [S * B, V] of the
+        blocks' rows, slot after slot (FLAT: a [S, B, V] array has B on the
+        sublanes, and every pass over it pays for the padding), `x` [S, B]
+        the blocks' tokens, `masked` [S, B] the rows still masked,
+        `n` (traced scalar) this step's n_s. Every masked row takes x0 and
+        its confidence c; unmasked are, a slot: every masked row with c >
+        `confidence_threshold` if they are at least n, else the n most
+        confident masked rows (ties: the earlier row; fewer than n masked:
+        all of them). Returns (x, masked, rows unmasked [S])."""
+        with jax.named_scope("denoise/confidence"):
+            logits = logits.astype(jnp.float32)
+            ids = jax.lax.broadcasted_iota(jnp.int32, logits.shape, 1)
+            logits = jnp.where(ids == self.mask_token_id, -jnp.inf, logits)
+            top = jnp.max(logits, axis=-1)
+            x0 = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            # softmax(logits)[argmax] = 1 / sum exp(logits - max)
+            conf = 1.0 / jnp.sum(jnp.exp(logits - top[:, None]), axis=-1)
+            x0, conf = x0.reshape(x.shape), conf.reshape(x.shape)
+        with jax.named_scope("denoise/unmask"):
+            conf = jnp.where(masked, conf, -jnp.inf)
+            # a row's rank by confidence among its slot's rows, ties to the
+            # earlier row (B is a handful: the B x B comparison is nothing)
+            idx = jnp.arange(x.shape[1])
+            ahead = (conf[:, None, :] > conf[:, :, None]) | (
+                (conf[:, None, :] == conf[:, :, None])
+                & (idx[None, None, :] < idx[None, :, None]))
+            move = masked & (jnp.sum(ahead, axis=-1) < n)
+            high = conf > self.confidence_threshold
+            enough = jnp.sum(high, axis=-1, keepdims=True) >= n
+            move = jnp.where(enough, high, move)
+            return (jnp.where(move, x0, x), masked & ~move,
+                    jnp.sum(move, axis=-1, dtype=jnp.int32))
+
+
 @dataclasses.dataclass
 class DecodeModelSpec:
     prefill_fn: Callable       # (params, tokens[B,T], cache, pad_mask) -> (logits[B,T,V], cache)
@@ -156,6 +236,21 @@ class DecodeModelSpec:
     # `window_blocks`, and passes every paged program its tables as the PAIR
     # (full tables [B, nb], ring tables [B, nbw]). None: one kind, one table.
     paged_cache_kinds: Optional[Callable] = None
+    # a model that generates by diffusion over blocks: the generator
+    # (`BlockDiffusion`: block length, mask id, steps, rule, threshold), and
+    #   denoise_paged_fn(params, tokens[S,B], pos[S], pool, block_tables[S,nb])
+    #       -> (logits[S*B,V], pool[, counts])
+    #     ONE forward of a block a slot, denoise or commit alike: writes the B
+    #     rows' k/v at pos..pos+B-1 (a later forward of the same block writes
+    #     over them) and attends [0, pos + B), every row's logits back, slot
+    #     after slot.
+    #     `mixed_paged_fn` then takes `token` [S, B] and gives logits
+    #     [G + S*B, V]. The scheduler builds its decode and mixed programs
+    #     from these (`step_programs.py`); a decode call commits whole blocks
+    #     and takes no token from the call before it. None: one token a slot
+    #     a forward.
+    generator: Optional[BlockDiffusion] = None
+    denoise_paged_fn: Optional[Callable] = None
     # cache-identity fingerprint for the prefix cache's hash chain
     # (inference/prefix_cache.py): every arch field that changes the KV
     # VALUES written for a given token stream must be folded in, so two
@@ -290,8 +385,23 @@ class InferenceEngine:
         self._cache_entry = (key, cache)
         return cache
 
+    def _refuse_for_generator(self, entry):
+        """The contiguous cache's entries run `prefill_fn` / `decode_fn`:
+        the causal mask, one token a forward. For a model that generates by
+        diffusion over blocks (`DecodeModelSpec.generator`) that is another
+        model's answer, so they refuse by name (`ServingEngine` refuses what
+        its generator cannot have the same way)."""
+        gen = getattr(self.model_spec, "generator", None)
+        if gen is not None:
+            raise ValueError(
+                f"model spec '{self.model_spec.name}' generates by diffusion "
+                f"over blocks of {gen.block_length}: {entry} on the "
+                f"contiguous cache is not built for it — it runs the causal "
+                f"mask, a token a forward; serve it through `.serving()`")
+
     def forward(self, tokens, cache=None, pad_mask=None):
         """Prefill forward (logits for a full sequence)."""
+        self._refuse_for_generator("forward()")
         tokens = jnp.asarray(tokens)
         if cache is None:
             cache = self._get_cache(
@@ -381,6 +491,7 @@ class InferenceEngine:
         `eos_token_id` (default: the model spec's) — the eos is kept, later
         slots are `pad_token_id`.
         """
+        self._refuse_for_generator("generate()")
         if self._generate_jit is None:
             self._generate_jit = self._build_generate()
         if isinstance(tokens, (list, tuple)) and tokens and np.ndim(tokens[0]) == 1 \

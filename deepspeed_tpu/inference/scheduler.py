@@ -41,6 +41,17 @@ Every such call has a record of its own in the step timeline's call ring
 (`telemetry/steptrace.py::CallRecord`): opened where it goes out
 (`_dispatching`), closed where it is read (`_read_back`).
 
+A model that generates by DIFFUSION OVER BLOCKS (`DecodeModelSpec.generator`)
+runs through the same loop with another kind of call: `decode_step` /
+`mixed_step` commit `blocks_per_call` whole blocks of B tokens a slot through
+denoise + commit forwards of B rows a slot, all slots block-synchronous, so
+the host still books `pos += window` at dispatch; call k+1 takes NOTHING from
+call k (a slot's first block is mask ids, or a new request's prompt tail:
+`_block_input`); a prompt's last chunk samples no first token; and what the
+call record and `stats()` count tells forwards and rows apart from tokens.
+Everything of it stands behind `self.generator`; docs/inference.md says what
+is refused under it.
+
 Compile accounting is first-class: `compile_stats()` reads the jit caches,
 and the serving tests assert <= 1 compile per bucket across any trace.
 
@@ -178,10 +189,10 @@ class _Call:
     request's life (`_vacate` puts a new one in its place), so a row here
     can only ever reach the request it was sampled for."""
     __slots__ = ("id", "out", "prev", "mixed", "win", "rows", "firsts",
-                 "chunks", "riding", "leaving")
+                 "chunks", "riding", "leaving", "skip", "walk")
 
     def __init__(self, id, out, prev, mixed, win, rows, firsts, chunks,
-                 riding):
+                 riding, skip=None, walk=None):
         self.id = id            # what a slot's `feed` names (a number, not
                                 # the call: a slot holds no call alive) and
                                 # its `steptrace.CallRecord`, which has its
@@ -199,6 +210,12 @@ class _Call:
                                 # dispatched before it
         self.riding = riding    # (request, start) of the chunks that rode
         self.leaving = []       # requests that gave up their slot at dispatch
+        self.skip = skip or {}  # a block-diffusion call: slot index -> the
+                                # prompt tokens that open its row (the clean
+                                # tokens of a request's first block)
+        self.walk = walk        # ... and `_decode_walk`'s counts of ONE
+                                # forward of each of its blocks: booked at the
+                                # read-back, by the forwards the call took
 
     def awaited(self):
         """Does anything wait for this call's read-back: a token a live
@@ -323,6 +340,27 @@ class ServingEngine:
         # speculative decoding: the verify step REPLACES the decode step
         # (and its window) when a drafter is configured
         self.spec_on = str(scfg.spec_decode.drafter or "off") != "off"
+        # a model that generates by diffusion over blocks
+        # (`DecodeModelSpec.generator`): a decode call commits
+        # `blocks_per_call` whole blocks of B tokens a slot, so positions
+        # advance by `window` = blocks_per_call * B a call, and the call's
+        # chunks ride its `ride_window` = blocks_per_call * (steps + 1)
+        # forwards. What this generator cannot have yet is refused HERE, by
+        # name, as the pools of two kinds refuse theirs
+        self.generator = None if self.streamed \
+            else getattr(spec, "generator", None)
+        self.denoising_steps = 0
+        if self.generator is not None:
+            self._refuse_for_generator(spec, scfg, engine.config)
+            B = self.generator.block_length
+            self.blocks_per_call = max(1, int(scfg.blocks_per_call))
+            self.denoising_steps = self.generator.steps
+            self.window = self.blocks_per_call * B
+            names = step_programs.step_counter_names(spec)
+            self._forward_counters = [names.index(name) for name in (
+                "denoise_forwards", "commit_forwards")]
+        self.ride_window = self.window if self.generator is None \
+            else self.blocks_per_call * (self.denoising_steps + 1)
         if self.streamed:
             # streamed-mode envelope: a K-step jitted window or a verify
             # chunk cannot host a per-layer Python walk — both are refused
@@ -543,11 +581,14 @@ class ServingEngine:
         # the tokens (a program whose tokens are not read — a prompt's
         # earlier chunks — parks its counts until the next read-back),
         # summed here and put on the step ring
-        self.step_counter_names = tuple(
-            getattr(engine.model_spec, "step_counters", None) or ())
+        self.step_counter_names = () if self.streamed \
+            else step_programs.step_counter_names(engine.model_spec)
         self.step_counter_totals = np.zeros(len(self.step_counter_names),
                                             np.int64)
         self._step_counts = np.zeros_like(self.step_counter_totals)
+        self._call_counts = ()      # of the call read last (`_fetch`)
+        self._walk_read = np.zeros(5)   # a block-diffusion call's walk,
+                                        # booked when it is read (`_read`)
         self._parked_counts = []
         # the loop runs one call deep (`_step_impl`): the newest dispatched
         # call whose tokens are unread, the completions a read-back outside
@@ -570,13 +611,16 @@ class ServingEngine:
             # the chunks a token of a mixed call takes: what the settings
             # already say (at most `prefill_budget` chunks a step, `window`
             # tokens a call), where the model's mixed program takes a group
-            group = -(-self.prefill_budget // self.window) \
+            group = -(-self.prefill_budget // self.ride_window) \
                 if getattr(spec, "mixed_chunk_groups", False) else 1
+            more = {} if self.generator is None else dict(
+                blocks_per_call=self.blocks_per_call,
+                denoising_steps=self.denoising_steps)
             self.programs = step_programs.build_resident(
                 spec, engine.config, engine._fn_transform,
-                window=self.window, max_slots=self.max_slots,
+                window=self.ride_window, max_slots=self.max_slots,
                 chunk=self.chunk, spec_on=self.spec_on, draft_k=self.draft_k,
-                replicated=self._replicated, watchdog=wd, group=group)
+                replicated=self._replicated, watchdog=wd, group=group, **more)
         # the device's side of the timeline, on demand: the recorder is
         # handed the built programs (`mixed_step` too, before any chunk has
         # ridden) and the SHAPES of their arguments, and lowers nothing
@@ -676,6 +720,57 @@ class ServingEngine:
                  f"prefill_chunk={self.chunk} weights={self.weight_quant}",
                  ranks=[0])
 
+    def _refuse_for_generator(self, spec, scfg, config):
+        """What a block-diffusion generator cannot have yet, each with its
+        reason (the engine says it at build time, never serves it wrong)."""
+        gen = self.generator
+        B = gen.block_length
+        kvd = str(scfg.quantization.kv_cache_dtype or "") \
+            or str(config.kv_cache_dtype)
+        asked = {
+            "spec_decode": (
+                self.spec_on,
+                "a verify chunk is causal inside and scores drafts of one "
+                "token a forward; a block's rows are generated together"),
+            "kv_cache_dtype int8": (
+                kvd == "int8",
+                "a block's rows are written once a denoise forward and read "
+                "by the walk at 8 x B query rows a KV head; the quantizing "
+                "write and the dequantizing walk are not built for it"),
+            "enable_prefix_caching": (
+                scfg.enable_prefix_caching,
+                "a block registers when its prompt chunk is dispatched, and "
+                "the block a prompt ends in is committed later by the decode "
+                "call that finishes it"),
+            "degradation": (
+                scfg.degradation.enabled,
+                "the ladder's window-shrink rung runs a one-token decode "
+                "program, and a call commits whole blocks"),
+            "sampling (greedy false)": (
+                not config.greedy,
+                "confidence is the probability of the argmax; the sampled "
+                "variants of the rule are not built"),
+            f"prefill_chunk {self.chunk}": (
+                self.chunk % B or self.block_size % B,
+                f"chunks and pool blocks hold whole blocks of {B}"),
+            "mixed_paged_fn / denoise_paged_fn": (
+                spec.denoise_paged_fn is None,
+                "the generator's forwards are the model's "
+                "`denoise_paged_fn`")}
+        for what, (wanted, why) in asked.items():
+            if wanted:
+                raise ValueError(
+                    f"model spec '{spec.name}' generates by diffusion over "
+                    f"blocks of {B}: {what} is not built for it — {why}")
+
+    def _whole(self, prompt_len):
+        """The prompt tokens that PREFILL covers: all of them, or for a
+        block-diffusion generator the prompt's whole blocks (the `L mod B`
+        tokens left open the first generated block as clean tokens)."""
+        if self.generator is None:
+            return prompt_len
+        return prompt_len - prompt_len % self.generator.block_length
+
     def _next_rng(self):
         if self.config.greedy:
             return self._rng                        # unused by the sampler
@@ -702,7 +797,7 @@ class ServingEngine:
         prompt_len = int(prompt_len)
         max_new = int(max_new)
         padded = (int(padded_prompt) if padded_prompt else
-                  -(-prompt_len // self.chunk) * self.chunk)
+                  -(-self._whole(prompt_len) // self.chunk) * self.chunk)
         if prompt_len < 1:
             raise InadmissibleRequestError(f"request {uid}: empty prompt")
         if max_new < 1:
@@ -710,6 +805,11 @@ class ServingEngine:
                 f"request {uid}: max_new_tokens < 1")
         eff_new = 1 if prefill_only else max_new
         eff_window = 1 if prefill_only else self.window
+        if self.generator is not None:
+            # decode calls write whole windows from the prompt's last whole
+            # block on: the prompt's tail and `max_new` tokens, rounded up
+            eff_new += prompt_len - self._whole(prompt_len) + 1
+            prompt_len = self._whole(prompt_len)
         # a verify step always writes its full k-draft overhang, so spec
         # decode sizes past the window math (which it replaces); a
         # prefill-only slot never verifies here
@@ -786,7 +886,7 @@ class ServingEngine:
             self._refuse_transplant()
         prompt = np.asarray(request.tokens, np.int32).reshape(-1)
         prompt_len = int(prompt.shape[0])
-        padded = -(-prompt_len // self.chunk) * self.chunk
+        padded = -(-self._whole(prompt_len) // self.chunk) * self.chunk
         need = self.check_admissible(prompt_len, request.max_new_tokens,
                                      prefill_only=prefill_only,
                                      uid=request.uid)
@@ -813,6 +913,13 @@ class ServingEngine:
                            t_arrive, prefill_only, trace, deadline_at))
 
     def _refuse_transplant(self):
+        if self.generator is not None:
+            raise ValueError(
+                f"model spec '{self.engine.model_spec.name}' generates by "
+                f"diffusion over blocks: block transplant (prefill-only "
+                f"slots, handoff) is not built for it — a prompt's last "
+                f"block is not committed when its prefill ends, and no "
+                f"first token is sampled to hand over")
         if self.ring_tables is not None:
             what = "a layer's recurrent state" if self.state_kind is not None \
                 else "a window layer's ring"
@@ -918,7 +1025,11 @@ class ServingEngine:
             slot.hashes = hashes
             slot.reg = len(hit)
             slot.cached = len(hit)
-            slot.pos = prompt_len
+            slot.pos = self._whole(prompt_len)
+            if not padded:
+                # (a block-diffusion prompt shorter than a block: nothing to
+                # prefill, its tokens open the first generated block)
+                slot.state = _DECODE
             slot.emitted = []
             slot.prefill_only = prefill_only
             slot.deadline = deadline_at
@@ -1606,19 +1717,23 @@ class ServingEngine:
                 pos = np.zeros((self.max_slots,), np.int32)
                 tables = np.full_like(self.tables, TRASH_BLOCK)
                 for s in dec:
+                    pos[s.idx] = s.pos
+                    tables[s.idx] = self.tables[s.idx]
+                    if self.generator is not None:
+                        continue    # (`_block_input` builds its rows)
                     if prior is not None and s.feed is not None \
                             and s.feed[0] == prior.id:
                         src[s.idx] = s.feed[1]      # still on the device
                     else:
                         tok[s.idx] = s.emitted[-1]
-                    pos[s.idx] = s.pos
-                    tables[s.idx] = self.tables[s.idx]
+                feed = (src, tok) if self.generator is None \
+                    else self._block_input(dec)
             spec_active = self.spec_on and not (
                 self.pressure is not None and self.pressure.spec_disabled)
             if spec_active:
                 self._verify_decode(dec, tok, pos, tables, finished)
             else:
-                walk, rode = self._launch(dec, riding, params, (src, tok),
+                walk, rode = self._launch(dec, riding, params, feed,
                                           pos, tables, finished)
                 reach = [a + b for a, b in zip(reach, rode)]
         if not (overlap and dec) or not self._pending.awaited():
@@ -1662,6 +1777,10 @@ class ServingEngine:
             counters = tuple(int(v) for v in self._step_counts)
             self.step_counter_totals += self._step_counts
             self._step_counts[:] = 0
+        if self.generator is not None:
+            # the walks of the calls READ in this step, as the counters are
+            walk = tuple(int(round(v)) for v in self._walk_read)
+            self._walk_read[:] = 0
         st.end_step(counters=counters,
                     decode_live_blocks=walk[0], decode_grid_steps=walk[1],
                     prefill_live_blocks=reach[0],
@@ -1762,16 +1881,17 @@ class ServingEngine:
                 or self.engine.mesh.size != 1 \
                 or (self.pressure is not None and self.pressure.level):
             return 0
-        return min(due, self.window * self.programs.group)
+        return min(due, self.ride_window * self.programs.group)
 
     def _chunk_input(self, slot, start):
         """The chunk of `slot`'s prompt at `start`: (tokens [1, chunk], index
         of the row whose logits count, whether it is the prompt's last)."""
         chunk = np.zeros((1, self.chunk), np.int32)
-        seg = slot.prompt[start:start + self.chunk]
+        whole = self._whole(slot.prompt_len)
+        seg = slot.prompt[start:min(start + self.chunk, whole)]
         chunk[0, :len(seg)] = seg
         final = start + self.chunk >= slot.padded_len
-        last = (slot.prompt_len - 1 - start) if final else self.chunk - 1
+        last = (whole - 1 - start) if final else self.chunk - 1
         return chunk, last, final
 
     def _chunk_written(self, slot, start, program):
@@ -1843,7 +1963,8 @@ class ServingEngine:
         # the first sampled token is EOS or max_new == 1 — the
         # router then sees a normal completion from this engine
         slot.state = _HANDOFF if slot.prefill_only else _DECODE
-        self._emit(slot, tok, finished)
+        if self.generator is None:  # (a block generator's chunk samples none)
+            self._emit(slot, tok, finished)
 
     def _prefill_chunk(self, slot, start, params, finished):
         """One prefill chunk of `slot` (its prompt from `start`) as a call of
@@ -1855,7 +1976,8 @@ class ServingEngine:
         st = self.steptrace
         ctx = slot.trace                      # _emit may retire the slot
         chunk, last, final = self._chunk_input(slot, start)
-        with (self._dispatching("serving/prefill_chunk", "prefill", firsts=1,
+        with (self._dispatching("serving/prefill_chunk", "prefill",
+                                firsts=int(self.generator is None),
                                 chunks=len(self._unread_chunks) + 1)
               if final else self._phase("serving/prefill_chunk")) as ph:
             st.dispatched()
@@ -1899,6 +2021,26 @@ class ServingEngine:
                                tid=self.trace_tid, attrs=attrs)
         return reach
 
+    def _block_input(self, dec):
+        """A block-diffusion call's tokens for the slots `dec`: (`tok`
+        [S, B] — a slot's first block of the call: mask ids where it goes on
+        generating, its prompt's last `L mod B` tokens before mask ids where
+        it begins, and no mask id in the row of a slot that is not in the
+        call —, slot index -> those prompt tokens' count). The host knows
+        all of it at dispatch: nothing is taken from the call in flight."""
+        gen = self.generator
+        B, mask = gen.block_length, gen.mask_token_id
+        tok = np.full((self.max_slots, B), int(mask == 0), np.int32)
+        skip = {}
+        for s in dec:
+            tok[s.idx] = mask
+            whole = self._whole(s.prompt_len)
+            if s.pos == whole and s.prompt_len > whole:
+                tail = s.prompt[whole:]
+                tok[s.idx, :len(tail)] = tail
+                skip[s.idx] = len(tail)
+        return tok, skip
+
     def _launch(self, dec, riding, params, tok, pos, tables, finished):
         """Dispatch the decode call for every slot in `dec` — `decode_step`,
         or with the chunks `riding` it ((slot, start) each; up to G a token
@@ -1928,29 +2070,40 @@ class ServingEngine:
         G = self.programs.group
         prior = self._pending
         no_prev = self.programs.no_prev
-        tok = (no_prev if prior is None else prior.prev,) + tok
+        gen, skip = self.generator, {}
+        if gen is None:
+            tok = (no_prev if prior is None else prior.prev,) + tok
+            ride = win      # the window positions a chunk group may ride
+            rows_win = win  # the rows a slot runs through the model
+        else:
+            # a call of blocks: `win` tokens a slot (its positions' advance),
+            # through `ride` forwards of B rows each where every block takes
+            # its `denoising_steps` (the read-back has the forwards it took)
+            tok, skip = tok
+            ride = self.ride_window
+            rows_win = ride * gen.block_length
         finals = []
         if riding:
             with self._phase("serving/decode_build"):
-                # chunk i of `riding` is chunk i % G of window token i // G:
-                # row i of the arrays' flat [win * G, ...] view, and first
-                # token i of the call's
-                chunks = np.zeros((win * G, self.chunk), np.int32)
-                starts = np.zeros((win * G,), np.int32)
-                lasts = np.zeros((win * G,), np.int32)
+                # chunk i of `riding` is chunk i % G of window token (a block
+                # generator's forward) i // G: row i of the arrays' flat
+                # [ride * G, ...] view, and first token i of the call's
+                chunks = np.zeros((ride * G, self.chunk), np.int32)
+                starts = np.zeros((ride * G,), np.int32)
+                lasts = np.zeros((ride * G,), np.int32)
                 for i, (slot, start) in enumerate(riding):
                     chunks[i:i + 1], lasts[i], final = self._chunk_input(
                         slot, start)
                     starts[i] = start
                     if final:
                         finals.append((slot, i))
-                chunks = chunks.reshape(win, G, self.chunk)
-                starts, lasts = starts.reshape(win, G), lasts.reshape(win, G)
+                chunks = chunks.reshape(ride, G, self.chunk)
+                starts, lasts = starts.reshape(ride, G), lasts.reshape(ride, G)
                 # chunks past `n` are never read: any slot's row fills them
                 idx = [slot.idx for slot, _ in riding]
-                idx += idx[-1:] * (win * G - n)
+                idx += idx[-1:] * (ride * G - n)
                 chunk_tables = jax.tree_util.tree_map(
-                    lambda t: t.reshape(win, G, -1),
+                    lambda t: t.reshape(ride, G, -1),
                     self._tables_arg(self.tables[idx], idx))
         step_fn = self.programs.mixed if riding else \
             self.programs.decode_w1() if use_w1 else self.programs.decode
@@ -1960,7 +2113,8 @@ class ServingEngine:
         with self._dispatching(
                 "serving/decode_window",
                 "mixed" if riding else "decode_w1" if use_w1 else "decode",
-                rows=len(dec), win=win, firsts=len(finals),
+                rows=len(dec), win=rows_win,
+                firsts=0 if gen else len(finals),
                 chunks=len(self._unread_chunks) + n):
             st.dispatched()
             if riding:
@@ -1984,20 +2138,28 @@ class ServingEngine:
                 self.chunk_groups += -(-n // G)
                 self.padded_chunks += -n % G
             walk = self._decode_walk(dec, pos, win)
-            call = _Call(self.device_calls, out,
-                         out[0] if riding else (no_prev[0], out[0]),
-                         bool(riding), win, dec, finals,
-                         self._unread_chunks, riding)
+            if gen is None:
+                call = _Call(self.device_calls, out,
+                             out[0] if riding else (no_prev[0], out[0]),
+                             bool(riding), win, dec, finals,
+                             self._unread_chunks, riding)
+            else:
+                # its output is the committed tokens alone: no first token
+                # is sampled, and the next call picks nothing from it
+                call = _Call(self.device_calls, out, None, False, win, dec,
+                             [], self._unread_chunks, riding, skip, walk)
             self._unread_chunks = []
             self._pending = call
             for s in dec:
                 s.pos += win
-                s.flying += win
+                s.flying += win - skip.get(s.idx, 0)
                 s.feed = (call.id, 1)
                 if len(s.emitted) + s.flying >= s.max_new:
                     self._leave(s, call)
             for slot, i in finals:
                 slot.state = _HANDOFF if slot.prefill_only else _DECODE
+                if gen is not None:
+                    continue    # it opens its first block in the next call
                 slot.flying = 1
                 slot.feed = (call.id, 2 + i)
                 if slot.max_new <= 1:
@@ -2017,6 +2179,14 @@ class ServingEngine:
         one speculative window."""
         # THE one host roundtrip per decode window — EOS/retirement decisions are host-side, amortized over `win` tokens
         with self._read_back(call.id, call.out) as (toks, rec):
+            if call.walk is not None:
+                # a walk a FORWARD: the call's counters say how many it took
+                # and not which block's they were, so each forward is booked
+                # as the call's mean one — exact where its blocks take the
+                # same number (flat logits: S + 1 each); its blocks lie B
+                # positions apart
+                self._walk_read += np.asarray(call.walk, np.float64) \
+                    * rec.forwards / self.blocks_per_call
             self._hand_out(call, toks, rec, finished)
 
     def _hand_out(self, call, toks, rec, finished):
@@ -2045,10 +2215,11 @@ class ServingEngine:
             for s in call.rows:
                 if s.state == _FREE:
                     continue
-                s.flying -= call.win
+                skip = call.skip.get(s.idx, 0)
+                s.flying -= call.win - skip
                 ctx = s.trace             # _retire closes the request
                 anchor, j = s.t_prev, 0
-                for t in nxt[s.idx]:
+                for t in nxt[s.idx][skip:]:
                     self._emit(s, int(t), finished)
                     j += 1
                     if s.state == _FREE:            # retired mid-window
@@ -2069,7 +2240,14 @@ class ServingEngine:
         the call's tokens."""
         from deepspeed_tpu.ops.pallas.decode_attention import (
             paged_decode_walk_steps, window_first_block)
-        at = pos[[s.idx for s in dec]] + np.arange(win)[:, None]
+        if self.generator is not None:
+            # a walk a FORWARD, at the block's last position: ONE forward of
+            # each of the call's blocks here (`_read` books them by the
+            # forwards the call took)
+            B = self.generator.block_length
+            at = pos[[s.idx for s in dec]] + np.arange(B - 1, win, B)[:, None]
+        else:
+            at = pos[[s.idx for s in dec]] + np.arange(win)[:, None]
         live = at // self.block_size + 1               # [win, slots]
         walk = (int(live.sum()),
                 sum(paged_decode_walk_steps(n) for n in live.sum(axis=1)),
@@ -2096,6 +2274,13 @@ class ServingEngine:
             if self._pending is None:
                 st.ready()      # else the next call is queued behind it
         rec = st.read_call(call_id, ph.t0, ph.t1)
+        if self.generator is not None and rec.win:
+            # the forwards the call took, by its own counters: `win` is the
+            # rows a slot ran through the model (what the readers divide by)
+            forwards = int(sum(self._call_counts[self._forward_counters]))
+            B = self.generator.block_length
+            rec = rec._replace(forwards=forwards, block_rows=B,
+                               win=forwards * B)
         tokens0 = self.tokens_generated
         yield toks, rec
         st.close_call(rec, self.tokens_generated - tokens0)
@@ -2110,6 +2295,7 @@ class ServingEngine:
         toks, *counts = jax.device_get([*out] + self._parked_counts)
         self._parked_counts = []
         self._step_counts += np.sum(counts, axis=0, dtype=np.int64)
+        self._call_counts = counts[0]       # this call's own
         return toks
 
     def _compiled_programs(self) -> int:
@@ -2245,6 +2431,19 @@ class ServingEngine:
             out["step_counters"] = dict(zip(
                 self.step_counter_names,
                 (int(v) for v in self.step_counter_totals)))
+        if self.generator is not None:
+            # forwards and rows apart from tokens: `tokens_generated` are
+            # committed AND delivered; the counters above have the forwards
+            c = out["step_counters"]
+            forwards = c["denoise_forwards"] + c["commit_forwards"]
+            out["generator"] = {
+                "kind": "block_diffusion",
+                "block_length": self.generator.block_length,
+                "denoising_steps": self.denoising_steps,
+                "blocks_per_call": self.blocks_per_call,
+                "remasking": self.generator.remasking,
+                "forwards": forwards,
+                "forwards_per_block": forwards / max(1, c["commit_forwards"])}
         if self.spec_on:
             out["spec_decode"] = {
                 "drafter": self.drafter.name,

@@ -7,11 +7,21 @@ of the engine it takes as arguments; nothing here imports the scheduler.
 
 ONE shape: every step program returns `((tokens...), counts), pool`.
 `counts` is the model's own per-call counters (`DecodeModelSpec.
-step_counters`, e.g. the routed experts'), summed over the call's tokens: an
-int32 `[len(step_counters)]` where the model names some, the EMPTY pytree
+step_counters`, e.g. the routed experts'), summed over the call's forwards:
+an int32 `[len(step_counters)]` where the model names some, the EMPTY pytree
 `()` where it names none (`build_resident`'s `paged`). An empty pytree adds nothing to a
 carry, an output or a `device_get`: the uncounted programs lower to the text
 they lowered to when they returned bare tokens.
+
+TWO kinds of generator fill the tokens. A model that emits one token a slot a
+forward: a decode or mixed call scans `window` forwards of one row a slot
+and returns `[S, window]` tokens, its first input `pick`ed on the device
+from the call before. A model that generates by DIFFUSION OVER BLOCKS
+(`DecodeModelSpec.generator`, `_block_diffusion_steps`): a call commits
+`blocks_per_call` whole blocks of B tokens a slot through denoise + commit
+forwards of B rows a slot, returns `[S, blocks_per_call * B]` committed
+tokens, counts its forwards (`engine.BLOCK_DIFFUSION_COUNTERS`, after the
+model's own) and takes NO token from the call before it.
 """
 
 import functools
@@ -20,7 +30,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from deepspeed_tpu.inference.engine import sample_logits
+from deepspeed_tpu.inference.engine import (BLOCK_DIFFUSION_COUNTERS,
+                                            sample_logits)
 
 # the whole-step programs, as `compile_stats()` and the watchdog name them
 _NAMES = ("decode_step", "prefill_step", "mixed_step", "verify_step",
@@ -106,24 +117,47 @@ def _sampler(cfg):
     return sample
 
 
+def step_counter_names(spec):
+    """What a resident engine's step programs count, in order: the model's
+    own (`DecodeModelSpec.step_counters`), then a block-diffusion
+    generator's forwards."""
+    return tuple(getattr(spec, "step_counters", None) or ()) + (
+        BLOCK_DIFFUSION_COUNTERS if getattr(spec, "generator", None) else ())
+
+
 def build_resident(spec, cfg, transform, *, window, max_slots, chunk,
-                   spec_on, draft_k, replicated, watchdog, group=1):
+                   spec_on, draft_k, replicated, watchdog, group=1,
+                   blocks_per_call=1, denoising_steps=0):
     """The whole-model programs of a resident engine: `cfg` its config (the
     sampler's settings), `transform` its dequantize-on-use wrapper of a model
     function, `replicated` its mesh's replicated sharding, `watchdog` its
     telemetry's compile watchdog, `group` the chunks a token of the mixed
     program takes (G; more than 1 only for a model whose
-    `mixed_chunk_groups` says its mixed program runs a group)."""
+    `mixed_chunk_groups` says its mixed program runs a group). For a model
+    with a block-diffusion `generator`: `blocks_per_call` blocks a slot a
+    call, at most `denoising_steps` denoise forwards a block, and `window`
+    is the forwards a call's chunks may ride (`_block_diffusion_steps`)."""
     G = group
-    counters = tuple(getattr(spec, "step_counters", None) or ())
+    generator = getattr(spec, "generator", None)
+    model_counters = tuple(getattr(spec, "step_counters", None) or ())
+    counters = step_counter_names(spec)
     sample = _sampler(cfg)
 
     def paged(fn):
         """A spec's paged function under the ONE arity `(logits, pool,
         counts)`: a counted model's returns its counters already, the
-        others get the empty pytree."""
+        others get the empty pytree (a generator's counters follow the
+        model's, zero until the block loop counts)."""
         fn = transform(fn)
-        return fn if counters else lambda *args: (*fn(*args), ())
+        if not generator:
+            return fn if counters else lambda *args: (*fn(*args), ())
+        extra = jnp.zeros((len(BLOCK_DIFFUSION_COUNTERS),), jnp.int32)
+
+        def counted(*args):
+            logits, pool, *counts = fn(*args)
+            return logits, pool, jnp.concatenate(
+                [*counts[:bool(model_counters)], extra])
+        return counted
 
     # what a window's sum of counts starts from: zeros, or the empty pytree
     no_counts = functools.partial(jnp.zeros, (len(counters),), jnp.int32) \
@@ -248,6 +282,13 @@ def build_resident(spec, cfg, transform, *, window, max_slots, chunk,
         _, _, pool, _, acc, first, toks = carry
         return ((first, toks), acc), pool
 
+    if generator:
+        # a call commits blocks and `pick`s nothing: the two programs above
+        # are replaced, the chunk program and the rest stay
+        make_decode_step, mixed_step = _block_diffusion_steps(
+            generator, paged(spec.denoise_paged_fn), mixed_paged, no_counts,
+            blocks=blocks_per_call, steps=denoising_steps, group=G)
+
     # the pool is donated: the update is in-place in HBM. The compile
     # watchdog (telemetry/flight_recorder.py) wraps each program when
     # telemetry is on: any cache miss after the ONE warmup compile is
@@ -296,10 +337,10 @@ def build_resident(spec, cfg, transform, *, window, max_slots, chunk,
         verify = watchdog.wrap(
             "verify_step", jax.jit(verify_step, donate_argnums=(3,)))
 
-    make_w1 = None if window == 1 else lambda: watchdog.wrap(
+    make_w1 = None if window == 1 or generator else lambda: watchdog.wrap(
         "decode_step_w1", jax.jit(make_decode_step(1), donate_argnums=(3,)))
 
-    no_prev = jax.device_put(
+    no_prev = None if generator else jax.device_put(
         (np.zeros((window * G,), np.int32),
          np.zeros((max_slots, window), np.int32)), replicated)
 
@@ -312,7 +353,8 @@ def build_resident(spec, cfg, transform, *, window, max_slots, chunk,
         one = jax.tree_util.tree_map(lambda t: np.asarray(t)[:1], tables)
         # a call's input tokens as the scheduler hands them: the call
         # before's outputs (on the device), the source a slot, the host's
-        tok = (no_prev, i32(S), i32(S))
+        tok = i32(S, generator.block_length) if generator \
+            else (no_prev, i32(S), i32(S))
         decode_args = (params, tok, i32(S), pool, tables, rng)
         return {
             "decode_step": decode_args, "decode_step_w1": decode_args,
@@ -327,6 +369,111 @@ def build_resident(spec, cfg, transform, *, window, max_slots, chunk,
 
     return StepPrograms(decode, prefill, mixed, verify, no_prev, parts={},
                         make_w1=make_w1, example_args=example_args, group=G)
+
+
+def _block_diffusion_steps(generator, denoise_paged, mixed_paged, no_counts,
+                           *, blocks, steps, group):
+    """The decode and mixed programs of a model that generates by diffusion
+    over blocks (`engine.BlockDiffusion`): `(make_decode_step, mixed_step)`
+    in `build_resident`'s places.
+
+    A call runs `blocks` blocks a slot, all slots block-synchronous. ONE loop
+    of forwards on the carried pool, each of B rows a slot
+    (`denoise_paged`; with chunks riding, `mixed_paged`: up to G chunks and
+    the S x B slot rows as one tensor). The carried state says what a forward
+    is: while a slot that runs still has a masked row it is a DENOISE forward
+    (the rule unmasks rows from its logits, `BlockDiffusion.unmask`; what it
+    wrote into the pool is written over by the next forward of the block);
+    else it is the block's COMMIT forward — its K/V stay, its tokens go to
+    the output, and every running slot opens its next block as B mask tokens
+    B positions on. A block's step schedule is n_s of `steps` steps; the
+    loop has no bound of its own because a block of B rows is clean after at
+    most `steps` of them.
+
+    `tok` [S, B]: a slot's first block as the host has it — mask ids where a
+    slot goes on generating, a prompt's last `L mod B` tokens before mask ids
+    where it begins, and NO mask id in the row of a slot that is not in the
+    call (it runs against the trash block and keeps no forward waiting).
+    Chunks ride forward 0, 1, ... in order, a group each; the LAST block's
+    commit waits (as denoise forwards that change nothing) until every group
+    has ridden, so a call always runs the chunks it was given. Returns
+    ((tokens [S, blocks * B]), counts), pool; the counters after the model's
+    own are `BLOCK_DIFFUSION_COUNTERS`."""
+    G, B = group, generator.block_length
+    mask_id = generator.mask_token_id
+    n_s = jnp.asarray(generator.transfers(steps) + [0], jnp.int32)
+    extra = len(BLOCK_DIFFUSION_COUNTERS)
+
+    def run(params, tok, pos, pool, tables, riding=None):
+        S = tok.shape[0]
+        running = jnp.any(tok == mask_id, axis=1)              # [S]
+        live = jnp.sum(running, dtype=jnp.int32)
+        groups = 0 if riding is None else (riding[4] + G - 1) // G
+
+        def forward(state, ride):
+            x, masked, pos, pool, b, s, f, out, acc = state
+            if ride:
+                chunks, starts, lasts, chunk_tables, n = riding
+
+                def at(a):
+                    return jax.lax.dynamic_index_in_dim(a, f, 0,
+                                                        keepdims=False)
+                count = () if G == 1 else (jnp.minimum(n - f * G, G),)
+                logits, pool, counts = mixed_paged(
+                    params, at(chunks), at(starts), at(lasts),
+                    jax.tree_util.tree_map(at, chunk_tables), x, pos, pool,
+                    tables, *count)
+                logits = logits[G:]     # the chunks' rows sample nothing
+            else:
+                logits, pool, counts = denoise_paged(params, x, pos, pool,
+                                                     tables)
+            commit = ~jnp.any(masked) & (
+                (b < blocks - 1) | (f + 1 >= groups))
+            x1, masked1, moved = generator.unmask(
+                logits, x, masked, n_s[jnp.minimum(s, steps)])
+            with jax.named_scope("denoise/commit"):
+                out = jnp.where(commit, jax.lax.dynamic_update_slice(
+                    out, x, (0, b * B)), out)
+                x = jnp.where(commit, jnp.where(running[:, None], mask_id,
+                                                tok), x1)
+                masked = jnp.where(commit, jnp.broadcast_to(
+                    running[:, None], masked.shape), masked1)
+                pos = jnp.where(commit & running, pos + B, pos)
+                acc = acc + counts
+                acc = acc.at[-extra:].add(jnp.stack([
+                    (~commit).astype(jnp.int32), commit.astype(jnp.int32),
+                    jnp.sum(jnp.where(running, moved, 0)) * ~commit,
+                    live * commit]))
+            return (x, masked, pos, pool, b + commit,
+                    jnp.where(commit, 0, s + 1), f + 1, out, acc)
+
+        zero = jnp.zeros((), jnp.int32)
+        state = (tok, tok == mask_id, pos, pool, zero, zero, zero,
+                 jnp.zeros((S, blocks * B), jnp.int32), no_counts())
+        if riding is not None:
+            state = jax.lax.while_loop(
+                lambda st: (st[6] < groups) & (st[4] < blocks),
+                lambda st: forward(st, True), state)
+        state = jax.lax.while_loop(
+            lambda st: st[4] < blocks, lambda st: forward(st, False), state)
+        _, _, _, pool, _, _, _, out, acc = state
+        return (out, acc), pool
+
+    def make_decode_step(_window):
+        def decode_step(params, tok, pos, pool, tables, rng):
+            del rng         # greedy: x0 is the argmax
+            return run(params, tok, pos, pool, tables)
+        return decode_step
+
+    def mixed_step(params, chunks, starts, lasts, chunk_tables, n, tok, pos,
+                   pool, tables, rng):
+        """`chunks` [W, G, C] (+ `starts`, `lasts`, `chunk_tables`): a group
+        a forward, of which the first `n` CHUNKS (traced) are real."""
+        del rng
+        return run(params, tok, pos, pool, tables,
+                   (chunks, starts, lasts, chunk_tables, n))
+
+    return make_decode_step, mixed_step
 
 
 def build_streamed(spec, cfg, *, num_layers, streamer, watchdog):

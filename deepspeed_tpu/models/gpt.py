@@ -102,6 +102,14 @@ class GPTConfig:
                                      # half; `x + norm(attn(x))`, then
                                      # `h + norm(mlp(h))` (ln1/ln2 scale the
                                      # halves' OUTPUTS)
+    block_length: int = 1            # generation by diffusion over blocks
+                                     # (SDAR): position i attends j iff
+                                     # j // B <= i // B, causal over blocks of
+                                     # B positions and BIDIRECTIONAL inside
+                                     # one, on the paged serving path (a row's
+                                     # frontier is the END of its block, `pos
+                                     # | (B - 1)`; B a power of two). 1: the
+                                     # causal mask
     tie_embeddings: bool = True
     remat: bool = True               # jax.checkpoint each block
     remat_policy: Any = None         # None: the block HOLDS what its backward
@@ -184,6 +192,8 @@ class GPTConfig:
         # a head width given apart (`attn_head_dim`) need not divide d_model
         assert self.attn_head_dim or self.d_model % self.n_head == 0
         assert self.n_head % self.n_kv_head == 0
+        assert self.block_length >= 1 \
+            and self.block_length & (self.block_length - 1) == 0
 
     @property
     def head_dim(self):
@@ -314,7 +324,9 @@ def gpt_init_fn(cfg: GPTConfig, dtype=jnp.float32):
             "ln2_scale": ones(L, D),
             "attn_qkv_w": norm(L, D, QKV),
             "attn_qkv_b": zeros(L, QKV),
-            "attn_out_w": norm(L, D, D, scale=proj_scale),
+            # ([L, D, D] wherever the heads' values tile the stream)
+            "attn_out_w": norm(L, cfg.n_head * cfg.value_dim, D,
+                               scale=proj_scale),
             "attn_out_b": zeros(L, D),
             "mlp_out_b": zeros(L, D),
         }
@@ -324,6 +336,9 @@ def gpt_init_fn(cfg: GPTConfig, dtype=jnp.float32):
         if cfg.qk_norm:
             block["q_norm_scale"] = ones(L, cfg.n_head * cfg.head_dim)
             block["k_norm_scale"] = ones(L, cfg.n_kv_head * cfg.head_dim)
+        if cfg.qk_norm_per_head:        # one scale vector the heads share
+            block["q_norm_scale"] = ones(L, cfg.head_dim)
+            block["k_norm_scale"] = ones(L, cfg.head_dim)
         if cfg.use_swiglu:
             block["mlp_gate_w"] = norm(L, D, F)
             block["mlp_up_w"] = norm(L, D, F)
@@ -368,8 +383,9 @@ def gpt_param_specs(cfg: GPTConfig):
     if not cfg.use_rmsnorm:
         block["ln1_bias"] = P(None, None)
         block["ln2_bias"] = P(None, None)
-    if cfg.qk_norm:
-        # the norm reduces over all heads' columns: replicated, like ln1
+    if cfg.qk_norm or cfg.qk_norm_per_head:
+        # the norm reduces over all heads' columns (or the heads share one
+        # scale vector): replicated, like ln1
         block["q_norm_scale"] = P(None, None)
         block["k_norm_scale"] = P(None, None)
     if cfg.use_swiglu:
@@ -1489,13 +1505,18 @@ def over_chunk_group(count, C, out, carry, run):
     return jax.lax.fori_loop(0, count, body, (out, carry))
 
 
-def decode_rows(block_tables, positions):
+def decode_rows(block_tables, positions, rows=1):
     """(tables [S, nb], positions [S]) of a paged call's decode rows: every
-    row of a decode call, the last S of a mixed call."""
+    row of a decode call, the last S of a mixed call. `rows` > 1 (a block
+    of a diffusion generator, `GPTConfig.block_length`): a slot has that
+    many rows, and its position is its LAST row's, the frontier they share."""
     if isinstance(block_tables, MixedTables):
         slots = block_tables.decode.shape[0]
+        if rows > 1:
+            return block_tables.decode, \
+                positions[0, -slots * rows:].reshape(slots, rows)[:, -1]
         return block_tables.decode, positions[0, -slots:]
-    return block_tables, positions[:, 0]
+    return block_tables, positions[:, -1 if rows > 1 else 0]
 
 
 def make_mixed_paged_fn(cfg, layers_paged, chunk_valid=False):
@@ -1509,7 +1530,12 @@ def make_mixed_paged_fn(cfg, layers_paged, chunk_valid=False):
     each weight is read once where the programs of their own read it 1 + G
     times. Only the attention half runs the chunks one after another
     (`_paged_attn_half`). Logits [G + S, V]: each chunk's `last_idx` row,
-    then the slots' rows. A family whose loop takes G > 1 says so
+    then the slots' rows. A model that generates by diffusion over blocks
+    (`cfg.block_length` B > 1) passes `token` [S, B], a block a slot at
+    `pos` .. `pos + B - 1`: the slots' rows are then S * B, slot after slot,
+    and the logits [G + S * B, V] (a decode call of such a model commits
+    whole blocks, not `window` tokens a slot: `inference/step_programs.py`).
+    A family whose loop takes G > 1 says so
     (`DecodeModelSpec.mixed_chunk_groups`); the others are handed G = 1.
     `chunk_valid`: the loop also takes `valid=`, the chunk's real positions
     `last_idx + 1` (a layer with recurrent state must not run it over the
@@ -1520,6 +1546,12 @@ def make_mixed_paged_fn(cfg, layers_paged, chunk_valid=False):
     def mixed_paged_fn(params, chunk_tokens, start_pos, last_idx, chunk_table,
                        token, pos, pool, block_tables, count=None, **loop):
         G, C = chunk_tokens.shape
+        if token.ndim == 2:
+            # a diffusion generator's block a slot (`cfg.block_length` rows
+            # at pos .. pos + B - 1), slot after slot
+            pos = (pos[:, None] + jnp.arange(token.shape[1],
+                                             dtype=jnp.int32)[None]).reshape(-1)
+            token = token.reshape(-1)
         tokens = jnp.concatenate([chunk_tokens.reshape(1, G * C),
                                   token[None]], axis=1)
         positions = jnp.concatenate(
@@ -1551,8 +1583,10 @@ def scan_paged(cfg: GPTConfig, blocks, x, pool, block_tables, positions,
     The pool is a PYTREE of [L, N, ...] leaves (k/v, plus the int8 pool's
     k_scale/v_scale), so the quantized and fp layouts share one scan body —
     a layer's pool arrives as a dict. `phase` labels the dispatch site
-    ("verify" for the spec-decode chunk; None = derive decode/prefill from
-    the chunk width; `block_tables` a `MixedTables` makes it "mixed", a
+    ("verify" for the spec-decode chunk; "denoise" for a diffusion
+    generator's block forward, `cfg.block_length` rows a slot that share one
+    frontier: the decode site with more rows; None = derive decode/prefill
+    from the chunk width; `block_tables` a `MixedTables` makes it "mixed", a
     chunk and the slots' decode rows in one x); `pool_writers[phase]`
     records the writer chosen and `attn_programs[phase]` the attention
     program the layers select.
@@ -1583,8 +1617,10 @@ def scan_paged(cfg: GPTConfig, blocks, x, pool, block_tables, positions,
     flags = _layer_local_flags(cfg)
     writer = attn_dispatch.kv_pool_writer(pool)
     mixed = isinstance(block_tables, MixedTables)
-    site = "mixed" if mixed else phase or (
-        "paged_decode" if x.shape[1] == 1 else "prefill_chunk")
+    # a diffusion generator's block forward ("denoise": `cfg.block_length`
+    # rows a slot that share one frontier) is the decode site with more rows
+    site = "mixed" if mixed else "paged_decode" if phase == "denoise" \
+        else phase or ("paged_decode" if x.shape[1] == 1 else "prefill_chunk")
     if pool_writers is not None:
         pool_writers[site] = writer
     # the decode kernel's work list is the same for every layer (a layer only
@@ -1594,8 +1630,9 @@ def scan_paged(cfg: GPTConfig, blocks, x, pool, block_tables, positions,
     if site in ("paged_decode", "mixed"):
         from deepspeed_tpu.ops.pallas.decode_attention import \
             paged_decode_work
+        rows = cfg.block_length if mixed or phase == "denoise" else 1
         decode_work = paged_decode_work(
-            *decode_rows(block_tables, positions), pool["k"].shape[3],
+            *decode_rows(block_tables, positions, rows), pool["k"].shape[3],
             window=_static_window(cfg))
 
     def layer(x, p, pool_l, flag, acc, layer_id, block_base=None):
@@ -1652,7 +1689,11 @@ def _paged_attend(q, k_ctx, v_ctx, q_pos, cfg: GPTConfig, local_flag=None,
     G = H // Hkv
     scale = score_scale(cfg, hd)
     k_pos = jnp.arange(S, dtype=jnp.int32)
-    valid = k_pos[None, None, :] <= q_pos[:, :, None]          # [B, C, S]
+    # (`block_length` > 1: causal over blocks, bidirectional inside one — a
+    # row sees up to the END of its block)
+    frontier = q_pos | (cfg.block_length - 1) if cfg.block_length > 1 \
+        else q_pos
+    valid = k_pos[None, None, :] <= frontier[:, :, None]       # [B, C, S]
     if cfg.sliding_window:
         win = valid & (q_pos[:, :, None] - k_pos[None, None, :]
                        < cfg.sliding_window)
@@ -1685,7 +1726,9 @@ def _paged_attn_half(x, p, pool_l, positions, block_tables,
     row's blocks (logical position -> table -> physical block scatter), then
     attends over the row's whole table. Returns (attn_out, pool_l).
 
-    A MIXED call (`block_tables` a `MixedTables`; x [1, G*C + S, D]): one
+    A MIXED call (`block_tables` a `MixedTables`; x [1, G*C + S, D], or
+    [1, G*C + S*B, D] where a slot's rows are a diffusion generator's block
+    of B = `cfg.block_length`): one
     QKV and one output projection over all the rows, and between them each
     of the group's chunks in order, then the slots' S rows, written and
     attended as their own program would (`_paged_write_attend`, once a
@@ -1736,7 +1779,9 @@ def _paged_attn_half(x, p, pool_l, positions, block_tables,
         # attends as it would in its own program (same dispatch site, same
         # kernels); their results meet again for ONE output projection
         S = block_tables.decode.shape[0]
-        R = x.shape[1] - S          # the chunks' rows, C a chunk
+        Bk = cfg.block_length       # a slot's rows: one token, or a
+                                    # diffusion generator's block
+        R = x.shape[1] - S * Bk     # the chunks' rows, C a chunk
         C = R // G
         chunk = partial(group, phase="prefill_chunk",
                         record="mixed/prefill_chunk")
@@ -1762,11 +1807,23 @@ def _paged_attn_half(x, p, pool_l, positions, block_tables,
         # pool leaf a layer (measured on the chip, PR 33: two copies of
         # K-EXAONE's 1.5 GB full-layer leaf a mixed token, 23% of its time)
         attn_c, pool_l = jax.lax.optimization_barrier((attn_c, pool_l))
-        attn_d, pool_l = group(
-            *(jnp.swapaxes(a[:, R:], 0, 1) for a in (q, k, v)), pool_l,
-            positions[:, R:].T, block_tables.decode, phase="paged_decode",
-            decode_work=decode_work, record="mixed/paged_decode")
-        attn = jnp.concatenate([attn_c, jnp.swapaxes(attn_d, 0, 1)], axis=1)
+        if Bk == 1:
+            attn_d, pool_l = group(
+                *(jnp.swapaxes(a[:, R:], 0, 1) for a in (q, k, v)), pool_l,
+                positions[:, R:].T, block_tables.decode,
+                phase="paged_decode", decode_work=decode_work,
+                record="mixed/paged_decode")
+            attn_d = jnp.swapaxes(attn_d, 0, 1)
+        else:
+            # a block a slot, [S, Bk, ...] as the denoise program has them
+            attn_d, pool_l = group(
+                *(a[0, R:].reshape((S, Bk) + a.shape[2:])
+                  for a in (q, k, v)), pool_l,
+                positions[0, R:].reshape(S, Bk), block_tables.decode,
+                phase="denoise", decode_work=decode_work,
+                record="mixed/paged_decode")
+            attn_d = attn_d.reshape(1, S * Bk, -1)
+        attn = jnp.concatenate([attn_c, attn_d], axis=1)
     else:
         attn, pool_l = group(q, k, v, pool_l, positions, block_tables,
                              phase=phase, decode_work=decode_work)
@@ -1842,17 +1899,39 @@ def _paged_write_attend(q, k, v, pool_l, positions, block_tables,
     # site's oracle: gather the row's whole table — dequantized where the
     # pool has scale leaves, whatever the program is called — and attend it
     # densely.
+    # A diffusion generator's block forward (`phase` "denoise": C =
+    # `cfg.block_length` rows a slot, denoise or commit) HAS a runner: once
+    # the block's K/V are written its rows all see the same keys, [0, p + C),
+    # so they are the decode walk with C x G query rows a KV head at the
+    # block's last position — no mask inside the block. The verify chunk
+    # still has none: its rows are causal INSIDE the chunk (row i sees
+    # pos + i), so they do not share one frontier.
+    denoise = phase == "denoise"
     site = _decode_attn_site(
-        cfg,
-        phase or ("paged_decode" if C == 1 else "prefill_chunk"), C, nb * bs,
+        cfg, "paged_decode" if denoise
+        else phase or ("paged_decode" if C == 1 else "prefill_chunk"),
+        1 if denoise else C, nb * bs,
         kv_dtype="int8" if quantized else str(jnp.dtype(pool_l["k"].dtype)),
         block_size=bs, pool_in_place=block_base is not None)
     sunk = {} if sink is None else dict(sink=sink)
+    if cfg.block_length > 1 and site.phase == "prefill_chunk":
+        sunk["block_length"] = cfg.block_length     # the chunk walk's mask
     program = attn_dispatch.select(site)
     if attn_programs is not None:
         attn_programs[record or site.phase] = program
     runner = attn_dispatch.get_program(program).runner
-    if runner is not None:
+    if runner is not None and denoise:
+        H, hd, Hkv = q.shape[2], q.shape[3], k.shape[2]
+        with jax.named_scope("walk"):
+            rows = jnp.swapaxes(q.reshape(B, C, Hkv, H // Hkv, hd), 1, 2)
+            attn = runner(rows.reshape(B, 1, C * H, hd), pool_l,
+                          block_tables, positions[:, -1],
+                          sm_scale=sm_scale(cfg),
+                          window=site.window or None, work=decode_work,
+                          **sunk)
+            attn = jnp.swapaxes(attn.reshape(B, Hkv, C, -1), 1, 2) \
+                .reshape(B, C, -1)
+    elif runner is not None:
         with jax.named_scope("walk"):
             attn = runner(q, pool_l, block_tables, positions[:, 0],
                           sm_scale=sm_scale(cfg),

@@ -135,11 +135,15 @@ def _stack_experts(params, cfg: MoEGPTConfig, rng, dtype):
     return {**params, "blocks": blocks}
 
 
-def moe_gpt_init_fn(cfg: MoEGPTConfig, dtype=jnp.float32):
+def moe_gpt_init_fn(cfg: MoEGPTConfig, dtype=jnp.float32,
+                    embedding_std=None):
     """jax-traceable initializer (rng -> params) for the `moe_freq` 1 layout,
     the twin of `gpt.py::gpt_init_fn`: under one `jit` the whole tree is
     made on the device in the type it is served in (the dense MLP leaves it
-    drops are never materialised)."""
+    drops are never materialised). `embedding_std`: the token embedding's
+    range where it is not the other matrices' 0.02 (a benchmark's way to a
+    stream in which a token's own embedding is not swamped by what the first
+    layers add to every token alike, as `exaone_moe_init_fn`'s)."""
     if cfg.moe_freq != 1:
         raise NotImplementedError(
             "moe_gpt_init_fn builds the stacked (moe_freq=1) layout only; "
@@ -148,7 +152,11 @@ def moe_gpt_init_fn(cfg: MoEGPTConfig, dtype=jnp.float32):
 
     def init(rng):
         rng, sub = jax.random.split(rng)
-        return _stack_experts(dense(rng), cfg, sub, dtype)
+        params = _stack_experts(dense(rng), cfg, sub, dtype)
+        if embedding_std is not None:
+            params["wte"] = (params["wte"].astype(jnp.float32)
+                             * (float(embedding_std) / 0.02)).astype(dtype)
+        return params
 
     return init
 
@@ -433,12 +441,22 @@ def moe_cache_identity(cfg: MoEGPTConfig, name: str = "") -> str:
     count and placement change every MoE layer's output, hence every later
     layer's K/V. Capacity knobs are absent on purpose — inference routing is
     capacity-free, so they cannot change cached bytes."""
+    blocks = f"blocks{cfg.block_length}|" if cfg.block_length > 1 else ""
     return (f"moe:{cfg.num_experts}|{cfg.moe_freq}|{cfg.top_k}|"
-            f"{int(cfg.norm_topk_prob)}|" + gpt_cache_identity(cfg, name))
+            f"{int(cfg.norm_topk_prob)}|" + blocks
+            + gpt_cache_identity(cfg, name))
 
 
-def make_moe_gpt_decode_model(cfg: MoEGPTConfig, params=None, name="moe-gpt", seed=0):
+def make_moe_gpt_decode_model(cfg: MoEGPTConfig, params=None, name="moe-gpt",
+                              seed=0, generator=None):
+    """`generator` (`inference.engine.BlockDiffusion`; its block length is
+    `cfg.block_length`): the model generates by diffusion over blocks, and
+    the spec carries it and `denoise_paged_fn`."""
     from deepspeed_tpu.inference.engine import DecodeModelSpec
+    if (generator.block_length if generator else 1) != cfg.block_length:
+        raise ValueError(
+            f"the generator's block length and the mask's must agree: "
+            f"{generator} against cfg.block_length {cfg.block_length}")
     if params is None:
         params = init_moe_gpt_params(cfg, seed=seed)
 
@@ -497,7 +515,10 @@ def make_moe_gpt_decode_model(cfg: MoEGPTConfig, params=None, name="moe-gpt", se
     # attention machinery as gpt.py's paged path, and the routed experts keep
     # every chunking of a prompt token-identical, which is what continuous
     # batching relies on. Each program also returns the routed layers'
-    # counters, summed over the layers (`step_counters` below).
+    # counters, summed over the layers (`step_counters` below). With a
+    # `generator` (diffusion over blocks) the spec also carries
+    # `denoise_paged_fn`, one forward of a block a slot, and a decode call
+    # commits whole blocks instead of emitting `window` tokens a slot.
     #
     # Experts stacked in `blocks` (moe_freq 1): every block is alike, so the
     # layer — attention half and routed experts — runs inside
@@ -526,7 +547,12 @@ def make_moe_gpt_decode_model(cfg: MoEGPTConfig, params=None, name="moe-gpt", se
         pool = {k: jnp.stack([s[k] for s in slices], 0) for k in pool}
         return x, pool, sum(counts)
 
-    def _layers_paged(params, x, pool, block_tables, positions, phase=None):
+    def _layers_paged(params, x, pool, block_tables, positions, phase=None,
+                      routing=False):
+        """`routing` (the stacked layout; a benchmark's check): a fourth
+        result, the experts every row was routed to, int32 [L, rows, top_k]
+        in the router's order — what the served forward chose on its own
+        activations."""
         if "moe_gate_w" not in params["blocks"]:     # per-layer trees
             return _loop_paged(params, x, pool, block_tables, positions,
                                phase)
@@ -536,30 +562,46 @@ def make_moe_gpt_decode_model(cfg: MoEGPTConfig, params=None, name="moe-gpt", se
         blocks = params["blocks"]
         scanned = {k: v for k, v in blocks.items()
                    if k not in _EXPERT_STACKS}
+        L, rows = cfg.n_layer, x.shape[0] * x.shape[1]
+        aux = no_counts
+        if routing:
+            # the scan SUMS a layer's third result: each layer's sets ride
+            # the sum at the layer's own place in one flat vector
+            aux = jnp.zeros((no_counts.size + L * rows * cfg.top_k,),
+                            jnp.int32)
 
         def routed_block(x, p, pool_l, positions, block_tables, cfg, layer,
                          **kwargs):
-            counted = []
+            counted, chosen = [], [] if routing else None
             x, pool_l = _block_paged(
                 x, p, pool_l, positions, block_tables, cfg,
                 mlp_fn=_routed_mlp_fn(_layer_experts(params, p, layer), cfg,
-                                      counted), **kwargs)
-            return x, pool_l, counted[0]
+                                      counted, chosen), **kwargs)
+            if not routing:
+                return x, pool_l, counted[0]
+            return x, pool_l, jax.lax.dynamic_update_slice(
+                jnp.zeros_like(aux).at[:no_counts.size].set(counted[0]),
+                chosen[0].reshape(-1),
+                (no_counts.size + layer * rows * cfg.top_k,))
 
-        return scan_paged(cfg, scanned, x, pool, block_tables, positions,
-                          phase=phase, pool_writers=pool_writers,
-                          block_fn=routed_block, aux=no_counts,
-                          attn_programs=attn_programs)
+        x, pool, aux = scan_paged(
+            cfg, scanned, x, pool, block_tables, positions, phase=phase,
+            pool_writers=pool_writers, block_fn=routed_block, aux=aux,
+            attn_programs=attn_programs)
+        if not routing:
+            return x, pool, aux
+        return (x, pool, aux[:no_counts.size],
+                aux[no_counts.size:].reshape(L, rows, cfg.top_k))
 
     def prefill_paged_fn(params, tokens, start_pos, last_idx, pool,
-                         block_tables):
+                         block_tables, **loop):
         B, C = tokens.shape
         positions = start_pos[:, None] + jnp.arange(C, dtype=jnp.int32)[None]
         x = _embed(params, tokens, positions, cfg)
-        x, pool, counts = _layers_paged(params, x, pool, block_tables,
-                                        positions)
+        x, pool, *counts = _layers_paged(params, x, pool, block_tables,
+                                         positions, **loop)
         logits = _lm_head(params, _last_rows(x, last_idx), cfg)[:, 0]
-        return logits, pool, counts
+        return (logits, pool, *counts)
 
     def decode_paged_fn(params, token, pos, pool, block_tables):
         x = _embed(params, token[:, None], pos[:, None], cfg)
@@ -577,6 +619,21 @@ def make_moe_gpt_decode_model(cfg: MoEGPTConfig, params=None, name="moe-gpt", se
         logits = _lm_head(params, x, cfg)
         return logits, pool, counts
 
+    def denoise_paged_fn(params, tokens, pos, pool, block_tables, **loop):
+        """One forward of a diffusion generator's block a slot (tokens
+        [S, B] at pos .. pos + B - 1), denoise or commit alike: the rows'
+        k/v written, [0, pos + B) attended, every row's logits [S * B, V],
+        slot after slot. Keywords go to the layer loop (`routing=True`: the
+        sets follow the counters)."""
+        S, B = tokens.shape
+        positions = pos[:, None] + jnp.arange(B, dtype=jnp.int32)[None]
+        x = _embed(params, tokens, positions, cfg)
+        x, pool, *counts = _layers_paged(params, x, pool, block_tables,
+                                         positions, phase="denoise", **loop)
+        # (the head over the rows as ONE [S * B, D] matrix: B is no tile)
+        logits = _lm_head(params, x.reshape(1, S * B, -1), cfg)[0]
+        return (logits, pool, *counts)
+
     def init_paged_pool(num_blocks, block_size, dtype=jnp.bfloat16,
                         kv_group_size=0):
         return init_paged_kv_pool(cfg, num_blocks, block_size, dtype,
@@ -585,6 +642,9 @@ def make_moe_gpt_decode_model(cfg: MoEGPTConfig, params=None, name="moe-gpt", se
     return DecodeModelSpec(prefill_fn=prefill_fn, decode_fn=decode_fn,
                            init_cache=init_cache, params=params,
                            param_specs=moe_gpt_param_specs(cfg), name=name,
+                           generator=generator,
+                           denoise_paged_fn=denoise_paged_fn if generator
+                           else None,
                            prefill_paged_fn=prefill_paged_fn,
                            decode_paged_fn=decode_paged_fn,
                            mixed_paged_fn=make_mixed_paged_fn(cfg,

@@ -483,10 +483,13 @@ def _run_paged_decode_quant(q, pool_l, block_tables, start, *, sm_scale=None,
 
 
 def _run_paged_prefill(q, pool_l, block_tables, start, *, sm_scale=None,
-                       window=None, work=None, rank=None, sink=None):
+                       window=None, work=None, rank=None, sink=None,
+                       block_length=1):
     from deepspeed_tpu.ops.pallas.prefill_attention import \
         paged_prefill_attention
     q, sm_scale, more = _walk_args(q, pool_l, sm_scale, sink)
+    if block_length > 1:    # a diffusion generator's block-causal mask
+        more["block_length"] = block_length
     return paged_prefill_attention(q, pool_l["k"], pool_l["v"], block_tables,
                                    start, sm_scale=sm_scale, window=window,
                                    **more)

@@ -104,7 +104,7 @@ def _tiles(C, block, Hkv, G, hd, itemsize, share=1):
 
 def _prefill_kernel(start_ref, bt_ref, q_ref, *refs, sm_scale, G, block, tk,
                     last_block, window=None, keys=1, values=True,
-                    sink=False):
+                    sink=False, block_length=1):
     # grid (B, Hkv // heads, C // tq, live blocks); q_ref / o_ref:
     # [1, tq, heads*G*hd], the step's query heads side by side in the lanes;
     # the `keys` key leaves' refs and v_ref: [1, heads, block, hd], ONE
@@ -119,6 +119,9 @@ def _prefill_kernel(start_ref, bt_ref, q_ref, *refs, sm_scale, G, block, tk,
     # v_ref, a key tile's first `acc_ref.shape[-1]` columns are its values.
     # `sink`: one more input, [heads*G, 1, _LANES] float32, a learned logit a
     # query head: every row's INITIAL softmax state (`_start_softmax`).
+    # `block_length` B > 1 (generation by diffusion over blocks): a row's
+    # frontier is the END of its block of B positions, `pos | (B - 1)`; the
+    # tile's first position is a multiple of B, so that is its row index's.
     *k_refs, o_ref, acc_ref, m_ref, l_ref = refs
     sink_ref = k_refs.pop() if sink else None
     v_ref = k_refs.pop() if values else None
@@ -152,6 +155,12 @@ def _prefill_kernel(start_ref, bt_ref, q_ref, *refs, sm_scale, G, block, tk,
             ahead = (j * block + t * tk - q_lo) \
                 + jax.lax.broadcasted_iota(jnp.int32, (tq, tk), 1) \
                 - jax.lax.broadcasted_iota(jnp.int32, (tq, tk), 0)
+            if block_length > 1:
+                # the row's index -> its block's last: `ahead` falls by what
+                # that adds
+                ahead = ahead - (block_length - 1 - (
+                    jax.lax.broadcasted_iota(jnp.int32, (tq, tk), 0)
+                    & (block_length - 1)))
 
         # unrolled: the heads' updates are independent, and the compiler
         # overlaps one's matmuls with another's softmax (a `fori_loop` over
@@ -211,7 +220,7 @@ def paged_prefill_live_blocks(start, chunk, block, table_blocks, window=None):
 
 def paged_prefill_attention(q, k_pool, v_pool, block_tables, start,
                             sm_scale=None, interpret=None, window=None,
-                            kr_pool=None, sink=None):
+                            kr_pool=None, sink=None, block_length=1):
     """Causal attention of a prefill chunk over a PAGED KV pool, the live
     blocks only.
 
@@ -239,7 +248,11 @@ def paged_prefill_attention(q, k_pool, v_pool, block_tables, start,
     the pool keeps it apart (`kv_pool.py::kv_leaf_shapes`); q is then
     `kv_pool.split_query`'s and `sm_scale` the caller's. `sink` [H]
     float32: a learned logit a head that joins every row's denominator —
-    the INITIAL state of the online softmax."""
+    the INITIAL state of the online softmax. `block_length` B > 1 (a power
+    of two that divides every `start`; no window): the block-causal mask of
+    a diffusion generator — row i sees the keys up to the end of its block
+    of B positions, `i | (B - 1)`, all of them in the pool already; 1 is
+    the causal mask, the kernel's text as it was."""
     if interpret is None:
         interpret = pallas_interpret()
     B, C, H, hd = q.shape
@@ -283,6 +296,9 @@ def paged_prefill_attention(q, k_pool, v_pool, block_tables, start,
             lambda b, g, *rest: kv_index(b, group(g), *rest))
 
     static, sunk, sunk_specs = {}, (), []
+    if block_length > 1:
+        assert window is None and tq % block_length == 0
+        static["block_length"] = block_length
     if kr_pool is not None:
         static["keys"] = 2
     if sink is not None:

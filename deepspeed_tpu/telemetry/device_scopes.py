@@ -76,6 +76,17 @@ SCOPES = {
     "head_loss": ("train step program",
                   "training: the LM head's logits and the cross entropy"),
     "sample": ("model step programs", "the sampler on a call's logits"),
+    # a block-diffusion generator's part of a forward (`inference/engine.py::
+    # BlockDiffusion`, `step_programs.py::_block_diffusion_steps`)
+    "denoise/confidence": ("model step programs",
+                           "x0 = argmax and its probability, every row of a "
+                           "block forward's logits"),
+    "denoise/unmask": ("model step programs",
+                       "the unmask rule: ranks, threshold, the rows that "
+                       "take x0"),
+    "denoise/commit": ("model step programs",
+                       "the block loop's state: a committed block to the "
+                       "output, the next block's mask tokens, the counters"),
     "optimizer": ("train step program",
                   "unscale, overflow check, gradient norm and clip, the "
                   "update, the loss scale"),

@@ -174,7 +174,14 @@ CallRecord = collections.namedtuple("CallRecord", [
     "emitted",          # tokens its read-back handed to live requests, first
                         # tokens included (<= rows * win + firsts: a tail past
                         # `max_new`, an EOS, a request that ended meanwhile)
-])
+    "forwards",         # a block-diffusion call (`DecodeModelSpec.generator`):
+                        # the forwards it took, denoise and commit, by its own
+                        # counters at the read-back, each of ...
+    "block_rows",       # ... this many rows a slot (the block length); `win`
+                        # is then forwards * block_rows, the rows a slot ran
+                        # through the model, and `emitted` the tokens
+                        # COMMITTED and delivered. 0, 0 for every other call
+], defaults=(0, 0))
 
 _LATEST = {}        # subsystem -> the most recently created recorder
 
