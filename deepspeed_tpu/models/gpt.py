@@ -492,7 +492,14 @@ def _rope(x, positions, rotary_dims, theta=10000.0):
     The transpose of a rotation turns the other way, so the backward pass IS
     this function at `-positions` (a `custom_vjp`), rounded once like the
     forward. Reverse mode passes it any number of times; forward mode
-    (`jax.jvp`) does not pass a `custom_vjp`."""
+    (`jax.jvp`) does not pass a `custom_vjp`. The backward's result stands
+    behind an `optimization_barrier`: the gradient goes on to a reshape
+    `[.., H, hd] -> [.., H*hd]`, which on the TPU is a relayout, and left to
+    itself XLA makes that reshape a bitcast by running THIS product T-minor,
+    with a transposing copy in front of it and one behind (PERF.md section
+    7); held, the product runs in the layout its operand has and the one
+    relayout rides in the update that places the gradient in the fused
+    projection's."""
     return _rotate(x, positions, rotary_dims, theta)
 
 
@@ -528,7 +535,8 @@ def _rotate(x, positions, rd, theta):
 
 _rope.defvjp(
     lambda x, positions, rd, theta: (_rotate(x, positions, rd, theta), positions),
-    lambda rd, theta, positions, g: (_rotate(g, -positions, rd, theta), None))
+    lambda rd, theta, positions, g: (
+        jax.lax.optimization_barrier(_rotate(g, -positions, rd, theta)), None))
 
 
 # What a block's backward reads of its forward is named where it is made

@@ -284,9 +284,10 @@ def _run_ring_ulysses(q, k, v, *, causal=True, sm_scale=None):
     return ring_ulysses_attention(q, k, v, causal=causal, sm_scale=sm_scale)
 
 
-def _per_shard(kernel, q):
-    """Run a zoo-layout ([B, T, H, hd]) attention kernel on each device's
-    own batch/head shard.
+def _per_shard(kernel, q, v):
+    """Run a zoo-layout attention kernel (q, k `[B, T, H, hd]`; v and the
+    result like them, or `[B, T, H*hd]`) on each device's own batch/head
+    shard.
 
     A compiled `pallas_call` is an opaque custom call: GSPMD cannot
     partition it, so inside the engine's partitioned `jit` it all-gathers
@@ -294,8 +295,9 @@ def _per_shard(kernel, q):
     interpreter lowers the kernel to ordinary HLO, which GSPMD does
     partition — so virtual-device runs never showed this). `shard_map`
     over the batch axes and the head axis hands the kernel the per-shard
-    operands instead. The sequence dim stays whole: this kernel attends
-    over all of T (context parallelism is the ring programs' job).
+    operands instead (merged heads split as their columns do: a shard's
+    heads are one run of them). The sequence dim stays whole: this kernel
+    attends over all of T (context parallelism is the ring programs' job).
 
     Left bare when there is nothing to split: no mesh, batch and tensor
     axes of size 1, a trace already inside a shard_map body
@@ -315,18 +317,28 @@ def _per_shard(kernel, q):
     if batch is None and heads is None:
         return kernel
     spec = P(batch, None, heads, None)
+    v_spec = spec if v.ndim == 4 else P(batch, None, heads)
     return jax.shard_map(kernel, mesh=mesh_mod.get_mesh(),
-                         in_specs=(spec, spec, spec), out_specs=spec,
+                         in_specs=(spec, spec, v_spec), out_specs=v_spec,
                          check_vma=False)
 
 
 def _run_flash(q, k, v, *, causal=True, sm_scale=None):
     import functools
 
-    from deepspeed_tpu.ops.pallas.flash_attention import flash_attention
+    from deepspeed_tpu.ops.pallas.flash_attention import (
+        flash_attention, flash_heads_in_place)
     kernel = functools.partial(flash_attention, causal=causal,
                                sm_scale=sm_scale)
-    return _per_shard(kernel, q)(q, k, v)
+    form = v.shape
+    if flash_heads_in_place(q.shape[-1]):
+        # the values and the result cross the shard_map as the projections'
+        # own columns: a reshape on either side of that boundary does not
+        # cancel against the model's, and XLA then lays the 4-D tensor out
+        # for it (PERF.md section 7: `[B, T, H, D] <-> [B, T, H*D]` is a
+        # relayout on the TPU)
+        v = v.reshape(v.shape[:2] + (-1,))
+    return _per_shard(kernel, q, v)(q, k, v).reshape(form)
 
 
 register_program(AttentionProgram(

@@ -6,7 +6,25 @@ kernels (`csrc/transformer/softmax_kernels.cu`, sparse/triton attention
 online softmax over KV blocks, O(T) memory, fp32 accumulation, causal masking,
 custom VJP with the standard recomputation backward.
 
-Layout: [B, H, T, D] (wrapper transposes from the zoo's [B, T, H, D]).
+Layout: the kernels walk (batch x head, live tile) and address a head's
+[block, D] tile by an INDEX MAP, in one of two arrays. q, k, dq and dk are
+[BH, T, D], head-major: the zoo hands them over as [B, T, H, D] and the
+wrapper's transpose rides in the fusion that made them (the rotation writes
+that layout for nothing; turned the other way XLA runs the rotation's
+convolution T-minor and pays a transposing copy on each side of it, PERF.md
+section 7). v, o, dO and dv — the tensors between a projection and a kernel
+with nothing in between — are the projections' own [B, T, H*D] where
+`flash_heads_in_place(D)`, D % 128 == 0: head h of a row is column block h,
+whole (16, 128) tiles, tile index (bh // H, tile, bh % H) in place of
+(bh, tile, 0) (`_column_tiles`), and no copy on either side of either pass.
+At any other head width (64, MLA's 192) and for "BHTD" callers (ring
+attention through `flash_attention_with_lse`) all eight are [BH, T, D] and
+the wrapper transposes from the zoo's [B, T, H, D]. The row statistics (lse,
+delta) are [BH, T / block_q, 1, block_q] float32 either way. On the TPU
+[B, T, H, D] <-> [B, T, H*D] is a relayout, not a reshape (the 3-D array
+tiles (T, lanes), the 4-D one (H, D)): nothing on the [B, T, H*D] path is
+ever given four dimensions — `delta`'s sum over a head's lanes is a product
+with a 0/1 [H*D, H] matrix (`_row_dots`).
 
 K/V STREAM from HBM: the grid is (batch x head, live tile) and Pallas's
 pipeline DMAs one double-buffered [block_k, D] (resp. [block_q, D] in the
@@ -112,6 +130,29 @@ def _k_tile(bh, step, qi_ref, ki_ref):
     return (bh, ki_ref[step], 0)
 
 
+def _column_tiles(heads):
+    """(`_q_tile`, `_k_tile`) for a `[B, T, H*D]` array: head h of a row is
+    its column block h, whole lane tiles where `D % 128 == 0`, so the
+    head-major layout is an index map and no copy."""
+
+    def tile_from(table):           # 0: the step's q block, 1: its k block
+        return lambda bh, step, *tables: (bh // heads, tables[table][step],
+                                          bh % heads)
+
+    return tile_from(0), tile_from(1)
+
+
+def _value_side(v, heads):
+    """(the array the kernels address, its q tile map, its k tile map) of a
+    tensor that never passes the rotation (v, o, dO, dv): `[B, H, T, D]` as
+    `[BH, T, D]` under `_q_tile` / `_k_tile` (`heads` None), or `[B, T, H*D]`
+    as it is under `_column_tiles`."""
+    if heads is None:
+        B, H, T, D = v.shape
+        return v.reshape(B * H, T, D), _q_tile, _k_tile
+    return (v,) + _column_tiles(heads)
+
+
 def _last_k_tile(causal, qi, block_q, block_k, nk):
     """The last k tile q block `qi` walks: the one its last row's own
     position falls in, or the sequence's last."""
@@ -207,10 +248,12 @@ def _fwd_kernel(qi_ref, ki_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref,
         lse_ref[...] = jnp.transpose(m_ref[...] + jnp.log(l_safe))[0:1, :]
 
 
-def _flash_fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret):
+def _flash_fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret,
+               heads=None):
     B, H, T, D = q.shape
     BH = B * H
-    q2, k2, v2 = (x.reshape(BH, T, D) for x in (q, k, v))
+    q2, k2 = (x.reshape(BH, T, D) for x in (q, k))
+    v2, vq_tile, vk_tile = _value_side(v, heads)
     qi, ki = _tile_tables(T, block_q, block_k, causal)
 
     out, lse = pl.pallas_call(
@@ -222,10 +265,10 @@ def _flash_fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret):
             in_specs=[
                 pl.BlockSpec((None, block_q, D), _q_tile),
                 pl.BlockSpec((None, block_k, D), _k_tile),
-                pl.BlockSpec((None, block_k, D), _k_tile),
+                pl.BlockSpec((None, block_k, D), vk_tile),
             ],
             out_specs=[
-                pl.BlockSpec((None, block_q, D), _q_tile),
+                pl.BlockSpec((None, block_q, D), vq_tile),
                 _row_stat_spec(block_q),
             ],
             scratch_shapes=[
@@ -234,13 +277,13 @@ def _flash_fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret):
                 pltpu.VMEM((block_q, _LANES), jnp.float32),
             ]),
         out_shape=[
-            jax.ShapeDtypeStruct((BH, T, D), q.dtype),
+            jax.ShapeDtypeStruct(v2.shape, q.dtype),
             jax.ShapeDtypeStruct((BH, T // block_q, 1, block_q), jnp.float32),
         ],
         interpret=interpret,
         name="dstpu_flash_fwd",
     )(qi, ki, q2, k2, v2)
-    return out.reshape(B, H, T, D), lse
+    return out.reshape(v.shape), lse
 
 
 # ----------------------------------------------------------------------
@@ -331,27 +374,46 @@ def _bwd_dkv_kernel(qi_ref, ki_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
         dv_ref[...] = dv_acc_ref[...].astype(dv_ref.dtype)
 
 
+def _row_dots(do, o, heads):
+    """delta, each row's `sum(dO * o)` over its head's columns, float32
+    `[B, H, T]`: of `[B, H, T, D]` operands (`heads` None), or of
+    `[B, T, H*D]` ones, where the sum over a head's lanes is a product with a
+    constant 0/1 `[H*D, H]` matrix at float32 precision — a reshape to
+    `[.., H, D]` would bring back the relayout of dO and o the index maps
+    spare (PERF.md section 7). Reading dO and o once is its whole cost on
+    the chip."""
+    prod = do.astype(jnp.float32) * o.astype(jnp.float32)
+    if heads is None:
+        return jnp.sum(prod, axis=-1)
+    head_of = np.arange(prod.shape[-1]) // (prod.shape[-1] // heads)
+    pick = jnp.asarray(head_of[:, None] == np.arange(heads), jnp.float32)
+    return jnp.swapaxes(
+        jnp.matmul(prod, pick, precision=jax.lax.Precision.HIGHEST), 1, 2)
+
+
 def _flash_bwd(res, g, sm_scale, causal, block_q, block_k, interpret,
-               delta_adjust=None):
+               delta_adjust=None, heads=None):
     q, k, v, o, lse = res
     do = g
     B, H, T, D = q.shape
     BH = B * H
-    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)  # [B,H,T]
+    delta = _row_dots(do, o, heads)                               # [B,H,T]
     if delta_adjust is not None:
         # lse cotangent: d lse/d s = p, so ds = p*(dp - delta + dlse) — i.e.
         # the existing kernels run unchanged with delta' = delta - dlse
         delta = delta - delta_adjust
 
-    q2, k2, v2, do2 = (x.reshape(BH, T, D) for x in (q, k, v, do))
+    q2, k2 = (x.reshape(BH, T, D) for x in (q, k))
+    v2, vq_tile, vk_tile = _value_side(v, heads)
+    do2 = _value_side(do, heads)[0]
     Tb = T // block_q
     delta2 = delta.reshape(BH, Tb, 1, block_q)          # lse: [BH, Tb, 1, block_q]
     operands = (q2, k2, v2, do2, lse, delta2)
     in_specs = [
         pl.BlockSpec((None, block_q, D), _q_tile),
         pl.BlockSpec((None, block_k, D), _k_tile),
-        pl.BlockSpec((None, block_k, D), _k_tile),
-        pl.BlockSpec((None, block_q, D), _q_tile),
+        pl.BlockSpec((None, block_k, D), vk_tile),
+        pl.BlockSpec((None, block_q, D), vq_tile),
         _row_stat_spec(block_q),
         _row_stat_spec(block_q),
     ]
@@ -381,7 +443,7 @@ def _flash_bwd(res, g, sm_scale, causal, block_q, block_k, interpret,
             in_specs=in_specs,
             out_specs=[
                 pl.BlockSpec((None, block_k, D), _k_tile),
-                pl.BlockSpec((None, block_k, D), _k_tile),
+                pl.BlockSpec((None, block_k, D), vk_tile),
             ],
             scratch_shapes=[
                 pltpu.VMEM((block_k, D), jnp.float32),
@@ -389,13 +451,13 @@ def _flash_bwd(res, g, sm_scale, causal, block_q, block_k, interpret,
             ]),
         out_shape=[
             jax.ShapeDtypeStruct((BH, T, D), q.dtype),
-            jax.ShapeDtypeStruct((BH, T, D), q.dtype),
+            jax.ShapeDtypeStruct(v2.shape, q.dtype),
         ],
         interpret=interpret,
         name="dstpu_flash_dkv",
     )(qi, ki, *operands)
 
-    return (dq.reshape(B, H, T, D), dk.reshape(B, H, T, D), dv.reshape(B, H, T, D))
+    return dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape)
 
 
 # ----------------------------------------------------------------------
@@ -403,9 +465,10 @@ def _flash_bwd(res, g, sm_scale, causal, block_q, block_k, interpret,
 # ----------------------------------------------------------------------
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
-def _flash(q, k, v, sm_scale, causal, block_q, block_k, interpret):
-    out, _ = _flash_fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+def _flash(q, k, v, sm_scale, causal, block_q, block_k, interpret, heads):
+    out, _ = _flash_fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret,
+                        heads)
     return out
 
 
@@ -415,15 +478,19 @@ def _flash(q, k, v, sm_scale, causal, block_q, block_k, interpret):
 FLASH_RESIDUALS = "flash_residuals"
 
 
-def _flash_vjp_fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret):
-    out, lse = _flash_fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret)
+def _flash_vjp_fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret,
+                   heads):
+    out, lse = _flash_fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret,
+                          heads)
     out = checkpoint_name(out, FLASH_RESIDUALS)
     lse = checkpoint_name(lse, FLASH_RESIDUALS)
     return out, (q, k, v, out, lse)
 
 
-def _flash_vjp_bwd(sm_scale, causal, block_q, block_k, interpret, res, g):
-    return _flash_bwd(res, g, sm_scale, causal, block_q, block_k, interpret)
+def _flash_vjp_bwd(sm_scale, causal, block_q, block_k, interpret, heads, res,
+                   g):
+    return _flash_bwd(res, g, sm_scale, causal, block_q, block_k, interpret,
+                      heads=heads)
 
 
 _flash.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
@@ -523,9 +590,20 @@ def flash_attention_with_lse(q, k, v, causal=True, sm_scale=None, block_q=None,
     return out, lse.reshape(B, H, T)
 
 
+def flash_heads_in_place(d_head):
+    """True when a head of a `[B, T, H*D]` array is a column block of whole
+    lane tiles, so the kernels address it there by an index map
+    (`_column_tiles`) and the values, the output and their gradients cross
+    the kernels' boundary without a copy."""
+    return d_head % _LANES == 0
+
+
 def flash_attention(q, k, v, causal=True, sm_scale=None, block_q=None,
                     block_k=None, layout="BTHD", interpret=None):
     """Flash attention. q,k,v: [B,T,H,D] ("BTHD", zoo layout) or [B,H,T,D].
+    Under "BTHD" with `flash_heads_in_place(D)` v may also come with its
+    heads merged, [B,T,H*D] (what the projection's columns are); the result
+    has v's form.
 
     Sequence length must be a multiple of the block size (the zoo pads to 128
     multiples; MXU-friendly anyway) and is otherwise bounded only by HBM
@@ -538,15 +616,27 @@ def flash_attention(q, k, v, causal=True, sm_scale=None, block_q=None,
     """
     if interpret is None:
         interpret = pallas_interpret()
+    heads, form = None, v.shape
     if layout == "BTHD":
-        q, k, v = (jnp.swapaxes(x, 1, 2) for x in (q, k, v))
+        q, k = (jnp.swapaxes(x, 1, 2) for x in (q, k))
+        if flash_heads_in_place(q.shape[-1]):
+            heads = q.shape[1]
+            v = v.reshape(v.shape[:2] + (-1,))
+        elif v.ndim == 4:
+            v = jnp.swapaxes(v, 1, 2)
+        else:
+            raise ValueError(
+                f"v with merged heads {v.shape} needs a head width of whole "
+                f"lane tiles (`flash_heads_in_place`), not {q.shape[-1]}")
     B, H, T, D = q.shape
     block_q, block_k = _default_blocks(T, D * q.dtype.itemsize, block_q,
                                        block_k)
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(D)
     out = _flash(q, k, v, float(sm_scale), bool(causal), int(block_q), int(block_k),
-                 bool(interpret))
+                 bool(interpret), heads)
+    if heads is not None:
+        return out.reshape(form)
     if layout == "BTHD":
         out = jnp.swapaxes(out, 1, 2)
     return out

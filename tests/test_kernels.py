@@ -213,6 +213,78 @@ class TestFlashAttention:
         ref = jnp.swapaxes(_ref_attention(*(jnp.swapaxes(x, 1, 2) for x in (q, k, v))), 1, 2)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=2e-3, atol=2e-3)
 
+    # (T, H, D, causal, merged v): a head 128 lanes wide is a column block of
+    # `[B, T, H*D]` and the kernels address v, o, dO and dv there; at 64 the
+    # copies stay
+    @pytest.mark.parametrize("T,H,D,causal,merged", [
+        (256, 2, 128, True, False),
+        (256, 2, 128, False, False),
+        (2048, 16, 128, True, False),
+        (2048, 16, 128, False, True),
+        (256, 2, 256, True, True),
+        (256, 2, 64, True, False),
+    ])
+    def test_heads_in_place_equal_the_head_major_copies(self, T, H, D, causal,
+                                                        merged):
+        """`flash_attention(layout="BTHD")` where `flash_heads_in_place(D)`:
+        the forward BIT FOR BIT what the `[B, H, T, D]` path gives on the
+        same inputs, the three gradients within the kernels' own tolerance
+        (`delta`'s sum runs in another order); v and the result may cross
+        with their heads merged; the kernels' operands are the projections'
+        own `[B, T, H*D]` arrays exactly where a head is whole lane tiles."""
+        from deepspeed_tpu.ops.pallas.flash_attention import (
+            flash_attention, flash_heads_in_place)
+        rng = np.random.default_rng(55)
+        q, k, v, w = (jnp.asarray(rng.normal(0, 1, (1, T, H, D)), jnp.float32)
+                      for _ in range(4))
+        assert flash_heads_in_place(D) == (D % 128 == 0)
+
+        def in_place(q, k, v):
+            if merged:
+                return flash_attention(q, k, v.reshape(1, T, H * D),
+                                       causal=causal).reshape(1, T, H, D)
+            return flash_attention(q, k, v, causal=causal)
+
+        def head_major(q, k, v):
+            return jnp.swapaxes(flash_attention(
+                *(jnp.swapaxes(x, 1, 2) for x in (q, k, v)), causal=causal,
+                layout="BHTD"), 1, 2)
+
+        np.testing.assert_array_equal(np.asarray(in_place(q, k, v)),
+                                      np.asarray(head_major(q, k, v)))
+        grads = [jax.grad(lambda q, k, v: jnp.sum(f(q, k, v) * w),
+                          argnums=(0, 1, 2))(q, k, v)
+                 for f in (in_place, head_major)]
+        for a, b, name in zip(*grads, "qkv"):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=5e-3, atol=5e-3, err_msg=f"d{name}")
+
+        # what the three kernels are handed: (shape, ...) of each call
+        handed = [[tuple(x.aval.shape) for x in eqn.invars]
+                  for eqn in _pallas_calls(jax.make_jaxpr(jax.grad(
+                      lambda q, k, v: jnp.sum(in_place(q, k, v) * w),
+                      argnums=(0, 1, 2)))(q, k, v).jaxpr)]
+        assert len(handed) == 3
+        for shapes in handed:
+            # q and k head-major (the rotation writes that for nothing); v —
+            # and dO behind it in the backward — as the columns they are
+            assert shapes[2:4] == [(H, T, D)] * 2
+            want = (1, T, H * D) if D % 128 == 0 else (H, T, D)
+            assert shapes[4] == want and (len(shapes) == 5
+                                          or shapes[5] == want)
+        if D % 128:
+            with pytest.raises(ValueError, match="whole lane tiles"):
+                flash_attention(q, k, v.reshape(1, T, H * D))
+
+
+def _pallas_calls(jaxpr):
+    """Every `pallas_call` equation of a jaxpr, nested ones included."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _pallas_calls(sub)
+
 
 class TestDecodeStreaming:
     """Blocked HBM-streaming decode attention (`ops/pallas/decode_attention`):

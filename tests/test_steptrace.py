@@ -1066,16 +1066,41 @@ def test_the_delta_rule_update_is_one_aliased_call_on_the_same_shell(
     assert 2 * 4 * row < used <= limit
 
 
+def _compiled_training_gradient(one_chip, monkeypatch, **model):
+    """The compiled text of a remat'd GPT loss's gradient — two layers, two
+    heads of 128, `[2, 2048]` tokens — for the described chip."""
+    from deepspeed_tpu.models.gpt import gpt_init_fn, gpt_loss
+    from deepspeed_tpu.platform import device
+    mesh_mod.clear_mesh()
+    monkeypatch.setattr(device, "on_tpu", lambda: True)   # what the chip sees
+    cfg = GPTConfig(n_layer=2, n_head=2, d_model=256, max_seq_len=2048,
+                    vocab_size=512, dtype=jnp.bfloat16, remat=True, **model)
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    params = on_chip(jax.eval_shape(gpt_init_fn(cfg, dtype=jnp.bfloat16),
+                                    jax.random.PRNGKey(0)))
+    toks = jax.ShapeDtypeStruct((2, 2048), jnp.int32, sharding=one_chip)
+    return jax.jit(jax.grad(lambda p, b: gpt_loss(p, b, None, cfg))).lower(
+        params, {"tokens": toks, "labels": toks}).compile().as_text()
+
+
+# Pythia's half: a partial rotation, both halves off the block's input
+_PYTHIA_LIKE = dict(use_rotary=True, rotary_pct=0.25, parallel_residual=True)
+
+
+@pytest.mark.parametrize("model", [{}, _PYTHIA_LIKE], ids=["plain", "rotary"])
 def test_training_step_holds_the_three_flash_kernels_by_result_signature(
-        one_chip, monkeypatch):
+        one_chip, monkeypatch, model):
     """`flash_roofline.train` tells the three flash kernels apart by the
     RESULT of their custom call (`benchmark/layer_metrics/flash_roofline.
     train.json`: fwd a tuple that starts bf16, f32; dkv bf16, bf16; dq a
     single bf16) and counts products by the calls it saw. So the compiled
     training step — a remat'd GPT loss and its gradient — must hold exactly
-    the three kernels, each matched by its own pattern and by no other."""
-    from deepspeed_tpu.models.gpt import gpt_init_fn, gpt_loss
-    from deepspeed_tpu.platform import device
+    the three kernels, each matched by its own pattern and by no other,
+    whatever the shapes their operands and results have."""
     bench = os.path.join(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))), "benchmark")
     sys.path.insert(0, bench)
@@ -1088,28 +1113,66 @@ def test_training_step_holds_the_three_flash_kernels_by_result_signature(
         patterns = json.load(f)["args"]["kernels"]
     assert set(patterns) == {"fwd", "dq", "dkv"}
 
-    mesh_mod.clear_mesh()
-    monkeypatch.setattr(device, "on_tpu", lambda: True)   # what the chip sees
-    cfg = GPTConfig(n_layer=2, n_head=2, d_model=256, max_seq_len=2048,
-                    vocab_size=512, dtype=jnp.bfloat16, remat=True)
-
-    def on_chip(tree):
-        return jax.tree_util.tree_map(lambda a: jax.ShapeDtypeStruct(
-            a.shape, a.dtype, sharding=one_chip), tree)
-
-    params = on_chip(jax.eval_shape(gpt_init_fn(cfg, dtype=jnp.bfloat16),
-                                    jax.random.PRNGKey(0)))
-    toks = jax.ShapeDtypeStruct((2, 2048), jnp.int32, sharding=one_chip)
+    text = _compiled_training_gradient(one_chip, monkeypatch, **model)
     # an operation as the reduced trace labels it
-    labels = [xplane.parse_op(line.removeprefix("ROOT "))[0]
-              for line in _kernel_lines(
-                  jax.grad(lambda p, b: gpt_loss(p, b, None, cfg)),
-                  params, {"tokens": toks, "labels": toks})]
+    labels = [xplane.parse_op(line.strip().removeprefix("ROOT "))[0]
+              for line in text.splitlines()
+              if "custom-call(" in line and "tpu_custom_call" in line]
     assert {lab.split(".")[0] for lab in labels} == {
         "dstpu_flash_fwd", "dstpu_flash_dq", "dstpu_flash_dkv"}
     for lab in labels:
         kinds = [k for k, rx in patterns.items() if re.search(rx, lab)]
         assert kinds == [lab.split(".")[0].removeprefix("dstpu_flash_")], lab
+
+
+def _relayouts(text, *scopes):
+    """(name, result) of every instruction outside a fused computation that
+    only lays a tensor out anew — a `copy`, a `transpose`, or a fusion the
+    compiler named for one (`copy_bitcast_fusion`, `transpose_...`) — and
+    whose `op_name` ends in one of `scopes`."""
+    found = []
+    for comp, lines in _computations(text).items():
+        if "fused_computation" in comp:
+            continue
+        for line in lines:
+            parsed = _HLO_LINE.match(line)
+            scope = re.search(r'op_name="([^"]*)"', line)
+            if not parsed or not scope or not scope.group(1).endswith(scopes):
+                continue
+            name, result, opcode = parsed.groups()
+            if opcode in ("copy", "transpose") or opcode == "fusion" and \
+                    name.startswith(("copy", "transpose")):
+                found.append((name, result))
+    return found
+
+
+def test_training_step_lays_nothing_out_anew_at_the_flash_kernels_boundary(
+        one_chip, monkeypatch):
+    """At a head width of 128 the flash kernels address v, o, dO and dv in
+    the projections' own `[B, T, H*D]` arrays (`flash_attention.py::
+    _column_tiles`) and q, k, dq, dk head-major as the rotation writes and
+    reads them, so the compiled gradient of a remat'd Pythia-like block
+    holds no relayout named for the kernels' boundary — `attn/transpose`,
+    `attn/reshape`: the parent held five there (dO head-major, o back, dq's
+    and dk's way into a T-minor rotation) — and none under
+    `attn/qkv/reshape` (the parent's seven: v and its recomputed copy, dv,
+    the rotation's two results turned `[B, T, H*D]`, ...). What is left
+    rides in a fusion that does other work: q's and k's relayout in the
+    rotation's (forward and recomputed), dq's and dk's in the update that
+    places them in the product's gradient (`models/gpt.py::_rope`'s
+    backward holds its result behind an `optimization_barrier`, PERF.md
+    section 7)."""
+    text = _compiled_training_gradient(one_chip, monkeypatch, **_PYTHIA_LIKE)
+    assert _relayouts(text, "attn/transpose", "attn/reshape",
+                      "attn/qkv/reshape") == []
+    # ... and the rotation still is one fusion a tensor with its matrix
+    # product inside: q and k, forward and recomputed, dq and dk
+    rotations = [
+        line for comp, lines in _computations(text).items()
+        if "fused_computation" not in comp for line in lines
+        if " fusion(" in line and "attn/qkv/dot_general" in line
+        and re.search(r"= bf16\[2,2048,2,128\]", line)]
+    assert len(rotations) == 6, rotations
 
 
 # ----------------------------------------------------------------------
