@@ -25,6 +25,11 @@ family's `residual_multiplier`, `f` by the half's letter:
                   per-head q/k norm and output gate where the family has them
     E  the family's expert half (`expert_half`): Nemotron-H's LatentMoE,
        Granite's gated experts beside a shared one
+    F  a dense gated feed-forward, (silu(u W_g) * (u W_u)) W_d: `gpt.py::_mlp`
+
+Under `cfg.post_norm` (the Olmo-2 order) a half reads the stream as it is
+and its OUTPUT is normed: `x <- x + r * RMSNorm(f(x))`, the half's one norm
+scale on the other side of `f`.
 
 What a family gives: `HybridConfig.pattern`, a BLOCK a layer, each block a
 string of its halves' letters ("EMEM*": a half a layer; ("ME", "ME", "*E"):
@@ -58,23 +63,40 @@ scalars (`residual_multiplier` here, `scale_attn`, `embedding_multiplier`,
   this chip holds `experts_held = (first, count)`, and what the others would
   add is left out, here and in the references alike.
 
-Not here: training, the contiguous-cache `generate()` path, and on a pool
-with a state kind the int8 pool, prefix caching, speculative verify and block
-transplant (`ServingEngine` refuses them with the reason).
+TRAINING (`hybrid_loss`, `hybrid_param_specs`; `models/olmo_hybrid.py` is
+the family that trains): the same halves over whole sequences from a zero
+state, a `jax.checkpoint` a HALF that holds nothing (a recurrent half: a
+segment of 4096 positions of it, on the carried state), a run's repeats
+scanned, the head through `ops/chunked_ce.py`; the delta rule's chunked scan
+brings its own backward (`ops/pallas/gdn.py`). What does not train: the
+expert half `E` (its halves count what they route, which has no gradient
+path here), packed documents (a state reset and a block-diagonal mask at a
+boundary), the recurrent halves under sequence parallelism (a chunk's state
+would cross devices).
+
+Not here: the contiguous-cache `generate()` path, a decode spec for a stack
+with `F` halves or `post_norm`, and on a pool with a state kind the int8 pool,
+prefix caching, speculative verify and block transplant (`ServingEngine`
+refuses them with the reason).
 """
 
 import copy
 import dataclasses
 import math
 from functools import partial
-from typing import Any, Optional, Tuple
+from typing import Any, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
 from deepspeed_tpu.inference.kv_cache import CacheKind
+from jax.sharding import PartitionSpec as P
+
+from deepspeed_tpu.comm.mesh import (BATCH_AXES, SEQ_AXIS, TENSOR_AXIS,
+                                     shard_constraint)
 from deepspeed_tpu.models.gpt import (MixedTables, _attn_half, _embed,
-                                      _last_rows, _lm_head, _norm,
+                                      _half_input, _head_table, _last_rows,
+                                      _lm_head, _mlp, _norm,
                                       _paged_attn_half, decode_rows,
                                       make_mixed_paged_fn, offset_tables,
                                       over_chunk_group)
@@ -84,7 +106,7 @@ from deepspeed_tpu.ops import attention_dispatch as attn_dispatch
 from deepspeed_tpu.ops.pallas import gdn, ssm
 from deepspeed_tpu.parallel.moe import HELD_ROUTED_COUNTERS
 
-MAMBA, DELTANET, ATTENTION, MOE = "M", "D", "*", "E"
+MAMBA, DELTANET, ATTENTION, MOE, DENSE = "M", "D", "*", "E", "F"
 
 
 @dataclasses.dataclass
@@ -101,6 +123,9 @@ class HybridConfig(MoEGPTConfig):
     gdn_value_heads: int = 32           # serve H value heads, of widths
     gdn_key_dim: int = 128              # K and
     gdn_value_dim: int = 128            # V
+    gdn_beta_scale: float = 1.0         # beta = this * sigmoid(b): 2.0 lets
+                                        # I - beta k k^T take a NEGATIVE
+                                        # eigenvalue, 1 - beta in (-1, 1)
     rotary_attention: bool = False      # the attention halves rotate q and k
     shared_d_ff: int = 0                # the shared expert's, at full width
     experts_held: Optional[Tuple[int, int]] = None  # (first, count); None=all
@@ -114,24 +139,25 @@ class HybridConfig(MoEGPTConfig):
         # a model WITHOUT learned positions; the attention halves are traced
         # on a copy with it off (`_attention_cfg`): they rotate nothing
         self.use_rotary = self.use_rmsnorm = True
-        self.post_norm = self.use_alibi = False
-        self.qk_norm = self.qk_norm_per_head = False
-        self.sliding_window = None
+        self.use_alibi, self.sliding_window = False, None
         self.moe_freq, self.n_layer = 1, len(self.pattern)
         super().__post_init__()
         if not self.pattern \
-                or set(self.halves) - {MAMBA, DELTANET, ATTENTION, MOE} \
+                or set(self.halves) - {MAMBA, DELTANET, ATTENTION, MOE,
+                                       DENSE} \
                 or {MAMBA, DELTANET} <= set(self.halves):
             raise ValueError(f"pattern {self.pattern!r}: a letter a layer, "
                              f"or a block of them, of {MAMBA!r} or "
                              f"{DELTANET!r} (one recurrent kind a stack), "
-                             f"{ATTENTION!r}, {MOE!r}")
+                             f"{ATTENTION!r}, {MOE!r}, {DENSE!r}")
         if self.mamba_num_heads % self.n_groups \
                 or self.ssm_inner % self.n_groups \
                 or self.gdn_value_heads % self.gdn_key_heads:
             raise ValueError("mamba_num_heads and the inner width divide "
                              "into n_groups, gdn_value_heads into "
                              "gdn_key_heads")
+        if MOE not in self.halves:      # no expert half: no experts asked for
+            return
         if self.experts_held is None:
             self.experts_held = (0, self.num_experts)
         first, count = self.experts_held
@@ -209,7 +235,7 @@ def stream_range(cfg: HybridConfig):
 
 
 def mixer_shapes(cfg: HybridConfig, kind):
-    """A recurrent or attention half's leaves -> (shape, init: a float =
+    """A recurrent, attention or dense half's leaves -> (shape, init: a float =
     normal of that range, 1.0 = ones, 0.0 = zeros, a name = `make_layer`'s
     own rule). A family's `layer_shapes(cfg, kind, router_std)` adds its
     expert half's."""
@@ -225,6 +251,15 @@ def mixer_shapes(cfg: HybridConfig, kind):
         if cfg.qk_norm_per_head:
             shapes.update({"q_norm_scale": ((hd,), 1.0),
                            "k_norm_scale": ((hd,), 1.0)})
+        if cfg.qk_norm:                 # over the whole projection
+            shapes.update({"q_norm_scale": ((cfg.n_head * hd,), 1.0),
+                           "k_norm_scale": ((cfg.n_kv_head * hd,), 1.0)})
+    elif kind == DENSE:
+        shapes.update({
+            "mlp_gate_w": ((D, cfg.d_ff), 0.02),
+            "mlp_up_w": ((D, cfg.d_ff), 0.02),
+            "mlp_down_w": ((cfg.d_ff, D), down),
+            "mlp_out_b": ((D,), 0.0)})
     elif kind == MAMBA:
         H, inner, W = cfg.mamba_num_heads, cfg.ssm_inner, cfg.conv_width
         shapes.update({
@@ -354,6 +389,16 @@ def _skip(y, x, p):
     return y + p["ssm_D"][:, None] * x.astype(jnp.float32)
 
 
+class Carried(NamedTuple):
+    """A recurrent half's cache as the two arrays of ONE call's sequences,
+    carried by the caller itself (a training step walking a long sequence a
+    segment at a time) and not rows of a pool: `state` [b, ...] float32,
+    `state_leaves`' `ssm` shape; `tail` [b, K - 1, W], the convolution's
+    last inputs. Every position of a chunk on it is real."""
+    state: Any
+    tail: Any
+
+
 def _chunk_start(conv_in, cfg, cache, rows, start):
     """What a chunk of b sequences starts from: conv_in [b, T, W], the
     convolution's inputs at its positions -> (seq [b, K - 1 + T, W]: the
@@ -361,6 +406,9 @@ def _chunk_start(conv_in, cfg, cache, rows, start):
     float32, the state before it, `state_leaves`' shape). `cache`: the
     carried `(ssm, conv)` pair, rows `rows` [b] of it this call's (None: no
     cache, every sequence from zero — the whole-sequence forward)."""
+    if isinstance(cache, Carried):
+        return jnp.concatenate([cache.tail.astype(conv_in.dtype), conv_in],
+                               axis=1), cache.state
     b, _, W = conv_in.shape
     tail = jnp.zeros((b, cfg.conv_kernel - 1, W), conv_in.dtype)
     S = jnp.zeros((b,) + state_leaves(cfg)["ssm"], jnp.float32)
@@ -379,6 +427,8 @@ def _chunk_end(seq, S, cfg, cache, rows, valid):
     chunks' too) of `seq`, where the first `valid` [b] positions are real."""
     if cache is None:
         return None
+    if isinstance(cache, Carried):
+        return Carried(S, seq[:, seq.shape[1] - (cfg.conv_kernel - 1):])
     tail = jax.vmap(lambda s, n: jax.lax.dynamic_slice_in_dim(
         s, n, cfg.conv_kernel - 1))(seq, valid)
     return (ssm.state_write(cache[0], rows, S),
@@ -440,6 +490,8 @@ def _recurrent(proj, chunk, token, cache, rows, positions, valid):
     slots'. `valid` [chunks]: a chunk's real positions (default: all)."""
     B, T = proj[0].shape[:2]
     part = lambda *at: tuple(a[at] for a in proj)
+    if isinstance(cache, Carried):      # the caller's own state: a chunk
+        return chunk(proj, cache, None, None, jnp.full((B,), T, jnp.int32))
     if isinstance(rows, MixedTables):
         # a chunk's rows [1, C, .], then a row a slot: the chunk first, whole
         # (its state read, scanned and written back), then the slots' token
@@ -480,7 +532,7 @@ def _mamba_half(x, p, cfg, cache=None, rows=None, positions=None, valid=None):
     """`f` of a Mamba-2 half on x [B, T, D] -> (f(RMSNorm(x)), cache);
     `cache`, `rows`, `positions`, `valid`: `_recurrent`'s."""
     B, T, _ = x.shape
-    u = _norm(x, p["ln1_scale"], None, True, cfg.norm_eps)
+    u = _half_input(x, p, cfg)
     with jax.named_scope("in_proj"):
         # xBC and dt are read at the half's start, z at its end: the barrier
         # HOLDS the product between them — left alone, XLA frees it after the
@@ -511,7 +563,7 @@ def _gdn_inputs(qkv, ba, p, cfg):
     """The convolved `[q | k | v]` [.., W] and raw `[b | a]` [.., 2 H] -> (q,
     k [.., G, K] float32, each key head's of unit length, q over sqrt(K)
     besides; v [.., H, V]; g [.., H] float32 log-decay; beta [.., H]
-    float32)."""
+    float32, `cfg.gdn_beta_scale` times a sigmoid)."""
     G, H = cfg.gdn_key_heads, cfg.gdn_value_heads
     K, V = cfg.gdn_key_dim, cfg.gdn_value_dim
     lead = qkv.shape[:-1]
@@ -523,8 +575,11 @@ def _gdn_inputs(qkv, ba, p, cfg):
 
     b, a = jnp.split(ba.astype(jnp.float32), 2, axis=-1)
     g = -jnp.exp(p["A_log"]) * jax.nn.softplus(a + p["dt_bias"])
-    return (unit(q) * K ** -0.5, unit(k), v.reshape(lead + (H, V)), g,
-            jax.nn.sigmoid(b))
+    q, k, v = unit(q) * K ** -0.5, unit(k), v.reshape(lead + (H, V))
+    beta = jax.nn.sigmoid(b)
+    if cfg.gdn_beta_scale != 1.0:
+        beta = beta * cfg.gdn_beta_scale
+    return q, k, v, g, beta
 
 
 def _gdn_chunk(p, cfg, proj, cache, rows, start, valid):
@@ -560,7 +615,7 @@ def _gdn_half(x, p, cfg, cache=None, rows=None, positions=None, valid=None):
     cache); `cache`, `rows`, `positions`, `valid`: `_recurrent`'s."""
     B, T, _ = x.shape
     H, V = cfg.gdn_value_heads, cfg.gdn_value_dim
-    u = _norm(x, p["ln1_scale"], None, True, cfg.norm_eps)
+    u = _half_input(x, p, cfg)
     with jax.named_scope("in_proj"):
         # q, k and v are read at the half's start, z at its end: held, as
         # `_mamba_half` holds its product and for its reason
@@ -586,8 +641,12 @@ def _gdn_half(x, p, cfg, cache=None, rows=None, positions=None, valid=None):
 RECURRENT = {MAMBA: ("ssm", _mamba_half), DELTANET: ("gdn", _gdn_half)}
 
 
-def _residual(x, out, cfg):
-    """`x + r * out`; a family without the multiplier adds `out` as it is."""
+def _residual(x, out, cfg, p):
+    """`x + r * out`; a family without the multiplier adds `out` as it is.
+    Under `cfg.post_norm` the half `p` read the stream un-normed
+    (`gpt.py::_half_input`) and its norm is taken HERE, of what it gives."""
+    if cfg.post_norm:
+        out = _norm(out, p["ln1_scale"], None, True, cfg.norm_eps)
     if cfg.residual_multiplier != 1.0:
         out = out * cfg.residual_multiplier
     return x + out
@@ -607,28 +666,188 @@ def _layers(params, cfg):
                 yield kind, jax.tree_util.tree_map(lambda a: a[n], tree)
 
 
-def hybrid_forward(params, tokens, cfg: HybridConfig, expert_half,
+def _half(x, p, kind, cfg, acfg, positions, expert_half=None, routing=None,
+          constrain=False):
+    """One half of a whole-sequence forward from a zero state: x [B, T, D]
+    -> x after it."""
+    if kind == ATTENTION:
+        with jax.named_scope("attn"):
+            out, _, _ = _attn_half(x, p, acfg, positions,
+                                   constrain=constrain)
+            return _residual(x, out, cfg, p)
+    if kind in RECURRENT:
+        scope, mixer = RECURRENT[kind]
+        with jax.named_scope(scope):
+            return _residual(x, mixer(x, p, cfg)[0], cfg, p)
+    with jax.named_scope("mlp"):
+        if kind == DENSE:
+            out = _mlp(_half_input(x, p, cfg), p, cfg, constrain)
+            return _residual(x, out, cfg, p)
+        out, _, top_e = expert_half(x, p, cfg)
+        if routing is not None:
+            routing.append(top_e)
+        return _residual(x, out, cfg, p)
+
+
+def _positions(tokens):
+    B, T = tokens.shape
+    return jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32)[None], (B, T))
+
+
+def hybrid_forward(params, tokens, cfg: HybridConfig, expert_half=None,
                    routing=None):
     """tokens [B, T] -> logits [B, T, V]: dense masked attention, the scan
     from a zero state, a Python loop over the halves. `routing`: a list that
     takes each expert half's chosen experts [B*T, top_k]."""
-    B, T = tokens.shape
-    positions = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32)[None], (B, T))
+    positions = _positions(tokens)
     acfg = _attention_cfg(cfg)
     x = _embed(params, tokens, positions, cfg)
     for kind, p in _layers(params, cfg):
-        if kind == ATTENTION:
-            out, _, _ = _attn_half(x, p, acfg, positions, constrain=False)
-        elif kind in RECURRENT:
-            scope, mixer = RECURRENT[kind]
-            with jax.named_scope(scope):
-                out, _ = mixer(x, p, cfg)
-        else:
-            out, _, top_e = expert_half(x, p, cfg)
-            if routing is not None:
-                routing.append(top_e)
-        x = _residual(x, out, cfg)
+        x = _half(x, p, kind, cfg, acfg, positions, expert_half, routing)
     return _lm_head(params, x, cfg)
+
+
+# ----------------------------------------------------------------------
+# training: the same halves, a `jax.checkpoint` a half, a run scanned
+# ----------------------------------------------------------------------
+
+# A block of the training step is a HALF — of a recurrent half, a SEGMENT of
+# it — under a `jax.checkpoint` that holds NOTHING: at 32768 positions a
+# sequence the one block whose backward is running takes what the step's
+# state leaves of a 16 GB chip (a layer's two halves together do not fit, nor
+# does a Gated DeltaNet half whole: PERF.md section 6, PR 56), so every block
+# is made again from its input.
+
+# positions a block of a recurrent half: a longer sequence runs the half a
+# segment at a time on the carried state and convolution tail (`Carried`), as
+# the serving path runs a prompt a chunk at a time
+SEGMENT = 4096
+
+# a chunk of the head's logits, float32 `[tokens, V / chunks]`, is held to
+# about this many bytes (`ops/chunked_ce.py`)
+_LOSS_CHUNK_BYTES = 1 << 28
+
+
+def _in_segments(x, p, kind, cfg, remat):
+    """A recurrent half AND its residual on x [B, T, D] from a zero state,
+    `SEGMENT` positions a block under `remat`: the whole segments scanned,
+    then what is left of T as a last, shorter block on the same carried
+    state (a block's size never follows T's remainder)."""
+    scope, mixer = RECURRENT[kind]
+    B, T, D = x.shape
+    whole = T // SEGMENT * SEGMENT
+
+    @remat
+    def segment(carry, x):
+        with jax.named_scope(scope):
+            out, carry = mixer(x, p, cfg, carry)
+            return carry, _residual(x, out, cfg, p)
+
+    leaves = state_leaves(cfg)
+    carry = Carried(jnp.zeros((B,) + leaves["ssm"], jnp.float32),
+                    jnp.zeros((B,) + leaves["conv"], x.dtype))
+    if not whole:
+        return segment(carry, x)[1]
+    first = x if whole == T else x[:, :whole]
+    carry, out = jax.lax.scan(
+        segment, carry,
+        jnp.moveaxis(first.reshape(B, -1, SEGMENT, D), 1, 0))
+    out = jnp.moveaxis(out, 0, 1).reshape(B, whole, D)
+    if whole == T:
+        return out
+    return jnp.concatenate([out, segment(carry, x[:, whole:])[1]], axis=1)
+
+
+def hybrid_hidden(params, tokens, cfg: HybridConfig):
+    """tokens [B, T] -> the stream after the last block [B, T, D], every
+    sequence from a zero state, as the training step runs it: each half (of
+    a recurrent half, each segment: `_in_segments`) under a `jax.checkpoint`
+    that holds nothing, the repeats of a run (`layer_runs`) scanned."""
+    if MOE in cfg.halves:
+        raise NotImplementedError(
+            "the expert half `E` does not train: its halves count what they "
+            "route and dispatch by sorted indices, and neither has a "
+            "gradient path here (ROADMAP, Reach)")
+    positions = _positions(tokens)
+    acfg = _attention_cfg(cfg)
+    x = _embed(params, tokens, positions, cfg)
+    x = shard_constraint(x, BATCH_AXES, SEQ_AXIS, None)
+    # `prevent_cse`: a scan's body holds SEVERAL blocks, and without the
+    # barrier XLA starts the next block's recomputation before this block's
+    # backward is done, both blocks' forwards alive at once
+    remat = partial(jax.checkpoint, prevent_cse=True,
+                    policy=jax.checkpoint_policies.nothing_saveable)
+
+    def half(x, p, kind):
+        if kind in RECURRENT:
+            x = _in_segments(x, p, kind, cfg, remat)
+        else:
+            x = remat(lambda x, p: _half(x, p, kind, cfg, acfg, positions,
+                                         constrain=True))(x, p)
+        return shard_constraint(x, BATCH_AXES, SEQ_AXIS, None)
+
+    for (unit, _), trees in zip(layer_runs(cfg), params["runs"]):
+        def body(x, trees, unit=unit):
+            for kind, p in zip(unit, trees):
+                x = half(x, p, kind)
+            return x, None
+
+        x, _ = jax.lax.scan(body, x, trees)     # a run of one repeat too
+    return x
+
+
+def hybrid_loss(params, batch, rng, cfg: HybridConfig):
+    """Causal-LM cross entropy of a hybrid stack, as `gpt.py::gpt_loss`
+    takes its batch: {"tokens": [B, T]} (the labels are the tokens shifted
+    by one) or {"tokens" | "input_ids", "labels"} (a negative label is
+    ignored). The head never materialises `[B * T, V]`: chunks of the
+    vocabulary through `ops/chunked_ce.py` (`cfg.loss_chunks`, or as many as
+    hold a chunk's logits to `_LOSS_CHUNK_BYTES`)."""
+    from deepspeed_tpu.ops.chunked_ce import chunked_softmax_xent
+    if cfg.logits_scaling != 1.0:
+        raise NotImplementedError("`logits_scaling` in the chunked loss")
+    tokens = batch.get("tokens", batch.get("input_ids"))
+    labels = batch.get("labels")
+    if labels is None:
+        tokens, labels = tokens[:, :-1], tokens[:, 1:]
+    x = hybrid_hidden(params, tokens, cfg)
+    rows = labels.size
+    chunks = cfg.loss_chunks or max(
+        1, -(-rows * cfg.vocab_size * 4 // _LOSS_CHUNK_BYTES))
+    with jax.named_scope("head"):
+        x = _norm(x, params["lnf_scale"], None, True, cfg.norm_eps)
+    with jax.named_scope("head_loss"):
+        nll = chunked_softmax_xent(
+            x.reshape(rows, -1), _head_table(params, cfg).astype(x.dtype),
+            labels.reshape(rows), chunks)
+        mask = (labels.reshape(rows) >= 0).astype(jnp.float32)
+        return (nll * mask).sum() / jnp.maximum(mask.sum(), 1.0)
+
+
+# a half's leaf -> where the tensor axis divides it (Megatron: column-parallel
+# in, row-parallel out), after the leading `[repeats]` axis; every other leaf
+# of a half, the recurrent halves' among them, is whole on every tensor rank
+_TENSOR_PARALLEL = {"attn_qkv_w": P(None, None, TENSOR_AXIS),
+                    "attn_qkv_b": P(None, TENSOR_AXIS),
+                    "attn_out_w": P(None, TENSOR_AXIS, None),
+                    "mlp_gate_w": P(None, None, TENSOR_AXIS),
+                    "mlp_up_w": P(None, None, TENSOR_AXIS),
+                    "mlp_down_w": P(None, TENSOR_AXIS, None)}
+
+
+def hybrid_param_specs(cfg: HybridConfig, layer_shapes):
+    """PartitionSpecs of `hybrid_init_fn`'s tree (`layer_shapes`: the
+    family's), for the engine to place the leaves by — ZeRO adds its axes
+    orthogonally, the abstract init makes each leaf in its shard."""
+    whole = lambda shape: P(*([None] * (1 + len(shape))))
+    specs = {"wte": P(TENSOR_AXIS, None), "lnf_scale": P(None),
+             "runs": [[{name: _TENSOR_PARALLEL.get(name, whole(shape))
+                        for name, (shape, _) in
+                        layer_shapes(cfg, kind, 0.02).items()}
+                       for kind in unit] for unit, _ in layer_runs(cfg)]}
+    if not cfg.tie_embeddings:
+        specs["lm_head"] = P(TENSOR_AXIS, None)
+    return specs
 
 
 # ----------------------------------------------------------------------
@@ -650,6 +869,11 @@ def make_hybrid_decode_model(cfg: HybridConfig, params, name, expert_half,
     result, the experts the call routed every row to — int32 `[expert
     halves, B, C, top_k]` (`C` 1 for decode), ascending in a token."""
     from deepspeed_tpu.inference.engine import DecodeModelSpec
+    if DENSE in cfg.halves or cfg.post_norm:
+        raise NotImplementedError(
+            f"model spec '{name}': the paged programs are not built for a "
+            f"stack with dense feed-forward halves or `post_norm` (no cell "
+            f"would guard them; ROADMAP, Reach)")
     runs = layer_runs(cfg)
     acfg = _attention_cfg(cfg)
     held = cfg.experts_held[1]
@@ -693,7 +917,7 @@ def make_hybrid_decode_model(cfg: HybridConfig, params, name, expert_half,
                         tables if in_place else offset(tables, base), acfg,
                         decode_work=work, attn_programs=attn_programs,
                         phase=None if mixed else site, **where)
-                    x = _residual(x, out, cfg)
+                    x = _residual(x, out, cfg, p)
                 flat = {**flat, **kv}
             elif kind in RECURRENT:
                 scope, mixer = RECURRENT[kind]
@@ -701,12 +925,12 @@ def make_hybrid_decode_model(cfg: HybridConfig, params, name, expert_half,
                     out, (state, conv) = mixer(
                         x, p, cfg, (flat["ssm"], flat["conv"]),
                         offset(state_rows, index * state_n), positions, valid)
-                    x = _residual(x, out, cfg)
+                    x = _residual(x, out, cfg, p)
                 flat = {**flat, "ssm": state, "conv": conv}
             else:
                 with jax.named_scope("mlp"):
                     out, counted, top_e = expert_half(x, p, cfg, **experts)
-                    x = _residual(x, out, cfg)
+                    x = _residual(x, out, cfg, p)
                 counts.append(counted)
                 if chosen is not None:
                     chosen.append(top_e)

@@ -34,8 +34,11 @@ out, here and in the reference alike.
 Not here: the multi-token-prediction module (the published `config.json` has
 no key for it; unserved, as K-EXAONE's, Nemotron's and GLM's are),
 `intermediate_size` (no layer is dense: `mlp_only_layers` is empty and
-`decoder_sparse_step` 1), training, the contiguous-cache `generate()` path,
-and what `hybrid.py` lists for a pool with a state kind.
+`decoder_sparse_step` 1), the contiguous-cache `generate()` path, what
+`hybrid.py` lists for a pool with a state kind, and TRAINING this family:
+`hybrid.py::hybrid_loss` trains the loop's Gated DeltaNet, attention and
+dense halves (`models/olmo_hybrid.py`), not the expert half `E` every layer
+here has.
 """
 
 import dataclasses

@@ -1,6 +1,6 @@
 """The gated delta rule (Gated DeltaNet's recurrence) on the per-slot state
 kind — the second body of `ops/pallas/ssm.py`'s in-place shell, and the
-chunked form a prefill chunk runs.
+chunked form a prefill chunk and a training step run.
 
     S_t = a_t S_(t-1) + k_t (outer) u_t      u_t = beta_t (v_t - a_t S_(t-1)^T k_t)
     o_t = S_t^T q_t                           a_t = exp(g_t), g_t <= 0
@@ -25,10 +25,16 @@ what the state already holds for `k_t` is taken out of `v_t` first.
   (matmul) form, plain `jax.numpy` under the caller's `gdn/scan` scope:
   inside a chunk of Q positions, with `gamma_i = sum_(j<=i) g_j` and
   `Gamma_ij = exp(gamma_i - gamma_j)`, the strictly-lower system `T = (I +
-  strict_lower((beta k) k^T * Gamma))^-1` (L is nilpotent: `(I - L)(I +
-  L^2)(I + L^4) ...`, five squarings at Q = 64), then matmuls against the
+  strict_lower((beta k) k^T * Gamma))^-1` (by the block rule from blocks of
+  one position up, `_inverse_of_unit_lower`), then matmuls against the
   carried state. `g = 0` AND `beta = 0` at a position leave the state alone
-  there (decay 1, nothing written): a chunk's padded tail.
+  there (decay 1, nothing written): a chunk's padded tail. The scan has a
+  BACKWARD of its own under `jax.custom_vjp` (`_chunk_scan_backward`, PR
+  56): a call keeps its inputs, makes a chunk's system and the chunks'
+  starting states again, and runs the chunks in reverse with the state's
+  cotangent carried — what a training step differentiates
+  (`models/hybrid.py::hybrid_loss`). `gdn_update`, the decode token's
+  kernel, has no gradient: nothing trains through a cache.
 
 Off the TPU `gdn_update` runs its `jax.numpy` twin (`gdn_update_reference`),
 which is also the kernel's test oracle; `gdn_scan_reference` (a position at
@@ -48,6 +54,7 @@ KERNEL_NAME = "dstpu_gdn_update"
 # bfloat16 passes. Six (HIGHEST) read the same errors against the float32
 # reference to three digits and cost 2.2% of Qwen3-Next's cell (PERF.md, PR 47)
 _SOLVE_PRECISION = jax.lax.Precision.HIGH
+_solve_dot = functools.partial(jnp.matmul, precision=_SOLVE_PRECISION)
 
 
 def _by_head(x, heads):
@@ -145,21 +152,147 @@ def gdn_scan_reference(q, k, v, g, beta, state):
 
 
 def _inverse_of_unit_lower(L):
-    """`(I + L)^-1` of strictly lower triangular L `[..., Q, Q]` float32:
-    `sum_n (-L)^n = (I - L)(I + L^2)(I + L^4) ...`, the powers by squaring
-    until they vanish (L^Q = 0). Float32 products above the default's one
-    bfloat16 pass: T's entries cancel, and a chunk's every later product
-    reads them."""
-    dot = functools.partial(jnp.matmul, precision=_SOLVE_PRECISION)
-    T = jnp.eye(L.shape[-1], dtype=L.dtype) - L
-    power, reach = L, 2                 # T is exact up to L^(reach - 1)
-    while reach < L.shape[-1]:
-        power = dot(power, power)
-        T = T + dot(T, power)
-        reach *= 2
-    return T
+    """`(I + L)^-1` of strictly lower triangular L `[..., Q, Q]` float32, by
+    the block rule `[[A, 0], [C, B]]^-1 = [[A^-1, 0], [-B^-1 C A^-1, B^-1]]`
+    from blocks of one position up: with X the inverse of the diagonal
+    blocks of s positions and L_s the part of L inside the blocks of 2 s but
+    outside those of s, `X <- X - X L_s X` (ten products at Q = 64: at s =
+    1, X is I and the step is `I - L_1`).
+    Every factor is an inverse of a sub-block, as bounded as the whole. The
+    series `(I - L)(I + L^2)(I + L^4) ...`, which served until PR 56 in ten
+    products, is not: a power L^n has entries the size of `|L|^n C(Q, n)`
+    that must cancel to the inverse's bounded ones, and float32 loses them
+    once beta k_i . k_j passes ~0.2 over a chunk of 64 (keys that resemble
+    each other, as trained ones do, or beta up to 2): it returned noise
+    there. Float32 products above the default's one bfloat16 pass: T's
+    entries cancel, and a chunk's every later product reads them."""
+    # L is HELD: six masked parts read it, and left alone XLA makes its
+    # producer (the decays' exponentials among them) again inside each
+    # product's fusion at a prefill chunk's size: 25% of the whole scan's
+    # estimated cycles (compiled for a v5e, PERF.md section 6, PR 56)
+    L = jax.lax.optimization_barrier(L)
+    Q = L.shape[-1]
+    at = jnp.arange(Q)
+    together = lambda size: at[:, None] // size == at[None, :] // size
+    between = lambda size: jnp.where(
+        together(2 * size) & ~together(size), L, 0.0)
+    X = jnp.eye(Q, dtype=L.dtype) - between(1)
+    size = 2
+    while size < Q:
+        X = X - _solve_dot(_solve_dot(X, between(size)), X)
+        size *= 2
+    return X
 
 
+def _chunks(q, k, v, g, beta, chunk):
+    """The inputs a chunk at a time, heads as (key head, value head of it) so
+    that q and k are never repeated: q, k `[b, c, Q, G, K]` in `v.dtype`, v
+    `[b, c, Q, G, per, V]`, g, beta `[b, c, Q, G, per]` float32; a ragged tail
+    padded with positions that leave the state alone."""
+    b, T, H, V = v.shape
+    G, K = k.shape[2:]
+    per = H // G
+    pad = -T % chunk
+    if pad:
+        q, k, v, g, beta = (
+            jnp.pad(x, [(0, 0), (0, pad)] + [(0, 0)] * (x.ndim - 2))
+            for x in (q, k, v, g, beta))
+    c, Q = (T + pad) // chunk, chunk
+    f32 = lambda x: x.astype(jnp.float32)
+    q, k = (x.astype(v.dtype).reshape(b, c, Q, G, K) for x in (q, k))
+    v = v.reshape(b, c, Q, G, per, V)
+    g, beta = (f32(x).reshape(b, c, Q, G, per) for x in (g, beta))
+    return q, k, v, g, beta
+
+
+_dot = functools.partial(jnp.einsum, preferred_element_type=jnp.float32)
+_f32 = lambda x: x.astype(jnp.float32)
+_heads_first = lambda x: jnp.moveaxis(x, (2, 3), (-2, -1))  # [b,c,G,per,i,j]
+
+
+def _decays(g):
+    """(gamma, the inclusive sum of a chunk's log-decays; Gamma `[b, c, i, j,
+    G, per]`: position j reaches i >= j decayed by exp(gamma_i - gamma_j))."""
+    Q = g.shape[2]
+    gamma = jnp.cumsum(g, axis=2)                           # inclusive
+    i_ge_j = jnp.tril(jnp.ones((Q, Q), bool))[:, :, None, None]
+    return gamma, jnp.exp(jnp.where(
+        i_ge_j, gamma[:, :, :, None] - gamma[:, :, None], -jnp.inf))
+
+
+def _system(k, beta, Gamma):
+    """The chunk's strictly-lower system L `[b, c, G, per, i, j]` float32."""
+    Q = k.shape[2]
+    kk = _dot("bcign,bcjgn->bcijg", k, k)[..., None]
+    return _heads_first(jnp.where(jnp.tril(jnp.ones((Q, Q), bool), -1)
+                                  [:, :, None, None],
+                                  beta[:, :, :, None] * kk * Gamma, 0.0))
+
+
+def _writes(k, v, beta, gamma):
+    """What each position writes, before the carried state is taken out:
+    (beta v, beta exp(gamma) k) in `v.dtype`."""
+    bv = (beta[..., None] * _f32(v)).astype(v.dtype)
+    bk = (beta * jnp.exp(gamma))[..., None] * _f32(k)[:, :, :, :, None]
+    return bv, bk.astype(v.dtype)
+
+
+def _solved(T_inv, bv, bk):
+    """... through the chunk's triangular system: (v_in `[b, c, G, per, i,
+    V]`, k_in `[.., i, K]`) float32."""
+    return (_dot("bcghij,bcjghp->bcghip", T_inv, bv),
+            _dot("bcghij,bcjghn->bcghin", T_inv, bk))
+
+
+def _reads(q, k, gamma, Gamma):
+    """What reads the state inside the chunk, and what the chunk hands on:
+    (reads `[b, c, G, per, i, j]`, q_in, k_out `[b, c, Q, G, per, K]`, in
+    `q.dtype`; the chunk's whole log-decay `[b, c, G, per]` float32)."""
+    dtype = q.dtype
+    reads = _heads_first(_dot("bcign,bcjgn->bcijg", q, k)[..., None]
+                         * Gamma).astype(dtype)
+    q_in = (jnp.exp(gamma)[..., None]
+            * _f32(q)[:, :, :, :, None]).astype(dtype)
+    last = gamma[:, :, -1]                                  # [b, c, G, per]
+    k_out = (jnp.exp(last[:, :, None] - gamma)[..., None]
+             * _f32(k)[:, :, :, :, None]).astype(dtype)
+    return reads, q_in, k_out, last
+
+
+def _by_chunk(xs):
+    return tuple(jnp.moveaxis(x, 1, 0) for x in xs)
+
+
+def _chunk_scan(q, k, v, g, beta, state, chunk):
+    """`gdn_chunk_scan`, the function itself."""
+    b, T, H, V = v.shape
+    G, K = k.shape[2:]
+    dtype = v.dtype
+    q, k, v, g, beta = _chunks(q, k, v, g, beta, chunk)
+    gamma, Gamma = _decays(g)
+    T_inv = _inverse_of_unit_lower(_system(k, beta, Gamma)).astype(dtype)
+    v_in, k_in = _solved(T_inv, *_writes(k, v, beta, gamma))
+    reads, q_in, k_out, last = _reads(q, k, gamma, Gamma)
+
+    def carry(S, inputs):
+        v_in, k_in, reads, q_in, k_out, keep = inputs
+        held = S.astype(dtype)
+        new = v_in - _dot("bghin,bghnp->bghip", k_in.astype(dtype), held)
+        o = _dot("bighn,bghnp->bghip", q_in, held) \
+            + _dot("bghij,bghjp->bghip", reads, new.astype(dtype))
+        S = keep[..., None, None] * S \
+            + _dot("bjghn,bghjp->bghnp", k_out, new.astype(dtype))
+        return S, o
+
+    state, o = jax.lax.scan(
+        carry, _f32(state).reshape(b, G, H // G, K, V),
+        _by_chunk((v_in, k_in, reads, q_in, k_out, jnp.exp(last))))
+    # [c, b, G, per, Q, V] -> [b, T, H, V]
+    o = jnp.moveaxis(o, (0, 4), (1, 2)).reshape(b, -1, H, V)
+    return o[:, :T], state.reshape(b, H, K, V)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
 def gdn_chunk_scan(q, k, v, g, beta, state, chunk):
     """The recurrence over T positions from a carried state, in its chunked
     form: within a chunk of `chunk` positions the delta rule's triangular
@@ -171,61 +304,92 @@ def gdn_chunk_scan(q, k, v, g, beta, state, chunk):
     alone; state: `[b, H, K, V]` float32. Returns (o `[b, T, H, V]` float32,
     the state after position T - 1). T need not be a multiple of `chunk`.
     Products take their inputs in `v.dtype` and accumulate in float32; the
-    decays and the triangular solve are float32 (`_SOLVE_PRECISION`)."""
+    decays and the triangular solve are float32 (`_SOLVE_PRECISION`).
+
+    Differentiable in everything but `chunk`, by a backward of
+    its own (`_chunk_scan_backward`): a call keeps its INPUTS for it and
+    nothing it made of them, so a long sequence is best run a segment of
+    chunks a call on the carried state (`models/hybrid.py::_in_segments`)."""
+    return _chunk_scan(q, k, v, g, beta, state, chunk)
+
+
+def _chunk_scan_forward(q, k, v, g, beta, state, chunk):
+    return (_chunk_scan(q, k, v, g, beta, state, chunk),
+            (q, k, v, g, beta, state))
+
+
+def _chunk_scan_backward(chunk, kept, cotangents):
+    """(dq, dk, dv, dg, dbeta, dstate) from (do, dstate after). The forward
+    keeps its inputs alone; everything a chunk's system is made of — the
+    decays, L, T = (I + L)^-1, what the positions write and read
+    — is made AGAIN here, and the carried state at each chunk's start by
+    running the state's recurrence forward once more (two of the forward's
+    four products a chunk), so no `[chunks, heads, Q, Q]` or `[chunks,
+    heads, K, V]` array outlives the forward. The chunks then run in REVERSE
+    with the state's cotangent carried; what is elementwise or a product
+    over one chunk is transposed by `jax.vjp` of the forward's own pieces,
+    and the inverse by its identity, `dL = -T^T dT T^T`, not through the
+    products that made it."""
+    q, k, v, g, beta, state = kept
+    do, dafter = cotangents
     b, T, H, V = v.shape
     G, K = k.shape[2:]
-    per = H // G
-    pad = -T % chunk
-    if pad:
-        q, k, v, g, beta = (
-            jnp.pad(x, [(0, 0), (0, pad)] + [(0, 0)] * (x.ndim - 2))
-            for x in (q, k, v, g, beta))
-    c, Q = (T + pad) // chunk, chunk
     dtype = v.dtype
-    dot = functools.partial(jnp.einsum, preferred_element_type=jnp.float32)
-    f32 = lambda x: x.astype(jnp.float32)
-    # heads as (key head, value head of it): q and k are never repeated
-    q, k = (x.astype(dtype).reshape(b, c, Q, G, K) for x in (q, k))
-    v = v.reshape(b, c, Q, G, per, V)
-    g, beta = (f32(x).reshape(b, c, Q, G, per) for x in (g, beta))
-    gamma = jnp.cumsum(g, axis=2)                           # inclusive
-    # position j reaches i >= j decayed by exp(gamma_i - gamma_j)
-    i_ge_j = jnp.tril(jnp.ones((Q, Q), bool))[:, :, None, None]
-    Gamma = jnp.exp(jnp.where(i_ge_j, gamma[:, :, :, None]
-                              - gamma[:, :, None], -jnp.inf))  # [b,c,i,j,G,per]
-    heads_first = lambda x: jnp.moveaxis(x, (2, 3), (-2, -1))  # [b,c,G,per,i,j]
-    kk = dot("bcign,bcjgn->bcijg", k, k)[..., None]
-    L = heads_first(jnp.where(jnp.tril(jnp.ones((Q, Q), bool), -1)
-                              [:, :, None, None],
-                              beta[:, :, :, None] * kk * Gamma, 0.0))
-    T_inv = _inverse_of_unit_lower(L).astype(dtype)
-    # what each position writes, before the carried state is taken out ...
-    bv = (beta[..., None] * f32(v)).astype(dtype)
-    bk = (beta * jnp.exp(gamma))[..., None] * f32(k)[:, :, :, :, None]
-    v_in = dot("bcghij,bcjghp->bcghip", T_inv, bv)
-    k_in = dot("bcghij,bcjghn->bcghin", T_inv, bk.astype(dtype))
-    # ... what reads it inside the chunk, and what the chunk hands on
-    reads = heads_first(dot("bcign,bcjgn->bcijg", q, k)[..., None]
-                        * Gamma).astype(dtype)
-    q_in = (jnp.exp(gamma)[..., None] * f32(q)[:, :, :, :, None]).astype(dtype)
-    last = gamma[:, :, -1]                                  # [b, c, G, per]
-    k_out = (jnp.exp(last[:, :, None] - gamma)[..., None]
-             * f32(k)[:, :, :, :, None]).astype(dtype)
 
-    def carry(S, inputs):
-        v_in, k_in, reads, q_in, k_out, keep = inputs
-        held = S.astype(dtype)
-        new = v_in - dot("bghin,bghnp->bghip", k_in.astype(dtype), held)
-        o = dot("bighn,bghnp->bghip", q_in, held) \
-            + dot("bghij,bghjp->bghip", reads, new.astype(dtype))
-        S = keep[..., None, None] * S \
-            + dot("bjghn,bghjp->bghnp", k_out, new.astype(dtype))
-        return S, o
+    def system(q, k, v, g, beta):
+        q, k, v, g, beta = _chunks(q, k, v, g, beta, chunk)
+        gamma, Gamma = _decays(g)
+        reads, q_in, k_out, last = _reads(q, k, gamma, Gamma)
+        return (_system(k, beta, Gamma), *_writes(k, v, beta, gamma),
+                reads, q_in, k_out, jnp.exp(last))
 
-    state, o = jax.lax.scan(
-        carry, f32(state).reshape(b, G, per, K, V),
-        tuple(jnp.moveaxis(x, 1, 0) for x in (
-            v_in, k_in, reads, q_in, k_out, jnp.exp(last))))
-    # [c, b, G, per, Q, V] -> [b, T, H, V]
-    o = jnp.moveaxis(o, (0, 4), (1, 2)).reshape(b, c * Q, H, V)
-    return o[:, :T], state.reshape(b, H, K, V)
+    (L, bv, bk, reads, q_in, k_out, keep), system_vjp = jax.vjp(
+        system, q, k, v, g, beta)
+    T_inv = _inverse_of_unit_lower(L)
+    (v_in, k_in), solved_vjp = jax.vjp(_solved, T_inv.astype(dtype), bv, bk)
+    by_chunk = _by_chunk((v_in, k_in, reads, q_in, k_out, keep))
+
+    def start(S, inputs):           # the state alone, forward: S at a start
+        v_in, k_in, _, _, k_out, keep = inputs
+        new = v_in - _dot("bghin,bghnp->bghip", k_in.astype(dtype),
+                          S.astype(dtype))
+        return keep[..., None, None] * S + _dot(
+            "bjghn,bghjp->bghnp", k_out, new.astype(dtype)), S
+
+    _, starts = jax.lax.scan(
+        start, _f32(state).reshape(b, G, H // G, K, V), by_chunk)
+    c = starts.shape[0]
+    do = jnp.pad(do, [(0, 0), (0, c * chunk - T), (0, 0), (0, 0)])
+    do = jnp.moveaxis(do.reshape(b, c, chunk, G, H // G, V), (1, 2), (0, 4))
+
+    def carry(dS, inputs):
+        v_in, k_in, reads, q_in, k_out, keep, S, do = inputs
+        held, k_in, do = S.astype(dtype), k_in.astype(dtype), do.astype(dtype)
+        new = (v_in - _dot("bghin,bghnp->bghip", k_in, held)).astype(dtype)
+        after = dS.astype(dtype)
+        dnew = _dot("bghij,bghip->bghjp", reads, do) \
+            + _dot("bjghn,bghnp->bghjp", k_out, after)
+        write = dnew.astype(dtype)
+        grads = (dnew, -_dot("bghip,bghnp->bghin", write, held),
+                 _dot("bghip,bghjp->bghij", do, new).astype(dtype),
+                 _dot("bghip,bghnp->bighn", do, held).astype(dtype),
+                 _dot("bghjp,bghnp->bjghn", new, after).astype(dtype),
+                 jnp.sum(dS * S, axis=(-2, -1)))
+        dS = keep[..., None, None] * dS \
+            + _dot("bighn,bghip->bghnp", q_in, do) \
+            - _dot("bghin,bghip->bghnp", k_in, write)
+        return dS, grads
+
+    dstate, grads = jax.lax.scan(
+        carry, _f32(dafter).reshape(b, G, H // G, K, V),
+        by_chunk + (starts, do), reverse=True)
+    dv_in, dk_in, dreads, dq_in, dk_out, dkeep = (
+        jnp.moveaxis(x, 0, 1) for x in grads)
+    dT, dbv, dbk = solved_vjp((dv_in, dk_in))
+    T_t = jnp.swapaxes(T_inv, -1, -2)
+    dL = -_solve_dot(_solve_dot(T_t, _f32(dT)), T_t)
+    return (*system_vjp((dL, dbv, dbk, dreads, dq_in, dk_out, dkeep)),
+            dstate.reshape(b, H, K, V).astype(state.dtype))
+
+
+gdn_chunk_scan.defvjp(_chunk_scan_forward, _chunk_scan_backward)

@@ -406,7 +406,9 @@ def test_holding_the_in_projection_changes_no_logit(monkeypatch):
     """`_gdn_half` holds `u @ gdn_qkvz_w` behind an `optimization_barrier`
     from the convolution to the gate, as `_mamba_half` holds its product
     (PR 42). The barrier is an identity: every Gated DeltaNet half passes its
-    product through one, and the logits are bit-equal without it."""
+    product through one (and the chunk's system L through another on its
+    way into the inverse, `gdn._inverse_of_unit_lower`, PR 56), and the
+    logits are bit-equal without them."""
     cfg = _cfg()
     params = _params(cfg)
     tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 24), 0,
@@ -417,7 +419,10 @@ def test_holding_the_in_projection_changes_no_logit(monkeypatch):
     forward = lambda p, t: qn.qwen3_next_forward(p, t, cfg)
     with_it = np.asarray(jax.jit(forward)(params, tokens))
     width = hybrid.mixer_shapes(cfg, hybrid.DELTANET)["gdn_qkvz_w"][0][-1]
-    assert held == [(2, 24, width)] * cfg.halves.count(hybrid.DELTANET)
+    layers = cfg.halves.count(hybrid.DELTANET)
+    assert [s for s in held if len(s) == 3] == [(2, 24, width)] * layers
+    Q = cfg.chunk_size
+    assert [s[-2:] for s in held if len(s) != 3] == [(Q, Q)] * layers
     monkeypatch.setattr(jax.lax, "optimization_barrier", lambda x: x)
     without = np.asarray(jax.jit(forward)(params, tokens))
     assert np.ptp(with_it) > 0 and np.array_equal(with_it, without)
