@@ -552,6 +552,15 @@ ATTN_OUT = "attn_out"           # the out-projection's result: the second
                                 # sequential, nothing does where it is parallel
 
 
+def on_one_device(n, axis):
+    """`n` of a dimension the mesh's `axis` divides, as one device holds it
+    (`shard_constraint` leaves a dimension whole where the axis does not
+    divide it; no mesh: whole)."""
+    if mesh_mod.has_mesh() and n % mesh_mod.axis_size(axis) == 0:
+        return n // mesh_mod.axis_size(axis)
+    return n
+
+
 def held_candidates(cfg: GPTConfig, B, T, attn_fn=None):
     """({name: bytes a layer on ONE device}, the step's working set with
     nothing named) for a `[B, T]` batch as the traced program sees it
@@ -569,8 +578,7 @@ def held_candidates(cfg: GPTConfig, B, T, attn_fn=None):
     is named, as `held_policy` takes it: every layer's input; the loss's
     (the logits and a quarter again); one block's backward (the MLP's
     product, its gradient and the QKV product)."""
-    divide = lambda n, axis: n // mesh_mod.axis_size(axis) \
-        if mesh_mod.has_mesh() and n % mesh_mod.axis_size(axis) == 0 else n
+    divide = on_one_device
     tokens = divide(B, BATCH_AXES) * divide(T, SEQ_AXIS)
     item = jnp.dtype(cfg.dtype).itemsize
     H, Hkv, hd = cfg.n_head, cfg.n_kv_head, cfg.head_dim

@@ -65,10 +65,14 @@ scalars (`residual_multiplier` here, `scale_attn`, `embedding_multiplier`,
 
 TRAINING (`hybrid_loss`, `hybrid_param_specs`; `models/olmo_hybrid.py` is
 the family that trains): the same halves over whole sequences from a zero
-state, a `jax.checkpoint` a HALF that holds nothing (a recurrent half: a
-segment of 4096 positions of it, on the carried state), a run's repeats
-scanned, the head through `ops/chunked_ce.py`; the delta rule's chunked scan
-brings its own backward (`ops/pallas/gdn.py`). What does not train: the
+state, a `jax.checkpoint` a HALF (a recurrent half: a segment of 4096
+positions of it, on the carried state) that holds what fits of the results
+its backward reads (`held_candidates`: the flash kernel's residuals first,
+then the attention half's and the feed-forwards' products, then the delta
+rule's scan output a segment; nothing where no budget is installed), a
+run's repeats scanned, the head through `ops/chunked_ce.py`;
+the delta rule's chunked scan brings its own backward
+(`ops/pallas/gdn.py`). What does not train: the
 expert half `E` (its halves count what they route, which has no gradient
 path here), packed documents (a state reset and a block-diagonal mask at a
 boundary), the recurrent halves under sequence parallelism (a chunk's state
@@ -94,16 +98,18 @@ from jax.sharding import PartitionSpec as P
 
 from deepspeed_tpu.comm.mesh import (BATCH_AXES, SEQ_AXIS, TENSOR_AXIS,
                                      shard_constraint)
-from deepspeed_tpu.models.gpt import (MixedTables, _attn_half, _embed,
-                                      _half_input, _head_table, _last_rows,
-                                      _lm_head, _mlp, _norm,
-                                      _paged_attn_half, decode_rows,
+from deepspeed_tpu.models.gpt import (MLP_PRODUCT, QKV_PRODUCT, MixedTables,
+                                      _attn_half, _embed, _half_input,
+                                      _head_table, _last_rows, _lm_head,
+                                      _mlp, _norm, _paged_attn_half,
+                                      _train_attn_site, decode_rows,
                                       make_mixed_paged_fn, offset_tables,
-                                      over_chunk_group)
+                                      on_one_device, over_chunk_group)
 from deepspeed_tpu.models.layer_pattern import repeated_runs
 from deepspeed_tpu.models.moe_gpt import MoEGPTConfig
 from deepspeed_tpu.ops import attention_dispatch as attn_dispatch
 from deepspeed_tpu.ops.pallas import gdn, ssm
+from deepspeed_tpu.ops.pallas.flash_attention import FLASH_RESIDUALS
 from deepspeed_tpu.parallel.moe import HELD_ROUTED_COUNTERS
 
 MAMBA, DELTANET, ATTENTION, MOE, DENSE = "M", "D", "*", "E", "F"
@@ -712,11 +718,13 @@ def hybrid_forward(params, tokens, cfg: HybridConfig, expert_half=None,
 # ----------------------------------------------------------------------
 
 # A block of the training step is a HALF — of a recurrent half, a SEGMENT of
-# it — under a `jax.checkpoint` that holds NOTHING: at 32768 positions a
-# sequence the one block whose backward is running takes what the step's
-# state leaves of a 16 GB chip (a layer's two halves together do not fit, nor
-# does a Gated DeltaNet half whole: PERF.md section 6, PR 56), so every block
-# is made again from its input.
+# it — under a `jax.checkpoint`: at 32768 positions a sequence the one block
+# whose backward is running takes most of what the step's state leaves of a
+# 16 GB chip (a layer's two halves together do not fit, nor does a Gated
+# DeltaNet half whole: PERF.md section 6, PR 56). What is left beside it is
+# spent on the results a block's backward reads of its forward, block by
+# block (`held_candidates`); a block that holds nothing is made again from
+# its input.
 
 # positions a block of a recurrent half: a longer sequence runs the half a
 # segment at a time on the carried state and convolution tail (`Carried`), as
@@ -727,17 +735,128 @@ SEGMENT = 4096
 # about this many bytes (`ops/chunked_ce.py`)
 _LOSS_CHUNK_BYTES = 1 << 28
 
+# a half's letter -> the named results its backward reads of its forward
+_BACKWARD_READS = {ATTENTION: (FLASH_RESIDUALS, QKV_PRODUCT),
+                   DELTANET: (gdn.SCAN_OUTPUT,), DENSE: (MLP_PRODUCT,)}
 
-def _in_segments(x, p, kind, cfg, remat):
+
+def _blocks(kind, T):
+    """The `jax.checkpoint` blocks a half of `kind` runs T positions in."""
+    return -(-T // SEGMENT) if kind in RECURRENT else 1
+
+
+def held_candidates(cfg: HybridConfig, B, T):
+    """({name: bytes a BLOCK on ONE device}, {name: the blocks that carry
+    it from the stack's END, a group a scanned block: `fit_held`'s
+    `layers`}, the step's working sets with nothing named: `held_plan`'s)
+    for a `[B, T]` batch as the traced program sees it —
+    `gpt.py::held_candidates` for a stack of halves, where a block is a half
+    and, of a recurrent half, a `SEGMENT` of it, so a name may be held by
+    its last few blocks alone (what is held there lives shortest: at this
+    cell's size the last run's residuals are gone before the step's memory
+    peaks).
+
+    The names are in the order `fit_held` takes them, by the milliseconds a
+    held GiB takes off a step on the v5e at Olmo-Hybrid-7B's widths, each
+    name forced in turn (PERF.md section 6, PR 57): the flash forward 211
+    (a kernel at four fifths of its roofline whose results are an eighth of
+    its inputs); the attention half's QKV product 22 and a feed-forward's
+    gate and up products 26 (matmuls near the peak; the narrower first: it
+    strands less of the room); the delta rule's scan output 4.5 (float32
+    `[b, SEGMENT, H, V]`, `ops/pallas/gdn.py::SCAN_OUTPUT`: held, the scan
+    runs once forward and never again, but of the forward only the loop over
+    the chunks was run again — XLA shares a chunk's systems between the
+    block made again and the scan's own backward — and stacking a result
+    through the two scans around a segment costs four fifths of what that
+    saves). The delta rule's in-projection is NOT a candidate: held in
+    every segment it lengthened the step by 6%.
+
+    A working set a RUN of the stack (`layer_runs`), at its backward: the
+    inputs of every block up to the run's last (a half's; a recurrent
+    half's a segment at a time, beside the state and the convolution tail
+    it starts from — the runs behind have run their backward and freed
+    theirs); the largest backward of one of its blocks — a feed-forward's
+    (both products, the activation's result, two of the three gradients),
+    the attention half's (the QKV product and its gradient, q, k, v, the
+    kernel's output and their four gradients), a recurrent segment's —
+    beside the gradients of this run and the runs behind it, by their share
+    of the weights (a scan's gradient stack is allocated where its backward
+    begins). The last run's has the loss's too: a chunk of the logits in
+    float32, its exponentials and its gradient, beside the stream's."""
+    divide = on_one_device
+    b, t = divide(B, BATCH_AXES), divide(T, SEQ_AXIS)
+    tokens, segment = b * t, b * min(t, SEGMENT)
+    item = jnp.dtype(cfg.dtype).itemsize
+    stream = tokens * cfg.d_model * item
+    hd, heads = cfg.head_dim, divide(cfg.n_head, TENSOR_AXIS)
+    qkv = tokens * hd * item * divide(
+        cfg.n_head * (2 if cfg.attn_output_gate else 1) + 2 * cfg.n_kv_head,
+        TENSOR_AXIS)
+    up = tokens * divide(cfg.d_ff, TENSOR_AXIS) * item
+    Wq, Wk, Wv = cfg.gdn_widths
+    in_proj = segment * (Wq + Wk + 2 * Wv) * item
+    flash = attn_dispatch.select(_train_attn_site(
+        _attention_cfg(cfg), T, T, False, None)) == "flash"
+    held = {
+        # the output, and a float32 log-sum-exp a row in the kernels' tile
+        # (`gpt.py::held_candidates`)
+        FLASH_RESIDUALS: tokens * heads * (hd * item + 8 * 4) if flash else 0,
+        QKV_PRODUCT: qkv, MLP_PRODUCT: 2 * up,
+        gdn.SCAN_OUTPUT: segment * Wv * 4}
+    runs = layer_runs(cfg)
+    # a name's blocks from the stack's END, where what is held lives
+    # shortest (a block's forward comes last and its backward first), a
+    # GROUP a scanned block: its repeats are one program and hold a name
+    # together
+    carriers = {name: tuple(
+        repeats for unit, repeats in reversed(runs) for kind in unit
+        if name in _BACKWARD_READS.get(kind, ())
+        for _ in range(_blocks(kind, T))) for name in held}
+    held = {name: nbytes for name, nbytes in held.items()
+            if nbytes and carriers[name]}
+
+    leaves = state_leaves(cfg)
+    carried = b * (math.prod(leaves["ssm"]) * 4
+                   + math.prod(leaves["conv"]) * item)
+    inputs = lambda kind: stream + \
+        (_blocks(kind, T) * carried if kind in RECURRENT else 0)
+    backward = {
+        DENSE: 5 * up, ATTENTION: 2 * qkv + 8 * tokens * heads * hd * item,
+        DELTANET: 8 * in_proj + 4 * segment * Wv * 4,
+        MAMBA: 8 * segment * (cfg.ssm_inner + cfg.conv_width
+                              + cfg.mamba_num_heads) * item}
+    weights = lambda kind: sum(
+        math.prod(shape) for shape, _ in mixer_shapes(cfg, kind).values())
+    of_run = [repeats * sum(weights(kind) for kind in unit)
+              for unit, repeats in runs]
+    ends = (1 if cfg.tie_embeddings else 2) * cfg.vocab_size * cfg.d_model
+    working_sets, before = [], 0
+    for r, (unit, repeats) in enumerate(runs):
+        before += repeats * sum(inputs(kind) for kind in unit)
+        working_sets.append(dict(
+            carried_bytes=before,
+            grads_share=(sum(of_run[r:]) + ends) / (sum(of_run) + ends),
+            backward_bytes=max(backward[kind] for kind in unit)))
+    chunks = cfg.loss_chunks or max(
+        1, -(-tokens * cfg.vocab_size * 4 // _LOSS_CHUNK_BYTES))
+    working_sets[-1]["loss_bytes"] = \
+        3 * tokens * 4 * (cfg.vocab_size // chunks) + 4 * stream
+    return held, {name: carriers[name] for name in held}, working_sets
+
+
+def _in_segments(x, p, kind, cfg, holds, remat):
     """A recurrent half AND its residual on x [B, T, D] from a zero state,
-    `SEGMENT` positions a block under `remat`: the whole segments scanned,
-    then what is left of T as a last, shorter block on the same carried
-    state (a block's size never follows T's remainder)."""
+    `SEGMENT` positions a block: the whole segments scanned, then what is
+    left of T as a last, shorter block on the same carried state (a block's
+    size never follows T's remainder). `holds`: the names each block holds
+    in turn, `remat(names)` its `jax.checkpoint`; the whole segments that
+    hold the same names are one scan."""
     scope, mixer = RECURRENT[kind]
     B, T, D = x.shape
-    whole = T // SEGMENT * SEGMENT
+    whole = T // SEGMENT
+    cut = lambda first, last: x if (first, last) == (0, T) else \
+        x[:, first:last]
 
-    @remat
     def segment(carry, x):
         with jax.named_scope(scope):
             out, carry = mixer(x, p, cfg, carry)
@@ -746,23 +865,33 @@ def _in_segments(x, p, kind, cfg, remat):
     leaves = state_leaves(cfg)
     carry = Carried(jnp.zeros((B,) + leaves["ssm"], jnp.float32),
                     jnp.zeros((B,) + leaves["conv"], x.dtype))
-    if not whole:
-        return segment(carry, x)[1]
-    first = x if whole == T else x[:, :whole]
-    carry, out = jax.lax.scan(
-        segment, carry,
-        jnp.moveaxis(first.reshape(B, -1, SEGMENT, D), 1, 0))
-    out = jnp.moveaxis(out, 0, 1).reshape(B, whole, D)
-    if whole == T:
-        return out
-    return jnp.concatenate([out, segment(carry, x[:, whole:])[1]], axis=1)
+    outs, at = [], 0
+    while at < whole:
+        upto = at + 1
+        while upto < whole and holds[upto] == holds[at]:
+            upto += 1
+        carry, out = jax.lax.scan(
+            remat(holds[at])(segment), carry,
+            jnp.moveaxis(cut(at * SEGMENT, upto * SEGMENT).reshape(
+                B, -1, SEGMENT, D), 1, 0))
+        outs.append(jnp.moveaxis(out, 0, 1).reshape(B, -1, D))
+        at = upto
+    if whole * SEGMENT < T:
+        outs.append(remat(holds[whole])(segment)(
+            carry, cut(whole * SEGMENT, T))[1])
+    return outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=1)
 
 
 def hybrid_hidden(params, tokens, cfg: HybridConfig):
     """tokens [B, T] -> the stream after the last block [B, T, D], every
     sequence from a zero state, as the training step runs it: each half (of
     a recurrent half, each segment: `_in_segments`) under a `jax.checkpoint`
-    that holds nothing, the repeats of a run (`layer_runs`) scanned."""
+    whose policy holds the names the installed budget has room for
+    (`held_candidates`, `activation_checkpointing.held_plan`: a name's last
+    blocks in model order; no budget, nothing held), the repeats of a run
+    (`layer_runs`) scanned."""
+    from deepspeed_tpu.runtime.activation_checkpointing import (
+        held_plan, policy_holding)
     if MOE in cfg.halves:
         raise NotImplementedError(
             "the expert half `E` does not train: its halves count what they "
@@ -772,24 +901,41 @@ def hybrid_hidden(params, tokens, cfg: HybridConfig):
     acfg = _attention_cfg(cfg)
     x = _embed(params, tokens, positions, cfg)
     x = shard_constraint(x, BATCH_AXES, SEQ_AXIS, None)
+    T = tokens.shape[1]
+    held, carriers, working_sets = held_candidates(cfg, *tokens.shape)
+    left = dict(held_plan(held, carriers, working_sets).blocks)
+    runs = layer_runs(cfg)
+    # the names every block holds, a name's blocks dealt from the stack's
+    # END (`held_candidates`' order). A scanned body is one program for all
+    # its repeats: a block of it holds a name in every repeat or in none
+    holds = [[[()] * _blocks(kind, T) for kind in unit] for unit, _ in runs]
+    for of_run, (unit, repeats) in reversed(list(zip(holds, runs))):
+        for of_half, kind in reversed(list(zip(of_run, unit))):
+            for block in reversed(range(len(of_half))):
+                names = tuple(name for name in _BACKWARD_READS.get(kind, ())
+                              if left.get(name, 0) >= repeats)
+                left.update({name: left[name] - repeats for name in names})
+                of_half[block] = names
+
     # `prevent_cse`: a scan's body holds SEVERAL blocks, and without the
     # barrier XLA starts the next block's recomputation before this block's
     # backward is done, both blocks' forwards alive at once
-    remat = partial(jax.checkpoint, prevent_cse=True,
-                    policy=jax.checkpoint_policies.nothing_saveable)
+    remat = lambda names: partial(jax.checkpoint, prevent_cse=True,
+                                  policy=policy_holding(names))
 
-    def half(x, p, kind):
+    def half(x, p, kind, holds):
         if kind in RECURRENT:
-            x = _in_segments(x, p, kind, cfg, remat)
+            x = _in_segments(x, p, kind, cfg, holds, remat)
         else:
-            x = remat(lambda x, p: _half(x, p, kind, cfg, acfg, positions,
-                                         constrain=True))(x, p)
+            x = remat(holds[0])(
+                lambda x, p: _half(x, p, kind, cfg, acfg, positions,
+                                   constrain=True))(x, p)
         return shard_constraint(x, BATCH_AXES, SEQ_AXIS, None)
 
-    for (unit, _), trees in zip(layer_runs(cfg), params["runs"]):
-        def body(x, trees, unit=unit):
-            for kind, p in zip(unit, trees):
-                x = half(x, p, kind)
+    for (unit, _), of_run, trees in zip(runs, holds, params["runs"]):
+        def body(x, trees, unit=unit, of_run=of_run):
+            for kind, p, names in zip(unit, trees, of_run):
+                x = half(x, p, kind, names)
             return x, None
 
         x, _ = jax.lax.scan(body, x, trees)     # a run of one repeat too

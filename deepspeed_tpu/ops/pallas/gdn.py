@@ -33,8 +33,10 @@ what the state already holds for `k_t` is taken out of `v_t` first.
   56): a call keeps its inputs, makes a chunk's system and the chunks'
   starting states again, and runs the chunks in reverse with the state's
   cotangent carried — what a training step differentiates
-  (`models/hybrid.py::hybrid_loss`). `gdn_update`, the decode token's
-  kernel, has no gradient: nothing trains through a cache.
+  (`models/hybrid.py::hybrid_loss`), whose blocks may HOLD the scan's
+  output under the name `SCAN_OUTPUT` and then never run it a second time.
+  `gdn_update`, the decode token's kernel, has no gradient: nothing trains
+  through a cache.
 
 Off the TPU `gdn_update` runs its `jax.numpy` twin (`gdn_update_reference`),
 which is also the kernel's test oracle; `gdn_scan_reference` (a position at
@@ -45,6 +47,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 
 from deepspeed_tpu.ops.pallas import ssm
@@ -309,13 +312,26 @@ def gdn_chunk_scan(q, k, v, g, beta, state, chunk):
     Differentiable in everything but `chunk`, by a backward of
     its own (`_chunk_scan_backward`): a call keeps its INPUTS for it and
     nothing it made of them, so a long sequence is best run a segment of
-    chunks a call on the carried state (`models/hybrid.py::_in_segments`)."""
+    chunks a call on the carried state (`models/hybrid.py::_in_segments`).
+    Where it is differentiated `o` carries the name `SCAN_OUTPUT`
+    (`jax.ad_checkpoint.checkpoint_name`, in the forward rule): a
+    `jax.checkpoint` that holds the name runs the scan once forward and
+    never again, its backward reading the held `o` and the inputs made
+    again. A call nobody differentiates lowers as it always has."""
     return _chunk_scan(q, k, v, g, beta, state, chunk)
 
 
+# The name of the scan's `o` where it is differentiated: the rule keeps its
+# inputs alone, so under a `jax.checkpoint` whose policy holds this name
+# nothing of the forward scan has a reader when the block is made again, and
+# the backward runs `_chunk_scan_backward` without it
+# (`models/hybrid.py::held_candidates`). Float32, the width it is computed at.
+SCAN_OUTPUT = "gdn_scan_output"
+
+
 def _chunk_scan_forward(q, k, v, g, beta, state, chunk):
-    return (_chunk_scan(q, k, v, g, beta, state, chunk),
-            (q, k, v, g, beta, state))
+    o, after = _chunk_scan(q, k, v, g, beta, state, chunk)
+    return (checkpoint_name(o, SCAN_OUTPUT), after), (q, k, v, g, beta, state)
 
 
 def _chunk_scan_backward(chunk, kept, cotangents):

@@ -23,7 +23,8 @@ memory allows (`fit_held`, `held_policy` below; docs/activation_checkpointing.md
 
 import contextlib
 import dataclasses
-from typing import Callable, Dict, Mapping, Optional, Tuple
+from typing import (Callable, Dict, Mapping, Optional, Sequence, Tuple,
+                    Union)
 
 import jax
 
@@ -171,20 +172,26 @@ HELD_MARGIN_SHARE = 1 / 16
 
 @dataclasses.dataclass(frozen=True)
 class HeldPlan:
-    """What a block holds for its backward, and why no more: the names taken
-    (a prefix of `bytes_per_layer`'s order), every candidate's bytes a layer
-    on one device, the bytes that were free for them (`margin_bytes`
-    already taken off) and the first name that did not fit (None: all did)."""
+    """What the blocks hold for their backward, and why no more: the names
+    taken (a prefix of `bytes_per_layer`'s order), every candidate's bytes a
+    block on one device (a block is a layer where `layers` is a number), the
+    blocks that carry a name (`layers`: one number for every name, or a
+    number a name), how many of them hold it (`blocks`: all of them but for
+    the last name taken, which may stop short), the bytes that were free for
+    them (`margin_bytes` already taken off) and the first name of which a
+    block did not fit (None: every block of every name did)."""
     names: Tuple[str, ...]
     bytes_per_layer: Dict[str, int]
-    layers: int
+    layers: Union[int, Dict[str, int]]
     free_bytes: int
     margin_bytes: int
     first_unfit: Optional[str]
+    blocks: Dict[str, int]
 
     @property
     def held_bytes(self) -> int:
-        return self.layers * sum(self.bytes_per_layer[n] for n in self.names)
+        return sum(n * self.bytes_per_layer[name]
+                   for name, n in self.blocks.items())
 
     def to_dict(self):
         return {**dataclasses.asdict(self), "names": list(self.names),
@@ -192,34 +199,57 @@ class HeldPlan:
 
     def render(self) -> str:
         from deepspeed_tpu.telemetry.memscope import fmt_bytes
-        over_layers = lambda n: fmt_bytes(self.layers * self.bytes_per_layer[n])
-        held = ", ".join(f"{n} {over_layers(n)}" for n in self.names) \
-            or "nothing"
-        why = "every candidate fits" if self.first_unfit is None else \
-            f"{self.first_unfit} ({over_layers(self.first_unfit)}) does not fit"
-        return (f"held for the backward over {self.layers} layers: {held} = "
+        size = lambda name, n: fmt_bytes(n * self.bytes_per_layer[name])
+        unfit = self.first_unfit
+        if isinstance(self.layers, int):
+            over, taken = f" over {self.layers} layers", lambda name, n: ""
+            missing = f"{unfit} ({size(unfit, self.layers)})" if unfit else ""
+        else:
+            over = ""
+            taken = lambda name, n: f" in {n} of {self.layers[name]} blocks"
+            missing = "" if not unfit else \
+                f"{'one more block of ' if unfit in self.blocks else ''}" \
+                f"{unfit} ({size(unfit, 1)} a block)"
+        held = ", ".join(f"{name}{taken(name, n)} {size(name, n)}"
+                         for name, n in self.blocks.items()) or "nothing"
+        why = f"{missing} does not fit" if unfit else "every candidate fits"
+        return (f"held for the backward{over}: {held} = "
                 f"{fmt_bytes(self.held_bytes)} of "
                 f"{fmt_bytes(self.free_bytes)} free (margin "
                 f"{fmt_bytes(self.margin_bytes)} kept); {why}")
 
 
 def fit_held(free_bytes: int, bytes_per_layer: Mapping[str, int],
-             layers: int, margin_bytes: int = 0) -> HeldPlan:
+             layers: Union[int, Mapping[str, Sequence[int]]],
+             margin_bytes: int = 0) -> HeldPlan:
     """The names to hold: `bytes_per_layer`'s names in ITS order (the caller
-    ranks them by the recompute a held byte saves) while their sum over
-    `layers` fits under `free_bytes - margin_bytes`; the first that does not
-    fit ends the list, so the sets are nested as the free bytes grow. Pure
-    arithmetic: nothing is compiled or allocated."""
+    ranks them by the recompute a held byte saves) while their sum over the
+    blocks that carry them fits under `free_bytes - margin_bytes`; the first
+    that does not fit ends the list, so the sets are nested as the free
+    bytes grow. `layers` a number: a name is held by every layer or by none
+    (one scanned block, one policy). A name's GROUPS of blocks, in the
+    caller's order (`{name: (3, 3, 1)}`: a group is what one policy covers,
+    a scanned block's repeats): of the name that ends the list the leading
+    groups that fit are held. Pure arithmetic: nothing is compiled or
+    allocated."""
     room = max(0, int(free_bytes) - int(margin_bytes))
-    names, used, unfit = [], 0, None
+    whole = isinstance(layers, int)
+    blocks, used, unfit = {}, 0, None
     for name, nbytes in bytes_per_layer.items():
-        if used + layers * nbytes > room:
-            unfit = name
+        fits = 0
+        for size in (layers,) if whole else layers[name]:
+            if used + size * nbytes > room:
+                unfit = name
+                break
+            used, fits = used + size * nbytes, fits + size
+        if fits:
+            blocks[name] = fits
+        if unfit:
             break
-        used += layers * nbytes
-        names.append(name)
-    return HeldPlan(tuple(names), dict(bytes_per_layer), int(layers), room,
-                    int(margin_bytes), unfit)
+    carrying = int(layers) if whole else \
+        {name: sum(groups) for name, groups in layers.items()}
+    return HeldPlan(tuple(blocks), dict(bytes_per_layer), carrying, room,
+                    int(margin_bytes), unfit, blocks)
 
 
 _BUDGET = None      # (free bytes, gradient bytes, margin, report)
@@ -244,20 +274,46 @@ def held_budget(free_bytes: int, grad_bytes: int = 0, margin_bytes: int = 0,
         _BUDGET = before
 
 
-def held_policy(bytes_per_layer: Mapping[str, int], layers: int,
-                carried_bytes: int = 0, loss_bytes: int = 0,
-                backward_bytes: int = 0):
-    """The `jax.checkpoint` policy of a block scanned over `layers`: hold the
-    named results that fit in the installed budget beside what the step
-    keeps with NOTHING held — the layers' inputs (`carried_bytes`) and the
-    larger of its two working sets, the loss's (`loss_bytes`) or one block's
-    backward beside the gradients (`backward_bytes`). No budget, or no room:
-    `nothing_saveable`, the program a block has always lowered to."""
+def held_plan(bytes_per_layer: Mapping[str, int],
+              layers: Union[int, Mapping[str, Sequence[int]]],
+              working_sets: Sequence[Mapping[str, float]]) -> HeldPlan:
+    """What fits of `bytes_per_layer` (`fit_held`) in the installed budget
+    beside what the step keeps with NOTHING held, at the moment that keeps
+    the most. A moment of `working_sets`: the inputs of the blocks whose
+    backward is still to come (`carried_bytes`) and the larger of two
+    working sets, the loss's (`loss_bytes`) or one block's backward
+    (`backward_bytes`) beside the gradients made so far (`grads_share` of
+    them; all of them where it is left out). The plan is said to the
+    budget's `report`. No budget: no room."""
     free, grads, margin, report = _BUDGET or (0, 0, 0, None)
-    floor = carried_bytes + max(loss_bytes, grads + backward_bytes)
+    floor = max(
+        at.get("carried_bytes", 0) + max(
+            at.get("loss_bytes", 0),
+            int(at.get("grads_share", 1.0) * grads)
+            + at.get("backward_bytes", 0))
+        for at in working_sets)
     plan = fit_held(free - floor, bytes_per_layer, layers, margin)
     if report is not None:
         report(plan)
-    if not plan.names:
+    return plan
+
+
+def policy_holding(names):
+    """The `jax.checkpoint` policy of a block that holds `names`; none:
+    `nothing_saveable`, the program a block has always lowered to."""
+    if not names:
         return jax.checkpoint_policies.nothing_saveable
-    return jax.checkpoint_policies.save_only_these_names(*plan.names)
+    return jax.checkpoint_policies.save_only_these_names(*names)
+
+
+def held_policy(bytes_per_layer: Mapping[str, int], layers: int,
+                carried_bytes: int = 0, loss_bytes: int = 0,
+                backward_bytes: int = 0):
+    """The `jax.checkpoint` policy of ONE block scanned over `layers`: every
+    layer holds the names that fit (`held_plan`) beside every layer's input
+    (`carried_bytes`), and the loss's working set or one block's backward
+    with all the gradients."""
+    plan = held_plan(bytes_per_layer, layers, [dict(
+        carried_bytes=carried_bytes, loss_bytes=loss_bytes,
+        backward_bytes=backward_bytes)])
+    return policy_holding(plan.names)

@@ -11,15 +11,17 @@ import pytest
 
 import deepspeed_tpu
 from deepspeed_tpu.comm import mesh as mesh_mod
-from deepspeed_tpu.models import gpt
+from deepspeed_tpu.models import gpt, hybrid, olmo_hybrid
 from deepspeed_tpu.models.gpt import (ATTN_OUT, MLP_PRODUCT, QKV_PRODUCT,
                                       GPTConfig, gpt_init_fn, gpt_loss,
                                       held_candidates, make_gpt_model)
 from deepspeed_tpu.ops.pallas.flash_attention import FLASH_RESIDUALS
+from deepspeed_tpu.ops.pallas.gdn import SCAN_OUTPUT
 from deepspeed_tpu.platform.accelerator import get_accelerator
 from deepspeed_tpu.runtime.activation_checkpointing import (HELD_MARGIN_SHARE,
                                                             fit_held,
-                                                            held_budget)
+                                                            held_budget,
+                                                            held_plan)
 
 GIB = 2**30
 # one block, every width its own: the QKV product [.., 192], the MLP's
@@ -309,3 +311,141 @@ def test_device_tree_bytes_reads_the_shards():
     bare = np.zeros((3,), np.float32)
     assert device_tree_bytes((whole, split, bare, None)) == 192 + 24 + 12
     assert tree_bytes((whole, split)) == 192 + 96
+
+
+# ---- (5) the hybrid loop's table: blocks, not layers ----------------------
+
+OLMO_PARAMS = 928_892_916       # bfloat16, two bfloat16 moments, no master
+HYBRID_ORDER = (FLASH_RESIDUALS, QKV_PRODUCT, MLP_PRODUCT, SCAN_OUTPUT)
+
+
+def _olmo_cell():
+    """`train_olmohybrid_seq32k_1chip`'s model and its table at [1, 32768]."""
+    import json
+    import os
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "olmo-hybrid-7b-4l-vp8.json")) as f:
+        cfg = olmo_hybrid.olmo_hybrid_config(
+            json.load(f), max_seq_len=32768, use_flash_attention=True)
+    return cfg, hybrid.held_candidates(cfg, 1, 32768)
+
+
+def test_the_hybrid_cells_bytes_a_block():
+    cfg, (held, carriers, working_sets) = _olmo_cell()
+    assert hybrid.layer_runs(cfg) == [("DF", 3), ("*F", 1)]
+    assert tuple(held) == HYBRID_ORDER
+    assert held == {
+        FLASH_RESIDUALS: 270 * MIB,         # 32768 x 30 x (128 x 2 + 32)
+        QKV_PRODUCT: 720 * MIB,             # 32768 x 90 heads of 128
+        MLP_PRODUCT: 1376 * MIB,            # gate and up, 32768 x 11008
+        SCAN_OUTPUT: 90 * MIB}              # float32 [1, 4096, 30, 192]
+    # from the stack's end; a scanned block's repeats are one group: the
+    # three `D` halves are ONE position of a run scanned three times, eight
+    # segments each, and their feed-forwards go together behind the last one
+    assert carriers == {FLASH_RESIDUALS: (1,), QKV_PRODUCT: (1,),
+                        MLP_PRODUCT: (1, 3), SCAN_OUTPUT: (3,) * 8}
+    stream = 32768 * 3840 * 2
+    carried = 30 * 96 * 192 * 4 + 3 * 11520 * 2     # a state and a tail
+    first, last = working_sets
+    assert first == dict(carried_bytes=3 * (2 * stream + 8 * carried),
+                         grads_share=1.0,
+                         backward_bytes=5 * 32768 * 11008 * 2)
+    assert last["carried_bytes"] == first["carried_bytes"] + 2 * stream
+    assert 0.30 < last["grads_share"] < 0.31        # `*F`, embedding, head
+    # seven chunks of the 12544-row slice, float32, three arrays of them
+    assert last["loss_bytes"] == 3 * 32768 * 4 * (12544 // 7) + 4 * stream
+
+
+@pytest.mark.parametrize("limit, blocks, unfit", [
+    # the cell as the engine hands it over: 15.75 GiB beside 5.19 of state.
+    # The last feed-forward's products fit; the three of the run scanned
+    # three times go together or not at all, and nothing behind them in the
+    # order jumps the queue
+    (V5E, {FLASH_RESIDUALS: 1, QKV_PRODUCT: 1, MLP_PRODUCT: 1}, MLP_PRODUCT),
+    (V5E + 3 * GIB, {FLASH_RESIDUALS: 1, QKV_PRODUCT: 1, MLP_PRODUCT: 1},
+     MLP_PRODUCT),
+    (V5E + 4 * GIB, {FLASH_RESIDUALS: 1, QKV_PRODUCT: 1, MLP_PRODUCT: 4,
+                     SCAN_OUTPUT: 3}, SCAN_OUTPUT),
+    (V5E + 5 * GIB, {FLASH_RESIDUALS: 1, QKV_PRODUCT: 1, MLP_PRODUCT: 4,
+                     SCAN_OUTPUT: 15}, SCAN_OUTPUT),
+    (V5E + 6 * GIB, dict(zip(HYBRID_ORDER, (1, 1, 4, 24))), None),
+    # less room
+    (V5E - GIB, {FLASH_RESIDUALS: 1, QKV_PRODUCT: 1}, MLP_PRODUCT),
+    (V5E - 3 * GIB + 200 * MIB, {FLASH_RESIDUALS: 1}, QKV_PRODUCT),
+    (V5E - 3 * GIB, {}, FLASH_RESIDUALS),
+    (0, {}, FLASH_RESIDUALS),
+], ids=["v5e", "three_ffns_do_not_fit", "scan_a_segment", "scan_5_segments",
+        "everything", "flash_and_qkv", "flash_alone", "nothing", "no_limit"])
+def test_hybrid_fit_table(limit, blocks, unfit):
+    _, (held, carriers, working_sets) = _olmo_cell()
+    plans = []
+    with held_budget(limit - 6 * OLMO_PARAMS, 2 * OLMO_PARAMS,
+                     int(limit * HELD_MARGIN_SHARE), plans.append):
+        held_plan(held, carriers, working_sets)
+    plan, = plans
+    assert plan.blocks == blocks and plan.first_unfit == unfit
+    assert plan.names == HYBRID_ORDER[:len(blocks)]      # a nested prefix
+    assert plan.layers == dict(zip(HYBRID_ORDER, (1, 1, 4, 24)))
+    assert plan.held_bytes == sum(n * held[name]
+                                  for name, n in blocks.items())
+    assert plan.held_bytes <= plan.free_bytes
+    if limit == V5E:
+        # what the compiler read with nothing held (compiled for a described
+        # v5e, PERF.md section 6, PR 57): 11.774 GiB
+        floor = limit - plan.margin_bytes - plan.free_bytes
+        assert abs(floor - 11.774 * GIB) < 0.1 * GIB
+        assert plan.render() == (
+            "held for the backward: flash_residuals in 1 of 1 blocks 270.00 "
+            "MiB, qkv_product in 1 of 1 blocks 720.00 MiB, mlp_product in 1 "
+            "of 4 blocks 1.34 GiB = 2.31 GiB of 3.03 GiB free (margin "
+            "1007.87 MiB kept); one more block of mlp_product (1.34 GiB a "
+            "block) does not fit")
+
+
+def test_fit_by_groups_is_monotone_and_never_over_budget():
+    held = {"a": 3, "b": 10, "c": 2}
+    groups = {"a": (2, 1), "b": (3, 3, 1), "c": (1,) * 4}
+    before = {}
+    for free in range(0, 120):
+        plan = fit_held(free, held, groups, margin_bytes=7)
+        assert plan.held_bytes <= max(0, free - 7)
+        assert plan.names == tuple(held)[:len(plan.names)]
+        # whole groups, from the front, and no name behind one cut short
+        for name, n in plan.blocks.items():
+            sizes = groups[name]
+            assert n in [sum(sizes[:k]) for k in range(1, len(sizes) + 1)]
+            assert n >= before.get(name, 0)
+        short = [n for n in plan.names if plan.blocks[n] < sum(groups[n])]
+        assert short in ([], list(plan.names[-1:]))
+        assert plan.first_unfit == (short[0] if short else
+                                    None if len(plan.names) == len(held)
+                                    else tuple(held)[len(plan.names)])
+        before = plan.blocks
+    assert before == {"a": 3, "b": 7, "c": 4}     # 9 + 70 + 8 of 113
+
+
+@pytest.mark.parametrize("shards, names, line", [
+    (1, (FLASH_RESIDUALS,),
+     "held for the backward over 24 layers: flash_residuals 1.69 GiB = 1.69 "
+     "GiB of 2.04 GiB free (margin 1007.87 MiB kept); mlp_product (6.00 "
+     "GiB) does not fit"),
+    (4, (FLASH_RESIDUALS, MLP_PRODUCT),
+     "held for the backward over 24 layers: flash_residuals 1.69 GiB, "
+     "mlp_product 6.00 GiB = 7.69 GiB of 9.37 GiB free (margin 1007.87 MiB "
+     "kept); qkv_product (4.50 GiB) does not fit"),
+], ids=["one_chip", "zero3_four_chips"])
+def test_the_gpt_cells_plans_are_what_they_were(shards, names, line):
+    """`fit_held` by groups left the GPT block's call alone: a number of
+    layers, every layer or none, the plan's line as PR 49 logged it."""
+    from deepspeed_tpu.runtime.activation_checkpointing import held_policy
+    held, working_set = held_candidates(PYTHIA, 8, 2048)
+    plans = []
+    with held_budget(V5E - 6 * N_PARAMS // shards, 2 * N_PARAMS // shards,
+                     MARGIN, plans.append):
+        policy = held_policy(held, PYTHIA.n_layer, **working_set)
+    plan, = plans
+    assert plan.names == names and plan.layers == 24
+    assert plan.blocks == {name: 24 for name in names}
+    assert plan.render() == line
+    assert policy is not jax.checkpoint_policies.nothing_saveable

@@ -24,6 +24,7 @@ from deepspeed_tpu.comm import mesh as mesh_mod
 from deepspeed_tpu.models import hybrid
 from deepspeed_tpu.models import olmo_hybrid as oh
 from deepspeed_tpu.ops.pallas import gdn
+from tests.test_held_residuals import _flash_forwards
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -316,6 +317,166 @@ def test_backward_keeps_nothing_of_a_chunks_system(model):
 
 
 # ----------------------------------------------------------------------
+# what a block holds for its backward (`hybrid.held_candidates`)
+# ----------------------------------------------------------------------
+
+V5E = 16909336064               # memory_stats()["bytes_limit"] of one v5e
+
+
+def _held_case(T, flash, monkeypatch):
+    """One period at T positions in blocks of 64 (`SEGMENT`): (cfg, params,
+    batch, the candidates' table, the bytes the step keeps with nothing
+    held when it has no gradients to count)."""
+    monkeypatch.setattr(hybrid, "SEGMENT", 64)
+    cfg = oh.olmo_hybrid_config(
+        {**PUBLISHED, "num_hidden_layers": 4, "layer_types": list(PERIOD)},
+        dtype=jnp.float32, use_flash_attention=flash)
+    params = lively(oh.olmo_hybrid_init_fn(cfg)(jax.random.PRNGKey(0)))
+    tokens = np.random.default_rng(3).integers(
+        0, cfg.vocab_size, (2, T + 1)).astype(np.int32)
+    batch = {"tokens": tokens[:, :-1], "labels": tokens[:, 1:]}
+    table = hybrid.held_candidates(cfg, 2, T)
+    floor = max(at["carried_bytes"] + max(at.get("loss_bytes", 0),
+                                          at["backward_bytes"])
+                for at in table[2])
+    return cfg, params, batch, table, floor
+
+
+def _budgeted(cfg, batch, free):
+    """loss and gradients traced with `free` bytes on offer (None: no
+    budget at all), and the plans the loop reported."""
+    from deepspeed_tpu.runtime.activation_checkpointing import held_budget
+    plans = []
+
+    def run(params):
+        fn = jax.value_and_grad(
+            lambda p: hybrid.hybrid_loss(p, batch, None, cfg))
+        if free is None:
+            return fn(params)
+        with held_budget(free, report=plans.append):
+            return fn(params)
+
+    return run, plans
+
+
+def _scans_made_again(jaxpr, times=1):
+    """The delta rule's forward scans that stand in a block's
+    recomputation, each as often as the loops around it run it."""
+    n = 0
+    for eqn in jaxpr.eqns:
+        stack = str(eqn.source_info.name_stack)
+        scan = eqn.primitive.name == "scan"
+        if scan and "gdn/scan" in stack and "rematted_computation" in stack:
+            n += times
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            n += _scans_made_again(
+                sub, times * (eqn.params["length"] if scan else 1))
+    return n
+
+
+# (T, flash, the names' blocks a plan should hold) -> the scan's forwards
+# made again: one a block that does not hold its output, of the twelve that
+# three `D` layers run in four blocks each (T 200: three of 64 and a tail).
+# A name's blocks are dealt from the stack's END.
+HELD_CASES = {
+    "nothing": (256, True, {}, 12),
+    "flash_alone": (256, True, {"flash_residuals": 1}, 12),
+    "flash_qkv_and_the_last_ffn": (
+        256, True, {"flash_residuals": 1, "qkv_product": 1, "mlp_product": 1},
+        12),
+    "the_last_two_segments_of_four": (
+        256, True, {"flash_residuals": 1, "qkv_product": 1, "mlp_product": 4,
+                    "gdn_scan_output": 6}, 6),
+    "every_name": (
+        256, True, {"flash_residuals": 1, "qkv_product": 1, "mlp_product": 4,
+                    "gdn_scan_output": 12}, 0),
+    "the_tail_alone": (
+        200, False, {"qkv_product": 1, "mlp_product": 4,
+                     "gdn_scan_output": 3}, 9),
+    "every_name_and_the_tail": (
+        200, False, {"qkv_product": 1, "mlp_product": 4,
+                     "gdn_scan_output": 12}, 0),
+}
+
+
+@pytest.mark.parametrize("case", list(HELD_CASES))
+def test_a_held_scan_output_is_not_made_again(case, monkeypatch):
+    """Counted on the jaxpr of the gradient: with `gdn.SCAN_OUTPUT` held a
+    block's recomputation holds NO forward scan (its every equation is dead
+    there: the rule keeps its inputs alone) and the backward is
+    `_chunk_scan_backward`'s two loops; the flash forward runs once where
+    its residuals are held."""
+    T, flash, blocks, again = HELD_CASES[case]
+    cfg, params, batch, (held, carriers, _), floor = _held_case(
+        T, flash, monkeypatch)
+    assert carriers[gdn.SCAN_OUTPUT] == (3,) * -(-T // 64)
+    free = floor + sum(n * held[name] for name, n in blocks.items())
+    run, plans = _budgeted(cfg, batch, free)
+    jaxpr = jax.make_jaxpr(run)(params).jaxpr
+    assert plans[-1].blocks == blocks
+    assert _scans_made_again(jaxpr) == again
+    if flash:
+        assert _flash_forwards(jaxpr) == (1 if blocks else 2)
+
+
+# XLA:CPU fuses two programs differently (a held result changes what stands
+# beside a product), and a fusion rounds differently: 4e-6 on a gradient.
+# With its fusion passes off the two programs run the same operations, and
+# the comparison reads the program's own arithmetic.
+UNFUSED = {"xla_disable_hlo_passes": "fusion,cpu-instruction-fusion"}
+
+
+@pytest.mark.parametrize("case", [c for c in HELD_CASES if c != "nothing"])
+def test_loss_and_every_gradient_are_equal_whatever_is_held(case,
+                                                            monkeypatch):
+    """EQUAL, not close: a held result IS the one the block would make
+    again (float32 scan output, no narrower copy), so a plan changes what
+    a step keeps and nothing it computes. Where a name stops midway
+    through a half the half's segments run as two scans, and a weight's
+    gradient is summed over the segments in two parts: the same terms in
+    another order, equal to rounding."""
+    T, flash, blocks, _ = HELD_CASES[case]
+    cfg, params, batch, (held, _, _), floor = _held_case(T, flash,
+                                                         monkeypatch)
+    unfused = lambda run: jax.jit(run).lower(params).compile(
+        compiler_options=UNFUSED)(params)
+    want_loss, want = unfused(_budgeted(cfg, batch, None)[0])
+    run, plans = _budgeted(
+        cfg, batch, floor + sum(n * held[name] for name, n in blocks.items()))
+    loss, got = unfused(run)
+    assert plans[-1].blocks == blocks
+    assert float(loss) == float(want_loss)
+    # whole segments that hold the output beside whole segments that do not
+    # (the tail of T 200 is a block of its own in either program, and the
+    # first to hold)
+    groups = blocks.get("gdn_scan_output", 0) // 3
+    split = 0 < groups - (1 if T % 64 and groups else 0) < T // 64
+    for (path, g), w in zip(jax.tree_util.tree_leaves_with_path(got),
+                            jax.tree_util.tree_leaves(want)):
+        if split:
+            np.testing.assert_allclose(g, w, rtol=2e-5, atol=1e-6 * float(
+                jnp.abs(w).max()), err_msg=jax.tree_util.keystr(path))
+        else:
+            assert bool((g == w).all()), jax.tree_util.keystr(path)
+
+
+@pytest.mark.parametrize("free", [None, 0], ids=["no_budget", "no_room"])
+def test_with_nothing_held_the_gradient_lowers_to_the_parents_text(
+        free, monkeypatch):
+    """No budget installed (the CPU harness, any caller outside the
+    engine), or one with no room: `nothing_saveable`, one scan a recurrent
+    half, and the text `hybrid_loss`'s gradient lowered to on the commit
+    this PR started from (`tests/step_program_hashes.json`, key
+    `olmo_hybrid_grad`, written there by this function on that commit)."""
+    cfg, params, batch, _, _ = _held_case(256, True, monkeypatch)
+    run, _ = _budgeted(cfg, batch, free)
+    text = _strip(jax.jit(run).lower(params).as_text())
+    with open(os.path.join(ROOT, "tests", "step_program_hashes.json")) as f:
+        assert hashlib.sha256(text.encode()).hexdigest()[:16] == \
+            json.load(f)["olmo_hybrid_grad"]["T256_flash_segments_of_64"]
+
+
+# ----------------------------------------------------------------------
 # through the engine
 # ----------------------------------------------------------------------
 
@@ -402,6 +563,36 @@ def test_training_step_names_the_delta_rules_backward():
     assert [r for r in named("gdn/scan", True) if r.opcode == "while"]
     from deepspeed_tpu.telemetry import device_scopes as ds
     assert not {r.scope for r in rows if ds.segments(r.scope) is None}
+
+
+@pytest.mark.parametrize("limit, names", [
+    (0, ()),                                  # the CPU reports no limit
+    (V5E, ("qkv_product", "mlp_product", "gdn_scan_output")),
+    (2**19, ()),                              # a limit the state fills
+])
+def test_engine_reports_the_hybrid_loops_plan(monkeypatch, limit, names):
+    """The engine offers the hybrid loop what it offers the GPT block: the
+    plan in `engine.held_plan` and the step ring's facts, one compile, the
+    same losses whatever is held."""
+    from deepspeed_tpu.platform.accelerator import get_accelerator
+    _, want = _train(0, 1)
+    monkeypatch.setattr(type(get_accelerator()), "total_memory",
+                        lambda self, device=None: limit)
+    engine, losses = _train(0, 1)
+    np.testing.assert_allclose(losses, want, rtol=1e-5)
+    plan = engine.held_plan
+    assert plan.names == names
+    # a name's blocks over the stack: one period, 96 positions a block
+    assert plan.layers == {"qkv_product": 1, "mlp_product": 4,
+                           "gdn_scan_output": 3}
+    assert plan.blocks == {name: plan.layers[name] for name in names}
+    assert plan.bytes_per_layer["gdn_scan_output"] == 4 * 96 * 4 * 48 * 4
+    assert engine.steptrace.facts["held_residuals"] == plan.to_dict()
+    assert engine._compiled_train_programs() == 1
+    if not names:
+        assert plan.first_unfit == "qkv_product"
+    else:
+        assert plan.first_unfit is None and 0 < plan.held_bytes
 
 
 # ----------------------------------------------------------------------
