@@ -41,16 +41,24 @@ Every such call has a record of its own in the step timeline's call ring
 (`telemetry/steptrace.py::CallRecord`): opened where it goes out
 (`_dispatching`), closed where it is read (`_read_back`).
 
-A model that generates by DIFFUSION OVER BLOCKS (`DecodeModelSpec.generator`)
-runs through the same loop with another kind of call: `decode_step` /
-`mixed_step` commit `blocks_per_call` whole blocks of B tokens a slot through
-denoise + commit forwards of B rows a slot, all slots block-synchronous, so
-the host still books `pos += window` at dispatch; call k+1 takes NOTHING from
-call k (a slot's first block is mask ids, or a new request's prompt tail:
-`_block_input`); a prompt's last chunk samples no first token; and what the
-call record and `stats()` count tells forwards and rows apart from tokens.
-Everything of it stands behind `self.generator`; docs/inference.md says what
-is refused under it.
+What KIND of decode call the model makes is a GENERATOR's to say
+(`inference/generators.py`; the engine always holds one, `self.gen`, and the
+loop calls it and tests nothing): the window a slot advances a call and the
+forwards a chunk may ride, what a slot feeds the call and which leading
+tokens of its row are not generated (`skip`), what the next call picks from
+this one, whether a prompt's last chunk samples a first token, where a
+call's walks are counted and when they join the step's sums, how a read-back
+closes the call's record. The plain instance is one token a slot a forward;
+DIFFUSION OVER BLOCKS (`DecodeModelSpec.generator`) commits whole blocks of B
+tokens a slot through denoise + commit forwards of B rows and takes nothing
+from the call before. docs/inference.md states the contract and what each
+instance refuses.
+
+What a call DOES is booked as `StepRecord` fields by name (`_book`): a decode
+call's walk and each chunk's are dicts of them, the arithmetic kept beside
+the kernel that does the work (`ops/pallas/*::*_walk_counts`; a chunk's
+through the `work` of the attention program it was traced with,
+`ops/attention_dispatch.py`), summed a step and handed to `end_step` whole.
 
 Compile accounting is first-class: `compile_stats()` reads the jit caches,
 and the serving tests assert <= 1 compile per bucket across any trace.
@@ -80,7 +88,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from deepspeed_tpu.inference import step_programs
+from deepspeed_tpu.inference import generators, step_programs
 from deepspeed_tpu.inference.audit import PoolAuditor, PoolCorruptionError
 from deepspeed_tpu.inference.kv_cache import (BlockAllocator, TRASH_BLOCK,
                                               blocks_needed, max_written_pos,
@@ -141,6 +149,25 @@ class CompletedRequest:
 
 _FREE, _PREFILL, _DECODE, _HANDOFF = 0, 1, 2, 3
 
+# Which field of the step record a count of a walk lands in. A kernel's host
+# twin names what the kernel does (`ops/pallas/*::*_walk_counts`); the loop
+# alone knows the phase it ran in and the kind of layer it was asked for.
+_DECODE_FIELDS = {"live_blocks": "decode_live_blocks",
+                  "grid_steps": "decode_grid_steps"}
+_DECODE_WINDOW_FIELDS = {"live_blocks": "decode_window_live_blocks",
+                         "table_blocks": "decode_window_table_blocks"}
+_CHUNK_FIELDS = {"live_blocks": "prefill_live_blocks",
+                 "table_blocks": "prefill_table_blocks",
+                 "kept_pairs": "prefill_kept_pairs",
+                 "latent_positions": "latent_chunk_positions"}
+_CHUNK_WINDOW_FIELDS = {"live_blocks": "prefill_window_live_blocks",
+                        "table_blocks": "prefill_window_table_blocks",
+                        "kept_pairs": "prefill_window_kept_pairs"}
+
+
+def _lands(counts, fields):
+    return {fields[name]: n for name, n in counts.items()}
+
 
 class _Slot:
     __slots__ = ("idx", "state", "uid", "prompt", "prompt_len", "padded_len",
@@ -189,17 +216,18 @@ class _Call:
     request's life (`_vacate` puts a new one in its place), so a row here
     can only ever reach the request it was sampled for."""
     __slots__ = ("id", "out", "prev", "mixed", "win", "rows", "firsts",
-                 "chunks", "riding", "leaving", "skip", "walk")
+                 "chunks", "riding", "leaving", "skip", "work")
 
     def __init__(self, id, out, prev, mixed, win, rows, firsts, chunks,
-                 riding, skip=None, walk=None):
+                 riding, skip, work):
         self.id = id            # what a slot's `feed` names (a number, not
                                 # the call: a slot holds no call alive) and
                                 # its `steptrace.CallRecord`, which has its
                                 # stamps
         self.out = out          # the program's output, still on the device
-        self.prev = prev        # of it, (first [W * G], nxt [S, win]) for
-                                # the next call's `pick`
+        self.prev = prev        # of it, what the next call picks its input
+                                # from: (first [W * G], nxt [S, win]), or
+                                # None (`generators.py`: `opens`)
         self.mixed = mixed      # `out` has first tokens beside the window
         self.win = win
         self.rows = rows        # requests in the decode window, row `idx` each
@@ -210,12 +238,13 @@ class _Call:
                                 # dispatched before it
         self.riding = riding    # (request, start) of the chunks that rode
         self.leaving = []       # requests that gave up their slot at dispatch
-        self.skip = skip or {}  # a block-diffusion call: slot index -> the
-                                # prompt tokens that open its row (the clean
-                                # tokens of a request's first block)
-        self.walk = walk        # ... and `_decode_walk`'s counts of ONE
-                                # forward of each of its blocks: booked at the
-                                # read-back, by the forwards the call took
+        self.skip = skip        # slot index -> the leading tokens of its
+                                # row that are not generated (a request's
+                                # prompt tail opening its first block; empty
+                                # for a token a row)
+        self.work = work        # `_decode_walk`'s `StepRecord` fields: what
+                                # of them is due at the read-back is the
+                                # generator's to say (`due`)
 
     def awaited(self):
         """Does anything wait for this call's read-back: a token a live
@@ -336,31 +365,23 @@ class ServingEngine:
         self.max_slots = int(scfg.max_slots)
         self.chunk = int(scfg.prefill_chunk or bs)
         self.prefill_budget = max(1, int(scfg.prefill_chunks_per_step))
-        self.window = max(1, int(scfg.decode_steps_per_sync))
         # speculative decoding: the verify step REPLACES the decode step
         # (and its window) when a drafter is configured
         self.spec_on = str(scfg.spec_decode.drafter or "off") != "off"
-        # a model that generates by diffusion over blocks
-        # (`DecodeModelSpec.generator`): a decode call commits
-        # `blocks_per_call` whole blocks of B tokens a slot, so positions
-        # advance by `window` = blocks_per_call * B a call, and the call's
-        # chunks ride its `ride_window` = blocks_per_call * (steps + 1)
-        # forwards. What this generator cannot have yet is refused HERE, by
-        # name, as the pools of two kinds refuse theirs
-        self.generator = None if self.streamed \
-            else getattr(spec, "generator", None)
-        self.denoising_steps = 0
-        if self.generator is not None:
-            self._refuse_for_generator(spec, scfg, engine.config)
-            B = self.generator.block_length
-            self.blocks_per_call = max(1, int(scfg.blocks_per_call))
-            self.denoising_steps = self.generator.steps
-            self.window = self.blocks_per_call * B
-            names = step_programs.step_counter_names(spec)
-            self._forward_counters = [names.index(name) for name in (
-                "denoise_forwards", "commit_forwards")]
-        self.ride_window = self.window if self.generator is None \
-            else self.blocks_per_call * (self.denoising_steps + 1)
+        # the kind of decode call the model makes (`generators.py`): the
+        # positions a slot advances a call (`window`) and the forwards its
+        # chunks may ride (`ride_window`) are the generator's; what one
+        # cannot have yet it refuses HERE, by name, as the pools of two
+        # kinds refuse theirs. `generator` is the model's own data
+        # (`DecodeModelSpec.generator`), None for a token a forward
+        self.gen = generators.build(
+            spec, scfg, engine.config, streamed=self.streamed,
+            window=max(1, int(scfg.decode_steps_per_sync)), chunk=self.chunk,
+            block_size=bs, spec_on=self.spec_on, max_slots=self.max_slots)
+        self.generator = self.gen.spec
+        self.window, self.ride_window = self.gen.window, self.gen.ride_window
+        self.blocks_per_call = self.gen.blocks_per_call
+        self.denoising_steps = self.gen.denoising_steps
         if self.streamed:
             # streamed-mode envelope: a K-step jitted window or a verify
             # chunk cannot host a per-layer Python walk — both are refused
@@ -563,8 +584,9 @@ class ServingEngine:
         # state proper (its first leaf), all layers
         self._state_token_bytes = 0
         if self.state_kind is not None:
-            leaf = self.pool[self.state_kind.leaves[0]]
-            self._state_token_bytes = 2 * int(leaf.nbytes // leaf.shape[1])
+            from deepspeed_tpu.ops.pallas.ssm import state_token_bytes
+            self._state_token_bytes = state_token_bytes(
+                self.pool[self.state_kind.leaves[0]])
         self.tables = np.full((self.max_slots, self.nb), TRASH_BLOCK, np.int32)
         self.slots = [_Slot(i) for i in range(self.max_slots)]
         self.queue = collections.deque()
@@ -587,8 +609,8 @@ class ServingEngine:
                                             np.int64)
         self._step_counts = np.zeros_like(self.step_counter_totals)
         self._call_counts = ()      # of the call read last (`_fetch`)
-        self._walk_read = np.zeros(5)   # a block-diffusion call's walk,
-                                        # booked when it is read (`_read`)
+        self._work = {}             # `StepRecord` field -> what the open
+                                    # step's calls do of it (`_book`)
         self._parked_counts = []
         # the loop runs one call deep (`_step_impl`): the newest dispatched
         # call whose tokens are unread, the completions a read-back outside
@@ -613,14 +635,12 @@ class ServingEngine:
             # tokens a call), where the model's mixed program takes a group
             group = -(-self.prefill_budget // self.ride_window) \
                 if getattr(spec, "mixed_chunk_groups", False) else 1
-            more = {} if self.generator is None else dict(
-                blocks_per_call=self.blocks_per_call,
-                denoising_steps=self.denoising_steps)
             self.programs = step_programs.build_resident(
                 spec, engine.config, engine._fn_transform,
                 window=self.ride_window, max_slots=self.max_slots,
                 chunk=self.chunk, spec_on=self.spec_on, draft_k=self.draft_k,
-                replicated=self._replicated, watchdog=wd, group=group, **more)
+                replicated=self._replicated, watchdog=wd, group=group,
+                **self.gen.program_args)
         # the device's side of the timeline, on demand: the recorder is
         # handed the built programs (`mixed_step` too, before any chunk has
         # ridden) and the SHAPES of their arguments, and lowers nothing
@@ -720,56 +740,12 @@ class ServingEngine:
                  f"prefill_chunk={self.chunk} weights={self.weight_quant}",
                  ranks=[0])
 
-    def _refuse_for_generator(self, spec, scfg, config):
-        """What a block-diffusion generator cannot have yet, each with its
-        reason (the engine says it at build time, never serves it wrong)."""
-        gen = self.generator
-        B = gen.block_length
-        kvd = str(scfg.quantization.kv_cache_dtype or "") \
-            or str(config.kv_cache_dtype)
-        asked = {
-            "spec_decode": (
-                self.spec_on,
-                "a verify chunk is causal inside and scores drafts of one "
-                "token a forward; a block's rows are generated together"),
-            "kv_cache_dtype int8": (
-                kvd == "int8",
-                "a block's rows are written once a denoise forward and read "
-                "by the walk at 8 x B query rows a KV head; the quantizing "
-                "write and the dequantizing walk are not built for it"),
-            "enable_prefix_caching": (
-                scfg.enable_prefix_caching,
-                "a block registers when its prompt chunk is dispatched, and "
-                "the block a prompt ends in is committed later by the decode "
-                "call that finishes it"),
-            "degradation": (
-                scfg.degradation.enabled,
-                "the ladder's window-shrink rung runs a one-token decode "
-                "program, and a call commits whole blocks"),
-            "sampling (greedy false)": (
-                not config.greedy,
-                "confidence is the probability of the argmax; the sampled "
-                "variants of the rule are not built"),
-            f"prefill_chunk {self.chunk}": (
-                self.chunk % B or self.block_size % B,
-                f"chunks and pool blocks hold whole blocks of {B}"),
-            "mixed_paged_fn / denoise_paged_fn": (
-                spec.denoise_paged_fn is None,
-                "the generator's forwards are the model's "
-                "`denoise_paged_fn`")}
-        for what, (wanted, why) in asked.items():
-            if wanted:
-                raise ValueError(
-                    f"model spec '{spec.name}' generates by diffusion over "
-                    f"blocks of {B}: {what} is not built for it — {why}")
-
     def _whole(self, prompt_len):
-        """The prompt tokens that PREFILL covers: all of them, or for a
-        block-diffusion generator the prompt's whole blocks (the `L mod B`
-        tokens left open the first generated block as clean tokens)."""
-        if self.generator is None:
-            return prompt_len
-        return prompt_len - prompt_len % self.generator.block_length
+        """The prompt tokens that PREFILL covers: its whole blocks of the
+        generator's (all of them at a token a row; the `L mod B` tokens a
+        block generator leaves open the first generated block as clean
+        tokens)."""
+        return prompt_len - prompt_len % self.gen.block
 
     def _next_rng(self):
         if self.config.greedy:
@@ -805,11 +781,11 @@ class ServingEngine:
                 f"request {uid}: max_new_tokens < 1")
         eff_new = 1 if prefill_only else max_new
         eff_window = 1 if prefill_only else self.window
-        if self.generator is not None:
-            # decode calls write whole windows from the prompt's last whole
-            # block on: the prompt's tail and `max_new` tokens, rounded up
-            eff_new += prompt_len - self._whole(prompt_len) + 1
-            prompt_len = self._whole(prompt_len)
+        # decode calls write from the prompt's last whole block on: its tail
+        # too, and one token more where the last chunk samples no first one
+        whole = self._whole(prompt_len)
+        eff_new += prompt_len - whole + (not self.gen.samples_first)
+        prompt_len = whole
         # a verify step always writes its full k-draft overhang, so spec
         # decode sizes past the window math (which it replaces); a
         # prefill-only slot never verifies here
@@ -913,13 +889,9 @@ class ServingEngine:
                            t_arrive, prefill_only, trace, deadline_at))
 
     def _refuse_transplant(self):
-        if self.generator is not None:
-            raise ValueError(
-                f"model spec '{self.engine.model_spec.name}' generates by "
-                f"diffusion over blocks: block transplant (prefill-only "
-                f"slots, handoff) is not built for it — a prompt's last "
-                f"block is not committed when its prefill ends, and no "
-                f"first token is sampled to hand over")
+        if self.gen.no_transplant:
+            raise ValueError(f"model spec '{self.engine.model_spec.name}' "
+                             f"{self.gen.no_transplant}")
         if self.ring_tables is not None:
             what = "a layer's recurrent state" if self.state_kind is not None \
                 else "a window layer's ring"
@@ -1534,7 +1506,7 @@ class ServingEngine:
     # speculative decoding: draft -> one fixed-shape verify -> accept+rewind
     # ------------------------------------------------------------------
 
-    def _verify_decode(self, dec, tok, pos, tables, finished):
+    def _verify_decode(self, dec, pos, tables, finished):
         """Draft+verify replacing the decode step: the drafter proposes up
         to `draft_k` tokens per slot, ONE jitted verify call scores drafts
         for ALL slots (writing their k/v at pos..pos+k through the tables),
@@ -1547,6 +1519,11 @@ class ServingEngine:
         blocks and table rows do not move."""
         st = self.steptrace
         with self._phase("serving/draft"):
+            # (nothing is in flight under spec decode, `_overlaps`: a slot's
+            # input is the token the host emitted last)
+            tok = np.zeros((self.max_slots,), np.int32)
+            for s in dec:
+                tok[s.idx] = s.emitted[-1]
             drafts, dlens = self.drafter.propose(dec, tok, pos, tables)
         if self.pressure is not None and self.pressure.draft_cap is not None:
             # ladder rung 1: cap the ACCEPTED draft length only — the
@@ -1668,13 +1645,6 @@ class ServingEngine:
         chunks0, tokens0 = self.prefill_chunks, self.tokens_generated
         calls0, overlapped0 = self.device_calls, self.overlapped_calls
         groups0, padded0 = self.chunk_groups, self.padded_chunks
-        walk = (0, 0, 0, 0, 0)  # the decode kernel's (live blocks, grid
-                             # steps, window layers' live blocks, ...
-                             # unwindowed) and a state kind's bytes
-        reach = [0] * 7     # the prefill kernel's (live, table) blocks, the
-                            # window layers' (live, unwindowed), a latent
-                            # kind's positions, the (query, position) pairs
-                            # a full and a window layer's masks keep
 
         overlap = self._overlaps()
         if not overlap:
@@ -1695,9 +1665,7 @@ class ServingEngine:
             s.state == _DECODE for s in self.slots))
         riding = due[len(due) - ride:]
         for slot, start in due[:len(due) - ride]:
-            for i, n in enumerate(self._prefill_chunk(slot, start, params,
-                                                       finished)):
-                reach[i] += n
+            self._prefill_chunk(slot, start, params, finished)
 
         # decode: ONE fixed-shape call for every slot; non-decoding slots
         # ride along against the trash block. With window > 1 the call
@@ -1711,31 +1679,21 @@ class ServingEngine:
         if dec:
             with self._phase("serving/decode_build"):
                 self.peak_active = max(self.peak_active, len(dec))
-                prior = self._pending
-                tok = np.zeros((self.max_slots,), np.int32)
-                src = np.zeros((self.max_slots,), np.int32)
                 pos = np.zeros((self.max_slots,), np.int32)
                 tables = np.full_like(self.tables, TRASH_BLOCK)
                 for s in dec:
                     pos[s.idx] = s.pos
                     tables[s.idx] = self.tables[s.idx]
-                    if self.generator is not None:
-                        continue    # (`_block_input` builds its rows)
-                    if prior is not None and s.feed is not None \
-                            and s.feed[0] == prior.id:
-                        src[s.idx] = s.feed[1]      # still on the device
-                    else:
-                        tok[s.idx] = s.emitted[-1]
-                feed = (src, tok) if self.generator is None \
-                    else self._block_input(dec)
-            spec_active = self.spec_on and not (
-                self.pressure is not None and self.pressure.spec_disabled)
+                spec_active = self.spec_on and not (
+                    self.pressure is not None and self.pressure.spec_disabled)
+                # what the slots feed the call is the generator's to build
+                # (the verify call takes the host's tokens: `_verify_decode`)
+                feed = None if spec_active else self.gen.feed(
+                    dec, self._pending, self.programs.no_prev)
             if spec_active:
-                self._verify_decode(dec, tok, pos, tables, finished)
+                self._verify_decode(dec, pos, tables, finished)
             else:
-                walk, rode = self._launch(dec, riding, params, feed,
-                                          pos, tables, finished)
-                reach = [a + b for a, b in zip(reach, rode)]
+                self._launch(dec, riding, params, feed, pos, tables, finished)
         if not (overlap and dec) or not self._pending.awaited():
             # nothing was put behind the call in flight, this step may leave
             # none, or no live request waits for what it left (every row
@@ -1777,28 +1735,15 @@ class ServingEngine:
             counters = tuple(int(v) for v in self._step_counts)
             self.step_counter_totals += self._step_counts
             self._step_counts[:] = 0
-        if self.generator is not None:
-            # the walks of the calls READ in this step, as the counters are
-            walk = tuple(int(round(v)) for v in self._walk_read)
-            self._walk_read[:] = 0
+        # what the step's calls do, by field (`_book`): the calls it
+        # dispatched, or where the generator says so the calls it READ (whose
+        # shares by the forwards taken are whole numbers in sum only)
+        work, self._work = self._work, {}
         st.end_step(counters=counters,
-                    decode_live_blocks=walk[0], decode_grid_steps=walk[1],
-                    prefill_live_blocks=reach[0],
-                    prefill_table_blocks=reach[1],
-                    decode_window_live_blocks=walk[2],
-                    decode_window_table_blocks=walk[3],
-                    prefill_window_live_blocks=reach[2],
-                    prefill_window_table_blocks=reach[3],
-                    latent_walk_blocks=walk[0] * self._latent,
-                    latent_chunk_positions=reach[4],
-                    prefill_kept_pairs=reach[5],
-                    prefill_window_kept_pairs=reach[6],
+                    **{name: int(round(n)) for name, n in work.items()},
                     admitted=admitted,
                     prefill_chunks=self.prefill_chunks - chunks0,
                     fused_chunks=len(riding),
-                    ssm_state_bytes=walk[4],
-                    ssm_chunk_tokens=(self.prefill_chunks - chunks0)
-                    * self.chunk * (self.state_kind is not None),
                     decoding=len(dec),
                     emitted=self.tokens_generated - tokens0,
                     queued=len(self.queue),
@@ -1894,43 +1839,11 @@ class ServingEngine:
         last = (whole - 1 - start) if final else self.chunk - 1
         return chunk, last, final
 
-    def _chunk_written(self, slot, start, program):
+    def _chunk_planned(self, slot, start):
         """Book a dispatched chunk of `slot`: what is planned of its prompt
         (`cursor` follows at the read-back that covers the chunk,
-        `_chunks_run`), counter, the prefix cache registrations it completes. Returns what the prefill kernel's
-        walk attends, a layer: (logical blocks under the chunk's frontier,
-        blocks in its table, and for a pool of two kinds the blocks a WINDOW
-        layer's walk visits and the blocks the same walk would visit with no
-        window, both in the window kind's blocks, the cached positions a
-        chunk over a LATENT kind attends, `start + chunk`, and the (query,
-        position) pairs the causal mask keeps of the chunk's walk, in a full
-        layer and, with the window, in a window layer) — zeros where
-        `program`, the attention program the chunk was traced with, is not
-        such a kernel."""
-        reach = (0,) * 7
-        if program in ("paged_prefill_kernel", "mla_prefill_kernel"):
-            from deepspeed_tpu.ops.pallas.prefill_attention import \
-                paged_prefill_live_blocks
-            table = self.tables.shape[1]
-            C = self.chunk
-            full = paged_prefill_live_blocks(
-                start, C, self.block_size, table)
-            kept = C * start + C * (C + 1) // 2
-            reach = (full, table, 0, 0,
-                     (start + C) * (program == "mla_prefill_kernel"), kept, 0)
-            if self.window_kind is not None:
-                # what a window layer's walk visits, in ITS blocks, of
-                # what the same chunk's walk would visit with no window
-                wkind = self.window_kind
-                width = self.ring_tables.shape[1]
-                # rows that see fewer than `window` positions: all they have
-                short = min(max(wkind.window - 1 - start, 0), C)
-                reach = (full, table, paged_prefill_live_blocks(
-                    start, C, wkind.block, width, wkind.window),
-                    paged_prefill_live_blocks(
-                        start, C, wkind.block, width), 0, kept,
-                    short * start + short * (short + 1) // 2
-                    + (C - short) * wkind.window)
+        `_chunks_run`), counter, the prefix cache registrations it
+        completes. What the chunk DOES is `_chunk_walk`'s to count."""
         slot.planned = start + self.chunk
         self._unread_chunks.append((slot, slot.planned))
         self.prefill_chunks += 1
@@ -1947,7 +1860,41 @@ class ServingEngine:
                 self.prefix_cache.register(slot.hashes[i],
                                            slot.blocks[i])
             slot.reg = max(slot.reg, hi)
-        return reach
+
+    def _chunk_walk(self, start, phase):
+        """What a chunk from `start` does, a layer, as `StepRecord` fields:
+        the positions a state kind's chunked scan runs over, and what the
+        attention program the chunk was traced with at `phase`
+        (`DecodeModelSpec.paged_attn_programs`: "prefill_chunk", or
+        "mixed/prefill_chunk" where it rides) counts of its walk — the
+        program's own `work` (`ops/attention_dispatch.py`), asked once for
+        the full layers' blocks and, for a pool of two kinds, once for a
+        WINDOW layer's walk in ITS blocks. Nothing of a walk where the
+        program has none (the gather oracle attends the whole table)."""
+        from deepspeed_tpu.ops.attention_dispatch import get_program
+        work = {"ssm_chunk_tokens": self.chunk} \
+            if self.state_kind is not None else {}
+        traced = getattr(self.engine.model_spec, "paged_attn_programs",
+                         None) or {}
+        count = get_program(traced[phase]).work if phase in traced else None
+        if count is None:
+            return work
+        work.update(_lands(count(
+            start, self.chunk, self.block_size, self.tables.shape[1]),
+            _CHUNK_FIELDS))
+        if self.window_kind is not None:
+            wkind = self.window_kind
+            work.update(_lands(count(
+                start, self.chunk, wkind.block, self.ring_tables.shape[1],
+                wkind.window), _CHUNK_WINDOW_FIELDS))
+        return work
+
+    def _book(self, work):
+        """Add `work` (`StepRecord` field -> count) to the open step's sums:
+        `end_step` takes them whole."""
+        sums = self._work
+        for name, n in work.items():
+            sums[name] = sums.get(name, 0) + n
 
     def _chunks_run(self, chunks):
         """A read-back returned that was dispatched after `chunks`
@@ -1963,7 +1910,7 @@ class ServingEngine:
         # the first sampled token is EOS or max_new == 1 — the
         # router then sees a normal completion from this engine
         slot.state = _HANDOFF if slot.prefill_only else _DECODE
-        if self.generator is None:  # (a block generator's chunk samples none)
+        if self.gen.samples_first:
             self._emit(slot, tok, finished)
 
     def _prefill_chunk(self, slot, start, params, finished):
@@ -1972,12 +1919,12 @@ class ServingEngine:
         input build, dispatch, the cache registrations it completes and,
         after the final chunk, the first-token read-back — a blocking read
         of this step's own, so a call in flight is read before it
-        (`_drain`). Returns `_chunk_written`'s walk counts."""
+        (`_drain`)."""
         st = self.steptrace
         ctx = slot.trace                      # _emit may retire the slot
         chunk, last, final = self._chunk_input(slot, start)
         with (self._dispatching("serving/prefill_chunk", "prefill",
-                                firsts=int(self.generator is None),
+                                firsts=int(self.gen.samples_first),
                                 chunks=len(self._unread_chunks) + 1)
               if final else self._phase("serving/prefill_chunk")) as ph:
             st.dispatched()
@@ -1995,8 +1942,8 @@ class ServingEngine:
                     np.asarray([last], np.int32),
                     self.tables[slot.idx][None])
             # counted here, while the device runs
-            reach = self._chunk_written(
-                slot, start, self.attention_programs().get("prefill_step"))
+            self._chunk_planned(slot, start)
+            self._book(self._chunk_walk(start, "prefill_chunk"))
             if not final:
                 # nobody reads this chunk's token: its counts wait for the
                 # next read-back
@@ -2019,29 +1966,8 @@ class ServingEngine:
                 attrs["call"] = rec.id
             self.tracer.record(ctx, "prefill_chunk", ph.t0, t1 - ph.t0,
                                tid=self.trace_tid, attrs=attrs)
-        return reach
 
-    def _block_input(self, dec):
-        """A block-diffusion call's tokens for the slots `dec`: (`tok`
-        [S, B] — a slot's first block of the call: mask ids where it goes on
-        generating, its prompt's last `L mod B` tokens before mask ids where
-        it begins, and no mask id in the row of a slot that is not in the
-        call —, slot index -> those prompt tokens' count). The host knows
-        all of it at dispatch: nothing is taken from the call in flight."""
-        gen = self.generator
-        B, mask = gen.block_length, gen.mask_token_id
-        tok = np.full((self.max_slots, B), int(mask == 0), np.int32)
-        skip = {}
-        for s in dec:
-            tok[s.idx] = mask
-            whole = self._whole(s.prompt_len)
-            if s.pos == whole and s.prompt_len > whole:
-                tail = s.prompt[whole:]
-                tok[s.idx, :len(tail)] = tail
-                skip[s.idx] = len(tail)
-        return tok, skip
-
-    def _launch(self, dec, riding, params, tok, pos, tables, finished):
+    def _launch(self, dec, riding, params, feed, pos, tables, finished):
         """Dispatch the decode call for every slot in `dec` — `decode_step`,
         or with the chunks `riding` it ((slot, start) each; up to G a token
         of the window, full groups first: token i takes chunks [i * G,
@@ -2049,18 +1975,19 @@ class ServingEngine:
         tokens after it are plain decode tokens) ONE `mixed_step` call — and
         only then
         read the call the step before left in flight (`_read`): this one is
-        queued behind it on the device meanwhile. `tok` is (src, host
-        tokens): a slot's input token is the host's, or stays on the device
-        as the output of the call in flight (`pick`). What the host can
-        count is booked at dispatch: positions, each chunk as
-        `_prefill_chunk` books its own, a prompt whose last chunk rides
-        (it decodes from the next call, on this call's first token), and a
-        request that reaches `max_new` inside this call (`_leave`). The read-
-        back brings the window's tokens, those first tokens and the model's
-        counters; it is left to the next step, which `_step_impl` decides.
-        Returns (`_decode_walk`'s counts, the chunks' `_chunk_written`
-        counts summed)."""
+        queued behind it on the device meanwhile. `feed` is the generator's
+        (the call's token argument, `skip`): for a token a row the host's
+        token, or the output of the call in flight, still on the device.
+        What the host can count is booked at dispatch: positions,
+        each chunk as `_prefill_chunk` books its own, the call's walks where
+        the generator says they are due (`_decode_walk`, `due`), a prompt
+        whose last chunk rides (it decodes from the next call; on this call's
+        first token, where one is sampled), and a request that reaches
+        `max_new` inside this call (`_leave`). The read-back brings the
+        window's tokens, those first tokens and the model's counters; it is
+        left to the next step, which `_step_impl` decides."""
         st = self.steptrace
+        gen = self.gen
         # the degraded paths (spec decode pressure-disabled, the ladder's
         # window-shrink rung) run the 1-STEP program: `programs.decode_w1`
         use_w1 = self.spec_on or (
@@ -2068,20 +1995,10 @@ class ServingEngine:
             and self.pressure.force_window_1)
         win, n = 1 if use_w1 else self.window, len(riding)
         G = self.programs.group
+        ride = self.ride_window     # the forwards a chunk group may ride
         prior = self._pending
         no_prev = self.programs.no_prev
-        gen, skip = self.generator, {}
-        if gen is None:
-            tok = (no_prev if prior is None else prior.prev,) + tok
-            ride = win      # the window positions a chunk group may ride
-            rows_win = win  # the rows a slot runs through the model
-        else:
-            # a call of blocks: `win` tokens a slot (its positions' advance),
-            # through `ride` forwards of B rows each where every block takes
-            # its `denoising_steps` (the read-back has the forwards it took)
-            tok, skip = tok
-            ride = self.ride_window
-            rows_win = ride * gen.block_length
+        tok, skip = feed
         finals = []
         if riding:
             with self._phase("serving/decode_build"):
@@ -2107,14 +2024,13 @@ class ServingEngine:
                     self._tables_arg(self.tables[idx], idx))
         step_fn = self.programs.mixed if riding else \
             self.programs.decode_w1() if use_w1 else self.programs.decode
-        rode = [0] * 7
         # the dispatch phase holds the jitted call alone: its two stamps are
         # the call record's launch, the arguments' hand-off and the enqueue
         with self._dispatching(
                 "serving/decode_window",
                 "mixed" if riding else "decode_w1" if use_w1 else "decode",
-                rows=len(dec), win=rows_win,
-                firsts=0 if gen else len(finals),
+                rows=len(dec), win=win * gen.row_forwards,
+                firsts=len(finals) * gen.samples_first,
                 chunks=len(self._unread_chunks) + n):
             st.dispatched()
             if riding:
@@ -2128,26 +2044,18 @@ class ServingEngine:
                                          self._next_rng())
         # what the call planned is booked here, while the device runs
         with self._phase("serving/decode_build"):
-            if riding:
-                program = (self.engine.model_spec.paged_attn_programs
-                           or {}).get("mixed/prefill_chunk")
-                rode = [sum(counts) for counts in zip(*(
-                    self._chunk_written(slot, start, program)
-                    for slot, start in riding))]
-                self.fused_chunks += n
-                self.chunk_groups += -(-n // G)
-                self.padded_chunks += -n % G
-            walk = self._decode_walk(dec, pos, win)
-            if gen is None:
-                call = _Call(self.device_calls, out,
-                             out[0] if riding else (no_prev[0], out[0]),
-                             bool(riding), win, dec, finals,
-                             self._unread_chunks, riding)
-            else:
-                # its output is the committed tokens alone: no first token
-                # is sampled, and the next call picks nothing from it
-                call = _Call(self.device_calls, out, None, False, win, dec,
-                             [], self._unread_chunks, riding, skip, walk)
+            for slot, start in riding:
+                self._chunk_planned(slot, start)
+                self._book(self._chunk_walk(start, "mixed/prefill_chunk"))
+            self.fused_chunks += n
+            self.chunk_groups += -(-n // G)
+            self.padded_chunks += -n % G
+            work = self._decode_walk(dec, pos, win)
+            self._book(gen.due(work))
+            prev, mixed = gen.opens(out, riding, no_prev)
+            call = _Call(self.device_calls, out, prev, mixed, win, dec,
+                         finals if gen.samples_first else [],
+                         self._unread_chunks, riding, skip, work)
             self._unread_chunks = []
             self._pending = call
             for s in dec:
@@ -2158,7 +2066,7 @@ class ServingEngine:
                     self._leave(s, call)
             for slot, i in finals:
                 slot.state = _HANDOFF if slot.prefill_only else _DECODE
-                if gen is not None:
+                if not gen.samples_first:
                     continue    # it opens its first block in the next call
                 slot.flying = 1
                 slot.feed = (call.id, 2 + i)
@@ -2166,7 +2074,6 @@ class ServingEngine:
                     self._leave(slot, call)
         if prior is not None:
             self._read(prior, finished)
-        return walk, rode
 
     def _read(self, call, finished):
         """THE one host roundtrip of a decode or mixed call — its blocking
@@ -2179,14 +2086,7 @@ class ServingEngine:
         one speculative window."""
         # THE one host roundtrip per decode window — EOS/retirement decisions are host-side, amortized over `win` tokens
         with self._read_back(call.id, call.out) as (toks, rec):
-            if call.walk is not None:
-                # a walk a FORWARD: the call's counters say how many it took
-                # and not which block's they were, so each forward is booked
-                # as the call's mean one — exact where its blocks take the
-                # same number (flat logits: S + 1 each); its blocks lie B
-                # positions apart
-                self._walk_read += np.asarray(call.walk, np.float64) \
-                    * rec.forwards / self.blocks_per_call
+            self._book(self.gen.due(call.work, rec))
             self._hand_out(call, toks, rec, finished)
 
     def _hand_out(self, call, toks, rec, finished):
@@ -2231,35 +2131,31 @@ class ServingEngine:
                                        attrs={"emitted": j, "call": call.id})
 
     def _decode_walk(self, dec, pos, win):
-        """What the paged decode kernel's walk has to do in a call of `win`
-        tokens for the slots `dec`, and what it is launched with, a layer:
-        (live (slot, block) pairs, grid steps, and for a pool of two kinds
-        the pairs a WINDOW layer's walk visits and the pairs it would visit
-        with no window, both in the window kind's blocks, and the bytes of
-        a state kind's state the call's tokens read + write), summed over
-        the call's tokens."""
-        from deepspeed_tpu.ops.pallas.decode_attention import (
-            paged_decode_walk_steps, window_first_block)
-        if self.generator is not None:
-            # a walk a FORWARD, at the block's last position: ONE forward of
-            # each of the call's blocks here (`_read` books them by the
-            # forwards the call took)
-            B = self.generator.block_length
-            at = pos[[s.idx for s in dec]] + np.arange(B - 1, win, B)[:, None]
-        else:
-            at = pos[[s.idx for s in dec]] + np.arange(win)[:, None]
-        live = at // self.block_size + 1               # [win, slots]
-        walk = (int(live.sum()),
-                sum(paged_decode_walk_steps(n) for n in live.sum(axis=1)),
-                0, 0, len(dec) * win * self._state_token_bytes)
+        """What the decode walks have to do in a call that advances the slots
+        `dec` by `win`, a layer, as `StepRecord` fields, summed over the
+        positions the generator counts a walk at (`walk_at`: a token's, or a
+        forward's): the paged decode kernel's live (slot, block) pairs and
+        grid steps (`paged_decode_walk_counts`; a latent pool's walk visits
+        the same pairs), for a pool of two kinds the pairs a WINDOW layer's
+        walk visits and the pairs it would visit with no window, both in the
+        window kind's blocks, and the bytes of a state kind's state the
+        call's tokens read + write. Booked whichever program walks: they
+        count the call, and the gather oracle serves the same pairs."""
+        from deepspeed_tpu.ops.pallas.decode_attention import \
+            paged_decode_walk_counts
+        at = self.gen.walk_at(pos[[s.idx for s in dec]], win)
+        work = _lands(paged_decode_walk_counts(at, self.block_size),
+                      _DECODE_FIELDS)
+        if self._latent:
+            work["latent_walk_blocks"] = work["decode_live_blocks"]
         if self.window_kind is not None:
             wkind = self.window_kind
-            whole = at // wkind.block + 1
-            walk = walk[:2] + (
-                int((whole - window_first_block(
-                    at, wkind.block, wkind.window)).sum()),
-                int(whole.sum())) + walk[4:]
-        return walk
+            work.update(_lands(paged_decode_walk_counts(
+                at, wkind.block, wkind.window), _DECODE_WINDOW_FIELDS))
+        if self.state_kind is not None:
+            work["ssm_state_bytes"] = \
+                len(dec) * win * self._state_token_bytes
+        return work
 
     @contextlib.contextmanager
     def _read_back(self, call_id, out):
@@ -2273,14 +2169,8 @@ class ServingEngine:
             toks = self._fetch(out)
             if self._pending is None:
                 st.ready()      # else the next call is queued behind it
-        rec = st.read_call(call_id, ph.t0, ph.t1)
-        if self.generator is not None and rec.win:
-            # the forwards the call took, by its own counters: `win` is the
-            # rows a slot ran through the model (what the readers divide by)
-            forwards = int(sum(self._call_counts[self._forward_counters]))
-            B = self.generator.block_length
-            rec = rec._replace(forwards=forwards, block_rows=B,
-                               win=forwards * B)
+        rec = self.gen.close(st.read_call(call_id, ph.t0, ph.t1),
+                             self._call_counts)
         tokens0 = self.tokens_generated
         yield toks, rec
         st.close_call(rec, self.tokens_generated - tokens0)
@@ -2431,19 +2321,9 @@ class ServingEngine:
             out["step_counters"] = dict(zip(
                 self.step_counter_names,
                 (int(v) for v in self.step_counter_totals)))
-        if self.generator is not None:
-            # forwards and rows apart from tokens: `tokens_generated` are
-            # committed AND delivered; the counters above have the forwards
-            c = out["step_counters"]
-            forwards = c["denoise_forwards"] + c["commit_forwards"]
-            out["generator"] = {
-                "kind": "block_diffusion",
-                "block_length": self.generator.block_length,
-                "denoising_steps": self.denoising_steps,
-                "blocks_per_call": self.blocks_per_call,
-                "remasking": self.generator.remasking,
-                "forwards": forwards,
-                "forwards_per_block": forwards / max(1, c["commit_forwards"])}
+        entry = self.gen.stats(out.get("step_counters"))
+        if entry is not None:
+            out["generator"] = entry
         if self.spec_on:
             out["spec_decode"] = {
                 "drafter": self.drafter.name,

@@ -13,15 +13,17 @@ an int32 `[len(step_counters)]` where the model names some, the EMPTY pytree
 carry, an output or a `device_get`: the uncounted programs lower to the text
 they lowered to when they returned bare tokens.
 
-TWO kinds of generator fill the tokens. A model that emits one token a slot a
-forward: a decode or mixed call scans `window` forwards of one row a slot
-and returns `[S, window]` tokens, its first input `pick`ed on the device
-from the call before. A model that generates by DIFFUSION OVER BLOCKS
+This is the DEVICE half of a generator; `inference/generators.py` is the
+host half, one contract the scheduler's loop calls. Two instances fill the
+tokens. One token a slot a forward: a decode or mixed call scans `window`
+forwards of one row a slot and returns `[S, window]` tokens, its first input
+`pick`ed on the device from the call before. DIFFUSION OVER BLOCKS
 (`DecodeModelSpec.generator`, `_block_diffusion_steps`): a call commits
 `blocks_per_call` whole blocks of B tokens a slot through denoise + commit
 forwards of B rows a slot, returns `[S, blocks_per_call * B]` committed
 tokens, counts its forwards (`engine.BLOCK_DIFFUSION_COUNTERS`, after the
-model's own) and takes NO token from the call before it.
+model's own) and takes NO token from the call before it. Both ride a group
+of chunks a forward through `_ride_group`.
 """
 
 import functools
@@ -115,6 +117,23 @@ def _sampler(cfg):
             return draw(logits, rng)
 
     return sample
+
+
+def _ride_group(mixed_paged, params, riding, i, G, tok, pos, pool, tables):
+    """Forward `i` of a mixed call with its chunk group riding: `riding` =
+    (`chunks` [W, G, C], `starts` / `lasts` [W, G], `chunk_tables`
+    [W, G, nb], `n` the chunks that are real), of which group `i` goes
+    through the model with the slots' rows `tok` as one tensor
+    (`DecodeModelSpec.mixed_paged_fn`). Returns its (logits, pool, counts)."""
+    chunks, starts, lasts, chunk_tables, n = riding
+
+    def at(a):
+        return jax.lax.dynamic_index_in_dim(a, i, 0, keepdims=False)
+    count = () if G == 1 else (jnp.minimum(n - i * G, G),)
+    return mixed_paged(
+        params, at(chunks), at(starts), at(lasts),
+        jax.tree_util.tree_map(at, chunk_tables), tok, pos, pool, tables,
+        *count)
 
 
 def step_counter_names(spec):
@@ -238,14 +257,9 @@ def build_resident(spec, cfg, transform, *, window, max_slots, chunk,
         tok = pick(tok)
 
         def ride(i, tok, pos, pool, rng):
-            def at(a):
-                return jax.lax.dynamic_index_in_dim(a, i, 0,
-                                                    keepdims=False)
-            count = () if G == 1 else (jnp.minimum(n - i * G, G),)
-            logits, pool, counts = mixed_paged(
-                params, at(chunks), at(starts), at(lasts),
-                jax.tree_util.tree_map(at, chunk_tables), tok, pos, pool,
-                tables, *count)
+            logits, pool, counts = _ride_group(
+                mixed_paged, params, (chunks, starts, lasts, chunk_tables, n),
+                i, G, tok, pos, pool, tables)
             sampled = sample(logits, rng)
             # (one chunk a token: the scalar, as it has always lowered)
             head = sampled[0] if G == 1 else sampled[:G]
@@ -413,16 +427,8 @@ def _block_diffusion_steps(generator, denoise_paged, mixed_paged, no_counts,
         def forward(state, ride):
             x, masked, pos, pool, b, s, f, out, acc = state
             if ride:
-                chunks, starts, lasts, chunk_tables, n = riding
-
-                def at(a):
-                    return jax.lax.dynamic_index_in_dim(a, f, 0,
-                                                        keepdims=False)
-                count = () if G == 1 else (jnp.minimum(n - f * G, G),)
-                logits, pool, counts = mixed_paged(
-                    params, at(chunks), at(starts), at(lasts),
-                    jax.tree_util.tree_map(at, chunk_tables), x, pos, pool,
-                    tables, *count)
+                logits, pool, counts = _ride_group(
+                    mixed_paged, params, riding, f, G, x, pos, pool, tables)
                 logits = logits[G:]     # the chunks' rows sample nothing
             else:
                 logits, pool, counts = denoise_paged(params, x, pos, pool,

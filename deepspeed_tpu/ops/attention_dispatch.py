@@ -160,6 +160,13 @@ class AttentionProgram:
       * contiguous decode: `(q, cache_k, cache_v, pos, *, sm_scale)` with q
         [B, H, hd] and the head-major cache -> [B, H, hd].
 
+    `work` counts, on the host, what ONE call of the program does a layer
+    that a dense attend over the whole table would not: for a chunk walk
+    `(start, chunk, block, table_blocks, window=None)` -> {`live_blocks`,
+    `table_blocks`, `kept_pairs`, ...} by the kernel's own names (the
+    scheduler alone knows which fields of its step record they land in). None
+    where the program walks nothing of the kind: the gather oracles.
+
     `when` is the human-readable engage condition for `dispatch_table()`
     and docs/kernels.md."""
     name: str
@@ -168,6 +175,7 @@ class AttentionProgram:
     matches: Callable[[AttnSite], bool]
     when: str = ""
     runner: Optional[Callable] = None
+    work: Optional[Callable] = None
 
 
 _REGISTRY: Dict[str, AttentionProgram] = {}
@@ -522,6 +530,22 @@ def _run_mla_prefill(q, pool_l, block_tables, start, *, sm_scale=None,
                                  sm_scale)
 
 
+# A chunk walk's `work` (`AttentionProgram`), its module imported where it is
+# asked for, as a runner's is.
+
+
+def _paged_prefill_work(start, chunk, block, table_blocks, window=None):
+    from deepspeed_tpu.ops.pallas.prefill_attention import \
+        paged_prefill_walk_counts
+    return paged_prefill_walk_counts(start, chunk, block, table_blocks,
+                                     window)
+
+
+def _mla_prefill_work(start, chunk, block, table_blocks, window=None):
+    from deepspeed_tpu.ops.pallas.mla_attention import mla_prefill_walk_counts
+    return mla_prefill_walk_counts(start, chunk, block, table_blocks, window)
+
+
 # A latent pool (MLA): the absorbed walks of `ops/pallas/mla_attention.py`.
 # They outrank every program above and match latent sites only, so a latent
 # site never selects a K/V program and no other site selects these.
@@ -539,7 +563,7 @@ register_program(AttentionProgram(
     when="latent pool in the in-place form, C % 128 == 0, block % 128 == "
          "0: absorbed flash walk over the blocks under the chunk's frontier "
          "(dstpu_mla_prefill)",
-    runner=_run_mla_prefill))
+    runner=_run_mla_prefill, work=_mla_prefill_work))
 
 register_program(AttentionProgram(
     name="mla_gather", phases=("paged_decode", "prefill_chunk"), priority=80,
@@ -587,7 +611,7 @@ register_program(AttentionProgram(
          "and, with a static window, from the block the window begins in; "
          "a sink logit, values of their own width and keys in two leaves "
          "as the decode walk takes them (dstpu_paged_prefill)",
-    runner=_run_paged_prefill))
+    runner=_run_paged_prefill, work=_paged_prefill_work))
 
 register_program(AttentionProgram(
     name="paged_gather_quant",

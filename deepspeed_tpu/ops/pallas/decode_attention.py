@@ -335,6 +335,24 @@ def paged_decode_walk_steps(live_blocks):
     return max(int(live_blocks), 1)
 
 
+def paged_decode_walk_counts(at, block_m, window=None):
+    """Host twin of what the walk does in a CALL, a layer: `at` [tokens,
+    slots] (numpy) the positions the call's tokens attend from, its live
+    slots only. `live_blocks`: the (slot, logical block) pairs it visits,
+    `pos // block_m + 1` a token a slot, and `grid_steps`: the block-axis
+    steps it is launched with, summed over the call's tokens; with a
+    `window`, the pairs from `window_first_block` on, of `table_blocks`: the
+    pairs the same walk would visit with no window."""
+    whole = at // block_m + 1
+    if window:
+        return {"live_blocks": int((whole - window_first_block(
+                    at, block_m, window)).sum()),
+                "table_blocks": int(whole.sum())}
+    return {"live_blocks": int(whole.sum()),
+            "grid_steps": sum(paged_decode_walk_steps(n)
+                              for n in whole.sum(axis=1))}
+
+
 def _heads_per_step(Hkv, head_tile_bytes, share=1):
     """KV heads a grid step carries: the most (a divisor of Hkv) whose K and
     V tiles, double-buffered, fit `_WALK_TILE_BYTES`. All of them at the

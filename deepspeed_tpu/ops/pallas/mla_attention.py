@@ -33,9 +33,9 @@ from jax.experimental.pallas import tpu as pltpu
 
 from deepspeed_tpu.ops.pallas.decode_attention import (NEG_INF, _LANES,
                                                        _paged_walk)
-from deepspeed_tpu.ops.pallas.prefill_attention import (_KV_TILES, _Q_TILES,
-                                                        _VMEM_LIMIT_BYTES,
-                                                        _prefill_kernel)
+from deepspeed_tpu.ops.pallas.prefill_attention import (
+    _KV_TILES, _Q_TILES, _VMEM_LIMIT_BYTES, _prefill_kernel,
+    paged_prefill_walk_counts)
 from deepspeed_tpu.platform.device import pallas_interpret
 
 # query heads a step of the chunk kernel carries (a divisor of the heads is
@@ -137,6 +137,14 @@ def mla_prefill_attention(q, pool, block_tables, start, rank, sm_scale,
         name="dstpu_mla_prefill",
     )(start, block_tables.astype(jnp.int32), q.reshape(B, C, H * width),
       pool)
+
+
+def mla_prefill_walk_counts(start, chunk, block, table_blocks, window=None):
+    """The chunk walk's counts over a latent pool (`paged_prefill_walk_counts`)
+    and `latent_positions`: the cached positions the chunk attends, a layer,
+    `start + chunk` (a latent kind has no window)."""
+    return dict(paged_prefill_walk_counts(start, chunk, block, table_blocks),
+                latent_positions=int(start) + int(chunk))
 
 
 def mla_attend_gathered(q, ctx, q_pos, rank, sm_scale):

@@ -218,6 +218,26 @@ def paged_prefill_live_blocks(start, chunk, block, table_blocks, window=None):
         - int(window_first_block(int(start), int(block), window))
 
 
+def paged_prefill_walk_counts(start, chunk, block, table_blocks, window=None):
+    """Host twin of what the walk does for ONE chunk, a layer (the registered
+    program's `work`, `ops/attention_dispatch.py`): `live_blocks`
+    (`paged_prefill_live_blocks`), of `table_blocks` — the blocks in its
+    table, what the gather path attends; with a `window`, what the same walk
+    would visit with none — and `kept_pairs`, the (query, position) pairs
+    the causal mask (and the window) keeps: what the chunk walks' roofline
+    counts as work."""
+    start, C = int(start), int(chunk)
+    # rows that see fewer than `window` positions (every row, with none): all
+    # they have
+    short = C if not window else min(max(window - 1 - start, 0), C)
+    return {"live_blocks": paged_prefill_live_blocks(
+                start, C, block, table_blocks, window),
+            "table_blocks": int(table_blocks) if not window
+            else paged_prefill_live_blocks(start, C, block, table_blocks),
+            "kept_pairs": short * start + short * (short + 1) // 2
+            + (C - short) * (window or 0)}
+
+
 def paged_prefill_attention(q, k_pool, v_pool, block_tables, start,
                             sm_scale=None, interpret=None, window=None,
                             kr_pool=None, sink=None, block_length=1):

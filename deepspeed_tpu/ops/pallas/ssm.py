@@ -100,6 +100,13 @@ def _mode(interpret, state=None):
 # ----------------------------------------------------------------------
 
 
+def state_token_bytes(state) -> int:
+    """What ONE decode token of one slot moves of a state kind's state
+    proper `[layers, rows, ...]`, all layers: its row of each, read and
+    written."""
+    return 2 * int(state.nbytes // state.shape[1])
+
+
 def ssm_update_reference(state, rows, a, dtx, B, C):
     """The oracle and the off-TPU path, in `ssm_update`'s terms."""
     H = state.shape[1]

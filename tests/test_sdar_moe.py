@@ -473,6 +473,12 @@ def _lowered_step_programs(kind):
                                 use_rotary=True, use_swiglu=True,
                                 use_rmsnorm=True, dtype=jnp.float32)
         spec = gpt_mod.make_gpt_decode_model(cfg, name="tiny")
+    elif kind == "sdar_moe":
+        cfg = sdar_moe.sdar_moe_config(PUBLISHED, 128, B, dtype=jnp.float32)
+        params = sdar_moe.sdar_moe_init_fn(cfg, dtype=jnp.float32)(
+            jax.random.PRNGKey(0))
+        spec = sdar_moe.make_sdar_moe_decode_model(
+            cfg, sdar_moe.generator(B, MASK, 2), params=params)
     else:
         cfg = moe_gpt.MoEGPTConfig(
             n_layer=2, n_head=4, n_kv_head=4, d_model=64, d_ff=32,
@@ -486,11 +492,16 @@ def _lowered_step_programs(kind):
         "dtype": "float32", "kv_cache_dtype": "float32", "greedy": True,
         "kv_block_size": 16, "max_out_tokens": 128})
     out = {}
-    for spec_decode in ({}, {"spec_decode": {"drafter": "ngram",
-                                             "draft_k": 3}}):
+    # (a block generator refuses spec decode: its own decode and mixed
+    # programs, two blocks a call, and the chunk program)
+    settings = ({"blocks_per_call": 2},) if kind == "sdar_moe" else (
+        {"decode_steps_per_sync": 3},
+        {"decode_steps_per_sync": 1,
+         "spec_decode": {"drafter": "ngram", "draft_k": 3}})
+    for more in settings:
+        spec_decode = "spec_decode" in more
         srv = engine.serving(max_slots=4, max_context=128, prefill_chunk=16,
-                             decode_steps_per_sync=1 if spec_decode else 3,
-                             prefill_chunks_per_step=4, **spec_decode)
+                             prefill_chunks_per_step=4, **more)
         for name, fn, args in srv.programs.examples(
                 engine.params, srv.pool, srv._tables_arg(srv.tables),
                 srv._rng):
@@ -501,11 +512,13 @@ def _lowered_step_programs(kind):
     return out
 
 
-@pytest.mark.parametrize("kind", ["gpt", "moe_gpt"])
+@pytest.mark.parametrize("kind", ["gpt", "moe_gpt", "sdar_moe"])
 def test_other_families_step_programs_lower_to_the_parents_text(kind):
     """`decode_step`, `prefill_step`, `mixed_step` and the spec-decode
     `verify_step` (whose chunk still has no runner: its rows are causal
-    inside the chunk) of the dense and the routed GPT family at a tiny size:
+    inside the chunk) of the dense and the routed GPT family at a tiny size,
+    and since PR 58 the block generator's own three (`sdar_moe`: its
+    `decode_step` and `mixed_step` are `_block_diffusion_steps`'):
     the hashes of their lowered text are those of the commit this PR started
     from (`tests/step_program_hashes.json`, written there by
     `_lowered_step_programs` on that commit). A PR that means to change
