@@ -79,12 +79,17 @@ def sample_logits(logits, rng, greedy=True, temperature=1.0, top_k=0,
 
 
 # what a block-diffusion call counts beside the model's own counters, in this
-# order (int32[4], summed over a call's forwards and read back with its
-# tokens): forwards whose rows the unmask rule read, forwards that wrote a
-# block's K/V for good, (slot, row) pairs the rule unmasked, (slot, block)
-# pairs committed for live slots
-BLOCK_DIFFUSION_COUNTERS = ("denoise_forwards", "commit_forwards",
-                            "rows_unmasked", "blocks_committed")
+# order (int32[5], summed over a call's forwards and read back with its
+# tokens): FUSED forwards (a block's commit and the next block's first
+# denoise step as one pass through the weights), then the four that count
+# ROLES and not passes — forwards whose rows the unmask rule read, forwards
+# that wrote a block's K/V for good (a fused forward is one of each: passes =
+# denoise + commit - fused), (slot, row) pairs the rule unmasked, (slot,
+# block) pairs committed for live slots. The four stay LAST, in this order:
+# the benchmark's check reads them as `counts[-4:]`
+BLOCK_DIFFUSION_COUNTERS = ("fused_forwards", "denoise_forwards",
+                            "commit_forwards", "rows_unmasked",
+                            "blocks_committed")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -244,13 +249,22 @@ class DecodeModelSpec:
     #     rows' k/v at pos..pos+B-1 (a later forward of the same block writes
     #     over them) and attends [0, pos + B), every row's logits back, slot
     #     after slot.
+    #     With tokens[S,2B] it is a FUSED forward: block b's clean tokens
+    #     (its commit) before block b+1's rows (its first denoise step), one
+    #     pass through every weight; both blocks' k/v are written, each block
+    #     attends to its own end, and the logits are block b+1's.
     #     `mixed_paged_fn` then takes `token` [S, B] and gives logits
-    #     [G + S*B, V]. The scheduler builds its decode and mixed programs
-    #     from these (`step_programs.py`); a decode call commits whole blocks
-    #     and takes no token from the call before it. None: one token a slot
-    #     a forward.
+    #     [G + S*B, V]. With `hidden=True` both give the sampling rows as
+    #     the layers leave them, [S*B, D], in the logits' place, and
+    #   head_fn(params, rows[N,D]) -> logits[N,V]
+    #     makes the logits of them: the block loop runs it, and the rule,
+    #     in the forwards that sample. The scheduler builds its decode and
+    #     mixed programs from these (`step_programs.py`); a decode call
+    #     commits whole blocks and takes no token from the call before it.
+    #     None: one token a slot a forward.
     generator: Optional[BlockDiffusion] = None
     denoise_paged_fn: Optional[Callable] = None
+    head_fn: Optional[Callable] = None
     # cache-identity fingerprint for the prefix cache's hash chain
     # (inference/prefix_cache.py): every arch field that changes the KV
     # VALUES written for a given token stream must be folded in, so two

@@ -84,13 +84,21 @@ class BlockDiffusionCalls:
     `DecodeModelSpec.generator`): a call commits `blocks_per_call` whole
     blocks of B tokens a slot, all slots block-synchronous, so positions
     advance by `window` = blocks_per_call * B a call and the host still books
-    them at dispatch; its chunks ride `ride_window` = blocks_per_call *
-    (steps + 1) forwards of B rows a slot. Call k+1 takes NOTHING from call
-    k, a prompt's last chunk samples no first token (its `L mod B` tail opens
-    the first generated block as clean tokens), and the forwards a call took
-    are known at its read-back only: its record and its walks are closed and
-    booked there. What it cannot have yet is refused at build time, by name,
-    as the pools of two kinds refuse theirs."""
+    them at dispatch. A block takes up to `steps` denoise forwards and a
+    commit forward, of B rows a slot each — ROLES, which the device counts
+    (`denoise_forwards`, `commit_forwards`); a block that is not the call's
+    last commits in the pass that is the next block's first denoise step, a
+    FUSED forward of 2B rows a slot (`fused_forwards`;
+    `step_programs.py::_block_diffusion_steps`), so a call's PASSES through
+    the weights are denoise + commit - fused, at most blocks_per_call *
+    (steps + 1) - (blocks_per_call - 1). Its chunks ride the passes of B
+    rows: `ride_window` = that less the blocks_per_call - 1 fused ones. Call
+    k+1 takes NOTHING from call k, a prompt's last chunk samples no first
+    token (its `L mod B` tail opens the first generated block as clean
+    tokens), and the forwards a call took are known at its read-back only:
+    its record and its walks are closed and booked there. What it cannot
+    have yet is refused at build time, by name, as the pools of two kinds
+    refuse theirs."""
 
     samples_first = False
     no_transplant = (
@@ -144,15 +152,16 @@ class BlockDiffusionCalls:
         self.blocks_per_call = max(1, int(scfg.blocks_per_call))
         self.denoising_steps = gen.steps
         self.window = self.blocks_per_call * B
-        self.ride_window = self.blocks_per_call * (self.denoising_steps + 1)
         # (a block runs B rows through up to `steps` denoise forwards and its
         # commit; the read-back has the forwards it took: `close`)
         self.row_forwards = self.denoising_steps + 1
+        self.ride_window = self.blocks_per_call * self.row_forwards \
+            - 2 * (self.blocks_per_call - 1)
         self.program_args = dict(blocks_per_call=self.blocks_per_call,
                                  denoising_steps=self.denoising_steps)
         names = step_counter_names(spec)
         self._forwards = [names.index(name) for name in (
-            "denoise_forwards", "commit_forwards")]
+            "denoise_forwards", "commit_forwards", "fused_forwards")]
 
     def feed(self, dec, prior, no_prev):
         """(`tok` [S, B] — a slot's first block of the call: mask ids where
@@ -183,36 +192,48 @@ class BlockDiffusionCalls:
         return pos + np.arange(self.block - 1, win, self.block)[:, None]
 
     def due(self, work, rec=None):
-        """At the read-back, by the forwards the call took: its counters say
-        how many and not which block's they were, so each forward is booked
-        as the call's mean one — exact where its blocks take the same number
-        (flat logits: S + 1 each); its blocks lie B positions apart."""
+        """At the read-back, by the walks the call took — one a denoise or
+        commit forward, two a fused one: `rec.win` rows a slot, B a walk. Its
+        counters say how many and not which block's they were, so each is
+        booked as the call's mean one — exact where its blocks take the same
+        number (flat logits: S + 1 each); its blocks lie B positions apart."""
         if rec is None:
             return {}
-        return {name: n * rec.forwards / self.blocks_per_call
+        walks = rec.win / self.block
+        return {name: n * walks / self.blocks_per_call
                 for name, n in work.items()}
 
     def close(self, rec, counts):
-        """The forwards the call took, by its own counters: `win` is the
-        rows a slot ran through the model (what the readers divide by)."""
+        """The forwards the call took, by its own counters: `forwards` its
+        passes through the weights, `win` the rows a slot ran through the
+        model (what the readers divide by) — B a denoise or commit forward,
+        2B a fused one."""
         if not rec.win:
             return rec
-        forwards = int(sum(counts[self._forwards]))
-        return rec._replace(forwards=forwards, block_rows=self.block,
-                            win=forwards * self.block)
+        denoise, commit, fused = (int(n) for n in counts[self._forwards])
+        return rec._replace(forwards=denoise + commit - fused,
+                            block_rows=self.block,
+                            win=(denoise + commit) * self.block)
 
     def stats(self, counters):
         # forwards and rows apart from tokens: `tokens_generated` are
-        # committed AND delivered; the step counters have the forwards
-        forwards = counters["denoise_forwards"] + counters["commit_forwards"]
+        # committed AND delivered; the step counters have the forwards, by
+        # ROLE — a fused forward is one pass that counts as two. `forwards`
+        # (as `CallRecord.forwards`) and `passes_per_block` are passes
+        # through the weights; `forwards_per_block` stays by role, what the
+        # rule took of a block whatever the loop fused (S + 1 on flat logits)
+        commits = max(1, counters["commit_forwards"])
+        roles = counters["denoise_forwards"] + counters["commit_forwards"]
+        forwards = roles - counters["fused_forwards"]
         return {"kind": "block_diffusion",
                 "block_length": self.block,
                 "denoising_steps": self.denoising_steps,
                 "blocks_per_call": self.blocks_per_call,
                 "remasking": self.spec.remasking,
                 "forwards": forwards,
-                "forwards_per_block":
-                    forwards / max(1, counters["commit_forwards"])}
+                "forwards_per_block": roles / commits,
+                "passes_per_block": forwards / commits,
+                "fused_forward_share": counters["fused_forwards"] / commits}
 
 
 def build(spec, scfg, config, *, streamed, window, chunk, block_size,

@@ -20,10 +20,12 @@ forwards of one row a slot and returns `[S, window]` tokens, its first input
 `pick`ed on the device from the call before. DIFFUSION OVER BLOCKS
 (`DecodeModelSpec.generator`, `_block_diffusion_steps`): a call commits
 `blocks_per_call` whole blocks of B tokens a slot through denoise + commit
-forwards of B rows a slot, returns `[S, blocks_per_call * B]` committed
-tokens, counts its forwards (`engine.BLOCK_DIFFUSION_COUNTERS`, after the
-model's own) and takes NO token from the call before it. Both ride a group
-of chunks a forward through `_ride_group`.
+forwards of B rows a slot (a block that is not its last commits in the pass
+that opens the next: a fused forward of 2B rows), returns `[S,
+blocks_per_call * B]` committed tokens, counts its forwards
+(`engine.BLOCK_DIFFUSION_COUNTERS`, after the model's own) and takes NO token
+from the call before it. Both ride a group of chunks a forward through
+`_ride_group`.
 """
 
 import functools
@@ -119,12 +121,14 @@ def _sampler(cfg):
     return sample
 
 
-def _ride_group(mixed_paged, params, riding, i, G, tok, pos, pool, tables):
+def _ride_group(mixed_paged, params, riding, i, G, tok, pos, pool, tables,
+                **more):
     """Forward `i` of a mixed call with its chunk group riding: `riding` =
     (`chunks` [W, G, C], `starts` / `lasts` [W, G], `chunk_tables`
     [W, G, nb], `n` the chunks that are real), of which group `i` goes
     through the model with the slots' rows `tok` as one tensor
-    (`DecodeModelSpec.mixed_paged_fn`). Returns its (logits, pool, counts)."""
+    (`DecodeModelSpec.mixed_paged_fn`, which takes `more` as keywords).
+    Returns its (logits, pool, counts)."""
     chunks, starts, lasts, chunk_tables, n = riding
 
     def at(a):
@@ -133,7 +137,7 @@ def _ride_group(mixed_paged, params, riding, i, G, tok, pos, pool, tables):
     return mixed_paged(
         params, at(chunks), at(starts), at(lasts),
         jax.tree_util.tree_map(at, chunk_tables), tok, pos, pool, tables,
-        *count)
+        *count, **more)
 
 
 def step_counter_names(spec):
@@ -172,8 +176,8 @@ def build_resident(spec, cfg, transform, *, window, max_slots, chunk,
             return fn if counters else lambda *args: (*fn(*args), ())
         extra = jnp.zeros((len(BLOCK_DIFFUSION_COUNTERS),), jnp.int32)
 
-        def counted(*args):
-            logits, pool, *counts = fn(*args)
+        def counted(*args, **more):
+            logits, pool, *counts = fn(*args, **more)
             return logits, pool, jnp.concatenate(
                 [*counts[:bool(model_counters)], extra])
         return counted
@@ -300,7 +304,8 @@ def build_resident(spec, cfg, transform, *, window, max_slots, chunk,
         # a call commits blocks and `pick`s nothing: the two programs above
         # are replaced, the chunk program and the rest stay
         make_decode_step, mixed_step = _block_diffusion_steps(
-            generator, paged(spec.denoise_paged_fn), mixed_paged, no_counts,
+            generator, paged(spec.denoise_paged_fn), mixed_paged,
+            transform(spec.head_fn), no_counts,
             blocks=blocks_per_call, steps=denoising_steps, group=G)
 
     # the pool is donated: the update is in-place in HBM. The compile
@@ -385,30 +390,39 @@ def build_resident(spec, cfg, transform, *, window, max_slots, chunk,
                         make_w1=make_w1, example_args=example_args, group=G)
 
 
-def _block_diffusion_steps(generator, denoise_paged, mixed_paged, no_counts,
-                           *, blocks, steps, group):
+def _block_diffusion_steps(generator, denoise_paged, mixed_paged, head,
+                           no_counts, *, blocks, steps, group):
     """The decode and mixed programs of a model that generates by diffusion
     over blocks (`engine.BlockDiffusion`): `(make_decode_step, mixed_step)`
     in `build_resident`'s places.
 
     A call runs `blocks` blocks a slot, all slots block-synchronous. ONE loop
-    of forwards on the carried pool, each of B rows a slot
+    of forwards on the carried pool, each ONE pass through every weight
     (`denoise_paged`; with chunks riding, `mixed_paged`: up to G chunks and
-    the S x B slot rows as one tensor). The carried state says what a forward
-    is: while a slot that runs still has a masked row it is a DENOISE forward
-    (the rule unmasks rows from its logits, `BlockDiffusion.unmask`; what it
-    wrote into the pool is written over by the next forward of the block);
-    else it is the block's COMMIT forward — its K/V stay, its tokens go to
-    the output, and every running slot opens its next block as B mask tokens
-    B positions on. A block's step schedule is n_s of `steps` steps; the
-    loop has no bound of its own because a block of B rows is clean after at
-    most `steps` of them.
+    the slots' rows as one tensor). The carried state says what a forward is,
+    and how wide. While a slot that runs still has a masked row it is a
+    DENOISE forward of B rows a slot: `head` makes the rows' logits and the
+    rule unmasks rows from them (`BlockDiffusion.unmask`; what the forward
+    wrote into the pool is written over by the next forward of the block).
+    A clean block is COMMITTED — its K/V stay, its tokens go to the output,
+    and every running slot opens its next block as B mask tokens B positions
+    on. A block that is not the call's last commits in a FUSED forward of 2B
+    rows a slot: its clean tokens at `pos .. pos + B - 1` and the next
+    block's mask rows behind them, each block attending to its own end, so
+    the pass is that block's commit AND the next one's first denoise step
+    and is counted as one of each (`denoise_forwards + commit_forwards`
+    counts roles; passes are that less `fused_forwards`). The call's last
+    block commits in a forward of its own, which samples nothing and runs no
+    head. A block's step schedule is n_s of `steps` steps; the loop has no
+    bound of its own because a block of B rows is clean after at most `steps`
+    of them.
 
     `tok` [S, B]: a slot's first block as the host has it — mask ids where a
     slot goes on generating, a prompt's last `L mod B` tokens before mask ids
     where it begins, and NO mask id in the row of a slot that is not in the
     call (it runs against the trash block and keeps no forward waiting).
-    Chunks ride forward 0, 1, ... in order, a group each; the LAST block's
+    Chunks ride the forwards of B rows in order, a group each (a fused
+    forward carries none: its chunks wait one forward); the LAST block's
     commit waits (as denoise forwards that change nothing) until every group
     has ridden, so a call always runs the chunks it was given. Returns
     ((tokens [S, blocks * B]), counts), pool; the counters after the model's
@@ -417,51 +431,115 @@ def _block_diffusion_steps(generator, denoise_paged, mixed_paged, no_counts,
     mask_id = generator.mask_token_id
     n_s = jnp.asarray(generator.transfers(steps) + [0], jnp.int32)
     extra = len(BLOCK_DIFFUSION_COUNTERS)
+    RIDE, PLAIN, FUSED = range(3)      # the kinds of forward: `due`
 
     def run(params, tok, pos, pool, tables, riding=None):
         S = tok.shape[0]
         running = jnp.any(tok == mask_id, axis=1)              # [S]
         live = jnp.sum(running, dtype=jnp.int32)
         groups = 0 if riding is None else (riding[4] + G - 1) // G
+        # a block as every running slot opens it
+        fresh = jnp.where(running[:, None], mask_id, tok)
+        opened = jnp.broadcast_to(running[:, None], tok.shape)
+
+        def sampled(rows, x, masked, n):
+            """The head and the rule over a forward's rows — in a forward
+            that has a masked row: a commit forward of its own, and one
+            that waits for chunks, sample nothing."""
+            return jax.lax.cond(
+                jnp.any(masked),
+                lambda: generator.unmask(head(params, rows), x, masked, n),
+                lambda: (x, masked, jnp.zeros((S,), jnp.int32)))
+
+        def booked(acc, counts, moved, *roles):
+            """`acc` with a forward's counters: the model's, and the block
+            loop's for its `roles` (fused, denoise, commit: 0 or 1 each)."""
+            fused, denoise, commit = (jnp.asarray(r, jnp.int32)
+                                      for r in roles)
+            return (acc + counts).at[-extra:].add(jnp.stack([
+                fused, denoise, commit,
+                jnp.sum(jnp.where(running, moved, 0)) * denoise,
+                live * commit]))
 
         def forward(state, ride):
+            """B rows a slot: a denoise step, or the last block's commit."""
             x, masked, pos, pool, b, s, f, out, acc = state
             if ride:
-                logits, pool, counts = _ride_group(
-                    mixed_paged, params, riding, f, G, x, pos, pool, tables)
-                logits = logits[G:]     # the chunks' rows sample nothing
+                rows, pool, counts = _ride_group(
+                    mixed_paged, params, riding, f, G, x, pos, pool, tables,
+                    hidden=True)
+                f = f + 1
             else:
-                logits, pool, counts = denoise_paged(params, x, pos, pool,
-                                                     tables)
-            commit = ~jnp.any(masked) & (
-                (b < blocks - 1) | (f + 1 >= groups))
-            x1, masked1, moved = generator.unmask(
-                logits, x, masked, n_s[jnp.minimum(s, steps)])
+                rows, pool, counts = denoise_paged(params, x, pos, pool,
+                                                   tables, hidden=True)
+            commit = ~jnp.any(masked) & (f >= groups)
+            x1, masked1, moved = sampled(
+                rows, x, masked, n_s[jnp.minimum(s, steps)])
             with jax.named_scope("denoise/commit"):
                 out = jnp.where(commit, jax.lax.dynamic_update_slice(
                     out, x, (0, b * B)), out)
-                x = jnp.where(commit, jnp.where(running[:, None], mask_id,
-                                                tok), x1)
-                masked = jnp.where(commit, jnp.broadcast_to(
-                    running[:, None], masked.shape), masked1)
+                x = jnp.where(commit, fresh, x1)
+                masked = jnp.where(commit, opened, masked1)
                 pos = jnp.where(commit & running, pos + B, pos)
-                acc = acc + counts
-                acc = acc.at[-extra:].add(jnp.stack([
-                    (~commit).astype(jnp.int32), commit.astype(jnp.int32),
-                    jnp.sum(jnp.where(running, moved, 0)) * ~commit,
-                    live * commit]))
+                acc = booked(acc, counts, moved, 0, ~commit, commit)
             return (x, masked, pos, pool, b + commit,
-                    jnp.where(commit, 0, s + 1), f + 1, out, acc)
+                    jnp.where(commit, 0, s + 1), f, out, acc)
+
+        def fused(state):
+            """2B rows a slot: block b's commit and block b + 1's first
+            denoise step."""
+            x, _, pos, pool, b, _, f, out, acc = state
+            rows, pool, counts = denoise_paged(
+                params, jnp.concatenate([x, fresh], axis=1), pos, pool,
+                tables, hidden=True)
+            x1, masked1, moved = sampled(rows, fresh, opened, n_s[0])
+            with jax.named_scope("denoise/commit"):
+                out = jax.lax.dynamic_update_slice(out, x, (0, b * B))
+                pos = jnp.where(running, pos + B, pos)
+                acc = booked(acc, counts, moved, 1, 1, 1)
+            return (x1, masked1, pos, pool, b + 1, jnp.ones_like(b), f, out,
+                    acc)
+
+        def due(state):
+            """Which forward the state asks for next."""
+            _, masked, _, _, b, _, f, _, _ = state
+            return jnp.where(
+                ~jnp.any(masked) & (b < blocks - 1), FUSED,
+                jnp.where(f < groups, RIDE, PLAIN))
+
+        def run_while(kind, body, state):
+            return jax.lax.while_loop(
+                lambda st: (st[4] < blocks) & (due(st) == kind), body, state)
+
+        # ONE traced body a kind of forward, whatever the call's blocks and
+        # chunks: each a loop that runs while its kind is due, one behind the
+        # other inside the call's loop. (Not a `lax.switch` a forward: a
+        # conditional does not hand the carried pool through in place. Its
+        # first branch copied a whole pool leaf into and out of every layer's
+        # write: compiled for a described v5e at the cell's size, PR 59;
+        # `tests/test_steptrace.py::test_block_diffusion_programs_hold_
+        # nothing_of_the_pools_size` holds it.)
+        def step(state):
+            if riding is not None:
+                state = run_while(RIDE, functools.partial(forward, ride=True),
+                                  state)
+            state = run_while(PLAIN, functools.partial(forward, ride=False),
+                              state)
+            return run_while(FUSED, fused, state) if blocks > 1 else state
 
         zero = jnp.zeros((), jnp.int32)
         state = (tok, tok == mask_id, pos, pool, zero, zero, zero,
                  jnp.zeros((S, blocks * B), jnp.int32), no_counts())
-        if riding is not None:
-            state = jax.lax.while_loop(
-                lambda st: (st[6] < groups) & (st[4] < blocks),
-                lambda st: forward(st, True), state)
-        state = jax.lax.while_loop(
-            lambda st: st[4] < blocks, lambda st: forward(st, False), state)
+        if blocks == 1:
+            # one block has no boundary to fuse: its riding forwards, then
+            # its others, once — and no loop around them (one that XLA can
+            # see runs once ABORTS the TPU compiler, jax 0.9 / libtpu 0.0.34:
+            # `hlo_instruction.cc: operands_[i] != nullptr`;
+            # `tests/test_steptrace.py` compiles both for a described v5e)
+            state = step(state)
+        else:
+            state = jax.lax.while_loop(lambda st: st[4] < blocks, step,
+                                       state)
         _, _, _, pool, _, _, _, out, acc = state
         return (out, acc), pool
 
@@ -474,7 +552,8 @@ def _block_diffusion_steps(generator, denoise_paged, mixed_paged, no_counts,
     def mixed_step(params, chunks, starts, lasts, chunk_tables, n, tok, pos,
                    pool, tables, rng):
         """`chunks` [W, G, C] (+ `starts`, `lasts`, `chunk_tables`): a group
-        a forward, of which the first `n` CHUNKS (traced) are real."""
+        a forward of B rows, of which the first `n` CHUNKS (traced) are
+        real."""
         del rng
         return run(params, tok, pos, pool, tables,
                    (chunks, starts, lasts, chunk_tables, n))

@@ -1521,18 +1521,32 @@ def over_chunk_group(count, C, out, carry, run):
     return jax.lax.fori_loop(0, count, body, (out, carry))
 
 
-def decode_rows(block_tables, positions, rows=1):
+def decode_rows(block_tables, positions, rows=1, group=0):
     """(tables [S, nb], positions [S]) of a paged call's decode rows: every
     row of a decode call, the last S of a mixed call. `rows` > 1 (a block
     of a diffusion generator, `GPTConfig.block_length`): a slot has that
-    many rows, and its position is its LAST row's, the frontier they share."""
+    many rows a GROUP, and a group's position is its LAST row's, the
+    frontier they share — group `group` of the slot's (a fused forward has
+    two: `block_groups`)."""
     if isinstance(block_tables, MixedTables):
         slots = block_tables.decode.shape[0]
         if rows > 1:
             return block_tables.decode, \
                 positions[0, -slots * rows:].reshape(slots, rows)[:, -1]
         return block_tables.decode, positions[0, -slots:]
-    return block_tables, positions[:, -1 if rows > 1 else 0]
+    return block_tables, positions[:, (group + 1) * rows - 1 if rows > 1
+                                   else 0]
+
+
+def block_groups(cfg, positions, phase):
+    """The groups of `cfg.block_length` rows a slot has in a paged call,
+    known where it is traced: 1 — but in a diffusion generator's block
+    forward (`phase` "denoise", positions [S, C]) that is FUSED, 2: block b's
+    clean tokens (its commit) before block b + 1's rows (its first denoise
+    step), one pass through every weight, each group walked at its own
+    frontier."""
+    return positions.shape[1] // cfg.block_length if phase == "denoise" else 1
+
 
 
 def make_mixed_paged_fn(cfg, layers_paged, chunk_valid=False):
@@ -1555,12 +1569,16 @@ def make_mixed_paged_fn(cfg, layers_paged, chunk_valid=False):
     (`DecodeModelSpec.mixed_chunk_groups`); the others are handed G = 1.
     `chunk_valid`: the loop also takes `valid=`, the chunk's real positions
     `last_idx + 1` (a layer with recurrent state must not run it over the
-    chunk's padding). Further keywords of a call go to the loop as they are
-    (a routed family's `routing=True`: what it returns beside the counters
-    follows them)."""
+    chunk's padding). `hidden=True` (a block generator's loop, whose chunks
+    sample nothing): the slots' rows as the layers leave them, [S * B, D],
+    in the logits' place — its rule runs the head over the forwards that
+    sample (`DecodeModelSpec.head_fn`). Further keywords of a call go to the
+    loop as they are (a routed family's `routing=True`: what it returns
+    beside the counters follows them)."""
 
     def mixed_paged_fn(params, chunk_tokens, start_pos, last_idx, chunk_table,
-                       token, pos, pool, block_tables, count=None, **loop):
+                       token, pos, pool, block_tables, count=None,
+                       hidden=False, **loop):
         G, C = chunk_tokens.shape
         if token.ndim == 2:
             # a diffusion generator's block a slot (`cfg.block_length` rows
@@ -1578,6 +1596,8 @@ def make_mixed_paged_fn(cfg, layers_paged, chunk_valid=False):
         x, pool, *counts = layers_paged(
             params, x, pool, mixed_tables(chunk_table, block_tables, count),
             positions, **valid, **loop)
+        if hidden:
+            return (x[0, G * C:], pool, *counts)
         D = x.shape[-1]
         last = _last_rows(x[:, :G * C].reshape(G, C, D), last_idx)
         with jax.named_scope("head"):
@@ -1647,9 +1667,15 @@ def scan_paged(cfg: GPTConfig, blocks, x, pool, block_tables, positions,
         from deepspeed_tpu.ops.pallas.decode_attention import \
             paged_decode_work
         rows = cfg.block_length if mixed or phase == "denoise" else 1
-        decode_work = paged_decode_work(
-            *decode_rows(block_tables, positions, rows), pool["k"].shape[3],
-            window=_static_window(cfg))
+
+        def work(group=0):
+            return paged_decode_work(
+                *decode_rows(block_tables, positions, rows, group),
+                pool["k"].shape[3], window=_static_window(cfg))
+        # (a block forward's is a LIST, one a group of a slot's rows: the
+        # walk takes them by `block_groups` too)
+        decode_work = work() if rows == 1 else [
+            work(g) for g in range(block_groups(cfg, positions, phase))]
 
     def layer(x, p, pool_l, flag, acc, layer_id, block_base=None):
         x, pool_l, *counts = block_fn(
@@ -1764,8 +1790,10 @@ def _paged_attn_half(x, p, pool_l, positions, block_tables,
     program builds them.
 
     `decode_work`: the decode kernels' work list of these tables
-    (`paged_decode_work`), where the caller built it outside its layer loop;
-    None leaves it to the kernel's wrapper. `attn_programs`: a dict that
+    (`paged_decode_work`), where the caller built it outside its layer loop
+    — in a generator's block forward a Python list of them, one a
+    `block_groups` group of a slot's rows; None leaves it to the kernel's
+    wrapper. `attn_programs`: a dict that
     takes the name of the attention program selected, by dispatch phase.
 
     Quantized pool: K/V are quantized AT CACHE-WRITE TIME (symmetric
@@ -1919,9 +1947,13 @@ def _paged_write_attend(q, k, v, pool_l, positions, block_tables,
     # `cfg.block_length` rows a slot, denoise or commit) HAS a runner: once
     # the block's K/V are written its rows all see the same keys, [0, p + C),
     # so they are the decode walk with C x G query rows a KV head at the
-    # block's last position — no mask inside the block. The verify chunk
-    # still has none: its rows are causal INSIDE the chunk (row i sees
-    # pos + i), so they do not share one frontier.
+    # block's last position — no mask inside the block. A FUSED forward's 2C
+    # rows a slot (`block_groups`) are written together above and walked a
+    # group at a time, each at its own block's last position: the first
+    # group's walk ends below the second's keys, so it reads what a forward
+    # of its own would. The verify chunk still has none: its rows are causal
+    # INSIDE the chunk (row i sees pos + i), so they do not share one
+    # frontier.
     denoise = phase == "denoise"
     site = _decode_attn_site(
         cfg, "paged_decode" if denoise
@@ -1938,15 +1970,20 @@ def _paged_write_attend(q, k, v, pool_l, positions, block_tables,
     runner = attn_dispatch.get_program(program).runner
     if runner is not None and denoise:
         H, hd, Hkv = q.shape[2], q.shape[3], k.shape[2]
-        with jax.named_scope("walk"):
-            rows = jnp.swapaxes(q.reshape(B, C, Hkv, H // Hkv, hd), 1, 2)
-            attn = runner(rows.reshape(B, 1, C * H, hd), pool_l,
-                          block_tables, positions[:, -1],
-                          sm_scale=sm_scale(cfg),
-                          window=site.window or None, work=decode_work,
-                          **sunk)
-            attn = jnp.swapaxes(attn.reshape(B, Hkv, C, -1), 1, 2) \
-                .reshape(B, C, -1)
+        Bk = cfg.block_length
+        groups = []
+        for g in range(block_groups(cfg, positions, phase)):
+            work = decode_work[g] if decode_work else None
+            with jax.named_scope("walk"):
+                rows = jnp.swapaxes(q[:, g * Bk:(g + 1) * Bk].reshape(
+                    B, Bk, Hkv, H // Hkv, hd), 1, 2)
+                attn = runner(rows.reshape(B, 1, Bk * H, hd), pool_l,
+                              block_tables, positions[:, (g + 1) * Bk - 1],
+                              sm_scale=sm_scale(cfg),
+                              window=site.window or None, work=work, **sunk)
+                groups.append(jnp.swapaxes(
+                    attn.reshape(B, Hkv, Bk, -1), 1, 2).reshape(B, Bk, -1))
+        attn = groups[0] if len(groups) == 1 else jnp.concatenate(groups, 1)
     elif runner is not None:
         with jax.named_scope("walk"):
             attn = runner(q, pool_l, block_tables, positions[:, 0],

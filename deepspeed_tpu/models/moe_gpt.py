@@ -55,7 +55,8 @@ from deepspeed_tpu.models.gpt import (GPTConfig, _act, _attn_half, _block,
                                       _block_decode, _block_paged,
                                       _decode_attn_half, _embed, _last_rows,
                                       _lm_head, _norm, _residual_mlp,
-                                      gpt_cache_identity, gpt_init_fn,
+                                      block_groups, gpt_cache_identity,
+                                      gpt_init_fn,
                                       init_gpt_params, init_kv_cache,
                                       init_paged_kv_pool, gpt_param_specs,
                                       make_mixed_paged_fn,
@@ -259,10 +260,14 @@ def _moe_mlp(x, mp, cfg: MoEGPTConfig, training=True, mesh=None):
     return out.reshape(B, T, D), l_aux, stats
 
 
-def _routed_mlp(x, experts, cfg: MoEGPTConfig):
+def _routed_mlp(x, experts, cfg: MoEGPTConfig, groups=1):
     """Capacity-free inference routing: x [B, T, D] -> (out, counters
     int32[4] in `parallel.moe.ROUTED_COUNTERS` order, the chosen experts
-    [B*T, top_k]).
+    [B*T, top_k]). `groups` > 1 (`gpt.py::block_groups`, the count the
+    attention half walks by): a row's T positions are that many groups, which
+    go through the experts together and are combined a group at a time
+    (`routed_experts`'s `groups`), so each leaves as a forward of its own
+    would leave it, to the bit.
 
     Every token goes to its `cfg.top_k` most probable experts, weighted by
     the router's probabilities (rescaled under `norm_topk_prob`) — routing
@@ -271,14 +276,20 @@ def _routed_mlp(x, experts, cfg: MoEGPTConfig):
     invariant). `experts`: `_layer_experts`' (tree, base)."""
     mp, base = experts
     B, T, D = x.shape
-    xf = x.reshape(B * T, D)
+    xf = x.reshape(B * T, D) if groups == 1 else jnp.swapaxes(
+        x.reshape(B, groups, -1, D), 0, 1).reshape(B * T, D)   # a group a run
     top_p, top_e = topk_routing(xf, mp["gate_w"], cfg.top_k,
                                 cfg.norm_topk_prob)
     out, counters = routed_experts(
         xf, top_p, top_e, {k: v for k, v in mp.items() if k != "gate_w"},
         activation=lambda h: _act(h, cfg), num_experts=cfg.num_experts,
-        expert_base=base)
-    return out.reshape(B, T, D), counters, top_e
+        expert_base=base, groups=groups)
+    if groups == 1:
+        return out.reshape(B, T, D), counters, top_e
+    # ... and back to a row's own order
+    rows = lambda a: jnp.swapaxes(a.reshape(groups, B, T // groups, -1), 0, 1)
+    return (rows(out).reshape(B, T, D), counters,
+            rows(top_e).reshape(B * T, -1))
 
 
 def _router_balance(x, gate_w, cfg: MoEGPTConfig):
@@ -294,16 +305,16 @@ def _router_balance(x, gate_w, cfg: MoEGPTConfig):
                    * jnp.mean(chosen, axis=0) / cfg.top_k) * E
 
 
-def _routed_mlp_fn(experts, cfg, counters=None, routing=None):
+def _routed_mlp_fn(experts, cfg, counters=None, routing=None, groups=1):
     """`_residual_mlp`'s `mlp_fn` for a routed layer (`experts`:
     `_layer_experts`' pair, None for a dense layer -> None); each call's
     counters and chosen experts are appended to the lists `counters` and
-    `routing` where given."""
+    `routing` where given. `groups`: `_routed_mlp`'s."""
     if experts is None:
         return None
 
     def mlp_fn(h):
-        out, counted, top_e = _routed_mlp(h, experts, cfg)
+        out, counted, top_e = _routed_mlp(h, experts, cfg, groups)
         if counters is not None:
             counters.append(counted)
         if routing is not None:
@@ -535,13 +546,14 @@ def make_moe_gpt_decode_model(cfg: MoEGPTConfig, params=None, name="moe-gpt",
 
     def _loop_paged(params, x, pool, block_tables, positions, phase=None):
         slices, counts = [], [no_counts]
+        groups = block_groups(cfg, positions, phase)
         for lid in range(cfg.n_layer):
             p = jax.tree_util.tree_map(lambda a: a[lid], params["blocks"])
             pool_l = {k: v[lid] for k, v in pool.items()}
             mp = _layer_experts(params, p, lid)
             x, pool_l = _block_paged(
                 x, p, pool_l, positions, block_tables, cfg, phase=phase,
-                mlp_fn=_routed_mlp_fn(mp, cfg, counts),
+                mlp_fn=_routed_mlp_fn(mp, cfg, counts, groups=groups),
                 attn_programs=attn_programs)
             slices.append(pool_l)
         pool = {k: jnp.stack([s[k] for s in slices], 0) for k in pool}
@@ -563,6 +575,8 @@ def make_moe_gpt_decode_model(cfg: MoEGPTConfig, params=None, name="moe-gpt",
         scanned = {k: v for k, v in blocks.items()
                    if k not in _EXPERT_STACKS}
         L, rows = cfg.n_layer, x.shape[0] * x.shape[1]
+        # (the walk's groups, `gpt.py::_paged_write_attend`: the same rule)
+        groups = block_groups(cfg, positions, phase)
         aux = no_counts
         if routing:
             # the scan SUMS a layer's third result: each layer's sets ride
@@ -576,7 +590,8 @@ def make_moe_gpt_decode_model(cfg: MoEGPTConfig, params=None, name="moe-gpt",
             x, pool_l = _block_paged(
                 x, p, pool_l, positions, block_tables, cfg,
                 mlp_fn=_routed_mlp_fn(_layer_experts(params, p, layer), cfg,
-                                      counted, chosen), **kwargs)
+                                      counted, chosen, groups),
+                **kwargs)
             if not routing:
                 return x, pool_l, counted[0]
             return x, pool_l, jax.lax.dynamic_update_slice(
@@ -619,20 +634,31 @@ def make_moe_gpt_decode_model(cfg: MoEGPTConfig, params=None, name="moe-gpt",
         logits = _lm_head(params, x, cfg)
         return logits, pool, counts
 
-    def denoise_paged_fn(params, tokens, pos, pool, block_tables, **loop):
+    def denoise_paged_fn(params, tokens, pos, pool, block_tables,
+                         hidden=False, **loop):
         """One forward of a diffusion generator's block a slot (tokens
         [S, B] at pos .. pos + B - 1), denoise or commit alike: the rows'
         k/v written, [0, pos + B) attended, every row's logits [S * B, V],
-        slot after slot. Keywords go to the layer loop (`routing=True`: the
-        sets follow the counters)."""
-        S, B = tokens.shape
-        positions = pos[:, None] + jnp.arange(B, dtype=jnp.int32)[None]
+        slot after slot. `tokens` [S, 2B]: a FUSED forward — a block's clean
+        tokens and the next block's rows as ONE pass through every weight,
+        both blocks' k/v written, each attended to its own end
+        (`gpt.py::block_groups`); the logits are the second block's, the
+        rows that sample. `hidden=True`: those rows as the layers leave them,
+        [S * B, D], in the logits' place (`head_fn` makes the logits of
+        them). Keywords go to the layer loop (`routing=True`: the sets, of
+        every row, follow the counters)."""
+        S, R = tokens.shape
+        B = cfg.block_length
+        positions = pos[:, None] + jnp.arange(R, dtype=jnp.int32)[None]
         x = _embed(params, tokens, positions, cfg)
         x, pool, *counts = _layers_paged(params, x, pool, block_tables,
                                          positions, phase="denoise", **loop)
+        rows = x[:, R - B:].reshape(S * B, -1)
+        return (rows if hidden else head_fn(params, rows), pool, *counts)
+
+    def head_fn(params, rows):
         # (the head over the rows as ONE [S * B, D] matrix: B is no tile)
-        logits = _lm_head(params, x.reshape(1, S * B, -1), cfg)[0]
-        return (logits, pool, *counts)
+        return _lm_head(params, rows[None], cfg)[0]
 
     def init_paged_pool(num_blocks, block_size, dtype=jnp.bfloat16,
                         kv_group_size=0):
@@ -645,6 +671,7 @@ def make_moe_gpt_decode_model(cfg: MoEGPTConfig, params=None, name="moe-gpt",
                            generator=generator,
                            denoise_paged_fn=denoise_paged_fn if generator
                            else None,
+                           head_fn=head_fn if generator else None,
                            prefill_paged_fn=prefill_paged_fn,
                            decode_paged_fn=decode_paged_fn,
                            mixed_paged_fn=make_mixed_paged_fn(cfg,

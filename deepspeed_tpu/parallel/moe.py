@@ -399,7 +399,7 @@ def topk_routing(x, gate_w, top_k, normalize=False, scoring="softmax",
 
 
 def routed_experts(x, top_p, top_e, experts, activation=None,
-                   num_experts=None, expert_base=0, held=None):
+                   num_experts=None, expert_base=0, held=None, groups=1):
     """The expert MLPs of N tokens on their k chosen experts each, with no
     capacity and no dropped token: x [N, D] -> (out [N, D] in `x.dtype`,
     counters int32[4] in `ROUTED_COUNTERS` order).
@@ -437,7 +437,16 @@ def routed_experts(x, top_p, top_e, experts, activation=None,
     an assignment to an expert that lives elsewhere contributes ZERO — what
     that expert would add is the other chip's part of the sum — and is
     counted (`HELD_ROUTED_COUNTERS`, five counters). No exchange, and
-    nothing that stands in for one."""
+    nothing that stands in for one.
+
+    `groups` > 1: COMBINE IN EQUAL RUNS. The N tokens are that many equal
+    runs of rows (run after run); they are dispatched and multiplied
+    TOGETHER — an expert's weights are read once — and the weighted sum is
+    made a run at a time, at a run's shapes. The sum is float32 and its order
+    is the compiler's, by shape, so a run's rows come out as a call of that
+    run alone would leave them, to the bit (on the chip, PR 59: summed over
+    1024 tokens for 512, one element in 30,000 rounded one bfloat16 step
+    apart; every other product of the layer was equal already)."""
     from deepspeed_tpu.ops.pallas.moe_gmm import moe_gmm
 
     N, D = x.shape
@@ -474,15 +483,28 @@ def routed_experts(x, top_p, top_e, experts, activation=None,
                                     axis=0)
             h = biased(gmm(rows, experts["w_up"]), "b_up")
             y = biased(gmm(activation(h), experts["w_down"]), "b_down")
+    def combine(back, top_p, flat_e):
+        """The n tokens whose assignments [k * n] (their sort keys `flat_e`)
+        lie at the sorted rows `back`: their k results weighted by `top_p`
+        [n, k] and summed, [n, D]."""
+        rows = jnp.take(y, back, axis=0, mode="clip").reshape(k, -1, D)
+        if held is not None:
+            # rows past the held ones are memory nobody wrote
+            rows = jnp.where((flat_e < E).reshape(k, -1, 1), rows, 0)
+        return jnp.sum(rows.astype(jnp.float32) * top_p.T[:, :, None],
+                       axis=0).astype(x.dtype)
+
     with jax.named_scope("moe/combine"):
         back = jnp.zeros((M,), jnp.int32).at[order].set(
             jnp.arange(M, dtype=jnp.int32))           # assignment -> sorted row
-        y = jnp.take(y, back, axis=0, mode="clip").reshape(k, N, D)
-        if held is not None:
-            # rows past the held ones are memory nobody wrote
-            y = jnp.where((flat_e < E).reshape(k, N, 1), y, 0)
-        out = jnp.sum(y.astype(jnp.float32) * top_p.T[:, :, None],
-                      axis=0).astype(x.dtype)
+        if groups == 1:
+            out = combine(back, top_p, flat_e)
+        else:
+            run = lambda a, g: a.reshape(k, groups, -1)[:, g].reshape(-1)
+            out = jnp.concatenate([
+                combine(run(back, g), jnp.split(top_p, groups)[g],
+                        run(flat_e, g) if held else None)
+                for g in range(groups)])
     if held is None:
         counters = jnp.stack([jnp.int32(1), jnp.int32(M),
                               jnp.sum(sizes > 0, dtype=jnp.int32),

@@ -175,12 +175,14 @@ CallRecord = collections.namedtuple("CallRecord", [
                         # tokens included (<= rows * win + firsts: a tail past
                         # `max_new`, an EOS, a request that ended meanwhile)
     "forwards",         # a block-diffusion call (`DecodeModelSpec.generator`):
-                        # the forwards it took, denoise and commit, by its own
-                        # counters at the read-back, each of ...
-    "block_rows",       # ... this many rows a slot (the block length); `win`
-                        # is then forwards * block_rows, the rows a slot ran
-                        # through the model, and `emitted` the tokens
-                        # COMMITTED and delivered. 0, 0 for every other call
+                        # its passes through the weights, by its own counters
+                        # at the read-back: denoise + commit forwards less the
+                        # fused ones, which are one of each, of ...
+    "block_rows",       # ... this many rows a slot a denoise or commit
+                        # forward (the block length); `win` is then (denoise
+                        # + commit) * block_rows, the rows a slot ran through
+                        # the model, and `emitted` the tokens COMMITTED and
+                        # delivered. 0, 0 for every other call
 ], defaults=(0, 0))
 
 _LATEST = {}        # subsystem -> the most recently created recorder

@@ -38,6 +38,10 @@ WRITES = {
     "chunk_inside_one_tile": (5, [18], [[1, 2]]),
     "chunk_off_a_tile_boundary": (20, [5], [[3, 2]]),
     "chunk_across_two_blocks": (24, [20, 3], [[1, 2], [5, 4]]),
+    # a block generator's fused forward: two blocks of 4 rows a slot as one
+    # run, which straddles a pool block where the first is the block's last
+    "two_row_blocks_across_a_pool_block": (8, [28, 12, 0],
+                                           [[1, 2], [3, 4], [0, 0]]),
 }
 
 

@@ -112,6 +112,31 @@ def test_a_tokens_result_is_the_same_bits_alone_and_in_a_batch_of_64(k, held):
     np.testing.assert_array_equal(run(perm), whole[perm])
 
 
+@pytest.mark.parametrize("held", [None, (8, 8)], ids=["all", "held"])
+@pytest.mark.parametrize("groups", [2, 4])
+def test_groups_of_rows_go_through_together_and_are_combined_apart(groups,
+                                                                   held):
+    """`groups`: equal runs of rows dispatched and multiplied as ONE call
+    (the counters say so) and combined a run at a time — each run's result
+    is the bits of a call of its own, gated experts and arbitrary weights
+    (on the CPU the k-term sum keeps its order whatever the shape; on the
+    chip it does not, which is what `groups` is for)."""
+    rng = np.random.default_rng(11 * groups)
+    n, k = 24, 4
+    x = jnp.asarray(rng.normal(0, 1, (groups * n, D)).astype(np.float32))
+    top_p, top_e = _routing(rng, groups * n, k)
+    top_p, top_e = jnp.asarray(top_p), jnp.asarray(top_e)
+    experts = _experts(rng, True, held[1] if held else ROUTED)
+    run = lambda rows, **more: routed_experts(
+        x[rows], top_p[rows], top_e[rows], experts, held=held, **more)
+    together, counters = run(slice(None), groups=groups)
+    for g in range(groups):
+        rows = slice(g * n, (g + 1) * n)
+        np.testing.assert_array_equal(np.asarray(together[rows]),
+                                      np.asarray(run(rows)[0]))
+    assert [int(c) for c in counters] == _counters(np.asarray(top_e), held)
+
+
 def _shapes(jaxpr, found):
     for eqn in jaxpr.eqns:
         found.update(tuple(v.aval.shape) for v in eqn.outvars)

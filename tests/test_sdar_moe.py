@@ -142,34 +142,40 @@ def test_served_tokens_are_the_reference_samplers(model, steps, peaked):
     stats = srv.stats()
     counted = stats["step_counters"]
     assert set(BLOCK_DIFFUSION_COUNTERS) <= set(counted)
-    assert stats["generator"]["forwards"] == \
-        counted["denoise_forwards"] + counted["commit_forwards"]
+    # (roles, not passes: a fused forward is a commit AND a denoise forward)
+    roles = counted["denoise_forwards"] + counted["commit_forwards"]
+    assert stats["generator"]["forwards"] == roles - counted["fused_forwards"]
     assert srv.fused_chunks > 0 and srv.compile_stats()["mixed_step"] == 1
     assert srv.compile_stats()["decode_step"] == 1
     assert srv.allocator.num_free == srv.allocator.capacity
     # every call opens its blocks together: a commit forward a block a call
     calls = [c for c in srv.steptrace.calls() if c.program != "prefill"]
     assert counted["commit_forwards"] == 2 * len(calls)
+    # ... and the first of the two commits in the pass that opens the second
+    assert counted["fused_forwards"] == len(calls)
+    assert stats["generator"]["fused_forward_share"] == 0.5
     per_block = counted["denoise_forwards"] / counted["commit_forwards"]
     # (a first block that opens with 3 prompt tokens is clean sooner)
     assert per_block <= steps
     if peaked:
         assert per_block < steps - 0.2    # blocks that ended early
     for c in calls:
-        assert c.block_rows == B and c.win == c.forwards * B
+        # a call's one fused pass runs 2B rows a slot, the others B
+        assert c.block_rows == B and c.win == (c.forwards + 1) * B
         assert c.emitted <= c.rows * 2 * B
     # the walk is booked where the forwards are: at the read-back, by the
-    # forwards the call took (a forward walks every live slot's blocks)
+    # walks the call took (a denoise or commit forward walks every live
+    # slot's blocks once, a fused one twice)
     names = list(srv.step_counter_names)
     at = [names.index(n) for n in ("denoise_forwards", "commit_forwards")]
     booked = 0
     for r in srv.steptrace.records():
-        forwards = sum(r.counters[i] for i in at) if r.counters else 0
-        assert (r.decode_live_blocks > 0) == (forwards > 0)
-        assert forwards <= r.decode_live_blocks \
-            <= forwards * srv.max_slots * srv.nb
-        booked += forwards
-    assert booked == stats["generator"]["forwards"]
+        walks = sum(r.counters[i] for i in at) if r.counters else 0
+        assert (r.decode_live_blocks > 0) == (walks > 0)
+        assert walks <= r.decode_live_blocks \
+            <= walks * srv.max_slots * srv.nb
+        booked += walks
+    assert booked == roles
 
 
 def test_unmask_rule_on_the_device_is_the_references(model):
@@ -285,16 +291,160 @@ def test_chunked_three_ways_is_one_pass_and_riding_changes_no_block(model):
                                       together[r.uid].tokens)
 
 
-@pytest.mark.parametrize("blocks_per_call", [1, 4])
-def test_blocks_per_call_changes_no_token(model, blocks_per_call):
-    reqs = requests()
+ALIGNED = [(20, 10), (16, 7), (4, 9), (40, 12), (36, 5), (8, 8)]
+
+
+@pytest.mark.parametrize("shapes", [SHAPES, ALIGNED],
+                         ids=["any_prompt", "whole_blocks"])
+@pytest.mark.parametrize("blocks_per_call", [1, 2, 4])
+def test_blocks_per_call_changes_no_token(model, blocks_per_call, shapes):
+    """... whatever a prompt's length mod B (`SHAPES`: a first block that
+    opens with a prompt's tail is clean sooner), and every block of a call
+    but its last commits in a fused forward: none at one block a call. With
+    prompts of whole blocks (`ALIGNED`) every block takes its two denoise
+    forwards and its commit, so a call's passes and rows are known."""
+    reqs = requests(shapes)
     engine, srv = serving(model, blocks_per_call=blocks_per_call)
+    before = dict(srv.stats().get("step_counters") or {})
+    seen = len(srv.steptrace.calls())
     done = srv.run(reqs)
     want = reference_tokens(engine, reqs, 2)
     for r in reqs:
         np.testing.assert_array_equal(done[r.uid].tokens, want[r.uid])
     assert srv.window == blocks_per_call * B
+    assert srv.ride_window == 3 * blocks_per_call - 2 * (blocks_per_call - 1)
     assert srv.allocator.num_free == srv.allocator.capacity
+    counted = {name: n - before.get(name, 0)
+               for name, n in srv.stats()["step_counters"].items()}
+    calls = [c for c in srv.steptrace.calls()[seen:]
+             if c.program != "prefill"]
+    assert counted["fused_forwards"] == len(calls) * (blocks_per_call - 1)
+    assert counted["commit_forwards"] == len(calls) * blocks_per_call
+    roles = counted["denoise_forwards"] + counted["commit_forwards"]
+    assert sum(c.forwards for c in calls) == roles - counted["fused_forwards"]
+    assert sum(c.win for c in calls) == roles * B
+    if shapes is SHAPES:
+        assert counted["denoise_forwards"] <= 2 * counted["commit_forwards"]
+        return
+    assert counted["denoise_forwards"] == 2 * counted["commit_forwards"]
+    for c in calls:
+        assert c.forwards == 2 * blocks_per_call + 1
+        assert c.win == 3 * blocks_per_call * B
+
+
+@pytest.mark.parametrize("owed", [0, 1, 3, 8])
+def test_chunks_owed_ride_the_forwards_of_b_rows_and_the_boundary_fuses(
+        model, owed):
+    """The SERVED `mixed_step` (`decode_step` where no chunk is owed) with
+    `owed` chunks of a prompt riding a call of two blocks — up to
+    `ride_window` x G = 4 x 2: none, one group half full, two groups, every
+    forward of B rows carrying a full group — against the same chunks through
+    `prefill_step` and then `decode_step`: the committed tokens of the
+    running slots, the block loop's counters (the boundary between the two
+    blocks fuses whatever rides: five passes for six roles) and the pool's
+    rows of the riding prompt and of the committed blocks."""
+    engine, srv = serving(model, prefill_chunks_per_step=6)
+    G, ride, chunk, nb = (srv.programs.group, srv.ride_window, srv.chunk,
+                          srv.nb)
+    assert (G, ride) == (2, 4)
+    rng = np.random.default_rng(owed)
+    prompts = [rng.integers(0, 500, (n,)).astype(np.int32)
+               for n in (16, 32, 8 * chunk)]
+    tables = np.zeros((srv.max_slots, nb), np.int32)
+    tables[:3] = 1 + np.arange(3 * nb).reshape(3, nb)
+    programs, params = srv.programs, engine.params
+
+    def chunk_args(slot, starts):
+        toks = np.stack([prompts[slot][a:a + chunk] for a in starts])
+        return (toks, np.asarray(starts, np.int32),
+                np.full((len(starts),), chunk - 1, np.int32),
+                np.tile(tables[slot], (len(starts), 1)))
+
+    def prefilled():
+        pool = jax.tree_util.tree_map(jnp.zeros_like, srv.pool)
+        for slot in (0, 1):
+            for start in range(0, len(prompts[slot]), chunk):
+                _, pool = programs.prefill(
+                    params, *(a[:1] for a in chunk_args(slot, [start])[:3]),
+                    pool, tables[slot][None], srv._next_rng())
+        return pool
+
+    tok = np.zeros((srv.max_slots, B), np.int32)
+    tok[:2] = MASK
+    pos = np.asarray([16, 32, 0, 0], np.int32)
+    shown = np.where((tok == MASK).any(1)[:, None], tables, 0)
+    starts = [i * chunk for i in range(owed)]
+
+    pool = prefilled()
+    if owed:
+        pad = lambda a: np.concatenate(
+            [a, np.repeat(a[-1:], ride * G - owed, axis=0)]).reshape(
+                (ride, G) + a.shape[1:])
+        served, pool = programs.mixed(
+            params, *(pad(a) for a in chunk_args(2, starts)),
+            np.int32(owed), tok, pos, pool, shown, srv._next_rng())
+    else:
+        served, pool = programs.decode(params, tok, pos, pool, shown,
+                                       srv._next_rng())
+    apart = prefilled()
+    for start in starts:
+        _, apart = programs.prefill(
+            params, *(a[:1] for a in chunk_args(2, [start])[:3]), apart,
+            tables[2][None], srv._next_rng())
+    alone, apart = programs.decode(params, tok, pos, apart, shown,
+                                   srv._next_rng())
+
+    (toks, counts), (want, want_counts) = jax.device_get((served, alone))
+    np.testing.assert_array_equal(toks[:2], want[:2])
+    names = list(srv.step_counter_names)
+    loop = counts[[names.index(n) for n in BLOCK_DIFFUSION_COUNTERS]]
+    assert list(loop[:3]) == [1, 4, 2] and loop[4] == 2 * 2
+    np.testing.assert_array_equal(
+        loop, want_counts[-len(BLOCK_DIFFUSION_COUNTERS):])
+    for leaf in ("k", "v"):     # (block 0 is the trash block)
+        np.testing.assert_allclose(np.asarray(pool[leaf][:, 1:]),
+                                   np.asarray(apart[leaf][:, 1:]),
+                                   rtol=2e-4, atol=2e-4)
+    assert programs.compile_counts()["decode_step"] == 1
+    assert programs.compile_counts().get("mixed_step", 1) == 1
+
+
+@pytest.mark.parametrize("denoise,commit,fused", [(4, 2, 1), (4, 2, 0),
+                                                  (3, 2, 1), (8, 4, 3)])
+def test_a_calls_record_and_the_stats_count_passes_and_rows_apart(
+        model, denoise, commit, fused):
+    """`BlockDiffusionCalls.close` / `due` / `stats` on counters as a call
+    reads them back: passes through the weights are denoise + commit - fused
+    (`forwards`), rows a slot ran and walks are by role (`win`, what
+    `sched_decode_useful_token_share.*` divides by; a fused forward is 2B
+    rows and two walks)."""
+    from deepspeed_tpu.telemetry.steptrace import CallRecord
+    _, srv = serving(model)
+    gen = srv.gen
+    names = list(srv.step_counter_names)
+    counts = np.zeros((len(names),), np.int64)
+    for name, n in (("denoise_forwards", denoise), ("commit_forwards", commit),
+                    ("fused_forwards", fused)):
+        counts[names.index(name)] = n
+    blank = CallRecord(*[0] * len(CallRecord._fields))
+    rec = gen.close(blank._replace(win=gen.window * gen.row_forwards),
+                    counts)
+    assert rec.forwards == denoise + commit - fused
+    assert rec.win == (denoise + commit) * B and rec.block_rows == B
+    assert gen.close(blank, counts) is blank       # (a call with no window)
+    # a forward's walk a block, booked by the walks the call took
+    assert gen.due({"decode_live_blocks": 10}) == {}
+    assert gen.due({"decode_live_blocks": 10}, rec) == {
+        "decode_live_blocks": 10 * (denoise + commit) / gen.blocks_per_call}
+    stats = gen.stats(dict(zip(names, counts)))
+    assert stats["forwards"] == denoise + commit - fused
+    assert stats["passes_per_block"] == (denoise + commit - fused) / commit
+    assert stats["forwards_per_block"] == (denoise + commit) / commit
+    assert stats["fused_forward_share"] == fused / commit
+    if (denoise, commit, fused) == (4, 2, 1):       # the cell's call
+        assert stats["passes_per_block"] == 2.5
+        assert stats["forwards_per_block"] == 3.0   # (roles: S + 1)
+        assert stats["fused_forward_share"] == 0.5
 
 
 # -- (e) ends inside a block, in a call, in flight ---------------------------
@@ -454,6 +604,69 @@ def test_denoise_rows_through_the_decode_walk_equal_the_oracle(model,
                                atol=2e-4)
 
 
+@pytest.mark.parametrize("force", [False, True], ids=["oracle", "walk"])
+def test_a_fused_forward_is_a_commit_then_a_denoise_forward(model, force):
+    """`denoise_paged_fn` with 2B rows a slot — a block's clean tokens and
+    the next block's mask rows — against a commit forward of the first
+    followed by a denoise forward of the second on the same pool: the second
+    block's logits, both blocks' K/V rows and the routed counters, through
+    the gather oracle and through `dstpu_paged_decode` (interpreted, a walk
+    a group). Slot 0's first block is its pool block's last (`pos % block ==
+    block - B`: the run of 2B rows straddles two pool blocks), slot 1 is
+    dead, slot 2 has one block behind it, slot 3 sits inside a pool block."""
+    cfg, _, _ = model
+    bs, nb = 128, 3
+    wide = dataclasses.replace(cfg, attn_head_dim=128,
+                               use_flash_attention=force)
+    params = jax.jit(lambda key: lively(sdar_moe.sdar_moe_init_fn(
+        wide, dtype=jnp.float32)(key)))(jax.random.PRNGKey(0))
+    spec = sdar_moe.make_sdar_moe_decode_model(
+        wide, sdar_moe.generator(B, MASK, 2), params=params)
+    rng = np.random.default_rng(9)
+    tables = np.zeros((4, nb), np.int32)
+    tables[0], tables[2], tables[3] = [1, 2, 3], [4, 5, 6], [7, 8, 9]
+    pos = np.asarray([bs - B, 0, B, bs + 5 * B], np.int32)
+    clean = rng.integers(0, 500, (4, B)).astype(np.int32)
+    fresh = np.full((4, B), MASK, np.int32)
+    filled = jax.tree_util.tree_map(
+        lambda a: jnp.asarray(rng.normal(size=a.shape), a.dtype),
+        spec.init_paged_pool(1 + 3 * nb, bs, jnp.float32))
+    forward = jax.jit(spec.denoise_paged_fn, static_argnames=("hidden",))
+
+    _, pool, commit_counts = forward(params, clean, pos, filled, tables)
+    want, pool, denoise_counts = forward(params, fresh, pos + B, pool, tables)
+    got, fused_pool, counts = forward(
+        params, np.concatenate([clean, fresh], axis=1), pos, filled, tables)
+    assert spec.paged_attn_programs["paged_decode"] == (
+        "paged_kernel" if force else "paged_gather")
+
+    live = [0, 2, 3]
+    rows = lambda a: np.asarray(a).reshape(4, B, -1)[live]
+    assert got.shape == want.shape == (4 * B, VOCAB)
+    np.testing.assert_allclose(rows(got), rows(want), rtol=2e-4, atol=2e-4)
+    for leaf in ("k", "v"):     # (block 0 is the trash block: slot 1's rows)
+        np.testing.assert_allclose(np.asarray(fused_pool[leaf][:, 1:]),
+                                   np.asarray(pool[leaf][:, 1:]),
+                                   rtol=2e-4, atol=2e-4)
+        # ... and the 2B rows are there: slot 0's on both sides of the edge
+        was = np.asarray(filled[leaf])
+        now = np.asarray(fused_pool[leaf])
+        assert (now[:, 1, :, bs - B:] != was[:, 1, :, bs - B:]).all()
+        assert (now[:, 2, :, :B] != was[:, 2, :, :B]).all()
+        np.testing.assert_array_equal(now[:, 2, :, B:], was[:, 2, :, B:])
+    # one pass routed both groups' rows
+    names = list(spec.step_counters)
+    at = names.index("moe_assignments")
+    assert counts[at] == commit_counts[at] + denoise_counts[at]
+    # the rows the head reads, handed back in the logits' place
+    hidden, _, _ = forward(params, np.concatenate([clean, fresh], axis=1),
+                           pos, filled, tables, hidden=True)
+    assert hidden.shape == (4 * B, wide.d_model)
+    np.testing.assert_allclose(
+        rows(jax.jit(spec.head_fn)(params, hidden)), rows(got), rtol=1e-5,
+        atol=1e-5)
+
+
 # -- (g) the other families' programs lower as the parent's ------------------
 
 
@@ -526,6 +739,35 @@ def test_other_families_step_programs_lower_to_the_parents_text(kind):
     with open(os.path.join(ROOT, "tests", "step_program_hashes.json")) as f:
         pinned = json.load(f)[kind]
     assert _lowered_step_programs(kind) == pinned
+
+
+@pytest.mark.parametrize("family, cases, model, serving", [
+    ("exaone_moe", "exaone_cases", {"held": (0, 8)}, {}),
+    ("mimo_v2_flash", "mimo_cases", {"held": (0, 8)},
+     {"prefill_chunks_per_step": 6}),
+    ("glm4_moe_lite", "glm_cases", {"held": (0, 8)}, {})])
+def test_the_held_and_latent_families_step_programs_lower_to_the_parents_text(
+        family, cases, model, serving):
+    """The other serving cells' families run the code PR 59 edited
+    (`routed_experts`' combine, with half the experts held; `scan_paged`'s
+    work list; `make_mixed_paged_fn`; MiMo a group of two chunks a token
+    through `over_chunk_group`; GLM-4.7-Flash the latent pool): the three
+    step programs of a tiny engine a family lower to the text they had on the
+    commit PR 59 started from (`tests/step_program_hashes.json`, written
+    there by this function's body in a `git archive` of 2bddd10)."""
+    import importlib
+    module = importlib.import_module("tests." + cases)
+    cfg = module._cfg(**model)
+    engine, srv = module._serving(cfg, module._params(cfg), one_device=True,
+                                  **serving)
+    assert srv.programs.group == (2 if family == "mimo_v2_flash" else 1)
+    got = {}
+    for name, fn, args in srv.programs.examples(
+            engine.params, srv.pool, srv._tables_arg(srv.tables), srv._rng):
+        text = _strip(jax.jit(fn).lower(*args).as_text())
+        got[name] = hashlib.sha256(text.encode()).hexdigest()[:16]
+    with open(os.path.join(ROOT, "tests", "step_program_hashes.json")) as f:
+        assert got == json.load(f)[family]
 
 
 # -- the benchmark's files ---------------------------------------------------
@@ -633,6 +875,13 @@ def test_the_cell_rehearses_on_the_cpu(trace):
     counted = line["notes"]["step_counters"]
     assert counted["blocks_committed"] > 0
     assert counted["denoise_forwards"] == 2 * counted["commit_forwards"]
+    # two blocks a call: one of every two commits rides the next block's
+    # first denoise forward, five passes for six roles
+    assert 2 * counted["fused_forwards"] == counted["commit_forwards"]
+    assert line["notes"]["generator"]["passes_per_block"] == 2.5
+    assert line["notes"]["generator"]["forwards_per_block"] == 3.0
+    assert line["notes"]["generator"]["fused_forward_share"] == 0.5
+    assert line["notes"]["logits"]["served_call_counters_equal"] is True
     if trace:
         assert "sched_decode_useful_token_share.throughput" in line["metrics"]
     else:

@@ -1557,6 +1557,105 @@ def test_routed_paged_programs_hold_nothing_of_the_pools_or_experts_size(
 
 
 # ----------------------------------------------------------------------
+# a generator's block loop (inference/step_programs.py::
+# _block_diffusion_steps): the same guard on the loop of loops
+# ----------------------------------------------------------------------
+
+
+def _compile_block_diffusion_programs(one_chip, blocks):
+    """`decode_step` and `mixed_step` as `build_resident` makes them for a
+    2-layer SDAR-shaped model (routed experts, blocks of 4 rows a slot, two
+    denoise steps) at the served tile widths (Hkv 4, pool blocks of 512, hd
+    128), `blocks` blocks a call and a group of 2 chunks a riding forward."""
+    from deepspeed_tpu.inference import step_programs
+    from deepspeed_tpu.inference.config import TpuInferenceConfig
+    from deepspeed_tpu.models import sdar_moe
+    from deepspeed_tpu.models.moe_gpt import MoEGPTConfig
+
+    def sds(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    B, steps, S, C, G, nb = 4, 2, 64, 256, 2, 3
+    cfg = MoEGPTConfig(vocab_size=512, n_layer=2, n_head=8, n_kv_head=4,
+                       d_model=1024, d_ff=1024, max_seq_len=nb * 512,
+                       use_rotary=True, use_rmsnorm=True, use_swiglu=True,
+                       qk_norm=True, tie_embeddings=False, num_experts=8,
+                       top_k=4, moe_freq=1, use_flash_attention=True,
+                       block_length=B, dtype=jnp.bfloat16, remat=False)
+    shapes = jax.eval_shape(sdar_moe.sdar_moe_init_fn(cfg, dtype=jnp.bfloat16),
+                            jax.random.PRNGKey(0))
+    spec = sdar_moe.make_sdar_moe_decode_model(
+        cfg, sdar_moe.generator(B, 511, steps), params=shapes, name="guard")
+    params = jax.tree_util.tree_map(lambda a: sds(a.shape, a.dtype), shapes)
+    pool = jax.tree_util.tree_map(
+        lambda a: sds(a.shape, a.dtype),
+        jax.eval_shape(lambda: spec.init_paged_pool(256, 512, jnp.bfloat16)))
+    layer_leaf = pool["k"].size // cfg.n_layer * pool["k"].dtype.itemsize
+    experts = shapes["blocks"]["moe_w_gate_up"]
+    layer_experts = experts.size // cfg.n_layer * experts.dtype.itemsize
+    W = blocks * (steps + 1) - 2 * (blocks - 1)      # the forwards that ride
+
+    class NoWatchdog:
+        def wrap(self, name, fn):
+            return fn
+
+    built = dict(step_programs.build_resident(
+        spec, TpuInferenceConfig.from_dict({"dtype": "bfloat16",
+                                            "greedy": True}),
+        lambda fn: fn, window=W, max_slots=S, chunk=C, spec_on=False,
+        draft_k=0, replicated=one_chip, watchdog=NoWatchdog(), group=G,
+        blocks_per_call=blocks, denoising_steps=steps).built())
+    slots = (sds((S, B)), sds((S,)), pool, sds((S, nb)),
+             sds((2,), jnp.uint32))
+    programs = {
+        "decode": built["decode_step"].lower(params, *slots).compile(),
+        "mixed": built["mixed_step"].lower(
+            params, sds((W, G, C)), sds((W, G)), sds((W, G)),
+            sds((W, G, nb)), sds(()), *slots).compile()}
+    return programs, layer_leaf, layer_experts, dict(spec.kv_pool_writers)
+
+
+@pytest.mark.parametrize("blocks", [1, 2])
+def test_block_diffusion_programs_hold_nothing_of_the_pools_size(
+        one_chip, monkeypatch, blocks):
+    """A call's loop runs a traced body a KIND of forward (B rows a slot
+    with a chunk group riding, B rows without, 2B rows fused), each a
+    `while` of its own inside the call's loop, on the carried pool. The
+    trap (PERF.md §6, PR 59): a conditional that the pool passes through —
+    a `lax.switch` over the kinds, a `lax.cond` around a forward — does not
+    hand it on in place, and XLA copies a whole pool leaf into and out of
+    every layer's write (at the cell's size: temporaries 3.95 GiB for
+    0.15). So, as for the other families' programs: nothing as large as a
+    layer's pool leaf or a layer's experts but the aliased Mosaic calls, and
+    temporaries under that size; the head's `lax.cond` takes rows only."""
+    from deepspeed_tpu.ops import attention_dispatch
+    from deepspeed_tpu.platform import device
+    mesh_mod.clear_mesh()
+    monkeypatch.setattr(device, "on_tpu", lambda: True)
+
+    programs, layer_leaf, layer_experts, writers = \
+        _compile_block_diffusion_programs(one_chip, blocks)
+    assert set(writers.values()) == {attention_dispatch.KV_POOL_WRITE_KERNEL}
+    for name, program in programs.items():
+        text = program.as_text()
+        # (by the pool's size: at these widths XLA prefetches a layer of
+        # experts into fast memory, which is not what is guarded)
+        large = _large_instructions(text, layer_leaf)
+        assert [x for x in large if x[1] not in _NO_NEW_BUFFER] == [], name
+        calls = {n.rsplit(".", 1)[0] for n, opcode in large
+                 if opcode == "custom-call"}
+        assert calls == {"dstpu_kv_pool_write"}, (name, calls)
+        assert program.memory_analysis().temp_size_in_bytes \
+            < min(layer_leaf, layer_experts), name
+        assert "dstpu_moe_gmm" in text, name
+        # a model body a kind of forward: one walk a group of rows a body
+        bodies = {"decode": 1, "mixed": 2}[name] + (blocks > 1)
+        walks = [c for c in _mosaic_calls(text)
+                 if c.startswith("dstpu_paged_decode")]
+        assert len(walks) == bodies + (blocks > 1), (name, walks)
+
+
+# ----------------------------------------------------------------------
 # a pool of TWO KINDS (models/exaone_moe.py): the same guard, both kinds
 # ----------------------------------------------------------------------
 
