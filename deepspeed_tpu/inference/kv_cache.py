@@ -73,7 +73,10 @@ class CacheKind:
     leaves, `[layers, 1 + slots, ...]` with row 0 the trash row): nothing is
     allocated, freed or walked, a program finds a slot's row by `state_rows`,
     zeroes it where a prompt begins (`start_pos == 0`) and hands it from one
-    call to the next."""
+    call to the next. `index_topk` > 0: a position's entry has an INDEX KEY
+    beside K and V (the leaf `ik`: a learned sparse-attention indexer,
+    `models/sparse_attn.py`), and a query attends the `index_topk` cached
+    positions the indexer scores highest and no other."""
     name: str
     layers: int
     block: int
@@ -81,6 +84,7 @@ class CacheKind:
     leaves: tuple = ("k", "v")
     state: bool = False
     entry_values: int = 0
+    index_topk: int = 0
 
 
 def state_rows(slots: int) -> np.ndarray:

@@ -430,6 +430,13 @@ class ServingEngine:
                     "kv_cache_dtype int8":
                         f"its leaves ({', '.join(first.leaves)}) have no "
                         f"scale leaves and its walks no dequantizing twin"}
+                if first.index_topk:
+                    # a sparse layer's index key rides the full kind's
+                    # blocks (`models/sparse_attn.py`): the prefix cache and
+                    # block transplant take it with them (a verify chunk is
+                    # refused above: the family has no `verify_paged_fn`)
+                    kept += (" with an index key a position (a learned "
+                             "sparse-attention indexer)")
             elif second.state:
                 self.state_kind = second
                 kept = ("a KV pool of two kinds (per-slot recurrent state "
@@ -580,6 +587,10 @@ class ServingEngine:
         # then books the decode walk's pairs as the latent walk's)
         self._latent = bool(self.cache_kinds) \
             and self.cache_kinds[0].name == "latent"
+        # ... or entries with an index key, of which a learned indexer
+        # selects `index_topk` a query (`CacheKind.index_topk`)
+        self._index_topk = self.cache_kinds[0].index_topk \
+            if self.cache_kinds else 0
         # what a decode token of one slot reads + writes of a state kind's
         # state proper (its first leaf), all layers
         self._state_token_bytes = 0
@@ -1874,6 +1885,9 @@ class ServingEngine:
         from deepspeed_tpu.ops.attention_dispatch import get_program
         work = {"ssm_chunk_tokens": self.chunk} \
             if self.state_kind is not None else {}
+        if self._index_topk:
+            work.update(self._sparse_work(
+                start + 1 + np.arange(self.chunk, dtype=np.int64)))
         traced = getattr(self.engine.model_spec, "paged_attn_programs",
                          None) or {}
         count = get_program(traced[phase]).work if phase in traced else None
@@ -1888,6 +1902,19 @@ class ServingEngine:
                 start, self.chunk, wkind.block, self.ring_tables.shape[1],
                 wkind.window), _CHUNK_WINDOW_FIELDS))
         return work
+
+    def _sparse_work(self, seen):
+        """What a sparse layer's indexer makes of queries that see `seen`
+        positions each (numpy; t + 1 for a query at t), a layer, as
+        `StepRecord` fields: the pairs scored, the pairs selected, and the
+        pairs the attention walks read for them — every position a query
+        sees, whatever was selected: the selection rides the dense walks as
+        a mask (`models/sparse_attn.py`)."""
+        scored = int(np.sum(seen))
+        return {"index_scored_positions": scored,
+                "selected_positions": int(np.sum(np.minimum(
+                    seen, self._index_topk))),
+                "sparse_walk_positions": scored}
 
     def _book(self, work):
         """Add `work` (`StepRecord` field -> count) to the open step's sums:
@@ -2148,6 +2175,8 @@ class ServingEngine:
                       _DECODE_FIELDS)
         if self._latent:
             work["latent_walk_blocks"] = work["decode_live_blocks"]
+        if self._index_topk:
+            work.update(self._sparse_work(at + 1))
         if self.window_kind is not None:
             wkind = self.window_kind
             work.update(_lands(paged_decode_walk_counts(

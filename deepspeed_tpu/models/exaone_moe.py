@@ -66,6 +66,9 @@ from deepspeed_tpu.models.gpt import (MixedTables, _attn_half, _embed,
 from deepspeed_tpu.models.mla import (LATENT_LEAF, entry_width, mla_attn_half,
                                       mla_shapes, paged_mla_half)
 from deepspeed_tpu.models.moe_gpt import MoEGPTConfig
+from deepspeed_tpu.models.sparse_attn import (INDEX_LEAF, index_shapes,
+                                              paged_sparse_half,
+                                              sparse_attn_half)
 from deepspeed_tpu.ops import attention_dispatch as attn_dispatch
 from deepspeed_tpu.ops.pallas.kv_pool import kv_leaf_shapes
 from deepspeed_tpu.parallel.moe import (HELD_ROUTED_COUNTERS, routed_experts,
@@ -73,6 +76,9 @@ from deepspeed_tpu.parallel.moe import (HELD_ROUTED_COUNTERS, routed_experts,
 
 WINDOW, FULL = "sliding_attention", "full_attention"
 LATENT = "latent_attention"     # MLA (`models/mla.py`): one entry a token
+SELECTED = "sparse_attention"   # a learned indexer selects what a query
+                                # attends (`models/sparse_attn.py`): the full
+                                # kind's entry and an index key beside it
 DENSE, SPARSE = "dense", "sparse"
 
 
@@ -186,6 +192,11 @@ class AttnKind:
                                 # under the `jax.named_scope` `attn_<name>`
     chunk_groups: bool = False  # its paged half runs a mixed call's GROUP
                                 # of chunks (`gpt.MixedTables.count`)
+    scope: str = ""             # the layers' `jax.named_scope` is
+                                # `attn_<scope>` where that is not the name
+    probes: bool = False        # its halves take `probe=` / `probed=`: what
+                                # a check reads of the layer beside its
+                                # result (`models/sparse_attn.py`)
 
     def leaves(self, cfg):
         """{name as the paged half reads it: the pool's leaf}."""
@@ -199,7 +210,10 @@ def _kv_values(cfg):
 # window and full layers (rotary and the window belong to the window layers,
 # a full layer has neither, unless the model's `kind_values` say otherwise);
 # a latent layer (`models/mla.py`) rotates inside its half and caches one
-# entry a token for all heads
+# entry a token for all heads; a sparse layer (`models/sparse_attn.py`) is a
+# full layer whose queries attend the positions its indexer selects, and its
+# entry the full kind's with the index key a third leaf: the allocator's
+# blocks, the tables and the host's books are the full kind's as they are
 ATTN_KINDS = {
     FULL: AttnKind("", _gqa_shapes, _attn_half, _paged_attn_half, _kv_entry,
                    _kv_values, "full", chunk_groups=True),
@@ -209,14 +223,24 @@ ATTN_KINDS = {
                      lambda cfg: {LATENT_LEAF: (1, entry_width(cfg))},
                      lambda cfg: cfg.kv_lora_rank + cfg.qk_rope_head_dim,
                      "latent"),
+    SELECTED: AttnKind(
+        "", lambda cfg: {**_gqa_shapes(cfg), **index_shapes(cfg)},
+        sparse_attn_half, paged_sparse_half,
+        lambda cfg: {**_kv_entry(cfg), INDEX_LEAF: (1, 128)},
+        lambda cfg: _kv_values(cfg) + cfg.index_head_dim,
+        "full", scope="sparse", probes=True),
 }
 
 
 def pool_kinds(cfg):
     """The kinds of the model's POOL, the allocator's first: window and full
     layers keep a pool of two kinds (either may have no layer), latent
-    layers a pool of one."""
-    return (LATENT,) if LATENT in cfg.layer_types else (FULL, WINDOW)
+    layers a pool of one, sparse layers a pool of one (the full kind's
+    blocks with one more leaf)."""
+    for one in (LATENT, SELECTED):
+        if one in cfg.layer_types:
+            return (one,)
+    return (FULL, WINDOW)
 
 
 def layer_plan(cfg: ExaoneMoEConfig):
@@ -265,7 +289,9 @@ def cache_kinds(cfg: ExaoneMoEConfig, block_size: int):
             cfg.window_block if window else block_size,
             int(kcfg[layer_type].sliding_window or 0) if window else 0,
             leaves=tuple(attn.leaves(kcfg[layer_type]).values()),
-            entry_values=attn.values(kcfg[layer_type]))
+            entry_values=attn.values(kcfg[layer_type]),
+            index_topk=kcfg[layer_type].index_topk
+            if layer_type == SELECTED else 0)
     return tuple(kind(layer_type) for layer_type in pool_kinds(cfg))
 
 
@@ -465,10 +491,12 @@ def _mlp_fn(p, cfg, mlp_kind, counts=None, routing=None, stacks=None,
 # ----------------------------------------------------------------------
 
 
-def exaone_moe_forward(params, tokens, cfg: ExaoneMoEConfig, routing=None):
+def exaone_moe_forward(params, tokens, cfg: ExaoneMoEConfig, routing=None,
+                       probed=None):
     """tokens [B, T] -> logits [B, T, V]: dense masked attention, a Python
     loop over the layers. `routing`: a list that takes each sparse layer's
-    chosen experts [B*T, top_k]."""
+    chosen experts [B*T, top_k]; `probed`: a list that takes what each layer
+    of a kind that `probes` hands out (`AttnKind.probes`)."""
     B, T = tokens.shape
     positions = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32)[None], (B, T))
     kcfg = _kind_cfgs(cfg)
@@ -476,8 +504,11 @@ def exaone_moe_forward(params, tokens, cfg: ExaoneMoEConfig, routing=None):
     kinds = zip(cfg.layer_types, cfg.mlp_layer_types)
     for (p, experts), (attn_kind, mlp_kind) in zip(_layers(params, cfg),
                                                    kinds):
-        attn_out, _, _ = ATTN_KINDS[attn_kind].dense(
-            x, p, kcfg[attn_kind], positions, constrain=False)
+        kind = ATTN_KINDS[attn_kind]
+        attn_out, _, _ = kind.dense(
+            x, p, kcfg[attn_kind], positions, constrain=False,
+            **(dict(probed=probed)
+               if kind.probes and probed is not None else {}))
         x = _residual_mlp(x, attn_out, p, cfg, constrain=False,
                           mlp_fn=_mlp_fn(p, cfg, mlp_kind, routing=routing,
                                          **experts))
@@ -505,12 +536,17 @@ def make_exaone_moe_decode_model(cfg: ExaoneMoEConfig, params=None,
     paged programs take `block_tables` as the PAIR `(full tables [B, nb],
     ring tables [B, nbw])` and a pool of two kinds (module docstring).
 
-    `prefill_paged_fn` and `decode_paged_fn` take one keyword beside the
+    `prefill_paged_fn` and `decode_paged_fn` take two keywords beside the
     contract's arguments: `routing=True` adds a FOURTH result, the experts
     the call routed every row to — int32 `[sparse layers, B, C, top_k]`
     (`C` 1 for decode), ascending in a token. The scheduler never passes it
     (it takes three results); a check that holds a reference to the served
-    programs' own choices calls the served spec's functions with it."""
+    programs' own choices calls the served spec's functions with it.
+    `probe=<a chunk row's index, int32 scalar>` (a model whose attention
+    kind `probes`: `models/sparse_attn.py`) adds a LAST result, (index
+    scores float32, selection bool), each `[layers, rows, nb * block]`: of
+    that row of the chunk, then of every slot's row (a mixed call: 1 + S
+    rows; a prefill call 1; a decode call S)."""
     from deepspeed_tpu.inference.engine import DecodeModelSpec
     if params is None:
         params = exaone_moe_init_fn(cfg)(jax.random.PRNGKey(seed))
@@ -526,7 +562,8 @@ def make_exaone_moe_decode_model(cfg: ExaoneMoEConfig, params=None,
     def per_period(kind):
         return sum(1 for attn, _ in period if attn == kind)
 
-    def _layers_paged(params, x, pool, block_tables, positions, routing):
+    def _layers_paged(params, x, pool, block_tables, positions, routing,
+                      probe=None):
         # a pool of one kind takes its tables bare, of two as the pair
         if len(kinds) == 1:
             block_tables = (block_tables,)
@@ -559,7 +596,8 @@ def make_exaone_moe_decode_model(cfg: ExaoneMoEConfig, params=None,
         # its blocks as `table + i * N`
         flat = {k: v.reshape((-1,) + v.shape[2:]) for k, v in pool.items()}
 
-        def layer(x, flat, p, layer_kinds, kind_index, counts, **experts):
+        def layer(x, flat, p, layer_kinds, kind_index, counts, probed=None,
+                  **experts):
             # `experts`: `_mlp_fn`'s keywords (routing=, stacks=, expert_base=)
             attn_kind, mlp_kind = layer_kinds
             kind = kinds[attn_kind]
@@ -567,9 +605,14 @@ def make_exaone_moe_decode_model(cfg: ExaoneMoEConfig, params=None,
             # the kernels take the layer's offset; the scatter and the
             # gather of the other form take tables already offset
             where = dict(block_base=base) if in_place else {}
+            if probed is not None and kind.probes:
+                # the layer's groups of rows, in order, as ONE entry
+                mine = []
+                where["probe"] = (probe, mine)
+                probed.append(mine)
             table = tables[attn_kind] if in_place \
                 else offset_tables(tables[attn_kind], base)
-            with jax.named_scope(f"attn_{kind.name}"):
+            with jax.named_scope(f"attn_{kind.scope or kind.name}"):
                 attn_out, pool_l = kind.paged(
                     x, p, {n: flat[leaf]
                            for n, leaf in leaves[attn_kind].items()},
@@ -586,10 +629,11 @@ def make_exaone_moe_decode_model(cfg: ExaoneMoEConfig, params=None,
 
         counts = []
         chosen = [] if routing else None     # a layer's [B*C, top_k]
+        probes = [] if probe is not None else None   # a layer's groups
         seen = dict.fromkeys(kinds, 0)
         for p, layer_kinds in zip(params["prologue"], prologue):
             x, flat = layer(x, flat, p, layer_kinds, seen[layer_kinds[0]],
-                            counts, routing=chosen)
+                            counts, probes, routing=chosen)
             seen[layer_kinds[0]] += 1
         acc = sum(counts, no_counts)
 
@@ -604,6 +648,7 @@ def make_exaone_moe_decode_model(cfg: ExaoneMoEConfig, params=None,
                 trees, n = inputs
                 counts = []
                 routed = [] if routing else None
+                probed = [] if probe is not None else None
                 rank = dict.fromkeys(kinds, 0)
                 for i, layer_kinds in enumerate(period):
                     kind = layer_kinds[0]
@@ -612,37 +657,47 @@ def make_exaone_moe_decode_model(cfg: ExaoneMoEConfig, params=None,
                     experts = dict(stacks=stacks[i], expert_base=n * held) \
                         if layer_kinds[1] == SPARSE else {}
                     x, flat = layer(x, flat, trees[i], layer_kinds, index,
-                                    counts, routing=routed, **experts)
-                return (x, flat, acc + sum(counts, no_counts)), routed
+                                    counts, probed, routing=routed, **experts)
+                return (x, flat, acc + sum(counts, no_counts)), \
+                    (routed, probed)
 
-            (x, flat, acc), routed = jax.lax.scan(
+            (x, flat, acc), (routed, probed) = jax.lax.scan(
                 body, (x, flat, acc),
                 (scanned, jnp.arange(periods, dtype=jnp.int32)))
             if routing and routed:
                 # [periods, B*C, k] a sparse position -> model order
                 chosen += [r[n] for n in range(periods) for r in routed]
+            if probed:
+                probes += [jax.tree_util.tree_map(lambda a: a[n], groups)
+                           for n in range(periods) for groups in probed]
         pool = {k: v.reshape(pool[k].shape) for k, v in flat.items()}
+        out = (x, pool, acc)
         if routing:
             B, C = positions.shape
-            return x, pool, acc, jnp.stack(
-                [jnp.sort(e, axis=-1).reshape(B, C, -1) for e in chosen])
-        return x, pool, acc
+            out += (jnp.stack(
+                [jnp.sort(e, axis=-1).reshape(B, C, -1) for e in chosen]),)
+        if probes is not None:
+            # a layer's groups of rows one after another: [layers, rows, S]
+            out += (tuple(
+                jnp.stack([jnp.concatenate([g[i] for g in groups])
+                           for groups in probes]) for i in range(2)),)
+        return out
 
     def prefill_paged_fn(params, tokens, start_pos, last_idx, pool,
-                         block_tables, routing=False):
+                         block_tables, routing=False, probe=None):
         B, C = tokens.shape
         positions = start_pos[:, None] + jnp.arange(C, dtype=jnp.int32)[None]
         x = _embed(params, tokens, positions, cfg)
         x, pool, *counted = _layers_paged(params, x, pool, block_tables,
-                                          positions, routing)
+                                          positions, routing, probe)
         return (_lm_head(params, _last_rows(x, last_idx), cfg)[:, 0], pool,
                 *counted)
 
     def decode_paged_fn(params, token, pos, pool, block_tables,
-                        routing=False):
+                        routing=False, probe=None):
         x = _embed(params, token[:, None], pos[:, None], cfg)
         x, pool, *counted = _layers_paged(params, x, pool, block_tables,
-                                          pos[:, None], routing)
+                                          pos[:, None], routing, probe)
         return (_lm_head(params, x, cfg)[:, 0], pool, *counted)
 
     def init_paged_pool(num_blocks, block_size, dtype=jnp.bfloat16,
@@ -651,8 +706,9 @@ def make_exaone_moe_decode_model(cfg: ExaoneMoEConfig, params=None,
             raise ValueError(
                 f"model spec '{name}': the int8 pool is not built for a pool "
                 f"of kinds {'/'.join(k.name for k in kinds.values())} (a "
-                f"window kind's rings and a latent kind's entries have no "
-                f"scale leaves, their walks no dequantizing twin)")
+                f"window kind's rings, a latent kind's entries and a sparse "
+                f"layer's index keys have no scale leaves, their walks no "
+                f"dequantizing twin)")
         if WINDOW in kinds and window_blocks is None:
             raise ValueError(
                 f"model spec '{name}' keeps a pool of two kinds: "

@@ -156,7 +156,9 @@ class AttentionProgram:
         and for a latent pool the latent rank (q then `[q~ | q_r | 0]`, the
         result the un-absorbed values) -> [B, C, H * hd] ([B, C, H * rank];
         H * the values' width where that is their own); a K/V walk also
-        takes `sink=` [H], given only at a site with a sink logit;
+        takes `sink=` [H], given only at a site with a sink logit, and
+        `selected=`, given only by a sparse layer (`models/sparse_attn.py`:
+        the positions each row attends, in the walk's own layout);
       * contiguous decode: `(q, cache_k, cache_v, pos, *, sm_scale)` with q
         [B, H, hd] and the head-major cache -> [B, H, hd].
 
@@ -441,9 +443,10 @@ KV_POOL_WRITE_SCATTER = "xla_scatter"
 def kv_pool_writer(pool) -> str:
     """Name the writer for `pool` (the `[L, N, Hkv, block, hd]` pytree of
     `init_paged_kv_pool` — `k`/`v`, each leaf with its own width, and `kr`
-    where the keys' half tile is kept apart, `kv_pool.py::kv_leaf_shapes` —
-    or a latent kind's one leaf `{"ckv": [L, N, 1, block, width]}`) from
-    what can be seen at trace time: the in-place
+    where the keys' half tile is kept apart, `kv_pool.py::kv_leaf_shapes`,
+    or `ik` where a sparse layer's indexer keeps its key
+    (`models/sparse_attn.py`) — or a latent kind's one leaf `{"ckv": [L, N,
+    1, block, width]}`) from what can be seen at trace time: the in-place
     kernel for a float pool made of whole native tiles, on a TPU, in a
     single-device program (a bare Mosaic call cannot be partitioned — the
     same limit as `dstpu_paged_decode`); the scatter everywhere else: the
@@ -451,7 +454,8 @@ def kv_pool_writer(pool) -> str:
     lane tile, a multi-device mesh."""
     from deepspeed_tpu.ops.pallas.kv_pool import pool_in_place_supported
     from deepspeed_tpu.platform.device import pallas_interpret
-    if (set(pool) in ({"k", "v"}, {"k", "kr", "v"}, {"ckv"})
+    if (set(pool) in ({"k", "v"}, {"k", "kr", "v"}, {"k", "v", "ik"},
+                      {"ckv"})
             and not pallas_interpret() and not active_mesh_axes()
             and all(pool_in_place_supported(x.dtype, x.shape[-2], x.shape[-1])
                     for x in pool.values())):
@@ -480,11 +484,14 @@ def _walk_args(q, pool_l, sm_scale, sink):
 
 
 def _run_paged_decode(q, pool_l, block_tables, start, *, sm_scale=None,
-                      window=None, work=None, rank=None, sink=None):
+                      window=None, work=None, rank=None, sink=None,
+                      selected=None):
     from deepspeed_tpu.ops.pallas.decode_attention import \
         paged_decode_attention
     B = q.shape[0]
     q, sm_scale, more = _walk_args(q, pool_l, sm_scale, sink)
+    if selected is not None:    # a sparse layer's selection, as a bias
+        more["selected"] = selected
     return paged_decode_attention(
         q[:, 0], pool_l["k"], pool_l["v"], block_tables, start,
         sm_scale=sm_scale, work=work, window=window,
@@ -504,10 +511,12 @@ def _run_paged_decode_quant(q, pool_l, block_tables, start, *, sm_scale=None,
 
 def _run_paged_prefill(q, pool_l, block_tables, start, *, sm_scale=None,
                        window=None, work=None, rank=None, sink=None,
-                       block_length=1):
+                       block_length=1, selected=None):
     from deepspeed_tpu.ops.pallas.prefill_attention import \
         paged_prefill_attention
     q, sm_scale, more = _walk_args(q, pool_l, sm_scale, sink)
+    if selected is not None:    # a sparse layer's selection, as a mask
+        more["selected"] = selected
     if block_length > 1:    # a diffusion generator's block-causal mask
         more["block_length"] = block_length
     return paged_prefill_attention(q, pool_l["k"], pool_l["v"], block_tables,

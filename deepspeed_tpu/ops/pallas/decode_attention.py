@@ -132,7 +132,7 @@ def _softmax_result(acc_ref, l_ref):
 
 
 def _online_softmax_tile(q, k, v, pos, j, acc_ref, m_ref, l_ref, *,
-                         sm_scale, block_m, window=None):
+                         sm_scale, block_m, window=None, bias=None):
     """One streamed KV tile's online-softmax update — the SINGLE definition
     of the decode-attention math, shared by the contiguous, paged, and
     quantized-paged kernels (the dequantizing kernel hands in already-
@@ -144,7 +144,10 @@ def _online_softmax_tile(q, k, v, pos, j, acc_ref, m_ref, l_ref, *,
     `_online_softmax_update`, carried across the (sequential, innermost)
     block axis. `window` (static; None = none): keys more than `window - 1`
     positions behind `pos` are masked too — the mask inside the first live
-    block of a windowed walk.
+    block of a windowed walk. `bias` [1, block_m] float32 (None = none): added
+    to every row's scores — 0 at the positions a sparse layer's indexer
+    selected for this slot and NEG_INF at the others
+    (`sparse_index.py::sparse_select`).
 
     native-dtype dots (fp32 accumulate via preferred_element_type):
     pre-casting K/V blocks to fp32 doubles the VMEM working set and VPU
@@ -156,6 +159,8 @@ def _online_softmax_tile(q, k, v, pos, j, acc_ref, m_ref, l_ref, *,
     if window is not None:
         seen = jnp.logical_and(seen, k_pos > pos - window)
     s = jnp.where(seen, s, NEG_INF)
+    if bias is not None:
+        s = s + bias
     _online_softmax_update(s, v, q.dtype, acc_ref, m_ref, l_ref)
 
 
@@ -386,16 +391,19 @@ def _vmem_tile_bytes(rows, cols, dtype):
 
 def _paged_walk_kernel(cnt_ref, slot_ref, blk_ref, pos_ref, bt_ref, q_ref,
                        *refs, load_head, sm_scale, block_m, last_block,
-                       window=None, sink=False):
+                       window=None, sink=False, selected=False):
     # grid (head groups, work items); a step holds every pool leaf's
     # [1, heads, block_m, ...] tile of ONE live (slot, logical block) pair,
     # resolved to its physical block by the index map (so bt_ref is unused
     # here). q_ref / o_ref: [1, heads, G, hd] of the pair's slot; scratch acc
     # [heads, G, hd] fp32, m/l [heads, G, _LANES] fp32 carry the online
     # softmax over a slot's pairs, which are consecutive and ascending.
-    # `sink`: one more input after the pool's, [heads, G, _LANES] float32.
+    # `sink`: one more input after the pool's, [heads, G, _LANES] float32;
+    # `selected`: one more after that, [1, 1, 1, block_m] float32, the pair's
+    # bias (`_online_softmax_tile`).
     del bt_ref
     *pool_refs, o_ref, acc_ref, m_ref, l_ref = refs
+    bias_ref = pool_refs.pop() if selected else None
     sink_ref = pool_refs.pop() if sink else None
     i = pl.program_id(1)
     b = slot_ref[i]
@@ -418,7 +426,9 @@ def _paged_walk_kernel(cnt_ref, slot_ref, blk_ref, pos_ref, bt_ref, q_ref,
             _online_softmax_tile(q_ref[0, h], k, v, pos, j, acc_ref.at[h],
                                  m_ref.at[h], l_ref.at[h],
                                  sm_scale=sm_scale, block_m=block_m,
-                                 window=window)
+                                 window=window,
+                                 **({} if bias_ref is None
+                                    else dict(bias=bias_ref[0, 0])))
 
     @pl.when(j == jnp.minimum(pos // block_m, last_block))
     def _finish():
@@ -427,7 +437,7 @@ def _paged_walk_kernel(cnt_ref, slot_ref, blk_ref, pos_ref, bt_ref, q_ref,
 
 def _paged_walk(load_head, q, leaves, block_tables, pos, work, sm_scale,
                 interpret, window=None, out_dim=None,
-                name="dstpu_paged_decode", sink=None):
+                name="dstpu_paged_decode", sink=None, selected=None):
     """THE walk over a paged pool, shared by the float and the int8 kernel:
     a 1-D list of the live (slot, logical block) pairs (`paged_decode_work`),
     its length the grid's DYNAMIC bound, so a dead slot and a block past a
@@ -444,7 +454,10 @@ def _paged_walk(load_head, q, leaves, block_tables, pos, work, sm_scale,
     half the first leaf's heads (the keys' half tile, `kv_pool.py::
     kv_leaf_shapes`): its step tile is the heads its step's KV heads share.
     `sink` [H] float32: a learned logit a head, the INITIAL state of every
-    row's online softmax (`_start_softmax`)."""
+    row's online softmax (`_start_softmax`). `selected` [nb, B, 1, block]
+    float32: a sparse layer's selection as a bias a (block, slot), 0 at the
+    positions the slot's query attends and NEG_INF at the others; the walk
+    still visits every live pair (the call is then named `<name>_sparse`)."""
     if interpret is None:
         interpret = pallas_interpret()
     B, H, hd = q.shape
@@ -478,12 +491,20 @@ def _paged_walk(load_head, q, leaves, block_tables, pos, work, sm_scale,
             sink.astype(jnp.float32).reshape(Hkv, G, 1), (Hkv, G, _LANES)),)
         sunk_specs = [pl.BlockSpec((heads, G, _LANES),
                                    lambda g, i, *_: (g, 0, 0))]
+    static = dict(sink=True) if sunk else {}
+    if selected is not None:
+        static["selected"] = True
+        name += "_sparse"
+        sunk += (selected,)
+        sunk_specs += [pl.BlockSpec(
+            (1, 1, 1, block_m),
+            lambda g, i, cnt_ref, slot_ref, blk_ref, pos_ref, bt_ref:
+            (blk_ref[i], slot_ref[i], 0, 0))]
 
     out = pl.pallas_call(
         functools.partial(_paged_walk_kernel, load_head=load_head,
                           sm_scale=sm_scale, block_m=block_m,
-                          last_block=nb - 1, window=window,
-                          **(dict(sink=True) if sunk else {})),
+                          last_block=nb - 1, window=window, **static),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=5,
             grid=(Hkv // heads, jnp.maximum(work.count[0], 1)),
@@ -521,7 +542,7 @@ def _load_split_head(pool_refs, h, dtype):
 
 def paged_decode_attention(q, k_pool, v_pool, block_tables, pos, sm_scale=None,
                            interpret=None, work=None, window=None,
-                           kr_pool=None, sink=None):
+                           kr_pool=None, sink=None, selected=None):
     """Decode attention over a PAGED KV pool (vLLM's PagedAttention layout).
 
     q: [B, H, hd]; k_pool/v_pool: [N, Hkv, block, hd] physical blocks shared
@@ -559,7 +580,7 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, pos, sm_scale=None,
         else (_load_split_head, (k_pool, kr_pool, v_pool))
     return _paged_walk(load, q, leaves, block_tables, pos, work, sm_scale,
                        interpret, window or None, out_dim=v_pool.shape[-1],
-                       sink=sink)
+                       sink=sink, selected=selected)
 
 
 def _dequant_tile(q, scale, dtype):

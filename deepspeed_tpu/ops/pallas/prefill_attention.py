@@ -104,7 +104,7 @@ def _tiles(C, block, Hkv, G, hd, itemsize, share=1):
 
 def _prefill_kernel(start_ref, bt_ref, q_ref, *refs, sm_scale, G, block, tk,
                     last_block, window=None, keys=1, values=True,
-                    sink=False, block_length=1):
+                    sink=False, block_length=1, selected=False):
     # grid (B, Hkv // heads, C // tq, live blocks); q_ref / o_ref:
     # [1, tq, heads*G*hd], the step's query heads side by side in the lanes;
     # the `keys` key leaves' refs and v_ref: [1, heads, block, hd], ONE
@@ -122,7 +122,11 @@ def _prefill_kernel(start_ref, bt_ref, q_ref, *refs, sm_scale, G, block, tk,
     # `block_length` B > 1 (generation by diffusion over blocks): a row's
     # frontier is the END of its block of B positions, `pos | (B - 1)`; the
     # tile's first position is a multiple of B, so that is its row index's.
+    # `selected`: one more input, [1, 1, tq, block] int8, a sparse layer's
+    # selection for this (block, query tile): 1 at the positions a row
+    # attends. Every live tile is then masked, below the diagonal too.
     *k_refs, o_ref, acc_ref, m_ref, l_ref = refs
+    sel_ref = k_refs.pop() if selected else None
     sink_ref = k_refs.pop() if sink else None
     v_ref = k_refs.pop() if values else None
     assert len(k_refs) == keys
@@ -161,6 +165,11 @@ def _prefill_kernel(start_ref, bt_ref, q_ref, *refs, sm_scale, G, block, tk,
                 ahead = ahead - (block_length - 1 - (
                     jax.lax.broadcasted_iota(jnp.int32, (tq, tk), 0)
                     & (block_length - 1)))
+        if sel_ref is not None:
+            # the same rows for every head: made once a tile of keys
+            chosen = sel_ref[0, 0, :, rows].astype(jnp.float32) > 0.5
+            if masked:
+                chosen = jnp.logical_and(chosen, ahead <= 0)
 
         # unrolled: the heads' updates are independent, and the compiler
         # overlaps one's matmuls with another's softmax (a `fori_loop` over
@@ -175,7 +184,9 @@ def _prefill_kernel(start_ref, bt_ref, q_ref, *refs, sm_scale, G, block, tk,
                     ref[0, (i // G) * ref.shape[1] // heads, rows, :]
                     for ref in k_refs[1:])
             s = _scores(q, k) * sm_scale
-            if masked:
+            if sel_ref is not None:
+                s = jnp.where(chosen, s, NEG_INF)
+            elif masked:
                 seen = ahead <= 0
                 if window is not None:
                     seen = jnp.logical_and(seen, ahead > -window)
@@ -240,7 +251,8 @@ def paged_prefill_walk_counts(start, chunk, block, table_blocks, window=None):
 
 def paged_prefill_attention(q, k_pool, v_pool, block_tables, start,
                             sm_scale=None, interpret=None, window=None,
-                            kr_pool=None, sink=None, block_length=1):
+                            kr_pool=None, sink=None, block_length=1,
+                            selected=None):
     """Causal attention of a prefill chunk over a PAGED KV pool, the live
     blocks only.
 
@@ -272,7 +284,12 @@ def paged_prefill_attention(q, k_pool, v_pool, block_tables, start,
     of two that divides every `start`; no window): the block-causal mask of
     a diffusion generator — row i sees the keys up to the end of its block
     of B positions, `i | (B - 1)`, all of them in the pool already; 1 is
-    the causal mask, the kernel's text as it was."""
+    the causal mask, the kernel's text as it was. `selected` [B, nb, C,
+    block] int8 (no window): a sparse layer's selection, block-major as
+    `sparse_index.py::sparse_select` leaves it — row c attends the
+    positions s <= start + c with a 1 at [b, s // block, c, s % block] and
+    no other; the walk still visits every block under the frontier (the
+    call is then named `dstpu_paged_prefill_sparse`)."""
     if interpret is None:
         interpret = pallas_interpret()
     B, C, H, hd = q.shape
@@ -327,6 +344,18 @@ def paged_prefill_attention(q, k_pool, v_pool, block_tables, start,
                                  (H, 1, _LANES)),)
         sunk_specs = [pl.BlockSpec((heads * G, 1, _LANES),
                                    lambda b, g, qi, j, *_: (g, 0, 0))]
+    name = "dstpu_paged_prefill"
+    if selected is not None:
+        assert window is None and block_length == 1
+        static["selected"] = True
+        name += "_sparse"
+        sunk += (selected,)
+
+        def sel_index(b, g, qi, j, start_ref, bt_ref):
+            frontier = jnp.minimum(
+                (start_ref[b] + (qi + 1) * tq - 1) // block, nb - 1)
+            return (b, jnp.minimum(j, frontier), qi, 0)
+        sunk_specs += [pl.BlockSpec((1, 1, tq, block), sel_index)]
     return pl.pallas_call(
         functools.partial(_prefill_kernel, sm_scale=sm_scale, G=G,
                           block=block, tk=tk, last_block=nb - 1,
@@ -347,6 +376,6 @@ def paged_prefill_attention(q, k_pool, v_pool, block_tables, start,
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=_VMEM_LIMIT_BYTES),
         interpret=interpret,
-        name="dstpu_paged_prefill",
+        name=name,
     )(start, block_tables.astype(jnp.int32), q.reshape(B, C, H * hd),
       *key_pools, v_pool, *sunk)

@@ -63,6 +63,10 @@ SCOPES = {
                     "the attention half of a WINDOW layer (two-kind pools)"),
     "attn_latent": ("model step programs",
                     "the attention half of a LATENT layer (MLA)"),
+    "attn_sparse": ("sparse attention indexer",
+                    "the attention half of a SPARSE layer: the full layer's "
+                    "half with the indexer's projections, score walk and "
+                    "selection between the write and the walk"),
     "ssm": ("recurrent state",
             "a Mamba-2 half: norm, in-projection, convolution, scan or "
             "update, gate norm, out-projection, residual"),
@@ -104,6 +108,16 @@ SCOPES = {
                      "the gather path's read of a row's whole table"),
     "paged_decode_work": ("Pallas kernels",
                           "the decode walk's work list, once a token"),
+    "index_proj": ("sparse attention indexer",
+                   "the indexer's query heads, its one key (LayerNorm, "
+                   "rope) and its head weights"),
+    "index_scores": ("sparse attention indexer",
+                     "the score walk over a sequence's cached index keys: "
+                     "`dstpu_sparse_index_scores[_decode]`, or the gather "
+                     "path's dense scores"),
+    "select": ("sparse attention indexer",
+               "the exact top-k of a query's scores: `dstpu_sparse_select`, "
+               "or its `jax.numpy` twin"),
     "mla/q_proj": ("model step programs", "MLA: q down, norm, up, rope"),
     "mla/kv_down": ("model step programs", "MLA: the latent, norm, rope"),
     "mla/expand": ("model step programs",

@@ -137,8 +137,20 @@ StepRecord = collections.namedtuple("StepRecord", [
                         # group, else 1) ...
     "padded_chunks",    # ... and the absent chunks in them (rows of padding
                         # through the matmuls): groups * G - fused_chunks
+    "index_scored_positions",   # a pool whose entry has an index key (a
+                        # learned sparse-attention indexer,
+                        # `models/sparse_attn.py`): the (query, cached
+                        # position) pairs this step's chunks and decode
+                        # tokens SCORED, a layer: t + 1 a query at t ...
+    "selected_positions",   # ... of them, the pairs the selection kept:
+                        # min(t + 1, topk) a query — the model's work ...
+    "sparse_walk_positions",    # ... and the pairs the attention walks
+                        # READ to attend them: every position under the
+                        # frontier in the form kept (the selection rides the
+                        # dense walks as a mask), so read / selected is the
+                        # form's waste; all three 0 with no indexer
 ], defaults=(0, 0, 0, 0, 0, 0, "", 0, (), 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0,
-             0, 0, 0, 0, 0, 0, 0))
+             0, 0, 0, 0, 0, 0, 0, 0, 0, 0))
 
 RequestRecord = collections.namedtuple("RequestRecord", [
     "uid", "t_submit", "t_admit",

@@ -834,6 +834,115 @@ def test_grouped_mixed_call_on_two_kinds_matches_the_calls_of_its_own():
     close(part[:G - 1], jnp.stack(firsts[:G - 1]))
 
 
+def test_sparse_index_kernels_compiled_at_the_served_shapes():
+    """`ops/pallas/sparse_index.py` at Keye-VL-2.0's served shapes (16 index
+    heads of 64, blocks of 512, a table of 132, chunks of 1024, `topk` 2048):
+    the score walks against their `jax.numpy` twin on the gathered keys, the
+    selection EXACTLY its twin's set (planted ties, a row of zeros of both
+    signs), and both masked walks against the dense attend under the same
+    selection."""
+    from deepspeed_tpu.models.gpt import GPTConfig
+    from deepspeed_tpu.models.sparse_attn import _attend_selected
+    from deepspeed_tpu.ops.pallas import sparse_index as si
+    from deepspeed_tpu.ops.pallas.decode_attention import \
+        paged_decode_attention
+    from deepspeed_tpu.ops.pallas.prefill_attention import \
+        paged_prefill_attention
+    rng = np.random.default_rng(60)
+    C, Hi, d, block, nb, M, topk = 1024, 16, 64, 512, 132, 200, 2048
+    H, Hkv, hd, S = 32, 4, 128, 16
+    keys = jnp.asarray(rng.normal(size=(M, 1, block, 128)),
+                       jnp.bfloat16).at[..., d:].set(0)
+    k_pool, v_pool = (jnp.asarray(rng.normal(size=(M, Hkv, block, hd)),
+                                  jnp.bfloat16) for _ in range(2))
+    live = 41                       # a chunk from position 19,700 to 20,723
+    table = np.zeros((1, nb), np.int32)
+    table[0, :live] = rng.permutation(np.arange(1, M))[:live]
+    start = jnp.asarray([19700], jnp.int32)
+    qi = jnp.asarray(rng.normal(size=(1, C, Hi, d)), jnp.bfloat16)
+    w = jnp.asarray(rng.normal(size=(1, C, Hi)), jnp.float32)
+    scores = jax.jit(lambda *a: si.paged_index_scores(*a, interpret=False))(
+        qi, w, keys, jnp.asarray(table), start)
+    ctx = keys[table[0, :live], 0].reshape(1, live * block, 128)[..., :d]
+    want = si.index_scores(qi, w, ctx)
+    got = np.asarray(scores)[:, :live].transpose(0, 2, 1, 3).reshape(
+        1, C, live * block)
+    # past a row's own position: whatever the memory held
+    limit = start[:, None] + jnp.arange(C)[None] + 1
+    seen = np.arange(live * block)[None, None] < np.asarray(limit)[..., None]
+    np.testing.assert_allclose(np.where(seen, got, 0),
+                               np.where(seen, np.asarray(want), 0),
+                               rtol=2e-2, atol=2e-2)
+    # the selection of the kernel's OWN scores, ties planted
+    planted = scores.at[0, 3, :, 7].set(0.25).at[0, 9, :, 100].set(0.25) \
+        .at[0, :live, 5, :].set(0.0).at[0, :live, 5, ::2].set(-0.0)
+    chosen = jax.jit(lambda a, b: si.sparse_select(
+        a, b, topk, interpret=False))(planted, limit)
+    flat = np.asarray(planted)[:, :live].transpose(0, 2, 1, 3).reshape(
+        1, C, live * block)
+    twin = np.asarray(si.select_topk(jnp.asarray(np.where(seen, flat, 0)),
+                                     limit, topk))
+    mine = np.asarray(chosen)[:, :live].transpose(0, 2, 1, 3).reshape(
+        1, C, live * block) > 0
+    differ = ((mine & seen) != twin)[0].sum(-1)
+    assert not differ.any(), (np.flatnonzero(differ)[:12], differ[differ > 0][:12],
+                              (mine & seen)[0].sum(-1)[differ > 0][:12])
+    assert (twin.sum(-1) == topk).all()
+    # the chunk walk under the selection
+    cfg = GPTConfig(n_head=H, n_kv_head=Hkv, d_model=H * hd)
+    q = jnp.asarray(rng.normal(size=(1, C, H, hd)), jnp.bfloat16)
+    out = jax.jit(lambda *a: paged_prefill_attention(
+        *a, selected=chosen, interpret=False))(
+        q, k_pool, v_pool, jnp.asarray(table), start)
+    gather = lambda pool: jnp.moveaxis(pool[table[0, :live]], 1, 0).reshape(
+        1, Hkv, live * block, hd)
+    ref = _attend_selected(q, gather(k_pool), gather(v_pool),
+                           jnp.asarray(twin), cfg)
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(ref, np.float32), atol=3e-2,
+                               rtol=3e-2)
+    # the slots' rows: scores over the work list, the bias, the decode walk
+    pos = np.asarray(rng.integers(3000, live * block, S), np.int32)
+    pos[5] = 0
+    tables = np.zeros((S, nb), np.int32)
+    for b in range(S):
+        n = pos[b] // block + 1
+        tables[b, :n] = rng.permutation(np.arange(1, M))[:n]
+    tables[5] = 0                   # a dead slot
+    qd = jnp.asarray(rng.normal(size=(S, Hi, d)), jnp.bfloat16)
+    wd = jnp.asarray(rng.normal(size=(S, Hi)), jnp.float32)
+    sd = jax.jit(lambda *a: si.paged_index_scores_decode(
+        *a, interpret=False))(qd, wd, keys, jnp.asarray(tables),
+                              jnp.asarray(pos))
+    bias = jax.jit(lambda a, b: si.sparse_select(
+        a, b, topk, bias=True, interpret=False))(
+        jnp.swapaxes(sd[:, :, 0], 0, 1)[None], jnp.asarray(pos + 1)[None])
+    qq = jnp.asarray(rng.normal(size=(S, H, hd)), jnp.bfloat16)
+    out = jax.jit(lambda *a: paged_decode_attention(
+        *a, selected=bias[0][:, :, None], interpret=False))(
+        qq, k_pool, v_pool, jnp.asarray(tables), jnp.asarray(pos))
+    for b in (0, 7, 15):
+        n = pos[b] // block + 1
+        ctx = keys[tables[b, :n], 0].reshape(1, n * block, 128)[..., :d]
+        want = np.asarray(si.index_scores(qd[b][None, None], wd[b][None, None],
+                                          ctx))[0, 0]
+        got = np.asarray(sd)[b, :n, 0].reshape(-1)
+        np.testing.assert_allclose(got[:pos[b] + 1], want[:pos[b] + 1],
+                                   rtol=2e-2, atol=2e-2)
+        twin = np.asarray(si.select_topk(jnp.asarray(got)[None],
+                                         jnp.asarray([pos[b] + 1]), topk))
+        mine = np.asarray(bias)[0, :n, b].reshape(-1) == 0
+        np.testing.assert_array_equal(mine, twin[0])
+        gather = lambda pool: jnp.moveaxis(pool[tables[b, :n]], 1, 0).reshape(
+            1, Hkv, n * block, hd)
+        ref = _attend_selected(qq[b][None, None], gather(k_pool),
+                               gather(v_pool), jnp.asarray(twin)[None], cfg)
+        np.testing.assert_allclose(np.asarray(out[b], np.float32).ravel(),
+                                   np.asarray(ref, np.float32).ravel(),
+                                   atol=3e-2, rtol=3e-2)
+    assert not np.asarray(out[5]).any()
+
+
 def test_quant_int4_kernels_refuse_on_tpu():
     """Recorded state, not a TODO: the packed-nibble kernels need stride-2
     lane indexing, which the Pallas TPU lowering refuses; the wrappers say
