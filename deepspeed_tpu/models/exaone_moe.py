@@ -71,6 +71,7 @@ from deepspeed_tpu.models.sparse_attn import (INDEX_LEAF, index_shapes,
                                               sparse_attn_half)
 from deepspeed_tpu.ops import attention_dispatch as attn_dispatch
 from deepspeed_tpu.ops.pallas.kv_pool import kv_leaf_shapes
+from deepspeed_tpu.ops.pallas.sparse_index import SELECT_COUNTERS
 from deepspeed_tpu.parallel.moe import (HELD_ROUTED_COUNTERS, routed_experts,
                                         topk_routing)
 
@@ -197,6 +198,10 @@ class AttnKind:
     probes: bool = False        # its halves take `probe=` / `probed=`: what
                                 # a check reads of the layer beside its
                                 # result (`models/sparse_attn.py`)
+    counters: tuple = ()        # the per-call counters its paged half
+                                # books: it takes `counted=`, a list, and
+                                # appends an int32 `[len(counters)]` a group
+                                # of rows its kernels ran for
 
     def leaves(self, cfg):
         """{name as the paged half reads it: the pool's leaf}."""
@@ -228,7 +233,7 @@ ATTN_KINDS = {
         sparse_attn_half, paged_sparse_half,
         lambda cfg: {**_kv_entry(cfg), INDEX_LEAF: (1, 128)},
         lambda cfg: _kv_values(cfg) + cfg.index_head_dim,
-        "full", scope="sparse", probes=True),
+        "full", scope="sparse", probes=True, counters=SELECT_COUNTERS),
 }
 
 
@@ -557,7 +562,20 @@ def make_exaone_moe_decode_model(cfg: ExaoneMoEConfig, params=None,
     leaves = {name: kind.leaves(kcfg[name]) for name, kind in kinds.items()}
     held = cfg.experts_held[1]
     no_counts = jnp.zeros((len(HELD_ROUTED_COUNTERS),), jnp.int32)
+    # the counters a kind's kernels book (ONE kind of a pool may), after the
+    # routed experts'
+    attn_counters = tuple(c for kind in kinds.values() for c in kind.counters)
+    assert sum(1 for kind in kinds.values() if kind.counters) <= 1
     pool_writers, attn_programs = {}, {}
+
+    def total(counts, attn_counts):
+        """A group of layers' counters as one vector, in `step_counters`'
+        order."""
+        acc = sum(counts, no_counts)
+        if not attn_counters:
+            return acc
+        return jnp.concatenate([acc, sum(
+            attn_counts, jnp.zeros((len(attn_counters),), jnp.int32))])
 
     def per_period(kind):
         return sum(1 for attn, _ in period if attn == kind)
@@ -596,8 +614,8 @@ def make_exaone_moe_decode_model(cfg: ExaoneMoEConfig, params=None,
         # its blocks as `table + i * N`
         flat = {k: v.reshape((-1,) + v.shape[2:]) for k, v in pool.items()}
 
-        def layer(x, flat, p, layer_kinds, kind_index, counts, probed=None,
-                  **experts):
+        def layer(x, flat, p, layer_kinds, kind_index, counts, attn_counts,
+                  probed=None, **experts):
             # `experts`: `_mlp_fn`'s keywords (routing=, stacks=, expert_base=)
             attn_kind, mlp_kind = layer_kinds
             kind = kinds[attn_kind]
@@ -605,6 +623,8 @@ def make_exaone_moe_decode_model(cfg: ExaoneMoEConfig, params=None,
             # the kernels take the layer's offset; the scatter and the
             # gather of the other form take tables already offset
             where = dict(block_base=base) if in_place else {}
+            if kind.counters:
+                where["counted"] = attn_counts
             if probed is not None and kind.probes:
                 # the layer's groups of rows, in order, as ONE entry
                 mine = []
@@ -627,15 +647,15 @@ def make_exaone_moe_decode_model(cfg: ExaoneMoEConfig, params=None,
                                                  **experts))
             return x, flat
 
-        counts = []
+        counts, attn_counts = [], []
         chosen = [] if routing else None     # a layer's [B*C, top_k]
         probes = [] if probe is not None else None   # a layer's groups
         seen = dict.fromkeys(kinds, 0)
         for p, layer_kinds in zip(params["prologue"], prologue):
             x, flat = layer(x, flat, p, layer_kinds, seen[layer_kinds[0]],
-                            counts, probes, routing=chosen)
+                            counts, attn_counts, probes, routing=chosen)
             seen[layer_kinds[0]] += 1
-        acc = sum(counts, no_counts)
+        acc = total(counts, attn_counts)
 
         if periods:
             # the scan slices the small leaves a period; the expert stacks
@@ -646,7 +666,7 @@ def make_exaone_moe_decode_model(cfg: ExaoneMoEConfig, params=None,
             def body(carry, inputs):
                 x, flat, acc = carry
                 trees, n = inputs
-                counts = []
+                counts, attn_counts = [], []
                 routed = [] if routing else None
                 probed = [] if probe is not None else None
                 rank = dict.fromkeys(kinds, 0)
@@ -657,8 +677,9 @@ def make_exaone_moe_decode_model(cfg: ExaoneMoEConfig, params=None,
                     experts = dict(stacks=stacks[i], expert_base=n * held) \
                         if layer_kinds[1] == SPARSE else {}
                     x, flat = layer(x, flat, trees[i], layer_kinds, index,
-                                    counts, probed, routing=routed, **experts)
-                return (x, flat, acc + sum(counts, no_counts)), \
+                                    counts, attn_counts, probed,
+                                    routing=routed, **experts)
+                return (x, flat, acc + total(counts, attn_counts)), \
                     (routed, probed)
 
             (x, flat, acc), (routed, probed) = jax.lax.scan(
@@ -741,6 +762,7 @@ def make_exaone_moe_decode_model(cfg: ExaoneMoEConfig, params=None,
                                cfg, block_size),
                            kv_pool_writers=pool_writers,
                            paged_attn_programs=attn_programs,
-                           step_counters=HELD_ROUTED_COUNTERS,
+                           step_counters=HELD_ROUTED_COUNTERS
+                           + attn_counters,
                            cache_fingerprint=fingerprint
                            or exaone_moe_cache_identity(cfg, name))

@@ -115,7 +115,8 @@ def sparse_attn_half(x, p, cfg, positions, attn_fn=None, constrain=True,
 
 def paged_sparse_half(x, p, pool_l, positions, block_tables, cfg,
                       local_flag=None, phase=None, block_base=None,
-                      decode_work=None, attn_programs=None, probe=None):
+                      decode_work=None, attn_programs=None, probe=None,
+                      counted=None):
     """The attention half against one sparse layer's paged pool
     (`gpt._paged_attn_half`'s signature and result).
 
@@ -130,7 +131,9 @@ def paged_sparse_half(x, p, pool_l, positions, block_tables, cfg,
     `probe`: None, or (a chunk row's index, traced int32 scalar; a list):
     the list takes, a group of rows, (index scores, selection) `[rows, nb *
     block]` of that row of a chunk / of every slot's row — what a check holds
-    against the reference's (positions past a row's own are garbage)."""
+    against the reference's (positions past a row's own are garbage).
+    `counted`: None, or a list that takes, a group of rows the selection
+    KERNEL ran for, its int32 `[3]` (`sparse_index.SELECT_COUNTERS`)."""
     del local_flag, phase
     T = x.shape[1]
     mixed = isinstance(block_tables, MixedTables)
@@ -144,7 +147,7 @@ def paged_sparse_half(x, p, pool_l, positions, block_tables, cfg,
     def group(rows, pool_l, positions, tables, site, work=None, record=None):
         return _write_attend(*(rows(a) for a in (q, k, v, qi, ki, w)),
                              pool_l, positions, tables, cfg, site, block_base,
-                             work, attn_programs, record, probe)
+                             work, attn_programs, record, probe, counted)
 
     if mixed:
         assert block_tables.chunk.shape[0] == 1     # `chunk_groups` False
@@ -172,7 +175,8 @@ def paged_sparse_half(x, p, pool_l, positions, block_tables, cfg,
 
 
 def _write_attend(q, k, v, qi, ki, w, pool_l, positions, block_tables, cfg,
-                  phase, block_base, work, attn_programs, record, probe):
+                  phase, block_base, work, attn_programs, record, probe,
+                  counted):
     """Rows that share a dispatch site, as `positions` [B, C] lays them out
     (a chunk's [1, C]; the slots' [S, 1]): write, score, select, attend ->
     (attn [B, C, H * hd], pool_l)."""
@@ -221,12 +225,15 @@ def _write_attend(q, k, v, qi, ki, w, pool_l, positions, block_tables, cfg,
                     qi, w, pool_l[INDEX_LEAF], block_tables, positions[:, 0])
         with jax.named_scope("select"):
             if decode:
-                chosen = sparse_index.sparse_select(
+                chosen, sweeps = sparse_index.sparse_select(
                     scores, limit[:, 0][None], cfg.index_topk, bias=True)
                 selected = chosen[0][:, :, None]        # [nb, B, 1, block]
             else:
-                chosen = selected = sparse_index.sparse_select(
+                chosen, sweeps = sparse_index.sparse_select(
                     scores, limit, cfg.index_topk)
+                selected = chosen
+            if counted is not None:
+                counted.append(sweeps)
         with jax.named_scope("walk"):
             attn = runner(q, kv, block_tables, positions[:, 0],
                           sm_scale=sm_scale(cfg), window=None, work=work,

@@ -25,14 +25,29 @@ oracles the tests hold the kernels to):
 - `paged_index_scores_decode` (`dstpu_sparse_index_scores_decode`): a row a
   slot over the decode walk's work list (`decode_attention.py::
   paged_decode_work`): `[B, nb, 1, block]`.
-- `sparse_select` (`dstpu_sparse_select`): the k-th largest score of a row by
-  COUNTING — 32 passes of compare-and-sum over the float's bit pattern (a
-  monotone int32 key), most significant bit first — then the tie rule by a
-  second search over the position's bits: exact, no sort (what `lax.top_k`
-  lowers to on this chip is a sort of the whole row). A row tile's keys stay
-  in VMEM for all the passes; only the blocks under the tile's frontier are
-  counted (a dynamic loop bound). The result is the walk's mask: int8 0 / 1
-  for a chunk, a float32 bias 0 / NEG_INF for the slots' rows.
+- `sparse_select` (`dstpu_sparse_select`): the walk needs the SET, not the
+  k-th score — any x with `count(key >= x) == topk` selects it — so the
+  kernel finds such an x from a verified bracket, by COUNTING (no sort: what
+  `lax.top_k` lowers to on this chip is a sort of the whole row). A row
+  tile's keys (the float's bit pattern as a monotone int32) stay in VMEM; a
+  SAMPLE of them (one key in `_sample_stride x lane tiles`, spread over every
+  block) is searched bit by bit for two order statistics around the rank the
+  k-th key would have in it; ONE sweep over the tile's keys counts both and
+  proves `count(>= lo) >= topk > count(> hi)` a row — a row it refutes falls
+  back to the key's whole range on the side the sweep proved — and the
+  integer interval is bisected until every row's `lo` selects exactly `topk`
+  keys or its interval is one key wide (then, only where a row has more keys
+  AT the k-th than it needs, the tie rule by a second search over the
+  position's bits). ~23 sweeps a tile of 64 rows for the 36 a tile of 32 of a
+  search over the key's 32 bits (PR 61; `SELECT_COUNTERS` count them: a
+  sweep's tail of cross-lane sums and exit test costs ~0.25 us whatever the
+  rows that share it), and a tile no row of which
+  has more than `topk` valid positions makes one. The kernel copies its own
+  operands: only the blocks under the tile's frontier move (the scores in,
+  the next tile's while this one searches; the result out), where a
+  `BlockSpec` would move the whole table's slab a tile. The result is the
+  walk's mask: int8 0 / 1 for a chunk, a float32 bias 0 / NEG_INF for the
+  slots' rows — `select_topk`'s set, bit for bit.
 
 Blocks past a row's frontier hold whatever the memory held, in the scores
 and in the selection alike: nobody reads them (the walks clamp to the
@@ -55,8 +70,10 @@ INT_MIN = -2**31
 _VMEM_LIMIT_BYTES = 96 * 2**20
 # query rows a score step carries (the largest that divides the chunk)
 _SCORE_Q_TILES = (256, 128)
-# rows a selection step carries: an int8 result tile is 32 sublanes
-_SELECT_ROWS = 32
+# rows a selection step carries (the largest that divides the call's): an
+# int8 result tile is 32 sublanes, and a sweep's cross-lane sums and exit
+# test cost ~0.25 us whatever the rows that share them
+_SELECT_ROWS = (64, 32)
 
 
 def pad_lanes(x):
@@ -98,8 +115,9 @@ def select_topk(scores, limit, topk):
     """The exact selection: scores [..., S] float32, `limit` [...] (a row's
     valid positions are s < limit) -> bool [..., S], true at the `topk`
     largest valid scores, ties to the earlier position; at every valid
-    position where limit <= topk. The kernel's algorithm in `jax.numpy`: the
-    k-th largest key by 32 counting passes, then the tie rule by rank."""
+    position where limit <= topk. The plain search in `jax.numpy` — the
+    k-th largest key by 32 counting passes over the key's bits, then the tie
+    rule by rank: the oracle the kernel's bracketed search is held to."""
     S = scores.shape[-1]
     valid = jnp.arange(S, dtype=jnp.int32) < limit[..., None]
     key = jnp.where(valid, sortable_keys(scores), INT_MIN)
@@ -269,121 +287,412 @@ def paged_index_scores_decode(qi, w, ik_pool, block_tables, pos, work=None,
 # ----------------------------------------------------------------------
 
 
-def _select_kernel(live_ref, s_ref, lim_ref, o_ref, key_ref, *, topk, block,
-                   pos_bits, bias):
-    # grid (B, R // tr); s_ref [1, nb, tr, block] float32 the row tile's
-    # scores, every block of the table (only `live_ref[b, i]` of them are
-    # read); lim_ref [1, tr, 128] int32 lane-replicated: a row's valid
-    # positions are s < lim; o_ref [1, nb, tr, block]; key_ref [nb, tr,
-    # block] int32 scratch: the monotone keys, INT_MIN at invalid positions.
-    n = live_ref[pl.program_id(0), pl.program_id(1)]
-    tr = lim_ref.shape[1]
-    lim = _widen(lim_ref[0], block)
-    lane = jax.lax.broadcasted_iota(jnp.int32, (tr, block), 1)
+# the sampled order statistic the bracket is built around: the sample's share
+# is the largest power of two that leaves about this many of a row's `topk`
+# best keys in it (a 32-bit search of the sample then costs 1/16 - 1/64 of a
+# sweep a bit)
+_SAMPLE_RANK = 64
+# the bracket's two sample ranks stand this many standard deviations off the
+# k-th key's (`_bracket_ranks`)
+_BRACKET_SIGMAS = 6.0
+# blocks a copy moves between HBM and the kernel's buffers
+_COPY_BLOCKS = 8
+# the sample's search stops at the bit where the bracket's ends are this many
+# steps apart
+_BRACKET_STEPS = 8
 
-    def make_keys(j, carry):
-        s = s_ref[0, j]
-        bits = jax.lax.bitcast_convert_type(jnp.where(s == 0.0, 0.0, s),
-                                            jnp.int32)     # -0.0 is 0.0
-        key = bits ^ ((bits >> 31) & 0x7FFFFFFF)
-        key_ref[j] = jnp.where(j * block + lane < lim, key, INT_MIN)
-        return carry
 
-    jax.lax.fori_loop(0, n, make_keys, 0)
+def _sample_stride(topk, groups):
+    """Blocks a sample slot takes its 128 lanes from (a power of two): the
+    sample's share of a row's keys is 1 / (stride * groups), `groups` the
+    lane tiles of a block."""
+    want = max(topk // (_SAMPLE_RANK * groups), 1)
+    return max(min(1 << (want.bit_length() - 1), _LANES // groups), 1)
 
-    def count(hit):
-        """hit(key [tr, block], first position) -> bool; the count a row,
-        float32 (exact: a row has under 2**24 positions), lane-replicated
-        [tr, 128]. A block's lane tiles are added as they are; ONE
-        cross-lane sum a pass."""
-        def body(j, c):
-            one = jnp.where(hit(key_ref[j], j * block), 1.0, 0.0)
-            for g in range(block // _LANES):
-                c = c + one[:, g * _LANES:(g + 1) * _LANES]
-            return c
-        c = jax.lax.fori_loop(0, n, body,
-                              jnp.zeros((tr, _LANES), jnp.float32))
-        return jnp.broadcast_to(jnp.sum(c, axis=-1, keepdims=True),
-                                (tr, _LANES))
 
-    # the k-th largest key, most significant bit first, in the order of the
-    # keys' bit patterns with the sign bit turned (`^ INT_MIN`)
-    def key_bit(t, cu):
-        cand = cu | jnp.left_shift(jnp.int32(1), 31 - t)
-        at = _widen(cand ^ INT_MIN, block)
-        return jnp.where(count(lambda key, _: key >= at) >= topk, cand, cu)
+def _select_kernel(live_ref, reads_ref, s_hbm, row_ref, o_hbm, stat_ref,
+                   key_ref, smp_ref, s_ref, o_ref, sems, *, topk, block,
+                   pos_bits, bias, stride):
+    # grid (B, R // tr), in order; s_hbm [B, nb, R, block] float32 and o_hbm
+    # (the result, as wide) stay in HBM: the kernel copies a row tile's slab
+    # of the `live_ref[b, i]` blocks under its frontier and of no other — in
+    # through s_ref [nb, tr, block], the NEXT tile's while this one searches
+    # (`reads_ref[b, i]` blocks: none for a tile that reads no score), out of
+    # o_ref [nb, tr, block] while the next one searches. row_ref [1, tr, 128]
+    # int32: a row's facts in its first lanes — its valid positions are s <
+    # lane 0, lanes 1 and 2 the bracket's two sample ranks
+    # (`_bracket_ranks`); stat_ref int32 SMEM [2 * tiles]: the tile's
+    # (sweeps, bracket refuted); key_ref [nb + 1, tr, block] int32 scratch:
+    # the monotone keys, INT_MIN at invalid positions; smp_ref [cdiv(nb,
+    # stride) + 1, tr, 128] int32 scratch: the sample, lane l of slot i the
+    # key of block `i * stride + (l % span) // groups`, lane tile `l %
+    # groups`, lane l (span = stride * groups): a sample spread over every
+    # block, a vector register a slot and eight rows.
+    b, i = pl.program_id(0), pl.program_id(1)
+    tiles = pl.num_programs(1)
+    tile = b * tiles + i
+    n = live_ref[b, i]
+    tr = row_ref.shape[1]
 
-    cu = jax.lax.fori_loop(0, 32, key_bit,
-                           jnp.zeros((tr, _LANES), jnp.int32))
-    tau = _widen(cu ^ INT_MIN, block)
-    need = topk - count(lambda key, _: key > tau)
-    surplus = count(lambda key, _: key == tau) - need
+    # a copy moves a row tile's slab of `_COPY_BLOCKS` blocks (a descriptor
+    # costs ~30 scalar bundles to issue): the last one of a tile starts
+    # where it still ends inside the table, so a tile moves up to that many
+    # blocks past its frontier — scores nobody reads, a result nobody reads
+    nb = s_ref.shape[0]
+    step = min(_COPY_BLOCKS, nb)
 
-    # the tie rule: the position of the `need`-th key equal to tau is the
-    # largest p with fewer than `need` of them before it. Searched only where
-    # some row of the tile HAS more keys at tau than it needs (with real
-    # scores: hardly ever; a row of fewer than `topk` valid positions has
-    # its invalid ones at tau = INT_MIN, and they are masked below anyway)
-    def pos_bit(t, p):
-        cand = p | jnp.left_shift(jnp.int32(1), pos_bits - 1 - t)
-        at = _widen(cand, block)
-        before = count(lambda key, first:
-                       (key == tau) & (first + lane < at))
-        return jnp.where(before < need, cand, p)
+    def each_copy(blocks, tile_b, tile_i, copy, act):
+        """`act` ("start" | "wait") on each `copy(b, i, first block)` that
+        moves the first `blocks` blocks of tile (tile_b, tile_i)."""
+        def body(c, carry):
+            getattr(copy(tile_b, tile_i, jnp.minimum(c * step, nb - step)),
+                    act)()
+            return carry
+        jax.lax.fori_loop(0, (blocks + step - 1) // step, body, 0)
 
+    def slab(hbm, b, i, j):
+        return hbm.at[b, pl.ds(j, step), pl.ds(i * tr, tr), :]
+
+    def fetch(b, i, j):         # the tile's scores -> s_ref
+        return pltpu.make_async_copy(
+            slab(s_hbm, b, i, j), s_ref.at[pl.ds(j, step)], sems.at[0])
+
+    def hand_back(b, i, j):     # o_ref -> the tile's result
+        return pltpu.make_async_copy(
+            o_ref.at[pl.ds(j, step)], slab(o_hbm, b, i, j), sems.at[1])
+
+    def neighbour(d):           # the tile d = -1 | +1 steps from this one
+        wraps = i + d == (tiles if d > 0 else -1)
+        return (jnp.where(wraps, b + d, b),
+                jnp.where(wraps, 0 if d > 0 else tiles - 1, i + d))
+
+    last_tile = pl.num_programs(0) * tiles - 1
+
+    @pl.when(tile == 0)
+    def _first():
+        each_copy(reads_ref[0, 0], 0, 0, fetch, "start")
+
+    def result_is_free():
+        @pl.when(tile > 0)
+        def _():    # the tile before has handed its result back
+            before = neighbour(-1)
+            each_copy(live_ref[before], *before, hand_back, "wait")
+
+    def result_goes():
+        each_copy(n, b, i, hand_back, "start")
+
+        @pl.when(tile == last_tile)
+        def _():
+            each_copy(n, b, i, hand_back, "wait")
+
+    def next_scores():
+        @pl.when(tile < last_tile)
+        def _():
+            after = neighbour(1)
+            each_copy(reads_ref[after], *after, fetch, "start")
+
+    groups = block // _LANES
+
+    def fact(c):            # a row's c-th fact, lane-replicated
+        return jnp.broadcast_to(row_ref[0, :, c:c + 1], (tr, _LANES))
+
+    lim = fact(0)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (tr, _LANES), 1)
+    # a row of `topk` valid positions or fewer keeps every one of them
+    searching = lim > topk
+
+    def lanes(x, g):
+        return x[:, g * _LANES:(g + 1) * _LANES]
+
+    def count(ref, slots, width, *hits):
+        """hits: (key [tr, 128], first position) -> bool, each counted over
+        the first `slots` entries of `ref` [., tr, width] in ONE sweep ->
+        a count a hit, float32 (exact: a row has under 2**24 positions),
+        lane-replicated [tr, 128]. A block's lane tiles are summed as a tree
+        before the one add into the accumulator, two blocks a turn."""
+        def block_of(j, acc):
+            key = ref[j]
+            out = []
+            for hit, c in zip(hits, acc):
+                one = [jnp.where(hit(lanes(key, g), j * width + g * _LANES),
+                                 1.0, 0.0) for g in range(width // _LANES)]
+                while len(one) > 1:
+                    one = [a + b for a, b in zip(one[::2], one[1::2])] \
+                        + one[len(one) & ~1:]
+                out.append(c + one[0])
+            return tuple(out)
+
+        zero = tuple(jnp.zeros((tr, _LANES), jnp.float32) for _ in hits)
+        # (an odd count's last turn reads the entry past it: INT_MIN)
+        acc = jax.lax.fori_loop(
+            0, (slots + 1) // 2,
+            lambda i, acc: block_of(2 * i + 1, block_of(2 * i, acc)), zero)
+        return [jnp.broadcast_to(jnp.sum(c, axis=-1, keepdims=True),
+                                 (tr, _LANES)) for c in acc]
+
+    def at_least(x):
+        return lambda key, _: key >= x
+
+    def any_row(flag):
+        # (a float32 reduction: an int32 one is two of them on this chip)
+        return jnp.max(jnp.where(flag, 1.0, 0.0)) > 0.0
+
+    def write(chosen_at):
+        """chosen_at(block j, lane tile g, positions [tr, 128]) -> bool: the
+        tile's result, a block after another, and on its way out."""
+        def body(j, carry):
+            for g in range(groups):
+                at = j * block + g * _LANES + lane
+                chosen = chosen_at(j, g, at) & (at < lim)
+                where = (j, slice(None), pl.ds(g * _LANES, _LANES))
+                if bias:
+                    o_ref[where] = jnp.where(chosen, 0.0, NEG_INF)
+                else:
+                    o_ref[where] = jnp.where(chosen, 1.0, 0.0).astype(
+                        o_ref.dtype)
+            return carry
+        result_is_free()
+        jax.lax.fori_loop(0, n, body, 0)
+        result_goes()
+
+    lowest = jnp.full((tr, _LANES), INT_MIN, jnp.int32)
     everything = jnp.full((tr, _LANES), (1 << pos_bits) - 1, jnp.int32)
-    p = _widen(jax.lax.cond(
-        jnp.max(jnp.where(cu == 0, 0.0, surplus)) > 0.0,
-        lambda: jax.lax.fori_loop(0, pos_bits, pos_bit,
-                                  jnp.zeros((tr, _LANES), jnp.int32)),
-        lambda: everything), block)
+    any_searching = reads_ref[b, i] > 0
 
-    def write(j, carry):
-        key = key_ref[j]
-        at = j * block + lane
-        chosen = ((key > tau) | ((key == tau) & (at <= p))) & (at < lim)
-        if bias:
-            o_ref[0, j] = jnp.where(chosen, 0.0, NEG_INF)
-        else:
-            o_ref[0, j] = jnp.where(chosen, 1.0, 0.0).astype(o_ref.dtype)
-        return carry
+    @pl.when(jnp.logical_not(any_searching))
+    def _all_valid():
+        # every position the keys would call valid is kept: no score is
+        # read, no key made (tau is under every key and no tie is cut)
+        next_scores()
+        write(lambda j, g, at: at >= 0)
+        stat_ref[2 * tile] = 1
+        stat_ref[2 * tile + 1] = 0
 
-    jax.lax.fori_loop(0, n, write, 0)
+    @pl.when(any_searching)
+    def _search():
+        span = stride * groups
+        slots = (n + stride - 1) // stride
+        source = lane % span
+
+        def clear(i, carry):
+            smp_ref[i] = lowest
+            return carry
+        jax.lax.fori_loop(0, slots + 1, clear, 0)
+
+        each_copy(n, b, i, fetch, "wait")
+
+        def make_keys(j, carry):
+            s = s_ref[j]
+            bits = jax.lax.bitcast_convert_type(jnp.where(s == 0.0, 0.0, s),
+                                                jnp.int32)     # -0.0 is 0.0
+            key = bits ^ ((bits >> 31) & 0x7FFFFFFF)
+            sample = smp_ref[j // stride]
+            for g in range(groups):
+                k = jnp.where(j * block + g * _LANES + lane < lim,
+                              lanes(key, g), INT_MIN)
+                key_ref[j, :, pl.ds(g * _LANES, _LANES)] = k
+                sample = jnp.where(source == (j % stride) * groups + g, k,
+                                   sample)
+            smp_ref[j // stride] = sample
+            return carry
+        jax.lax.fori_loop(0, n, make_keys, 0)
+        # (the entry behind the last is no row's key: a sweep goes two
+        # entries a turn; the sample's was cleared above)
+        key_ref[n] = jnp.full(key_ref.shape[1:], INT_MIN, jnp.int32)
+        next_scores()
+
+        # the bracket: two order statistics of the sample, around the rank
+        # the k-th key would have in it
+        rank_lo = fact(1).astype(jnp.float32)
+        rank_hi = fact(2).astype(jnp.float32)      # a LARGER key: `hi`
+
+        def sample_bit(t, cu):
+            bit = jnp.left_shift(jnp.int32(1), 31 - t)
+            cand = [c | bit for c in cu]
+            got = count(smp_ref, slots, _LANES,
+                        *(at_least(c ^ INT_MIN) for c in cand))
+            return tuple(jnp.where(g >= r, c, u) for g, r, c, u
+                         in zip(got, (rank_lo, rank_hi), cand, cu))
+
+        # the two statistics' leading bits, four at a time, until they stand
+        # `_BRACKET_STEPS` steps of the bits left apart in every row (the
+        # bits below then widen the bracket by under a quarter; rows whose
+        # sample ties at both ranks run all 32 and end on the tie)
+        def coarse(state):
+            t, u_lo, u_hi = state
+            apart = jax.lax.shift_right_logical(u_hi - u_lo, 32 - t)
+            return (t < 32) & any_row(searching & (apart < _BRACKET_STEPS))
+
+        def four_more(state):
+            t, u_lo, u_hi = state
+            return (t + 4,) + jax.lax.fori_loop(t, t + 4, sample_bit,
+                                                (u_lo, u_hi))
+
+        zero = jnp.zeros((tr, _LANES), jnp.int32)
+        bits, u_lo, u_hi = jax.lax.while_loop(
+            coarse, four_more, (jnp.int32(8),) + jax.lax.fori_loop(
+                0, 8, sample_bit, (zero, zero)))
+        below = jax.lax.shift_right_logical(jnp.int32(-1), bits)
+        below = jnp.where(bits == 32, 0, below)
+        s_lo = u_lo ^ INT_MIN
+        s_hi = jnp.where(rank_hi < 1.0, jnp.int32(2**31 - 1),
+                         (u_hi | below) ^ INT_MIN)
+
+        # ONE sweep proves the bracket a row: count(key >= lo) >= topk >
+        # count(key > hi) from here on (where many keys tie AT the k-th the
+        # two ends meet on it). A row it refutes keeps what the sweep did
+        # prove: the k-th key lies on the bracket's other side
+        c_lo, c_hi = count(key_ref, n, block, at_least(s_lo),
+                           lambda key, _: key > s_hi)
+        under, over = c_hi >= topk, c_lo < topk
+        lo = jnp.where(under, s_hi + 1, jnp.where(over, INT_MIN, s_lo))
+        hi = jnp.where(under, jnp.int32(2**31 - 1),
+                       jnp.where(over, s_lo - 1, s_hi))
+        c_at = jnp.where(under, c_hi, jnp.where(
+            over, (n * block).astype(jnp.float32), c_lo))
+        refuted = any_row(searching & (under | over))
+
+        # bisect the integer interval; a row is decided when `lo` selects
+        # exactly `topk` keys (no tie can matter) or the interval is one key
+        def open_rows(lo, hi, c_at):
+            return searching & (lo < hi) & (c_at != topk)
+
+        def middle(a, b):       # the upper middle of [a, b], a where a == b
+            return (a | b) - ((a ^ b) >> 1)
+
+        def narrow(state):
+            lo, hi, c_at, sweeps = state
+            is_open = open_rows(lo, hi, c_at)
+            mid = middle(lo, hi)
+            c_mid, = count(key_ref, n, block, at_least(mid))
+            up = is_open & (c_mid >= topk)
+            return (jnp.where(up, mid, lo),
+                    jnp.where(is_open & ~up, mid - 1, hi),
+                    jnp.where(up, c_mid, c_at), sweeps + 1)
+
+        lo, hi, c_at, sweeps = jax.lax.while_loop(
+            lambda s: any_row(open_rows(*s[:3])),
+            narrow, (lo, hi, c_at, jnp.int32(0)))
+
+        # `lo` is the k-th key now, or above every key the set leaves out
+        tau = jnp.where(searching, lo, INT_MIN)
+        # the tie rule, where some row has more keys at tau than it needs
+        # (with real scores: hardly ever): the position of the `need`-th key
+        # equal to tau is the largest p with fewer than `need` before it
+        tied = searching & (c_at > topk)
+
+        def tie_rule():
+            need = topk - count(key_ref, n, block,
+                                lambda key, _: key > tau)[0]
+
+            def pos_bit(t, p):
+                cand = p | jnp.left_shift(jnp.int32(1), pos_bits - 1 - t)
+                before, = count(
+                    key_ref, n, block, lambda key, first:
+                    (key == tau) & (first + lane < cand))
+                return jnp.where(before < need, cand, p)
+
+            p = jax.lax.fori_loop(0, pos_bits, pos_bit, zero)
+            return jnp.where(tied, p, everything), jnp.int32(1 + pos_bits)
+
+        p, tie_sweeps = jax.lax.cond(
+            any_row(tied), tie_rule,
+            lambda: (everything, jnp.int32(0)))
+
+        def chosen_at(j, g, at):
+            key = key_ref[j, :, pl.ds(g * _LANES, _LANES)]
+            return (key > tau) | ((key == tau) & (at <= p))
+        write(chosen_at)
+        # the keys, the proof, the write; the sample's bits at its share
+        stat_ref[2 * tile] = sweeps + tie_sweeps + 3 \
+            + (2 * bits + span - 1) // span
+        stat_ref[2 * tile + 1] = refuted.astype(jnp.int32)
+
+
+SELECT_COUNTERS = ("sparse_select_tiles", "sparse_select_sweeps",
+                   "sparse_select_fallback_tiles")
+
+
+def _bracket_ranks(limit, topk, span):
+    """limit [...] int32 -> (rank_lo, rank_hi) int32: the two ranks in a
+    row's sample (one key in `span`: limit / span = M keys of it) whose keys
+    bracket the row's k-th largest. The sample holds X of the row's `topk`
+    best keys, hypergeometric around k = topk / span, and the r-th largest
+    sampled key is one of them iff X >= r; asin(sqrt(X / M)) is normal with
+    deviation 1 / (2 sqrt(M)) (times the finite population's
+    sqrt(1 - 1 / span)) whether a row keeps a ninth of its positions or
+    nearly all, so the two ranks stand `_BRACKET_SIGMAS` of those either side
+    of k, one rank of slack: at 6 a row in ~10**9 is refuted (the exact
+    tails, 1e-9 to 3e-9 at contexts of 2.1k to 66k), with scores in any order
+    that does not know the sample's layout."""
+    m = jnp.maximum(limit, 1).astype(jnp.float32) / span
+    centre = jnp.arcsin(jnp.sqrt(jnp.minimum(topk / span / m, 1.0)))
+    half = _BRACKET_SIGMAS * 0.5 * (1.0 - 1.0 / span) ** 0.5 / jnp.sqrt(m)
+    rank_hi = m * jnp.sin(jnp.maximum(centre - half, 0.0)) ** 2
+    rank_lo = m * jnp.sin(jnp.minimum(centre + half, jnp.pi / 2)) ** 2
+    return (jnp.ceil(rank_lo).astype(jnp.int32) + 1,
+            jnp.floor(rank_hi).astype(jnp.int32) - 1)
+
+
+def select_rows(rows):
+    """Rows of a call's `rows` that one step of the selection carries."""
+    return next((t for t in _SELECT_ROWS if rows % t == 0), rows)
 
 
 def sparse_select(scores, limit, topk, bias=False, interpret=None):
     """The walk's mask from a call's index scores, block-major as the score
     walks leave them: scores [B, nb, R, block] float32, limit [B, R] int32 (a
-    row's valid positions are s < limit) -> [B, nb, R, block], int8 1 at the
+    row's valid positions are s < limit) -> ([B, nb, R, block], int8 1 at the
     selected positions and 0 elsewhere, or with `bias` float32 0 and NEG_INF
-    — `select_topk`'s set, for every block up to the row tile's frontier."""
+    — `select_topk`'s set, for every block up to the row tile's frontier;
+    int32 [3] in `SELECT_COUNTERS` order: the call's row tiles, the sweeps
+    over a tile's keys they made, the tiles with a row whose bracket the
+    proving sweep refuted)."""
     if interpret is None:
         interpret = pallas_interpret()
     B, nb, R, block = scores.shape
-    tr = _SELECT_ROWS if R % _SELECT_ROWS == 0 else R
+    tr = select_rows(R)
+    tiles = R // tr
     limit = limit.astype(jnp.int32)
     live = jnp.minimum(
-        (jnp.max(limit.reshape(B, R // tr, tr), axis=-1) - 1) // block + 1,
+        (jnp.max(limit.reshape(B, tiles, tr), axis=-1) - 1) // block + 1,
         nb).astype(jnp.int32)
-    tile = (1, nb, tr, block)
-    return pl.pallas_call(
+    # the blocks whose scores a tile reads: none where no row has more
+    # valid positions than `topk`
+    reads = jnp.where(jnp.max(limit.reshape(B, tiles, tr), axis=-1) > topk,
+                      live, 0)
+    kind = jnp.float32 if bias else jnp.int8
+    stride = _sample_stride(topk, block // _LANES)
+    chosen, stats = pl.pallas_call(
         functools.partial(_select_kernel, topk=topk, block=block,
                           pos_bits=max((nb * block - 1).bit_length(), 1),
-                          bias=bias),
+                          bias=bias, stride=stride),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(B, R // tr),
-            in_specs=[pl.BlockSpec(tile, lambda b, i, live_ref: (b, 0, i, 0)),
+            num_scalar_prefetch=2,
+            grid=(B, tiles),
+            in_specs=[pl.BlockSpec(memory_space=pl.ANY),
                       pl.BlockSpec((1, tr, _LANES),
-                                   lambda b, i, live_ref: (b, i, 0))],
-            out_specs=pl.BlockSpec(tile, lambda b, i, live_ref: (b, 0, i, 0)),
-            scratch_shapes=[pltpu.VMEM((nb, tr, block), jnp.int32)],
+                                   lambda b, i, live_ref, reads_ref:
+                                   (b, i, 0))],
+            out_specs=[pl.BlockSpec(memory_space=pl.ANY),
+                       pl.BlockSpec(memory_space=pltpu.SMEM)],
+            scratch_shapes=[pltpu.VMEM((nb + 1, tr, block), jnp.int32),
+                            pltpu.VMEM((pl.cdiv(nb, stride) + 1, tr, _LANES),
+                                       jnp.int32),
+                            pltpu.VMEM((nb, tr, block), jnp.float32),
+                            pltpu.VMEM((nb, tr, block), kind),
+                            pltpu.SemaphoreType.DMA((2,))],
         ),
-        out_shape=jax.ShapeDtypeStruct(
-            scores.shape, jnp.float32 if bias else jnp.int8),
+        out_shape=[jax.ShapeDtypeStruct(scores.shape, kind),
+                   jax.ShapeDtypeStruct((2 * B * tiles,), jnp.int32)],
         compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
             vmem_limit_bytes=_VMEM_LIMIT_BYTES),
         interpret=interpret,
         name="dstpu_sparse_select",
-    )(live, scores,
-      jnp.broadcast_to(limit[..., None], (B, R, _LANES)))
+    )(live, reads, scores, pad_lanes(jnp.stack(
+        [limit, *_bracket_ranks(limit, topk, stride * (block // _LANES))],
+        axis=-1)))
+    stats = jnp.sum(stats.reshape(B * tiles, 2), axis=0)
+    return chosen, jnp.concatenate(
+        [jnp.full((1,), B * tiles, jnp.int32), stats])

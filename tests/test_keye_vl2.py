@@ -340,10 +340,10 @@ def test_the_select_kernel_is_its_twin_block_major(bias):
     seen = np.arange(nb * block)[None] < limit[:, None]
     dirty = np.where(seen, scores, np.nan).astype(np.float32)
     major = jnp.asarray(dirty.reshape(rows, nb, block).transpose(1, 0, 2))
-    got = sparse_index.sparse_select(
+    got, _ = sparse_index.sparse_select(
         major[None], jnp.asarray(limit, jnp.int32)[None], topk, bias=bias,
-        interpret=True)[0]
-    got = np.asarray(got).transpose(1, 0, 2).reshape(rows, nb * block)
+        interpret=True)
+    got = np.asarray(got[0]).transpose(1, 0, 2).reshape(rows, nb * block)
     chosen = got == 0 if bias else got > 0
     want = _stable_sort_sets(scores, limit, topk)
     live = (np.arange(nb * block)[None] // block
@@ -351,6 +351,121 @@ def test_the_select_kernel_is_its_twin_block_major(bias):
     np.testing.assert_array_equal(chosen & live & seen, want)
     # ... and nothing past a row's own positions in a block the walk reads
     assert not (chosen & live & ~seen).any()
+
+
+def _select_case(case, rng):
+    """-> (scores [rows, S] float32, limit [rows], topk, block): what the
+    bracket's search has to get right, a case a kind of input."""
+    rows, nb, block, topk = 64, 8, 128, 192
+    S = nb * block
+    limit = np.full(rows, S)
+    if case == "model_form":
+        # 16 heads of w * relu(q . k), rows of three tiles under their own
+        # frontiers (a chunk's: one more position a row)
+        rows = 192
+        limit = np.full(rows, S)
+        q, k = rng.normal(size=(rows, 16, 8)), rng.normal(size=(S, 8))
+        w = rng.normal(size=(rows, 16, 1))
+        scores = (np.maximum(np.einsum("rhd,sd->rhs", q, k), 0) * w).sum(1)
+        limit = S - rows + 1 + np.arange(rows)
+    elif case == "ties_with_surplus":
+        # eight levels: every row has more keys AT its k-th than it needs
+        scores = np.round(rng.normal(size=(rows, S)) * 2) / 2
+    elif case == "ties_without_surplus":
+        # the k-th key is one of a tied group that the set takes WHOLE
+        scores = rng.normal(size=(rows, S))
+        order = np.argsort(-scores, axis=1)
+        tied = scores[np.arange(rows), order[:, topk - 1]]
+        for r in range(rows):
+            scores[r, order[r, topk - 5:topk]] = tied[r]
+    elif case == "all_equal":
+        scores = np.full((rows, S), 0.75)
+        scores[1::2] = -2.5
+    elif case == "zeros_of_both_signs":
+        scores = np.where(rng.random((rows, S)) < 0.5, 0.0, -0.0)
+        scores[::3, ::7] = 1e-3     # fewer positives than topk
+    elif case == "negative_and_subnormal":
+        scores = -np.abs(rng.normal(size=(rows, S)))
+        scores[:, ::5] = rng.normal(size=(rows, (S + 4) // 5)) * 1e-41
+        scores[0] *= 1e30
+    elif case == "limits_from_one":
+        # one tile's rows from a single valid position to the table's end
+        rows = 32
+        scores = rng.normal(size=(rows, S))
+        limit = np.unique(np.concatenate(
+            [[1, 2, topk - 1, topk, topk + 1, S],
+             rng.integers(1, S + 1, 64)]))[:rows]
+        limit = np.sort(np.resize(limit, rows))[::-1].copy()
+    elif case == "within_topk":
+        # a tile no row of which has more positions than topk: nothing read
+        rows = 32
+        scores = np.full((rows, S), np.nan)
+        limit = rng.integers(1, topk + 1, rows)
+    else:
+        assert case == "adversarial"
+        # every key the sample holds small, every other large: the bracket
+        # lies under the k-th key and the proving sweep must say so
+        stride = sparse_index._sample_stride(topk, block // 128)
+        pos = np.arange(S)
+        sampled = (pos % 128) % stride == (pos // block) % stride
+        scores = np.abs(rng.normal(size=(rows, S))) + 1.0
+        scores = np.where(sampled[None], -scores, scores)
+    return scores.astype(np.float32), limit.astype(np.int32), topk, block
+
+
+@pytest.mark.parametrize("bias", [False, True], ids=["mask", "bias"])
+@pytest.mark.parametrize("case", [
+    "model_form", "ties_with_surplus", "ties_without_surplus", "all_equal",
+    "zeros_of_both_signs", "negative_and_subnormal", "limits_from_one",
+    "within_topk", "adversarial"])
+def test_the_select_kernel_finds_the_twins_set_from_a_verified_bracket(
+        case, bias):
+    """Bit-equal to `select_topk` whatever the sample said: the bracket only
+    decides how many sweeps the search makes (the counters)."""
+    scores, limit, topk, block = _select_case(
+        case, np.random.default_rng(len(case)))
+    rows, S = scores.shape
+    nb, tr = S // block, sparse_index.select_rows(rows)
+    seen = np.arange(S)[None] < limit[:, None]
+    # past a row's own positions: whatever the memory held
+    dirty = np.where(seen, scores, np.nan).astype(np.float32)
+    major = jnp.asarray(dirty.reshape(rows, nb, block).transpose(1, 0, 2))
+    got, (tiles, sweeps, fallback) = sparse_index.sparse_select(
+        major[None], jnp.asarray(limit)[None], topk, bias=bias,
+        interpret=True)
+    got = np.asarray(got[0]).transpose(1, 0, 2).reshape(rows, S)
+    chosen = got == 0 if bias else got > 0
+    want = np.asarray(sparse_index.select_topk(
+        jnp.asarray(np.where(seen, scores, 0.0)), jnp.asarray(limit), topk))
+    assert (want.sum(-1) == np.minimum(limit, topk)).all()
+    frontier = (limit.reshape(-1, tr).max(-1) - 1) // block
+    live = np.arange(S)[None] // block <= np.repeat(frontier, tr)[:, None]
+    np.testing.assert_array_equal(chosen & live & seen, want)
+    assert not (chosen & live & ~seen).any()
+    # the counters: a sweep to make the keys, the sample's search (8 to 32
+    # bits of two ranks at its share), the proof, the bisection, the write
+    stride = sparse_index._sample_stride(topk, block // 128)
+    least = 3 + 16 // (stride * (block // 128))
+    fixed = 3 + 64 // (stride * (block // 128))
+    pos_bits = (S - 1).bit_length()
+    assert int(tiles) == rows // tr
+    if case == "within_topk":
+        assert (int(sweeps), int(fallback)) == (int(tiles), 0)
+    elif case == "adversarial":
+        assert int(fallback) == int(tiles)
+        # ... and the search still ends inside the key's 32 bits
+        assert int(sweeps) <= int(tiles) * (fixed + 32)
+    elif case in ("ties_with_surplus", "all_equal", "zeros_of_both_signs"):
+        # the position search ran: one sweep for `need`, one a position bit
+        assert int(sweeps) >= int(tiles) * (least + 1 + pos_bits)
+    else:
+        assert int(fallback) == 0
+        # no tie is cut: no position search, and the bisection is over the
+        # interval the bracket left, not over the key's 32 bits (but where
+        # the bracket spans subnormal AND normal floats, 2**29 keys apart,
+        # and two of a row's few subnormals may tie at its k-th key)
+        most = 32 + 1 + pos_bits if case == "negative_and_subnormal" else 24
+        assert int(tiles) * least <= int(sweeps) <= int(tiles) * (fixed + most)
 
 
 def test_the_score_walks_are_their_twin_over_the_paged_index_keys():
@@ -427,6 +542,48 @@ def test_the_kernels_serve_the_references_tokens_through_the_carried_pool(
     records = srv.steptrace.records()
     assert sum(r.prefill_live_blocks for r in records) > 0
     assert sum(r.decode_live_blocks for r in records) > 0
+    # the selection's own counters, read back with the tokens: a chunk of
+    # 128 rows is two row tiles a layer, a decode token one (both slots'
+    # rows); a tile makes one sweep where no row is past `topk` positions
+    # (the first chunk of a prompt) and at most the sample's 32 + 3, the
+    # key's 32 bits and a position search; no bracket of this model's
+    # scores is refuted
+    counted = stats["step_counters"]
+    assert sparse_index.select_rows(128) == 64
+    tiles = cfg.n_layer * (2 * stats["prefill_chunks"]
+                           + 2 * stats["decode_steps"])
+    assert counted["sparse_select_tiles"] == tiles
+    most = 3 + 64 // sparse_index._sample_stride(150, 1) + 32 + 1 + 9
+    assert tiles < counted["sparse_select_sweeps"] <= tiles * most
+    assert counted["sparse_select_fallback_tiles"] == 0
+    names = srv.step_counter_names
+    assert names[-3:] == sparse_index.SELECT_COUNTERS
+    assert np.sum([r.counters for r in records], axis=0)[-3:].tolist() \
+        == [counted[name] for name in sparse_index.SELECT_COUNTERS]
+
+
+@pytest.mark.parametrize("family, cases, builder", [
+    ("exaone_moe", "exaone_cases", "make_exaone_moe_decode_model"),
+    ("glm4_moe_lite", "glm_cases", "make_glm4_moe_lite_decode_model"),
+    ("mimo_v2_flash", "mimo_cases", "make_mimo_v2_flash_decode_model"),
+    ("keye_vl2", "keye_cases", "make_keye_vl2_decode_model")])
+def test_only_the_selected_kind_names_the_selections_counters(
+        family, cases, builder):
+    """K-EXAONE's, GLM's and MiMo's `step_counters` are the routed experts'
+    five, as before PR 61 (their step programs' text is pinned in
+    `tests/step_program_hashes.json`); the family with the `SELECTED` kind
+    adds the selection's three BEHIND them, so a reader by index keeps its
+    places."""
+    import importlib
+    from deepspeed_tpu.parallel.moe import HELD_ROUTED_COUNTERS
+    module = importlib.import_module("tests." + cases)
+    model = importlib.import_module("deepspeed_tpu.models." + family)
+    cfg = module._cfg()
+    spec = getattr(model, builder)(cfg, params=module._params(cfg))
+    own = sparse_index.SELECT_COUNTERS if family == "keye_vl2" else ()
+    assert spec.step_counters == HELD_ROUTED_COUNTERS + own
+    assert [kind.counters for kind in em.ATTN_KINDS.values()] \
+        == [(), (), (), sparse_index.SELECT_COUNTERS]
 
 
 # ----------------------------------------------------------------------

@@ -876,18 +876,49 @@ def test_sparse_index_kernels_compiled_at_the_served_shapes():
     # the selection of the kernel's OWN scores, ties planted
     planted = scores.at[0, 3, :, 7].set(0.25).at[0, 9, :, 100].set(0.25) \
         .at[0, :live, 5, :].set(0.0).at[0, :live, 5, ::2].set(-0.0)
-    chosen = jax.jit(lambda a, b: si.sparse_select(
-        a, b, topk, interpret=False))(planted, limit)
-    flat = np.asarray(planted)[:, :live].transpose(0, 2, 1, 3).reshape(
-        1, C, live * block)
-    twin = np.asarray(si.select_topk(jnp.asarray(np.where(seen, flat, 0)),
-                                     limit, topk))
-    mine = np.asarray(chosen)[:, :live].transpose(0, 2, 1, 3).reshape(
-        1, C, live * block) > 0
-    differ = ((mine & seen) != twin)[0].sum(-1)
-    assert not differ.any(), (np.flatnonzero(differ)[:12], differ[differ > 0][:12],
-                              (mine & seen)[0].sum(-1)[differ > 0][:12])
-    assert (twin.sum(-1) == topk).all()
+    select = jax.jit(lambda a, b: si.sparse_select(a, b, topk,
+                                                   interpret=False))
+
+    def selected(major, limit, live):
+        """-> (the kernel's mask, the twin's set, the kernel's counters),
+        within the rows' own positions."""
+        chosen, counts = select(major, limit)
+        seen = np.arange(live * block)[None, None] \
+            < np.asarray(limit)[..., None]
+        flat = np.asarray(major)[:, :live].transpose(0, 2, 1, 3).reshape(
+            1, C, live * block)
+        twin = np.asarray(si.select_topk(
+            jnp.asarray(np.where(seen, flat, 0)), limit, topk))
+        mine = np.asarray(chosen)[:, :live].transpose(0, 2, 1, 3).reshape(
+            1, C, live * block) > 0
+        differ = ((mine & seen) != twin)[0].sum(-1)
+        assert not differ.any(), (
+            np.flatnonzero(differ)[:12], differ[differ > 0][:12],
+            (mine & seen)[0].sum(-1)[differ > 0][:12])
+        assert (twin.sum(-1) == topk).all()
+        return chosen, twin, np.asarray(counts)
+
+    chosen, twin, counts = selected(planted, limit, live)
+    # 16 row tiles; no bracket refuted, and far fewer sweeps than the 36 a
+    # tile that a search over the key's 32 bits makes (PR 61)
+    assert counts[0] == C // 64 and counts[2] == 0
+    assert counts[1] < 28 * counts[0]
+    # ... an ADVERSARIAL layout: every key the sample holds small (lane l of
+    # block j, lane tile g, where l % 32 == (j % 8) * 4 + g at this `topk`),
+    # every other large — every bracket lies under the row's k-th key, the
+    # proving sweep says so, and the set is still the twin's
+    j, _, l = np.ogrid[:nb, :1, :block]
+    sampled = (l % 128) % 32 == (j % 8) * 4 + l // 128
+    hostile = jnp.where(jnp.asarray(sampled)[None], -jnp.abs(scores) - 1.0,
+                        jnp.abs(scores))
+    _, _, counts = selected(hostile, limit, live)
+    assert counts[2] == counts[0] == C // 64
+    # ... and the table's last chunk: a frontier of 66,560 positions
+    deep = jnp.asarray(rng.normal(size=(1, nb, C, block)), jnp.float32)
+    far = jnp.asarray([130 * block - C], jnp.int32)[:, None] \
+        + jnp.arange(C)[None] + 1
+    _, _, counts = selected(deep, far, 130)
+    assert counts[2] == 0 and counts[1] < 28 * counts[0]
     # the chunk walk under the selection
     cfg = GPTConfig(n_head=H, n_kv_head=Hkv, d_model=H * hd)
     q = jnp.asarray(rng.normal(size=(1, C, H, hd)), jnp.bfloat16)
@@ -914,7 +945,7 @@ def test_sparse_index_kernels_compiled_at_the_served_shapes():
     sd = jax.jit(lambda *a: si.paged_index_scores_decode(
         *a, interpret=False))(qd, wd, keys, jnp.asarray(tables),
                               jnp.asarray(pos))
-    bias = jax.jit(lambda a, b: si.sparse_select(
+    bias, _ = jax.jit(lambda a, b: si.sparse_select(
         a, b, topk, bias=True, interpret=False))(
         jnp.swapaxes(sd[:, :, 0], 0, 1)[None], jnp.asarray(pos + 1)[None])
     qq = jnp.asarray(rng.normal(size=(S, H, hd)), jnp.bfloat16)
