@@ -153,7 +153,8 @@ _FREE, _PREFILL, _DECODE, _HANDOFF = 0, 1, 2, 3
 # twin names what the kernel does (`ops/pallas/*::*_walk_counts`); the loop
 # alone knows the phase it ran in and the kind of layer it was asked for.
 _DECODE_FIELDS = {"live_blocks": "decode_live_blocks",
-                  "grid_steps": "decode_grid_steps"}
+                  "grid_steps": "decode_grid_steps",
+                  "rows": "decode_walk_rows"}
 _DECODE_WINDOW_FIELDS = {"live_blocks": "decode_window_live_blocks",
                          "table_blocks": "decode_window_table_blocks"}
 _CHUNK_FIELDS = {"live_blocks": "prefill_live_blocks",
@@ -166,7 +167,8 @@ _CHUNK_WINDOW_FIELDS = {"live_blocks": "prefill_window_live_blocks",
 
 
 def _lands(counts, fields):
-    return {fields[name]: n for name, n in counts.items()}
+    """The counts that `fields` names, under their fields."""
+    return {fields[name]: n for name, n in counts.items() if name in fields}
 
 
 class _Slot:
@@ -591,6 +593,12 @@ class ServingEngine:
         # selects `index_topk` a query (`CacheKind.index_topk`)
         self._index_topk = self.cache_kinds[0].index_topk \
             if self.cache_kinds else 0
+        # the last dimensions of the leaves a full layer's decode walk reads
+        # (`decode_attention._frontier_rows`: what it moves of a short
+        # table's frontier block follows them)
+        self._walk_widths = tuple(
+            self.pool[leaf].shape[-1] for leaf in
+            (self.cache_kinds[0].leaves if self.cache_kinds else self.pool))
         # what a decode token of one slot reads + writes of a state kind's
         # state proper (its first leaf), all layers
         self._state_token_bytes = 0
@@ -619,6 +627,11 @@ class ServingEngine:
         self.step_counter_totals = np.zeros(len(self.step_counter_names),
                                             np.int64)
         self._step_counts = np.zeros_like(self.step_counter_totals)
+        # ... and, reported beside them, what the decode walks had to visit
+        # and what they moved of it, summed over the steps (the step ring's
+        # fields of these names: a short table's walk moves fewer rows than
+        # its pairs' blocks hold, `decode_attention._frontier_rows`)
+        self.walk_totals = {"decode_live_blocks": 0, "decode_walk_rows": 0}
         self._call_counts = ()      # of the call read last (`_fetch`)
         self._work = {}             # `StepRecord` field -> what the open
                                     # step's calls do of it (`_book`)
@@ -1750,8 +1763,10 @@ class ServingEngine:
         # dispatched, or where the generator says so the calls it READ (whose
         # shares by the forwards taken are whole numbers in sum only)
         work, self._work = self._work, {}
-        st.end_step(counters=counters,
-                    **{name: int(round(n)) for name, n in work.items()},
+        work = {name: int(round(n)) for name, n in work.items()}
+        for name in self.walk_totals:
+            self.walk_totals[name] += work.get(name, 0)
+        st.end_step(counters=counters, **work,
                     admitted=admitted,
                     prefill_chunks=self.prefill_chunks - chunks0,
                     fused_chunks=len(riding),
@@ -2161,18 +2176,20 @@ class ServingEngine:
         """What the decode walks have to do in a call that advances the slots
         `dec` by `win`, a layer, as `StepRecord` fields, summed over the
         positions the generator counts a walk at (`walk_at`: a token's, or a
-        forward's): the paged decode kernel's live (slot, block) pairs and
-        grid steps (`paged_decode_walk_counts`; a latent pool's walk visits
-        the same pairs), for a pool of two kinds the pairs a WINDOW layer's
-        walk visits and the pairs it would visit with no window, both in the
-        window kind's blocks, and the bytes of a state kind's state the
-        call's tokens read + write. Booked whichever program walks: they
-        count the call, and the gather oracle serves the same pairs."""
+        forward's): the paged decode kernel's live (slot, block) pairs, its
+        grid steps and the rows it moves (`paged_decode_walk_counts`; a
+        latent pool's walk visits the same pairs), for a pool of two kinds
+        the pairs a WINDOW layer's walk visits and the pairs it would visit
+        with no window, both in the window kind's blocks, and the bytes of a
+        state kind's state the call's tokens read + write. Booked whichever
+        program walks: they count the call, and the gather oracle serves the
+        same pairs."""
         from deepspeed_tpu.ops.pallas.decode_attention import \
             paged_decode_walk_counts
         at = self.gen.walk_at(pos[[s.idx for s in dec]], win)
-        work = _lands(paged_decode_walk_counts(at, self.block_size),
-                      _DECODE_FIELDS)
+        work = _lands(paged_decode_walk_counts(
+            at, self.block_size, nb=self.nb, widths=self._walk_widths,
+            selected=bool(self._index_topk)), _DECODE_FIELDS)
         if self._latent:
             work["latent_walk_blocks"] = work["decode_live_blocks"]
         if self._index_topk:
@@ -2349,7 +2366,8 @@ class ServingEngine:
             # and steps; `StepRecord.counters` has them a step
             out["step_counters"] = dict(zip(
                 self.step_counter_names,
-                (int(v) for v in self.step_counter_totals)))
+                (int(v) for v in self.step_counter_totals)),
+                **self.walk_totals)
         entry = self.gen.stats(out.get("step_counters"))
         if entry is not None:
             out["generator"] = entry

@@ -39,6 +39,15 @@ KV head of a block in one step (`_paged_walk`). Measured alone on a v5e (PR
 10 live rows of 32 (14 blocks of 8 heads, 29 MB), where the grid (B, Hkv, nb)
 of one head a step took 1.52 ms whatever was live — 0.18 us a dead step; 2.1
 ms for 746 blocks (1.56 GB: 91% of 819 GB/s).
+
+Where a table is a few blocks long the walk moves its FRONTIER block — the one
+that holds `pos`, half dead on average — by row tiles (`_frontier_rows`,
+`_paged_cut_kernel`, PR 62): the kernel copies a pair's live tiles itself and
+folds them in one update, so rows past `pos` are neither read nor multiplied.
+Alone on a v5e at OLMoE's served shapes (64 slots, 16 KV heads, a 3-block
+table, positions drawn from its traffic: 0.61 of the moved rows live) a call
+takes 345.6 us where the whole blocks take 426.2; every block full, the same
+359.8 against 359.7 (PERF.md section 6, PR 62).
 """
 
 import collections
@@ -340,22 +349,31 @@ def paged_decode_walk_steps(live_blocks):
     return max(int(live_blocks), 1)
 
 
-def paged_decode_walk_counts(at, block_m, window=None):
+def paged_decode_walk_counts(at, block_m, window=None, nb=None, widths=(),
+                             selected=False):
     """Host twin of what the walk does in a CALL, a layer: `at` [tokens,
     slots] (numpy) the positions the call's tokens attend from, its live
     slots only. `live_blocks`: the (slot, logical block) pairs it visits,
     `pos // block_m + 1` a token a slot, and `grid_steps`: the block-axis
     steps it is launched with, summed over the call's tokens; with a
     `window`, the pairs from `window_first_block` on, of `table_blocks`: the
-    pairs the same walk would visit with no window."""
+    pairs the same walk would visit with no window. `rows`: the rows of K
+    (and of V) it moves for those pairs — whole blocks, but for the frontier
+    block of a short table, which moves in tiles of `_frontier_rows` (`nb`
+    the table's blocks, `widths` the pool leaves' and `selected` as the
+    walk's; no `nb`: whole blocks)."""
     whole = at // block_m + 1
     if window:
-        return {"live_blocks": int((whole - window_first_block(
-                    at, block_m, window)).sum()),
-                "table_blocks": int(whole.sum())}
+        live = int((whole - window_first_block(at, block_m, window)).sum())
+        return {"live_blocks": live, "table_blocks": int(whole.sum()),
+                "rows": live * block_m}
+    tr = block_m if nb is None \
+        else _frontier_rows(nb, block_m, widths, selected=selected)
     return {"live_blocks": int(whole.sum()),
             "grid_steps": sum(paged_decode_walk_steps(n)
-                              for n in whole.sum(axis=1))}
+                              for n in whole.sum(axis=1)),
+            "rows": int(((whole - 1) * block_m
+                         + (at % block_m // tr + 1) * tr).sum())}
 
 
 def _heads_per_step(Hkv, head_tile_bytes, share=1):
@@ -371,6 +389,33 @@ def _heads_per_step(Hkv, head_tile_bytes, share=1):
                          or 2 * heads * head_tile_bytes > _WALK_TILE_BYTES):
         heads -= 1
     return heads
+
+
+# The frontier block of a walk is half dead on average: 1 / (2 x table blocks)
+# of what a full table moves. Tables of up to `_CUT_TABLE_BLOCKS` blocks (a
+# sixteenth and more) move it in tiles of `_FRONTIER_ROWS` rows; longer ones
+# keep the whole-block walk, which stands at 92-93% of its count there.
+_CUT_TABLE_BLOCKS = 8
+_FRONTIER_ROWS = 128
+
+
+def _frontier_rows(nb, block_m, widths, window=None, selected=False):
+    """The rows `tr` of the tiles the walk moves its FRONTIER block in, read
+    from shapes alone: `block_m` (the whole block: `_paged_walk_kernel`, the
+    walk of every table past `_CUT_TABLE_BLOCKS` blocks) unless the table is
+    short, and then `_FRONTIER_ROWS` (`_paged_cut_kernel`) — whole tiles of
+    every pool dtype (16 rows bfloat16, 32 int8). `widths`: the pool
+    leaves' last dimensions, whole lane tiles each for the cut: Mosaic
+    copies no row slice of a narrower leaf (an int8 pool's scale columns, a
+    head of 64), so such a pool keeps the whole block. So does a walk with a
+    `window` (its dead rows lie in its FIRST block) or a sparse layer's
+    `selected` bias, whatever its table. The kernel and its host twin
+    (`paged_decode_walk_counts`) share this one definition."""
+    if (window or selected or nb > _CUT_TABLE_BLOCKS
+            or block_m <= _FRONTIER_ROWS or block_m % _FRONTIER_ROWS
+            or any(width % _LANES for width in widths)):
+        return block_m
+    return _FRONTIER_ROWS
 
 
 def _leaf_heads(leaf_heads, Hkv, heads):
@@ -435,6 +480,98 @@ def _paged_walk_kernel(cnt_ref, slot_ref, blk_ref, pos_ref, bt_ref, q_ref,
         o_ref[0] = _softmax_result(acc_ref, l_ref).astype(o_ref.dtype)
 
 
+def _paged_cut_kernel(cnt_ref, slot_ref, blk_ref, pos_ref, bt_ref, q_ref,
+                      *refs, load_head, sm_scale, block_m, tr, last_block,
+                      leaf_groups, sink=False):
+    # `_paged_walk_kernel`'s grid, steps and softmax state, for a short table
+    # (`_frontier_rows`): the pool's leaves stay in HBM (`pl.ANY`) and a step
+    # copies ITS pair's live row tiles of `tr` rows itself — every tile of a
+    # block below the slot's frontier, those up to `pos` of the frontier
+    # block — into one half of `bufs` ([2, heads, block_m, x] a leaf) while
+    # the step before computes on the other. The live tiles are folded in ONE
+    # update, its static row count a branch: an update's fixed part (two
+    # cross-lane reductions, alpha, the accumulator's rescale: 1.4 us of 16
+    # heads on a v5e) is paid a pair, not a tile. `leaf_groups`: a leaf's
+    # `_leaf_heads` group function. No window, no selection (the rule keeps
+    # those on the whole block).
+    pool_hbm = refs[:len(leaf_groups)]
+    rest = list(refs[len(leaf_groups):])
+    sink_ref = rest.pop(0) if sink else None
+    o_ref, acc_ref, m_ref, l_ref, *bufs, sem = rest
+    g, i = pl.program_id(0), pl.program_id(1)
+    groups, items = pl.num_programs(0), pl.num_programs(1)
+    half = (g * items + i) % 2
+
+    def pair(ii):
+        """(slot, logical block, pos, live row tiles) of work item `ii`:
+        no tile in the one step of a call with nothing live."""
+        b = slot_ref[ii]
+        j = blk_ref[ii]
+        pos = pos_ref[b]
+        rows = jnp.where(j == jnp.minimum(pos // block_m, last_block),
+                         jnp.minimum(pos - j * block_m, block_m - 1) + 1,
+                         block_m)
+        return b, j, pos, jnp.where(ii < cnt_ref[0], pl.cdiv(rows, tr), 0)
+
+    def each_copy(gg, ii, half, act):
+        """`act` ("start" | "wait") on the copies of item `ii`'s live row
+        tiles, head group `gg`, into half `half` of the buffers: a wait
+        names the copies its start named."""
+        b, j, _, tiles = pair(ii)
+        block = bt_ref[b, j]
+        for t in range(block_m // tr):
+            @pl.when(t < tiles)
+            def _():
+                for hbm, buf, group in zip(pool_hbm, bufs, leaf_groups):
+                    mine = buf.shape[1]
+                    getattr(pltpu.make_async_copy(
+                        hbm.at[block, pl.ds(group(gg) * mine, mine),
+                               pl.ds(t * tr, tr)],
+                        buf.at[half, :, pl.ds(t * tr, tr)],
+                        sem.at[half]), act)()
+
+    @pl.when(jnp.logical_and(g == 0, i == 0))
+    def _first():
+        each_copy(g, i, half, "start")
+
+    # the next step's tiles, on their way while this step computes
+    @pl.when(jnp.logical_or(i + 1 < items, g + 1 < groups))
+    def _next():
+        wraps = i + 1 == items
+        each_copy(jnp.where(wraps, g + 1, g), jnp.where(wraps, 0, i + 1),
+                  1 - half, "start")
+
+    each_copy(g, i, half, "wait")
+    b, j, pos, tiles = pair(i)
+
+    @pl.when(j == 0)
+    def _init():
+        _start_softmax(acc_ref, m_ref, l_ref,
+                       None if sink_ref is None else sink_ref[...])
+
+    def fold(rows):
+        # the block's first `rows` rows, as `_paged_walk_kernel` folds all
+        # of them: the tile that holds `pos` keeps its mask. The heads are a
+        # loop Mosaic unrolls whole, so a branch is TRACED once and not a
+        # head: four branches of sixteen unrolled heads took a step program
+        # 2-6 s more to trace on the serving host (PERF.md section 6, PR 62)
+        live = [buf.at[pl.ds(half, 1), :, pl.ds(0, rows)] for buf in bufs]
+
+        def head(h, _):
+            k, v = load_head(live, h, q_ref.dtype)
+            _online_softmax_tile(q_ref[0, h], k, v, pos - j * block_m, 0,
+                                 acc_ref.at[h], m_ref.at[h], l_ref.at[h],
+                                 sm_scale=sm_scale, block_m=rows)
+        jax.lax.fori_loop(0, q_ref.shape[1], head, None, unroll=True)
+
+    for n in range(1, block_m // tr + 1):
+        pl.when(tiles == n)(functools.partial(fold, n * tr))
+
+    @pl.when(j == jnp.minimum(pos // block_m, last_block))
+    def _finish():
+        o_ref[0] = _softmax_result(acc_ref, l_ref).astype(o_ref.dtype)
+
+
 def _paged_walk(load_head, q, leaves, block_tables, pos, work, sm_scale,
                 interpret, window=None, out_dim=None,
                 name="dstpu_paged_decode", sink=None, selected=None):
@@ -457,7 +594,11 @@ def _paged_walk(load_head, q, leaves, block_tables, pos, work, sm_scale,
     row's online softmax (`_start_softmax`). `selected` [nb, B, 1, block]
     float32: a sparse layer's selection as a bias a (block, slot), 0 at the
     positions the slot's query attends and NEG_INF at the others; the walk
-    still visits every live pair (the call is then named `<name>_sparse`)."""
+    still visits every live pair (the call is then named `<name>_sparse`).
+    A short table's walk (`_frontier_rows` under the block) is the same
+    grid on `_paged_cut_kernel`, which copies a pair's live row tiles
+    itself; every other walk builds the `pallas_call` it built before that
+    kernel was (`tests/step_program_hashes.json`, `long_table_walks`)."""
     if interpret is None:
         interpret = pallas_interpret()
     B, H, hd = q.shape
@@ -501,21 +642,37 @@ def _paged_walk(load_head, q, leaves, block_tables, pos, work, sm_scale,
             lambda g, i, cnt_ref, slot_ref, blk_ref, pos_ref, bt_ref:
             (blk_ref[i], slot_ref[i], 0, 0))]
 
+    tr = _frontier_rows(nb, block_m, [x.shape[-1] for x in leaves], window,
+                        selected is not None)
+    if tr == block_m:
+        kernel = functools.partial(_paged_walk_kernel, window=window)
+        pool_specs = [pair_spec(x) for x in leaves]
+        copied = []
+    else:
+        geometry = [_leaf_heads(x.shape[1], Hkv, heads) for x in leaves]
+        kernel = functools.partial(
+            _paged_cut_kernel, tr=tr,
+            leaf_groups=tuple(group for _, group in geometry))
+        pool_specs = [pl.BlockSpec(memory_space=pl.ANY)] * len(leaves)
+        # what the pipeline would hold of the pool, held by the kernel
+        copied = [pltpu.VMEM((2, mine, block_m, x.shape[-1]), x.dtype)
+                  for x, (mine, _) in zip(leaves, geometry)] \
+            + [pltpu.SemaphoreType.DMA((2,))]
+
     out = pl.pallas_call(
-        functools.partial(_paged_walk_kernel, load_head=load_head,
-                          sm_scale=sm_scale, block_m=block_m,
-                          last_block=nb - 1, window=window, **static),
+        functools.partial(kernel, load_head=load_head, sm_scale=sm_scale,
+                          block_m=block_m, last_block=nb - 1, **static),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=5,
             grid=(Hkv // heads, jnp.maximum(work.count[0], 1)),
             in_specs=[pl.BlockSpec((1, heads, G, hd), slot_index)]
-            + [pair_spec(x) for x in leaves] + sunk_specs,
+            + pool_specs + sunk_specs,
             out_specs=pl.BlockSpec((1, heads, G, out_dim), slot_index),
             scratch_shapes=[
                 pltpu.VMEM((heads, G, out_dim), jnp.float32),
                 pltpu.VMEM((heads, G, _LANES), jnp.float32),
                 pltpu.VMEM((heads, G, _LANES), jnp.float32),
-            ],
+            ] + copied,
         ),
         out_shape=jax.ShapeDtypeStruct((B, Hkv, G, out_dim), q.dtype),
         interpret=interpret,

@@ -86,6 +86,10 @@ StepRecord = collections.namedtuple("StepRecord", [
     "decode_grid_steps",    # block-axis steps `dstpu_paged_decode`'s walk
                         # takes for those positions, a layer, summed over
                         # the call's tokens (KV heads folded out)
+    "decode_walk_rows",     # rows of K (and of V) that walk moves for those
+                        # pairs, a layer: `decode_live_blocks` x block, less
+                        # the dead row tiles of a SHORT table's frontier
+                        # blocks (`decode_attention._frontier_rows`)
     "prefill_live_blocks",  # logical blocks `dstpu_paged_prefill` walks for
                         # this step's chunks, a layer:
                         # (start + chunk - 1) // block + 1 a chunk ...
@@ -150,7 +154,7 @@ StepRecord = collections.namedtuple("StepRecord", [
                         # dense walks as a mask), so read / selected is the
                         # form's waste; all three 0 with no indexer
 ], defaults=(0, 0, 0, 0, 0, 0, "", 0, (), 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0,
-             0, 0, 0, 0, 0, 0, 0, 0, 0, 0))
+             0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0))
 
 RequestRecord = collections.namedtuple("RequestRecord", [
     "uid", "t_submit", "t_admit",

@@ -1,7 +1,12 @@
 """Pallas kernel numerics vs XLA reference (reference pattern: tests/unit/ops/*
 golden-numerics tests). Run in interpret mode on the CPU harness."""
 
+import functools
+import glob
+import hashlib
+import json
 import math
+import os
 
 import jax
 import jax.numpy as jnp
@@ -479,3 +484,175 @@ class TestTheOnlineSoftmaxHasOneForm:
             monkeypatch,
             lambda *a: da.paged_decode_attention_quant(*a, interpret=True),
             q, kq, vq, ks, vs, tables, pos)
+
+
+# ----------------------------------------------------------------------
+# the decode walk's frontier cut (PR 62): a short table's frontier block
+# moves by row tiles, a long table's walk is the call it was
+# ----------------------------------------------------------------------
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+_CUT_BLOCK, _CUT_NB, _CUT_TILE = 512, 3, 128
+# where the cut changes what is copied: both ends of a tile and of a block
+CUT_POSITIONS = [0, _CUT_TILE - 1, _CUT_TILE, _CUT_BLOCK - 1, _CUT_BLOCK,
+                 _CUT_NB * _CUT_BLOCK - 1]
+# (Hkv, G, what is special about the pool or the walk)
+CUT_KINDS = {"g1": (4, 1, None), "g32": (1, 32, None), "int8": (2, 4, "int8"),
+             "kr_pool": (2, 2, "kr_pool"), "sink": (2, 4, "sink"),
+             "head-groups": (4, 2, "groups")}
+
+
+def _dense_walk(q, keys, values, tables, pos, sm_scale, sink=None):
+    """The walk's result by a dense softmax over the gathered blocks,
+    float32: q [B, H, dk]; keys / values [N, Hkv, block, dk | dv]; `sink`
+    [H] a logit a head that joins the denominator and has no value."""
+    B, H, _ = q.shape
+    Hkv = keys.shape[1]
+
+    def gathered(pool):
+        x = jnp.moveaxis(pool[tables], 2, 1)        # [B, Hkv, nb, block, w]
+        return x.reshape(B, Hkv, -1, x.shape[-1]).astype(jnp.float32)
+    k, v = gathered(keys), gathered(values)
+    s = jnp.einsum("bkgd,bkmd->bkgm", q.reshape(B, Hkv, H // Hkv, -1)
+                   .astype(jnp.float32), k) * sm_scale
+    s = jnp.where(jnp.arange(k.shape[2]) <= pos[:, None, None, None], s,
+                  -1e30)
+    if sink is not None:
+        s = jnp.concatenate([s, jnp.broadcast_to(
+            sink.reshape(1, Hkv, -1, 1), s.shape[:3] + (1,))], axis=-1)
+        v = jnp.concatenate([v, jnp.zeros_like(v[:, :, :1])], axis=2)
+    out = jnp.einsum("bkgm,bkmd->bkgd", jax.nn.softmax(s, axis=-1), v)
+    return out.reshape(B, H, -1)
+
+
+@pytest.mark.parametrize("pos", CUT_POSITIONS)
+@pytest.mark.parametrize("kind", list(CUT_KINDS))
+def test_a_short_tables_walk_reads_its_frontier_block_by_row_tiles(
+        kind, pos, monkeypatch):
+    """`_paged_cut_kernel` (interpreted) against the gather oracle with the
+    frontier at both ends of a row tile and of a block and at the table's
+    last position, a dead slot between the live ones, one query row a KV
+    head and 32, the keys' half tile apart, a sink — and the int8 pool,
+    whose scale columns no row slice of can be copied: the rule keeps it on
+    the whole block, and the call says which walk was built. "head-groups":
+    two KV heads a step of four, so a head group's last step starts the
+    next group's first copies."""
+    from deepspeed_tpu.inference.quantization import quantize_kv
+    from deepspeed_tpu.ops.pallas import decode_attention as da
+    Hkv, G, special = CUT_KINDS[kind]
+    hd, block, nb = 128, _CUT_BLOCK, _CUT_NB
+    rng = np.random.default_rng(62 + pos)
+    N = 1 + 2 * nb
+    normal = lambda *shape: jnp.asarray(rng.normal(size=shape), jnp.float32)
+    k, v = normal(N, Hkv, block, hd), normal(N, Hkv, block, hd)
+    # slot 1 is dead: its table is the trash block throughout
+    tables = np.zeros((3, nb), np.int32)
+    tables[[0, 2]] = rng.permutation(np.arange(1, N)).reshape(2, nb)
+    tables = jnp.asarray(tables)
+    at = jnp.asarray([pos, 300, 700], jnp.int32)
+    scale = 1.0 / math.sqrt(hd)
+    if special == "int8":
+        (kq, ks), (vq, vs) = quantize_kv(k, 32), quantize_kv(v, 32)
+        q = normal(3, Hkv * G, hd)
+        walk = lambda: da.paged_decode_attention_quant(
+            q, kq, vq, ks, vs, tables, at, interpret=True)
+        want = da.paged_decode_attention_quant_reference(
+            q, {"k": kq, "v": vq, "k_scale": ks, "v_scale": vs}, tables, at)
+    elif special == "kr_pool":
+        kr = normal(N, Hkv // 2, block, 128)
+        q = normal(3, Hkv * G, hd + 128)
+        scale = 1.0 / math.sqrt(hd + 128)
+        walk = lambda: da.paged_decode_attention(
+            q, k, v, tables, at, sm_scale=scale, kr_pool=kr, interpret=True)
+        want = _dense_walk(q, jnp.concatenate(
+            [k, jnp.repeat(kr, 2, axis=1)], axis=-1), v, tables, at, scale)
+    elif special == "sink":
+        q, logit = normal(3, Hkv * G, hd), normal(Hkv * G)
+        walk = lambda: da.paged_decode_attention(
+            q, k, v, tables, at, sink=logit, interpret=True)
+        want = _dense_walk(q, k, v, tables, at, scale, logit)
+    else:
+        if special == "groups":
+            monkeypatch.setattr(da, "_WALK_TILE_BYTES",
+                                2 * 2 * 2 * block * hd * 4)
+        q = normal(3, Hkv * G, hd)
+        walk = lambda: da.paged_decode_attention(q, k, v, tables, at,
+                                                 interpret=True)
+        want = da.paged_decode_attention_reference(q, k, v, tables, at)
+    # the kernel's own copies, or the pipeline's whole blocks
+    cut = special != "int8"
+    assert ("dma_start" in str(jax.make_jaxpr(walk)())) == cut
+    got = np.asarray(walk())
+    np.testing.assert_allclose(got[[0, 2]], np.asarray(want)[[0, 2]],
+                               rtol=2e-5, atol=2e-5)
+    assert not got[1].any()
+
+
+def _served_walk_shapes(config):
+    """(q, pool leaf, tables, pos) of a benchmark configuration's decode
+    walk as `ShapeDtypeStruct`s, from its file: slots, heads, block and
+    table as served."""
+    with open(os.path.join(ROOT, "benchmark", "configs", config)) as f:
+        cfg = json.load(f)
+    s = cfg["serving"]
+    B, block = s["max_slots"], s["kv_block_size"]
+    H, Hkv = cfg["num_attention_heads"], cfg["num_key_value_heads"]
+    hd = cfg.get("head_dim") or cfg["hidden_size"] // H
+    sds = jax.ShapeDtypeStruct
+    return (sds((B, H, hd), jnp.bfloat16),
+            sds((s["num_kv_blocks"], Hkv, block, hd), jnp.bfloat16),
+            sds((B, s["max_context"] // block), jnp.int32),
+            sds((B,), jnp.int32))
+
+
+def _long_table_walk_hashes():
+    """The jaxpr text of `paged_decode_attention` at Granite's and
+    Qwen3-Next's served table shapes (10 blocks: past the cut), hashed:
+    written to `tests/step_program_hashes.json` (`long_table_walks`) by this
+    function in the tree PR 62 started from."""
+    from deepspeed_tpu.ops.pallas.decode_attention import \
+        paged_decode_attention
+    out = {}
+    for config in ("granite-4.0-h-small-10l-ep4.json",
+                   "qwen3-next-80b-a3b-12l-ep8.json"):
+        q, pool, tables, pos = _served_walk_shapes(config)
+        text = str(jax.make_jaxpr(functools.partial(
+            paged_decode_attention, interpret=False))(
+                q, pool, pool, tables, pos))
+        out[config[:-5]] = hashlib.sha256(text.encode()).hexdigest()[:16]
+    return out
+
+
+def test_a_long_tables_walk_is_the_call_it_was_before_the_cut():
+    with open(os.path.join(ROOT, "tests", "step_program_hashes.json")) as f:
+        assert _long_table_walk_hashes() == json.load(f)["long_table_walks"]
+
+
+def test_the_rule_cuts_the_short_tables_of_the_benchmark_and_no_other():
+    """Every configuration the benchmark serves, by its own file: the eight
+    tables of ten blocks and more keep the whole block (Granite's and
+    Qwen3-Next's walks would read past 100% of a count made of whole
+    blocks), OLMoE's three blocks and SDAR's five move their frontier block
+    in tiles of 128 rows; a window or a sparse layer's selection keeps the
+    whole block whatever the table."""
+    from deepspeed_tpu.ops.pallas.decode_attention import _frontier_rows
+    tiles = {}
+    for path in sorted(glob.glob(os.path.join(ROOT, "benchmark", "configs",
+                                              "*.json"))):
+        with open(path) as f:
+            cfg = json.load(f)
+        if "serving" not in cfg:
+            continue
+        s = cfg["serving"]
+        tiles[os.path.basename(path).split("-")[0]] = _frontier_rows(
+            s["max_context"] // s["kv_block_size"], s["kv_block_size"],
+            (128, 128))
+    assert tiles == {"olmoe": 128, "sdar": 128, **dict.fromkeys(
+        ["glm", "granite", "k", "keye", "mimo", "mistral", "nemotron",
+         "qwen3"], 512)}
+    assert _frontier_rows(3, 512, (128, 128), window=128) == 512
+    assert _frontier_rows(3, 512, (128, 128), selected=True) == 512
+    assert _frontier_rows(3, 512, (128, 4)) == 512      # int8 scale columns
+    assert _frontier_rows(3, 128, (128, 128)) == 128    # a block of one tile
+    assert _frontier_rows(8, 512, (640,)) == 128 \
+        and _frontier_rows(9, 512, (640,)) == 512

@@ -694,7 +694,14 @@ def test_counters_are_reported_only_where_the_model_names_some(family):
     else:
         assert all(len(r.counters) == len(names) for r in records)
         totals = np.sum([r.counters for r in records], axis=0)
-        assert dict(zip(names, totals)) == serving.stats()["step_counters"]
+        # ... and beside the model's, the decode walks' pairs and the rows
+        # they moved (whole blocks: these tables' blocks are one tile)
+        walks = {f: sum(getattr(r, f) for r in records)
+                 for f in ("decode_live_blocks", "decode_walk_rows")}
+        assert walks["decode_walk_rows"] \
+            == walks["decode_live_blocks"] * serving.block_size > 0
+        assert dict(zip(names, totals), **walks) \
+            == serving.stats()["step_counters"]
         # every token of every call routed once a layer, chunks included
         assert totals[names.index("moe_router_calls")] > 0
 

@@ -50,7 +50,7 @@ STEP_FIELDS = (
     "latent_chunk_positions", "prefill_kept_pairs",
     "prefill_window_kept_pairs", "fused_chunks", "ssm_state_bytes",
     "ssm_chunk_tokens", "device_calls", "overlapped_calls", "chunk_groups",
-    "padded_chunks")
+    "padded_chunks", "decode_walk_rows")
 CALL_FIELDS = ("program", "rows", "win", "firsts", "chunks", "forwards",
                "block_rows", "emitted")
 

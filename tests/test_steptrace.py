@@ -616,6 +616,45 @@ def test_decode_walk_counts_equal_a_hand_count_from_the_lengths(window):
     assert sum(r.decode_grid_steps for r in recs) == want
 
 
+def test_a_short_tables_decode_walk_serves_generates_tokens_and_books_its_rows():
+    """A table of three blocks of 256 through the scheduler with the kernel
+    forced (`use_flash_attention`; interpreted here): the walk moves its
+    frontier block in tiles of 128 rows (`decode_attention._frontier_rows`),
+    the tokens are `generate`'s, and `decode_walk_rows` is the hand count —
+    under `decode_live_blocks` x block, which a whole-block walk moves."""
+    import dataclasses
+    _mk_mesh()
+    block, tile = 256, 128
+    cfg = dataclasses.replace(TINY, n_head=2, n_kv_head=2, d_model=256,
+                              max_seq_len=768, use_flash_attention=True)
+    engine = init_inference(
+        model=make_gpt_decode_model(cfg=cfg, name="tiny"),
+        config={"dtype": "float32", "kv_cache_dtype": "float32",
+                "greedy": True, "kv_block_size": block,
+                "max_out_tokens": 768})
+    serving = engine.serving(max_slots=2, max_context=768, prefill_chunk=128,
+                             decode_steps_per_sync=2)
+    lengths = [(100, 5), (250, 9), (300, 4)]    # a tile's, a block's edge
+    rng = np.random.default_rng(62)
+    reqs = [Request(uid=i, max_new_tokens=m, stop_on_eos=False,
+                    tokens=rng.integers(0, 256, (n,)).astype(np.int32))
+            for i, (n, m) in enumerate(lengths)]
+    done = serving.run(reqs)
+    for r in reqs:
+        want = engine.generate(r.tokens[None], max_new_tokens=r.max_new_tokens)
+        np.testing.assert_array_equal(done[r.uid].tokens,
+                                      np.asarray(want)[0])
+    assert serving.attention_programs()["decode_step"] == "paged_kernel"
+    fed = [n + t for n, m in lengths for t in range(-(-(m - 1) // 2) * 2)]
+    recs = serving.steptrace.records()
+    assert sum(r.decode_live_blocks for r in recs) \
+        == sum(p // block + 1 for p in fed)
+    moved = sum(p // block * block + (p % block // tile + 1) * tile
+                for p in fed)
+    assert sum(r.decode_walk_rows for r in recs) == moved \
+        < sum(r.decode_live_blocks for r in recs) * block
+
+
 def test_compiles_names_the_step_that_compiled():
     serving = _engine().serving(max_slots=2, max_context=128)
     serving.run(_requests(2))

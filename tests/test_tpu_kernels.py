@@ -834,6 +834,49 @@ def test_grouped_mixed_call_on_two_kinds_matches_the_calls_of_its_own():
     close(part[:G - 1], jnp.stack(firsts[:G - 1]))
 
 
+@pytest.mark.parametrize("cell", ["olmoe", "sdar"])
+def test_the_frontier_cut_compiled_at_the_served_shapes(cell, monkeypatch):
+    """`_paged_cut_kernel` at the two served shapes whose tables are short
+    (OLMoE: 64 slots, 16 KV heads of one query row, 3 blocks; SDAR: 128
+    slots, 4 KV heads of 32 rows — 8 query heads x a block of 4 positions —
+    5 blocks, `pos | 3`): frontiers at both ends of every row tile of the
+    first block, of a later block and at the table's last position, dead
+    slots between the live ones — against the gather oracle, and against the
+    whole-block walk the rule is steered back to."""
+    from deepspeed_tpu.ops.pallas import decode_attention as da
+    B, Hkv, G, nb, N = {"olmoe": (64, 16, 1, 3, 131),
+                        "sdar": (128, 4, 32, 5, 449)}[cell]
+    assert da._frontier_rows(nb, 512, (128, 128)) == 128
+    edges = [t * 128 + d for t in range(4) for d in (0, 127)]
+    contexts = edges + [512 + e for e in edges] + [nb * 512 - 1, 300, 700]
+    rng = np.random.default_rng(62)
+    kq, kk, kv = jax.random.split(jax.random.PRNGKey(62), 3)
+    q = jax.random.normal(kq, (B, Hkv * G, 128), jnp.bfloat16)
+    k = jax.random.normal(kk, (N, Hkv, 512, 128), jnp.bfloat16)
+    v = jax.random.normal(kv, (N, Hkv, 512, 128), jnp.bfloat16)
+    tables = np.zeros((B, nb), np.int32)
+    pos = np.zeros((B,), np.int32)
+    physical = iter(1 + rng.permutation(N - 1))
+    rows = rng.permutation(B)[:len(contexts)]
+    for b, at in zip(rows, contexts):
+        pos[b] = at | 3 if cell == "sdar" else at
+        for j in range(pos[b] // 512 + 1):
+            tables[b, j] = next(physical)
+    live = np.zeros((B,), bool)
+    live[rows] = True
+    operands = (q, k, v, jnp.asarray(tables), jnp.asarray(pos))
+    walk = lambda: np.asarray(jax.jit(lambda *a: da.paged_decode_attention(
+        *a, interpret=False))(*operands), np.float32)
+    out = walk()
+    ref = np.asarray(da.paged_decode_attention_reference(*operands),
+                     np.float32)
+    np.testing.assert_allclose(out[live], ref[live], atol=3e-2, rtol=3e-2)
+    assert not out[~live].any()
+    monkeypatch.setattr(da, "_frontier_rows",
+                        lambda nb, block_m, *a, **kw: block_m)
+    np.testing.assert_allclose(out, walk(), atol=1e-2, rtol=1e-2)
+
+
 def test_sparse_index_kernels_compiled_at_the_served_shapes():
     """`ops/pallas/sparse_index.py` at Keye-VL-2.0's served shapes (16 index
     heads of 64, blocks of 512, a table of 132, chunks of 1024, `topk` 2048):

@@ -20,16 +20,26 @@ def _blocks_seen(pos, block, window=None):
     return len({p // block for p in range(first, pos + 1)})
 
 
-def _decode(at, block, window=None):
+def _rows_moved(pos, block, tile):
+    """Rows of the blocks under `pos` that a walk moves: every row of a
+    block below the frontier, the frontier block's up to the end of the
+    tile of `tile` rows that holds `pos`."""
+    return len({p for p in range((pos // block + 1) * block)
+                if p // block < pos // block or p // tile <= pos // tile})
+
+
+def _decode(at, block, window=None, tile=None):
     whole = [[_blocks_seen(p, block) for p in row] for row in at]
     if window:
-        return {"live_blocks": sum(_blocks_seen(p, block, window)
-                                   for row in at for p in row),
-                "table_blocks": sum(map(sum, whole))}
+        live = sum(_blocks_seen(p, block, window) for row in at for p in row)
+        return {"live_blocks": live, "table_blocks": sum(map(sum, whole)),
+                "rows": live * block}       # a window walk: whole blocks
     return {"live_blocks": sum(map(sum, whole)),
             # a token's walk is launched with its live pairs (one step where
             # there are none: an empty call still runs)
-            "grid_steps": sum(max(sum(row), 1) for row in whole)}
+            "grid_steps": sum(max(sum(row), 1) for row in whole),
+            "rows": sum(_rows_moved(p, block, tile or block)
+                        for row in at for p in row)}
 
 
 def _chunk(start, C, block, table, window=None):
@@ -56,7 +66,23 @@ CASES = [
     ("decode", lambda: paged_decode_walk_counts(np.array([[511], [512]]), 512),
      lambda: _decode([[511], [512]], 512)),
     ("decode", lambda: paged_decode_walk_counts(np.zeros((2, 0), int), 16),
-     lambda: {"live_blocks": 0, "grid_steps": 2}),
+     lambda: {"live_blocks": 0, "grid_steps": 2, "rows": 0}),
+    # a SHORT table's walk moves its frontier block in tiles of 128 rows
+    # (`_frontier_rows`): positions at both ends of a tile and of a block
+    ("decode", lambda: paged_decode_walk_counts(
+        np.array([[0, 127, 128, 511], [512, 700, 1023, 1535]]), 512, nb=3,
+        widths=(128, 128)),
+     lambda: _decode([[0, 127, 128, 511], [512, 700, 1023, 1535]], 512,
+                     tile=128)),
+    # ... and whole where the table is long, a leaf is narrower than a lane
+    # tile, a sparse layer's selection rides the walk, or the block is a tile
+    *[("decode", lambda block=block, kw=kw: paged_decode_walk_counts(
+        np.array([[0, 130, 600]]), block, **kw),
+       lambda block=block: _decode([[0, 130, 600]], block))
+      for block, kw in [(512, dict(nb=9, widths=(128, 128))),
+                        (512, dict(nb=3, widths=(128, 4))),
+                        (512, dict(nb=3, widths=(128,), selected=True)),
+                        (128, dict(nb=8, widths=(128,))), (512, {})]],
     # a window layer's: window 8 in blocks of 8, window 128 in blocks of 128
     ("window", lambda: paged_decode_walk_counts(
         np.array([[3, 8, 40], [4, 9, 41]]), 8, 8),
